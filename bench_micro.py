@@ -1,4 +1,4 @@
-"""Micro-benchmarks for the decode roofline investigation (VERDICT r4 #2).
+"""Micro-benchmarks for the decode roofline investigation.
 
 Isolates where the gap between measured decode tok/s and the
 weight-bandwidth bound goes:
@@ -10,8 +10,10 @@ weight-bandwidth bound goes:
   * forward-only vs forward+sampling decode step (sampling overhead).
   * KV-cache attention read cost vs context length.
 
-Run on the real chip: `python bench_micro.py` (JSON lines to stdout).
-Not driver-facing — bench.py remains the one-line contract.
+`python bench_micro.py` prints JSON lines; on the chip it runs through the
+chip tool, alone (a chip belongs to one process). tools/perf_smoke.py calls
+its CPU-sized entry points as a CI regression gate — those numbers are CPU
+numbers and say nothing about the device.
 """
 
 import json
@@ -119,11 +121,11 @@ def bench_step_breakdown(preset="1b", quant="int8", multi=32, paged=False):
     from localai_tpu.models import llama as mdl
     from localai_tpu.models.registry import (
         DEBUG_PRESETS,
-        synthetic_quantized_params,
+        synthetic_params,
     )
 
     cfg = dataclasses.replace(DEBUG_PRESETS[preset], dtype="bfloat16")
-    params = synthetic_quantized_params(cfg, quant)
+    params = synthetic_params(cfg, quant)
     runner = ModelRunner(cfg, params, num_slots=8, max_ctx=1024,
                          prefill_buckets=[128], kv_dtype="int8",
                          paged=paged)
@@ -281,10 +283,7 @@ def anatomy_smoke(preset: str = "tiny", num_slots: int = 4,
     for _ in range(dispatches):
         tl = time.perf_counter()
         toks = runner.step_n_async(multi)
-        try:
-            toks.copy_to_host_async()
-        except AttributeError:
-            pass
+        toks.copy_to_host_async()
         launch_acc += (time.perf_counter() - tl) * 1e3
         q.append(toks)
         if len(q) >= depth:

@@ -469,12 +469,17 @@ def serve(app_config: Optional[AppConfig] = None) -> None:
         except Exception as e:  # noqa: BLE001
             log.warning("preload of %s failed: %s", name, e)
     # load_to_memory = eager engine load (parity: LoadToMemory,
-    # startup.go:148-176)
+    # startup.go:148-176). A model the operator asked to have loaded at
+    # boot and that cannot load is fatal: a server that logs a warning and
+    # then answers /readyz with nothing loaded hides the failure until the
+    # first request.
     for name in cfg.load_to_memory or cfg.preload_models:
         try:
             state.manager.get(name)
-        except Exception as e:  # noqa: BLE001
-            log.warning("eager load of %s failed: %s", name, e)
+        except Exception:
+            log.exception("eager load of %s failed; not serving", name)
+            state.shutdown()
+            raise
     if cfg.federated and cfg.federated_router:
         # join a federation: announce our address to the router (parity:
         # the p2p node advertising its service tunnel, federated_server.go)

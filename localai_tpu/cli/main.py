@@ -70,7 +70,9 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--mesh", default=_env_default("mesh", ""),
                      help="mesh shape, e.g. data=2,model=4 (default: auto)")
     run.add_argument("--platform", default=_env_default("platform", None),
-                     help="force JAX platform (cpu for tests)")
+                     help="JAX platform of THIS process (cpu keeps a "
+                          "worker-fleet front door off the chips); "
+                          "workers are not affected")
     # SLO observatory targets (obs.slo): p95 latency bounds in ms; when
     # the error-budget burn rate exceeds --slo-burn-threshold on both the
     # 1m and 5m windows, new generation work is shed with 429+Retry-After
@@ -393,7 +395,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     if cmd == "run":
         if args.platform:
-            os.environ.setdefault("JAX_PLATFORMS", args.platform)
+            # this process only, and never through os.environ: spawned
+            # workers copy the environment, and a server kept off the
+            # chips with --platform cpu must not drag its pinned TPU
+            # workers onto the CPU with it
+            import jax
+
+            jax.config.update("jax_platforms", args.platform)
         from localai_tpu.api.server import serve
         from localai_tpu.config.app_config import AppConfig
 

@@ -165,10 +165,12 @@ class EngineConfig(BaseModel):
                                       # only — nibble-packed along head_dim;
                                       # LOCALAI_KV_DTYPE overrides defaults)
     quantization: Optional[str] = None  # "int8" | "int8_w8a8" | "int4"
-    donate_kv: bool = True            # buffer donation for in-place KV updates
-    decode_steps_per_dispatch: int = 16  # tokens per dispatch (lax.scan) —
-                                      # amortizes host→device RTT; lower it
-                                      # for tighter streaming cadence
+    donate_kv: bool = True            # accepted for config compatibility; the
+                                      # runner always donates the KV cache
+    decode_steps_per_dispatch: int = 16  # tokens per dispatch (lax.scan):
+                                      # one dispatch and one result fetch
+                                      # per that many tokens; lower it for
+                                      # tighter streaming cadence
     pipeline_depth: int = 2           # in-flight decode dispatches
     stream_latency_ms: float = 100.0  # SSE delivery-lag bound: with a stream
                                       # attached the scheduler shrinks the

@@ -10,7 +10,10 @@ Layout: k,v each [num_layers, num_slots, num_kv_heads, max_ctx, head_dim].
 Heads lead the context dim so the last two axes are (context, head_dim) —
 the (sublane, lane) tiling Mosaic requires for the flash kernels' per-head
 HBM→VMEM DMA slices (ops.attention), and a contiguous stream per head.
-All updates are functional; jit donation makes them in-place in HBM.
+All updates are functional. jit donation lets XLA reuse the cache's buffers;
+it does not yet make the update in-place: compiled for v5e, the layer scan
+that carries the cache (models.llama.forward) holds a second, cache-sized
+temp (PERF.md).
 """
 
 from __future__ import annotations

@@ -11,10 +11,14 @@ its slice.
 
 Env derivation by platform:
 
-  * **tpu** — ``TPU_VISIBLE_DEVICES=<ids>`` (libtpu claims only those
-    chips) plus ``TPU_PROCESS_BOUNDS``/``TPU_CHIPS_PER_PROCESS_BOUNDS``
-    cleared to single-process defaults so a pod-sliced parent env can't
-    leak multi-process topology into the worker.
+  * **tpu** — what libtpu needs to run an independent process on a
+    subset of a host's chips (:func:`tpu_process_env`):
+    ``TPU_VISIBLE_CHIPS=<ids>`` plus the slice's own single-process
+    topology (``TPU_CHIPS_PER_PROCESS_BOUNDS`` = the slice's chip grid,
+    ``TPU_PROCESS_BOUNDS=1,1,1``), and ``JAX_PLATFORMS=tpu`` — set
+    explicitly, because the worker inherits the server's environment and
+    the recommended server runs on the CPU; a worker that cannot get its
+    chip must fail, not serve from the CPU.
   * **cpu** — ``JAX_PLATFORMS=cpu`` plus
     ``XLA_FLAGS=--xla_force_host_platform_device_count=<per>`` (virtual
     CPU devices; the CI/test shape).
@@ -24,10 +28,11 @@ Env derivation by platform:
 
 The pure core (:func:`pinning_env`) takes platform/device-count
 explicitly so tests pin the partition math without touching a backend.
-On a real fleet host declare the topology with
-``LOCALAI_FLEET_PIN_PLATFORM=tpu LOCALAI_FLEET_PIN_DEVICES=8`` — the
-API server process must not probe (and thereby claim) the accelerators
-its workers are about to be pinned to (see :func:`derive_pinning_env`).
+The topology is declared, never probed:
+``LOCALAI_FLEET_PIN_PLATFORM=tpu LOCALAI_FLEET_PIN_DEVICES=8``. A chip
+belongs to one process, so the API server process must not initialize the
+accelerators its workers are about to be pinned to (see
+:func:`derive_pinning_env`).
 """
 
 from __future__ import annotations
@@ -66,13 +71,7 @@ def pinning_env(index: int, replicas: int, *, platform: str,
             replicas, n_devices % replicas)
     ids = range(index * per, (index + 1) * per)
     if platform == "tpu":
-        return {
-            "TPU_VISIBLE_DEVICES": ",".join(str(i) for i in ids),
-            # single-process topology inside the slice: a pod-sliced
-            # parent env must not leak its process bounds into the worker
-            "TPU_PROCESS_BOUNDS": "",
-            "TPU_CHIPS_PER_PROCESS_BOUNDS": "",
-        }
+        return tpu_process_env(ids)
     if platform == "cpu":
         return {
             "JAX_PLATFORMS": "cpu",
@@ -85,37 +84,56 @@ def pinning_env(index: int, replicas: int, *, platform: str,
     return {}
 
 
+# a slice's chip grid as libtpu wants it (x,y,z); v5e/v6e hosts are 2-D.
+# Only the one-chip row has run on hardware (PERF.md, PR 21).
+_TPU_CHIP_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def tpu_process_env(chip_ids) -> dict[str, str]:
+    """Env for ONE independent JAX process owning ``chip_ids`` of a TPU
+    host (pure — no jax import); chip_smoke.py pins its one-chip leg with
+    this same recipe. Established on a v5litepod-4 host (libtpu 0.0.34,
+    four concurrent one-chip processes, PR 21): ``TPU_VISIBLE_CHIPS`` alone
+    is not enough — three of four processes abort on libtpu's
+    one-process-per-host lockfile; declaring the process's own topology
+    (chips-per-process bounds that are a subset of the host, process
+    bounds 1,1,1) is what lifts that lock. Per-process runtime ports
+    (``TPU_PROCESS_PORT``/``_ADDRESSES``) are not needed: the processes
+    never talk to each other. Each process sees its chip as device 0."""
+    ids = [int(i) for i in chip_ids]
+    if len(ids) not in _TPU_CHIP_BOUNDS:
+        raise ValueError(
+            f"no TPU process topology for a {len(ids)}-chip slice "
+            f"(have {sorted(_TPU_CHIP_BOUNDS)})")
+    return {
+        "JAX_PLATFORMS": "tpu",
+        "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in ids),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _TPU_CHIP_BOUNDS[len(ids)],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 def derive_pinning_env(index: int, replicas: int) -> dict[str, str]:
     """:func:`pinning_env` for this host's accelerators.
 
     Topology comes from ``LOCALAI_FLEET_PIN_PLATFORM`` +
-    ``LOCALAI_FLEET_PIN_DEVICES`` when set — the operator-declared truth
-    for fleet deployments where the API server itself must not touch the
-    accelerators (the recommended worker-fleet setup runs the server
-    under ``--platform cpu`` so it never holds a TPU chip; probing
-    jax.devices() there would both report the WRONG platform and, on an
-    unforced server, initialize libtpu in the parent and claim every
-    chip the workers need). Falls back to the parent's live backend only
-    when the env is absent — correct for in-process experiments, logged
-    so a misconfigured fleet is diagnosable."""
+    ``LOCALAI_FLEET_PIN_DEVICES`` — the operator-declared truth. There is
+    no fallback to this process's own backend: asking ``jax.devices()``
+    here would initialize libtpu in the server and take every chip the
+    workers are about to be pinned to (a chip belongs to one process), and
+    under ``--platform cpu`` it would report the wrong platform."""
     import os
 
     platform = os.environ.get("LOCALAI_FLEET_PIN_PLATFORM", "")
     nd = os.environ.get("LOCALAI_FLEET_PIN_DEVICES", "")
-    if platform and nd:
-        return pinning_env(index, replicas, platform=platform,
-                           n_devices=int(nd))
-    import jax
-
-    devs = jax.devices()
-    log.info(
-        "device pinning: LOCALAI_FLEET_PIN_PLATFORM/_DEVICES unset; "
-        "deriving from this process's backend (%d %s device(s)) — on a "
-        "TPU host declare the topology via env so the server process "
-        "never initializes (and holds) the chips itself",
-        len(devs), devs[0].platform)
-    return pinning_env(index, replicas, platform=devs[0].platform,
-                       n_devices=len(devs))
+    if not (platform and nd):
+        raise ValueError(
+            "--fleet-device-pinning needs the host topology declared: set "
+            "LOCALAI_FLEET_PIN_PLATFORM (tpu|cpu) and "
+            "LOCALAI_FLEET_PIN_DEVICES (accelerators on this host); the "
+            "server does not probe devices its workers will own")
+    return pinning_env(index, replicas, platform=platform,
+                       n_devices=int(nd))
 
 
 def pinned_worker_env(base: Optional[dict], index: int,

@@ -494,7 +494,8 @@ class ReplicaPool:
                         "occupancy", "queue_depth", "kv_utilization",
                         "total_generated_tokens", "step_ms_p50",
                         "step_ms_p99", "spec_accept_rate",
-                        "spec_tokens_per_dispatch", "error",
+                        "spec_tokens_per_dispatch", "paged_attn_impl",
+                        "error",
                     ) if k in m
                 }
             reps.append(snap)

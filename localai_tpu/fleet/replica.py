@@ -170,6 +170,8 @@ class BaseReplica:
                 round(time.monotonic() - self.checked_mono, 1)
                 if self.checked_mono is not None else None),
             "age_s": round(time.monotonic() - self.started_at, 1),
+            # worker replicas: the device their LoadModel reported
+            "device": getattr(self, "device", None) or None,
         }
 
     # -- transport (subclass responsibility) -------------------------------
@@ -241,6 +243,7 @@ class _ClientReplica(BaseReplica):
     mcfg = None
     app = None
     _client = None
+    device: dict = {}     # what the worker's LoadModel reported it runs on
 
     def _load_model(self) -> None:
         import yaml
@@ -256,6 +259,10 @@ class _ClientReplica(BaseReplica):
         if not res.success:
             raise RuntimeError(
                 f"replica {self.id} LoadModel failed: {res.message}")
+        from localai_tpu.worker.process import check_worker_device
+
+        self.device = check_worker_device(
+            res.message, getattr(self, "_env", None), self.id)
 
     def _dial(self, timeout: float) -> bool:
         return self._client is not None and self._client.health(timeout)

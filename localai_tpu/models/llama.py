@@ -211,10 +211,14 @@ def param_shapes(cfg: LlamaConfig) -> dict:
     return shapes
 
 
-def init_params(rng: jax.Array, cfg: LlamaConfig) -> PyTree:
-    """Random init (testing / benchmarking with synthetic weights)."""
+def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
+    """Random init (testing / benchmarking with synthetic weights). Each
+    leaf is its own jitted program so that, with a ``placement``
+    (parallel.sharding.ParamPlacement), it is generated directly on the
+    devices that will hold it — no leaf is ever whole on one chip first."""
     shapes = param_shapes(cfg)
-    flat, treedef = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(rng, len(flat))
     dtype = jnp.dtype(cfg.dtype)
 
@@ -223,7 +227,15 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> PyTree:
             return jnp.ones(shape, dtype)
         return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dtype)
 
-    return jax.tree.unflatten(treedef, [mk(k, s) for k, s in zip(keys, flat)])
+    leaves = []
+    for k, (kpath, shape) in zip(keys, flat):
+        sh = None
+        if placement is not None:
+            sh = placement.shardings(
+                tuple(p.key for p in kpath), jax.ShapeDtypeStruct(shape, dtype))
+        leaves.append(jax.jit(  # jaxlint: disable=jit-in-loop
+            mk, static_argnums=1, out_shardings=sh)(k, shape))
+    return jax.tree.unflatten(treedef, leaves)
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from localai_tpu.models.llama import LlamaConfig, param_shapes
-from localai_tpu.utils import jaxcompat
 
 log = logging.getLogger(__name__)
 
@@ -68,16 +67,21 @@ def load_llama_params(
     model_dir: str | Path,
     cfg: Optional[LlamaConfig] = None,
     dtype: str = "bfloat16",
-    shard_fn=None,
     hf: Optional[dict] = None,
+    quantization: str = "",
+    placement=None,
 ) -> tuple[LlamaConfig, Any]:
-    """Load an HF llama/mistral/qwen2 checkpoint into the stacked pytree.
-
-    ``shard_fn(path_tuple, np_array) -> jax.Array`` lets the caller place
-    each param with a NamedSharding (device_put per shard); default is
-    single-device jnp.asarray. ``hf`` is the already-parsed config.json
-    (avoids re-reading when the caller has it).
+    """Load an HF llama/mistral/qwen2 checkpoint into the stacked pytree,
+    one leaf at a time: read and stack on the host, cast (or, with
+    ``quantization``, quantize — models.quant.quantize_tensor_host) on the
+    host, then hand the SERVED form to ``placement.put``
+    (parallel.sharding.ParamPlacement), which sends each device its shard.
+    Neither the bf16 model nor an f32 copy of any leaf ever exists on a
+    device, and the host holds one stacked leaf at a time. ``hf`` is the
+    already-parsed config.json (avoids re-reading when the caller has it).
     """
+    from localai_tpu.models.quant import quantize_plan, quantize_tensor_host
+
     model_dir = Path(model_dir)
     if hf is None:
         hf = read_hf_config(model_dir)
@@ -94,12 +98,28 @@ def load_llama_params(
     if body + "embed_tokens.weight" not in tensors:
         if "model.language_model.embed_tokens.weight" in tensors:
             body, head = "model.language_model.", "lm_head.weight"
-    dt = jnp.dtype(dtype)
-    put = shard_fn or (lambda path, a: jnp.asarray(a, dt))
+    if not cfg.tie_word_embeddings and head not in tensors:
+        cfg = LlamaConfig(**{**cfg.__dict__, "tie_word_embeddings": True})
+    np_dtype = np.dtype(jnp.dtype(dtype))
+    expected = param_shapes(cfg)
+
+    def place(path: tuple[str, ...], a: np.ndarray):
+        want = expected[path[0]] if len(path) == 1 else expected[path[0]][path[1]]
+        if tuple(a.shape) != tuple(want):
+            raise ValueError(
+                f"param {path}: shape {a.shape} != expected {want}")
+        plan = quantize_plan(path, a.ndim, quantization) if quantization else None
+        if plan is None:
+            # source dtype stays on the host until here (bf16 checkpoints
+            # stay 2 bytes/elem); the cast is a host pass too
+            leaf = a.astype(np_dtype, copy=False)
+        else:
+            leaf = quantize_tensor_host(a, *plan)
+        if placement is None:
+            return jax.device_put(leaf)
+        return placement.put(path, leaf)
 
     def stack(fmt: str, transpose: bool) -> np.ndarray:
-        # keep source dtype on host (bf16 checkpoints stay 2 bytes/elem);
-        # the device put casts to the target dtype
         mats = []
         for i in range(cfg.num_layers):
             a = _get(tensors, fmt.format(i=i))
@@ -107,13 +127,13 @@ def load_llama_params(
         return np.stack(mats)
 
     L = body + "layers.{i}."
-    layers = {
-        "attn_norm": stack(L + "input_layernorm.weight", False),
-        "wq": stack(L + "self_attn.q_proj.weight", True),
-        "wk": stack(L + "self_attn.k_proj.weight", True),
-        "wv": stack(L + "self_attn.v_proj.weight", True),
-        "wo": stack(L + "self_attn.o_proj.weight", True),
-        "mlp_norm": stack(L + "post_attention_layernorm.weight", False),
+    layer_src: dict[str, Any] = {
+        "attn_norm": (L + "input_layernorm.weight", False),
+        "wq": (L + "self_attn.q_proj.weight", True),
+        "wk": (L + "self_attn.k_proj.weight", True),
+        "wv": (L + "self_attn.v_proj.weight", True),
+        "wo": (L + "self_attn.o_proj.weight", True),
+        "mlp_norm": (L + "post_attention_layernorm.weight", False),
     }
     if cfg.num_experts:
         # Mixtral layout: block_sparse_moe.gate (router) +
@@ -129,49 +149,28 @@ def load_llama_params(
                 ]))
             return np.stack(outer)
 
-        layers["moe_gate"] = stack(
-            L + "block_sparse_moe.gate.weight", True)
-        layers["w_gate"] = stack_experts("w1")
-        layers["w_up"] = stack_experts("w3")
-        layers["w_down"] = stack_experts("w2")
+        layer_src["moe_gate"] = (L + "block_sparse_moe.gate.weight", True)
+        layer_src.update(w_gate="w1", w_up="w3", w_down="w2")
     else:
-        layers["w_gate"] = stack(L + "mlp.gate_proj.weight", True)
-        layers["w_up"] = stack(L + "mlp.up_proj.weight", True)
-        layers["w_down"] = stack(L + "mlp.down_proj.weight", True)
+        layer_src["w_gate"] = (L + "mlp.gate_proj.weight", True)
+        layer_src["w_up"] = (L + "mlp.up_proj.weight", True)
+        layer_src["w_down"] = (L + "mlp.down_proj.weight", True)
     if cfg.attention_bias:
-        layers["bq"] = stack(L + "self_attn.q_proj.bias", False)
-        layers["bk"] = stack(L + "self_attn.k_proj.bias", False)
-        layers["bv"] = stack(L + "self_attn.v_proj.bias", False)
+        layer_src["bq"] = (L + "self_attn.q_proj.bias", False)
+        layer_src["bk"] = (L + "self_attn.k_proj.bias", False)
+        layer_src["bv"] = (L + "self_attn.v_proj.bias", False)
 
+    # one leaf at a time: build on the host, place, drop the host copy
+    layers = {}
+    for name, src in layer_src.items():
+        host = stack_experts(src) if isinstance(src, str) else stack(*src)
+        layers[name] = place(("layers", name), host)
+        del host
     params: dict[str, Any] = {
-        "embed": _get(tensors, body + "embed_tokens.weight"),
-        "final_norm": _get(tensors, body + "norm.weight"),
+        "embed": place(("embed",), _get(tensors, body + "embed_tokens.weight")),
+        "final_norm": place(("final_norm",), _get(tensors, body + "norm.weight")),
         "layers": layers,
     }
     if not cfg.tie_word_embeddings:
-        if head in tensors:
-            params["lm_head"] = _get(tensors, head).T
-        else:
-            cfg = LlamaConfig(**{**cfg.__dict__, "tie_word_embeddings": True})
-
-    placed = jaxcompat.tree_map_with_path(lambda p, a: put(p, a), params)
-    _check_shapes(cfg, placed)
-    return cfg, placed
-
-
-def _check_shapes(cfg: LlamaConfig, params: Any) -> None:
-    expected = param_shapes(cfg)
-
-    def chk(path, exp):
-        node = params
-        for k in path:
-            node = node[k]
-        if tuple(node.shape) != tuple(exp):
-            raise ValueError(f"param {path}: shape {node.shape} != expected {exp}")
-
-    for name, v in expected.items():
-        if isinstance(v, dict):
-            for k, s in v.items():
-                chk((name, k), s)
-        else:
-            chk((name,), v)
+        params["lm_head"] = place(("lm_head",), _get(tensors, head).T)
+    return cfg, params

@@ -337,7 +337,8 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
     shardings. Shared by the serving path and multi-host followers — a
     follower MUST construct a bit-identical runner (same config, same
     seed) so replayed commands keep every host in the same program."""
-    from localai_tpu.models.registry import resolve_model
+    from localai_tpu.models.registry import resolve_config, resolve_model
+    from localai_tpu.parallel.sharding import ParamPlacement
 
     eng = mcfg.engine
     shard = mcfg.sharding
@@ -380,11 +381,11 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
                          model=want_tp)
             )
 
-    model = resolve_model(
-        mcfg.model or mcfg.name,
-        model_path=app.model_path,
-        dtype=eng.dtype,
-    )
+    # the shape first, the weights last: the mesh and every leaf's placement
+    # are fixed before anything is loaded, so each leaf is created in its
+    # served form on the devices that will hold it
+    ref = mcfg.model or mcfg.name
+    cfg = resolve_config(ref, app.model_path, eng.dtype)
     if mesh is None and not explicit_mesh:
         # meshed serving is the default hot path whenever >1 accelerator
         # is visible (pjit tensor-parallel, paged pool sharded over
@@ -394,25 +395,18 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
         # Speculative decoding composes now — the draft runner shares
         # the target's mesh (localai_tpu.spec.ModelDrafter)
         if not (app.mirror_port or eng.grp_attn_n > 1):
-            mesh = _auto_mesh(model.cfg, eng.max_slots)
+            mesh = _auto_mesh(cfg, eng.max_slots)
             if mesh is not None:
                 log.info("auto mesh for %s: %s", mcfg.name,
                          dict(mesh.shape))
+    model = resolve_model(
+        ref,
+        model_path=app.model_path,
+        dtype=eng.dtype,
+        quantization=eng.quantization,
+        placement=ParamPlacement(cfg, mesh),
+    )
     params = model.params
-    if eng.quantization:
-        from localai_tpu.models.quant import quantize_params
-
-        params = quantize_params(params, eng.quantization)
-    if mesh is not None:
-        if mesh.shape.get("pipe", 1) > 1:
-            # layer-sharded capacity mode (parallel.pipeline)
-            from localai_tpu.parallel.pipeline import shard_params_pp
-
-            params = shard_params_pp(params, model.cfg, mesh)
-        else:
-            from localai_tpu.parallel import sharding as shd
-
-            params = shd.shard_params(params, model.cfg, mesh)
     ctx = mcfg.context_size or app.context_size
     # self-extend lifts the trained-context ceiling by the group factor
     # (llama.cpp: n_ctx >= n_ctx_train * ga_n, grpc-server.cpp:535)
@@ -835,6 +829,8 @@ class ModelManager:
         if ext or mcfg.backend == "worker":
             from localai_tpu.worker.serving import WorkerServingModel
 
+            if not ext:
+                self._check_one_process_per_chip(mcfg, spawning_worker=True)
             return WorkerServingModel(
                 mcfg, self.app, self.pool(), external_address=ext or None
             )
@@ -846,6 +842,7 @@ class ModelManager:
             from localai_tpu.models.mamba_serving import MambaServingModel
 
             return MambaServingModel(mcfg, self.app)
+        self._check_one_process_per_chip(mcfg, spawning_worker=False)
         try:
             return build_serving_model(mcfg, self.app)
         except Exception:
@@ -864,6 +861,62 @@ class ModelManager:
                     f"matching endpoint)"
                 ) from None
             raise
+
+    def _check_one_process_per_chip(self, mcfg: ModelConfig, *,
+                                    spawning_worker: bool) -> None:
+        """A TPU chip belongs to one process at a time: a process that
+        has initialized JAX on the TPU holds every chip it can see, and a
+        second process that needs one then fails or hangs inside libtpu
+        with nothing in the log. So an in-process engine and a spawned
+        worker cannot share a host's chips — refuse the second load here,
+        where the reason is known. (A server started with --platform cpu
+        holds no chip; a worker whose ``worker_env`` puts it on the CPU or
+        pins its own chips is the operator's explicit layout.)"""
+        own = (self.app.platform
+               or os.environ.get("JAX_PLATFORMS", "")).split(",")[0]
+        if own == "cpu":
+            return
+        with self._lock:
+            loaded = list(self._models.values())
+        if spawning_worker:
+            wenv = self.app.worker_env or {}
+            if (wenv.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+                    or "TPU_VISIBLE_CHIPS" in wenv):
+                return
+            holders = [sm.name for sm in loaded
+                       if isinstance(sm, ServingModel)]
+            if not holders:
+                return
+            import jax
+
+            if jax.default_backend() != "tpu":  # initialized: holders exist
+                return
+            raise RuntimeError(
+                f"model {mcfg.name!r} (backend: worker) needs a TPU chip, "
+                f"but this server process holds the host's chips for its "
+                f"in-process model(s) {holders}; a chip belongs to one "
+                f"process. Serve every model in-process, or start the "
+                f"server with --platform cpu and serve them all from "
+                f"workers (--fleet-device-pinning partitions the chips)")
+        def spawned_on_tpu(sm) -> bool:
+            # a spawned worker model, or a fleet with spawned replicas
+            # (adopted remotes live on other hosts)
+            if getattr(sm, "external_address", None) is not None:
+                return False
+            pool = getattr(sm, "pool", None)
+            members = ([r for r in pool.members() if r.respawnable]
+                       if hasattr(pool, "members") else [sm])
+            return any((getattr(m, "device", None) or {}).get("platform")
+                       == "tpu" for m in members)
+
+        holders = [sm.name for sm in loaded if spawned_on_tpu(sm)]
+        if holders:
+            raise RuntimeError(
+                f"model {mcfg.name!r} would load in this server process, "
+                f"but spawned worker(s) for {holders} hold this host's TPU "
+                f"chips; a chip belongs to one process. Give this model "
+                f"`backend: worker` too and start the server with "
+                f"--platform cpu")
 
     def _load_fleet(self, mcfg: ModelConfig) -> Any:
         """Build a FleetServingModel: N engine replicas behind one facade

@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from functools import partial
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -170,6 +170,49 @@ def quantize_tensor4(w, axis: int, group: int = 128) -> QuantizedTensor:
     )
 
 
+def quantize_tensor_host(w, axis: int, mode: str = "int8",
+                         group: int = 128) -> QuantizedTensor:
+    """numpy twin of :func:`quantize_tensor` / :func:`quantize_tensor4` for
+    the load path: a checkpoint leaf is quantized on the HOST and only its
+    served form crosses to the device (an f32 copy of a stacked 8B leaf is
+    7.5 GB — on the chip that alone is half of HBM). Same arithmetic in the
+    same order, so the result equals the device version bit for bit
+    (tests/test_load_path.py). Leaves with a leading layer axis quantize one
+    layer at a time, bounding the host f32 working set to one layer."""
+    import numpy as np
+
+    w = np.asarray(w)
+    if w.ndim >= 3 and axis >= 1:
+        parts = [quantize_tensor_host(w[i], axis - 1, mode, group)
+                 for i in range(w.shape[0])]
+        return QuantizedTensor(
+            q=np.stack([p.q for p in parts]),
+            scale=np.stack([p.scale for p in parts]),
+            axis=axis, mode=parts[0].mode)
+    wf = w.astype(np.float32)
+    if mode == "int4":
+        import ml_dtypes
+
+        shape = wf.shape
+        K = shape[axis]
+        g = _group_size(K, group)
+        grouped = wf.reshape(shape[:axis] + (K // g, g) + shape[axis + 1:])
+        amax = np.max(np.abs(grouped), axis=axis + 1)
+        scale = np.maximum(amax, np.float32(1e-8)) / np.float32(7.0)
+        q = np.clip(np.round(grouped / np.expand_dims(scale, axis + 1)),
+                    -7, 7).astype(np.int8).astype(ml_dtypes.int4)
+        return QuantizedTensor(q=q.reshape(shape), scale=scale, axis=axis,
+                               mode="w4")
+    if mode not in ("int8", "int8_w8a8"):
+        raise ValueError(f"unsupported quantization mode {mode!r}")
+    amax = np.max(np.abs(wf), axis=axis)
+    scale = np.maximum(amax, np.float32(1e-8)) / np.float32(127.0)
+    q = np.clip(np.round(wf / np.expand_dims(scale, axis)),
+                -127, 127).astype(np.int8)
+    return QuantizedTensor(q=q, scale=scale, axis=axis,
+                           mode="w8a8" if mode == "int8_w8a8" else "w8")
+
+
 def _grouped_dequant(qt: QuantizedTensor, dtype) -> jax.Array:
     """w4 dequant to ``dtype``: expand scale over its groups."""
     shape = qt.q.shape
@@ -216,14 +259,18 @@ def quantize_lastdim4(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return packed, scale
 
 
-def unpack_int4_lastdim(packed: jax.Array) -> jax.Array:
+def unpack_int4_lastdim(packed: jax.Array, dtype=jnp.int8) -> jax.Array:
     """Inverse of the :func:`quantize_lastdim4` packing: int8 [..., K/2] →
-    int8 [..., K] in [-8, 7]. Low nibbles sign-extend via the left/right
-    arithmetic-shift pair; high nibbles via a plain arithmetic right
-    shift — both are VPU-native, no lookup tables."""
-    lo = jnp.right_shift(jnp.left_shift(packed, 4).astype(jnp.int8), 4)
-    hi = jnp.right_shift(packed, 4)
-    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
+    ``dtype`` [..., K] holding values in [-8, 7]. The shifts run in int32:
+    Mosaic has no int8 vector shift on v5e (``arith.shli`` on i8 fails to
+    legalize), and XLA fuses the widening away. Low nibbles sign-extend
+    via the left/right arithmetic-shift pair; the widened byte's own sign
+    extension makes the high nibble a plain arithmetic right shift. The
+    kernels pass ``dtype=float32`` to skip an int8 round trip."""
+    p32 = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(p32, 28), 28)
+    hi = jnp.right_shift(p32, 4)
+    return jnp.concatenate([lo, hi], axis=-1).astype(dtype)
 
 
 def _int8_dot(xq: jax.Array, wq: jax.Array, transpose_w: bool) -> jax.Array:
@@ -341,51 +388,63 @@ _LAYER_AXES = {
 }
 
 
+def quantize_plan(path: tuple[str, ...], ndim: int,
+                  mode: str) -> Optional[tuple[int, str]]:
+    """(contraction axis, effective mode) for the param leaf at ``path``,
+    or None when it stays in its source dtype (norm gains, qkv biases, the
+    MoE router). THE single statement of which params quantize how — the
+    device path (:func:`quantize_params`), the host load path
+    (models.loader) and the synthetic generator (models.registry) all read
+    it.
+
+    embed quantizes per-row (axis 1) so both the gather and the
+    tied-embedding logits matmul stay exact per-channel, and stays int8
+    even in int4 mode (gather accuracy is cheap — int8 embed is 2% of a
+    4-bit 8B — and the tied-logits path keeps its exact per-channel form);
+    lm_head per output column (axis 0). Stacked layer weights [L, K, N]
+    quantize over K (axis 1) so scales stack [L, N] and scan alongside the
+    weights. Expert-stacked [L, E, K, N] weights contract over axis 2 and
+    are per-channel int8 regardless of mode (moe_up/moe_down fix the einsum
+    layout — group-wise w4 metadata wouldn't survive the scan slice)."""
+    if mode not in ("int8", "int8_w8a8", "int4"):
+        raise ValueError(f"unsupported quantization mode {mode!r}")
+    if path == ("embed",):
+        return 1, ("int8" if mode == "int4" else mode)
+    if path == ("lm_head",):
+        return 0, mode
+    if len(path) == 2 and path[0] == "layers" and path[1] in _LAYER_AXES:
+        if ndim == 4:
+            return 2, "int8"
+        return _LAYER_AXES[path[1]], mode
+    return None
+
+
 def quantize_params(params: PyTree, mode: str = "int8",
                     group: int = 128) -> PyTree:
-    """Quantize a llama param pytree's matmul weights in place of bf16.
-
-    embed is quantized per-row (axis=-1) so both the gather and the
-    tied-embedding logits matmul stay exact per-channel; lm_head per
-    output column (axis=0). Stacked layer weights [L, K, N] quantize over
-    K (axis=1) so scales stack [L, N] and scan alongside the weights.
+    """Quantize a llama param pytree's matmul weights in place of bf16, on
+    the device they live on (tests and tools; the serving load path
+    quantizes on the host, leaf by leaf — :func:`quantize_tensor_host`).
 
     mode: 'int8' (weight-only), 'int8_w8a8' (+ dynamic activation quant,
     native int8 MXU dot), or 'int4' (group-wise int4 weight-only, the
     TPU analogue of the reference's default q4 serving — see
-    QuantizedTensor). For 'int4', layer matmuls go group-wise while embed
-    stays per-row int8: gather accuracy is cheap (int8 embed is 2% of 4-bit
-    8B total) and the tied-logits path keeps its exact per-channel form.
+    QuantizedTensor). Which leaf quantizes how is :func:`quantize_plan`.
     """
-    if mode not in ("int8", "int8_w8a8", "int4"):
-        raise ValueError(f"unsupported quantization mode {mode!r}")
 
-    if mode == "int4":
-        def qt(w, axis):
+    def qt(path, w):
+        plan = quantize_plan(path, w.ndim, mode)
+        if plan is None:
+            return w
+        axis, leaf_mode = plan
+        if leaf_mode == "int4":
             return quantize_tensor4(w, axis, group=group)
-    else:
-        mm_mode = "w8a8" if mode == "int8_w8a8" else "w8"
+        return dataclasses.replace(
+            quantize_tensor(w, axis),
+            mode="w8a8" if leaf_mode == "int8_w8a8" else "w8")
 
-        def qt(w, axis):
-            return dataclasses.replace(quantize_tensor(w, axis), mode=mm_mode)
-
-    out = dict(params)
-    out["embed"] = (quantize_tensor(params["embed"], axis=1)
-                    if mode == "int4" else qt(params["embed"], axis=1))
-    if "lm_head" in params:
-        out["lm_head"] = qt(params["lm_head"], axis=0)
-    layers = dict(params["layers"])
-    moe = layers.get("w_gate") is not None and layers["w_gate"].ndim == 4
-    for name, axis in _LAYER_AXES.items():
-        if moe and name in ("w_gate", "w_up", "w_down"):
-            # expert-stacked [L, E, K, N]: contraction K is axis 2;
-            # per-channel int8 regardless of mode (moe_up/moe_down fix the
-            # einsum layout — group-wise w4 metadata wouldn't survive the
-            # scan slice)
-            layers[name] = quantize_tensor(layers[name], axis=2)
-        else:
-            layers[name] = qt(layers[name], axis=axis)
-    out["layers"] = layers
+    out = {k: qt((k,), v) for k, v in params.items() if k != "layers"}
+    out["layers"] = {k: qt(("layers", k), v)
+                     for k, v in params["layers"].items()}
     return out
 
 

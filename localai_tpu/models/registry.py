@@ -8,19 +8,20 @@ we resolve a weights ref to one JAX model family and load it.
 Refs:
   * a local dir with config.json + *.safetensors  → HF checkpoint
   * "debug:tiny" / "debug:small" / "debug:1b" ... → random-weight presets
-    (byte tokenizer; used by tests and synthetic benchmarks)
+    (byte tokenizer; used by tests, chip_smoke.py and synthetic benchmarks)
 """
 
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from pathlib import Path
 from typing import Any, Optional
 
 import jax
+import jax.numpy as jnp
 
-from localai_tpu.models.llama import LlamaConfig, init_params
-from localai_tpu.utils import jaxcompat
+from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.utils.tokenizer import ByteTokenizer, Tokenizer, load_tokenizer
 
 # Synthetic presets: shapes only, random weights. "llama3-8b" matches
@@ -65,113 +66,141 @@ class LoadedModel:
     image_token_id: Optional[int] = None  # HF image_token_index when present
 
 
-def resolve_model(
-    ref: str,
-    model_path: str | Path = "models",
-    dtype: str = "bfloat16",
-    shard_fn=None,
-    seed: int = 0,
-) -> LoadedModel:
+def resolve_config(ref: str, model_path: str | Path = "models",
+                   dtype: str = "bfloat16") -> LlamaConfig:
+    """The model's shape without its weights — what the manager needs to
+    lay out the mesh and the per-leaf placement BEFORE anything is loaded."""
     if ref.startswith("debug:"):
         name = ref.split(":", 1)[1]
         if name not in DEBUG_PRESETS:
             raise ValueError(
                 f"unknown debug preset {name!r}; have {sorted(DEBUG_PRESETS)}"
             )
-        cfg = dataclasses.replace(DEBUG_PRESETS[name], dtype=dtype)
-        params = init_params(jax.random.key(seed), cfg)
-        if shard_fn is not None:
-            params = jaxcompat.tree_map_with_path(shard_fn, params)
-        return LoadedModel(cfg, params, ByteTokenizer(), ref)
+        return dataclasses.replace(DEBUG_PRESETS[name], dtype=dtype)
+    cand = _checkpoint_dir(ref, model_path)
+    from localai_tpu.models.loader import load_hf_config
 
+    return dataclasses.replace(load_hf_config(cand), dtype=dtype)
+
+
+def _checkpoint_dir(ref: str, model_path: str | Path) -> Path:
     for cand in (Path(ref), Path(model_path) / ref):
         if (cand / "config.json").exists():
-            from localai_tpu.models.loader import (
-                load_llama_params,
-                read_hf_config,
-            )
-
-            hf = read_hf_config(cand)
-            cfg, params = load_llama_params(
-                cand, dtype=dtype, shard_fn=shard_fn, hf=hf
-            )
-            cfg = dataclasses.replace(cfg, dtype=dtype)
-            return LoadedModel(
-                cfg, params, load_tokenizer(cand), ref,
-                model_dir=cand,
-                hf_type=hf.get("model_type", ""),
-                image_token_id=hf.get("image_token_index"),
-            )
+            return cand
     raise FileNotFoundError(
         f"model ref {ref!r} not found (looked for config.json under {ref} and "
         f"{Path(model_path) / ref})"
     )
 
 
-def synthetic_quantized_params(
-    cfg: LlamaConfig, mode: str = "int8", group: int = 128, seed: int = 0
-) -> Any:
-    """Random weights generated DIRECTLY in quantized form — an 8B-class
-    bf16 init (16 GB) would not fit a single v5e chip, but its int8 form
-    (8 GB) does. Used by bench.py for north-star-shaped synthetic serving;
-    scale magnitudes match init_params' 0.02-std gaussians so activations
-    stay in a realistic range."""
-    import jax.numpy as jnp
+def resolve_model(
+    ref: str,
+    model_path: str | Path = "models",
+    dtype: str = "bfloat16",
+    seed: int = 0,
+    quantization: str = "",
+    placement=None,
+) -> LoadedModel:
+    """Load (or synthesize) a model in its SERVED form: with
+    ``quantization`` set the params come back quantized, and with a
+    ``placement`` (parallel.sharding.ParamPlacement) every leaf is created
+    on the devices that will hold it — checkpoint leaves are quantized on
+    the host and sent shard by shard, debug presets are generated in place.
+    The bf16 model is never resident on a device when a quantized one was
+    asked for."""
+    if ref.startswith("debug:"):
+        cfg = resolve_config(ref, model_path, dtype)
+        params = synthetic_params(cfg, quantization, seed=seed,
+                                  placement=placement)
+        return LoadedModel(cfg, params, ByteTokenizer(), ref)
 
+    cand = _checkpoint_dir(ref, model_path)
+    from localai_tpu.models.loader import load_llama_params, read_hf_config
+
+    hf = read_hf_config(cand)
+    cfg, params = load_llama_params(
+        cand, dtype=dtype, hf=hf, quantization=quantization,
+        placement=placement,
+    )
+    cfg = dataclasses.replace(cfg, dtype=dtype)
+    return LoadedModel(
+        cfg, params, load_tokenizer(cand), ref,
+        model_dir=cand,
+        hf_type=hf.get("model_type", ""),
+        image_token_id=hf.get("image_token_index"),
+    )
+
+
+def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
+                     seed: int = 0, placement=None) -> Any:
+    """Seeded random weights for a debug preset, generated leaf by leaf
+    DIRECTLY in their served form and on the devices that will hold them
+    (each leaf is one jitted program whose ``out_shardings`` come from
+    ``placement``). An 8B-class bf16 init (16 GB) does not fit a v5e chip,
+    its int8 form (8 GB) does; this is how the server loads
+    ``debug:llama3-8b`` with ``engine.quantization`` set.
+
+    Without ``quantization`` this is models.llama.init_params. Quantized
+    leaves are uniform random integers over the full range with a constant
+    scale chosen so the dequantized weights keep init_params' 0.02
+    amplitude — activations stay in a realistic range without ever
+    materializing a float copy to quantize; norm gains are 1, biases 0."""
     from localai_tpu.models import llama as mdl
-    from localai_tpu.models.quant import QuantizedTensor, _group_size
+    from localai_tpu.models.quant import (QuantizedTensor, _group_size,
+                                          quantize_plan)
 
-    if mode not in ("int8", "int4", "int8_w8a8"):
-        raise ValueError(f"unsupported synthetic quant mode {mode!r}")
+    if not quantization:
+        return mdl.init_params(jax.random.key(seed), cfg, placement)
     shapes = mdl.param_shapes(cfg)
-    keys = iter(jax.random.split(jax.random.key(seed), 32))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    keys = jax.random.split(jax.random.key(seed), len(flat))
+    dtype = jnp.dtype(cfg.dtype)
 
-    mm8 = "w8a8" if mode == "int8_w8a8" else "w8"
+    def plain(key, shape, name):
+        if name.endswith("norm"):
+            return jnp.ones(shape, dtype)
+        if name in ("bq", "bk", "bv"):
+            return jnp.zeros(shape, dtype)
+        # the tiny MoE router stays in the compute dtype
+        return (jax.random.normal(key, shape, jnp.float32) * 0.02
+                ).astype(dtype)
 
-    def qweight(shape, axis, bits):
-        lim, mm = (7, "w4") if bits == 4 else (127, mm8)
+    def quantized(key, shape, axis, mode):
+        bits = 4 if mode == "int4" else 8
+        lim = 7 if bits == 4 else 127
         # raw uint8 bits reinterpreted as int8 — no int32 intermediates
         # (randint would spike 4× the tensor size during generation)
         v = jax.lax.bitcast_convert_type(
-            jax.random.bits(next(keys), shape, jnp.uint8), jnp.int8
-        )
+            jax.random.bits(key, shape, jnp.uint8), jnp.int8)
         if bits == 4:
             q = jnp.maximum(v >> 4, -7).astype(jnp.int4)
+            K = shape[axis]
+            sshape = (shape[:axis] + (K // _group_size(K, 128),)
+                      + shape[axis + 1:])
         else:
             q = jnp.maximum(v, -127)
-        if bits == 4:
-            K = shape[axis]
-            gc = K // _group_size(K, group)
-            sshape = shape[:axis] + (gc,) + shape[axis + 1:]
-        else:
             sshape = shape[:axis] + shape[axis + 1:]
-        scale = jnp.full(sshape, 0.02 / lim, jnp.float32)
-        return QuantizedTensor(q=q, scale=scale, axis=axis, mode=mm)
+        mm = {"int4": "w4", "int8_w8a8": "w8a8"}.get(mode, "w8")
+        return QuantizedTensor(
+            q=q, scale=jnp.full(sshape, 0.02 / lim, jnp.float32),
+            axis=axis, mode=mm)
 
-    bits = 4 if mode == "int4" else 8
-    dtype = jnp.dtype(cfg.dtype)
-    params: dict = {
-        # embeddings stay int8 even in int4 mode (see quantize_params)
-        "embed": qweight(shapes["embed"], 1, 8),
-        "final_norm": jnp.ones(shapes["final_norm"], dtype),
-    }
-    if "lm_head" in shapes:
-        params["lm_head"] = qweight(shapes["lm_head"], 0, bits)
-    layers = {}
-    for name, shape in shapes["layers"].items():
-        if name in ("attn_norm", "mlp_norm"):
-            layers[name] = jnp.ones(shape, dtype)
-        elif name in ("bq", "bk", "bv"):
-            layers[name] = jnp.zeros(shape, dtype)
-        elif name == "moe_gate":  # tiny router stays in the compute dtype
-            layers[name] = (jax.random.normal(
-                next(keys), shape, jnp.float32) * 0.02).astype(dtype)
-        elif len(shape) == 4:     # expert-stacked moe weights: int8 only
-            layers[name] = qweight(shape, 2, 8)
+    leaves = []
+    for key, (kpath, shape) in zip(keys, flat):
+        path = tuple(k.key for k in kpath)
+        plan = quantize_plan(path, len(shape), quantization)
+        if plan is None:
+            make = partial(plain, key, shape, path[-1])
         else:
-            layers[name] = qweight(shape, 1, bits)
-    params["layers"] = layers
-    return params
+            make = partial(quantized, key, shape, *plan)
+        shardings = (placement.shardings(path, jax.eval_shape(make))
+                     if placement is not None else None)
+        # one program per leaf: XLA fuses generation into the (sharded)
+        # output, so a leaf's working set is the leaf itself
+        leaves.append(jax.jit(  # jaxlint: disable=jit-in-loop
+            make, out_shardings=shardings)())
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def resolve_tokenizer(ref: str, model_path: str | Path = "models"):

@@ -4,9 +4,9 @@ Three independent questions, three tools:
 
   * **Is the device answering at all?** :func:`probe_device` dispatches a
     tiny jitted add from a SIDE thread and joins it with a timeout — the
-    only safe way to ask, because a dead tunnel makes the dispatch block
-    forever and a blocked probe must never take the caller (the bench main
-    thread, an HTTP handler) down with it. The probe program is compiled
+    only safe way to ask, because a device that stopped answering makes
+    the dispatch block forever and a blocked probe must never take the
+    caller (an HTTP handler) down with it. The probe program is compiled
     once per process; repeat probes are a microsecond dispatch.
   * **How full is it?** :func:`device_memory` reads per-device
     ``memory_stats()`` (bytes_in_use / peak / limit — absent on CPU, where
@@ -20,9 +20,10 @@ Three independent questions, three tools:
 
 :func:`roofline` is the shared peak table the compiled-program cost
 observatory (obs.compile) divides by: known TPU generations by device_kind
-substring, env overrides ``LOCALAI_PEAK_GBPS``/``LOCALAI_PEAK_TFLOPS``,
-and an explicitly marked ``assumed`` fallback for unknown hosts (the CPU
-test mesh still gets a nonzero fraction, clearly labeled).
+substring, env overrides ``LOCALAI_PEAK_GBPS``/``LOCALAI_PEAK_TFLOPS``.
+A device that is not in the table has NO peak — the observatory then
+reports no roofline fraction ("not measured") instead of a fraction of an
+invented number.
 """
 
 from __future__ import annotations
@@ -36,8 +37,10 @@ from typing import Any, Iterable, Optional
 from localai_tpu.obs.metrics import REGISTRY, Registry
 
 # device_kind substring (lowercased) → (peak HBM GB/s, peak bf16 TFLOP/s).
-# Public spec-sheet numbers; the observatory reports fractions, so ±10% on
-# the peak moves the fraction, not the measured numerator.
+# Source: Google Cloud TPU documentation, "System architecture" pages per
+# generation (cloud.google.com/tpu/docs/system-architecture-tpu-vm and the
+# v5e / v5p / v6e pages), per-chip HBM bandwidth and peak bf16 compute.
+# jax reports a v5e chip as device_kind "TPU v5 lite".
 _ROOFLINES = (
     ("v6", (1640.0, 918.0)),
     ("v5p", (2765.0, 459.0)),
@@ -47,22 +50,20 @@ _ROOFLINES = (
     ("v3", (900.0, 123.0)),
     ("v2", (700.0, 46.0)),
 )
-# unknown device (CPU test mesh): a deliberately modest desktop-class guess,
-# reported with assumed=True so nobody mistakes the fraction for a
-# measurement of the host
-_ASSUMED = (25.0, 0.5)
 
 
 def roofline(device: Optional[Any] = None) -> dict:
     """Peak bandwidth/compute for ``device`` (default: first jax device).
-    ``{"peak_gbps", "peak_tflops", "source": "env"|"table"|"assumed"}``."""
+    ``{"peak_gbps", "peak_tflops", "source": "env"|"table"|"unknown"}`` —
+    a peak is None when neither the env nor the table gives it (the CPU
+    test mesh, a TPU generation newer than the table)."""
     env_bw = os.environ.get("LOCALAI_PEAK_GBPS")
     env_fl = os.environ.get("LOCALAI_PEAK_TFLOPS")
     if env_bw or env_fl:
         try:
             return {
-                "peak_gbps": float(env_bw or _ASSUMED[0]),
-                "peak_tflops": float(env_fl or _ASSUMED[1]),
+                "peak_gbps": float(env_bw) if env_bw else None,
+                "peak_tflops": float(env_fl) if env_fl else None,
                 "source": "env",
             }
         except ValueError:
@@ -80,8 +81,23 @@ def roofline(device: Optional[Any] = None) -> dict:
         if sub in kind:
             return {"peak_gbps": bw, "peak_tflops": fl, "source": "table",
                     "device_kind": kind}
-    return {"peak_gbps": _ASSUMED[0], "peak_tflops": _ASSUMED[1],
-            "source": "assumed", "device_kind": kind}
+    return {"peak_gbps": None, "peak_tflops": None, "source": "unknown",
+            "device_kind": kind}
+
+
+def device_report() -> dict:
+    """The backend this process computes on, as jax reports it:
+    ``{"platform", "device_kind", "device_count"}``. A spawned worker
+    returns this from LoadModel so its parent can refuse a replica that
+    came up on the wrong device (worker.process.check_worker_device)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": getattr(devices[0], "device_kind", ""),
+        "device_count": len(devices),
+    }
 
 
 # -- liveness probe ---------------------------------------------------------
@@ -126,8 +142,8 @@ def probe_device(timeout: float = 5.0, *,
                  fn: Optional[Any] = None) -> ProbeResult:
     """Run a liveness round-trip in a side thread; join with ``timeout``.
 
-    A hung tunnel leaves the probe thread blocked (daemon — it dies with
-    the process) and returns ok=False error="timeout" in ``timeout``
+    A device that never answers leaves the probe thread blocked (daemon —
+    it dies with the process) and returns ok=False error="timeout" in ``timeout``
     seconds instead of hanging the caller. ``fn`` is a test hook
     (inject a blocking callable to exercise the timeout path)."""
     reg = registry or REGISTRY
@@ -298,7 +314,7 @@ def update_device_gauges(runners: Iterable[Any] = (),
                          registry: Optional[Registry] = None) -> None:
     """Scrape-time refresh (no device dispatch): memory_stats + census.
     The probe is deliberately NOT here — /metrics must never push work onto
-    a possibly-wedged device; probes run from /debug/devices, the bench,
-    or an operator."""
+    a possibly-wedged device; probes run from /debug/devices or an
+    operator."""
     device_memory(registry)
     hbm_census(known_arrays(runners), registry)

@@ -512,8 +512,9 @@ class Registry:
         self.paged_kernel_impl = Gauge(
             "localai_paged_kernel_impl",
             "1 for the paged decode attention implementation each engine "
-            "selected (impl=pallas|lax) — a silent fallback off the "
-            "Pallas kernel flips the labeled series",
+            "selected (impl=pallas|pallas_interpret|lax): the compiled "
+            "Mosaic kernel, the same kernel in the Pallas interpreter, or "
+            "gather + XLA",
         )
         self.kv_invariant_violations = Counter(
             "localai_kv_invariant_violations_total",
@@ -682,7 +683,7 @@ def update_engine_gauges(name: str, m: dict,
         if impl:
             # one-hot over the impl label so a kernel→fallback flip is a
             # visible series transition, not a silent value change
-            for label in ("pallas", "lax"):
+            for label in ("pallas", "pallas_interpret", "lax"):
                 reg.paged_kernel_impl.set(
                     1.0 if impl == label else 0.0, model=name, impl=label)
     if "kv_tier_spills" in m:

@@ -1,12 +1,22 @@
 """TPU kernels (Pallas) and their selection policy.
 
-``resolve_attn_impl`` decides the attention implementation for the engine:
-  * "pallas"  — flash kernels (ops.attention), the default on real TPU
-  * "xla"     — pure-XLA grouped attention (models.llama._grouped_attn),
-                the default off-TPU and the numerical reference
-  * "pallas_interpret" — flash kernels in interpreter mode (CPU tests)
+The engine has three attention implementations:
+  * "pallas"  — flash kernels (ops.attention), compiled by Mosaic; TPU only
+  * "xla"     — pure-XLA grouped attention (models.llama._grouped_attn) and
+                the gather-based paged reference; the default off-TPU and
+                the numerical reference
+  * "pallas_interpret" — the flash kernels in the Pallas interpreter (CPU
+                tests and debugging; never chosen automatically)
 
-Override with env ``LOCALAI_ATTN_IMPL`` or per-runner ``attn_impl=``.
+``auto`` means "pallas" on a TPU backend and "xla" everywhere else. The
+selectors never trade a kernel for XLA behind the caller's back: on a TPU
+a shape the kernels cannot take is an error that names the gate and the
+explicit ``attn_impl: xla`` override, and "pallas" without a TPU backend is
+an error that names "pallas_interpret". Every combination a selector
+answers "pallas" for is compiled for v5e in tests/test_tpu_compile.py.
+
+Override with env ``LOCALAI_ATTN_IMPL`` / ``LOCALAI_PAGED_ATTN_IMPL`` or
+per-runner ``attn_impl=``.
 """
 
 from __future__ import annotations
@@ -28,54 +38,75 @@ __all__ = [
     "paged_decode_attention_ref",
     "prefill_attention",
     "resolve_attn_impl",
+    "select_attn_impl",
     "select_paged_attn_impl",
 ]
+
+_OVERRIDE = ("set engine.attn_impl: xla to serve this shape with XLA "
+             "attention instead")
 
 
 def resolve_attn_impl(requested: str = "auto",
                       backend: str | None = None) -> tuple[str, bool]:
     """Returns (impl, interpret) with impl in {"xla", "pallas"}."""
-    backend = backend or jax.default_backend()
     impl = requested
     if impl in ("auto", ""):
         # env only overrides the default, never an explicit per-runner choice
         impl = os.environ.get("LOCALAI_ATTN_IMPL", "") or "auto"
+    return _resolve(impl, backend or jax.default_backend())
+
+
+def _resolve(impl: str, backend: str) -> tuple[str, bool]:
+    """auto → the backend default; validates the name; "pallas" off-TPU is
+    an error, never a quiet switch to the interpreter."""
     if impl in ("auto", ""):
         impl = "pallas" if backend == "tpu" else "xla"
     if impl == "pallas_interpret":
         return "pallas", True
     if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
-    return impl, impl == "pallas" and backend != "tpu"
+    if impl == "pallas" and backend != "tpu":
+        raise ValueError(
+            f"attention impl 'pallas' needs a TPU backend (this one is "
+            f"{backend!r}); ask for 'pallas_interpret' to run the kernels "
+            f"in the Pallas interpreter")
+    return impl, False
+
+
+def _check_tp_heads(num_heads: int, num_kv_heads: int, tp: int) -> None:
+    # under a mesh the flash kernels run per-device via shard_map (slots
+    # on 'data', heads on 'model') — both head counts must split evenly or
+    # the per-shard GQA grouping misaligns (a replicated-KV pool has no
+    # per-shard head group to walk)
+    if tp > 1 and (num_heads % tp or num_kv_heads % tp):
+        raise ValueError(
+            f"Pallas attention under tensor_parallel {tp} needs both head "
+            f"counts ({num_heads} q / {num_kv_heads} kv) divisible by it; "
+            f"{_OVERRIDE}")
 
 
 def select_attn_impl(requested: str, *, num_heads: int, num_kv_heads: int,
                      head_dim: int, max_ctx: int, tp: int = 1,
-                     backend: str | None = None) -> tuple[str, bool, str]:
-    """The FULL engine attention-impl decision — resolve_attn_impl plus
-    every fallback gate ModelRunner applies, as one pure function so CI can
-    assert which path a given (model, mesh) lands on at hardware shapes
-    (VERDICT r4 #9: a silent Pallas→XLA fallback regression must fail a
-    test, not just slow the bench).
+                     backend: str | None = None) -> tuple[str, bool]:
+    """The FULL engine attention-impl decision for the contiguous-cache
+    programs (prefill_attention, decode_attention) — resolve_attn_impl
+    plus every shape gate, as one pure function so CI can assert which
+    path a given (model, mesh) lands on at hardware shapes.
 
-    Returns (impl, interpret, reason) — reason is "" when no fallback
-    fired, else a human-readable explanation.
+    Returns (impl, interpret). Raises ValueError when the resolved impl is
+    the compiled kernel and the shape cannot take it.
     """
     impl, interpret = resolve_attn_impl(requested, backend)
-    if impl == "pallas" and tp > 1 and (num_heads % tp or num_kv_heads % tp):
-        # under a mesh the flash kernels run per-device via shard_map
-        # (slots on 'data', heads on 'model') — head groups must split
-        # evenly or the kernel's GQA grouping would misalign
-        return "xla", False, (
-            f"heads ({num_heads} q / {num_kv_heads} kv) not divisible by "
-            f"tensor_parallel {tp}")
-    if impl == "pallas" and not interpret and (head_dim % 128
-                                               or max_ctx % 128):
-        # Mosaic lane tiling is 128-wide; unaligned head_dim/ctx (tiny
-        # debug models, hd-64 families) take the XLA path on real TPU
-        return "xla", False, (
-            f"head_dim={head_dim} ctx={max_ctx} not 128-aligned")
-    return impl, interpret, ""
+    if impl != "pallas":
+        return impl, interpret
+    _check_tp_heads(num_heads, num_kv_heads, tp)
+    if not interpret and (head_dim % 128 or max_ctx % 128):
+        # Mosaic DMA slices are 128 lanes wide: hd-64 families and
+        # unaligned contexts have no compiled kernel
+        raise ValueError(
+            f"Pallas attention needs head_dim and context 128-aligned "
+            f"(head_dim={head_dim} ctx={max_ctx}); {_OVERRIDE}")
+    return impl, interpret
 
 
 def select_paged_attn_impl(requested: str, *, num_heads: int,
@@ -83,32 +114,30 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
                            block_tokens: int, tp: int = 1,
                            kv_dtype: str = "bfloat16",
                            backend: str | None = None,
-                           tuned=None) -> tuple[str, bool, str]:
+                           tuned=None) -> tuple[str, bool]:
     """Attention-impl decision for the PAGED decode path (the paged analogue
-    of ``select_attn_impl``). Returns (impl, interpret, reason).
+    of ``select_attn_impl``). Returns (impl, interpret); raises ValueError
+    when the resolved impl is the compiled kernel and the shape cannot take
+    it.
 
     The Pallas paged kernel DMAs one [block_tokens, head_dim] physical
     block per online-softmax step, so on hardware it needs Mosaic-tileable
-    blocks: head_dim 128-aligned and block_tokens covering the dtype's
-    sublane minimum (32 covers int8, the narrowest full-width pool dtype).
-    int4 pools are nibble-packed along head_dim, so their DMA'd last dim
-    is head_dim/2 — on hardware that needs head_dim 256-aligned to stay
-    lane-tileable (hd-128 int4 models take the gather fallback unless a
-    tuned or env override proves the kernel). The ``gather + XLA``
-    fallback (ops.paged_decode_attention_ref wired through the paged
-    write policies) has no shape constraints and is the CPU/test path.
+    blocks: head_dim 128-aligned and block_tokens a multiple of 32 (the
+    int8 sublane tile; every such size is compiled for v5e in the tests).
+    int4 pools are nibble-packed along head_dim, so their DMA'd last dim is
+    head_dim/2 — that needs head_dim 256-aligned, and below it the packed
+    rows are also padded back to 128 lanes in HBM, so an hd-128 int4 pool
+    would cost what the int8 pool costs. The ``gather + XLA`` path
+    (ops.paged_decode_attention_ref wired through the paged write
+    policies) has no shape constraints and is the CPU/test path.
 
     Precedence: an explicit ``requested`` wins; then the
     ``LOCALAI_PAGED_ATTN_IMPL`` env override; then a tuned entry from the
     per-shape tuning table (ops.tuning, keyed by head_dim / kv heads /
     kv_dtype / tp — pass ``tuned`` to reuse an entry the caller already
     looked up and skip the second lookup receipt); then the backend
-    default. Hard shape gates apply to every source except the explicit
-    env override-to-xla (tuned "pallas" on an untileable shape still
-    falls back, with the reason reported). A tuned "pallas" is honored
-    ONLY on a real TPU backend: off-TPU that impl would mean the Pallas
-    *interpreter* — orders of magnitude slower — and the table is an
-    automatic source, not a user's explicit interpret opt-in.
+    default. A tuned "pallas" is honored ONLY on a real TPU backend: the
+    table is an automatic source, and off-TPU "pallas" is an error.
     """
     backend = backend or jax.default_backend()
     impl = requested
@@ -122,33 +151,25 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
         if tuned is not None and tuned.impl and (
                 tuned.impl != "pallas" or backend == "tpu"):
             impl = tuned.impl
-    if impl in ("auto", ""):
-        impl = "pallas" if backend == "tpu" else "xla"
-    if impl not in ("pallas", "pallas_interpret", "xla"):
-        raise ValueError(f"unknown paged attention impl {impl!r}")
-    if (impl in ("pallas", "pallas_interpret") and tp > 1
-            and (num_heads % tp or num_kv_heads % tp)):
-        # under a mesh the paged kernel runs per-device via shard_map
-        # (tables/slots on 'data', heads on 'model') — both head counts
-        # must split evenly or the per-shard GQA grouping misaligns (a
-        # replicated-KV pool has no per-shard head group to walk)
-        return "xla", False, (
-            f"heads ({num_heads} q / {num_kv_heads} kv) not divisible by "
-            f"tensor_parallel {tp}")
-    if impl == "pallas_interpret":
-        return "pallas", True, ""
-    interpret = impl == "pallas" and backend != "tpu"
-    if impl == "pallas" and not interpret:
+    impl, interpret = _resolve(impl, backend)
+    if impl != "pallas":
+        return impl, interpret
+    _check_tp_heads(num_heads, num_kv_heads, tp)
+    if num_heads % num_kv_heads:
+        raise ValueError(
+            f"Pallas paged attention needs grouped heads ({num_heads} q / "
+            f"{num_kv_heads} kv); {_OVERRIDE}")
+    if not interpret:
         if head_dim % 128 or block_tokens % 32:
-            return "xla", False, (
-                f"head_dim={head_dim} block_tokens={block_tokens} not "
-                f"Mosaic-tileable (need hd%128==0, bt%32==0)")
+            raise ValueError(
+                f"Pallas paged attention needs Mosaic-tileable blocks "
+                f"(head_dim % 128 == 0, block_tokens % 32 == 0; got "
+                f"head_dim={head_dim} block_tokens={block_tokens}); "
+                f"{_OVERRIDE}")
         if kv_dtype == "int4" and head_dim % 256:
-            # the nibble-packed pool's DMA'd last dim is head_dim/2
-            return "xla", False, (
-                f"int4 pool packs head_dim to {head_dim // 2} lanes "
-                f"(need hd%256==0 for the packed Mosaic tiling)")
-        if num_heads % num_kv_heads:
-            return "xla", False, (
-                f"heads ({num_heads} q / {num_kv_heads} kv) not grouped")
-    return impl, interpret, ""
+            raise ValueError(
+                f"an int4 KV pool packs head_dim {head_dim} into "
+                f"{head_dim // 2}-lane rows, which Mosaic cannot DMA and "
+                f"which HBM tiling pads back to 128 lanes (no saving over "
+                f"int8); use kv_dtype: int8, or {_OVERRIDE}")
+    return impl, interpret

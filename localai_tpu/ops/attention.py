@@ -71,10 +71,15 @@ def _pick_block_aligned(total: int, target: int) -> int:
     return b
 
 
+def _scale_rows(ks_ref, vs_ref):
+    """(ks_block, vs_block) readers for ``_flash_loop`` over VMEM-resident
+    [1, 1, n_blocks, block_k] scale slabs: block i's scales are row i."""
+    return (lambda i: ks_ref[0, 0, pl.ds(i, 1), :],
+            lambda i: vs_ref[0, 0, pl.ds(i, 1), :])
 
 
 def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
-                mask_for_block, scales=None, scale_dma=None, depth: int = 2,
+                mask_for_block, scales=None, depth: int = 2,
                 unpack: bool = False):
     """Online-softmax loop over KV blocks [lo, nb) with ``depth``-deep
     double-buffered DMA (depth 2 = classic ping-pong; 3 keeps one extra
@@ -86,19 +91,14 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
     [rows or 1, block_k] keep-mask. Returns the attention output [rows, hd].
 
     ``scales`` fuses scaled-int8 KV dequantization into the loop:
-    (ks_block, vs_block) functions yielding block i's [block_k] f32
-    per-position scales (read from VMEM-resident scale rows). The dequant
-    never materializes K/V in bf16 — per-position K scales distribute over
-    the score matmul columns (q·(k·s) = (q·k)·s) and V scales over the
-    probability columns (p@(v·s) = (p·s)@v), so both apply as [1, block_k]
-    row multiplies on the VPU while the MXU matmuls stay int8-sourced.
-
-    ``scale_dma`` is the paged-kernel variant of ``scales``: scale rows
-    live per-block in HBM (pool layout, no per-head VMEM residency), so
-    they ride the same double-buffered DMA as K/V. A tuple
-    (ks_hbm(i), vs_hbm(i), ksbuf, vsbuf, kssem, vssem) — block i's [1,
-    block_k] HBM slices plus their [depth, 1, block_k] scratch and
-    semaphores. Mutually exclusive with ``scales``.
+    (ks_block, vs_block) functions yielding block i's [1, block_k] f32
+    per-position scale row (read from VMEM-resident scale rows — one row
+    per KV block, so the read is a sublane index, never a lane slice). The
+    dequant never materializes K/V in bf16 — per-position K scales
+    distribute over the score matmul columns (q·(k·s) = (q·k)·s) and V
+    scales over the probability columns (p@(v·s) = (p·s)@v), so both apply
+    as [1, block_k] row multiplies on the VPU while the MXU matmuls stay
+    int8-sourced.
 
     ``unpack=True`` fuses int4 KV dequantization: the buffered blocks are
     nibble-packed int8 ([block_k, hd/2], models.quant.quantize_lastdim4)
@@ -109,26 +109,14 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
     rows, hd = q.shape
     if scales is not None:
         ks_block, vs_block = scales
-    if scale_dma is not None:
-        ks_hbm, vs_hbm, ksbuf, vsbuf, kssem, vssem = scale_dma
 
     def start(i, slot):
         pltpu.make_async_copy(k_hbm(i), kbuf.at[slot], ksem.at[slot]).start()
         pltpu.make_async_copy(v_hbm(i), vbuf.at[slot], vsem.at[slot]).start()
-        if scale_dma is not None:
-            pltpu.make_async_copy(
-                ks_hbm(i), ksbuf.at[slot], kssem.at[slot]).start()
-            pltpu.make_async_copy(
-                vs_hbm(i), vsbuf.at[slot], vssem.at[slot]).start()
 
     def wait(i, slot):
         pltpu.make_async_copy(k_hbm(i), kbuf.at[slot], ksem.at[slot]).wait()
         pltpu.make_async_copy(v_hbm(i), vbuf.at[slot], vsem.at[slot]).wait()
-        if scale_dma is not None:
-            pltpu.make_async_copy(
-                ks_hbm(i), ksbuf.at[slot], kssem.at[slot]).wait()
-            pltpu.make_async_copy(
-                vs_hbm(i), vsbuf.at[slot], vssem.at[slot]).wait()
 
     # prime the pipeline: depth-1 blocks in flight before the first fold
     # (the loop body keeps exactly depth-1 ahead of the block in hand)
@@ -148,16 +136,14 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
 
         wait(i, slot)
         if unpack:
-            k = _unpack_nibbles(kbuf[slot]).astype(jnp.float32)
-            v = _unpack_nibbles(vbuf[slot]).astype(jnp.float32)
+            k = _unpack_nibbles(kbuf[slot], jnp.float32)
+            v = _unpack_nibbles(vbuf[slot], jnp.float32)
         else:
             k = kbuf[slot].astype(jnp.float32)
             v = vbuf[slot].astype(jnp.float32)
         s = q @ k.T  # [rows, block_k] — MXU
         if scales is not None:
-            s = s * ks_block(i)[None, :]
-        elif scale_dma is not None:
-            s = s * ksbuf[slot]
+            s = s * ks_block(i)
         s = jnp.where(mask_for_block(i), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -166,9 +152,7 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
         # weighted-value numerator
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if scales is not None:
-            p = p * vs_block(i)[None, :]
-        elif scale_dma is not None:
-            p = p * vsbuf[slot]
+            p = p * vs_block(i)
         acc_new = acc * alpha + p @ v
         return m_new, l_new, acc_new
 
@@ -189,9 +173,9 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
                    quantized: bool):
     # k_ref/v_ref are the FULL [S, Hkv, C, hd] cache in HBM (Mosaic only
     # allows whole-array ANY refs); slot/head are picked in the DMA slice.
-    # When quantized, ks/vs_ref are this (slot, head)'s [C] f32 scale rows,
-    # auto-loaded into VMEM by their BlockSpec (a scale row is ≤32 KB even
-    # at 8k context — no manual DMA needed).
+    # When quantized, ks/vs_ref are this (slot, head)'s f32 scales as
+    # [C/block_k, block_k] rows, auto-loaded into VMEM by their BlockSpec
+    # (≤32 KB even at 8k context — no manual DMA needed).
     if quantized:
         ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem = rest
     else:
@@ -219,8 +203,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
 
     scales = None
     if quantized:
-        scales = (lambda i: ks_ref[0, 0, pl.ds(i * block_k, block_k)],
-                  lambda i: vs_ref[0, 0, pl.ds(i * block_k, block_k)])
+        scales = _scale_rows(ks_ref, vs_ref)
     out = _flash_loop(q, (slice_of(k_ref), slice_of(v_ref)),
                       kbuf, vbuf, ksem, vsem, lo, nb, block_k, mask_for_block,
                       scales=scales)
@@ -271,11 +254,13 @@ def decode_attention(
     ]
     args = [positions.astype(jnp.int32), qg, k_cache, v_cache]
     if quantized:
-        # scale rows ride normal VMEM blocks — one [C] f32 row per
-        # (slot, head) grid step (≤32 KB at 8k context)
-        in_specs += [pl.BlockSpec((1, 1, C), lambda s, h: (s, h, 0)),
-                     pl.BlockSpec((1, 1, C), lambda s, h: (s, h, 0))]
-        args += [k_scale, v_scale]
+        # scales ride normal VMEM blocks, one [C/bk, bk] slab per (slot,
+        # head) grid step: the last two block dims equal the array's, which
+        # is what the Mosaic block rule asks of a non-(8,128) block
+        spec = pl.BlockSpec((1, 1, C // bk, bk), lambda s, h: (s, h, 0, 0))
+        in_specs += [spec, spec]
+        args += [k_scale.reshape(S, Hkv, C // bk, bk),
+                 v_scale.reshape(S, Hkv, C // bk, bk)]
     scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
     out = pl.pallas_call(
         kernel,
@@ -410,13 +395,14 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     # k_ref/v_ref are the FULL [N, Hkv, bt, hd] block pool in HBM; the
     # block walked at loop step i is tbl_ref[slot, i] (SMEM block table),
     # so the DMA gathers physically-scattered blocks in logical order.
-    # Scale rows ([N, Hkv, bt] f32 for int8/int4 pools) are per-block in
-    # HBM and ride the same buffered DMA (scale_dma in _flash_loop). int4
-    # pools arrive nibble-packed [N, Hkv, bt, hd/2] and unpack in VMEM
+    # Scales for int8/int4 pools arrive already gathered in logical order,
+    # [MB, bt] f32 per (slot, head), VMEM-resident through their BlockSpec:
+    # a [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
+    # tile at every block size below 128, so it cannot ride the K/V DMA.
+    # int4 pools arrive nibble-packed [N, Hkv, bt, hd/2] and unpack in VMEM
     # after the DMA wait — half the int8 path's bytes per block.
     if quantized:
-        (ks_ref, vs_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf,
-         ksem, vsem, kssem, vssem) = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem = rest
     else:
         o_ref, kbuf, vbuf, ksem, vsem = rest
     s_idx = pl.program_id(0)
@@ -440,18 +426,12 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
             keep &= idx > pos - sliding_window
         return keep
 
-    def scale_slice_of(ref):
-        # keep the head axis as a size-1 slice so src/dst ranks match the
-        # [1, bt] scratch rows (and the DMA stays 2-D for Mosaic tiling)
-        return lambda i: ref.at[tbl_ref[s_idx, i], pl.ds(h_idx, 1)]
-
-    scale_dma = None
+    scales = None
     if quantized:
-        scale_dma = (scale_slice_of(ks_ref), scale_slice_of(vs_ref),
-                     ksbuf, vsbuf, kssem, vssem)
+        scales = _scale_rows(ks_ref, vs_ref)
     out = _flash_loop(q, (slice_of(k_ref), slice_of(v_ref)),
                       kbuf, vbuf, ksem, vsem, lo, nb, bt, mask_for_block,
-                      scale_dma=scale_dma, depth=num_buffers, unpack=int4)
+                      scales=scales, depth=num_buffers, unpack=int4)
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -513,12 +493,13 @@ def paged_decode_attention(
         pltpu.VMEM((depth, bt, v_cache.shape[-1]), v_cache.dtype),
     ]
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        args += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((depth, 1, bt), jnp.float32),
-                    pltpu.VMEM((depth, 1, bt), jnp.float32)]
-    scratch += [pltpu.SemaphoreType.DMA((depth,))] * (4 if quantized else 2)
+        # gather each slot's scale rows in XLA (small: S·MB·Hkv·bt f32) and
+        # hand the kernel one [MB, bt] slab per (slot, head) grid step
+        spec = pl.BlockSpec((1, 1, MB, bt), lambda s, h: (s, h, 0, 0))
+        in_specs += [spec, spec]
+        args += [gather_block_scales(k_scale, tables).reshape(S, Hkv, MB, bt),
+                 gather_block_scales(v_scale, tables).reshape(S, Hkv, MB, bt)]
+    scratch += [pltpu.SemaphoreType.DMA((depth,))] * 2
     out = pl.pallas_call(
         kernel,
         grid=(S, Hkv),

@@ -51,8 +51,7 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int,
 
     @pl.when(k == n_k - 1)
     def _done():
-        scale = s_ref[...].astype(jnp.float32)[None, :]
-        o_ref[...] = (acc_ref[...] * scale).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
 def _pick(total: int, target: int, quantum: int) -> int:
@@ -94,13 +93,15 @@ def w8_matmul(x: jax.Array, q: jax.Array, scale: jax.Array, *,
         in_specs=[
             pl.BlockSpec((Mp, bk), lambda n, k: (0, k)),
             w_spec,
-            pl.BlockSpec((bn,), lambda n, k: (n,)),
+            # scales ride as a [1, N] row: a 1-D f32 operand's XLA tiling
+            # (T(1024)) is not the one Mosaic derives from a (bn,) block
+            pl.BlockSpec((1, bn), lambda n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, scale.astype(jnp.float32).reshape(1, N))
     return out[:M]
 
 
@@ -114,8 +115,11 @@ def _w4_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int,
 
     x = x_ref[...]                                # [M, bk]
     w = q_ref[...].astype(x.dtype)                # [bk, bn]
-    s = s_ref[...].astype(jnp.float32)            # [bk//group, bn]
     gc = w.shape[0] // group
+    # s_ref holds ALL K/group scale rows of this N block (a (bk/group, bn)
+    # block is below Mosaic's 8-sublane minimum); this K step's rows start
+    # at k * gc and are read one at a time (a dynamic multi-row sublane
+    # slice must start on a multiple of 8)
     # per-group scaled partial dots: y = sum_g (x_g @ w_g) * s_g — the
     # group count per block is small and static (e.g. 512/128 = 4)
     for gi in range(gc):
@@ -124,7 +128,7 @@ def _w4_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int,
             w[gi * group:(gi + 1) * group],
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] += part * s[gi][None, :]
+        acc_ref[...] += part * s_ref[pl.ds(k * gc + gi, 1), :]
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -155,13 +159,13 @@ def w4_matmul(x: jax.Array, q: jax.Array, scale: jax.Array, *,
         in_specs=[
             pl.BlockSpec((Mp, bk), lambda n, k: (0, k)),
             pl.BlockSpec((bk, bn), lambda n, k: (k, n)),
-            pl.BlockSpec((bk // group, bn), lambda n, k: (k, n)),
+            pl.BlockSpec((K // group, bn), lambda n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, scale.astype(jnp.float32))
     return out[:M]
 
 

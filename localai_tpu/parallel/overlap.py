@@ -41,13 +41,12 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from localai_tpu.models import llama as mdl
 from localai_tpu.models import quant as qnt
 from localai_tpu.models.llama import LlamaConfig
-from localai_tpu.utils.jaxcompat import shard_map
 
 log = logging.getLogger(__name__)
 

@@ -25,14 +25,12 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from localai_tpu.models import llama as mdl
 from localai_tpu.models import quant as qnt
 from localai_tpu.models.llama import LlamaConfig
-
-from localai_tpu.utils.jaxcompat import shard_map
 
 
 def _pipe_spec(ndim: int) -> P:
@@ -157,19 +155,7 @@ def pp_param_specs(cfg: LlamaConfig, mesh: Mesh) -> dict:
 
 
 def shard_params_pp(params: Any, cfg: LlamaConfig, mesh: Mesh) -> Any:
-    from jax.sharding import NamedSharding
+    """shard_params on a 'pipe' mesh: ParamPlacement picks pp_param_specs."""
+    from localai_tpu.parallel.sharding import shard_params
 
-    from localai_tpu.parallel.sharding import expand_quantized_spec
-
-    specs = pp_param_specs(cfg, mesh)
-
-    def put(spec_leaf, arr):
-        spec = expand_quantized_spec(spec_leaf, arr, mesh)
-        return jax.tree.map(
-            lambda s, a: jax.device_put(a, NamedSharding(mesh, s)),
-            spec, arr, is_leaf=lambda x: isinstance(x, P),
-        )
-
-    return jax.tree.map(
-        put, specs, params, is_leaf=lambda x: isinstance(x, P)
-    )
+    return shard_params(params, cfg, mesh)

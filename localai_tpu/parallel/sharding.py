@@ -31,7 +31,6 @@ import logging
 from typing import Any, Optional
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from localai_tpu.models.llama import LlamaConfig
@@ -225,41 +224,55 @@ def expand_quantized_spec(spec_leaf: P, arr: Any, mesh: Mesh) -> Any:
 def shard_params(
     params: Any, cfg: LlamaConfig, mesh: Mesh
 ) -> Any:
-    """Place an already-loaded param pytree onto the mesh (specs per
-    param_specs + expand_quantized_spec)."""
-    specs = param_specs(cfg, mesh)
+    """Place an already-loaded param pytree onto the mesh, leaf by leaf
+    through :class:`ParamPlacement`. Tests and tools only: the serving load
+    path never holds the unsharded tree on a device in the first place."""
+    from localai_tpu.models.quant import QuantizedTensor
 
-    def put(spec_leaf, arr):
-        spec = expand_quantized_spec(spec_leaf, arr, mesh)
-        return jax.tree.map(
-            lambda s, a: jax.device_put(a, NamedSharding(mesh, s)),
-            spec, arr, is_leaf=lambda x: isinstance(x, P),
-        )
-
-    return jax.tree.map(
-        put, specs, params, is_leaf=lambda x: isinstance(x, P)
-    )
+    place = ParamPlacement(cfg, mesh)
+    return jax.tree_util.tree_map_with_path(
+        lambda kp, leaf: place.put(tuple(k.key for k in kp), leaf), params,
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
 
 
-def make_shard_fn(cfg: LlamaConfig, mesh: Mesh, dtype: str = "bfloat16"):
-    """shard_fn for models.loader.load_llama_params: places each tensor
-    shard-by-shard at load time so the full checkpoint never materializes
-    unsharded in device memory."""
-    import jax.numpy as jnp
+class ParamPlacement:
+    """Where each leaf of the llama param pytree lives, decided BEFORE the
+    leaf exists so the load path can create it there: ``put`` sends a host
+    leaf (numpy, or a QuantizedTensor of numpy) shard by shard to its
+    devices, and ``shardings`` hands a generator the ``out_shardings`` to
+    build a synthetic leaf in place. Nothing unsharded ever lands on one
+    chip (a whole bf16 8B model is 16 GB — a v5e chip's entire HBM).
 
-    specs = param_specs(cfg, mesh)
-    dt = jnp.dtype(dtype)
+    Without a mesh every leaf goes to the default device."""
 
-    def fn(path: tuple, arr: np.ndarray) -> jax.Array:
-        node: Any = specs
-        for k in path:
-            key = getattr(k, "key", getattr(k, "name", k))
+    def __init__(self, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
+        self.mesh = mesh
+        self.specs: Optional[dict] = None
+        if mesh is not None:
+            if mesh.shape.get("pipe", 1) > 1:
+                # layer-sharded capacity mode (parallel.pipeline)
+                from localai_tpu.parallel.pipeline import pp_param_specs
+
+                self.specs = pp_param_specs(cfg, mesh)
+            else:
+                self.specs = param_specs(cfg, mesh)
+
+    def shardings(self, path: tuple[str, ...], leaf: Any) -> Any:
+        """Sharding pytree for the leaf at ``path`` (``leaf`` may hold
+        arrays, numpy or ShapeDtypeStructs — only its structure and scale
+        shape are read); None means the default device."""
+        if self.mesh is None:
+            return None
+        node: Any = self.specs
+        for key in path:
             node = node[key]
-        return jax.device_put(
-            jnp.asarray(arr, dt), NamedSharding(mesh, node)
-        )
+        spec = expand_quantized_spec(node, leaf, self.mesh)
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), spec,
+                            is_leaf=lambda x: isinstance(x, P))
 
-    return fn
+    def put(self, path: tuple[str, ...], leaf: Any) -> Any:
+        sh = self.shardings(path, leaf)
+        return jax.device_put(leaf) if sh is None else jax.device_put(leaf, sh)
 
 
 def slots_per_data_shard(num_slots: int, mesh: Mesh) -> int:

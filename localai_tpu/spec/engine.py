@@ -402,21 +402,20 @@ def build_spec_engine(target: ModelRunner, *,
                          "(want auto | ngram | model)")
     if not draft_ref:
         raise ValueError("drafter 'model' needs a draft_model reference")
-    from localai_tpu.models.registry import resolve_model
+    from localai_tpu.models.registry import resolve_config, resolve_model
+    from localai_tpu.parallel.sharding import ParamPlacement
 
-    draft = resolve_model(draft_ref, model_path=model_path, dtype=dtype)
-    if draft.cfg.vocab_size != target.cfg.vocab_size:
+    dcfg = resolve_config(draft_ref, model_path, dtype)
+    if dcfg.vocab_size != target.cfg.vocab_size:
         raise ValueError(
-            f"draft vocab {draft.cfg.vocab_size} != target vocab "
+            f"draft vocab {dcfg.vocab_size} != target vocab "
             f"{target.cfg.vocab_size} — speculative decoding needs a "
             "shared tokenizer")
-    params = draft.params
-    if target.mesh is not None:
-        from localai_tpu.parallel import sharding as shd
-
-        params = shd.shard_params(params, draft.cfg, target.mesh)
+    # leaves land on the target's mesh as they load (never whole on chip 0)
+    draft = resolve_model(draft_ref, model_path=model_path, dtype=dtype,
+                          placement=ParamPlacement(dcfg, target.mesh))
     runner = ModelRunner(
-        draft.cfg, params,
+        draft.cfg, draft.params,
         num_slots=target.num_slots,
         max_ctx=target.max_ctx,
         prefill_buckets=list(target.buckets[:-1]) or None,

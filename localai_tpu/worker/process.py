@@ -30,6 +30,34 @@ from localai_tpu.worker.client import WorkerClient
 log = logging.getLogger(__name__)
 
 
+def check_worker_device(message: str, env: Optional[dict],
+                        name: str) -> dict:
+    """Read the device a worker's LoadModel reported and refuse the worker
+    when it is not on the platform its spawn env asked for
+    (``JAX_PLATFORMS``; device pinning always sets it). A worker that was
+    meant for a TPU and quietly came up on the CPU would serve — slowly and
+    without a word. Returns the reported device ({} from a third-party
+    worker that reports none)."""
+    import json
+
+    try:
+        device = json.loads(message).get("device") or {}
+    except (ValueError, AttributeError):
+        device = {}
+    want = ((env or {}).get("JAX_PLATFORMS") or "").split(",")[0]
+    got = device.get("platform", "")
+    if want and got and got != want:
+        raise RuntimeError(
+            f"worker {name} was spawned for platform {want!r} but loaded "
+            f"its model on {got!r} ({device.get('device_kind', '')} x"
+            f"{device.get('device_count', '?')}); refusing the replica")
+    if device:
+        log.info("worker %s serves from %s (%s x%s)", name, got,
+                 device.get("device_kind", ""),
+                 device.get("device_count", "?"))
+    return device
+
+
 class WorkerProcess:
     """One spawned worker and its client handle."""
 

@@ -131,7 +131,12 @@ class BackendServicer:
                 mcfg = ModelConfig.model_validate(doc)
                 app = AppConfig(model_path=request.model_path or "models")
                 self._sm = build_serving_model(mcfg, app)
-                return pb.Result(success=True, message="ok")
+                # the parent checks where this worker actually came up
+                # (worker.process.check_worker_device)
+                from localai_tpu.obs.device import device_report
+
+                return pb.Result(success=True, message=json.dumps(
+                    {"device": device_report()}))
             except Exception as e:  # noqa: BLE001 — report, don't crash
                 self._load_error = f"{type(e).__name__}: {e}"
                 log.exception("LoadModel failed")
@@ -632,16 +637,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     # deterministic fault injection (chaos harness): LOCALAI_FAULT_* in a
     # spawned worker's env arms its registry at boot, never per request
     _faults.install_from_env()
-    # honor JAX_PLATFORMS even when a sitecustomize imported jax before the
-    # env var could take effect (jax.config wins until backend init)
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # noqa: BLE001 — backend already initialized
-            pass
     try:
         servicer = SERVICERS[args.servicer]()
     except KeyError:

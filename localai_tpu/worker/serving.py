@@ -137,7 +137,7 @@ class WorkerScheduler:
         # engine-phase spans in the worker process under the same trace id
         self.telemetry = EngineTelemetry(model=owner.name)
         # the RPC stream is a device round-trip once removed: a wedged
-        # worker (or its tunnel) stops the reply stream, and the watchdog
+        # worker stops the reply stream, and the watchdog
         # must see that silence like any other stall
         self.watchdog = obs_watchdog.WATCHDOG
         self._wd_channel = f"rpc:{owner.name}"
@@ -175,7 +175,7 @@ class WorkerScheduler:
     def _run(self, handle: WorkerGenHandle) -> None:
         tr = handle.trace
         # armed across the whole RPC, pulsed per reply: a worker that stops
-        # streaming (dead process, dead tunnel) trips the stall watchdog
+        # streaming (dead process, wedged device) trips the stall watchdog
         # even though grpc's own 600 s deadline is nowhere near
         self.watchdog.arm(self._wd_channel)
         try:
@@ -194,7 +194,7 @@ class WorkerScheduler:
                 watchdog=self.watchdog, channel=self._wd_channel, tr=tr)
             if not got_final:
                 # the stream ended without the final usage Reply: the
-                # worker died (or the tunnel dropped) mid-generation.
+                # worker died (or its connection dropped) mid-generation.
                 # Mark the handle failed — completion_tokens falls back
                 # to the streamed-delta count instead of reporting 0.
                 finish = "error"
@@ -309,6 +309,12 @@ class WorkerServingModel:
             raise RuntimeError(
                 f"worker LoadModel failed for {self.name}: {res.message}"
             )
+        from localai_tpu.worker.process import check_worker_device
+
+        self.device = check_worker_device(
+            res.message,
+            None if self.external_address is not None
+            else self.app.worker_env, self.name)
 
     def touch(self) -> None:
         self.last_used = time.monotonic()

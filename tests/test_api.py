@@ -605,7 +605,8 @@ def test_debug_devices_reports_health_and_census(client):
     assert census["by_category"]["kv_cache"] > 0
     assert data["probe"]["ok"] is True
     assert data["probe"]["seconds"] > 0
-    assert data["roofline"]["peak_gbps"] > 0
+    # the CPU test mesh is not in the peak table: no invented peak
+    assert data["roofline"]["peak_gbps"] is None
     assert isinstance(data["watchdog"], dict)
 
 
@@ -626,20 +627,19 @@ def test_debug_programs_reports_cost_and_roofline_fraction(client):
     })
     assert r.status_code == 200
     data = client.get("/debug/programs").json()
-    assert data["roofline"]["peak_gbps"] > 0
+    assert data["roofline"]["source"] == "unknown"
     programs = data["programs"]
     assert programs
     decode = [p for p in programs
               if p["program"].startswith("decode") and p.get("flops")]
     assert decode, f"no decode cost entry in {programs}"
     d = decode[0]
-    # the acceptance criterion: nonzero FLOPs/bytes and an achieved
-    # bandwidth fraction for the decode-step program on the CPU test mesh
+    # nonzero FLOPs/bytes and an achieved rate for the decode-step
+    # program; no roofline FRACTION on the CPU test mesh — it has no peak
     assert d["flops"] > 0 and d["bytes_accessed"] > 0
-    withfrac = [p for p in decode
-                if p.get("bandwidth_fraction") is not None]
-    assert withfrac, "no decode entry joined with a measured latency"
-    assert withfrac[0]["bandwidth_fraction"] >= 0
+    rated = [p for p in decode if p.get("achieved_gbps") is not None]
+    assert rated, "no decode entry joined with a measured latency"
+    assert all("bandwidth_fraction" not in p for p in decode)
     # filter to live instances: the backend-shutdown test earlier in this
     # module unloads/reloads the model, leaving dead catalog entries
     # (cost_error="program no longer live") next to the live ones.

@@ -1,59 +1,110 @@
-"""Pallas-path guarantees (VERDICT r4 #9): for the hardware shapes that
-matter, the engine's attention-impl decision must land on the flash
-kernels — a silent Pallas→XLA fallback regression fails HERE instead of
-surfacing as a bench slowdown. The decision is a pure function
-(ops.select_attn_impl) evaluated as-if on TPU (backend='tpu'), so these
-assertions hold on CPU CI."""
+"""Pallas-path guarantees: for the hardware shapes that matter, the
+engine's attention-impl decision must land on the flash kernels, and a
+shape the kernels cannot take must be refused at load — never traded for
+XLA behind the caller's back. The decision is a pure function
+(ops.select_attn_impl / ops.select_paged_attn_impl) evaluated as-if on TPU
+(backend='tpu'), so these assertions hold on CPU CI. That every combination
+answered "pallas" here also compiles for v5e is tests/test_tpu_compile.py's
+job."""
 
 import pytest
 
-from localai_tpu.ops import select_attn_impl
+from localai_tpu.ops import (resolve_attn_impl, select_attn_impl,
+                             select_paged_attn_impl)
 
 # Llama-3-8B: 32 q heads / 8 kv heads / head_dim 128 — the north-star
-# serving config (BENCH, debug:llama3-8b)
+# serving config (chip_smoke.py, debug:llama3-8b)
 L8B = dict(num_heads=32, num_kv_heads=8, head_dim=128)
 
 
 @pytest.mark.parametrize("tp", [1, 4, 8])
 @pytest.mark.parametrize("ctx", [1024, 8192])
 def test_llama8b_lands_on_pallas_on_tpu(tp, ctx):
-    impl, interpret, why = select_attn_impl(
-        "auto", **L8B, max_ctx=ctx, tp=tp, backend="tpu")
-    assert impl == "pallas" and not interpret, why
-    assert why == ""
+    assert select_attn_impl(
+        "auto", **L8B, max_ctx=ctx, tp=tp, backend="tpu") == ("pallas", False)
 
 
-def test_llama1b_hd64_falls_back_with_reason():
-    """debug:1b has head_dim 64 — documented XLA fallback, with a reason."""
-    impl, _, why = select_attn_impl(
-        "auto", num_heads=32, num_kv_heads=8, head_dim=64,
-        max_ctx=1024, backend="tpu")
-    assert impl == "xla" and "128-aligned" in why
+@pytest.mark.parametrize("tp", [1, 4, 8])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("bt", [32, 64, 128, 256])
+def test_llama8b_paged_lands_on_pallas_on_tpu(tp, kv_dtype, bt):
+    assert select_paged_attn_impl(
+        "auto", **L8B, block_tokens=bt, tp=tp, kv_dtype=kv_dtype,
+        backend="tpu") == ("pallas", False)
 
 
-def test_unaligned_ctx_falls_back():
-    impl, _, why = select_attn_impl(
-        "auto", **L8B, max_ctx=1000, backend="tpu")
-    assert impl == "xla" and "128-aligned" in why
+def test_llama1b_hd64_is_refused_with_the_override_named():
+    """debug:1b has head_dim 64: no compiled kernel. On a TPU `auto` must
+    say so at load (it used to serve XLA attention with a log.info)."""
+    with pytest.raises(ValueError, match="128-aligned.*attn_impl: xla"):
+        select_attn_impl("auto", num_heads=32, num_kv_heads=8, head_dim=64,
+                         max_ctx=1024, backend="tpu")
+    with pytest.raises(ValueError, match="tileable.*attn_impl: xla"):
+        select_paged_attn_impl(
+            "auto", num_heads=32, num_kv_heads=8, head_dim=64,
+            block_tokens=64, backend="tpu")
+    # the explicit choice is honoured
+    assert select_attn_impl("xla", num_heads=32, num_kv_heads=8, head_dim=64,
+                            max_ctx=1024, backend="tpu") == ("xla", False)
 
 
-def test_indivisible_heads_fall_back_under_tp():
-    impl, _, why = select_attn_impl(
-        "auto", num_heads=32, num_kv_heads=8, head_dim=128,
-        max_ctx=1024, tp=3, backend="tpu")
-    assert impl == "xla" and "divisible" in why
+def test_unaligned_ctx_is_refused():
+    with pytest.raises(ValueError, match="128-aligned"):
+        select_attn_impl("auto", **L8B, max_ctx=1000, backend="tpu")
 
 
-def test_cpu_auto_is_xla_but_interpret_available():
-    impl, interpret, _ = select_attn_impl(
-        "auto", **L8B, max_ctx=1024, backend="cpu")
-    assert impl == "xla"
-    impl, interpret, _ = select_attn_impl(
-        "pallas_interpret", **L8B, max_ctx=1024, backend="cpu")
-    assert impl == "pallas" and interpret
+def test_unaligned_block_tokens_is_refused():
+    with pytest.raises(ValueError, match="block_tokens % 32"):
+        select_paged_attn_impl("auto", **L8B, block_tokens=48, backend="tpu")
 
 
-def test_runner_exposes_decision(tiny_runner=None):
+def test_indivisible_heads_are_refused_under_tp():
+    with pytest.raises(ValueError, match="divisible"):
+        select_attn_impl("auto", **L8B, max_ctx=1024, tp=3, backend="tpu")
+    with pytest.raises(ValueError, match="divisible"):
+        select_paged_attn_impl("pallas_interpret", **L8B, block_tokens=64,
+                               tp=3, backend="cpu")
+
+
+def test_int4_pool_gate():
+    """The nibble-packed pool needs hd%256==0 for the compiled kernel
+    (packed lane dim = hd/2); at hd 128 Mosaic refuses the 64-lane DMA and
+    HBM tiling pads the rows back to 128 lanes, so the selector refuses
+    it rather than gather behind the caller's back. Interpret mode and an
+    explicit xla are unaffected."""
+    with pytest.raises(ValueError, match="int4.*kv_dtype: int8"):
+        select_paged_attn_impl("auto", **L8B, block_tokens=64,
+                               kv_dtype="int4", backend="tpu")
+    assert select_paged_attn_impl(
+        "auto", num_heads=32, num_kv_heads=8, head_dim=256,
+        block_tokens=64, kv_dtype="int4", backend="tpu") == ("pallas", False)
+    assert select_paged_attn_impl(
+        "pallas_interpret", **L8B, block_tokens=64, kv_dtype="int4",
+        backend="tpu") == ("pallas", True)
+    assert select_paged_attn_impl(
+        "xla", **L8B, block_tokens=64, kv_dtype="int4",
+        backend="tpu") == ("xla", False)
+
+
+def test_cpu_auto_is_xla_and_interpret_is_only_ever_explicit():
+    assert select_attn_impl(
+        "auto", **L8B, max_ctx=1024, backend="cpu") == ("xla", False)
+    assert select_attn_impl(
+        "pallas_interpret", **L8B, max_ctx=1024,
+        backend="cpu") == ("pallas", True)
+    # "pallas" off-TPU used to mean the interpreter without saying so
+    for select in (
+        lambda: resolve_attn_impl("pallas", backend="cpu"),
+        lambda: select_attn_impl("pallas", **L8B, max_ctx=1024,
+                                 backend="cpu"),
+        lambda: select_paged_attn_impl("pallas", **L8B, block_tokens=64,
+                                       backend="cpu"),
+    ):
+        with pytest.raises(ValueError, match="pallas_interpret"):
+            select()
+
+
+def test_runner_exposes_decision():
     """The runner's attn_impl reflects select_attn_impl verbatim."""
     from localai_tpu.engine.runner import ModelRunner
     from localai_tpu.models.registry import resolve_model
@@ -65,3 +116,6 @@ def test_runner_exposes_decision(tiny_runner=None):
     r2 = ModelRunner(tiny.cfg, tiny.params, num_slots=2, max_ctx=128,
                      prefill_buckets=[64], attn_impl="xla")
     assert r2.attn_impl == "xla"
+    with pytest.raises(ValueError, match="pallas_interpret"):
+        ModelRunner(tiny.cfg, tiny.params, num_slots=2, max_ctx=128,
+                    prefill_buckets=[64], attn_impl="pallas")

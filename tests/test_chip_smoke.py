@@ -1,0 +1,133 @@
+"""chip_smoke.py's steps, driven at debug:tiny on the CPU — and the proof that
+run plainly it accepts nothing but a TPU.
+
+The chip is reached only through the chip tool; what tier-1 can hold is the
+script's own logic: the kernel phase's cases and references (in the Pallas
+interpreter, asked for explicitly), the server phase's requests and
+assertions against a real ``localai_tpu.cli.main run`` child, and the
+no-path-from-a-failure-to-exit-0 contract."""
+
+import json
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+import chip_smoke  # noqa: E402
+
+# debug:tiny: 4 q heads / 2 kv heads / head_dim 16, max context 512
+TINY = dict(model="debug:tiny", context=512, slots=4)
+TINY_HEADS = {"num_heads": 4, "num_kv_heads": 2, "head_dim": 16}
+
+
+@pytest.fixture()
+def smoke(tmp_path):
+    s = chip_smoke.Smoke(tmp_path / "out", expect_platform="cpu")
+    yield s
+    s.close()
+
+
+def test_kernel_phase_steps_on_cpu(smoke, capfd):
+    report = chip_smoke.kernel_phase(
+        smoke, context=256, slots=4, heads=TINY_HEADS, ffn=128,
+        prefill_buckets=(128,), interpret=True)
+    assert smoke.device["platform"] == "cpu"
+    # parent and child together: every stdout line names the device
+    lines = capfd.readouterr().out.strip().splitlines()
+    assert len(lines) > 5 and all(
+        line.startswith("[platform=") for line in lines)
+    names = [c["case"] for c in report["cases"]]
+    # every entry point the selectors can answer "pallas" for
+    for want in ("paged_decode bfloat16", "paged_decode int8",
+                 "paged_decode int4", "decode bfloat16", "decode int8",
+                 "prefill T=128", "w8_matmul", "w8_matmul transposed",
+                 "w4_matmul"):
+        assert any(n.startswith(want) for n in names), (want, names)
+    assert all(c["ok"] for c in report["cases"])
+    assert report["interpret"] is True
+
+
+def test_server_phase_steps_on_cpu(smoke, capsys):
+    phase = chip_smoke.server_phase(
+        smoke, chips=1, long_prompt=200, expect_impl="lax",
+        # a 64-token prefill chunk so the "longer than the chunk" request
+        # is still chunked at tiny's context
+        engine={"prefill_chunk": 64}, **TINY)
+    assert phase["requests"]["speculative_windows"] >= 1
+    assert phase["requests"]["prefix_tokens_reused"] >= 64
+    assert {"prefill_chunk", "decode_n", "verify"} <= set(
+        phase["compile_count"])
+    # every printed line names the device
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert lines and all(
+        line.startswith("[platform=") and " kind=" in line
+        and " count=" in line for line in lines)
+    assert "platform=cpu kind=cpu count=1" in lines[-1]
+    log = (smoke.out_dir / "server_1chip.log").read_text()
+    assert "loaded model smoke (debug:tiny)" in log
+
+
+def test_server_phase_fails_on_the_wrong_kernel_impl(smoke):
+    """No path from a failed assertion to a passing phase: the CPU server
+    serves gather+XLA, so expecting the compiled kernel must raise (and the
+    child must not be left running)."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="kernel impl"):
+        chip_smoke.server_phase(
+            smoke, chips=1, long_prompt=200, expect_impl="pallas",
+            engine={"prefill_chunk": 64}, **TINY)
+    assert "server_1chip" not in smoke.report["phases"]
+
+
+def test_server_phase_fails_when_the_model_cannot_load(smoke):
+    """A load that fails is fatal: the server exits instead of serving with
+    nothing loaded, and the smoke says so."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="before it was ready"):
+        chip_smoke.server_phase(
+            smoke, chips=1, model="debug:no-such-preset", context=512,
+            slots=4, load_timeout=120)
+
+
+def test_fleet_phase_steps_on_cpu(smoke):
+    """Two pinned one-device workers behind the router, the server itself
+    kept off them (--platform cpu): each worker reports its device, the
+    burst reaches both, SIGTERM ends everything cleanly."""
+    phase = chip_smoke.fleet_phase(smoke, replicas=2, expect_impl="lax",
+                                   **TINY)
+    assert [r["device"]["platform"] for r in phase["replicas"]] == [
+        "cpu", "cpu"]
+    assert all(r["device"]["device_count"] == 1 for r in phase["replicas"])
+    assert all(n > 0 for n in phase["served"].values())
+
+
+def test_plain_run_exits_nonzero_without_a_tpu(tmp_path):
+    """`python chip_smoke.py` in this sandbox: no accelerator, so a non-zero
+    exit within seconds and no result line — never a CPU-served pass."""
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "chip_smoke.py"), "--out",
+         str(tmp_path / "out")],
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    for line in proc.stdout.splitlines():
+        assert not line.startswith("{"), line
+    assert '"ok"' not in proc.stdout
+    assert "kernel phase child exited" in proc.stderr
+
+
+def test_result_line_shape(smoke, monkeypatch, capsys):
+    """The last stdout line of a passing run is the JSON the driver reads."""
+    monkeypatch.setattr(chip_smoke, "Smoke", lambda out: smoke)
+    monkeypatch.setattr(
+        chip_smoke, "kernel_phase",
+        lambda s: s.device.update(platform="tpu", kind="TPU v5 lite",
+                                  count=1))
+    monkeypatch.setattr(chip_smoke, "server_phase", lambda s, chips: {})
+    assert chip_smoke.main([]) == 0
+    assert not smoke.cache_dir.exists()      # nothing left outside the tree
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    assert json.loads(last) == {
+        "ok": True, "chips": 1, "phases": [], "claim": None,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}

@@ -255,6 +255,35 @@ def test_stall_escalates_to_rebuild_and_recovers(tiny):
         wd.stop()
 
 
+def test_cold_compile_is_not_taken_for_a_stall(tiny):
+    """A cold XLA compile of an 8B program runs for tens of seconds on the
+    chip, the stall deadline is 60 s, and the supervisor's cure for a stall
+    is to fence the engine and fail the requests in flight. Compilation
+    happens synchronously inside the jit CALL; the watchdog only counts
+    while a guard is armed, and guards wrap result FETCHES — so no compile,
+    however long, is ever inside one. Pinned here with every program's
+    first dispatch slowed to 3× the deadline: the request completes, no
+    stall is recorded, nothing is rebuilt."""
+    reg, wd, runner, sched, sup = _supervised(tiny, "coldcompile")
+    faults.arm(FaultSpec(site="engine.compile", mode="sleep",
+                         delay_s=3 * wd.deadline, times=0))
+    try:
+        # a multi-chunk prompt: several prefill programs, then decode, then
+        # speculation-free multi-step decode — each "compiles" for 1.2 s
+        done = sched.generate(
+            _req("cold compile " * 4, max_new_tokens=24), timeout=120)
+        assert done.finish_reason in ("stop", "length")
+        assert done.completion_tokens == 24
+        assert faults.snapshot()[0]["fired"] >= 3    # the schedule ran
+        assert sched.rebuilds == 0 and sup.attempts == 0
+        assert not wd.stalled()
+        assert not [line for line in reg.render().splitlines()
+                    if line.startswith("localai_stalls_total")]
+    finally:
+        sched.shutdown()
+        wd.stop()
+
+
 def test_rebuild_exhaustion_marks_model_failed(tiny):
     # every rebuild's probe dispatch is forced to fail (the allocator
     # reports exhaustion forever), so the supervisor must walk its whole

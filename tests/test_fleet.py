@@ -1018,16 +1018,97 @@ def test_pinning_env_partitions_tpu_hosts():
 
     envs = [pinning_env(i, 4, platform="tpu", n_devices=8)
             for i in range(4)]
-    slices = [e["TPU_VISIBLE_DEVICES"] for e in envs]
+    slices = [e["TPU_VISIBLE_CHIPS"] for e in envs]
     assert slices == ["0,1", "2,3", "4,5", "6,7"]  # disjoint, covering
-    # pod-topology env must not leak into single-process workers
-    assert all(e["TPU_PROCESS_BOUNDS"] == "" for e in envs)
+    # each worker is its own single-process topology over its slice (a
+    # pod-sliced parent env must not leak in; without the bounds libtpu's
+    # one-process-per-host lockfile aborts all but one worker)
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,1,1" for e in envs)
+    assert all(e["JAX_PLATFORMS"] == "tpu" for e in envs)
+
+    # one chip each on a four-chip host: the layout chip_smoke.py runs
+    envs = [pinning_env(i, 4, platform="tpu", n_devices=4)
+            for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
 
     # uneven split: remainder devices stay unused, never skew one replica
     envs = [pinning_env(i, 3, platform="tpu", n_devices=8)
             for i in range(3)]
-    assert [e["TPU_VISIBLE_DEVICES"] for e in envs] == \
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == \
         ["0,1", "2,3", "4,5"]
+
+
+def test_pinned_tpu_worker_does_not_inherit_the_servers_cpu_platform(
+        monkeypatch):
+    """The fleet runbook: the server runs under --platform cpu so it holds
+    no chip, and its pinned workers own the TPUs. The worker's spawn env is
+    a copy of the server's os.environ plus the pinning keys — so the flag
+    must not live in os.environ, and the pinning must name the platform
+    itself, or every worker silently serves from the CPU."""
+    import os
+    import sys
+
+    from localai_tpu.cli import main as cli
+    from localai_tpu.fleet import pinning
+    from localai_tpu.worker.process import WorkerProcess
+
+    # 1. --platform no longer writes the process environment
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    served = {}
+    monkeypatch.setitem(
+        sys.modules, "localai_tpu.api.server",
+        type(sys)("fake_server"))
+    sys.modules["localai_tpu.api.server"].serve = (
+        lambda cfg: served.setdefault("platform", cfg.platform))
+    import jax
+
+    before = jax.config.jax_platforms
+    try:
+        assert cli.main(["run", "--platform", "cpu"]) == 0
+    finally:
+        jax.config.update("jax_platforms", before)
+    assert served["platform"] == "cpu"
+    assert "JAX_PLATFORMS" not in os.environ
+
+    # 2. even an ambient JAX_PLATFORMS=cpu (this sandbox's) loses to the
+    # pinning env in the worker's spawn environment
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setenv("LOCALAI_FLEET_PIN_PLATFORM", "tpu")
+    monkeypatch.setenv("LOCALAI_FLEET_PIN_DEVICES", "4")
+    env = pinning.pinned_worker_env(None, 2, 4)
+    spawned = {}
+
+    class FakePopen:
+        def __init__(self, cmd, env=None, **kw):
+            spawned.update(env)
+            raise RuntimeError("spawn captured")
+
+    monkeypatch.setattr("localai_tpu.worker.process.subprocess.Popen",
+                        FakePopen)
+    with pytest.raises(RuntimeError, match="spawn captured"):
+        WorkerProcess("m/r2", env=env).start()
+    assert spawned["JAX_PLATFORMS"] == "tpu"
+    assert spawned["TPU_VISIBLE_CHIPS"] == "2"
+
+
+def test_worker_on_the_wrong_device_is_refused():
+    """A worker reports where its model actually loaded; a replica that
+    was spawned for a TPU and came up elsewhere is refused."""
+    import json
+
+    from localai_tpu.worker.process import check_worker_device
+
+    on = lambda platform: json.dumps({"device": {  # noqa: E731
+        "platform": platform, "device_kind": "k", "device_count": 1}})
+    assert check_worker_device(on("tpu"), {"JAX_PLATFORMS": "tpu"},
+                               "w")["platform"] == "tpu"
+    with pytest.raises(RuntimeError, match="spawned for platform 'tpu'"):
+        check_worker_device(on("cpu"), {"JAX_PLATFORMS": "tpu"}, "w")
+    # no declared platform, or a third-party worker that reports nothing
+    assert check_worker_device(on("cpu"), None, "w")["platform"] == "cpu"
+    assert check_worker_device("ok", {"JAX_PLATFORMS": "tpu"}, "w") == {}
 
 
 def test_pinning_env_cpu_and_unknown_platforms():
@@ -1049,15 +1130,15 @@ def test_pinned_worker_env_operator_keys_win():
 
     orig = pinning.derive_pinning_env
     pinning.derive_pinning_env = lambda i, n: {
-        "TPU_VISIBLE_DEVICES": "0,1", "TPU_PROCESS_BOUNDS": ""}
+        "TPU_VISIBLE_CHIPS": "0,1", "TPU_PROCESS_BOUNDS": "1,1,1"}
     try:
         merged = pinning.pinned_worker_env(
-            {"TPU_VISIBLE_DEVICES": "6,7", "MY_FLAG": "1"}, 0, 2)
+            {"TPU_VISIBLE_CHIPS": "6,7", "MY_FLAG": "1"}, 0, 2)
     finally:
         pinning.derive_pinning_env = orig
-    assert merged["TPU_VISIBLE_DEVICES"] == "6,7"  # explicit wins
+    assert merged["TPU_VISIBLE_CHIPS"] == "6,7"    # explicit wins
     assert merged["MY_FLAG"] == "1"
-    assert merged["TPU_PROCESS_BOUNDS"] == ""      # derived fills gaps
+    assert merged["TPU_PROCESS_BOUNDS"] == "1,1,1"  # derived fills gaps
 
 
 def test_pinning_env_declared_topology_beats_backend_probe(monkeypatch):
@@ -1069,4 +1150,15 @@ def test_pinning_env_declared_topology_beats_backend_probe(monkeypatch):
     monkeypatch.setenv("LOCALAI_FLEET_PIN_PLATFORM", "tpu")
     monkeypatch.setenv("LOCALAI_FLEET_PIN_DEVICES", "8")
     env = pinning.derive_pinning_env(1, 4)
-    assert env["TPU_VISIBLE_DEVICES"] == "2,3"  # not this process's CPUs
+    assert env["TPU_VISIBLE_CHIPS"] == "2,3"  # not this process's CPUs
+
+
+def test_pinning_without_a_declared_topology_is_an_error(monkeypatch):
+    """No fallback to jax.devices() in the server: probing would take
+    every chip the workers are about to be pinned to."""
+    from localai_tpu.fleet import pinning
+
+    monkeypatch.delenv("LOCALAI_FLEET_PIN_PLATFORM", raising=False)
+    monkeypatch.delenv("LOCALAI_FLEET_PIN_DEVICES", raising=False)
+    with pytest.raises(ValueError, match="LOCALAI_FLEET_PIN_PLATFORM"):
+        pinning.derive_pinning_env(0, 4)

@@ -202,21 +202,3 @@ def test_int4_requires_paged():
     with pytest.raises(ValueError, match="int4"):
         ModelRunner(model.cfg, model.params, num_slots=2, max_ctx=128,
                     prefill_buckets=[64], kv_dtype="int4", paged=False)
-
-
-def test_select_paged_attn_impl_int4_gate():
-    """Hardware gate pin: the nibble-packed pool needs hd%256==0 for the
-    Pallas kernel on real TPU (packed lane dim = hd/2); interpret mode
-    and the xla fallback are unaffected."""
-    impl, interpret, why = ops.select_paged_attn_impl(
-        "pallas", num_heads=32, num_kv_heads=8, head_dim=128,
-        block_tokens=64, kv_dtype="int4", backend="tpu")
-    assert impl == "xla" and "int4" in why
-    impl, interpret, why = ops.select_paged_attn_impl(
-        "pallas", num_heads=32, num_kv_heads=8, head_dim=256,
-        block_tokens=64, kv_dtype="int4", backend="tpu")
-    assert impl == "pallas" and not interpret and why == ""
-    impl, interpret, _ = ops.select_paged_attn_impl(
-        "pallas_interpret", num_heads=32, num_kv_heads=8, head_dim=128,
-        block_tokens=64, kv_dtype="int4", backend="tpu")
-    assert impl == "pallas" and interpret

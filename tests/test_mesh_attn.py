@@ -57,15 +57,15 @@ def test_pallas_mesh_greedy_parity(small, ref_seq):
     assert out == ref_seq
 
 
-def test_pallas_mesh_falls_back_when_heads_dont_divide(small):
-    """debug:small has 4 kv heads; tp=8 can't split them — XLA path with a
-    log, not a wrong kernel."""
+def test_pallas_mesh_is_refused_when_heads_dont_divide(small):
+    """debug:small has 4 kv heads; tp=8 can't split them — refused at
+    construction, never a wrong kernel or a quiet XLA path."""
     mesh = build_mesh(MeshPlan(model=8))
     sp = shd.shard_params(small.params, small.cfg, mesh)
-    r = ModelRunner(small.cfg, sp, num_slots=8, max_ctx=256,
+    with pytest.raises(ValueError, match="divisible"):
+        ModelRunner(small.cfg, sp, num_slots=8, max_ctx=256,
                     prefill_buckets=[64], mesh=mesh,
                     attn_impl="pallas_interpret")
-    assert r.attn_impl == "xla"
 
 
 def test_sp_prefill_serves_long_prompt(small):
@@ -128,7 +128,9 @@ def test_sp_through_build_serving_model(tmp_path):
         ))
         h.result(timeout=120)
         assert h.finish_reason in ("stop", "length")
-        assert sm.runner.last_prefill_path == "sp"
+        # the manager serves paged by default, so the ring prefill is the
+        # paged variant
+        assert sm.runner.last_prefill_path == "paged_sp"
     finally:
         sm.scheduler.shutdown()
 

@@ -139,10 +139,10 @@ def test_quantized_moe_serving():
 
 
 def test_synthetic_quantized_moe_params():
-    from localai_tpu.models.registry import synthetic_quantized_params
+    from localai_tpu.models.registry import synthetic_params
 
     cfg = dataclasses.replace(DEBUG_PRESETS["tiny-moe"], dtype="bfloat16")
-    params = synthetic_quantized_params(cfg, "int8")
+    params = synthetic_params(cfg, "int8")
     assert params["layers"]["w_gate"].q.shape[1] == cfg.num_experts
     r = ModelRunner(cfg, params, num_slots=2, max_ctx=128,
                     prefill_buckets=[32], kv_dtype="int8")

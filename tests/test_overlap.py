@@ -12,6 +12,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from localai_tpu.engine.runner import ModelRunner
@@ -19,7 +20,6 @@ from localai_tpu.models.registry import resolve_model
 from localai_tpu.parallel import overlap as ovl
 from localai_tpu.parallel import sharding as shd
 from localai_tpu.parallel.mesh import MeshPlan, build_mesh
-from localai_tpu.utils.jaxcompat import shard_map
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 2, reason="needs >=2 virtual devices")

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from localai_tpu.engine import kvcache as kvc
@@ -14,7 +15,6 @@ from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.models.registry import resolve_model
 from localai_tpu.parallel.mesh import MeshPlan, build_mesh
 from localai_tpu.parallel.ring import ring_attention, sp_prefill_forward
-from localai_tpu.utils.jaxcompat import shard_map
 
 
 @pytest.fixture(scope="module")

@@ -3,6 +3,7 @@ forces xla_force_host_platform_device_count=8 — the simulated-multi-host
 strategy SURVEY.md §4 calls for, absent in the reference)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -97,15 +98,45 @@ def test_kv_replication_fallback_when_tp_exceeds_kv_heads():
     assert spec == P(None, "data", None, None, None)
 
 
-def test_make_shard_fn_places_loader_tensors(mesh):
-    tiny = resolve_model("debug:small", dtype="float32")
-    fn = shd.make_shard_fn(tiny.cfg, mesh, dtype="float32")
-    arr = np.zeros((tiny.cfg.num_layers, tiny.cfg.hidden_size,
-                    tiny.cfg.num_heads * tiny.cfg.hd), np.float32)
-    placed = fn(
-        (jax.tree_util.DictKey("layers"), jax.tree_util.DictKey("wq")), arr
-    )
+def test_param_placement_places_host_leaves_sharded(mesh):
+    """The load path's placement: a host leaf (plain or host-quantized)
+    goes straight to its shards — q with the weight's spec, the scale with
+    the contracted axis dropped."""
+    from localai_tpu.models.quant import quantize_tensor_host
+
+    small = resolve_model("debug:small", dtype="float32")
+    place = shd.ParamPlacement(small.cfg, mesh)
+    arr = np.random.default_rng(0).standard_normal(
+        (small.cfg.num_layers, small.cfg.hidden_size,
+         small.cfg.num_heads * small.cfg.hd)).astype(np.float32)
+    placed = place.put(("layers", "wq"), arr)
     assert placed.sharding.shard_shape(placed.shape)[-1] == arr.shape[-1] // 4
+    qt = place.put(("layers", "wq"), quantize_tensor_host(arr, 1))
+    assert qt.q.sharding.shard_shape(qt.q.shape)[-1] == arr.shape[-1] // 4
+    assert qt.scale.sharding.shard_shape(qt.scale.shape) == (
+        small.cfg.num_layers, arr.shape[-1] // 4)
+    # no mesh: the default device
+    solo = shd.ParamPlacement(small.cfg).put(("layers", "wq"), arr)
+    assert len(solo.sharding.device_set) == 1
+
+
+def test_debug_preset_leaves_are_generated_on_their_shards(mesh):
+    """debug presets never exist unsharded: each leaf's generator program
+    writes straight into the placement's sharding, values unchanged."""
+    small = resolve_model("debug:small", dtype="float32")
+    sharded = resolve_model(
+        "debug:small", dtype="float32",
+        placement=shd.ParamPlacement(small.cfg, mesh))
+    wq = sharded.params["layers"]["wq"]
+    assert wq.sharding.shard_shape(wq.shape)[-1] == wq.shape[-1] // 4
+    np.testing.assert_array_equal(
+        np.asarray(wq), np.asarray(small.params["layers"]["wq"]))
+    q8 = resolve_model(
+        "debug:small", dtype="float32", quantization="int8",
+        placement=shd.ParamPlacement(small.cfg, mesh))
+    up = q8.params["layers"]["w_up"]
+    assert up.q.dtype == jnp.int8 and up.mode == "w8"
+    assert up.q.sharding.shard_shape(up.q.shape)[-1] == up.q.shape[-1] // 4
 
 
 # ---------------------------------------------------------------------------

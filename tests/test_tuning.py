@@ -75,29 +75,30 @@ def _write_table(path, key, **entry):
 
 def test_select_consults_tuned_impl(_fresh_table):
     """A tuned impl drives the auto decision on the shape it was measured
-    for — and ONLY that shape. Off-TPU a tuned "pallas" is IGNORED (it
-    would mean the Pallas interpreter — the table is an automatic source,
-    not an interpret opt-in), while a tuned "xla" is honored anywhere."""
+    for — and ONLY that shape. Off-TPU a tuned "pallas" is IGNORED (the
+    table is an automatic source; off-TPU "pallas" is an error and the
+    interpreter is only ever an explicit choice), while a tuned "xla" is
+    honored anywhere."""
     _write_table(_fresh_table, tuning.shape_key(128, 8, "bfloat16", 1),
                  impl="pallas", block_tokens=64)
-    impl, interpret, why = ops.select_paged_attn_impl(
+    assert ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
-        block_tokens=64, kv_dtype="bfloat16", backend="tpu")
-    assert (impl, interpret, why) == ("pallas", False, "")
+        block_tokens=64, kv_dtype="bfloat16",
+        backend="tpu") == ("pallas", False)
     # the same tuned "pallas" off-TPU falls back to the backend default
-    impl, interpret, _ = ops.select_paged_attn_impl(
+    assert ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
-        block_tokens=64, kv_dtype="bfloat16", backend="cpu")
-    assert (impl, interpret) == ("xla", False)
+        block_tokens=64, kv_dtype="bfloat16",
+        backend="cpu") == ("xla", False)
     # a tuned "xla" overrides the TPU default
     _write_table(_fresh_table, tuning.shape_key(128, 8, "bfloat16", 1),
                  impl="xla")
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="tpu")
     assert impl == "xla"
     # a different shape misses the table → backend default (xla on cpu)
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=4, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="cpu")
     assert impl == "xla"
@@ -113,12 +114,12 @@ def test_select_reuses_caller_tuned_entry(_fresh_table):
         return sum(s.values())
 
     n0 = lookups()
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="tpu",
         tuned=tuning.TuneEntry(impl="xla"))
     assert impl == "xla"
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="tpu",
         tuned=tuning.TuneEntry())  # empty = looked up, no preference
@@ -127,21 +128,22 @@ def test_select_reuses_caller_tuned_entry(_fresh_table):
 
 
 def test_hard_gates_override_tuned_pallas(_fresh_table):
-    """A tuned "pallas" on a Mosaic-untileable shape still falls back —
-    the table can prefer, never force, a kernel the hardware rejects."""
+    """A tuned "pallas" on a Mosaic-untileable shape is refused like any
+    other — the table can prefer, never force, a kernel the hardware
+    rejects."""
     _write_table(_fresh_table, tuning.shape_key(100, 8, "bfloat16", 1),
                  impl="pallas")
-    impl, _, why = ops.select_paged_attn_impl(
-        "auto", num_heads=32, num_kv_heads=8, head_dim=100,
-        block_tokens=64, kv_dtype="bfloat16", backend="tpu")
-    assert impl == "xla" and "tileable" in why
+    with pytest.raises(ValueError, match="tileable"):
+        ops.select_paged_attn_impl(
+            "auto", num_heads=32, num_kv_heads=8, head_dim=100,
+            block_tokens=64, kv_dtype="bfloat16", backend="tpu")
 
 
 def test_env_override_beats_tuned(_fresh_table, monkeypatch):
     _write_table(_fresh_table, tuning.shape_key(128, 8, "bfloat16", 1),
                  impl="pallas")
     monkeypatch.setenv("LOCALAI_PAGED_ATTN_IMPL", "xla")
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "auto", num_heads=32, num_kv_heads=8, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="tpu")
     assert impl == "xla"
@@ -150,7 +152,7 @@ def test_env_override_beats_tuned(_fresh_table, monkeypatch):
 def test_explicit_request_beats_everything(_fresh_table):
     _write_table(_fresh_table, tuning.shape_key(128, 8, "bfloat16", 1),
                  impl="pallas")
-    impl, _, _ = ops.select_paged_attn_impl(
+    impl, _ = ops.select_paged_attn_impl(
         "xla", num_heads=32, num_kv_heads=8, head_dim=128,
         block_tokens=64, kv_dtype="bfloat16", backend="tpu")
     assert impl == "xla"
@@ -204,9 +206,18 @@ def test_autotune_smoke_cli(tmp_path, monkeypatch):
     out = tmp_path / "table.json"
     monkeypatch.setenv(tuning.ENV_CACHE, str(out))
     tuning.reset()
-    rc = at.main(["--preset", "tiny", "--kv-dtypes", "float32",
-                  "--tp", "1", "--blocks", "8", "--buffers", "2",
-                  "--ctx", "32", "--out", str(out)])
+    argv = ["--preset", "tiny", "--kv-dtypes", "float32", "--tp", "1",
+            "--blocks", "8", "--buffers", "2", "--ctx", "32",
+            "--out", str(out)]
+    # it tunes a TPU: on this backend it refuses, unless told explicitly
+    # that this is the interpreter-mode machinery smoke
+    with pytest.raises(SystemExit) as refused:
+        at.main(argv)
+    assert refused.value.code == 2 and not out.exists()
+    with pytest.raises(SystemExit):     # and never into the default table
+        at.main([a for a in argv if a not in ("--out", str(out))]
+                + ["--interpret"])
+    rc = at.main(argv + ["--interpret"])
     assert rc == 0
     table = tuning.TuningTable.load(str(out))
     key = tuning.shape_key(16, 2, "float32", 1)

@@ -79,7 +79,7 @@ def test_guard_context_manager_and_background_thread(wd_parts):
 
     def hung_dispatch():
         with wd.guard("device"):
-            release.wait(5.0)             # the simulated dead tunnel
+            release.wait(5.0)             # the simulated wedged device
 
     t = threading.Thread(target=hung_dispatch, daemon=True)
     t.start()
@@ -87,7 +87,7 @@ def test_guard_context_manager_and_background_thread(wd_parts):
     assert wd.stalled("device")
     status = wd.status()["device"]
     assert status["armed"] == 1 and status["stalled"]
-    release.set()                         # tunnel comes back
+    release.set()                         # the device answers again
     t.join(2.0)
     deadline = time.monotonic() + 2.0
     while wd.stalled("device") and time.monotonic() < deadline:
@@ -177,10 +177,10 @@ def test_probe_device_timeout_path():
 
 def test_probe_device_error_path():
     def boom():
-        raise RuntimeError("tunnel reset")
+        raise RuntimeError("device reset")
 
     res = obs_device.probe_device(timeout=5.0, registry=Registry(), fn=boom)
-    assert not res.ok and "tunnel reset" in res.error
+    assert not res.ok and "device reset" in res.error
 
 
 def test_hbm_census_attributes_categories():
@@ -219,11 +219,22 @@ def test_roofline_env_override(monkeypatch):
     assert rl["peak_gbps"] == 123.5 and rl["source"] == "env"
 
 
-def test_roofline_assumed_on_cpu(monkeypatch):
+def test_roofline_has_no_peak_for_a_device_not_in_the_table(monkeypatch):
+    """The CPU test mesh is not in the table: no peak, so the observatory
+    prints no roofline fraction — it used to divide by an invented
+    25 GB/s / 0.5 TFLOP/s."""
     monkeypatch.delenv("LOCALAI_PEAK_GBPS", raising=False)
     monkeypatch.delenv("LOCALAI_PEAK_TFLOPS", raising=False)
     rl = obs_device.roofline()
-    assert rl["peak_gbps"] > 0 and rl["source"] in ("assumed", "table")
+    assert rl == {"peak_gbps": None, "peak_tflops": None,
+                  "source": "unknown", "device_kind": "cpu"}
+
+    class V5e:
+        device_kind = "TPU v5 lite"
+
+    rl = obs_device.roofline(V5e())
+    assert (rl["peak_gbps"], rl["peak_tflops"], rl["source"]) == (
+        819.0, 197.0, "table")
 
 
 # -- program cost catalog ---------------------------------------------------

@@ -14,22 +14,31 @@ shapes (heads/tp) — under ``shard_map`` the kernel body IS the
 single-device kernel, so the local measurement is the honest one and no
 multi-device dispatch is needed to tune for a mesh.
 
-Usage:
-    python tools/autotune.py                      # 1b + 8b shapes, this
-                                                  # backend's impl set
-    python tools/autotune.py --preset tiny --kv-dtypes float32,int4 \
-        --tp 1,2 --interpret --out tuning.json    # CI smoke (CPU: the
-                                                  # Pallas points run in
-                                                  # interpret mode)
-    python tools/autotune.py --smoke              # the CI sweep above
+It tunes the backend JAX gives it and never picks one itself. That backend
+must be a TPU: a table of winners timed on anything else would steer a TPU
+runner by the CPU's (or the Pallas interpreter's) preferences. ``--interpret``
+is the explicit exception — the CI smoke that exercises the sweep machinery
+off-TPU with the Pallas points in interpret mode; it must name its own
+``--out`` (never the table a runner reads by default) and every line it
+prints says ``"interpret": true``. On the chip, run it through the chip
+tool, alone: a chip belongs to one process.
 
-Output: one JSON line per measured point plus a final summary line; the
-table file is the artifact CI uploads.
+Only points ``ops.select_paged_attn_impl`` would serve are measured (a shape
+it refuses is recorded as refused, with its reason); a point it allows that
+then fails to compile or run is a bug and ends the sweep.
+
+Usage:
+    python tools/autotune.py                      # 8b shapes, on a TPU
+    python tools/autotune.py --preset tiny --kv-dtypes float32,int4 \
+        --tp 1,2 --interpret --out tuning.json    # off-TPU machinery smoke
+    python tools/autotune.py --smoke --out tuning.json   # the CI sweep above
+
+Output: one JSON line per point (each names the device) plus a final
+summary line; the table file is the artifact CI uploads.
 """
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -37,9 +46,9 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# the shapes worth tuning out of the box: the bench/serving presets
+# the shapes worth tuning out of the box: the serving presets (debug:1b is
+# head_dim 64 — no compiled kernel serves it, so only its gather points
+# would be measured)
 PRESET_SHAPES = {
     "tiny": (16, 2),          # debug:tiny (tests, CI smoke)
     "small": (32, 4),
@@ -116,12 +125,20 @@ def measure_point(head_dim: int, kv_heads: int, kv_dtype: str, *,
 
 
 def sweep(shapes, kv_dtypes, tps, *, block_candidates, buffer_candidates,
-          impls, ctx: int, interpret: bool, table) -> list[dict]:
-    """Measure every point, install the per-key winners into ``table``,
-    and return the point records."""
+          impls, ctx: int, interpret: bool, table, device: dict,
+          group: int = 4) -> list[dict]:
+    """Measure every point the selector would serve, install the per-key
+    winners into ``table``, and return the point records."""
+    from localai_tpu import ops
     from localai_tpu.ops import tuning
 
     records = []
+
+    def emit(rec: dict) -> None:
+        rec.update(device=device, interpret=interpret)
+        records.append(rec)
+        print(json.dumps(rec))
+
     for hd, kv in shapes:
         for kv_dtype in kv_dtypes:
             if kv_dtype == "int4" and hd % 2:
@@ -137,26 +154,27 @@ def sweep(shapes, kv_dtypes, tps, *, block_candidates, buffer_candidates,
                     for bt in block_candidates:
                         if bt > ctx:
                             continue
-                        for nb in bufs:
+                        if impl == "pallas":
                             try:
-                                us = measure_point(
-                                    hd, kv // tp, kv_dtype, impl=impl,
-                                    block_tokens=bt, num_buffers=nb,
-                                    ctx=ctx, interpret=interpret)
-                            except Exception as e:  # noqa: BLE001
-                                rec = {"key": key, "impl": impl,
-                                       "block_tokens": bt,
-                                       "num_buffers": nb,
-                                       "error": f"{type(e).__name__}: "
-                                                f"{e}"[:200]}
-                                records.append(rec)
-                                print(json.dumps(rec))
+                                ops.select_paged_attn_impl(
+                                    "pallas_interpret" if interpret
+                                    else "pallas",
+                                    num_heads=kv * group, num_kv_heads=kv,
+                                    head_dim=hd, block_tokens=bt, tp=tp,
+                                    kv_dtype=kv_dtype)
+                            except ValueError as e:
+                                emit({"key": key, "impl": impl,
+                                      "block_tokens": bt,
+                                      "refused": str(e)})
                                 continue
-                            rec = {"key": key, "impl": impl,
-                                   "block_tokens": bt, "num_buffers": nb,
-                                   "us": round(us, 1)}
-                            records.append(rec)
-                            print(json.dumps(rec))
+                        for nb in bufs:
+                            us = measure_point(
+                                hd, kv // tp, kv_dtype, impl=impl,
+                                block_tokens=bt, num_buffers=nb,
+                                group=group, ctx=ctx, interpret=interpret)
+                            emit({"key": key, "impl": impl,
+                                  "block_tokens": bt, "num_buffers": nb,
+                                  "us": round(us, 1)})
                             if best is None or us < best[0]:
                                 best = (us, impl, bt, nb)
                 if best is None:
@@ -182,7 +200,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--preset", action="append", default=[],
                     choices=sorted(PRESET_SHAPES),
-                    help="model shape preset(s) to tune (default: 1b + "
+                    help="model shape preset(s) to tune (default: "
                          "llama3-8b; repeatable)")
     ap.add_argument("--kv-dtypes", default="bfloat16,int8,int4",
                     help="comma list of KV dtypes to tune")
@@ -195,11 +213,12 @@ def main(argv=None) -> int:
     ap.add_argument("--ctx", type=int, default=512,
                     help="context rows per measured slot")
     ap.add_argument("--interpret", action="store_true",
-                    help="include Pallas points in interpret mode off-TPU "
-                         "(CI machinery smoke; timings are not "
-                         "hardware-representative)")
+                    help="off-TPU machinery smoke: run the Pallas points "
+                         "in the interpreter (timings mean nothing; needs "
+                         "--out). Without it a non-TPU backend is an error")
     ap.add_argument("--out", default="",
-                    help="table path (default LOCALAI_TUNE_CACHE)")
+                    help="table path (default LOCALAI_TUNE_CACHE; required "
+                         "with --interpret)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI sweep: tiny shape, float32+int4, "
                          "tp 1+2, blocks 8/16, interpret")
@@ -207,10 +226,25 @@ def main(argv=None) -> int:
 
     import jax
 
-    jax.config.update("jax_platforms",
-                      os.environ.get("JAX_PLATFORMS", "cpu").split(",")[0])
-
     from localai_tpu.ops import tuning
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    on_tpu = device["platform"] == "tpu"
+    if args.smoke:
+        args.interpret = True
+    if not on_tpu and not args.interpret:
+        ap.error(f"autotune tunes a TPU and found {device}; winners timed "
+                 f"here would steer a TPU runner by this backend's "
+                 f"preferences (--interpret runs the off-TPU machinery "
+                 f"smoke)")
+    if on_tpu and args.interpret:
+        ap.error("--interpret on a TPU would time the interpreter, not "
+                 "the kernels")
+    if args.interpret and not args.out:
+        ap.error("--interpret needs --out: an interpreter-timed table must "
+                 "never land where a runner reads it by default")
 
     if args.smoke:
         shapes = [PRESET_SHAPES["tiny"]]
@@ -218,10 +252,9 @@ def main(argv=None) -> int:
         tps = [1, 2]
         blocks = [8, 16]
         buffers = [2, 3]
-        args.interpret = True
         ctx = 64
     else:
-        presets = args.preset or ["1b", "llama3-8b"]
+        presets = args.preset or ["llama3-8b"]
         shapes = [PRESET_SHAPES[p] for p in presets]
         kv_dtypes = [d for d in args.kv_dtypes.split(",") if d]
         tps = [int(t) for t in args.tp.split(",") if t]
@@ -229,17 +262,14 @@ def main(argv=None) -> int:
         buffers = [int(b) for b in args.buffers.split(",") if b]
         ctx = args.ctx
 
-    on_tpu = jax.default_backend() == "tpu"
-    impls = ["xla"]
-    if on_tpu or args.interpret:
-        impls.append("pallas")
+    impls = ["xla", "pallas"]
 
     path = args.out or tuning.cache_path()
     table = tuning.TuningTable.load(path)
     t0 = time.monotonic()
     records = sweep(shapes, kv_dtypes, tps, block_candidates=blocks,
                     buffer_candidates=buffers, impls=impls, ctx=ctx,
-                    interpret=not on_tpu, table=table)
+                    interpret=args.interpret, table=table, device=device)
     if not path:
         print(json.dumps({"error": "no table path (LOCALAI_TUNE_CACHE=0 "
                                    "and no --out)"}))
@@ -250,9 +280,9 @@ def main(argv=None) -> int:
         "table": saved,
         "entries": len(table.entries),
         "points_measured": sum(1 for r in records if "us" in r),
-        "points_failed": sum(1 for r in records if "error" in r),
-        "backend": jax.default_backend(),
-        "interpret": not on_tpu,
+        "points_refused": sum(1 for r in records if "refused" in r),
+        "device": device,
+        "interpret": args.interpret,
         "sweep_s": round(time.monotonic() - t0, 1),
     }))
     return 0

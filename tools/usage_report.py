@@ -9,12 +9,11 @@ the waste decomposition — each as the latest cumulative counter value
 plus the delta across the loaded window, so "who burned the device this
 afternoon" is answerable from a dead snapshot.
 
-``--ingest-bench <dir-or-file>...`` folds ``BENCH_*.json`` result lines
-(the one-JSON-line contract from ``bench.py``: ``{"metric", "value",
-"unit", ...}`` with an optional nested ``"secondary"``) into the same
-store as ``bench.<metric>`` gauge series, timestamped at each file's
-mtime — the hardware-round trajectory lands in the one place that
-already knows how to downsample and persist it. ``--ingest-autoscale``
+``--ingest-bench <dir-or-file>...`` folds saved ``bench.py`` output (one
+JSON object per line: ``{"metric", "value", "unit", "device", ...}``) into
+the same store as ``bench.<metric>`` gauge series, timestamped at each
+file's mtime. The repo commits no such files: save the output of a chip run
+yourself (``*.jsonl`` in a directory, or name the files). ``--ingest-autoscale``
 does the same for the ``autoscale_report.json`` artifact telemetry_smoke
 round 20 leaves behind: the fleet's capacity trajectory replays at its
 recorded timestamps and the decision counts / cold-start latency land as
@@ -168,37 +167,33 @@ def _bench_files(paths: list[str]) -> list[str]:
     files: list[str] = []
     for p in paths:
         if os.path.isdir(p):
-            files.extend(sorted(glob.glob(os.path.join(p, "BENCH_*.json"))))
+            files.extend(sorted(glob.glob(os.path.join(p, "*.jsonl"))))
         else:
             files.append(p)
     return files
 
 
 def ingest_bench(h: History, paths: list[str]) -> int:
-    """Fold BENCH_*.json one-line results into ``bench.<metric>`` gauge
-    series at each file's mtime. Returns points ingested; unreadable or
-    shapeless files are skipped with a stderr note (report tooling never
-    hard-fails on one bad round)."""
+    """Fold saved bench.py output (one JSON object per line) into
+    ``bench.<metric>`` gauge series at each file's mtime. Returns points
+    ingested; unreadable files and non-metric lines are skipped with a
+    stderr note (report tooling never hard-fails on one bad file)."""
     ingested = 0
     for path in _bench_files(paths):
         try:
             with open(path, encoding="utf-8") as f:
-                doc = json.load(f)
+                lines = [json.loads(ln) for ln in f if ln.strip()]
             ts = os.path.getmtime(path)
         except (OSError, ValueError) as e:
             sys.stderr.write(f"usage_report: skipping {path}: {e}\n")
             continue
-        stack = [doc]
-        while stack:
-            line = stack.pop()
+        for line in lines:
             if not isinstance(line, dict):
                 continue
             metric, value = line.get("metric"), line.get("value")
             if isinstance(metric, str) and isinstance(value, (int, float)):
                 h.record(f"bench.{metric}", float(value), ts=ts)
                 ingested += 1
-            if isinstance(line.get("secondary"), dict):
-                stack.append(line["secondary"])
     return ingested
 
 
@@ -264,8 +259,9 @@ def main(argv=None) -> int:
                         help="ring resolution to report at (seconds)")
     parser.add_argument("--ingest-bench", nargs="+", default=[],
                         metavar="PATH",
-                        help="BENCH_*.json files or directories to fold "
-                             "into the store as bench.<metric> series")
+                        help="saved bench.py output (JSON lines) — files, "
+                             "or directories of *.jsonl — to fold into "
+                             "the store as bench.<metric> series")
     parser.add_argument("--ingest-autoscale", nargs="+", default=[],
                         metavar="PATH",
                         help="autoscale_report*.json files or "
