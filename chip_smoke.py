@@ -26,7 +26,10 @@ caught and turned into a report line. It reads no state from outside the
 tree (LOCALAI_TUNE_CACHE=0; the JAX compilation cache its children share is
 a temp dir created for the run and removed after it). Every line it prints
 names platform, device_kind and device count; the last line of a passing run
-is one JSON object. It measures nothing: "claim" is null.
+is one JSON object with exactly the keys the driver reads,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+It measures nothing: the summary it writes (report.json, and the line before
+the last) ends with "claim": null.
 
 The steps are functions so tests/test_chip_smoke.py can drive them at
 debug:tiny on the CPU.
@@ -798,15 +801,18 @@ def main(argv=None) -> int:
             fleet_phase(smoke, replicas=args.chips)
     finally:
         smoke.close()
-    smoke.report.update(device=smoke.device,
-                        seconds=round(time.monotonic() - t0, 1))
+    smoke.report.update(device=smoke.device, chips=args.chips,
+                        seconds=round(time.monotonic() - t0, 1), claim=None)
     smoke.save_report()
     smoke.say(f"all phases passed in {smoke.report['seconds']}s; report in "
               f"{smoke.out_dir / 'report.json'}")
-    print(json.dumps({"ok": True, "device": smoke.device,
-                      "chips": args.chips,
-                      "phases": sorted(smoke.report["phases"]),
-                      "claim": None}))
+    smoke.say("summary " + json.dumps(
+        {"chips": args.chips, "phases": sorted(smoke.report["phases"]),
+         "claim": None}))
+    # the result line: these keys and no others (the driver's contract)
+    print(json.dumps({"ok": True, "device": {
+        "platform": smoke.device["platform"], "kind": smoke.device["kind"],
+        "count": smoke.device["count"]}}), flush=True)
     return 0
 
 

@@ -127,7 +127,11 @@ def test_result_line_shape(smoke, monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "server_phase", lambda s, chips: {})
     assert chip_smoke.main([]) == 0
     assert not smoke.cache_dir.exists()      # nothing left outside the tree
-    last = capsys.readouterr().out.strip().splitlines()[-1]
-    assert json.loads(last) == {
-        "ok": True, "chips": 1, "phases": [], "claim": None,
+    lines = capsys.readouterr().out.strip().splitlines()
+    # exactly these keys: the driver refuses a result line with any other
+    assert json.loads(lines[-1]) == {
+        "ok": True,
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert lines[-2].endswith('"claim": null}')
+    report = json.loads((smoke.out_dir / "report.json").read_text())
+    assert report["claim"] is None and report["chips"] == 1
