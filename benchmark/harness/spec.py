@@ -1,0 +1,131 @@
+"""Finds a cell's files by the names in BENCHMARK.json.
+
+A later PR adds a configuration, a traffic mix, a cell or a per-layer metric
+by adding ``benchmark/configs/<n>.json``, ``benchmark/traffic/<n>.json``,
+``benchmark/cells/<n>.json`` or ``benchmark/layers/<n>.py`` and one entry in
+BENCHMARK.json; nothing here names a cell, a configuration or a metric.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+from pathlib import Path
+from typing import Any, Callable, Optional
+
+ROOT = Path(__file__).resolve().parents[2]
+
+# a cell file states how hard the cell is driven and what "met" means
+CELL_KEYS = {"rate_rps", "clients", "ramp_s", "drain_s", "limits", "knee",
+             "why"}
+# a configuration file's own keys; every other top-level key is the
+# published config.json, passed verbatim to LlamaConfig.from_hf
+CONFIG_KEYS = {"name", "source", "reduced", "assumed", "deployment", "chips",
+               "context_size", "engine", "sharding", "reference", "hbm",
+               "notes"}
+
+
+class SpecError(ValueError):
+    pass
+
+
+def _read(path: Path) -> dict:
+    try:
+        return json.loads(path.read_text())
+    except FileNotFoundError:
+        raise SpecError(f"{path} does not exist") from None
+
+
+@dataclasses.dataclass(frozen=True)
+class Cell:
+    """One entry of BENCHMARK.json's ``workloads`` with its files loaded."""
+
+    name: str
+    chips: int
+    config_name: str
+    config_file: Path
+    config: dict            # benchmark/configs/<config>.json, whole
+    traffic: dict           # benchmark/traffic/<traffic>.json, whole
+    drive: dict             # benchmark/cells/<name>.json, whole
+    end_to_end: tuple       # metric entries this cell reports, --trace 0
+    per_layer: tuple        # metric entries this cell reports, --trace 1
+    run_seconds: int
+
+    @property
+    def published(self) -> dict:
+        """The configuration's published keys (what from_hf is given)."""
+        return {k: v for k, v in self.config.items()
+                if k not in CONFIG_KEYS}
+
+    @property
+    def max_slots(self) -> int:
+        return int(self.config["engine"]["max_slots"])
+
+
+def bench_dir(root: Path = ROOT) -> Path:
+    """The benchmark's own directory: the first of BENCHMARK.json's paths."""
+    return root / _read(root / "BENCHMARK.json")["paths"][0]
+
+
+def _reported(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(workload: str, root: Path = ROOT) -> Cell:
+    bench = _read(root / "BENCHMARK.json")
+    entry = next((w for w in bench["workloads"] if w["name"] == workload),
+                 None)
+    if entry is None:
+        raise SpecError(
+            f"no workload {workload!r} in BENCHMARK.json; have "
+            f"{[w['name'] for w in bench['workloads']]}")
+    cfg_entry = next(c for c in bench["configs"]
+                     if c["name"] == entry["config"])
+    config = _read(root / cfg_entry["file"])
+    if not isinstance(config.get("reference", {}).get("epsilon"),
+                      (int, float)):
+        raise SpecError(f"{cfg_entry['file']}: no reference.epsilon (the "
+                        f"largest shortfall the reference check allows)")
+    base = bench_dir(root)
+    drive = _read(base / "cells" / f"{workload}.json")
+    unknown = set(drive) - CELL_KEYS
+    if unknown:
+        raise SpecError(f"cells/{workload}.json: unknown keys "
+                        f"{sorted(unknown)}")
+    from harness import traffic as trf
+
+    traffic = trf.validate(
+        _read(base / "traffic" / f"{entry['traffic']}.json"),
+        f"traffic/{entry['traffic']}.json")
+    if (traffic["loop"] == "open") == ("clients" in drive):
+        raise SpecError(
+            f"cells/{workload}.json: an open-loop mix takes rate_rps, a "
+            f"closed-loop mix takes clients")
+    e2e = tuple(m for m in bench["end_to_end"] if _reported(m, workload))
+    names = {m["name"] for m in e2e}
+    # a per-layer metric is reported only where the metric it moves is (the
+    # contract's rule): ``moves`` says what the layer should move, and a
+    # ``workloads`` list narrows the cells further
+    per_layer = tuple(m for m in bench["per_layer"]
+                      if _reported(m, workload) and m["moves"] in names)
+    return Cell(name=workload, chips=int(entry["chips"]),
+                config_name=entry["config"],
+                config_file=root / cfg_entry["file"], config=config,
+                traffic=traffic, drive=drive,
+                end_to_end=e2e, per_layer=per_layer,
+                run_seconds=int(bench["run_seconds"]))
+
+
+def load_reader(metric: str, root: Path = ROOT) -> Callable[[Any],
+                                                            Optional[float]]:
+    """``benchmark/layers/<metric>.py``'s ``read(ctx)``: the metric's value,
+    or None when there is nothing to read (the metric is then left out)."""
+    path = bench_dir(root) / "layers" / f"{metric}.py"
+    if not path.exists():
+        raise SpecError(f"per-layer metric {metric!r} has no reader {path}")
+    spec = importlib.util.spec_from_file_location(
+        "layer_" + metric.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read

@@ -1,0 +1,389 @@
+#!/usr/bin/env python3
+"""Run one cell of the benchmark once, in a new process.
+
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+Starts the server as a child (benchmark/serve.py), waits for the load, warms
+up the cell's own shapes, checks served tokens against the plain reference,
+ramps, measures from the client for ``--seconds``, stops the child, and prints
+as its last stdout line one JSON object with ``correct``, ``attempted``,
+``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``).
+``--trace 0`` gives the cell's end-to-end metrics, ``--trace 1`` its per-layer
+ones. No chip, fewer chips than the cell asks for, or a device kind that is
+not in the benchmark's peak table is an error and prints no result line: never
+a CPU number.
+
+This parent never initialises a JAX backend: the child holds the chip(s).
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import gc
+import json
+import os
+import shutil
+import sys
+import time
+from pathlib import Path
+
+import aiohttp
+
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
+
+from harness import metrics as mtr  # noqa: E402
+from harness import spec, traffic as trf  # noqa: E402
+from harness.client import Client, drain  # noqa: E402
+from harness.peaks import peaks  # noqa: E402
+from harness.server import (HarnessFailure, Server, check_devices,  # noqa: E402
+                            compiles)
+
+TRACE_AT_S, TRACE_FOR_S = 10.0, 3.0     # the traced slice of the window
+PROBE_TOKENS = (16, 200, 600, 1100)     # prompt lengths of the reference
+PROBE_EACH, PROBE_NEW = 4, 4            # probes: 4 each, 4 new tokens
+ANCHOR = (time.time(), time.monotonic())    # Unix <-> this process's clock
+
+
+def say(msg: str) -> None:
+    print(f"[benchmark {time.strftime('%H:%M:%S')}] {msg}", file=sys.stderr,
+          flush=True)
+
+
+# ---------------------------------------------------------------------------
+# set-up steps
+
+
+async def warm_up(client: Client, server: Server, cell: spec.Cell,
+                  seed: int) -> dict:
+    """Replay seeded samples of the cell's own mix, closed loop, until a
+    whole round starts no new program: first one request alone (the fastest
+    decode steps, so the largest steps-per-dispatch the scheduler picks),
+    then rounds at the cell's concurrency (the slowest). Only this cell's
+    shapes; outputs are cut short, since a decode program's shape does not
+    depend on how long it runs."""
+    mix = cell.traffic
+    rounds, quiet = 0, 0
+    before = compiles(server.metrics())
+    start = before
+    await client.closed_rounds(
+        trf.warm_sample(mix, 2, seed, 0, cap_output=48), 1)
+    while quiet < 1 and rounds < 6:
+        rounds += 1
+        await client.closed_rounds(
+            trf.warm_sample(mix, 12, seed, rounds, cap_output=48),
+            cell.max_slots)
+        now = compiles(server.metrics())
+        quiet = quiet + 1 if now == before and rounds > 1 else 0
+        before = now
+    bad = [r for r in client.records if r.stream == "warm" and r.problem()]
+    if bad:
+        raise HarnessFailure(f"warm-up request failed: {bad[0].problem()}")
+    return {"rounds": rounds, "programs": int(before - start)}
+
+
+async def fill_prefixes(client: Client, cell: spec.Cell) -> None:
+    """The mix's shared prefixes, each sent once, so the window finds them in
+    the prefix cache (a cache filled in set-up is part of the deployment)."""
+    mix = cell.traffic
+    if not (mix["prefix"]["fill_in_setup"] and mix["prefix"]["pool"]):
+        return
+    reqs = [trf.Request(i, "prefix", None, [trf.Turn(len(p), 1, 0.0, 1, p)])
+            for i, p in enumerate(trf.prefixes(mix))]
+    await client.closed_rounds(reqs, cell.max_slots)
+
+
+async def reference_check(client: Client, server: Server, cell: spec.Cell,
+                          seed: int) -> dict:
+    """16 seeded greedy probes over HTTP, then the plain reference on the
+    served weights in the child (harness/refcheck.py). Prompt lengths span a
+    one-bucket prefill, a chunked (> 512 token) prefill and multi-block
+    contexts; tokens 2-4 decode through the cache."""
+    rng = trf.stream_rng(seed, "probe")
+    reqs = []
+    room = int(cell.config["context_size"]) - PROBE_NEW - 1
+    for n in (min(n, room) for n in PROBE_TOKENS):
+        for _ in range(PROBE_EACH):
+            # n tokens with the BOS the byte tokenizer adds
+            reqs.append(trf.Request(len(reqs), "probe", None, [trf.Turn(
+                n - 1, PROBE_NEW, 0.0, 0, trf.random_text(rng, n - 1))]))
+    await client.closed_rounds(reqs, 4, sampling={"temperature": 0.0})
+    served = sorted((r for r in client.records if r.stream == "probe"),
+                    key=lambda r: r.idx)
+    probes = []
+    for req, rec in zip(reqs, served):
+        if rec.problem():
+            raise HarnessFailure(f"probe failed: {rec.problem()}")
+        prompt = [256] + list(req.turns[0].text.encode())   # BOS + bytes
+        if rec.prompt_tokens != len(prompt):
+            raise HarnessFailure(
+                f"the server counted {rec.prompt_tokens} prompt tokens for a "
+                f"probe of {len(prompt)}: not the byte tokenizer?")
+        probes.append({"prompt": prompt, "served": list(rec.text.encode())})
+    reply = await asyncio.get_running_loop().run_in_executor(
+        None, server.reference, probes)
+    short = [p["shortfall"] for rows in reply["shortfalls"] for p in rows]
+    epsilon = float(cell.config["reference"]["epsilon"])
+    return {"ok": max(short) <= epsilon, "epsilon": epsilon,
+            "positions": len(short), "max_shortfall": max(short),
+            "nonzero": sum(s > 0 for s in short),
+            "shortfalls": sorted(short)[-8:],
+            "margin_p10": mtr.percentile(
+                [p["margin"] for rows in reply["shortfalls"] for p in rows],
+                10)}
+
+
+# ---------------------------------------------------------------------------
+# the window
+
+
+async def poll_flight(server: Server, client: Client, until: float,
+                      out: list) -> None:
+    """Page the flight ring out while the window runs (the endpoint returns
+    at most 4096 records a call)."""
+    since = 0.0
+    while True:
+        async with client.session.get(
+                f"{server.base}/debug/flight?since={since!r}&limit=4096"
+        ) as resp:
+            ring = (await resp.json())["models"].get(server.name, {})
+        recs = ring.get("records", [])
+        out += recs
+        if recs:
+            since = recs[-1]["ts"]
+        if time.monotonic() > until:
+            return
+        await asyncio.sleep(2.0)
+
+
+async def capture_trace(server: Server, client: Client, at: float,
+                        seconds: float, out: dict) -> None:
+    """POST /backend/trace at ``at``: the profiler runs in the process that
+    holds the chip, while it serves. The call returns when the trace is
+    written (a few seconds on one chip)."""
+    await asyncio.sleep(max(0.0, at - time.monotonic()))
+    out["asked_unix"] = time.time()
+    try:
+        async with client.session.post(
+                server.base + "/backend/trace", json={"seconds": seconds},
+                timeout=aiohttp.ClientTimeout(total=240)) as resp:
+            if resp.status != 200:
+                raise HarnessFailure(f"/backend/trace answered {resp.status}: "
+                                     f"{await resp.text()}")
+            out.update(await resp.json())
+    except (aiohttp.ClientError, asyncio.TimeoutError) as e:
+        raise HarnessFailure(
+            f"/backend/trace did not answer: {type(e).__name__}: {e}") from e
+    out["answered_unix"] = time.time()
+
+
+async def run_window(client: Client, server: Server, cell: spec.Cell,
+                     seed: int, seconds: float, trace: bool,
+                     traced: dict) -> mtr.Window:
+    """The ramp (the cell's own traffic, loading the system, not scored),
+    then the window, then the drain. Returns the window's edges; what was
+    read from the server on the way (compile counters at the edges and, with
+    ``trace``, the profiler's answer and the flight ring) goes into
+    ``traced``."""
+    mix, drive = cell.traffic, cell.drive
+    ramp_s = float(drive.get("ramp_s", 5.0))
+    tasks: set = set()
+    side: list = []
+    gc.collect()
+    gc.freeze()
+    t0 = time.monotonic() + 0.05
+    # an open loop waits for what it sent; a closed loop cuts off at the close
+    drain_s = float(drive.get("drain_s", 30.0)) if mix["loop"] == "open" else 0
+    w = mtr.Window(t0 + ramp_s, t0 + ramp_s + seconds,
+                   t0 + ramp_s + seconds + drain_s)
+    if trace:
+        at = w.t_open + min(TRACE_AT_S, seconds / 3)
+        side.append(asyncio.ensure_future(capture_trace(
+            server, client, at, min(TRACE_FOR_S, seconds / 4), traced)))
+        traced["flight"] = []
+        side.append(asyncio.ensure_future(poll_flight(
+            server, client, w.t_close + 1.0, traced["flight"])))
+
+    async def snapshot_at_open() -> None:
+        await asyncio.sleep(max(0.0, w.t_open - time.monotonic()))
+        traced["compiles_open"] = compiles(
+            await asyncio.get_running_loop().run_in_executor(
+                None, server.metrics))
+
+    side.append(asyncio.ensure_future(snapshot_at_open()))
+    if mix["loop"] == "open":
+        schedule = trf.open_schedule(mix, float(drive["rate_rps"]), ramp_s,
+                                     seconds, seed)
+        await client.open_loop(schedule, t0, tasks)
+        await asyncio.sleep(max(0.0, w.t_close - time.monotonic()))
+    else:
+        clients = int(drive["clients"])
+        await client.closed_loop(trf.closed_stream(mix, clients, seed),
+                                 clients, t0, ramp_s, w.t_close, tasks)
+    traced["compiles_close"] = compiles(
+        await asyncio.get_running_loop().run_in_executor(
+            None, server.metrics))
+    await drain(tasks, w.t_end)
+    for t in side:
+        await t
+    gc.unfreeze()
+    return w
+
+
+# ---------------------------------------------------------------------------
+# one run
+
+
+def wait_ready(server: Server, cell: spec.Cell,
+               platform: str) -> tuple[float, dict, dict]:
+    """Until the model is loaded on the devices the cell asks for: (seconds
+    since the child started, the device as JAX reports it, its peaks)."""
+    load_s = server.wait_loaded(1000.0)
+    device = check_devices(server.get("/system"), cell.chips, platform)
+    peak = peaks(device["kind"])    # raises for a kind with no entry
+    say(f"loaded in {load_s:.1f}s on {device}")
+    return load_s, device, peak
+
+
+async def one_run(server: Server, cell: spec.Cell, args, t_start: float,
+                  platform: str) -> dict:
+    load_s, device, peak = wait_ready(server, cell, platform)
+    parts = {"load_s": load_s}
+    traced: dict = {}
+    async with Client(server.base, server.name, cell.traffic["sampling"],
+                      run_tag=f"s{args.seed}") as client:
+        t = time.monotonic()
+        parts["warmup"] = await warm_up(client, server, cell, args.seed)
+        parts["warmup_s"] = time.monotonic() - t
+        say(f"warm-up {parts['warmup_s']:.1f}s {parts['warmup']}")
+        t = time.monotonic()
+        await fill_prefixes(client, cell)
+        check = await reference_check(client, server, cell, args.seed)
+        parts["reference_s"] = time.monotonic() - t
+        say(f"reference check {parts['reference_s']:.1f}s {check}")
+        w = await run_window(client, server, cell, args.seed, args.seconds,
+                             bool(args.trace), traced)
+        records = client.records
+        if args.trace:
+            async with client.session.get(
+                    server.base + "/v1/traces?limit=500&kind=request") as r:
+                traced["traces"] = (await r.json())["traces"]
+    devices = server.get("/debug/devices?probe=0")
+    parts["ramp_s"] = float(cell.drive.get("ramp_s", 5.0))
+    setup_s = w.t_open - t_start
+    loop = cell.traffic["loop"]
+    attempted, failed = mtr.counts(records, w, loop)
+    e2e = mtr.end_to_end(records, w, loop, setup_s)
+    mem = [d["memory"]["peak_bytes_in_use"] for d in devices["devices"]
+           if d.get("memory")]
+    device["memory_peak_bytes"] = max(mem) if mem else 0
+    scored = mtr.scored(records, w, loop)
+    problems = sorted({r.problem() for r in scored} - {""})
+    late = [r.sent - r.due for r in scored if r.sent is not None]
+    return {
+        "cell": cell, "records": records, "window": w, "loop": loop,
+        "anchor": ANCHOR,
+        "setup": parts, "setup_s": setup_s, "check": check,
+        "attempted": attempted, "failed": failed, "problems": problems,
+        "e2e": e2e, "device": device, "devices": devices, "peak": peak,
+        "traced": traced, "send_late_ms_p99": (
+            1e3 * mtr.percentile(late, 99) if late else None),
+        "compiles_in_window": traced["compiles_close"]
+        - traced["compiles_open"],
+    }
+
+
+def save_raw(run_dir: Path, ctx: dict, args) -> None:
+    """The client's timelines, for reading a run again without the chip."""
+    w = ctx["window"]
+    raw = {"workload": ctx["cell"].name, "seed": args.seed,
+           "seconds": args.seconds, "trace": args.trace,
+           "window": [w.t_open, w.t_close, w.t_end], "loop": ctx["loop"],
+           "setup": ctx["setup"], "setup_s": ctx["setup_s"],
+           "check": ctx["check"], "e2e": ctx["e2e"],
+           "problems": ctx["problems"],
+           "compiles_in_window": ctx["compiles_in_window"],
+           "records": [{
+               "idx": r.idx, "stream": r.stream, "due": r.due, "sent": r.sent,
+               "status": r.status, "times": r.times, "counts": r.counts,
+               "done": r.done, "ended": r.ended, "max_tokens": r.max_tokens,
+               "prompt_tokens": r.prompt_tokens, "problem": r.problem(),
+           } for r in ctx["records"] if r.stream in ("ramp", "window")]}
+    (run_dir / f"raw-seed{args.seed}-trace{args.trace}.json").write_text(
+        json.dumps(raw))
+
+
+def run(args, *, platform: str = "tpu", root: Path = spec.ROOT) -> dict:
+    """Everything but the printing; returns the result line's object."""
+    t_start = time.monotonic()
+    cell = spec.load_cell(args.workload, root)
+    if args.seconds is None:
+        args.seconds = float(cell.run_seconds)
+    run_dir = spec.bench_dir(root) / ".run" / cell.name
+    shutil.rmtree(run_dir, ignore_errors=True)
+    server = Server(cell, root, run_dir, platform=platform)
+    try:
+        ctx = asyncio.run(one_run(server, cell, args, t_start, platform))
+    except BaseException:
+        server.kill()
+        raise
+    server.stop()
+    save_raw(run_dir, ctx, args)
+    say(f"set-up {ctx['setup_s']:.1f}s parts {ctx['setup']}")
+    summary = {k: ctx[k] for k in ("setup", "check", "problems",
+                                   "send_late_ms_p99", "compiles_in_window")}
+    summary["all_end_to_end"] = ctx["e2e"]
+    print("summary " + json.dumps(summary), flush=True)
+    result = {
+        "correct": bool(ctx["check"]["ok"] and ctx["failed"] == 0),
+        "attempted": ctx["attempted"], "failed": ctx["failed"],
+    }
+    if not args.trace:
+        units = {m["name"]: m["unit"] for m in cell.end_to_end}
+        result["metrics"] = {n: {"value": ctx["e2e"][n], "unit": u}
+                             for n, u in units.items()}
+    else:
+        from harness import trace_reduce
+
+        ctx["trace"] = trace_reduce.reduce_run(run_dir, ctx["traced"])
+        result["metrics"] = {}
+        for m in cell.per_layer:
+            value = spec.load_reader(m["name"], root)(ctx)
+            if value is not None:
+                result["metrics"][m["name"]] = {"value": float(value),
+                                                "unit": m["unit"]}
+        ctx["device"].update(busy_s=ctx["trace"]["busy_s"],
+                             window_s=ctx["trace"]["window_s"])
+        result["breakdown"] = ctx["trace"]["breakdown"]
+        if ctx["trace"].get("notes"):
+            print("trace " + json.dumps(ctx["trace"]["notes"]), flush=True)
+    result["device"] = ctx["device"]
+    return result
+
+
+def main(argv=None, *, platform: str = "tpu",
+         root: Path = spec.ROOT) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    asked = os.environ.get("JAX_PLATFORMS", "")
+    if asked and platform not in asked.split(","):
+        print(f"JAX_PLATFORMS={asked!r}: the benchmark runs on a {platform} "
+              f"or not at all", file=sys.stderr)
+        return 2
+    try:
+        result = run(args, platform=platform, root=root)
+    except (HarnessFailure, spec.SpecError, LookupError) as e:
+        print(f"benchmark failed: {e}", file=sys.stderr)
+        return 1
+    # the result line: these keys and no others (the driver's contract)
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
