@@ -7,8 +7,10 @@ to read.
 The context (run.py builds it): ``cell``, ``records`` (client timelines),
 ``window``, ``loop``, ``setup`` (set-up by parts), ``traced`` (flight records,
 request traces, compile counters), ``trace`` (harness/trace_reduce.py's
-reduction), ``devices`` (/debug/devices), ``device``, ``peak``, ``anchor``
-((unix, monotonic) read together in the parent).
+reduction: with ``--trace 2`` of a slice traced after the window, so ask
+``trace_window`` and not ``window`` where the slice is), ``devices``
+(/debug/devices), ``device``, ``peak``, ``anchor`` ((unix, monotonic) read
+together in the parent).
 """
 
 from __future__ import annotations

@@ -49,7 +49,7 @@ class Cell:
     traffic: dict           # benchmark/traffic/<traffic>.json, whole
     drive: dict             # benchmark/cells/<name>.json, whole
     end_to_end: tuple       # metric entries this cell reports, --trace 0
-    per_layer: tuple        # metric entries this cell reports, --trace 1
+    per_layer: tuple        # ... and with --trace 1 (alone) or 2 (both)
     run_seconds: int
 
     @property

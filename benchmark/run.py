@@ -1,17 +1,21 @@
 #!/usr/bin/env python3
 """Run one cell of the benchmark once, in a new process.
 
-    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 Starts the server as a child (benchmark/serve.py), waits for the load, warms
 up the cell's own shapes, checks served tokens against the plain reference,
 ramps, measures from the client for ``--seconds``, stops the child, and prints
 as its last stdout line one JSON object with ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``).
+``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1|2``).
 ``--trace 0`` gives the cell's end-to-end metrics, ``--trace 1`` its per-layer
-ones. No chip, fewer chips than the cell asks for, or a device kind that is
-not in the benchmark's peak table is an error and prints no result line: never
-a CPU number.
+ones from a slice traced INSIDE the window. ``--trace 2`` is a ``--trace 0``
+run to the end of its last scored request, which then reads the server's
+rings and traces a slice of the same mix under a stream of its own
+(``traced_slice``): both kinds of metric on one line, and nothing of the
+tracing in an end-to-end number. No chip, fewer chips than the cell asks for,
+or a device kind that is not in the benchmark's peak table is an error and
+prints no result line: never a CPU number.
 
 This parent never initialises a JAX backend: the child holds the chip(s).
 """
@@ -20,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import gc
 import json
 import os
@@ -27,6 +32,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from typing import Optional
 
 import aiohttp
 
@@ -41,6 +47,11 @@ from harness.server import (HarnessFailure, Server, check_devices,  # noqa: E402
                             compiles)
 
 TRACE_AT_S, TRACE_FOR_S = 10.0, 3.0     # the traced slice of the window
+# --trace 2's slice comes after the window, under a stream of its own: its
+# seed is no run's (--seed stays under 2**32), its open-loop schedule runs
+# this long past its ramp (on one v5e chip the capture answers 11-19 s after
+# its 3 s, PERF.md section 6; what is due after the answer is never sent)
+TRACE_SEED, TRACE_SPAN_S = 1 << 32, 30.0
 PROBE_TOKENS = (16, 200, 600, 1100)     # prompt lengths of the reference
 PROBE_EACH, PROBE_NEW = 4, 4            # probes: 4 each, 4 new tokens
 ANCHOR = (time.time(), time.monotonic())    # Unix <-> this process's clock
@@ -138,30 +149,42 @@ async def reference_check(client: Client, server: Server, cell: spec.Cell,
 # the window
 
 
+async def read_flight(server: Server, client: Client, since: float,
+                      out: list) -> float:
+    """The flight ring's records after ``since`` (a record's ``ts``; 0 =
+    all) into ``out``: the newest 4096 of them, the most the endpoint
+    returns. Returns the last ``ts`` read."""
+    async with client.session.get(
+            f"{server.base}/debug/flight?since={since!r}&limit=4096"
+    ) as resp:
+        ring = (await resp.json())["models"].get(server.name, {})
+    recs = ring.get("records", [])
+    out += recs
+    return recs[-1]["ts"] if recs else since
+
+
 async def poll_flight(server: Server, client: Client, until: float,
                       out: list) -> None:
-    """Page the flight ring out while the window runs (the endpoint returns
-    at most 4096 records a call)."""
+    """Page the flight ring out while the window runs."""
     since = 0.0
     while True:
-        async with client.session.get(
-                f"{server.base}/debug/flight?since={since!r}&limit=4096"
-        ) as resp:
-            ring = (await resp.json())["models"].get(server.name, {})
-        recs = ring.get("records", [])
-        out += recs
-        if recs:
-            since = recs[-1]["ts"]
+        since = await read_flight(server, client, since, out)
         if time.monotonic() > until:
             return
         await asyncio.sleep(2.0)
+
+
+async def read_traces(server: Server, client: Client) -> list:
+    async with client.session.get(
+            server.base + "/v1/traces?limit=500&kind=request") as r:
+        return (await r.json())["traces"]
 
 
 async def capture_trace(server: Server, client: Client, at: float,
                         seconds: float, out: dict) -> None:
     """POST /backend/trace at ``at``: the profiler runs in the process that
     holds the chip, while it serves. The call returns when the trace is
-    written (a few seconds on one chip)."""
+    written (11-19 s after its ``seconds`` on one v5e chip, PR 25)."""
     await asyncio.sleep(max(0.0, at - time.monotonic()))
     out["asked_unix"] = time.time()
     try:
@@ -231,6 +254,71 @@ async def run_window(client: Client, server: Server, cell: spec.Cell,
     return w
 
 
+async def traced_slice(client: Client, server: Server, cell: spec.Cell,
+                       seed: int, w: mtr.Window, traced: dict) -> None:
+    """``--trace 2`` after the window: first what the window left in the
+    server's rings (the flight ring, the request spans), read once; then the
+    cell's mix again under the stream ``trace`` (seeded, never scored: its
+    requests are due after the close), ramped as the window's was, and
+    ``POST /backend/trace`` for TRACE_FOR_S with that traffic running until
+    it answers; then a bounded drain (a reply's ``usage`` comes at its end)
+    and the rings again, for the slice. A profiler's first start costs more
+    than its second, so one capture is made and thrown away while the slice
+    ramps."""
+    mix, drive = cell.traffic, cell.drive
+    ramp_s = float(drive.get("ramp_s", 5.0))
+    flight: list = []
+    since = await read_flight(server, client, 0.0, flight)
+    # the endpoint gives the newest 4096: enough for the window unless the
+    # oldest it gave is younger than the window's opening
+    traced["flight_cut"] = len(flight) == 4096 and (
+        flight[0]["ts_unix"] - ANCHOR[0] + ANCHOR[1] > w.t_open)
+    traces = await read_traces(server, client)
+    loop = asyncio.get_running_loop()
+    before = compiles(await loop.run_in_executor(None, server.metrics))
+    answered = asyncio.Event()
+    tasks: set = set()
+    t0 = time.monotonic() + 0.05
+    if mix["loop"] == "open":
+        schedule = [dataclasses.replace(r, stream="trace")
+                    for r in trf.open_schedule(
+                        mix, float(drive["rate_rps"]), ramp_s, TRACE_SPAN_S,
+                        seed + TRACE_SEED)]
+        sender = asyncio.ensure_future(client.open_loop(schedule, t0, tasks))
+    else:
+        clients = int(drive["clients"])
+        supply = trf.closed_stream(mix, clients, seed + TRACE_SEED)
+
+        async def caller(i: int) -> None:   # client.closed_loop's, but it
+            # stops when the capture has answered, not at a set time
+            await asyncio.sleep(max(
+                0.0, t0 + ramp_s * i / clients - time.monotonic()))
+            while not answered.is_set():
+                await client.converse(dataclasses.replace(
+                    next(supply), stream="trace"), time.monotonic())
+
+        tasks |= {asyncio.ensure_future(caller(i)) for i in range(clients)}
+        sender = None
+    try:
+        first: dict = {}
+        await capture_trace(server, client, t0, 0.1, first)
+        shutil.rmtree(server.run_dir / first["trace_dir"],
+                      ignore_errors=True)
+        await capture_trace(server, client, t0 + ramp_s, TRACE_FOR_S, traced)
+    finally:
+        answered.set()          # a closed loop's callers start no more,
+        if sender is not None:  # nor does an open loop's schedule
+            sender.cancel()
+            await asyncio.gather(sender, return_exceptions=True)
+        await drain(tasks, time.monotonic()
+                    + float(drive.get("drain_s", 30.0)))
+    traced["compiles_in_slice"] = compiles(
+        await loop.run_in_executor(None, server.metrics)) - before
+    await read_flight(server, client, since, flight)
+    traced["flight"] = flight
+    traced["traces"] = traces + await read_traces(server, client)
+
+
 # ---------------------------------------------------------------------------
 # one run
 
@@ -263,22 +351,26 @@ async def one_run(server: Server, cell: spec.Cell, args, t_start: float,
         parts["reference_s"] = time.monotonic() - t
         say(f"reference check {parts['reference_s']:.1f}s {check}")
         w = await run_window(client, server, cell, args.seed, args.seconds,
-                             bool(args.trace), traced)
+                             args.trace == 1, traced)
         records = client.records
-        if args.trace:
-            async with client.session.get(
-                    server.base + "/v1/traces?limit=500&kind=request") as r:
-                traced["traces"] = (await r.json())["traces"]
+        if args.trace == 1:
+            traced["traces"] = await read_traces(server, client)
+        # --trace 2: a closed loop was cut off at the close, an open loop's
+        # drain has returned: every scored record has ended, and what is
+        # scored is final before the server is asked anything more
+        final = list(records)
+        if args.trace == 2:
+            await traced_slice(client, server, cell, args.seed, w, traced)
     devices = server.get("/debug/devices?probe=0")
     parts["ramp_s"] = float(cell.drive.get("ramp_s", 5.0))
     setup_s = w.t_open - t_start
     loop = cell.traffic["loop"]
-    attempted, failed = mtr.counts(records, w, loop)
-    e2e = mtr.end_to_end(records, w, loop, setup_s)
+    attempted, failed = mtr.counts(final, w, loop)
+    e2e = mtr.end_to_end(final, w, loop, setup_s)
     mem = [d["memory"]["peak_bytes_in_use"] for d in devices["devices"]
            if d.get("memory")]
     device["memory_peak_bytes"] = max(mem) if mem else 0
-    scored = mtr.scored(records, w, loop)
+    scored = mtr.scored(final, w, loop)
     problems = sorted({r.problem() for r in scored} - {""})
     late = [r.sent - r.due for r in scored if r.sent is not None]
     return {
@@ -292,6 +384,16 @@ async def one_run(server: Server, cell: spec.Cell, args, t_start: float,
         "compiles_in_window": traced["compiles_close"]
         - traced["compiles_open"],
     }
+
+
+def slice_tok_s(ctx: dict) -> Optional[float]:
+    """Tokens per second the clients received inside the traced slice: set
+    against ``out_tok_s`` it says what the tracing costs while it is on."""
+    from harness import layerlib
+
+    win = layerlib.trace_window(ctx)
+    return None if win is None else mtr.tokens_in(
+        ctx["records"], *win) / (win[1] - win[0])
 
 
 def save_raw(run_dir: Path, ctx: dict, args) -> None:
@@ -309,7 +411,8 @@ def save_raw(run_dir: Path, ctx: dict, args) -> None:
                "status": r.status, "times": r.times, "counts": r.counts,
                "done": r.done, "ended": r.ended, "max_tokens": r.max_tokens,
                "prompt_tokens": r.prompt_tokens, "problem": r.problem(),
-           } for r in ctx["records"] if r.stream in ("ramp", "window")]}
+           } for r in ctx["records"]
+               if r.stream in ("ramp", "window", "trace")]}
     (run_dir / f"raw-seed{args.seed}-trace{args.trace}.json").write_text(
         json.dumps(raw))
 
@@ -339,25 +442,33 @@ def run(args, *, platform: str = "tpu", root: Path = spec.ROOT) -> dict:
         "correct": bool(ctx["check"]["ok"] and ctx["failed"] == 0),
         "attempted": ctx["attempted"], "failed": ctx["failed"],
     }
-    if not args.trace:
-        units = {m["name"]: m["unit"] for m in cell.end_to_end}
-        result["metrics"] = {n: {"value": ctx["e2e"][n], "unit": u}
-                             for n, u in units.items()}
-    else:
+    result["metrics"] = {} if args.trace == 1 else {
+        m["name"]: {"value": ctx["e2e"][m["name"]], "unit": m["unit"]}
+        for m in cell.end_to_end}
+    if args.trace:
         from harness import trace_reduce
 
-        ctx["trace"] = trace_reduce.reduce_run(run_dir, ctx["traced"])
-        result["metrics"] = {}
+        trace = ctx["trace"] = trace_reduce.reduce_run(run_dir, ctx["traced"])
+        notes = trace["notes"]
+        notes["slice_tok_s"] = slice_tok_s(ctx)
+        if args.trace == 2:
+            shutil.rmtree(run_dir / ctx["traced"]["trace_dir"],
+                          ignore_errors=True)     # reduced: delete it
+            notes.update({k: ctx["traced"][k] for k in (
+                "compiles_in_slice", "flight_cut")})
+            if notes["compiles_in_slice"]:
+                # a program compiled inside the slice voids the slice, not
+                # the run: the device-trace readers find no trace
+                ctx["trace"] = None
         for m in cell.per_layer:
             value = spec.load_reader(m["name"], root)(ctx)
             if value is not None:
                 result["metrics"][m["name"]] = {"value": float(value),
                                                 "unit": m["unit"]}
-        ctx["device"].update(busy_s=ctx["trace"]["busy_s"],
-                             window_s=ctx["trace"]["window_s"])
-        result["breakdown"] = ctx["trace"]["breakdown"]
-        if ctx["trace"].get("notes"):
-            print("trace " + json.dumps(ctx["trace"]["notes"]), flush=True)
+        ctx["device"].update(busy_s=trace["busy_s"],
+                             window_s=trace["window_s"])
+        result["breakdown"] = trace["breakdown"]
+        print("trace " + json.dumps(notes), flush=True)
     result["device"] = ctx["device"]
     return result
 
@@ -368,7 +479,7 @@ def main(argv=None, *, platform: str = "tpu",
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = ap.parse_args(argv)
     asked = os.environ.get("JAX_PLATFORMS", "")
     if asked and platform not in asked.split(","):

@@ -162,7 +162,9 @@ def test_an_unknown_workload_or_key_is_an_error(bench_copy, capsys):
 def test_benchmark_json_names_only_files_that_exist():
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     assert set(spec) == {"command", "paths", "run_seconds", "configs",
-                         "workloads", "end_to_end", "per_layer"}
+                         "workloads", "end_to_end", "per_layer",
+                         "trace_in_run"}
+    assert spec["trace_in_run"] is True     # run.py takes --trace 2
     for c in spec["configs"]:
         assert (ROOT / c["file"]).exists()
     for w in spec["workloads"]:

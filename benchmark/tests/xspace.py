@@ -25,11 +25,18 @@ def _map_entry(num: int, key: int, value: bytes) -> bytes:
     return _field(num, _field(1, key) + _field(2, value))
 
 
-def plane(name: str, lines: dict, stats: dict | None = None) -> bytes:
+def plane(name: str, lines: dict, stats: dict | None = None,
+          meta: dict | None = None) -> bytes:
     """lines: {line name: [(event name, start_ns, duration_ns), ...]};
-    stats: {stat name: unsigned value} on the plane itself."""
+    stats: {stat name: unsigned value} on the plane itself; meta: {event
+    name: {stat name: str or unsigned value}} on the events' METADATA, where
+    a TPU trace keeps an operation's ``tf_op`` and ``program_id``."""
     names = sorted({ev[0] for evs in lines.values() for ev in evs})
     ids = {n: i + 1 for i, n in enumerate(names)}
+    stats = dict(stats or {})
+    stat_ids = {n: i + 1 for i, n in enumerate(
+        list(stats) + sorted({k for m in (meta or {}).values() for k in m}
+                             - set(stats)))}
     body = _field(2, name)
     for i, (line_name, events) in enumerate(lines.items()):
         line = _field(1, i + 1) + _field(2, line_name) + _field(3, 0)
@@ -39,10 +46,15 @@ def plane(name: str, lines: dict, stats: dict | None = None) -> bytes:
                            + _field(3, dur_ns * 1000))
         body += _field(3, line)
     for n, i in ids.items():
-        body += _map_entry(4, i, _field(1, i) + _field(2, n))
-    for i, (stat, value) in enumerate((stats or {}).items()):
-        body += _map_entry(5, i + 1, _field(1, i + 1) + _field(2, stat))
-        body += _field(6, _field(1, i + 1) + _field(3, value))
+        entry = _field(1, i) + _field(2, n)
+        for stat, value in (meta or {}).get(n, {}).items():
+            entry += _field(5, _field(1, stat_ids[stat]) + _field(
+                3 if isinstance(value, int) else 5, value))
+        body += _map_entry(4, i, entry)
+    for stat, i in stat_ids.items():
+        body += _map_entry(5, i, _field(1, i) + _field(2, stat))
+    for stat, value in stats.items():
+        body += _field(6, _field(1, stat_ids[stat]) + _field(3, value))
     return body
 
 

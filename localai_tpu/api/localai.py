@@ -377,8 +377,11 @@ async def engine_metrics(request: web.Request) -> web.Response:
 
 
 async def backend_trace(request: web.Request) -> web.Response:
-    """POST {seconds?, dir?} → capture a device/XLA profiler trace
-    (jax.profiler, TensorBoard/XProf format) while serving continues.
+    """POST {seconds?, dir?, python_tracer?} → capture a device/XLA
+    profiler trace (jax.profiler, TensorBoard/XProf format) while serving
+    continues. It holds the device's operations under their scope names
+    and the scheduler's ``sched.*`` phases on one clock; Python frames
+    only with ``"python_tracer": true`` (they slow the host it measures).
     The TPU-era upgrade of the reference's pprof-style debug surface:
     traces show per-program device time, fusion layout, and HBM traffic —
     the ground truth for kernel/serving optimization. API-key-protected;
@@ -398,6 +401,9 @@ async def backend_trace(request: web.Request) -> web.Response:
         raise web.HTTPBadRequest(text="seconds must be a number")
     if not 0.1 <= seconds <= 60.0:
         raise web.HTTPBadRequest(text="seconds must be in [0.1, 60]")
+    python_tracer = body.get("python_tracer", False)
+    if not isinstance(python_tracer, bool):
+        raise web.HTTPBadRequest(text="python_tracer must be true or false")
     from localai_tpu.utils.paths import verify_path
 
     state = _state(request)
@@ -408,23 +414,19 @@ async def backend_trace(request: web.Request) -> web.Response:
         raise web.HTTPBadRequest(text=str(e))
 
     def capture() -> str:
-        import jax
-
         # single-flight is SHARED with the anomaly profiler
-        # (obs.profiler): the device runs at most one capture at a time
-        # no matter which surface asked for it
-        from localai_tpu.obs.profiler import PROFILER
+        # (obs.profiler), and so is the capture itself: the device runs
+        # at most one at a time no matter which surface asked for it
+        from localai_tpu.obs import profiler
 
-        if not PROFILER.acquire_capture():
+        if not profiler.PROFILER.acquire_capture():
             raise RuntimeError("a trace capture is already running")
         try:
             path = str(out / time.strftime("trace-%Y%m%d-%H%M%S"))
-            jax.profiler.start_trace(path)
-            time.sleep(seconds)
-            jax.profiler.stop_trace()
+            profiler.capture(path, seconds, python_tracer)
             return path
         finally:
-            PROFILER.release_capture()
+            profiler.PROFILER.release_capture()
 
     loop = asyncio.get_running_loop()
     try:

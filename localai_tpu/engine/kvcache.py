@@ -240,26 +240,30 @@ def paged_decode_write(tables: jax.Array, positions: jax.Array,
         if len(layer_kv) == 4:  # scaled int8/int4 pool
             k_layer, v_layer, ks_layer, vs_layer = layer_kv
             quant, int4 = _pool_quant(layer_kv, k_new)
-            kq, ks = quant(k_new[:, 0])    # [S, H, hd or hd/2], [S, H]
-            vq, vs = quant(v_new[:, 0])
-            new_k = k_layer.at[blk, :, off].set(kq)
-            new_v = v_layer.at[blk, :, off].set(vq)
-            new_ks = ks_layer.at[blk, :, off].set(ks)
-            new_vs = vs_layer.at[blk, :, off].set(vs)
+            with jax.named_scope("kv_pool.write"):
+                kq, ks = quant(k_new[:, 0])    # [S, H, hd or hd/2], [S, H]
+                vq, vs = quant(v_new[:, 0])
+                new_k = k_layer.at[blk, :, off].set(kq)
+                new_v = v_layer.at[blk, :, off].set(vq)
+                new_ks = ks_layer.at[blk, :, off].set(ks)
+                new_vs = vs_layer.at[blk, :, off].set(vs)
             new_kv = (new_k, new_v, new_ks, new_vs)
             if raw:
                 return new_kv, (new_k, new_ks), (new_v, new_vs)
-            keys = _gather_dequant(new_k, new_ks, tables, dt, int4)
-            values = _gather_dequant(new_v, new_vs, tables, dt, int4)
+            with jax.named_scope("kv_pool.gather"):
+                keys = _gather_dequant(new_k, new_ks, tables, dt, int4)
+                values = _gather_dequant(new_v, new_vs, tables, dt, int4)
             return new_kv, keys, values
         k_layer, v_layer = layer_kv               # [N, H, bt, hd]
         kdt = k_layer.dtype
-        new_k = k_layer.at[blk, :, off].set(k_new[:, 0].astype(kdt))
-        new_v = v_layer.at[blk, :, off].set(v_new[:, 0].astype(kdt))
+        with jax.named_scope("kv_pool.write"):
+            new_k = k_layer.at[blk, :, off].set(k_new[:, 0].astype(kdt))
+            new_v = v_layer.at[blk, :, off].set(v_new[:, 0].astype(kdt))
         if raw:
             return (new_k, new_v), new_k, new_v
-        return ((new_k, new_v), gather_blocks(new_k, tables).astype(dt),
-                gather_blocks(new_v, tables).astype(dt))
+        with jax.named_scope("kv_pool.gather"):
+            return ((new_k, new_v), gather_blocks(new_k, tables).astype(dt),
+                    gather_blocks(new_v, tables).astype(dt))
 
     return write
 
@@ -290,21 +294,25 @@ def paged_prefill_write(table_row: jax.Array, offset: jax.Array,
         if len(layer_kv) == 4:  # scaled int8/int4 pool
             k_layer, v_layer, ks_layer, vs_layer = layer_kv
             quant, int4 = _pool_quant(layer_kv, k_new)
-            kq, ks = quant(k_new[0])       # [T, H, hd or hd/2], [T, H]
-            vq, vs = quant(v_new[0])
-            new_k = k_layer.at[blk, :, off].set(kq)
-            new_v = v_layer.at[blk, :, off].set(vq)
-            new_ks = ks_layer.at[blk, :, off].set(ks)
-            new_vs = vs_layer.at[blk, :, off].set(vs)
-            keys = _gather_dequant(new_k, new_ks, row, dt, int4)
-            values = _gather_dequant(new_v, new_vs, row, dt, int4)
+            with jax.named_scope("kv_pool.write"):
+                kq, ks = quant(k_new[0])   # [T, H, hd or hd/2], [T, H]
+                vq, vs = quant(v_new[0])
+                new_k = k_layer.at[blk, :, off].set(kq)
+                new_v = v_layer.at[blk, :, off].set(vq)
+                new_ks = ks_layer.at[blk, :, off].set(ks)
+                new_vs = vs_layer.at[blk, :, off].set(vs)
+            with jax.named_scope("kv_pool.gather"):
+                keys = _gather_dequant(new_k, new_ks, row, dt, int4)
+                values = _gather_dequant(new_v, new_vs, row, dt, int4)
             return (new_k, new_v, new_ks, new_vs), keys, values
         k_layer, v_layer = layer_kv
         kdt = k_layer.dtype
-        new_k = k_layer.at[blk, :, off].set(k_new[0].astype(kdt))
-        new_v = v_layer.at[blk, :, off].set(v_new[0].astype(kdt))
-        return ((new_k, new_v), gather_blocks(new_k, row).astype(dt),
-                gather_blocks(new_v, row).astype(dt))
+        with jax.named_scope("kv_pool.write"):
+            new_k = k_layer.at[blk, :, off].set(k_new[0].astype(kdt))
+            new_v = v_layer.at[blk, :, off].set(v_new[0].astype(kdt))
+        with jax.named_scope("kv_pool.gather"):
+            return ((new_k, new_v), gather_blocks(new_k, row).astype(dt),
+                    gather_blocks(new_v, row).astype(dt))
 
     return write
 

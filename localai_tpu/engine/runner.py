@@ -39,6 +39,7 @@ from localai_tpu.engine.kvcache import KVCache
 from localai_tpu.models import llama as mdl
 from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.obs import compile as obs_compile
+from localai_tpu.obs.profiler import scoped
 from localai_tpu.obs import watchdog as obs_watchdog
 
 log = logging.getLogger(__name__)
@@ -567,6 +568,7 @@ class ModelRunner:
 
     # -- jitted programs -------------------------------------------------
 
+    @scoped("decode")
     def _decode_fn(self, params, kv: KVCache, state: DecodeState):
         cfg = self.cfg
         pos = state.positions
@@ -601,6 +603,7 @@ class ModelRunner:
                     check_vma=False,
                 )
 
+            @scoped("attn.decode")
             def attn(q, keys, values, _mask):  # q [S,1,Hq,hd], keys [S,Hkv,C,hd]
                 if raw_kv:  # (int8 cache, f32 scales) — fused dequant
                     out = kernel(q[:, 0], keys[0], values[0], pos,
@@ -724,6 +727,7 @@ class ModelRunner:
         )
         return new_state, emitted
 
+    @scoped("verify")
     def _verify_fn(self, params, kv: KVCache, state: DecodeState,
                    proposals):
         """One speculative verify dispatch over the contiguous cache: a
@@ -746,6 +750,7 @@ class ModelRunner:
         new_state, emitted = self._accept_scan(state, logits, proposals)
         return KVCache.from_stacked(new_stack), new_state, emitted
 
+    @scoped("verify")
     def _verify_paged_fn(self, params, kv: kvc.PagedKVCache,
                          state: DecodeState, tables, proposals):
         """Paged twin of _verify_fn: draft rows scatter through the block
@@ -809,6 +814,7 @@ class ModelRunner:
         )
         return kv, state, tokens
 
+    @scoped("prefill")
     def _prefill_fn(self, params, kv: KVCache, state: DecodeState,
                     tokens, length, slot, *, bucket: int, embeds=None):
         cfg = self.cfg
@@ -858,6 +864,7 @@ class ModelRunner:
             params, kv, state, tokens, length, slot, bucket=bucket, embeds=x
         )
 
+    @scoped("prefill")
     def _prefill_resume_fn(self, params, kv: KVCache, state: DecodeState,
                            tokens, length, offset, slot, counts_row,
                            *, bucket: int):
@@ -896,6 +903,7 @@ class ModelRunner:
         )
         return KVCache.from_stacked(new_stack), new_state, tok[0]
 
+    @scoped("prefill")
     def _prefill_sp_fn(self, params, kv: KVCache, state: DecodeState,
                        tokens, length, slot, *, bucket: int):
         """Sequence-parallel prefill: the prompt chunks over the 'seq' mesh
@@ -951,6 +959,7 @@ class ModelRunner:
 
     # -- paged programs (block-pool KV; engine.paged / kvcache.Paged*) ---
 
+    @scoped("decode")
     def _decode_paged_fn(self, params, kv: kvc.PagedKVCache,
                          state: DecodeState, tables):
         """Batched single-token decode over the block pool. ``tables``
@@ -1014,6 +1023,7 @@ class ModelRunner:
                     check_vma=False,
                 )
 
+            @scoped("attn.paged_decode")
             def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; keys = pool
                 if kv.quantized:  # (int8 pool, f32 scales) — fused dequant
                     out = kernel(q[:, 0], keys[0], values[0], tables, pos,
@@ -1064,6 +1074,7 @@ class ModelRunner:
         )
         return kv, state, tokens
 
+    @scoped("prefill")
     def _prefill_paged_fn(self, params, kv, state, tokens, length, offset,
                           table_row, slot, counts_row, *, bucket: int,
                           sample: bool, embeds=None):
@@ -1120,6 +1131,7 @@ class ModelRunner:
             embeds=x,
         )
 
+    @scoped("prefill")
     def _prefill_paged_sp_fn(self, params, kv, state, tokens, length,
                              table_row, slot, counts_row, *, bucket: int):
         """Sequence-parallel paged prefill: the prompt chunks over the
@@ -1275,6 +1287,7 @@ class ModelRunner:
                 check_vma=False,
             )
 
+        @scoped("attn.prefill")
         def attn(q, keys, values, _mask):  # q [1,T,Hq,hd], keys [1,Hkv,T,hd]
             out = kernel(q[0], keys[0], values[0], length)
             return out[None]

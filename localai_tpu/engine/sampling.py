@@ -25,6 +25,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from localai_tpu.obs.profiler import scoped
+
 MAX_TOPK = 256  # candidate cap; llama.cpp default top_k=40
 
 
@@ -99,6 +101,7 @@ def apply_penalties(
     return logits
 
 
+@scoped("sample")
 def sample(
     logits: jax.Array,        # [S, V] (any float dtype)
     params: SamplingParams,

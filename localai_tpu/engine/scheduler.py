@@ -33,6 +33,7 @@ from collections import deque
 from typing import Any, Optional, Protocol, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from localai_tpu.engine.runner import NAN_TOKEN, ModelRunner
 from localai_tpu.engine.stream import IncrementalDetokenizer, StopChecker
@@ -996,7 +997,8 @@ class Scheduler:
             # a device that never answers parks this exact line forever, and
             # the stall forensics must say so.
             t_sync = time.monotonic()  # anatomy: the result-fetch block
-            with self.watchdog.guard(self._wd_channel):
+            with self.watchdog.guard(self._wd_channel), \
+                    TraceAnnotation("sched.wait_device"):  # waiting, not work
                 if _faults.ACTIVE:  # chaos: wedge/raise inside the guard
                     _faults.apply("engine.drain", key=self._wd_channel)
                 rows = np.asarray(toks)  # jaxlint: disable=host-sync-in-hot-path
@@ -1060,7 +1062,8 @@ class Scheduler:
                 # real device-side guard end to end
                 self._inject_slot_faults()
             t_adm = time.monotonic()
-            admitted = self._admit_pending()
+            with TraceAnnotation("sched.admit"):
+                admitted = self._admit_pending()
             adm_s = time.monotonic() - t_adm
             if admitted and not self._chunked:
                 # one-shot admissions dispatch AND sync a full prefill
@@ -1073,7 +1076,10 @@ class Scheduler:
             # chunks and decode dispatches alternate — a long prompt
             # spreads its prefill across the batch's decode cadence
             # instead of stalling it
-            chunked = self._step_prefill_chunk()
+            chunked = False
+            if self._prefills:
+                with TraceAnnotation("sched.prefill_chunk"):
+                    chunked = self._step_prefill_chunk()
             if not self._slots:
                 self._last_drain_t = None  # idle gap would pollute the EMA
                 if inflight:
@@ -1087,7 +1093,8 @@ class Scheduler:
                     self._anat_sched_s = 0.0
                     self._anat_launch_s = 0.0
                     self._anat_overlap_s = 0.0
-                    self._wake.wait(timeout=0.05)
+                    with TraceAnnotation("sched.idle"):
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
                 continue
             try:
@@ -1180,7 +1187,8 @@ class Scheduler:
                         t_issue = time.monotonic()
                         # None = the drafter declined (no lookup hit
                         # anywhere) — fall through to plain decode
-                        spec_rows = self.spec.step_spec_async()
+                        with TraceAnnotation("sched.decode_launch"):
+                            spec_rows = self.spec.step_spec_async()
                         # anatomy: proposal + verify enqueue span (host
                         # drafter work rides in launch — documented
                         # caveat); a declined proposal dispatched nothing,
@@ -1213,15 +1221,16 @@ class Scheduler:
                     self._dispatch_seq += 1
                     fresh = self._fresh_shape(steps)
                     t_issue = time.monotonic()
-                    if steps > 1:
-                        tokens = self.runner.step_n_async(steps)
-                    else:
-                        tokens = self.runner.step_async()
-                    self.last_dispatch_steps = steps
-                    try:
-                        tokens.copy_to_host_async()
-                    except AttributeError:
-                        pass
+                    with TraceAnnotation("sched.decode_launch"):
+                        if steps > 1:
+                            tokens = self.runner.step_n_async(steps)
+                        else:
+                            tokens = self.runner.step_async()
+                        self.last_dispatch_steps = steps
+                        try:
+                            tokens.copy_to_host_async()
+                        except AttributeError:
+                            pass
                     # anatomy: async enqueue span (jit call + D2H start)
                     self._anat_launch_s += time.monotonic() - t_issue
                     inflight.append((tokens, self._dispatch_seq, steps,
@@ -1710,21 +1719,22 @@ class Scheduler:
         # that finishes at row i (removed from _slots) ignores rows i+1..;
         # ``frozen`` slots only advanced on the first step of the dispatch,
         # so only row 0 is theirs.
-        for i in range(rows.shape[0]):
-            for slot, ctx in list(self._slots.items()):
-                if seq <= ctx.admit_seq:
-                    continue
-                if i > 0 and frozen is not None and slot in frozen:
-                    continue
-                tok = int(rows[i, slot])
-                if tok == NAN_TOKEN:
-                    # per-row NaN/inf guard sentinel: fail THIS request,
-                    # quarantine the slot, keep the rest of the batch
-                    self._poisoned(slot, ctx)
-                    continue
-                if tok < 0:  # SKIP sentinel: speculative window ended early
-                    continue
-                self._consume(slot, ctx, tok)
+        with TraceAnnotation("sched.process"):
+            for i in range(rows.shape[0]):
+                for slot, ctx in list(self._slots.items()):
+                    if seq <= ctx.admit_seq:
+                        continue
+                    if i > 0 and frozen is not None and slot in frozen:
+                        continue
+                    tok = int(rows[i, slot])
+                    if tok == NAN_TOKEN:
+                        # per-row NaN/inf guard sentinel: fail THIS request,
+                        # quarantine the slot, keep the rest of the batch
+                        self._poisoned(slot, ctx)
+                        continue
+                    if tok < 0:  # SKIP sentinel: spec window ended early
+                        continue
+                    self._consume(slot, ctx, tok)
 
     def _consume(self, slot: int, ctx: _SlotCtx, token_id: int) -> None:
         """Handle one sampled token for one slot: stream, stop, constrain."""

@@ -258,34 +258,41 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
         Hq = lp["wq"].shape[-1] // hd
         Hkv = lp["wk"].shape[-1] // hd
 
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = qnt.matmul(h, lp["wq"])
-    k = qnt.matmul(h, lp["wk"])
-    v = qnt.matmul(h, lp["wv"])
-    if "bq" in lp:
-        q = q + lp["bq"].astype(q.dtype)
-        k = k + lp["bk"].astype(k.dtype)
-        v = v + lp["bv"].astype(v.dtype)
-    q = q.reshape(*q.shape[:-1], Hq, hd)
-    k = k.reshape(*k.shape[:-1], Hkv, hd)
-    v = v.reshape(*v.shape[:-1], Hkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    # the scopes name the model's parts in the profiler's trace (metadata
+    # only: the compiled program is the same with or without them)
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q = qnt.matmul(h, lp["wq"])
+        k = qnt.matmul(h, lp["wk"])
+        v = qnt.matmul(h, lp["wv"])
+        if "bq" in lp:
+            q = q + lp["bq"].astype(q.dtype)
+            k = k + lp["bk"].astype(k.dtype)
+            v = v + lp["bv"].astype(v.dtype)
+        q = q.reshape(*q.shape[:-1], Hq, hd)
+        k = k.reshape(*k.shape[:-1], Hkv, hd)
+        v = v.reshape(*v.shape[:-1], Hkv, hd)
+    with jax.named_scope("attn.rope"):
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     attn, new_kv = attend(q, k, v)
-    attn = attn.reshape(*attn.shape[:-2], Hq * hd)
-    wo_out = qnt.matmul(attn, lp["wo"])
-    x = x + (reduce(wo_out) if reduce is not None else wo_out)
+    with jax.named_scope("attn.out"):
+        attn = attn.reshape(*attn.shape[:-2], Hq * hd)
+        wo_out = qnt.matmul(attn, lp["wo"])
+        x = x + (reduce(wo_out) if reduce is not None else wo_out)
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     if "moe_gate" in lp:
-        out = _moe_mlp(cfg, h, lp, reduce)
+        with jax.named_scope("moe"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _moe_mlp(cfg, h, lp, reduce)
     else:
-        gated = (jax.nn.silu(qnt.matmul(h, lp["w_gate"]))
-                 * qnt.matmul(h, lp["w_up"]))
-        down = qnt.matmul(gated, lp["w_down"])
-        out = reduce(down) if reduce is not None else down
-    x = x + out
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            gated = (jax.nn.silu(qnt.matmul(h, lp["w_gate"]))
+                     * qnt.matmul(h, lp["w_up"]))
+            down = qnt.matmul(gated, lp["w_down"])
+            x = x + (reduce(down) if reduce is not None else down)
     return x, new_kv
 
 
@@ -366,12 +373,20 @@ def forward(
     cos_t, sin_t = rope
     cos = cos_t[positions][:, :, None, :]  # [B, T, 1, hd/2]
     sin = sin_t[positions][:, :, None, :]
-    if embeds is None:
-        x = qnt.embed_rows(params["embed"], tokens, jnp.dtype(cfg.dtype))
-    else:
-        x = embeds.astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("embed"):
+        if embeds is None:
+            x = qnt.embed_rows(params["embed"], tokens, jnp.dtype(cfg.dtype))
+        else:
+            x = embeds.astype(jnp.dtype(cfg.dtype))
     if attn is None:
-        attn = lambda q, keys, values, m: _grouped_attn(cfg, q, keys, values, m)  # noqa: E731
+        # the XLA attend over the context kv_write exposes: what a prefill
+        # chunk runs (and a decode step under attn_impl: xla). A kernel
+        # passed in as ``attn`` brings its own scope (attn.paged_decode)
+        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
+
+        def attn(q, keys, values, m):
+            with jax.named_scope(xla_scope):
+                return _grouped_attn(cfg, q, keys, values, m)
 
     def body(carry, layer_in):
         lp, layer_kv = layer_in
@@ -383,12 +398,15 @@ def forward(
         y, new_kv = _layer(cfg, carry, lp, cos, sin, attend, reduce=reduce)
         return y, new_kv
 
-    x, new_kv_stack = lax.scan(body, x, (params["layers"], kv_stack))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("layers"):
+        x, new_kv_stack = lax.scan(body, x, (params["layers"], kv_stack))
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x, new_kv_stack
 
 
 def logits_from_hidden(cfg: LlamaConfig, params: PyTree, x: jax.Array) -> jax.Array:
-    if cfg.tie_word_embeddings:
-        return qnt.matmul_t(x, params["embed"])
-    return qnt.matmul(x, params["lm_head"])
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            return qnt.matmul_t(x, params["embed"])
+        return qnt.matmul(x, params["lm_head"])

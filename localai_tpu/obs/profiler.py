@@ -28,14 +28,15 @@ has to fire **when** the anomaly happens.  This module arms exactly that:
     listed at ``GET /debug/profiles`` and counted as
     ``localai_profiles_captured_total{trigger=...}``.
 
-The capture itself wraps ``jax.profiler.start_trace``/``stop_trace``
-(the same machinery as ``POST /backend/trace``); tests inject a fake
+The capture itself is :func:`capture` (the one place that starts
+``jax.profiler``, shared with ``POST /backend/trace``); tests inject a fake
 ``capture_fn`` and clock, so the trigger/rate-limit/single-flight state
 machine is exercised without a device.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -65,16 +66,43 @@ def enabled_from_env() -> bool:
     return os.environ.get("LOCALAI_PROFILE_ON_ANOMALY", "0") == "1"
 
 
-def _jax_capture(path: str, seconds: float) -> None:
-    """The real capture: a bounded jax.profiler trace window (XProf/
-    TensorBoard format, same as POST /backend/trace)."""
+def capture(path: str, seconds: float, python_tracer: bool = False) -> None:
+    """THE capture, for both surfaces (the anomaly profiler and ``POST
+    /backend/trace``): a bounded jax.profiler window, XProf/TensorBoard
+    format. The host tracer is on, so the scheduler's ``sched.*``
+    TraceAnnotations (engine/scheduler.py) land in the same trace, on the
+    same clock, as the device's operations. The Python tracer (every
+    frame of every thread: it slows the host the trace is meant to
+    measure) is off unless asked for."""
     import jax
 
-    jax.profiler.start_trace(path)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 2
+    options.python_tracer_level = 1 if python_tracer else 0
+    jax.profiler.start_trace(path, profiler_options=options)
     try:
         time.sleep(seconds)
     finally:
         jax.profiler.stop_trace()
+
+
+def scoped(name: str) -> Callable[[Callable], Callable]:
+    """Decorator: what the function stages into a program carries ``name``
+    in the profiler's trace (``jax.named_scope``: metadata, the compiled
+    program is the same). A new context manager a call, where
+    ``@jax.named_scope(name)`` would share one between tracing threads."""
+
+    def deco(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return deco
 
 
 class ProfileManager:
@@ -110,7 +138,7 @@ class ProfileManager:
                        else _env_float("LOCALAI_PROFILE_POLL_S", 5.0))
         self.registry = registry or REGISTRY
         self._clock = clock
-        self._capture_fn = capture_fn or _jax_capture
+        self._capture_fn = capture_fn or capture
         # single-flight: at most one capture at a time, manual included
         # (POST /backend/trace acquires the same lock)
         self._capture_lock = threading.Lock()

@@ -264,6 +264,7 @@ def decode_attention(
     scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
     out = pl.pallas_call(
         kernel,
+        name="decode_attn",
         grid=(S, Hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),
@@ -344,6 +345,7 @@ def prefill_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="prefill_attn",
         grid=(Hkv, T // bq),
         in_specs=[
             pl.BlockSpec((1,), lambda h, i: (0,), memory_space=pltpu.SMEM),
@@ -502,6 +504,7 @@ def paged_decode_attention(
     scratch += [pltpu.SemaphoreType.DMA((depth,))] * 2
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attn",
         grid=(S, Hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),

@@ -1,0 +1,241 @@
+"""The benchmark's tracing in the run that measures (``--trace 2``, ISSUE 25),
+held to its order of events on the CPU at the benchmark's tiny test
+configuration, and the reduction of a trace that names what ran: scope paths
+for the device's operations, ``sched.*`` phases for its idle gaps, the three
+readers that rest on them. Traces are hand-made XSpace protobufs
+(benchmark/tests/xspace.py): the CPU backend has no device plane."""
+
+import importlib.util
+import json
+import sys
+import time
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmark"
+sys.path[:0] = [str(BENCH), str(BENCH / "tests")]
+
+import run as bench  # noqa: E402
+import xspace  # noqa: E402
+from harness import metrics as mtr  # noqa: E402
+from harness import peaks, spec  # noqa: E402
+from harness import trace_reduce as tr  # noqa: E402
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_tests_conftest", BENCH / "tests" / "conftest.py")
+_conftest = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_conftest)
+bench_copy = _conftest.bench_copy   # the fixture: a copy with the tiny cells
+
+T0 = 1_790_000_000_000_000_000      # Unix ns
+FP = 77                             # the decode program's fingerprint
+POOL = "bf16[2,9,2,16,16]"          # tiny: 2 layers, 9 blocks, 2 kv heads
+HLO = {
+    "mlp": "%fusion.1 = bf16[4,128]{1,0} fusion(bf16[4,64]{1,0} %p0)",
+    "kernel": "%paged_decode_attn.2 = bf16[4,2,2,16]{3,2,1,0} custom-call("
+              "bf16[4,2,2,16]{3,2,1,0} %q), custom_call_target="
+              '"tpu_custom_call"',
+    "write": "%scatter.3 = bf16[9,2,16,16]{3,2,1,0} scatter(bf16[9,2,16,16]"
+             "{3,2,1,0} %pool)",
+    "restack": f"%copy.4 = {POOL}{{4,3,2,1,0}} copy({POOL}{{4,3,2,1,0}} %kv)",
+    "slice": "%dynamic-slice.5 = s8[1,64,64]{2,1,0} dynamic-slice(s8[2,64,64]"
+             "{2,1,0} %w)",
+    "prefill": "%fusion.9 = bf16[1,32,64]{2,1,0} fusion(bf16[1,32,64]{2,1,0}"
+               " %x)",
+}
+PATH = "jit(_decode_paged_fn)/jit(main)/decode/layers/while/body/"
+
+
+def named_trace(tmp_path, host=True, scopes=True) -> Path:
+    """One chip, two decode steps and a prefill chunk (ns from the trace's
+    start), idle in [400,600) and [800,900) and [1000,1100); the engine
+    thread admits in [380,590), processes twice and waits in [790,1000)."""
+    ops = [(HLO["mlp"], 100, 100), (HLO["kernel"], 200, 100),
+           (HLO["write"], 300, 50), (HLO["restack"], 350, 50),
+           (HLO["slice"], 600, 50), (HLO["mlp"], 650, 150),
+           (HLO["prefill"], 900, 100), (HLO["mlp"], 1100, 100)]
+    meta = {HLO["mlp"]: {"tf_op": PATH + "mlp/dot_general:"},
+            HLO["kernel"]: {"tf_op": PATH + "closed_call/attn.paged_decode/"
+                                            "paged_decode_attn/pallas_call:"},
+            HLO["write"]: {"tf_op": PATH + "kv_pool.write/scatter:"},
+            HLO["slice"]: {"tf_op": PATH + "dynamic_slice:"},
+            HLO["prefill"]: {"tf_op": "jit(_prefill_paged_fn)/jit(main)/"
+                                      "prefill/layers/while/body/mlp/dot:"}}
+    for name in meta:
+        meta[name]["program_id"] = 88 if name == HLO["prefill"] else FP
+    planes = [xspace.plane("/device:TPU:0", {
+        "XLA Ops": ops,
+        "XLA Modules": [(f"jit__decode_paged_fn({FP})", 100, 300),
+                        (f"jit__decode_paged_fn({FP})", 600, 200),
+                        ("jit__prefill_paged_fn(88)", 900, 100),
+                        (f"jit__decode_paged_fn({FP})", 1100, 100)]},
+        meta=meta if scopes else None)]
+    if host:
+        planes.append(xspace.plane("/host:CPU", {
+            "engine-tiny/71": [("sched.process", 340, 20),
+                               ("sched.admit", 380, 210),
+                               ("sched.decode_launch", 590, 10),
+                               ("sched.process", 760, 30),
+                               ("sched.wait_device", 790, 210)],
+            "MainThread/1": [("sched.admit", 0, 2000),     # not the engine:
+                             ("aiohttp", 0, 50)]}))        # fewer phases
+    planes.append(xspace.plane("Task Environment", {}, {
+        "profile_start_time": T0, "profile_stop_time": T0 + 2000}))
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(xspace.space(planes))
+    return path
+
+
+def test_scope_paths_drop_transforms_control_flow_and_the_primitive():
+    assert tr.scope_path(PATH + "mlp/dot_general:") == "decode/layers/mlp"
+    assert tr.scope_path("jit(f)/jit(main)/decode/layers/while/body/"
+                         "closed_call/attn.paged_decode/paged_decode_attn/"
+                         "pallas_call") == (
+        "decode/layers/attn.paged_decode/paged_decode_attn")
+    assert tr.scope_path("jit(step)/while/body/closed_call/dot_general:") == ""
+    assert tr.scope_path("jit(f)/cond/branch_1_fun/sample/top_k") == "sample"
+
+
+def test_operations_go_by_scope_and_gaps_by_the_schedulers_phase(tmp_path):
+    out = tr.reduce(named_trace(tmp_path))
+    ops = {k: pytest.approx(v) for k, v in out["breakdown"]["device_ops"]}
+    assert ops == {
+        # a leaf scope sums what was staged under it, in either program
+        "decode/layers/mlp": 350e-9, "prefill/layers/mlp": 100e-9,
+        "decode/layers/attn.paged_decode/paged_decode_attn": 100e-9,
+        "decode/layers/kv_pool.write": 50e-9,
+        # directly under a scope that has scopes inside: by shape
+        "decode/layers s8[1,64,64]": 50e-9,
+        # the program named nothing (an XLA layout copy): as before
+        f"copy.4 copy {POOL}": 50e-9}
+    # by HLO name too, for the readers that match on it
+    assert tr.op_seconds(out, r"custom-call:tpu_custom_call")[0] == (
+        pytest.approx(100e-9))
+    assert tr.module_seconds(out, "decode") == (pytest.approx(600e-9), 3)
+    # gaps, longest first: [400,600) is the admission's, [800,900) falls
+    # under the wait for the device, [1000,1100) under nothing the engine
+    # thread wrote: the other thread's two-microsecond "sched.admit" is not
+    # the engine's
+    assert out["breakdown"]["idle_gaps"] == [
+        ["sched.admit", pytest.approx(200e-9)],
+        ["sched.wait_device", pytest.approx(100e-9)],
+        ["unattributed", pytest.approx(100e-9)]]
+    assert out["notes"]["engine_line"] == "engine-tiny/71"
+
+
+def test_a_gap_no_phase_covers_is_unattributed(tmp_path):
+    phases = [(0.0, 1.0, "sched.admit"), (1.0, 1.5, "sched.process")]
+    assert tr.gap_owner((0.8, 0.5), phases) == "sched.process"
+    assert tr.gap_owner((2.0, 0.5), phases) == "unattributed"
+    out = tr.reduce(named_trace(tmp_path, host=False))
+    assert {g[0] for g in out["breakdown"]["idle_gaps"]} == {"unattributed"}
+    assert out["phases"] == [] and out["idle_owned_s"] is None
+
+
+def tiny_ctx(trace) -> dict:
+    class Cell:
+        published = {"num_hidden_layers": 2, "num_key_value_heads": 2,
+                     "hidden_size": 64, "num_attention_heads": 4}
+        config = {"engine": {"kv_num_blocks": 9, "kv_block_tokens": 16}}
+
+    return {"cell": Cell, "trace": trace}
+
+
+@pytest.mark.parametrize("metric, value", [
+    # of 600 ns in the decode programs: the scoped write (50) and the
+    # unscoped pool-shaped copy (50); the weight slice is not the pool's
+    ("runner.kv_move_share", 100.0 * 100 / 600),
+    # 20 and 30 ns: numpy's p90 between two samples
+    ("sched.process_ms_p90", 29e-6),
+    # idle under the admission and the launch ([400,600)) over 1100 ns;
+    # what falls under sched.wait_device is not the scheduler's
+    ("sched.device_idle_share", 100.0 * 200 / 1100),
+])
+def test_the_new_readers_read_scopes_and_phases(tmp_path, metric, value):
+    read = spec.load_reader(metric, ROOT)
+    assert read(tiny_ctx(tr.reduce(named_trace(tmp_path)))) == (
+        pytest.approx(value))
+    # a program that names nothing (the parent's), or no trace at all:
+    # nothing to read, and no error
+    bare = tr.reduce(named_trace(tmp_path, host=False, scopes=False))
+    assert read(tiny_ctx(bare)) is None
+    assert read(tiny_ctx(None)) is None
+
+
+# ---------------------------------------------------------------------------
+# --trace 2, end to end on the CPU
+
+
+@pytest.mark.parametrize("workload", ["tiny-open", "tiny-closed"])
+def test_trace_2_scores_first_and_asks_the_server_afterwards(
+        bench_copy, workload, capsys, monkeypatch, tmp_path):
+    """One line with both kinds of metric; the end-to-end values are what
+    ``--trace 0`` computes from the same records; the rings are read, the
+    profiler started and the ``trace`` stream sent only after the last scored
+    record has ended."""
+    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    asked: dict[str, list] = {}
+    for name in ("read_flight", "read_traces", "capture_trace"):
+        def spy(*args, _real=getattr(bench, name), _name=name, **kw):
+            asked.setdefault(_name, []).append(time.monotonic())
+            return _real(*args, **kw)
+        monkeypatch.setattr(bench, name, spy)
+
+    def reduce_run(run_dir, traced, _real=tr.reduce_run):
+        # the real capture's file is there (and has no device plane)
+        assert tr.find_xplane(run_dir, traced) is not None
+        fake = named_trace(tmp_path)
+        monkeypatch.setattr(tr, "find_xplane", lambda *a: fake)
+        return _real(run_dir, traced)
+
+    monkeypatch.setattr(tr, "reduce_run", reduce_run)
+    rc = bench.main(["--workload", workload, "--seed", str(2**31 + 5),
+                     "--seconds", "3", "--trace", "2"], platform="cpu",
+                    root=bench_copy)
+    assert rc == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    out = json.loads(lines[-1])
+    assert set(out) == {"correct", "attempted", "failed", "metrics",
+                        "device", "breakdown"}
+    assert out["correct"] is True and out["failed"] == 0
+    cell = spec.load_cell(workload, bench_copy)
+    e2e = {m["name"] for m in cell.end_to_end}
+    assert e2e < set(out["metrics"])
+    for name in ("sched.host_share", "runner.occupancy_mean",
+                 "runner.compiles_in_window", "device.idle_share",
+                 "runner.kv_move_share", "sched.process_ms_p90",
+                 "sched.device_idle_share"):
+        assert name in out["metrics"], name
+    assert {"busy_s", "window_s", "memory_peak_bytes"} <= set(out["device"])
+    assert out["breakdown"]["idle_gaps"][0][0] == "sched.admit"
+
+    run_dir = bench_copy / "benchmark" / ".run" / workload
+    raw = json.loads(next(run_dir.glob("raw-*.json")).read_text())
+    records = [mtr.Record(
+        idx=r["idx"], stream=r["stream"], due=r["due"], sent=r["sent"],
+        status=r["status"], times=r["times"], counts=r["counts"],
+        done=r["done"], ended=r["ended"], max_tokens=r["max_tokens"],
+        # the raw file keeps a reply's verdict, not its text
+        error=r["problem"], finish_reason="length",
+        text="a" * r["max_tokens"], completion_tokens=r["max_tokens"],
+    ) for r in raw["records"]]
+    w = mtr.Window(*raw["window"])
+    # what --trace 0 prints: the same function on the records as they stood
+    # when the last scored one ended; the slice's, all later, change nothing
+    again = mtr.end_to_end(records, w, raw["loop"], raw["setup_s"])
+    for name in e2e:
+        assert out["metrics"][name]["value"] == pytest.approx(again[name])
+    scored = mtr.scored(records, w, raw["loop"])
+    slice_ = [r for r in records if r.stream == "trace"]
+    assert out["attempted"] == len(scored) > 0 and slice_
+    assert not {id(r) for r in slice_} & {id(r) for r in scored}
+    last = max(r.ended for r in scored)
+    if raw["loop"] == "closed":     # cut off at the close
+        last = max(last, w.t_close)
+    assert len(asked["capture_trace"]) == 2     # one thrown away, one read
+    for name, times in asked.items():
+        assert min(times) > last, name
+    assert min(r.sent for r in slice_) > last
+    assert not list(run_dir.rglob("*.xplane.pb"))   # reduced, then deleted

@@ -9,6 +9,9 @@ import json
 import threading
 import time
 
+import pytest
+
+from localai_tpu.obs import profiler as obs_profiler
 from localai_tpu.obs.flight import FlightRecorder
 from localai_tpu.obs.metrics import Registry
 from localai_tpu.obs.profiler import ProfileManager
@@ -261,3 +264,153 @@ def test_install_idempotent_and_stop_deregisters(tmp_path):
     pm.install(watchdog=wd, slo=slo)
     assert len(wd._callbacks) == 1 and len(slo._shed_callbacks) == 1
     pm.stop()
+
+
+# -- one capture, one clock: names in the profiler's own trace (ISSUE 25) ----
+
+
+def test_one_place_starts_the_profiler():
+    """``jax.profiler.start_trace`` is called from obs.profiler.capture and
+    nowhere else in the package: both surfaces share it."""
+    import pathlib
+    import re
+
+    import localai_tpu
+
+    root = pathlib.Path(localai_tpu.__file__).parent
+    calls = {str(p.relative_to(root))
+             for p in root.rglob("*.py")
+             if re.search(r"\bstart_trace\(", p.read_text())}
+    assert calls == {"obs/profiler.py"}
+    assert ProfileManager()._capture_fn is obs_profiler.capture
+
+
+@pytest.mark.parametrize("asked, level", [(False, 0), (True, 1)])
+def test_capture_turns_the_python_tracer_off_unless_asked(
+        monkeypatch, tmp_path, asked, level):
+    import jax
+
+    seen = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda path, profiler_options=None: seen.append(profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    obs_profiler.capture(str(tmp_path), 0.0, python_tracer=asked)
+    assert seen[0].python_tracer_level == level
+    assert seen[0].host_tracer_level >= 1     # TraceAnnotations are recorded
+
+
+def test_backend_trace_python_tracer_is_off_unless_the_body_asks(
+        tmp_path, monkeypatch):
+    """The capture behind POST /backend/trace is obs.profiler's one capture
+    function: Python frames are recorded only for {"python_tracer": true}."""
+    import jax
+
+    levels = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda path, profiler_options=None: levels.append(
+            profiler_options.python_tracer_level))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    from test_api import _ServerThread, make_state
+
+    state = make_state(tmp_path, write_tiny=True)
+    srv = _ServerThread(state)
+    try:
+        import httpx
+
+        with httpx.Client(base_url=srv.base, timeout=120.0) as c:
+            for body in ({"seconds": 0.1},
+                         {"seconds": 0.1, "python_tracer": False},
+                         {"seconds": 0.1, "python_tracer": True}):
+                assert c.post("/backend/trace", json=body).status_code == 200
+            assert c.post("/backend/trace", json={
+                "seconds": 0.1, "python_tracer": "yes"}).status_code == 400
+    finally:
+        srv.stop()
+    assert levels == [0, 0, 1]
+
+
+@pytest.fixture(scope="module")
+def paged_runner():
+    from localai_tpu.engine.runner import ModelRunner
+    from localai_tpu.models.registry import resolve_model
+
+    tiny = resolve_model("debug:tiny", dtype="float32")
+    return ModelRunner(tiny.cfg, tiny.params, num_slots=4, max_ctx=128,
+                       paged=True, kv_block_tokens=16, prefill_chunk=32,
+                       kv_dtype="float32", attn_impl="pallas_interpret")
+
+
+def test_a_capture_holds_the_schedulers_phases(paged_runner, tmp_path):
+    """A real 0.3 s capture on the CPU while a request is served: the
+    engine thread's line of the host plane carries the ``sched.*``
+    annotations, no Python frame (the tracer is off by default)."""
+    from jax.profiler import ProfileData
+
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    s = Scheduler(paged_runner, ByteTokenizer())
+    try:
+        done, served = threading.Event(), []
+
+        def serve():    # requests back to back until the capture is over
+            while not done.is_set():
+                served.append(s.generate(GenRequest(
+                    prompt=list(b"x" * 40), max_new_tokens=32,
+                    ignore_eos=True)).finish_reason)
+
+        s.generate(GenRequest(prompt=list(b"x" * 40), max_new_tokens=2))
+        t = threading.Thread(target=serve)
+        t.start()
+        try:
+            obs_profiler.capture(str(tmp_path), 0.3)
+        finally:
+            done.set()
+            t.join(120)
+        assert not t.is_alive() and set(served) == {"length"}
+    finally:
+        s.shutdown()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(str(path)).planes
+            if p.name == "/host:CPU"]
+    by_line = {ln.name: {ev.name for ev in ln.events}
+               for p in host for ln in p.lines}
+    phases = {n for names in by_line.values() for n in names
+              if n.startswith("sched.")}
+    assert {"sched.admit", "sched.decode_launch", "sched.wait_device",
+            "sched.process"} <= phases
+    # one thread wrote them all: the engine thread
+    assert sum(1 for names in by_line.values()
+               if any(n.startswith("sched.") for n in names)) == 1
+    assert not any(n.startswith("$") for names in by_line.values()
+                   for n in names)        # "$file:line fn" = a Python frame
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lowered_programs_carry_the_scope_names(paged_runner, program):
+    """Scopes are metadata of the lowered program (no profiler needed): the
+    model's parts, the pool's writes and the kernel are named in both
+    serving programs."""
+    import jax
+    import jax.numpy as jnp
+
+    r = paged_runner
+    if program == "decode":
+        lowered = jax.jit(r._decode_paged_fn).lower(
+            r.params, r.kv, r.state, r.block_tables)
+        want = ("decode/", "attn.paged_decode", "paged_decode_attn")
+    else:
+        i32 = jnp.int32
+        lowered = jax.jit(
+            r._prefill_paged_fn, static_argnames=("bucket", "sample")).lower(
+            r.params, r.kv, r.state, jnp.zeros((1, 32), i32), i32(5),
+            i32(0), jnp.zeros((r.max_blocks,), i32), i32(0),
+            jnp.zeros((r.cfg.vocab_size,), i32), bucket=32, sample=True)
+        want = ("prefill/", "attn.prefill", "kv_pool.gather")
+    text = lowered.as_text(debug_info=True)
+    for name in want + ("embed", "layers", "attn.qkv", "attn.rope",
+                        "kv_pool.write", "attn.out", "mlp", "final_norm",
+                        "lm_head", "sample"):
+        assert name in text, name
