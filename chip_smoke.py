@@ -245,8 +245,15 @@ def kernel_child(spec: dict) -> int:
             quant = quantize_lastdim4 if kv_dtype == "int4" else quantize_lastdim
             (k, ks), (v, vs) = quant(k), quant(v)
             extra = (ks, vs)
-        run(f"paged_decode {kv_dtype} bt={bt} hd={hd}",
-            lambda *a: ops.paged_decode_attention(*a, interpret=interpret),
+
+        # the kernel takes the pool stacked over layers and a layer index
+        # (in the served programs the pool is the layer scan's carry)
+        def paged_kernel(q, k, v, tables, positions, *scales):
+            return ops.paged_decode_attention(
+                q, k[None], v[None], jnp.int32(0), tables, positions,
+                *(x[None] for x in scales), interpret=interpret)
+
+        run(f"paged_decode {kv_dtype} bt={bt} hd={hd}", paged_kernel,
             ops.paged_decode_attention_ref,
             q, k, v, tables, positions, *extra)
 
@@ -272,13 +279,15 @@ def kernel_child(spec: dict) -> int:
 
     q = normal((S, Hq, hd))
     k, v = normal((S, Hkv, ctx, hd)), normal((S, Hkv, ctx, hd))
-    run("decode bfloat16",
-        lambda *a: ops.decode_attention(*a, interpret=interpret),
-        decode_ref, q, k, v, positions)
+    def decode_kernel(q, k, v, pos, *scales):
+        return ops.decode_attention(q, k[None], v[None], jnp.int32(0), pos,
+                                    *(x[None] for x in scales),
+                                    interpret=interpret)
+
+    run("decode bfloat16", decode_kernel, decode_ref, q, k, v, positions)
     (k8, ks), (v8, vs) = quantize_lastdim(k), quantize_lastdim(v)
-    run("decode int8",
-        lambda *a: ops.decode_attention(*a, interpret=interpret),
-        decode_ref, q, k8, v8, positions, ks, vs)
+    run("decode int8", decode_kernel, decode_ref, q, k8, v8, positions,
+        ks, vs)
 
     for T in spec["prefill_buckets"]:
         length = jnp.int32(T - T // 3)               # a padded bucket

@@ -3,23 +3,37 @@
 Replaces llama.cpp's per-slot KV management (kv_cache_clear / cache_tokens /
 n_ctx-per-slot partitioning, /root/reference/backend/cpp/llama/
 grpc-server.cpp:176,906,1546-1990) with a TPU-native layout: one statically
-shaped tensor pair per model, stacked over layers so the layer loop can
-``lax.scan`` it, sliced per slot by masking — never by ragged mutation.
+shaped tensor pair per model, stacked over layers, sliced per slot by
+masking — never by ragged mutation.
 
-Layout: k,v each [num_layers, num_slots, num_kv_heads, max_ctx, head_dim].
+Layout: k,v each [num_layers, num_slots, num_kv_heads, max_ctx, head_dim]
+(paged: [num_layers, num_blocks, num_kv_heads, block_tokens, head_dim]).
 Heads lead the context dim so the last two axes are (context, head_dim) —
 the (sublane, lane) tiling Mosaic requires for the flash kernels' per-head
 HBM→VMEM DMA slices (ops.attention), and a contiguous stream per head.
-All updates are functional. jit donation lets XLA reuse the cache's buffers;
-it does not yet make the update in-place: compiled for v5e, the layer scan
-that carries the cache (models.llama.forward) holds a second, cache-sized
-temp (PERF.md).
+
+All updates are functional, and compiled they are in place. The stack is
+the CARRY of the layer scan (models.llama.forward), donated by the runner's
+jits. One contract for every write policy below:
+
+    kv_write(kv_stack, layer, k_new, v_new) -> (new_kv_stack, keys, values)
+
+``kv_stack`` is the whole stacked pytree (``stacked()``), ``layer`` the
+scan's i32 layer index. A policy scatters ONLY the new rows into the stack
+(``_write_rows``, ``_write_run``, ``_write_chunk``) and hands the attend
+what it reads: the blocks or rows gathered straight from the 5-D array,
+or, ``raw=True``, a ``LayerView`` of the stack for a Pallas kernel that
+indexes the layer itself. Nothing slices a layer out of the stack to update
+it or to pass it on: such a slice, its re-layout and the restack cost more
+than the rest of a decode step together (PERF.md, PR 26).
+tests/test_tpu_compile.py compiles the runner's programs for a described
+v5e and holds them to no cache-sized temp and no layer-shaped copy.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +75,8 @@ class KVCache:
         return self.k_scale is not None
 
     def stacked(self):
-        """The pytree scanned alongside layers in models.llama.forward."""
+        """The pytree models.llama.forward carries through its layer scan
+        and the write policies update in place."""
         if self.k_scale is None:
             return (self.k, self.v)
         return (self.k, self.v, self.k_scale, self.v_scale)
@@ -200,22 +215,159 @@ def init_paged_cache(
                         v=zeros(shape, dt, sharding))
 
 
-def _pool_quant(layer_kv, k_new):
-    """The quantizer matching a paged pool's storage: int4 when the pool's
-    last dim is the packed hd/2 (self-describing layout), else int8.
-    ``k_new`` carries the full head_dim."""
-    int4 = layer_kv[0].shape[-1] * 2 == k_new.shape[-1]
-    return (_quant_chunk4 if int4 else _quant_chunk), int4
+class LayerView(NamedTuple):
+    """What a ``raw=True`` policy hands a Pallas decode kernel in place of
+    keys (and of values): the WHOLE stacked cache, the layer to read, and
+    the stacked scales of a quantized cache. The kernel picks the layer in
+    its DMA slice (ops.attention), so no per-layer slice ever exists."""
+
+    cache: jax.Array                    # [L, ...] all layers
+    layer: jax.Array                    # scalar i32
+    scale: Optional[jax.Array] = None   # [L, ...] f32, quantized caches
 
 
-def _gather_dequant(cache, scales, tables, dt, int4: bool):
-    """Gather + dequantize a quantized pool's logical context for the XLA
-    attend: [S, H, MB*bt, hd] in ``dt`` (int4 pools unpack first)."""
-    g = gather_blocks(cache, tables)
-    if int4:
-        g = _unpack4(g)
-    return (g.astype(dt)
-            * gather_block_scales(scales, tables)[..., None].astype(dt))
+def _is_int4(kv_stack, k_new) -> bool:
+    """A paged pool is self-describing: int4 when its last dim is the
+    packed hd/2 (``k_new`` carries the full head_dim)."""
+    return len(kv_stack) == 4 and kv_stack[0].shape[-1] * 2 == k_new.shape[-1]
+
+
+def _write(kv_stack, k_new, v_new, put):
+    """The new stack, ``put(leaf, rows)`` applied to every leaf of
+    ``kv_stack`` with the rows that leaf stores for ``k_new``/``v_new
+    [..., hd]``: the rows in the cache's dtype, or, for a scaled int8/int4
+    cache (a 4-tuple), the quantized rows and their ``[...]`` scale rows."""
+    with jax.named_scope("kv_pool.write"):
+        stored = (k_new, v_new)
+        if len(kv_stack) == 4:
+            quant = _quant_chunk4 if _is_int4(kv_stack, k_new) else _quant_chunk
+            (kq, kscale), (vq, vscale) = quant(k_new), quant(v_new)
+            stored = (kq, vq, kscale, vscale)
+        return tuple(put(leaf, rows.astype(leaf.dtype))
+                     for leaf, rows in zip(kv_stack, stored))
+
+
+def _scatter_per_head(cache, idx, rows):
+    """``cache[i0, i1, h(, i2)] = rows[..., h]`` for every head ``h``:
+    ``cache [L, N, H, ...]``; ``idx`` the layer, the block or slot and,
+    when rows and not blocks are written, the token, broadcast to one shape
+    ``[...]``; ``rows [..., H, *window]``, the window being the cache's
+    remaining axes.
+
+    The heads are part of the INDEX, not of the update window, and their
+    index is an iota the compiler can read, laid among the others by hand.
+    The first keeps the stack row-major and the write in place (see
+    ``_write_rows``). The second keeps the write on its own chip under a
+    tensor-parallel mesh, where cache and rows are sharded over the heads:
+    the partitioner proves from the iota that every shard writes its own
+    heads only. Through ``.at[i0, i1, arange(H), i2]`` the CPU pipeline does
+    not see it and all-gathers the rows and their indices, a collective a
+    layer for K and for V; libtpu's happens to (tests/test_kv_contract.py
+    ``test_policy_is_shard_local_over_the_heads``, tests/test_tpu_compile.py
+    ``test_cell_programs_write_their_own_heads_on_a_tp4_mesh``)."""
+    idx = jnp.broadcast_arrays(*idx)
+    nb = idx[0].ndim
+    shape = (*idx[0].shape, cache.shape[2], 1)
+    cols = [jnp.broadcast_to(i.astype(jnp.int32)[..., None, None], shape)
+            for i in idx]
+    cols.insert(2, lax.broadcasted_iota(jnp.int32, shape, nb))
+    into = tuple(range(len(cols)))
+    dnums = lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(nb + 1, rows.ndim)),
+        inserted_window_dims=into, scatter_dims_to_operand_dims=into)
+    return lax.scatter(cache, lax.concatenate(cols, nb + 1), rows, dnums)
+
+
+def _write_rows(kv_stack, layer, blk, off, k_new, v_new):
+    """THE row writer of the scatter policies: ``k_new``/``v_new
+    [..., H, hd]`` land at ``[layer, blk, :, off]`` of the stacked cache,
+    ``blk``/``off`` shaped ``[...]``, everything else untouched.
+
+    The heads are part of the INDEX (``_scatter_per_head``), not of the
+    update window. With the window over (H, hd) and the token axis between
+    them, XLA lays the scatter's operand out tokens-outside-heads, the
+    Pallas kernels (and the row-major parameter) ask for
+    heads-outside-tokens, and the difference is a layout copy of the whole
+    stack per layer. With the window over hd alone the scatter keeps the
+    row-major layout and updates the carried stack in place
+    (tests/test_tpu_compile.py
+    ``test_cell_programs_write_the_pool_in_place``)."""
+
+    def put(cache, rows):
+        return _scatter_per_head(cache, (layer, blk, off), rows)
+
+    return _write(kv_stack, k_new, v_new, put)
+
+
+def _write_run(kv_stack, layer, table_row, offset, length, k_new, v_new):
+    """Scatter one sequence's run of consecutive positions
+    ``[offset, offset + length)``, rows ``k_new``/``v_new [T, H, hd]`` (the
+    first ``length`` real), into the stacked pool through ``table_row``
+    — a block at a time. A run of T tokens touches at most T/bt + 1 blocks:
+    they are gathered, the new rows laid over them, and scattered back
+    with the whole ``[bt, hd]`` block of one head as the update window:
+    (T/bt + 1) x H windows a layer where a window per row and head is
+    T x H, and XLA's TPU scatter walks its windows one by one (18 ms of an
+    82 ms chunk of 512 at Mistral-7B against 1 ms, PERF.md PR 26). Rows
+    outside the run keep what the blocks held; blocks the run does not
+    reach are written back unchanged, to the trash block."""
+    bt, MB = kv_stack[0].shape[3], table_row.shape[0]
+    nblk = -(-k_new.shape[0] // bt) + 1
+    first = offset // bt
+    pos = first * bt + jnp.arange(nblk * bt)
+    real = ((pos >= offset) & (pos < offset + length)).reshape(nblk, bt)
+    j = first + jnp.arange(nblk)
+    ids = jnp.where((j < MB) & real.any(axis=1),
+                    table_row[jnp.minimum(j, MB - 1)], 0)
+
+    def put(cache, rows):       # rows [T, H, hd] or, for scales, [T, H]
+        frame = lax.dynamic_update_slice(
+            jnp.zeros((nblk * bt, *rows.shape[1:]), rows.dtype), rows,
+            (offset % bt,) + (0,) * (rows.ndim - 1))
+        frame = jnp.moveaxis(                  # [nblk, H, bt(, hd)]
+            frame.reshape(nblk, bt, *rows.shape[1:]), 1, 2)
+        keep = real.reshape(nblk, 1, bt, *(1,) * (rows.ndim - 2))
+        merged = jnp.where(keep, frame, cache[layer, ids])
+        return _scatter_per_head(cache, (layer, ids), merged)
+
+    return _write(kv_stack, k_new, v_new, put)
+
+
+def _pairs(kv_stack):
+    """((k, k_scale), (v, v_scale)) of a stacked cache; the scales are None
+    unless it is scaled int8/int4 (a 4-tuple)."""
+    if len(kv_stack) == 4:
+        k, v, ks, vs = kv_stack
+        return (k, ks), (v, vs)
+    k, v = kv_stack
+    return (k, None), (v, None)
+
+
+def _views(kv_stack, layer):
+    """(keys, values) for the ``raw=True`` policies."""
+    return tuple(LayerView(cache, layer, scale)
+                 for cache, scale in _pairs(kv_stack))
+
+
+def _gather_context(kv_stack, layer, tables, k_new):
+    """(keys, values) ``[S, H, MB*bt, hd]`` in ``k_new``'s dtype for the
+    XLA attend over a block pool: the blocks the tables name, gathered
+    straight from the stacked pool (layer and blocks in ONE gather),
+    dequantized when the pool is scaled int8/int4."""
+    dt, int4 = k_new.dtype, _is_int4(kv_stack, k_new)
+
+    def one(cache, scales):
+        g = gather_blocks(cache, tables, layer)
+        if scales is None:
+            return g.astype(dt)
+        if int4:
+            g = _unpack4(g)
+        return (g.astype(dt)
+                * gather_block_scales(scales, tables, layer)[..., None]
+                .astype(dt))
+
+    with jax.named_scope("kv_pool.gather"):
+        return tuple(one(cache, scales) for cache, scales in _pairs(kv_stack))
 
 
 def paged_decode_write(tables: jax.Array, positions: jax.Array,
@@ -223,47 +375,23 @@ def paged_decode_write(tables: jax.Array, positions: jax.Array,
     """KV write policy for batched single-token decode over a block pool.
 
     tables: [S, MB] i32 block tables, positions: [S]. Writes k/v_new
-    [S, 1, H, hd] at pool[tables[s, pos//bt], :, pos%bt]. Released slots'
-    table rows are all-zeros, so their (static-shape-mandated) garbage
-    writes land in the trash block.
+    [S, 1, H, hd] at pool[layer, tables[s, pos//bt], :, pos%bt]. Released
+    slots' table rows are all-zeros, so their (static-shape-mandated)
+    garbage writes land in the trash block.
 
     ``raw=False`` exposes the gathered logical context [S, H, MB*bt, hd]
-    for the XLA attend; ``raw=True`` passes the pool through untouched for
-    the Pallas paged kernel (which walks the tables itself)."""
+    for the XLA attend; ``raw=True`` hands the Pallas paged kernel (which
+    walks the tables itself) a :class:`LayerView` of the stack."""
 
-    def write(layer_kv, k_new, v_new):
-        dt = k_new.dtype
-        bt = layer_kv[0].shape[2]
+    def write(kv_stack, layer, k_new, v_new):
+        bt = kv_stack[0].shape[3]
         s = jnp.arange(tables.shape[0])
         blk = tables[s, positions // bt]          # [S]
-        off = positions % bt
-        if len(layer_kv) == 4:  # scaled int8/int4 pool
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            quant, int4 = _pool_quant(layer_kv, k_new)
-            with jax.named_scope("kv_pool.write"):
-                kq, ks = quant(k_new[:, 0])    # [S, H, hd or hd/2], [S, H]
-                vq, vs = quant(v_new[:, 0])
-                new_k = k_layer.at[blk, :, off].set(kq)
-                new_v = v_layer.at[blk, :, off].set(vq)
-                new_ks = ks_layer.at[blk, :, off].set(ks)
-                new_vs = vs_layer.at[blk, :, off].set(vs)
-            new_kv = (new_k, new_v, new_ks, new_vs)
-            if raw:
-                return new_kv, (new_k, new_ks), (new_v, new_vs)
-            with jax.named_scope("kv_pool.gather"):
-                keys = _gather_dequant(new_k, new_ks, tables, dt, int4)
-                values = _gather_dequant(new_v, new_vs, tables, dt, int4)
-            return new_kv, keys, values
-        k_layer, v_layer = layer_kv               # [N, H, bt, hd]
-        kdt = k_layer.dtype
-        with jax.named_scope("kv_pool.write"):
-            new_k = k_layer.at[blk, :, off].set(k_new[:, 0].astype(kdt))
-            new_v = v_layer.at[blk, :, off].set(v_new[:, 0].astype(kdt))
+        new = _write_rows(kv_stack, layer, blk, positions % bt,
+                          k_new[:, 0], v_new[:, 0])
         if raw:
-            return (new_k, new_v), new_k, new_v
-        with jax.named_scope("kv_pool.gather"):
-            return ((new_k, new_v), gather_blocks(new_k, tables).astype(dt),
-                    gather_blocks(new_v, tables).astype(dt))
+            return (new, *_views(new, layer))
+        return (new, *_gather_context(new, layer, tables, k_new))
 
     return write
 
@@ -274,79 +402,16 @@ def paged_prefill_write(table_row: jax.Array, offset: jax.Array,
 
     table_row: [MB] i32, offset: absolute start position of this chunk,
     length: real (unpadded) tokens in the chunk. Token t of the chunk
-    lands at pool[table_row[(offset+t)//bt], :, (offset+t)%bt]; padding
-    rows (t >= length) are redirected to the trash block so a padded
-    bucket can never clobber the sequence's own reserved blocks. Exposes
-    the gathered FULL logical context [1, H, MB*bt, hd] so chunk tokens
-    attend over the kept prefix + earlier chunks (resume-style)."""
+    lands at pool[layer, table_row[(offset+t)//bt], :, (offset+t)%bt];
+    padding rows (t >= length) are written nowhere, so a padded bucket can
+    never clobber the sequence's own reserved blocks (``_write_run``).
+    Exposes the gathered FULL logical context [1, H, MB*bt, hd] so chunk
+    tokens attend over the kept prefix + earlier chunks (resume-style)."""
 
-    def write(layer_kv, k_new, v_new):  # k_new [1, T, H, hd]
-        dt = k_new.dtype
-        bt = layer_kv[0].shape[2]
-        MB = table_row.shape[0]
-        T = k_new.shape[1]
-        t = jnp.arange(T)
-        pos = offset + t
-        valid = t < length
-        blk = jnp.where(valid, table_row[jnp.minimum(pos // bt, MB - 1)], 0)
-        off = pos % bt
-        row = table_row[None]                     # [1, MB]
-        if len(layer_kv) == 4:  # scaled int8/int4 pool
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            quant, int4 = _pool_quant(layer_kv, k_new)
-            with jax.named_scope("kv_pool.write"):
-                kq, ks = quant(k_new[0])   # [T, H, hd or hd/2], [T, H]
-                vq, vs = quant(v_new[0])
-                new_k = k_layer.at[blk, :, off].set(kq)
-                new_v = v_layer.at[blk, :, off].set(vq)
-                new_ks = ks_layer.at[blk, :, off].set(ks)
-                new_vs = vs_layer.at[blk, :, off].set(vs)
-            with jax.named_scope("kv_pool.gather"):
-                keys = _gather_dequant(new_k, new_ks, row, dt, int4)
-                values = _gather_dequant(new_v, new_vs, row, dt, int4)
-            return (new_k, new_v, new_ks, new_vs), keys, values
-        k_layer, v_layer = layer_kv
-        kdt = k_layer.dtype
-        with jax.named_scope("kv_pool.write"):
-            new_k = k_layer.at[blk, :, off].set(k_new[0].astype(kdt))
-            new_v = v_layer.at[blk, :, off].set(v_new[0].astype(kdt))
-        with jax.named_scope("kv_pool.gather"):
-            return ((new_k, new_v), gather_blocks(new_k, row).astype(dt),
-                    gather_blocks(new_v, row).astype(dt))
-
-    return write
-
-
-def verify_write(positions: jax.Array):
-    """KV write policy for the batched speculative verify forward: writes
-    the window chunk [S, T, H, hd] at cache[s, :, positions[s] + t] and
-    exposes the full per-layer cache as keys ([S, H, C, hd]) —
-    ``decode_write`` generalized to T tokens per slot. Rejected positions
-    leave garbage KV *above* each slot's accepted frontier, which the
-    decode masks never read and later writes overwrite — rollback is free
-    by construction (same invariant as the bucketed prefill paths)."""
-
-    def write(layer_kv, k_new, v_new):
-        dt = k_new.dtype
-        S, T = k_new.shape[0], k_new.shape[1]
-        s = jnp.arange(S)[:, None]
-        pmat = positions[:, None] + jnp.arange(T)[None, :]  # [S, T]
-        if len(layer_kv) == 4:  # scaled int8 cache
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            kq, ks = _quant_chunk(k_new)  # [S, T, H, hd], [S, T, H]
-            vq, vs = _quant_chunk(v_new)
-            new_k = k_layer.at[s, :, pmat].set(kq)
-            new_v = v_layer.at[s, :, pmat].set(vq)
-            new_ks = ks_layer.at[s, :, pmat].set(ks)
-            new_vs = vs_layer.at[s, :, pmat].set(vs)
-            keys = new_k.astype(dt) * new_ks[..., None].astype(dt)
-            values = new_v.astype(dt) * new_vs[..., None].astype(dt)
-            return (new_k, new_v, new_ks, new_vs), keys, values
-        k_layer, v_layer = layer_kv
-        kdt = k_layer.dtype
-        new_k = k_layer.at[s, :, pmat].set(k_new.astype(kdt))
-        new_v = v_layer.at[s, :, pmat].set(v_new.astype(kdt))
-        return (new_k, new_v), new_k.astype(dt), new_v.astype(dt)
+    def write(kv_stack, layer, k_new, v_new):  # k_new [1, T, H, hd]
+        new = _write_run(kv_stack, layer, table_row, offset, length,
+                         k_new[0], v_new[0])
+        return (new, *_gather_context(new, layer, table_row[None], k_new))
 
     return write
 
@@ -357,7 +422,7 @@ def paged_verify_write(tables: jax.Array, positions: jax.Array,
     block pool — ``paged_decode_write`` generalized to T tokens per slot.
 
     Window token t of slot s lands at
-    ``pool[tables[s, (positions[s]+t)//bt], :, (positions[s]+t)%bt]``.
+    ``pool[layer, tables[s, (positions[s]+t)//bt], :, (positions[s]+t)%bt]``.
     Rows at or past ``ctx_limit`` (the runner's max_ctx) redirect to the
     trash block: near the context edge a window row beyond the last real
     position must never wrap onto the slot's own earlier rows via the
@@ -372,35 +437,16 @@ def paged_verify_write(tables: jax.Array, positions: jax.Array,
     overwritten by the next window/decode write before anything can
     attend to them."""
 
-    def write(layer_kv, k_new, v_new):  # k_new [S, T, H, hd]
-        dt = k_new.dtype
-        bt = layer_kv[0].shape[2]
+    def write(kv_stack, layer, k_new, v_new):  # k_new [S, T, H, hd]
+        bt = kv_stack[0].shape[3]
         MB = tables.shape[1]
         S, T = k_new.shape[0], k_new.shape[1]
         s = jnp.arange(S)[:, None]
         pmat = positions[:, None] + jnp.arange(T)[None, :]   # [S, T]
-        safe = pmat < ctx_limit
-        blk = jnp.where(
-            safe, tables[s, jnp.minimum(pmat // bt, MB - 1)], 0)
-        off = pmat % bt
-        if len(layer_kv) == 4:  # scaled int8/int4 pool
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            quant, int4 = _pool_quant(layer_kv, k_new)
-            kq, ks = quant(k_new)       # [S, T, H, hd or hd/2], [S, T, H]
-            vq, vs = quant(v_new)
-            new_k = k_layer.at[blk, :, off].set(kq)
-            new_v = v_layer.at[blk, :, off].set(vq)
-            new_ks = ks_layer.at[blk, :, off].set(ks)
-            new_vs = vs_layer.at[blk, :, off].set(vs)
-            keys = _gather_dequant(new_k, new_ks, tables, dt, int4)
-            values = _gather_dequant(new_v, new_vs, tables, dt, int4)
-            return (new_k, new_v, new_ks, new_vs), keys, values
-        k_layer, v_layer = layer_kv               # [N, H, bt, hd]
-        kdt = k_layer.dtype
-        new_k = k_layer.at[blk, :, off].set(k_new.astype(kdt))
-        new_v = v_layer.at[blk, :, off].set(v_new.astype(kdt))
-        return ((new_k, new_v), gather_blocks(new_k, tables).astype(dt),
-                gather_blocks(new_v, tables).astype(dt))
+        blk = jnp.where(pmat < ctx_limit,
+                        tables[s, jnp.minimum(pmat // bt, MB - 1)], 0)
+        new = _write_rows(kv_stack, layer, blk, pmat % bt, k_new, v_new)
+        return (new, *_gather_context(new, layer, tables, k_new))
 
     return write
 
@@ -418,75 +464,95 @@ def verify_mask(cfg: LlamaConfig, positions: jax.Array, T: int,
     return m
 
 
+def _layer_context(kv_stack, layer, dt, slot=None):
+    """(keys, values) for the XLA attend over the contiguous cache: the
+    layer's rows [S, H, C, hd] (one slot's, [1, H, C, hd], with ``slot``)
+    read from the stack and dequantized when it is scaled int8."""
+
+    def rows(a):
+        if slot is None:
+            return lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+        return a[layer, jnp.reshape(slot, (1,))]
+
+    def one(cache, scales):
+        if scales is None:
+            return rows(cache).astype(dt)
+        return rows(cache).astype(dt) * rows(scales)[..., None].astype(dt)
+
+    return tuple(one(cache, scales) for cache, scales in _pairs(kv_stack))
+
+
+def verify_write(positions: jax.Array):
+    """KV write policy for the batched speculative verify forward: writes
+    the window chunk [S, T, H, hd] at cache[layer, s, :, positions[s] + t]
+    and exposes the layer's cache as keys ([S, H, C, hd]) —
+    ``decode_write`` generalized to T tokens per slot. Rejected positions
+    leave garbage KV *above* each slot's accepted frontier, which the
+    decode masks never read and later writes overwrite — rollback is free
+    by construction (same invariant as the bucketed prefill paths)."""
+
+    def write(kv_stack, layer, k_new, v_new):
+        S, T = k_new.shape[0], k_new.shape[1]
+        s = jnp.broadcast_to(jnp.arange(S)[:, None], (S, T))
+        pmat = positions[:, None] + jnp.arange(T)[None, :]  # [S, T]
+        new = _write_rows(kv_stack, layer, s, pmat, k_new, v_new)
+        return (new, *_layer_context(new, layer, k_new.dtype))
+
+    return write
+
+
 def decode_write(positions: jax.Array, raw: bool = False):
     """KV write policy for batched single-token decode.
 
     positions: [S] — write location per slot. Returns a ``kv_write`` closure
     for models.llama.forward: writes k/v_new [S, 1, H, hd] at
-    cache[s, :, positions[s]] and exposes the full per-layer cache as keys
+    cache[layer, s, :, positions[s]] and exposes the layer's cache as keys
     ([S, H, C, hd]).
 
-    ``raw=True`` (int8 cache + Pallas decode kernel): keys/values are passed
-    through as ``(int8 cache, f32 scales)`` tuples — dequantization happens
-    inside the flash kernel, so no [S, H, C, hd] bf16 copy is ever built."""
+    ``raw=True`` (Pallas decode kernel): keys/values are
+    :class:`LayerView`s of the stack — the kernel reads the layer (and
+    dequantizes an int8 cache) itself, so neither a per-layer slice nor a
+    [S, H, C, hd] bf16 copy is ever built."""
 
-    def write(layer_kv, k_new, v_new):
-        dt = k_new.dtype
-        s = jnp.arange(layer_kv[0].shape[0])
-        if len(layer_kv) == 4:  # scaled int8 cache
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            kq, ks = _quant_chunk(k_new[:, 0])  # [S, H, hd], [S, H]
-            vq, vs = _quant_chunk(v_new[:, 0])
-            # advanced indices (s, positions) separated by the head slice →
-            # result dims [S, H, ...]
-            new_k = k_layer.at[s, :, positions].set(kq)
-            new_v = v_layer.at[s, :, positions].set(vq)
-            new_ks = ks_layer.at[s, :, positions].set(ks)
-            new_vs = vs_layer.at[s, :, positions].set(vs)
-            new_kv = (new_k, new_v, new_ks, new_vs)
-            if raw:
-                return new_kv, (new_k, new_ks), (new_v, new_vs)
-            keys = new_k.astype(dt) * new_ks[..., None].astype(dt)
-            values = new_v.astype(dt) * new_vs[..., None].astype(dt)
-            return new_kv, keys, values
-        k_layer, v_layer = layer_kv  # [S, H, C, hd]
-        kdt = k_layer.dtype
-        new_k = k_layer.at[s, :, positions].set(k_new[:, 0].astype(kdt))
-        new_v = v_layer.at[s, :, positions].set(v_new[:, 0].astype(kdt))
-        return (new_k, new_v), new_k.astype(dt), new_v.astype(dt)
+    def write(kv_stack, layer, k_new, v_new):
+        s = jnp.arange(kv_stack[0].shape[1])
+        new = _write_rows(kv_stack, layer, s, positions,
+                          k_new[:, 0], v_new[:, 0])
+        if raw:
+            return (new, *_views(new, layer))
+        return (new, *_layer_context(new, layer, k_new.dtype))
 
     return write
+
+
+def _write_chunk(kv_stack, layer, slot, offset, k_hm, v_hm):
+    """One slot's head-major chunk [1, H, T, hd] into the stacked
+    contiguous cache at [layer, slot, :, offset:offset+T] (quantized
+    first, with its scale rows, when the cache is scaled int8): one
+    dynamic_update_slice a leaf, in place on the carried stack."""
+    zero = jnp.zeros((), jnp.int32)
+    idx = (layer, slot, zero, offset, zero)
+
+    def put(stack, chunk):
+        return lax.dynamic_update_slice(stack, chunk[None], idx[:stack.ndim])
+
+    return _write(kv_stack, k_hm, v_hm, put)
 
 
 def prefill_write(slot: jax.Array, offset: jax.Array):
     """KV write policy for single-sequence prefill into one slot.
 
-    Writes the whole chunk [1, T, H, hd] at cache[slot, :, offset:offset+T]
-    and attends over the chunk itself (fresh context ⇒ T² attention, not
-    T·C). Keys are exposed head-major: [1, H, T, hd]."""
+    Writes the whole chunk [1, T, H, hd] at
+    cache[layer, slot, :, offset:offset+T] and attends over the chunk
+    itself (fresh context ⇒ T² attention, not T·C). Keys are exposed
+    head-major: [1, H, T, hd] — the unquantized chunk even when the cache
+    is int8 (quantization error only enters on later decode reads)."""
 
-    def write(layer_kv, k_new, v_new):
+    def write(kv_stack, layer, k_new, v_new):
         k_hm = k_new.transpose(0, 2, 1, 3)  # [1, H, T, hd]
         v_hm = v_new.transpose(0, 2, 1, 3)
-        zero = jnp.zeros((), jnp.int32)
-        idx = (slot, zero, offset, zero)
-        if len(layer_kv) == 4:  # scaled int8 cache
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            kq, ks = _quant_chunk(k_hm)  # [1, H, T, hd], [1, H, T]
-            vq, vs = _quant_chunk(v_hm)
-            new_k = lax.dynamic_update_slice(k_layer, kq, idx)
-            new_v = lax.dynamic_update_slice(v_layer, vq, idx)
-            new_ks = lax.dynamic_update_slice(ks_layer, ks, (slot, zero, offset))
-            new_vs = lax.dynamic_update_slice(vs_layer, vs, (slot, zero, offset))
-            # fresh-context prefill attends over the chunk itself, so the
-            # exposed keys/values are the unquantized chunk — quantization
-            # error only enters on later decode reads
-            return (new_k, new_v, new_ks, new_vs), k_hm, v_hm
-        k_layer, v_layer = layer_kv  # [S, H, C, hd]
-        kdt = k_layer.dtype
-        new_k = lax.dynamic_update_slice(k_layer, k_hm.astype(kdt), idx)
-        new_v = lax.dynamic_update_slice(v_layer, v_hm.astype(kdt), idx)
-        return (new_k, new_v), k_hm, v_hm
+        return (_write_chunk(kv_stack, layer, slot, offset, k_hm, v_hm),
+                k_hm, v_hm)
 
     return write
 
@@ -497,39 +563,15 @@ def resume_write(slot: jax.Array, offset: jax.Array):
     slot cache_tokens, /root/reference/backend/cpp/llama/grpc-server.cpp:
     67-74,1651-1668).
 
-    Writes the chunk [1, T, H, hd] at cache[slot, :, offset:offset+T] like
-    prefill_write, but exposes the slot's FULL cache row as keys
+    Writes the chunk [1, T, H, hd] at cache[layer, slot, :, offset:offset+T]
+    like prefill_write, but exposes the slot's FULL cache row as keys
     ([1, H, C, hd]) so the new tokens attend over the kept prefix."""
 
-    def write(layer_kv, k_new, v_new):
-        k_hm = k_new.transpose(0, 2, 1, 3)  # [1, H, T, hd]
-        v_hm = v_new.transpose(0, 2, 1, 3)
-        zero = jnp.zeros((), jnp.int32)
-        idx = (slot, zero, offset, zero)
-        dt = k_new.dtype
-
-        def row(cache, scales=None):
-            r = lax.dynamic_index_in_dim(cache, slot, 0, keepdims=True)
-            if scales is None:
-                return r.astype(dt)
-            s = lax.dynamic_index_in_dim(scales, slot, 0, keepdims=True)
-            return r.astype(dt) * s[..., None].astype(dt)
-
-        if len(layer_kv) == 4:  # scaled int8 cache
-            k_layer, v_layer, ks_layer, vs_layer = layer_kv
-            kq, ks = _quant_chunk(k_hm)
-            vq, vs = _quant_chunk(v_hm)
-            new_k = lax.dynamic_update_slice(k_layer, kq, idx)
-            new_v = lax.dynamic_update_slice(v_layer, vq, idx)
-            new_ks = lax.dynamic_update_slice(ks_layer, ks, (slot, zero, offset))
-            new_vs = lax.dynamic_update_slice(vs_layer, vs, (slot, zero, offset))
-            return ((new_k, new_v, new_ks, new_vs),
-                    row(new_k, new_ks), row(new_v, new_vs))
-        k_layer, v_layer = layer_kv
-        kdt = k_layer.dtype
-        new_k = lax.dynamic_update_slice(k_layer, k_hm.astype(kdt), idx)
-        new_v = lax.dynamic_update_slice(v_layer, v_hm.astype(kdt), idx)
-        return (new_k, new_v), row(new_k), row(new_v)
+    def write(kv_stack, layer, k_new, v_new):
+        new = _write_chunk(kv_stack, layer, slot, offset,
+                           k_new.transpose(0, 2, 1, 3),
+                           v_new.transpose(0, 2, 1, 3))
+        return (new, *_layer_context(new, layer, k_new.dtype, slot=slot))
 
     return write
 

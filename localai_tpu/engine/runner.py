@@ -10,10 +10,14 @@ XLA's compile-once/static-shape model:
   * Prefill lengths are bucketed (powers of a small set) so at most
     len(buckets) prefill programs are ever compiled — no recompilation
     storms from arbitrary prompt lengths.
-  * KV cache and decode state are donated on every dispatch. Donation
-    lets XLA reuse the buffers; it does not by itself make the update
-    in-place (the layer scan in models.llama.forward carries the KV pool
-    as xs→ys, and compiled for v5e that costs a pool-sized temp — PERF.md).
+  * KV cache and decode state are donated on every dispatch, and the
+    cache is written in place: the layer scan in models.llama.forward
+    carries the stacked cache, the write policies (engine.kvcache) scatter
+    the new rows into it, the Pallas kernels read it by layer index, so
+    the donated buffer is aliased from argument through loop state to
+    output. Compiled for v5e no program holds a cache-sized temp or copies
+    a layer of the cache (tests/test_tpu_compile.py
+    test_cell_programs_write_the_pool_in_place).
   * Sampling runs on device in the same program as the forward pass; the
     only per-step host traffic is the [S] sampled-token vector.
 """
@@ -573,8 +577,8 @@ class ModelRunner:
         cfg = self.cfg
         pos = state.positions
         attn = None
-        raw_kv = self.decode_attn_impl == "pallas" and kv.quantized
-        if self.decode_attn_impl == "pallas":
+        raw_kv = self.decode_attn_impl == "pallas"
+        if raw_kv:
             from localai_tpu import ops
 
             kernel = partial(
@@ -588,13 +592,15 @@ class ModelRunner:
                 # per-device kernel over (slots/'data', heads/'model'):
                 # decode attention is independent across slots and head
                 # groups, so the shard_map body is the single-device kernel
+                # (the stacked cache's layer axis whole on every device)
                 in_specs = [P("data", "model", None),
-                            P("data", "model", None, None),
-                            P("data", "model", None, None),
+                            P(None, "data", "model", None, None),
+                            P(None, "data", "model", None, None),
+                            P(),
                             P("data")]
-                if raw_kv:
-                    in_specs += [P("data", "model", None),
-                                 P("data", "model", None)]
+                if kv.quantized:
+                    in_specs += [P(None, "data", "model", None),
+                                 P(None, "data", "model", None)]
                 kernel = shard_map(
                     kernel,
                     mesh=self.mesh,
@@ -604,13 +610,11 @@ class ModelRunner:
                 )
 
             @scoped("attn.decode")
-            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd], keys [S,Hkv,C,hd]
-                if raw_kv:  # (int8 cache, f32 scales) — fused dequant
-                    out = kernel(q[:, 0], keys[0], values[0], pos,
-                                 keys[1], values[1])
-                else:
-                    out = kernel(q[:, 0], keys, values, pos)
-                return out[:, None]
+            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; kvc.LayerViews
+                args = (q[:, 0], keys.cache, values.cache, keys.layer, pos)
+                if kv.quantized:  # f32 scale stacks — fused dequant
+                    args += (keys.scale, values.scale)
+                return kernel(*args)[:, None]
 
         if attn is None:
             attn = self._se_attn(
@@ -1001,20 +1005,21 @@ class ModelRunner:
                 from jax.sharding import PartitionSpec as P
 
                 # per-device kernel over (slots/'data', heads/'model'):
-                # the pool's block axis stays whole on every device (table
-                # values are global block ids), its kv-head axis shards on
-                # 'model', and each data shard walks its own slots' SMEM
-                # table mirror — the shard_map body is the single-device
-                # kernel (select_paged_attn_impl refuses Pallas when the
-                # head groups don't split over tp)
+                # the stacked pool's layer and block axes stay whole on
+                # every device (table values are global block ids), its
+                # kv-head axis shards on 'model', and each data shard walks
+                # its own slots' SMEM table mirror — the shard_map body is
+                # the single-device kernel (select_paged_attn_impl refuses
+                # Pallas when the head groups don't split over tp)
                 in_specs = [P("data", "model", None),
-                            P(None, "model", None, None),
-                            P(None, "model", None, None),
+                            P(None, None, "model", None, None),
+                            P(None, None, "model", None, None),
+                            P(),
                             P("data", None),
                             P("data")]
                 if kv.quantized:
-                    in_specs += [P(None, "model", None),
-                                 P(None, "model", None)]
+                    in_specs += [P(None, None, "model", None),
+                                 P(None, None, "model", None)]
                 kernel = shard_map(
                     kernel,
                     mesh=self.mesh,
@@ -1024,13 +1029,12 @@ class ModelRunner:
                 )
 
             @scoped("attn.paged_decode")
-            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; keys = pool
-                if kv.quantized:  # (int8 pool, f32 scales) — fused dequant
-                    out = kernel(q[:, 0], keys[0], values[0], tables, pos,
-                                 keys[1], values[1])
-                else:
-                    out = kernel(q[:, 0], keys, values, tables, pos)
-                return out[:, None]
+            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; kvc.LayerViews
+                args = (q[:, 0], keys.cache, values.cache, keys.layer,
+                        tables, pos)
+                if kv.quantized:  # f32 scale stacks — fused dequant
+                    args += (keys.scale, values.scale)
+                return kernel(*args)[:, None]
 
         mask = kvc.decode_mask(cfg, pos, self.ctx_pad)
         write = kvc.paged_decode_write(tables, pos, raw=raw)

@@ -351,8 +351,10 @@ def forward(
     params: PyTree,
     tokens: jax.Array,      # [B, T] int32
     positions: jax.Array,   # [B, T] int32 (absolute positions for RoPE)
-    kv_write: Any,          # KV write policy: fn(layer_kv, k, v) -> (new_layer_kv, keys, values)
-    kv_stack: Any,          # stacked KV pytree scanned alongside layers (or None)
+    kv_write: Any,          # KV write policy (engine.kvcache):
+                            # fn(kv_stack, layer, k, v) -> (new_kv_stack, keys, values)
+    kv_stack: Any,          # stacked KV pytree [L, ...]: carried through the
+                            # layer scan and written in place, never sliced
     mask: jax.Array,        # [B, T, Lk] bool attention mask
     rope: tuple[jax.Array, jax.Array],
     attn: Any = None,       # optional override: fn(q, keys, values, mask) -> out
@@ -367,8 +369,16 @@ def forward(
 ) -> tuple[jax.Array, Any]:
     """Shared transformer trunk: returns (hidden [B, T, D], updated kv_stack).
 
-    The layer loop is ``lax.scan`` over stacked weights + stacked KV so XLA
-    compiles one layer body regardless of depth.
+    The layer loop is ``lax.scan`` over the stacked weights and the layer
+    index, so XLA compiles one layer body regardless of depth. The stacked
+    KV is the scan's CARRY beside the activations: ``kv_write`` scatters
+    the layer's new rows into the whole stack and exposes what the attend
+    needs of it. A donated argument that becomes a loop carry and then the
+    output is what XLA aliases end to end, so the update is in place; a
+    scan cannot alias an ``xs`` to a ``ys``, which is why the stack is not
+    scanned (that costs a second stack of temp, and a slice-out and a
+    restack per layer). tests/test_tpu_compile.py holds the compiled
+    programs to no stack-sized temp and no layer-shaped copy.
     """
     cos_t, sin_t = rope
     cos = cos_t[positions][:, :, None, :]  # [B, T, 1, hd/2]
@@ -389,17 +399,20 @@ def forward(
                 return _grouped_attn(cfg, q, keys, values, m)
 
     def body(carry, layer_in):
-        lp, layer_kv = layer_in
+        x, kv = carry
+        lp, layer = layer_in
 
         def attend(q, k_new, v_new):
-            new_kv, keys, values = kv_write(layer_kv, k_new, v_new)
+            new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
             return attn(q, keys, values, mask), new_kv
 
-        y, new_kv = _layer(cfg, carry, lp, cos, sin, attend, reduce=reduce)
-        return y, new_kv
+        return _layer(cfg, x, lp, cos, sin, attend, reduce=reduce), None
 
+    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
     with jax.named_scope("layers"):
-        x, new_kv_stack = lax.scan(body, x, (params["layers"], kv_stack))
+        (x, new_kv_stack), _ = lax.scan(
+            body, (x, kv_stack),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x, new_kv_stack

@@ -13,10 +13,11 @@ Replaces (TPU-era) the reference's per-slot CPU attention inside llama.cpp's
 grpc-server.cpp:1546-1990). Two shapes of the same kernel:
 
   * ``decode_attention`` — q is one token per slot, KV is the slot cache
-    head-major [S, Hkv, C, hd] (so per-head DMA slices are (context, hd) —
-    the (sublane, lane) tiling Mosaic requires); grid (S, Hkv); the GQA
-    group (g = Hq/Hkv queries) forms the row dimension of the MXU matmul.
-    Masking comes from per-slot write positions, not a materialized mask.
+    head-major and stacked over layers [L, S, Hkv, C, hd], read at a layer
+    index (per-head DMA slices are (context, hd) — the (sublane, lane)
+    tiling Mosaic requires); grid (S, Hkv); the GQA group (g = Hq/Hkv
+    queries) forms the row dimension of the MXU matmul. Masking comes from
+    per-slot write positions, not a materialized mask.
   * ``prefill_attention`` — single-sequence causal attention [T, ...];
     grid (Hkv, T/block_q); rows are (q-position × group) pairs; KV blocks
     beyond the causal frontier or the real prompt length are not fetched.
@@ -168,11 +169,13 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
-                   sm_scale: float, sliding_window: Optional[int],
-                   quantized: bool):
-    # k_ref/v_ref are the FULL [S, Hkv, C, hd] cache in HBM (Mosaic only
-    # allows whole-array ANY refs); slot/head are picked in the DMA slice.
+def _decode_kernel(layer_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+                   block_k: int, sm_scale: float,
+                   sliding_window: Optional[int], quantized: bool):
+    # k_ref/v_ref are the FULL stacked [L, S, Hkv, C, hd] cache in HBM
+    # (Mosaic only allows whole-array ANY refs); layer (an SMEM scalar),
+    # slot and head are picked in the DMA slice, so no layer is ever
+    # sliced out of the stack for the kernel.
     # When quantized, ks/vs_ref are this (slot, head)'s f32 scales as
     # [C/block_k, block_k] rows, auto-loaded into VMEM by their BlockSpec
     # (≤32 KB even at 8k context — no manual DMA needed).
@@ -182,9 +185,10 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
         o_ref, kbuf, vbuf, ksem, vsem = rest
     s_idx = pl.program_id(0)
     h_idx = pl.program_id(1)
+    layer = layer_ref[0]
     pos = pos_ref[s_idx]
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [g, hd]
-    ctx = k_ref.shape[2]
+    ctx = k_ref.shape[3]
 
     nb = jnp.minimum(pos // block_k + 1, ctx // block_k)
     lo = jnp.int32(0)
@@ -192,7 +196,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
         lo = jnp.maximum((pos - sliding_window + 1) // block_k, 0)
 
     def slice_of(ref):
-        return lambda i: ref.at[s_idx, h_idx, pl.ds(i * block_k, block_k), :]
+        return lambda i: ref.at[layer, s_idx, h_idx,
+                                pl.ds(i * block_k, block_k), :]
 
     def mask_for_block(i):
         idx = i * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
@@ -212,24 +217,29 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
 
 def decode_attention(
     q: jax.Array,            # [S, Hq, hd]
-    k_cache: jax.Array,      # [S, Hkv, C, hd] head-major slot cache
-    v_cache: jax.Array,      # [S, Hkv, C, hd]
+    k_cache: jax.Array,      # [L, S, Hkv, C, hd] stacked head-major cache
+    v_cache: jax.Array,      # [L, S, Hkv, C, hd]
+    layer: jax.Array,        # scalar i32 — the layer of the stack to read
     positions: jax.Array,    # [S] i32 — current token's KV write position
-    k_scale: Optional[jax.Array] = None,  # [S, Hkv, C] f32 (scaled-int8 KV)
+    k_scale: Optional[jax.Array] = None,  # [L, S, Hkv, C] f32 (int8 KV)
     v_scale: Optional[jax.Array] = None,
     *,
     sliding_window: Optional[int] = None,
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Flash GQA decode attention over the slot cache. Returns [S, Hq, hd].
+    """Flash GQA decode attention over one layer of the stacked slot
+    cache. Returns [S, Hq, hd]. The stack arrives whole and the layer is
+    picked in the kernel's DMA slice: inside the layer scan the cache is
+    a loop carry, and a per-layer slice handed to a custom call would be
+    a copy of that layer.
 
     With ``k_scale``/``v_scale`` the cache is scaled int8 and dequantization
     fuses into the flash loop (scores/probs column scaling) — decode reads
     half the KV bytes of bf16 and never materializes a dequantized cache.
     """
     S, Hq, hd = q.shape
-    Hkv, C = k_cache.shape[1], k_cache.shape[2]
+    Hkv, C = k_cache.shape[2], k_cache.shape[3]
     g = Hq // Hkv
     bk = _pick_block_aligned(C, block_k)
     qg = q.reshape(S, Hkv, g, hd)
@@ -241,10 +251,11 @@ def decode_attention(
     )
     in_specs = [
         # SMEM blocks must cover the whole array; index by slot inside
+        pl.BlockSpec((1,), lambda s, h: (0,), memory_space=pltpu.SMEM),
         pl.BlockSpec((S,), lambda s, h: (0,), memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),
         # K/V stay whole in HBM (ANY refs must be unblocked); the
-        # kernel DMAs block_k slices per (slot, head) itself
+        # kernel DMAs block_k slices per (layer, slot, head) itself
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -252,15 +263,18 @@ def decode_attention(
         pltpu.VMEM((2, bk, hd), k_cache.dtype),
         pltpu.VMEM((2, bk, hd), v_cache.dtype),
     ]
-    args = [positions.astype(jnp.int32), qg, k_cache, v_cache]
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    args = [layer, positions.astype(jnp.int32), qg, k_cache, v_cache]
     if quantized:
         # scales ride normal VMEM blocks, one [C/bk, bk] slab per (slot,
         # head) grid step: the last two block dims equal the array's, which
-        # is what the Mosaic block rule asks of a non-(8,128) block
+        # is what the Mosaic block rule asks of a non-(8,128) block. The
+        # layer's scales are sliced out here (hd/4 times smaller than the
+        # layer's K or V, which never is)
         spec = pl.BlockSpec((1, 1, C // bk, bk), lambda s, h: (s, h, 0, 0))
         in_specs += [spec, spec]
-        args += [k_scale.reshape(S, Hkv, C // bk, bk),
-                 v_scale.reshape(S, Hkv, C // bk, bk)]
+        args += [k_scale[layer[0]].reshape(S, Hkv, C // bk, bk),
+                 v_scale[layer[0]].reshape(S, Hkv, C // bk, bk)]
     scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
     out = pl.pallas_call(
         kernel,
@@ -372,43 +386,51 @@ def prefill_attention(
 # ---------------------------------------------------------------------------
 
 
-def gather_blocks(cache: jax.Array, tables: jax.Array) -> jax.Array:
+def gather_blocks(cache: jax.Array, tables: jax.Array,
+                  layer: Optional[jax.Array] = None) -> jax.Array:
     """[N, H, bt, hd] block pool + [S, MB] i32 tables -> [S, H, MB*bt, hd]
     logical context rows — THE pool-gather used by the pure-lax paged
-    attention path and the paged KV write policies (engine.kvcache)."""
+    attention path and the paged KV write policies (engine.kvcache).
+    With ``layer`` the cache is the stacked [L, N, H, bt, hd] pool and the
+    layer rides the SAME gather (``cache[layer, tables]``): slicing the
+    layer out first would copy all its N blocks to read MB of them."""
     S, MB = tables.shape
-    _, H, bt, hd = cache.shape
-    g = cache[tables]                              # [S, MB, H, bt, hd]
+    H, bt, hd = cache.shape[-3:]
+    g = cache[tables] if layer is None else cache[layer, tables]
     return g.transpose(0, 2, 1, 3, 4).reshape(S, H, MB * bt, hd)
 
 
-def gather_block_scales(scales: jax.Array, tables: jax.Array) -> jax.Array:
-    """[N, H, bt] scale pool + [S, MB] tables -> [S, H, MB*bt]."""
+def gather_block_scales(scales: jax.Array, tables: jax.Array,
+                        layer: Optional[jax.Array] = None) -> jax.Array:
+    """[N, H, bt] scale pool ([L, N, H, bt] with ``layer``) + [S, MB]
+    tables -> [S, H, MB*bt]."""
     S, MB = tables.shape
-    _, H, bt = scales.shape
-    g = scales[tables]                             # [S, MB, H, bt]
+    H, bt = scales.shape[-2:]
+    g = scales[tables] if layer is None else scales[layer, tables]
     return g.transpose(0, 2, 1, 3).reshape(S, H, MB * bt)
 
 
-def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
-                         block_tokens: int, sm_scale: float,
+def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
+                         *rest, block_tokens: int, sm_scale: float,
                          sliding_window: Optional[int], quantized: bool,
                          int4: bool, num_buffers: int):
-    # k_ref/v_ref are the FULL [N, Hkv, bt, hd] block pool in HBM; the
-    # block walked at loop step i is tbl_ref[slot, i] (SMEM block table),
-    # so the DMA gathers physically-scattered blocks in logical order.
+    # k_ref/v_ref are the FULL stacked [L, N, Hkv, bt, hd] block pool in
+    # HBM; the block walked at loop step i is tbl_ref[slot, i] (SMEM block
+    # table) of layer layer_ref[0] (SMEM scalar), so the DMA gathers
+    # physically-scattered blocks in logical order straight from the stack.
     # Scales for int8/int4 pools arrive already gathered in logical order,
     # [MB, bt] f32 per (slot, head), VMEM-resident through their BlockSpec:
     # a [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
     # tile at every block size below 128, so it cannot ride the K/V DMA.
-    # int4 pools arrive nibble-packed [N, Hkv, bt, hd/2] and unpack in VMEM
-    # after the DMA wait — half the int8 path's bytes per block.
+    # int4 pools arrive nibble-packed [L, N, Hkv, bt, hd/2] and unpack in
+    # VMEM after the DMA wait — half the int8 path's bytes per block.
     if quantized:
         ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem = rest
     else:
         o_ref, kbuf, vbuf, ksem, vsem = rest
     s_idx = pl.program_id(0)
     h_idx = pl.program_id(1)
+    layer = layer_ref[0]
     pos = pos_ref[s_idx]
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [g, hd]
     bt = block_tokens
@@ -419,7 +441,7 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
         lo = jnp.maximum((pos - sliding_window + 1) // bt, 0)
 
     def slice_of(ref):
-        return lambda i: ref.at[tbl_ref[s_idx, i], h_idx]
+        return lambda i: ref.at[layer, tbl_ref[s_idx, i], h_idx]
 
     def mask_for_block(i):
         idx = i * bt + lax.broadcasted_iota(jnp.int32, (1, bt), 1)
@@ -439,32 +461,40 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_decode_attention(
     q: jax.Array,            # [S, Hq, hd]
-    k_cache: jax.Array,      # [N, Hkv, bt, hd] block pool
-                             # (int4: nibble-packed [N, Hkv, bt, hd/2])
-    v_cache: jax.Array,      # [N, Hkv, bt, hd]
+    k_cache: jax.Array,      # [L, N, Hkv, bt, hd] stacked block pool
+                             # (int4: nibble-packed [L, N, Hkv, bt, hd/2])
+    v_cache: jax.Array,      # [L, N, Hkv, bt, hd]
+    layer: jax.Array,        # scalar i32 — the layer of the stack to read
     tables: jax.Array,       # [S, MB] i32 per-slot block tables
     positions: jax.Array,    # [S] i32 — current token's KV write position
-    k_scale: Optional[jax.Array] = None,  # [N, Hkv, bt] f32 (int8/int4)
+    k_scale: Optional[jax.Array] = None,  # [L, N, Hkv, bt] f32 (int8/int4)
     v_scale: Optional[jax.Array] = None,
     *,
     sliding_window: Optional[int] = None,
     interpret: bool = False,
     num_buffers: int = 2,
 ) -> jax.Array:
-    """Flash GQA decode attention over a paged block pool. Returns
-    [S, Hq, hd]. The kernel walks each slot's block table in SMEM and
-    DMAs one [bt, hd] physical block per online-softmax step — identical
-    math to ``decode_attention``, with the contiguous slot row replaced
-    by gather-over-block-table.
+    """Flash GQA decode attention over one layer of the stacked paged
+    block pool. Returns [S, Hq, hd]. The kernel walks each slot's block
+    table in SMEM and DMAs one [bt, hd] physical block per online-softmax
+    step — identical math to ``decode_attention``, with the contiguous
+    slot row replaced by gather-over-block-table.
+
+    The pool arrives WHOLE, all layers, and ``layer`` picks the layer in
+    the DMA slice: inside the layer scan (models.llama.forward) the pool
+    is a loop carry written in place, and handing this custom call a
+    per-layer slice would make XLA copy that layer (76 MB at Mistral-7B's
+    289 blocks) before every call. tests/test_tpu_compile.py holds the
+    compiled decode program to no such copy.
 
     Under a mesh the runner wraps this in ``shard_map`` with slots (q,
     tables, positions) on 'data' and head groups (q, pool) on 'model':
     the body is then the per-device single-chip kernel, so the pool's
-    block axis must arrive WHOLE on every device (table values are
-    global physical block ids) and both head counts must divide the
+    layer and block axes must arrive WHOLE on every device (table values
+    are global physical block ids) and both head counts must divide the
     'model' width (``ops.select_paged_attn_impl`` gates that)."""
     S, Hq, hd = q.shape
-    Hkv, bt = k_cache.shape[1], k_cache.shape[2]
+    Hkv, bt = k_cache.shape[2], k_cache.shape[3]
     MB = tables.shape[1]
     g = Hq // Hkv
     qg = q.reshape(S, Hkv, g, hd)
@@ -479,6 +509,7 @@ def paged_decode_attention(
         int4=int4, num_buffers=depth,
     )
     in_specs = [
+        pl.BlockSpec((1,), lambda s, h: (0,), memory_space=pltpu.SMEM),
         pl.BlockSpec((S,), lambda s, h: (0,), memory_space=pltpu.SMEM),
         pl.BlockSpec((S, MB), lambda s, h: (0, 0), memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),
@@ -486,7 +517,8 @@ def paged_decode_attention(
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    args = [positions.astype(jnp.int32), tables.astype(jnp.int32), qg,
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    args = [layer, positions.astype(jnp.int32), tables.astype(jnp.int32), qg,
             k_cache, v_cache]
     scratch = [
         # int4 pools buffer the packed [bt, hd/2] bytes — unpack happens
@@ -495,12 +527,15 @@ def paged_decode_attention(
         pltpu.VMEM((depth, bt, v_cache.shape[-1]), v_cache.dtype),
     ]
     if quantized:
-        # gather each slot's scale rows in XLA (small: S·MB·Hkv·bt f32) and
-        # hand the kernel one [MB, bt] slab per (slot, head) grid step
+        # gather each slot's scale rows in XLA (small: S·MB·Hkv·bt f32),
+        # layer and blocks in one gather, and hand the kernel one [MB, bt]
+        # slab per (slot, head) grid step
         spec = pl.BlockSpec((1, 1, MB, bt), lambda s, h: (s, h, 0, 0))
         in_specs += [spec, spec]
-        args += [gather_block_scales(k_scale, tables).reshape(S, Hkv, MB, bt),
-                 gather_block_scales(v_scale, tables).reshape(S, Hkv, MB, bt)]
+        args += [gather_block_scales(k_scale, tables, layer[0]).reshape(
+                     S, Hkv, MB, bt),
+                 gather_block_scales(v_scale, tables, layer[0]).reshape(
+                     S, Hkv, MB, bt)]
     scratch += [pltpu.SemaphoreType.DMA((depth,))] * 2
     out = pl.pallas_call(
         kernel,

@@ -196,12 +196,12 @@ def paged_decode_trunk(
             )
 
             def attn(q, keys, values, _mask):  # q [S,1,Hq_loc,hd]
-                if quantized:  # (packed pool, f32 scales) — fused dequant
-                    out = kernel(q[:, 0], keys[0], values[0], tables,
-                                 positions, keys[1], values[1])
-                else:
-                    out = kernel(q[:, 0], keys, values, tables, positions)
-                return out[:, None]
+                # keys/values: kvc.LayerViews of the local pool shard
+                args = (q[:, 0], keys.cache, values.cache, keys.layer,
+                        tables, positions)
+                if quantized:  # f32 scale stacks — fused dequant
+                    args += (keys.scale, values.scale)
+                return kernel(*args)[:, None]
 
         hidden, new_stack = mdl.forward(
             cfg, trunk, tokens[:, None], positions[:, None],

@@ -45,7 +45,7 @@ def pp_forward(
     params: Any,
     tokens: jax.Array,      # [B, T] i32
     positions: jax.Array,   # [B, T] i32
-    kv_write: Any,          # fn(layer_kv, k, v) -> (new_layer_kv, keys, vals)
+    kv_write: Any,          # fn(kv_stack, layer, k, v) -> (new_stack, keys, vals)
     kv_stack: Any,          # stacked KV pytree, L axis 'pipe'-sharded
     mask: jax.Array,        # [B, T, Lk] bool
     rope: tuple[jax.Array, jax.Array],
@@ -71,26 +71,36 @@ def pp_forward(
         else:
             x = emb_in.astype(dtype)
 
+        n_local = jax.tree.leaves(layers_local)[0].shape[0]
+
         def block(x, kv_block, write_real):
             """My layer block over x; KV updates applied only when
-            ``write_real`` (this tick carries my real activations)."""
+            ``write_real`` (this tick carries my real activations). Same
+            contract as models.llama.forward: the stage's stack is the
+            scan's carry, ``kv_write`` takes it whole with the stage-local
+            layer index."""
 
             def body(carry, layer_in):
-                lp, layer_kv = layer_in
+                x, kv = carry
+                lp, layer = layer_in
 
                 def attend(q, k_new, v_new):
-                    new_kv, keys, values = kv_write(layer_kv, k_new, v_new)
+                    new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
                     out = mdl._grouped_attn(cfg, q, keys, values, mask)
                     return out, new_kv
 
-                y, new_kv = mdl._layer(cfg, carry, lp, cos, sin, attend)
-                new_kv = jax.tree.map(
-                    lambda new, old: jnp.where(write_real, new, old),
-                    new_kv, layer_kv,
-                )
-                return y, new_kv
+                return mdl._layer(cfg, x, lp, cos, sin, attend), None
 
-            return lax.scan(body, x, (layers_local, kv_block))
+            (y, new_kv), _ = lax.scan(
+                body, (x, kv_block),
+                (layers_local, jnp.arange(n_local, dtype=jnp.int32)))
+            # an off-turn tick ran on garbage activations: keep the stack
+            # it started from (one select of the stage's stack a tick)
+            new_kv = jax.tree.map(
+                lambda new, old: jnp.where(write_real, new, old),
+                new_kv, kv_block,
+            )
+            return y, new_kv
 
         def tick(carry, s):
             x, kv = carry

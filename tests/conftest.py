@@ -53,3 +53,20 @@ def tmp_models_dir(tmp_path):
     d = tmp_path / "models"
     d.mkdir()
     return d
+
+
+@pytest.fixture()
+def in_stack():
+    """``in_stack(a, layer=1, layers=3)``: ``a`` as layer ``layer`` of a
+    stacked KV cache whose other layers hold noise: what the attention
+    kernels take, since the cache rides the layer scan as a carry (a kernel
+    that ignored its layer index would read noise)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    def stack(a, layer=1, layers=3):
+        noise = np.random.default_rng(99).integers(
+            -100, 100, (layers,) + a.shape)
+        return jnp.asarray(noise, a.dtype).at[layer].set(a)
+
+    return stack

@@ -61,7 +61,7 @@ def _paged_problem(rng, ctx):
 
 
 @pytest.mark.parametrize("ctx", [24, 112])  # two lengths (multi-block)
-def test_paged_int4_vs_f32_parity_ref_and_interpret(ctx):
+def test_paged_int4_vs_f32_parity_ref_and_interpret(ctx, in_stack):
     rng = np.random.default_rng(1)
     q, kf, vf, tables, positions = _paged_problem(rng, ctx)
     ref_f32 = ops.paged_decode_attention_ref(
@@ -76,22 +76,23 @@ def test_paged_int4_vs_f32_parity_ref_and_interpret(ctx):
         np.asarray(ref_i4), np.asarray(ref_f32), rtol=0.2, atol=0.2)
     # Pallas interpret vs the lax ref: identical int4 math, ~fp32 exact
     pal_i4 = ops.paged_decode_attention(
-        q, kq, vq, tables, positions, ks, vs, interpret=True)
+        q, in_stack(kq), in_stack(vq), jnp.int32(1), tables, positions,
+        in_stack(ks), in_stack(vs), interpret=True)
     np.testing.assert_allclose(
         np.asarray(pal_i4), np.asarray(ref_i4), rtol=1e-5, atol=1e-5)
 
 
-def test_paged_int4_buffer_depths_identical():
+def test_paged_int4_buffer_depths_identical(in_stack):
     rng = np.random.default_rng(2)
     q, kf, vf, tables, positions = _paged_problem(rng, 64)
     kq, ks = quantize_lastdim4(kf)
     vq, vs = quantize_lastdim4(vf)
-    d2 = ops.paged_decode_attention(
-        q, kq, vq, tables, positions, ks, vs, interpret=True,
-        num_buffers=2)
-    d3 = ops.paged_decode_attention(
-        q, kq, vq, tables, positions, ks, vs, interpret=True,
-        num_buffers=3)
+    stacked = (in_stack(kq), in_stack(vq), jnp.int32(1), tables, positions,
+               in_stack(ks), in_stack(vs))
+    d2 = ops.paged_decode_attention(q, *stacked, interpret=True,
+                                    num_buffers=2)
+    d3 = ops.paged_decode_attention(q, *stacked, interpret=True,
+                                    num_buffers=3)
     np.testing.assert_array_equal(np.asarray(d2), np.asarray(d3))
 
 

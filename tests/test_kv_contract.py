@@ -1,0 +1,331 @@
+"""The KV write contract: ``kv_write(kv_stack, layer, k_new, v_new)``.
+
+Since the stacked cache rides the layer scan as a carry (models.llama
+.forward), every policy of engine.kvcache takes the WHOLE stack and the
+layer index, scatters only the new rows into it and exposes what the attend
+reads. Each case below holds one policy, on one cache dtype, to a plain
+row-by-row numpy writer: exactly the rows the per-layer policies wrote
+(the trash block's too, where a policy sends rows there), in the one layer
+named, and not one other element of the stack changed; what it exposes is
+that layer of the new stack. The kernels are held to their references on
+the layer they are given, over a stack whose other layers hold noise.
+"""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from localai_tpu import ops
+from localai_tpu.engine import kvcache as kvc
+from localai_tpu.models.quant import (quantize_lastdim, quantize_lastdim4,
+                                      unpack_int4_lastdim)
+
+L, LAYER = 3, 1
+H, HD = 2, 16
+BT, MB, N = 8, 3, 12            # paged: block tokens, blocks a slot, pool
+S, C = 3, 24                    # contiguous: slots, context (= MB * BT)
+DT = jnp.float32                # the activations' dtype
+
+
+def _noise_stack(rng, kv_dtype, lead):
+    """A stacked cache full of noise (so an element that changed shows),
+    as the 2- or 4-tuple ``stacked()`` gives. ``lead`` = (N,) or (S,)."""
+    tokens = BT if lead == (N,) else C
+    shape = (L, *lead, H, tokens, HD // 2 if kv_dtype == "int4" else HD)
+    if kv_dtype == "bfloat16":
+        return tuple(jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                     for _ in range(2))
+    vals = [jnp.asarray(rng.integers(-100, 100, shape), jnp.int8)
+            for _ in range(2)]
+    scales = [jnp.asarray(rng.uniform(0.01, 0.02, shape[:4]), jnp.float32)
+              for _ in range(2)]
+    return (*vals, *scales)
+
+
+def _stored(kv_dtype, rows):
+    """(what the cache stores for ``rows [..., H, HD]``, its scales or None)."""
+    if kv_dtype == "bfloat16":
+        return np.asarray(rows.astype(jnp.bfloat16).astype(jnp.float32)), None
+    quant = quantize_lastdim4 if kv_dtype == "int4" else quantize_lastdim
+    q, scale = quant(rows)
+    return np.asarray(q), np.asarray(scale)
+
+
+def _expected(stack, kv_dtype, targets, k_rows, v_rows):
+    """The stack after a plain writer put row i of ``k_rows``/``v_rows``
+    ``[n, H, HD]`` at ``[LAYER, lead, :, tok]`` for ``targets[i] = (lead,
+    tok)``: numpy, one row at a time."""
+    out = [np.array(a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a)
+           for a in stack]
+    for which, rows in ((0, k_rows), (1, v_rows)):
+        vals, scales = _stored(kv_dtype, rows)
+        for i, (lead, tok) in enumerate(targets):
+            out[which][LAYER, lead, :, tok] = vals[i]
+            if scales is not None:
+                out[which + 2][LAYER, lead, :, tok] = scales[i]
+    return out
+
+
+def _assert_stack(new, expected):
+    assert len(new) == len(expected)
+    for got, want in zip(new, expected):
+        got = np.asarray(got.astype(jnp.float32)
+                         if got.dtype == jnp.bfloat16 else got)
+        np.testing.assert_array_equal(got, want)
+
+
+def _dequant_layer(new, kv_dtype):
+    """The named layer of the new stack as the XLA attend reads it."""
+    def one(cache, scale=None):
+        a = cache[LAYER]
+        if kv_dtype == "int4":
+            a = unpack_int4_lastdim(a)
+        a = a.astype(DT)
+        return a if scale is None else a * scale[LAYER][..., None].astype(DT)
+
+    if len(new) == 4:
+        return one(new[0], new[2]), one(new[1], new[3])
+    return one(new[0]), one(new[1])
+
+
+def _assert_views(new, keys, values):
+    """``raw=True``: the kernel gets the new stack itself and the layer."""
+    scales = new[2:] if len(new) == 4 else (None, None)
+    for view, cache, scale in zip((keys, values), new[:2], scales):
+        assert isinstance(view, kvc.LayerView)
+        assert view.cache is cache and view.scale is scale
+        assert int(view.layer) == LAYER
+
+
+def _rows(rng, *lead):
+    return (jnp.asarray(rng.normal(size=(*lead, H, HD)), DT),
+            jnp.asarray(rng.normal(size=(*lead, H, HD)), DT))
+
+
+# ---------------------------------------------------------------------------
+# paged policies
+
+# slot 1 is released (all-zero table row: its write lands in the trash block)
+TABLES = np.array([[1, 4, 7], [0, 0, 0], [9, 2, 5]], np.int32)
+
+
+def _paged_decode(rng, raw):
+    positions = np.array([13, 5, 23], np.int32)
+    k_new, v_new = _rows(rng, S, 1)
+    write = kvc.paged_decode_write(jnp.asarray(TABLES), jnp.asarray(positions),
+                                   raw=raw)
+    targets = [(int(TABLES[s, positions[s] // BT]), int(positions[s] % BT))
+               for s in range(S)]
+    return write, k_new, v_new, targets, k_new[:, 0], v_new[:, 0], TABLES
+
+
+def _paged_prefill(rng, offset=3):
+    # an 8-token bucket holding 5 real tokens from ``offset``: rows 0-4 go
+    # through the table (a run of consecutive positions, written a block at
+    # a time); the 3 padding rows are written nowhere. From 19 the bucket
+    # runs past the table's last block: the block after it is the trash
+    table_row, length, T = TABLES[2], 5, 8
+    k_new, v_new = _rows(rng, 1, T)
+    write = kvc.paged_prefill_write(jnp.asarray(table_row), jnp.int32(offset),
+                                    jnp.int32(length))
+    targets = [(int(table_row[(offset + t) // BT]), (offset + t) % BT)
+               for t in range(length)]
+    return (write, k_new, v_new, targets, k_new[0, :length],
+            v_new[0, :length], table_row[None])
+
+
+def _paged_verify(rng):
+    # slot 2's window crosses ctx_limit: its last row goes to the trash
+    positions, T, ctx_limit = np.array([6, 2, 20], np.int32), 3, 22
+    k_new, v_new = _rows(rng, S, T)
+    write = kvc.paged_verify_write(jnp.asarray(TABLES), jnp.asarray(positions),
+                                   ctx_limit)
+    targets = []
+    for s in range(S):
+        for t in range(T):
+            p = int(positions[s]) + t
+            blk = int(TABLES[s, min(p // BT, MB - 1)]) if p < ctx_limit else 0
+            targets.append((blk, p % BT))
+    flat = lambda a: a.reshape(S * T, H, HD)  # noqa: E731
+    return write, k_new, v_new, targets, flat(k_new), flat(v_new), TABLES
+
+
+PAGED = {
+    "decode_raw": lambda rng: _paged_decode(rng, raw=True),
+    "decode_gathered": lambda rng: _paged_decode(rng, raw=False),
+    "prefill": _paged_prefill,
+    "prefill_table_end": lambda rng: _paged_prefill(rng, offset=19),
+    "verify": _paged_verify,
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "int4"])
+@pytest.mark.parametrize("policy", sorted(PAGED))
+def test_paged_policy_writes_only_its_rows(policy, kv_dtype):
+    rng = np.random.default_rng(3)
+    stack = _noise_stack(rng, kv_dtype, (N,))
+    write, k_new, v_new, targets, k_rows, v_rows, tables = PAGED[policy](rng)
+    assert len(set(targets)) == len(targets)     # no two rows collide
+    new, keys, values = write(stack, jnp.int32(LAYER), k_new, v_new)
+    _assert_stack(new, _expected(stack, kv_dtype, targets, k_rows, v_rows))
+    if policy == "decode_raw":
+        _assert_views(new, keys, values)
+        return
+    # the gathered logical context [S, H, MB*bt, hd] of the named layer
+    for got, layer in zip((keys, values), _dequant_layer(new, kv_dtype)):
+        want = np.asarray(layer)[tables]             # [S, MB, H, bt, hd]
+        want = want.transpose(0, 2, 1, 3, 4).reshape(
+            tables.shape[0], H, MB * BT, HD)
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+# ---------------------------------------------------------------------------
+# contiguous policies
+
+
+def _contig_decode(rng, raw):
+    positions = np.array([0, 11, 23], np.int32)
+    k_new, v_new = _rows(rng, S, 1)
+    write = kvc.decode_write(jnp.asarray(positions), raw=raw)
+    targets = [(s, int(positions[s])) for s in range(S)]
+    return write, k_new, v_new, targets, k_new[:, 0], v_new[:, 0], None
+
+
+def _contig_verify(rng):
+    positions, T = np.array([0, 9, 20], np.int32), 4
+    k_new, v_new = _rows(rng, S, T)
+    write = kvc.verify_write(jnp.asarray(positions))
+    targets = [(s, int(positions[s]) + t) for s in range(S) for t in range(T)]
+    flat = lambda a: a.reshape(S * T, H, HD)  # noqa: E731
+    return write, k_new, v_new, targets, flat(k_new), flat(v_new), None
+
+
+def _contig_chunk(rng, make):
+    slot, offset, T = 2, 5, 8
+    k_new, v_new = _rows(rng, 1, T)
+    write = make(jnp.int32(slot), jnp.int32(offset))
+    targets = [(slot, offset + t) for t in range(T)]
+    return write, k_new, v_new, targets, k_new[0], v_new[0], slot
+
+
+CONTIGUOUS = {
+    "decode_raw": lambda rng: _contig_decode(rng, raw=True),
+    "decode": lambda rng: _contig_decode(rng, raw=False),
+    "verify": _contig_verify,
+    "prefill": lambda rng: _contig_chunk(rng, kvc.prefill_write),
+    "resume": lambda rng: _contig_chunk(rng, kvc.resume_write),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("policy", sorted(CONTIGUOUS))
+def test_contiguous_policy_writes_only_its_rows(policy, kv_dtype):
+    rng = np.random.default_rng(4)
+    stack = _noise_stack(rng, kv_dtype, (S,))
+    write, k_new, v_new, targets, k_rows, v_rows, slot = CONTIGUOUS[policy](rng)
+    new, keys, values = write(stack, jnp.int32(LAYER), k_new, v_new)
+    _assert_stack(new, _expected(stack, kv_dtype, targets, k_rows, v_rows))
+    if policy == "decode_raw":
+        _assert_views(new, keys, values)
+    elif policy == "prefill":
+        # a fresh prefill attends over its own chunk, head-major, unquantized
+        np.testing.assert_array_equal(
+            np.asarray(keys), np.asarray(k_new.transpose(0, 2, 1, 3)))
+        np.testing.assert_array_equal(
+            np.asarray(values), np.asarray(v_new.transpose(0, 2, 1, 3)))
+    else:
+        # the named layer's rows: every slot's, or (resume) the one slot's
+        for got, layer in zip((keys, values), _dequant_layer(new, kv_dtype)):
+            want = np.asarray(layer)
+            want = want[slot][None] if policy == "resume" else want
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
+# ---------------------------------------------------------------------------
+# under tensor parallelism a chip writes its own heads
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("layout, policy", [
+    *(("paged", p) for p in sorted(PAGED)),
+    *(("contiguous", p) for p in sorted(CONTIGUOUS))])
+def test_policy_is_shard_local_over_the_heads(layout, policy, kv_dtype):
+    """The cache sharded over its kv heads on a 'model' mesh, the new rows
+    over theirs (parallel.sharding.paged_kv_spec / kv_spec): the partitioner
+    gives every device the write of its own heads and what the attend reads
+    of them, with no collective. It can only when it SEES that the scatter's
+    head index is the head's own number (``kvcache._scatter_per_head``'s
+    iota); an index it cannot read makes it all-gather the rows, or the
+    blocks a chunk touches, once a layer for K and for V."""
+    import re
+
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    rng = np.random.default_rng(7)
+    mesh = Mesh(np.array(jax.devices()[:H]), ("model",))
+    stack = _noise_stack(rng, kv_dtype, (N,) if layout == "paged" else (S,))
+    write, k_new, v_new, *_ = (
+        PAGED if layout == "paged" else CONTIGUOUS)[policy](rng)
+    heads = lambda a, axis: NamedSharding(  # noqa: E731
+        mesh, P(*[None] * axis, "model", *[None] * (a.ndim - axis - 1)))
+    compiled = jax.jit(
+        lambda stack, k_new, v_new: write(
+            stack, jnp.int32(LAYER), k_new, v_new),
+        in_shardings=(tuple(heads(a, 2) for a in stack),
+                      heads(k_new, 2), heads(v_new, 2)),
+    ).lower(stack, k_new, v_new).compile()
+    collectives = re.findall(
+        r" (?:all-gather|all-reduce|all-to-all|collective-permute"
+        r"|reduce-scatter)[\w\-]*\(", compiled.as_text())
+    assert not collectives, collectives
+
+
+# ---------------------------------------------------------------------------
+# the kernels read the layer they are given
+
+
+def _kernel_stack(rng, kv_dtype, lead):
+    tokens = BT if lead == (N,) else C
+    full = jnp.asarray(rng.normal(size=(L, *lead, H, tokens, HD)), DT)
+    if kv_dtype == "float32":
+        return full, None
+    quant = quantize_lastdim4 if kv_dtype == "int4" else quantize_lastdim
+    return quant(full)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8", "int4"])
+def test_paged_kernel_reads_the_layer_it_is_given(kv_dtype, layer):
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.normal(size=(S, 2 * H, HD)), DT)
+    (k, ks), (v, vs) = (_kernel_stack(rng, kv_dtype, (N,)) for _ in range(2))
+    tables = jnp.asarray([[1, 4, 7], [3, 6, 8], [9, 2, 5]], jnp.int32)
+    positions = jnp.asarray([13, 0, 23], jnp.int32)
+    scales = () if ks is None else (ks, vs)
+    out = ops.paged_decode_attention(
+        q, k, v, jnp.int32(layer), tables, positions, *scales, interpret=True)
+    ref = ops.paged_decode_attention_ref(
+        q, k[layer], v[layer], tables, positions,
+        *(s[layer] for s in scales))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_contiguous_kernel_reads_the_layer_it_is_given(kv_dtype, layer):
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.normal(size=(S, 2 * H, HD)), DT)
+    (k, ks), (v, vs) = (_kernel_stack(rng, kv_dtype, (S,)) for _ in range(2))
+    positions = jnp.asarray([13, 0, 23], jnp.int32)
+    scales = () if ks is None else (ks, vs)
+    out = ops.decode_attention(
+        q, k, v, jnp.int32(layer), positions, *scales, block_k=8,
+        interpret=True)
+    # a layer [S, H, C, hd] is a pool of S one-block slots to the reference
+    ref = ops.paged_decode_attention_ref(
+        q, k[layer], v[layer], jnp.arange(S, dtype=jnp.int32)[:, None],
+        positions, *(s[layer] for s in scales))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)

@@ -22,7 +22,7 @@ def _cfg(Hq=8, Hkv=4, hd=16, window=None):
 
 
 @pytest.mark.parametrize("window", [None, 24])
-def test_decode_attention_matches_xla(window):
+def test_decode_attention_matches_xla(window, in_stack):
     cfg = _cfg(window=window)
     S, C = 4, 64
     rng = np.random.default_rng(0)
@@ -33,7 +33,8 @@ def test_decode_attention_matches_xla(window):
 
     ref = mdl._grouped_attn(cfg, q[:, None], k, v,
                             kvc.decode_mask(cfg, pos, C))[:, 0]
-    out = ops_attn.decode_attention(q, k, v, pos, sliding_window=window,
+    out = ops_attn.decode_attention(q, in_stack(k), in_stack(v),
+                                    jnp.int32(1), pos, sliding_window=window,
                                     block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -77,7 +78,7 @@ def test_runner_pallas_matches_xla_end_to_end():
 
 
 @pytest.mark.parametrize("window", [None, 24])
-def test_decode_attention_int8_kv_matches_dequant_xla(window):
+def test_decode_attention_int8_kv_matches_dequant_xla(window, in_stack):
     """Fused int8-KV dequant in the flash decode kernel: scales applied to
     score/prob columns must equal attention over the dequantized cache."""
     cfg = _cfg(window=window)
@@ -98,7 +99,9 @@ def test_decode_attention_int8_kv_matches_dequant_xla(window):
     v = vq.astype(jnp.float32) * vs[..., None]
     ref = mdl._grouped_attn(cfg, q[:, None], k, v,
                             kvc.decode_mask(cfg, pos, C))[:, 0]
-    out = ops_attn.decode_attention(q, kq, vq, pos, ks, vs,
+    out = ops_attn.decode_attention(q, in_stack(kq), in_stack(vq),
+                                    jnp.int32(1), pos,
+                                    in_stack(ks), in_stack(vs),
                                     sliding_window=window,
                                     block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

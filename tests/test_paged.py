@@ -148,7 +148,7 @@ def test_allocator_never_shares_final_prompt_token_block():
 # ---------------------------------------------------------------------------
 
 
-def test_paged_attention_matches_contiguous_two_lengths():
+def test_paged_attention_matches_contiguous_two_lengths(in_stack):
     """Two sequences at different lengths sharing one block pool: paged
     decode attention (lax reference AND Pallas interpret kernel) must
     match the contiguous flash/XLA path to <= 1e-2."""
@@ -174,13 +174,15 @@ def test_paged_attention_matches_contiguous_two_lengths():
             contig_k[s, :, b * bt:(b + 1) * bt] = blk_k
             contig_v[s, :, b * bt:(b + 1) * bt] = blk_v
 
+    layer = jnp.int32(1)
     ref_contig = ops.decode_attention(
-        q, jnp.asarray(contig_k), jnp.asarray(contig_v), positions,
-        interpret=True)
+        q, in_stack(jnp.asarray(contig_k)), in_stack(jnp.asarray(contig_v)),
+        layer, positions, interpret=True)
     out_lax = ops.paged_decode_attention_ref(
         q, pool_k, pool_v, tables, positions)
     out_pallas = ops.paged_decode_attention(
-        q, pool_k, pool_v, tables, positions, interpret=True)
+        q, in_stack(pool_k), in_stack(pool_v), layer, tables, positions,
+        interpret=True)
     assert float(jnp.max(jnp.abs(out_lax - ref_contig))) <= 1e-2
     assert float(jnp.max(jnp.abs(out_pallas - ref_contig))) <= 1e-2
 

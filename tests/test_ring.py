@@ -28,14 +28,19 @@ def _reference_forward(model_cfg, params, tokens, length):
     rope = mdl.rope_table(model_cfg, T)
     mask = kvc.prefill_mask(model_cfg, T, length)
 
-    def write(layer_kv, k, v):
+    def write(kv_stack, layer, k, v):
         # pass the fresh chunk through (head-major for _grouped_attn) and
-        # stack the token-major chunk as the per-layer output
-        return (k[0], v[0]), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        # keep the token-major chunk as the layer's row of the carried stack
+        ks, vs = kv_stack
+        return ((ks.at[layer].set(k[0]), vs.at[layer].set(v[0])),
+                k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
 
+    empty = jnp.zeros((model_cfg.num_layers, T, model_cfg.num_kv_heads,
+                       model_cfg.hd), jnp.dtype(model_cfg.dtype))
     hidden, kvs = mdl.forward(
         model_cfg, params, tokens[None],
-        jnp.arange(T, dtype=jnp.int32)[None], write, None, mask, rope,
+        jnp.arange(T, dtype=jnp.int32)[None], write, (empty, empty), mask,
+        rope,
     )
     return hidden, kvs
 

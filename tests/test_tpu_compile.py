@@ -32,8 +32,10 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from localai_tpu import ops
 from localai_tpu.ops import qmatmul
 
-# Llama-3-8B head shapes: 32 q heads / 8 kv heads / head_dim 128, 8 slots
+# Llama-3-8B head shapes: 32 q heads / 8 kv heads / head_dim 128, 8 slots;
+# the kernels take the cache stacked over LAYERS layers and a layer index
 S, HQ, HKV, HD = 8, 32, 8, 128
+LAYERS = 2
 HBM_BYTES = 15.75 * 2**30          # one v5e chip, as libtpu reports it
 
 bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
@@ -72,12 +74,13 @@ def test_contiguous_kernels_compile(topo, ctx):
     assert ops.select_attn_impl(
         "auto", num_heads=HQ, num_kv_heads=HKV, head_dim=HD, max_ctx=ctx,
         backend="tpu") == ("pallas", False)
-    kv = ((S, HKV, ctx, HD), bf16)
+    kv = ((LAYERS, S, HKV, ctx, HD), bf16)
     compile_for(topo, ops.decode_attention,
-                ((S, HQ, HD), bf16), kv, kv, ((S,), i32))
-    kv8, sc = ((S, HKV, ctx, HD), i8), ((S, HKV, ctx), f32)
+                ((S, HQ, HD), bf16), kv, kv, ((), i32), ((S,), i32))
+    kv8 = ((LAYERS, S, HKV, ctx, HD), i8)
+    sc = ((LAYERS, S, HKV, ctx), f32)
     compile_for(topo, ops.decode_attention,
-                ((S, HQ, HD), bf16), kv8, kv8, ((S,), i32), sc, sc)
+                ((S, HQ, HD), bf16), kv8, kv8, ((), i32), ((S,), i32), sc, sc)
     # every prefill bucket the runner builds for this context
     for T in [b for b in (128, 512, 2048, 8192) if b <= ctx]:
         compile_for(topo, ops.prefill_attention, ((T, HQ, HD), bf16),
@@ -88,14 +91,15 @@ def test_sliding_window_kernels_compile(topo):
     """Mistral-class masking is a static kernel variant."""
     import functools
 
-    kv = ((S, HKV, 2048, HD), bf16)
+    kv = ((LAYERS, S, HKV, 2048, HD), bf16)
     compile_for(topo, functools.partial(ops.decode_attention,
                                         sliding_window=1024),
-                ((S, HQ, HD), bf16), kv, kv, ((S,), i32))
-    pool = ((65, HKV, 64, HD), bf16)
+                ((S, HQ, HD), bf16), kv, kv, ((), i32), ((S,), i32))
+    pool = ((LAYERS, 65, HKV, 64, HD), bf16)
     compile_for(topo, functools.partial(ops.paged_decode_attention,
                                         sliding_window=1024),
-                ((S, HQ, HD), bf16), pool, pool, ((S, 32), i32), ((S,), i32))
+                ((S, HQ, HD), bf16), pool, pool, ((), i32), ((S, 32), i32),
+                ((S,), i32))
 
 
 # ---------------------------------------------------------------------------
@@ -105,11 +109,11 @@ def test_sliding_window_kernels_compile(topo):
 def paged_args(kv_dtype, bt, hq, hkv, hd, ctx=2048, n_blocks=65):
     packed = hd // 2 if kv_dtype == "int4" else hd
     dt = {"bfloat16": bf16, "float32": f32}.get(kv_dtype, i8)
-    pool = ((n_blocks, hkv, bt, packed), dt)
-    args = [((S, hq, hd), bf16), pool, pool, ((S, ctx // bt), i32),
-            ((S,), i32)]
+    pool = ((LAYERS, n_blocks, hkv, bt, packed), dt)
+    args = [((S, hq, hd), bf16), pool, pool, ((), i32),
+            ((S, ctx // bt), i32), ((S,), i32)]
     if kv_dtype in ("int8", "int4"):
-        args += [((n_blocks, hkv, bt), f32)] * 2
+        args += [((LAYERS, n_blocks, hkv, bt), f32)] * 2
     return args
 
 
@@ -154,15 +158,16 @@ def test_selector_refuses_what_mosaic_refuses(topo):
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_kernel_compiles_under_shard_map_tp4(topo, kv_dtype):
     """The meshed runner's wrapping (engine.runner._decode_paged_fn): slots
-    on 'data', heads on 'model', the pool's block axis whole."""
+    on 'data', heads on 'model', the pool's layer and block axes whole."""
     assert ops.select_paged_attn_impl(
         "auto", num_heads=HQ, num_kv_heads=HKV, head_dim=HD, block_tokens=64,
         tp=4, kv_dtype=kv_dtype, backend="tpu") == ("pallas", False)
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
-    specs = [P("data", "model", None), P(None, "model", None, None),
-             P(None, "model", None, None), P("data", None), P("data")]
+    specs = [P("data", "model", None), P(None, None, "model", None, None),
+             P(None, None, "model", None, None), P(), P("data", None),
+             P("data")]
     if kv_dtype == "int8":
-        specs += [P(None, "model", None)] * 2
+        specs += [P(None, None, "model", None)] * 2
     kernel = jax.shard_map(
         ops.paged_decode_attention, mesh=mesh, in_specs=tuple(specs),
         out_specs=P("data", "model", None), check_vma=False)
@@ -205,6 +210,98 @@ def test_qmatmul_lm_head_compiles(topo):
 # the smoke's whole programs, and their HBM
 
 
+def abstract_runner(topo, monkeypatch, cfg, tp=1, **runner_kw):
+    """A paged ModelRunner over abstract int8 weights and an eval_shape'd
+    pool (nothing of model size on the host), its paged kernel resolved to
+    the compiled one as the TPU selector would. With ``tp`` > 1 it is built
+    over a 1 x tp mesh ('model' = tp): of CPU devices while it places its
+    small state, of the topology's devices for what it compiles. Returns
+    (runner, the argument avals of its programs as a dict)."""
+    from localai_tpu.engine import kvcache as kvc
+    from localai_tpu.engine.runner import ModelRunner
+    from localai_tpu.models.quant import QuantizedTensor
+    from localai_tpu.models.registry import synthetic_params
+    from localai_tpu.parallel import sharding as shd
+    from localai_tpu.parallel.mesh import MeshPlan, build_mesh
+
+    mesh = None
+    if tp > 1:
+        mesh = build_mesh(MeshPlan(model=tp), devices=topo.devices[:tp])
+        runner_kw["mesh"] = build_mesh(MeshPlan(model=tp),
+                                       devices=jax.devices()[:tp])
+
+    def on(spec=P()):
+        if mesh is None:
+            return SingleDeviceSharding(topo.devices[0])
+        return NamedSharding(mesh, spec)
+
+    def abstract(tree, spec_of=lambda a: a.sharding.spec):
+        """``tree``'s arrays as avals on the topology's devices, sharded
+        as the runner placed them."""
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=on(spec_of(a) if mesh is not None else P())),
+            tree)
+
+    place = shd.ParamPlacement(cfg, mesh)
+
+    def placed(kp, leaf):
+        where = (place.shardings(tuple(k.key for k in kp), leaf)
+                 or jax.tree.map(lambda _: on(), leaf))
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            leaf, where)
+
+    params = jax.tree_util.tree_map_with_path(
+        placed, jax.eval_shape(lambda: synthetic_params(cfg, "int8")),
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
+    real_init = kvc.init_paged_cache
+    monkeypatch.setattr(
+        kvc, "init_paged_cache",
+        lambda *a, **k: jax.eval_shape(lambda: real_init(*a, **k)))
+    r = ModelRunner(cfg, params, paged=True, attn_impl="pallas_interpret",
+                    **runner_kw)
+    # as the TPU selector would have it: the compiled kernel
+    assert ops.select_paged_attn_impl(
+        "auto", num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+        head_dim=cfg.hd, block_tokens=r.block_tokens, tp=tp,
+        backend="tpu") == ("pallas", False)
+    r._paged_attn_interpret = r._attn_interpret = False
+    if mesh is not None:
+        r.mesh = mesh
+    pool_spec = None if mesh is None else tuple(r._paged_sharding.spec)
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=on())
+    avals = {
+        "params": params,
+        # the eval_shape'd pool carries no sharding: the runner's own spec
+        "kv": abstract(r.kv, lambda a: P(*pool_spec[:a.ndim])),
+        "state": abstract(r.state), "tables": abstract(r.block_tables),
+        "scalar": scalar,
+        "proposals": jax.ShapeDtypeStruct((r.num_slots, 4), i32,
+                                          sharding=on()),
+    }
+
+    def chunk(bucket):
+        """_prefill_paged_fn's arguments after (params, kv, state)."""
+        return (jax.ShapeDtypeStruct((1, bucket), i32, sharding=on()),
+                scalar, scalar,
+                jax.ShapeDtypeStruct((r.max_blocks,), i32, sharding=on()),
+                scalar,
+                jax.ShapeDtypeStruct((cfg.vocab_size,), i32, sharding=on()))
+
+    avals["chunk"] = chunk
+    return r, avals
+
+
+def compile_program(fn, *args, **static):
+    """A runner program compiled for the topology as the runner jits it
+    (KV and decode state donated)."""
+    return jax.jit(fn, donate_argnums=(1, 2),
+                   static_argnames=tuple(static)).trace(
+        *args, **static).lower(lowering_platforms=("tpu",)).compile()
+
+
 def test_smoke_programs_fit_one_chip(topo, monkeypatch):
     """Every program the scheduler dispatches for chip_smoke.py's server —
     debug:llama3-8b, int8 weights, its SLOTS and CONTEXT, bf16 paged KV —
@@ -212,66 +309,160 @@ def test_smoke_programs_fit_one_chip(topo, monkeypatch):
     host); the pool is eval_shape'd."""
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     import chip_smoke
-    from localai_tpu.engine import kvcache as kvc
-    from localai_tpu.engine.runner import ModelRunner
-    from localai_tpu.models.registry import DEBUG_PRESETS, synthetic_params
+    from localai_tpu.models.registry import DEBUG_PRESETS
 
-    sh = SingleDeviceSharding(topo.devices[0])
     cfg = dataclasses.replace(DEBUG_PRESETS["llama3-8b"], dtype="bfloat16")
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.hd) == (HQ, HKV, HD)
+    r, a = abstract_runner(topo, monkeypatch, cfg, num_slots=chip_smoke.SLOTS,
+                           max_ctx=chip_smoke.CONTEXT)
+    base = (a["params"], a["kv"], a["state"])
 
-    def abstract(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
-            tree)
-
-    params = abstract(jax.eval_shape(lambda: synthetic_params(cfg, "int8")))
-    real_init = kvc.init_paged_cache
-    monkeypatch.setattr(
-        kvc, "init_paged_cache",
-        lambda *a, **k: jax.eval_shape(lambda: real_init(*a, **k)))
-    r = ModelRunner(cfg, params, num_slots=chip_smoke.SLOTS,
-                    max_ctx=chip_smoke.CONTEXT, paged=True,
-                    attn_impl="pallas_interpret")
-    # as the TPU selector would have it: the compiled kernel
-    assert ops.select_paged_attn_impl(
-        "auto", num_heads=HQ, num_kv_heads=HKV, head_dim=HD,
-        block_tokens=r.block_tokens, backend="tpu") == ("pallas", False)
-    r._paged_attn_interpret = r._attn_interpret = False
-    kv, state, tables = abstract(r.kv), abstract(r.state), abstract(
-        r.block_tables)
-    scalar = jax.ShapeDtypeStruct((), i32, sharding=sh)
-
-    def hbm(fn, *args, donate=(1, 2), **static):
-        c = jax.jit(fn, donate_argnums=donate,
-                    static_argnames=tuple(static)).trace(
-            *args, **static).lower(lowering_platforms=("tpu",)).compile()
-        m = c.memory_analysis()
+    def hbm(fn, *args, **static):
+        m = compile_program(fn, *args, **static).memory_analysis()
         return (m.argument_size_in_bytes + m.temp_size_in_bytes
                 + m.output_size_in_bytes - m.alias_size_in_bytes
                 + m.generated_code_size_in_bytes)
 
     need = {
-        "decode": hbm(r._decode_paged_fn, params, kv, state, tables),
+        "decode": hbm(r._decode_paged_fn, *base, a["tables"]),
         # the scheduler's default dispatch: 16 steps in one program
-        "decode_n": hbm(r._decode_paged_n_fn, params, kv, state, tables,
-                        n=16),
+        "decode_n": hbm(r._decode_paged_n_fn, *base, a["tables"], n=16),
         # the n-gram speculation lane, default gamma 4
-        "verify": hbm(r._verify_paged_fn, params, kv, state, tables,
-                      jax.ShapeDtypeStruct((chip_smoke.SLOTS, 4), i32,
-                                           sharding=sh)),
+        "verify": hbm(r._verify_paged_fn, *base, a["tables"], a["proposals"]),
     }
     for bucket in (128, 512):
         for sample in (False, True):
             need[f"prefill_chunk {bucket} sample={sample}"] = hbm(
-                r._prefill_paged_fn, params, kv, state,
-                jax.ShapeDtypeStruct((1, bucket), i32, sharding=sh),
-                scalar, scalar,
-                jax.ShapeDtypeStruct((r.max_blocks,), i32, sharding=sh),
-                scalar,
-                jax.ShapeDtypeStruct((cfg.vocab_size,), i32, sharding=sh),
+                r._prefill_paged_fn, *base, *a["chunk"](bucket),
                 bucket=bucket, sample=sample)
     worst = max(need, key=need.get)
     assert need[worst] < HBM_BYTES, (
         f"{worst} needs {need[worst] / 2**30:.2f} GiB of "
         f"{HBM_BYTES / 2**30:.2f}: chip_smoke.CONTEXT no longer fits")
+
+
+# ---------------------------------------------------------------------------
+# the benchmark cell's programs write the KV pool in place
+
+
+@pytest.fixture(scope="module")
+def cell():
+    """benchmark/configs/mistral-7b-v0.3-int8.json: the published keys as a
+    LlamaConfig, and the engine's sizes."""
+    import json
+
+    from localai_tpu.models.llama import LlamaConfig
+
+    root = Path(__file__).resolve().parent.parent
+    doc = json.loads(
+        (root / "benchmark/configs/mistral-7b-v0.3-int8.json").read_text())
+    # from_hf reads the published keys it knows and no other
+    cfg = dataclasses.replace(LlamaConfig.from_hf(doc), dtype="bfloat16")
+    return cfg, doc
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_n2",
+                                     "prefill_chunk_512",
+                                     "prefill_chunk_512_sample"])
+def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
+                                               program):
+    """The guard of PERF.md's PR 26: at the benchmark cell's shapes (32
+    layers, 289 blocks x 64 tokens, 8 kv heads, head_dim 128, 16 slots, int8
+    weights, the compiled paged kernel) the decode step, two steps in one
+    dispatch and a 512-token prefill chunk hold no second pool and copy no
+    layer of it. The stacked pool is the layer scan's carry, the write
+    policies scatter rows into it, the kernel reads it by layer index: one
+    per-layer slice anywhere (a policy's ``k[layer]``, a kernel that takes a
+    4-D pool, a scatter XLA lays out unlike its reader) brings back a
+    pool-sized temp and a 76 MB copy a layer, which only the compiler shows.
+    """
+    cfg, doc = cell
+    eng = doc["engine"]
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    pool = a["kv"].k.shape
+    assert pool == (32, 289, 8, 64, 128) and a["kv"].k.dtype == bf16
+    base = (a["params"], a["kv"], a["state"])
+    c = {
+        "decode": lambda: compile_program(
+            r._decode_paged_fn, *base, a["tables"]),
+        "decode_n2": lambda: compile_program(
+            r._decode_paged_n_fn, *base, a["tables"], n=2),
+        "prefill_chunk_512": lambda: compile_program(
+            r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
+            sample=False),
+        "prefill_chunk_512_sample": lambda: compile_program(
+            r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
+            sample=True),
+    }[program]()
+    assert_in_place(program, c, pool)
+
+
+def assert_in_place(program, c, pool):
+    """``c``, compiled, holds no second pool (``pool``: one device's K or V
+    stack) and copies no layer of it."""
+    import re
+
+    pool_bytes = 2 * np.prod(pool) * 2
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 2, (
+        f"{program}: temp {temp / 2**30:.2f} GiB, the pool is "
+        f"{pool_bytes / 2**30:.2f}: something holds a second pool")
+    # nothing may PRODUCE the pool, one layer of it or a layer's slice but
+    # the policies' scatters, fused and aliased onto their operand (in
+    # place), and what only names a buffer
+    shapes = "|".join(
+        r"\[" + ",".join(map(str, s)) + r"\]"
+        for s in (pool, (1,) + pool[1:], pool[1:]))
+    produces = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+(?:" + shapes
+        + r")(?:\{[^}]*\})? ([\w\-]+)\((.*)$", re.M)
+    free = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "scatter"}           # a scatter line is a fusion's own body
+    moved = [
+        (op, name) for name, op, rest in produces.findall(c.as_text())
+        if op not in free and not (
+            op == "fusion" and 'kv_pool.write/scatter"' in rest
+            and '"aliasing_operands"' in rest)]
+    assert not moved, f"{program} moves the pool or a layer of it: {moved}"
+
+
+@pytest.mark.parametrize("program, overlap", [
+    ("decode", "0"), ("decode", "auto"), ("prefill_chunk_512", "auto")])
+def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
+        topo, monkeypatch, cell, program, overlap):
+    """The same programs over a 1 x 4 mesh, the pool sharded over its kv
+    heads ('model'): under GSPMD (``LOCALAI_MESH_OVERLAP=0``, and every
+    prefill) as inside the manual-TP trunk's shard_map (``auto``) each chip
+    writes the rows of its own two heads into its own shard, in place. The
+    partitioner has to SEE that: the policies index the head axis with an
+    iota (``kvcache._scatter_per_head``), and a scatter it cannot prove
+    shard-local gathers the new rows, or the blocks a chunk touches, from
+    all chips, a collective a layer for K and for V."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    monkeypatch.setenv("LOCALAI_MESH_OVERLAP", overlap)
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, tp=4, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    assert bool(r.overlap_mode) == (overlap == "auto")
+    assert a["kv"].k.sharding.shard_shape(a["kv"].k.shape) == (
+        32, 289, 2, 64, 128)
+    base = (a["params"], a["kv"], a["state"])
+    if program == "decode":
+        c = compile_program(r._decode_paged_fn, *base, a["tables"])
+    else:
+        c = compile_program(r._prefill_paged_fn, *base, *a["chunk"](512),
+                            bucket=512, sample=False)
+    assert_in_place(program, c, (32, 289, 2, 64, 128))
+    collectives = [
+        ln.strip()[:160] for ln in c.as_text().splitlines()
+        if "kv_pool." in ln and re.search(
+            r" (all-gather|all-reduce|all-to-all|collective-permute"
+            r"|reduce-scatter)[\w\-]*\(", ln)]
+    assert not collectives, f"{program}: the pool's write talks: {collectives}"

@@ -110,9 +110,11 @@ def measure_point(head_dim: int, kv_heads: int, kv_dtype: str, *,
 
     if impl == "pallas":
         def fn(q, k, v, t, p, ks, vs):
+            # a one-layer stack: the kernel reads the pool by layer index
+            ks, vs = (None, None) if ks is None else (ks[None], vs[None])
             return ops.paged_decode_attention(
-                q, k, v, t, p, ks, vs, interpret=interpret,
-                num_buffers=num_buffers)
+                q, k[None], v[None], jnp.int32(0), t, p, ks, vs,
+                interpret=interpret, num_buffers=num_buffers)
     else:
         def fn(q, k, v, t, p, ks, vs):
             return ops.paged_decode_attention_ref(q, k, v, t, p, ks, vs)
