@@ -8,14 +8,20 @@ one layer at a time, teacher-forced on the served tokens. For each of a
 probe's 4 positions it returns the shortfall: the largest reference logit
 among the 26 letters minus the reference logit of the token that was served.
 An exact server has shortfall 0 everywhere; rounding in the served path flips
-near-ties and leaves a small one; the parent holds every shortfall to the
-configuration file's ``reference.epsilon``, which is set from the shortfalls
-measured on the chip at that configuration's widths, with the reason beside it.
-The served path computes in bfloat16 with float32 accumulation and writes
-bfloat16 logits: half an ulp at |logit| in [2, 4) is 2^-7 = 0.0078, and two
-letters whose reference logits are closer than the accumulated rounding swap
-places. A lower precision than the configuration states (int8 activations)
-flips ties several times wider apart.
+near-ties and leaves a small one at a few positions. The parent
+(run.py ``judge``) holds ONE number of the run's 64 shortfalls to the
+configuration file's ``reference.epsilon``: the one its
+``reference.statistic`` names, the largest (``max``, the default) or the
+``mean``; both are printed in every run. The limit is set from readings on the
+chip at that configuration's widths, sound runs and the control's, with both
+beside it in the file. The served path computes in bfloat16 with float32
+accumulation and writes bfloat16 logits: half an ulp at |logit| in [2, 4) is
+2^-7 = 0.0078, and two letters whose reference logits are closer than the
+accumulated rounding swap places. A lower precision than the configuration
+states (int8 activations, the control) flips several times as many ties,
+several times wider apart. At the 24B's widths one near-tie sets the largest
+shortfall of a run and no limit on it tells the two apart, so its file judges
+the mean (PERF.md sections 2 and 6, PR 27).
 
 From the program this reads the loaded runner's ``params`` pytree and nothing
 else: leaves are arrays, or quantised tensors with ``q`` (int8), ``scale``

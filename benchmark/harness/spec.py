@@ -18,7 +18,7 @@ ROOT = Path(__file__).resolve().parents[2]
 
 # a cell file states how hard the cell is driven and what "met" means
 CELL_KEYS = {"rate_rps", "clients", "ramp_s", "drain_s", "limits", "knee",
-             "why"}
+             "knee_rps", "why"}
 # a configuration file's own keys; every other top-level key is the
 # published config.json, passed verbatim to LlamaConfig.from_hf
 CONFIG_KEYS = {"name", "source", "reduced", "assumed", "deployment", "chips",
@@ -83,10 +83,13 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     cfg_entry = next(c for c in bench["configs"]
                      if c["name"] == entry["config"])
     config = _read(root / cfg_entry["file"])
-    if not isinstance(config.get("reference", {}).get("epsilon"),
-                      (int, float)):
+    reference = config.get("reference", {})
+    if not isinstance(reference.get("epsilon"), (int, float)):
         raise SpecError(f"{cfg_entry['file']}: no reference.epsilon (the "
                         f"largest shortfall the reference check allows)")
+    if reference.get("statistic", "max") not in ("max", "mean"):
+        raise SpecError(f"{cfg_entry['file']}: reference.statistic is 'max' "
+                        f"(the default) or 'mean'")
     base = bench_dir(root)
     drive = _read(base / "cells" / f"{workload}.json")
     unknown = set(drive) - CELL_KEYS

@@ -17,6 +17,14 @@ import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
+import jax  # noqa: E402
+
+# eight CPU devices IN THIS PROCESS, for the tensor-parallel reference case
+# (not XLA_FLAGS: a child server inherits the environment, and a one-chip
+# cell must find one device); tests/conftest.py, which may have run first,
+# asks for the same eight
+jax.config.update("jax_num_cpu_devices", 8)
+
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))

@@ -65,6 +65,32 @@ def test_a_closed_loop_cell_reports_tokens_per_second(bench_copy, cpu_peaks,
     assert out["attempted"] >= 4 and out["failed"] == 0 and out["correct"]
 
 
+def hand_made_trace(monkeypatch, path, ops, chips=1):
+    """Have ``trace_reduce.reduce_run`` read a hand-made trace in place of
+    the real capture's (the CPU backend writes no device plane): ``ops`` on
+    each of ``chips`` planes, two executions of the decode program, the
+    profile starting when the capture was asked for."""
+    real_reduce = trace_reduce.reduce_run
+
+    def reduce_run(run_dir, traced):
+        # the real capture's file is there
+        assert trace_reduce.find_xplane(run_dir, traced) is not None
+        start = int(traced["asked_unix"] * 1e9)
+        path.write_bytes(xspace.space([
+            xspace.plane(f"/device:TPU:{i}", {
+                "XLA Ops": ops,
+                "XLA Modules": [("jit__decode_paged_fn(1)", 1000, 10**8),
+                                ("jit__decode_paged_fn(1)", 3 * 10**8,
+                                 2 * 10**8)]})
+            for i in range(chips)] + [xspace.plane("Task Environment", {}, {
+                "profile_start_time": start,
+                "profile_stop_time": start + 10**9})]))
+        monkeypatch.setattr(trace_reduce, "find_xplane", lambda *a: path)
+        return real_reduce(run_dir, traced)
+
+    monkeypatch.setattr(trace_reduce, "reduce_run", reduce_run)
+
+
 def test_a_traced_run_reports_the_per_layer_metrics(bench_copy, cpu_peaks,
                                                     capsys, monkeypatch,
                                                     tmp_path):
@@ -72,26 +98,9 @@ def test_a_traced_run_reports_the_per_layer_metrics(bench_copy, cpu_peaks,
     hand-made trace; everything around it (the capture through
     /backend/trace, the flight ring, the spans, the readers found by name,
     the line) is the real path."""
-    fake = tmp_path / "fake.xplane.pb"
-    real_reduce = trace_reduce.reduce_run
-
-    def reduce_run(run_dir, traced):
-        assert trace_reduce.find_xplane(run_dir, traced) is not None
-        start = int(traced["asked_unix"] * 1e9)
-        fake.write_bytes(xspace.space([
-            xspace.plane("/device:TPU:0", {
-                "XLA Ops": [("fusion.1", 1000, 10**8),
-                            ("fusion.2", 3 * 10**8, 2 * 10**8)],
-                "XLA Modules": [("jit__decode_paged_fn(1)", 1000, 10**8),
-                                ("jit__decode_paged_fn(1)", 3 * 10**8,
-                                 2 * 10**8)]}),
-            xspace.plane("Task Environment", {}, {
-                "profile_start_time": start,
-                "profile_stop_time": start + 10**9})]))
-        monkeypatch.setattr(trace_reduce, "find_xplane", lambda *a: fake)
-        return real_reduce(run_dir, traced)
-
-    monkeypatch.setattr(trace_reduce, "reduce_run", reduce_run)
+    hand_made_trace(monkeypatch, tmp_path / "fake.xplane.pb",
+                    [("fusion.1", 1000, 10**8),
+                     ("fusion.2", 3 * 10**8, 2 * 10**8)])
     rc = bench.main(["--workload", "tiny-open", "--seed", "2", "--seconds",
                      "6", "--trace", "1"], platform="cpu", root=bench_copy)
     assert rc == 0
@@ -113,6 +122,127 @@ def test_a_traced_run_reports_the_per_layer_metrics(bench_copy, cpu_peaks,
         assert name in m, name
     # nothing to read -> left out: the hand-made trace has no custom call
     assert "paged_decode_attn_roofline" not in m
+
+
+@pytest.mark.parametrize("workload, chips, slice_s", [
+    ("tiny-open", 1, 3.0), ("tiny-tp4-open", 4, 1.5)])
+def test_the_traced_slice_is_cut_to_the_chips(
+        bench_copy, cpu_peaks, capsys, monkeypatch, tmp_path, workload,
+        chips, slice_s):
+    """``--trace 2`` asks the profiler for ``run.trace_for_s(chips)`` seconds
+    (one capture of 0.1 s is thrown away first): 3.0 on one chip, 1.5 on
+    four, since what a capture costs goes with seconds x planes and the
+    profiler writes a plane a chip. The four-chip cell is served
+    tensor-parallel over four CPU devices of the child, and its line carries
+    ``mesh.collective_share``, which no one-chip cell's does."""
+    assert bench.trace_for_s(chips) == slice_s
+    monkeypatch.setenv(
+        "XLA_FLAGS", f"--xla_force_host_platform_device_count={chips}")
+    asked = []
+
+    def capture(server, client, at, seconds, out, _real=bench.capture_trace):
+        asked.append(seconds)
+        return _real(server, client, at, seconds, out)
+
+    monkeypatch.setattr(bench, "capture_trace", capture)
+    ops = [("%fusion.1 = f32[4,64]{1,0} fusion(f32[4,64]{1,0} %x)", 1000,
+            10**8)]
+    if chips > 1:
+        ops.append(("%all-reduce.2 = f32[4,64]{1,0} all-reduce(f32[4,64]{1,0} "
+                    "%fusion.1)", 2 * 10**8, 10**8))
+    hand_made_trace(monkeypatch, tmp_path / "fake.xplane.pb", ops, chips)
+    rc = bench.main(["--workload", workload, "--seed", str(2**31 + 9),
+                     "--seconds", "3", "--trace", "2"], platform="cpu",
+                    root=bench_copy)
+    assert rc == 0
+    assert asked == [0.1, slice_s]
+    out = result_line(capsys)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["device"]["count"] == chips
+    got = {k: v["value"] for k, v in out["metrics"].items()
+           if k.startswith("mesh.")}
+    assert got == (pytest.approx({"mesh.collective_share": 50.0}, abs=1e-3)
+                   if chips > 1 else {})
+    assert "ttft_ms_mean" in out["metrics"]
+
+
+def test_a_compile_inside_the_slice_voids_the_slice_and_not_the_run(
+        bench_copy, cpu_peaks, capsys, monkeypatch, tmp_path):
+    """A watched program that compiles while the slice is traced (the counter
+    is made to step once the slice's capture has answered) takes the
+    device-trace readers' trace away: their metrics are left out of the
+    line. The window closed before, so the run stays ``correct`` with every
+    end-to-end metric and the window-wide readers on its line."""
+    late = []
+
+    async def capture(server, client, at, seconds, out,
+                      _real=bench.capture_trace):
+        await _real(server, client, at, seconds, out)
+        if seconds != 0.1:          # not the capture that is thrown away
+            late.append(1)
+
+    monkeypatch.setattr(bench, "capture_trace", capture)
+    monkeypatch.setattr(bench, "compiles",
+                        lambda text, _real=bench.compiles:
+                        _real(text) + len(late))
+    hand_made_trace(monkeypatch, tmp_path / "fake.xplane.pb",
+                    [("fusion.1", 1000, 10**8)])
+    rc = bench.main(["--workload", "tiny-open", "--seed", "11", "--seconds",
+                     "3", "--trace", "2"], platform="cpu", root=bench_copy)
+    assert rc == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    out = json.loads(lines[-1])
+    notes = json.loads(next(ln for ln in lines if ln.startswith("trace "))[6:])
+    assert notes["compiles_in_slice"] == 1
+    assert out["correct"] is True and out["failed"] == 0
+    m = out["metrics"]
+    assert {"ttft_ms_mean", "tpot_ms_p90", "stall_ms_p98", "setup_s"} <= set(m)
+    assert m["runner.compiles_in_window"]["value"] == 0
+    assert "runner.occupancy_mean" in m
+    for name in ("device.idle_share", "model.decode_bw_share",
+                 "runner.kv_move_share", "sched.device_idle_share"):
+        assert name not in m, name
+
+
+def test_the_four_chip_cell_reports_what_a_chat_cell_and_a_mesh_report():
+    """BENCHMARK.json as committed: ``ms24b-tp4-chat`` gets TTFT, the four
+    readers that move it and the ``mesh.*`` reader; the one-chip cells get no
+    ``mesh.*`` reader; its rate is 0.7-0.8 x the knee its file states."""
+    from harness import spec
+
+    chat = {"http.overhead_ms_p50", "sched.queue_wait_ms_p90",
+            "runner.kv_used_peak_share", "model.prefill_mfu"}
+    mesh = {"mesh.collective_share"}
+    cells = {w["name"]: spec.load_cell(w["name"]) for w in json.loads(
+        (ROOT / "BENCHMARK.json").read_text())["workloads"]}
+    new = cells["ms24b-tp4-chat"]
+    assert new.chips == 4 and new.config["sharding"] == {
+        "tensor_parallel_size": 4}
+    assert {m["name"] for m in new.end_to_end} == {
+        "ttft_ms_mean", "tpot_ms_p90", "stall_ms_p98", "setup_s"}
+    layers = {m["name"] for m in new.per_layer}
+    assert chat | mesh <= layers
+    # m7b-chat reads the gap tail per layer, not end to end (it sits on a
+    # cliff there), and the two readers that move the tail under names that
+    # move its TTFT: otherwise the two chat cells report the same
+    one = cells["m7b-chat"]
+    assert {m["name"] for m in one.end_to_end} == {
+        "ttft_ms_mean", "tpot_ms_p90", "setup_s"}
+    split = {"gen.send_late_ms_p99", "runner.compiles_in_window"}
+    assert layers - mesh - split == {m["name"] for m in one.per_layer} - {
+        "sched.stall_ms_p98"} - {s + ".ttft" for s in split}
+    assert split <= layers and {s + ".ttft" for s in split} | {
+        "sched.stall_ms_p98"} <= {m["name"] for m in one.per_layer}
+    # every per-layer metric names an end-to-end metric its cells report
+    for cell in cells.values():
+        assert {m["moves"] for m in cell.per_layer} <= {
+            m["name"] for m in cell.end_to_end}, cell.name
+    for name, cell in cells.items():
+        if cell.chips == 1:
+            assert not mesh & {m["name"] for m in cell.per_layer}, name
+    # the rate is what the sweep found: 0.7-0.8 x the knee in the cell file
+    knee = new.drive["knee_rps"]
+    assert 0.7 * knee <= new.drive["rate_rps"] <= 0.8 * knee + 1e-9
 
 
 def test_plain_run_exits_nonzero_without_a_tpu():

@@ -7,6 +7,8 @@ from pathlib import Path
 import pytest
 
 import xspace
+from conftest import ROOT
+from harness import spec
 from harness import trace_reduce as tr
 
 FIXTURE = Path(__file__).parent / "data" / "v5e_small.xplane.pb"
@@ -99,3 +101,39 @@ def test_recorded_v5e_trace():
     assert out["modules"] and all(
         lo - 1e-3 <= s < e <= hi + 1e-3 for s, e, _ in out["modules"])
     assert sum(out["ops"].values()) == pytest.approx(out["busy_s"], rel=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the reader of what only a mesh has
+
+
+def meshed(tmp_path, collective=True):
+    """Two chips, each: a fusion in [0,400), an all-reduce (or, without
+    ``collective``, a fusion) in [400,600), a fusion in [600,900)."""
+    chip = {"XLA Ops": [
+        ("%fusion.1 = bf16[32,5120]{1,0} fusion(bf16[32,5120]{1,0} %x)",
+         0, 400),
+        ("%all-reduce.7 = bf16[32,5120]{1,0} all-reduce(bf16[32,5120]{1,0} "
+         "%fusion.1), replica_groups={{0,1}}" if collective else
+         "%fusion.7 = bf16[32,5120]{1,0} fusion(bf16[32,5120]{1,0} %x)",
+         400, 200),
+        ("%fusion.2 = bf16[32,8192]{1,0} fusion(bf16[32,5120]{1,0} %y)",
+         600, 300)]}
+    return tr.reduce(write(tmp_path, [
+        xspace.plane("/device:TPU:0", chip),
+        xspace.plane("/device:TPU:1", chip)]))
+
+
+def test_the_mesh_reader_reads_the_collectives_share_of_busy_time(tmp_path):
+    read = spec.load_reader("mesh.collective_share", ROOT)
+    assert read({"trace": meshed(tmp_path)}) == pytest.approx(
+        100.0 * 200 / 900)
+    # a mesh whose trace holds no collective reads 0: a rename or a removal
+    # shows, the metric does not vanish
+    assert read({"trace": meshed(tmp_path, collective=False)}) == 0.0
+    # one chip runs no collective, and without a trace there is nothing to
+    # read: the metric is left out
+    one = tr.reduce(write(tmp_path, [xspace.plane("/device:TPU:0", {
+        "XLA Ops": [("fusion.1", 0, 400)]})]))
+    assert read({"trace": one}) is None
+    assert read({"trace": None}) is None and read({}) is None
