@@ -2,9 +2,10 @@
 
 Runs in the child (the process that holds the chip and the weights), while
 the server is otherwise idle. The parent served each probe over HTTP, greedy,
-4 new tokens; here the plain float32 forward pass
-(benchmark/reference/llama_family.py) runs on the SERVED weights, dequantised
-one layer at a time, teacher-forced on the served tokens. For each of a
+4 new tokens; here the plain float32 forward pass of the configuration's
+family (``benchmark/reference/<family>.py``: the configuration file names it,
+harness/spec.py has the contract) runs on the SERVED weights, dequantised one
+layer at a time, teacher-forced on the served tokens. For each of a
 probe's 4 positions it returns the shortfall: the largest reference logit
 among the 26 letters minus the reference logit of the token that was served.
 An exact server has shortfall 0 everywhere; rounding in the served path flips
@@ -24,8 +25,12 @@ shortfall of a run and no limit on it tells the two apart, so its file judges
 the mean (PERF.md sections 2 and 6, PR 27).
 
 From the program this reads the loaded runner's ``params`` pytree and nothing
-else: leaves are arrays, or quantised tensors with ``q`` (int8), ``scale``
+else: ``embed``, ``final_norm``, ``lm_head`` (unless tied) and ``layers``, a
+pytree of stacked per-layer weights that only the family's ``decoder_layer``
+names; leaves are arrays, or quantised tensors with ``q`` (int8), ``scale``
 (float32, the weight's shape without the contraction axis) and ``axis``.
+``served_param_count`` counts them, for the set-up's check that the model
+served is the model the file describes (the family's ``param_count``).
 """
 
 from __future__ import annotations
@@ -34,15 +39,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from reference import llama_family as ref
-
 LETTERS = slice(ord("a"), ord("z") + 1)
+
+
+def _quantised(leaf) -> bool:
+    return hasattr(leaf, "q")
+
+
+def served_param_count(params) -> int:
+    """Weights and norm gains the served model holds: every leaf's elements
+    (a quantised leaf's ``q``; its scales are no parameters), global shapes
+    where the leaf is sharded."""
+    return sum(int((leaf.q if _quantised(leaf) else leaf).size)
+               for leaf in jax.tree_util.tree_leaves(params,
+                                                     is_leaf=_quantised))
 
 
 def _f32(leaf, index=None, cols=None):
     """A weight (or layer ``index`` of a stacked one, or columns of it) as
     float32, dequantised if it is a quantised tensor."""
-    quantised = hasattr(leaf, "q")
+    quantised = _quantised(leaf)
     if quantised and leaf.scale.ndim != leaf.q.ndim - 1:
         raise NotImplementedError("group-wise scales (int4) are not covered "
                                   "by the reference check yet")
@@ -65,7 +81,7 @@ def _f32(leaf, index=None, cols=None):
 
 def _embed(params, tokens):
     e = params["embed"]
-    if hasattr(e, "q"):     # per-row scales
+    if _quantised(e):       # per-row scales
         return e.q[tokens].astype(jnp.float32) * e.scale[tokens][..., None]
     return e[tokens].astype(jnp.float32)
 
@@ -77,44 +93,44 @@ def _head(params):
     return _embed(params, jnp.arange(LETTERS.start, LETTERS.stop)).T
 
 
-def programs(hf: dict, n_tokens: int):
+def programs(family, hf: dict, n_tokens: int):
     """The reference as three plain callables over same-length sequences
     [B, T]: tokens -> x, (x, stacked layers, layer index) -> x, and
     (params, x at the wanted positions) -> logits over the 26 letters."""
-    heads, kv_heads = hf["num_attention_heads"], hf["num_key_value_heads"]
-    hd = int(hf.get("head_dim") or hf["hidden_size"] // heads)
-    eps = float(hf.get("rms_norm_eps", 1e-5))
-    cos, sin = ref.rope_angles(jnp.arange(n_tokens), hd,
-                               float(hf.get("rope_theta", 10000.0)))
+    cos, sin = family.rope_tables(hf, n_tokens)
 
     def layer(x, layers, index):
-        w = {k: _f32(v, index) for k, v in layers.items()}
-        return jax.vmap(lambda s: ref.decoder_layer(
-            s, w, cos, sin, num_heads=heads, num_kv_heads=kv_heads,
-            head_dim=hd, eps=eps))(x)
+        w = jax.tree_util.tree_map(lambda leaf: _f32(leaf, index), layers,
+                                   is_leaf=_quantised)
+        return jax.vmap(
+            lambda s: family.decoder_layer(s, w, cos, sin, hf))(x)
 
     def logits(params, x):
-        return jax.vmap(lambda s: ref.logits(
+        return jax.vmap(lambda s: family.logits(
             s, params["final_norm"].astype(jnp.float32), _head(params),
-            eps))(x)
+            hf))(x)
 
     return _embed, layer, logits
 
 
-def reference_logits(params, hf: dict, tokens: np.ndarray, last: int):
+def reference_logits(params, family, hf: dict, tokens: np.ndarray,
+                     last: int):
     """Reference logits over the 26 letters at the ``last`` final positions
     of each of tokens [B, T] (same-length sequences, one vmapped batch), one
     layer's weights dequantised at a time."""
-    embed, layer, logits = (jax.jit(f) for f in programs(hf, tokens.shape[1]))
+    embed, layer, logits = (jax.jit(f) for f in programs(
+        family, hf, tokens.shape[1]))
+    n_layers = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
     with jax.default_matmul_precision("highest"):
         x = embed(params, jnp.asarray(tokens, jnp.int32))
-        for index in range(hf["num_hidden_layers"]):
+        for index in range(n_layers):
             x = layer(x, params["layers"], jnp.int32(index))
         out = logits(params, x[:, -last:])
     return np.asarray(out, np.float32)
 
 
-def shortfalls(params, hf: dict, probes: list[dict]) -> list[dict]:
+def shortfalls(params, family, hf: dict,
+               probes: list[dict]) -> list[dict]:
     """probes: [{"prompt": [ids], "served": [ids]}]. Same-length probes run
     as one batch. Returns, per probe, per served position: the shortfall and
     the reference's own margin (largest minus second largest letter logit)."""
@@ -127,7 +143,8 @@ def shortfalls(params, hf: dict, probes: list[dict]) -> list[dict]:
         # served[:-1] to predict the rest
         tokens = np.array([probes[i]["prompt"] + probes[i]["served"][:-1]
                            for i in idxs], np.int32)
-        lg = reference_logits(params, hf, tokens, n_new)   # [B, n_new, 26]
+        lg = reference_logits(params, family, hf, tokens,
+                              n_new)                       # [B, n_new, 26]
         for b, i in enumerate(idxs):
             rows = []
             for k, tok in enumerate(probes[i]["served"]):

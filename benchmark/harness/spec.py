@@ -4,11 +4,44 @@ A later PR adds a configuration, a traffic mix, a cell or a per-layer metric
 by adding ``benchmark/configs/<n>.json``, ``benchmark/traffic/<n>.json``,
 ``benchmark/cells/<n>.json`` or ``benchmark/layers/<n>.py`` and one entry in
 BENCHMARK.json; nothing here names a cell, a configuration or a metric.
+
+An ARCHITECTURE is added the same way. Everything the benchmark knows about
+one (its forward pass in plain float32, and the operations and bytes that
+forward pass needs) is ONE module, ``benchmark/reference/<family>.py``, which
+a configuration file names as ``reference.family`` (absent: DEFAULT_FAMILY).
+Nothing else under ``harness/`` or ``layers/`` names an architecture, a model
+type or a layer's weight. A family module defines, ``hf`` being the
+configuration's published keys:
+
+  the mathematics, one sequence at a time, float32, no cache, no kernels
+  (callers hold ``jax.default_matmul_precision("highest")``):
+    rope_tables(hf, n_tokens) -> (cos, sin) for positions 0 .. n_tokens - 1
+    decoder_layer(x, w, cos, sin, hf) -> x     x [T, D]; w is ONE layer's
+        weights, float32, a pytree under the names the served ``layers``
+        pytree uses (harness/refcheck.py dequantises it leaf by leaf)
+    logits(x, final_norm, head, hf) -> [T, V']  for the head columns given
+  the arithmetic, of the published keys and of counts the client saw, never
+  of the program (the readers under ``layers/`` and the set-up's parameter
+  check use it through harness/work.py):
+    param_count(hf)            every weight the served model holds
+    layer_params(hf)           matmul weights of one layer: what HBM holds
+    token_params(hf)           weights ONE token's forward multiplies, all
+                               layers, head left out: what prefill flops count
+    step_params(hf, tokens)    weights a decode step over ``tokens`` query
+                               tokens must READ, all layers and the head; an
+                               EXPECTATION where it depends on the tokens,
+                               never an upper bound: a need that is overstated
+                               reads over 100% of a roofline
+    kv_bytes_per_token(hf, element_bytes)     what one token adds to the cache
+    q_elements_per_token(hf)   one token's q (and attention output) elements
+    attn_flops(hf, pairs)      over (query token, attended token) pairs
 """
 
 from __future__ import annotations
 
+import ast
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -24,6 +57,13 @@ CELL_KEYS = {"rate_rps", "clients", "ramp_s", "drain_s", "limits", "knee",
 CONFIG_KEYS = {"name", "source", "reduced", "assumed", "deployment", "chips",
                "context_size", "engine", "sharding", "reference", "hbm",
                "notes"}
+
+
+# the family of a configuration file that names none: the dense GQA decoder
+DEFAULT_FAMILY = "llama_family"
+FAMILY_CONTRACT = ("rope_tables", "decoder_layer", "logits", "param_count",
+                   "layer_params", "token_params", "step_params",
+                   "kv_bytes_per_token", "q_elements_per_token", "attn_flops")
 
 
 class SpecError(ValueError):
@@ -46,6 +86,7 @@ class Cell:
     config_name: str
     config_file: Path
     config: dict            # benchmark/configs/<config>.json, whole
+    family_file: Path       # benchmark/reference/<reference.family>.py
     traffic: dict           # benchmark/traffic/<traffic>.json, whole
     drive: dict             # benchmark/cells/<name>.json, whole
     end_to_end: tuple       # metric entries this cell reports, --trace 0
@@ -62,10 +103,55 @@ class Cell:
     def max_slots(self) -> int:
         return int(self.config["engine"]["max_slots"])
 
+    @functools.cached_property
+    def family(self):
+        """The family module (imports JAX: the parent asks after the window,
+        when a reader wants its arithmetic, and never in set-up)."""
+        return load_family(self.family_file)
+
 
 def bench_dir(root: Path = ROOT) -> Path:
     """The benchmark's own directory: the first of BENCHMARK.json's paths."""
     return root / _read(root / "BENCHMARK.json")["paths"][0]
+
+
+def family_file(config: dict, where: str, root: Path = ROOT) -> Path:
+    """The module ``reference.family`` names, checked against the contract
+    without importing it (it imports JAX)."""
+    name = config.get("reference", {}).get("family", DEFAULT_FAMILY)
+    path = bench_dir(root) / "reference" / f"{name}.py"
+    try:
+        body = ast.parse(path.read_text()).body
+    except FileNotFoundError:
+        raise SpecError(f"{where}: reference.family {name!r} names no file: "
+                        f"{path} does not exist") from None
+    defined = set()
+    for node in body:
+        if isinstance(node, ast.FunctionDef):
+            defined.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            defined |= {a.asname or a.name for a in node.names}
+        elif isinstance(node, ast.Assign):
+            defined |= {t.id for t in node.targets if isinstance(t, ast.Name)}
+    missing = [n for n in FAMILY_CONTRACT if n not in defined]
+    if missing:
+        raise SpecError(f"{path}: a family module defines {FAMILY_CONTRACT} "
+                        f"(harness/spec.py); this one lacks {missing}")
+    return path
+
+
+def _load_module(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(path: Path):
+    """Import a family module by path. Its sibling imports
+    (``from reference import ...``) resolve through the ``benchmark``
+    directory on ``sys.path``, which every entry point puts there."""
+    return _load_module("family_" + path.stem, path)
 
 
 def _reported(metric: dict, cell: str) -> bool:
@@ -90,6 +176,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     if reference.get("statistic", "max") not in ("max", "mean"):
         raise SpecError(f"{cfg_entry['file']}: reference.statistic is 'max' "
                         f"(the default) or 'mean'")
+    family = family_file(config, cfg_entry["file"], root)
     base = bench_dir(root)
     drive = _read(base / "cells" / f"{workload}.json")
     unknown = set(drive) - CELL_KEYS
@@ -115,6 +202,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     return Cell(name=workload, chips=int(entry["chips"]),
                 config_name=entry["config"],
                 config_file=root / cfg_entry["file"], config=config,
+                family_file=family,
                 traffic=traffic, drive=drive,
                 end_to_end=e2e, per_layer=per_layer,
                 run_seconds=int(bench["run_seconds"]))
@@ -127,8 +215,5 @@ def load_reader(metric: str, root: Path = ROOT) -> Callable[[Any],
     path = bench_dir(root) / "layers" / f"{metric}.py"
     if not path.exists():
         raise SpecError(f"per-layer metric {metric!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "layer_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(
+        "layer_" + metric.replace(".", "_").replace("-", "_"), path).read

@@ -17,6 +17,7 @@ def read(ctx):
     tokens, pairs = ll.prefilled_in(ctx, *win)
     if not seconds or not tokens:
         return None
-    flops = work.prefill_flops(ctx["cell"].published, tokens, pairs)
+    cell = ctx["cell"]
+    flops = work.prefill_flops(cell.family, cell.published, tokens, pairs)
     return (100.0 * flops / seconds
-            / (ctx["peak"]["bf16_flops"] * ctx["cell"].chips))
+            / (ctx["peak"]["bf16_flops"] * cell.chips))

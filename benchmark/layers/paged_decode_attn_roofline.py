@@ -21,6 +21,6 @@ def read(ctx):
     if not seconds or not tokens:
         return None
     cell = ctx["cell"]
-    need = work.paged_decode_attn(cell.published, cell.config["engine"],
-                                  attended, tokens)
+    need = work.paged_decode_attn(cell.family, cell.published,
+                                  cell.config["engine"], attended, tokens)
     return ll.share_of_roofline(need, seconds, ctx)

@@ -9,7 +9,7 @@ program names no scope."""
 
 import re
 
-from harness import trace_reduce, work
+from harness import trace_reduce
 
 PROGRAMS = r"decode"        # jit__decode_paged_fn, jit__decode_paged_n_fn
 SCOPE = "kv_pool."
@@ -17,7 +17,9 @@ DIMS = re.compile(r"\[([\d,]*)\]$")
 
 
 def pool_dims(cell) -> set[tuple[int, ...]]:
-    """The pool's dimensions and one layer's, each sorted."""
+    """The pool's dimensions and one layer's, each sorted: the paged pool of
+    an attention that caches K and V a kv head (this reader's own knowledge
+    of engine/kvcache.py's layout, not the harness's of an architecture)."""
     hf, engine = cell.published, cell.config["engine"]
     if "kv_num_blocks" not in engine:
         return set()        # sized by the program: only the scopes tell
@@ -26,7 +28,8 @@ def pool_dims(cell) -> set[tuple[int, ...]]:
         "tensor_parallel_size") or 1)
     layer = (int(engine["kv_num_blocks"]), hf["num_key_value_heads"] // tp,
              int(engine.get("kv_block_tokens", 64)),    # the engine's default
-             work.head_dim(hf))
+             int(hf.get("head_dim")
+                 or hf["hidden_size"] // hf["num_attention_heads"]))
     return {tuple(sorted(layer)),
             tuple(sorted((hf["num_hidden_layers"],) + layer)),
             tuple(sorted((1,) + layer))}

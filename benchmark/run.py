@@ -123,7 +123,11 @@ async def reference_check(client: Client, server: Server, cell: spec.Cell,
     """16 seeded greedy probes over HTTP, then the plain reference on the
     served weights in the child (harness/refcheck.py). Prompt lengths span a
     one-bucket prefill, a chunked (> 512 token) prefill and multi-block
-    contexts; tokens 2-4 decode through the cache."""
+    contexts; tokens 2-4 decode through the cache. Beside it, the model
+    served is the model the file describes: the served parameter count
+    equals the family's ``param_count`` of the published keys exactly, or
+    the run is not correct (a published key the program reads under another
+    name, or not at all, shows here)."""
     rng = trf.stream_rng(seed, "probe")
     reqs = []
     room = int(cell.config["context_size"]) - PROBE_NEW - 1
@@ -150,7 +154,15 @@ async def reference_check(client: Client, server: Server, cell: spec.Cell,
     short = [p["shortfall"] for rows in reply["shortfalls"] for p in rows]
     margin = mtr.percentile(
         [p["margin"] for rows in reply["shortfalls"] for p in rows], 10)
-    return {**judge(short, cell.config["reference"]), "margin_p10": margin}
+    check = {**judge(short, cell.config["reference"]), "margin_p10": margin,
+             "params_served": reply["params"]["served"],
+             "params_described": reply["params"]["described"]}
+    if check["params_served"] != check["params_described"]:
+        say(f"NOT the model described: the server holds "
+            f"{check['params_served']} parameters, the configuration's "
+            f"family counts {check['params_described']} from its file")
+        check["ok"] = False
+    return check
 
 
 def judge(short: list, reference: dict) -> dict:

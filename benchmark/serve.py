@@ -16,6 +16,10 @@ Three things of its own, then the program's normal path
 
 Besides, it hosts the reference check (benchmark/harness/refcheck.py) on a
 side port: the check needs the served weights, and only this process has them.
+The reference is the configuration's family's (``reference.family``,
+harness/spec.py); step 1 is what is still one architecture's here: a family
+that ``models/llama.py`` does not serve needs an entry of its own from
+published keys in the program first (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -47,10 +51,14 @@ def write_models_dir(path: Path, name: str, config: dict) -> None:
 
 
 class Control(BaseHTTPRequestHandler):
-    """POST /reference {"probes": [...]} -> {"shortfalls": [...]}."""
+    """POST /reference {"probes": [...]} -> {"shortfalls": [...], "params":
+    {"served": n, "described": n}}: the check's shortfalls, and the served
+    model's parameter count beside the one its family works out from the
+    published keys."""
 
     published: dict = {}
     name: str = ""
+    family = None
 
     def do_POST(self):  # noqa: N802 — http.server's naming
         from harness import refcheck
@@ -58,8 +66,12 @@ class Control(BaseHTTPRequestHandler):
         body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
         try:
             params = SERVING[self.name].runner.params
-            reply = {"shortfalls": refcheck.shortfalls(
-                params, self.published, body["probes"])}
+            reply = {
+                "shortfalls": refcheck.shortfalls(
+                    params, self.family, self.published, body["probes"]),
+                "params": {
+                    "served": refcheck.served_param_count(params),
+                    "described": self.family.param_count(self.published)}}
             code = 200
         except Exception as e:  # noqa: BLE001 — reported to the parent,
             # which fails the run; the traceback goes to the server log
@@ -88,12 +100,13 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     config = json.loads(Path(args.config).read_text())
 
-    from harness.spec import CONFIG_KEYS
+    from harness import spec
     from localai_tpu.cli.main import main as cli_main
     from localai_tpu.models import manager, registry
     from localai_tpu.models.llama import LlamaConfig
 
-    published = {k: v for k, v in config.items() if k not in CONFIG_KEYS}
+    published = {k: v for k, v in config.items()
+                 if k not in spec.CONFIG_KEYS}
     registry.DEBUG_PRESETS[args.name] = LlamaConfig.from_hf(published)
     write_models_dir(Path(args.models_path), args.name, config)
 
@@ -108,6 +121,7 @@ def main(argv=None) -> int:
     manager.build_serving_model = remember
 
     Control.published, Control.name = published, args.name
+    Control.family = spec.load_family(spec.family_file(config, args.config))
     control = ThreadingHTTPServer(("127.0.0.1", args.control_port), Control)
     threading.Thread(target=control.serve_forever, daemon=True,
                      name="benchmark-control").start()

@@ -14,8 +14,8 @@ import pytest
 
 import run as bench
 import xspace
-from conftest import BENCH, ROOT
-from harness import peaks, trace_reduce
+from conftest import BENCH, DATA, ROOT
+from harness import peaks, spec, trace_reduce
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
@@ -63,6 +63,89 @@ def test_a_closed_loop_cell_reports_tokens_per_second(bench_copy, cpu_peaks,
     assert set(out["metrics"]) == {"tpot_ms_p90", "stall_ms_p98",
                                    "out_tok_s", "setup_s"}
     assert out["attempted"] >= 4 and out["failed"] == 0 and out["correct"]
+
+
+def add_architecture(root, family="moe_family"):
+    """What a later PR adds for a configuration of another architecture, the
+    family module apart (``benchmark/reference/<family>.py``; here the one
+    the benchmark has): a configuration file that names its family, a cell
+    file, their two BENCHMARK.json entries, and the cell's name appended to
+    the ``workloads`` lists of the metrics it reports. No file that is there
+    is edited. Returns the configuration file's path."""
+    config = json.loads((DATA / "configs" / "tiny.json").read_text())
+    config.update({"name": "tiny-moe", "model_type": "mixtral",
+                   "num_local_experts": 4, "num_experts_per_tok": 2})
+    config["reference"] = {**config["reference"], "family": family}
+    path = root / "benchmark" / "configs" / "tiny-moe.json"
+    path.write_text(json.dumps(config))
+    (root / "benchmark" / "cells" / "tiny-moe-closed.json").write_text(
+        (DATA / "cells" / "tiny-closed.json").read_text())
+    bench_json = root / "BENCHMARK.json"
+    entries = json.loads(bench_json.read_text())
+    entries["configs"].append({
+        "name": "tiny-moe", "source": "benchmark/tests", "reduced": [],
+        "file": "benchmark/configs/tiny-moe.json", "why": "a test"})
+    entries["workloads"].append({
+        "name": "tiny-moe-closed", "config": "tiny-moe",
+        "traffic": "tiny-closed", "chips": 1, "why": "a test"})
+    for metric in entries["end_to_end"] + entries["per_layer"]:
+        if "tiny-closed" in metric.get("workloads", ()):
+            metric["workloads"].append("tiny-moe-closed")
+    bench_json.write_text(json.dumps(entries, indent=1))
+    return path
+
+
+def test_an_added_architecture_runs_by_files_alone(bench_copy, cpu_peaks,
+                                                   capsys):
+    """A sparse-expert configuration (4 experts, top-2: a block no other
+    configuration of the benchmark has) runs by name, is checked against ITS
+    family's reference and is counted by ITS family's arithmetic."""
+    add_architecture(bench_copy)
+    cell = spec.load_cell("tiny-moe-closed", bench_copy)
+    assert cell.family_file == (bench_copy / "benchmark" / "reference"
+                                / "moe_family.py")
+    rc = bench.main(["--workload", "tiny-moe-closed", "--seed", "5",
+                     "--seconds", "3", "--trace", "0"], platform="cpu",
+                    root=bench_copy)
+    assert rc == 0
+    out = result_line(capsys)
+    assert out["correct"] is True and out["failed"] == 0
+    assert set(out["metrics"]) == {"tpot_ms_p90", "stall_ms_p98",
+                                   "out_tok_s", "setup_s"}
+    check = json.loads(next((bench_copy / "benchmark" / ".run"
+                             / "tiny-moe-closed").glob("raw-*.json"))
+                       .read_text())["check"]
+    # the served model is the model described: four experts a layer and a
+    # router, 287552 weights, where the dense tiny configuration holds 139584
+    assert check["params_served"] == check["params_described"] == 287552
+    assert check["params_served"] == cell.family.param_count(cell.published)
+    dense = spec.load_cell("tiny-closed", bench_copy)
+    assert dense.family.param_count(dense.published) == 139584
+    assert check["positions"] == 64 and check["ok"] is True
+
+
+def test_a_family_that_is_missing_or_incomplete_is_an_error(bench_copy,
+                                                            capsys):
+    config = add_architecture(bench_copy, family="no_such_family")
+    with pytest.raises(spec.SpecError, match="reference.family "
+                       "'no_such_family' names no file"):
+        spec.load_cell("tiny-moe-closed", bench_copy)
+    # a module with the mathematics and without the arithmetic
+    (bench_copy / "benchmark" / "reference" / "half_family.py").write_text(
+        "from reference.llama_family import (decoder_layer, logits,\n"
+        "                                    rope_tables)\n")
+    config.write_text(json.dumps({**json.loads(config.read_text()),
+                                  "reference": {"epsilon": 0.06,
+                                                "family": "half_family"}}))
+    with pytest.raises(spec.SpecError) as e:
+        spec.load_cell("tiny-moe-closed", bench_copy)
+    assert "half_family.py" in str(e.value)
+    assert "param_count" in str(e.value) and "step_params" in str(e.value)
+    assert "decoder_layer" not in str(e.value).split("lacks")[1]
+    # and the run says so, with no result line
+    assert bench.main(["--workload", "tiny-moe-closed"], platform="cpu",
+                      root=bench_copy) != 0
+    assert "{" not in capsys.readouterr().out
 
 
 def hand_made_trace(monkeypatch, path, ops, chips=1):

@@ -6,10 +6,15 @@ weights that differ from the served ones shows a mean shortfall far above the
 epsilon a configuration file allows (the control that is a LOWER PRECISION,
 int8 activations, fails on the chip at the published widths and not here: at
 test widths it flips no more near-ties than weight-only int8 does, PERF.md
-section 2). Both at two tiny shapes: the 7B's (one device, head_dim =
-hidden_size / heads) and the 24B's (an explicit head_dim that is not
+section 2). Both at three tiny shapes: the 7B's (one device, head_dim =
+hidden_size / heads), the 24B's (an explicit head_dim that is not
 hidden_size / heads, served tensor-parallel over four devices: conftest.py
-asks the CPU backend for eight)."""
+asks the CPU backend for eight), both against the default family, and a
+Mixtral-style sparse-expert block (4 experts, top-2) against
+``reference/moe_family.py``: the plug for an architecture, proven by one the
+program serves. That case has a second failing control, the one ISSUE 29 has
+to turn green for OLMoE: the same served tokens judged under
+``norm_topk_prob: false``, which the program does not read."""
 
 import dataclasses
 import json
@@ -20,7 +25,7 @@ import pytest
 
 import run as bench
 from conftest import BENCH
-from harness import refcheck
+from harness import refcheck, spec
 
 # what the landed configurations allow on the chip, each by the statistic its
 # file names (run.py ``judge``); here compute is float32
@@ -35,21 +40,40 @@ HF = {"vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
 # head_dim 16 where hidden_size / heads is 8; 4 kv heads, one a device
 HF_TP4 = {**HF, "num_attention_heads": 8, "num_key_value_heads": 4,
           "head_dim": 16}
+# Mixtral's keys: the expert count as ``num_local_experts``, no
+# ``norm_topk_prob`` (always renormalised)
+HF_MOE = {**HF, "model_type": "mixtral", "num_local_experts": 4,
+          "num_experts_per_tok": 2}
 LETTERS = {i: 100.0 for i in range(ord("a"), ord("z") + 1)}
+# (published keys, devices, family, new tokens a probe). The sparse-expert
+# case serves 16 new tokens a probe, 32 positions, and its experts' up and
+# down projections are scaled 8x: at the generator's 0.02 the whole block
+# moves a logit by less than most margins, and halving it (the second
+# control) flipped 1 position of 128
+DENSE, DENSE_TP4, MOE = ((HF, 1, "llama_family", 4),
+                         (HF_TP4, 4, "llama_family", 4),
+                         (HF_MOE, 1, "moe_family", 16))
+EXPERT_GAIN = 8.0
 
 
-@pytest.fixture(scope="module", params=[(HF, 1), (HF_TP4, 4)],
-                ids=["one-device", "head_dim-tp4"])
+@pytest.fixture(scope="module", params=[DENSE, DENSE_TP4, MOE],
+                ids=["one-device", "head_dim-tp4", "sparse-experts"])
 def served(request):
     from localai_tpu.engine.runner import ModelRunner
     from localai_tpu.models.llama import LlamaConfig
     from localai_tpu.models.registry import synthetic_params
 
-    hf, tp = request.param
+    hf, tp, family, n_new = request.param
+    family = spec.load_family(BENCH / "reference" / f"{family}.py")
     # float32 compute: the served path then differs from the reference by
     # summation order only, and every shortfall is ~0
     cfg = dataclasses.replace(LlamaConfig.from_hf(hf), dtype="float32")
     params = synthetic_params(cfg, "int8", seed=0)
+    if "moe_gate" in params["layers"]:
+        for name in ("w_up", "w_down"):
+            leaf = params["layers"][name]
+            params["layers"][name] = dataclasses.replace(
+                leaf, scale=leaf.scale * EXPERT_GAIN)
     mesh = None
     if tp > 1:
         from localai_tpu.parallel import sharding as shd
@@ -70,35 +94,78 @@ def served(request):
         slot = runner.acquire_slot()
         toks = [runner.admit(slot, prompt, temperature=0.0,
                              logit_bias=LETTERS)]
-        for _ in range(3):
+        for _ in range(n_new - 1):
             toks.append(int(runner.step()[slot]))
         runner.release(slot)
         probes.append({"prompt": prompt, "served": toks})
-    return runner.params, hf, probes
+    return runner.params, family, hf, probes
 
 
 def test_served_tokens_rank_first_in_the_reference(served):
-    params, hf, probes = served
-    rows = refcheck.shortfalls(params, hf, probes)
+    params, family, hf, probes = served
+    rows = refcheck.shortfalls(params, family, hf, probes)
     short = [p["shortfall"] for r in rows for p in r]
-    assert len(short) == 8
+    assert len(short) == sum(len(p["served"]) for p in probes) >= 8
     assert all(ord("a") <= t <= ord("z") for p in probes for t in p["served"])
     assert max(short) < 1e-4, short
     assert [bench.judge(short, ref)["statistic"] for ref in LANDED] == [
         "max", "mean"]
     assert all(bench.judge(short, ref)["ok"] for ref in LANDED)
+    # the model served is the model the keys describe, to the last weight
+    # (global shapes where the leaves are sharded)
+    assert refcheck.served_param_count(params) == family.param_count(hf)
 
 
 def test_the_check_fails_on_other_weights(served):
-    params, hf, probes = served
+    params, family, hf, probes = served
     head = params["lm_head"]
     other = {**params, "lm_head": dataclasses.replace(
         head, q=jax.numpy.flip(head.q, axis=0))}
-    short = [p["shortfall"] for r in refcheck.shortfalls(other, hf, probes)
-             for p in r]
+    short = [p["shortfall"] for r in refcheck.shortfalls(
+        other, family, hf, probes) for p in r]
     assert not any(bench.judge(short, ref)["ok"] for ref in LANDED)
     # far over: ten times what the 24B's file allows the mean
     assert sum(short) / len(short) > 10 * LANDED[1]["epsilon"]
+
+
+@pytest.mark.parametrize("served", [MOE], indirect=True,
+                         ids=["sparse-experts"])
+def test_the_check_fails_where_the_file_says_not_to_renormalise(served):
+    """The same served tokens, judged as OLMoE's file would have them
+    (``norm_topk_prob: false``): the program renormalises the top-k whatever
+    the file says (``models/llama.py _moe_mlp``), so the check must FAIL. Of
+    the 32 positions 9 flipped when this was written (mean shortfall 0.018,
+    largest 0.14): over both landed limits. This is the control ISSUE 29 has
+    to turn green, by a program that reads the key."""
+    params, family, hf, probes = served
+    as_olmoe = {**hf, "norm_topk_prob": False}
+    short = [p["shortfall"] for r in refcheck.shortfalls(
+        params, family, as_olmoe, probes) for p in r]
+    assert len(short) == 32 and sum(s > 0 for s in short) >= 5, short
+    assert not any(bench.judge(short, ref)["ok"] for ref in LANDED)
+
+
+def test_a_file_served_as_another_model_is_caught_by_the_count():
+    """OLMoE publishes its expert count as ``num_experts``; ``from_hf`` reads
+    ``num_local_experts`` alone, so today the file would be served, silently,
+    as a DENSE model one expert wide. The set-up's parameter check
+    (run.py ``reference_check``) is what says so."""
+    from localai_tpu.models.llama import LlamaConfig, param_shapes
+
+    family = spec.load_family(BENCH / "reference" / "moe_family.py")
+    as_olmoe = {k: v for k, v in HF_MOE.items() if k != "num_local_experts"}
+    as_olmoe["num_experts"] = 4
+    assert family.param_count(as_olmoe) == family.param_count(HF_MOE)
+    shapes = param_shapes(LlamaConfig.from_hf(as_olmoe))
+    held = sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)))
+    assert "moe_gate" not in shapes["layers"]
+    assert held != family.param_count(as_olmoe)
+    # and Mixtral's own name is served as described
+    shapes = param_shapes(LlamaConfig.from_hf(HF_MOE))
+    assert sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))) == (
+        family.param_count(HF_MOE))
 
 
 def test_the_verdict_is_on_the_statistic_the_configuration_names():
