@@ -120,8 +120,10 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
     when the resolved impl is the compiled kernel and the shape cannot take
     it.
 
-    The Pallas paged kernel DMAs one [block_tokens, head_dim] physical
-    block per online-softmax step, so on hardware it needs Mosaic-tileable
+    The Pallas paged kernel DMAs a table entry's [kv_heads, block_tokens,
+    head_dim] pool row, every local kv head in one copy, into a block_tokens
+    slice of its step's [kv_heads, P·block_tokens, head_dim] buffer (several
+    entries an online-softmax step), so on hardware it needs Mosaic-tileable
     blocks: head_dim 128-aligned and block_tokens a multiple of 32 (the
     int8 sublane tile; every such size is compiled for v5e in the tests).
     int4 pools are nibble-packed along head_dim, so their DMA'd last dim is

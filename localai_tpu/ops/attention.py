@@ -10,7 +10,8 @@ O(block_k · hd) regardless of context length, and only blocks inside the
 
 Replaces (TPU-era) the reference's per-slot CPU attention inside llama.cpp's
 ``llama_decode`` hot loop (/root/reference/backend/cpp/llama/
-grpc-server.cpp:1546-1990). Two shapes of the same kernel:
+grpc-server.cpp:1546-1990). Two shapes of one kernel over contiguous K/V,
+and the serving path's own over the block pool:
 
   * ``decode_attention`` — q is one token per slot, KV is the slot cache
     head-major and stacked over layers [L, S, Hkv, C, hd], read at a layer
@@ -21,10 +22,20 @@ grpc-server.cpp:1546-1990). Two shapes of the same kernel:
   * ``prefill_attention`` — single-sequence causal attention [T, ...];
     grid (Hkv, T/block_q); rows are (q-position × group) pairs; KV blocks
     beyond the causal frontier or the real prompt length are not fetched.
+  * ``paged_decode_attention`` — q is one token per slot, KV is the block
+    pool [L, N, Hkv, bt, hd] read through per-slot block tables; grid (S,):
+    ONE program a slot holds every local kv head. A table entry's pool row
+    [Hkv, bt, hd] is contiguous and moves in one copy; a step of the walk
+    starts the copies of several entries together and folds them as one
+    [Hkv, P·bt, hd] online-softmax tile (both matmuls batched over the head
+    axis), ``num_buffers`` steps are in flight, and a slot's last step
+    starts the next slot's first, so only a call's first program waits on
+    a copy nothing hides. P comes from the pool's shape and a VMEM budget
+    (``paged_decode_tiling``).
 
-Both run under ``interpret=True`` on CPU for tests (tests/test_ops.py) and
-compile to Mosaic on real TPU. Sliding-window (Mistral) masking is supported
-statically.
+All run under ``interpret=True`` on CPU for tests (tests/test_ops.py,
+tests/test_paged.py) and compile to Mosaic on real TPU. Sliding-window
+(Mistral) masking is supported statically.
 """
 
 from __future__ import annotations
@@ -80,12 +91,10 @@ def _scale_rows(ks_ref, vs_ref):
 
 
 def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
-                mask_for_block, scales=None, depth: int = 2,
-                unpack: bool = False):
-    """Online-softmax loop over KV blocks [lo, nb) with ``depth``-deep
-    double-buffered DMA (depth 2 = classic ping-pong; 3 keeps one extra
-    block in flight for gather-latency-bound paged pools — autotunable via
-    ops.tuning).
+                mask_for_block, scales=None):
+    """Online-softmax loop over KV blocks [lo, nb) of a contiguous K/V with
+    ping-pong double-buffered DMA (the contiguous decode kernel and the
+    prefill kernel; the paged decode kernel has a loop of its own).
 
     q: [rows, hd] f32 (pre-scaled). ``kv_slice(hbm_ref, i)`` yields the
     [block_k, hd] HBM slice for block i; ``mask_for_block(i)`` the
@@ -100,11 +109,6 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
     scales over the probability columns (p@(v·s) = (p·s)@v), so both apply
     as [1, block_k] row multiplies on the VPU while the MXU matmuls stay
     int8-sourced.
-
-    ``unpack=True`` fuses int4 KV dequantization: the buffered blocks are
-    nibble-packed int8 ([block_k, hd/2], models.quant.quantize_lastdim4)
-    and unpack in VMEM right after the DMA wait — HALF the int8 path's
-    HBM bytes moved per block, with the same per-position scale fusion.
     """
     k_hbm, v_hbm = kv_slice
     rows, hd = q.shape
@@ -119,29 +123,19 @@ def _flash_loop(q, kv_slice, kbuf, vbuf, ksem, vsem, lo, nb, block_k,
         pltpu.make_async_copy(k_hbm(i), kbuf.at[slot], ksem.at[slot]).wait()
         pltpu.make_async_copy(v_hbm(i), vbuf.at[slot], vsem.at[slot]).wait()
 
-    # prime the pipeline: depth-1 blocks in flight before the first fold
-    # (the loop body keeps exactly depth-1 ahead of the block in hand)
-    start(lo, 0)
-    for j in range(1, depth - 1):
-        @pl.when(lo + j < nb)
-        def _prime(j=j):
-            start(lo + j, j)
+    start(lo, 0)    # one block in flight before the first fold
 
     def body(i, carry):
         m, l, acc = carry
-        slot = lax.rem(i - lo, depth)
+        slot = lax.rem(i - lo, 2)
 
-        @pl.when(i + depth - 1 < nb)
+        @pl.when(i + 1 < nb)
         def _prefetch():
-            start(i + depth - 1, lax.rem(i + depth - 1 - lo, depth))
+            start(i + 1, 1 - slot)
 
         wait(i, slot)
-        if unpack:
-            k = _unpack_nibbles(kbuf[slot], jnp.float32)
-            v = _unpack_nibbles(vbuf[slot], jnp.float32)
-        else:
-            k = kbuf[slot].astype(jnp.float32)
-            v = vbuf[slot].astype(jnp.float32)
+        k = kbuf[slot].astype(jnp.float32)
+        v = vbuf[slot].astype(jnp.float32)
         s = q @ k.T  # [rows, block_k] — MXU
         if scales is not None:
             s = s * ks_block(i)
@@ -410,53 +404,181 @@ def gather_block_scales(scales: jax.Array, tables: jax.Array,
     return g.transpose(0, 2, 1, 3).reshape(S, H, MB * bt)
 
 
+# K and V bytes the paged decode kernel keeps in VMEM for its copies: every
+# buffer of the ring, every local kv head. A step's tile is cut to it, so a
+# step holds the same few hundred KiB in flight whatever the model's local
+# head count, block size or element size, and a deeper ring means smaller
+# steps, not more VMEM (1 MiB: two steps of two 128 KiB rows at Mistral-7B's
+# 8 kv heads, two steps of eight 32 KiB rows at a chip's 2 of the 24B's)
+_PAGED_KV_VMEM_BYTES = 1 << 20
+# ... and to this many table entries, each a copy of its own that the scalar
+# core issues and waits for in unrolled code (on the chip, PR 29: a chip's 2
+# heads of the 24B ran fastest at 8 entries a step, 16 lost 17%)
+_PAGED_STEP_BLOCKS_MAX = 8
+
+
+def paged_decode_tiling(kv_heads: int, block_tokens: int, row_lanes: int,
+                        itemsize: int, max_blocks: int,
+                        num_buffers: int = 2) -> tuple[int, int, int]:
+    """(blocks a step, steps in flight, VMEM bytes of the K/V ring) the
+    paged decode kernel derives from the pool it is handed: ``kv_heads``
+    local heads of ``row_lanes`` stored elements (hd, or hd/2 packed) of
+    ``itemsize`` bytes, tables ``max_blocks`` wide. A step is the largest
+    power of two of table entries, at most ``_PAGED_STEP_BLOCKS_MAX``, whose
+    K and V rows, ``num_buffers`` steps deep, fit ``_PAGED_KV_VMEM_BYTES``;
+    never under one block (a pool whose single row is over the budget gets
+    one-block steps)."""
+    depth = max(2, int(num_buffers))
+    row = 2 * kv_heads * block_tokens * row_lanes * itemsize  # K + V
+    blocks = max(1, min(_PAGED_KV_VMEM_BYTES // (depth * row),
+                        _PAGED_STEP_BLOCKS_MAX, max_blocks))
+    blocks = 1 << (blocks.bit_length() - 1)
+    return blocks, depth, depth * blocks * row
+
+
 def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
-                         *rest, block_tokens: int, sm_scale: float,
-                         sliding_window: Optional[int], quantized: bool,
-                         int4: bool, num_buffers: int):
-    # k_ref/v_ref are the FULL stacked [L, N, Hkv, bt, hd] block pool in
-    # HBM; the block walked at loop step i is tbl_ref[slot, i] (SMEM block
-    # table) of layer layer_ref[0] (SMEM scalar), so the DMA gathers
-    # physically-scattered blocks in logical order straight from the stack.
-    # Scales for int8/int4 pools arrive already gathered in logical order,
-    # [MB, bt] f32 per (slot, head), VMEM-resident through their BlockSpec:
-    # a [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
-    # tile at every block size below 128, so it cannot ride the K/V DMA.
-    # int4 pools arrive nibble-packed [L, N, Hkv, bt, hd/2] and unpack in
-    # VMEM after the DMA wait — half the int8 path's bytes per block.
+                         *rest, block_tokens: int, blocks: int, depth: int,
+                         sm_scale: float, sliding_window: Optional[int],
+                         quantized: bool, int4: bool, mm_dtype):
+    # One program a slot, every local kv head in it. k_ref/v_ref are the
+    # FULL stacked [L, N, Hkv, bt, hd] block pool in HBM; a table entry's
+    # row pool[layer, tbl[s, i]] is one contiguous [Hkv, bt, hd] slab and
+    # moves in ONE copy. A step of the walk covers ``blocks`` consecutive
+    # table entries [t*blocks, (t+1)*blocks): their copies start together,
+    # land side by side in a [Hkv, blocks*bt, hd] buffer of a ``depth``-deep
+    # ring and fold as one online-softmax tile; entries outside [lo, nb)
+    # are not copied (their columns are masked, over whatever the buffer
+    # held). Before a slot's last fold the NEXT slot's first step is started
+    # into the free buffer, so only the call's first program waits on a
+    # copy nothing hides; the ring position rides SMEM scratch across grid
+    # steps (the grid is sequential: 'arbitrary').
+    # Scales of int8/int4 pools arrive gathered in logical order, one
+    # [Hkv, steps, blocks*bt] f32 slab a slot through their BlockSpec (a
+    # [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
+    # tile): step t's scales are row t. int4 pools arrive nibble-packed
+    # [L, N, Hkv, bt, hd/2] and unpack in VMEM after the wait.
     if quantized:
-        ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem, ring_ref = rest
     else:
-        o_ref, kbuf, vbuf, ksem, vsem = rest
+        o_ref, kbuf, vbuf, ksem, vsem, ring_ref = rest
     s_idx = pl.program_id(0)
-    h_idx = pl.program_id(1)
+    n_slots = pl.num_programs(0)
     layer = layer_ref[0]
-    pos = pos_ref[s_idx]
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [g, hd]
-    bt = block_tokens
+    bt, P = block_tokens, blocks
+    T = P * bt
 
-    nb = jnp.minimum(pos // bt + 1, tbl_ref.shape[1])
-    lo = jnp.int32(0)
-    if sliding_window is not None:
-        lo = jnp.maximum((pos - sliding_window + 1) // bt, 0)
+    def walk(slot):
+        """(pos, lo, nb, first step, steps) of ``slot``'s walk."""
+        pos = pos_ref[slot]
+        nb = jnp.minimum(pos // bt + 1, tbl_ref.shape[1])
+        lo = jnp.int32(0)
+        if sliding_window is not None:
+            lo = jnp.clip((pos - sliding_window + 1) // bt, 0, nb - 1)
+        t0 = lo // P
+        return pos, lo, nb, t0, (nb + P - 1) // P - t0
 
-    def slice_of(ref):
-        return lambda i: ref.at[layer, tbl_ref[s_idx, i], h_idx]
+    def k_copy(row, into, buf):
+        return pltpu.make_async_copy(k_ref.at[layer, row], kbuf.at[into],
+                                     ksem.at[buf])
 
-    def mask_for_block(i):
-        idx = i * bt + lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    def v_copy(row, into, buf):
+        return pltpu.make_async_copy(v_ref.at[layer, row], vbuf.at[into],
+                                     vsem.at[buf])
+
+    def entries(slot, lo, nb, t, buf, do):
+        """``do(row, into, buf)`` for each table entry of step ``t`` that
+        the walk [lo, nb) holds: its pool row, its place in the buffer."""
+        for j in range(P):
+            blk = t * P + j
+
+            @pl.when((blk >= lo) & (blk < nb))
+            def _(j=j, blk=blk):
+                do(tbl_ref[slot, blk],
+                   (buf, slice(None), pl.ds(j * bt, bt), slice(None)), buf)
+
+    def start(slot, lo, nb, t, buf):
+        entries(slot, lo, nb, t, buf,
+                lambda *c: (k_copy(*c).start(), v_copy(*c).start()))
+
+    pos, lo, nb, t0, steps = walk(s_idx)
+
+    @pl.when(s_idx == 0)
+    def _cold():
+        # skipped entries leave a buffer's columns as they were: masked to
+        # probability 0, which only a finite value multiplies to 0
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        ring_ref[0] = 0
+        start(s_idx, lo, nb, t0, 0)
+
+    base = ring_ref[0]          # the buffer this slot's first step is in
+    for j in range(1, depth - 1):
+        @pl.when(j < steps)
+        def _prime(j=j):
+            start(s_idx, lo, nb, t0 + j, lax.rem(base + j, depth))
+
+    q = q_ref[0].astype(mm_dtype)                     # [Hkv, g, hd]
+    Hkv, g, hd = q.shape
+
+    def fold(i, carry):
+        m, l, acc = carry
+        t = t0 + i
+        buf = lax.rem(base + i, depth)
+        entries(s_idx, lo, nb, t, buf, lambda *c: k_copy(*c).wait())
+        if int4:
+            k = _unpack_nibbles(kbuf[buf], jnp.float32)
+        else:
+            k = kbuf[buf]
+        s = jnp.einsum("hgd,htd->hgt", q, k.astype(mm_dtype),
+                       preferred_element_type=jnp.float32) * sm_scale
+        if quantized:
+            s = s * ks_ref[0, :, pl.ds(t, 1), :]
+        idx = t * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
         keep = idx <= pos
         if sliding_window is not None:
             keep &= idx > pos - sliding_window
-        return keep
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        # denominator sums the raw probabilities; V scales touch only the
+        # weighted-value numerator
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, :, pl.ds(t, 1), :]
+        entries(s_idx, lo, nb, t, buf, lambda *c: v_copy(*c).wait())
+        if int4:
+            v = _unpack_nibbles(vbuf[buf], jnp.float32)
+        else:
+            v = vbuf[buf].astype(jnp.float32)
+        acc_new = acc * alpha + jnp.einsum(
+            "hgt,htd->hgd", p, v, preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
 
-    scales = None
-    if quantized:
-        scales = _scale_rows(ks_ref, vs_ref)
-    out = _flash_loop(q, (slice_of(k_ref), slice_of(v_ref)),
-                      kbuf, vbuf, ksem, vsem, lo, nb, bt, mask_for_block,
-                      scales=scales, depth=num_buffers, unpack=int4)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    def body(i, carry):
+        @pl.when(i + depth - 1 < steps)
+        def _prefetch():
+            start(s_idx, lo, nb, t0 + i + depth - 1,
+                  lax.rem(base + i + depth - 1, depth))
+        return fold(i, carry)
+
+    m0 = jnp.full((Hkv, g, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((Hkv, g, 1), jnp.float32)
+    acc0 = jnp.zeros((Hkv, g, hd), jnp.float32)
+    carry = lax.fori_loop(0, steps - 1, body, (m0, l0, acc0))
+
+    # the last fold: its buffer's successor is free (every step before it
+    # has been folded), so the next slot's first copies go there now
+    after = lax.rem(base + steps, depth)
+
+    @pl.when(s_idx + 1 < n_slots)
+    def _next_slot():
+        _, lo_n, nb_n, t0_n, _ = walk(s_idx + 1)
+        start(s_idx + 1, lo_n, nb_n, t0_n, after)
+
+    ring_ref[0] = after
+    _, l, acc = fold(steps - 1, carry)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -475,10 +597,14 @@ def paged_decode_attention(
     num_buffers: int = 2,
 ) -> jax.Array:
     """Flash GQA decode attention over one layer of the stacked paged
-    block pool. Returns [S, Hq, hd]. The kernel walks each slot's block
-    table in SMEM and DMAs one [bt, hd] physical block per online-softmax
-    step — identical math to ``decode_attention``, with the contiguous
-    slot row replaced by gather-over-block-table.
+    block pool. Returns [S, Hq, hd]. One program a slot walks the slot's
+    block table in SMEM; a table entry's row of the pool, every (local) kv
+    head of it, is one contiguous copy, several entries are in flight a
+    step and fold as one online-softmax tile, ``num_buffers`` steps are in
+    flight, and a slot's last step starts the next slot's first
+    (``paged_decode_tiling`` derives the step from the pool's shape) —
+    identical math to ``decode_attention``, with the contiguous slot row
+    replaced by gather-over-block-table.
 
     The pool arrives WHOLE, all layers, and ``layer`` picks the layer in
     the DMA slice: inside the layer scan (models.llama.forward) the pool
@@ -489,10 +615,11 @@ def paged_decode_attention(
 
     Under a mesh the runner wraps this in ``shard_map`` with slots (q,
     tables, positions) on 'data' and head groups (q, pool) on 'model':
-    the body is then the per-device single-chip kernel, so the pool's
-    layer and block axes must arrive WHOLE on every device (table values
-    are global physical block ids) and both head counts must divide the
-    'model' width (``ops.select_paged_attn_impl`` gates that)."""
+    the body is then the per-device single-chip kernel over the LOCAL kv
+    heads (read from the pool's shape), so the pool's layer and block axes
+    must arrive WHOLE on every device (table values are global physical
+    block ids) and both head counts must divide the 'model' width
+    (``ops.select_paged_attn_impl`` gates that)."""
     S, Hq, hd = q.shape
     Hkv, bt = k_cache.shape[2], k_cache.shape[3]
     MB = tables.shape[1]
@@ -501,50 +628,60 @@ def paged_decode_attention(
     quantized = k_scale is not None
     # an int4 pool is self-describing: its last dim is the packed hd/2
     int4 = quantized and k_cache.shape[-1] * 2 == hd
-    depth = max(2, int(num_buffers))
-
+    P, depth, _ = paged_decode_tiling(
+        Hkv, bt, k_cache.shape[-1], k_cache.dtype.itemsize, MB, num_buffers)
+    # K is bf16 (or small integers) in HBM and q bf16 from the model: their
+    # products are exact in float32, so the score matmul takes them as they
+    # are and accumulates in float32; any other pairing multiplies in f32
+    exact = (q.dtype == jnp.bfloat16
+             and k_cache.dtype in (jnp.bfloat16, jnp.int8))
     kernel = functools.partial(
-        _paged_decode_kernel, block_tokens=bt, sm_scale=hd ** -0.5,
-        sliding_window=sliding_window, quantized=quantized,
-        int4=int4, num_buffers=depth,
+        _paged_decode_kernel, block_tokens=bt, blocks=P, depth=depth,
+        sm_scale=hd ** -0.5, sliding_window=sliding_window,
+        quantized=quantized, int4=int4,
+        mm_dtype=jnp.bfloat16 if exact else jnp.float32,
     )
     in_specs = [
-        pl.BlockSpec((1,), lambda s, h: (0,), memory_space=pltpu.SMEM),
-        pl.BlockSpec((S,), lambda s, h: (0,), memory_space=pltpu.SMEM),
-        pl.BlockSpec((S, MB), lambda s, h: (0, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),
-        # the pool stays whole in HBM; blocks are gathered by table DMA
+        pl.BlockSpec((1,), lambda s: (0,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((S,), lambda s: (0,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((S, MB), lambda s: (0, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, Hkv, g, hd), lambda s: (s, 0, 0, 0)),
+        # the pool stays whole in HBM; rows are gathered by table DMA
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
     args = [layer, positions.astype(jnp.int32), tables.astype(jnp.int32), qg,
             k_cache, v_cache]
-    scratch = [
-        # int4 pools buffer the packed [bt, hd/2] bytes — unpack happens
-        # after the DMA wait, so the scratch mirrors the pool's last dim
-        pltpu.VMEM((depth, bt, k_cache.shape[-1]), k_cache.dtype),
-        pltpu.VMEM((depth, bt, v_cache.shape[-1]), v_cache.dtype),
-    ]
     if quantized:
         # gather each slot's scale rows in XLA (small: S·MB·Hkv·bt f32),
-        # layer and blocks in one gather, and hand the kernel one [MB, bt]
-        # slab per (slot, head) grid step
-        spec = pl.BlockSpec((1, 1, MB, bt), lambda s, h: (s, h, 0, 0))
+        # layer and blocks in one gather, padded to whole steps: the kernel
+        # gets one [Hkv, steps, P*bt] slab a slot
+        steps = -(-MB // P)
+        padded = jnp.pad(tables, ((0, 0), (0, steps * P - MB)))
+        spec = pl.BlockSpec((1, Hkv, steps, P * bt), lambda s: (s, 0, 0, 0))
         in_specs += [spec, spec]
-        args += [gather_block_scales(k_scale, tables, layer[0]).reshape(
-                     S, Hkv, MB, bt),
-                 gather_block_scales(v_scale, tables, layer[0]).reshape(
-                     S, Hkv, MB, bt)]
-    scratch += [pltpu.SemaphoreType.DMA((depth,))] * 2
+        args += [gather_block_scales(sc, padded, layer[0]).reshape(
+                     S, Hkv, steps, P * bt) for sc in (k_scale, v_scale)]
+    # int4 pools buffer the packed [.., hd/2] bytes (unpack happens after
+    # the wait), so the ring mirrors the pool's last dim
+    ring = (depth, Hkv, P * bt, k_cache.shape[-1])
     out = pl.pallas_call(
         kernel,
         name="paged_decode_attn",
-        grid=(S, Hkv),
+        grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda s, h: (s, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, g, hd), lambda s: (s, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, Hkv, g, hd), q.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM(ring, k_cache.dtype),
+            pltpu.VMEM(ring, v_cache.dtype),
+            pltpu.SemaphoreType.DMA((depth,)),
+            pltpu.SemaphoreType.DMA((depth,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
     return out.reshape(S, Hq, hd)

@@ -63,7 +63,7 @@ class TuneEntry:
 
     impl: str = ""          # "pallas" | "xla" | "" (auto)
     block_tokens: int = 0   # pool block size; 0 = LOCALAI_KV_BLOCK_TOKENS
-    num_buffers: int = 0    # flash-loop DMA depth; 0 = 2 (ping-pong)
+    num_buffers: int = 0    # paged kernel: steps in flight; 0 = 2
     us: float = 0.0         # best measured microseconds per dispatch
 
     @staticmethod

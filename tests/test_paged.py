@@ -187,6 +187,95 @@ def test_paged_attention_matches_contiguous_two_lengths(in_stack):
     assert float(jnp.max(jnp.abs(out_pallas - ref_contig))) <= 1e-2
 
 
+def _kernel_cases():
+    """Every local head count against every pool, each with the cells'
+    group and block (g 4, bt 64) and with the other pair under a sliding
+    window, compared exactly (float32 q); the two pairs the other way
+    round; then the cells' own pairing, bf16 q against a bf16 or integer
+    pool, whose score matmul takes the operands as stored."""
+    pools = ("bfloat16", "int8", "int4")
+    cases = [(hkv, *rest, "float32") for hkv in (1, 2, 8) for kv in pools
+             for rest in ((4, 64, kv, False), (1, 32, kv, True))]
+    cases += [(8, 4, 32, "bfloat16", True, "float32"),
+              (8, 1, 64, "bfloat16", False, "float32"),
+              (2, 4, 32, "int8", True, "float32"),
+              (2, 1, 64, "int8", False, "float32")]
+    cases += [(8, 4, 64, "bfloat16", False, "bfloat16"),
+              (2, 4, 64, "bfloat16", True, "bfloat16"),
+              (8, 4, 64, "int8", True, "bfloat16"),
+              (2, 4, 64, "int8", False, "bfloat16")]
+    return cases
+
+
+@pytest.mark.parametrize("hkv,g,bt,kv_dtype,windowed,q_dtype",
+                         _kernel_cases())
+def test_paged_kernel_matches_reference(hkv, g, bt, kv_dtype, windowed,
+                                        q_dtype, in_stack):
+    """The kernel (interpret mode) against ``paged_decode_attention_ref``
+    at real head width, so that the step it derives (P table entries, from
+    the local head count, the block and the element size) is the served
+    one: frontiers on both sides of a block's and of a step's last row and
+    on the table's last, ragged slots, empty slots on the trash block
+    between live ones (what the cross-slot prefetch walks into), and a
+    call of one slot."""
+    from localai_tpu.models.quant import quantize_lastdim, quantize_lastdim4
+    from localai_tpu.ops.attention import paged_decode_tiling
+
+    hd = 128
+    rng = np.random.default_rng(hkv * 1000 + g * 100 + bt)
+    pool_dt = {"bfloat16": jnp.bfloat16}.get(kv_dtype, jnp.int8)
+    lanes = hd // 2 if kv_dtype == "int4" else hd
+    # the table is wider than two steps and ends inside one
+    P, _, _ = paged_decode_tiling(hkv, bt, lanes,
+                                  jnp.dtype(pool_dt).itemsize, 1 << 20)
+    MB = 2 * P + 1
+    edges = [0, bt - 1, None, bt, P * bt - 1, None, P * bt, MB * bt - 1,
+             (MB * bt) // 2 + 7]
+    positions = np.asarray([p or 0 for p in edges], np.int32)
+    need = [0 if p is None else p // bt + 1 for p in edges]
+    N = sum(need) + 1
+    free = list(rng.permutation(np.arange(1, N)))
+    tables = np.zeros((len(edges), MB), np.int32)   # trash-padded
+    for s, n in enumerate(need):
+        tables[s, :n] = [free.pop() for _ in range(n)]
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+    window = bt + 5 if windowed else None   # a walk that starts mid-step
+
+    q = jnp.asarray(rng.normal(size=(len(edges), hkv * g, hd)),
+                    jnp.dtype(q_dtype))
+    kf, vf = (jnp.asarray(rng.normal(size=(N, hkv, bt, hd)), jnp.float32)
+              for _ in range(2))
+    scales = ()
+    if kv_dtype == "bfloat16":
+        k, v = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
+    else:
+        quant = quantize_lastdim4 if kv_dtype == "int4" else quantize_lastdim
+        (k, ks), (v, vs) = quant(kf), quant(vf)
+        scales = (ks, vs)
+
+    ref = ops.paged_decode_attention_ref(
+        q, k, v, tables, positions, *scales, sliding_window=window)
+    pool, scales = ([in_stack(a) for a in xs] for xs in ((k, v), scales))
+    out = ops.paged_decode_attention(
+        q, *pool, jnp.int32(1), tables, positions, *scales,
+        sliding_window=window, interpret=True)
+    # float32 q: the same float32 arithmetic in another order; bf16 q: the
+    # result is rounded to bf16 on both sides
+    tol = 2e-5 if q_dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    if windowed:
+        return
+    one = slice(7, 8)          # S = 1: the first program is the last
+    out1 = ops.paged_decode_attention(
+        q[one], *pool, jnp.int32(1), tables[one], positions[one], *scales,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(out1, np.float32),
+                               np.asarray(ref[one], np.float32),
+                               rtol=tol, atol=tol)
+
+
 def test_paged_runner_matches_contiguous_greedy():
     """End-to-end engine parity: same weights, two prompts of different
     lengths sharing the paged pool — greedy decode must match the

@@ -162,19 +162,55 @@ def test_paged_kernel_compiles_under_shard_map_tp4(topo, kv_dtype):
     assert ops.select_paged_attn_impl(
         "auto", num_heads=HQ, num_kv_heads=HKV, head_dim=HD, block_tokens=64,
         tp=4, kv_dtype=kv_dtype, backend="tpu") == ("pallas", False)
+    compile_under_shard_map_tp4(topo, paged_args(kv_dtype, 64, HQ, HKV, HD))
+
+
+def compile_under_shard_map_tp4(topo, args):
+    """``ops.paged_decode_attention`` over ``args`` ((shape, dtype) pairs,
+    scales last if any) as the meshed runner wraps it, compiled for a
+    1 x 4 mesh of the topology's chips."""
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
     specs = [P("data", "model", None), P(None, None, "model", None, None),
              P(None, None, "model", None, None), P(), P("data", None),
-             P("data")]
-    if kv_dtype == "int8":
-        specs += [P(None, None, "model", None)] * 2
+             P("data")] + [P(None, None, "model", None)] * (len(args) - 6)
     kernel = jax.shard_map(
         ops.paged_decode_attention, mesh=mesh, in_specs=tuple(specs),
         out_specs=P("data", "model", None), check_vma=False)
-    avals = [jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, sp))
-             for (shape, dt), sp in zip(
-                 paged_args(kv_dtype, 64, HQ, HKV, HD), specs)]
-    compile_for(topo, kernel, *avals)
+    return compile_for(topo, kernel, *[
+        jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, sp))
+        for (shape, dt), sp in zip(args, specs)])
+
+
+@pytest.mark.parametrize("cell_shape", [
+    # S, kv heads on a chip, table width, pool blocks, layers, tp
+    ("m7b", 16, 8, 64, 289, 32, 1),
+    ("ms24b-tp4", 32, 2, 128, 641, 40, 4)])
+def test_paged_kernel_at_the_cells_shapes(topo, cell_shape):
+    """The two served shapes of the kernel (benchmark/configs: Mistral-7B on
+    one chip, every one of its 8 kv heads in a program; Mistral-Small-24B
+    at tp=4 under ``shard_map``, a chip's 2): the step it derives is the
+    one PERF.md's numbers were read at, its K/V ring is inside the budget
+    the derivation cuts it to, and Mosaic takes it."""
+    from localai_tpu.ops import attention as att
+
+    _, S, hkv, MB, N, L, tp = cell_shape
+    blocks, depth, ring = att.paged_decode_tiling(hkv, 64, HD, 2, MB)
+    assert (blocks, depth) == ({8: 2, 2: 8}[hkv], 2)
+    assert ring == depth * blocks * 2 * hkv * 64 * HD * 2
+    assert ring <= att._PAGED_KV_VMEM_BYTES
+    # a third step in flight shrinks the step, not the budget
+    assert att.paged_decode_tiling(hkv, 64, HD, 2, MB, 3)[2] <= ring
+    pool = ((L, N, hkv * tp, 64, HD), bf16)
+    args = [((S, 4 * hkv * tp, HD), bf16), pool, pool, ((), i32),
+            ((S, MB), i32), ((S,), i32)]
+    if tp == 1:
+        text = compile_for(topo, ops.paged_decode_attention, *args).as_text()
+    else:
+        text = compile_under_shard_map_tp4(topo, args).as_text()
+    # ONE custom call, the one benchmark/layers/paged_decode_attn_roofline.py
+    # sums: a helper kernel beside it would be counted into it
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attn" in text
 
 
 # ---------------------------------------------------------------------------
@@ -398,6 +434,11 @@ def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
             sample=True),
     }[program]()
     assert_in_place(program, c, pool)
+    # the decode programs' one Pallas call is the paged kernel (the roofline
+    # reader sums every ``tpu_custom_call`` of theirs); chunked prefill
+    # attends through XLA and holds none
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == (
+        1 if program.startswith("decode") else 0)
 
 
 def assert_in_place(program, c, pool):

@@ -1,8 +1,10 @@
 """Per-shape paged-attention autotuner (ops.tuning's writer).
 
 Sweeps the paged decode attention dispatch over its real tuning axes —
-kernel impl (Pallas flash vs gather+XLA ref), pool ``block_tokens``, DMA
-``num_buffers`` — on REAL timings at the shapes a model family serves,
+kernel impl (Pallas flash vs gather+XLA ref), pool ``block_tokens``, the
+kernel's ``num_buffers`` (steps of copies in flight; the kernel cuts a
+step's table entries to its VMEM budget itself, so a deeper ring means
+smaller steps) — on REAL timings at the shapes a model family serves,
 and persists the winner per ``(head_dim, kv_heads, kv_dtype, tp)`` key to
 the tuning table (``LOCALAI_TUNE_CACHE`` / ``--out``). The engine then
 picks the tuned configuration automatically: ``select_paged_attn_impl``
@@ -211,7 +213,8 @@ def main(argv=None) -> int:
     ap.add_argument("--blocks", default="16,32,64,128",
                     help="block_tokens candidates")
     ap.add_argument("--buffers", default="2,3",
-                    help="num_buffers candidates (pallas only)")
+                    help="num_buffers candidates: steps in flight "
+                         "(pallas only)")
     ap.add_argument("--ctx", type=int, default=512,
                     help="context rows per measured slot")
     ap.add_argument("--interpret", action="store_true",
