@@ -23,8 +23,8 @@ the expected platform — children get JAX_PLATFORMS set explicitly, so with no
 TPU it fails within seconds instead of serving from the CPU. Any failed
 assertion or child exit code ends it non-zero with no result line; nothing is
 caught and turned into a report line. It reads no state from outside the
-tree (LOCALAI_TUNE_CACHE=0; the JAX compilation cache its children share is
-a temp dir created for the run and removed after it). Every line it prints
+tree (the JAX compilation cache its children share is a temp dir created for
+the run and removed after it). Every line it prints
 names platform, device_kind and device count; the last line of a passing run
 is one JSON object with exactly the keys the driver reads,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -118,7 +118,6 @@ class Smoke:
         env = dict(os.environ)
         env.update({
             "JAX_PLATFORMS": self.expect_platform,
-            "LOCALAI_TUNE_CACHE": "0",
             "JAX_COMPILATION_CACHE_DIR": str(self.cache_dir),
             "PYTHONPATH": str(ROOT),
             "PYTHONUNBUFFERED": "1",

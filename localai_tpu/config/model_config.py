@@ -162,8 +162,7 @@ class EngineConfig(BaseModel):
     dtype: str = "bfloat16"           # compute/weight dtype
     kv_dtype: str = "bfloat16"        # KV-cache dtype: bfloat16/float32,
                                       # scaled int8, or int4 (paged pools
-                                      # only — nibble-packed along head_dim;
-                                      # LOCALAI_KV_DTYPE overrides defaults)
+                                      # only — nibble-packed along head_dim)
     quantization: Optional[str] = None  # "int8" | "int8_w8a8" | "int4"
     donate_kv: bool = True            # accepted for config compatibility; the
                                       # runner always donates the KV cache

@@ -65,6 +65,17 @@ def block_tokens_default() -> int:
     return max(8, v)
 
 
+def overcommit_default() -> float:
+    """Default pool size as a ratio of the contiguous layout's footprint
+    (``LOCALAI_KV_OVERCOMMIT``, default 1.0; ``engine.kv_num_blocks`` sets
+    an absolute count and wins)."""
+    try:
+        v = float(os.environ.get("LOCALAI_KV_OVERCOMMIT", "") or 1.0)
+    except ValueError:
+        return 1.0
+    return max(0.01, v)
+
+
 @dataclasses.dataclass
 class BlockStats:
     total: int          # allocatable blocks (pool minus the trash block)

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import os
 import time
 from functools import partial
 from typing import Any, Optional
@@ -59,6 +58,10 @@ NAN_TOKEN = -2
 # (step, slot) — the slot's window ended at an earlier rejection (or the
 # slot is inactive). Consumers (scheduler._process_rows) skip it.
 SKIP = -1
+
+# tokens a chunked-prefill dispatch covers unless engine.prefill_chunk says
+# otherwise (never under one KV block)
+PREFILL_CHUNK = 512
 
 
 def _prompt_counts_row(vocab_size: int, prompt) -> np.ndarray:
@@ -118,7 +121,7 @@ class ModelRunner:
         sp_threshold: int = 1024,
         ga_n: int = 1,
         ga_w: int = 512,
-        paged: Any = "auto",
+        paged: bool = False,
         kv_block_tokens: Optional[int] = None,
         kv_num_blocks: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
@@ -223,71 +226,36 @@ class ModelRunner:
             incompat.append("pipeline parallelism")
         if ga_n > 1:
             incompat.append("self-extend")
-        if paged in ("auto", None):
-            # bare runners (tests, tools) default contiguous; the serving
-            # manager and bench enable paged whenever compatible — flip
-            # globally with LOCALAI_KV_PAGED=1
-            want_paged = os.environ.get("LOCALAI_KV_PAGED", "0") == "1"
-            self.paged = want_paged and not incompat
-        else:
-            self.paged = bool(paged)
-            if self.paged and incompat:
-                raise ValueError(
-                    f"paged KV cache is incompatible with {incompat}")
+        # bare runners (tests, tools) are contiguous unless asked; the
+        # serving manager asks for paged whenever the engine is compatible
+        self.paged = bool(paged)
+        if self.paged and incompat:
+            raise ValueError(
+                f"paged KV cache is incompatible with {incompat}")
         if kv_dtype == "int4" and not self.paged:
             raise ValueError(
                 "kv_dtype=int4 requires the paged KV layout (the nibble-"
                 "packed pool scatter only exists for block pools); use "
                 "int8 for contiguous caches")
-        tp_width = mesh.shape["model"] if mesh is not None else 1
         if self.paged:
-            # per-shape tuned defaults (ops.tuning, written by
-            # tools/autotune.py): explicit kwargs and env knobs win,
-            # then the tuning table, then the built-in defaults
-            from localai_tpu.ops import tuning as ops_tuning
-
-            tuned = ops_tuning.lookup(
-                cfg.hd, cfg.num_kv_heads, kv_dtype, tp_width)
-            try:
-                env_bt = int(
-                    os.environ.get("LOCALAI_KV_BLOCK_TOKENS", "") or 0)
-            except ValueError:
-                env_bt = 0
             self.block_tokens = max(8, int(
-                kv_block_tokens or env_bt
-                or (tuned.block_tokens if tuned else 0)
-                or pgd.block_tokens_default()))
-            try:
-                env_buf = int(
-                    os.environ.get("LOCALAI_PAGED_NUM_BUFFERS", "") or 0)
-            except ValueError:
-                env_buf = 0
-            self.paged_num_buffers = max(2, int(
-                env_buf or (tuned.num_buffers if tuned else 0) or 2))
+                kv_block_tokens or pgd.block_tokens_default()))
             self.max_blocks = -(-self.max_ctx // self.block_tokens)
             self.ctx_pad = self.max_blocks * self.block_tokens
             # default pool = the contiguous layout's HBM footprint (every
-            # slot can still reach max_ctx) scaled by LOCALAI_KV_OVERCOMMIT
-            # (ratio, default 1.0 — <1 shrinks for true overcommit, >1
+            # slot can still reach max_ctx) scaled by the overcommit ratio
+            # (paged.overcommit_default: <1 shrinks for true overcommit, >1
             # grows past the contiguous footprint), plus the trash block;
-            # LOCALAI_KV_BLOCKS / kv_num_blocks set an absolute count and
-            # win over the ratio
-            try:
-                self.kv_overcommit = max(0.01, float(
-                    os.environ.get("LOCALAI_KV_OVERCOMMIT", "1.0") or 1.0))
-            except ValueError:
-                self.kv_overcommit = 1.0
+            # kv_num_blocks sets an absolute count and wins over the ratio
+            self.kv_overcommit = pgd.overcommit_default()
             default_blocks = max(
                 self.max_blocks,
                 int(num_slots * self.max_blocks * self.kv_overcommit)) + 1
-            env_blocks = os.environ.get("LOCALAI_KV_BLOCKS", "")
-            num_blocks = int(kv_num_blocks or env_blocks or default_blocks)
             self.allocator = pgd.BlockAllocator(
-                num_blocks, self.block_tokens, self.max_blocks)
-            chunk_env = os.environ.get("LOCALAI_PREFILL_CHUNK_TOKENS", "512")
+                int(kv_num_blocks or default_blocks), self.block_tokens,
+                self.max_blocks)
             self.prefill_chunk = max(
-                self.block_tokens,
-                int(prefill_chunk or chunk_env or 512))
+                self.block_tokens, int(prefill_chunk or PREFILL_CHUNK))
             (self.paged_attn_impl,
              self._paged_attn_interpret) = ops.select_paged_attn_impl(
                 attn_impl,
@@ -295,37 +263,22 @@ class ModelRunner:
                 num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.hd,
                 block_tokens=self.block_tokens,
-                tp=tp_width,
+                tp=mesh.shape["model"] if mesh is not None else 1,
                 kv_dtype=kv_dtype,
-                # reuse the entry fetched above — an empty TuneEntry
-                # means "already looked up, no preference", so one
-                # construction emits exactly one lookup receipt
-                tuned=tuned or ops_tuning.TuneEntry(),
             )
             # collective/compute overlap (parallel.overlap): meshed decode
             # runs the trunk as a manual-TP shard_map with the per-layer
             # psums decomposed into chunked psum_scatter+all_gather so ICI
-            # latency hides behind the matmuls. LOCALAI_MESH_OVERLAP =
-            # auto(default)/psum/0; resolve_mode gates unsupported meshes
-            # back to GSPMD.
+            # latency hides behind the matmuls; resolve_mode gates
+            # unsupported meshes back to GSPMD.
             self.overlap_mode = ""
-            self.overlap_chunks = 4
             if mesh is not None:
                 from localai_tpu.parallel import overlap as ovl
 
-                self.overlap_mode, ovl_why = ovl.resolve_mode(
-                    cfg, mesh,
-                    os.environ.get("LOCALAI_MESH_OVERLAP", "auto"))
-                try:
-                    self.overlap_chunks = max(1, int(os.environ.get(
-                        "LOCALAI_MESH_OVERLAP_CHUNKS", "") or 4))
-                except ValueError:
-                    pass
+                self.overlap_mode, ovl_why = ovl.resolve_mode(cfg, mesh)
                 if self.overlap_mode:
-                    log.info(
-                        "meshed decode: manual-TP %s reductions "
-                        "(chunks=%d)", self.overlap_mode,
-                        self.overlap_chunks)
+                    log.info("meshed decode: manual-TP %s reductions",
+                             self.overlap_mode)
                 elif ovl_why:
                     log.info("meshed decode overlap unavailable: %s "
                              "(GSPMD psum path)", ovl_why)
@@ -336,8 +289,6 @@ class ModelRunner:
         else:
             self.allocator = None
             self.overlap_mode = ""
-            self.overlap_chunks = 4
-            self.paged_num_buffers = 2
         # shardings are kept so reinit() (self-healing engine rebuild)
         # can rebuild the device state into the exact same layout
         self._kv_sharding = None
@@ -982,10 +933,8 @@ class ModelRunner:
                 kv.stacked(), tables, self.rope,
                 ctx_pad=self.ctx_pad,
                 mode=self.overlap_mode,
-                chunks=self.overlap_chunks,
                 use_pallas=self.paged_attn_impl == "pallas",
                 interpret=self._paged_attn_interpret,
-                num_buffers=self.paged_num_buffers,
             )
             new_state, tokens = self._decode_tail(params, state, hidden)
             return (kvc.PagedKVCache.from_stacked(new_stack), new_state,
@@ -999,7 +948,6 @@ class ModelRunner:
                 ops.paged_decode_attention,
                 sliding_window=cfg.sliding_window,
                 interpret=self._paged_attn_interpret,
-                num_buffers=self.paged_num_buffers,
             )
             if self.mesh is not None:
                 from jax.sharding import PartitionSpec as P
@@ -1382,7 +1330,7 @@ class ModelRunner:
                 raise RuntimeError(
                     "KV block pool exhausted: cannot reserve "
                     f"{len(prompt)} prompt tokens (direct admit has no "
-                    "queue; size the pool via LOCALAI_KV_BLOCKS or admit "
+                    "queue; size the pool via engine.kv_num_blocks or admit "
                     "through the scheduler)")
             while True:
                 tok = adm.step_chunk()
@@ -1519,15 +1467,15 @@ class ModelRunner:
         spec_tokens = max(0, min(int(spec_tokens), self.max_ctx - reserve))
         if self.allocator.blocks_for(
                 reserve + spec_tokens) > self.allocator.num_blocks - 1:
-            # can NEVER fit, even with an empty pool (overcommitted
-            # LOCALAI_KV_BLOCKS): reject like the prompt-exceeds-context
+            # can NEVER fit, even with an empty pool (an overcommitted
+            # kv_num_blocks): reject like the prompt-exceeds-context
             # check — holding it would head-of-line block admission forever
             raise ValueError(
                 f"reservation of {reserve + spec_tokens} tokens "
                 f"({self.allocator.blocks_for(reserve + spec_tokens)} "
                 f"blocks) exceeds the block pool "
                 f"({self.allocator.num_blocks - 1} blocks); "
-                "lower max_new_tokens or raise LOCALAI_KV_BLOCKS")
+                "lower max_new_tokens or raise engine.kv_num_blocks")
         mm = mm_embeds is not None and len(mm_embeds) > 0
         lcp = 0
         if resident and not mm and self._loaded_rows.get(slot):

@@ -980,9 +980,9 @@ class Scheduler:
         # pipeline_depth dispatches stay in flight, and each result's D2H
         # copy starts immediately (copy_to_host_async). The device never
         # waits for the host round-trip and the dispatch overhead is
-        # amortized over multi_step tokens (see bench.py). Grammar
-        # constraints need the sampled token on the host before the next
-        # dispatch (the FSM mask feeds the next step), so constrained slots
+        # amortized over multi_step tokens. Grammar constraints need the
+        # sampled token on the host before the next dispatch (the FSM mask
+        # feeds the next step), so constrained slots
         # run synchronously one token per dispatch — but via the frozen-slot
         # program the UNconstrained slots still ride the same dispatch for
         # multi_step tokens (one tool-call request no longer de-pipelines

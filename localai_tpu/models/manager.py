@@ -417,39 +417,20 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
     # decoding runs block-native on this layout (localai_tpu.spec), so
     # draft-model engines are paged too; only multi-host mirroring still
     # drives the contiguous layout, and the runner itself gates off
-    # pipeline-parallel/self-extend. Explicit per-model config wins;
-    # otherwise the compatibility decision applies and LOCALAI_KV_PAGED=0
-    # force-disables (=1 adds nothing here: auto already enables
-    # everything compatible, and overriding the mirror exclusion would
-    # crash that engine at load).
+    # pipeline-parallel/self-extend. Explicit per-model config
+    # (engine.kv_paged) wins; otherwise the compatibility decision applies.
     paged = eng.kv_paged
     if paged is None:
         paged = ((mesh is None or mesh.shape.get("pipe", 1) == 1)
                  and eng.grp_attn_n <= 1
-                 and not app.mirror_port
-                 and os.environ.get("LOCALAI_KV_PAGED", "") != "0")
-    # LOCALAI_KV_DTYPE flips the KV-cache dtype fleet-wide (int8 halves
-    # KV bytes vs bf16; int4 halves them again via the nibble-packed
-    # paged pool). Explicit per-model config wins; int4 only exists for
-    # the paged layout, so contiguous engines (mirrors, self-extend)
-    # keep their configured dtype with a warning instead of crashing
-    # at runner construction.
-    kv_dtype = eng.kv_dtype
-    env_kv = os.environ.get("LOCALAI_KV_DTYPE", "").strip()
-    if env_kv and kv_dtype == "bfloat16":
-        if env_kv == "int4" and not paged:
-            log.warning(
-                "LOCALAI_KV_DTYPE=int4 ignored for %s: int4 KV requires "
-                "the paged layout (engine is contiguous)", mcfg.name)
-        else:
-            kv_dtype = env_kv
+                 and not app.mirror_port)
     runner = ModelRunner(
         model.cfg,
         params,
         num_slots=eng.max_slots,
         max_ctx=ctx,
         prefill_buckets=eng.prefill_buckets,
-        kv_dtype=kv_dtype,
+        kv_dtype=eng.kv_dtype,
         rope_freq_base=mcfg.rope_freq_base,
         rope_freq_scale=mcfg.rope_freq_scale,
         seed=mcfg.seed or 0,

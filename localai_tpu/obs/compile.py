@@ -26,8 +26,7 @@ dispatch would double every compile on the serving path, so the observatory
 pays that price only when an operator actually asks "where does the
 bandwidth go". The scheduler feeds measured per-dispatch latency via
 :func:`note_latency`; the report divides bytes-accessed and FLOPs by it and
-by the device roofline (obs.device) into achieved fractions — the direct
-answer to bench_micro's decode-bandwidth question.
+by the device roofline (obs.device) into achieved fractions.
 """
 
 from __future__ import annotations

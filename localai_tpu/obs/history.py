@@ -68,8 +68,8 @@ class History:
                ts: Optional[float] = None) -> None:
         """One point into all three rings. ``ts`` defaults to now (wall
         clock — history outlives the process, so monotonic won't do);
-        explicit timestamps let tools/usage_report.py ingest BENCH_*
-        points at their recorded times."""
+        explicit timestamps let tools/usage_report.py ingest an
+        artifact's points at their recorded times."""
         if ts is None:
             ts = time.time()
         value = float(value)

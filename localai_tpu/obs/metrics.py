@@ -493,22 +493,6 @@ class Registry:
             "1 after the supervisor exhausted its bounded rebuild budget "
             "and marked the model failed (submits fail fast)",
         )
-        self.autotune_lookups = Counter(
-            "localai_autotune_lookups_total",
-            "Per-shape kernel tuning-table lookups (ops.tuning) by "
-            "result=hit|miss — a fleet whose table stopped matching its "
-            "serving shapes shows an all-miss ratio here",
-        )
-        self.autotune_entries = Gauge(
-            "localai_autotune_table_entries",
-            "Entries in the loaded kernel tuning table "
-            "(LOCALAI_TUNE_CACHE; 0 = defaults everywhere)",
-        )
-        self.autotune_sweep_seconds = Gauge(
-            "localai_autotune_sweep_seconds",
-            "Wall seconds of the last tools/autotune.py sweep per shape "
-            "key",
-        )
         self.paged_kernel_impl = Gauge(
             "localai_paged_kernel_impl",
             "1 for the paged decode attention implementation each engine "

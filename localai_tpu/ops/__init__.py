@@ -15,13 +15,10 @@ explicit ``attn_impl: xla`` override, and "pallas" without a TPU backend is
 an error that names "pallas_interpret". Every combination a selector
 answers "pallas" for is compiled for v5e in tests/test_tpu_compile.py.
 
-Override with env ``LOCALAI_ATTN_IMPL`` / ``LOCALAI_PAGED_ATTN_IMPL`` or
-per-runner ``attn_impl=``.
+The one input is the runner's ``attn_impl=`` (YAML ``engine.attn_impl``).
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -48,17 +45,10 @@ _OVERRIDE = ("set engine.attn_impl: xla to serve this shape with XLA "
 
 def resolve_attn_impl(requested: str = "auto",
                       backend: str | None = None) -> tuple[str, bool]:
-    """Returns (impl, interpret) with impl in {"xla", "pallas"}."""
-    impl = requested
-    if impl in ("auto", ""):
-        # env only overrides the default, never an explicit per-runner choice
-        impl = os.environ.get("LOCALAI_ATTN_IMPL", "") or "auto"
-    return _resolve(impl, backend or jax.default_backend())
-
-
-def _resolve(impl: str, backend: str) -> tuple[str, bool]:
-    """auto → the backend default; validates the name; "pallas" off-TPU is
-    an error, never a quiet switch to the interpreter."""
+    """Returns (impl, interpret) with impl in {"xla", "pallas"}: auto →
+    the backend default; validates the name; "pallas" off-TPU is an error,
+    never a quiet switch to the interpreter."""
+    impl, backend = requested, backend or jax.default_backend()
     if impl in ("auto", ""):
         impl = "pallas" if backend == "tpu" else "xla"
     if impl == "pallas_interpret":
@@ -113,8 +103,7 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
                            num_kv_heads: int, head_dim: int,
                            block_tokens: int, tp: int = 1,
                            kv_dtype: str = "bfloat16",
-                           backend: str | None = None,
-                           tuned=None) -> tuple[str, bool]:
+                           backend: str | None = None) -> tuple[str, bool]:
     """Attention-impl decision for the PAGED decode path (the paged analogue
     of ``select_attn_impl``). Returns (impl, interpret); raises ValueError
     when the resolved impl is the compiled kernel and the shape cannot take
@@ -132,28 +121,8 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
     would cost what the int8 pool costs. The ``gather + XLA`` path
     (ops.paged_decode_attention_ref wired through the paged write
     policies) has no shape constraints and is the CPU/test path.
-
-    Precedence: an explicit ``requested`` wins; then the
-    ``LOCALAI_PAGED_ATTN_IMPL`` env override; then a tuned entry from the
-    per-shape tuning table (ops.tuning, keyed by head_dim / kv heads /
-    kv_dtype / tp — pass ``tuned`` to reuse an entry the caller already
-    looked up and skip the second lookup receipt); then the backend
-    default. A tuned "pallas" is honored ONLY on a real TPU backend: the
-    table is an automatic source, and off-TPU "pallas" is an error.
     """
-    backend = backend or jax.default_backend()
-    impl = requested
-    if impl in ("auto", ""):
-        impl = os.environ.get("LOCALAI_PAGED_ATTN_IMPL", "") or "auto"
-    if impl in ("auto", ""):
-        if tuned is None:
-            from localai_tpu.ops import tuning
-
-            tuned = tuning.lookup(head_dim, num_kv_heads, kv_dtype, tp)
-        if tuned is not None and tuned.impl and (
-                tuned.impl != "pallas" or backend == "tpu"):
-            impl = tuned.impl
-    impl, interpret = _resolve(impl, backend)
+    impl, interpret = resolve_attn_impl(requested, backend)
     if impl != "pallas":
         return impl, interpret
     _check_tp_heads(num_heads, num_kv_heads, tp)

@@ -415,11 +415,16 @@ _PAGED_KV_VMEM_BYTES = 1 << 20
 # core issues and waits for in unrolled code (on the chip, PR 29: a chip's 2
 # heads of the 24B ran fastest at 8 entries a step, 16 lost 17%)
 _PAGED_STEP_BLOCKS_MAX = 8
+# steps in flight. On the chip (PR 29, us a layer at the cells' shapes, the
+# ring at 1 MiB) a third step won nothing: 48.1 / 29.7 / 335 / 52.5 against
+# two steps' 42.0 / 28.3 / 290 / 45.7
+_PAGED_NUM_BUFFERS = 2
 
 
 def paged_decode_tiling(kv_heads: int, block_tokens: int, row_lanes: int,
                         itemsize: int, max_blocks: int,
-                        num_buffers: int = 2) -> tuple[int, int, int]:
+                        num_buffers: int = _PAGED_NUM_BUFFERS
+                        ) -> tuple[int, int, int]:
     """(blocks a step, steps in flight, VMEM bytes of the K/V ring) the
     paged decode kernel derives from the pool it is handed: ``kv_heads``
     local heads of ``row_lanes`` stored elements (hd, or hd/2 packed) of
@@ -594,7 +599,7 @@ def paged_decode_attention(
     *,
     sliding_window: Optional[int] = None,
     interpret: bool = False,
-    num_buffers: int = 2,
+    num_buffers: int = _PAGED_NUM_BUFFERS,
 ) -> jax.Array:
     """Flash GQA decode attention over one layer of the stacked paged
     block pool. Returns [S, Hq, hd]. One program a slot walks the slot's

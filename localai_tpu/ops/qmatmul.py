@@ -17,8 +17,8 @@ the tied-embedding lm_head (x @ W.T with per-row scales) by swapping the
 block index map and contracting on the weight block's minor axis — the
 int8 table is still read in its native row-major layout.
 
-Enabled from models.quant.matmul via LOCALAI_W8_KERNEL=1 (opt-in until
-hardware measurement picks the default; bench_micro.py measures both).
+Enabled from models.quant.matmul via LOCALAI_W8_KERNEL=1 (opt-in: no cell
+of the benchmark turns it on; ROADMAP C11 decides it with A3).
 """
 
 from __future__ import annotations

@@ -28,14 +28,16 @@ Scope gates (``resolve_mode``): paged KV, 'model' the only busy mesh axis
 (data/seq/expert/pipe == 1 — the pool writes of distinct data shards
 cannot be reconciled manually without an extra collective), dense MLP,
 and tp dividing heads/kv-heads/ffn/hidden. Everything else keeps the
-GSPMD path. Knob: ``LOCALAI_MESH_OVERLAP`` = auto/1 (overlap when
-supported, the default), ``psum`` (manual shard_map, undecomposed psum —
-the parity reference), ``0`` (GSPMD, the pre-overlap behavior).
+GSPMD path. Knob, read by ``resolve_mode`` alone: ``LOCALAI_MESH_OVERLAP``
+= auto/1 (overlap when supported, the default), ``psum`` (manual shard_map,
+undecomposed psum — the parity reference), ``0`` (GSPMD, the pre-overlap
+behavior).
 """
 
 from __future__ import annotations
 
 import logging
+import os
 from functools import partial
 from typing import Any, Optional
 
@@ -52,13 +54,20 @@ log = logging.getLogger(__name__)
 
 TRUNK_KEYS = ("embed", "final_norm", "layers")
 
+# independent psum_scatter+all_gather pairs a row-parallel product splits
+# into along the hidden dim
+CHUNKS = 4
+
 
 def resolve_mode(cfg: LlamaConfig, mesh: Optional[Mesh],
-                 requested: str = "auto") -> tuple[str, str]:
+                 requested: Optional[str] = None) -> tuple[str, str]:
     """The overlap-path decision: ("overlap" | "psum" | "", reason).
 
+    ``requested`` defaults to ``LOCALAI_MESH_OVERLAP`` (unset: "auto").
     "" keeps the GSPMD decode; the reason explains any gate that fired
     (empty when the requested mode is simply honored)."""
+    if requested is None:
+        requested = os.environ.get("LOCALAI_MESH_OVERLAP", "")
     req = (requested or "auto").strip().lower()
     if req in ("0", "off", "none"):
         return "", ""
@@ -86,7 +95,7 @@ def resolve_mode(cfg: LlamaConfig, mesh: Optional[Mesh],
     return want, ""
 
 
-def make_reduce(mode: str, tp: int, chunks: int = 4,
+def make_reduce(mode: str, tp: int, chunks: int = CHUNKS,
                 axis_name: str = "model"):
     """The row-parallel reduction for the manual-TP trunk.
 
@@ -144,10 +153,8 @@ def paged_decode_trunk(
     *,
     ctx_pad: int,
     mode: str = "overlap",
-    chunks: int = 4,
     use_pallas: bool = False,
     interpret: bool = False,
-    num_buffers: int = 2,
 ) -> tuple[jax.Array, tuple]:
     """One batched single-token paged decode FORWARD under manual tensor
     parallelism: returns (hidden [S, 1, D] replicated, new kv_stacked pool
@@ -178,7 +185,7 @@ def paged_decode_trunk(
 
     def local_fn(trunk, tokens, positions, kv_stacked, tables,
                  cos_t, sin_t):
-        reduce = make_reduce(mode, tp, chunks)
+        reduce = make_reduce(mode, tp)
         mask = kvc.decode_mask(cfg, positions, ctx_pad)
         write = kvc.paged_decode_write(tables, positions, raw=use_pallas)
         if embed_sharded:
@@ -192,7 +199,7 @@ def paged_decode_trunk(
             kernel = partial(
                 ops.paged_decode_attention,
                 sliding_window=cfg.sliding_window,
-                interpret=interpret, num_buffers=num_buffers,
+                interpret=interpret,
             )
 
             def attn(q, keys, values, _mask):  # q [S,1,Hq_loc,hd]

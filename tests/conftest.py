@@ -8,11 +8,6 @@ import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-# hermetic kernel tuning: a developer machine's ~/.cache tuning table must
-# not change runner defaults (block size, impl) under test; the tuning
-# tests opt back in with their own tmp-path tables
-os.environ.setdefault("LOCALAI_TUNE_CACHE", "0")
-
 import jax  # noqa: E402
 
 jax.config.update("jax_num_cpu_devices", 8)

@@ -358,8 +358,8 @@ def test_abandoned_engine_thread_exits_without_touching_new_state(tiny):
 
 
 def test_disarmed_hot_path_is_one_boolean():
-    """The contract perf_smoke relies on: with nothing armed, injection
-    sites reduce to a module-attribute truthiness check."""
+    """With nothing armed, injection sites reduce to a module-attribute
+    truthiness check."""
     assert faults.active() is False
     # the scheduler/runner sites all gate on this exact attribute; a
     # regression to per-dispatch env reads would show up here

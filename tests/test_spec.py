@@ -148,7 +148,7 @@ def test_paged_ngram_greedy_parity(tiny):
     got = _spec_tokens(eng, REPEAT, 24)
     assert got == ref
     # the verify-k dispatch actually amortized: >1 token per window once
-    # the stream cycles (the perf_smoke spec gate pins this too)
+    # the stream cycles
     assert eng.tokens_per_dispatch > 1.0
     assert eng.accept_rate > 0.0
     assert not eng.target.allocator.check_invariants()

@@ -381,17 +381,21 @@ def test_smoke_programs_fit_one_chip(topo, monkeypatch):
 # the benchmark cell's programs write the KV pool in place
 
 
-@pytest.fixture(scope="module")
-def cell():
-    """benchmark/configs/mistral-7b-v0.3-int8.json: the published keys as a
-    LlamaConfig, and the engine's sizes."""
+M7B, MS24B = "mistral-7b-v0.3-int8", "mistral-small-24b-int8-tp4"
+
+
+@pytest.fixture
+def cell(request):
+    """benchmark/configs/<name>.json (the one-chip 7B unless a test names
+    another): the published keys as a LlamaConfig, and the engine's sizes."""
     import json
 
     from localai_tpu.models.llama import LlamaConfig
 
     root = Path(__file__).resolve().parent.parent
+    name = getattr(request, "param", M7B)
     doc = json.loads(
-        (root / "benchmark/configs/mistral-7b-v0.3-int8.json").read_text())
+        (root / f"benchmark/configs/{name}.json").read_text())
     # from_hf reads the published keys it knows and no other
     cfg = dataclasses.replace(LlamaConfig.from_hf(doc), dtype="bfloat16")
     return cfg, doc
@@ -470,18 +474,24 @@ def assert_in_place(program, c, pool):
     assert not moved, f"{program} moves the pool or a layer of it: {moved}"
 
 
-@pytest.mark.parametrize("program, overlap", [
-    ("decode", "0"), ("decode", "auto"), ("prefill_chunk_512", "auto")])
+@pytest.mark.parametrize("cell, program, overlap", [
+    (M7B, "decode", "0"), (M7B, "decode", "auto"),
+    (M7B, "prefill_chunk_512", "auto"),
+    (MS24B, "decode", "auto"), (MS24B, "decode_n2", "auto"),
+    (MS24B, "prefill_chunk_512", "auto")], indirect=["cell"])
 def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
         topo, monkeypatch, cell, program, overlap):
-    """The same programs over a 1 x 4 mesh, the pool sharded over its kv
-    heads ('model'): under GSPMD (``LOCALAI_MESH_OVERLAP=0``, and every
-    prefill) as inside the manual-TP trunk's shard_map (``auto``) each chip
-    writes the rows of its own two heads into its own shard, in place. The
-    partitioner has to SEE that: the policies index the head axis with an
-    iota (``kvcache._scatter_per_head``), and a scatter it cannot prove
-    shard-local gathers the new rows, or the blocks a chunk touches, from
-    all chips, a collective a layer for K and for V."""
+    """A configuration file's programs over a 1 x 4 mesh, the pool sharded
+    over its kv heads ('model'): under GSPMD (``LOCALAI_MESH_OVERLAP=0``,
+    and every prefill) as inside the manual-TP trunk's shard_map (``auto``)
+    each chip writes the rows of its own two heads into its own shard, in
+    place. The partitioner has to SEE that: the policies index the head axis
+    with an iota (``kvcache._scatter_per_head``), and a scatter it cannot
+    prove shard-local gathers the new rows, or the blocks a chunk touches,
+    from all chips, a collective a layer for K and for V. The 7B's file is
+    the shared code's guard; the 24B's is the four-chip cell itself (40
+    layers, 641 blocks, 32 slots), whose programs the chip sees in one cell
+    at four chips' cost."""
     import re
 
     cfg, doc = cell
@@ -492,18 +502,27 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
         max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
         kv_block_tokens=64)
     assert bool(r.overlap_mode) == (overlap == "auto")
-    assert a["kv"].k.sharding.shard_shape(a["kv"].k.shape) == (
-        32, 289, 2, 64, 128)
+    shard = (cfg.num_layers, eng["kv_num_blocks"], cfg.num_kv_heads // 4,
+             64, cfg.hd)
+    assert shard[2:] == (2, 64, 128)
+    assert a["kv"].k.sharding.shard_shape(a["kv"].k.shape) == shard
     base = (a["params"], a["kv"], a["state"])
     if program == "decode":
         c = compile_program(r._decode_paged_fn, *base, a["tables"])
+    elif program == "decode_n2":
+        c = compile_program(r._decode_paged_n_fn, *base, a["tables"], n=2)
     else:
         c = compile_program(r._prefill_paged_fn, *base, *a["chunk"](512),
                             bucket=512, sample=False)
-    assert_in_place(program, c, (32, 289, 2, 64, 128))
+    assert_in_place(program, c, shard)
+    text = c.as_text()
     collectives = [
-        ln.strip()[:160] for ln in c.as_text().splitlines()
+        ln.strip()[:160] for ln in text.splitlines()
         if "kv_pool." in ln and re.search(
             r" (all-gather|all-reduce|all-to-all|collective-permute"
             r"|reduce-scatter)[\w\-]*\(", ln)]
     assert not collectives, f"{program}: the pool's write talks: {collectives}"
+    # one Pallas call in a decode program, the paged kernel inside its
+    # shard_map; none in a chunk
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if program.startswith("decode") else 0)

@@ -9,14 +9,9 @@ the waste decomposition — each as the latest cumulative counter value
 plus the delta across the loaded window, so "who burned the device this
 afternoon" is answerable from a dead snapshot.
 
-``--ingest-bench <dir-or-file>...`` folds saved ``bench.py`` output (one
-JSON object per line: ``{"metric", "value", "unit", "device", ...}``) into
-the same store as ``bench.<metric>`` gauge series, timestamped at each
-file's mtime. The repo commits no such files: save the output of a chip run
-yourself (``*.jsonl`` in a directory, or name the files). ``--ingest-autoscale``
-does the same for the ``autoscale_report.json`` artifact telemetry_smoke
-round 20 leaves behind: the fleet's capacity trajectory replays at its
-recorded timestamps and the decision counts / cold-start latency land as
+``--ingest-autoscale <dir-or-file>...`` folds the ``autoscale_report.json``
+artifact telemetry_smoke round 20 leaves behind into the same store: the
+fleet's capacity trajectory replays at its recorded timestamps and the decision counts / cold-start latency land as
 ``autoscale.*`` series. ``--save`` writes the merged snapshot back
 (tmp + ``os.replace``, same as the live writer).
 
@@ -98,7 +93,6 @@ def build_report(h: History, *, res: int = 10) -> dict:
             "tokens_generated": _collect(h, "tokens_generated", res),
             "requests_shed": _collect(h, "requests_shed", res),
         },
-        "bench": _collect(h, "bench", res),
         "autoscale": _collect(h, "autoscale", res),
         "fleet_target_replicas": _collect(h, "fleet_target_replicas",
                                           res),
@@ -141,14 +135,6 @@ def render_text(report: dict, out=None) -> None:
             for r, s in sorted(report["waste_tokens"].items())]
     _table("waste by reason", ["reason", "tokens", "Δtokens"], rows, out)
 
-    if report["bench"]:
-        rows = [[m, s["latest"], s["points"],
-                 time.strftime("%Y-%m-%d %H:%M",
-                               time.localtime(s["to_ts"]))]
-                for m, s in sorted(report["bench"].items())]
-        _table("bench trajectory", ["metric", "last", "points", "as of"],
-               rows, out)
-
     if report["autoscale"] or report["fleet_target_replicas"]:
         rows = [[f"target_replicas.{m}", s["latest"], s["points"],
                  time.strftime("%Y-%m-%d %H:%M",
@@ -161,40 +147,6 @@ def render_text(report: dict, out=None) -> None:
                  for m, s in sorted(report["autoscale"].items())]
         _table("elastic capacity",
                ["metric", "last", "points", "as of"], rows, out)
-
-
-def _bench_files(paths: list[str]) -> list[str]:
-    files: list[str] = []
-    for p in paths:
-        if os.path.isdir(p):
-            files.extend(sorted(glob.glob(os.path.join(p, "*.jsonl"))))
-        else:
-            files.append(p)
-    return files
-
-
-def ingest_bench(h: History, paths: list[str]) -> int:
-    """Fold saved bench.py output (one JSON object per line) into
-    ``bench.<metric>`` gauge series at each file's mtime. Returns points
-    ingested; unreadable files and non-metric lines are skipped with a
-    stderr note (report tooling never hard-fails on one bad file)."""
-    ingested = 0
-    for path in _bench_files(paths):
-        try:
-            with open(path, encoding="utf-8") as f:
-                lines = [json.loads(ln) for ln in f if ln.strip()]
-            ts = os.path.getmtime(path)
-        except (OSError, ValueError) as e:
-            sys.stderr.write(f"usage_report: skipping {path}: {e}\n")
-            continue
-        for line in lines:
-            if not isinstance(line, dict):
-                continue
-            metric, value = line.get("metric"), line.get("value")
-            if isinstance(metric, str) and isinstance(value, (int, float)):
-                h.record(f"bench.{metric}", float(value), ts=ts)
-                ingested += 1
-    return ingested
 
 
 def _autoscale_files(paths: list[str]) -> list[str]:
@@ -257,11 +209,6 @@ def main(argv=None) -> int:
     parser.add_argument("--res", type=int, default=10,
                         choices=sorted(CAPACITY),
                         help="ring resolution to report at (seconds)")
-    parser.add_argument("--ingest-bench", nargs="+", default=[],
-                        metavar="PATH",
-                        help="saved bench.py output (JSON lines) — files, "
-                             "or directories of *.jsonl — to fold into "
-                             "the store as bench.<metric> series")
     parser.add_argument("--ingest-autoscale", nargs="+", default=[],
                         metavar="PATH",
                         help="autoscale_report*.json files or "
@@ -275,18 +222,13 @@ def main(argv=None) -> int:
                              "of tables")
     args = parser.parse_args(argv)
 
-    if not args.snapshot_dir and not args.ingest_bench \
-            and not args.ingest_autoscale:
-        parser.error("need a snapshot dir, --ingest-bench and/or "
-                     "--ingest-autoscale")
+    if not args.snapshot_dir and not args.ingest_autoscale:
+        parser.error("need a snapshot dir and/or --ingest-autoscale")
 
     h = History()
     if args.snapshot_dir and not h.load(args.snapshot_dir):
         sys.stderr.write(f"usage_report: no readable history.json under "
                          f"{args.snapshot_dir!r} (starting empty)\n")
-    if args.ingest_bench:
-        n = ingest_bench(h, args.ingest_bench)
-        sys.stderr.write(f"usage_report: ingested {n} bench point(s)\n")
     if args.ingest_autoscale:
         n = ingest_autoscale(h, args.ingest_autoscale)
         sys.stderr.write(f"usage_report: ingested {n} autoscale "
