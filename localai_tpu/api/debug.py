@@ -24,7 +24,8 @@ per-dispatch records (step times, occupancy, queue depth, KV utilization,
 tokens, preemptions, speculative acceptance) with windowed step-time
 percentiles. ``?since=<monotonic ts>`` returns only records newer than
 the given timestamp (pollers pass the ``ts`` of the last record they
-saw); ``?limit=N`` bounds the newest records returned. The "what was the
+saw); ``?limit=N`` (at most 4096) bounds the records returned: the newest
+N, or with ``since`` the first N after it (a page). The "what was the
 engine doing for the last N seconds" view — reading it never touches a
 device.
 

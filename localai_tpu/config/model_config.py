@@ -171,10 +171,12 @@ class EngineConfig(BaseModel):
                                       # per that many tokens; lower it for
                                       # tighter streaming cadence
     pipeline_depth: int = 2           # in-flight decode dispatches
-    stream_latency_ms: float = 100.0  # SSE delivery-lag bound: with a stream
-                                      # attached the scheduler shrinks the
-                                      # dispatch size to keep
-                                      # steps×depth×step_time under this
+    stream_latency_ms: float = 100.0  # SSE delivery-lag CEILING: with a
+                                      # stream attached a dispatch holds the
+                                      # fewest steps that hide the host's
+                                      # work behind the device, and never so
+                                      # many that steps×depth×step_time
+                                      # passes this
     sp_prefill_threshold: int = 1024  # prompts at/above this many tokens
                                       # take the ring-attention prefill when
                                       # the mesh has a 'seq' axis

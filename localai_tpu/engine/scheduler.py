@@ -49,6 +49,12 @@ from localai_tpu.obs.engine import EngineTelemetry
 log = logging.getLogger(__name__)
 
 
+def _ema(prev: Optional[float], sample: float) -> float:
+    """The smoothing of the scheduler's per-dispatch timings (step seconds,
+    host seconds): a fifth of each new sample."""
+    return sample if prev is None else 0.8 * prev + 0.2 * sample
+
+
 class _EngineAbandoned(Exception):
     """Raised inside a fenced-off engine thread (its epoch was bumped by
     a rebuild while it sat in a blocked round-trip): exit without
@@ -326,11 +332,16 @@ class Scheduler:
         # Delivery lag ≈ multi_step×pipeline_depth×step-time;
         # when any active request has an SSE stream attached, the dispatch
         # size adapts down (power-of-two steps, so at most log2(multi_step)
-        # program variants ever compile) to keep that product under
-        # stream_latency_target seconds. Batch requests keep the full size.
+        # program variants ever compile): the smallest that keeps the device
+        # busy while the host handles a dispatch, and never more than keeps
+        # that product under stream_latency_target seconds
+        # (_effective_steps). Batch requests keep the full size.
         self.multi_step = max(1, multi_step)
         self.stream_latency_target = stream_latency_target
         self._step_ema: Optional[float] = None   # seconds per decoded token
+        # host seconds per pipelined decode dispatch: the flight record's
+        # gap + sched + launch (what the device does not wait for)
+        self._host_ema: Optional[float] = None
         self._last_drain_t: Optional[float] = None
         # dispatch-anatomy accumulators (obs.anatomy): measured host-phase
         # seconds since the LAST flight record, taken-and-reset by
@@ -1043,6 +1054,11 @@ class Scheduler:
             if rows.ndim == 1:
                 rows = rows[None]
             self._process_rows(rows, seq)
+            phases = self._take_anat(dt, sync_s)
+            if not fresh and k > 0:
+                self._observe_host_time(
+                    (phases["gap_ms"] + phases["sched_ms"]
+                     + phases["launch_ms"]) * 1e-3)
             # flight ring: spec windows carry their yield as steps plus
             # per-dispatch proposed/accepted counts (ROADMAP item 3:
             # accept-rate in the flight ring); compile-bearing dispatches
@@ -1052,7 +1068,7 @@ class Scheduler:
                 k_eff, dt, fresh,
                 spec_proposed=window["proposed"] if window else 0,
                 spec_accepted=window["accepted"] if window else 0,
-                sync_s=sync_s,
+                phases=phases,
             )
 
         while not self._stopping and self._epoch == epoch:
@@ -1119,7 +1135,7 @@ class Scheduler:
                     constrained = constrained_slots()
                     if not self._slots or not constrained:
                         continue
-                    steps = self._effective_steps()
+                    steps = self._effective_steps(pipelined=False)
                     self._dispatch_seq += 1
                     if len(constrained) == len(self._slots) or steps == 1:
                         fresh = self._fresh_shape(1)
@@ -1320,20 +1336,33 @@ class Scheduler:
         adaptive streaming dispatch size."""
         if dt <= 0:
             return
-        self._step_ema = (
-            dt if self._step_ema is None else 0.8 * self._step_ema + 0.2 * dt
-        )
+        self._step_ema = _ema(self._step_ema, dt)
 
-    def _effective_steps(self) -> int:
+    def _observe_host_time(self, host_s: float) -> None:
+        """Fold one pipelined decode dispatch's host seconds (the flight
+        record's gap + sched + launch) into the EMA that says how long a
+        dispatch must last on the device to hide the host."""
+        self._host_ema = _ema(self._host_ema, host_s)
+
+    def _effective_steps(self, pipelined: bool = True) -> int:
         """Tokens per dispatch for the next dispatch.
 
         Batch-only traffic takes the full multi_step (throughput). With any
         SSE stream attached, delivery lag ≈ steps×pipeline_depth×step_time
-        must stay under stream_latency_target, so the step count shrinks to
-        fit — quantized DOWN to a power of two, bounding the number of
-        distinct compiled decode programs at log2(multi_step)+1. With no
+        must stay under stream_latency_target: that budget is a CEILING,
+        the step count that fits it quantized DOWN to a power of two
+        (bounding the number of distinct compiled decode programs at
+        log2(multi_step)+1). On the pipelined path the step count is the
+        SMALLEST power of two whose dispatch lasts as long on the device as
+        the host spends on one (steps×step_time ≥ host_time, both EMAs of
+        post-compile dispatches), never above the ceiling: a longer
+        dispatch amortises host work the device never waits for, and every
+        arrival waits behind it. So a faster step alone never lengthens the
+        dispatch; a host slower than the device does, as far as the budget
+        lets it. The synchronous path (``pipelined`` False: constrained
+        slots) hides no host work, so the budget alone sizes it. With no
         timing sample yet, streams get single-step dispatches (latency-safe;
-        the EMA fills in from the first post-compile dispatch).
+        the EMAs fill in from the first post-compile dispatch).
         """
         k = self.multi_step
         if k <= 1:
@@ -1348,8 +1377,13 @@ class Scheduler:
             return 1
         budget = self.stream_latency_target / max(1, self.pipeline_depth)
         n = int(budget / self._step_ema) if self._step_ema > 0 else k
+        ceiling = 1
+        while ceiling * 2 <= min(n, k):
+            ceiling *= 2
+        if not pipelined:
+            return ceiling
         p = 1
-        while p * 2 <= min(n, k):
+        while p < ceiling and p * self._step_ema < (self._host_ema or 0.0):
             p *= 2
         return p
 

@@ -269,6 +269,13 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
             q = q + lp["bq"].astype(q.dtype)
             k = k + lp["bk"].astype(k.dtype)
             v = v + lp["bv"].astype(v.dtype)
+        # the head split stays off the dots: folded into them, XLA wants each
+        # weight [H, hd, D] and, the stacked leaves being [L, D, H*hd],
+        # transposes every stack once a dispatch and stages a copy of each
+        # layer's slice (16% of a 7B decode step). Behind the barrier the
+        # dots stay [.., D] x [D, H*hd] and read the scanned slice in place,
+        # as the MLP's and wo's do (tests/test_tpu_compile.py holds it)
+        q, k, v = lax.optimization_barrier((q, k, v))
         q = q.reshape(*q.shape[:-1], Hq, hd)
         k = k.reshape(*k.shape[:-1], Hkv, hd)
         v = v.reshape(*v.shape[:-1], Hkv, hd)

@@ -151,8 +151,10 @@ class FlightRecorder:
         """Resident records oldest → newest as JSON-able dicts.
 
         ``since`` filters on the record's monotonic timestamp (pollers pass
-        the ``ts`` of the last record they saw); ``limit`` keeps the newest
-        N after filtering.
+        the ``ts`` of the last record they saw). ``limit`` keeps N records:
+        the newest N of the ring (the tail view), or with ``since`` the
+        OLDEST N after it, so that a poller pages forward and misses
+        nothing however many dispatches fell between two of its reads.
         """
         # copy the selected rows under the lock, format after releasing
         # it: building (up to capacity) dicts must not block the engine
@@ -162,7 +164,7 @@ class FlightRecorder:
             if since:
                 order = order[self._ts[order] > since]
             if limit is not None and len(order) > limit:
-                order = order[-limit:]
+                order = order[:limit] if since else order[-limit:]
             cols = {
                 "ts": self._ts[order].tolist(),
                 "steps": self._steps[order].tolist(),

@@ -103,6 +103,27 @@ def test_snapshot_since_and_limit():
     assert fl.snapshot(since=106.0) == []
 
 
+def test_snapshot_since_pages_forward():
+    """A poller that feeds back the last ``ts`` it saw reads every record
+    once, however many fell between two reads: with ``since`` a ``limit``
+    keeps the OLDEST records after it (benchmark/run.py reads the ring once
+    after its traced slice, 4096 rows at most, and the slice comes first)."""
+    fl = FlightRecorder(16)
+    for i in range(10):
+        _rec(fl, i, ts=100.0 + i)
+
+    def depths(**kw):
+        return [r["queue_depth"] for r in fl.snapshot(**kw)]
+
+    assert depths(limit=4) == [6, 7, 8, 9]               # the tail view
+    seen, since = [], 100.5
+    while page := fl.snapshot(since=since, limit=4):
+        seen += [r["queue_depth"] for r in page]
+        since = page[-1]["ts"]
+    assert seen == list(range(1, 10))                    # none missed
+    assert depths(since=104.5, limit=4) == [5, 6, 7, 8]
+
+
 # -- dispatch anatomy (phase columns + obs.anatomy) --------------------------
 
 

@@ -396,6 +396,7 @@ def _bare_scheduler(multi_step=16, pipeline_depth=2, target=0.1):
     s.pipeline_depth = pipeline_depth
     s.stream_latency_target = target
     s._step_ema = None
+    s._host_ema = None
     s._lock = threading.Lock()
     s._slots = {}
     return s
@@ -409,29 +410,89 @@ def _fake_slot(stream: bool):
     )
 
 
-def test_effective_steps_full_size_without_streams():
+# multi_step 16, depth 2, target 100 ms: the budget is 50 ms a dispatch.
+# (streams of the slots, step_ema, host_ema, k pipelined, k synchronous)
+_STEPS_CASES = {
+    # no stream attached: the full multi_step, whatever the timings
+    "idle engine": ((), None, None, 16, 16),
+    "batch-only, slow steps": ((False,), 0.05, None, 16, 16),
+    "batch-only, host hidden": ((False,), 0.010, 0.002, 16, 16),
+    # no timing sample yet: latency-safe single step
+    "stream, no step sample": ((True,), None, None, 1, 1),
+    "stream, no host sample": ((True,), 0.001, None, 1, 16),
+    # the budget is the ceiling: the host wants more than it allows
+    "1 ms steps, host 20 ms: capped at multi_step": (
+        (True,), 0.001, 0.020, 16, 16),
+    "10 ms steps, host 100 ms: 5 fit, round DOWN": (
+        (True,), 0.010, 0.100, 4, 4),
+    "50 ms steps: single-step dispatches": ((True,), 0.050, 0.100, 1, 1),
+    "a mixed batch: one stream bounds the lag for everyone": (
+        (True, False), 0.050, 0.100, 1, 1),
+    # the host hidden behind one step: a faster step never raises k
+    "15 ms steps, host 2 ms": ((True,), 0.015, 0.002, 1, 2),
+    "10 ms steps, host 2 ms": ((True,), 0.010, 0.002, 1, 4),
+    "5 ms steps, host 2 ms": ((True,), 0.005, 0.002, 1, 8),
+    # a host slower than the dispatch: the smallest k that hides it
+    "10 ms steps, host 12 ms": ((True,), 0.010, 0.012, 2, 4),
+    "5 ms steps, host 12 ms": ((True,), 0.005, 0.012, 4, 8),
+    "1 ms steps, host 6 ms: a small model keeps its long dispatch": (
+        (True,), 0.001, 0.006, 8, 16),
+    "a dispatch exactly as long as the host's work": (
+        (True,), 0.004, 0.008, 2, 8),
+    # ... and not past the budget
+    "20 ms steps, host 90 ms": ((True,), 0.020, 0.090, 2, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_STEPS_CASES))
+def test_effective_steps(case):
+    """With a stream attached a pipelined dispatch holds the FEWEST steps
+    that keep the device busy while the host handles one (k×step_ema >=
+    host_ema, a power of two), under the latency budget's ceiling (the
+    largest power of two with k×depth×step_ema <= target, multi_step at
+    most); the synchronous path hides nothing and takes the ceiling;
+    batch-only traffic takes multi_step."""
+    streams, step_ema, host_ema, k_pipelined, k_sync = _STEPS_CASES[case]
     s = _bare_scheduler()
-    assert s._effective_steps() == 16            # idle engine
-    s._slots[0] = _fake_slot(stream=False)
-    s._step_ema = 0.05                           # slow steps, but batch-only
-    assert s._effective_steps() == 16
+    for i, stream in enumerate(streams):
+        s._slots[i] = _fake_slot(stream=stream)
+    s._step_ema, s._host_ema = step_ema, host_ema
+    assert s._effective_steps() == k_pipelined
+    assert s._effective_steps(pipelined=False) == k_sync
+    assert k_pipelined <= k_sync
 
 
-def test_effective_steps_shrinks_for_streams():
+@pytest.mark.parametrize("host_ema", [0.002, 0.012, 0.030])
+def test_a_faster_step_never_lengthens_a_hidden_dispatch(host_ema):
+    """The regression of PERF.md's PR 31, by construction: as the step
+    gets faster the dispatch's wall time (k×step_ema) never grows past
+    what the host needs or one step, whichever is longer, and k never
+    passes the budget's."""
     s = _bare_scheduler()
     s._slots[0] = _fake_slot(stream=True)
-    # no timing sample yet → latency-safe single step
-    assert s._effective_steps() == 1
-    # budget = 0.1/2 = 50ms per dispatch
-    s._step_ema = 0.001   # 1ms/token → 50 tokens fit → capped at multi_step
-    assert s._effective_steps() == 16
-    s._step_ema = 0.010   # 10ms/token → 5 fit → round DOWN to power of two
-    assert s._effective_steps() == 4
-    s._step_ema = 0.050   # 50ms/token → single-step dispatches
-    assert s._effective_steps() == 1
-    # a mixed batch with one stream still bounds the lag for everyone
-    s._slots[1] = _fake_slot(stream=False)
-    assert s._effective_steps() == 1
+    s._host_ema = host_ema
+    for step_ms in range(60, 0, -1):
+        s._step_ema = step_ms * 1e-3
+        k = s._effective_steps()
+        assert k <= s._effective_steps(pipelined=False)
+        # hidden already at k/2 (or k = 1): no longer than it has to be
+        assert k == 1 or (k // 2) * s._step_ema < host_ema
+
+
+def test_host_ema_is_fed_by_pipelined_decode_dispatches(sched):
+    """The EMA beside ``_step_ema``: host seconds a pipelined decode
+    dispatch (what the flight ring splits out as gap + sched + launch),
+    present once a post-compile dispatch has drained and no larger than a
+    dispatch's wall."""
+    h = sched.submit(_req("host ema", max_new_tokens=24, temperature=0.0,
+                          ignore_eos=True, stream=True))
+    list(h)
+    assert _wait(lambda: not sched.busy)
+    assert sched._host_ema is not None and sched._host_ema >= 0.0
+    rows = [r for r in sched.flight.snapshot()
+            if r["program"] in ("decode", "decode_n") and not r["compile"]]
+    assert rows
+    assert sched._host_ema <= max(r["dispatch_ms"] for r in rows) * 1e-3
 
 
 def test_streaming_request_bounds_delivery_lag(sched):

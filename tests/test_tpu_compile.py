@@ -338,6 +338,20 @@ def compile_program(fn, *args, **static):
         *args, **static).lower(lowering_platforms=("tpu",)).compile()
 
 
+def compile_cell_program(r, a, program):
+    """One of a cell's dispatched programs over ``abstract_runner``'s
+    (runner, avals): ``decode``, ``decode_n2`` or ``prefill_chunk_512``
+    (``..._sample``: the chunk that ends a prompt and samples)."""
+    base = (a["params"], a["kv"], a["state"])
+    if program == "decode":
+        return compile_program(r._decode_paged_fn, *base, a["tables"])
+    if program == "decode_n2":
+        return compile_program(r._decode_paged_n_fn, *base, a["tables"], n=2)
+    return compile_program(
+        r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
+        sample=program.endswith("_sample"))
+
+
 def test_smoke_programs_fit_one_chip(topo, monkeypatch):
     """Every program the scheduler dispatches for chip_smoke.py's server —
     debug:llama3-8b, int8 weights, its SLOTS and CONTEXT, bf16 paged KV —
@@ -424,19 +438,7 @@ def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
         kv_block_tokens=64)
     pool = a["kv"].k.shape
     assert pool == (32, 289, 8, 64, 128) and a["kv"].k.dtype == bf16
-    base = (a["params"], a["kv"], a["state"])
-    c = {
-        "decode": lambda: compile_program(
-            r._decode_paged_fn, *base, a["tables"]),
-        "decode_n2": lambda: compile_program(
-            r._decode_paged_n_fn, *base, a["tables"], n=2),
-        "prefill_chunk_512": lambda: compile_program(
-            r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
-            sample=False),
-        "prefill_chunk_512_sample": lambda: compile_program(
-            r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
-            sample=True),
-    }[program]()
+    c = compile_cell_program(r, a, program)
     assert_in_place(program, c, pool)
     # the decode programs' one Pallas call is the paged kernel (the roofline
     # reader sums every ``tpu_custom_call`` of theirs); chunked prefill
@@ -506,14 +508,7 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
              64, cfg.hd)
     assert shard[2:] == (2, 64, 128)
     assert a["kv"].k.sharding.shard_shape(a["kv"].k.shape) == shard
-    base = (a["params"], a["kv"], a["state"])
-    if program == "decode":
-        c = compile_program(r._decode_paged_fn, *base, a["tables"])
-    elif program == "decode_n2":
-        c = compile_program(r._decode_paged_n_fn, *base, a["tables"], n=2)
-    else:
-        c = compile_program(r._prefill_paged_fn, *base, *a["chunk"](512),
-                            bucket=512, sample=False)
+    c = compile_cell_program(r, a, program)
     assert_in_place(program, c, shard)
     text = c.as_text()
     collectives = [
@@ -526,3 +521,53 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
     # shard_map; none in a chunk
     assert text.count('custom_call_target="tpu_custom_call"') == (
         1 if program.startswith("decode") else 0)
+
+
+# ---------------------------------------------------------------------------
+# the cells' decode programs read wq, wk and wv where they lie
+
+
+@pytest.mark.parametrize("cell, tp, program", [
+    (M7B, 1, "decode"), (M7B, 1, "decode_n2"),
+    (MS24B, 4, "decode"), (MS24B, 4, "decode_n2")], indirect=["cell"])
+def test_cell_decode_programs_read_qkv_weights_in_place(
+        topo, monkeypatch, cell, tp, program):
+    """The guard of PERF.md's PR 32 (ROADMAP A3): the layer scan hands
+    ``_layer`` one layer's ``[D, H*hd]`` int8 slice of each stacked ``[L, D,
+    H*hd]`` leaf, and the q, k and v dots read it there, as the MLP's and
+    ``wo``'s do. With the head split folded into the dot XLA wants the
+    weight ``[H, hd, D]``: it transposes each whole stack once a dispatch (a
+    ``copy`` of ``s8[L, D, H*hd]`` hoisted out of the step loop, 0.75 GiB of
+    temps at the 7B's n = 2) and copies each layer's ``s8[1, D, H*hd]``
+    slice before the dot: 2.1 ms of a 14.4 ms step on the chip. Only the
+    compiler shows it. Under tp = 4 the shards carry the LOCAL head counts
+    (the 24B: ``[40, 5120, 1024]`` and ``[40, 5120, 256]``)."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    if tp > 1:
+        monkeypatch.setenv("LOCALAI_MESH_OVERLAP", "auto")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, tp=tp, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    c = compile_cell_program(r, a, program)
+    L, D = cfg.num_layers, cfg.hidden_size
+    widths = {cfg.num_heads * cfg.hd // tp, cfg.num_kv_heads * cfg.hd // tp}
+    layers = a["params"]["layers"]
+    assert {layers[w].q.sharding.shard_shape(layers[w].q.shape)
+            for w in ("wq", "wk", "wv")} == {(L, D, n) for n in widths}
+    # nothing may PRODUCE a whole stack or one layer of it by moving bytes:
+    # a dot's own fusion slices the stack it is handed (a ``dynamic-slice``
+    # inside the fused computation) and names no such result
+    shapes = "|".join(rf"\[{lead},{D},{n}\]"
+                      for lead in (L, 1) for n in sorted(widths))
+    moved = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = s8(?:" + shapes + r")(?:\{[^}]*\})? "
+        r"(copy|copy-start|copy-done|transpose|fusion)\(", c.as_text(), re.M)
+    assert not moved, (
+        f"{program} copies the attention weights before use: {moved}")
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < L * D * min(widths), (
+        f"{program}: temp {temp / 2**20:.0f} MiB holds a weight stack")
