@@ -424,6 +424,18 @@ class ModelRunner:
         self._embed = obs_compile.watch(
             jax.jit(self._embed_fn, static_argnames=("bucket",)), "embed"
         )
+        # the slot's lifecycle on the device, one small program each way
+        # (an eager ``.at[slot].set`` a field was a launch a field, on every
+        # chip, with the device waiting)
+        self._arm_slot = obs_compile.watch(
+            jax.jit(self._arm_slot_fn, donate_argnums=(0, 1)), "arm_slot")
+        self._release_slot = obs_compile.watch(
+            jax.jit(self._release_slot_fn, donate_argnums=(0, 1)),
+            "release_slot")
+        # programs the admission path has launched (arming updates and
+        # prefill dispatches): Scheduler.metrics() sets it against the
+        # admissions made
+        self.admit_programs = 0
         # KV prefix reuse (parity: common_part, grpc-server.cpp:67-74):
         # suffix prefill only pays off past a minimum shared prefix
         self.prefix_reuse_min = 16
@@ -467,6 +479,7 @@ class ModelRunner:
                 self.allocator.attach_tier(
                     tier, pack=self.pack_block, load=self.load_block)
         else:
+            self.block_tables = None    # the slot programs take it as it is
             self.kv = kvc.init_cache(
                 cfg, self.num_slots, self.max_ctx, self.kv_dtype,
                 sharding=self._kv_sharding,
@@ -912,6 +925,42 @@ class ModelRunner:
         )
         return new_kv, new_state, tok[0]
 
+    # -- slot lifecycle programs (both layouts) ---------------------------
+
+    def _arm_slot_fn(self, state: DecodeState, tables, ints, floats,
+                     bias_row, table_row):
+        """Everything an admission writes for its slot outside the prefill
+        itself, in one update: the sampling parameters (``ints`` / ``floats``
+        as ``SamplingParams.pack`` orders them, behind the slot, a seed flag
+        and the seed), the PRNG key where the request brought a seed, the
+        logit-bias row, and on a paged runner the slot's block-table row
+        (``tables`` and ``table_row`` None on a contiguous one)."""
+        slot, seeded, seed = ints[0], ints[1], ints[2]
+        key = jnp.where(seeded > 0, jax.random.key(seed), state.keys[slot])
+        state = dataclasses.replace(
+            state,
+            params=state.params.with_packed(slot, ints[3:], floats),
+            keys=state.keys.at[slot].set(key),
+            bias=state.bias.at[slot].set(bias_row),
+        )
+        if tables is not None:
+            tables = tables.at[slot].set(table_row)
+        return state, tables
+
+    def _release_slot_fn(self, state: DecodeState, tables, slot):
+        """A slot leaves the batch. On a paged runner its device table row
+        also goes back to the trash block, so that the decode programs'
+        static-shape garbage writes cannot touch reallocated blocks, and
+        its frontier to 0; a contiguous slot keeps its frontier, which is
+        what says how many of its rows a later prompt may reuse."""
+        state = dataclasses.replace(
+            state, active=state.active.at[slot].set(False))
+        if tables is not None:
+            state = dataclasses.replace(
+                state, positions=state.positions.at[slot].set(0))
+            tables = tables.at[slot].set(0)
+        return state, tables
+
     # -- paged programs (block-pool KV; engine.paged / kvcache.Paged*) ---
 
     @scoped("decode")
@@ -1289,8 +1338,8 @@ class ModelRunner:
         resident: Optional[list[int]] = None,       # slot's previous tokens
                                                     # (enables prefix reuse)
         valid_n: Optional[int] = None,              # slot's KV frontier, from
-                                                    # a batched slot_positions()
-                                                    # read (None → read it here)
+                                                    # free_frontiers() (None →
+                                                    # read it here)
         reserve_tokens: Optional[int] = None,       # paged mode: worst-case
                                                     # rows (prompt + max_new)
                                                     # to reserve; None → max_ctx
@@ -1304,8 +1353,8 @@ class ModelRunner:
         When ``resident`` is given and shares a long-enough prefix with the
         prompt, the prefix KV is kept and only the tail is prefilled
         (parity: llama.cpp common_part slot reuse, grpc-server.cpp:67-74).
-        Callers that already hold a slot_positions() snapshot pass
-        ``valid_n`` so admission stays a single device sync."""
+        Callers that already hold a free_frontiers() snapshot pass
+        ``valid_n``, so that a contiguous admission reads the device once."""
         if not prompt:
             prompt = [0]
         n = len(prompt)
@@ -1346,13 +1395,14 @@ class ModelRunner:
                   else self.bucket_for(n))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(tail)] = tail
-        self._prepare_slot(
+        self._arm(self._arm_args(
             slot, temperature=temperature, top_k=top_k, top_p=top_p,
             min_p=min_p, repeat_penalty=repeat_penalty,
             presence_penalty=presence_penalty,
             frequency_penalty=frequency_penalty,
             seed=seed, logit_bias=logit_bias, bias_row=bias_row,
-        )
+        ))
+        self.admit_programs += 1        # the prefill below
         n_seq = self.mesh.shape.get("seq", 1) if self.mesh is not None else 1
         use_sp = (
             self.sp_enabled and not lcp and mm_embeds is None
@@ -1396,30 +1446,16 @@ class ModelRunner:
         with self.watchdog.guard("device"):
             return int(tok)  # jaxlint: disable=host-sync-in-hot-path
 
-    def _prepare_slot(self, slot: int, *, temperature=None, top_k=None,
-                      top_p=None, min_p=None, repeat_penalty=None,
-                      presence_penalty=None, frequency_penalty=None,
-                      seed=None, logit_bias=None, bias_row=None) -> None:
-        """Per-slot sampling params + PRNG seed + logit-bias row — the
-        admission preamble shared by the contiguous and paged paths."""
-        self.state = dataclasses.replace(
-            self.state,
-            params=self.state.params.with_slot(
-                slot,
-                temperature=temperature,
-                top_k=top_k,
-                top_p=top_p,
-                min_p=min_p,
-                repeat_penalty=repeat_penalty,
-                presence_penalty=presence_penalty,
-                frequency_penalty=frequency_penalty,
-            ),
-        )
-        if seed is not None:
-            self.state = dataclasses.replace(
-                self.state,
-                keys=self.state.keys.at[slot].set(jax.random.key(seed)),
-            )
+    def _arm_args(self, slot: int, *, seed=None, logit_bias=None,
+                  bias_row=None, **sampling) -> tuple:
+        """The host's half of arming a slot: ``_arm_slot_fn``'s arguments
+        after the state and the tables, but for the table row. Sampling
+        parameters left None reset to the engine's defaults, so a reused
+        slot never inherits the previous request's; the seed is cut to
+        32 bits as ``jax.random.key`` cuts a Python int."""
+        ints, floats = smp.SamplingParams.pack(**sampling)
+        head = [slot, seed is not None,
+                np.int64(seed or 0).astype(np.int32)]
         if bias_row is not None:
             row = np.asarray(bias_row, np.float32).copy()
         else:
@@ -1428,7 +1464,14 @@ class ModelRunner:
             for tid, b in logit_bias.items():
                 if 0 <= int(tid) < self.cfg.vocab_size:
                     row[int(tid)] += b
-        self.set_bias(slot, row)
+        return (np.concatenate([np.array(head, np.int32), ints]), floats,
+                row)
+
+    def _arm(self, args: tuple, table_row=None) -> None:
+        """Dispatch the one program that arms a slot (``_arm_slot_fn``)."""
+        self.state, self.block_tables = self._arm_slot(
+            self.state, self.block_tables, *args, table_row)
+        self.admit_programs += 1
 
     # -- paged admission (chunked prefill; engine.paged) -----------------
 
@@ -1442,9 +1485,10 @@ class ModelRunner:
         **sampling,
     ) -> Optional["PagedAdmission"]:
         """Start a chunked paged admission: reserve blocks (sharing pooled
-        prefix blocks where the prompt allows), arm the slot's sampling
-        state, and return a PagedAdmission whose ``step_chunk()`` the
-        caller drives — interleaving chunk dispatches with decode
+        prefix blocks where the prompt allows), prepare on the host what
+        arms the slot (dispatched with the final chunk: nothing runs on the
+        device here), and return a PagedAdmission whose ``launch_chunk()``
+        the caller drives — interleaving chunk dispatches with decode
         dispatches so one long prompt never stalls other slots' TPOT.
         ``spec_tokens`` reserves extra speculation rows past the decode
         worst case (a draft window writes up to gamma rows beyond the
@@ -1515,8 +1559,8 @@ class ModelRunner:
                   and self.bucket_for(n) % n_seq == 0)
         if use_sp:
             self.last_prefill_path = "paged_sp"
-        self._prepare_slot(slot, **sampling)
         return PagedAdmission(self, slot, list(prompt), lcp,
+                              self._arm_args(slot, **sampling),
                               mm_embeds=mm_embeds,
                               mm_positions=mm_positions, sp=use_sp)
 
@@ -1526,11 +1570,11 @@ class ModelRunner:
 
     def _finish_paged_admit(self, slot: int, prompt: list[int],
                             mm: bool) -> None:
-        """Final-chunk bookkeeping: expose the block table to the decode
-        programs, publish the prompt's full blocks to the prefix pool
-        (their contents are dispatched by now; token-keyed sharing is
-        meaningless for multimodal prompts), mark the slot live."""
-        self._install_table_row(slot)
+        """Final-chunk bookkeeping, all of it the host's (the arming update
+        exposed the block table to the decode programs): publish the
+        prompt's full blocks to the prefix pool (their contents are
+        dispatched by now; token-keyed sharing is meaningless for
+        multimodal prompts), mark the slot live."""
         if not mm:
             self.allocator.register_prefix(slot, prompt)
         self._loaded_rows.pop(slot, None)
@@ -1713,22 +1757,14 @@ class ModelRunner:
         )
 
     def release(self, slot: int) -> None:
-        self.state = dataclasses.replace(
-            self.state, active=self.state.active.at[slot].set(False)
-        )
         if self.paged:
             # free the slot's blocks (prompt blocks registered in the
-            # prefix pool survive as reclaimable cache) and point the
-            # device table row at the trash block so the decode programs'
-            # static-shape garbage writes can't touch reallocated blocks
+            # prefix pool survive as reclaimable cache); the program below
+            # points the device table row at the trash block
             self.allocator.release(slot)
             self._loaded_rows.pop(slot, None)
-            self.block_tables = self.block_tables.at[slot].set(
-                jnp.zeros(self.max_blocks, jnp.int32))
-            self.state = dataclasses.replace(
-                self.state,
-                positions=self.state.positions.at[slot].set(0),
-            )
+        self.state, self.block_tables = self._release_slot(
+            self.state, self.block_tables, np.int32(slot))
         self._active_slots.discard(slot)
         if slot not in self._free_slots:
             self._free_slots.append(slot)
@@ -1739,12 +1775,28 @@ class ModelRunner:
         # device round-trip (and no stall behind in-flight decodes)
         return bool(self._active_slots)
 
+    def free_frontiers(self) -> np.ndarray:
+        """[S] KV frontier of every FREE slot (entries of slots in use mean
+        nothing): how many of a slot's rows a new prompt could reuse. The
+        admission path ranks the free slots by it. A paged runner knows it
+        on the host: a released slot's blocks are gone (0), and a free
+        slot holds rows only where ``load_prefix`` has just put them
+        (``_loaded_rows``), exactly what the device's ``positions`` say
+        there. A contiguous runner's rows outlive a release, and only the
+        device knows how far a finished stream got: the read stays."""
+        if not self.paged:
+            return self.slot_positions()
+        out = np.zeros(self.num_slots, np.int32)
+        for slot, n in self._loaded_rows.items():
+            out[slot] = n
+        return out
+
     def slot_positions(self) -> np.ndarray:
-        """Every slot's KV frontier in ONE [S] transfer. The scheduler's
-        admit path ranks ALL free slots by reusable prefix; per-slot
-        int() reads would multiply the device sync by the candidate
-        count."""
-        # single batched admit-time read — the one deliberate sync here
+        """Every slot's KV frontier as the device holds it, in ONE [S]
+        transfer: a BLOCKING read of the state the newest dispatch returns,
+        so it waits for every dispatch in flight and leaves the device with
+        nothing queued. For tests, tools and the contiguous layout's
+        admission (``free_frontiers``); never on a paged admission."""
         with self.watchdog.guard("device"):
             return np.asarray(  # jaxlint: disable=host-sync-in-hot-path
                 self.state.positions
@@ -1956,31 +2008,33 @@ class ModelRunner:
 class PagedAdmission:
     """One in-flight chunked paged admission (ModelRunner.begin_admit).
 
-    The scheduler drives ``step_chunk()`` from its engine loop,
+    The scheduler drives ``launch_chunk()`` from its engine loop,
     interleaving chunk dispatches with decode dispatches; direct callers
-    (bench, tests) just loop it. Only the FINAL chunk samples — it
-    installs the slot's device block-table row, publishes prompt blocks
-    to the prefix pool, arms the slot, and returns the first token."""
+    (tests, tools) loop ``step_chunk()``. Only the FINAL chunk samples: the
+    one arming update goes out in front of it (sampling state, bias row,
+    the slot's device block-table row), the chunk arms the slot on the
+    device, and the first token's copy to the host starts at once
+    (``first``). No call here waits for the device but ``first_token()``."""
 
     def __init__(self, runner: ModelRunner, slot: int, prompt: list[int],
-                 start: int, mm_embeds=None, mm_positions=None,
-                 sp: bool = False):
+                 start: int, arm_args: tuple, mm_embeds=None,
+                 mm_positions=None, sp: bool = False):
         self.runner = runner
         self.slot = slot
         self.prompt = prompt
         self.pos = start                     # next position to prefill
         self.prefix_reused = start           # shared/loaded rows (telemetry)
         self.path = runner.last_prefill_path
+        self.arm_args = arm_args             # ModelRunner._arm_args
         self.mm = mm_embeds is not None and len(mm_embeds) > 0
         self.mm_embeds = mm_embeds
         self.mm_positions = mm_positions
         self.sp = sp                         # ring-attention one-shot path
-        self.first_token: Optional[int] = None
+        self.first: Optional[jax.Array] = None   # the final chunk's sample
         self.done = False
-        # dispatch-anatomy scratch for the last step_chunk() call: enqueue
-        # span vs the final chunk's first-token fetch (obs.anatomy)
+        # dispatch-anatomy scratch: the last launch_chunk()'s enqueue span
+        # (obs.anatomy)
         self.last_launch_ms = 0.0
-        self.last_sync_ms = 0.0
 
     @property
     def chunks_remaining(self) -> int:
@@ -1994,15 +2048,24 @@ class PagedAdmission:
     def _counts_row(self) -> np.ndarray:
         return _prompt_counts_row(self.runner.cfg.vocab_size, self.prompt)
 
-    def step_chunk(self) -> Optional[int]:
-        """Dispatch the next prefill chunk; returns the first sampled
-        token once the admission is complete, else None."""
+    def launch_chunk(self) -> bool:
+        """Dispatch the next prefill chunk without waiting for it; True when
+        it was the final one (``first`` then holds the sampled token, on its
+        way to the host). Arguments go up as host arrays: the dispatch's
+        own transfer, no program of their own."""
         assert not self.done
         r = self.runner
-        slot = self.slot
+        slot = np.int32(self.slot)
         n = len(self.prompt)
         t0 = time.perf_counter()
-        table_row = jnp.asarray(r.allocator.table_row(slot))
+        table_row = np.asarray(r.allocator.table_row(self.slot), np.int32)
+        rem = n - self.pos
+        last = self.sp or self.mm or rem <= r.prefill_chunk
+        if last:
+            # before the chunk that samples with them; not earlier, for a
+            # decode step between two chunks must still find the slot's
+            # device table row on the trash block
+            r._arm(self.arm_args, table_row)
         if self.sp:
             # ring attention over the 'seq' mesh axis, scattered straight
             # into the reserved blocks — the whole prompt in one dispatch
@@ -2010,55 +2073,59 @@ class PagedAdmission:
             padded = np.zeros(bucket, np.int32)
             padded[:n] = self.prompt
             r.kv, r.state, tok = r._prefill_paged_sp(
-                r.params, r.kv, r.state, jnp.asarray(padded), jnp.int32(n),
-                table_row, jnp.int32(slot),
-                jnp.asarray(self._counts_row()), bucket=bucket,
+                r.params, r.kv, r.state, padded, np.int32(n),
+                table_row, slot, self._counts_row(), bucket=bucket,
             )
             self.pos = n
-            last = True
         elif self.mm:
             bucket = r.bucket_for(n)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = self.prompt
             r.kv, r.state, tok = r._prefill_paged_mm(
-                r.params, r.kv, r.state, jnp.asarray(padded), jnp.int32(n),
-                table_row, jnp.int32(slot),
+                r.params, r.kv, r.state, padded, np.int32(n),
+                table_row, slot,
                 jnp.asarray(self.mm_embeds, jnp.float32),
                 jnp.asarray(self.mm_positions, jnp.int32),
-                jnp.asarray(self._counts_row()), bucket=bucket,
+                self._counts_row(), bucket=bucket,
             )
             self.pos = n
-            last = True
         else:
-            rem = n - self.pos
             take = min(rem, r.prefill_chunk)
-            last = take == rem
             bucket = r.bucket_for(take)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :take] = self.prompt[self.pos:self.pos + take]
-            crow = (jnp.asarray(self._counts_row()) if last
+            crow = (self._counts_row() if last
                     else r._zero_counts)  # sample=False ignores counts
             r.kv, r.state, tok = r._prefill_paged(
-                r.params, r.kv, r.state, jnp.asarray(padded),
-                jnp.int32(take), jnp.int32(self.pos), table_row,
-                jnp.int32(slot), crow, bucket=bucket,
+                r.params, r.kv, r.state, padded,
+                np.int32(take), np.int32(self.pos), table_row,
+                slot, crow, bucket=bucket,
                 sample=last,
             )
             self.pos += take
-        if not last:
-            # pure async enqueue — no sync on intermediate chunks
-            self.last_launch_ms = (time.perf_counter() - t0) * 1e3
-            self.last_sync_ms = 0.0
-            return None
-        self.done = True
-        r._finish_paged_admit(slot, self.prompt, mm=self.mm)
-        # the admit-time prefill/decode handoff sync, same as admit()
-        t1 = time.perf_counter()
-        with r.watchdog.guard("device"):
-            self.first_token = int(tok)  # jaxlint: disable=host-sync-in-hot-path
-        self.last_launch_ms = (t1 - t0) * 1e3
-        self.last_sync_ms = (time.perf_counter() - t1) * 1e3
-        return self.first_token
+        r.admit_programs += 1
+        if last:
+            self.done = True
+            self.first = tok
+            try:
+                tok.copy_to_host_async()
+            except AttributeError:
+                pass
+            r._finish_paged_admit(self.slot, self.prompt, mm=self.mm)
+        self.last_launch_ms = (time.perf_counter() - t0) * 1e3
+        return last
+
+    def first_token(self) -> int:
+        """The first sampled token, on the host: waits for the final chunk
+        (guarded: a device that never answers would hang here silently)."""
+        with self.runner.watchdog.guard("device"):
+            return int(self.first)  # jaxlint: disable=host-sync-in-hot-path
+
+    def step_chunk(self) -> Optional[int]:
+        """Dispatch the next chunk; the first token once the admission is
+        complete (waiting for it), else None. The one-call form for
+        callers with nothing to overlap."""
+        return self.first_token() if self.launch_chunk() else None
 
     def abort(self) -> None:
         """Abandon a part-way admission (client cancelled while chunks

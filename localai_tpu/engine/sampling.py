@@ -24,6 +24,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from localai_tpu.obs.profiler import scoped
 
@@ -83,6 +84,31 @@ class SamplingParams:
                 val = self.DEFAULTS[f.name]
             out[f.name] = arr.at[slot].set(jnp.asarray(val, arr.dtype))
         return SamplingParams(**out)
+
+    @classmethod
+    def pack(cls, **kw) -> tuple[np.ndarray, np.ndarray]:
+        """One slot's values as two host vectors, (i32, f32), each in field
+        order, None -> the engine default: the arguments of ONE program
+        that arms a slot (``with_packed``) in place of a dispatch a field.
+        A value its field cannot hold raises here, on the host."""
+        ints, floats = [], []
+        for f in dataclasses.fields(cls):
+            val = kw.get(f.name)
+            if val is None:
+                val = cls.DEFAULTS[f.name]
+            if isinstance(cls.DEFAULTS[f.name], int):
+                ints.append(np.int32(val))
+            else:
+                floats.append(np.float32(val))
+        return np.array(ints, np.int32), np.array(floats, np.float32)
+
+    def with_packed(self, slot, ints, floats) -> "SamplingParams":
+        """``with_slot`` from ``pack``'s vectors (traced, inside a program)."""
+        ints, floats = iter(ints), iter(floats)
+        return self.with_slot(slot, **{
+            f.name: next(ints if isinstance(self.DEFAULTS[f.name], int)
+                         else floats)
+            for f in dataclasses.fields(self)})
 
 
 def apply_penalties(

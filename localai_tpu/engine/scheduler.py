@@ -219,13 +219,31 @@ class GenHandle:
 class _PendingPrefill:
     """A chunked paged admission in flight: the engine loop dispatches one
     chunk per iteration (interleaved with decode dispatches) until the
-    final chunk samples the first token and the slot goes live."""
+    final chunk samples the first token and arms the slot on the device;
+    the token itself comes back through the pipeline (``_Dispatch.first``)."""
 
     slot: int
     handle: GenHandle
     adm: Any                 # engine.runner.PagedAdmission
     base: Optional[np.ndarray]
     mask_set: bool
+
+
+@dataclasses.dataclass
+class _Dispatch:
+    """One dispatch whose result the host has not read: its copy to the
+    host started at launch, and the engine loop reads them in the order
+    they were launched."""
+
+    toks: Any                # the device array the host will read
+    seq: int                 # dispatch counter at launch
+    k: int                   # decode steps; 0 = a speculative window
+    pipelined: bool          # launched behind another dispatch
+    t_issue: float
+    fresh: bool              # first dispatch of its shape: pays a compile
+    # a final prefill chunk (``toks`` its one sampled token, k = 0): the
+    # admission whose first token this is
+    first: Optional[_PendingPrefill] = None
 
 
 @dataclasses.dataclass
@@ -363,6 +381,13 @@ class Scheduler:
         self._chunked = bool(getattr(runner, "paged", False))
         self._prefills: "deque[_PendingPrefill]" = deque()
         self.total_prefill_chunks = 0
+        # what an admission costs the device besides its prefill: the
+        # admissions made, and the times the admission path read the device
+        # and WAITED (a frontier read to pick a slot, a first token the
+        # host had to see before the next dispatch); the programs it
+        # launched are the runner's count (``admit_programs``)
+        self.total_admissions = 0
+        self.total_admit_blocking_reads = 0
         # a request the paged block pool couldn't cover yet: admission is
         # FIFO, so it parks here (not back in the queue) until blocks free
         self._held: Optional[GenHandle] = None
@@ -596,6 +621,12 @@ class Scheduler:
             "total_prompt_tokens": totals["prompt"],
             "total_generated_tokens": totals["generated"],
             "prefix_tokens_reused": self.runner.total_prefix_reused,
+            # the admission path (engine-thread counters, read unlocked
+            # like the flight ring's): blocking reads and programs are
+            # set against admissions by whoever reads them
+            "admissions": self.total_admissions,
+            "admit_blocking_reads": self.total_admit_blocking_reads,
+            "admit_programs": getattr(self.runner, "admit_programs", 0),
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -998,10 +1029,19 @@ class Scheduler:
         # program the UNconstrained slots still ride the same dispatch for
         # multi_step tokens (one tool-call request no longer de-pipelines
         # the whole batch).
-        inflight: deque[tuple[Any, int, int, bool, float, bool]] = deque()
+        #
+        # An admission's first token rides the same pipeline: the final
+        # prefill chunk arms its slot ON THE DEVICE, so the decode step
+        # that follows is launched behind it at once and the chunk's one
+        # sampled token is read here in its turn — after the dispatches
+        # that were in flight when the request arrived (their tokens go out
+        # on time), before the decode steps launched behind it.
+        inflight: deque[_Dispatch] = deque()
 
         def drain_one() -> None:
-            toks, seq, k, pipelined, t_issue, fresh = inflight.popleft()
+            d = inflight.popleft()
+            toks, seq, k, pipelined, t_issue, fresh = (
+                d.toks, d.seq, d.k, d.pipelined, d.t_issue, d.fresh)
             # the designed drain point: copy_to_host_async started this
             # D2H at dispatch time, so materializing here overlaps with
             # the next dispatch already running on device. Watchdog-guarded:
@@ -1019,6 +1059,12 @@ class Scheduler:
                 raise _EngineAbandoned
             now = time.monotonic()
             sync_s = now - t_sync
+            if d.first is not None:
+                # a final prefill chunk: the device has just finished it,
+                # so the decode step behind it is timed from here
+                self._last_drain_t = now
+                self._first_token(d.first, int(rows))
+                return
             window = None
             if k == 0 and self.spec is not None:  # speculative window
                 window = self.spec.observe_window(rows)
@@ -1095,7 +1141,24 @@ class Scheduler:
             chunked = False
             if self._prefills:
                 with TraceAnnotation("sched.prefill_chunk"):
-                    chunked = self._step_prefill_chunk()
+                    chunked, entry = self._step_prefill_chunk()
+                if entry is not None:
+                    entry.pipelined = bool(inflight)
+                    # the host waits for the token only where the next
+                    # dispatch needs it, by what the request carries: an
+                    # FSM whose mask feeds the next step, a drafter that
+                    # proposes from drained history. Everything launched
+                    # before the chunk is read first, in order.
+                    wait = (entry.first.handle.request.constraint is not None
+                            or (self.spec is not None
+                                and not self.spec.pipeline_safe))
+                    if wait:
+                        self.total_admit_blocking_reads += 1
+                        while inflight:
+                            drain_one()
+                    inflight.append(entry)
+                    if wait:
+                        drain_one()
             if not self._slots:
                 self._last_drain_t = None  # idle gap would pollute the EMA
                 if inflight:
@@ -1183,9 +1246,11 @@ class Scheduler:
                     # decode — the whole drain/resync/propose sequence
                     # is only worth paying when a window could land
                     spec_ready = self._spec_ready()
-                    if spec_ready and self._spec_dirty and inflight:
+                    if (spec_ready and (self._spec_dirty or self._spec_stale)
+                            and inflight):
                         # a resync must see the COMPLETE resident record
-                        # — drain the in-flight plain dispatches before
+                        # — drain the in-flight plain dispatches (and a
+                        # freshly armed slot's first token) before
                         # rebuilding drafts
                         drain_one()
                         continue
@@ -1226,9 +1291,10 @@ class Scheduler:
                         # k=0 marks a spec window: rows carry SKIP
                         # sentinels; the drain folds the real token yield
                         # into the flight ring + step-time EMA
-                        inflight.append((spec_rows, self._dispatch_seq, 0,
-                                         bool(inflight), t_issue, fresh))
-                        if len(inflight) >= self.pipeline_depth:
+                        inflight.append(_Dispatch(
+                            spec_rows, self._dispatch_seq, 0,
+                            bool(inflight), t_issue, fresh))
+                        while len(inflight) >= self.pipeline_depth:
                             drain_one()
                         continue
                     if self.spec is not None:
@@ -1249,9 +1315,15 @@ class Scheduler:
                             pass
                     # anatomy: async enqueue span (jit call + D2H start)
                     self._anat_launch_s += time.monotonic() - t_issue
-                    inflight.append((tokens, self._dispatch_seq, steps,
-                                     bool(inflight), t_issue, fresh))
-                    if len(inflight) >= self.pipeline_depth:
+                    inflight.append(_Dispatch(
+                        tokens, self._dispatch_seq, steps,
+                        bool(inflight), t_issue, fresh))
+                    # with a final chunk's entry in the queue this reads
+                    # more than one: the step that was in flight at the
+                    # arrival (on time), then the chunk's token as soon as
+                    # the chunk is done, the step just launched queued
+                    # behind it on the device
+                    while len(inflight) >= self.pipeline_depth:
                         drain_one()
             except _EngineAbandoned:
                 raise
@@ -1409,8 +1481,8 @@ class Scheduler:
             if self._held is not None:
                 if (not self._held.cancelled
                         and not self._reservation_fits(self._held.request)):
-                    # still no room — skip the (vocab-row + cache-scan +
-                    # device-read) admission preamble entirely; this runs
+                    # still no room — skip the (vocab-row + cache-scan)
+                    # admission preamble entirely; this runs
                     # every engine iteration while parked, exactly under
                     # saturation. A cancelled parked request falls through
                     # to the cancelled check below and is dropped now —
@@ -1431,7 +1503,7 @@ class Scheduler:
             if not self._reservation_fits(handle.request):
                 # block pool can't cover the reservation yet: park the
                 # request BEFORE the admission preamble (bias row, prompt
-                # cache scan, slot_positions device read) so saturation
+                # cache scan) so saturation
                 # costs host arithmetic only. Interactive requests hold
                 # their place (FIFO); a batch request goes back to its own
                 # lane so it can never block interactive admissions.
@@ -1443,10 +1515,10 @@ class Scheduler:
             # prefer the free slot whose resident tokens share the longest
             # prefix with this prompt (KV prefix-cache reuse); the loop
             # guard guarantees a free slot exists (slot lists are mutated
-            # only on this thread). One batched [S] positions read serves
-            # the whole ranking + admit — free slots' frontiers are frozen
+            # only on this thread). One [S] snapshot of the free slots'
+            # frontiers serves the whole ranking + admit — they are frozen
             # until we prefill them, so the snapshot stays valid.
-            positions = self._engine.slot_positions()
+            positions = self._free_frontiers()
             slot = self._engine.acquire_slot(
                 self._best_slot(handle.request.prompt, positions)
             )
@@ -1498,7 +1570,7 @@ class Scheduler:
         )
         resident = self._resident.get(slot)
         if positions is None:
-            positions = self._engine.slot_positions()
+            positions = self._free_frontiers()
         valid_n = int(positions[slot])
         rows = getattr(self._engine, "resident_rows", None)
         if rows is not None:
@@ -1552,6 +1624,7 @@ class Scheduler:
                 return False
             handle.admit_index = self._admit_seq
             self._admit_seq += 1
+            self.total_admissions += 1
             self.telemetry.admitted(
                 handle.trace, slot=slot,
                 queue_wait=time.monotonic() - handle.t_submit,
@@ -1564,6 +1637,9 @@ class Scheduler:
             return True
         handle.admit_index = self._admit_seq  # engine thread is sole writer
         self._admit_seq += 1
+        self.total_admissions += 1
+        # a one-shot admission returns its first token: the host waits
+        self.total_admit_blocking_reads += 1
         self.telemetry.admitted(
             handle.trace, slot=slot,
             queue_wait=time.monotonic() - handle.t_submit,
@@ -1575,14 +1651,19 @@ class Scheduler:
             path=self.runner.last_prefill_path,
             prefix_reused=self._engine.last_prefix_reused,
         )
-        self._activate_slot(slot, handle, base, mask is not None, int(first))
+        self._consume(
+            slot, self._install_slot(slot, handle, base, mask is not None),
+            int(first))
         return True
 
-    def _activate_slot(self, slot: int, handle: GenHandle,
-                       base: Optional[np.ndarray], mask_set: bool,
-                       first: int) -> None:
-        """Prefill finished (one-shot or final chunk): record the resident
-        tokens, install the live slot context, consume the first token."""
+    def _install_slot(self, slot: int, handle: GenHandle,
+                      base: Optional[np.ndarray],
+                      mask_set: bool) -> _SlotCtx:
+        """The slot's prefill is dispatched to its end (one-shot, or the
+        final chunk, which arms the slot on the device): record the
+        resident tokens and install the live slot context. Rows of
+        dispatches issued up to now are not this request's
+        (``admit_seq``); its first token is consumed when it is read."""
         req = handle.request
         # multimodal KV mixes injected embeddings with token ids, so the
         # token record alone can't prove prefix equality — never reuse it.
@@ -1608,7 +1689,21 @@ class Scheduler:
             # slot's draft stale so the drafter is seeded from the
             # resident record before the next speculative window
             self._spec_stale.add(slot)
-        self._consume(slot, ctx, first)
+        return ctx
+
+    # engine-thread only (called from _run_loop's drain) — see _run_loop
+    def _first_token(  # jaxlint: disable=lock-guarded-attr
+            self, pf: _PendingPrefill, token_id: int) -> None:
+        """A chunked admission's first token is on the host: the end of its
+        ``prefill`` span, and the first token its stream consumes."""
+        self.telemetry.prefill_done(
+            pf.handle.trace,
+            path=getattr(pf.adm, "path", "paged"),
+            prefix_reused=pf.adm.prefix_reused,
+        )
+        ctx = self._slots.get(pf.slot)
+        if ctx is not None and ctx.handle is pf.handle:
+            self._consume(pf.slot, ctx, token_id)
 
     def _reservation_fits(self, req: GenRequest) -> bool:
         """Host-arithmetic estimate of whether ``req``'s block reservation
@@ -1630,14 +1725,19 @@ class Scheduler:
         need = alloc.blocks_for(reserve) - len(alloc.match_prefix(req.prompt))
         return alloc.stats().available >= need
 
-    def _step_prefill_chunk(self) -> bool:
-        """Dispatch ONE pending prefill chunk (FIFO across admissions) and
-        finalize the admission on its final chunk. Returns True if a chunk
-        was dispatched. The flight record tags these dispatches as
-        ``prefill_chunk`` with steps=0, keeping them out of the decode
-        step-time percentiles while /debug/flight still shows them."""
+    def _step_prefill_chunk(self) -> tuple[bool, Optional[_Dispatch]]:
+        """Dispatch ONE pending prefill chunk (FIFO across admissions).
+        Returns (whether anything was done, the final chunk's entry for the
+        pipeline). Nothing here waits for the device: a final chunk arms
+        its slot there, so the slot's context is installed at once and the
+        chunk's sampled token is read in its turn (``_first_token``). The
+        flight record tags these dispatches as ``prefill_chunk`` with
+        steps=0, keeping them out of the decode step-time percentiles
+        while /debug/flight still shows them; it accounts the launch
+        alone (``sync_ms`` 0: the wait for a first token, where there is
+        one, lies in the engine loop's drain)."""
         if not self._prefills:
-            return False
+            return False, None
         pf = self._prefills[0]
         if pf.handle.cancelled:
             self._prefills.popleft()
@@ -1646,46 +1746,49 @@ class Scheduler:
                 self.total_preemptions += 1
             self.telemetry.finished(pf.handle.trace, pf.handle, "cancelled")
             pf.handle._finish("cancelled")
-            return True
+            return True, None
         t0 = time.monotonic()
-        first = pf.adm.step_chunk()
+        last = pf.adm.launch_chunk()
+        entry = None
+        if last:
+            self._prefills.popleft()
+            entry = _Dispatch(pf.adm.first, self._dispatch_seq, 0, False,
+                              t0, False, first=pf)
+            self._install_slot(pf.slot, pf.handle, pf.base, pf.mask_set)
         dt = time.monotonic() - t0
         self.total_prefill_chunks += 1
-        # anatomy: the admission object split its own wall into enqueue
-        # vs the final chunk's first-token fetch; the remainder of THIS
-        # span is chunk staging (sched). Pre-built phases so the chunk
-        # does not consume accumulators owed to the next decode record —
-        # and its whole span becomes overlap there (no double count).
+        # anatomy: the admission object measured its own enqueue span; the
+        # remainder of THIS span is chunk staging and slot bookkeeping
+        # (sched). Pre-built phases so the chunk does not consume
+        # accumulators owed to the next decode record — and its whole span
+        # becomes overlap there (no double count).
         wall_ms = max(0.0, dt) * 1e3
-        sync_ms = min(getattr(pf.adm, "last_sync_ms", 0.0), wall_ms)
-        launch_ms = min(getattr(pf.adm, "last_launch_ms", 0.0),
-                        wall_ms - sync_ms)
+        launch_ms = min(getattr(pf.adm, "last_launch_ms", 0.0), wall_ms)
         self._flight_record(
             "prefill_chunk", 0, dt, False,
-            phases={"gap_ms": 0.0,
-                    "sched_ms": wall_ms - sync_ms - launch_ms,
-                    "launch_ms": launch_ms, "sync_ms": sync_ms})
+            phases={"gap_ms": 0.0, "sched_ms": wall_ms - launch_ms,
+                    "launch_ms": launch_ms, "sync_ms": 0.0})
         self._anat_overlap_s += dt
-        if first is None:
-            return True
-        self._prefills.popleft()
-        self.telemetry.prefill_done(
-            pf.handle.trace,
-            path=getattr(pf.adm, "path", "paged"),
-            prefix_reused=pf.adm.prefix_reused,
-        )
-        self._activate_slot(pf.slot, pf.handle, pf.base, pf.mask_set, first)
-        return True
+        return True, entry
+
+    def _free_frontiers(self) -> np.ndarray:
+        """[S] KV frontier of every free slot. A paged runner answers from
+        the host (its free slots hold what ``load_prefix`` put there, else
+        nothing); a contiguous one must read the device, which waits for
+        every dispatch in flight and is counted."""
+        if not self._chunked:
+            self.total_admit_blocking_reads += 1
+        return self._engine.free_frontiers()
 
     def _best_slot(self, prompt: list[int],
                    positions: Optional[np.ndarray] = None) -> Optional[int]:
         """Free slot with the longest reusable token prefix (None → FIFO).
         Uses the runner's own feasibility gates so the ranking can't pick a
         slot whose reuse collapses to zero at admit time. ``positions`` is
-        the batched slot_positions() snapshot — passing valid_n explicitly
+        the ``_free_frontiers()`` snapshot — passing valid_n explicitly
         keeps this loop free of per-candidate device syncs."""
         if positions is None:
-            positions = self._engine.slot_positions()
+            positions = self._free_frontiers()
         best, best_lcp = None, 0
         for s in self._engine.free_slots():
             r = self._resident.get(s)

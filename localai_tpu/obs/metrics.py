@@ -215,6 +215,22 @@ class Registry:
             "localai_decode_dispatches_total",
             "Compiled decode programs dispatched by the engine thread",
         )
+        self.admissions = Counter(
+            "localai_admissions_total",
+            "Requests admitted into a slot by the engine thread",
+        )
+        self.admit_blocking_reads = Counter(
+            "localai_admit_blocking_reads_total",
+            "Times the admission path read the device and waited for it "
+            "(a frontier read to pick a slot, a first token the host "
+            "needed before the next dispatch): 0 an admission on a paged "
+            "engine, but for constrained requests and host drafters",
+        )
+        self.admit_programs = Counter(
+            "localai_admit_programs_total",
+            "Programs the admission path launched (the arming update and "
+            "the prefill dispatches): 1 + its chunks an admission",
+        )
         self.prompt_cache_hits = Counter(
             "localai_prompt_cache_hits_total",
             "Disk prompt-KV cache lookups that returned a usable prefix",
@@ -679,6 +695,11 @@ def update_engine_gauges(name: str, m: dict,
         reg.kv_tier_reloads.set_total(
             m.get("kv_tier_reloads", 0), model=name)
     reg.decode_dispatches.set_total(m.get("dispatches", 0), model=name)
+    if "admissions" in m:
+        reg.admissions.set_total(m["admissions"], model=name)
+        reg.admit_blocking_reads.set_total(
+            m.get("admit_blocking_reads", 0), model=name)
+        reg.admit_programs.set_total(m.get("admit_programs", 0), model=name)
     if m.get("shed_total"):
         # shed admissions are whole-request waste (no tokens were ever
         # generated); the requests_shed family stays owned by obs.slo —

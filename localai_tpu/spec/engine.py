@@ -285,6 +285,9 @@ class SpecEngine:
     def load_prefix(self, slot: int, arrays: dict, n: int) -> bool:
         return self.target.load_prefix(slot, arrays, n)
 
+    def free_frontiers(self) -> np.ndarray:
+        return self.target.free_frontiers()
+
     def slot_positions(self) -> np.ndarray:
         return self.target.slot_positions()
 

@@ -486,6 +486,14 @@ def test_paged_metrics_export_block_gauges(tiny):
         assert 'localai_kv_blocks_free{model="tiny"}' in text
         assert 'localai_kv_blocks_used{model="tiny"}' in text
         assert 'localai_prefill_chunk_queue_depth{model="tiny"}' in text
+        # the admission path's counters: one admission, no blocking device
+        # read on it, the arming update and one chunk
+        assert (m["admissions"], m["admit_blocking_reads"],
+                m["admit_programs"]) == (1, 0, 2)
+        for name, value in (("admissions", 1), ("admit_blocking_reads", 0),
+                            ("admit_programs", 2)):
+            assert (f'localai_{name}_total{{model="tiny"}} {value}'
+                    in text), name
     finally:
         s.shutdown()
 

@@ -519,3 +519,380 @@ def test_streaming_request_bounds_delivery_lag(sched):
     # via median): the old fixed 16×2 dispatch would burst, not trickle
     gaps.sort()
     assert gaps[len(gaps) // 2] < 1.0
+
+
+# ---------------------------------------------------------------------------
+# the paged admission path: no blocking device read, one program to arm a
+# slot, the first token back through the pipeline
+
+
+PAGED_KW = dict(num_slots=4, max_ctx=96, prefill_buckets=[16, 32],
+                kv_dtype="float32", paged=True, kv_block_tokens=16,
+                prefill_chunk=16)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return resolve_model("debug:tiny", dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def paged(tiny):
+    """A paged scheduler whose runner refuses the blocking frontier read,
+    and a second runner of the same shape for one-request-at-a-time
+    references."""
+    runner = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+
+    def no_read():
+        raise AssertionError("slot_positions(): a blocking device read on "
+                             "a paged admission")
+
+    runner.slot_positions = no_read
+    s = Scheduler(runner, ByteTokenizer(), multi_step=4)
+    yield s, ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    s.shutdown()
+
+
+GREEDY = dict(temperature=0.0)
+SEEDED = dict(temperature=0.8, top_p=0.95, seed=2147503333)
+
+
+def _alone(ref: ModelRunner, text: str, n: int, sampling: dict,
+           logit_bias=None) -> list[int]:
+    """The request's tokens served alone and synchronously: admit() then
+    step(), each read at once. The scheduler bans the ids the byte
+    tokenizer cannot produce; so does this."""
+    row = np.zeros(ref.cfg.vocab_size, np.float32)
+    row[ByteTokenizer().vocab_size:] = -1e30
+    slot = ref.acquire_slot()
+    out = [ref.admit(slot, ByteTokenizer().encode(text), bias_row=row,
+                     logit_bias=logit_bias, **sampling)]
+    while len(out) < n:
+        out.append(int(ref.step()[slot]))
+    ref.release(slot)
+    return out
+
+
+def _keeper(s: Scheduler):
+    """A long stream that keeps the batch decoding while others arrive."""
+    h = s.submit(_req("keeper", max_new_tokens=80, stream=True,
+                      ignore_eos=True, **GREEDY))
+    assert _wait(lambda: h.completion_tokens >= 3)
+    return h
+
+
+def _hold(monkeypatch, s: Scheduler, hold) -> None:
+    """Let the engine thread admit nothing while ``hold`` is set, so that
+    what is submitted meanwhile is found in ONE iteration."""
+    real = s._admit_pending
+    monkeypatch.setattr(
+        s, "_admit_pending", lambda: False if hold.is_set() else real())
+
+
+class _Band:
+    """Allow a band of tokens (sampled, not forced) for ``limit`` steps."""
+
+    def __init__(self, limit):
+        self.row = np.full(512, -1e30, np.float32)
+        self.row[60:80] = 0.0
+        self.limit, self.steps = limit, 0
+
+    def allowed_mask(self):
+        return self.row
+
+    def advance(self, tid):
+        self.steps += 1
+
+    @property
+    def done(self):
+        return self.steps >= self.limit
+
+
+@pytest.mark.parametrize("sampling", [GREEDY, SEEDED],
+                         ids=["greedy", "seeded"])
+@pytest.mark.parametrize("case", [
+    "arrival_mid_decode", "two_arrivals_one_iteration", "multi_chunk_prompt",
+    "first_token_stops", "max_tokens_1", "cancel_before_first_token_read",
+    "constrained_beside_free"])
+def test_streams_equal_the_request_served_alone(paged, monkeypatch, case,
+                                                sampling):
+    """However an admission interleaves with the batch, each stream is the
+    one its request gives alone, read synchronously: the pipelined first
+    token and the dropped reads change WHEN the host sees a token, never
+    which. (The arming program is held to the parent's eager updates in
+    test_one_program_arms_a_slot_as_the_eager_updates_did.)"""
+    import threading
+
+    s, ref = paged
+    keeper = _keeper(s)
+    kw = dict(max_new_tokens=10, ignore_eos=True, stream=True, **sampling)
+    texts = ["first arrival"]
+    extra = {}
+    if case == "two_arrivals_one_iteration":
+        texts.append("second arrival")
+    elif case == "multi_chunk_prompt":      # 41 tokens: three chunks of 16
+        # (its first block differs by sampling: no pooled prefix to share)
+        texts = [f"{len(sampling)} prompt long enough to take three chunks"]
+    elif case == "first_token_stops":
+        extra = dict(logit_bias={65: 100.0}, stop=["A"])
+    elif case == "max_tokens_1":
+        kw["max_new_tokens"] = 1
+    elif case == "cancel_before_first_token_read":
+        real = s._install_slot
+
+        def cancel_at_launch(slot, handle, base, mask_set):
+            ctx = real(slot, handle, base, mask_set)
+            if handle is not keeper and handle.request.stream:
+                handle.cancel()     # after the final chunk's launch
+            return ctx
+
+        monkeypatch.setattr(s, "_install_slot", cancel_at_launch)
+    hold = threading.Event()
+    hold.set()
+    _hold(monkeypatch, s, hold)
+    chunks0 = s.total_prefill_chunks
+    handles = [s.submit(_req(t, **kw, **extra)) for t in texts]
+    con = None
+    if case == "constrained_beside_free":
+        con = s.submit(_req("tool", max_new_tokens=6, temperature=1.0,
+                            seed=1234, constraint=_Band(6)))
+    hold.clear()
+    for h, t in zip(handles, texts):
+        h.result(60)
+        want = _alone(ref, t, kw["max_new_tokens"], sampling,
+                      extra.get("logit_bias"))
+        if case == "first_token_stops":
+            assert (h.finish_reason, h.token_ids, h.text) == (
+                "stop", want[:1], "")
+            assert want[0] == 65
+        elif case == "cancel_before_first_token_read":
+            assert (h.finish_reason, h.token_ids) == ("cancelled", [])
+        else:
+            assert (h.finish_reason, h.token_ids) == ("length", want)
+    if case == "multi_chunk_prompt":
+        assert s.total_prefill_chunks - chunks0 == 3
+    if con is not None:
+        got = con.result(60).token_ids
+        monkeypatch.undo()
+        alone = s.generate(_req("tool", max_new_tokens=6, temperature=1.0,
+                                seed=1234, constraint=_Band(6))).token_ids
+        assert len(got) == 6 and got == alone
+    keeper.cancel()
+    # the keeper decoded on through every admission: its own stream too
+    got = keeper.result(60).token_ids
+    assert got == _alone(ref, "keeper", len(got), GREEDY)
+
+
+def test_prompt_cache_hit_gives_the_stream_of_a_full_prefill(tiny, tmp_path):
+    """A disk prompt-cache hit moves a FREE slot's frontier (load_prefix):
+    the host's mirror follows it, the admission resumes past the loaded
+    rows, and the stream is the full prefill's."""
+    from localai_tpu.engine.promptcache import PromptKVCache
+
+    prompt = "the shared system prompt that should be cached once, and more"
+    kw = dict(max_new_tokens=8, ignore_eos=True, **SEEDED)
+    outs = []
+    for _ in range(2):      # the second scheduler starts cold, cache warm
+        cache = PromptKVCache(tmp_path / "pc")
+        runner = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+        runner.slot_positions = None    # calling it would raise
+        s = Scheduler(runner, ByteTokenizer(), prompt_cache=cache)
+        try:
+            outs.append(s.generate(_req(prompt, **kw), timeout=120).token_ids)
+        finally:
+            s.shutdown()
+    assert cache.hits == 1 and runner.total_prefix_reused > 0
+    assert outs[0] == outs[1]
+    assert s.metrics()["admit_blocking_reads"] == 0
+
+
+def test_first_token_returns_through_the_pipeline_in_order(paged,
+                                                           monkeypatch):
+    """An arrival beside a decoding stream. (1) The decode step behind the
+    final chunk is launched BEFORE that chunk's token is read on the host:
+    nothing on the path waits for the device. (2) The rows of every
+    dispatch launched before the chunk (the one in flight at the arrival
+    among them) are delivered BEFORE the new request's first token, those
+    launched behind it after. (3) The counters say so: no blocking read,
+    two programs for this one-chunk admission."""
+    s, _ = paged
+    keeper = _keeper(s)
+    log = []
+    real_launch, real_first = s.runner.step_async, s._first_token
+    real_rows, real_install = s._process_rows, s._install_slot
+
+    def launch():
+        log.append(("decode_launch", s._dispatch_seq))
+        return real_launch()
+
+    def first(pf, tok):
+        log.append(("first_token_read", None))
+        return real_first(pf, tok)
+
+    def rows(r, seq, frozen=None):
+        log.append(("rows", seq))
+        return real_rows(r, seq, frozen)
+
+    def install(*a):
+        log.append(("final_chunk_launched", s._dispatch_seq))
+        return real_install(*a)
+
+    monkeypatch.setattr(s.runner, "step_async", launch)
+    monkeypatch.setattr(s, "_first_token", first)
+    monkeypatch.setattr(s, "_process_rows", rows)
+    monkeypatch.setattr(s, "_install_slot", install)
+    before = s.metrics()
+    h = s.generate(_req("arrival", max_new_tokens=6, stream=True,
+                        ignore_eos=True, **GREEDY))
+    assert h.finish_reason == "length"
+    keeper.cancel()
+    keeper.result(60)
+    monkeypatch.undo()
+    after = s.metrics()
+    names = [n for n, _ in log]
+    armed, read = (names.index("final_chunk_launched"),
+                   names.index("first_token_read"))
+    seq = log[armed][1]         # the dispatch counter at the chunk's launch
+    assert "decode_launch" in names[armed:read], log[armed:read + 1]
+    assert all(q <= seq for n, q in log[:read] if n == "rows")
+    assert [q for n, q in log[read:] if n == "rows"][0] == seq + 1
+    # a dispatch WAS in flight at the arrival, and was read after the launch
+    assert any(n == "rows" and q <= seq for n, q in log[armed:read])
+    assert after["admissions"] - before["admissions"] == 1
+    assert after["admit_blocking_reads"] == before["admit_blocking_reads"]
+    assert after["admit_programs"] - before["admit_programs"] == 2
+    assert s.flight.snapshot(limit=400) and all(
+        r["sync_ms"] == 0.0 for r in s.flight.snapshot(limit=400)
+        if r["program"] == "prefill_chunk")
+
+
+def test_a_constrained_first_token_is_waited_for_and_counted(paged):
+    """Where the next dispatch needs the token (an FSM's mask), the host
+    still waits for it, and the counter says it did."""
+    s, _ = paged
+    before = s.metrics()["admit_blocking_reads"]
+    h = s.generate(_req("tool", max_new_tokens=6, temperature=0.0,
+                        constraint=_Band(3)))
+    assert len(h.token_ids) == 3 and all(60 <= t < 80 for t in h.token_ids)
+    assert s.metrics()["admit_blocking_reads"] == before + 1
+
+
+def test_contiguous_admission_keeps_and_counts_its_reads(sched):
+    """The contiguous layout cannot know a free slot's frontier on the
+    host and returns its first token from admit(): two blocking reads an
+    admission, counted."""
+    before = sched.metrics()
+    sched.generate(_req("contiguous", max_new_tokens=3, temperature=0.0))
+    after = sched.metrics()
+    assert after["admissions"] - before["admissions"] == 1
+    assert (after["admit_blocking_reads"]
+            - before["admit_blocking_reads"]) == 2
+
+
+def test_host_mirror_of_free_frontiers_equals_the_device(tiny):
+    """free_frontiers() answers from the host what slot_positions() reads
+    from the device, for every free slot: after a release, after
+    load_prefix moved a free slot's frontier, after a rebuild."""
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+
+    def agree(slots):
+        host, device = r.free_frontiers(), r.slot_positions()
+        assert [int(host[s]) for s in slots] == [
+            int(device[s]) for s in slots]
+        return [int(host[s]) for s in slots]
+
+    assert agree(r.free_slots()) == [0, 0, 0, 0]
+    a, b = r.acquire_slot(), r.acquire_slot()
+    prompt = list(b"rows that outlive their slot in a file")
+    r.admit(a, prompt, temperature=0.0)
+    r.admit(b, list(b"another"), temperature=0.0)
+    for _ in range(3):
+        r.step()
+    exported = r.export_prefix(a, 32)
+    r.release(a)
+    assert agree(r.free_slots()) == [0, 0, 0]      # b still decodes at 10
+    assert r.load_prefix(a, exported, 32)
+    assert agree([a]) == [32]
+    r.release(a)        # what the scheduler does when the admission fails
+    assert agree([a]) == [0]
+    r.release(b)
+    r.reinit()
+    assert agree(r.free_slots()) == [0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("seed", [None, 0, 2147503333, 2**32 + 9])
+def test_one_program_arms_a_slot_as_the_eager_updates_did(tiny, seed):
+    """The arming program's state is, leaf for leaf, what the parent's
+    eager updates left: seven ``.at[slot].set`` of SamplingParams.with_slot,
+    the key of the seed (the slot's own where there is none), the bias row,
+    the slot's block-table row."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    slot, kw = 2, dict(temperature=0.7, top_k=11, min_p=0.05,
+                       presence_penalty=0.25)
+    row = np.linspace(-1, 1, r.cfg.vocab_size).astype(np.float32)
+    table_row = np.arange(r.max_blocks, dtype=np.int32)[::-1].copy()
+    st = r.state
+    want = dataclasses.replace(
+        st, params=st.params.with_slot(slot, **kw),
+        keys=(st.keys if seed is None
+              else st.keys.at[slot].set(jax.random.key(seed))),
+        bias=st.bias.at[slot].set(jnp.asarray(row)))
+    want_tables = r.block_tables.at[slot].set(jnp.asarray(table_row))
+    want = jax.tree.map(
+        lambda a: np.asarray(jax.random.key_data(a) if jnp.issubdtype(
+            a.dtype, jax.dtypes.prng_key) else a), want)
+    want_tables = np.asarray(want_tables)
+    r._arm(r._arm_args(slot, seed=seed, bias_row=row, **kw), table_row)
+    got = jax.tree.map(
+        lambda a: np.asarray(jax.random.key_data(a) if jnp.issubdtype(
+            a.dtype, jax.dtypes.prng_key) else a), r.state)
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(w, g)
+    np.testing.assert_array_equal(want_tables, np.asarray(r.block_tables))
+
+
+def test_an_admission_launches_two_programs_and_a_release_one(tiny,
+                                                              monkeypatch):
+    """A one-chunk admission is the arming update and the chunk, a release
+    one program, and nothing runs eagerly beside them (every eager
+    ``.at[].set`` or conversion was a program of its own on every chip)."""
+    from jax._src import dispatch
+
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    slot = r.acquire_slot()
+    r.admit(slot, list(b"warm the programs"), temperature=0.5, seed=3)
+    r.step()
+    r.release(slot)
+    launched = []
+    for name in ("_arm_slot", "_release_slot", "_prefill_paged",
+                 "_decode_paged"):
+        def counted(*a, _f=getattr(r, name), _n=name, **k):
+            launched.append(_n)
+            return _f(*a, **k)
+        monkeypatch.setattr(r, name, counted)
+    real = dispatch.xla_primitive_callable
+
+    def eager(prim, **params):
+        launched.append(f"eager:{prim.name}")
+        return real(prim, **params)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", eager)
+    slot = r.acquire_slot()
+    programs = r.admit_programs
+    adm = r.begin_admit(slot, list(b"one chunk"), temperature=0.5, seed=4,
+                        bias_row=np.zeros(r.cfg.vocab_size, np.float32))
+    assert launched == []           # begin_admit is the host's alone
+    assert adm.launch_chunk()
+    assert launched == ["_arm_slot", "_prefill_paged"]
+    assert r.admit_programs - programs == 2
+    r.step_async()
+    del launched[:]
+    r.release(slot)
+    assert launched == ["_release_slot"]
+    monkeypatch.undo()
+    assert adm.first_token() >= 0
