@@ -31,6 +31,12 @@ names; leaves are arrays, or quantised tensors with ``q`` (int8), ``scale``
 (float32, the weight's shape without the contraction axis) and ``axis``.
 ``served_param_count`` counts them, for the set-up's check that the model
 served is the model the file describes (the family's ``param_count``).
+
+Which row of the stack runs when, how often, and what happens between is the
+family's to say (its optional ``walk``, harness/spec.py); a family that says
+nothing gets ``in_order``: every row once. Either way a layer runs through the
+ONE jitted program, its weights dequantised inside it: never more than one
+layer in float32 at a time.
 """
 
 from __future__ import annotations
@@ -93,6 +99,13 @@ def _head(params):
     return _embed(params, jnp.arange(LETTERS.start, LETTERS.stop)).T
 
 
+def in_order(x, layer, rows: int, leaf, hf: dict):
+    """The walk of a family that defines none: every row once, in order."""
+    for index in range(rows):
+        x = layer(x, index)
+    return x
+
+
 def programs(family, hf: dict, n_tokens: int):
     """The reference as three plain callables over same-length sequences
     [B, T]: tokens -> x, (x, stacked layers, layer index) -> x, and
@@ -117,14 +130,27 @@ def reference_logits(params, family, hf: dict, tokens: np.ndarray,
                      last: int):
     """Reference logits over the 26 letters at the ``last`` final positions
     of each of tokens [B, T] (same-length sequences, one vmapped batch), one
-    layer's weights dequantised at a time."""
+    layer's weights dequantised at a time, the layers in the order the
+    family's ``walk`` gives (``in_order`` where it has none)."""
     embed, layer, logits = (jax.jit(f) for f in programs(
         family, hf, tokens.shape[1]))
-    n_layers = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
+    rows = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
+
+    def one_layer(x, index: int):
+        """Row ``index`` of the served stack applied to x [B, T, D]."""
+        if not 0 <= index < rows:
+            # a dynamic index out of range is clamped in silence
+            raise IndexError(f"the walk asked for row {index}; the served "
+                             f"model holds {rows}")
+        return layer(x, params["layers"], jnp.int32(index))
+
+    walk = getattr(family, "walk", None) or in_order
     with jax.default_matmul_precision("highest"):
-        x = embed(params, jnp.asarray(tokens, jnp.int32))
-        for index in range(n_layers):
-            x = layer(x, params["layers"], jnp.int32(index))
+        # the embedded probes go to the walk as a temporary: a name for them
+        # here would keep one more [B, T, D] on the device for the whole
+        # walk, and the check's longest probes set the process's peak
+        x = walk(embed(params, jnp.asarray(tokens, jnp.int32)), one_layer,
+                 rows, lambda name: _f32(params[name]), hf)
         out = logits(params, x[:, -last:])
     return np.asarray(out, np.float32)
 

@@ -84,7 +84,6 @@ def child_env(cell: Cell, root: Path, platform: str) -> dict:
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": platform,
-        "LOCALAI_TUNE_CACHE": "0",      # no tuning table from outside the tree
         "PYTHONPATH": str(root),
         "PYTHONUNBUFFERED": "1",
         # long enough rings for a whole window

@@ -35,6 +35,18 @@ configuration's published keys:
     kv_bytes_per_token(hf, element_bytes)     what one token adds to the cache
     q_elements_per_token(hf)   one token's q (and attention output) elements
     attn_flops(hf, pairs)      over (query token, attended token) pairs
+  and MAY define (FAMILY_OPTIONAL; a family that defines none is a stack of
+  like layers, each row run once, in order, with a cache entry a layer):
+    walk(x, layer, rows, leaf, hf) -> x     the order the layers run in.
+        x [B, T, D] are the embedded probes; ``layer(x, index)`` applies ONE
+        served row of the ``rows`` the stack holds (the harness dequantises
+        it and vmaps ``decoder_layer`` over the batch); ``leaf(name)`` is a
+        served non-layer leaf in float32 ("final_norm", ...). Which row runs
+        when, how often, and what happens to x between is the family's; what
+        it returns is what ``logits`` is given
+    cache_layers(hf)           layers the K/V cache holds, where that is not
+                               ``num_hidden_layers`` (a row that runs twice a
+                               token caches twice)
 """
 
 from __future__ import annotations
@@ -64,6 +76,8 @@ DEFAULT_FAMILY = "llama_family"
 FAMILY_CONTRACT = ("rope_tables", "decoder_layer", "logits", "param_count",
                    "layer_params", "token_params", "step_params",
                    "kv_bytes_per_token", "q_elements_per_token", "attn_flops")
+# not asked for; where a module binds one, it binds a function
+FAMILY_OPTIONAL = ("walk", "cache_layers")
 
 
 class SpecError(ValueError):
@@ -125,18 +139,25 @@ def family_file(config: dict, where: str, root: Path = ROOT) -> Path:
     except FileNotFoundError:
         raise SpecError(f"{where}: reference.family {name!r} names no file: "
                         f"{path} does not exist") from None
-    defined = set()
+    defined, constants = set(), set()
     for node in body:
         if isinstance(node, ast.FunctionDef):
             defined.add(node.name)
         elif isinstance(node, (ast.Import, ast.ImportFrom)):
             defined |= {a.asname or a.name for a in node.names}
         elif isinstance(node, ast.Assign):
-            defined |= {t.id for t in node.targets if isinstance(t, ast.Name)}
+            names = {t.id for t in node.targets if isinstance(t, ast.Name)}
+            defined |= names
+            if isinstance(node.value, ast.Constant):
+                constants |= names
     missing = [n for n in FAMILY_CONTRACT if n not in defined]
     if missing:
         raise SpecError(f"{path}: a family module defines {FAMILY_CONTRACT} "
                         f"(harness/spec.py); this one lacks {missing}")
+    no_function = sorted(constants.intersection(FAMILY_OPTIONAL))
+    if no_function:
+        raise SpecError(f"{path}: {no_function} must be functions where a "
+                        f"family module defines them (harness/spec.py)")
     return path
 
 

@@ -2,8 +2,9 @@
 the operations the program staged under a ``kv_pool.*`` scope
 (engine/kvcache.py), plus the copies and restacks XLA adds around them, which
 carry no scope or only the scan's and are known by their result's shape: the
-pool's [layers, blocks, kv heads, block tokens, head dim] or one layer's slice
-of it, in any order of dimensions, worked out from the cell's configuration.
+pool's [cache layers, blocks, kv heads, block tokens, head dim] or one layer's
+slice of it, in any order of dimensions, worked out from the cell's
+configuration and its family (``cache_layers``, harness/spec.py).
 The layer metric of "write the pool in place" (ROADMAP A1). None where the
 program names no scope."""
 
@@ -30,8 +31,15 @@ def pool_dims(cell) -> set[tuple[int, ...]]:
              int(engine.get("kv_block_tokens", 64)),    # the engine's default
              int(hf.get("head_dim")
                  or hf["hidden_size"] // hf["num_attention_heads"]))
-    return {tuple(sorted(layer)),
-            tuple(sorted((hf["num_hidden_layers"],) + layer)),
+    # the pool's leading dimension is the family's to say, where a layer's
+    # weights cache more than once a token
+    # (tier-1's tests/test_bench_trace.py reads with a cell that has no
+    # family)
+    cache_layers = getattr(getattr(cell, "family", None), "cache_layers",
+                           None)
+    layers = int(cache_layers(hf)) if cache_layers else (
+        hf["num_hidden_layers"])
+    return {tuple(sorted(layer)), tuple(sorted((layers,) + layer)),
             tuple(sorted((1,) + layer))}
 
 

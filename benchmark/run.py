@@ -52,6 +52,9 @@ TRACE_AT_S, TRACE_FOR_S = 10.0, 3.0     # the traced slice of the window
 # this long past its ramp (on one v5e chip the capture answers 11-19 s after
 # its 3 s, PERF.md section 6; what is due after the answer is never sent)
 TRACE_SEED, TRACE_SPAN_S = 1 << 32, 30.0
+# the most rows /debug/flight answers with, and a ``since`` before every
+# record: paging starts at the ring's oldest (0 would ask for the newest)
+FLIGHT_PAGE, RING_START = 4096, 1e-9
 
 
 def trace_for_s(chips: int) -> float:
@@ -188,17 +191,34 @@ def judge(short: list, reference: dict) -> dict:
 
 
 async def read_flight(server: Server, client: Client, since: float,
-                      out: list) -> float:
-    """The flight ring's records after ``since`` (a record's ``ts``; 0 =
-    all) into ``out``: the newest 4096 of them, the most the endpoint
-    returns. Returns the last ``ts`` read."""
+                      out: list) -> tuple[float, bool]:
+    """One page of the flight ring into ``out``: the OLDEST ``FLIGHT_PAGE``
+    records after ``since`` (a record's ``ts``), or with ``since`` 0 the
+    newest. Returns the last ``ts`` read and whether the page came full."""
     async with client.session.get(
-            f"{server.base}/debug/flight?since={since!r}&limit=4096"
-    ) as resp:
+            f"{server.base}/debug/flight?since={since!r}"
+            f"&limit={FLIGHT_PAGE}") as resp:
         ring = (await resp.json())["models"].get(server.name, {})
     recs = ring.get("records", [])
+    full = len(recs) == FLIGHT_PAGE
+    if full and recs[0]["ts"] < recs[-1]["ts"]:
+        # the next page starts strictly after this one's last ``ts``: where
+        # the page ends inside a run of rows that share it, the rest of the
+        # run would be lost. Hold the run back; it comes whole next time
+        recs = [r for r in recs if r["ts"] < recs[-1]["ts"]]
     out += recs
-    return recs[-1]["ts"] if recs else since
+    return (recs[-1]["ts"] if recs else since), full
+
+
+async def page_flight(server: Server, client: Client, since: float,
+                      out: list) -> float:
+    """Every record the ring holds after ``since`` into ``out``, page by page
+    forward until a page comes back short: nothing between two reads is
+    missed, however many dispatches fell there. Returns the last ``ts``."""
+    full = True
+    while full:
+        since, full = await read_flight(server, client, since, out)
+    return since
 
 
 async def poll_flight(server: Server, client: Client, until: float,
@@ -206,7 +226,7 @@ async def poll_flight(server: Server, client: Client, until: float,
     """Page the flight ring out while the window runs."""
     since = 0.0
     while True:
-        since = await read_flight(server, client, since, out)
+        since, _ = await read_flight(server, client, since, out)
         if time.monotonic() > until:
             return
         await asyncio.sleep(2.0)
@@ -296,7 +316,8 @@ async def run_window(client: Client, server: Server, cell: spec.Cell,
 async def traced_slice(client: Client, server: Server, cell: spec.Cell,
                        seed: int, w: mtr.Window, traced: dict) -> None:
     """``--trace 2`` after the window: first what the window left in the
-    server's rings (the flight ring, the request spans), read once; then the
+    server's rings (the flight ring, paged forward from its oldest record;
+    the request spans); then the
     cell's mix again under the stream ``trace`` (seeded, never scored: its
     requests are due after the close), ramped as the window's was, and
     ``POST /backend/trace`` for ``trace_for_s`` with that traffic running until
@@ -307,10 +328,10 @@ async def traced_slice(client: Client, server: Server, cell: spec.Cell,
     mix, drive = cell.traffic, cell.drive
     ramp_s = float(drive.get("ramp_s", 5.0))
     flight: list = []
-    since = await read_flight(server, client, 0.0, flight)
-    # the endpoint gives the newest 4096: enough for the window unless the
-    # oldest it gave is younger than the window's opening
-    traced["flight_cut"] = len(flight) == 4096 and (
+    since = await page_flight(server, client, RING_START, flight)
+    # the ring holds set-up's rows in front of the window's: it has wrapped
+    # past the opening unless its oldest record is older than that
+    traced["flight_cut"] = not flight or (
         flight[0]["ts_unix"] - ANCHOR[0] + ANCHOR[1] > w.t_open)
     traces = await read_traces(server, client)
     loop = asyncio.get_running_loop()
@@ -354,7 +375,7 @@ async def traced_slice(client: Client, server: Server, cell: spec.Cell,
                     + float(drive.get("drain_s", 30.0)))
     traced["compiles_in_slice"] = compiles(
         await loop.run_in_executor(None, server.metrics)) - before
-    await read_flight(server, client, since, flight)
+    await page_flight(server, client, since, flight)
     traced["flight"] = flight
     traced["traces"] = traces + await read_traces(server, client)
 

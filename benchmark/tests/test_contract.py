@@ -14,22 +14,10 @@ import pytest
 
 import run as bench
 import xspace
-from conftest import BENCH, DATA, ROOT
-from harness import peaks, spec, trace_reduce
+from conftest import BENCH, ROOT, add_architecture, result_line
+from harness import spec, trace_reduce
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-
-
-@pytest.fixture()
-def cpu_peaks(monkeypatch):
-    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
-
-
-def result_line(capsys) -> dict:
-    lines = capsys.readouterr().out.strip().splitlines()
-    for line in lines[:-1]:
-        assert not line.startswith("{"), line
-    return json.loads(lines[-1])
 
 
 def test_an_added_cell_runs_by_name_and_prints_the_result_line(
@@ -65,34 +53,9 @@ def test_a_closed_loop_cell_reports_tokens_per_second(bench_copy, cpu_peaks,
     assert out["attempted"] >= 4 and out["failed"] == 0 and out["correct"]
 
 
-def add_architecture(root, family="moe_family"):
-    """What a later PR adds for a configuration of another architecture, the
-    family module apart (``benchmark/reference/<family>.py``; here the one
-    the benchmark has): a configuration file that names its family, a cell
-    file, their two BENCHMARK.json entries, and the cell's name appended to
-    the ``workloads`` lists of the metrics it reports. No file that is there
-    is edited. Returns the configuration file's path."""
-    config = json.loads((DATA / "configs" / "tiny.json").read_text())
-    config.update({"name": "tiny-moe", "model_type": "mixtral",
-                   "num_local_experts": 4, "num_experts_per_tok": 2})
-    config["reference"] = {**config["reference"], "family": family}
-    path = root / "benchmark" / "configs" / "tiny-moe.json"
-    path.write_text(json.dumps(config))
-    (root / "benchmark" / "cells" / "tiny-moe-closed.json").write_text(
-        (DATA / "cells" / "tiny-closed.json").read_text())
-    bench_json = root / "BENCHMARK.json"
-    entries = json.loads(bench_json.read_text())
-    entries["configs"].append({
-        "name": "tiny-moe", "source": "benchmark/tests", "reduced": [],
-        "file": "benchmark/configs/tiny-moe.json", "why": "a test"})
-    entries["workloads"].append({
-        "name": "tiny-moe-closed", "config": "tiny-moe",
-        "traffic": "tiny-closed", "chips": 1, "why": "a test"})
-    for metric in entries["end_to_end"] + entries["per_layer"]:
-        if "tiny-closed" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-moe-closed")
-    bench_json.write_text(json.dumps(entries, indent=1))
-    return path
+# Mixtral's keys: a sparse-expert block no other configuration has
+MOE = {"model_type": "mixtral", "num_local_experts": 4,
+       "num_experts_per_tok": 2}
 
 
 def test_an_added_architecture_runs_by_files_alone(bench_copy, cpu_peaks,
@@ -100,7 +63,7 @@ def test_an_added_architecture_runs_by_files_alone(bench_copy, cpu_peaks,
     """A sparse-expert configuration (4 experts, top-2: a block no other
     configuration of the benchmark has) runs by name, is checked against ITS
     family's reference and is counted by ITS family's arithmetic."""
-    add_architecture(bench_copy)
+    add_architecture(bench_copy, "tiny-moe", "moe_family", **MOE)
     cell = spec.load_cell("tiny-moe-closed", bench_copy)
     assert cell.family_file == (bench_copy / "benchmark" / "reference"
                                 / "moe_family.py")
@@ -126,7 +89,7 @@ def test_an_added_architecture_runs_by_files_alone(bench_copy, cpu_peaks,
 
 def test_a_family_that_is_missing_or_incomplete_is_an_error(bench_copy,
                                                             capsys):
-    config = add_architecture(bench_copy, family="no_such_family")
+    config = add_architecture(bench_copy, "tiny-moe", "no_such_family", **MOE)
     with pytest.raises(spec.SpecError, match="reference.family "
                        "'no_such_family' names no file"):
         spec.load_cell("tiny-moe-closed", bench_copy)
@@ -285,6 +248,63 @@ def test_a_compile_inside_the_slice_voids_the_slice_and_not_the_run(
     for name in ("device.idle_share", "model.decode_bw_share",
                  "runner.kv_move_share", "sched.device_idle_share"):
         assert name not in m, name
+
+
+class FakeRing:
+    """``/debug/flight`` as the program answers it: with ``since`` the OLDEST
+    ``limit`` records after it, without it the newest."""
+
+    def __init__(self, n_rows: int, tied: range = range(0)):
+        # the rows of ``tied`` share one ``ts``; ``row`` tells them apart
+        self.rows = [{"ts": 100.0 + (tied[0] if i in tied else i),
+                      "ts_unix": 5000.0 + i, "row": i}
+                     for i in range(n_rows)]
+        self.asked: list = []
+        self.session, self.base, self.name = self, "http://ring", "tiny"
+
+    def get(self, url: str):
+        query = dict(kv.split("=") for kv in url.split("?")[1].split("&"))
+        since, limit = float(query["since"]), int(query["limit"])
+        self.asked.append(since)
+        after = [r for r in self.rows if r["ts"] > since]
+        page = after[:limit] if since else after[-limit:]
+        ring = self
+
+        class Reply:
+            async def __aenter__(self):
+                return self
+
+            async def __aexit__(self, *exc):
+                return False
+
+            async def json(self):
+                return {"models": {ring.name: {"records": page}}}
+
+        return Reply()
+
+
+@pytest.mark.parametrize("n_rows, pages, tied", [
+    (0, 1, range(0)), (300, 1, range(0)), (4096, 2, range(0)),
+    (9000, 3, range(0)), (9000, 3, range(4094, 4098)),
+    (9000, 3, range(8188, 8200))])
+def test_the_flight_ring_is_paged_forward_until_a_short_page(n_rows, pages,
+                                                             tied):
+    """A window's rows are read whole however many they are (one read of the
+    newest 4096 cut a window of a faster step): forward from the ring's
+    oldest record, page by page, and a later read goes on where it ended.
+    Rows that share a ``ts`` across a page's end are not lost to the next
+    page's strict ``ts > since``."""
+    import asyncio
+
+    ring = FakeRing(n_rows, tied)
+    out: list = []
+    since = asyncio.run(bench.page_flight(ring, ring, bench.RING_START, out))
+    assert out == ring.rows and len(ring.asked) == pages
+    assert ring.asked[0] == bench.RING_START > 0    # 0 asks for the newest
+    assert since == (ring.rows[-1]["ts"] if n_rows else bench.RING_START)
+    ring.rows += [{"ts": 1e6 + i, "ts_unix": 1e7 + i} for i in range(5)]
+    asyncio.run(bench.page_flight(ring, ring, since, out))
+    assert out == ring.rows
 
 
 def test_the_four_chip_cell_reports_what_a_chat_cell_and_a_mesh_report():
