@@ -58,6 +58,16 @@ ROOT = Path(__file__).resolve().parent
 # Llama-3-8B attention shapes (models.registry DEBUG_PRESETS["llama3-8b"])
 HEADS = {"num_heads": 32, "num_kv_heads": 8, "head_dim": 128}
 
+# The looped decoder's shape of the paged kernel (benchmark/configs/
+# ouro-2.6b-int8.json): 16 query = 16 kv heads (of HEADS' head_dim), a pool
+# of 4 passes x 48 layers, read at both ends of the first two passes
+LOOPED = {"heads": 16, "cache_layers": 192, "picks": [0, 47, 48, 191]}
+# and the looped tiny model the second server phase serves: debug:tiny-loop,
+# 2 layers run 3 times a token (models.registry.DEBUG_PRESETS)
+LOOPED_SERVER = dict(model="debug:tiny-loop", context=512, slots=4,
+                     long_prompt=200, engine={"prefill_chunk": 64},
+                     tag="looped")
+
 # The context the one-chip server phase serves. Largest that fits every
 # program the scheduler dispatches on a 15.75 GiB v5e chip, from compiling
 # the runner's programs for the v5e topology (PERF.md "Bring-up"):
@@ -263,6 +273,40 @@ def kernel_child(spec: dict) -> int:
     # it serves — same bytes per row as the 8B int8 pool
     paged_case("int4", Hq // 2, Hkv // 2, 2 * hd)
 
+    # -- the looped decoder's shape of the same kernel: one query row a kv
+    # head (plain multi-head), and a pool whose leading dimension is passes
+    # x layers, read at the first and last cache layer of two passes
+    def looped_case(heads, cache_layers, picks):
+        selected(ops.select_paged_attn_impl, num_heads=heads,
+                 num_kv_heads=heads, head_dim=hd, block_tokens=bt,
+                 kv_dtype="bfloat16")
+        mb = min(MB, 4)                 # a short context: the pool is deep
+        n = S * mb + 1
+        tabs = jnp.asarray(rng.permutation(np.arange(1, n))[:S * mb]
+                           .reshape(S, mb), jnp.int32)
+        pos = jnp.minimum(positions, mb * bt - 1)
+        q = normal((S, heads, hd))
+        # the pool goes in as an ARGUMENT (closed over, its 2 x 1.7 GB
+        # would be lowered into every program as constants)
+        k = jnp.zeros((cache_layers, n, heads, bt, hd), bf16)
+        v = jnp.zeros((cache_layers, n, heads, bt, hd), bf16)
+        for layer in picks:
+            k = k.at[layer].set(normal((n, heads, bt, hd)))
+            v = v.at[layer].set(normal((n, heads, bt, hd)))
+        for layer in picks:
+            run(f"paged_decode looped q_per_kv=1 layer {layer} of "
+                f"{cache_layers}",
+                lambda q, k, v, tabs, pos, layer=layer:
+                    ops.paged_decode_attention(
+                        q, k, v, jnp.int32(layer), tabs, pos,
+                        interpret=interpret),
+                lambda q, k, v, tabs, pos, layer=layer:
+                    ops.paged_decode_attention_ref(
+                        q, k[layer], v[layer], tabs, pos),
+                q, k, v, tabs, pos)
+
+    looped_case(**spec["looped"])
+
     # -- contiguous cache: decode + prefill (embeddings, mirrored engines) -
     selected(ops.select_attn_impl, num_heads=Hq, num_kv_heads=Hkv,
              head_dim=hd, max_ctx=ctx)
@@ -338,14 +382,14 @@ def kernel_child(spec: dict) -> int:
 def kernel_phase(smoke: Smoke, *, context: int = CONTEXT, slots: int = SLOTS,
                  heads: dict = HEADS, ffn: int = 14336,
                  prefill_buckets=(128, 512, 2048), interpret: bool = False,
-                 timeout: float = 600.0) -> dict:
+                 looped: dict = LOOPED, timeout: float = 600.0) -> dict:
     """One child on the chip: every Pallas entry point, compiled, against
     its lax reference. Returns the child's report (and learns the device)."""
     report_path = smoke.out_dir / "kernels.json"
     spec = {"expect_platform": smoke.expect_platform, "context": context,
             "slots": slots, "ffn": ffn, "interpret": interpret, "seed": 0,
             "prefill_buckets": [b for b in prefill_buckets if b <= context],
-            "report": str(report_path), **heads}
+            "report": str(report_path), "looped": looped, **heads}
     smoke.say("kernel phase: starting")
     proc = subprocess.run(
         [sys.executable, str(Path(__file__).resolve()), "--kernel-child",
@@ -805,6 +849,10 @@ def main(argv=None) -> int:
     try:
         kernel_phase(smoke)
         server_phase(smoke, chips=args.chips)
+        # the looped decoder through the same entry points (one chip, pinned:
+        # its two heads split over no mesh): passes x layers of cache, the
+        # compiled kernel at one query row a kv head
+        server_phase(smoke, chips=1, **LOOPED_SERVER)
         if args.chips > 1:
             fleet_phase(smoke, replicas=args.chips)
     finally:

@@ -6,8 +6,10 @@ grpc-server.cpp:176,906,1546-1990) with a TPU-native layout: one statically
 shaped tensor pair per model, stacked over layers, sliced per slot by
 masking — never by ragged mutation.
 
-Layout: k,v each [num_layers, num_slots, num_kv_heads, max_ctx, head_dim]
-(paged: [num_layers, num_blocks, num_kv_heads, block_tokens, head_dim]).
+Layout: k,v each [cache layers, num_slots, num_kv_heads, max_ctx, head_dim]
+(paged: [cache layers, num_blocks, num_kv_heads, block_tokens, head_dim]);
+cache layers = ``LlamaConfig.cache_layers``: the model's layers, times its
+passes where the stack runs several times a token (pass-major).
 Heads lead the context dim so the last two axes are (context, head_dim) —
 the (sublane, lane) tiling Mosaic requires for the flash kernels' per-head
 HBM→VMEM DMA slices (ops.attention), and a contiguous stream per head.
@@ -19,7 +21,8 @@ jits. One contract for every write policy below:
     kv_write(kv_stack, layer, k_new, v_new) -> (new_kv_stack, keys, values)
 
 ``kv_stack`` is the whole stacked pytree (``stacked()``), ``layer`` the
-scan's i32 layer index. A policy scatters ONLY the new rows into the stack
+i32 CACHE layer (the scan's layer index, plus pass x layers in a looped
+model). A policy scatters ONLY the new rows into the stack
 (``_write_rows``, ``_write_run``, ``_write_chunk``) and hands the attend
 what it reads: the blocks or rows gathered straight from the 5-D array,
 or, ``raw=True``, a ``LayerView`` of the stack for a Pallas kernel that
@@ -93,7 +96,7 @@ def init_cache(
     dtype: str = "bfloat16",
     sharding: Optional[jax.sharding.Sharding] = None,
 ) -> KVCache:
-    shape = (cfg.num_layers, num_slots, cfg.num_kv_heads, max_ctx, cfg.hd)
+    shape = (cfg.cache_layers, num_slots, cfg.num_kv_heads, max_ctx, cfg.hd)
     dt = jnp.dtype(dtype)
 
     def zeros(shp, d, shd):
@@ -183,7 +186,7 @@ def init_paged_cache(
         raise ValueError(f"int4 KV needs an even head_dim, got {cfg.hd}")
     # int4 pools store nibble-packed int8 along head_dim (hd/2 bytes/row)
     hd = cfg.hd // 2 if int4 else cfg.hd
-    shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_tokens, hd)
+    shape = (cfg.cache_layers, num_blocks, cfg.num_kv_heads, block_tokens, hd)
     dt = jnp.dtype("int8") if int4 else jnp.dtype(dtype)
     quantized = int4 or dt == jnp.int8
 

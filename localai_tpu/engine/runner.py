@@ -176,6 +176,11 @@ class ModelRunner:
                 raise ValueError(
                     "self-extend is not supported with pipeline "
                     "parallelism")
+            if cfg.num_passes > 1:
+                raise ValueError(
+                    "a looped decoder (num_passes > 1) is not supported "
+                    "with pipeline parallelism: the stage chain runs the "
+                    "stack once")
             attn_impl = "xla"
             log.info("pipeline parallelism: %d stages x %d layers",
                      n_pipe, cfg.num_layers // n_pipe)
@@ -406,6 +411,9 @@ class ModelRunner:
             # self-extend keeps the cache unroped; the ring prefill writes
             # roped K, so the two modes are mutually exclusive
             and ga_n == 1
+            # the ring prefill walks the stack once and returns a K/V row a
+            # layer: a looped decoder keeps the chunked path
+            and cfg.num_passes == 1
         )
         self.sp_threshold = sp_threshold
         self.last_prefill_path = ""
@@ -1214,7 +1222,7 @@ class ModelRunner:
         cfg = self.cfg
         # throwaway scratch cache stays in the compute dtype even when the
         # serving cache is int8 — it is read back within the same program
-        kv_shape = (cfg.num_layers, 1, cfg.num_kv_heads, bucket, cfg.hd)
+        kv_shape = (cfg.cache_layers, 1, cfg.num_kv_heads, bucket, cfg.hd)
         kv = (jnp.zeros(kv_shape, jnp.dtype(cfg.dtype)),
               jnp.zeros(kv_shape, jnp.dtype(cfg.dtype)))
         positions = jnp.arange(bucket, dtype=jnp.int32)[None, :]
@@ -1931,7 +1939,7 @@ class ModelRunner:
             return host
 
         k, v = unpack("k"), unpack("v")
-        L, H, hd = self.cfg.num_layers, self.cfg.num_kv_heads, self.cfg.hd
+        L, H, hd = self.cfg.cache_layers, self.cfg.num_kv_heads, self.cfg.hd
         if str(self.kv_dtype) == "int4":
             hd //= 2  # int4 exports stay nibble-packed along head_dim
         if k.shape != (L, H, n, hd) or v.shape != (L, H, n, hd):

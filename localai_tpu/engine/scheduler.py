@@ -388,6 +388,12 @@ class Scheduler:
         # launched are the runner's count (``admit_programs``)
         self.total_admissions = 0
         self.total_admit_blocking_reads = 0
+        # passes over the layer stack a forward makes (a looped decoder's
+        # ``num_passes``; 1 for every other model) and those dispatched so
+        # far: the flight ring's ``passes`` column, ``loop_passes`` below
+        self._passes_per_forward = int(getattr(
+            getattr(runner, "cfg", None), "num_passes", 1))
+        self.total_loop_passes = 0
         # a request the paged block pool couldn't cover yet: admission is
         # FIFO, so it parks here (not back in the queue) until blocks free
         self._held: Optional[GenHandle] = None
@@ -627,6 +633,7 @@ class Scheduler:
             "admissions": self.total_admissions,
             "admit_blocking_reads": self.total_admit_blocking_reads,
             "admit_programs": getattr(self.runner, "admit_programs", 0),
+            "loop_passes": self.total_loop_passes,
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -746,9 +753,15 @@ class Scheduler:
         )
         if phases is None:
             phases = self._take_anat(dt, sync_s)
+        # a decode dispatch runs a forward a step; a chunk or a speculative
+        # window (whose ``steps`` is its yield) runs one
+        forwards = int(steps) if program.startswith("decode") else 1
+        passes = forwards * self._passes_per_forward
+        self.total_loop_passes += passes
         self.flight.record(
             program=program,
             steps=steps,
+            passes=passes,
             dispatch_ms=dt * 1e3,
             occupancy=len(self._slots) / num_slots if num_slots else 0.0,
             batch_slots=batch_slots,

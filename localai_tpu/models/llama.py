@@ -53,6 +53,11 @@ class LlamaConfig:
     num_experts: int = 0                  # Mixtral-class sparse MoE MLP
                                           # (0 = dense mlp)
     num_experts_per_tok: int = 2          # router top-k
+    num_passes: int = 1                   # looped decoder: the whole stack
+                                          # runs this often a token, the
+                                          # final norm after every pass
+    post_norm: bool = False               # "sandwich" layer: a second norm
+                                          # on each branch's OUTPUT
     dtype: str = "bfloat16"
 
     @property
@@ -60,12 +65,25 @@ class LlamaConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def cache_layers(self) -> int:
+        """Leading dimension of the K/V cache: an entry for every (pass,
+        layer) pair, pass-major (``pass * num_layers + layer``). THE one
+        place a cache's layer count comes from (engine.kvcache, the
+        runner's scratch caches and prefix import)."""
+        return self.num_passes * self.num_layers
+
+    @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
     @classmethod
     def from_hf(cls, hf: dict) -> "LlamaConfig":
-        """Build from an HF config.json dict (llama/mistral/qwen2 families)."""
+        """Build from an HF config.json dict, by its ``model_type``: llama,
+        mistral, mixtral (``num_local_experts``), qwen2 (qkv bias), and
+        ouro, the looped decoder (``total_ut_steps`` passes over the stack,
+        four norms a layer; its exit gate is not served: every pass runs
+        for every token)."""
+        ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),
             hidden_size=hf.get("hidden_size", 4096),
@@ -85,6 +103,8 @@ class LlamaConfig:
             sliding_window=hf.get("sliding_window"),
             num_experts=hf.get("num_local_experts", 0),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+            num_passes=int(hf.get("total_ut_steps", 1)) if ouro else 1,
+            post_norm=ouro,
         )
 
 
@@ -202,6 +222,9 @@ def param_shapes(cfg: LlamaConfig) -> dict:
             "w_up": (L, E, D, F),
             "w_down": (L, E, F, D),
         })
+    if cfg.post_norm:
+        shapes["layers"]["attn_post_norm"] = (L, D)
+        shapes["layers"]["mlp_post_norm"] = (L, D)
     if cfg.attention_bias:
         shapes["layers"]["bq"] = (L, Hq * hd)
         shapes["layers"]["bk"] = (L, Hkv * hd)
@@ -222,8 +245,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
     keys = jax.random.split(rng, len(flat))
     dtype = jnp.dtype(cfg.dtype)
 
-    def mk(k, shape):
-        if len(shape) == 1:  # norm gains
+    def mk(k, shape, unit=False):
+        if len(shape) == 1 or unit:  # norm gains
             return jnp.ones(shape, dtype)
         return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dtype)
 
@@ -233,8 +256,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
         if placement is not None:
             sh = placement.shardings(
                 tuple(p.key for p in kpath), jax.ShapeDtypeStruct(shape, dtype))
+        # a branch's OUTPUT norm gets gain 1: at the 0.02 the stacked
+        # pre-norm gains are drawn with, a layer (and so a whole pass)
+        # would change nothing
+        unit = kpath[-1].key.endswith("post_norm")
         leaves.append(jax.jit(  # jaxlint: disable=jit-in-loop
-            mk, static_argnums=1, out_shardings=sh)(k, shape))
+            mk, static_argnums=(1, 2), out_shardings=sh)(k, shape, unit))
     return jax.tree.unflatten(treedef, leaves)
 
 
@@ -287,7 +314,11 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
     with jax.named_scope("attn.out"):
         attn = attn.reshape(*attn.shape[:-2], Hq * hd)
         wo_out = qnt.matmul(attn, lp["wo"])
-        x = x + (reduce(wo_out) if reduce is not None else wo_out)
+        if reduce is not None:
+            wo_out = reduce(wo_out)
+        if "attn_post_norm" in lp:      # sandwich layer: x + N2(Attn(N1 x))
+            wo_out = rms_norm(wo_out, lp["attn_post_norm"], cfg.rms_norm_eps)
+        x = x + wo_out
 
     if "moe_gate" in lp:
         with jax.named_scope("moe"):
@@ -299,7 +330,11 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
             gated = (jax.nn.silu(qnt.matmul(h, lp["w_gate"]))
                      * qnt.matmul(h, lp["w_up"]))
             down = qnt.matmul(gated, lp["w_down"])
-            x = x + (reduce(down) if reduce is not None else down)
+            if reduce is not None:
+                down = reduce(down)
+            if "mlp_post_norm" in lp:   # sandwich layer: h + N4(MLP(N3 h))
+                down = rms_norm(down, lp["mlp_post_norm"], cfg.rms_norm_eps)
+            x = x + down
     return x, new_kv
 
 
@@ -386,6 +421,12 @@ def forward(
     scanned (that costs a second stack of temp, and a slice-out and a
     restack per layer). tests/test_tpu_compile.py holds the compiled
     programs to no stack-sized temp and no layer-shaped copy.
+
+    A looped decoder (``cfg.num_passes`` > 1) runs that scan inside ONE
+    rolled loop over the passes, the final norm after every pass; (x, kv)
+    is the carry of both loops and ``kv_write`` is handed the CACHE layer
+    ``pass * layers + layer`` (``cfg.cache_layers`` of them). With one pass
+    nothing of that is traced: the programs are what they were.
     """
     cos_t, sin_t = rope
     cos = cos_t[positions][:, :, None, :]  # [B, T, 1, hd/2]
@@ -405,24 +446,49 @@ def forward(
             with jax.named_scope(xla_scope):
                 return _grouped_attn(cfg, q, keys, values, m)
 
-    def body(carry, layer_in):
-        x, kv = carry
-        lp, layer = layer_in
-
-        def attend(q, k_new, v_new):
-            new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
-            return attn(q, keys, values, mask), new_kv
-
-        return _layer(cfg, x, lp, cos, sin, attend, reduce=reduce), None
-
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
-    with jax.named_scope("layers"):
-        (x, new_kv_stack), _ = lax.scan(
-            body, (x, kv_stack),
-            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
-    with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return x, new_kv_stack
+
+    def stack(x, kv, first=None):
+        """The whole stack once; its layers write and read the cache layers
+        ``first .. first + n_layers - 1`` (None: 0, and nothing is added)."""
+        cache_layer = jnp.arange(n_layers, dtype=jnp.int32)
+        if first is not None:
+            cache_layer = first + cache_layer
+
+        def body(carry, layer_in):
+            x, kv = carry
+            lp, layer = layer_in
+
+            def attend(q, k_new, v_new):
+                new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
+                return attn(q, keys, values, mask), new_kv
+
+            return _layer(cfg, x, lp, cos, sin, attend, reduce=reduce), None
+
+        with jax.named_scope("layers"):
+            (x, kv), _ = lax.scan(
+                body, (x, kv), (params["layers"], cache_layer))
+        return x, kv
+
+    if cfg.num_passes == 1:
+        x, new_kv_stack = stack(x, kv_stack)
+        with jax.named_scope("final_norm"):
+            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return x, new_kv_stack
+
+    # looped decoder: ONE rolled loop over the passes, its body the layer
+    # scan and the final norm (which runs after EVERY pass: its output feeds
+    # the next pass, and the last pass's goes to the head). (x, kv) is the
+    # carry of both loops, so the cache is still written in place; pass t
+    # of layer l owns cache layer ``t * n_layers + l``
+    def one_pass(t, carry):
+        with jax.named_scope("loop.pass"):
+            x, kv = stack(*carry, t * n_layers)
+        with jax.named_scope("loop.norm"):
+            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return x, kv
+
+    return lax.fori_loop(0, cfg.num_passes, one_pass, (x, kv_stack))
 
 
 def logits_from_hidden(cfg: LlamaConfig, params: PyTree, x: jax.Array) -> jax.Array:

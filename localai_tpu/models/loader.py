@@ -71,7 +71,7 @@ def load_llama_params(
     quantization: str = "",
     placement=None,
 ) -> tuple[LlamaConfig, Any]:
-    """Load an HF llama/mistral/qwen2 checkpoint into the stacked pytree,
+    """Load an HF llama/mistral/qwen2/ouro checkpoint into the stacked pytree,
     one leaf at a time: read and stack on the host, cast (or, with
     ``quantization``, quantize — models.quant.quantize_tensor_host) on the
     host, then hand the SERVED form to ``placement.put``
@@ -135,6 +135,11 @@ def load_llama_params(
         "wo": (L + "self_attn.o_proj.weight", True),
         "mlp_norm": (L + "post_attention_layernorm.weight", False),
     }
+    if cfg.post_norm:
+        # the looped decoder's sandwich layer: each branch's output norm
+        layer_src["attn_post_norm"] = (L + "input_layernorm_2.weight", False)
+        layer_src["mlp_post_norm"] = (
+            L + "post_attention_layernorm_2.weight", False)
     if cfg.num_experts:
         # Mixtral layout: block_sparse_moe.gate (router) +
         # experts.{j}.w1/w3/w2 (gate/up/down) → expert-stacked [L, E, K, N]

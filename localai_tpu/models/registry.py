@@ -41,6 +41,15 @@ DEBUG_PRESETS: dict[str, LlamaConfig] = {
         num_heads=4, num_kv_heads=2, max_position_embeddings=512,
         num_experts=4, num_experts_per_tok=2,
     ),
+    # a looped decoder (model_type ouro): 2 sandwich layers run 3 times a
+    # token, 6 cache layers; plain multi-head at the compiled kernels'
+    # head_dim (chip_smoke.py serves it beside the 8B)
+    "tiny-loop": LlamaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=128,
+        max_position_embeddings=512, rms_norm_eps=1e-6,
+        num_passes=3, post_norm=True,
+    ),
     "1b": LlamaConfig(
         vocab_size=128256, hidden_size=2048, intermediate_size=8192,
         num_layers=16, num_heads=32, num_kv_heads=8,

@@ -59,6 +59,7 @@ class FlightRecorder:
         self._lock = threading.Lock()
         self._ts = np.zeros(n)
         self._steps = np.zeros(n, np.int64)
+        self._passes = np.zeros(n, np.int64)
         self._dispatch_ms = np.zeros(n)
         self._occupancy = np.zeros(n)
         self._batch_slots = np.zeros(n, np.int64)
@@ -88,7 +89,7 @@ class FlightRecorder:
                compile: bool = False, ts: Optional[float] = None,
                batch_slots: int = 0, gap_ms: float = 0.0,
                sched_ms: float = 0.0, launch_ms: float = 0.0,
-               sync_ms: float = 0.0) -> None:
+               sync_ms: float = 0.0, passes: int = 0) -> None:
         """Append one dispatch record (host scalars only).
 
         ``batch_slots`` tags the record with the lane mix: how many of the
@@ -103,12 +104,17 @@ class FlightRecorder:
         :mod:`obs.anatomy` for phase semantics). The scheduler guarantees
         their sum never exceeds ``dispatch_ms``; callers that cannot
         attribute phases pass the zero defaults and the record degrades
-        to the undifferentiated pre-anatomy shape."""
+        to the undifferentiated pre-anatomy shape.
+
+        ``passes`` counts the passes over the layer stack the dispatch ran:
+        its forwards times the model's passes a forward (a looped decoder
+        runs its stack several times a token; every other model once)."""
         now = time.monotonic() if ts is None else ts
         with self._lock:
             i = self._n % self.capacity
             self._ts[i] = now
             self._steps[i] = steps
+            self._passes[i] = passes
             self._dispatch_ms[i] = dispatch_ms
             self._occupancy[i] = occupancy
             self._batch_slots[i] = batch_slots
@@ -168,6 +174,7 @@ class FlightRecorder:
             cols = {
                 "ts": self._ts[order].tolist(),
                 "steps": self._steps[order].tolist(),
+                "passes": self._passes[order].tolist(),
                 "ms": self._dispatch_ms[order].tolist(),
                 "occ": self._occupancy[order].tolist(),
                 "batch": self._batch_slots[order].tolist(),
@@ -197,6 +204,7 @@ class FlightRecorder:
                 "ts_unix": round(mono_to_wall(cols["ts"][j]), 6),
                 "program": cols["program"][j],
                 "steps": steps,
+                "passes": cols["passes"][j],
                 "dispatch_ms": round(ms, 3),
                 "step_ms": (round(ms / steps, 4) if steps > 0 else None),
                 "occupancy": round(cols["occ"][j], 4),

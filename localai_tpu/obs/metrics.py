@@ -231,6 +231,12 @@ class Registry:
             "Programs the admission path launched (the arming update and "
             "the prefill dispatches): 1 + its chunks an admission",
         )
+        self.loop_passes = Counter(
+            "localai_loop_passes_total",
+            "Passes over the layer stack the engine dispatched: forwards "
+            "times the model's passes a forward (a looped decoder runs its "
+            "stack several times a token; every other model once)",
+        )
         self.prompt_cache_hits = Counter(
             "localai_prompt_cache_hits_total",
             "Disk prompt-KV cache lookups that returned a usable prefix",
@@ -700,6 +706,8 @@ def update_engine_gauges(name: str, m: dict,
         reg.admit_blocking_reads.set_total(
             m.get("admit_blocking_reads", 0), model=name)
         reg.admit_programs.set_total(m.get("admit_programs", 0), model=name)
+    if "loop_passes" in m:
+        reg.loop_passes.set_total(m["loop_passes"], model=name)
     if m.get("shed_total"):
         # shed admissions are whole-request waste (no tokens were ever
         # generated); the requests_shed family stays owned by obs.slo —

@@ -83,6 +83,9 @@ def param_specs(
             "w_down": P(None, "model", None),
         },
     }
+    if cfg.post_norm:
+        specs["layers"]["attn_post_norm"] = P(None, None)
+        specs["layers"]["mlp_post_norm"] = P(None, None)
     if cfg.attention_bias:
         specs["layers"]["bq"] = P(None, "model")
         specs["layers"]["bk"] = P(None, "model")

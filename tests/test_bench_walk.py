@@ -1,0 +1,76 @@
+"""Cases of ``benchmark/tests/`` that need no server, run AS THEY STAND so
+that tier-1 counts them (PERF.md section 7 (A); ``benchmark/tests/`` itself is
+not part of tier-1): the order a family's ``walk`` gives, the loop a family
+without one keeps, a row the model does not hold, ``cache_layers``, an optional
+name that is no function (``test_walk.py``); the flight ring paged forward
+(``test_contract.py``); and what the committed BENCHMARK.json names.
+
+The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
+``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
+``conftest`` under that name while they import: their ``from conftest import
+...`` means the benchmark's, and ``conftest`` here is tier-1's."""
+
+import importlib.util
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmark"
+sys.path[:0] = [str(BENCH), str(BENCH / "tests")]
+
+
+def _load(name: str, **modules):
+    """``benchmark/tests/<name>.py`` as a module of its own name space, with
+    ``modules`` standing in ``sys.modules`` while it imports."""
+    saved = {k: sys.modules.get(k) for k in modules}
+    sys.modules.update(modules)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            f"benchmark_tests_{name}", BENCH / "tests" / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                sys.modules.pop(k, None)
+            else:
+                sys.modules[k] = v
+    return mod
+
+
+_conftest = _load("conftest")
+_walk = _load("test_walk", conftest=_conftest)
+_contract = _load("test_contract", conftest=_conftest)
+_ouro = _load("test_ouro_family", conftest=_conftest)
+
+# the fixtures those cases ask for
+bench_copy = _conftest.bench_copy
+cpu_peaks = _conftest.cpu_peaks
+params = _walk.params
+
+test_the_layers_run_in_the_order_the_walk_gives = (
+    _walk.test_the_layers_run_in_the_order_the_walk_gives)
+test_a_family_without_a_walk_gets_the_loop_it_had = (
+    _walk.test_a_family_without_a_walk_gets_the_loop_it_had)
+test_a_row_the_model_does_not_hold_is_an_error = (
+    _walk.test_a_row_the_model_does_not_hold_is_an_error)
+test_the_pools_leading_dimension_is_the_familys = (
+    _walk.test_the_pools_leading_dimension_is_the_familys)
+test_an_optional_name_that_is_no_function_is_an_error = (
+    _walk.test_an_optional_name_that_is_no_function_is_an_error)
+test_the_flight_ring_is_paged_forward_until_a_short_page = (
+    _contract.test_the_flight_ring_is_paged_forward_until_a_short_page)
+test_the_four_chip_cell_reports_what_a_chat_cell_and_a_mesh_report = (
+    _contract
+    .test_the_four_chip_cell_reports_what_a_chat_cell_and_a_mesh_report)
+test_benchmark_json_names_only_files_that_exist = (
+    _contract.test_benchmark_json_names_only_files_that_exist)
+# PR 37's files: the looped family's hand arithmetic, its walk, its cell, its readers
+test_the_hand_arithmetic_of_the_published_keys = (
+    _ouro.test_the_hand_arithmetic_of_the_published_keys)
+test_the_walk_is_every_pass_in_order_with_the_norm_between = (
+    _ouro.test_the_walk_is_every_pass_in_order_with_the_norm_between)
+test_the_new_cell_reports_what_the_issue_names = (
+    _ouro.test_the_new_cell_reports_what_the_issue_names)
+test_the_loop_readers_read_the_ring_and_the_scope = (
+    _ouro.test_the_loop_readers_read_the_ring_and_the_scope)

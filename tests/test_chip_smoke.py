@@ -22,6 +22,8 @@ import chip_smoke  # noqa: E402
 # debug:tiny: 4 q heads / 2 kv heads / head_dim 16, max context 512
 TINY = dict(model="debug:tiny", context=512, slots=4)
 TINY_HEADS = {"num_heads": 4, "num_kv_heads": 2, "head_dim": 16}
+# the looped case at tiny's head_dim: 4 = 4 heads, 2 passes x 3 layers
+TINY_LOOPED = {"heads": 4, "cache_layers": 6, "picks": [0, 2, 3, 5]}
 
 
 @pytest.fixture()
@@ -34,7 +36,7 @@ def smoke(tmp_path):
 def test_kernel_phase_steps_on_cpu(smoke, capfd):
     report = chip_smoke.kernel_phase(
         smoke, context=256, slots=4, heads=TINY_HEADS, ffn=128,
-        prefill_buckets=(128,), interpret=True)
+        prefill_buckets=(128,), interpret=True, looped=TINY_LOOPED)
     assert smoke.device["platform"] == "cpu"
     # parent and child together: every stdout line names the device
     lines = capfd.readouterr().out.strip().splitlines()
@@ -43,7 +45,10 @@ def test_kernel_phase_steps_on_cpu(smoke, capfd):
     names = [c["case"] for c in report["cases"]]
     # every entry point the selectors can answer "pallas" for
     for want in ("paged_decode bfloat16", "paged_decode int8",
-                 "paged_decode int4", "decode bfloat16", "decode int8",
+                 "paged_decode int4",
+                 "paged_decode looped q_per_kv=1 layer 0 of 6",
+                 "paged_decode looped q_per_kv=1 layer 5 of 6",
+                 "decode bfloat16", "decode int8",
                  "prefill T=128", "w8_matmul", "w8_matmul transposed",
                  "w4_matmul"):
         assert any(n.startswith(want) for n in names), (want, names)
@@ -69,6 +74,19 @@ def test_server_phase_steps_on_cpu(smoke, capsys):
     assert "platform=cpu kind=cpu count=1" in lines[-1]
     log = (smoke.out_dir / "server_1chip.log").read_text()
     assert "loaded model smoke (debug:tiny)" in log
+
+
+def test_looped_server_phase_steps_on_cpu(smoke):
+    """The second server phase: ``debug:tiny-loop`` (2 sandwich layers run 3
+    times a token) through the same requests and assertions: chunked
+    prefill, a full batch, shared prefix blocks and the speculation lane
+    over 6 cache layers."""
+    phase = chip_smoke.server_phase(
+        smoke, chips=1, expect_impl="lax", **chip_smoke.LOOPED_SERVER)
+    assert phase["requests"]["speculative_windows"] >= 1
+    assert phase["requests"]["prefix_tokens_reused"] >= 64
+    log = (smoke.out_dir / "server_looped.log").read_text()
+    assert "loaded model smoke (debug:tiny-loop)" in log
 
 
 def test_server_phase_fails_on_the_wrong_kernel_impl(smoke):
@@ -124,7 +142,7 @@ def test_result_line_shape(smoke, monkeypatch, capsys):
         chip_smoke, "kernel_phase",
         lambda s: s.device.update(platform="tpu", kind="TPU v5 lite",
                                   count=1))
-    monkeypatch.setattr(chip_smoke, "server_phase", lambda s, chips: {})
+    monkeypatch.setattr(chip_smoke, "server_phase", lambda s, **kw: {})
     assert chip_smoke.main([]) == 0
     assert not smoke.cache_dir.exists()      # nothing left outside the tree
     lines = capsys.readouterr().out.strip().splitlines()

@@ -495,11 +495,27 @@ def test_host_ema_is_fed_by_pipelined_decode_dispatches(sched):
     assert sched._host_ema <= max(r["dispatch_ms"] for r in rows) * 1e-3
 
 
-def test_streaming_request_bounds_delivery_lag(sched):
+def test_streaming_request_bounds_delivery_lag(sched, monkeypatch):
     """End-to-end: with an SSE stream attached, inter-delta delivery lag
     stays bounded (the dispatch size adapts down from multi_step=16)."""
     import time as _time
 
+    # every choice of the rule beside the step time it was made WITH: read
+    # afterwards, ``_step_ema`` holds dispatches the choice never saw, and
+    # on a loaded machine (tier-1 runs six workers) one slow dispatch more
+    # than doubles it: that comparison came and went with the load (the
+    # second timed case of PERF.md section 7 (B), found in PR 37)
+    real, chosen = sched._effective_steps, []
+
+    def choose(pipelined=True):
+        with sched._lock:
+            streaming = any(c.handle.request.stream
+                            for c in sched._slots.values())
+        ema, k = sched._step_ema, real(pipelined)
+        chosen.append((k, ema, streaming))
+        return k
+
+    monkeypatch.setattr(sched, "_effective_steps", choose)
     h = sched.submit(_req("stream latency", max_new_tokens=24,
                           temperature=0.0, ignore_eos=True, stream=True))
     arrivals = []
@@ -508,12 +524,15 @@ def test_streaming_request_bounds_delivery_lag(sched):
     assert h.finish_reason is not None
     # the engine must have taken the adaptive path (a power of two ≤ 16),
     # and its own lag model — steps×depth×ema — must fit the target with
-    # the step size it chose
-    steps = sched.last_dispatch_steps
-    assert steps in (1, 2, 4, 8, 16)
-    if sched._step_ema is not None and steps > 1:
-        assert steps * sched.pipeline_depth * sched._step_ema <= \
-            2 * sched.stream_latency_target
+    # the step size it chose, at the step time it chose it by
+    assert sched.last_dispatch_steps in (1, 2, 4, 8, 16)
+    with_stream = [(k, ema) for k, ema, streaming in chosen if streaming]
+    assert with_stream
+    for k, ema in with_stream:
+        assert k in (1, 2, 4, 8, 16)
+        if ema is not None and k > 1:
+            assert (k * sched.pipeline_depth * ema
+                    <= sched.stream_latency_target), (k, ema)
     gaps = [b - a for a, b in zip(arrivals, arrivals[1:])]
     # generous wall-clock bound (CPU test machine, first-compile excluded
     # via median): the old fixed 16×2 dispatch would burst, not trickle
@@ -706,24 +725,29 @@ def test_prompt_cache_hit_gives_the_stream_of_a_full_prefill(tiny, tmp_path):
     assert s.metrics()["admit_blocking_reads"] == 0
 
 
-def test_first_token_returns_through_the_pipeline_in_order(paged,
-                                                           monkeypatch):
-    """An arrival beside a decoding stream. (1) The decode step behind the
-    final chunk is launched BEFORE that chunk's token is read on the host:
-    nothing on the path waits for the device. (2) The rows of every
-    dispatch launched before the chunk (the one in flight at the arrival
-    among them) are delivered BEFORE the new request's first token, those
-    launched behind it after. (3) The counters say so: no blocking read,
-    two programs for this one-chunk admission."""
-    s, _ = paged
+def _arrival_log(s: Scheduler, monkeypatch, steps=None,
+                 spy_on_step_n: bool = True):
+    """An arrival beside a decoding stream, with the engine's launches,
+    reads and deliveries logged in order. ``steps`` pins what
+    ``_effective_steps`` answers (None: its own rule, which under a loaded
+    host picks 2 or 4 for the tiny model: the host is then slow against the
+    step); a dispatch of k > 1 steps is launched through
+    ``runner.step_n_async``, one of k = 1 through ``step_async``, so a spy
+    that is to see every decode launch sits on BOTH. Returns (log, counters
+    before, counters after, the keeper's tokens at the arrival's end)."""
     keeper = _keeper(s)
     log = []
-    real_launch, real_first = s.runner.step_async, s._first_token
-    real_rows, real_install = s._process_rows, s._install_slot
+    real_launch, real_launch_n = s.runner.step_async, s.runner.step_n_async
+    real_first, real_rows = s._first_token, s._process_rows
+    real_install = s._install_slot
 
     def launch():
         log.append(("decode_launch", s._dispatch_seq))
         return real_launch()
+
+    def launch_n(n):
+        log.append(("decode_launch", s._dispatch_seq))
+        return real_launch_n(n)
 
     def first(pf, tok):
         log.append(("first_token_read", None))
@@ -738,6 +762,11 @@ def test_first_token_returns_through_the_pipeline_in_order(paged,
         return real_install(*a)
 
     monkeypatch.setattr(s.runner, "step_async", launch)
+    if spy_on_step_n:
+        monkeypatch.setattr(s.runner, "step_n_async", launch_n)
+    if steps is not None:
+        monkeypatch.setattr(s, "_effective_steps",
+                            lambda pipelined=True: steps)
     monkeypatch.setattr(s, "_first_token", first)
     monkeypatch.setattr(s, "_process_rows", rows)
     monkeypatch.setattr(s, "_install_slot", install)
@@ -745,14 +774,41 @@ def test_first_token_returns_through_the_pipeline_in_order(paged,
     h = s.generate(_req("arrival", max_new_tokens=6, stream=True,
                         ignore_eos=True, **GREEDY))
     assert h.finish_reason == "length"
+    # the scene needs the keeper still decoding when the arrival is done:
+    # one that had spent its 80 tokens left the arrival alone in the batch
+    spent = keeper.completion_tokens
     keeper.cancel()
     keeper.result(60)
     monkeypatch.undo()
-    after = s.metrics()
+    return log, before, s.metrics(), spent
+
+
+def _around_the_chunk(log):
+    """(names, index of the final chunk's launch, index of its token's
+    read, the dispatch counter at the launch)."""
     names = [n for n, _ in log]
     armed, read = (names.index("final_chunk_launched"),
                    names.index("first_token_read"))
-    seq = log[armed][1]         # the dispatch counter at the chunk's launch
+    return names, armed, read, log[armed][1]
+
+
+@pytest.mark.parametrize("steps", [None, 1, 2, 4])
+def test_first_token_returns_through_the_pipeline_in_order(paged,
+                                                           monkeypatch,
+                                                           steps):
+    """An arrival beside a decoding stream. (1) The decode step behind the
+    final chunk is launched BEFORE that chunk's token is read on the host:
+    nothing on the path waits for the device. (2) The rows of every
+    dispatch launched before the chunk (the one in flight at the arrival
+    among them) are delivered BEFORE the new request's first token, those
+    launched behind it after. (3) The counters say so: no blocking read,
+    two programs for this one-chunk admission. Whatever the dispatch's step
+    count: the scheduler's own choice (which follows the host's load), and
+    1, 2 and 4 pinned."""
+    s, _ = paged
+    log, before, after, spent = _arrival_log(s, monkeypatch, steps)
+    assert spent < 80, "the keeper ran out before the arrival was served"
+    names, armed, read, seq = _around_the_chunk(log)
     assert "decode_launch" in names[armed:read], log[armed:read + 1]
     assert all(q <= seq for n, q in log[:read] if n == "rows")
     assert [q for n, q in log[read:] if n == "rows"][0] == seq + 1
@@ -764,6 +820,24 @@ def test_first_token_returns_through_the_pipeline_in_order(paged,
     assert s.flight.snapshot(limit=400) and all(
         r["sync_ms"] == 0.0 for r in s.flight.snapshot(limit=400)
         if r["program"] == "prefill_chunk")
+
+
+def test_a_spy_on_step_async_alone_misses_a_two_step_dispatch(paged,
+                                                              monkeypatch):
+    """Why the test above came and went with the machine's load until
+    PR 37: its spy sat on ``runner.step_async`` ALONE. With two steps a
+    dispatch (pinned here; a loaded host makes ``_effective_steps`` choose
+    it) every decode launch goes through ``step_n_async``, the log holds no
+    launch at all, and (1) above reads as broken though the order is sound:
+    the rows still arrive around the first token in dispatch order."""
+    s, _ = paged
+    log, _, _, spent = _arrival_log(s, monkeypatch, steps=2,
+                                    spy_on_step_n=False)
+    assert spent < 80
+    names, armed, read, seq = _around_the_chunk(log)
+    assert "decode_launch" not in names
+    assert all(q <= seq for n, q in log[:read] if n == "rows")
+    assert [q for n, q in log[read:] if n == "rows"][0] == seq + 1
 
 
 def test_a_constrained_first_token_is_waited_for_and_counted(paged):

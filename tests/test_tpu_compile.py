@@ -340,15 +340,16 @@ def compile_program(fn, *args, **static):
 
 def compile_cell_program(r, a, program):
     """One of a cell's dispatched programs over ``abstract_runner``'s
-    (runner, avals): ``decode``, ``decode_n2`` or ``prefill_chunk_512``
+    (runner, avals): ``decode``, ``decode_n2`` or ``prefill_chunk_<bucket>``
     (``..._sample``: the chunk that ends a prompt and samples)."""
     base = (a["params"], a["kv"], a["state"])
     if program == "decode":
         return compile_program(r._decode_paged_fn, *base, a["tables"])
     if program == "decode_n2":
         return compile_program(r._decode_paged_n_fn, *base, a["tables"], n=2)
+    bucket = int(program.split("_")[2])
     return compile_program(
-        r._prefill_paged_fn, *base, *a["chunk"](512), bucket=512,
+        r._prefill_paged_fn, *base, *a["chunk"](bucket), bucket=bucket,
         sample=program.endswith("_sample"))
 
 
@@ -396,6 +397,7 @@ def test_smoke_programs_fit_one_chip(topo, monkeypatch):
 
 
 M7B, MS24B = "mistral-7b-v0.3-int8", "mistral-small-24b-int8-tp4"
+OURO = "ouro-2.6b-int8"
 
 
 @pytest.fixture
@@ -474,6 +476,52 @@ def assert_in_place(program, c, pool):
             op == "fusion" and 'kv_pool.write/scatter"' in rest
             and '"aliasing_operands"' in rest)]
     assert not moved, f"{program} moves the pool or a layer of it: {moved}"
+
+
+@pytest.mark.parametrize("cell", [OURO], indirect=True)
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk_128",
+                                     "prefill_chunk_512_sample"])
+def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
+                                                      cell, program):
+    """PR 37: the looped decoder's programs (48 layers run 4 times a token,
+    a cache layer for every (pass, layer) pair: 192 x 101 blocks x 16 kv
+    heads x 64 x 128) are held to what the others are. The (x, kv) carry
+    goes through the loop over passes AND the layer scan inside it, so the
+    9.5 GiB pool is still one buffer from argument to output: no pool-sized
+    temp, no layer-shaped copy; the weight stacks are read where they lie
+    by every pass (no stack-sized temp: an unrolled loop, or a scan that
+    takes the stack as ``xs`` of the OUTER loop, would stage one); and a
+    decode step holds ONE paged kernel call, inside both loops, which picks
+    cache layer ``pass * 48 + layer`` in its DMA slice."""
+    cfg, doc = cell
+    eng = doc["engine"]
+    assert (cfg.num_passes, cfg.num_layers, cfg.cache_layers) == (4, 48, 192)
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    pool = a["kv"].k.shape
+    assert pool == (192, 101, 16, 64, 128) and a["kv"].k.dtype == bf16
+    c = compile_cell_program(r, a, program)
+    assert_in_place(program, c, pool)
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if program == "decode" else 0)
+    m = c.memory_analysis()
+    # no weight stack staged: the smallest stacked leaf is [48, 2048, 2048]
+    assert m.temp_size_in_bytes < cfg.num_layers * cfg.hidden_size ** 2, (
+        f"{program}: temp {m.temp_size_in_bytes / 2**20:.0f} MiB holds a "
+        f"weight stack")
+    # the loop is rolled: one layer body, not one a pass (the scope names
+    # every operation of a layer carries appear under ONE loop.pass)
+    assert text.count("loop.pass/layers") > 0
+    need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    print(f"HBM {program}: arguments {m.argument_size_in_bytes / 2**30:.3f} "
+          f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
+          f"{need / 2**30:.3f} GiB")
+    assert need < HBM_BYTES
 
 
 @pytest.mark.parametrize("cell, program, overlap", [
