@@ -6,7 +6,10 @@ Drives the main path once, through the entry points a user would call:
   * kernel phase — ONE child process on the chip compiles (never interprets)
     every Pallas entry point the selectors in localai_tpu.ops answer "pallas"
     for at the Llama-3-8B head shapes, and compares each with its lax
-    reference on seeded random data;
+    reference on seeded random data; where the paged kernel also WRITES the
+    decode step's rows (unscaled pools), the pool it hands back with the
+    policy's scatter's, exactly (``--chips 4``: one more child runs that
+    case under ``shard_map`` over the four chips, each writing its heads);
   * server phase — ``python -m localai_tpu.cli.main run`` as a child, serving
     ``debug:llama3-8b`` int8 at all 32 layers and published widths, asserted
     from outside over HTTP: the devices it reports, a handful of
@@ -210,7 +213,13 @@ def kernel_child(spec: dict) -> int:
 
     cases = []
 
-    def run(name, kernel, reference, *args):
+    def finish() -> int:
+        report = {"device": device, "interpret": interpret, "tolerance": TOL,
+                  "block_tokens": bt, "cases": cases}
+        Path(spec["report"]).write_text(json.dumps(report, indent=2) + "\n")
+        return 0 if all(c["ok"] for c in cases) else 4
+
+    def run(name, kernel, reference, *args, tol=TOL):
         t0 = time.monotonic()
         got = np.asarray(jax.block_until_ready(jax.jit(kernel)(*args)),
                          np.float32)
@@ -219,7 +228,7 @@ def kernel_child(spec: dict) -> int:
             ref = np.asarray(jax.jit(reference)(*args), np.float32)
         err = float(np.max(np.abs(got - ref)))
         ok = (got.shape == ref.shape and bool(np.all(np.isfinite(got)))
-              and bool(np.allclose(got, ref, atol=TOL, rtol=TOL)))
+              and bool(np.allclose(got, ref, atol=tol, rtol=tol)))
         cases.append({"case": name, "ok": ok, "max_abs_err": round(err, 5),
                       "shape": list(got.shape),
                       "compile_and_run_s": round(seconds, 2)})
@@ -244,6 +253,73 @@ def kernel_child(spec: dict) -> int:
     positions = jnp.asarray([edges[i % len(edges)] for i in range(S)],
                             jnp.int32)
 
+    def writes_case(name, q, k, v, layer, tabs, pos, wrap=lambda f: f):
+        """The kernel as the decode step's WRITER (unscaled pools): handed
+        the pool of before the step and the step's rows, its output against
+        the reference over the pool the policy's scatter
+        (``kvcache._write_rows``) leaves, and the two pools it hands back
+        against that pool, EXACTLY, in every layer outside the trash block
+        (largest difference 0). ``wrap``: the kernel under a mesh."""
+        from localai_tpu.engine import kvcache as kvc
+
+        k_new, v_new = (normal((q.shape[0], k.shape[2], k.shape[-1]))
+                        for _ in range(2))
+        kernel = wrap(lambda *a: ops.paged_decode_attention(
+            *a, interpret=interpret))
+
+        def written(q, k, v, tabs, pos, k_new, v_new):
+            return kernel(q, k, v, jnp.int32(layer), tabs, pos, None, None,
+                          k_new, v_new)
+
+        def scattered(q, k, v, tabs, pos, k_new, v_new):
+            blk = tabs[jnp.arange(q.shape[0]), pos // bt]
+            return kvc._write_rows((k, v), jnp.int32(layer), blk, pos % bt,
+                                   k_new, v_new)
+
+        def apart(q, k, v, *rest):
+            return jnp.stack([
+                jnp.max(jnp.abs(f32(a[:, 1:]) - f32(b[:, 1:])))
+                for a, b in zip(written(q, k, v, *rest)[1:],
+                                scattered(q, k, v, *rest))])
+
+        def reference(q, k, v, tabs, pos, *rows):
+            k2, v2 = scattered(q, k, v, tabs, pos, *rows)
+            return ops.paged_decode_attention_ref(q, k2[layer], v2[layer],
+                                                  tabs, pos)
+
+        args = (q, k, v, tabs, pos, k_new, v_new)
+        run(f"{name} writes: output", lambda *a: written(*a)[0], reference,
+            *args)
+        run(f"{name} writes: pool against the scatter's", apart,
+            lambda *a: jnp.zeros(2), *args, tol=0.0)
+
+    if spec.get("mesh"):
+        # -- four chips: the kernel under shard_map as the meshed runner
+        # wraps it, heads on 'model': each chip writes its own heads' rows
+        from jax import shard_map
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        mesh = Mesh(np.array(devices[:spec["mesh"]]).reshape(1, -1),
+                    ("data", "model"))
+        rows, pool = P("data", "model", None), P(None, None, "model", None,
+                                                 None)
+        MB = min(spec["context"] // bt, 8)
+        N = S * MB + 1
+        tabs = jnp.asarray(rng.permutation(np.arange(1, N))[:S * MB]
+                           .reshape(S, MB), jnp.int32)
+        pos = jnp.asarray([(i * (bt + 5)) % (MB * bt) for i in range(S)],
+                          jnp.int32)
+        writes_case(
+            f"paged_decode bfloat16 bt={bt} hd={hd} on {spec['mesh']} chips",
+            normal((S, Hq, hd)), normal((2, N, Hkv, bt, hd)),
+            normal((2, N, Hkv, bt, hd)), 1, tabs, pos,
+            wrap=lambda f: shard_map(
+                f, mesh=mesh, in_specs=(
+                    rows, pool, pool, P(), P("data", None), P("data"), None,
+                    None, rows, rows),
+                out_specs=(rows, pool, pool), check_vma=False))
+        return finish()
+
     def paged_case(kv_dtype, Hq, Hkv, hd):
         selected(ops.select_paged_attn_impl, num_heads=Hq, num_kv_heads=Hkv,
                  head_dim=hd, block_tokens=bt, kv_dtype=kv_dtype)
@@ -265,6 +341,10 @@ def kernel_child(spec: dict) -> int:
         run(f"paged_decode {kv_dtype} bt={bt} hd={hd}", paged_kernel,
             ops.paged_decode_attention_ref,
             q, k, v, tables, positions, *extra)
+        if not extra:       # an unscaled pool: the kernel writes the step
+            writes_case(f"paged_decode {kv_dtype} bt={bt} hd={hd}", q,
+                        jnp.stack([k, v]), jnp.stack([v, k]), 1, tables,
+                        positions)
 
     paged_case("bfloat16", Hq, Hkv, hd)
     paged_case("int8", Hq, Hkv, hd)
@@ -304,6 +384,8 @@ def kernel_child(spec: dict) -> int:
                     ops.paged_decode_attention_ref(
                         q, k[layer], v[layer], tabs, pos),
                 q, k, v, tabs, pos)
+        writes_case(f"paged_decode looped q_per_kv=1 layer {picks[-1]} of "
+                    f"{cache_layers}", q, k, v, picks[-1], tabs, pos)
 
     looped_case(**spec["looped"])
 
@@ -373,10 +455,7 @@ def kernel_child(spec: dict) -> int:
         run(name, kern, lambda x, q, s, wd=wd: f32(x) @ wd,
             x, qt.q, qt.scale)
 
-    report = {"device": device, "interpret": interpret, "tolerance": TOL,
-              "block_tokens": bt, "cases": cases}
-    Path(spec["report"]).write_text(json.dumps(report, indent=2) + "\n")
-    return 0 if all(c["ok"] for c in cases) else 4
+    return finish()
 
 
 def kernel_phase(smoke: Smoke, *, context: int = CONTEXT, slots: int = SLOTS,
@@ -404,6 +483,32 @@ def kernel_phase(smoke: Smoke, *, context: int = CONTEXT, slots: int = SLOTS,
                   f"(tolerance {report['tolerance']})")
     check(report["interpret"] == interpret, "kernel phase ran interpreted")
     smoke.report["phases"]["kernels"] = report
+    return report
+
+
+def mesh_kernel_phase(smoke: Smoke, *, chips: int, context: int = CONTEXT,
+                      slots: int = SLOTS, heads: dict = HEADS,
+                      interpret: bool = False, timeout: float = 600.0,
+                      env: dict | None = None) -> dict:
+    """One child over ``chips`` chips: the paged kernel under ``shard_map``
+    as the meshed runner wraps it, writing the step's rows: each chip its
+    own heads', the pool against the scatter's."""
+    report_path = smoke.out_dir / "kernels_mesh.json"
+    spec = {"expect_platform": smoke.expect_platform, "context": context,
+            "slots": slots, "interpret": interpret, "seed": 1, "mesh": chips,
+            "report": str(report_path), **heads}
+    smoke.say(f"kernel phase over {chips} chips: starting")
+    proc = subprocess.run(
+        [sys.executable, str(Path(__file__).resolve()), "--kernel-child",
+         json.dumps(spec)], env=smoke.child_env(env), timeout=timeout)
+    check(proc.returncode == 0,
+          f"kernel phase over {chips} chips: child exited {proc.returncode}")
+    report = json.loads(report_path.read_text())
+    check(report["device"]["count"] >= chips,
+          f"kernel phase over {chips} chips saw {report['device']}")
+    for c in report["cases"]:
+        smoke.say(f"kernel {c['case']}: max_abs_err={c['max_abs_err']}")
+    smoke.report["phases"]["kernels_mesh"] = report
     return report
 
 
@@ -727,6 +832,12 @@ def server_phase(smoke: Smoke, *, chips: int = 1,
         check(value("localai_paged_kernel_impl", impl=expect_impl) == [1.0],
               f"paged kernel impl is not {expect_impl}: "
               f"{[s for s in samples if s[0] == 'localai_paged_kernel_impl']}")
+        # who writes a decode step's rows: the kernel wherever it serves
+        # (the smoke's pools are bf16), the scatter under gather + XLA
+        writer = "scatter" if expect_impl == "lax" else "kernel"
+        check(value("localai_paged_kv_write_impl", impl=writer) == [1.0],
+              f"a decode step's K/V rows are not written by the {writer}: "
+              f"{[s for s in samples if s[0] == 'localai_paged_kv_write_impl']}")
         check(not any(value("localai_engine_rebuilds_total")),
               "the engine was rebuilt")
         check(not any(value("localai_stalls_total")), "a stall was recorded")
@@ -848,6 +959,8 @@ def main(argv=None) -> int:
     t0 = time.monotonic()
     try:
         kernel_phase(smoke)
+        if args.chips > 1:
+            mesh_kernel_phase(smoke, chips=args.chips)
         server_phase(smoke, chips=args.chips)
         # the looped decoder through the same entry points (one chip, pinned:
         # its two heads split over no mesh): passes x layers of cache, the

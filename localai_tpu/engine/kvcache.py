@@ -29,6 +29,14 @@ or, ``raw=True``, a ``LayerView`` of the stack for a Pallas kernel that
 indexes the layer itself. Nothing slices a layer out of the stack to update
 it or to pass it on: such a slice, its re-layout and the restack cost more
 than the rest of a decode step together (PERF.md, PR 26).
+
+One policy leaves the write to its attend: ``paged_decode_write(raw=True)``
+over an unscaled pool hands the stack back UNTOUCHED, its views carry the
+step's rows (``LayerView.new``), and the paged kernel, which has the very
+block the row belongs to in VMEM, lays it there and copies it back
+(``kernel_attend``; ``models.llama.forward`` takes the stack an attend
+hands back beside its output). A scatter's cost is its windows', ~100 ns
+each whatever their bytes, S x H x 2 of them a layer (PERF.md, PR 38).
 tests/test_tpu_compile.py compiles the runner's programs for a described
 v5e and holds them to no cache-sized temp and no layer-shaped copy.
 """
@@ -222,11 +230,14 @@ class LayerView(NamedTuple):
     """What a ``raw=True`` policy hands a Pallas decode kernel in place of
     keys (and of values): the WHOLE stacked cache, the layer to read, and
     the stacked scales of a quantized cache. The kernel picks the layer in
-    its DMA slice (ops.attention), so no per-layer slice ever exists."""
+    its DMA slice (ops.attention), so no per-layer slice ever exists.
+    ``new``: the step's rows in the cache's dtype, where the policy has NOT
+    stored them and the kernel is to."""
 
     cache: jax.Array                    # [L, ...] all layers
     layer: jax.Array                    # scalar i32
     scale: Optional[jax.Array] = None   # [L, ...] f32, quantized caches
+    new: Optional[jax.Array] = None     # [S, H, hd], for the kernel to write
 
 
 def _is_int4(kv_stack, k_new) -> bool:
@@ -384,9 +395,18 @@ def paged_decode_write(tables: jax.Array, positions: jax.Array,
 
     ``raw=False`` exposes the gathered logical context [S, H, MB*bt, hd]
     for the XLA attend; ``raw=True`` hands the Pallas paged kernel (which
-    walks the tables itself) a :class:`LayerView` of the stack."""
+    walks the tables itself) a :class:`LayerView` of the stack. Over an
+    unscaled pool (a 2-tuple stack) that kernel is the writer too: the
+    stack goes back untouched and the views carry the rows, cast to what
+    the pool stores. A scaled pool's f32 scale row ``[.., bt]`` is narrower
+    than a DMA tile: it keeps the scatter, as the XLA attend does."""
 
     def write(kv_stack, layer, k_new, v_new):
+        if raw and len(kv_stack) == 2:
+            with jax.named_scope("kv_pool.write"):
+                return (kv_stack, *(
+                    LayerView(cache, layer, None, new[:, 0].astype(cache.dtype))
+                    for cache, new in zip(kv_stack, (k_new, v_new))))
         bt = kv_stack[0].shape[3]
         s = jnp.arange(tables.shape[0])
         blk = tables[s, positions // bt]          # [S]
@@ -397,6 +417,26 @@ def paged_decode_write(tables: jax.Array, positions: jax.Array,
         return (new, *_gather_context(new, layer, tables, k_new))
 
     return write
+
+
+def kernel_attend(kernel, tables: jax.Array, positions: jax.Array):
+    """The ``attn`` of ``models.llama.forward`` over the views
+    ``paged_decode_write(raw=True)`` hands out: ``kernel`` is
+    ``ops.paged_decode_attention`` (under ``shard_map`` or not) by position,
+    scales and new rows last, None where the views hold none. Where they
+    carry the rows, the kernel wrote them and the stack it returns goes
+    back beside the output."""
+
+    def attn(q, keys, values, _mask):   # q [S, 1, Hq, hd]
+        got = kernel(q[:, 0], keys.cache, values.cache, keys.layer, tables,
+                     positions, keys.scale, values.scale, keys.new,
+                     values.new)
+        if keys.new is None:
+            return got[:, None]
+        out, *pools = got
+        return out[:, None], tuple(pools)
+
+    return attn
 
 
 def paged_prefill_write(table_row: jax.Array, offset: jax.Array,

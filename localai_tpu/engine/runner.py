@@ -1016,30 +1016,27 @@ class ModelRunner:
                 # its own slots' SMEM table mirror — the shard_map body is
                 # the single-device kernel (select_paged_attn_impl refuses
                 # Pallas when the head groups don't split over tp)
-                in_specs = [P("data", "model", None),
-                            P(None, None, "model", None, None),
-                            P(None, None, "model", None, None),
-                            P(),
-                            P("data", None),
-                            P("data")]
-                if kv.quantized:
-                    in_specs += [P(None, None, "model", None),
-                                 P(None, None, "model", None)]
+                # Of the last four arguments a pool has two: the f32
+                # scale stacks of a scaled one (fused dequant), or the
+                # step's rows, which the kernel writes into each shard's
+                # own heads of an unscaled one (the pools then come back,
+                # aliased, beside the output)
+                rows = P("data", "model", None)
+                pool = P(None, None, "model", None, None)
+                scale = P(None, None, "model", None)
                 kernel = shard_map(
                     kernel,
                     mesh=self.mesh,
-                    in_specs=tuple(in_specs),
-                    out_specs=P("data", "model", None),
+                    in_specs=(rows, pool, pool, P(), P("data", None),
+                              P("data"),
+                              *((scale, scale, None, None) if kv.quantized
+                                else (None, None, rows, rows))),
+                    out_specs=rows if kv.quantized else (rows, pool, pool),
                     check_vma=False,
                 )
 
-            @scoped("attn.paged_decode")
-            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; kvc.LayerViews
-                args = (q[:, 0], keys.cache, values.cache, keys.layer,
-                        tables, pos)
-                if kv.quantized:  # f32 scale stacks — fused dequant
-                    args += (keys.scale, values.scale)
-                return kernel(*args)[:, None]
+            attn = scoped("attn.paged_decode")(
+                kvc.kernel_attend(kernel, tables, pos))
 
         mask = kvc.decode_mask(cfg, pos, self.ctx_pad)
         write = kvc.paged_decode_write(tables, pos, raw=raw)
@@ -1776,6 +1773,16 @@ class ModelRunner:
         self._active_slots.discard(slot)
         if slot not in self._free_slots:
             self._free_slots.append(slot)
+
+    @property
+    def paged_kv_write_impl(self) -> str:
+        """Who writes a decode step's new K/V rows into the block pool:
+        ``kernel``, the Pallas paged kernel that reads them (an unscaled
+        pool: ``kvcache.paged_decode_write``), else the policy's
+        ``scatter``."""
+        if self.paged_attn_impl == "pallas" and not self.kv.quantized:
+            return "kernel"
+        return "scatter"
 
     @property
     def any_active(self) -> bool:

@@ -590,6 +590,10 @@ class Scheduler:
                     else "pallas_interpret"
                     if getattr(self.runner, "_paged_attn_interpret", False)
                     else "pallas"),
+                # who writes a decode step's rows ("kernel" | "scatter"):
+                # the localai_paged_kv_write_impl series
+                "paged_kv_write_impl": getattr(
+                    self.runner, "paged_kv_write_impl", "scatter"),
                 "kv_dtype": str(self.runner.kv_dtype),
                 "kv_blocks_total": st.total,
                 # free = immediately free + reclaimable prefix-pool cache

@@ -400,7 +400,9 @@ def forward(
     mask: jax.Array,        # [B, T, Lk] bool attention mask
     rope: tuple[jax.Array, jax.Array],
     attn: Any = None,       # optional override: fn(q, keys, values, mask) -> out
-                            # (Pallas flash kernels inject here; None = XLA)
+                            # (Pallas flash kernels inject here; None = XLA),
+                            # or -> (out, new_kv_stack) where kv_write left
+                            # the layer's rows for the attend to store
     embeds: Optional[jax.Array] = None,  # [B, T, D] input embeddings override
                             # (multimodal injection bypasses the token gather)
     reduce: Any = None,     # manual-TP row-parallel reduction applied to the
@@ -415,7 +417,9 @@ def forward(
     index, so XLA compiles one layer body regardless of depth. The stacked
     KV is the scan's CARRY beside the activations: ``kv_write`` scatters
     the layer's new rows into the whole stack and exposes what the attend
-    needs of it. A donated argument that becomes a loop carry and then the
+    needs of it (or leaves the rows to an attend that stores them itself
+    and hands the stack back: the paged decode kernel). A donated argument
+    that becomes a loop carry and then the
     output is what XLA aliases end to end, so the update is in place; a
     scan cannot alias an ``xs`` to a ``ys``, which is why the stack is not
     scanned (that costs a second stack of temp, and a slice-out and a
@@ -461,7 +465,10 @@ def forward(
 
             def attend(q, k_new, v_new):
                 new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
-                return attn(q, keys, values, mask), new_kv
+                out = attn(q, keys, values, mask)
+                if isinstance(out, tuple):  # the attend wrote the stack
+                    out, new_kv = out
+                return out, new_kv
 
             return _layer(cfg, x, lp, cos, sin, attend, reduce=reduce), None
 

@@ -522,6 +522,13 @@ class Registry:
             "Mosaic kernel, the same kernel in the Pallas interpreter, or "
             "gather + XLA",
         )
+        self.paged_kv_write_impl = Gauge(
+            "localai_paged_kv_write_impl",
+            "1 for who writes a decode step's new K/V rows into the block "
+            "pool of each engine (impl=kernel|scatter): the paged decode "
+            "kernel that reads them (an unscaled pool under the Pallas "
+            "kernel), or the policy's scatter in front of the attend",
+        )
         self.kv_invariant_violations = Counter(
             "localai_kv_invariant_violations_total",
             "BlockAllocator.check_invariants violations observed at "
@@ -692,6 +699,11 @@ def update_engine_gauges(name: str, m: dict,
             for label in ("pallas", "pallas_interpret", "lax"):
                 reg.paged_kernel_impl.set(
                     1.0 if impl == label else 0.0, model=name, impl=label)
+        writer = m.get("paged_kv_write_impl")
+        if writer:
+            for label in ("kernel", "scatter"):
+                reg.paged_kv_write_impl.set(
+                    1.0 if writer == label else 0.0, model=name, impl=label)
     if "kv_tier_spills" in m:
         # host-RAM tier attached (single engine OR the fleet roll-up —
         # the latter carries the tier sums without the kv_blocks pane)

@@ -444,7 +444,8 @@ def paged_decode_tiling(kv_heads: int, block_tokens: int, row_lanes: int,
 def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
                          *rest, block_tokens: int, blocks: int, depth: int,
                          sm_scale: float, sliding_window: Optional[int],
-                         quantized: bool, int4: bool, mm_dtype):
+                         quantized: bool, int4: bool, mm_dtype,
+                         write_rows: int = 0):
     # One program a slot, every local kv head in it. k_ref/v_ref are the
     # FULL stacked [L, N, Hkv, bt, hd] block pool in HBM; a table entry's
     # row pool[layer, tbl[s, i]] is one contiguous [Hkv, bt, hd] slab and
@@ -462,8 +463,22 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
     # [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
     # tile): step t's scales are row t. int4 pools arrive nibble-packed
     # [L, N, Hkv, bt, hd/2] and unpack in VMEM after the wait.
+    # With ``write_rows`` the kernel is also the step's WRITER (unscaled
+    # pools): the slot's new K and V rows arrive as one [1, Hkv, hd] block,
+    # the pool is aliased to two outputs, and the slot's last step, which
+    # holds the frontier block tbl[s, pos // bt], lays the row over position
+    # pos % bt of it in the ring before it folds, then copies the aligned
+    # group of ``write_rows`` rows that holds the position (whole tiles,
+    # every head in one strided copy, staged in a buffer of its own so the
+    # ring is free at once) back to pool[layer, row]. Beside the one row the
+    # group carries what was just read from those rows: this slot's older
+    # tokens, or rows past its frontier that nothing reads. A slot on the
+    # trash block (row 0: released slots) writes nothing back.
     if quantized:
         ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem, ring_ref = rest
+    elif write_rows:
+        (knew_ref, vnew_ref, o_ref, kout_ref, vout_ref, kbuf, vbuf, ksem,
+         vsem, ring_ref, kstage, vstage, wsem) = rest
     else:
         o_ref, kbuf, vbuf, ksem, vsem, ring_ref = rest
     s_idx = pl.program_id(0)
@@ -514,6 +529,8 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
         kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
         vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
         ring_ref[0] = 0
+        if write_rows:
+            ring_ref[1] = 0     # no write-back in flight
         start(s_idx, lo, nb, t0, 0)
 
     base = ring_ref[0]          # the buffer this slot's first step is in
@@ -525,11 +542,46 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
     q = q_ref[0].astype(mm_dtype)                     # [Hkv, g, hd]
     Hkv, g, hd = q.shape
 
-    def fold(i, carry):
+    def write_back(stage, out_ref, sem):
+        return pltpu.make_async_copy(
+            stage, out_ref.at[layer, frontier, :, pl.ds(group, write_rows), :],
+            wsem.at[sem])
+
+    def lay(new_ref, ring, buf, stage, out_ref, sem):
+        """The slot's new row over its place in the ring (``buf``: the last
+        step's buffer, which has landed), and its group of rows on the way
+        back to the pool."""
+        at = (buf, slice(None),
+              pl.ds(pl.multiple_of(lax.rem(nb - 1, P) * bt + group,
+                                   write_rows), write_rows), slice(None))
+        hit = lax.broadcasted_iota(
+            jnp.int32, (1, write_rows, 1), 1) == pos % bt - group
+        rows = jnp.where(hit, new_ref[0][:, None, :], ring[at])
+        ring[at] = rows
+
+        @pl.when(frontier != 0)
+        def _():
+            stage[...] = rows
+            write_back(stage, out_ref, sem).start()
+
+    if write_rows:
+        frontier = tbl_ref[s_idx, nb - 1]   # the block the position is in
+        group = pl.multiple_of(pos % bt // write_rows * write_rows,
+                               write_rows)
+
+    def fold(i, carry, last=False):
         m, l, acc = carry
         t = t0 + i
         buf = lax.rem(base + i, depth)
         entries(s_idx, lo, nb, t, buf, lambda *c: k_copy(*c).wait())
+        if last and write_rows:
+            # the staging buffers are the previous slot's until its copies
+            # have left them: a whole program ago
+            @pl.when(ring_ref[1] == 1)
+            def _():
+                write_back(kstage, kout_ref, 0).wait()
+                write_back(vstage, vout_ref, 1).wait()
+            lay(knew_ref, kbuf, buf, kstage, kout_ref, 0)
         if int4:
             k = _unpack_nibbles(kbuf[buf], jnp.float32)
         else:
@@ -552,6 +604,8 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
         if quantized:
             p = p * vs_ref[0, :, pl.ds(t, 1), :]
         entries(s_idx, lo, nb, t, buf, lambda *c: v_copy(*c).wait())
+        if last and write_rows:
+            lay(vnew_ref, vbuf, buf, vstage, vout_ref, 1)
         if int4:
             v = _unpack_nibbles(vbuf[buf], jnp.float32)
         else:
@@ -582,8 +636,18 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
         start(s_idx + 1, lo_n, nb_n, t0_n, after)
 
     ring_ref[0] = after
-    _, l, acc = fold(steps - 1, carry)
+    _, l, acc = fold(steps - 1, carry, last=True)
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if write_rows:
+        # the next slot waits for these copies before it stages its own;
+        # the call's last program waits itself: the next call of this cache
+        # layer, one step later, reads the row
+        ring_ref[1] = (frontier != 0).astype(jnp.int32)
+
+        @pl.when((s_idx + 1 == n_slots) & (frontier != 0))
+        def _():
+            write_back(kstage, kout_ref, 0).wait()
+            write_back(vstage, vout_ref, 1).wait()
 
 
 def paged_decode_attention(
@@ -596,11 +660,14 @@ def paged_decode_attention(
     positions: jax.Array,    # [S] i32 — current token's KV write position
     k_scale: Optional[jax.Array] = None,  # [L, N, Hkv, bt] f32 (int8/int4)
     v_scale: Optional[jax.Array] = None,
+    k_new: Optional[jax.Array] = None,  # [S, Hkv, hd], the pool's dtype:
+    v_new: Optional[jax.Array] = None,  # the step's rows, for the kernel
+                                        # to WRITE (unscaled pools)
     *,
     sliding_window: Optional[int] = None,
     interpret: bool = False,
     num_buffers: int = _PAGED_NUM_BUFFERS,
-) -> jax.Array:
+):
     """Flash GQA decode attention over one layer of the stacked paged
     block pool. Returns [S, Hq, hd]. One program a slot walks the slot's
     block table in SMEM; a table entry's row of the pool, every (local) kv
@@ -624,13 +691,37 @@ def paged_decode_attention(
     heads (read from the pool's shape), so the pool's layer and block axes
     must arrive WHOLE on every device (table values are global physical
     block ids) and both head counts must divide the 'model' width
-    (``ops.select_paged_attn_impl`` gates that)."""
+    (``ops.select_paged_attn_impl`` gates that).
+
+    With ``k_new`` / ``v_new`` the kernel also WRITES the step: the pool is
+    as it was BEFORE the step, the rows are each slot's new K and V in the
+    pool's dtype, and the call returns ``(out, k_cache, v_cache)``, the
+    pools aliased to their arguments and holding row ``s`` at
+    ``[layer, tables[s, positions[s] // bt], :, positions[s] % bt]``,
+    trash block aside (a slot whose frontier entry is block 0 writes
+    nothing). The slot's program lays the row over the frontier block it
+    has in VMEM anyway, attends over it as if a scatter had stored it
+    first, and copies the aligned group of rows around the position back.
+    The attention output stays the FIRST result (the benchmark's readers
+    name an operation by the first shape of its result). Unscaled pools
+    only: a scaled pool's f32 scale row is narrower than a DMA tile, and
+    its policy scatters (engine.kvcache)."""
     S, Hq, hd = q.shape
     Hkv, bt = k_cache.shape[2], k_cache.shape[3]
     MB = tables.shape[1]
     g = Hq // Hkv
     qg = q.reshape(S, Hkv, g, hd)
     quantized = k_scale is not None
+    writes = k_new is not None
+    if writes and quantized:
+        raise ValueError("the paged kernel writes unscaled pools only")
+    # rows a write-back moves: whole (sublane) tiles of the pool's dtype
+    # around the position, or the block where it holds no whole tile
+    write_rows = 0
+    if writes:
+        write_rows = 32 // k_cache.dtype.itemsize
+        if bt % write_rows:
+            write_rows = bt
     # an int4 pool is self-describing: its last dim is the packed hd/2
     int4 = quantized and k_cache.shape[-1] * 2 == hd
     P, depth, _ = paged_decode_tiling(
@@ -645,6 +736,7 @@ def paged_decode_attention(
         sm_scale=hd ** -0.5, sliding_window=sliding_window,
         quantized=quantized, int4=int4,
         mm_dtype=jnp.bfloat16 if exact else jnp.float32,
+        write_rows=write_rows,
     )
     in_specs = [
         pl.BlockSpec((1,), lambda s: (0,), memory_space=pltpu.SMEM),
@@ -671,25 +763,45 @@ def paged_decode_attention(
     # int4 pools buffer the packed [.., hd/2] bytes (unpack happens after
     # the wait), so the ring mirrors the pool's last dim
     ring = (depth, Hkv, P * bt, k_cache.shape[-1])
-    out = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, Hkv, g, hd), lambda s: (s, 0, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((S, Hkv, g, hd), q.dtype)]
+    scratch = [
+        pltpu.VMEM(ring, k_cache.dtype),
+        pltpu.VMEM(ring, v_cache.dtype),
+        pltpu.SemaphoreType.DMA((depth,)),
+        pltpu.SemaphoreType.DMA((depth,)),
+        # the ring position and, where the kernel writes, whether the
+        # previous slot's write-back is in flight
+        pltpu.SMEM((2,), jnp.int32),
+    ]
+    aliases = {}
+    if writes:
+        row = pl.BlockSpec((1, Hkv, hd), lambda s: (s, 0, 0))
+        in_specs += [row, row]
+        args += [k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype)]
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        out_shape += [jax.ShapeDtypeStruct(c.shape, c.dtype)
+                      for c in (k_cache, v_cache)]
+        aliases = {4: 1, 5: 2}      # the pools: written where they lie
+        stage = (Hkv, write_rows, hd)
+        scratch += [pltpu.VMEM(stage, k_cache.dtype),
+                    pltpu.VMEM(stage, v_cache.dtype),
+                    pltpu.SemaphoreType.DMA((2,))]
+    out, *pools = pl.pallas_call(
         kernel,
         name="paged_decode_attn",
         grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hkv, g, hd), lambda s: (s, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM(ring, k_cache.dtype),
-            pltpu.VMEM(ring, v_cache.dtype),
-            pltpu.SemaphoreType.DMA((depth,)),
-            pltpu.SemaphoreType.DMA((depth,)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
-    return out.reshape(S, Hq, hd)
+    out = out.reshape(S, Hq, hd)
+    return (out, *pools) if writes else out
 
 
 def paged_decode_attention_ref(

@@ -164,8 +164,9 @@ def paged_decode_trunk(
     The shard_map body is the per-device slice of the trunk: Megatron
     column/row-parallel matmuls over the local head/ffn shard, the paged
     attention (Pallas kernel or the gather ref) over the local kv-head
-    shard of the pool, the KV scatter through the (replicated, data==1)
-    block tables into the local shard, and the two per-layer reductions
+    shard of the pool, the KV write through the (replicated, data==1)
+    block tables into the local shard (by the kernel itself on an unscaled
+    pool, else the policy's scatter), and the two per-layer reductions
     via :func:`make_reduce` — decomposed when ``mode="overlap"``."""
     from localai_tpu.engine import kvcache as kvc
     from localai_tpu.parallel import sharding as shd
@@ -196,19 +197,14 @@ def paged_decode_trunk(
         if use_pallas:
             from localai_tpu import ops
 
-            kernel = partial(
-                ops.paged_decode_attention,
-                sliding_window=cfg.sliding_window,
-                interpret=interpret,
-            )
-
-            def attn(q, keys, values, _mask):  # q [S,1,Hq_loc,hd]
-                # keys/values: kvc.LayerViews of the local pool shard
-                args = (q[:, 0], keys.cache, values.cache, keys.layer,
-                        tables, positions)
-                if quantized:  # f32 scale stacks — fused dequant
-                    args += (keys.scale, values.scale)
-                return kernel(*args)[:, None]
+            # over kvc.LayerViews of the local pool shard: each chip's
+            # kernel writes the step's rows of its own heads (unscaled
+            # pools; a scaled one's policy has scattered them)
+            attn = kvc.kernel_attend(
+                partial(ops.paged_decode_attention,
+                        sliding_window=cfg.sliding_window,
+                        interpret=interpret),
+                tables, positions)
 
         hidden, new_stack = mdl.forward(
             cfg, trunk, tokens[:, None], positions[:, None],

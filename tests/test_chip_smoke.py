@@ -46,6 +46,10 @@ def test_kernel_phase_steps_on_cpu(smoke, capfd):
     # every entry point the selectors can answer "pallas" for
     for want in ("paged_decode bfloat16", "paged_decode int8",
                  "paged_decode int4",
+                 # PR 38: the kernel as the step's writer, against the scatter
+                 "paged_decode bfloat16 bt=64 hd=16 writes: output",
+                 "paged_decode bfloat16 bt=64 hd=16 writes: pool",
+                 "paged_decode looped q_per_kv=1 layer 5 of 6 writes: pool",
                  "paged_decode looped q_per_kv=1 layer 0 of 6",
                  "paged_decode looped q_per_kv=1 layer 5 of 6",
                  "decode bfloat16", "decode int8",
@@ -54,6 +58,20 @@ def test_kernel_phase_steps_on_cpu(smoke, capfd):
         assert any(n.startswith(want) for n in names), (want, names)
     assert all(c["ok"] for c in report["cases"])
     assert report["interpret"] is True
+
+
+def test_mesh_kernel_phase_steps_on_cpu(smoke):
+    """``--chips 4``'s extra child, over two virtual CPU devices: the kernel
+    under ``shard_map`` writes each shard's own heads; the pool it hands
+    back is the scatter's to the bit."""
+    report = chip_smoke.mesh_kernel_phase(
+        smoke, chips=2, context=256, slots=4, heads=TINY_HEADS,
+        interpret=True,
+        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=2"})
+    assert [c["case"].split(" writes: ")[1] for c in report["cases"]] == [
+        "output", "pool against the scatter's"]
+    assert all(c["ok"] for c in report["cases"])
+    assert report["cases"][1]["max_abs_err"] == 0.0
 
 
 def test_server_phase_steps_on_cpu(smoke, capsys):

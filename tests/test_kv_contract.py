@@ -89,12 +89,33 @@ def _dequant_layer(new, kv_dtype):
 
 
 def _assert_views(new, keys, values):
-    """``raw=True``: the kernel gets the new stack itself and the layer."""
+    """``raw=True``: the kernel gets the stack itself and the layer (the
+    new stack, or the one it is to write: ``LayerView.new``)."""
     scales = new[2:] if len(new) == 4 else (None, None)
     for view, cache, scale in zip((keys, values), new[:2], scales):
         assert isinstance(view, kvc.LayerView)
-        assert view.cache is cache and view.scale is scale
-        assert int(view.layer) == LAYER
+        assert view.scale is scale and int(view.layer) == LAYER
+        assert view.cache is cache or view.new is not None
+
+
+def _written_by_the_kernel(stack, new, keys, values, k_rows, v_rows):
+    """``raw=True`` over an unscaled pool (PR 38): the policy hands the
+    stack back untouched, the views carry the rows as the pool stores them,
+    and the paged kernel writes them (here at a block narrower than a tile:
+    the whole block goes back). Returns the stack the attend hands back."""
+    from functools import partial
+
+    assert all(a is b for a, b in zip(new, stack))
+    for view, rows in zip((keys, values), (k_rows, v_rows)):
+        np.testing.assert_array_equal(
+            np.asarray(view.new.astype(jnp.float32)), _stored("bfloat16", rows)[0])
+    attend = kvc.kernel_attend(
+        partial(ops.paged_decode_attention, interpret=True),
+        jnp.asarray(TABLES), jnp.asarray(POSITIONS))
+    q = jnp.zeros((S, 1, 2 * H, HD), DT)
+    _, written = attend(q, keys, values, None)
+    # what the next layer's policy is handed: views of the written stack
+    return written
 
 
 def _rows(rng, *lead):
@@ -109,8 +130,11 @@ def _rows(rng, *lead):
 TABLES = np.array([[1, 4, 7], [0, 0, 0], [9, 2, 5]], np.int32)
 
 
+POSITIONS = np.array([13, 5, 23], np.int32)     # a decode step's frontiers
+
+
 def _paged_decode(rng, raw):
-    positions = np.array([13, 5, 23], np.int32)
+    positions = POSITIONS
     k_new, v_new = _rows(rng, S, 1)
     write = kvc.paged_decode_write(jnp.asarray(TABLES), jnp.asarray(positions),
                                    raw=raw)
@@ -167,6 +191,13 @@ def test_paged_policy_writes_only_its_rows(policy, kv_dtype):
     write, k_new, v_new, targets, k_rows, v_rows, tables = PAGED[policy](rng)
     assert len(set(targets)) == len(targets)     # no two rows collide
     new, keys, values = write(stack, jnp.int32(LAYER), k_new, v_new)
+    if policy == "decode_raw" and len(stack) == 2:
+        new = _written_by_the_kernel(stack, new, keys, values, k_rows, v_rows)
+        # the released slot's row goes nowhere: the trash block is as it was
+        targets, k_rows, v_rows = (
+            [x for x, (blk, _) in zip(xs, targets) if blk != 0]
+            for xs in (targets, k_rows, v_rows))
+        k_rows, v_rows = jnp.stack(k_rows), jnp.stack(v_rows)
     _assert_stack(new, _expected(stack, kv_dtype, targets, k_rows, v_rows))
     if policy == "decode_raw":
         _assert_views(new, keys, values)
@@ -329,3 +360,90 @@ def test_contiguous_kernel_reads_the_layer_it_is_given(kv_dtype, layer):
         positions, *(s[layer] for s in scales))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# PR 38: only the Pallas kernel over an unscaled pool writes for its policy
+
+
+def _decode_write_before_the_kernel_wrote(tables, positions, raw=False):
+    """``engine.kvcache.paged_decode_write`` as it stood before PR 38: it
+    scatters the step's rows whatever the pool and whoever attends."""
+
+    def write(kv_stack, layer, k_new, v_new):
+        bt = kv_stack[0].shape[3]
+        s = jnp.arange(tables.shape[0])
+        blk = tables[s, positions // bt]
+        new = kvc._write_rows(kv_stack, layer, blk, positions % bt,
+                              k_new[:, 0], v_new[:, 0])
+        if raw:
+            return (new, *kvc._views(new, layer))
+        return (new, *kvc._gather_context(new, layer, tables, k_new))
+
+    return write
+
+
+def _lowered(r):
+    """The runner's paged programs, lowered: one decode step, two in one
+    dispatch, a prefill chunk, a speculative verify window."""
+    import jax
+
+    base = (r.params, r.kv, r.state)
+    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
+             r.block_tables[0], jnp.int32(0),
+             jnp.zeros(r.cfg.vocab_size, jnp.int32))
+    return {
+        "decode": jax.jit(r._decode_paged_fn).lower(
+            *base, r.block_tables).as_text(),
+        "decode_n2": jax.jit(
+            r._decode_paged_n_fn, static_argnames=("n",)).lower(
+                *base, r.block_tables, n=2).as_text(),
+        "prefill": jax.jit(
+            r._prefill_paged_fn, static_argnames=("bucket", "sample")).lower(
+                *base, *chunk, bucket=32, sample=True).as_text(),
+        "verify": jax.jit(r._verify_paged_fn).lower(
+            *base, r.block_tables,
+            jnp.zeros((r.num_slots, 4), jnp.int32)).as_text(),
+    }
+
+
+@pytest.mark.parametrize("kv_dtype, attn_impl, kernel_writes", [
+    ("bfloat16", "pallas_interpret", True),
+    ("float32", "pallas_interpret", True),
+    ("int8", "pallas_interpret", False),
+    ("int4", "pallas_interpret", False),
+    ("bfloat16", "xla", False),
+    ("int8", "xla", False)])
+def test_only_the_kernel_over_an_unscaled_pool_writes(monkeypatch, kv_dtype,
+                                                      attn_impl,
+                                                      kernel_writes):
+    """The switch is the stack's arity and ``raw``: with the policy of
+    before PR 38 in its place (copied above), every program of a scaled
+    pool or of the XLA attend lowers to the SAME text, to the letter, and
+    so do every pool's prefill chunk and verify window; only the decode
+    programs of the Pallas kernel over an unscaled pool differ: K's scatter
+    and V's are gone from them (tests/test_tpu_compile.py reads the
+    compiled cells' programs for the aliased call)."""
+    from localai_tpu.engine.runner import ModelRunner
+    from localai_tpu.models.registry import resolve_model
+
+    model = resolve_model("debug:tiny", dtype="bfloat16")
+
+    def runner():
+        return ModelRunner(model.cfg, model.params, num_slots=4, max_ctx=128,
+                           paged=True, kv_block_tokens=16, kv_dtype=kv_dtype,
+                           prefill_buckets=[32], attn_impl=attn_impl)
+
+    now = _lowered(runner())
+    monkeypatch.setattr(kvc, "paged_decode_write",
+                        _decode_write_before_the_kernel_wrote)
+    before = _lowered(runner())
+    assert now["prefill"] == before["prefill"]
+    assert now["verify"] == before["verify"]
+    for program in ("decode", "decode_n2"):
+        if not kernel_writes:
+            assert now[program] == before[program]
+            continue
+        # K's scatter and V's are gone (the sampler keeps one of its own)
+        op = '"stablehlo.scatter"('
+        assert now[program].count(op) == before[program].count(op) - 2

@@ -340,7 +340,10 @@ def _forward_before_passes(cfg, params, tokens, positions, kv_write, kv_stack,
 
         def attend(q, k_new, v_new):
             new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
-            return attn(q, keys, values, mask), new_kv
+            out = attn(q, keys, values, mask)
+            if isinstance(out, tuple):      # PR 38: the kernel wrote the rows
+                out, new_kv = out
+            return out, new_kv
 
         return _layer_before_passes(cfg, x, lp, cos, sin, attend), None
 

@@ -146,3 +146,93 @@ def test_overlap_multi_slot_and_release(monkeypatch):
         return out
 
     assert run("auto") == run("0")
+
+
+# ---------------------------------------------------------------------------
+# PR 38: over an unscaled pool the paged kernel writes the step's rows
+
+
+def test_kernel_under_shard_map_writes_its_own_heads():
+    """``ops.paged_decode_attention`` with the step's rows, wrapped as the
+    meshed runner wraps it (heads of q, pool and rows on 'model'): each
+    shard's kernel lays the rows of ITS heads into its shard of the pool.
+    The pool that comes back equals the scatter's bit for bit outside the
+    trash block, the output the kernel's over the scattered pool."""
+    from functools import partial
+
+    from localai_tpu import ops
+    from localai_tpu.engine import kvcache as kvc
+
+    mesh = _tp_mesh(2)
+    rng = np.random.default_rng(11)
+    S, Hq, Hkv, hd, bt, MB, L, layer = 3, 8, 4, 128, 16, 3, 2, 1
+    N = S * MB + 1
+    k, v = (jnp.asarray(rng.normal(size=(L, N, Hkv, bt, hd)), jnp.bfloat16)
+            for _ in range(2))
+    tables = jnp.asarray([[1, 4, 7], [0, 0, 0], [9, 2, 5]], jnp.int32)
+    positions = jnp.asarray([15, 0, 40], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(S, Hq, hd)), jnp.bfloat16)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(S, Hkv, hd)), jnp.bfloat16)
+                    for _ in range(2))
+    rows, pool = P(None, "model", None), P(None, None, "model", None, None)
+    kernel = partial(ops.paged_decode_attention, interpret=True)
+    meshed = jax.jit(shard_map(
+        kernel, mesh=mesh,
+        in_specs=(rows, pool, pool, P(), P(), P(), None, None, rows, rows),
+        out_specs=(rows, pool, pool), check_vma=False))
+    out, k_out, v_out = meshed(q, k, v, jnp.int32(layer), tables, positions,
+                               None, None, k_new, v_new)
+    blk = tables[jnp.arange(S), positions // bt]
+    want_k, want_v = kvc._write_rows((k, v), jnp.int32(layer), blk,
+                                     positions % bt, k_new, v_new)
+    for got, want, was in ((k_out, want_k, k), (v_out, want_v, v)):
+        got, want, was = (np.asarray(a, np.float32) for a in (got, want, was))
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        np.testing.assert_array_equal(got[:, 0], was[:, 0])
+    parent = kernel(q, want_k, want_v, jnp.int32(layer), tables, positions)
+    live = np.asarray([True, False, True])
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[live],
+                                  np.asarray(parent, np.float32)[live])
+
+
+@pytest.mark.parametrize("mode, kv_dtype", [
+    ("0", "float32"), ("auto", "float32"), ("0", "int8")])
+def test_meshed_runner_kernel_writes_what_the_scatter_wrote(monkeypatch,
+                                                            mode, kv_dtype):
+    """The meshed paged runner with the Pallas kernel (under GSPMD inside
+    the runner's ``shard_map`` around the kernel, under ``auto`` inside the
+    manual-TP trunk) against the same runner on the XLA attend, whose
+    policy scatters: the same greedy tokens, and the same pool outside the
+    trash block (another attend's float order moves later layers' rows in
+    their last bits). A scaled pool keeps the scatter under the kernel too
+    (its scale stacks ride where the rows would): the same tokens."""
+    monkeypatch.setenv("LOCALAI_MESH_OVERLAP", mode)
+    model = resolve_model("debug:tiny", dtype="float32")
+    mesh = _tp_mesh(2)
+    params = shd.shard_params(model.params, model.cfg, mesh)
+
+    def run(attn_impl):
+        r = ModelRunner(model.cfg, params, num_slots=4, max_ctx=128,
+                        prefill_buckets=[64], kv_dtype=kv_dtype,
+                        paged=True, kv_block_tokens=16, mesh=mesh,
+                        attn_impl=attn_impl)
+        assert bool(r.overlap_mode) == (mode == "auto")
+        assert r.paged_kv_write_impl == (
+            "kernel" if (attn_impl, kv_dtype) == (
+                "pallas_interpret", "float32") else "scatter")
+        s0, s1 = r.acquire_slot(), r.acquire_slot()
+        toks = [r.admit(s0, list(range(1, 30)), temperature=0.0),
+                r.admit(s1, list(range(5, 40)), temperature=0.0)]
+        toks.extend(np.asarray(r.step_n(4)).ravel().tolist())
+        toks.extend(np.asarray(r.step()).ravel().tolist())
+        return toks, np.asarray(r.kv.k), np.asarray(r.kv.v)
+
+    scatter, kernel = run("xla"), run("pallas_interpret")
+    assert kernel[0] == scatter[0]
+    if kv_dtype == "int8":
+        return
+    for got, want in zip(kernel[1:], scatter[1:]):
+        # the decode rows are there (values of ~1e-2 at this model)
+        assert (np.abs(want[:, 1:]).sum(axis=(0, 2, 4)) > 0).sum() >= 39 + 10
+        np.testing.assert_allclose(got[:, 1:], want[:, 1:],
+                                   rtol=1e-4, atol=1e-7)

@@ -276,6 +276,118 @@ def test_paged_kernel_matches_reference(hkv, g, bt, kv_dtype, windowed,
                                rtol=tol, atol=tol)
 
 
+def _writer_cases():
+    """(pool dtype, kv heads, group, block tokens, windowed, cache layers,
+    layer): both unscaled dtypes (their tiles hold 16 and 8 rows), g = 4
+    and g = 1, a local head count of one chip of four, a block of one tile,
+    a sliding window, and the first and last cache layers of a looped
+    stack's passes (pass * 48 + layer of 192)."""
+    cases = [("bfloat16", 8, 4, 64, False, 2, 1),
+             ("bfloat16", 2, 4, 64, True, 2, 0),
+             ("bfloat16", 4, 1, 32, False, 2, 1),
+             ("bfloat16", 2, 1, 16, True, 3, 2),
+             ("float32", 8, 4, 64, True, 2, 1),
+             ("float32", 2, 1, 32, False, 2, 0),
+             ("float32", 1, 4, 8, False, 2, 1)]
+    cases += [("bfloat16", 2, 1, 32, False, 192, layer)
+              for layer in (0, 47, 48, 191)]
+    return cases
+
+
+@pytest.mark.parametrize("kv_dtype,hkv,g,bt,windowed,layers,layer",
+                         _writer_cases() + [("int8", 2, 4, 32, False, 2, 1)])
+def test_paged_kernel_writes_the_row_it_reads(kv_dtype, hkv, g, bt, windowed,
+                                              layers, layer):
+    """PR 38: over an unscaled pool the decode policy (``raw=True``) hands
+    the stack back untouched and the kernel writes the step's rows. After a
+    step through that path the pool equals the pool after ``_write_rows``
+    BIT FOR BIT outside block 0, in every layer (what is copied back beside
+    the one row is what those rows held), block 0 is as it was (a slot on
+    the trash block writes nothing), and the output is the kernel's over
+    the scattered pool to the bit, and the reference's. Frontiers at rows
+    0, 15, 16 and bt - 1 of a block, on both sides of a step's last row, on
+    the table's last row, a released slot between live ones. A scaled pool
+    keeps the scatter: the policy writes, the attend returns no stack."""
+    from functools import partial
+
+    from localai_tpu.engine import kvcache as kvc
+    from localai_tpu.models.quant import quantize_lastdim
+    from localai_tpu.ops.attention import paged_decode_tiling
+
+    hd = 128
+    rng = np.random.default_rng(hkv * 1000 + g * 100 + bt + layer)
+    pool_dt = jnp.dtype("int8" if kv_dtype == "int8" else kv_dtype)
+    P, _, _ = paged_decode_tiling(hkv, bt, hd, pool_dt.itemsize, 1 << 20)
+    MB = 2 * P + 1
+    rows = sorted({0, 15 % bt, 16 % bt, bt - 1})
+    edges = [*rows, None, *(bt + r for r in rows), P * bt - 1, P * bt,
+             None, MB * bt - 1, (MB * bt) // 2 + 7]
+    if layers > 8:                      # the looped stack: a few slots do
+        edges = [15 % bt, None, bt + 16 % bt, MB * bt - 1]
+    positions = np.asarray([p or 0 for p in edges], np.int32)
+    live = np.asarray([p is not None for p in edges])
+    need = [0 if p is None else p // bt + 1 for p in edges]
+    N = sum(need) + 1
+    free = list(rng.permutation(np.arange(1, N)))
+    tables = np.zeros((len(edges), MB), np.int32)   # trash-padded
+    for s, n in enumerate(need):
+        tables[s, :n] = [free.pop() for _ in range(n)]
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+    window = bt + 5 if windowed else None
+    S = len(edges)
+
+    q = jnp.asarray(rng.normal(size=(S, hkv * g, hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(S, 1, hkv, hd)),
+                                jnp.float32) for _ in range(2))
+    full = [jnp.asarray(rng.normal(size=(layers, N, hkv, bt, hd)),
+                        jnp.float32) for _ in range(2)]
+    if kv_dtype == "int8":
+        (k, ks), (v, vs) = (quantize_lastdim(a) for a in full)
+        stack = (k, v, ks, vs)
+    else:
+        stack = tuple(a.astype(pool_dt) for a in full)
+    at = jnp.int32(layer)
+    blk = tables[jnp.arange(S), positions // bt]
+    want = kvc._write_rows(stack, at, blk, positions % bt,
+                           k_new[:, 0], v_new[:, 0])
+
+    kernel = partial(ops.paged_decode_attention, sliding_window=window,
+                     interpret=True)
+    write = kvc.paged_decode_write(tables, positions, raw=True)
+    new, keys, values = write(stack, at, k_new, v_new)
+    got = kvc.kernel_attend(kernel, tables, positions)(
+        q[:, None], keys, values, None)
+    parent = kernel(q, want[0], want[1], at, tables, positions, *want[2:])
+    if kv_dtype == "int8":
+        # the scatter path, as it was: the policy wrote, the views name the
+        # new stack and carry no rows, the attend hands back no stack
+        assert keys.new is None and values.new is None
+        for a, b in zip(new, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert keys.cache is new[0] and keys.scale is new[2]
+        np.testing.assert_array_equal(np.asarray(got[:, 0]),
+                                      np.asarray(parent))
+        return
+    assert all(a is b for a, b in zip(new, stack))      # untouched
+    assert keys.cache is stack[0] and values.cache is stack[1]
+    assert keys.new.dtype == values.new.dtype == pool_dt
+    out, pools = got
+    assert len(pools) == 2
+    for was, now, scattered in zip(stack, pools, want):
+        was, now, scattered = (np.asarray(a, np.float32)
+                               for a in (was, now, scattered))
+        np.testing.assert_array_equal(now[:, 1:], scattered[:, 1:])
+        np.testing.assert_array_equal(now[:, 0], was[:, 0])
+    np.testing.assert_array_equal(np.asarray(out[live, 0]),
+                                  np.asarray(parent[live]))
+    ref = ops.paged_decode_attention_ref(
+        q, want[0][layer], want[1][layer], tables, positions,
+        sliding_window=window)
+    tol = 2e-5 if kv_dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(out[live, 0]),
+                               np.asarray(ref[live]), rtol=tol, atol=tol)
+
+
 def test_paged_runner_matches_contiguous_greedy():
     """End-to-end engine parity: same weights, two prompts of different
     lengths sharing the paged pool — greedy decode must match the
@@ -496,6 +608,38 @@ def test_paged_metrics_export_block_gauges(tiny):
                     in text), name
     finally:
         s.shutdown()
+
+
+@pytest.mark.parametrize("kv_dtype, attn_impl, writer", [
+    ("float32", "pallas_interpret", "kernel"),
+    ("int8", "pallas_interpret", "scatter"),
+    ("float32", "xla", "scatter")])
+def test_metrics_say_who_writes_the_decode_rows(tiny, kv_dtype, attn_impl,
+                                                writer):
+    """``localai_paged_kv_write_impl{impl=kernel|scatter}``, one-hot beside
+    ``localai_paged_kernel_impl``: the kernel writes an unscaled pool it
+    attends over; a scaled pool and the XLA attend keep the scatter."""
+    from localai_tpu.obs import metrics as obs_metrics
+
+    runner = ModelRunner(tiny.cfg, tiny.params, num_slots=2, max_ctx=96,
+                         prefill_buckets=[16, 32], kv_dtype=kv_dtype,
+                         paged=True, kv_block_tokens=16, prefill_chunk=16,
+                         attn_impl=attn_impl)
+    assert runner.paged_kv_write_impl == writer
+    s = Scheduler(runner, ByteTokenizer())
+    try:
+        s.generate(GenRequest(prompt=list(b"who writes"), max_new_tokens=3,
+                              temperature=0.0), timeout=60)
+        m = s.metrics()
+    finally:
+        s.shutdown()
+    assert m["paged_kv_write_impl"] == writer
+    reg = obs_metrics.Registry()
+    obs_metrics.update_engine_gauges("tiny", m, registry=reg)
+    text = reg.render()
+    for label in ("kernel", "scatter"):
+        assert (f'localai_paged_kv_write_impl{{impl="{label}",model="tiny"}} '
+                f'{1.0 if label == writer else 0.0}') in text, text
 
 
 def test_disk_prefix_export_transfers_across_layouts(tiny):

@@ -443,10 +443,10 @@ def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
     c = compile_cell_program(r, a, program)
     assert_in_place(program, c, pool)
     # the decode programs' one Pallas call is the paged kernel (the roofline
-    # reader sums every ``tpu_custom_call`` of theirs); chunked prefill
-    # attends through XLA and holds none
-    assert c.as_text().count('custom_call_target="tpu_custom_call"') == (
-        1 if program.startswith("decode") else 0)
+    # reader sums every ``tpu_custom_call`` of theirs), which writes the
+    # step's rows too; chunked prefill attends through XLA, holds none, and
+    # writes through the policy's scatter as before
+    assert_who_writes(program, c.as_text(), pool)
 
 
 def assert_in_place(program, c, pool):
@@ -478,6 +478,39 @@ def assert_in_place(program, c, pool):
     assert not moved, f"{program} moves the pool or a layer of it: {moved}"
 
 
+def assert_who_writes(program, text, pool):
+    """PR 38. A DECODE program holds no scatter into the pool: its one
+    Pallas call takes the step's K and V rows as its last operands and
+    hands both pools (``pool``: one device's K or V stack) back aliased to
+    the operands they came in as, the attention output staying its FIRST
+    result (the benchmark's readers name an operation by the first shape of
+    its result; a call named by the pool's would be read as a move of it).
+    A PREFILL program holds no Pallas call and its policy's scatters, fused
+    and aliased onto the pool (``assert_in_place`` lets nothing else
+    produce a pool-shaped result in either, so no copy stands before or
+    behind the aliased call)."""
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    if not program.startswith("decode"):
+        assert not calls
+        assert 'kv_pool.write/scatter"' in text
+        return
+    assert len(calls) == 1 and "paged_decode_attn" in calls[0]
+    assert "kv_pool.write/scatter" not in text, (
+        f"{program} still scatters the step's rows")
+    shape = "bf16[" + ",".join(map(str, pool)) + "]"
+    rows = f"bf16[{{}},{pool[2]},{pool[4]}]"
+    results = calls[0].split(" custom-call(")[0]
+    operands = calls[0].split("operand_layout_constraints={")[1].split("}, ")
+    assert "output_to_operand_aliasing={{1}: (4, {}), {2}: (5, {})}" in calls[0]
+    assert results.count(shape) == 2 and not results.split("= (")[1].startswith(
+        shape), results
+    assert operands[4].startswith(shape) and operands[5].startswith(shape)
+    slots = operands[1].split("[")[1].split("]")[0]
+    assert operands[6].startswith(rows.format(slots)), operands[6]
+    assert operands[7].startswith(rows.format(slots)), operands[7]
+
+
 @pytest.mark.parametrize("cell", [OURO], indirect=True)
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk_128",
                                      "prefill_chunk_512_sample"])
@@ -505,8 +538,7 @@ def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
     c = compile_cell_program(r, a, program)
     assert_in_place(program, c, pool)
     text = c.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == (
-        1 if program == "decode" else 0)
+    assert_who_writes(program, text, pool)
     m = c.memory_analysis()
     # no weight stack staged: the smallest stacked leaf is [48, 2048, 2048]
     assert m.temp_size_in_bytes < cfg.num_layers * cfg.hidden_size ** 2, (
@@ -561,14 +593,15 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
     text = c.as_text()
     collectives = [
         ln.strip()[:160] for ln in text.splitlines()
-        if "kv_pool." in ln and re.search(
+        if ("kv_pool." in ln or "attn.paged_decode" in ln) and re.search(
             r" (all-gather|all-reduce|all-to-all|collective-permute"
             r"|reduce-scatter)[\w\-]*\(", ln)]
     assert not collectives, f"{program}: the pool's write talks: {collectives}"
     # one Pallas call in a decode program, the paged kernel inside its
-    # shard_map; none in a chunk
-    assert text.count('custom_call_target="tpu_custom_call"') == (
-        1 if program.startswith("decode") else 0)
+    # shard_map, handed the step's rows of the chip's own two heads (no
+    # gather of rows in front of it: the shapes are the shard's); none in a
+    # chunk, which scatters
+    assert_who_writes(program, text, shard)
 
 
 # ---------------------------------------------------------------------------
