@@ -32,8 +32,9 @@ device.
 ``GET /debug/anatomy`` — the dispatch-anatomy breakdown (obs.anatomy):
 per-model windowed gap/sched/launch/sync phase percentiles and totals
 from the flight ring's phase columns, the derived
-``host_overhead_fraction`` / ``device_bubble_fraction``, per-phase wall
-shares (stacked-bar ready), and the unattributed remainder.
+``host_overhead_fraction``, per-phase wall shares (stacked-bar ready), and
+the unattributed remainder. How idle the DEVICE was is measured, not
+estimated: ``POST /backend/trace``.
 ``?window=S`` sets the window (default 60 s; ``window=0`` reads the
 whole ring). The "where did the dispatch time go" view — host-side
 reads only, zero device syncs.

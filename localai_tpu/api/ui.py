@@ -687,7 +687,8 @@ async def slo_page(request: web.Request) -> web.Response:
   <h2>Dispatch anatomy</h2>
   <div class="dim" style="margin-bottom:6px">
     windowed wall-time shares per model: gap / sched / launch / sync /
-    unattributed (obs.anatomy — bubble is an estimator)</div>
+    unattributed (obs.anatomy; the device's idle time is in a
+    POST /backend/trace capture, not here)</div>
   <div id="anatomy" class="dim">loading…</div>
 </div>"""
     script = """
@@ -771,8 +772,7 @@ async function refresh() {
       row.style.margin = '6px 0';
       const label = document.createElement('div');
       label.textContent = name + ' — host overhead ' +
-        fmt(m.host_overhead_fraction, 3) + ' · bubble ' +
-        fmt(m.device_bubble_fraction, 3) + ' · ' + m.samples +
+        fmt(m.host_overhead_fraction, 3) + ' · ' + m.samples +
         ' dispatches / ' + fmt(m.dispatch_ms_total, 0) + ' ms';
       row.appendChild(label);
       const bar = document.createElement('div');

@@ -2050,6 +2050,11 @@ class PagedAdmission:
         # dispatch-anatomy scratch: the last launch_chunk()'s enqueue span
         # (obs.anatomy)
         self.last_launch_ms = 0.0
+        # what the last launch_chunk() held, under the flight ring's names
+        # (obs.flight WORK_COLUMNS): real tokens, the rows the program
+        # computes, the cached tokens in front of the chunk, the positions
+        # its attend spans
+        self.last_chunk: dict[str, int] = {}
 
     @property
     def chunks_remaining(self) -> int:
@@ -2075,6 +2080,7 @@ class PagedAdmission:
         t0 = time.perf_counter()
         table_row = np.asarray(r.allocator.table_row(self.slot), np.int32)
         rem = n - self.pos
+        offset = self.pos
         last = self.sp or self.mm or rem <= r.prefill_chunk
         if last:
             # before the chunk that samples with them; not earlier, for a
@@ -2091,6 +2097,7 @@ class PagedAdmission:
                 r.params, r.kv, r.state, padded, np.int32(n),
                 table_row, slot, self._counts_row(), bucket=bucket,
             )
+            take, ctx = n, bucket       # the ring attends the prompt alone
             self.pos = n
         elif self.mm:
             bucket = r.bucket_for(n)
@@ -2103,6 +2110,7 @@ class PagedAdmission:
                 jnp.asarray(self.mm_positions, jnp.int32),
                 self._counts_row(), bucket=bucket,
             )
+            take, ctx = n, r.ctx_pad
             self.pos = n
         else:
             take = min(rem, r.prefill_chunk)
@@ -2117,7 +2125,10 @@ class PagedAdmission:
                 slot, crow, bucket=bucket,
                 sample=last,
             )
+            ctx = r.ctx_pad             # resume_mask spans the padded context
             self.pos += take
+        self.last_chunk = {"chunk_tokens": take, "chunk_bucket": bucket,
+                           "chunk_offset": offset, "chunk_ctx": ctx}
         r.admit_programs += 1
         if last:
             self.done = True

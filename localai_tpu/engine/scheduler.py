@@ -244,6 +244,9 @@ class _Dispatch:
     # a final prefill chunk (``toks`` its one sampled token, k = 0): the
     # admission whose first token this is
     first: Optional[_PendingPrefill] = None
+    # what the launch held, counted when it was enqueued and written with
+    # its row at the drain (``Scheduler._launch``)
+    held: Optional[dict] = None
 
 
 @dataclasses.dataclass
@@ -408,6 +411,12 @@ class Scheduler:
         self._stopping = False
         self._lock = threading.Lock()
         self._dispatch_seq = 0
+        # serving programs launched, one count a program (decode, a prefill
+        # chunk, a speculative window): the flight row's ``launch`` and, for
+        # a decode or prefill program, the host trace's ``sched.launch/<n>``.
+        # Not ``_dispatch_seq``, which chunks do not move (``admit_seq``,
+        # the quarantine release)
+        self._launch_seq = 0
         # self-healing (faults.supervisor): rebuild() bumps _epoch so a
         # wedged engine thread — parked inside a device round-trip that
         # may never return — is fenced off and exits harmlessly when (if)
@@ -654,7 +663,6 @@ class Scheduler:
             # dispatch anatomy (obs.anatomy): windowed host/device
             # attribution over the same ring the step percentiles read
             "host_overhead_fraction": anat["host_overhead_fraction"],
-            "device_bubble_fraction": anat["device_bubble_fraction"],
             "dispatch_phase_ms": obs_anatomy.phase_quantiles(anat),
             **(
                 {"prompt_cache": self.prompt_cache.stats()}
@@ -733,10 +741,41 @@ class Scheduler:
         return {"gap_ms": gap * 1e3, "sched_ms": sched * 1e3,
                 "launch_ms": launch * 1e3, "sync_ms": sync * 1e3}
 
+    def _launch(self, k: int = 0, inflight: Sequence[_Dispatch] = (),
+                ) -> dict:  # jaxlint: disable=lock-guarded-attr
+        """Number the serving program about to be enqueued, and count what
+        a decode launch of ``k`` steps holds, NOW: the slots that hold a
+        stream, and the cached tokens its steps attend (step j of a stream
+        attends its prompt, what it has generated and j more). A stream
+        that ends inside the dispatch stays counted: the device attended
+        for it. Host mirrors of the engine thread, as ``_flight_record``'s,
+        brought up to the device by what ``inflight`` (launched, not yet
+        read) will add to them: a decode dispatch its k tokens for every
+        stream armed before it, a final chunk its stream's first token (a
+        speculative window's yield is not known before it is read: not
+        added). The counts ride to the row under obs.flight's
+        WORK_COLUMNS."""
+        self._launch_seq += 1
+        held = {"launch": self._launch_seq}
+        if k:
+            live = len(self._slots)
+            cached = 0
+            for c in self._slots.values():
+                cached += c.handle.prompt_tokens + c.generated
+                for d in inflight:
+                    if d.first is not None:
+                        cached += d.first.handle is c.handle
+                    elif d.seq > c.admit_seq:
+                        cached += d.k
+            held["live_slots"] = live
+            held["attended_tokens"] = k * cached + live * (k * (k - 1) // 2)
+        return held
+
     def _flight_record(self, program: str, steps: int, dt: float,
                        fresh: bool, spec_proposed: int = 0,
                        spec_accepted: int = 0, sync_s: float = 0.0,
                        phases: Optional[dict] = None,
+                       held: Optional[dict] = None,
                        ) -> None:  # jaxlint: disable=lock-guarded-attr
         """One flight-ring record at a drain point. Everything here is a
         host mirror this (engine) thread already owns — ``_slots`` is only
@@ -748,7 +787,9 @@ class Scheduler:
         ``sync_s`` is the measured result-fetch block for this drain;
         phase attribution comes from _take_anat unless the caller passes
         a pre-built ``phases`` dict (prefill chunks, whose span must not
-        consume the accumulators owed to the next decode record)."""
+        consume the accumulators owed to the next decode record). ``held``
+        is the one part that is NOT end-of-dispatch state: what the launch
+        held when it was enqueued (``_launch``), carried here."""
         emitted = self._tokens_emitted
         num_slots = self.runner.num_slots
         batch_slots = sum(
@@ -782,6 +823,7 @@ class Scheduler:
             launch_ms=phases["launch_ms"],
             sync_ms=phases["sync_ms"],
             compile=fresh,
+            **(held or {}),
         )
         self._flight_mark = emitted
         if spec_proposed > spec_accepted:
@@ -1131,7 +1173,7 @@ class Scheduler:
                 k_eff, dt, fresh,
                 spec_proposed=window["proposed"] if window else 0,
                 spec_accepted=window["accepted"] if window else 0,
-                phases=phases,
+                phases=phases, held=d.held,
             )
 
         while not self._stopping and self._epoch == epoch:
@@ -1219,8 +1261,11 @@ class Scheduler:
                     self._dispatch_seq += 1
                     if len(constrained) == len(self._slots) or steps == 1:
                         fresh = self._fresh_shape(1)
+                        held = self._launch(1)
                         t0 = time.monotonic()
-                        rows = self.runner.step()[None]
+                        with TraceAnnotation(
+                                f"sched.launch/{held['launch']}"):
+                            rows = self.runner.step()[None]
                         dt = time.monotonic() - t0
                         # anatomy: the runner split its own wall into
                         # enqueue vs result-fetch — harvest the scratch
@@ -1233,13 +1278,17 @@ class Scheduler:
                         self._process_rows(rows, self._dispatch_seq)
                         self._flight_record(
                             "decode", 1, dt, fresh,
-                            sync_s=self.runner.last_sync_ms * 1e-3)
+                            sync_s=self.runner.last_sync_ms * 1e-3,
+                            held=held)
                     else:
                         freeze = np.zeros(self.runner.num_slots, bool)
                         freeze[list(constrained)] = True
                         fresh = self._fresh_shape(("frozen", steps))
+                        held = self._launch()   # no decode row: no counts
                         t0 = time.monotonic()
-                        rows = self.runner.step_frozen_n(freeze, steps)
+                        with TraceAnnotation(
+                                f"sched.launch/{held['launch']}"):
+                            rows = self.runner.step_frozen_n(freeze, steps)
                         dt = time.monotonic() - t0
                         self._anat_launch_s += (
                             self.runner.last_launch_ms * 1e-3)
@@ -1253,7 +1302,8 @@ class Scheduler:
                         )
                         self._flight_record(
                             "decode_frozen_n", steps, dt, fresh,
-                            sync_s=self.runner.last_sync_ms * 1e-3)
+                            sync_s=self.runner.last_sync_ms * 1e-3,
+                            held=held)
                     self._last_drain_t = None  # sync path: drain clock stale
                 else:
                     # cheap speculation pre-gate, BEFORE any drain or
@@ -1299,6 +1349,11 @@ class Scheduler:
                                 time.monotonic() - t_issue)
                     if spec_rows is not None:
                         self._dispatch_seq += 1
+                        # a window takes its number once it is enqueued (a
+                        # declined draft leaves none unused); no reader
+                        # joins a window to its execution, so no
+                        # ``sched.launch/<n>`` names it
+                        held = self._launch()
                         fresh = self._fresh_shape("spec")
                         self.last_dispatch_steps = self.spec.gamma + 1
                         try:
@@ -1310,7 +1365,7 @@ class Scheduler:
                         # into the flight ring + step-time EMA
                         inflight.append(_Dispatch(
                             spec_rows, self._dispatch_seq, 0,
-                            bool(inflight), t_issue, fresh))
+                            bool(inflight), t_issue, fresh, held=held))
                         while len(inflight) >= self.pipeline_depth:
                             drain_one()
                         continue
@@ -1319,12 +1374,15 @@ class Scheduler:
                     steps = self._effective_steps()
                     self._dispatch_seq += 1
                     fresh = self._fresh_shape(steps)
+                    held = self._launch(steps, inflight)
                     t_issue = time.monotonic()
                     with TraceAnnotation("sched.decode_launch"):
-                        if steps > 1:
-                            tokens = self.runner.step_n_async(steps)
-                        else:
-                            tokens = self.runner.step_async()
+                        with TraceAnnotation(
+                                f"sched.launch/{held['launch']}"):
+                            if steps > 1:
+                                tokens = self.runner.step_n_async(steps)
+                            else:
+                                tokens = self.runner.step_async()
                         self.last_dispatch_steps = steps
                         try:
                             tokens.copy_to_host_async()
@@ -1334,7 +1392,7 @@ class Scheduler:
                     self._anat_launch_s += time.monotonic() - t_issue
                     inflight.append(_Dispatch(
                         tokens, self._dispatch_seq, steps,
-                        bool(inflight), t_issue, fresh))
+                        bool(inflight), t_issue, fresh, held=held))
                     # with a final chunk's entry in the queue this reads
                     # more than one: the step that was in flight at the
                     # arrival (on time), then the chunk's token as soon as
@@ -1764,8 +1822,11 @@ class Scheduler:
             self.telemetry.finished(pf.handle.trace, pf.handle, "cancelled")
             pf.handle._finish("cancelled")
             return True, None
+        held = self._launch()
         t0 = time.monotonic()
-        last = pf.adm.launch_chunk()
+        with TraceAnnotation(f"sched.launch/{held['launch']}"):
+            last = pf.adm.launch_chunk()
+        held.update(getattr(pf.adm, "last_chunk", {}))
         entry = None
         if last:
             self._prefills.popleft()
@@ -1784,7 +1845,8 @@ class Scheduler:
         self._flight_record(
             "prefill_chunk", 0, dt, False,
             phases={"gap_ms": 0.0, "sched_ms": wall_ms - launch_ms,
-                    "launch_ms": launch_ms, "sync_ms": 0.0})
+                    "launch_ms": launch_ms, "sync_ms": 0.0},
+            held=held)
         self._anat_overlap_s += dt
         return True, entry
 

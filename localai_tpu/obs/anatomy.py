@@ -33,14 +33,10 @@ that no measured phase claims (computed by exclusion). Consequences:
 * ``host_overhead_fraction`` = (gap+sched+launch) / dispatch wall — the
   share of accounted time the host spent NOT blocked on the device. This
   is the number ROADMAP's fused k-step dispatch must drive down.
-* ``device_bubble_fraction`` is an ESTIMATOR, not a measurement: per
-  record ``max(0, (gap+sched+launch) - sync)``. When the host later
-  blocked ``sync`` ms, the device queue was covering at least that much
-  host time (pipelining hid it — no bubble); host time the device never
-  made the host pay for is presumed device idleness. It can under-count
-  bubbles hidden by deep pipelines and over-count when the device
-  finished mid-``sync``; trends and cross-phase comparisons are
-  meaningful, single absolute samples are not.
+* How idle the DEVICE was is not in these columns. The host cannot
+  tell from its own clock whether the device queue covered its time.
+  ``POST /backend/trace`` measures it (the trace holds the device's
+  operations beside the engine thread's ``sched.*`` phases, on one clock).
 
 Caveats worth restating wherever these numbers render: compile-bearing
 rows are excluded (a single trace would drown every phase); ``launch``

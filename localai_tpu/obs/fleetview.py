@@ -74,8 +74,8 @@ def telemetry_payload(scheduler: Any, *, trace_id: str = "",
                         if limit > 0 else []),
             "percentiles": flight.percentiles(),
             # dispatch anatomy (obs.anatomy): windowed phase breakdown +
-            # host/bubble fractions, so the fleet view gets per-replica
-            # bubble columns without a second RPC
+            # the host's share, so the fleet view gets per-replica phase
+            # columns without a second RPC
             "anatomy": flight.phases(
                 window_s=60.0) if hasattr(flight, "phases") else None,
             "dispatches": flight.count,
@@ -345,7 +345,6 @@ def fleet_flight(sm: Any, *, since: float = 0.0,
             "percentiles": flight.get("percentiles"),
             "anatomy": flight.get("anatomy"),
             "host_overhead_fraction": anatomy.get("host_overhead_fraction"),
-            "device_bubble_fraction": anatomy.get("device_bubble_fraction"),
             "dispatches": flight.get("dispatches"),
             "tokens_total": flight.get("tokens_total"),
         }

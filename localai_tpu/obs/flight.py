@@ -43,6 +43,12 @@ import numpy as np
 from localai_tpu.obs.trace import mono_to_wall
 
 
+# what a launch held, as columns of the ring and keys of /debug/flight, in
+# the order of record()'s keywords
+WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "chunk_tokens",
+                "chunk_bucket", "chunk_offset", "chunk_ctx")
+
+
 def _default_capacity() -> int:
     try:
         return max(1, int(os.environ.get("LOCALAI_FLIGHT_CAPACITY", "512")))
@@ -76,6 +82,8 @@ class FlightRecorder:
         self._sync_ms = np.zeros(n)
         self._compile = np.zeros(n, bool)
         self._program: list[str] = [""] * n
+        # what the launch held, taken when it was enqueued (see record())
+        self._work = np.zeros((n, len(WORK_COLUMNS)), np.int64)
         self._n = 0                # records ever written (ring head = n % cap)
         self.total_tokens = 0      # cumulative, survives wraparound
 
@@ -89,7 +97,10 @@ class FlightRecorder:
                compile: bool = False, ts: Optional[float] = None,
                batch_slots: int = 0, gap_ms: float = 0.0,
                sched_ms: float = 0.0, launch_ms: float = 0.0,
-               sync_ms: float = 0.0, passes: int = 0) -> None:
+               sync_ms: float = 0.0, passes: int = 0, launch: int = 0,
+               live_slots: int = 0, attended_tokens: int = 0,
+               chunk_tokens: int = 0, chunk_bucket: int = 0,
+               chunk_offset: int = 0, chunk_ctx: int = 0) -> None:
         """Append one dispatch record (host scalars only).
 
         ``batch_slots`` tags the record with the lane mix: how many of the
@@ -108,7 +119,20 @@ class FlightRecorder:
 
         ``passes`` counts the passes over the layer stack the dispatch ran:
         its forwards times the model's passes a forward (a looped decoder
-        runs its stack several times a token; every other model once)."""
+        runs its stack several times a token; every other model once).
+
+        ``launch`` and the six counts after it say what work the launch
+        held, taken when its program was ENQUEUED and not at the drain
+        (``WORK_COLUMNS``): ``launch`` is the scheduler's launch number,
+        which the host trace carries as ``sched.launch/<n>`` around the
+        same enqueue, so that a row is tied to its device execution
+        without clock arithmetic. A decode row holds ``live_slots`` (slots
+        with a stream at the launch) and ``attended_tokens`` (cached tokens
+        its steps attend, summed over steps and live slots); a prefill row
+        holds ``chunk_tokens`` (real tokens), ``chunk_bucket`` (rows the
+        program computes), ``chunk_offset`` (cached tokens in front of the
+        chunk) and ``chunk_ctx`` (positions its attend spans). 0 wherever
+        a row's kind has no such count."""
         now = time.monotonic() if ts is None else ts
         with self._lock:
             i = self._n % self.capacity
@@ -132,6 +156,9 @@ class FlightRecorder:
             self._sync_ms[i] = sync_ms
             self._compile[i] = compile
             self._program[i] = program
+            self._work[i] = (launch, live_slots, attended_tokens,
+                             chunk_tokens, chunk_bucket, chunk_offset,
+                             chunk_ctx)
             self._n += 1
             self.total_tokens += int(tokens)
 
@@ -192,6 +219,7 @@ class FlightRecorder:
                 "compile": self._compile[order].tolist(),
                 "program": [self._program[i] for i in order],
             }
+            work = self._work[order].tolist()
         out = []
         for j in range(len(cols["ts"])):
             steps = cols["steps"][j]
@@ -221,6 +249,7 @@ class FlightRecorder:
                 "launch_ms": round(cols["launch"][j], 3),
                 "sync_ms": round(cols["sync"][j], 3),
                 "compile": cols["compile"][j],
+                **dict(zip(WORK_COLUMNS, work[j])),
             })
         return out
 
@@ -265,18 +294,11 @@ class FlightRecorder:
         compile's minutes of tracing would drown every phase). For each
         phase in gap/sched/launch/sync: ``{phase}_ms_p50/p90/p99`` and
         ``{phase}_ms_total`` over the window, plus ``dispatch_ms_total``,
-        ``host_ms_total`` (gap+sched+launch) and the two derived gauges:
-
-        * ``host_overhead_fraction`` = host_ms_total / dispatch_ms_total —
-          the share of accounted wall time the host spent NOT blocked on
-          the device.
-        * ``device_bubble_fraction`` — estimator of device idle share:
-          per record ``max(0, (gap+sched+launch) - sync_ms)`` summed over
-          the window, / dispatch_ms_total. A record whose host phases
-          were fully covered by a later sync wait means the device queue
-          hid the host time (no bubble); host time the device did NOT
-          make the host wait for is (estimated) device idleness. An
-          estimator, not a measurement — see :mod:`obs.anatomy`.
+        ``host_ms_total`` (gap+sched+launch) and the derived gauge
+        ``host_overhead_fraction`` = host_ms_total / dispatch_ms_total —
+        the share of accounted wall time the host spent NOT blocked on
+        the device. How idle the DEVICE was is not in these columns: the
+        profiler measures it (``POST /backend/trace``).
         """
         with self._lock:
             order = self._order()
@@ -303,7 +325,6 @@ class FlightRecorder:
             out["dispatch_ms_total"] = 0.0
             out["host_ms_total"] = 0.0
             out["host_overhead_fraction"] = None
-            out["device_bubble_fraction"] = None
             return out
         for ph, arr in ph_cols.items():
             p50, p90, p99 = np.percentile(arr, (50, 90, 99))
@@ -318,15 +339,9 @@ class FlightRecorder:
         out["host_ms_p50"] = round(float(p50), 4)
         out["host_ms_p90"] = round(float(p90), 4)
         out["host_ms_p99"] = round(float(p99), 4)
-        bubble = np.maximum(0.0, host - ph_cols["sync"])
         total = float(dispatch.sum())
         out["dispatch_ms_total"] = round(total, 3)
         out["host_ms_total"] = round(float(host.sum()), 3)
-        if total > 0:
-            out["host_overhead_fraction"] = round(float(host.sum()) / total, 4)
-            out["device_bubble_fraction"] = round(
-                float(bubble.sum()) / total, 4)
-        else:
-            out["host_overhead_fraction"] = None
-            out["device_bubble_fraction"] = None
+        out["host_overhead_fraction"] = (
+            round(float(host.sum()) / total, 4) if total > 0 else None)
         return out

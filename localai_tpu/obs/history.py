@@ -144,8 +144,7 @@ class History:
                   ("kv_utilization", "kv_utilization"),
                   # dispatch anatomy (obs.anatomy): None until the ring's
                   # window holds a non-compile dispatch — skip, don't zero
-                  ("host_overhead_fraction", "host_overhead_fraction"),
-                  ("device_bubble_fraction", "device_bubble_fraction"))
+                  ("host_overhead_fraction", "host_overhead_fraction"))
         for key, series in gauges:
             if m.get(key) is not None:
                 self.record(f"{series}.{model}", m[key])

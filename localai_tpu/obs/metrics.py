@@ -307,12 +307,6 @@ class Registry:
             "blocked on the device (gap+sched+launch over dispatch "
             "wall) — the number fused multi-step dispatch must drive down",
         )
-        self.device_bubble_fraction = Gauge(
-            "localai_device_bubble_fraction",
-            "Estimated share of windowed dispatch wall time the device "
-            "sat idle: host phases not covered by a later result-fetch "
-            "block (estimator — see obs.anatomy caveats)",
-        )
         self.slo_burn_rate = Gauge(
             "localai_slo_burn_rate",
             "Error-budget burn rate per model and window "
@@ -767,7 +761,7 @@ def update_engine_gauges(name: str, m: dict,
         if v is not None:
             reg.step_time_ms.set(v, model=name, quantile=q)
     # dispatch anatomy (obs.anatomy): windowed phase percentiles + the
-    # derived host/bubble fractions; absent keys (old-version payloads,
+    # derived host share; absent keys (old-version payloads,
     # empty windows) simply leave the gauges untouched
     for ph, qs in (m.get("dispatch_phase_ms") or {}).items():
         for q, v in qs.items():
@@ -777,9 +771,6 @@ def update_engine_gauges(name: str, m: dict,
     v = m.get("host_overhead_fraction")
     if v is not None:
         reg.host_overhead_fraction.set(v, model=name)
-    v = m.get("device_bubble_fraction")
-    if v is not None:
-        reg.device_bubble_fraction.set(v, model=name)
 
 
 REGISTRY = Registry()

@@ -19,8 +19,9 @@ sys.path[:0] = [str(BENCH), str(BENCH / "tests")]
 
 import run as bench  # noqa: E402
 import xspace  # noqa: E402
+from harness import launches as lch  # noqa: E402
 from harness import metrics as mtr  # noqa: E402
-from harness import peaks, spec  # noqa: E402
+from harness import peaks, spec, work  # noqa: E402
 from harness import trace_reduce as tr  # noqa: E402
 
 _spec = importlib.util.spec_from_file_location(
@@ -48,10 +49,25 @@ HLO = {
 PATH = "jit(_decode_paged_fn)/jit(main)/decode/layers/while/body/"
 
 
-def named_trace(tmp_path, host=True, scopes=True) -> Path:
+LAUNCHES = (12, 13, 14, 15)         # the launch numbers of ``named_trace``
+CHAT_COUNTED = {"model.prefill_mfu.counted", "runner.prefill_pad_share",
+                "runner.prefill_attend_pad_share"}
+
+
+def named_trace(tmp_path, host=True, scopes=True, launches=None) -> Path:
     """One chip, two decode steps and a prefill chunk (ns from the trace's
     start), idle in [400,600) and [800,900) and [1000,1100); the engine
-    thread admits in [380,590), processes twice and waits in [790,1000)."""
+    thread admits in [380,590), processes twice and waits in [790,1000).
+
+    With ``launches`` (four numbers, or "bare") the engine thread also
+    launches: the decode step that runs at 600 inside the phase it had
+    (``sched.decode_launch`` [590,600)), a chunk in a ``sched.prefill_chunk``
+    [600,640) that runs at 900, a decode step in a ``sched.decode_launch``
+    [1040,1090) that runs at 1100, and one in [1150,1160) that the trace
+    holds no execution of; the decode step at 100 was launched before the
+    capture began. The numbers name the ``sched.launch/<n>`` nested in each
+    (the third as long as its phase, inside the idle gap [1000,1100));
+    "bare" writes the phases and no launch: the program before PR 39."""
     ops = [(HLO["mlp"], 100, 100), (HLO["kernel"], 200, 100),
            (HLO["write"], 300, 50), (HLO["restack"], 350, 50),
            (HLO["slice"], 600, 50), (HLO["mlp"], 650, 150),
@@ -73,12 +89,21 @@ def named_trace(tmp_path, host=True, scopes=True) -> Path:
                         (f"jit__decode_paged_fn({FP})", 1100, 100)]},
         meta=meta if scopes else None)]
     if host:
+        engine = [("sched.process", 340, 20), ("sched.admit", 380, 210),
+                  ("sched.decode_launch", 590, 10),
+                  ("sched.process", 760, 30),
+                  ("sched.wait_device", 790, 210)]
+        if launches:
+            engine += [("sched.prefill_chunk", 600, 40),
+                       ("sched.decode_launch", 1040, 50),
+                       ("sched.decode_launch", 1150, 10)]
+        if launches and launches != "bare":
+            engine += [(f"sched.launch/{n}", at, ns) for n, (at, ns) in zip(
+                launches, ((592, 6), (605, 30), (1040, 50), (1152, 6)))]
+        # as the profiler writes a line: by start, the enclosing event first
+        engine.sort(key=lambda ev: (ev[1], -ev[2]))
         planes.append(xspace.plane("/host:CPU", {
-            "engine-tiny/71": [("sched.process", 340, 20),
-                               ("sched.admit", 380, 210),
-                               ("sched.decode_launch", 590, 10),
-                               ("sched.process", 760, 30),
-                               ("sched.wait_device", 790, 210)],
+            "engine-tiny/71": engine,
             "MainThread/1": [("sched.admit", 0, 2000),     # not the engine:
                              ("aiohttp", 0, 50)]}))        # fewer phases
     planes.append(xspace.plane("Task Environment", {}, {
@@ -165,6 +190,140 @@ def test_the_new_readers_read_scopes_and_phases(tmp_path, metric, value):
 
 
 # ---------------------------------------------------------------------------
+# what a launch holds (PR 39): the ring row of an execution, by launch number
+
+
+def ring_row(launch, program, at_s, **held) -> dict:
+    """A flight-ring row as /debug/flight serves it, drained ``at_s`` into
+    the window of ``launch_ctx``."""
+    return {"launch": launch, "program": program, "compile": False,
+            "steps": 0, "ts_unix": 1000.0 + at_s,
+            **{c: 0 for c in ("live_slots", "attended_tokens", "chunk_tokens",
+                              "chunk_bucket", "chunk_offset", "chunk_ctx")},
+            **held}
+
+
+RING = [
+    # a chunk of the window that the slice does not hold, a compile-bearing
+    # one, and one from before the window
+    ring_row(3, "prefill_chunk", -5.0, chunk_tokens=7, chunk_bucket=128,
+             chunk_offset=0, chunk_ctx=512),
+    {**ring_row(9, "prefill_chunk", 2.0, chunk_tokens=1, chunk_bucket=128,
+                chunk_offset=0, chunk_ctx=512), "compile": True},
+    ring_row(11, "prefill_chunk", 3.0, chunk_tokens=100, chunk_bucket=128,
+             chunk_offset=0, chunk_ctx=512),
+    # the slice's four launches (LAUNCHES)
+    ring_row(12, "decode", 4.0, steps=1, live_slots=3, attended_tokens=300),
+    ring_row(13, "prefill_chunk", 4.1, chunk_tokens=20, chunk_bucket=32,
+             chunk_offset=64, chunk_ctx=512),
+    ring_row(14, "decode_n", 4.2, steps=2, live_slots=2,
+             attended_tokens=500),
+    ring_row(15, "decode", 4.3, steps=1, live_slots=2, attended_tokens=260),
+]
+
+
+def launch_ctx(trace) -> dict:
+    """A run of the 7B's chat cell whose window is [0, 10) s of the ring's
+    clock, with ``trace`` as its slice."""
+    return {"cell": spec.load_cell("m7b-chat", ROOT), "trace": trace,
+            "traced": {"flight": RING}, "anchor": (1000.0, 0.0),
+            "window": mtr.Window(0.0, 10.0, 12.0),
+            "peak": peaks.PEAKS["TPU v5 lite"]}
+
+
+def test_the_join_keeps_the_launches_the_slice_holds_whole(tmp_path):
+    """Launched before the capture (the step at 100), inside it (three),
+    and as it stopped (one with no execution): the inner three are matched,
+    each to its own row and its own device time, and the counts of what was
+    left out go onto the ``trace`` line."""
+    ctx = launch_ctx(tr.reduce(named_trace(tmp_path, launches=LAUNCHES)))
+    joined = lch.join(ctx)
+    assert [(row["launch"], kind, round((e - s) * 1e9))
+            for row, kind, s, e in joined["pairs"]] == [
+        (12, "decode", 200), (13, "prefill", 100), (14, "decode", 100)]
+    assert {k: joined[k] for k in ("early", "late", "unrun", "unrowed")} == {
+        "early": 1, "late": 0, "unrun": [15], "unrowed": 0}
+    # the least room to a pair's bounds: launch 12 began 8 ns before its
+    # step did, and the step had ended 352 ns before launch 15 began
+    assert ctx["trace"]["notes"]["launches"] == {
+        "matched": {"decode": 2, "prefill": 1}, "executions": 4,
+        "early": 1, "late": 0, "unrun": [15], "unrowed": 0,
+        "slack_ms": [pytest.approx(8e-6, abs=1e-3),
+                     pytest.approx(352e-6, abs=1e-3)]}
+    # no launch in the trace (the parent's), no ``launch`` in the ring (the
+    # parent's), no trace (a voided slice): nothing to join, and no error
+    bare = launch_ctx(tr.reduce(named_trace(tmp_path, launches="bare")))
+    assert lch.join(bare) is None
+    old = {**ctx, "traced": {"flight": [
+        {k: v for k, v in r.items() if k != "launch"} for r in RING]}}
+    assert lch.join(old) is None
+    assert lch.join(launch_ctx(None)) is None
+
+
+def _counted(metric, ctx):
+    """The four readers' numbers by hand, from RING and the trace's times."""
+    cell = ctx["cell"]
+    fam, hf = cell.family, cell.published
+    if metric == "model.prefill_mfu.counted":
+        # launch 13 alone: 20 real tokens behind 64 cached, 100 ns
+        pairs = 20 * 64 + 20 * 21 // 2
+        flops = 2.0 * fam.token_params(hf) * 20 + fam.attn_flops(hf, pairs)
+        return 100.0 * flops / 100e-9 / 197e12
+    if metric == "model.decode_bw_share.counted":
+        # launches 12 (one step over 3 streams, 200 ns) and 14 (two steps
+        # over 2, 100 ns); 15 never ran, the step at 100 has no launch
+        weights = fam.step_params(hf, 3) + 2 * fam.step_params(hf, 2)
+        kv = (300 + 500) * fam.kv_bytes_per_token(hf, 2.0)
+        return 100.0 * (weights * 1.0 + kv) / 819e9 / 300e-9
+    # the window's chunks: launches 11 and 13 (3 is before it, 9 compiled)
+    if metric == "runner.prefill_pad_share":
+        return 100.0 * (1 - (100 + 20) / (128 + 32))
+    assert metric == "runner.prefill_attend_pad_share"
+    real = 100 * 101 // 2 + (20 * 64 + 20 * 21 // 2)
+    return 100.0 * (1 - real / (128 * 512 + 32 * 512))
+
+
+@pytest.mark.parametrize("metric", [
+    "model.prefill_mfu.counted", "model.decode_bw_share.counted",
+    "runner.prefill_pad_share", "runner.prefill_attend_pad_share"])
+def test_the_counted_readers_sum_matched_work_over_matched_time(
+        tmp_path, metric):
+    read = spec.load_reader(metric, ROOT)
+    ctx = launch_ctx(tr.reduce(named_trace(tmp_path, launches=LAUNCHES)))
+    assert read(ctx) == pytest.approx(_counted(metric, ctx), rel=1e-9)
+    # the parent's program (no launch named, no count in the ring): None,
+    # and no error; the two window-wide readers need no trace at all
+    parent = launch_ctx(tr.reduce(named_trace(tmp_path, launches="bare")))
+    parent["traced"] = {"flight": [
+        {k: v for k, v in r.items() if k in (
+            "program", "compile", "steps", "ts_unix")} for r in RING]}
+    assert read(parent) is None
+    voided = launch_ctx(None)
+    if metric.startswith("runner."):
+        assert read(voided) == pytest.approx(_counted(metric, ctx))
+    else:
+        assert read(voided) is None
+
+
+def test_a_nested_launch_changes_no_phase_reading(tmp_path):
+    """The readers of the engine thread's phases read THE SAME with the
+    launches nested in them as without: a gap's owner (the third launch
+    covers the gap [1000,1100) exactly as far as its phase does, and the
+    phase keeps it), the idle the scheduler owns, ``sched.process``."""
+    bare = tr.reduce(named_trace(tmp_path, launches="bare"))
+    nested = tr.reduce(named_trace(tmp_path, launches=LAUNCHES))
+    assert nested["notes"]["phases"] == bare["notes"]["phases"] + 4
+    assert nested["breakdown"]["idle_gaps"] == bare["breakdown"]["idle_gaps"]
+    assert [g[0] for g in bare["breakdown"]["idle_gaps"]] == [
+        "sched.admit", "sched.wait_device", "sched.decode_launch"]
+    assert nested["idle_owned_s"] == pytest.approx(bare["idle_owned_s"])
+    assert bare["idle_owned_s"] == pytest.approx(250e-9)
+    for metric in ("sched.process_ms_p90", "sched.device_idle_share"):
+        read = spec.load_reader(metric, ROOT)
+        assert read(tiny_ctx(nested)) == read(tiny_ctx(bare)) is not None
+
+
+# ---------------------------------------------------------------------------
 # --trace 2, end to end on the CPU
 
 
@@ -176,6 +335,13 @@ def test_trace_2_scores_first_and_asks_the_server_afterwards(
     profiler started and the ``trace`` stream sent only after the last scored
     record has ended."""
     monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    # the open-loop tiny cell reports TTFT: it stands for the chat cells in
+    # the three metrics of PR 39 that name their cells (in the copy)
+    entries = json.loads((bench_copy / "BENCHMARK.json").read_text())
+    for m in entries["per_layer"]:
+        if m["name"] in CHAT_COUNTED:
+            m["workloads"].append("tiny-open")
+    (bench_copy / "BENCHMARK.json").write_text(json.dumps(entries))
     asked: dict[str, list] = {}
     for name in ("read_flight", "read_traces", "capture_trace"):
         def spy(*args, _real=getattr(bench, name), _name=name, **kw):
@@ -186,7 +352,14 @@ def test_trace_2_scores_first_and_asks_the_server_afterwards(
     def reduce_run(run_dir, traced, _real=tr.reduce_run):
         # the real capture's file is there (and has no device plane)
         assert tr.find_xplane(run_dir, traced) is not None
-        fake = named_trace(tmp_path)
+        # the hand-made trace's launches carry numbers of the ring's own
+        # rows of the kinds it runs: decode, a chunk, decode, decode
+        ring = {"decode": [], "prefill": []}
+        for r in traced["flight"]:
+            if lch.row_kind(r["program"]) and not r["compile"]:
+                ring[lch.row_kind(r["program"])].append(r["launch"])
+        dec, pre = ring["decode"][-3:], ring["prefill"][-1]
+        fake = named_trace(tmp_path, launches=(dec[0], pre, dec[1], dec[2]))
         monkeypatch.setattr(tr, "find_xplane", lambda *a: fake)
         return _real(run_dir, traced)
 
@@ -208,6 +381,14 @@ def test_trace_2_scores_first_and_asks_the_server_afterwards(
                  "runner.kv_move_share", "sched.process_ms_p90",
                  "sched.device_idle_share"):
         assert name in out["metrics"], name
+    # PR 39's four: the decode share in every cell, the prefill three where
+    # the cell reports TTFT and in no other
+    assert "model.decode_bw_share.counted" in out["metrics"]
+    assert (CHAT_COUNTED <= set(out["metrics"])) == (workload == "tiny-open")
+    assert (CHAT_COUNTED & set(out["metrics"]) == set()) == (
+        workload == "tiny-closed")
+    notes = json.loads(next(ln for ln in lines if ln.startswith("trace "))[6:])
+    assert notes["launches"]["matched"] == {"decode": 2, "prefill": 1}
     assert {"busy_s", "window_s", "memory_peak_bytes"} <= set(out["device"])
     assert out["breakdown"]["idle_gaps"][0][0] == "sched.admit"
 

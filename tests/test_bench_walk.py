@@ -3,7 +3,8 @@ that tier-1 counts them (PERF.md section 7 (A); ``benchmark/tests/`` itself is
 not part of tier-1): the order a family's ``walk`` gives, the loop a family
 without one keeps, a row the model does not hold, ``cache_layers``, an optional
 name that is no function (``test_walk.py``); the flight ring paged forward
-(``test_contract.py``); and what the committed BENCHMARK.json names.
+(``test_contract.py``); what the committed BENCHMARK.json names; and the
+join of a slice's launches to its executions (``test_launches.py``).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -42,6 +43,7 @@ _conftest = _load("conftest")
 _walk = _load("test_walk", conftest=_conftest)
 _contract = _load("test_contract", conftest=_conftest)
 _ouro = _load("test_ouro_family", conftest=_conftest)
+_launches = _load("test_launches", conftest=_conftest)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -74,3 +76,10 @@ test_the_new_cell_reports_what_the_issue_names = (
     _ouro.test_the_new_cell_reports_what_the_issue_names)
 test_the_loop_readers_read_the_ring_and_the_scope = (
     _ouro.test_the_loop_readers_read_the_ring_and_the_scope)
+# PR 39's file: a slice's launches joined to its executions, by hand
+test_the_ith_launch_is_the_ith_execution_launched_in_the_capture = (
+    _launches.test_the_ith_launch_is_the_ith_execution_launched_in_the_capture)
+test_a_join_that_does_not_hold_voids_the_slice_and_says_why = (
+    _launches.test_a_join_that_does_not_hold_voids_the_slice_and_says_why)
+test_matched_gives_a_kinds_rows_with_their_device_seconds = (
+    _launches.test_matched_gives_a_kinds_rows_with_their_device_seconds)

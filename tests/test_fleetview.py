@@ -330,12 +330,11 @@ def test_fleet_flight_merges_replicas(fleet):
     assert all("percentiles" in p for p in
                (out["replicas"][rid] for rid in with_records))
     # dispatch-anatomy columns on every merged row, fraction gauges per
-    # replica pane (the per-replica bubble columns on /debug/fleet/flight)
+    # replica pane (the per-replica phase columns on /debug/fleet/flight)
     for rec in out["records"]:
         for ph in ("gap_ms", "sched_ms", "launch_ms", "sync_ms"):
             assert ph in rec
     assert all("host_overhead_fraction" in out["replicas"][rid]
-               and "device_bubble_fraction" in out["replicas"][rid]
                for rid in with_records)
 
 
@@ -370,7 +369,6 @@ def test_fleet_flight_tolerates_replicas_without_phase_columns():
         assert row[ph] is None
     pane = out["replicas"]["legacy/r0"]
     assert pane["host_overhead_fraction"] is None
-    assert pane["device_bubble_fraction"] is None
     assert pane["anatomy"] is None
 
 

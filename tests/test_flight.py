@@ -45,6 +45,45 @@ def test_total_tokens_survives_wraparound():
     assert fl.total_tokens == 70                # not just the resident 2
 
 
+WORK = {"launch": 7, "live_slots": 3, "attended_tokens": 1234,
+        "chunk_tokens": 88, "chunk_bucket": 128, "chunk_offset": 512,
+        "chunk_ctx": 4096}
+
+
+@pytest.mark.parametrize("column", sorted(WORK))
+def test_what_a_launch_held_round_trips(column):
+    """Each of the seven columns goes in by record()'s keyword and comes
+    out of snapshot() under the same name, beside rows that gave none."""
+    from localai_tpu.obs.flight import WORK_COLUMNS
+
+    assert set(WORK_COLUMNS) == set(WORK)
+    fl = FlightRecorder(8)
+    _rec(fl, 0)
+    fl.record(program="prefill_chunk", steps=0, dispatch_ms=3.0,
+              occupancy=0.5, queue_depth=1, kv_utilization=0.25, tokens=0,
+              **{column: WORK[column]})
+    bare, row = fl.snapshot()
+    assert row[column] == WORK[column] and type(row[column]) is int
+    # every other column, and every column of a row that gave none: 0
+    assert {c: row[c] for c in WORK if c != column} == {
+        c: 0 for c in WORK if c != column}
+    assert {c: bare[c] for c in WORK} == {c: 0 for c in WORK}
+
+
+def test_what_a_launch_held_survives_the_wrap():
+    fl = FlightRecorder(4)
+    for i in range(1, 11):
+        fl.record(program="decode", steps=1, dispatch_ms=1.0, occupancy=1.0,
+                  queue_depth=0, kv_utilization=0.5, tokens=4, launch=i,
+                  live_slots=4, attended_tokens=100 * i)
+    snap = fl.snapshot()
+    assert [r["launch"] for r in snap] == [7, 8, 9, 10]
+    assert [r["attended_tokens"] for r in snap] == [700, 800, 900, 1000]
+    # a page forward from a row keeps its columns too
+    page = fl.snapshot(since=snap[1]["ts"], limit=1)
+    assert [r["launch"] for r in page] == [9] and page[0]["live_slots"] == 4
+
+
 def test_percentile_math_matches_numpy():
     fl = FlightRecorder(64)
     ms = [4.0, 8.0, 12.0, 16.0, 40.0]
@@ -178,9 +217,9 @@ def test_phases_percentile_math_matches_numpy():
     assert ph["host_ms_total"] == pytest.approx(host.sum(), abs=1e-3)
     assert ph["host_overhead_fraction"] == pytest.approx(
         host.sum() / 100.0, abs=1e-3)
-    bubble = np.maximum(0.0, host - np.array(syncs))
-    assert ph["device_bubble_fraction"] == pytest.approx(
-        bubble.sum() / 100.0, abs=1e-3)
+    # the host's clock splits HOST time; no estimate of the device's idle
+    # share rides along (the profiler measures it)
+    assert not [k for k in ph if "bubble" in k]
 
 
 def test_phases_exclude_compile_rows_and_window():
@@ -207,7 +246,6 @@ def test_phases_empty_returns_none_percentiles():
     for name in ("gap", "sched", "launch", "sync", "host"):
         assert ph[f"{name}_ms_p50"] is None
     assert ph["host_overhead_fraction"] is None
-    assert ph["device_bubble_fraction"] is None
     assert ph["dispatch_ms_total"] == 0.0
 
 

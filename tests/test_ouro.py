@@ -14,6 +14,7 @@ from the published description) run as the benchmark runs it
 import dataclasses
 import json
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -559,13 +560,20 @@ def test_the_flight_ring_and_metrics_count_passes():
                                   max_new_tokens=12, temperature=0.0,
                                   ignore_eos=True), timeout=120)
         assert h.completion_tokens == 12
-        rows = s.flight.snapshot()
+        # the dispatch in flight when the reply ended drains a moment later:
+        # read ring and counter once both have it
+        deadline = time.monotonic() + 10.0
+        while True:
+            rows, m = s.flight.snapshot(), s.metrics()
+            if (m["loop_passes"] == sum(x["passes"] for x in rows)
+                    or time.monotonic() > deadline):
+                break
+            time.sleep(0.05)
         decode = [x for x in rows if x["program"].startswith("decode")]
         assert decode and all(x["passes"] == PASSES * x["steps"]
                               for x in decode)
         chunks = [x for x in rows if x["program"] == "prefill_chunk"]
         assert chunks and all(x["passes"] == PASSES for x in chunks)
-        m = s.metrics()
         assert m["loop_passes"] == sum(x["passes"] for x in rows)
         obs_metrics.update_engine_gauges("looped", m)
         text = obs_metrics.REGISTRY.render()

@@ -388,6 +388,69 @@ def test_a_capture_holds_the_schedulers_phases(paged_runner, tmp_path):
                    for n in names)        # "$file:line fn" = a Python frame
 
 
+def test_a_capture_holds_every_launch_under_its_phase(paged_runner, tmp_path):
+    """A real 0.3 s capture on the CPU: the engine thread's line holds a
+    ``sched.launch/<n>`` for each serving program enqueued meanwhile, each
+    INSIDE the phase that was open (a decode launch in
+    ``sched.decode_launch``, a chunk in ``sched.prefill_chunk``), one a
+    phase, and every n is the ``launch`` of a ring row of that kind; the
+    line comes sorted, an enclosing event before what it encloses (what the
+    benchmark's reduction of a gap's owner rests on)."""
+    from jax.profiler import ProfileData
+
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    s = Scheduler(paged_runner, ByteTokenizer())
+    try:
+        done = threading.Event()
+
+        def serve():
+            while not done.is_set():
+                s.generate(GenRequest(prompt=list(b"y" * 70),
+                                      max_new_tokens=16, ignore_eos=True))
+
+        s.generate(GenRequest(prompt=list(b"y" * 70), max_new_tokens=2))
+        t = threading.Thread(target=serve)
+        t.start()
+        try:
+            obs_profiler.capture(str(tmp_path), 0.3)
+        finally:
+            done.set()
+            t.join(120)
+        rows = {r["launch"]: r["program"] for r in s.flight.snapshot()}
+    finally:
+        s.shutdown()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    lines = [[(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+              for ev in ln.events if ev.name.startswith("sched.")]
+             for p in ProfileData.from_file(str(path)).planes
+             if p.name == "/host:CPU" for ln in p.lines]
+    line = max(lines, key=len)
+    assert sum(bool(ln) for ln in lines) == 1       # the engine thread's
+    assert line == sorted(line, key=lambda ev: (ev[0], -ev[1]))
+    launches = [ev for ev in line if ev[2].startswith("sched.launch/")]
+    phases = [ev for ev in line if not ev[2].startswith("sched.launch/")]
+    assert launches
+    seen = set()
+    for lo, hi, name in launches:
+        n = int(name.rsplit("/", 1)[1])
+        around = [p for p in phases if p[0] <= lo and hi <= p[1]]
+        assert len(around) == 1, (name, around)
+        kind = {"sched.decode_launch": "decode",
+                "sched.prefill_chunk": "prefill_chunk"}[around[0][2]]
+        assert rows[n].startswith(kind), (name, rows[n], around)
+        # one launch a phase: the phase's own time is its launch's
+        assert sum(around[0][0] <= l[0] < around[0][1]
+                   for l in launches) == 1
+        seen.add(kind)
+    # a loaded machine fits a handful of launches into 0.3 s: decode steps
+    # always, a chunk nearly always
+    assert "decode" in seen and seen <= {"decode", "prefill_chunk"}
+    ns = [int(ev[2].rsplit("/", 1)[1]) for ev in launches]
+    assert ns == sorted(set(ns))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_lowered_programs_carry_the_scope_names(paged_runner, program):
     """Scopes are metadata of the lowered program (no profiler needed): the

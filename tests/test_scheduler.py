@@ -970,3 +970,121 @@ def test_an_admission_launches_two_programs_and_a_release_one(tiny,
     assert launched == ["_release_slot"]
     monkeypatch.undo()
     assert adm.first_token() >= 0
+
+
+# -- what a launch holds (PR 39): the flight ring's launch number and counts --
+
+
+_HELD_RUNS = iter(range(1000))
+
+
+def _held_run(s: Scheduler, monkeypatch, steps):
+    """Three prompts of known lengths, the second sharing the first's two
+    leading blocks, served one after the other beside a decoding keeper,
+    with every decode launch logged AS THE DEVICE STOOD when its program
+    was enqueued: each live slot's frontier, read from the state the newest
+    program returns (the read waits for what is in flight; the host's
+    mirrors still lack it). Returns (the ring rows of this run by launch
+    number, the log by launch number: (k, [(handle, frontier, tokens the
+    host had counted)]), [(prompt tokens, tokens the prefix pool served)])."""
+    if steps is not None:
+        monkeypatch.setattr(s, "_effective_steps",
+                            lambda pipelined=True: steps)
+    log = {}
+
+    def spy(real):
+        def launch(*n):
+            at = np.asarray(s.runner.state.positions)
+            log[s._launch_seq] = (n[0] if n else 1, [
+                (c.handle, int(at[slot]), c.handle.completion_tokens)
+                for slot, c in s._slots.items()])
+            return real(*n)
+        return launch
+
+    monkeypatch.setattr(s.runner, "step_async", spy(s.runner.step_async))
+    monkeypatch.setattr(s.runner, "step_n_async",
+                        spy(s.runner.step_n_async))
+    mark = s._launch_seq
+    keeper = _keeper(s)
+    # the prefix pool outlives a run: each run's prompts open with its own tag
+    tag = f"run {next(_HELD_RUNS):03d} "
+    first = tag + "a prompt of forty tokens, shared"    # 40 bytes
+    assert len(first) == 40
+    prompts = [first, first[:32] + "then its own", tag + "x" * 62]
+    served = []
+    for text in prompts:
+        reused = s.runner.total_prefix_reused
+        h = s.generate(_req(text, max_new_tokens=5, ignore_eos=True,
+                            **GREEDY), timeout=120)
+        assert h.finish_reason == "length"
+        served.append((h.prompt_tokens, s.runner.total_prefix_reused - reused))
+    keeper.cancel()
+    keeper.result(60)
+    assert _wait(lambda: not s.busy)
+    rows = {r["launch"]: r for r in s.flight.snapshot() if r["launch"] > mark}
+    return rows, log, served
+
+
+@pytest.mark.parametrize("steps", [None, 2], ids=["own_k", "k_2"])
+def test_a_decode_row_holds_what_its_launch_held(paged, monkeypatch, steps):
+    """``live_slots`` and ``attended_tokens`` of every decode row are the
+    device's own counts AT THE LAUNCH (a slot whose frontier stands at p
+    attends p + 1 + j in step j), not the drain's one pipelined dispatch
+    later, and not the host mirror's, which lacks what is still in flight;
+    a stream whose last token comes in the dispatch is in it; every other
+    count of a decode row is 0."""
+    s, _ = paged
+    rows, log, _ = _held_run(s, monkeypatch, steps)
+    decode = {n: r for n, r in rows.items() if r["program"].startswith("decode")}
+    assert len(decode) > 10 and set(decode) <= set(log)
+    ended_inside = lagged = 0
+    for n, row in decode.items():
+        k, held = log[n]
+        assert row["steps"] == k and (steps is None or k == steps)
+        assert row["live_slots"] == len(held) > 0
+        assert row["attended_tokens"] == sum(
+            at + 1 + j for _, at, _ in held for j in range(k))
+        assert not any(row[c] for c in ("chunk_tokens", "chunk_bucket",
+                                        "chunk_offset", "chunk_ctx"))
+        done = [(h, at + 1 - h.prompt_tokens, seen) for h, at, seen in held]
+        ended_inside += sum(
+            h.finish_reason == "length" and g < h.completion_tokens == 5 <= g + k
+            for h, g, _ in done)            # the keeper's limit is 80
+        lagged += any(g > seen for _, g, seen in done)
+    assert ended_inside == 3       # each of the three requests' last token
+    assert lagged                  # the mirror alone would have undercounted
+    # the drain would have counted otherwise: some row's launch held MORE
+    # streams than were left when its tokens had been processed
+    assert any(r["live_slots"] > round(r["occupancy"] * s.runner.num_slots)
+               for r in decode.values())
+
+
+@pytest.mark.parametrize("steps", [None, 2], ids=["own_k", "k_2"])
+def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
+    """Sum of ``chunk_tokens`` = the prompts' tokens less what the prefix
+    pool served; a prompt's chunks run from the tokens the pool served in
+    steps of ``prefill_chunk``; the bucket is the runner's and holds the
+    chunk; the attend spans the padded context; ``launch`` is unique and
+    increasing over all rows, whatever their kind."""
+    s, _ = paged
+    r = s.runner
+    rows, _, served = _held_run(s, monkeypatch, steps)
+    order = sorted(rows)
+    assert order == [rows[n]["launch"] for n in order] and order[0] > 0
+    assert len(set(order)) == len(rows)
+    ts = [rows[n]["ts"] for n in order if rows[n]["program"] == "prefill_chunk"]
+    assert ts == sorted(ts)
+    chunks = [rows[n] for n in order if rows[n]["program"] == "prefill_chunk"]
+    assert served[1][1] == 32 and served[0][1] == served[2][1] == 0
+    want = [(6, 0)]                                 # the keeper's one chunk
+    for prompt, reused in served:
+        want += [(min(r.prefill_chunk, prompt - at), at)
+                 for at in range(reused, prompt, r.prefill_chunk)]
+    assert [(c["chunk_tokens"], c["chunk_offset"]) for c in chunks] == want
+    assert sum(c["chunk_tokens"] for c in chunks) == 6 + sum(
+        p - reused for p, reused in served)
+    for c in chunks:
+        assert c["chunk_bucket"] in r.buckets
+        assert c["chunk_bucket"] == r.bucket_for(c["chunk_tokens"])
+        assert c["chunk_ctx"] == r.ctx_pad == 96
+        assert c["live_slots"] == c["attended_tokens"] == 0

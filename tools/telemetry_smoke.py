@@ -72,8 +72,7 @@ generations through the continuous-batching scheduler, then:
      ``dispatch_ms`` (the interval-tiling invariant), the derived
      host-overhead fraction in (0, 1), the
      ``localai_dispatch_phase_ms`` / ``localai_host_overhead_fraction``
-     / ``localai_device_bubble_fraction`` series rendering, and the
-     client-observed TTFT p95 agreeing with the server-side histogram;
+     series rendering, and the client-observed TTFT p95 agreeing with the server-side histogram;
      the breakdown lands in ``--anatomy-out`` (a CI artifact);
 
  12. asserts the round-20 elastic capacity loop: a 1-replica autoscaled
@@ -209,7 +208,7 @@ REQUIRED_USAGE = (
 )
 # dispatch-anatomy series (round 19): after real traffic through the
 # smoke engine, every phase column must render a windowed percentile and
-# both derived fractions must be present (values asserted in-code by
+# the derived host fraction must be present (values asserted in-code by
 # check_anatomy; the exposition check pins the series names)
 REQUIRED_ANATOMY = (
     'localai_dispatch_phase_ms{model="smoke",phase="gap",quantile="p50"}',
@@ -217,7 +216,6 @@ REQUIRED_ANATOMY = (
     'localai_dispatch_phase_ms{model="smoke",phase="launch",quantile="p50"}',
     'localai_dispatch_phase_ms{model="smoke",phase="sync",quantile="p99"}',
     'localai_host_overhead_fraction{model="smoke"}',
-    'localai_device_bubble_fraction{model="smoke"}',
 )
 # elastic-capacity series (round 20): the autoscaled fleet must record a
 # spike-driven scale-out, the quiesce-driven scale-to-zero, the cold
@@ -903,18 +901,14 @@ def check_anatomy(sched, tok, registry, anatomy_out: str) -> list[str]:
                 f"(program={r['program']})")
             break
 
-    # (b) derived fractions: genuine open-interval fractions
+    # (b) the derived fraction: a genuine open-interval fraction
     anat = obs_anatomy.summarize(sched.flight, window_s=None)
     hof = anat["host_overhead_fraction"]
-    bubble = anat["device_bubble_fraction"]
     if not anat["samples"]:
         problems.append("anatomy: summarize() saw zero samples")
     elif hof is None or not (0.0 < hof < 1.0):
         problems.append(
             f"anatomy: host_overhead_fraction {hof} outside (0, 1)")
-    if bubble is not None and not (0.0 <= bubble <= 1.0):
-        problems.append(
-            f"anatomy: device_bubble_fraction {bubble} outside [0, 1]")
 
     # (c) client-vs-server latency cross-check: diff the histogram around
     # the loadgen run (isolating exactly this traffic's server view),
