@@ -25,10 +25,12 @@ i32 CACHE layer (the scan's layer index, plus pass x layers in a looped
 model). A policy scatters ONLY the new rows into the stack
 (``_write_rows``, ``_write_run``, ``_write_chunk``) and hands the attend
 what it reads: the blocks or rows gathered straight from the 5-D array,
-or, ``raw=True``, a ``LayerView`` of the stack for a Pallas kernel that
-indexes the layer itself. Nothing slices a layer out of the stack to update
-it or to pass it on: such a slice, its re-layout and the restack cost more
-than the rest of a decode step together (PERF.md, PR 26).
+or a ``LayerView`` of the stack for an attend that picks its own reads: a
+Pallas kernel that indexes the layer itself (``raw=True``), the paged
+chunk's ``span_attend``, which gathers the span of the table row its
+``offset`` asks for (PERF.md, PR 40). Nothing slices a layer out of the
+stack to update it or to pass it on: such a slice, its re-layout and the
+restack cost more than the rest of a decode step together (PERF.md, PR 26).
 
 One policy leaves the write to its attend: ``paged_decode_write(raw=True)``
 over an unscaled pool hands the stack back UNTOUCHED, its views carry the
@@ -50,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.llama import LlamaConfig, _grouped_attn
 from localai_tpu.models.quant import (
     quantize_lastdim as _quant_chunk,
     quantize_lastdim4 as _quant_chunk4,
@@ -227,10 +229,12 @@ def init_paged_cache(
 
 
 class LayerView(NamedTuple):
-    """What a ``raw=True`` policy hands a Pallas decode kernel in place of
-    keys (and of values): the WHOLE stacked cache, the layer to read, and
-    the stacked scales of a quantized cache. The kernel picks the layer in
-    its DMA slice (ops.attention), so no per-layer slice ever exists.
+    """What a ``raw=True`` policy hands a Pallas decode kernel (and
+    ``paged_prefill_write`` its ``span_attend``) in place of keys (and of
+    values): the WHOLE stacked cache, the layer to read, and the stacked
+    scales of a quantized cache. The kernel picks the layer in its DMA
+    slice (ops.attention), the span attend in its gather, so no per-layer
+    slice ever exists.
     ``new``: the step's rows in the cache's dtype, where the policy has NOT
     stored them and the kernel is to."""
 
@@ -363,6 +367,13 @@ def _views(kv_stack, layer):
                  for cache, scale in _pairs(kv_stack))
 
 
+def _stacked(keys: LayerView, values: LayerView):
+    """The stacked cache ``_views`` was given, from its two views."""
+    if keys.scale is None:
+        return (keys.cache, values.cache)
+    return (keys.cache, values.cache, keys.scale, values.scale)
+
+
 def _gather_context(kv_stack, layer, tables, k_new):
     """(keys, values) ``[S, H, MB*bt, hd]`` in ``k_new``'s dtype for the
     XLA attend over a block pool: the blocks the tables name, gathered
@@ -439,6 +450,37 @@ def kernel_attend(kernel, tables: jax.Array, positions: jax.Array):
     return attn
 
 
+def span_ladder(bucket: int, ctx_pad: int, block_tokens: int) -> tuple[int, ...]:
+    """The spans a ``bucket``-token chunk's attend may take: multiples of
+    ``block_tokens`` that double from 512 (the bucket where that is larger)
+    and end at ``ctx_pad`` (289 blocks of 64 on one chip: 512, 1024, 2048,
+    4096). Short and static: each rung is one branch of every prefill
+    program (``span_attend``)."""
+    c = -(-max(512, bucket) // block_tokens) * block_tokens
+    rungs = []
+    while c < ctx_pad:
+        rungs.append(c)
+        c *= 2
+    return (*rungs, ctx_pad)
+
+
+def attend_rung(need, rungs: tuple[int, ...]):
+    """Index of the smallest of ``rungs`` (a ``span_ladder``) that covers
+    ``need`` = ``offset + bucket`` positions (the last where none does: a
+    chunk never attends past ``ctx_pad``). ``need`` is the program's traced
+    scalar or the host's integer: ONE expression serves both, so the span
+    the ring row states is the span the device took."""
+    return sum((need > c) * 1 for c in rungs[:-1])
+
+
+def attend_span(offset: int, bucket: int, ctx_pad: int,
+                block_tokens: int) -> int:
+    """Positions the attend of a ``bucket``-token chunk behind ``offset``
+    cached tokens spans (host integers; the flight ring's ``chunk_ctx``)."""
+    rungs = span_ladder(bucket, ctx_pad, block_tokens)
+    return rungs[attend_rung(offset + bucket, rungs)]
+
+
 def paged_prefill_write(table_row: jax.Array, offset: jax.Array,
                         length: jax.Array):
     """KV write policy for one chunked-prefill dispatch into a block table.
@@ -448,15 +490,52 @@ def paged_prefill_write(table_row: jax.Array, offset: jax.Array,
     lands at pool[layer, table_row[(offset+t)//bt], :, (offset+t)%bt];
     padding rows (t >= length) are written nowhere, so a padded bucket can
     never clobber the sequence's own reserved blocks (``_write_run``).
-    Exposes the gathered FULL logical context [1, H, MB*bt, hd] so chunk
-    tokens attend over the kept prefix + earlier chunks (resume-style)."""
+    Hands ``span_attend`` a :class:`LayerView` of the written stack: the
+    attend gathers the span of the table row the chunk needs (the kept
+    prefix + earlier chunks + itself, resume-style) and no more."""
 
     def write(kv_stack, layer, k_new, v_new):  # k_new [1, T, H, hd]
         new = _write_run(kv_stack, layer, table_row, offset, length,
                          k_new[0], v_new[0])
-        return (new, *_gather_context(new, layer, table_row[None], k_new))
+        return (new, *_views(new, layer))
 
     return write
+
+
+def span_attend(cfg: LlamaConfig, table_row: jax.Array, offset: jax.Array,
+                ctx_pad: int):
+    """The ``attn`` of ``models.llama.forward`` over the views
+    ``paged_prefill_write`` hands out: a chunk attends the prefix
+    it has, not the width of the pool. ``lax.switch`` on the rung
+    ``attend_rung`` picks from the traced ``offset``: branch ``c`` gathers
+    the first ``c`` positions of the table row (``_gather_context``:
+    layer and blocks in one gather, a scaled pool dequantised) and runs the
+    grouped attend under ``mask[..., :c]`` (``resume_mask`` over
+    ``ctx_pad``, sliced: a sliding window stays what it was). Every position
+    it leaves out was masked for every row of the chunk and weighed
+    ``exp(-1e30 - max) = 0``: a real row's result is the full span's up to
+    the order of a float32 sum. One program a bucket, as before: the rungs
+    are its branches. NOT cut, each as it was: the verify window
+    (``paged_verify_write``: a slot its own prefix), the contiguous cache's
+    resume (``resume_write``: the slot's whole row), the ring prefill (no
+    pool gathered) and the pipeline-parallel forward (contiguous only)."""
+    def attn(q, keys, values, mask):    # q [1, T, Hq, hd]; LayerViews
+        bucket, bt = q.shape[1], keys.cache.shape[3]
+        rungs = span_ladder(bucket, ctx_pad, bt)
+
+        def rung(c):
+            def run(q, stack, mask):
+                k, v = _gather_context(
+                    stack, keys.layer, table_row[None, :c // bt], q)
+                with jax.named_scope("attn.prefill"):
+                    return _grouped_attn(cfg, q, k, v, mask[..., :c])
+            return run
+
+        return lax.switch(attend_rung(offset + bucket, rungs),
+                          [rung(c) for c in rungs],
+                          q, _stacked(keys, values), mask)
+
+    return attn
 
 
 def paged_verify_write(tables: jax.Array, positions: jax.Array,

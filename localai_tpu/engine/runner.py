@@ -741,6 +741,9 @@ class ModelRunner:
         positions = p0[:, None] + jnp.arange(T)[None, :]     # [S, T]
         tokens = jnp.concatenate(
             [state.tokens[:, None], proposals], axis=1)      # [S, T]
+        # the window's attend spans the padded context (every slot its own
+        # prefix: no one span serves the batch; kvcache.span_attend is the
+        # single-sequence chunk's)
         mask = kvc.verify_mask(cfg, p0, T, self.ctx_pad)
         write = kvc.paged_verify_write(tables, p0, self.max_ctx)
         hidden, new_stack = self._forward(
@@ -855,6 +858,9 @@ class ModelRunner:
         positions = offset + jnp.arange(bucket, dtype=jnp.int32)[None, :]
         attn = self._se_attn(
             positions, jnp.arange(self.max_ctx, dtype=jnp.int32))
+        # the contiguous cache's resume attends the slot's whole row, as it
+        # did (the span of a chunk's attend is cut on the paged path alone:
+        # kvcache.span_attend)
         mask = kvc.resume_mask(cfg, bucket, offset, self.max_ctx)
         write = kvc.resume_write(slot, offset)
         hidden, new_stack = self._forward(
@@ -1093,9 +1099,12 @@ class ModelRunner:
         cfg = self.cfg
         positions = offset + jnp.arange(bucket, dtype=jnp.int32)[None, :]
         mask = kvc.resume_mask(cfg, bucket, offset, self.ctx_pad)
+        # the attend spans the rung of the ladder that covers offset +
+        # bucket, picked on the device: the mask is sliced to it
         write = kvc.paged_prefill_write(table_row, offset, length)
         hidden, new_stack = self._forward(
             params, tokens, positions, write, kv.stacked(), mask,
+            attn=kvc.span_attend(cfg, table_row, offset, self.ctx_pad),
             embeds=embeds,
         )
         new_kv = kvc.PagedKVCache.from_stacked(new_stack)
@@ -1119,6 +1128,14 @@ class ModelRunner:
             counts=counts,
         )
         return new_kv, new_state, tok[0]
+
+    def chunk_span(self, offset: int, bucket: int) -> int:
+        """Positions the attend of ``_prefill_paged_fn`` spans for a chunk of
+        ``bucket`` rows behind ``offset`` cached tokens: the rung the program
+        takes on the device, by the same arithmetic (the flight ring's
+        ``chunk_ctx``)."""
+        return kvc.attend_span(offset, bucket, self.ctx_pad,
+                               self.block_tokens)
 
     def _prefill_paged_mm_fn(self, params, kv, state, tokens, length,
                              table_row, slot, mm_embeds, mm_positions,
@@ -1153,6 +1170,8 @@ class ModelRunner:
         from localai_tpu.parallel import ring
 
         cfg = self.cfg
+        # the ring attends the prompt's own bucket and gathers nothing from
+        # the pool: no span to cut (the ring row's chunk_ctx is the bucket)
         hidden, (ks, vs) = ring.sp_prefill_forward(
             cfg, params, tokens, length, self.mesh, self.rope
         )
@@ -1254,7 +1273,9 @@ class ModelRunner:
         """models.llama.forward, or the pipeline-parallel stage chain
         when the mesh has a 'pipe' axis (layer-sharded capacity scaling —
         parallel.pipeline; attn overrides don't apply there: pp forces the
-        XLA attend and gates self-extend/Pallas off at init)."""
+        XLA attend and gates self-extend/Pallas off at init, and serves the
+        contiguous cache alone, so the paged chunk's span attend never
+        meets it)."""
         if self.pp_enabled:
             from localai_tpu.parallel import pipeline as pp
 
@@ -2110,7 +2131,7 @@ class PagedAdmission:
                 jnp.asarray(self.mm_positions, jnp.int32),
                 self._counts_row(), bucket=bucket,
             )
-            take, ctx = n, r.ctx_pad
+            take, ctx = n, r.chunk_span(0, bucket)
             self.pos = n
         else:
             take = min(rem, r.prefill_chunk)
@@ -2125,7 +2146,7 @@ class PagedAdmission:
                 slot, crow, bucket=bucket,
                 sample=last,
             )
-            ctx = r.ctx_pad             # resume_mask spans the padded context
+            ctx = r.chunk_span(offset, bucket)
             self.pos += take
         self.last_chunk = {"chunk_tokens": take, "chunk_bucket": bucket,
                            "chunk_offset": offset, "chunk_ctx": ctx}

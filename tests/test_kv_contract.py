@@ -202,6 +202,12 @@ def test_paged_policy_writes_only_its_rows(policy, kv_dtype):
     if policy == "decode_raw":
         _assert_views(new, keys, values)
         return
+    if policy.startswith("prefill"):
+        # the chunk's attend gathers its own span (kvcache.span_attend)
+        # from the views: here the whole table row
+        _assert_views(new, keys, values)
+        keys, values = kvc._gather_context(new, keys.layer,
+                                           jnp.asarray(tables), k_new)
     # the gathered logical context [S, H, MB*bt, hd] of the named layer
     for got, layer in zip((keys, values), _dequant_layer(new, kv_dtype)):
         want = np.asarray(layer)[tables]             # [S, MB, H, bt, hd]
@@ -296,13 +302,21 @@ def test_policy_is_shard_local_over_the_heads(layout, policy, kv_dtype):
     rng = np.random.default_rng(7)
     mesh = Mesh(np.array(jax.devices()[:H]), ("model",))
     stack = _noise_stack(rng, kv_dtype, (N,) if layout == "paged" else (S,))
-    write, k_new, v_new, *_ = (
+    write, k_new, v_new, *_, tables = (
         PAGED if layout == "paged" else CONTIGUOUS)[policy](rng)
     heads = lambda a, axis: NamedSharding(  # noqa: E731
         mesh, P(*[None] * axis, "model", *[None] * (a.ndim - axis - 1)))
+
+    def write_and_read(stack, k_new, v_new):
+        new, keys, values = write(stack, jnp.int32(LAYER), k_new, v_new)
+        if layout == "paged" and policy.startswith("prefill"):
+            # a chunk's attend gathers what it reads (kvcache.span_attend)
+            keys, values = kvc._gather_context(
+                new, keys.layer, jnp.asarray(tables), k_new)
+        return new, keys, values
+
     compiled = jax.jit(
-        lambda stack, k_new, v_new: write(
-            stack, jnp.int32(LAYER), k_new, v_new),
+        write_and_read,
         in_shardings=(tuple(heads(a, 2) for a in stack),
                       heads(k_new, 2), heads(v_new, 2)),
     ).lower(stack, k_new, v_new).compile()

@@ -419,15 +419,19 @@ def cell(request):
 
 @pytest.mark.parametrize("program", ["decode", "decode_n2",
                                      "prefill_chunk_512",
-                                     "prefill_chunk_512_sample"])
+                                     "prefill_chunk_512_sample",
+                                     "prefill_chunk_128",
+                                     "prefill_chunk_128_sample"])
 def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
                                                program):
     """The guard of PERF.md's PR 26: at the benchmark cell's shapes (32
     layers, 289 blocks x 64 tokens, 8 kv heads, head_dim 128, 16 slots, int8
     weights, the compiled paged kernel) the decode step, two steps in one
-    dispatch and a 512-token prefill chunk hold no second pool and copy no
-    layer of it. The stacked pool is the layer scan's carry, the write
-    policies scatter rows into it, the kernel reads it by layer index: one
+    dispatch and a prefill chunk of either bucket hold no second pool and
+    copy no layer of it: not with the chunk's attend a ``conditional`` inside
+    the layer scan either, a branch a span (PR 40; the pool is its operand
+    and no branch's result). The stacked pool is the layer scan's carry, the
+    write policies scatter rows into it, the kernel reads it by layer index: one
     per-layer slice anywhere (a policy's ``k[layer]``, a kernel that takes a
     4-D pool, a scatter XLA lays out unlike its reader) brings back a
     pool-sized temp and a 76 MB copy a layer, which only the compiler shows.
@@ -446,7 +450,7 @@ def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
     # reader sums every ``tpu_custom_call`` of theirs), which writes the
     # step's rows too; chunked prefill attends through XLA, holds none, and
     # writes through the policy's scatter as before
-    assert_who_writes(program, c.as_text(), pool)
+    assert_who_writes(program, c.as_text(), pool, spans(r, program))
 
 
 def assert_in_place(program, c, pool):
@@ -478,7 +482,18 @@ def assert_in_place(program, c, pool):
     assert not moved, f"{program} moves the pool or a layer of it: {moved}"
 
 
-def assert_who_writes(program, text, pool):
+def spans(r, program):
+    """The spans a chunk program's attend may take (None: a decode
+    program)."""
+    from localai_tpu.engine import kvcache as kvc
+
+    if program.startswith("decode"):
+        return None
+    return kvc.span_ladder(int(program.split("_")[2]), r.ctx_pad,
+                           r.block_tokens)
+
+
+def assert_who_writes(program, text, pool, ladder=None):
     """PR 38. A DECODE program holds no scatter into the pool: its one
     Pallas call takes the step's K and V rows as its last operands and
     hands both pools (``pool``: one device's K or V stack) back aliased to
@@ -488,12 +503,24 @@ def assert_who_writes(program, text, pool):
     A PREFILL program holds no Pallas call and its policy's scatters, fused
     and aliased onto the pool (``assert_in_place`` lets nothing else
     produce a pool-shaped result in either, so no copy stands before or
-    behind the aliased call)."""
+    behind the aliased call). PR 40: its attend is ONE ``conditional`` (in
+    the layer scan's body), a branch for every span of ``ladder``, each
+    gathering that span's positions of a chip's kv heads and no more."""
+    import re
+
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     if not program.startswith("decode"):
         assert not calls
         assert 'kv_pool.write/scatter"' in text
+        switch = [ln for ln in text.splitlines() if " conditional(" in ln]
+        assert len(switch) == 1, switch
+        branches = switch[0].split("branch_computations={")[1].split("}")[0]
+        assert len(branches.split(",")) == len(ladder) > 1
+        for span in ladder:     # K's gather and V's: span / bt whole blocks
+            blocks = f"bf16[{span // pool[3]},{','.join(map(str, pool[2:]))}]"
+            assert len(re.findall(
+                "= " + re.escape(blocks) + r"\S* gather\(", text)) == 2, span
         return
     assert len(calls) == 1 and "paged_decode_attn" in calls[0]
     assert "kv_pool.write/scatter" not in text, (
@@ -513,6 +540,7 @@ def assert_who_writes(program, text, pool):
 
 @pytest.mark.parametrize("cell", [OURO], indirect=True)
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk_128",
+                                     "prefill_chunk_512",
                                      "prefill_chunk_512_sample"])
 def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
                                                       cell, program):
@@ -538,7 +566,7 @@ def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
     c = compile_cell_program(r, a, program)
     assert_in_place(program, c, pool)
     text = c.as_text()
-    assert_who_writes(program, text, pool)
+    assert_who_writes(program, text, pool, spans(r, program))
     m = c.memory_analysis()
     # no weight stack staged: the smallest stacked leaf is [48, 2048, 2048]
     assert m.temp_size_in_bytes < cfg.num_layers * cfg.hidden_size ** 2, (
@@ -560,7 +588,9 @@ def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
     (M7B, "decode", "0"), (M7B, "decode", "auto"),
     (M7B, "prefill_chunk_512", "auto"),
     (MS24B, "decode", "auto"), (MS24B, "decode_n2", "auto"),
-    (MS24B, "prefill_chunk_512", "auto")], indirect=["cell"])
+    (MS24B, "prefill_chunk_512", "auto"),
+    (MS24B, "prefill_chunk_512_sample", "auto"),
+    (MS24B, "prefill_chunk_128", "auto")], indirect=["cell"])
 def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
         topo, monkeypatch, cell, program, overlap):
     """A configuration file's programs over a 1 x 4 mesh, the pool sharded
@@ -600,8 +630,9 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
     # one Pallas call in a decode program, the paged kernel inside its
     # shard_map, handed the step's rows of the chip's own two heads (no
     # gather of rows in front of it: the shapes are the shard's); none in a
-    # chunk, which scatters
-    assert_who_writes(program, text, shard)
+    # chunk, which scatters, and whose attend's branches (the rung is a
+    # replicated scalar) gather the chip's own two heads of their span
+    assert_who_writes(program, text, shard, spans(r, program))
 
 
 # ---------------------------------------------------------------------------
