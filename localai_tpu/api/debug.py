@@ -347,6 +347,10 @@ async def kv(request: web.Request) -> web.Response:
             ts = alloc.tier_stats()
             if ts is not None:
                 models[name]["tier"] = ts
+            # per-slot state that is not keys, held beside the pool
+            state_bytes = getattr(sm.runner, "state_bytes", 0)
+            if state_bytes:
+                models[name]["state_bytes"] = state_bytes
         return models
 
     return web.json_response(

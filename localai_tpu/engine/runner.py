@@ -64,6 +64,15 @@ SKIP = -1
 PREFILL_CHUNK = 512
 
 
+def _refusal(what: str) -> str:
+    """The one sentence that refuses ``what`` for a model with recurrent
+    state (``LlamaConfig.recurrent``)."""
+    return (f"{what} is not served for a model whose layers carry recurrent "
+            f"state (model_type qwen3_next): that state is not keys, it "
+            f"lives in one dense row a slot beside the paged K/V pool and "
+            f"cannot be re-read, split or shipped as a prefix of keys can")
+
+
 def _prompt_counts_row(vocab_size: int, prompt) -> np.ndarray:
     """[V] i32 bincount of the FULL prompt for resume-style prefills (the
     in-program count would only see the tail chunk)."""
@@ -86,9 +95,13 @@ class DecodeState:
     bias: jax.Array        # [S, V] f32 — additive logit bias (logit_bias API
                            #              + grammar/FSM masks as -1e30)
     params: smp.SamplingParams
+    # per-slot state that is not keys (models.qwen3_next.init_rec); None, and
+    # so no leaf of any program, for every other model
+    rec: Any = None
 
     @staticmethod
-    def init(num_slots: int, vocab_size: int, seed: int = 0) -> "DecodeState":
+    def init(num_slots: int, vocab_size: int, seed: int = 0,
+             rec: Any = None) -> "DecodeState":
         return DecodeState(
             tokens=jnp.zeros(num_slots, jnp.int32),
             positions=jnp.zeros(num_slots, jnp.int32),
@@ -97,6 +110,7 @@ class DecodeState:
             counts=jnp.zeros((num_slots, vocab_size), jnp.int32),
             bias=jnp.zeros((num_slots, vocab_size), jnp.float32),
             params=smp.SamplingParams.init(num_slots),
+            rec=rec,
         )
 
 
@@ -237,6 +251,19 @@ class ModelRunner:
         if self.paged and incompat:
             raise ValueError(
                 f"paged KV cache is incompatible with {incompat}")
+        # a model whose layers carry per-slot state that is not keys
+        # (models.qwen3_next): that state is one dense row a slot beside
+        # the block pool, and what takes a sequence for its keys is refused
+        self.recurrent = bool(cfg.recurrent)
+        if self.recurrent:
+            if self.pp_enabled:
+                raise ValueError(_refusal("pipeline parallelism"))
+            if mesh is not None and mesh.shape.get("seq", 1) > 1:
+                raise ValueError(_refusal("the ring prefill"))
+            if mesh is not None:
+                raise ValueError(_refusal("a device mesh"))
+            if not self.paged:
+                raise ValueError(_refusal("the contiguous K/V layout"))
         if kv_dtype == "int4" and not self.paged:
             raise ValueError(
                 "kv_dtype=int4 requires the paged KV layout (the nibble-"
@@ -492,7 +519,13 @@ class ModelRunner:
                 cfg, self.num_slots, self.max_ctx, self.kv_dtype,
                 sharding=self._kv_sharding,
             )
-        state = DecodeState.init(self.num_slots, cfg.vocab_size, self._seed)
+        rec = None
+        if self.recurrent:
+            from localai_tpu.models import qwen3_next
+
+            rec = qwen3_next.init_rec(cfg, self.num_slots)
+        state = DecodeState.init(self.num_slots, cfg.vocab_size, self._seed,
+                                 rec=rec)
         if self.mesh is not None:
             state = self._place_state(state)
         self.state = state
@@ -528,6 +561,7 @@ class ModelRunner:
                 ),
                 state.params,
             ),
+            rec=state.rec,
         )
 
     def reinit(self) -> None:
@@ -1046,6 +1080,17 @@ class ModelRunner:
 
         mask = kvc.decode_mask(cfg, pos, self.ctx_pad)
         write = kvc.paged_decode_write(tables, pos, raw=raw)
+        if self.recurrent:
+            # a slot with no stream is the identity on its state; the step's
+            # routed work rides behind the S sampled tokens, in their copy
+            hidden, new_stack, rec, routed = self._forward_rec(
+                params, state.tokens[:, None], pos[:, None], write,
+                kv.stacked(), mask, state.rec, state.active[:, None],
+                attn=attn)
+            new_state, tokens = self._decode_tail(
+                params, dataclasses.replace(state, rec=rec), hidden)
+            return (kvc.PagedKVCache.from_stacked(new_stack), new_state,
+                    jnp.concatenate([tokens, routed]))
         hidden, new_stack = self._forward(
             params, state.tokens[:, None], pos[:, None],
             write, kv.stacked(), mask, attn=attn,
@@ -1102,11 +1147,27 @@ class ModelRunner:
         # the attend spans the rung of the ladder that covers offset +
         # bucket, picked on the device: the mask is sliced to it
         write = kvc.paged_prefill_write(table_row, offset, length)
-        hidden, new_stack = self._forward(
-            params, tokens, positions, write, kv.stacked(), mask,
-            attn=kvc.span_attend(cfg, table_row, offset, self.ctx_pad),
-            embeds=embeds,
-        )
+        attn = kvc.span_attend(cfg, table_row, offset, self.ctx_pad)
+        routed = None
+        if self.recurrent:
+            # the chunk goes on from the slot's state at ``offset``: zero at
+            # 0 whatever the slot held (the arming program runs before the
+            # LAST chunk, too late to zero it), rows past ``length`` leave
+            # it be
+            hidden, new_stack, rec, routed = self._forward_rec(
+                params, tokens, positions, write, kv.stacked(), mask,
+                state.rec, (jnp.arange(bucket) < length)[None, :],
+                attn=attn, embeds=embeds, slot=slot, fresh=offset == 0)
+            # only the final chunk's token is copied to the host: the routed
+            # work of the chunks before it waits in ``routed`` for that copy
+            routed = rec["routed"] + routed
+            rec["routed"] = jnp.zeros_like(routed) if sample else routed
+            state = dataclasses.replace(state, rec=rec)
+        else:
+            hidden, new_stack = self._forward(
+                params, tokens, positions, write, kv.stacked(), mask,
+                attn=attn, embeds=embeds,
+            )
         new_kv = kvc.PagedKVCache.from_stacked(new_stack)
         if not sample:
             return new_kv, state, jnp.zeros((), jnp.int32)
@@ -1127,6 +1188,8 @@ class ModelRunner:
             keys=state.keys.at[slot].set(new_key[0]),
             counts=counts,
         )
+        if routed is not None:
+            return new_kv, new_state, jnp.concatenate([tok, routed])
         return new_kv, new_state, tok[0]
 
     def chunk_span(self, offset: int, bucket: int) -> int:
@@ -1246,9 +1309,18 @@ class ModelRunner:
         write = kvc.prefill_write(jnp.int32(0), jnp.zeros((), jnp.int32))
         attn = self._prefill_attn(length) or self._se_attn(
             positions, positions[0])
-        hidden, _ = self._forward(
-            params, tokens, positions, write, kv, mask, attn=attn,
-        )
+        if self.recurrent:
+            from localai_tpu.models import qwen3_next
+
+            hidden, *_ = self._forward_rec(
+                params, tokens, positions, write, kv, mask,
+                qwen3_next.init_rec(cfg, 1),
+                (jnp.arange(bucket) < length)[None, :], attn=attn,
+                slot=jnp.int32(0))
+        else:
+            hidden, _ = self._forward(
+                params, tokens, positions, write, kv, mask, attn=attn,
+            )
         valid = (jnp.arange(bucket) < length)[None, :, None]
         # pool in f32: a bf16 sum over thousands of positions loses the
         # precision the embeddings exist to provide
@@ -1287,6 +1359,23 @@ class ModelRunner:
             self.cfg, params, tokens, positions, write, stack, mask,
             self.rope, attn=attn, embeds=embeds,
         )
+
+    def _forward_rec(self, params, tokens, positions, write, stack, mask,
+                     rec, valid, attn=None, embeds=None, slot=None,
+                     fresh=None):
+        """models.qwen3_next.forward for a model with recurrent state: the
+        hidden states and the K/V stack as ``_forward`` returns them, then
+        the new state and the launch's routed work [experts touched,
+        token-expert pairs that landed here]."""
+        from localai_tpu.models import qwen3_next
+
+        rec = dict(rec)
+        carried = rec.pop("routed")
+        hidden, new_stack, new_rec, routed = qwen3_next.forward(
+            self.cfg, params, tokens, positions, write, stack, mask,
+            self.rope, attn=attn, embeds=embeds, rec=rec, valid=valid,
+            slot=slot, fresh=fresh)
+        return hidden, new_stack, {**new_rec, "routed": carried}, routed
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""
@@ -1564,8 +1653,11 @@ class ModelRunner:
             if slot in self.allocator.tables:  # stale loaded rows
                 self.allocator.release(slot)
             self._loaded_rows.pop(slot, None)
+            # blocks of a prefix are shared by the prompt's tokens; a model
+            # with recurrent state shares none (``reusable_prefix``)
             shared = self.allocator.allocate(
-                slot, reserve, prompt=None if mm else prompt,
+                slot, reserve,
+                prompt=None if mm or self.recurrent else prompt,
                 spec_tokens=spec_tokens)
             if shared is None:
                 return None
@@ -1601,7 +1693,7 @@ class ModelRunner:
         prompt's full blocks to the prefix pool (their contents are
         dispatched by now; token-keyed sharing is meaningless for
         multimodal prompts), mark the slot live."""
-        if not mm:
+        if not mm and not self.recurrent:
             self.allocator.register_prefix(slot, prompt)
         self._loaded_rows.pop(slot, None)
         self._active_slots.add(slot)
@@ -1618,7 +1710,9 @@ class ModelRunner:
         collapse to zero at admit time. ``valid_n`` overrides the KV
         validity frontier (disk prompt-cache hits score their own row count
         instead of the slot's current position)."""
-        if not resident or not prompt:
+        if not resident or not prompt or self.recurrent:
+            # prefix reuse, refused for recurrent state: the keys of a
+            # prefix can be read again, the state after it was not kept
             return 0
         if valid_n is None:
             valid_n = (self._loaded_rows.get(slot, 0) if self.paged
@@ -1695,6 +1789,9 @@ class ModelRunner:
         Works on both KV layouts; the paged variant writes the window
         through the block-table mirror and rolls rejected tails back
         per slot. No host sync — callers overlap the read."""
+        if self.recurrent:
+            # a rejected draft token has already moved the state it met
+            raise ValueError(_refusal("speculative decoding"))
         proposals = jnp.asarray(proposals, jnp.int32)
         if self.paged:
             self.kv, self.state, emitted = self._verify_paged(
@@ -1804,6 +1901,14 @@ class ModelRunner:
         if self.paged_attn_impl == "pallas" and not self.kv.quantized:
             return "kernel"
         return "scatter"
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of per-slot recurrent state held beside the K/V pool (0
+        for a model whose layers carry none)."""
+        if not self.recurrent:
+            return 0
+        return sum(a.nbytes for a in self.state.rec.values())
 
     @property
     def any_active(self) -> bool:
@@ -1950,6 +2055,9 @@ class ModelRunner:
         (admit() then reuses them via the resident/resume path). Returns
         False on any mismatch (dtype, shape, context) — callers fall back
         to a full prefill."""
+        if self.recurrent:
+            log.warning("%s", _refusal("the prompt cache's import"))
+            return False
         if str(arrays.get("kv_dtype")) != str(self.kv_dtype):
             return False
         want_rope = "raw" if self.ga_n > 1 else "roped"
@@ -2166,7 +2274,10 @@ class PagedAdmission:
         """The first sampled token, on the host: waits for the final chunk
         (guarded: a device that never answers would hang here silently)."""
         with self.runner.watchdog.guard("device"):
-            return int(self.first)  # jaxlint: disable=host-sync-in-hot-path
+            # a model with recurrent state sends the chunk's routed work
+            # behind the token (``_prefill_paged_fn``)
+            return int(np.asarray(  # jaxlint: disable=host-sync-in-hot-path
+                self.first).reshape(-1)[0])
 
     def step_chunk(self) -> Optional[int]:
         """Dispatch the next chunk; the first token once the admission is

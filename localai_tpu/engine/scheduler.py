@@ -397,6 +397,15 @@ class Scheduler:
         self._passes_per_forward = int(getattr(
             getattr(runner, "cfg", None), "num_passes", 1))
         self.total_loop_passes = 0
+        # a model with routed experts and recurrent state (models.qwen3_next)
+        # counts each launch's routed work on the device and sends it behind
+        # the sampled tokens (``_routed``); these are the lifetime sums, and
+        # the slots armed with a zeroed state
+        self._recurrent = bool(getattr(
+            getattr(runner, "cfg", None), "recurrent", False))
+        self.total_experts_touched = 0
+        self.total_local_assignments = 0
+        self.total_state_slots_armed = 0
         # a request the paged block pool couldn't cover yet: admission is
         # FIFO, so it parks here (not back in the queue) until blocks free
         self._held: Optional[GenHandle] = None
@@ -647,6 +656,11 @@ class Scheduler:
             "admit_blocking_reads": self.total_admit_blocking_reads,
             "admit_programs": getattr(self.runner, "admit_programs", 0),
             "loop_passes": self.total_loop_passes,
+            **({"moe_experts_touched": self.total_experts_touched,
+                "moe_assignments": self.total_local_assignments,
+                "state_slots_armed": self.total_state_slots_armed,
+                "state_bytes": self.runner.state_bytes}
+               if self._recurrent else {}),
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -740,6 +754,23 @@ class Scheduler:
         self._anat_overlap_s = 0.0
         return {"gap_ms": gap * 1e3, "sched_ms": sched * 1e3,
                 "launch_ms": launch * 1e3, "sync_ms": sync * 1e3}
+
+    def _routed(self, rows: np.ndarray,
+                held: Optional[dict] = None) -> np.ndarray:
+        """Split what a launch's copy brought: the sampled tokens, and behind
+        them (a model with routed experts; nothing otherwise) the launch's
+        [experts touched, token-expert pairs here] a step, which go to the
+        totals and, summed, to the launch's flight row."""
+        if not self._recurrent:
+            return rows
+        rows = rows.reshape(-1, rows.shape[-1])
+        touched, pairs = rows[:, -2:].sum(axis=0).tolist()
+        self.total_experts_touched += touched
+        self.total_local_assignments += pairs
+        if held is not None:
+            held["experts_touched"] = touched
+            held["local_assignments"] = pairs
+        return rows[:, :-2]
 
     def _launch(self, k: int = 0, inflight: Sequence[_Dispatch] = (),
                 ) -> dict:  # jaxlint: disable=lock-guarded-attr
@@ -1118,11 +1149,12 @@ class Scheduler:
                 raise _EngineAbandoned
             now = time.monotonic()
             sync_s = now - t_sync
+            rows = self._routed(rows, d.held)
             if d.first is not None:
                 # a final prefill chunk: the device has just finished it,
                 # so the decode step behind it is timed from here
                 self._last_drain_t = now
-                self._first_token(d.first, int(rows))
+                self._first_token(d.first, int(rows.reshape(-1)[0]))
                 return
             window = None
             if k == 0 and self.spec is not None:  # speculative window
@@ -1265,7 +1297,8 @@ class Scheduler:
                         t0 = time.monotonic()
                         with TraceAnnotation(
                                 f"sched.launch/{held['launch']}"):
-                            rows = self.runner.step()[None]
+                            rows = self._routed(self.runner.step()[None],
+                                                held)
                         dt = time.monotonic() - t0
                         # anatomy: the runner split its own wall into
                         # enqueue vs result-fetch — harvest the scratch
@@ -1288,7 +1321,9 @@ class Scheduler:
                         t0 = time.monotonic()
                         with TraceAnnotation(
                                 f"sched.launch/{held['launch']}"):
-                            rows = self.runner.step_frozen_n(freeze, steps)
+                            rows = self._routed(
+                                self.runner.step_frozen_n(freeze, steps),
+                                held)
                         dt = time.monotonic() - t0
                         self._anat_launch_s += (
                             self.runner.last_launch_ms * 1e-3)
@@ -1700,6 +1735,7 @@ class Scheduler:
             handle.admit_index = self._admit_seq
             self._admit_seq += 1
             self.total_admissions += 1
+            self.total_state_slots_armed += self._recurrent
             self.telemetry.admitted(
                 handle.trace, slot=slot,
                 queue_wait=time.monotonic() - handle.t_submit,

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, ClassVar, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,9 +60,20 @@ class LlamaConfig:
                                           # on each branch's OUTPUT
     dtype: str = "bfloat16"
 
+    # a family whose layers carry per-slot state that is not keys (a
+    # subclass: models.qwen3_next): the engine keeps that state beside the
+    # K/V pool and refuses what re-reads a prefix from keys alone
+    recurrent: ClassVar[bool] = False
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of a head that RoPE rotates: all of them, but for a
+        family with a partial rotary factor."""
+        return self.hd
 
     @property
     def cache_layers(self) -> int:
@@ -82,7 +93,13 @@ class LlamaConfig:
         mistral, mixtral (``num_local_experts``), qwen2 (qkv bias), and
         ouro, the looped decoder (``total_ut_steps`` passes over the stack,
         four norms a layer; its exit gate is not served: every pass runs
-        for every token)."""
+        for every token). ``qwen3_next`` (periods of Gated DeltaNet layers
+        and a gated full-attention layer, routed experts) is a subclass with
+        its own keys: models.qwen3_next."""
+        if hf.get("model_type") == "qwen3_next":
+            from localai_tpu.models.qwen3_next import Qwen3NextConfig
+
+            return Qwen3NextConfig.from_hf(hf)
         ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),
@@ -115,13 +132,13 @@ class LlamaConfig:
 def rope_table(cfg: LlamaConfig, max_len: int,
                freq_base: Optional[float] = None,
                freq_scale: Optional[float] = None) -> tuple[jax.Array, jax.Array]:
-    """Precompute (cos, sin) [max_len, hd/2] in float32.
+    """Precompute (cos, sin) [max_len, rotary_dim/2] in float32.
 
     Supports HF rope_scaling types 'linear', 'llama3', 'yarn' and the
     reference's raw rope_freq_base/rope_freq_scale overrides
     (/root/reference/core/config/backend_config.go:162-163).
     """
-    hd = cfg.hd
+    hd = cfg.rotary_dim
     base = freq_base or cfg.rope_theta
     inv_freq = 1.0 / (base ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     sc = cfg.rope_scaling or {}
@@ -194,6 +211,10 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 def param_shapes(cfg: LlamaConfig) -> dict:
     """Shapes of the stacked-parameter pytree."""
+    if cfg.recurrent:
+        from localai_tpu.models import qwen3_next
+
+        return qwen3_next.param_shapes(cfg)
     D, F, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     shapes = {
@@ -234,34 +255,39 @@ def param_shapes(cfg: LlamaConfig) -> dict:
     return shapes
 
 
+def _init_leaf(key, shape, name: str, dtype):
+    """One synthetic leaf: N(0, 0.02), a vector gain 1. A branch's OUTPUT
+    norm gets gain 1 too: at the 0.02 the stacked pre-norm gains are drawn
+    with, a layer (and so a whole pass) would change nothing."""
+    if len(shape) == 1 or name.endswith("post_norm"):
+        return jnp.ones(shape, dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+
+
 def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
     """Random init (testing / benchmarking with synthetic weights). Each
     leaf is its own jitted program so that, with a ``placement``
     (parallel.sharding.ParamPlacement), it is generated directly on the
     devices that will hold it — no leaf is ever whole on one chip first."""
+    leaf = _init_leaf
+    if cfg.recurrent:
+        from localai_tpu.models import qwen3_next
+
+        leaf = qwen3_next.init_leaf
     shapes = param_shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(rng, len(flat))
     dtype = jnp.dtype(cfg.dtype)
-
-    def mk(k, shape, unit=False):
-        if len(shape) == 1 or unit:  # norm gains
-            return jnp.ones(shape, dtype)
-        return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dtype)
-
     leaves = []
     for k, (kpath, shape) in zip(keys, flat):
         sh = None
         if placement is not None:
             sh = placement.shardings(
                 tuple(p.key for p in kpath), jax.ShapeDtypeStruct(shape, dtype))
-        # a branch's OUTPUT norm gets gain 1: at the 0.02 the stacked
-        # pre-norm gains are drawn with, a layer (and so a whole pass)
-        # would change nothing
-        unit = kpath[-1].key.endswith("post_norm")
         leaves.append(jax.jit(  # jaxlint: disable=jit-in-loop
-            mk, static_argnums=(1, 2), out_shardings=sh)(k, shape, unit))
+            leaf, static_argnums=(1, 2, 3), out_shardings=sh)(
+                k, shape, kpath[-1].key, dtype))
     return jax.tree.unflatten(treedef, leaves)
 
 

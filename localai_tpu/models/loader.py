@@ -9,6 +9,7 @@ tensors along a leading axis so the model can lax.scan over layers.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import logging
 from pathlib import Path
@@ -71,10 +72,10 @@ def load_llama_params(
     quantization: str = "",
     placement=None,
 ) -> tuple[LlamaConfig, Any]:
-    """Load an HF llama/mistral/qwen2/ouro checkpoint into the stacked pytree,
-    one leaf at a time: read and stack on the host, cast (or, with
-    ``quantization``, quantize — models.quant.quantize_tensor_host) on the
-    host, then hand the SERVED form to ``placement.put``
+    """Load an HF llama/mistral/qwen2/ouro/qwen3_next checkpoint into the
+    stacked pytree, one leaf at a time: read and stack on the host, cast (or,
+    with ``quantization``, quantize — models.quant.quantize_tensor_host) on
+    the host, then hand the SERVED form to ``placement.put``
     (parallel.sharding.ParamPlacement), which sends each device its shard.
     Neither the bf16 model nor an f32 copy of any leaf ever exists on a
     device, and the host holds one stacked leaf at a time. ``hf`` is the
@@ -99,7 +100,7 @@ def load_llama_params(
         if "model.language_model.embed_tokens.weight" in tensors:
             body, head = "model.language_model.", "lm_head.weight"
     if not cfg.tie_word_embeddings and head not in tensors:
-        cfg = LlamaConfig(**{**cfg.__dict__, "tie_word_embeddings": True})
+        cfg = dataclasses.replace(cfg, tie_word_embeddings=True)
     np_dtype = np.dtype(jnp.dtype(dtype))
     expected = param_shapes(cfg)
 
@@ -125,6 +126,24 @@ def load_llama_params(
             a = _get(tensors, fmt.format(i=i))
             mats.append(a.T if transpose else a)
         return np.stack(mats)
+
+    if cfg.recurrent:
+        # periods of DeltaNet and gated-attention layers with routed experts:
+        # the family's own names and regrouping (models.qwen3_next)
+        from localai_tpu.models import qwen3_next
+
+        qwen3_next.refuse_quantization(quantization)
+        layers = {name: place(("layers", name), host)
+                  for name, host in qwen3_next.checkpoint_leaves(
+                      cfg, lambda n: _get(tensors, n), body)}
+        return cfg, {
+            "embed": place(("embed",),
+                           _get(tensors, body + "embed_tokens.weight")),
+            "final_norm": place(("final_norm",),
+                                _get(tensors, body + "norm.weight")),
+            "layers": layers,
+            **({} if cfg.tie_word_embeddings else {
+                "lm_head": place(("lm_head",), _get(tensors, head).T)})}
 
     L = body + "layers.{i}."
     layer_src: dict[str, Any] = {

@@ -394,7 +394,8 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
         # and self-extend forces the unroped single-row cache.
         # Speculative decoding composes now — the draft runner shares
         # the target's mesh (localai_tpu.spec.ModelDrafter)
-        if not (app.mirror_port or eng.grp_attn_n > 1):
+        # (nor a model with recurrent state: its runner takes no mesh yet)
+        if not (app.mirror_port or eng.grp_attn_n > 1 or cfg.recurrent):
             mesh = _auto_mesh(cfg, eng.max_slots)
             if mesh is not None:
                 log.info("auto mesh for %s: %s", mcfg.name,
@@ -494,6 +495,12 @@ def build_serving_model(mcfg: ModelConfig, app: AppConfig) -> ServingModel:
             "%s: speculative decoding is not supported with self-extend "
             "(grp_attn_n>1); serving without it", mcfg.name,
         )
+    elif spec_want and getattr(runner, "recurrent", False):
+        if eng.spec:    # asked for by name; the default just stays off
+            log.warning(
+                "%s: speculative decoding is not supported for a model "
+                "with recurrent state (a rejected draft token has already "
+                "moved it); serving without it", mcfg.name)
     elif spec_want and getattr(runner, "pp_enabled", False):
         log.warning(
             "%s: speculative decoding is not supported with pipeline "
@@ -531,6 +538,11 @@ def build_serving_model(mcfg: ModelConfig, app: AppConfig) -> ServingModel:
             "%s: prompt_cache_path is not supported with multi-host command "
             "mirroring (KV loads would desync followers); ignoring", mcfg.name
         )
+    elif mcfg.prompt_cache_path and getattr(runner, "recurrent", False):
+        log.warning(
+            "%s: prompt_cache_path is not supported for a model with "
+            "recurrent state (the state after a prefix is not kept with its "
+            "keys); ignoring", mcfg.name)
     elif mcfg.prompt_cache_path:
         from pathlib import Path
 

@@ -290,7 +290,11 @@ def known_arrays(runners: Iterable[Any]) -> dict[str, list]:
     skipped."""
     kv: list = []
     weights: list = []
+    state: list = []
     for r in runners:
+        rec = getattr(getattr(r, "state", None), "rec", None)
+        if rec is not None:     # per-slot recurrent state beside the pool
+            state.extend(rec.values())
         cache = getattr(r, "kv", None)
         if cache is not None:
             try:
@@ -307,7 +311,8 @@ def known_arrays(runners: Iterable[Any]) -> dict[str, list]:
                 weights.extend(jax.tree.leaves(params))
             except Exception:  # noqa: BLE001
                 pass
-    return {"kv_cache": kv, "weights": weights}
+    return {"kv_cache": kv, "weights": weights,
+            **({"recurrent_state": state} if state else {})}
 
 
 def update_device_gauges(runners: Iterable[Any] = (),

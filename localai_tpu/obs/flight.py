@@ -46,7 +46,8 @@ from localai_tpu.obs.trace import mono_to_wall
 # what a launch held, as columns of the ring and keys of /debug/flight, in
 # the order of record()'s keywords
 WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "chunk_tokens",
-                "chunk_bucket", "chunk_offset", "chunk_ctx")
+                "chunk_bucket", "chunk_offset", "chunk_ctx",
+                "experts_touched", "local_assignments")
 
 
 def _default_capacity() -> int:
@@ -100,7 +101,8 @@ class FlightRecorder:
                sync_ms: float = 0.0, passes: int = 0, launch: int = 0,
                live_slots: int = 0, attended_tokens: int = 0,
                chunk_tokens: int = 0, chunk_bucket: int = 0,
-               chunk_offset: int = 0, chunk_ctx: int = 0) -> None:
+               chunk_offset: int = 0, chunk_ctx: int = 0,
+               experts_touched: int = 0, local_assignments: int = 0) -> None:
         """Append one dispatch record (host scalars only).
 
         ``batch_slots`` tags the record with the lane mix: how many of the
@@ -132,7 +134,16 @@ class FlightRecorder:
         holds ``chunk_tokens`` (real tokens), ``chunk_bucket`` (rows the
         program computes), ``chunk_offset`` (cached tokens in front of the
         chunk) and ``chunk_ctx`` (positions its attend spans). 0 wherever
-        a row's kind has no such count."""
+        a row's kind has no such count.
+
+        ``experts_touched`` and ``local_assignments`` are the two counts
+        NOT taken at the enqueue: a model with routed experts counts them on
+        the device and sends them behind the sampled tokens, so a decode row
+        has them at its drain: held experts with at least one token, summed
+        over the expert blocks and the steps, and token-expert pairs that
+        landed on the experts held here. A chunk's row is written at its
+        launch, before its counts exist: 0 there (the scheduler's totals
+        have them)."""
         now = time.monotonic() if ts is None else ts
         with self._lock:
             i = self._n % self.capacity
@@ -158,7 +169,7 @@ class FlightRecorder:
             self._program[i] = program
             self._work[i] = (launch, live_slots, attended_tokens,
                              chunk_tokens, chunk_bucket, chunk_offset,
-                             chunk_ctx)
+                             chunk_ctx, experts_touched, local_assignments)
             self._n += 1
             self.total_tokens += int(tokens)
 

@@ -237,6 +237,22 @@ class Registry:
             "times the model's passes a forward (a looped decoder runs its "
             "stack several times a token; every other model once)",
         )
+        self.moe_experts_touched = Counter(
+            "localai_moe_experts_touched_total",
+            "Held routed experts that had at least one token, summed over "
+            "the expert blocks of every launch (counted on the device; each "
+            "one is an expert's three matrices read)",
+        )
+        self.moe_assignments = Counter(
+            "localai_moe_assignments_total",
+            "Token-expert pairs the router sent to experts held here "
+            "(counted on the device)",
+        )
+        self.state_slots_armed = Counter(
+            "localai_state_slots_armed_total",
+            "Slots armed with a zeroed recurrent state: one an admission of "
+            "a model whose layers carry state that is not keys",
+        )
         self.prompt_cache_hits = Counter(
             "localai_prompt_cache_hits_total",
             "Disk prompt-KV cache lookups that returned a usable prefix",
@@ -714,6 +730,11 @@ def update_engine_gauges(name: str, m: dict,
         reg.admit_programs.set_total(m.get("admit_programs", 0), model=name)
     if "loop_passes" in m:
         reg.loop_passes.set_total(m["loop_passes"], model=name)
+    if "moe_experts_touched" in m:
+        reg.moe_experts_touched.set_total(
+            m["moe_experts_touched"], model=name)
+        reg.moe_assignments.set_total(m["moe_assignments"], model=name)
+        reg.state_slots_armed.set_total(m["state_slots_armed"], model=name)
     if m.get("shed_total"):
         # shed admissions are whole-request waste (no tokens were ever
         # generated); the requests_shed family stays owned by obs.slo —

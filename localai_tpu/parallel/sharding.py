@@ -63,6 +63,25 @@ def param_specs(
 ) -> dict:
     """PartitionSpec pytree matching models.llama.param_shapes (divisibility-
     sanitized against the mesh)."""
+    if cfg.recurrent:
+        # periods of DeltaNet and gated-attention layers with routed experts
+        # (models.qwen3_next): the experts over 'expert', everything else of
+        # a layer whole on every chip (data-parallel attention beside
+        # expert-parallel experts), the vocabulary over 'model'. The specs
+        # of a deployment; engine.runner takes no mesh for such a model yet
+        from localai_tpu.models import qwen3_next
+
+        shapes = shapes or qwen3_next.param_shapes(cfg)
+        expert = {n: P(None, None, "expert", None, None)
+                  for n in qwen3_next.EXPERT_LEAVES}
+        specs = {"embed": P("model", None), "final_norm": P(),
+                 "lm_head": P(None, "model"),
+                 "layers": {n: expert.get(n, P())
+                            for n in shapes["layers"]}}
+        return jax.tree.map(
+            lambda sh, sp: _sanitize(sp, sh, mesh), shapes,
+            {k: specs[k] for k in shapes},
+            is_leaf=lambda x: isinstance(x, tuple))
     tp = mesh.shape["model"]
     if cfg.num_heads % tp != 0:
         raise ValueError(

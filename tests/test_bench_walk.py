@@ -3,8 +3,11 @@ that tier-1 counts them (PERF.md section 7 (A); ``benchmark/tests/`` itself is
 not part of tier-1): the order a family's ``walk`` gives, the loop a family
 without one keeps, a row the model does not hold, ``cache_layers``, an optional
 name that is no function (``test_walk.py``); the flight ring paged forward
-(``test_contract.py``); what the committed BENCHMARK.json names; and the
-join of a slice's launches to its executions (``test_launches.py``).
+(``test_contract.py``); what the committed BENCHMARK.json names; the
+join of a slice's launches to its executions (``test_launches.py``); and the
+sparse hybrid family's file, cell and readers, with a small model of it
+served through the harness and the control that fails
+(``test_qwen3_next_family.py``).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -44,6 +47,7 @@ _walk = _load("test_walk", conftest=_conftest)
 _contract = _load("test_contract", conftest=_conftest)
 _ouro = _load("test_ouro_family", conftest=_conftest)
 _launches = _load("test_launches", conftest=_conftest)
+_qn = _load("test_qwen3_next_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -83,3 +87,19 @@ test_a_join_that_does_not_hold_voids_the_slice_and_says_why = (
     _launches.test_a_join_that_does_not_hold_voids_the_slice_and_says_why)
 test_matched_gives_a_kinds_rows_with_their_device_seconds = (
     _launches.test_matched_gives_a_kinds_rows_with_their_device_seconds)
+# PR 41's file: the sparse hybrid family's hand arithmetic, the period's
+# leaves, its cell, its readers, and a small model through the harness
+test_the_hand_arithmetic_of_the_hybrid_familys_published_keys = (
+    _qn.test_the_hand_arithmetic_of_the_published_keys)
+test_every_published_number_of_the_catalog_is_in_the_file = (
+    _qn.test_every_published_number_of_the_catalog_is_in_the_file)
+test_the_served_stack_is_a_stack_of_periods = (
+    _qn.test_the_served_stack_is_a_stack_of_periods)
+test_the_hybrid_cell_reports_what_the_issue_names = (
+    _qn.test_the_new_cell_reports_what_the_issue_names)
+test_the_new_readers_read_the_ring_and_the_scopes = (
+    _qn.test_the_new_readers_read_the_ring_and_the_scopes)
+test_a_hybrid_model_runs_by_files_alone = (
+    _qn.test_a_hybrid_model_runs_by_files_alone)
+test_the_control_fails_a_family_without_the_decay = (
+    _qn.test_the_control_fails_a_family_without_the_decay)

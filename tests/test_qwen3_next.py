@@ -1,0 +1,735 @@
+"""Qwen3-Next (``model_type: qwen3_next``) on the normal serving path:
+periods of three Gated DeltaNet layers and one gated full-attention layer,
+each followed by routed experts with a shared expert; per-slot recurrent
+state beside the paged K/V pool. CPU, tiny widths, seeded random weights
+(norm gains, ``A_log`` and ``dt_bias`` included), 2 periods, 4 of 8 experts
+held (``expert_parallel`` size 2, rank 1).
+
+The served path is the runner's own programs (``_prefill_paged_fn`` /
+``_decode_paged_fn``), driven by ``admit`` and ``step`` and tapped for the
+logits they sample from; the reference is the benchmark's plain float32
+family (benchmark/reference/qwen3_next_family.py, written from the published
+description) run as the benchmark runs it (harness/refcheck.py): the FULL
+forward over prompt + served tokens, no cache, no state carried.
+"""
+
+import dataclasses
+import hashlib
+import json
+import sys
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(ROOT / "benchmark")]
+
+from harness import refcheck, spec  # noqa: E402
+from localai_tpu.engine.runner import ModelRunner  # noqa: E402
+from localai_tpu.models import llama as mdl  # noqa: E402
+from localai_tpu.models import qwen3_next as qn  # noqa: E402
+from localai_tpu.models.llama import LlamaConfig  # noqa: E402
+from localai_tpu.models.registry import synthetic_params  # noqa: E402
+
+HF = {"model_type": "qwen3_next", "vocab_size": 384, "hidden_size": 64,
+      "num_hidden_layers": 8, "num_attention_heads": 4,
+      "num_key_value_heads": 2, "head_dim": 32, "rope_theta": 1e7,
+      "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
+      "tie_word_embeddings": False, "num_experts": 4,
+      "num_experts_per_tok": 3, "full_attention_interval": 4,
+      "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+      "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+      "linear_conv_kernel_dim": 4, "partial_rotary_factor": 0.25,
+      "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+      "norm_topk_prob": True, "expert_parallel": {"size": 2, "rank": 1}}
+PERIODS, G = 2, 3
+RNG = np.random.default_rng(41)
+PROMPT = RNG.integers(1, 380, 23).tolist()      # two chunks: 16 + 7 of 16
+SHORT = RNG.integers(1, 380, 9).tolist()        # one chunk, 7 padded rows
+STEPS = 8
+# float32 serving: what is left between the two is summation order (the
+# chunk's recurrence is the reference's, token by token)
+F32_TOL = 2e-5
+# bfloat16 serving, logits up to ~2 under weights three times their drawn
+# size: every activation is rounded to 8 bits (2^-9 of its size) some fifty
+# times in a row through 8 layers of two branches each, the conv rows are
+# kept in bfloat16 and the logits are written in bfloat16 (half an ulp at 1-2
+# is 0.004); the state S stays float32. With EVERY expert chosen (top-8 of
+# 8: the routing weights move smoothly) that reads 0.07-0.11 at the worst of
+# 9 x 384 logits and 0.013-0.019 in the mean over three seeds. With top-3 of
+# 8 a rounded router flips near-ties between experts, which the float32
+# reference does not follow: the worst logit then reads 0.07-0.37 and the
+# mean 0.014-0.064, so that case is held by its mean. (The mathematics left
+# out below is held in float32, where it moves logits 10 x F32_TOL and more.)
+BF16_TOL, BF16_MEAN_TOL = 0.25, 0.12
+
+
+@pytest.fixture(scope="module")
+def family():
+    return spec.load_family(spec.family_file(
+        {"reference": {"family": "qwen3_next_family"}},
+        "tests/test_qwen3_next.py"))
+
+
+def config(dtype="float32", **changed):
+    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
+                               dtype=dtype)
+
+
+def seeded_params(cfg, seed: int = 0):
+    """The program's seeded weights with every zero-centred gain redrawn at
+    0.3 (at the 0.02 they are drawn with, ``1 + w`` and ``w``... differ
+    all the same, but swapping two norms would change little), the gated
+    norm's plain gain at 1 + 0.3 N, and the matmul weights three times as
+    large, so that every branch weighs on the logits."""
+    params = mdl.init_params(jax.random.key(seed), cfg)
+    rng = np.random.default_rng(seed + 1)
+
+    def redraw(name, a):
+        if name in qn.ZERO_CENTRED:
+            return jnp.asarray(0.3 * rng.standard_normal(a.shape), a.dtype)
+        if name == "gdn_out_norm":
+            return jnp.asarray(1 + 0.3 * rng.standard_normal(a.shape),
+                               a.dtype)
+        if name in ("gdn_A_log", "gdn_dt_bias"):
+            return a
+        return (3.0 * a.astype(jnp.float32)).astype(a.dtype)
+
+    out = {k: redraw(k, v) for k, v in params.items() if k != "layers"}
+    out["layers"] = {k: redraw(k, v) for k, v in params["layers"].items()}
+    return out
+
+
+def tap(runner: ModelRunner) -> list:
+    """The runner's own prefill and decode programs, each also returning the
+    logits it samples from (``logits_from_hidden``'s result, taken inside
+    the same trace); the list they are appended to."""
+    seen: list = []
+
+    def wrap(fn, **jit_kw):
+        def with_logits(*a, **k):
+            inside: list = []
+            real = mdl.logits_from_hidden
+
+            def spy(cfg, params, x):
+                inside.append(real(cfg, params, x))
+                return inside[-1]
+
+            mdl.logits_from_hidden = spy
+            try:
+                out = fn(*a, **k)
+            finally:
+                mdl.logits_from_hidden = real
+            return out, (inside[0] if inside else None)
+
+        jitted = jax.jit(with_logits, **jit_kw)
+
+        def call(*a, **k):
+            out, logits = jitted(*a, **k)
+            if logits is not None:
+                seen.append(np.asarray(logits, np.float32))
+            return out
+
+        return call
+
+    runner._prefill_paged = wrap(runner._prefill_paged_fn,
+                                 static_argnames=("bucket", "sample"))
+    runner._decode_paged = wrap(runner._decode_paged_fn)
+    return seen
+
+
+def runner_for(cfg, params, **kw) -> ModelRunner:
+    kw = {"num_slots": 4, "max_ctx": 128, "paged": True,
+          "kv_block_tokens": 16, "prefill_chunk": 16,
+          "prefill_buckets": [16, 32], "attn_impl": "xla",
+          "kv_dtype": cfg.dtype, **kw}
+    return ModelRunner(cfg, params, **kw)
+
+
+def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
+                  steps: int = STEPS):
+    """Prefill then ``steps`` decode steps through pool and state: ([1 +
+    steps, V] logits, the greedy tokens)."""
+    mark = len(seen)
+    tokens = [r.admit(slot, prompt, temperature=0.0)]
+    tokens += [int(r.step()[slot]) for _ in range(steps)]
+    logits = np.stack([seen[mark][0]] + [row[slot] for row in seen[mark + 1:]])
+    return logits, tokens
+
+
+def reference_logits(family, params, hf, prompt, tokens, monkeypatch):
+    """The family's full forward over prompt + served tokens: [n, V]."""
+    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
+    seq = np.array([prompt + tokens[:-1]], np.int32)
+    return refcheck.reference_logits(params, family, hf, seq, len(tokens))[0]
+
+
+def agree(served, ref, tol):
+    assert np.abs(ref).max() > 0.2          # logits, not zeros
+    assert np.abs(served - ref).max() < tol, np.abs(served - ref).max()
+
+
+# ---------------------------------------------------------------------------
+# (i) the served path against the plain reference
+
+
+@pytest.mark.parametrize("dtype, chosen", [
+    ("float32", 3), ("bfloat16", 8), ("bfloat16", 3)])
+def test_served_logits_match_the_reference(family, monkeypatch, dtype,
+                                           chosen):
+    """A prompt over two chunks (the second with padded rows), then decode
+    steps: the logits each program samples from against the full forward."""
+    hf = {**HF, "num_experts_per_tok": chosen}
+    cfg = config(dtype, num_experts_per_tok=chosen)
+    params = seeded_params(cfg)
+    r = runner_for(cfg, params)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    assert r.admit_programs == 1 + 2            # the arming and two chunks
+    assert r.kv.k.shape[0] == PERIODS == cfg.cache_layers
+    assert r.state.rec["S"].shape == (PERIODS, G, 4, 4, 16, 16)
+    assert r.state.rec["S"].dtype == jnp.float32
+    assert r.state_bytes == sum(a.nbytes for a in r.state.rec.values())
+    ref = reference_logits(family, params, hf, PROMPT, tokens, monkeypatch)
+    if dtype == "float32":
+        agree(served, ref, F32_TOL)
+        assert (served.argmax(-1) == ref.argmax(-1)).all()
+    elif chosen == 8:
+        agree(served, ref, BF16_TOL)
+    else:
+        assert np.abs(served - ref).mean() < BF16_MEAN_TOL
+        assert np.abs(served - ref).max() < 4 * BF16_TOL
+
+
+def test_a_chunk_with_padded_rows_leaves_the_state_exact(family, monkeypatch):
+    """9 real tokens in a bucket of 16: the 7 rows past ``length`` are the
+    identity on S and on the conv rows, which hold tokens 6, 7, 8."""
+    cfg = config()
+    params = seeded_params(cfg)
+    r = runner_for(cfg, params)
+    served, tokens = served_logits(r, tap(r), 2, SHORT, steps=3)
+    agree(served, reference_logits(family, params, HF, SHORT, tokens,
+                                   monkeypatch), F32_TOL)
+    # whatever the padded rows hold, the state the chunk leaves is the same
+    # bit for bit, and it is the state of a bucket the prompt fills to the
+    # row (up to the order of a float32 sum: other shapes, other programs)
+    from localai_tpu.engine.runner import _prompt_counts_row
+
+    def state_after(junk: int, bucket: int = 16, **kw):
+        r = runner_for(cfg, params, **kw)
+        adm = r.begin_admit(2, SHORT, temperature=0.0)
+        row = np.asarray(r.allocator.table_row(2), np.int32)
+        r._arm(adm.arm_args, row)
+        chunk = np.full((1, bucket), junk, np.int32)
+        chunk[0, :9] = SHORT
+        r.kv, r.state, tok = r._prefill_paged(
+            r.params, r.kv, r.state, chunk, np.int32(9), np.int32(0), row,
+            np.int32(2), _prompt_counts_row(cfg.vocab_size, SHORT),
+            bucket=bucket, sample=True)
+        return {n: np.asarray(r.state.rec[n][:, :, 2])
+                for n in ("S", "conv")}, int(tok[0])
+
+    zeros, tok = state_after(0)
+    junk, tok_junk = state_after(377)
+    exact, _ = state_after(0, bucket=9, prefill_buckets=[9], prefill_chunk=9,
+                           kv_block_tokens=9, max_ctx=126)
+    assert tok == tok_junk == tokens[0]
+    for name in ("S", "conv"):
+        np.testing.assert_array_equal(zeros[name], junk[name])
+        np.testing.assert_allclose(zeros[name], exact[name], atol=1e-5)
+
+
+def test_a_slot_armed_again_after_release_starts_from_zero(family,
+                                                           monkeypatch):
+    cfg = config()
+    params = seeded_params(cfg)
+    r = runner_for(cfg, params)
+    seen = tap(r)
+    served_logits(r, seen, 1, PROMPT, steps=4)
+    assert float(jnp.abs(r.state.rec["S"][:, :, 1]).max()) > 0
+    r.release(1)
+    r._free_slots.remove(1)
+    served, tokens = served_logits(r, seen, 1, SHORT, steps=4)
+    agree(served, reference_logits(family, params, HF, SHORT, tokens,
+                                   monkeypatch), F32_TOL)
+
+
+def test_two_streams_of_different_length_beside_empty_slots(family,
+                                                            monkeypatch):
+    """Slots 0 and 2 hold streams of 23 and 9 prompt tokens, 1 and 3 none:
+    each stream's logits are its own reference's, and a decode step leaves
+    the empty slots' state exactly as it was (zero)."""
+    cfg = config()
+    params = seeded_params(cfg)
+    r = runner_for(cfg, params)
+    seen = tap(r)
+    first = {0: r.admit(0, PROMPT, temperature=0.0),
+             2: r.admit(2, SHORT, temperature=0.0)}
+    prefill = {0: seen[0][0], 2: seen[1][0]}
+    rows = [r.step() for _ in range(STEPS)]
+    for slot, prompt in ((0, PROMPT), (2, SHORT)):
+        tokens = [first[slot]] + [int(row[slot]) for row in rows]
+        served = np.stack([prefill[slot]] + [s[slot] for s in seen[2:]])
+        agree(served, reference_logits(family, params, HF, prompt, tokens,
+                                       monkeypatch), F32_TOL)
+    for name in ("S", "conv"):
+        assert not np.asarray(r.state.rec[name][:, :, [1, 3]]).any()
+    # the routed work rides behind the S tokens: 8 expert blocks, at most 4
+    # held experts each, two live tokens
+    touched, pairs = rows[-1][-2:]
+    assert 0 < touched <= pairs <= 2 * 8 * 3 and touched <= 8 * 4
+
+
+# ---------------------------------------------------------------------------
+# (ii) mathematics left out fails (i)'s tolerance
+
+
+def no_decay(monkeypatch):
+    def step(S, q, k, v, g, beta):
+        u = jnp.einsum("...kv,...k->...v", S, k)
+        S = S + k[..., :, None] * (beta[..., None] * (v - u))[..., None, :]
+        return S, jnp.einsum("...kv,...k->...v", S, q)
+
+    monkeypatch.setattr(qn, "gdn_step", step)
+    return {}
+
+
+def no_one_plus_in_the_norm(monkeypatch):
+    def norm(x, w, eps):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(var + eps)
+                * w.astype(jnp.float32)).astype(x.dtype)
+
+    monkeypatch.setattr(qn, "zc_norm", norm)
+    return {}
+
+
+def rope_on_every_dim(monkeypatch):
+    return {"partial_rotary_factor": 1.0}
+
+
+def no_output_gate(monkeypatch):
+    monkeypatch.setattr(qn, "output_gate", lambda attn, gate: attn)
+    return {}
+
+
+def no_silu_z(monkeypatch):
+    def norm(o, z, w, eps):
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        return o * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
+
+    monkeypatch.setattr(qn, "gated_norm", norm)
+    return {}
+
+
+def unnormalised_top_k(monkeypatch):
+    return {"norm_topk_prob": False}
+
+
+def no_shared_gate(monkeypatch):
+    from localai_tpu.models import quant as qnt
+
+    def shared(h, lp, m):
+        y = (jax.nn.silu(qnt.matmul(h, lp["shared_gate"][m]))
+             * qnt.matmul(h, lp["shared_up"][m]))
+        return qnt.matmul(y, lp["shared_down"][m]).astype(jnp.float32)
+
+    monkeypatch.setattr(qn, "shared_expert", shared)
+    return {}
+
+
+def plain_gain_on_the_gated_norm_as_one_plus(monkeypatch):
+    real = qn.gated_norm
+    monkeypatch.setattr(qn, "gated_norm",
+                        lambda o, z, w, eps: real(o, z, 1.0 + w, eps))
+    return {}
+
+
+@pytest.mark.parametrize("left_out", [
+    no_decay, no_one_plus_in_the_norm, rope_on_every_dim, no_output_gate,
+    no_silu_z, unnormalised_top_k, no_shared_gate,
+    plain_gain_on_the_gated_norm_as_one_plus])
+def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
+                                                  left_out):
+    cfg = config()
+    params = seeded_params(cfg)
+    served_cfg = config(**left_out(monkeypatch))
+    r = runner_for(served_cfg, params)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    monkeypatch.undo()
+    ref = reference_logits(family, params, HF, PROMPT, tokens, monkeypatch)
+    assert np.abs(served - ref).max() > 10 * F32_TOL
+
+
+# ---------------------------------------------------------------------------
+# (iii) the share: the ranks' routed parts and the shared expert ONCE
+
+
+def test_the_ranks_routed_parts_and_one_shared_expert_are_the_uncut_layer():
+    """The model-configs guide's share test: an expert block cut over
+    ``size`` ranks (each holds E / size experts, routes over all E) against
+    the same block whole."""
+    size, E = 4, 8
+    whole = config(num_experts=E, expert_parallel={"size": 1, "rank": 0})
+    params = seeded_params(whole, seed=3)
+    lp = jax.tree.map(lambda a: a[0], {
+        k: v for k, v in params["layers"].items()
+        if k not in qn.EXPERT_LEAVES})
+    h = jnp.asarray(RNG.standard_normal((1, 6, 64)), jnp.float32)
+    valid = jnp.ones((1, 6), bool)
+
+    def block(cfg, experts):
+        out, counts = qn._moe(cfg, h, lp, 2, experts, jnp.int32(0), valid)
+        shared = qn.shared_expert(h.reshape(-1, 64), lp, 2).reshape(h.shape)
+        return np.asarray(out), np.asarray(shared), np.asarray(counts)
+
+    experts = tuple(params["layers"][n] for n in qn.EXPERT_LEAVES)
+    uncut, shared, counts = block(whole, experts)
+    assert counts[1] == 6 * 3                       # every pair lands
+    parts, pairs = [], 0
+    for rank in range(size):
+        cut = config(num_experts=E // size,
+                     expert_parallel={"size": size, "rank": rank})
+        held = tuple(w[:, :, rank * 2:(rank + 1) * 2] for w in experts)
+        out, sh, c = block(cut, held)
+        np.testing.assert_array_equal(sh, shared)
+        parts.append(out - sh)
+        pairs += int(c[1])
+    assert pairs == 6 * 3
+    np.testing.assert_allclose(sum(parts) + shared, uncut, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# (iv) what is refused, with one sentence each
+
+
+def _mesh(**axes):
+    from localai_tpu.parallel.mesh import MeshPlan, build_mesh
+
+    n = int(np.prod(list(axes.values())))
+    return build_mesh(MeshPlan(**axes), devices=jax.devices()[:n])
+
+
+@pytest.mark.parametrize("what, kw", [
+    ("the contiguous K/V layout", {"paged": False}),
+    ("pipeline parallelism", {"paged": False, "mesh": {"pipe": 2}}),
+    ("the ring prefill", {"mesh": {"seq": 2}}),
+    ("a device mesh", {"mesh": {"model": 2}}),
+])
+def test_layouts_that_take_a_sequence_for_its_keys_are_refused(what, kw):
+    cfg = config()
+    kw = dict(kw)
+    if "mesh" in kw:
+        kw["mesh"] = _mesh(**kw["mesh"])
+    with pytest.raises(ValueError, match=f"^{what} is not served"):
+        runner_for(cfg, seeded_params(cfg), **kw)
+
+
+def test_speculation_and_prefix_reuse_are_refused():
+    cfg = config()
+    r = runner_for(cfg, seeded_params(cfg))
+    with pytest.raises(ValueError, match="^speculative decoding is not"):
+        r.verify_async(np.zeros((4, 2), np.int32))
+    # the same prompt twice: no block of the first is shared with the second
+    # (a dense model's allocator would share the 16-token block), and a
+    # resident record is no reason to skip a token
+    first = r.admit(0, PROMPT, temperature=0.0)
+    assert r.admit(1, PROMPT, temperature=0.0,
+                   resident=list(PROMPT)) == first
+    assert (r.last_prefix_reused, r.total_prefix_reused) == (0, 0)
+    assert r.allocator.shared_tokens_total == 0
+    assert r.reusable_prefix(2, list(PROMPT), list(PROMPT), valid_n=23) == 0
+    # the prompt cache's import: rows of keys without the state behind them
+    assert r.load_prefix(2, r.export_prefix(0, 16), 16) is False
+    with pytest.raises(ValueError, match="quantization"):
+        synthetic_params(cfg, "int8")
+
+
+# ---------------------------------------------------------------------------
+# (v) the scheduler: routed work in the flight ring and in /metrics
+
+
+def test_the_flight_ring_and_metrics_count_routed_work():
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.obs import metrics as obs_metrics
+    from localai_tpu.obs.flight import WORK_COLUMNS
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    assert WORK_COLUMNS[-2:] == ("experts_touched", "local_assignments")
+    cfg = config()
+    r = runner_for(cfg, seeded_params(cfg))
+    s = Scheduler(r, ByteTokenizer(), multi_step=2)
+    try:
+        h = s.generate(GenRequest(
+            prompt=ByteTokenizer().encode("state beside the pool"),
+            max_new_tokens=12, temperature=0.0, ignore_eos=True),
+            timeout=120)
+        assert h.completion_tokens == 12
+        deadline = time.monotonic() + 10.0
+        while True:     # the dispatch in flight at the reply's end drains
+            rows, m = s.flight.snapshot(), s.metrics()
+            decode = [x for x in rows if x["program"].startswith("decode")]
+            if (sum(x["steps"] for x in decode) >= 11
+                    and m["moe_experts_touched"]
+                    > sum(x["experts_touched"] for x in decode)
+                    or time.monotonic() > deadline):
+                break
+            time.sleep(0.05)
+        # one live token a step, 8 expert blocks, top-3 of 8 with 4 held
+        for x in decode:
+            assert 0 < x["experts_touched"] == x["local_assignments"]
+            assert x["local_assignments"] <= x["steps"] * 8 * 3
+        chunks = [x for x in rows if x["program"] == "prefill_chunk"]
+        assert chunks and all(x["experts_touched"] == 0 for x in chunks)
+        # the totals hold the chunks' routed work too (it came with the
+        # first token): 21 prompt tokens over two chunks
+        in_chunks = (m["moe_assignments"]
+                     - sum(x["local_assignments"] for x in decode))
+        assert 0 < in_chunks <= 21 * 8 * 3
+        assert m["state_slots_armed"] == 1
+        assert m["state_bytes"] == r.state_bytes > 0
+        obs_metrics.update_engine_gauges("qn", m)
+        text = obs_metrics.REGISTRY.render()
+        for name, key in (("moe_experts_touched", "moe_experts_touched"),
+                          ("moe_assignments", "moe_assignments"),
+                          ("state_slots_armed", "state_slots_armed")):
+            assert f'localai_{name}_total{{model="qn"}} {m[key]}' in text
+    finally:
+        s.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# (vi) the published keys; the leaves and their specs
+
+
+def test_from_hf_reads_the_published_keys_and_the_share():
+    doc = json.loads((ROOT / "benchmark/configs/qwen3-next-80b-a3b-ep8.json"
+                      ).read_text())
+    cfg = LlamaConfig.from_hf({k: v for k, v in doc.items()
+                               if k not in spec.CONFIG_KEYS})
+    assert type(cfg) is qn.Qwen3NextConfig and cfg.recurrent
+    assert (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.hd) == (
+        2048, 16, 2, 256)
+    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.linear_conv_kernel_dim) == (16, 32, 128, 128, 4)
+    assert (cfg.rotary_dim, cfg.rope_theta) == (64, 1e7)
+    assert (cfg.num_experts, cfg.router_width, cfg.num_experts_per_tok,
+            cfg.ep_size, cfg.ep_rank) == (64, 512, 10, 8, 0)
+    assert (cfg.num_layers, cfg.periods, cfg.cache_layers) == (12, 3, 3)
+    assert (cfg.moe_intermediate_size,
+            cfg.shared_expert_intermediate_size) == (512, 512)
+    assert cfg.vocab_size == 151936 // 8 and not cfg.tie_word_embeddings
+    with pytest.raises(ValueError, match="whole periods"):
+        LlamaConfig.from_hf({**HF, "num_hidden_layers": 6})
+    # model_type decides the class: the same keys without it are a dense
+    # decoder's, as they were
+    assert type(LlamaConfig.from_hf(
+        {k: v for k, v in HF.items() if k != "model_type"})) is LlamaConfig
+
+
+def test_every_leaf_has_a_spec_and_the_experts_go_over_the_expert_axis():
+    from jax.sharding import PartitionSpec as P
+
+    from localai_tpu.parallel import sharding as shd
+
+    cfg = config()
+    specs = shd.param_specs(cfg, _mesh(expert=2, model=2))
+    shapes = mdl.param_shapes(cfg)
+    assert set(specs["layers"]) == set(shapes["layers"])
+    for name in qn.EXPERT_LEAVES:
+        assert specs["layers"][name] == P(None, None, "expert", None, None)
+    assert specs["layers"]["gdn_in_qkvz"] == P()
+    assert specs["embed"] == P("model", None)
+    # every layers leaf leads with the period (what harness/refcheck.py
+    # indexes a row by)
+    assert {s[0] for s in shapes["layers"].values()} == {PERIODS}
+
+
+def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
+        tmp_path):
+    """``models/loader.py`` for the family: a checkpoint written HERE in the
+    published layout (tensor names of ``modeling_qwen3_next.py``, linear
+    weights [out, in], ``in_proj_qkvz`` / ``in_proj_ba`` grouped by key head,
+    the conv as [C, 1, K], every one of the 8 experts) loads to the served
+    leaves it was made from; a rank of two loads its 4 experts of each block
+    and the whole router."""
+    from safetensors.numpy import save_file
+
+    from localai_tpu.models.loader import load_llama_params
+
+    whole_hf = {**HF, "num_experts": 8,
+                "expert_parallel": {"size": 1, "rank": 0}}
+    whole = config(num_experts=8, expert_parallel={"size": 1, "rank": 0})
+    params = jax.tree.map(np.asarray, seeded_params(whole, seed=5))
+    lay = params["layers"]
+    Hk, rep, dk, dv = 2, 2, 16, 16
+    out = {"model.embed_tokens.weight": params["embed"],
+           "model.norm.weight": params["final_norm"],
+           "lm_head.weight": params["lm_head"].T}
+    for i in range(8):
+        p, r = divmod(i, 4)
+        pre = f"model.layers.{i}."
+        out[pre + "post_attention_layernorm.weight"] = lay["mlp_norm"][p, r]
+        out[pre + "mlp.gate.weight"] = lay["moe_gate"][p, r].T
+        out[pre + "mlp.shared_expert_gate.weight"] = (
+            lay["shared_router"][p, r][None])
+        for ours, theirs in (("gate", "gate_proj"), ("up", "up_proj"),
+                             ("down", "down_proj")):
+            out[pre + f"mlp.shared_expert.{theirs}.weight"] = (
+                lay["shared_" + ours][p, r].T)
+            for e in range(8):
+                out[pre + f"mlp.experts.{e}.{theirs}.weight"] = (
+                    lay["w_" + ours][p, r, e].T)
+        if r == 3:
+            out[pre + "input_layernorm.weight"] = lay["attn_norm"][p]
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wo", "o_proj")):
+                out[pre + f"self_attn.{theirs}.weight"] = lay[ours][p].T
+            out[pre + "self_attn.q_norm.weight"] = lay["q_norm"][p]
+            out[pre + "self_attn.k_norm.weight"] = lay["k_norm"][p]
+            continue
+        out[pre + "input_layernorm.weight"] = lay["gdn_norm"][p, r]
+        a = pre + "linear_attn."
+        # flat [q; k; v; z] columns -> rows grouped a key head: its q, its
+        # k, its two value heads' v, their z
+        w = lay["gdn_in_qkvz"][p, r].T
+        q, k, v, z = np.split(w, [Hk * dk, 2 * Hk * dk,
+                                  2 * Hk * dk + Hk * rep * dv])
+        out[a + "in_proj_qkvz.weight"] = np.concatenate([
+            np.concatenate([q[h * dk:(h + 1) * dk], k[h * dk:(h + 1) * dk],
+                            v[h * rep * dv:(h + 1) * rep * dv],
+                            z[h * rep * dv:(h + 1) * rep * dv]])
+            for h in range(Hk)])
+        b, g = np.split(lay["gdn_in_ba"][p, r].T, 2)
+        out[a + "in_proj_ba.weight"] = np.concatenate([
+            np.concatenate([b[h * rep:(h + 1) * rep],
+                            g[h * rep:(h + 1) * rep]]) for h in range(Hk)])
+        out[a + "conv1d.weight"] = lay["gdn_conv"][p, r].T[:, None, :]
+        out[a + "A_log"] = lay["gdn_A_log"][p, r]
+        out[a + "dt_bias"] = lay["gdn_dt_bias"][p, r]
+        out[a + "norm.weight"] = lay["gdn_out_norm"][p, r]
+        out[a + "out_proj.weight"] = lay["gdn_wo"][p, r].T
+    save_file({k: np.ascontiguousarray(v) for k, v in out.items()},
+              str(tmp_path / "model.safetensors"))
+    cfg, loaded = load_llama_params(tmp_path, dtype="float32", hf=whole_hf)
+    assert cfg == dataclasses.replace(whole, dtype=cfg.dtype)
+    jax.tree.map(np.testing.assert_array_equal, params,
+                 jax.tree.map(np.asarray, loaded))
+    cut, held = load_llama_params(
+        tmp_path, dtype="float32",
+        hf={**whole_hf, "num_experts": 4,
+            "expert_parallel": {"size": 2, "rank": 1}})
+    assert (cut.num_experts, cut.router_width, cut.ep_rank) == (4, 8, 1)
+    for name in qn.EXPERT_LEAVES:
+        np.testing.assert_array_equal(held["layers"][name],
+                                      lay[name][:, :, 4:])
+    np.testing.assert_array_equal(held["layers"]["moe_gate"], lay["moe_gate"])
+    with pytest.raises(ValueError, match="quantization"):
+        load_llama_params(tmp_path, hf=whole_hf, quantization="int8")
+
+
+# ---------------------------------------------------------------------------
+# (vii) with another model_type nothing new is traced
+
+
+# sha256 of the lowered text (StableHLO, no debug info) of the programs of
+# the benchmark's three older configurations at small sizes, taken from the
+# PARENT commit of PR 41 by the recipe below under this installation (jax
+# 0.9.0): the four older cells' programs are the parent's to the letter. A
+# change that means to alter them regenerates these from its own parent.
+PARENT_TEXT = {
+    "mistral-7b-v0.3-int8": {
+        "decode":
+            "baa1406ee9c01d3b963f5c61e49733862edf9d5068baeca3debafa48ce5f9d3c",
+        "decode_n":
+            "b6ee8421e7afd9d2e26275faae560e1a8c88323ea16bd7c82bbbe75e7bce5ad9",
+        "prefill":
+            "599cbba1a7e95614fb5846840dd4978bacc9c393acb4af1f8eb086e407ed6221",
+        "arm":
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
+    },
+    "mistral-small-24b-int8-tp4": {
+        "decode":
+            "503c966d1edbe23694e56d38d430812f927cf516b1916eead3d9176d6d9c80d0",
+        "decode_n":
+            "9c010d35067779369d5a77135978096bc4813a76e677003b4c3a5128eb8aaea5",
+        "prefill":
+            "cad6fb5681759581db2636b284306e0d5e6e56a716728643a4675e80570b1df5",
+        "arm":
+            "3efff5c076c6e3e16b28936ccc7fd25d87950dbbcfc6726e4694f537db26b136",
+    },
+    "ouro-2.6b-int8": {
+        "decode":
+            "987e3c17874bd5a1a2f9c0b882a826aefdb740cc4e46a27dc325ee418cc1553a",
+        "decode_n":
+            "3fc11f0849ff1edd43c8a735837d7d47d4c64b36a13762bbaa8b3115a27d0ae1",
+        "prefill":
+            "0b650eeb87911e84357dfa15991209c1044b5f79e7d3ab3ada678ada7c7414e6",
+        "arm":
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
+    },
+}
+
+
+def _older_cells_runner(name: str) -> ModelRunner:
+    from localai_tpu.parallel.mesh import MeshPlan, build_mesh
+    from localai_tpu.parallel.sharding import ParamPlacement
+
+    doc = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
+    cut = {"hidden_size": 256, "intermediate_size": 512,
+           "num_hidden_layers": 2, "num_attention_heads": 4,
+           "num_key_value_heads": 2, "head_dim": 128, "vocab_size": 512,
+           "max_position_embeddings": 512}
+    mesh = None
+    if name.endswith("tp4"):
+        cut.update(num_attention_heads=8, num_key_value_heads=4)
+        mesh = build_mesh(MeshPlan(model=4), devices=jax.devices()[:4])
+    if name.startswith("ouro"):
+        cut.update(num_key_value_heads=4)
+    cfg = dataclasses.replace(LlamaConfig.from_hf({**doc, **cut}),
+                              dtype="bfloat16")
+    params = synthetic_params(cfg, "int8", seed=0,
+                              placement=ParamPlacement(cfg, mesh))
+    return ModelRunner(cfg, params, num_slots=4, max_ctx=128, paged=True,
+                       kv_block_tokens=16, attn_impl="pallas_interpret",
+                       mesh=mesh)
+
+
+def lowered_texts(r: ModelRunner) -> dict:
+    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
+             r.block_tables[0], jnp.int32(0),
+             jnp.zeros(r.cfg.vocab_size, jnp.int32))
+    ints, floats = r.state.params.pack()
+    return {
+        "decode": jax.jit(r._decode_paged_fn).lower(
+            r.params, r.kv, r.state, r.block_tables).as_text(),
+        "decode_n": jax.jit(
+            r._decode_paged_n_fn, static_argnames=("n",)).lower(
+                r.params, r.kv, r.state, r.block_tables, n=4).as_text(),
+        "prefill": jax.jit(
+            r._prefill_paged_fn, static_argnames=("bucket", "sample")).lower(
+                r.params, r.kv, r.state, *chunk, bucket=32,
+                sample=True).as_text(),
+        "arm": jax.jit(r._arm_slot_fn).lower(
+            r.state, r.block_tables,
+            np.concatenate([np.array([0, 0, 0], np.int32), ints]), floats,
+            jnp.zeros(r.cfg.vocab_size, jnp.float32),
+            r.block_tables[0]).as_text()}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TEXT))
+def test_the_older_cells_programs_lower_to_the_parents_text(name):
+    r = _older_cells_runner(name)
+    assert r.recurrent is False and r.state.rec is None
+    now = {k: hashlib.sha256(t.encode()).hexdigest()
+           for k, t in lowered_texts(r).items()}
+    assert now == PARENT_TEXT[name]
+    named = jax.jit(r._decode_paged_fn).lower(
+        r.params, r.kv, r.state, r.block_tables).as_text(debug_info=True)
+    for scope in ("gdn/", "moe/", "attn_gate"):
+        assert scope not in named

@@ -246,8 +246,10 @@ def test_qmatmul_lm_head_compiles(topo):
 # the smoke's whole programs, and their HBM
 
 
-def abstract_runner(topo, monkeypatch, cfg, tp=1, **runner_kw):
-    """A paged ModelRunner over abstract int8 weights and an eval_shape'd
+def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
+                    **runner_kw):
+    """A paged ModelRunner over abstract int8 weights (or as ``quantization``
+    says: "" is the compute dtype's) and an eval_shape'd
     pool (nothing of model size on the host), its paged kernel resolved to
     the compiled one as the TPU selector would. With ``tp`` > 1 it is built
     over a 1 x tp mesh ('model' = tp): of CPU devices while it places its
@@ -290,7 +292,7 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, **runner_kw):
             leaf, where)
 
     params = jax.tree_util.tree_map_with_path(
-        placed, jax.eval_shape(lambda: synthetic_params(cfg, "int8")),
+        placed, jax.eval_shape(lambda: synthetic_params(cfg, quantization)),
         is_leaf=lambda x: isinstance(x, QuantizedTensor))
     real_init = kvc.init_paged_cache
     monkeypatch.setattr(
@@ -398,6 +400,7 @@ def test_smoke_programs_fit_one_chip(topo, monkeypatch):
 
 M7B, MS24B = "mistral-7b-v0.3-int8", "mistral-small-24b-int8-tp4"
 OURO = "ouro-2.6b-int8"
+QN80 = "qwen3-next-80b-a3b-ep8"
 
 
 @pytest.fixture
@@ -582,6 +585,69 @@ def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
           f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
           f"{need / 2**30:.3f} GiB")
     assert need < HBM_BYTES
+
+
+@pytest.mark.parametrize("cell", [QN80], indirect=True)
+@pytest.mark.parametrize("program", ["decode", "decode_n2",
+                                     "prefill_chunk_128",
+                                     "prefill_chunk_512_sample"])
+def test_hybrid_cell_programs_fit_one_chip(topo, monkeypatch, cell, program):
+    """PR 41: the configuration FILE of the sparse hybrid decoder (three
+    periods of 3 DeltaNet layers + 1 gated full-attention layer, 64 held
+    experts a block, bfloat16 weights, 32 slots of float32 state beside a
+    3 x 641-block pool of head_dim 256) compiles for one v5e chip and fits
+    it, with the numbers its ``hbm`` block restates. The pool and the
+    per-slot state are the period scan's carry, written in place, and the
+    weights are read in place: no second pool, no second state (590 MiB), no
+    period of experts (1.5 GiB) and no period of DeltaNet projections (144
+    MiB) staged: temps under 72 MiB. A decode program holds exactly ONE
+    Pallas call, the paged kernel
+    (benchmark/layers/paged_decode_attn_roofline.py sums every
+    ``tpu_custom_call`` of a slice): the DeltaNet step, the conv and the
+    experts are XLA."""
+    cfg, doc = cell
+    eng = doc["engine"]
+    assert cfg.recurrent and not eng.get("quantization")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="",
+        num_slots=eng["max_slots"], max_ctx=doc["context_size"],
+        kv_num_blocks=eng["kv_num_blocks"], kv_block_tokens=64)
+    pool = a["kv"].k.shape
+    assert pool == (3, 641, 2, 64, 256) and a["kv"].k.dtype == bf16
+    state = a["state"].rec["S"]
+    assert state.shape == (3, 3, 32, 32, 128, 128) and state.dtype == f32
+    c = compile_cell_program(r, a, program)
+    text = c.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    if program.startswith("decode"):
+        assert len(calls) == 1 and "paged_decode_attn" in calls[0]
+        # nothing stages a period's DeltaNet layers of state (192 MiB: an
+        # index by the period in front of the layer's did), nor a period's
+        # DeltaNet projections (144 MiB: the scan's slice of a [P, G, ...]
+        # leaf did, copied whole before a layer's was taken)
+        assert "f32[3,32,32,128,128]" not in text
+        assert "bf16[3,2048,12288]" not in text
+    else:
+        assert not calls
+    m = c.memory_analysis()
+    state_bytes = int(np.prod(state.shape)) * 4
+    assert m.temp_size_in_bytes < state_bytes / 8, (
+        f"{program}: temp {m.temp_size_in_bytes / 2**20:.0f} MiB holds a "
+        f"second state, a period's experts or a period's projections")
+    need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    print(f"HBM {program}: arguments {m.argument_size_in_bytes / 2**30:.3f} "
+          f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
+          f"{need / 2**30:.3f} GiB")
+    hbm = doc["hbm"]
+    # (a chunk that samples nothing takes no head: 0.07 GiB fewer)
+    assert (hbm["arguments_gib"] - 0.08 < m.argument_size_in_bytes / 2**30
+            <= hbm["arguments_gib"] + 0.005)
+    assert need / 2**30 <= hbm["largest_program_gib"] + 0.001
+    # over the floor a new cell is held to: a quarter of the chip
+    assert 0.25 * HBM_BYTES < need < HBM_BYTES
 
 
 @pytest.mark.parametrize("cell, program, overlap", [
