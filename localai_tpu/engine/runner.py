@@ -264,6 +264,13 @@ class ModelRunner:
                 raise ValueError(_refusal("a device mesh"))
             if not self.paged:
                 raise ValueError(_refusal("the contiguous K/V layout"))
+            # its routed experts: ops.moe's kernel where attention's are
+            # kernels (the value: in the Pallas interpreter), None for the
+            # XLA loop where ``attn_impl`` says xla
+            impl, interpret = ops.select_moe_impl(
+                attn_impl, hidden=cfg.hidden_size,
+                intermediate=cfg.moe_intermediate_size)
+            self.experts_kernel = interpret if impl == "pallas" else None
         if kv_dtype == "int4" and not self.paged:
             raise ValueError(
                 "kv_dtype=int4 requires the paged KV layout (the nibble-"
@@ -1374,7 +1381,7 @@ class ModelRunner:
         hidden, new_stack, new_rec, routed = qwen3_next.forward(
             self.cfg, params, tokens, positions, write, stack, mask,
             self.rope, attn=attn, embeds=embeds, rec=rec, valid=valid,
-            slot=slot, fresh=fresh)
+            slot=slot, fresh=fresh, experts_kernel=self.experts_kernel)
         return hidden, new_stack, {**new_rec, "routed": carried}, routed
 
     def _prefill_attn(self, length):

@@ -22,15 +22,17 @@ What the serving engine holds of it (engine.runner):
   * the expert block is TOLD which experts it holds (``ep_size``,
     ``ep_rank``: a deployment's expert parallelism): the router scores all
     ``num_experts x ep_size`` experts, the ``num_experts`` held here compute
-    their part for the tokens routed to them, in a loop over the experts that
+    their part for the tokens routed to them, in a walk over the experts that
     HAVE a token (a step reads the weights of the experts it touched and no
-    others), and the shared expert is added. On one chip the layer runs
+    others: ops.moe's grouped kernel where attention's are kernels, a
+    ``fori_loop`` as XLA), and the shared expert is added. On one chip the layer runs
     without its exchange: the sum is this chip's partial result.
 
 The plain reference is benchmark/reference/qwen3_next_family.py, and
-tests/test_qwen3_next.py holds this file to it. No Pallas kernel: the
-DeltaNet step, the conv and the experts are XLA under named scopes
-(``gdn/*``, ``moe/*``, ``attn_gate``).
+tests/test_qwen3_next.py holds this file to it. The DeltaNet step, the conv,
+the router and the shared expert are XLA under named scopes (``gdn/*``,
+``moe/*``, ``attn_gate``); the routed experts' kernel runs under
+``moe/experts``, as their loop does.
 """
 
 from __future__ import annotations
@@ -523,11 +525,14 @@ def shared_expert(h, lp, m_idx: int):
     return gate * y.astype(jnp.float32)
 
 
-def _moe(cfg: Qwen3NextConfig, h, lp, m_idx: int, experts, p, valid):
+def _moe(cfg: Qwen3NextConfig, h, lp, m_idx: int, experts, p, valid,
+         experts_kernel: Optional[bool] = None):
     """Expert block m of period p on normed h [B, T, D]: this chip's part of
     the routed sum plus the shared expert. ``experts``: the three stacked
     expert leaves WHOLE ([P, M, E, ...]), indexed here by (p, m, expert) so
     that a step reads the experts it touched and nothing else of them.
+    ``experts_kernel``: None is the XLA loop over the touched experts, else
+    ops.moe's grouped kernel (the value: in the Pallas interpreter).
     Returns (out, [experts touched, token-expert pairs here] i32)."""
     shape = h.shape
     h = h.reshape(-1, shape[-1])
@@ -539,25 +544,38 @@ def _moe(cfg: Qwen3NextConfig, h, lp, m_idx: int, experts, p, valid):
         # the experts that have a token first, in their own order
         order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
     with jax.named_scope("experts"):
-        w_gate, w_up, w_down = experts
+        if experts_kernel is not None:
+            from localai_tpu.ops import moe
 
-        def pick(w, e):
-            return lax.dynamic_slice(
-                w, (p, m_idx, e, 0, 0), (1, 1, 1) + w.shape[3:])[0, 0, 0]
-
-        def one_expert(i, acc):
-            e = order[i]
-            y = (jax.nn.silu(qnt.matmul(h, pick(w_gate, e)))
-                 * qnt.matmul(h, pick(w_up, e)))
-            y = qnt.matmul(y, pick(w_down, e))
-            col = lax.dynamic_index_in_dim(weights, e, 1, keepdims=True)
-            return acc + col * y.astype(jnp.float32)
-
-        routed = lax.fori_loop(0, n_touched, one_expert,
-                               jnp.zeros(h.shape, jnp.float32))
+            routed = moe.moe_experts(h, weights, order, n_touched, experts,
+                                     p, m_idx, interpret=experts_kernel)
+        else:
+            routed = _experts_loop(h, weights, order, n_touched, experts,
+                                   p, m_idx)
     with jax.named_scope("shared"):
         out = (routed + shared_expert(h, lp, m_idx)).astype(h.dtype)
     return out.reshape(shape), jnp.stack([n_touched, jnp.sum(load)])
+
+
+def _experts_loop(h, weights, order, n_touched, experts, p, m_idx):
+    """ops.moe.moe_experts as XLA, and its oracle: a loop over the touched
+    experts, three dots behind a scalar-indexed slice an iteration."""
+    w_gate, w_up, w_down = experts
+
+    def pick(w, e):
+        return lax.dynamic_slice(
+            w, (p, m_idx, e, 0, 0), (1, 1, 1) + w.shape[3:])[0, 0, 0]
+
+    def one_expert(i, acc):
+        e = order[i]
+        y = (jax.nn.silu(qnt.matmul(h, pick(w_gate, e)))
+             * qnt.matmul(h, pick(w_up, e)))
+        y = qnt.matmul(y, pick(w_down, e))
+        col = lax.dynamic_index_in_dim(weights, e, 1, keepdims=True)
+        return acc + col * y.astype(jnp.float32)
+
+    return lax.fori_loop(0, n_touched, one_expert,
+                         jnp.zeros(h.shape, jnp.float32))
 
 
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
@@ -585,6 +603,7 @@ def forward(
     fresh: Any = None,      # with ``slot``: the chunk starts the sequence
                             # (offset 0), so the state it starts from is zero
                             # whatever the slot held
+    experts_kernel: Optional[bool] = None,  # ``_moe``'s
 ) -> tuple[jax.Array, Any, dict, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
@@ -608,7 +627,7 @@ def forward(
     layers = params["layers"]
     # the expert stacks stay OUT of the scanned operands: a scanned slice of
     # them would be a period's experts (1.6 GB at the published widths)
-    # staged for the loop over the touched ones
+    # staged for the walk over the touched ones
     experts = tuple(layers[n] for n in EXPERT_LEAVES)
     # nor do the DeltaNet's large projections: the scan's slice of a
     # [P, G, ...] leaf is the period's G layers, copied whole (0.45 GB a step
@@ -627,7 +646,8 @@ def forward(
         def moe(x, m_idx, counts):
             with jax.named_scope("moe"):
                 h = zc_norm(x, lp["mlp_norm"][m_idx], eps)
-                out, c = _moe(cfg, h, lp, m_idx, experts, p, valid)
+                out, c = _moe(cfg, h, lp, m_idx, experts, p, valid,
+                              experts_kernel)
             return x + out, counts + c
 
         for g_idx in range(G):

@@ -16,6 +16,10 @@ an error that names "pallas_interpret". Every combination a selector
 answers "pallas" for is compiled for v5e in tests/test_tpu_compile.py.
 
 The one input is the runner's ``attn_impl=`` (YAML ``engine.attn_impl``).
+The routed experts of a model that has them as a loop over the touched ones
+(models.qwen3_next) go the same way on the same input: ops.moe's grouped
+kernel where attention's are kernels, the XLA loop under ``xla``
+(``select_moe_impl``).
 """
 
 from __future__ import annotations
@@ -36,6 +40,7 @@ __all__ = [
     "prefill_attention",
     "resolve_attn_impl",
     "select_attn_impl",
+    "select_moe_impl",
     "select_paged_attn_impl",
 ]
 
@@ -143,4 +148,23 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
                 f"{head_dim // 2}-lane rows, which Mosaic cannot DMA and "
                 f"which HBM tiling pads back to 128 lanes (no saving over "
                 f"int8); use kv_dtype: int8, or {_OVERRIDE}")
+    return impl, interpret
+
+
+def select_moe_impl(requested: str, *, hidden: int, intermediate: int,
+                    backend: str | None = None) -> tuple[str, bool]:
+    """The routed experts' path (ops.moe.moe_experts or the XLA loop of
+    models.qwen3_next._moe), decided as attention's is and from the same
+    request. Returns (impl, interpret); raises ValueError when the resolved
+    impl is the compiled kernel and the widths cannot take it: an expert's
+    matrices are its blocks, [hidden, intermediate] and back, and Mosaic
+    tiles both dims by 128 lanes."""
+    impl, interpret = resolve_attn_impl(requested, backend)
+    if impl == "pallas" and not interpret and (hidden % 128
+                                               or intermediate % 128):
+        raise ValueError(
+            f"the grouped expert kernel needs hidden and expert widths "
+            f"128-aligned (hidden={hidden} intermediate={intermediate}); "
+            f"set engine.attn_impl: xla to serve the experts as the XLA "
+            f"loop instead")
     return impl, interpret

@@ -1,0 +1,119 @@
+"""The routed experts of one expert block as ONE Pallas call: a grouped
+matmul whose grid walks the experts that HAVE a token, in ``order``.
+
+models.qwen3_next._moe's XLA path is a ``lax.fori_loop`` over the touched
+experts, three small dots an iteration behind a scalar-indexed slice: nothing
+fetches expert i + 1 while expert i is computed. Here the three expert
+leaves go in WHOLE ([P, M, E, D, F] / [P, M, E, F, D], where they lie in HBM)
+and the index maps pick block (p, m, order[i]) from scalar-prefetched
+indices, so the pipeline copies expert i + 1's ``w_gate``, ``w_up`` and
+``w_down`` into VMEM while expert i's three dots run. The grid's bound is
+``n_touched`` itself, known on the device only: no step reads an expert no
+token chose. (A block no real row reached walks ONE step, which computes
+nothing: its copy of ``order[0]`` is the only read of an expert nobody chose.)
+
+The form is the loop's: every row against every touched expert, the routing
+weight 0 where a token did not choose it (bytes-bound at a decode step's and a
+chunk's row counts), the experts summed in the same order into a float32
+[N, D] block that stays in VMEM for the whole walk. The operands are the
+leaves' dtype and every product accumulates in float32; ``silu(gate) * up`` is
+formed in float32 and rounded once, for the third dot (the loop rounds each
+dot's result).
+
+Runs under ``interpret=True`` on the CPU (tests/test_moe_kernel.py) and is
+compiled for v5e at the served widths in tests/test_tpu_compile.py.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# rows a grid step holds: at 256 rows an expert's three dots take about as
+# long as its 6.3 MB take to arrive (v5e, D 2048, F 512), so more rows a
+# tile would buy nothing, and more rows than this are an outer grid axis
+ROW_TILE = 256
+# an expert's three matrices, double-buffered, are 12.6 MB at the published
+# widths: with the row blocks over the 16 MB Mosaic allows a kernel unasked
+# (a v5e core has 128). F in tiles would fit without asking and measured the
+# same (PERF.md section 6, PR 42): whole experts are one grid axis fewer and
+# contiguous copies. 32 MiB serve the kernel as well; what is asked for is
+# also what XLA keeps free of its own arrays round the call, and at 64 a
+# 512-row chunk's temps read lowest
+VMEM_LIMIT = 64 * 2**20
+
+
+def _kernel(order_ref, meta_ref, h_ref, wts_ref, wg_ref, wu_ref, wd_ref,
+            o_ref):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < meta_ref[0])
+    def _():
+        h = h_ref[...]
+        gate = jnp.dot(h, wg_ref[...], preferred_element_type=jnp.float32)
+        up = jnp.dot(h, wu_ref[...], preferred_element_type=jnp.float32)
+        y = (jax.nn.silu(gate) * up).astype(h.dtype)
+        y = jnp.dot(y, wd_ref[...], preferred_element_type=jnp.float32)
+        # the expert's column of the routing weights: one lane of [rows, E]
+        wts = wts_ref[...]
+        lane = lax.broadcasted_iota(jnp.int32, wts.shape, 1)
+        col = jnp.sum(jnp.where(lane == order_ref[i], wts, 0.0), axis=1,
+                      keepdims=True)
+        o_ref[...] += col * y
+
+
+def moe_experts(h, weights, order, n_touched, experts, p, m_idx, *,
+                interpret: bool = False):
+    """sum over the first ``n_touched`` experts e of ``order`` of
+    ``weights[:, e] * (silu(h @ w_gate[p, m_idx, e]) * (h @ w_up[p, m_idx,
+    e])) @ w_down[p, m_idx, e]``: [N, D] float32.
+
+    h [N, D]; weights [N, E] float32; order [E] i32; ``experts`` the three
+    leaves whole, ``w_gate`` and ``w_up`` [P, M, E, D, F], ``w_down`` [P, M,
+    E, F, D]; ``p`` and ``m_idx`` scalars (traced or not)."""
+    w_gate, w_up, w_down = experts
+    N, D = h.shape
+    E, F = w_gate.shape[2], w_gate.shape[4]
+    tile = min(N, ROW_TILE)
+    pad = -N % tile
+    if pad:
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, pad), (0, 0)))
+    meta = jnp.stack([jnp.asarray(v, jnp.int32)
+                      for v in (n_touched, p, m_idx)])
+
+    def expert(r, i, order, meta):
+        return meta[1], meta[2], order[i], 0, 0
+
+    def rows(r, i, order, meta):
+        return r, 0
+
+    out = pl.pallas_call(
+        _kernel,
+        name="moe_experts",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=((N + pad) // tile, jnp.maximum(n_touched, 1)),
+            in_specs=[
+                pl.BlockSpec((tile, D), rows),
+                pl.BlockSpec((tile, E), rows),
+                pl.BlockSpec((None, None, None, D, F), expert),
+                pl.BlockSpec((None, None, None, D, F), expert),
+                pl.BlockSpec((None, None, None, F, D), expert),
+            ],
+            out_specs=pl.BlockSpec((tile, D), rows),
+        ),
+        out_shape=jax.ShapeDtypeStruct((N + pad, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(order, meta, h, weights, w_gate, w_up, w_down)
+    return out[:N] if pad else out

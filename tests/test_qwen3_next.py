@@ -142,12 +142,23 @@ def tap(runner: ModelRunner) -> list:
     return seen
 
 
-def runner_for(cfg, params, **kw) -> ModelRunner:
+EXPERTS = ("loop", "kernel")
+
+
+def runner_for(cfg, params, experts="loop", **kw) -> ModelRunner:
+    """``experts``: the routed experts as the XLA loop, or as ops.moe's
+    kernel in the Pallas interpreter under the same XLA attention (steered
+    here, before the first program is traced: ``attn_impl`` would take
+    attention to its kernels too)."""
     kw = {"num_slots": 4, "max_ctx": 128, "paged": True,
           "kv_block_tokens": 16, "prefill_chunk": 16,
           "prefill_buckets": [16, 32], "attn_impl": "xla",
           "kv_dtype": cfg.dtype, **kw}
-    return ModelRunner(cfg, params, **kw)
+    r = ModelRunner(cfg, params, **kw)
+    assert r.experts_kernel is None
+    if experts == "kernel":
+        r.experts_kernel = True
+    return r
 
 
 def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
@@ -177,16 +188,17 @@ def agree(served, ref, tol):
 # (i) the served path against the plain reference
 
 
+@pytest.mark.parametrize("experts", EXPERTS)
 @pytest.mark.parametrize("dtype, chosen", [
     ("float32", 3), ("bfloat16", 8), ("bfloat16", 3)])
 def test_served_logits_match_the_reference(family, monkeypatch, dtype,
-                                           chosen):
+                                           chosen, experts):
     """A prompt over two chunks (the second with padded rows), then decode
     steps: the logits each program samples from against the full forward."""
     hf = {**HF, "num_experts_per_tok": chosen}
     cfg = config(dtype, num_experts_per_tok=chosen)
     params = seeded_params(cfg)
-    r = runner_for(cfg, params)
+    r = runner_for(cfg, params, experts)
     served, tokens = served_logits(r, tap(r), 1, PROMPT)
     assert r.admit_programs == 1 + 2            # the arming and two chunks
     assert r.kv.k.shape[0] == PERIODS == cfg.cache_layers
@@ -353,12 +365,13 @@ def plain_gain_on_the_gated_norm_as_one_plus(monkeypatch):
     no_decay, no_one_plus_in_the_norm, rope_on_every_dim, no_output_gate,
     no_silu_z, unnormalised_top_k, no_shared_gate,
     plain_gain_on_the_gated_norm_as_one_plus])
+@pytest.mark.parametrize("experts", EXPERTS)
 def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
-                                                  left_out):
+                                                  left_out, experts):
     cfg = config()
     params = seeded_params(cfg)
     served_cfg = config(**left_out(monkeypatch))
-    r = runner_for(served_cfg, params)
+    r = runner_for(served_cfg, params, experts)
     served, tokens = served_logits(r, tap(r), 1, PROMPT)
     monkeypatch.undo()
     ref = reference_logits(family, params, HF, PROMPT, tokens, monkeypatch)
