@@ -522,17 +522,83 @@ def span_attend(cfg: LlamaConfig, table_row: jax.Array, offset: jax.Array,
     def attn(q, keys, values, mask):    # q [1, T, Hq, hd]; LayerViews
         bucket, bt = q.shape[1], keys.cache.shape[3]
         rungs = span_ladder(bucket, ctx_pad, bt)
-
-        def rung(c):
-            def run(q, stack, mask):
-                k, v = _gather_context(
-                    stack, keys.layer, table_row[None, :c // bt], q)
-                with jax.named_scope("attn.prefill"):
-                    return _grouped_attn(cfg, q, k, v, mask[..., :c])
-            return run
-
         return lax.switch(attend_rung(offset + bucket, rungs),
-                          [rung(c) for c in rungs],
+                          [_rung(cfg, table_row, keys.layer, c // bt,
+                                 "attn.prefill") for c in rungs],
+                          q, _stacked(keys, values), mask)
+
+    return attn
+
+
+def _rung(cfg, table_row, layer, blocks: int, scope: str):
+    """One branch of a chunk's attend: the first ``blocks`` entries of the
+    table row gathered, the grouped attend under the mask sliced to them."""
+    def run(q, stack, mask):
+        k, v = _gather_context(stack, layer, table_row[None, :blocks], q)
+        with jax.named_scope(scope):
+            return _grouped_attn(cfg, q, k, v,
+                                 mask[..., :blocks * stack[0].shape[3]])
+    return run
+
+
+@dataclasses.dataclass(frozen=True)
+class KindView:
+    """What the masks and attends here read of a config, for ONE kind of
+    attention layer of a stack that has several (``LlamaConfig.attn_kinds``:
+    the runner builds a mask and an attend a kind): the head size, and the
+    kind's window (None: it sees every key)."""
+
+    hd: int
+    sliding_window: Optional[int]
+
+
+def window_span(window: int, bucket: int, block_tokens: int) -> int:
+    """Positions a ``bucket``-token chunk of a WINDOW layer gathers once its
+    prefix is longer than the window: ``window + bucket`` in whole blocks,
+    and one block more for a start inside a block."""
+    return (-(-(window + bucket) // block_tokens) + 1) * block_tokens
+
+
+def window_attend(view: KindView, table_row: jax.Array, offset: jax.Array,
+                  ctx_pad: int):
+    """``span_attend`` for a layer whose queries see ``view.sliding_window``
+    keys: the chunk attends ITS WINDOW of the prefix, not the prefix. One
+    more branch shape beside the ladder's: the rungs shorter than
+    ``window_span`` serve a short prefix as they do everywhere (the mask,
+    sliced, holds the window), and past them ONE branch gathers
+    ``window_span`` positions from table entry ``(offset - window + 1) //
+    bt`` on (a dynamic slice of the table row, a static width; clamped to the
+    row) under the window's mask over the positions it gathered. A key it
+    leaves out lies outside every row's window. Where the window spans the
+    context (``window_span >= ctx_pad``) this is ``span_attend``."""
+    window = view.sliding_window
+
+    def attn(q, keys, values, mask):    # q [1, T, Hq, hd]; LayerViews
+        bucket, bt = q.shape[1], keys.cache.shape[3]
+        span = window_span(window, bucket, bt)
+        if span >= ctx_pad:
+            return span_attend(view, table_row, offset, ctx_pad)(
+                q, keys, values, mask)
+        rungs = tuple(c for c in span_ladder(bucket, ctx_pad, bt)
+                      if c < span)
+
+        def in_window(q, stack, _mask):
+            nb = span // bt
+            first = jnp.clip((offset - window + 1) // bt, 0,
+                             table_row.shape[0] - nb)
+            k, v = _gather_context(
+                stack, keys.layer,
+                lax.dynamic_slice(table_row, (first,), (nb,))[None], q)
+            kpos = first * bt + jnp.arange(span)[None, None, :]
+            qpos = offset + jnp.arange(bucket)[None, :, None]
+            with jax.named_scope("attn.prefill_window"):
+                return _grouped_attn(
+                    view, q, k, v, (kpos <= qpos) & (kpos > qpos - window))
+
+        return lax.switch(attend_rung(offset + bucket, (*rungs, span)),
+                          [*(_rung(view, table_row, keys.layer, c // bt,
+                                   "attn.prefill_window") for c in rungs),
+                           in_window],
                           q, _stacked(keys, values), mask)
 
     return attn
@@ -716,7 +782,8 @@ def resume_mask(cfg: LlamaConfig, seq_len: int,
 
 def decode_mask(cfg: LlamaConfig, positions: jax.Array, max_ctx: int) -> jax.Array:
     """[S, 1, C] attention mask for decode: attend to all written positions
-    (≤ current), optionally sliding-window limited (Mistral-style)."""
+    (≤ current), optionally sliding-window limited (``cfg``: the model's
+    config, or a ``KindView`` of one kind of its layers)."""
     idx = jnp.arange(max_ctx)[None, None, :]
     pos = positions[:, None, None]
     m = idx <= pos

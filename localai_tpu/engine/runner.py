@@ -73,6 +73,13 @@ def _refusal(what: str) -> str:
             f"cannot be re-read, split or shipped as a prefix of keys can")
 
 
+def _kinds_refusal(cfg: LlamaConfig, what: str) -> str:
+    """The one sentence that refuses ``what`` for a stack with more than one
+    kind of attention layer (``LlamaConfig.attn_kinds``): its family's own
+    (the family module's ``refusal``)."""
+    return mdl.family_module(cfg).refusal(what)
+
+
 def _prompt_counts_row(vocab_size: int, prompt) -> np.ndarray:
     """[V] i32 bincount of the FULL prompt for resume-style prefills (the
     in-program count would only see the tail chunk)."""
@@ -95,8 +102,9 @@ class DecodeState:
     bias: jax.Array        # [S, V] f32 — additive logit bias (logit_bias API
                            #              + grammar/FSM masks as -1e30)
     params: smp.SamplingParams
-    # per-slot state that is not keys (models.qwen3_next.init_rec); None, and
-    # so no leaf of any program, for every other model
+    # per-slot state that is not keys (models.qwen3_next.init_rec) and, for
+    # a model with routed experts, the routed work of chunks that have not
+    # sampled yet; None, and so no leaf of any program, for every other model
     rec: Any = None
 
     @staticmethod
@@ -248,6 +256,18 @@ class ModelRunner:
         # bare runners (tests, tools) are contiguous unless asked; the
         # serving manager asks for paged whenever the engine is compatible
         self.paged = bool(paged)
+        # a stack with more than one kind of attention layer (models.afmoe):
+        # a mask and an attend a kind, through the paged pool on one chip
+        self.kinds = cfg.attn_kinds
+        if self.kinds:
+            for what, asked in (
+                    ("pipeline parallelism", self.pp_enabled),
+                    ("a device mesh", mesh is not None),
+                    ("self-extend", ga_n > 1),
+                    ("the contiguous K/V layout", not self.paged),
+                    (f"a {kv_dtype} K/V pool", kv_dtype in ("int8", "int4"))):
+                if asked:
+                    raise ValueError(_kinds_refusal(cfg, what))
         if self.paged and incompat:
             raise ValueError(
                 f"paged KV cache is incompatible with {incompat}")
@@ -264,9 +284,12 @@ class ModelRunner:
                 raise ValueError(_refusal("a device mesh"))
             if not self.paged:
                 raise ValueError(_refusal("the contiguous K/V layout"))
-            # its routed experts: ops.moe's kernel where attention's are
-            # kernels (the value: in the Pallas interpreter), None for the
-            # XLA loop where ``attn_impl`` says xla
+        # a model with routed experts (models.experts): its forward counts
+        # each launch's routed work, and the experts run as ops.moe's kernel
+        # where attention's are kernels (the value: in the Pallas
+        # interpreter), None for the XLA loop where ``attn_impl`` says xla
+        self.routed = bool(cfg.routed)
+        if self.routed:
             impl, interpret = ops.select_moe_impl(
                 attn_impl, hidden=cfg.hidden_size,
                 intermediate=cfg.moe_intermediate_size)
@@ -526,13 +549,8 @@ class ModelRunner:
                 cfg, self.num_slots, self.max_ctx, self.kv_dtype,
                 sharding=self._kv_sharding,
             )
-        rec = None
-        if self.recurrent:
-            from localai_tpu.models import qwen3_next
-
-            rec = qwen3_next.init_rec(cfg, self.num_slots)
         state = DecodeState.init(self.num_slots, cfg.vocab_size, self._seed,
-                                 rec=rec)
+                                 rec=self._init_rec(self.num_slots))
         if self.mesh is not None:
             state = self._place_state(state)
         self.state = state
@@ -540,6 +558,19 @@ class ModelRunner:
         # host mirror of which slots are serving: admit()/release() are the
         # only transitions, so liveness queries never touch the device
         self._active_slots: set[int] = set()
+
+    def _init_rec(self, num_slots: int):
+        """``DecodeState.rec`` for ``num_slots`` slots: the recurrent state
+        (models.qwen3_next.init_rec), for a model with routed experts at
+        least the routed work of chunks whose token no copy brings to the
+        host yet (``_prefill_paged_fn``), None for every other model."""
+        if self.recurrent:
+            from localai_tpu.models import qwen3_next
+
+            return qwen3_next.init_rec(self.cfg, num_slots)
+        if self.routed:
+            return {"routed": jnp.zeros(2, jnp.int32)}
+        return None
 
     def _place_state(self, state: DecodeState) -> DecodeState:
         """Shard a fresh DecodeState over the mesh (the construction-time
@@ -1086,8 +1117,22 @@ class ModelRunner:
                 kvc.kernel_attend(kernel, tables, pos))
 
         mask = kvc.decode_mask(cfg, pos, self.ctx_pad)
+        if self.kinds:
+            # a mask and an attend a KIND of layer: a window layer's kernel
+            # call walks its window's blocks alone, under a scope of its own
+            views = self._kind_views()
+            mask = {kind: kvc.decode_mask(view, pos, self.ctx_pad)
+                    for kind, view in views}
+            if raw:
+                attn = {kind: scoped(
+                    "attn.window_decode" if view.sliding_window
+                    else "attn.paged_decode")(kvc.kernel_attend(partial(
+                        ops.paged_decode_attention,
+                        sliding_window=view.sliding_window,
+                        interpret=self._paged_attn_interpret), tables, pos))
+                    for kind, view in views}
         write = kvc.paged_decode_write(tables, pos, raw=raw)
-        if self.recurrent:
+        if self.routed:
             # a slot with no stream is the identity on its state; the step's
             # routed work rides behind the S sampled tokens, in their copy
             hidden, new_stack, rec, routed = self._forward_rec(
@@ -1155,8 +1200,17 @@ class ModelRunner:
         # bucket, picked on the device: the mask is sliced to it
         write = kvc.paged_prefill_write(table_row, offset, length)
         attn = kvc.span_attend(cfg, table_row, offset, self.ctx_pad)
+        if self.kinds:
+            # a window layer's chunk gathers its window of the prefix
+            views = self._kind_views()
+            mask = {kind: kvc.resume_mask(view, bucket, offset, self.ctx_pad)
+                    for kind, view in views}
+            attn = {kind: (kvc.window_attend if view.sliding_window
+                           else kvc.span_attend)(
+                view, table_row, offset, self.ctx_pad)
+                for kind, view in views}
         routed = None
-        if self.recurrent:
+        if self.routed:
             # the chunk goes on from the slot's state at ``offset``: zero at
             # 0 whatever the slot held (the arming program runs before the
             # LAST chunk, too late to zero it), rows past ``length`` leave
@@ -1316,12 +1370,14 @@ class ModelRunner:
         write = kvc.prefill_write(jnp.int32(0), jnp.zeros((), jnp.int32))
         attn = self._prefill_attn(length) or self._se_attn(
             positions, positions[0])
-        if self.recurrent:
-            from localai_tpu.models import qwen3_next
-
+        if self.kinds:      # the XLA attend under each kind's mask
+            mask = {kind: kvc.prefill_mask(view, bucket, length)
+                    for kind, view in self._kind_views()}
+            attn = None
+        if self.routed:
             hidden, *_ = self._forward_rec(
                 params, tokens, positions, write, kv, mask,
-                qwen3_next.init_rec(cfg, 1),
+                self._init_rec(1),
                 (jnp.arange(bucket) < length)[None, :], attn=attn,
                 slot=jnp.int32(0))
         else:
@@ -1370,19 +1426,31 @@ class ModelRunner:
     def _forward_rec(self, params, tokens, positions, write, stack, mask,
                      rec, valid, attn=None, embeds=None, slot=None,
                      fresh=None):
-        """models.qwen3_next.forward for a model with recurrent state: the
-        hidden states and the K/V stack as ``_forward`` returns them, then
-        the new state and the launch's routed work [experts touched,
-        token-expert pairs that landed here]."""
-        from localai_tpu.models import qwen3_next
-
+        """The forward of a model with routed experts (``cfg.routed``), its
+        family module's own (``models.llama.family_module``): one that
+        carries recurrent state too (``cfg.recurrent``) takes and returns
+        it, one with ``cfg.attn_kinds`` is handed a mask and an attend a
+        kind of layer. Returns the hidden states and the K/V stack as
+        ``_forward`` does, then the new state and the launch's routed work
+        [experts touched, token-expert pairs that landed here]."""
         rec = dict(rec)
         carried = rec.pop("routed")
-        hidden, new_stack, new_rec, routed = qwen3_next.forward(
-            self.cfg, params, tokens, positions, write, stack, mask,
-            self.rope, attn=attn, embeds=embeds, rec=rec, valid=valid,
-            slot=slot, fresh=fresh, experts_kernel=self.experts_kernel)
-        return hidden, new_stack, {**new_rec, "routed": carried}, routed
+        forward = partial(
+            mdl.family_module(self.cfg).forward, self.cfg, params, tokens,
+            positions, write, stack, mask, self.rope, attn=attn,
+            embeds=embeds, valid=valid, experts_kernel=self.experts_kernel)
+        if self.recurrent:
+            hidden, new_stack, rec, routed = forward(rec=rec, slot=slot,
+                                                     fresh=fresh)
+        else:
+            hidden, new_stack, routed = forward()
+        return hidden, new_stack, {**rec, "routed": carried}, routed
+
+    def _kind_views(self) -> list:
+        """(kind, what engine.kvcache's masks and attends read of the
+        config for that kind of layer) for each of ``cfg.attn_kinds``."""
+        return [(kind, kvc.KindView(self.cfg.hd, window))
+                for kind, window in self.kinds]
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""
@@ -1799,6 +1867,9 @@ class ModelRunner:
         if self.recurrent:
             # a rejected draft token has already moved the state it met
             raise ValueError(_refusal("speculative decoding"))
+        if self.kinds:      # the verify window has one attend for all layers
+            raise ValueError(
+                _kinds_refusal(self.cfg, "speculative decoding"))
         proposals = jnp.asarray(proposals, jnp.int32)
         if self.paged:
             self.kv, self.state, emitted = self._verify_paged(

@@ -397,12 +397,17 @@ class Scheduler:
         self._passes_per_forward = int(getattr(
             getattr(runner, "cfg", None), "num_passes", 1))
         self.total_loop_passes = 0
-        # a model with routed experts and recurrent state (models.qwen3_next)
+        # a model with routed experts (models.qwen3_next, models.afmoe)
         # counts each launch's routed work on the device and sends it behind
         # the sampled tokens (``_routed``); these are the lifetime sums, and
-        # the slots armed with a zeroed state
-        self._recurrent = bool(getattr(
-            getattr(runner, "cfg", None), "recurrent", False))
+        # for a model with recurrent state the slots armed with it zeroed
+        cfg = getattr(runner, "cfg", None)
+        self._recurrent = bool(getattr(cfg, "recurrent", False))
+        self._routed_model = bool(getattr(cfg, "routed", False))
+        # keys a window layer's query sees (``LlamaConfig.sliding_window``;
+        # 0: no layer has a window): what ``window_tokens`` cuts a stream's
+        # context to
+        self._window = int(getattr(cfg, "sliding_window", 0) or 0)
         self.total_experts_touched = 0
         self.total_local_assignments = 0
         self.total_state_slots_armed = 0
@@ -581,6 +586,12 @@ class Scheduler:
                 1 for c in self._slots.values()
                 if c.handle.request.priority >= PRIORITY_BATCH
             )
+            # tokens the pool holds that no window layer can read any more:
+            # each live stream's context past the window, in whole blocks
+            bt = getattr(self.runner, "block_tokens", 1)
+            window_dead = sum(
+                max(c.handle.prompt_tokens + c.generated - self._window, 0)
+                // bt * bt for c in self._slots.values())
             # capture the lifetime counters under the same lock: a scrape
             # must not interleave half-updated totals from a mid-dispatch
             # engine iteration
@@ -657,10 +668,13 @@ class Scheduler:
             "admit_programs": getattr(self.runner, "admit_programs", 0),
             "loop_passes": self.total_loop_passes,
             **({"moe_experts_touched": self.total_experts_touched,
-                "moe_assignments": self.total_local_assignments,
-                "state_slots_armed": self.total_state_slots_armed,
+                "moe_assignments": self.total_local_assignments}
+               if self._routed_model else {}),
+            **({"state_slots_armed": self.total_state_slots_armed,
                 "state_bytes": self.runner.state_bytes}
                if self._recurrent else {}),
+            **({"kv_window_dead_tokens": window_dead}
+               if self._window else {}),
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -761,7 +775,7 @@ class Scheduler:
         them (a model with routed experts; nothing otherwise) the launch's
         [experts touched, token-expert pairs here] a step, which go to the
         totals and, summed, to the launch's flight row."""
-        if not self._recurrent:
+        if not self._routed_model:
             return rows
         rows = rows.reshape(-1, rows.shape[-1])
         touched, pairs = rows[:, -2:].sum(axis=0).tolist()
@@ -777,7 +791,8 @@ class Scheduler:
         """Number the serving program about to be enqueued, and count what
         a decode launch of ``k`` steps holds, NOW: the slots that hold a
         stream, and the cached tokens its steps attend (step j of a stream
-        attends its prompt, what it has generated and j more). A stream
+        attends its prompt, what it has generated and j more; a window
+        layer's call the last ``sliding_window`` of them). A stream
         that ends inside the dispatch stays counted: the device attended
         for it. Host mirrors of the engine thread, as ``_flight_record``'s,
         brought up to the device by what ``inflight`` (launched, not yet
@@ -790,16 +805,21 @@ class Scheduler:
         held = {"launch": self._launch_seq}
         if k:
             live = len(self._slots)
-            cached = 0
+            cached = windowed = 0
             for c in self._slots.values():
-                cached += c.handle.prompt_tokens + c.generated
+                n = c.handle.prompt_tokens + c.generated
                 for d in inflight:
                     if d.first is not None:
-                        cached += d.first.handle is c.handle
+                        n += d.first.handle is c.handle
                     elif d.seq > c.admit_seq:
-                        cached += d.k
+                        n += d.k
+                cached += n
+                if self._window:
+                    windowed += sum(min(n + j, self._window)
+                                    for j in range(k))
             held["live_slots"] = live
             held["attended_tokens"] = k * cached + live * (k * (k - 1) // 2)
+            held["window_tokens"] = windowed
         return held
 
     def _flight_record(self, program: str, steps: int, dt: float,

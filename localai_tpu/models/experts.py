@@ -1,0 +1,134 @@
+"""Routed experts beside a shared expert: the ONE routing and dispatch of the
+sparse families that are told which experts they hold (models.qwen3_next,
+models.afmoe).
+
+An expert block scores a token over ALL ``num_experts x ep_size`` experts of
+the deployment, keeps its k choices, and computes the part of the routed sum
+that the ``num_experts`` experts HELD here give, in a walk over the held
+experts that HAVE a token (ops.moe's grouped kernel where attention's are
+kernels, ``experts_loop`` as XLA); the shared expert is added. On one chip the
+block runs without its exchange: the sum is this chip's partial result.
+
+Two things differ between the families, and are the block's arguments: the
+scoring rule (``softmax_scores`` / ``sigmoid_scores``: logits -> a token's k
+weights and choices) and the shared expert (a closure: under a sigmoid gate,
+or none). Everything else is here once: the weights of the held experts, the
+tokens an expert got, the order of the walk, the two counts a launch reports
+([experts touched, token-expert pairs that landed here]; engine.scheduler
+``_routed``), the scopes ``router``, ``experts``, ``shared``.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from localai_tpu.models import quant as qnt
+
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def softmax_scores(k: int, renormalise: bool) -> Callable:
+    """softmax over ALL experts, the k largest, renormalised to sum 1 where
+    the family says so."""
+    def score(logits):
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = lax.top_k(probs, k)
+        if renormalise:
+            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        return topv, topi
+
+    return score
+
+
+def sigmoid_scores(k: int, bias, renormalise: bool, scale: float) -> Callable:
+    """s = sigmoid(logits) over ALL experts; the k largest of ``s + bias``
+    are chosen (the bias SELECTS and does not weigh); a choice weighs its
+    own s, over the sum of the k (+ 1e-20) where the family renormalises,
+    times ``scale``."""
+    def score(logits):
+        s = jax.nn.sigmoid(logits)
+        _, topi = lax.top_k(s + bias.astype(jnp.float32), k)
+        topv = jnp.take_along_axis(s, topi, axis=-1)
+        if renormalise:
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        return topv * scale, topi
+
+    return score
+
+
+def route(h, w_router, score: Callable, num_experts: int, ep_rank: int,
+          valid):
+    """Routing weights of the experts HELD here. h [N, D] -> (weights
+    [N, E] float32, 0 off a token's choices and for a token that is not
+    real; tokens an expert got [E] i32)."""
+    E = num_experts
+    logits = qnt.matmul(h, w_router).astype(jnp.float32)      # over ALL
+    topv, topi = score(logits)
+    local = topi - ep_rank * E
+    here = (local >= 0) & (local < E) & valid[:, None]
+    onehot = jax.nn.one_hot(jnp.where(here, local, E), E, dtype=jnp.float32)
+    return (jnp.sum(onehot * topv[..., None], axis=1),
+            jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32))
+
+
+def experts_loop(h, weights, order, n_touched, experts, p, m_idx):
+    """ops.moe.moe_experts as XLA, and its oracle: a loop over the touched
+    experts, three dots behind a scalar-indexed slice an iteration."""
+    w_gate, w_up, w_down = experts
+
+    def pick(w, e):
+        return lax.dynamic_slice(
+            w, (p, m_idx, e, 0, 0), (1, 1, 1) + w.shape[3:])[0, 0, 0]
+
+    def one_expert(i, acc):
+        e = order[i]
+        y = (jax.nn.silu(qnt.matmul(h, pick(w_gate, e)))
+             * qnt.matmul(h, pick(w_up, e)))
+        y = qnt.matmul(y, pick(w_down, e))
+        col = lax.dynamic_index_in_dim(weights, e, 1, keepdims=True)
+        return acc + col * y.astype(jnp.float32)
+
+    return lax.fori_loop(0, n_touched, one_expert,
+                         jnp.zeros(h.shape, jnp.float32))
+
+
+def moe_block(h, w_router, score: Callable, experts, p, m_idx, *,
+              num_experts: int, ep_rank: int, valid, shared: Callable,
+              experts_kernel: Optional[bool] = None):
+    """One expert block on normed h [N, D]: this chip's part of the routed
+    sum plus the shared expert. ``experts``: the three stacked expert leaves
+    WHOLE ([rows, M, E, ...]), indexed here by (p, m_idx, expert) so that a
+    step reads the experts it touched and nothing else of them. ``shared``:
+    h -> the shared expert's [N, D] float32. ``experts_kernel``: None is the
+    XLA loop over the touched experts, else ops.moe's grouped kernel (the
+    value: in the Pallas interpreter). Returns (out [N, D], experts touched,
+    tokens each held expert got [E]): ``counts`` makes the launch's two
+    numbers of the last two."""
+    with jax.named_scope("router"):
+        weights, load = route(h, w_router, score, num_experts, ep_rank, valid)
+        touched = load > 0
+        n_touched = jnp.sum(touched).astype(jnp.int32)
+        # the experts that have a token first, in their own order
+        order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        if experts_kernel is not None:
+            from localai_tpu.ops import moe
+
+            routed = moe.moe_experts(h, weights, order, n_touched, experts,
+                                     p, m_idx, interpret=experts_kernel)
+        else:
+            routed = experts_loop(h, weights, order, n_touched, experts,
+                                  p, m_idx)
+    with jax.named_scope("shared"):
+        out = (routed + shared(h)).astype(h.dtype)
+    return out, n_touched, load
+
+
+def counts(n_touched, load):
+    """[experts touched, token-expert pairs that landed here] i32: what a
+    launch reports of one expert block."""
+    return jnp.stack([n_touched, jnp.sum(load)])

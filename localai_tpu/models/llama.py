@@ -49,7 +49,11 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     attention_bias: bool = False          # Qwen2-style qkv bias
     rope_scaling: Optional[dict] = None   # HF rope_scaling dict
-    sliding_window: Optional[int] = None  # Mistral-style (mask-only)
+    sliding_window: Optional[int] = None  # keys a query sees, itself
+                                          # included: every layer's (Mistral)
+                                          # or, where a family names layer
+                                          # kinds, its window layers' alone
+                                          # (models.afmoe: ``attn_kinds``)
     num_experts: int = 0                  # Mixtral-class sparse MoE MLP
                                           # (0 = dense mlp)
     num_experts_per_tok: int = 2          # router top-k
@@ -64,6 +68,16 @@ class LlamaConfig:
     # subclass: models.qwen3_next): the engine keeps that state beside the
     # K/V pool and refuses what re-reads a prefix from keys alone
     recurrent: ClassVar[bool] = False
+    # a family with a parameter pytree and a forward of its own: the module
+    # under localai_tpu.models that has them (``family_module``)
+    family: ClassVar[Optional[str]] = None
+    # a family with routed experts that are told which they hold: its
+    # forward counts each launch's routed work (models.experts)
+    routed: ClassVar[bool] = False
+    # a stack with more than one KIND of attention layer: (kind, window)
+    # pairs, the window None where the kind sees every key (models.afmoe).
+    # None: every layer attends alike, under ``sliding_window``
+    attn_kinds: ClassVar[Optional[tuple]] = None
 
     @property
     def hd(self) -> int:
@@ -95,11 +109,17 @@ class LlamaConfig:
         four norms a layer; its exit gate is not served: every pass runs
         for every token). ``qwen3_next`` (periods of Gated DeltaNet layers
         and a gated full-attention layer, routed experts) is a subclass with
-        its own keys: models.qwen3_next."""
+        its own keys: models.qwen3_next; so is ``afmoe`` (window and full
+        attention layers in one stack, dense layers in front of
+        sigmoid-routed experts): models.afmoe."""
         if hf.get("model_type") == "qwen3_next":
             from localai_tpu.models.qwen3_next import Qwen3NextConfig
 
             return Qwen3NextConfig.from_hf(hf)
+        if hf.get("model_type") == "afmoe":
+            from localai_tpu.models.afmoe import AfmoeConfig
+
+            return AfmoeConfig.from_hf(hf)
         ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),
@@ -209,12 +229,23 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 # Parameters
 # ---------------------------------------------------------------------------
 
+def family_module(cfg: LlamaConfig):
+    """The module of a family that brings its own parameters and forward
+    (``param_shapes``, ``init_leaf``, ``checkpoint_leaves``,
+    ``refuse_quantization``, ``forward``, and ``refusal`` where it has
+    ``attn_kinds``); None for this file's."""
+    if cfg.family is None:
+        return None
+    import importlib
+
+    return importlib.import_module(f"localai_tpu.models.{cfg.family}")
+
+
 def param_shapes(cfg: LlamaConfig) -> dict:
     """Shapes of the stacked-parameter pytree."""
-    if cfg.recurrent:
-        from localai_tpu.models import qwen3_next
-
-        return qwen3_next.param_shapes(cfg)
+    fam = family_module(cfg)
+    if fam is not None:
+        return fam.param_shapes(cfg)
     D, F, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     shapes = {
@@ -269,11 +300,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
     leaf is its own jitted program so that, with a ``placement``
     (parallel.sharding.ParamPlacement), it is generated directly on the
     devices that will hold it — no leaf is ever whole on one chip first."""
-    leaf = _init_leaf
-    if cfg.recurrent:
-        from localai_tpu.models import qwen3_next
-
-        leaf = qwen3_next.init_leaf
+    fam = family_module(cfg)
+    leaf = _init_leaf if fam is None else fam.init_leaf
     shapes = param_shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from localai_tpu.models.llama import LlamaConfig, param_shapes
+from localai_tpu.models.llama import (LlamaConfig, family_module,
+                                      param_shapes)
 
 log = logging.getLogger(__name__)
 
@@ -72,7 +73,7 @@ def load_llama_params(
     quantization: str = "",
     placement=None,
 ) -> tuple[LlamaConfig, Any]:
-    """Load an HF llama/mistral/qwen2/ouro/qwen3_next checkpoint into the
+    """Load an HF llama/mistral/qwen2/ouro/qwen3_next/afmoe checkpoint into the
     stacked pytree, one leaf at a time: read and stack on the host, cast (or,
     with ``quantization``, quantize — models.quant.quantize_tensor_host) on
     the host, then hand the SERVED form to ``placement.put``
@@ -103,6 +104,7 @@ def load_llama_params(
         cfg = dataclasses.replace(cfg, tie_word_embeddings=True)
     np_dtype = np.dtype(jnp.dtype(dtype))
     expected = param_shapes(cfg)
+    fam = family_module(cfg)
 
     def place(path: tuple[str, ...], a: np.ndarray):
         want = expected[path[0]] if len(path) == 1 else expected[path[0]][path[1]]
@@ -110,7 +112,9 @@ def load_llama_params(
             raise ValueError(
                 f"param {path}: shape {a.shape} != expected {want}")
         plan = quantize_plan(path, a.ndim, quantization) if quantization else None
-        if plan is None:
+        if path[-1] in getattr(fam, "FLOAT32_LEAVES", ()):
+            leaf = a.astype(np.float32)     # as published, whatever ``dtype``
+        elif plan is None:
             # source dtype stays on the host until here (bf16 checkpoints
             # stay 2 bytes/elem); the cast is a host pass too
             leaf = a.astype(np_dtype, copy=False)
@@ -127,21 +131,25 @@ def load_llama_params(
             mats.append(a.T if transpose else a)
         return np.stack(mats)
 
-    if cfg.recurrent:
-        # periods of DeltaNet and gated-attention layers with routed experts:
-        # the family's own names and regrouping (models.qwen3_next)
-        from localai_tpu.models import qwen3_next
-
-        qwen3_next.refuse_quantization(quantization)
-        layers = {name: place(("layers", name), host)
-                  for name, host in qwen3_next.checkpoint_leaves(
-                      cfg, lambda n: _get(tensors, n), body)}
+    if fam is not None:
+        # a family with a pytree of its own (models.qwen3_next: periods of
+        # DeltaNet and gated-attention layers; models.afmoe: a dense prefix
+        # beside rows of window and full layers): its own names and
+        # regrouping. A leaf that is no ``layers`` leaf is a top-level one
+        fam.refuse_quantization(quantization)
+        layers, top = {}, {}
+        for name, host in fam.checkpoint_leaves(
+                cfg, lambda n: _get(tensors, n), body):
+            if name in expected["layers"]:
+                layers[name] = place(("layers", name), host)
+            else:
+                top[name] = place((name,), host)
         return cfg, {
             "embed": place(("embed",),
                            _get(tensors, body + "embed_tokens.weight")),
             "final_norm": place(("final_norm",),
                                 _get(tensors, body + "norm.weight")),
-            "layers": layers,
+            "layers": layers, **top,
             **({} if cfg.tie_word_embeddings else {
                 "lm_head": place(("lm_head",), _get(tensors, head).T)})}
 

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from localai_tpu.models import experts as xp
 from localai_tpu.models import llama as mdl
 from localai_tpu.models import quant as qnt
 from localai_tpu.models.llama import LlamaConfig
@@ -72,6 +73,8 @@ class Qwen3NextConfig(LlamaConfig):
     ep_rank: int = 0          # which of them this is
 
     recurrent: ClassVar[bool] = True
+    family: ClassVar[str] = "qwen3_next"
+    routed: ClassVar[bool] = True
 
     def __post_init__(self):
         if self.num_layers % self.full_attention_interval:
@@ -496,23 +499,6 @@ def _full_attention(cfg: Qwen3NextConfig, h, lp, cos, sin, attend):
     return out, new_kv
 
 
-def route(cfg: Qwen3NextConfig, h, w_router, valid):
-    """Routing weights of the experts HELD here. h [N, D] -> (weights
-    [N, E] float32, 0 off a token's choices and for a token that is not
-    real; tokens an expert got [E] i32)."""
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = qnt.matmul(h, w_router).astype(jnp.float32)      # over ALL
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = lax.top_k(probs, k)
-    if cfg.norm_topk_prob:
-        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    local = topi - cfg.ep_rank * E
-    here = (local >= 0) & (local < E) & valid[:, None]
-    onehot = jax.nn.one_hot(jnp.where(here, local, E), E, dtype=jnp.float32)
-    return (jnp.sum(onehot * topv[..., None], axis=1),
-            jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32))
-
-
 def shared_expert(h, lp, m_idx: int):
     """The shared expert of block m on h [N, D], under its sigmoid gate
     (``shared_router``: D -> 1); float32."""
@@ -527,58 +513,22 @@ def shared_expert(h, lp, m_idx: int):
 
 def _moe(cfg: Qwen3NextConfig, h, lp, m_idx: int, experts, p, valid,
          experts_kernel: Optional[bool] = None):
-    """Expert block m of period p on normed h [B, T, D]: this chip's part of
-    the routed sum plus the shared expert. ``experts``: the three stacked
-    expert leaves WHOLE ([P, M, E, ...]), indexed here by (p, m, expert) so
-    that a step reads the experts it touched and nothing else of them.
-    ``experts_kernel``: None is the XLA loop over the touched experts, else
-    ops.moe's grouped kernel (the value: in the Pallas interpreter).
+    """Expert block m of period p on normed h [B, T, D] (models.experts
+    ``moe_block``, the routing and dispatch this family shares with
+    models.afmoe): softmax scores, the shared expert under its gate.
     Returns (out, [experts touched, token-expert pairs here] i32)."""
     shape = h.shape
-    h = h.reshape(-1, shape[-1])
-    with jax.named_scope("router"):
-        weights, load = route(cfg, h, lp["moe_gate"][m_idx],
-                              valid.reshape(-1))
-        touched = load > 0
-        n_touched = jnp.sum(touched).astype(jnp.int32)
-        # the experts that have a token first, in their own order
-        order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
-    with jax.named_scope("experts"):
-        if experts_kernel is not None:
-            from localai_tpu.ops import moe
-
-            routed = moe.moe_experts(h, weights, order, n_touched, experts,
-                                     p, m_idx, interpret=experts_kernel)
-        else:
-            routed = _experts_loop(h, weights, order, n_touched, experts,
-                                   p, m_idx)
-    with jax.named_scope("shared"):
-        out = (routed + shared_expert(h, lp, m_idx)).astype(h.dtype)
-    return out.reshape(shape), jnp.stack([n_touched, jnp.sum(load)])
+    out, n_touched, load = xp.moe_block(
+        h.reshape(-1, shape[-1]), lp["moe_gate"][m_idx],
+        xp.softmax_scores(cfg.num_experts_per_tok, cfg.norm_topk_prob),
+        experts, p, m_idx, num_experts=cfg.num_experts, ep_rank=cfg.ep_rank,
+        valid=valid.reshape(-1),
+        shared=lambda h: shared_expert(h, lp, m_idx),
+        experts_kernel=experts_kernel)
+    return out.reshape(shape), xp.counts(n_touched, load)
 
 
-def _experts_loop(h, weights, order, n_touched, experts, p, m_idx):
-    """ops.moe.moe_experts as XLA, and its oracle: a loop over the touched
-    experts, three dots behind a scalar-indexed slice an iteration."""
-    w_gate, w_up, w_down = experts
-
-    def pick(w, e):
-        return lax.dynamic_slice(
-            w, (p, m_idx, e, 0, 0), (1, 1, 1) + w.shape[3:])[0, 0, 0]
-
-    def one_expert(i, acc):
-        e = order[i]
-        y = (jax.nn.silu(qnt.matmul(h, pick(w_gate, e)))
-             * qnt.matmul(h, pick(w_up, e)))
-        y = qnt.matmul(y, pick(w_down, e))
-        col = lax.dynamic_index_in_dim(weights, e, 1, keepdims=True)
-        return acc + col * y.astype(jnp.float32)
-
-    return lax.fori_loop(0, n_touched, one_expert,
-                         jnp.zeros(h.shape, jnp.float32))
-
-
-EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+EXPERT_LEAVES = xp.EXPERT_LEAVES
 # a DeltaNet layer's two large projections: [P, G, ...] leaves that the
 # forward reads as [P G, ...]
 GDN_FLAT = ("gdn_in_qkvz", "gdn_wo")

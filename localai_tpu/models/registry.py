@@ -160,10 +160,9 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
 
     if not quantization:
         return mdl.init_params(jax.random.key(seed), cfg, placement)
-    if cfg.recurrent:
-        from localai_tpu.models import qwen3_next
-
-        qwen3_next.refuse_quantization(quantization)
+    fam = mdl.family_module(cfg)
+    if fam is not None:
+        fam.refuse_quantization(quantization)
     shapes = mdl.param_shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))

@@ -45,8 +45,8 @@ from localai_tpu.obs.trace import mono_to_wall
 
 # what a launch held, as columns of the ring and keys of /debug/flight, in
 # the order of record()'s keywords
-WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "chunk_tokens",
-                "chunk_bucket", "chunk_offset", "chunk_ctx",
+WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "window_tokens",
+                "chunk_tokens", "chunk_bucket", "chunk_offset", "chunk_ctx",
                 "experts_touched", "local_assignments")
 
 
@@ -100,8 +100,9 @@ class FlightRecorder:
                sched_ms: float = 0.0, launch_ms: float = 0.0,
                sync_ms: float = 0.0, passes: int = 0, launch: int = 0,
                live_slots: int = 0, attended_tokens: int = 0,
-               chunk_tokens: int = 0, chunk_bucket: int = 0,
-               chunk_offset: int = 0, chunk_ctx: int = 0,
+               window_tokens: int = 0, chunk_tokens: int = 0,
+               chunk_bucket: int = 0, chunk_offset: int = 0,
+               chunk_ctx: int = 0,
                experts_touched: int = 0, local_assignments: int = 0) -> None:
         """Append one dispatch record (host scalars only).
 
@@ -123,18 +124,21 @@ class FlightRecorder:
         its forwards times the model's passes a forward (a looped decoder
         runs its stack several times a token; every other model once).
 
-        ``launch`` and the six counts after it say what work the launch
+        ``launch`` and the seven counts after it say what work the launch
         held, taken when its program was ENQUEUED and not at the drain
         (``WORK_COLUMNS``): ``launch`` is the scheduler's launch number,
         which the host trace carries as ``sched.launch/<n>`` around the
         same enqueue, so that a row is tied to its device execution
         without clock arithmetic. A decode row holds ``live_slots`` (slots
         with a stream at the launch) and ``attended_tokens`` (cached tokens
-        its steps attend, summed over steps and live slots); a prefill row
-        holds ``chunk_tokens`` (real tokens), ``chunk_bucket`` (rows the
-        program computes), ``chunk_offset`` (cached tokens in front of the
-        chunk) and ``chunk_ctx`` (positions its attend spans). 0 wherever
-        a row's kind has no such count.
+        its steps attend, summed over steps and live slots) and
+        ``window_tokens`` (the same sum with each stream's context cut to the
+        model's attention window: what a window layer's call reads; 0 for a
+        model with no window); a prefill row holds ``chunk_tokens`` (real
+        tokens), ``chunk_bucket`` (rows the program computes),
+        ``chunk_offset`` (cached tokens in front of the chunk) and
+        ``chunk_ctx`` (positions its attend spans). 0 wherever a row's kind
+        has no such count.
 
         ``experts_touched`` and ``local_assignments`` are the two counts
         NOT taken at the enqueue: a model with routed experts counts them on
@@ -168,8 +172,9 @@ class FlightRecorder:
             self._compile[i] = compile
             self._program[i] = program
             self._work[i] = (launch, live_slots, attended_tokens,
-                             chunk_tokens, chunk_bucket, chunk_offset,
-                             chunk_ctx, experts_touched, local_assignments)
+                             window_tokens, chunk_tokens, chunk_bucket,
+                             chunk_offset, chunk_ctx, experts_touched,
+                             local_assignments)
             self._n += 1
             self.total_tokens += int(tokens)
 

@@ -248,6 +248,13 @@ class Registry:
             "Token-expert pairs the router sent to experts held here "
             "(counted on the device)",
         )
+        self.kv_window_dead_tokens = Gauge(
+            "localai_kv_window_dead_tokens",
+            "Tokens the block pool holds that no window layer can read any "
+            "more: each live stream's context past the model's attention "
+            "window, in whole blocks (what per-kind block tables would free "
+            "on the window layers)",
+        )
         self.state_slots_armed = Counter(
             "localai_state_slots_armed_total",
             "Slots armed with a zeroed recurrent state: one an admission of "
@@ -734,7 +741,10 @@ def update_engine_gauges(name: str, m: dict,
         reg.moe_experts_touched.set_total(
             m["moe_experts_touched"], model=name)
         reg.moe_assignments.set_total(m["moe_assignments"], model=name)
+    if "state_slots_armed" in m:
         reg.state_slots_armed.set_total(m["state_slots_armed"], model=name)
+    if "kv_window_dead_tokens" in m:
+        reg.kv_window_dead_tokens.set(m["kv_window_dead_tokens"], model=name)
     if m.get("shed_total"):
         # shed admissions are whole-request waste (no tokens were ever
         # generated); the requests_shed family stays owned by obs.slo —

@@ -34,8 +34,10 @@ and the serving path's own over the block pool:
     (``paged_decode_tiling``).
 
 All run under ``interpret=True`` on CPU for tests (tests/test_ops.py,
-tests/test_paged.py) and compile to Mosaic on real TPU. Sliding-window
-(Mistral) masking is supported statically.
+tests/test_paged.py) and compile to Mosaic on real TPU. A sliding window is
+a static argument of every kernel: every layer's (a Mistral-style model) or,
+where a stack has window and full layers (models.afmoe), the window layers'
+alone, a call a kind; the decode kernels start their walk at the window.
 """
 
 from __future__ import annotations

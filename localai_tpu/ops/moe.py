@@ -1,9 +1,9 @@
 """The routed experts of one expert block as ONE Pallas call: a grouped
 matmul whose grid walks the experts that HAVE a token, in ``order``.
 
-models.qwen3_next._moe's XLA path is a ``lax.fori_loop`` over the touched
-experts, three small dots an iteration behind a scalar-indexed slice: nothing
-fetches expert i + 1 while expert i is computed. Here the three expert
+models.experts' XLA path (``experts_loop``) is a ``lax.fori_loop`` over the
+touched experts, three small dots an iteration behind a scalar-indexed slice:
+nothing fetches expert i + 1 while expert i is computed. Here the three expert
 leaves go in WHOLE ([P, M, E, D, F] / [P, M, E, F, D], where they lie in HBM)
 and the index maps pick block (p, m, order[i]) from scalar-prefetched
 indices, so the pipeline copies expert i + 1's ``w_gate``, ``w_up`` and
@@ -20,11 +20,20 @@ leaves' dtype and every product accumulates in float32; ``silu(gate) * up`` is
 formed in float32 and rounded once, for the third dot (the loop rounds each
 dot's result).
 
+An expert too large to lie in VMEM whole, twice (``BLOCK_BYTES``: 3072 x 3072
+is 18 MiB a matrix), goes through in TILES of its intermediate width F, an
+innermost grid axis: ``(silu(h Wg[:, f]) * (h Wu[:, f])) Wd[f, :]`` summed
+over the tiles f is the expert (the down projection is linear in F), each
+tile's product rounded once as the whole expert's is. An expert that fits
+(2048 x 512) has no such axis: its program is what it was.
+
 Runs under ``interpret=True`` on the CPU (tests/test_moe_kernel.py) and is
 compiled for v5e at the served widths in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -44,13 +53,35 @@ ROW_TILE = 256
 # also what XLA keeps free of its own arrays round the call, and at 64 a
 # 512-row chunk's temps read lowest
 VMEM_LIMIT = 64 * 2**20
+# the most an expert's three blocks may take of it, double-buffered: a whole
+# expert where that fits, else the widest 128-aligned tile of F that divides
+# it and does (3072 x 512 at D 3072: 18 MiB, F in six tiles)
+BLOCK_BYTES = 24 * 2**20
+
+
+def f_tile(D: int, F: int, itemsize: int) -> int:
+    """Columns of F a grid step holds: F, or the widest tile under
+    ``BLOCK_BYTES`` that divides it in 128-lane multiples."""
+    fits = BLOCK_BYTES // (2 * 3 * D * itemsize)
+    if F <= fits:
+        return F
+    tiles = [t for t in range(128, F, 128) if F % t == 0 and t <= fits]
+    if not tiles:
+        raise ValueError(
+            f"the grouped expert kernel finds no 128-aligned tile of the "
+            f"expert width {F} that fits VMEM at hidden {D}; set "
+            f"engine.attn_impl: xla to serve the experts as the XLA loop")
+    return tiles[-1]
 
 
 def _kernel(order_ref, meta_ref, h_ref, wts_ref, wg_ref, wu_ref, wd_ref,
-            o_ref):
+            o_ref, *, tiled: bool):
     i = pl.program_id(1)
+    first = i == 0
+    if tiled:       # the walk's first step is the first expert's first tile
+        first &= pl.program_id(2) == 0
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -81,6 +112,8 @@ def moe_experts(h, weights, order, n_touched, experts, p, m_idx, *,
     w_gate, w_up, w_down = experts
     N, D = h.shape
     E, F = w_gate.shape[2], w_gate.shape[4]
+    tf = f_tile(D, F, w_gate.dtype.itemsize)
+    tiled = tf < F
     tile = min(N, ROW_TILE)
     pad = -N % tile
     if pad:
@@ -89,30 +122,38 @@ def moe_experts(h, weights, order, n_touched, experts, p, m_idx, *,
     meta = jnp.stack([jnp.asarray(v, jnp.int32)
                       for v in (n_touched, p, m_idx)])
 
-    def expert(r, i, order, meta):
-        return meta[1], meta[2], order[i], 0, 0
+    # index maps take (row tile, expert step[, F tile], order, meta)
+    def up(r, i, *rest):
+        *f, order, meta = rest
+        return meta[1], meta[2], order[i], 0, f[0] if f else 0
 
-    def rows(r, i, order, meta):
+    def down(r, i, *rest):
+        *f, order, meta = rest
+        return meta[1], meta[2], order[i], f[0] if f else 0, 0
+
+    def rows(r, i, *rest):
         return r, 0
 
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, tiled=tiled),
         name="moe_experts",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=((N + pad) // tile, jnp.maximum(n_touched, 1)),
+            grid=((N + pad) // tile, jnp.maximum(n_touched, 1),
+                  *((F // tf,) if tiled else ())),
             in_specs=[
                 pl.BlockSpec((tile, D), rows),
                 pl.BlockSpec((tile, E), rows),
-                pl.BlockSpec((None, None, None, D, F), expert),
-                pl.BlockSpec((None, None, None, D, F), expert),
-                pl.BlockSpec((None, None, None, F, D), expert),
+                pl.BlockSpec((None, None, None, D, tf), up),
+                pl.BlockSpec((None, None, None, D, tf), up),
+                pl.BlockSpec((None, None, None, tf, D), down),
             ],
             out_specs=pl.BlockSpec((tile, D), rows),
         ),
         out_shape=jax.ShapeDtypeStruct((N + pad, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary",
+                                 *(("arbitrary",) if tiled else ())),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(order, meta, h, weights, w_gate, w_up, w_down)

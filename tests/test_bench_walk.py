@@ -7,7 +7,8 @@ name that is no function (``test_walk.py``); the flight ring paged forward
 join of a slice's launches to its executions (``test_launches.py``); and the
 sparse hybrid family's file, cell and readers, with a small model of it
 served through the harness and the control that fails
-(``test_qwen3_next_family.py``).
+(``test_qwen3_next_family.py``); the same for the window / full attention
+family (``test_afmoe_family.py``).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -48,6 +49,7 @@ _contract = _load("test_contract", conftest=_conftest)
 _ouro = _load("test_ouro_family", conftest=_conftest)
 _launches = _load("test_launches", conftest=_conftest)
 _qn = _load("test_qwen3_next_family", conftest=_conftest, test_walk=_walk)
+_af = _load("test_afmoe_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -103,3 +105,21 @@ test_a_hybrid_model_runs_by_files_alone = (
     _qn.test_a_hybrid_model_runs_by_files_alone)
 test_the_control_fails_a_family_without_the_decay = (
     _qn.test_the_control_fails_a_family_without_the_decay)
+# PR 44's file: the window / full attention family's hand arithmetic, the
+# dense prefix beside the rows, its cell, its readers, and a small model
+# through the harness with the control that fails
+test_the_hand_arithmetic_of_the_mixed_stacks_published_keys = (
+    _af.test_the_hand_arithmetic_of_the_mixed_stacks_published_keys)
+test_every_published_number_of_the_mixed_stacks_catalog_row_is_in_the_file = (
+    _af
+    .test_every_published_number_of_the_mixed_stacks_catalog_row_is_in_the_file)
+test_the_served_pytree_is_a_dense_prefix_beside_rows = (
+    _af.test_the_served_pytree_is_a_dense_prefix_beside_rows)
+test_the_mixed_cell_reports_what_the_issue_names = (
+    _af.test_the_mixed_cell_reports_what_the_issue_names)
+test_the_swa_readers_read_the_ring_and_the_scopes = (
+    _af.test_the_swa_readers_read_the_ring_and_the_scopes)
+test_a_mixed_attention_model_runs_by_files_alone = (
+    _af.test_a_mixed_attention_model_runs_by_files_alone)
+test_the_control_fails_a_family_whose_window_layers_see_every_key = (
+    _af.test_the_control_fails_a_family_whose_window_layers_see_every_key)

@@ -1,6 +1,6 @@
 """ops/moe.py ``moe_experts``, the routed experts of one expert block as one
 grouped Pallas kernel, in the interpreter on the CPU against the loop it
-replaces on a TPU (models/qwen3_next.py ``_experts_loop``, which stays the XLA
+replaces on a TPU (models/experts.py ``experts_loop``, which stays the XLA
 path and is the oracle here): seeded weights at small widths, stacked over
 (period, block) as the served leaves are. ``_moe`` itself, routed through
 either, has to return the same output and the same counts."""
@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from localai_tpu import ops
+from localai_tpu.models import experts as xp
 from localai_tpu.models import qwen3_next as qn
 from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.ops import moe
@@ -69,17 +70,60 @@ def test_the_kernel_is_the_loop(dtype, n_rows, touched, at):
                                interpret=True)
 
     got = kernel(jnp.int32(at[0]))
-    want = qn._experts_loop(h, weights, order, n, experts, *at)
+    want = xp.experts_loop(h, weights, order, n, experts, *at)
     assert got.shape == (n_rows, D) and got.dtype == jnp.float32
     assert np.isfinite(np.asarray(got)).all()
     if touched:
         assert np.abs(np.asarray(want)).max() > 0.5
-        elsewhere = qn._experts_loop(h, weights, order, n, experts,
+        elsewhere = xp.experts_loop(h, weights, order, n, experts,
                                      (at[0] + 1) % P, at[1])
         assert np.abs(np.asarray(want - elsewhere)).max() > 0.5
     else:
         assert not np.asarray(got).any()
     assert np.abs(np.asarray(got - want)).max() < TOL[dtype]
+
+
+@pytest.mark.parametrize("touched", [[], [6, 1, 3], list(range(E))],
+                         ids=["none", "some", "all"])
+@pytest.mark.parametrize("n_rows", [32, moe.ROW_TILE + 40])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_an_expert_too_large_for_vmem_goes_through_in_tiles_of_f(
+        monkeypatch, dtype, n_rows, touched):
+    """PR 44: an expert whose three matrices do not lie in VMEM whole, twice
+    (3072 x 3072 at the new configuration's widths), is walked in tiles of
+    its intermediate width, an innermost grid axis. Forced here at small
+    widths by the budget: F 256 in two tiles of 128. The loop stays the
+    oracle; an expert that fits takes no such axis."""
+    F2 = 256
+    rng = np.random.default_rng(7)
+    experts = tuple(jnp.asarray(0.1 * rng.standard_normal(shape), dtype)
+                    for shape in ((P, M, E, D, F2), (P, M, E, D, F2),
+                                  (P, M, E, F2, D)))
+    itemsize = jnp.dtype(dtype).itemsize
+    assert moe.f_tile(D, F2, itemsize) == F2        # it fits: one block
+    assert moe.f_tile(2048, 512, 2) == 512          # the hybrid's: whole
+    assert moe.f_tile(3072, 3072, 2) == 512         # the new widths: six
+    monkeypatch.setattr(moe, "BLOCK_BYTES", 2 * 3 * D * itemsize * 128)
+    assert moe.f_tile(D, F2, itemsize) == 128
+    h = jnp.asarray(rng.standard_normal((n_rows, D)), dtype)
+    weights, order = routed(n_rows, touched, seed=n_rows)
+    n = jnp.int32(len(touched))
+    @jax.jit    # the block's index traced, as the row scan hands it over
+    def kernel(p):
+        return moe.moe_experts(h, weights, order, n, experts, p, 2,
+                               interpret=True)
+
+    got = kernel(jnp.int32(1))
+    want = xp.experts_loop(h, weights, order, n, experts, 1, 2)
+    assert got.shape == (n_rows, D) and got.dtype == jnp.float32
+    if touched:
+        assert np.abs(np.asarray(want)).max() > 0.5
+    else:
+        assert not np.asarray(got).any()
+    assert np.abs(np.asarray(got - want)).max() < 2 * TOL[dtype]
+    monkeypatch.setattr(moe, "BLOCK_BYTES", 1)
+    with pytest.raises(ValueError, match="no 128-aligned tile"):
+        moe.f_tile(D, F2, itemsize)
 
 
 HF = {"model_type": "qwen3_next", "vocab_size": 64, "hidden_size": D,

@@ -306,7 +306,7 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
         head_dim=cfg.hd, block_tokens=r.block_tokens, tp=tp,
         backend="tpu") == ("pallas", False)
     r._paged_attn_interpret = r._attn_interpret = False
-    if r.recurrent:     # and its routed experts: the compiled kernel too
+    if r.routed:        # and its routed experts: the compiled kernel too
         assert ops.select_moe_impl(
             "auto", hidden=cfg.hidden_size,
             intermediate=cfg.moe_intermediate_size,
@@ -408,6 +408,7 @@ def test_smoke_programs_fit_one_chip(topo, monkeypatch):
 M7B, MS24B = "mistral-7b-v0.3-int8", "mistral-small-24b-int8-tp4"
 OURO = "ouro-2.6b-int8"
 QN80 = "qwen3-next-80b-a3b-ep8"
+TRL = "trinity-large-ep8"
 
 
 @pytest.fixture
@@ -685,6 +686,92 @@ def test_hybrid_cell_programs_fit_one_chip(topo, monkeypatch, cell, program):
     over = 0.011 if program == "prefill_chunk_512_sample" else 0.001
     assert need / 2**30 <= hbm["largest_program_gib"] + over
     # over the floor a new cell is held to: a quarter of the chip
+    assert 0.25 * HBM_BYTES < need < HBM_BYTES
+
+
+@pytest.mark.parametrize("cell", [TRL], indirect=True)
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk_128_sample",
+                                     "prefill_chunk_512",
+                                     "prefill_chunk_512_sample"])
+def test_mixed_attention_cell_programs_fit_one_chip(topo, monkeypatch, cell,
+                                                    program):
+    """PR 44: the configuration FILE of the window / full attention stack
+    with its dense layer and its sigmoid-routed experts (1 dense + 1 row of
+    4 expert layers, 32 held experts a layer, bfloat16 weights, a 5 x
+    2048-block pool, 18432 positions) compiles for one v5e chip and fits it,
+    with the numbers its ``hbm`` block restates. What Mosaic compiled of a
+    decode program: the paged kernel three times (the dense layer's window
+    call, and in the row scan's body ONE window call for its three window
+    layers' places each and one full call: five calls in the text, four
+    under ``attn.window_decode`` with the window's bound, one under
+    ``attn.paged_decode``), and ``moe_experts`` once an expert layer, the
+    three expert leaves its operands WHOLE; of a prefill chunk the
+    ``moe_experts`` calls alone. The pool is the scan's carry: no
+    pool-shaped temp, no row of experts (7 GiB) or of attention weights
+    staged. The chunk's attends are XLA: a full layer's widest branch spans
+    the 18432 positions, a window layer's ``window_span`` = 4672 + the
+    bucket's blocks."""
+    cfg, doc = cell
+    eng = doc["engine"]
+    assert cfg.attn_kinds == (("sliding_attention", 4096),
+                              ("full_attention", None))
+    assert cfg.routed and not cfg.recurrent and not eng.get("quantization")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="",
+        num_slots=eng["max_slots"], max_ctx=doc["context_size"],
+        kv_num_blocks=eng["kv_num_blocks"], kv_block_tokens=64)
+    pool = a["kv"].k.shape
+    assert pool == (5, eng["kv_num_blocks"], 8, 64, 128)
+    assert a["kv"].k.dtype == bf16 and r.max_blocks == 288
+    c = compile_cell_program(r, a, program)
+    text = c.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    experts = [ln for ln in calls if "moe/experts/moe_experts" in ln]
+    rows = (eng["max_slots"] if program == "decode"
+            else int(program.split("_")[2]))
+    leaves = tuple(a["params"]["layers"][n] for n in ("w_gate", "w_up",
+                                                      "w_down"))
+    assert [w.shape for w in leaves] == [
+        (1, 4, 32, 3072, 3072)] * 3
+    # (a chunk that samples nothing needs the last layer's K/V and not its
+    # experts' output: the compiler drops that call)
+    assert len(experts) == (3 if program == "prefill_chunk_512" else 4)
+    for ln in experts:
+        assert f"f32[{rows},3072]" in ln.split("custom-call(")[0]
+        assert "bf16[1,4,32,3072,3072]" in ln
+    rest = [ln for ln in calls if ln not in experts]
+    if program == "decode":
+        window = [ln for ln in rest
+                  if "attn.window_decode/paged_decode_attn" in ln]
+        full = [ln for ln in rest
+                if "attn.paged_decode/paged_decode_attn" in ln]
+        assert (len(window), len(full), len(rest)) == (4, 1, 5)
+    else:
+        assert not rest
+        assert "attn.prefill_window" in text
+    # no row's experts or attention weights staged, no second pool
+    for staged in ("bf16[32,3072,3072]", "bf16[4,32,3072,3072]",
+                   "bf16[4,3072,6144]", "bf16[4,6144,3072]",
+                   f"bf16[5,{eng['kv_num_blocks']},8,64,128]{{"):
+        assert staged not in text.replace("parameter(", "").split(
+            "ENTRY")[0] or staged.startswith("bf16[5,")
+    m = c.memory_analysis()
+    pool_bytes = int(np.prod(pool)) * 2
+    need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    print(f"HBM {program}: arguments {m.argument_size_in_bytes / 2**30:.3f} "
+          f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
+          f"{need / 2**30:.3f} GiB")
+    # the pool is written in place: a program's temps hold no second one
+    assert m.temp_size_in_bytes < 2 * pool_bytes
+    if program == "decode":
+        assert m.temp_size_in_bytes < 0.25 * 2**30
+    hbm = doc["hbm"]
+    assert (hbm["arguments_gib"] - 0.16 < m.argument_size_in_bytes / 2**30
+            <= hbm["arguments_gib"] + 0.005)
+    assert need / 2**30 <= hbm["largest_program_gib"] + 0.001
     assert 0.25 * HBM_BYTES < need < HBM_BYTES
 
 
