@@ -678,7 +678,8 @@ class ModelRunner:
         pos = state.positions
         logits = mdl.logits_from_hidden(self.cfg, params, hidden[:, 0])
         tokens, keys = smp.sample(
-            logits, state.params, state.counts, state.keys, state.bias
+            logits, state.params, state.counts, state.keys, state.bias,
+            mesh=self.mesh,
         )
         # inactive/frozen slots keep their key: a seeded request's stream must
         # not depend on batch composition (key advances == tokens sampled)
@@ -727,7 +728,8 @@ class ModelRunner:
             counts, keys, still, n_emit, final_tok = carry
             logits_t, draft_t, t = xs  # [S, V], [S], scalar
             tok, new_keys = smp.sample(
-                logits_t, state.params, counts, keys, state.bias
+                logits_t, state.params, counts, keys, state.bias,
+                mesh=self.mesh,
             )
             # per-row NaN/inf guard, same contract as _decode_tail: a
             # non-finite effective logits row reports the NAN_TOKEN
@@ -884,7 +886,7 @@ class ModelRunner:
         slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
         tok, new_key = smp.sample(
             logits, slot_params, counts[slot][None], state.keys[slot][None],
-            state.bias[slot][None],
+            state.bias[slot][None], mesh=self.mesh,
         )
         new_state = dataclasses.replace(
             state,
@@ -945,7 +947,7 @@ class ModelRunner:
         slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
         tok, new_key = smp.sample(
             logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None],
+            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
         )
         new_state = dataclasses.replace(
             state,
@@ -999,7 +1001,7 @@ class ModelRunner:
         slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
         tok, new_key = smp.sample(
             logits, slot_params, counts[slot][None], state.keys[slot][None],
-            state.bias[slot][None],
+            state.bias[slot][None], mesh=self.mesh,
         )
         new_state = dataclasses.replace(
             state,
@@ -1239,7 +1241,7 @@ class ModelRunner:
         slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
         tok, new_key = smp.sample(
             logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None],
+            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
         )
         new_state = dataclasses.replace(
             state,
@@ -1341,7 +1343,7 @@ class ModelRunner:
         slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
         tok, new_key = smp.sample(
             logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None],
+            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
         )
         new_state = dataclasses.replace(
             state,

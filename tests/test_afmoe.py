@@ -561,25 +561,27 @@ def test_speculation_and_quantization_are_refused():
 # ``qn_texts`` below under this installation (jax 0.9.0): the routing and the
 # expert dispatch moved out of models/qwen3_next.py into models/experts.py,
 # and the sparse hybrid's programs are the parent's to the letter, the kernel
-# path's and the XLA loop's.
+# path's and the XLA loop's. PR 45 retook the six of programs that sample
+# (``prefill_0`` stands as taken) for the one ``stablehlo.reduce_precision``
+# ``sample`` gained (tests/test_qwen3_next.py PARENT_TEXT says how checked).
 QN_PARENT_TEXT = {
     "pallas_interpret": {
         "decode":
-            "7ca51c5a746aa9daf8540de28ec72948773eb17ce90f12b3dd8d9f3560be645b",
+            "bc48b0e687acee250e860fac091448d59d257e76ea6aa3f6fe64b5d42aff7126",
         "decode_n":
-            "638a17b2e014ae557d45ecaaf5a677fafaa704e9ac78accc911e1a1155707987",
+            "c95cf671bfa775591c27e8ea970d5e122c97b934ec78a81c059ead93d45845c2",
         "prefill_1":
-            "61a0eb78545bf552b13f0de321e349395d85ab997d8a5fec1b683f8a4e0682d7",
+            "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
         "prefill_0":
             "7dd2443df4e98f837b8555fd6623c5d128539b89e653c9fed3ac59289142fc08",
     },
     "xla": {
         "decode":
-            "e468970f076eb06a59c903728e39be0e2b1ed8cefb6a8076a3e083fbfe70d034",
+            "e03b0cae3c794183ef5b23d3bcf79397bbc84f6ff63a830980e9b553ba43d173",
         "decode_n":
-            "bfda3f3a9e407dfebe6b4c7f7f5b5015c5b8f8ed40ccff9a17f7e00d411bb354",
+            "3aaf8ca9f6fb5474663d4cf30601161f90b86f2391b608129d12e13ebc461e08",
         "prefill_1":
-            "d22665709ec27fbe25316286775d870191235511d62d1903c02dceb0c2bdeb25",
+            "6f692f72bdc7e9cc5a38c698b9e8b4b1b6e01a318b2b0c75bb47c1a30437443c",
         "prefill_0":
             "e0664a26be528c1a5f909534d54256275c2044f56213e4b2e664ea4e0ab56074",
     },

@@ -655,34 +655,39 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
 # PARENT commit of PR 41 by the recipe below under this installation (jax
 # 0.9.0): the four older cells' programs are the parent's to the letter. A
 # change that means to alter them regenerates these from its own parent.
+# PR 45 retook the nine of programs that sample (``arm`` stands as taken):
+# ``sample`` holds a bfloat16 head's float32 copy to bfloat16 with ONE
+# ``stablehlo.reduce_precision``; with that line taken out and the numbered
+# values aside, each text is PR 45's parent's line for line (the two-stage
+# candidates leave them alone: these vocabularies take the one ``top_k``).
 PARENT_TEXT = {
     "mistral-7b-v0.3-int8": {
         "decode":
-            "baa1406ee9c01d3b963f5c61e49733862edf9d5068baeca3debafa48ce5f9d3c",
+            "becee62042000f4f6926225feb39568937e521fadb2f78cebf9a1c67ff8f55d9",
         "decode_n":
-            "b6ee8421e7afd9d2e26275faae560e1a8c88323ea16bd7c82bbbe75e7bce5ad9",
+            "4304b0ddc87e98c88f931474636a4a0fd8456c10b7b020f1857fe84c1e8b9447",
         "prefill":
-            "599cbba1a7e95614fb5846840dd4978bacc9c393acb4af1f8eb086e407ed6221",
+            "5da338912ddb6924ef2ad7c994a1a6db2a1d233d941281a99b3c37a6affe5b12",
         "arm":
             "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
     },
     "mistral-small-24b-int8-tp4": {
         "decode":
-            "503c966d1edbe23694e56d38d430812f927cf516b1916eead3d9176d6d9c80d0",
+            "106a4e5803761ad155f42445f27ec1a05090e5a7aa97ea7b55e85b7ecec387f3",
         "decode_n":
-            "9c010d35067779369d5a77135978096bc4813a76e677003b4c3a5128eb8aaea5",
+            "8147b53f653f2845f2cc9e685c694891aee1f07ef402014b9daf7baa88cc1766",
         "prefill":
-            "cad6fb5681759581db2636b284306e0d5e6e56a716728643a4675e80570b1df5",
+            "72bfe1e112c7a68df594347a5684c47074932e0da55929f006b4730ebfa37af5",
         "arm":
             "3efff5c076c6e3e16b28936ccc7fd25d87950dbbcfc6726e4694f537db26b136",
     },
     "ouro-2.6b-int8": {
         "decode":
-            "987e3c17874bd5a1a2f9c0b882a826aefdb740cc4e46a27dc325ee418cc1553a",
+            "fcce185254ac8c7d2087b67167bb86366cbaac12fe789945230beb5337ba3ef9",
         "decode_n":
-            "3fc11f0849ff1edd43c8a735837d7d47d4c64b36a13762bbaa8b3115a27d0ae1",
+            "a68c907f5457d42050173341eada9e5fee52a3a68f27c53953e4789d9c05632d",
         "prefill":
-            "0b650eeb87911e84357dfa15991209c1044b5f79e7d3ab3ada678ada7c7414e6",
+            "a6e0180316c86e3f77a14a239cb921c62f4bac783543a9b790d6eed56d97be94",
         "arm":
             "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
     },

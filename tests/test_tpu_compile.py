@@ -934,3 +934,61 @@ def test_cell_slot_lifecycle_programs_compile_in_place(topo, monkeypatch,
                 name, given, got)
         assert out_tables.is_equivalent_to(
             a["tables"].sharding, a["tables"].ndim), name
+
+
+# ---------------------------------------------------------------------------
+# the sampler sorts no vocabulary
+
+
+@pytest.mark.parametrize("cell, tp", [(QN80, 1), (TRL, 1), (MS24B, 4)],
+                         indirect=["cell"])
+def test_cell_decode_programs_sort_no_vocabulary(topo, monkeypatch, cell, tp):
+    """PR 45: the decode step's sampler finds its 256 candidates in two
+    stages (``engine/sampling.py top_candidates``: the best chunks of a row,
+    then the best of those), so the compiled decode program of the two
+    expert cells (vocabularies of 18992 and 25024 a chip: 10% of their steps
+    went to one sort of the row block) and of the four-chip cell holds no
+    ``sort`` and no ``TopK`` over anything as wide as the ``[S, V]`` logits
+    a chip holds. On four chips the logits are sharded over the vocabulary:
+    each chip takes the stages over its own 32768 and the chips exchange
+    their 256 candidates a row (two all-gathers of ``[32, 1024]``), so no
+    collective's result is as wide as a chip's logits either (the
+    partitioner, left alone with the stages, re-shards them through 27
+    collectives: which is why ``sample`` is handed the mesh)."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    if tp > 1:
+        monkeypatch.setenv("LOCALAI_MESH_OVERLAP", "auto")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, tp=tp,
+        quantization=eng.get("quantization") or "",
+        num_slots=eng["max_slots"], max_ctx=doc["context_size"],
+        kv_num_blocks=eng["kv_num_blocks"], kv_block_tokens=64)
+    text = compile_cell_program(r, a, "decode").as_text()
+    wide = eng["max_slots"] * cfg.vocab_size // tp
+
+    def widest(line):
+        return max(int(np.prod([int(d) for d in dims.split(",")]))
+                   for dims in re.findall(r"\[(\d+(?:,\d+)*)\]", line))
+
+    sorts = [ln.strip() for ln in text.splitlines() if re.search(
+        r' sort\(|custom_call_target="TopK"', ln)]
+    # (under a mesh the stages sit inside a ``shard_map``, which the trace's
+    # reader drops from a scope path: ``decode/sample/topk`` on both)
+    assert any(re.search(r"/sample/(shard_map/)?topk/", ln) for ln in sorts)
+    assert re.search(r"/sample/(shard_map/)?chunk_max/", text)
+    for ln in sorts:
+        assert widest(ln.split(", metadata=")[0]) < wide, ln[:200]
+    if tp > 1:
+        talk = [(ln.strip(), m.group(1)) for ln in text.splitlines()
+                if (m := re.search(
+                    r" = (.*?) (?:all-gather|all-reduce|all-to-all"
+                    r"|collective-permute|reduce-scatter)[\w\-]*\(", ln))]
+        for ln, result in talk:
+            assert widest(result) < wide, ln[:200]
+        sampler = [ln for ln, _ in talk if "/sample/" in ln]
+        assert len(sampler) == 2 and all(
+            " all-gather" in ln and "/sample/shard_map/merge/" in ln
+            for ln in sampler), [ln[:160] for ln in sampler]
