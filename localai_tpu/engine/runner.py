@@ -285,15 +285,16 @@ class ModelRunner:
             if not self.paged:
                 raise ValueError(_refusal("the contiguous K/V layout"))
         # a model with routed experts (models.experts): its forward counts
-        # each launch's routed work, and the experts run as ops.moe's kernel
+        # each launch's routed work, and its own kernels (the experts as
+        # ops.moe's; a recurrent family's decode step as ops.gdn's) run
         # where attention's are kernels (the value: in the Pallas
-        # interpreter), None for the XLA loop where ``attn_impl`` says xla
+        # interpreter), None for their XLA forms where ``attn_impl`` says xla
         self.routed = bool(cfg.routed)
         if self.routed:
             impl, interpret = ops.select_moe_impl(
                 attn_impl, hidden=cfg.hidden_size,
                 intermediate=cfg.moe_intermediate_size)
-            self.experts_kernel = interpret if impl == "pallas" else None
+            self.family_kernels = interpret if impl == "pallas" else None
         if kv_dtype == "int4" and not self.paged:
             raise ValueError(
                 "kv_dtype=int4 requires the paged KV layout (the nibble-"
@@ -1440,7 +1441,7 @@ class ModelRunner:
         forward = partial(
             mdl.family_module(self.cfg).forward, self.cfg, params, tokens,
             positions, write, stack, mask, self.rope, attn=attn,
-            embeds=embeds, valid=valid, experts_kernel=self.experts_kernel)
+            embeds=embeds, valid=valid, kernels=self.family_kernels)
         if self.recurrent:
             hidden, new_stack, rec, routed = forward(rec=rec, slot=slot,
                                                      fresh=fresh)

@@ -417,7 +417,8 @@ def forward(
     embeds: Optional[jax.Array] = None,
     *,
     valid: jax.Array,       # [B, T] bool: the real tokens
-    experts_kernel: Optional[bool] = None,  # models.experts.moe_block's
+    kernels: Optional[bool] = None,     # models.experts.moe_block's
+                            # ``experts_kernel``
 ) -> tuple[jax.Array, Any, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
     stack, [experts touched, token-expert pairs] summed over the expert
@@ -493,7 +494,7 @@ def forward(
                         shared=lambda h, w=w: shared_expert(
                             h, w("shared_gate"), w("shared_up"),
                             w("shared_down")),
-                        experts_kernel=experts_kernel)
+                        experts_kernel=kernels)
                     out = post_norm(out.reshape(x.shape),
                                     w("mlp_post_norm"), eps)
                 x = x + out

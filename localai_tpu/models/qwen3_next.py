@@ -29,15 +29,18 @@ What the serving engine holds of it (engine.runner):
     without its exchange: the sum is this chip's partial result.
 
 The plain reference is benchmark/reference/qwen3_next_family.py, and
-tests/test_qwen3_next.py holds this file to it. The DeltaNet step, the conv,
-the router and the shared expert are XLA under named scopes (``gdn/*``,
-``moe/*``, ``attn_gate``); the routed experts' kernel runs under
-``moe/experts``, as their loop does.
+tests/test_qwen3_next.py holds this file to it. The conv, the router, the
+shared expert and a chunk's DeltaNet scan are XLA under named scopes
+(``gdn/*``, ``moe/*``, ``attn_gate``); the routed experts' kernel runs under
+``moe/experts``, as their loop does, and the decode step's recurrence
+(ops.gdn's kernel where attention's are kernels, ``gdn_step`` as XLA) under
+``gdn/state``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, ClassVar, Optional
 
@@ -393,6 +396,32 @@ def gdn_step(S, q, k, v, g, beta):
     return decay[..., None] * S + k[..., :, None] * d[..., None, :], o
 
 
+def recur(S0, q, k, v, g, beta):
+    """The recurrence as XLA from state S0 [B, Hv, dk, dv] over the tokens
+    of q, k [B, T, Hv, dk], v [B, T, Hv, dv], g, beta [B, T, Hv]:
+    (S after them, o [B, T, Hv, dv])."""
+    if q.shape[1] == 1:     # the decode step: no loop
+        S, o = gdn_step(S0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        return S, o[:, None]
+    # a prefill chunk: the same recurrence, token by token
+    S, o = lax.scan(lambda S, xs: gdn_step(S, *xs), S0, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    return S, jnp.moveaxis(o, 0, 1)
+
+
+def recur_in_place(S_all, p, g_idx: int, interpret: bool, q, k, v, g, beta):
+    """``recur`` for the decode step (T = 1, batch row b is slot b) as
+    ops.gdn's kernel on layer (p, g_idx) of the carried state ``S_all``
+    [P, G, slots, Hv, dk, dv]: (``S_all`` with the layer's rows stepped, in
+    place; o [B, 1, Hv, dv])."""
+    from localai_tpu.ops import gdn
+
+    S_all, o = gdn.gdn_state_step(
+        S_all, p, g_idx, *(t[:, 0] for t in (q, k, v, g, beta)),
+        interpret=interpret)
+    return S_all, o[:, None]
+
+
 def gated_norm(o, z, w, eps: float):
     """The DeltaNet's output norm, float32: the ONE norm with a plain gain
     (not 1 + w), and it norms BEFORE the gate silu(z)."""
@@ -401,12 +430,14 @@ def gated_norm(o, z, w, eps: float):
     return o * jax.nn.silu(z.astype(jnp.float32))
 
 
-def _gdn(cfg: Qwen3NextConfig, h, lp, g_idx: int, w_in, w_out, S0, conv0,
-         valid):
-    """The DeltaNet mixer on normed activations h [B, T, D] from state
-    (S0 [B, Hv, dk, dv], conv0 [B, K-1, C]); ``valid`` [B, T] marks the real
-    tokens, a PREFIX of each row; ``w_in``, ``w_out`` the layer's two large
-    projections (``GDN_FLAT``). Returns (out [B, T, D], S, conv)."""
+def _gdn(cfg: Qwen3NextConfig, h, lp, g_idx: int, w_in, w_out, state_step,
+         conv0, valid):
+    """The DeltaNet mixer on normed activations h [B, T, D] from the conv's
+    rows conv0 [B, K-1, C] and the state ``state_step`` steps: ``recur``
+    on the layer's S0, or the decode step's kernel on the carried array;
+    ``valid`` [B, T] marks the real tokens, a PREFIX of each row; ``w_in``,
+    ``w_out`` the layer's two large projections (``GDN_FLAT``). Returns
+    (out [B, T, D], ``state_step``'s state, conv)."""
     B, T, _ = h.shape
     Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -444,17 +475,7 @@ def _gdn(cfg: Qwen3NextConfig, h, lp, g_idx: int, w_in, w_out, S0, conv0,
         # a token that is not real is the identity on S: no decay, no write
         g = jnp.where(valid[..., None], g, 0.0)
         beta = jnp.where(valid[..., None], beta, 0.0)
-        if T == 1:      # the decode step: no loop
-            S, o = gdn_step(S0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                            beta[:, 0])
-            o = o[:, None]
-        else:           # a prefill chunk: the same recurrence, token by token
-            def body(S, xs):
-                return gdn_step(S, *xs)
-
-            S, o = lax.scan(body, S0, tuple(
-                jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
-            o = jnp.moveaxis(o, 0, 1)
+        S, o = state_step(q, k, v, g, beta)
     with jax.named_scope("out"):
         o = gated_norm(o, z.reshape(B, T, Hv, dv),
                        lp["gdn_out_norm"][g_idx], cfg.rms_norm_eps)
@@ -553,7 +574,9 @@ def forward(
     fresh: Any = None,      # with ``slot``: the chunk starts the sequence
                             # (offset 0), so the state it starts from is zero
                             # whatever the slot held
-    experts_kernel: Optional[bool] = None,  # ``_moe``'s
+    kernels: Optional[bool] = None,     # None: the experts' walk and the
+                            # DeltaNet's decode step are XLA; else ops.moe's
+                            # and ops.gdn's kernels (the value: interpreted)
 ) -> tuple[jax.Array, Any, dict, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
@@ -588,6 +611,9 @@ def forward(
     scanned = {n: w for n, w in layers.items()
                if n not in EXPERT_LEAVES + GDN_FLAT}
     eps, G = cfg.rms_norm_eps, cfg.gdn_per_period
+    # the decode step (batch row b is slot b, one token): the recurrence is
+    # ONE kernel a layer on the carried state, which is then never sliced
+    fused = kernels is not None and slot is None and tokens.shape[1] == 1
 
     def period(carry, xs):
         x, kv, S_all, conv_all, counts = carry
@@ -596,8 +622,7 @@ def forward(
         def moe(x, m_idx, counts):
             with jax.named_scope("moe"):
                 h = zc_norm(x, lp["mlp_norm"][m_idx], eps)
-                out, c = _moe(cfg, h, lp, m_idx, experts, p, valid,
-                              experts_kernel)
+                out, c = _moe(cfg, h, lp, m_idx, experts, p, valid, kernels)
             return x + out, counts + c
 
         for g_idx in range(G):
@@ -605,19 +630,24 @@ def forward(
                 # the per-slot arrays are read and written under the scope
                 # of the recurrence: ``gdn/state`` is all that moves state
                 with jax.named_scope("state"):
-                    S0 = _rec_read(S_all, p, g_idx, slot)
+                    S0 = None if fused else _rec_read(S_all, p, g_idx, slot)
                     conv0 = _rec_read(conv_all, p, g_idx, slot)
-                    if fresh is not None:
+                    if fresh is not None:       # a chunk: never fused
                         S0 = jnp.where(fresh, 0.0, S0)
                         conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
+                    state_step = (
+                        functools.partial(recur_in_place, S_all, p, g_idx,
+                                          kernels)
+                        if fused else functools.partial(recur, S0))
                 h = zc_norm(x, lp["gdn_norm"][g_idx], eps)
                 out, S, conv = _gdn(
                     cfg, h, lp, g_idx,
                     *(lax.dynamic_index_in_dim(w, p * G + g_idx, 0,
                                                keepdims=False)
-                      for w in (w_in, w_out)), S0, conv0, valid)
+                      for w in (w_in, w_out)), state_step, conv0, valid)
                 with jax.named_scope("state"):
-                    S_all = _rec_write(S_all, S, p, g_idx, slot)
+                    S_all = S if fused else _rec_write(S_all, S, p, g_idx,
+                                                       slot)
                     conv_all = _rec_write(conv_all, conv, p, g_idx, slot)
                 x = x + out
             x, counts = moe(x, g_idx, counts)

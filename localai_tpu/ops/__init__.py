@@ -19,7 +19,8 @@ The one input is the runner's ``attn_impl=`` (YAML ``engine.attn_impl``).
 The routed experts of a model that has them as a loop over the touched ones
 (models.qwen3_next) go the same way on the same input: ops.moe's grouped
 kernel where attention's are kernels, the XLA loop under ``xla``
-(``select_moe_impl``).
+(``select_moe_impl``); the decode step of its DeltaNet layers goes with them
+(ops.gdn's kernel, or ``gdn_step`` as XLA).
 """
 
 from __future__ import annotations

@@ -160,7 +160,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype, impl,
     r = runner_for(cfg, params, impl)
     assert cfg.row_kinds == ((S, F, S, S) if deep else (S, S, F, S))
     assert r.kinds == ((S, 8), (F, None)) and r.routed and not r.recurrent
-    assert (r.experts_kernel is not None) == (impl == "pallas_interpret")
+    assert (r.family_kernels is not None) == (impl == "pallas_interpret")
     served, tokens = served_logits(r, tap(r), 1, PROMPT)
     assert r.admit_programs == 1 + 3            # the arming and three chunks
     assert r.kv.k.shape[0] == cfg.cache_layers == (10 if deep else 5)
@@ -564,12 +564,16 @@ def test_speculation_and_quantization_are_refused():
 # path's and the XLA loop's. PR 45 retook the six of programs that sample
 # (``prefill_0`` stands as taken) for the one ``stablehlo.reduce_precision``
 # ``sample`` gained (tests/test_qwen3_next.py PARENT_TEXT says how checked).
+# PR 46 retook the kernel path's two DECODE programs, which now hold
+# ops/gdn.py's kernel where they sliced the state and stepped it as XLA; its
+# chunks and all four of the XLA path stand as taken: the step they run moved
+# into ``recur`` and lowers to the letter it did.
 QN_PARENT_TEXT = {
     "pallas_interpret": {
         "decode":
-            "bc48b0e687acee250e860fac091448d59d257e76ea6aa3f6fe64b5d42aff7126",
+            "2bd70500098b54f9a9898e008ef5b4e334cb87b0a8ed7c6efceb084c07399679",
         "decode_n":
-            "c95cf671bfa775591c27e8ea970d5e122c97b934ec78a81c059ead93d45845c2",
+            "96c2e18114a786bd279129f62923fbed9179822f1370735ad69b234e0567001f",
         "prefill_1":
             "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
         "prefill_0":

@@ -146,18 +146,19 @@ EXPERTS = ("loop", "kernel")
 
 
 def runner_for(cfg, params, experts="loop", **kw) -> ModelRunner:
-    """``experts``: the routed experts as the XLA loop, or as ops.moe's
-    kernel in the Pallas interpreter under the same XLA attention (steered
-    here, before the first program is traced: ``attn_impl`` would take
-    attention to its kernels too)."""
+    """``experts``: the routed experts as the XLA loop and the DeltaNet's
+    decode step as ``gdn_step``, or both as their kernels (ops.moe's,
+    ops.gdn's: ONE decision of the runner) in the Pallas interpreter under
+    the same XLA attention (steered here, before the first program is
+    traced: ``attn_impl`` would take attention to its kernels too)."""
     kw = {"num_slots": 4, "max_ctx": 128, "paged": True,
           "kv_block_tokens": 16, "prefill_chunk": 16,
           "prefill_buckets": [16, 32], "attn_impl": "xla",
           "kv_dtype": cfg.dtype, **kw}
     r = ModelRunner(cfg, params, **kw)
-    assert r.experts_kernel is None
+    assert r.family_kernels is None
     if experts == "kernel":
-        r.experts_kernel = True
+        r.family_kernels = True
     return r
 
 
@@ -205,6 +206,12 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype,
     assert r.state.rec["S"].shape == (PERIODS, G, 4, 4, 16, 16)
     assert r.state.rec["S"].dtype == jnp.float32
     assert r.state_bytes == sum(a.nbytes for a in r.state.rec.values())
+    # the decode program that served them holds the DeltaNet's kernel once a
+    # DeltaNet layer of the (rolled) period, or not at all
+    traced = str(jax.make_jaxpr(r._decode_paged_fn)(
+        r.params, r.kv, r.state, r.block_tables))
+    assert traced.count("name=gdn_state_step") == (
+        G if experts == "kernel" else 0)
     ref = reference_logits(family, params, hf, PROMPT, tokens, monkeypatch)
     if dtype == "float32":
         agree(served, ref, F32_TOL)
@@ -309,6 +316,39 @@ def no_decay(monkeypatch):
     return {}
 
 
+def kernel_only(left_out):
+    """A term left out of ops.gdn's ``head_step``: the decode steps of the
+    ``kernel`` runner miss it, the ``loop`` runner's never ran it."""
+    left_out.kernel_only = True
+    return left_out
+
+
+def _head_step_with(monkeypatch, change):
+    from localai_tpu.ops import gdn
+
+    real = gdn.head_step
+    monkeypatch.setattr(gdn, "head_step",
+                        lambda S, *rest: change(real, S, *rest))
+    return {}
+
+
+@kernel_only
+def no_decay_in_the_kernels_step(monkeypatch):
+    def change(real, S, kc, qc, v, decay, beta, kq):
+        return real(S, kc, qc, v, jnp.ones_like(decay), beta, kq)
+
+    return _head_step_with(monkeypatch, change)
+
+
+@kernel_only
+def no_outer_product_in_the_kernels_step(monkeypatch):
+    """The state decays and is never written: ``k (x) d`` dropped."""
+    def change(real, S, kc, qc, v, decay, beta, kq):
+        return decay * S, real(S, kc, qc, v, decay, beta, kq)[1]
+
+    return _head_step_with(monkeypatch, change)
+
+
 def no_one_plus_in_the_norm(monkeypatch):
     def norm(x, w, eps):
         xf = x.astype(jnp.float32)
@@ -364,10 +404,14 @@ def plain_gain_on_the_gated_norm_as_one_plus(monkeypatch):
 @pytest.mark.parametrize("left_out", [
     no_decay, no_one_plus_in_the_norm, rope_on_every_dim, no_output_gate,
     no_silu_z, unnormalised_top_k, no_shared_gate,
-    plain_gain_on_the_gated_norm_as_one_plus])
+    plain_gain_on_the_gated_norm_as_one_plus, no_decay_in_the_kernels_step,
+    no_outer_product_in_the_kernels_step])
 @pytest.mark.parametrize("experts", EXPERTS)
 def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
                                                   left_out, experts):
+    """``no_decay`` patches ``gdn_step``, which the ``kernel`` runner's decode
+    steps no longer run (its chunks do): the two ``kernel_only`` cases leave
+    a term out of the step a cell runs, and move nothing under ``loop``."""
     cfg = config()
     params = seeded_params(cfg)
     served_cfg = config(**left_out(monkeypatch))
@@ -375,7 +419,10 @@ def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
     served, tokens = served_logits(r, tap(r), 1, PROMPT)
     monkeypatch.undo()
     ref = reference_logits(family, params, HF, PROMPT, tokens, monkeypatch)
-    assert np.abs(served - ref).max() > 10 * F32_TOL
+    if getattr(left_out, "kernel_only", False) and experts == "loop":
+        agree(served, ref, F32_TOL)
+    else:
+        assert np.abs(served - ref).max() > 10 * F32_TOL
 
 
 # ---------------------------------------------------------------------------

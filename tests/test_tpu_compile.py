@@ -311,8 +311,8 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
             "auto", hidden=cfg.hidden_size,
             intermediate=cfg.moe_intermediate_size,
             backend="tpu") == ("pallas", False)
-        assert r.experts_kernel is True
-        r.experts_kernel = False
+        assert r.family_kernels is True
+        r.family_kernels = False
     if mesh is not None:
         r.mesh = mesh
     pool_spec = None if mesh is None else tuple(r._paged_sharding.spec)
@@ -612,10 +612,14 @@ def test_hybrid_cell_programs_fit_one_chip(topo, monkeypatch, cell, program):
     Mosaic compiled of a decode program is the paged kernel, once in the
     period scan's body, and the routed experts' grouped kernel
     (ops/moe.py ``moe_experts``: once an expert block of a period, the three
-    expert leaves its operands WHOLE) and nothing else; of a prefill chunk
-    the ``moe_experts`` calls alone, its rows the bucket's. The DeltaNet step
-    and the conv are XLA. (benchmark/layers/paged_decode_attn_roofline.py
-    sums every ``tpu_custom_call`` of a slice: in this cell it now sums both
+    expert leaves its operands WHOLE); of a prefill chunk the ``moe_experts``
+    calls alone, its rows the bucket's. PR 46: and of a decode program the
+    DeltaNet step (ops/gdn.py ``gdn_state_step``: once a DeltaNet layer of
+    the period, the carried state its operand WHOLE and its aliased result),
+    so no decode program slices a layer's state out of the carry or lays it
+    back; a chunk's token scan and the conv are XLA.
+    (benchmark/layers/paged_decode_attn_roofline.py sums every
+    ``tpu_custom_call`` of a slice: in this cell it now sums all three
     kernels, PERF.md section 7.)"""
     cfg, doc = cell
     eng = doc["engine"]
@@ -646,17 +650,32 @@ def test_hybrid_cell_programs_fit_one_chip(topo, monkeypatch, cell, program):
         assert f"f32[{rows},2048]" in ln.split("custom-call(")[0]
         for w in leaves:
             assert f"bf16[{','.join(map(str, w.shape))}]" in ln
-    rest = [ln for ln in calls if ln not in experts]
+    steps = [ln for ln in calls if "gdn/state/gdn_state_step" in ln]
+    rest = [ln for ln in calls if ln not in experts + steps]
     if program.startswith("decode"):
         assert len(rest) == 1 and "paged_decode_attn" in rest[0]
+        # one call a DeltaNet layer of the (rolled) period: the carried
+        # state goes in whole and comes back as the same buffer
+        carried = "f32[3,3,32,32,128,128]"
+        assert len(steps) == cfg.gdn_per_period
+        for ln in steps:
+            results, operands = ln.split(" custom-call(")
+            assert results.split("= (")[1].startswith(carried)
+            assert operands.split("operand_layout_constraints={")[1].split(
+                "}, ")[1].startswith(carried)
+            assert "output_to_operand_aliasing={{0}: (1, {})}" in ln
+        # nothing slices a layer's state out of the carry or lays it back,
         # nothing stages a period's DeltaNet layers of state (192 MiB: an
         # index by the period in front of the layer's did), nor a period's
         # DeltaNet projections (144 MiB: the scan's slice of a [P, G, ...]
         # leaf did, copied whole before a layer's was taken)
+        assert "f32[32,32,128,128]" not in text
+        assert not [ln for ln in text.splitlines()
+                    if f"= {carried}" in ln and "dynamic-update-slice(" in ln]
         assert "f32[3,32,32,128,128]" not in text
         assert "bf16[3,2048,12288]" not in text
     else:
-        assert not rest
+        assert not rest and not steps
     # no block's experts staged for the kernel, nor a period's
     for staged in ("bf16[64,2048,512]", "bf16[64,512,2048]",
                    "bf16[4,64,2048,512]", "bf16[4,64,512,2048]"):
