@@ -41,23 +41,31 @@ hands back beside its output). A scatter's cost is its windows', ~100 ns
 each whatever their bytes, S x H x 2 of them a layer (PERF.md, PR 38).
 tests/test_tpu_compile.py compiles the runner's programs for a described
 v5e and holds them to no cache-sized temp and no layer-shaped copy.
+
+Which policy, attend and mask a program gets is a LAYOUT's to say
+(``PagedLayout``, ``ContiguousLayout`` at the end of this file): the runner
+builds one and runs one family of programs over it.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional
+from functools import partial
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from localai_tpu import ops
 from localai_tpu.models.llama import LlamaConfig, _grouped_attn
 from localai_tpu.models.quant import (
     quantize_lastdim as _quant_chunk,
     quantize_lastdim4 as _quant_chunk4,
     unpack_int4_lastdim as _unpack4,
 )
+from localai_tpu.obs.profiler import scoped
 from localai_tpu.ops.attention import gather_block_scales, gather_blocks
 
 
@@ -123,8 +131,6 @@ def init_cache(
     scale_sharding = None
     if dt == jnp.int8 and sharding is not None:
         # scales drop the head_dim axis; reuse the kv spec minus its last entry
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         spec = sharding.spec
         scale_sharding = NamedSharding(sharding.mesh, P(*tuple(spec)[:4]))
     if dt == jnp.int8:
@@ -213,8 +219,6 @@ def init_paged_cache(
     scale_sharding = None
     if quantized and sharding is not None:
         # scale pool drops the head_dim axis; reuse the pool spec minus it
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         scale_sharding = NamedSharding(
             sharding.mesh, P(*tuple(sharding.spec)[:4]))
     if quantized:
@@ -799,3 +803,261 @@ def prefill_mask(cfg: LlamaConfig, seq_len: int, length: jax.Array) -> jax.Array
     if cfg.sliding_window:
         m &= t[None, None, :] > t[None, :, None] - cfg.sliding_window
     return m
+
+
+# ---------------------------------------------------------------------------
+# the layouts: how a runner's K/V is laid out, written, attended and masked
+# ---------------------------------------------------------------------------
+#
+# One object a runner (``engine.runner.ModelRunner.layout``), built once from
+# what the runner resolved at load. The runner's ONE family of programs asks
+# it for the device state, for ``(write, attend, mask)`` of a decode step and
+# of a chunk behind a cached prefix, for ``(write, mask)`` of a verify
+# window, and for the cache type its stack goes back into; it names no
+# kernel, no ``shard_map`` spec and no write policy itself. ``tables`` /
+# ``table_row`` are the block pool's and None over the contiguous rows.
+
+
+def kind_views(cfg: LlamaConfig) -> list:
+    """(kind, what the masks and attends here read of the config for that
+    kind of layer) for each of ``cfg.attn_kinds``."""
+    return [(kind, KindView(cfg.hd, window))
+            for kind, window in cfg.attn_kinds]
+
+
+@dataclasses.dataclass
+class ContiguousLayout:
+    """Rows a slot (``KVCache``): what pipeline parallelism, self-extend,
+    the mirror port and the draft runner still serve from."""
+
+    cfg: LlamaConfig
+    mesh: Any
+    kv_dtype: str
+    num_slots: int
+    max_ctx: int
+    attn_impl: str                  # ops.select_attn_impl: "pallas" | "xla"
+    interpret: bool
+    # (qpos, kpos) -> the attend where the Pallas kernel does not serve:
+    # self-extend's, or None for the model's own XLA attend
+    xla_attend: Callable
+
+    kv_write_impl = "scatter"
+    from_stacked = staticmethod(KVCache.from_stacked)
+
+    def __post_init__(self):
+        self.ctx = self.max_ctx     # the length masks are built to
+        self.kv_sharding = None
+        if self.mesh is not None:
+            from localai_tpu.parallel import sharding as shd
+
+            self.kv_sharding = NamedSharding(
+                self.mesh, shd.kv_spec(self.cfg, self.mesh))
+
+    def init(self):
+        """(the cache, no tables), zeroed in the layout's sharding."""
+        return init_cache(self.cfg, self.num_slots, self.max_ctx,
+                          self.kv_dtype, sharding=self.kv_sharding), None
+
+    def decode(self, kv: KVCache, tables, positions):
+        cfg = self.cfg
+        attn = None
+        raw = self.attn_impl == "pallas"
+        if raw:
+            kernel = partial(
+                ops.decode_attention,
+                sliding_window=cfg.sliding_window,
+                interpret=self.interpret,
+            )
+            if self.mesh is not None:
+                # per-device kernel over (slots/'data', heads/'model'):
+                # decode attention is independent across slots and head
+                # groups, so the shard_map body is the single-device kernel
+                # (the stacked cache's layer axis whole on every device)
+                in_specs = [P("data", "model", None),
+                            P(None, "data", "model", None, None),
+                            P(None, "data", "model", None, None),
+                            P(),
+                            P("data")]
+                if kv.quantized:
+                    in_specs += [P(None, "data", "model", None),
+                                 P(None, "data", "model", None)]
+                kernel = shard_map(
+                    kernel,
+                    mesh=self.mesh,
+                    in_specs=tuple(in_specs),
+                    out_specs=P("data", "model", None),
+                    check_vma=False,
+                )
+
+            @scoped("attn.decode")
+            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; LayerViews
+                args = (q[:, 0], keys.cache, values.cache, keys.layer,
+                        positions)
+                if kv.quantized:  # f32 scale stacks — fused dequant
+                    args += (keys.scale, values.scale)
+                return kernel(*args)[:, None]
+
+        if attn is None:
+            attn = self.xla_attend(
+                positions[:, None], jnp.arange(self.ctx, dtype=jnp.int32))
+        mask = decode_mask(cfg, positions, self.ctx)
+        return decode_write(positions, raw=raw), attn, mask
+
+    def verify(self, tables, positions, T: int):
+        return (verify_write(positions),
+                verify_mask(self.cfg, positions, T, self.ctx))
+
+    def chunk(self, table_row, slot, positions, offset, length):
+        """The chunk attends the slot's whole row (XLA: keys span the cache
+        row, which the fresh-chunk Pallas prefill kernel does not model;
+        the span of a chunk's attend is cut over the pool alone)."""
+        attn = self.xla_attend(
+            positions, jnp.arange(self.ctx, dtype=jnp.int32))
+        mask = resume_mask(self.cfg, positions.shape[1], offset, self.ctx)
+        return resume_write(slot, offset), attn, mask
+
+
+@dataclasses.dataclass
+class PagedLayout:
+    """The block pool (``PagedKVCache``) and the [S, MB] device mirror of
+    the allocator's block tables: what every cell serves from."""
+
+    cfg: LlamaConfig
+    mesh: Any
+    kv_dtype: str
+    num_slots: int
+    max_ctx: int
+    attn_impl: str          # ops.select_paged_attn_impl: "pallas" | "xla"
+    interpret: bool
+    block_tokens: int
+    max_blocks: int         # table entries a slot
+    num_blocks: int         # blocks in the pool, the trash block included
+    overlap_mode: str       # parallel.overlap.resolve_mode ("": GSPMD)
+
+    from_stacked = staticmethod(PagedKVCache.from_stacked)
+
+    def __post_init__(self):
+        self.ctx = self.max_blocks * self.block_tokens
+        self.kv_sharding = self.table_sharding = None
+        if self.mesh is not None:
+            from localai_tpu.parallel import sharding as shd
+
+            # pool kv-heads on 'model' (paged_kv_spec); the [S, MB] table
+            # mirror carries the 'data' sharding instead — the pool has no
+            # slot axis to put it on
+            self.kv_sharding = NamedSharding(
+                self.mesh, shd.paged_kv_spec(self.cfg, self.mesh))
+            self.table_sharding = NamedSharding(
+                self.mesh, shd.block_table_spec())
+
+    @property
+    def kv_write_impl(self) -> str:
+        """Who writes a decode step's new rows into the pool: ``kernel``,
+        the Pallas paged kernel that reads them (an unscaled pool:
+        ``paged_decode_write``), else the policy's ``scatter``."""
+        unscaled = self.kv_dtype not in ("int8", "int4")
+        return "kernel" if self.attn_impl == "pallas" and unscaled else "scatter"
+
+    def init(self):
+        """(the pool, the table mirror: every row on the trash block),
+        zeroed in the layout's shardings."""
+        tables = jnp.zeros((self.num_slots, self.max_blocks), jnp.int32)
+        if self.table_sharding is not None:
+            tables = jax.device_put(tables, self.table_sharding)
+        return init_paged_cache(
+            self.cfg, self.num_blocks, self.block_tokens, self.kv_dtype,
+            sharding=self.kv_sharding), tables
+
+    def tp_trunk(self, params, rope, tokens, positions, kv: PagedKVCache,
+                 tables):
+        """The decode forward as a manual-TP trunk with decomposed per-layer
+        reductions (parallel.overlap; where ``overlap_mode`` is set)."""
+        from localai_tpu.parallel import overlap as ovl
+
+        trunk = {k: params[k] for k in ovl.TRUNK_KEYS}
+        return ovl.paged_decode_trunk(
+            self.cfg, trunk, self.mesh, tokens, positions,
+            kv.stacked(), tables, rope,
+            ctx_pad=self.ctx,
+            mode=self.overlap_mode,
+            use_pallas=self.attn_impl == "pallas",
+            interpret=self.interpret,
+        )
+
+    def _kernel(self, sliding_window):
+        return partial(ops.paged_decode_attention,
+                       sliding_window=sliding_window,
+                       interpret=self.interpret)
+
+    def decode(self, kv: PagedKVCache, tables, positions):
+        cfg = self.cfg
+        raw = self.attn_impl == "pallas"
+        attn = None
+        if raw:
+            kernel = self._kernel(cfg.sliding_window)
+            if self.mesh is not None:
+                # per-device kernel over (slots/'data', heads/'model'):
+                # the stacked pool's layer and block axes stay whole on
+                # every device (table values are global block ids), its
+                # kv-head axis shards on 'model', and each data shard walks
+                # its own slots' SMEM table mirror — the shard_map body is
+                # the single-device kernel (select_paged_attn_impl refuses
+                # Pallas when the head groups don't split over tp)
+                # Of the last four arguments a pool has two: the f32
+                # scale stacks of a scaled one (fused dequant), or the
+                # step's rows, which the kernel writes into each shard's
+                # own heads of an unscaled one (the pools then come back,
+                # aliased, beside the output)
+                rows = P("data", "model", None)
+                pool = P(None, None, "model", None, None)
+                scale = P(None, None, "model", None)
+                kernel = shard_map(
+                    kernel,
+                    mesh=self.mesh,
+                    in_specs=(rows, pool, pool, P(), P("data", None),
+                              P("data"),
+                              *((scale, scale, None, None) if kv.quantized
+                                else (None, None, rows, rows))),
+                    out_specs=rows if kv.quantized else (rows, pool, pool),
+                    check_vma=False,
+                )
+            attn = scoped("attn.paged_decode")(
+                kernel_attend(kernel, tables, positions))
+        mask = decode_mask(cfg, positions, self.ctx)
+        if cfg.attn_kinds:
+            # a mask and an attend a KIND of layer: a window layer's kernel
+            # call walks its window's blocks alone, under a scope of its own
+            views = kind_views(cfg)
+            mask = {kind: decode_mask(view, positions, self.ctx)
+                    for kind, view in views}
+            if raw:
+                attn = {kind: scoped(
+                    "attn.window_decode" if view.sliding_window
+                    else "attn.paged_decode")(kernel_attend(
+                        self._kernel(view.sliding_window), tables, positions))
+                    for kind, view in views}
+        return paged_decode_write(tables, positions, raw=raw), attn, mask
+
+    def verify(self, tables, positions, T: int):
+        """The window's attend spans the padded context (every slot its own
+        prefix: no one span serves the batch; ``span_attend`` is the
+        single-sequence chunk's)."""
+        return (paged_verify_write(tables, positions, self.max_ctx),
+                verify_mask(self.cfg, positions, T, self.ctx))
+
+    def chunk(self, table_row, slot, positions, offset, length):
+        """The attend spans the rung of the ladder that covers ``offset`` +
+        the bucket, picked on the device: the mask is sliced to it."""
+        cfg, bucket = self.cfg, positions.shape[1]
+        mask = resume_mask(cfg, bucket, offset, self.ctx)
+        attn = span_attend(cfg, table_row, offset, self.ctx)
+        if cfg.attn_kinds:
+            # a window layer's chunk gathers its window of the prefix
+            views = kind_views(cfg)
+            mask = {kind: resume_mask(view, bucket, offset, self.ctx)
+                    for kind, view in views}
+            attn = {kind: (window_attend if view.sliding_window
+                           else span_attend)(
+                view, table_row, offset, self.ctx)
+                for kind, view in views}
+        return paged_prefill_write(table_row, offset, length), attn, mask

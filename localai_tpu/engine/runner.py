@@ -352,16 +352,9 @@ class ModelRunner:
         else:
             self.allocator = None
             self.overlap_mode = ""
-        # shardings are kept so reinit() (self-healing engine rebuild)
-        # can rebuild the device state into the exact same layout
-        self._kv_sharding = None
-        self._paged_sharding = None
-        self._table_sharding = None
         self._seed = seed
         self.kv_dtype = kv_dtype
         if mesh is not None:
-            from jax.sharding import NamedSharding
-
             from localai_tpu.models import quant as qnt
             from localai_tpu.parallel import sharding as shd
 
@@ -372,17 +365,20 @@ class ModelRunner:
             self.params = params = qnt.block_w8_kernel_params(
                 params, "runner built over a device mesh")
             shd.slots_per_data_shard(num_slots, mesh)  # divisibility check
-            if self.paged:
-                # pool kv-heads on 'model' (paged_kv_spec); the [S, MB]
-                # table mirror carries the 'data' sharding instead — the
-                # pool has no slot axis to put it on
-                self._paged_sharding = NamedSharding(
-                    mesh, shd.paged_kv_spec(cfg, mesh))
-                self._table_sharding = NamedSharding(
-                    mesh, shd.block_table_spec())
-            else:
-                self._kv_sharding = NamedSharding(
-                    mesh, shd.kv_spec(cfg, mesh))
+        # how the K/V is laid out, written, attended and masked: the one
+        # family of programs below asks the layout (engine.kvcache); it
+        # keeps its shardings, so reinit() (self-healing engine rebuild)
+        # rebuilds the device state into the exact same layout
+        if self.paged:
+            self.layout = kvc.PagedLayout(
+                cfg, mesh, kv_dtype, num_slots, self.max_ctx,
+                self.paged_attn_impl, self._paged_attn_interpret,
+                self.block_tokens, self.max_blocks,
+                self.allocator.num_blocks, self.overlap_mode)
+        else:
+            self.layout = kvc.ContiguousLayout(
+                cfg, mesh, kv_dtype, num_slots, self.max_ctx,
+                self.decode_attn_impl, self._attn_interpret, self._se_attn)
         self._init_device_state()
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -395,60 +391,45 @@ class ModelRunner:
         # wall time lands in the localai_xla_compile_* series (the
         # jax.monitoring listener supplements this where available)
         obs_compile.install()
-        self._decode = obs_compile.watch(
-            jax.jit(self._decode_fn, donate_argnums=(1, 2)), "decode"
-        )
-        self._decode_n = obs_compile.watch(jax.jit(
-            self._decode_n_fn, static_argnames=("n",), donate_argnums=(1, 2)
+        # ONE family over both layouts (the names are the block pool's: the
+        # cells' XLA modules are called by them), with the labels an
+        # operator's per-program series have had on each
+        self._decode_paged = obs_compile.watch(
+            jax.jit(self._decode_paged_fn, donate_argnums=(1, 2)), "decode")
+        self._decode_paged_n = obs_compile.watch(jax.jit(
+            self._decode_paged_n_fn, static_argnames=("n",),
+            donate_argnums=(1, 2),
         ), "decode_n")
-        self._decode_frozen_n = obs_compile.watch(jax.jit(
-            self._decode_frozen_n_fn, static_argnames=("n",),
+        self._decode_paged_frozen_n = obs_compile.watch(jax.jit(
+            self._decode_paged_frozen_n_fn, static_argnames=("n",),
             donate_argnums=(1, 2),
         ), "decode_frozen_n")
         # speculative verify (localai_tpu.spec): one batched T-wide target
         # forward scores a whole draft window per dispatch. One program per
         # gamma (the window width is baked into the proposals shape).
-        self._verify = obs_compile.watch(
-            jax.jit(self._verify_fn, donate_argnums=(1, 2)), "verify"
-        )
-        self._prefill = obs_compile.watch(jax.jit(
-            self._prefill_fn, static_argnames=("bucket",), donate_argnums=(1, 2)
-        ), "prefill")
-        self._prefill_mm = obs_compile.watch(jax.jit(
-            self._prefill_mm_fn, static_argnames=("bucket",),
+        self._verify_paged = obs_compile.watch(
+            jax.jit(self._verify_paged_fn, donate_argnums=(1, 2)), "verify")
+        self._prefill_paged = obs_compile.watch(jax.jit(
+            self._prefill_paged_fn, static_argnames=("bucket", "sample"),
             donate_argnums=(1, 2),
-        ), "prefill_mm")
-        self._prefill_resume = obs_compile.watch(jax.jit(
-            self._prefill_resume_fn, static_argnames=("bucket",),
-            donate_argnums=(1, 2),
-        ), "prefill_resume")
+        ), "prefill_chunk" if self.paged else "prefill_resume")
+        # the fresh whole-prompt prefills stay a layout's own (ROADMAP C1b):
+        # a multimodal one and, over a 'seq' mesh axis, a ring-attention one
+        # (one long prompt uses every chip without stalling decode — chosen
+        # by admit / begin_admit), under the same labels on both
+        def fresh(fn, label):
+            return obs_compile.watch(jax.jit(
+                fn, static_argnames=("bucket",), donate_argnums=(1, 2)), label)
+
         if self.paged:
-            # paged variants keep the contiguous programs' obs labels so
-            # the cost observatory's per-program series stay comparable
-            # across layouts; the chunked prefill gets its own label.
-            self._decode_paged = obs_compile.watch(
-                jax.jit(self._decode_paged_fn, donate_argnums=(1, 2)),
-                "decode")
-            self._decode_paged_n = obs_compile.watch(jax.jit(
-                self._decode_paged_n_fn, static_argnames=("n",),
-                donate_argnums=(1, 2),
-            ), "decode_n")
-            self._decode_paged_frozen_n = obs_compile.watch(jax.jit(
-                self._decode_paged_frozen_n_fn, static_argnames=("n",),
-                donate_argnums=(1, 2),
-            ), "decode_frozen_n")
-            self._verify_paged = obs_compile.watch(
-                jax.jit(self._verify_paged_fn, donate_argnums=(1, 2)),
-                "verify")
-            self._prefill_paged = obs_compile.watch(jax.jit(
-                self._prefill_paged_fn,
-                static_argnames=("bucket", "sample"),
-                donate_argnums=(1, 2),
-            ), "prefill_chunk")
-            self._prefill_paged_mm = obs_compile.watch(jax.jit(
-                self._prefill_paged_mm_fn, static_argnames=("bucket",),
-                donate_argnums=(1, 2),
-            ), "prefill_mm")
+            self._prefill_paged_mm = fresh(self._prefill_paged_mm_fn,
+                                           "prefill_mm")
+            self._prefill_paged_sp = fresh(self._prefill_paged_sp_fn,
+                                           "prefill_sp")
+        else:
+            self._prefill = fresh(self._prefill_fn, "prefill")
+            self._prefill_mm = fresh(self._prefill_mm_fn, "prefill_mm")
+            self._prefill_sp = fresh(self._prefill_sp_fn, "prefill_sp")
         # sequence-parallel prefill: long prompts chunk over the 'seq' mesh
         # axis and run ring attention (parallel.ring) straight into the
         # slot cache. Composes with TP: weights stay 'model'-sharded
@@ -475,18 +456,6 @@ class ModelRunner:
         )
         self.sp_threshold = sp_threshold
         self.last_prefill_path = ""
-        self._prefill_sp = obs_compile.watch(jax.jit(
-            self._prefill_sp_fn, static_argnames=("bucket",),
-            donate_argnums=(1, 2),
-        ), "prefill_sp")
-        if self.paged:
-            # ring-attention prefill straight into the sharded block pool
-            # (one long prompt uses every chip without stalling decode —
-            # chosen by begin_admit when the mesh has a 'seq' axis)
-            self._prefill_paged_sp = obs_compile.watch(jax.jit(
-                self._prefill_paged_sp_fn, static_argnames=("bucket",),
-                donate_argnums=(1, 2),
-            ), "prefill_sp")
         self._embed = obs_compile.watch(
             jax.jit(self._embed_fn, static_argnames=("bucket",)), "embed"
         )
@@ -517,21 +486,16 @@ class ModelRunner:
         after a suspected device wedge — params, compiled programs, and
         shardings are untouched, so no retrace/recompile happens."""
         cfg = self.cfg
-        if self.paged:
+        # the cache and, over the pool, the table mirror (None over the
+        # contiguous rows: the slot programs take it as it is)
+        self.kv, self.block_tables = self.layout.init()
+        if self.allocator is not None:
             self.allocator = pgd.BlockAllocator(
                 self.allocator.num_blocks, self.block_tokens,
                 self.max_blocks)
             # disk prompt-cache rows loaded into a slot's fresh blocks
             # (the only slot-resident reuse that survives release)
             self._loaded_rows: dict[int, int] = {}
-            tables = jnp.zeros((self.num_slots, self.max_blocks), jnp.int32)
-            if self._table_sharding is not None:
-                tables = jax.device_put(tables, self._table_sharding)
-            self.block_tables = tables
-            self.kv = kvc.init_paged_cache(
-                cfg, self.allocator.num_blocks, self.block_tokens,
-                self.kv_dtype, sharding=self._paged_sharding,
-            )
             # HBM→host prefix-pool tiering (LOCALAI_KV_TIER_MB, off by
             # default): LRU pool evictions spill their raw block rows to
             # host RAM and re-onboard on a later chain hit. Rebuilt with
@@ -544,12 +508,6 @@ class ModelRunner:
             if tier is not None:
                 self.allocator.attach_tier(
                     tier, pack=self.pack_block, load=self.load_block)
-        else:
-            self.block_tables = None    # the slot programs take it as it is
-            self.kv = kvc.init_cache(
-                cfg, self.num_slots, self.max_ctx, self.kv_dtype,
-                sharding=self._kv_sharding,
-            )
         state = DecodeState.init(self.num_slots, cfg.vocab_size, self._seed,
                                  rec=self._init_rec(self.num_slots))
         if self.mesh is not None:
@@ -617,65 +575,9 @@ class ModelRunner:
 
     # -- jitted programs -------------------------------------------------
 
-    @scoped("decode")
-    def _decode_fn(self, params, kv: KVCache, state: DecodeState):
-        cfg = self.cfg
-        pos = state.positions
-        attn = None
-        raw_kv = self.decode_attn_impl == "pallas"
-        if raw_kv:
-            from localai_tpu import ops
-
-            kernel = partial(
-                ops.decode_attention,
-                sliding_window=cfg.sliding_window,
-                interpret=self._attn_interpret,
-            )
-            if self.mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                # per-device kernel over (slots/'data', heads/'model'):
-                # decode attention is independent across slots and head
-                # groups, so the shard_map body is the single-device kernel
-                # (the stacked cache's layer axis whole on every device)
-                in_specs = [P("data", "model", None),
-                            P(None, "data", "model", None, None),
-                            P(None, "data", "model", None, None),
-                            P(),
-                            P("data")]
-                if kv.quantized:
-                    in_specs += [P(None, "data", "model", None),
-                                 P(None, "data", "model", None)]
-                kernel = shard_map(
-                    kernel,
-                    mesh=self.mesh,
-                    in_specs=tuple(in_specs),
-                    out_specs=P("data", "model", None),
-                    check_vma=False,
-                )
-
-            @scoped("attn.decode")
-            def attn(q, keys, values, _mask):  # q [S,1,Hq,hd]; kvc.LayerViews
-                args = (q[:, 0], keys.cache, values.cache, keys.layer, pos)
-                if kv.quantized:  # f32 scale stacks — fused dequant
-                    args += (keys.scale, values.scale)
-                return kernel(*args)[:, None]
-
-        if attn is None:
-            attn = self._se_attn(
-                pos[:, None], jnp.arange(self.max_ctx, dtype=jnp.int32))
-        mask = kvc.decode_mask(cfg, pos, self.max_ctx)
-        write = kvc.decode_write(pos, raw=raw_kv)
-        hidden, new_stack = self._forward(
-            params, state.tokens[:, None], pos[:, None],
-            write, kv.stacked(), mask, attn=attn,
-        )
-        new_state, tokens = self._decode_tail(params, state, hidden)
-        return KVCache.from_stacked(new_stack), new_state, tokens
-
     def _decode_tail(self, params, state: DecodeState, hidden):
-        """Sampling + per-slot state advance shared by the contiguous and
-        paged decode programs (KV-layout-independent)."""
+        """Sampling + per-slot state advance of the decode step
+        (KV-layout-independent)."""
         pos = state.positions
         logits = mdl.logits_from_hidden(self.cfg, params, hidden[:, 0])
         tokens, keys = smp.sample(
@@ -779,94 +681,66 @@ class ModelRunner:
         return new_state, emitted
 
     @scoped("verify")
-    def _verify_fn(self, params, kv: KVCache, state: DecodeState,
-                   proposals):
-        """One speculative verify dispatch over the contiguous cache: a
-        T=gamma+1-wide batched forward scores every draft position at each
-        slot's frontier (positions offset per slot — decode generalized to
-        T tokens), then the accept/sample scan emits the accepted prefix +
-        correction. proposals [S, gamma] i32; returns emitted [T, S]."""
+    def _verify_paged_fn(self, params, kv, state: DecodeState, tables,
+                         proposals):
+        """One speculative verify dispatch: a T=gamma+1-wide batched forward
+        scores every draft position at each slot's frontier (positions
+        offset per slot — decode generalized to T tokens), then the
+        accept/sample scan emits the accepted prefix + correction. The
+        window's rows go where the layout's write puts them (over the pool:
+        through the block tables into each slot's reserved speculation
+        blocks) and attend over the prefix + the window so far; the accept
+        scan rolls every slot's frontier back independently — the rejected
+        tail is a per-slot position rollback, never a table mutation
+        (co-batched slots are untouched by construction). proposals
+        [S, gamma] i32; returns emitted [T, S]."""
         cfg = self.cfg
         T = proposals.shape[1] + 1
         p0 = state.positions
         positions = p0[:, None] + jnp.arange(T)[None, :]     # [S, T]
         tokens = jnp.concatenate(
             [state.tokens[:, None], proposals], axis=1)      # [S, T]
-        mask = kvc.verify_mask(cfg, p0, T, self.max_ctx)
-        write = kvc.verify_write(p0)
+        write, mask = self.layout.verify(tables, p0, T)
         hidden, new_stack = self._forward(
             params, tokens, positions, write, kv.stacked(), mask,
         )
         logits = mdl.logits_from_hidden(cfg, params, hidden)  # [S, T, V]
         new_state, emitted = self._accept_scan(state, logits, proposals)
-        return KVCache.from_stacked(new_stack), new_state, emitted
+        return self.layout.from_stacked(new_stack), new_state, emitted
 
-    @scoped("verify")
-    def _verify_paged_fn(self, params, kv: kvc.PagedKVCache,
-                         state: DecodeState, tables, proposals):
-        """Paged twin of _verify_fn: draft rows scatter through the block
-        tables into each slot's reserved speculation blocks, window tokens
-        attend resume-style over the gathered prefix + window, and the
-        accept scan rolls every slot's frontier back independently — the
-        rejected tail is a per-slot position rollback, never a table
-        mutation (co-batched slots are untouched by construction)."""
-        cfg = self.cfg
-        T = proposals.shape[1] + 1
-        p0 = state.positions
-        positions = p0[:, None] + jnp.arange(T)[None, :]     # [S, T]
-        tokens = jnp.concatenate(
-            [state.tokens[:, None], proposals], axis=1)      # [S, T]
-        # the window's attend spans the padded context (every slot its own
-        # prefix: no one span serves the batch; kvcache.span_attend is the
-        # single-sequence chunk's)
-        mask = kvc.verify_mask(cfg, p0, T, self.ctx_pad)
-        write = kvc.paged_verify_write(tables, p0, self.max_ctx)
-        hidden, new_stack = self._forward(
-            params, tokens, positions, write, kv.stacked(), mask,
+    def _first_token(self, params, state: DecodeState, hidden, length, slot,
+                     offset=None, counts_row=None, prompt=None):
+        """What ends every prefill: take the row at ``length - 1`` of
+        ``hidden [1, T, D]``, sample it with the slot's parameters and arm
+        the slot at its frontier, ``length`` behind the ``offset`` cached
+        tokens of a chunk (None: a fresh prompt). The slot's penalty counts are
+        ``counts_row`` ([V] i32, the host's bincount of the FULL prompt: a
+        program that sees a tail of it cannot count) or, where none comes,
+        ``prompt``'s ([T], or [1, T] as the forward took it) first ``length``
+        tokens counted here. Returns the armed state and the token, [1]."""
+        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
+                                              keepdims=True)
+        logits = mdl.logits_from_hidden(self.cfg, params, last_h)  # [1, V]
+        if counts_row is None:
+            counts = smp.count_prompt_tokens(
+                state.counts, slot, prompt[0] if prompt.ndim == 2 else prompt,
+                length)
+        else:
+            counts = state.counts.at[slot].set(counts_row)
+        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
+        tok, new_key = smp.sample(
+            logits, slot_params, counts[slot][None], state.keys[slot][None],
+            state.bias[slot][None], mesh=self.mesh,
         )
-        logits = mdl.logits_from_hidden(cfg, params, hidden)  # [S, T, V]
-        new_state, emitted = self._accept_scan(state, logits, proposals)
-        return kvc.PagedKVCache.from_stacked(new_stack), new_state, emitted
-
-    def _decode_n_fn(self, params, kv: KVCache, state: DecodeState, *, n: int):
-        """n decode steps in ONE dispatch via lax.scan — one host→device
-        dispatch and one result fetch per n tokens. Returns tokens [n, S]."""
-
-        def body(carry, _):
-            kv, state = carry
-            kv, state, tokens = self._decode_fn(params, kv, state)
-            return (kv, state), tokens
-
-        (kv, state), tokens = jax.lax.scan(
-            body, (kv, state), None, length=n
-        )
-        return kv, state, tokens
-
-    def _decode_frozen_n_fn(self, params, kv: KVCache, state: DecodeState,
-                            freeze, *, n: int):
-        """n decode steps in one dispatch where slots in ``freeze`` advance
-        only on the FIRST step — the per-slot constraint gating path: a
-        grammar-constrained slot needs its logit mask refreshed by the host
-        between tokens (so it gets one token per dispatch), while the
-        unconstrained slots ride the same dispatch for n tokens. Replaces the
-        whole-batch synchronous fallback (one constrained request no longer
-        de-pipelines the batch). Returns tokens [n, S]; rows 1..n-1 are only
-        meaningful for non-frozen slots."""
-        full_active = state.active
-
-        def body(carry, i):
-            kv, st = carry
-            eff = jnp.where(i == 0, full_active, full_active & ~freeze)
-            kv, st, tokens = self._decode_fn(
-                params, kv, dataclasses.replace(st, active=eff)
-            )
-            st = dataclasses.replace(st, active=full_active)
-            return (kv, st), tokens
-
-        (kv, state), tokens = jax.lax.scan(
-            body, (kv, state), jnp.arange(n), length=n
-        )
-        return kv, state, tokens
+        return dataclasses.replace(
+            state,
+            tokens=state.tokens.at[slot].set(tok[0]),
+            positions=state.positions.at[slot].set(
+                length if offset is None else offset + length),
+            active=state.active.at[slot].set(True),
+            keys=state.keys.at[slot].set(new_key[0]),
+            counts=counts,
+        ), tok
 
     @scoped("prefill")
     def _prefill_fn(self, params, kv: KVCache, state: DecodeState,
@@ -881,22 +755,8 @@ class ModelRunner:
             params, tokens, positions, write, kv.stacked(), mask,
             attn=attn, embeds=embeds,
         )
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1, keepdims=True)
-        logits = mdl.logits_from_hidden(cfg, params, last_h)  # [1, V]
-        counts = smp.count_prompt_tokens(state.counts, slot, tokens[0], length)
-        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
-        tok, new_key = smp.sample(
-            logits, slot_params, counts[slot][None], state.keys[slot][None],
-            state.bias[slot][None], mesh=self.mesh,
-        )
-        new_state = dataclasses.replace(
-            state,
-            tokens=state.tokens.at[slot].set(tok[0]),
-            positions=state.positions.at[slot].set(length),
-            active=state.active.at[slot].set(True),
-            keys=state.keys.at[slot].set(new_key[0]),
-            counts=counts,
-        )
+        new_state, tok = self._first_token(
+            params, state, hidden, length, slot, prompt=tokens)
         return KVCache.from_stacked(new_stack), new_state, tok[0]
 
     def _prefill_mm_fn(self, params, kv: KVCache, state: DecodeState,
@@ -917,48 +777,6 @@ class ModelRunner:
         return self._prefill_fn(
             params, kv, state, tokens, length, slot, bucket=bucket, embeds=x
         )
-
-    @scoped("prefill")
-    def _prefill_resume_fn(self, params, kv: KVCache, state: DecodeState,
-                           tokens, length, offset, slot, counts_row,
-                           *, bucket: int):
-        """Suffix prefill: the slot keeps ``offset`` tokens of reused prefix
-        KV; only the tail chunk is computed, attending over prefix + chunk
-        (XLA path — keys span the full cache row, which the fresh-chunk
-        Pallas prefill kernel does not model). ``counts_row`` [V] i32 is the
-        host-side bincount of the FULL prompt (the in-program count would
-        only see the tail); it rides this dispatch so resume stays a single
-        program launch."""
-        cfg = self.cfg
-        positions = offset + jnp.arange(bucket, dtype=jnp.int32)[None, :]
-        attn = self._se_attn(
-            positions, jnp.arange(self.max_ctx, dtype=jnp.int32))
-        # the contiguous cache's resume attends the slot's whole row, as it
-        # did (the span of a chunk's attend is cut on the paged path alone:
-        # kvcache.span_attend)
-        mask = kvc.resume_mask(cfg, bucket, offset, self.max_ctx)
-        write = kvc.resume_write(slot, offset)
-        hidden, new_stack = self._forward(
-            params, tokens, positions, write, kv.stacked(), mask, attn=attn,
-        )
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
-                                              keepdims=True)
-        logits = mdl.logits_from_hidden(cfg, params, last_h)  # [1, V]
-        counts = state.counts.at[slot].set(counts_row)
-        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
-        tok, new_key = smp.sample(
-            logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
-        )
-        new_state = dataclasses.replace(
-            state,
-            tokens=state.tokens.at[slot].set(tok[0]),
-            positions=state.positions.at[slot].set(offset + length),
-            active=state.active.at[slot].set(True),
-            keys=state.keys.at[slot].set(new_key[0]),
-            counts=counts,
-        )
-        return KVCache.from_stacked(new_stack), new_state, tok[0]
 
     @scoped("prefill")
     def _prefill_sp_fn(self, params, kv: KVCache, state: DecodeState,
@@ -995,23 +813,8 @@ class ModelRunner:
                 k=jax.lax.dynamic_update_slice(kv.k, k_hm.astype(kdt), idx),
                 v=jax.lax.dynamic_update_slice(kv.v, v_hm.astype(kdt), idx),
             )
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
-                                              keepdims=True)
-        logits = mdl.logits_from_hidden(cfg, params, last_h)  # [1, V]
-        counts = smp.count_prompt_tokens(state.counts, slot, tokens, length)
-        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
-        tok, new_key = smp.sample(
-            logits, slot_params, counts[slot][None], state.keys[slot][None],
-            state.bias[slot][None], mesh=self.mesh,
-        )
-        new_state = dataclasses.replace(
-            state,
-            tokens=state.tokens.at[slot].set(tok[0]),
-            positions=state.positions.at[slot].set(length),
-            active=state.active.at[slot].set(True),
-            keys=state.keys.at[slot].set(new_key[0]),
-            counts=counts,
-        )
+        new_state, tok = self._first_token(
+            params, state, hidden, length, slot, prompt=tokens)
         return new_kv, new_state, tok[0]
 
     # -- slot lifecycle programs (both layouts) ---------------------------
@@ -1050,91 +853,23 @@ class ModelRunner:
             tables = tables.at[slot].set(0)
         return state, tables
 
-    # -- paged programs (block-pool KV; engine.paged / kvcache.Paged*) ---
+    # -- the serving programs: one family over the runner's layout --------
+    # (named for the block pool's: the cells' XLA modules, the benchmark's
+    # readers and its tests call them so; ROADMAP C1b)
 
     @scoped("decode")
-    def _decode_paged_fn(self, params, kv: kvc.PagedKVCache,
-                         state: DecodeState, tables):
-        """Batched single-token decode over the block pool. ``tables``
-        [S, MB] i32 is the device mirror of the allocator's block tables
-        (not donated — it changes only at admit/release)."""
-        cfg = self.cfg
+    def _decode_paged_fn(self, params, kv, state: DecodeState, tables):
+        """Batched single-token decode. ``tables`` [S, MB] i32 is the device
+        mirror of the allocator's block tables (not donated — it changes
+        only at admit/release), None over the contiguous rows."""
         pos = state.positions
         if self.overlap_mode:
-            # manual-TP trunk with decomposed per-layer reductions
-            # (parallel.overlap); sampling/logits keep the GSPMD tail
-            from localai_tpu.parallel import overlap as ovl
-
-            trunk = {k: params[k] for k in ovl.TRUNK_KEYS}
-            hidden, new_stack = ovl.paged_decode_trunk(
-                cfg, trunk, self.mesh, state.tokens, pos,
-                kv.stacked(), tables, self.rope,
-                ctx_pad=self.ctx_pad,
-                mode=self.overlap_mode,
-                use_pallas=self.paged_attn_impl == "pallas",
-                interpret=self._paged_attn_interpret,
-            )
+            # the pool's manual-TP trunk; sampling/logits keep the GSPMD tail
+            hidden, new_stack = self.layout.tp_trunk(
+                params, self.rope, state.tokens, pos, kv, tables)
             new_state, tokens = self._decode_tail(params, state, hidden)
-            return (kvc.PagedKVCache.from_stacked(new_stack), new_state,
-                    tokens)
-        raw = self.paged_attn_impl == "pallas"
-        attn = None
-        if raw:
-            from localai_tpu import ops
-
-            kernel = partial(
-                ops.paged_decode_attention,
-                sliding_window=cfg.sliding_window,
-                interpret=self._paged_attn_interpret,
-            )
-            if self.mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                # per-device kernel over (slots/'data', heads/'model'):
-                # the stacked pool's layer and block axes stay whole on
-                # every device (table values are global block ids), its
-                # kv-head axis shards on 'model', and each data shard walks
-                # its own slots' SMEM table mirror — the shard_map body is
-                # the single-device kernel (select_paged_attn_impl refuses
-                # Pallas when the head groups don't split over tp)
-                # Of the last four arguments a pool has two: the f32
-                # scale stacks of a scaled one (fused dequant), or the
-                # step's rows, which the kernel writes into each shard's
-                # own heads of an unscaled one (the pools then come back,
-                # aliased, beside the output)
-                rows = P("data", "model", None)
-                pool = P(None, None, "model", None, None)
-                scale = P(None, None, "model", None)
-                kernel = shard_map(
-                    kernel,
-                    mesh=self.mesh,
-                    in_specs=(rows, pool, pool, P(), P("data", None),
-                              P("data"),
-                              *((scale, scale, None, None) if kv.quantized
-                                else (None, None, rows, rows))),
-                    out_specs=rows if kv.quantized else (rows, pool, pool),
-                    check_vma=False,
-                )
-
-            attn = scoped("attn.paged_decode")(
-                kvc.kernel_attend(kernel, tables, pos))
-
-        mask = kvc.decode_mask(cfg, pos, self.ctx_pad)
-        if self.kinds:
-            # a mask and an attend a KIND of layer: a window layer's kernel
-            # call walks its window's blocks alone, under a scope of its own
-            views = self._kind_views()
-            mask = {kind: kvc.decode_mask(view, pos, self.ctx_pad)
-                    for kind, view in views}
-            if raw:
-                attn = {kind: scoped(
-                    "attn.window_decode" if view.sliding_window
-                    else "attn.paged_decode")(kvc.kernel_attend(partial(
-                        ops.paged_decode_attention,
-                        sliding_window=view.sliding_window,
-                        interpret=self._paged_attn_interpret), tables, pos))
-                    for kind, view in views}
-        write = kvc.paged_decode_write(tables, pos, raw=raw)
+            return self.layout.from_stacked(new_stack), new_state, tokens
+        write, attn, mask = self.layout.decode(kv, tables, pos)
         if self.routed:
             # a slot with no stream is the identity on its state; the step's
             # routed work rides behind the S sampled tokens, in their copy
@@ -1144,19 +879,20 @@ class ModelRunner:
                 attn=attn)
             new_state, tokens = self._decode_tail(
                 params, dataclasses.replace(state, rec=rec), hidden)
-            return (kvc.PagedKVCache.from_stacked(new_stack), new_state,
+            return (self.layout.from_stacked(new_stack), new_state,
                     jnp.concatenate([tokens, routed]))
         hidden, new_stack = self._forward(
             params, state.tokens[:, None], pos[:, None],
             write, kv.stacked(), mask, attn=attn,
         )
         new_state, tokens = self._decode_tail(params, state, hidden)
-        return kvc.PagedKVCache.from_stacked(new_stack), new_state, tokens
+        return self.layout.from_stacked(new_stack), new_state, tokens
 
     def _decode_paged_n_fn(self, params, kv, state, tables, *, n: int):
-        """n paged decode steps in one dispatch (lax.scan) — the paged
-        twin of _decode_n_fn. The block tables are loop-invariant: every
-        admitted slot's table already covers its full reservation."""
+        """n decode steps in ONE dispatch via lax.scan — one host→device
+        dispatch and one result fetch per n tokens. Returns tokens [n, S].
+        The block tables are loop-invariant: every admitted slot's table
+        already covers its full reservation."""
 
         def body(carry, _):
             kv, state = carry
@@ -1169,7 +905,14 @@ class ModelRunner:
 
     def _decode_paged_frozen_n_fn(self, params, kv, state, tables, freeze,
                                   *, n: int):
-        """Paged twin of _decode_frozen_n_fn (see its docstring)."""
+        """n decode steps in one dispatch where slots in ``freeze`` advance
+        only on the FIRST step — the per-slot constraint gating path: a
+        grammar-constrained slot needs its logit mask refreshed by the host
+        between tokens (so it gets one token per dispatch), while the
+        unconstrained slots ride the same dispatch for n tokens. Replaces the
+        whole-batch synchronous fallback (one constrained request no longer
+        de-pipelines the batch). Returns tokens [n, S]; rows 1..n-1 are only
+        meaningful for non-frozen slots."""
         full_active = state.active
 
         def body(carry, i):
@@ -1190,28 +933,19 @@ class ModelRunner:
     def _prefill_paged_fn(self, params, kv, state, tokens, length, offset,
                           table_row, slot, counts_row, *, bucket: int,
                           sample: bool, embeds=None):
-        """One chunked-prefill dispatch: write ``length`` real tokens of the
-        chunk at absolute positions [offset, offset+length) through the
-        slot's block table, attending resume-style over the gathered prefix
-        + chunk. Non-final chunks (``sample=False``) leave the decode state
-        untouched; the final chunk samples the first token and arms the
-        slot exactly like the contiguous prefill paths."""
-        cfg = self.cfg
+        """One chunk behind ``offset`` cached tokens: write its ``length``
+        real tokens at absolute positions [offset, offset+length) (over the
+        pool through ``table_row``, the slot's block table; over the
+        contiguous rows, where ``table_row`` is None, into ``slot``'s row),
+        attending resume-style over the prefix + chunk. Non-final chunks
+        (``sample=False``) leave the decode state untouched; the final chunk
+        samples the first token and arms the slot exactly like the fresh
+        prefill paths. ``counts_row`` [V] i32 is the host-side bincount of
+        the FULL prompt; it rides this dispatch so the final chunk stays a
+        single program launch."""
         positions = offset + jnp.arange(bucket, dtype=jnp.int32)[None, :]
-        mask = kvc.resume_mask(cfg, bucket, offset, self.ctx_pad)
-        # the attend spans the rung of the ladder that covers offset +
-        # bucket, picked on the device: the mask is sliced to it
-        write = kvc.paged_prefill_write(table_row, offset, length)
-        attn = kvc.span_attend(cfg, table_row, offset, self.ctx_pad)
-        if self.kinds:
-            # a window layer's chunk gathers its window of the prefix
-            views = self._kind_views()
-            mask = {kind: kvc.resume_mask(view, bucket, offset, self.ctx_pad)
-                    for kind, view in views}
-            attn = {kind: (kvc.window_attend if view.sliding_window
-                           else kvc.span_attend)(
-                view, table_row, offset, self.ctx_pad)
-                for kind, view in views}
+        write, attn, mask = self.layout.chunk(table_row, slot, positions,
+                                              offset, length)
         routed = None
         if self.routed:
             # the chunk goes on from the slot's state at ``offset``: zero at
@@ -1232,26 +966,11 @@ class ModelRunner:
                 params, tokens, positions, write, kv.stacked(), mask,
                 attn=attn, embeds=embeds,
             )
-        new_kv = kvc.PagedKVCache.from_stacked(new_stack)
+        new_kv = self.layout.from_stacked(new_stack)
         if not sample:
             return new_kv, state, jnp.zeros((), jnp.int32)
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
-                                              keepdims=True)
-        logits = mdl.logits_from_hidden(cfg, params, last_h)  # [1, V]
-        counts = state.counts.at[slot].set(counts_row)
-        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
-        tok, new_key = smp.sample(
-            logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
-        )
-        new_state = dataclasses.replace(
-            state,
-            tokens=state.tokens.at[slot].set(tok[0]),
-            positions=state.positions.at[slot].set(offset + length),
-            active=state.active.at[slot].set(True),
-            keys=state.keys.at[slot].set(new_key[0]),
-            counts=counts,
-        )
+        new_state, tok = self._first_token(
+            params, state, hidden, length, slot, offset, counts_row)
         if routed is not None:
             return new_kv, new_state, jnp.concatenate([tok, routed])
         return new_kv, new_state, tok[0]
@@ -1337,23 +1056,8 @@ class ModelRunner:
                 v=kv.v.at[:, blk, :, off].set(
                     vs.transpose(1, 0, 2, 3).astype(kdt)),
             )
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
-                                              keepdims=True)
-        logits = mdl.logits_from_hidden(cfg, params, last_h)  # [1, V]
-        counts = state.counts.at[slot].set(counts_row)
-        slot_params = jax.tree.map(lambda a: a[slot][None], state.params)
-        tok, new_key = smp.sample(
-            logits, slot_params, counts[slot][None],
-            state.keys[slot][None], state.bias[slot][None], mesh=self.mesh,
-        )
-        new_state = dataclasses.replace(
-            state,
-            tokens=state.tokens.at[slot].set(tok[0]),
-            positions=state.positions.at[slot].set(length),
-            active=state.active.at[slot].set(True),
-            keys=state.keys.at[slot].set(new_key[0]),
-            counts=counts,
-        )
+        new_state, tok = self._first_token(
+            params, state, hidden, length, slot, counts_row=counts_row)
         return new_kv, new_state, tok[0]
 
     def _embed_fn(self, params, tokens, length, *, bucket: int):
@@ -1375,7 +1079,7 @@ class ModelRunner:
             positions, positions[0])
         if self.kinds:      # the XLA attend under each kind's mask
             mask = {kind: kvc.prefill_mask(view, bucket, length)
-                    for kind, view in self._kind_views()}
+                    for kind, view in kvc.kind_views(cfg)}
             attn = None
         if self.routed:
             hidden, *_ = self._forward_rec(
@@ -1396,7 +1100,9 @@ class ModelRunner:
 
     def _se_attn(self, qpos, kpos):
         """Self-extend attend for the XLA paths (None when ga_n == 1) —
-        the single construction point for all four call sites."""
+        the single construction point: the fresh prefill and the embedding
+        here, the decode step and the chunk through the contiguous layout,
+        which is handed it."""
         if self.ga_n <= 1:
             return None
         from localai_tpu.engine import selfextend as se
@@ -1448,12 +1154,6 @@ class ModelRunner:
         else:
             hidden, new_stack, routed = forward()
         return hidden, new_stack, {**rec, "routed": carried}, routed
-
-    def _kind_views(self) -> list:
-        """(kind, what engine.kvcache's masks and attends read of the
-        config for that kind of layer) for each of ``cfg.attn_kinds``."""
-        return [(kind, kvc.KindView(self.cfg.hd, window))
-                for kind, window in self.kinds]
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""
@@ -1611,10 +1311,11 @@ class ModelRunner:
         elif lcp:
             self.last_prefill_path = "resume"
             crow = _prompt_counts_row(self.cfg.vocab_size, prompt)
-            self.kv, self.state, tok = self._prefill_resume(
+            self.kv, self.state, tok = self._prefill_paged(
                 self.params, self.kv, self.state,
                 jnp.asarray(padded), jnp.int32(len(tail)), jnp.int32(lcp),
-                jnp.int32(slot), jnp.asarray(crow), bucket=bucket,
+                None, jnp.int32(slot), jnp.asarray(crow), bucket=bucket,
+                sample=True,
             )
         elif mm_embeds is not None and len(mm_embeds):
             self.last_prefill_path = "mm"
@@ -1849,13 +1550,8 @@ class ModelRunner:
     def step_async(self) -> jax.Array:
         """Like step() but returns the device array without synchronizing —
         callers overlap the host read with the next dispatch."""
-        if self.paged:
-            self.kv, self.state, tokens = self._decode_paged(
-                self.params, self.kv, self.state, self.block_tables
-            )
-            return tokens
-        self.kv, self.state, tokens = self._decode(
-            self.params, self.kv, self.state
+        self.kv, self.state, tokens = self._decode_paged(
+            self.params, self.kv, self.state, self.block_tables
         )
         return tokens
 
@@ -1864,8 +1560,8 @@ class ModelRunner:
         [S, gamma] draft ``proposals`` with a single gamma+1-wide target
         forward, accept/sample on device, and return the [gamma+1, S]
         emitted-token device array (SKIP = nothing for that step/slot).
-        Works on both KV layouts; the paged variant writes the window
-        through the block-table mirror and rolls rejected tails back
+        Works on both KV layouts; over the pool the window is written
+        through the block-table mirror and rejected tails roll back
         per slot. No host sync — callers overlap the read."""
         if self.recurrent:
             # a rejected draft token has already moved the state it met
@@ -1874,14 +1570,8 @@ class ModelRunner:
             raise ValueError(
                 _kinds_refusal(self.cfg, "speculative decoding"))
         proposals = jnp.asarray(proposals, jnp.int32)
-        if self.paged:
-            self.kv, self.state, emitted = self._verify_paged(
-                self.params, self.kv, self.state, self.block_tables,
-                proposals,
-            )
-            return emitted
-        self.kv, self.state, emitted = self._verify(
-            self.params, self.kv, self.state, proposals
+        self.kv, self.state, emitted = self._verify_paged(
+            self.params, self.kv, self.state, self.block_tables, proposals,
         )
         return emitted
 
@@ -1901,13 +1591,8 @@ class ModelRunner:
     def step_n_async(self, n: int) -> jax.Array:
         """Like step_n() but returns the [n, S] device array without
         synchronizing — callers overlap the host read with later dispatches."""
-        if self.paged:
-            self.kv, self.state, tokens = self._decode_paged_n(
-                self.params, self.kv, self.state, self.block_tables, n=n
-            )
-            return tokens
-        self.kv, self.state, tokens = self._decode_n(
-            self.params, self.kv, self.state, n=n
+        self.kv, self.state, tokens = self._decode_paged_n(
+            self.params, self.kv, self.state, self.block_tables, n=n
         )
         return tokens
 
@@ -1915,16 +1600,10 @@ class ModelRunner:
         """n decode iterations where ``freeze``-masked slots advance only on
         the first; returns tokens [n, S] (rows 1+ stale for frozen slots)."""
         t0 = time.perf_counter()
-        if self.paged:
-            self.kv, self.state, tokens = self._decode_paged_frozen_n(
-                self.params, self.kv, self.state, self.block_tables,
-                jnp.asarray(freeze, jnp.bool_), n=n,
-            )
-        else:
-            self.kv, self.state, tokens = self._decode_frozen_n(
-                self.params, self.kv, self.state,
-                jnp.asarray(freeze, jnp.bool_), n=n,
-            )
+        self.kv, self.state, tokens = self._decode_paged_frozen_n(
+            self.params, self.kv, self.state, self.block_tables,
+            jnp.asarray(freeze, jnp.bool_), n=n,
+        )
         # synchronous by contract: the frozen slots' constraint masks need
         # the sampled token on the host before the next dispatch
         t1 = time.perf_counter()
@@ -1961,7 +1640,7 @@ class ModelRunner:
         )
 
     def release(self, slot: int) -> None:
-        if self.paged:
+        if self.allocator is not None:
             # free the slot's blocks (prompt blocks registered in the
             # prefix pool survive as reclaimable cache); the program below
             # points the device table row at the trash block
@@ -1975,13 +1654,10 @@ class ModelRunner:
 
     @property
     def paged_kv_write_impl(self) -> str:
-        """Who writes a decode step's new K/V rows into the block pool:
-        ``kernel``, the Pallas paged kernel that reads them (an unscaled
-        pool: ``kvcache.paged_decode_write``), else the policy's
+        """Who writes a decode step's new K/V rows into the cache (the
+        layout's ``kv_write_impl``): ``kernel`` or the policy's
         ``scatter``."""
-        if self.paged_attn_impl == "pallas" and not self.kv.quantized:
-            return "kernel"
-        return "scatter"
+        return self.layout.kv_write_impl
 
     @property
     def state_bytes(self) -> int:
@@ -2034,7 +1710,7 @@ class ModelRunner:
         payload (BlockAllocator tiering). Rows keep the pool dtype
         byte-exact: bf16 stays bf16, int4 stays nibble-packed (half the
         f32 bytes), so spill→reload is an identity round-trip."""
-        if not self.paged:
+        if self.allocator is None:
             return None
         kv = self.kv
         out = {"k": np.asarray(kv.k[:, bid]), "v": np.asarray(kv.v[:, bid])}

@@ -248,7 +248,7 @@ class ModelDrafter:
             jax.jit(self._draft_fn, donate_argnums=(1, 2)), "draft_window"
         )
 
-    def _draft_fn(self, params, kv, state, tokens, positions):
+    def _draft_fn(self, params, kv, state, tables, tokens, positions):
         """Resync the draft frontier from the target's, then decode
         gamma+1 greedy steps under lax.scan; returns [S, gamma]
         proposals."""
@@ -257,7 +257,7 @@ class ModelDrafter:
 
         def body(carry, _):
             kv, st = carry
-            kv, st, tok = self.runner._decode_fn(params, kv, st)
+            kv, st, tok = self.runner._decode_paged_fn(params, kv, st, tables)
             return (kv, st), tok
 
         (kv, state), toks = jax.lax.scan(
@@ -268,7 +268,8 @@ class ModelDrafter:
     def propose(self, target_tokens, target_positions):
         r = self.runner
         r.kv, r.state, props = self._draft(
-            r.params, r.kv, r.state, target_tokens, target_positions
+            r.params, r.kv, r.state, r.block_tables, target_tokens,
+            target_positions
         )
         return props
 

@@ -242,11 +242,11 @@ class SpecEngine:
 
     def admit(self, slot: int, prompt: list[int], **kw) -> int:
         """Prefill the target; the first sampled token seeds the drafter.
-        Paged targets get the speculation-row lookahead reserved on top
-        of any caller reservation (the scheduler's chunked path does the
-        same through begin_admit)."""
-        if self.paged:
-            kw.setdefault("spec_tokens", self.gamma + 1)
+        The speculation-row lookahead is reserved on top of any caller
+        reservation (a contiguous target's rows reach ``max_ctx`` and its
+        ``admit`` ignores it; the scheduler's chunked path does the same
+        through begin_admit)."""
+        kw.setdefault("spec_tokens", self.gamma + 1)
         first = self.target.admit(slot, prompt, **kw)
         self.last_prefix_reused = self.target.last_prefix_reused
         self.drafter.admit(slot, list(prompt) or [0], first,

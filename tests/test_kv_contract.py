@@ -461,3 +461,131 @@ def test_only_the_kernel_over_an_unscaled_pool_writes(monkeypatch, kv_dtype,
         # K's scatter and V's are gone (the sampler keeps one of its own)
         op = '"stablehlo.scatter"('
         assert now[program].count(op) == before[program].count(op) - 2
+
+
+# ---------------------------------------------------------------------------
+# PR 47: ONE family of serving programs over a layout object. From the same
+# prompts and seeds both layouts emit the same tokens through each program.
+
+PA = list(b"the quick brown fox jumps over the dog")    # paged: three chunks
+PB = list(b"hi")
+PA2 = PA[:24] + list(b" leaps past a cat")       # a chunk behind PA's prefix
+GREEDY = {"temperature": 0.0}
+SEEDED = {"temperature": 0.8, "top_k": 40, "seed": 11, "repeat_penalty": 1.1}
+GAMMA, STREAM = 3, 9
+
+
+def _family_runner(layout):
+    from localai_tpu.engine.runner import ModelRunner
+    from localai_tpu.models.registry import resolve_model
+
+    tiny = resolve_model("debug:tiny", dtype="float32")
+    r = ModelRunner(tiny.cfg, tiny.params, num_slots=3, max_ctx=96,
+                    prefill_buckets=[16, 32], kv_dtype="float32",
+                    paged=layout == "paged", kv_block_tokens=16,
+                    prefill_chunk=16)
+    assert type(r.layout) is {"paged": kvc.PagedLayout,
+                              "contiguous": kvc.ContiguousLayout}[layout]
+    return r
+
+
+def _admit_pair(r):
+    """PA (greedy) and PB (seeded sampling) in two slots; their slots and
+    first tokens. The speculation rows are the pool's to reserve."""
+    a, b = r.acquire_slot(), r.acquire_slot()
+    return a, b, [r.admit(a, PA, spec_tokens=GAMMA + 1, **GREEDY)], [
+        r.admit(b, PB, spec_tokens=GAMMA + 1, **SEEDED)]
+
+
+@pytest.fixture(scope="module")
+def family_streams():
+    """What each prompt's stream is: one decode step a dispatch over the
+    block pool (the path every cell serves by)."""
+    r = _family_runner("paged")
+    a, b, sa, sb = _admit_pair(r)
+    c = r.acquire_slot()
+    sc = [r.admit(c, PA2, **SEEDED)]
+    for _ in range(STREAM):
+        toks = r.step()
+        for s, out in ((a, sa), (b, sb), (c, sc)):
+            out.append(int(toks[s]))
+    return {"a": sa, "b": sb, "a2": sc}
+
+
+def _decode(r, want):
+    a, b, sa, sb = _admit_pair(r)
+    for _ in range(8):
+        toks = r.step()
+        sa.append(int(toks[a]))
+        sb.append(int(toks[b]))
+    return {"a": sa, "b": sb}
+
+
+def _decode_n(r, want):
+    a, b, sa, sb = _admit_pair(r)
+    for _ in range(2):
+        toks = r.step_n(4)                              # [4, S]
+        sa += [int(t) for t in toks[:, a]]
+        sb += [int(t) for t in toks[:, b]]
+    return {"a": sa, "b": sb}
+
+
+def _frozen_n(r, want):
+    """PB's slot frozen: one token a dispatch, where PA's rides for four."""
+    a, b, sa, sb = _admit_pair(r)
+    freeze = np.zeros(r.num_slots, bool)
+    freeze[b] = True
+    for _ in range(2):
+        toks = r.step_frozen_n(freeze, 4)
+        sa += [int(t) for t in toks[:, a]]
+        sb.append(int(toks[0, b]))
+    return {"a": sa, "b": sb}
+
+
+def _verify(r, want):
+    """Two windows: PA's drafts are its stream (all accepted, and the bonus
+    token), PB's are wrong (the correction alone)."""
+    from localai_tpu.engine.runner import SKIP
+
+    a, b, sa, sb = _admit_pair(r)
+    V = r.cfg.vocab_size
+    for _ in range(2):
+        props = np.zeros((r.num_slots, GAMMA), np.int32)
+        props[a] = want["a"][len(sa):len(sa) + GAMMA]
+        props[b] = [(t + 1) % V for t in want["b"][len(sb):len(sb) + GAMMA]]
+        emitted = np.asarray(r.verify_async(props))      # [GAMMA + 1, S]
+        sa += [int(t) for t in emitted[:, a] if t != SKIP]
+        sb += [int(t) for t in emitted[:, b] if t != SKIP]
+    assert len(sa) == 1 + 2 * (GAMMA + 1) and len(sb) == 3
+    return {"a": sa, "b": sb}
+
+
+def _chunk(r, want):
+    """PA2 behind what PA left: the contiguous slot's own rows, the pool's
+    shared block. Both go through the one chunk program."""
+    s = r.acquire_slot()
+    left = PA + [r.admit(s, PA, **GREEDY)]
+    left.append(int(r.step()[s]))
+    r.release(s)
+    s = r.acquire_slot(s)
+    out = [r.admit(s, PA2, resident=left, **SEEDED)]
+    assert r.last_prefill_path == ("paged_shared" if r.paged else "resume")
+    assert r.last_prefix_reused == (16 if r.paged else 24)
+    out += [int(r.step()[s]) for _ in range(5)]
+    return {"a2": out}
+
+
+@pytest.mark.parametrize("program", [_decode, _decode_n, _frozen_n, _verify,
+                                     _chunk], ids=lambda f: f.__name__[1:])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_both_layouts_emit_the_same_tokens_through_the_one_family(
+        family_streams, layout, program):
+    """``decode`` holds the pair tests/test_paged.py held as
+    ``test_paged_runner_matches_contiguous_greedy`` (its prompts, PA's
+    stream greedy)."""
+    r = _family_runner(layout)
+    got = program(r, family_streams)
+    for name, stream in got.items():
+        assert stream == family_streams[name][:len(stream)], (name, layout)
+    if r.paged:
+        assert not r.allocator.check_invariants()

@@ -4,7 +4,8 @@ the final norm after every pass, a K/V cache entry for every (pass, layer)
 pair. CPU, tiny widths, seeded weights, 2 layers x 3 passes.
 
 The served path is the runner's own programs (``_prefill_paged_fn`` /
-``_decode_paged_fn``, and the contiguous ones), driven by ``admit`` and
+``_decode_paged_fn`` over either layout, and the contiguous rows' fresh
+``_prefill_fn``), driven by ``admit`` and
 ``step`` and tapped for the logits they sample from; the reference is the
 benchmark's plain float32 family (benchmark/reference/ouro_family.py, written
 from the published description) run as the benchmark runs it
@@ -115,15 +116,13 @@ def tap(runner: ModelRunner) -> list:
 
         return call
 
-    if runner.paged:
-        runner._prefill_paged = wrap(runner._prefill_paged_fn,
-                                     static_argnames=("bucket", "sample"))
-        runner._decode_paged = wrap(runner._decode_paged_fn)
-    else:
+    # one family of programs over both layouts; the fresh whole-prompt
+    # prefill is the contiguous rows' own
+    runner._prefill_paged = wrap(runner._prefill_paged_fn,
+                                 static_argnames=("bucket", "sample"))
+    runner._decode_paged = wrap(runner._decode_paged_fn)
+    if not runner.paged:
         runner._prefill = wrap(runner._prefill_fn, static_argnames=("bucket",))
-        runner._prefill_resume = wrap(runner._prefill_resume_fn,
-                                      static_argnames=("bucket",))
-        runner._decode = wrap(runner._decode_fn)
     return seen
 
 

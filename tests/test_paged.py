@@ -388,32 +388,9 @@ def test_paged_kernel_writes_the_row_it_reads(kv_dtype, hkv, g, bt, windowed,
                                np.asarray(ref[live]), rtol=tol, atol=tol)
 
 
-def test_paged_runner_matches_contiguous_greedy():
-    """End-to-end engine parity: same weights, two prompts of different
-    lengths sharing the paged pool — greedy decode must match the
-    contiguous runner token-for-token."""
-    tiny = resolve_model("debug:tiny", dtype="float32")
-    rc = ModelRunner(tiny.cfg, tiny.params, num_slots=4, max_ctx=96,
-                     prefill_buckets=[16, 32], kv_dtype="float32")
-    rp = ModelRunner(tiny.cfg, tiny.params, num_slots=4, max_ctx=96,
-                     prefill_buckets=[16, 32], kv_dtype="float32",
-                     paged=True, kv_block_tokens=16, prefill_chunk=16)
-    assert rp.paged
-    pa = list(b"the quick brown fox jumps over the dog")  # chunked: 3 chunks
-    pb = list(b"hi")
-    seqs = {}
-    for name, r in (("contig", rc), ("paged", rp)):
-        s1 = r.acquire_slot()
-        t1 = r.admit(s1, pa, temperature=0.0)
-        s2 = r.acquire_slot()
-        t2 = r.admit(s2, pb, temperature=0.0)
-        a, b = [t1], [t2]
-        for _ in range(8):
-            toks = r.step()
-            a.append(int(toks[s1]))
-            b.append(int(toks[s2]))
-        seqs[name] = (a, b)
-    assert seqs["paged"] == seqs["contig"]
+# The greedy pair (two prompts sharing the pool, token for token against the
+# contiguous runner) is the ``decode`` case of tests/test_kv_contract.py
+# ``test_both_layouts_emit_the_same_tokens_through_the_one_family``.
 
 
 def test_paged_runner_pallas_kernel_matches_xla_end_to_end():

@@ -59,14 +59,12 @@ BUILD = {
 def shape_of(r):
     """What the deleted inputs decided: layout, block size, pool size, chunk,
     kernels, KV dtype, overlap, and the decode program as lowered."""
+    lowered = jax.jit(r._decode_paged_fn).lower(
+        r.params, r.kv, r.state, r.block_tables)
+    pool = None
     if r.paged:
-        lowered = jax.jit(r._decode_paged_fn).lower(
-            r.params, r.kv, r.state, r.block_tables)
         pool = (r.block_tokens, r.allocator.num_blocks, r.prefill_chunk,
                 r.paged_attn_impl, r._paged_attn_interpret, r.overlap_mode)
-    else:
-        lowered = jax.jit(r._decode_fn).lower(r.params, r.kv, r.state)
-        pool = None
     return {"paged": r.paged, "pool": pool, "kv_dtype": r.kv_dtype,
             "attn": (r.attn_impl, r._attn_interpret),
             "decode": lowered.as_text()}
@@ -120,6 +118,30 @@ def test_the_hot_path_reads_no_environment():
         readers = sorted(str(p.relative_to(PKG)) for p, s in sources.items()
                          if reads.search(s))
         assert readers == [module], (name, readers)
+
+
+def test_the_runner_holds_one_family_of_serving_programs():
+    """One decode, decode-n, frozen-n, verify and chunk program over the
+    runner's layout object (engine.kvcache): the contiguous-named twins are
+    gone, and what dispatches the family does not ask which layout it has."""
+    import inspect
+
+    for twin in ("_decode_fn", "_decode_n_fn", "_decode_frozen_n_fn",
+                 "_verify_fn", "_prefill_resume_fn"):
+        assert not hasattr(ModelRunner, twin), twin
+    for name in ("step_async", "verify_async", "step_n_async",
+                 "step_frozen_n"):
+        assert "paged" not in inspect.getsource(
+            getattr(ModelRunner, name)).replace("_paged", ""), name
+    # the layout's decisions are the layout's: the runner names no decode
+    # kernel and no decode write policy
+    source = (PKG / "engine/runner.py").read_text()
+    assert "decode_attention" not in source
+    for policy in ("decode_write", "verify_write", "resume_write",
+                   "paged_decode_write", "paged_verify_write",
+                   "paged_prefill_write", "span_attend", "window_attend",
+                   "kernel_attend"):
+        assert f"kvc.{policy}" not in source, policy
 
 
 @pytest.fixture(scope="module")

@@ -306,6 +306,7 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
         head_dim=cfg.hd, block_tokens=r.block_tokens, tp=tp,
         backend="tpu") == ("pallas", False)
     r._paged_attn_interpret = r._attn_interpret = False
+    r.layout.interpret = False
     if r.routed:        # and its routed experts: the compiled kernel too
         assert ops.select_moe_impl(
             "auto", hidden=cfg.hidden_size,
@@ -314,8 +315,8 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
         assert r.family_kernels is True
         r.family_kernels = False
     if mesh is not None:
-        r.mesh = mesh
-    pool_spec = None if mesh is None else tuple(r._paged_sharding.spec)
+        r.mesh = r.layout.mesh = mesh
+    pool_spec = None if mesh is None else tuple(r.layout.kv_sharding.spec)
     scalar = jax.ShapeDtypeStruct((), i32, sharding=on())
     avals = {
         "params": params,
