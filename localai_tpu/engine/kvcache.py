@@ -43,8 +43,16 @@ tests/test_tpu_compile.py compiles the runner's programs for a described
 v5e and holds them to no cache-sized temp and no layer-shaped copy.
 
 Which policy, attend and mask a program gets is a LAYOUT's to say
-(``PagedLayout``, ``ContiguousLayout`` at the end of this file): the runner
-builds one and runs one family of programs over it.
+(``PagedLayout``, ``ContiguousLayout``, ``LatentLayout`` at the end of this
+file): the runner builds one and runs one family of programs over it.
+
+A LATENT pool (``LatentKVCache``; latent attention, models.deepseek) is the
+block pool with another page: ONE array ``[cache layers, num_blocks,
+block_tokens, lanes]``, a token's row its compressed latent and its one
+shared rope key, no kv-head axis, keys and values the same rows. Allocator,
+block tables and prefix sharing are the block pool's; its write policies
+(``latent_decode_write``, ``latent_prefill_write``) keep the contract above
+with ONE new-rows argument and one view (``LatentView``).
 """
 
 from __future__ import annotations
@@ -66,7 +74,8 @@ from localai_tpu.models.quant import (
     unpack_int4_lastdim as _unpack4,
 )
 from localai_tpu.obs.profiler import scoped
-from localai_tpu.ops.attention import gather_block_scales, gather_blocks
+from localai_tpu.ops.attention import (gather_block_scales, gather_blocks,
+                                       latent_lanes)
 
 
 @jax.tree_util.register_dataclass
@@ -321,6 +330,22 @@ def _write_rows(kv_stack, layer, blk, off, k_new, v_new):
     return _write(kv_stack, k_new, v_new, put)
 
 
+def _run_blocks(table_row, offset, length, T: int, bt: int):
+    """The blocks a run of ``T`` rows (the first ``length`` real) at
+    positions ``[offset, offset + length)`` touches: (how many at most,
+    which of their rows are the run's ``[nblk, bt]``, their pool ids
+    ``[nblk]``: the trash block for one the run does not reach)."""
+    MB = table_row.shape[0]
+    nblk = -(-T // bt) + 1
+    first = offset // bt
+    pos = first * bt + jnp.arange(nblk * bt)
+    real = ((pos >= offset) & (pos < offset + length)).reshape(nblk, bt)
+    j = first + jnp.arange(nblk)
+    ids = jnp.where((j < MB) & real.any(axis=1),
+                    table_row[jnp.minimum(j, MB - 1)], 0)
+    return nblk, real, ids
+
+
 def _write_run(kv_stack, layer, table_row, offset, length, k_new, v_new):
     """Scatter one sequence's run of consecutive positions
     ``[offset, offset + length)``, rows ``k_new``/``v_new [T, H, hd]`` (the
@@ -333,14 +358,9 @@ def _write_run(kv_stack, layer, table_row, offset, length, k_new, v_new):
     82 ms chunk of 512 at Mistral-7B against 1 ms, PERF.md PR 26). Rows
     outside the run keep what the blocks held; blocks the run does not
     reach are written back unchanged, to the trash block."""
-    bt, MB = kv_stack[0].shape[3], table_row.shape[0]
-    nblk = -(-k_new.shape[0] // bt) + 1
-    first = offset // bt
-    pos = first * bt + jnp.arange(nblk * bt)
-    real = ((pos >= offset) & (pos < offset + length)).reshape(nblk, bt)
-    j = first + jnp.arange(nblk)
-    ids = jnp.where((j < MB) & real.any(axis=1),
-                    table_row[jnp.minimum(j, MB - 1)], 0)
+    bt = kv_stack[0].shape[3]
+    nblk, real, ids = _run_blocks(table_row, offset, length,
+                                  k_new.shape[0], bt)
 
     def put(cache, rows):       # rows [T, H, hd] or, for scales, [T, H]
         frame = lax.dynamic_update_slice(
@@ -806,6 +826,221 @@ def prefill_mask(cfg: LlamaConfig, seq_len: int, length: jax.Array) -> jax.Array
 
 
 # ---------------------------------------------------------------------------
+# the latent page: one row a token a layer, keys and values the same rows
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LatentKVCache:
+    """c: [L, N, bt, lanes]: the block pool of a model with latent attention.
+    A token's row is ``cfg.latent_width`` elements (its compressed latent,
+    then its rope key, shared by every head) in whole 128-lane tiles
+    (``ops.attention.latent_lanes``: the pad is zeros and is what HBM's
+    tiling costs anyway). Block 0 is the trash block, as the paged pool's."""
+
+    c: jax.Array
+
+    quantized = False
+
+    def stacked(self):
+        return (self.c,)
+
+    @staticmethod
+    def from_stacked(t) -> "LatentKVCache":
+        return LatentKVCache(*t)
+
+
+def init_latent_cache(cfg: LlamaConfig, num_blocks: int, block_tokens: int,
+                      dtype: str = "bfloat16") -> LatentKVCache:
+    return LatentKVCache(jnp.zeros(
+        (cfg.cache_layers, num_blocks, block_tokens,
+         latent_lanes(cfg.latent_width)), jnp.dtype(dtype)))
+
+
+class LatentView(NamedTuple):
+    """What a latent write policy hands its attend: the WHOLE stacked pool
+    and the layer to read (``LayerView``'s reasons), and the step's rows in
+    the pool's dtype and lanes where the policy has NOT stored them and the
+    kernel is to."""
+
+    cache: jax.Array                    # [L, N, bt, lanes]
+    layer: jax.Array                    # scalar i32
+    new: Optional[jax.Array] = None     # [S, lanes]
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttend:
+    """The ``attn`` a latent layout hands the model's forward. ``path`` says
+    which form of the attention the model is to compute, ``run`` attends:
+
+    ``absorbed`` (a decode step): the queries folded into the latent space,
+    attended over the rows as they lie: ``run(q [B, T, H, W], view, mask, *,
+    scale, v_lanes) -> out [B, T, H, v_lanes]``, or ``(out, stack)`` where
+    the attend wrote the step's rows;
+    ``decompressed`` (a chunk behind a cached prefix): keys and values
+    rebuilt from the rows, a stretch of the span at a time: ``run(q [1, T,
+    H, dq], view, mask, *, scale, expand, v_dim) -> out [1, T, H, v_dim]``,
+    where ``expand(rows [n, W]) -> (k [n, H, dq], v [n, H, v_dim])`` is the
+    model's.
+    """
+
+    path: str
+    run: Callable
+
+
+def _pad_lanes(rows, lanes: int):
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1)
+                   + [(0, lanes - rows.shape[-1])])
+
+
+def latent_decode_write(tables: jax.Array, positions: jax.Array,
+                        raw: bool = False):
+    """``paged_decode_write`` for a latent pool: ``write(stack, layer, row
+    [S, 1, W]) -> (stack, view)``. ``raw``: the kernel writes (the stack
+    goes back untouched, the view carries the rows); else one scatter, a
+    row a slot, released slots' into the trash block."""
+
+    def write(stack, layer, row):
+        (cache,) = stack
+        with jax.named_scope("kv_pool.write"):
+            new = _pad_lanes(row[:, 0].astype(cache.dtype), cache.shape[-1])
+            if raw:
+                return stack, LatentView(cache, layer, new)
+            bt = cache.shape[2]
+            blk = tables[jnp.arange(tables.shape[0]), positions // bt]
+            cache = cache.at[layer, blk, positions % bt].set(new)
+        return (cache,), LatentView(cache, layer)
+
+    return write
+
+
+def latent_kernel_attend(tables, positions, interpret: bool) -> LatentAttend:
+    """The absorbed attend as ``ops.latent_decode_attention``, which writes
+    the step's rows too."""
+    def run(q, view, _mask, *, scale, v_lanes):     # q [S, 1, H, W]
+        out, cache = ops.latent_decode_attention(
+            q[:, 0], view.cache, view.layer, tables, positions, view.new,
+            v_lanes=v_lanes, sm_scale=scale, interpret=interpret)
+        return out[:, None], (cache,)
+
+    return LatentAttend("absorbed", scoped("attn.latent_decode")(run))
+
+
+def latent_xla_attend(tables) -> LatentAttend:
+    """The absorbed attend as XLA over the rows the tables name (layer and
+    blocks in one gather): the CPU path and the kernel's oracle."""
+    def run(q, view, mask, *, scale, v_lanes):      # mask [S, T, C]
+        S, MB = tables.shape
+        with jax.named_scope("kv_pool.gather"):
+            rows = view.cache[view.layer, tables]
+            rows = rows.reshape(S, MB * rows.shape[2], rows.shape[3])
+            rows = rows[..., :q.shape[-1]].astype(q.dtype)
+        scores = jnp.einsum("sthd,sld->shtl", q, rows).astype(
+            jnp.float32) * scale
+        scores = jnp.where(mask[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+        return jnp.einsum("shtl,sld->sthd", probs, rows[..., :v_lanes])
+
+    return LatentAttend("absorbed", scoped("attn.latent_decode")(run))
+
+
+def latent_prefill_write(table_row: jax.Array, offset: jax.Array,
+                         length: jax.Array):
+    """``paged_prefill_write`` for a latent pool: ``write(stack, layer, row
+    [1, T, W])`` lays the chunk's first ``length`` rows at positions
+    ``[offset, offset + length)`` through ``table_row``, a block at a time
+    (``_write_run``'s reasons: the blocks the run touches are gathered, the
+    rows laid over them and whole blocks scattered back; blocks it does not
+    reach go to the trash block unchanged)."""
+
+    def write(stack, layer, row):
+        (cache,) = stack
+        bt, lanes = cache.shape[2], cache.shape[3]
+        nblk, real, ids = _run_blocks(table_row, offset, length,
+                                      row.shape[1], bt)
+        with jax.named_scope("kv_pool.write"):
+            rows = _pad_lanes(row[0].astype(cache.dtype), lanes)
+            frame = lax.dynamic_update_slice(
+                jnp.zeros((nblk * bt, lanes), cache.dtype), rows,
+                (offset % bt, 0)).reshape(nblk, bt, lanes)
+            merged = jnp.where(real[..., None], frame, cache[layer, ids])
+            cache = cache.at[layer, ids].set(merged)
+        return (cache,), LatentView(cache, layer)
+
+    return write
+
+
+# positions of the span a chunk's decompressed attend rebuilds at a time
+# (whole blocks): the keys and values of 64 heads over 1024 rows are 40 MiB
+# in bfloat16, a 512-token chunk's scores over them 128 MiB in float32
+LATENT_WALK_TOKENS = 1024
+
+
+def latent_walk(block_tokens: int) -> int:
+    """Rows a step of ``latent_span_attend`` covers: whole blocks."""
+    return max(1, LATENT_WALK_TOKENS // block_tokens) * block_tokens
+
+
+def latent_attend_span(offset: int, bucket: int, block_tokens: int) -> int:
+    """``attend_span`` for a latent pool (host integers or the program's
+    traced scalars: ONE expression serves both): the walk's whole steps
+    that cover ``offset + bucket`` positions."""
+    walk = latent_walk(block_tokens)
+    return (offset + bucket + walk - 1) // walk * walk
+
+
+def latent_span_attend(table_row: jax.Array, offset: jax.Array
+                       ) -> LatentAttend:
+    """The decompressed attend of a chunk behind ``offset`` cached tokens:
+    a rolled loop over the span, ``latent_walk`` rows a step (a traced trip
+    count: the chunk attends the prefix it has, ``latent_attend_span``):
+    each step gathers its rows through the table row, has the model rebuild
+    their keys and values (``expand``) and folds them into an online
+    softmax, so that neither a span's keys (1.3 GiB at 32768 rows of 64
+    heads) nor a chunk's scores over it ever exist whole. A position past a
+    row's own is masked: the walk needs no mask handed in."""
+    def run(q, view, _mask, *, scale, expand, v_dim):    # q [1, T, H, dq]
+        cache, layer = view.cache, view.layer
+        bt, T, H = cache.shape[2], q.shape[1], q.shape[2]
+        walk = latent_walk(bt)
+        nb = walk // bt
+        steps = latent_attend_span(offset, T, bt) // walk
+        MB = table_row.shape[0]
+        table = jnp.pad(table_row, (0, -MB % nb))   # whole steps: trash
+        steps = jnp.minimum(steps, table.shape[0] // nb)
+        qpos = offset + jnp.arange(T)
+        qh = q[0].transpose(1, 0, 2)                # [H, T, dq]
+
+        def step(i, carry):
+            m, l, acc = carry
+            with jax.named_scope("kv_pool.gather"):
+                ids = lax.dynamic_slice(table, (i * nb,), (nb,))
+                rows = cache[layer, ids].reshape(walk, cache.shape[3])
+            k, v = expand(rows)             # [walk, H, dq], [walk, H, dv]
+            s = jnp.einsum("htd,lhd->htl", qh, k).astype(
+                jnp.float32) * scale
+            kpos = i * walk + jnp.arange(walk)
+            s = jnp.where(kpos[None, None, :] <= qpos[None, :, None], s,
+                          -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "htl,lhd->htd", p.astype(v.dtype), v).astype(jnp.float32)
+            return m_new, l, acc
+
+        _, l, acc = lax.fori_loop(0, steps, step, (
+            jnp.full((H, T, 1), -1e30, jnp.float32),
+            jnp.zeros((H, T, 1), jnp.float32),
+            jnp.zeros((H, T, v_dim), jnp.float32)))
+        return (acc / l).astype(q.dtype).transpose(1, 0, 2)[None]
+
+    return LatentAttend("decompressed", scoped("attn.latent_chunk")(run))
+
+
+# ---------------------------------------------------------------------------
 # the layouts: how a runner's K/V is laid out, written, attended and masked
 # ---------------------------------------------------------------------------
 #
@@ -1045,6 +1280,11 @@ class PagedLayout:
         return (paged_verify_write(tables, positions, self.max_ctx),
                 verify_mask(self.cfg, positions, T, self.ctx))
 
+    def chunk_span(self, offset: int, bucket: int) -> int:
+        """Positions a chunk's attend spans (host integers; the flight
+        ring's ``chunk_ctx``): the rung its program takes on the device."""
+        return attend_span(offset, bucket, self.ctx, self.block_tokens)
+
     def chunk(self, table_row, slot, positions, offset, length):
         """The attend spans the rung of the ladder that covers ``offset`` +
         the bucket, picked on the device: the mask is sliced to it."""
@@ -1061,3 +1301,94 @@ class PagedLayout:
                 view, table_row, offset, self.ctx)
                 for kind, view in views}
         return paged_prefill_write(table_row, offset, length), attn, mask
+
+
+@dataclasses.dataclass
+class LatentLayout:
+    """The latent block pool (``LatentKVCache``) behind the block pool's
+    tables: what a model with latent attention serves from, on one chip.
+    Its attends say which FORM of the attention the model computes: a
+    decode step the absorbed one over the rows as they lie (the Pallas
+    kernel, which writes the step's rows, or XLA), a chunk the decompressed
+    one over the span it has (``latent_span_attend``)."""
+
+    cfg: LlamaConfig
+    kv_dtype: str
+    num_slots: int
+    max_ctx: int
+    attn_impl: str          # ops.select_latent_attn_impl: "pallas" | "xla"
+    interpret: bool
+    block_tokens: int
+    max_blocks: int
+    num_blocks: int
+
+    from_stacked = staticmethod(LatentKVCache.from_stacked)
+
+    def __post_init__(self):
+        self.ctx = self.max_blocks * self.block_tokens
+
+    @property
+    def kv_write_impl(self) -> str:
+        return "kernel" if self.attn_impl == "pallas" else "scatter"
+
+    def init(self):
+        return init_latent_cache(
+            self.cfg, self.num_blocks, self.block_tokens,
+            self.kv_dtype), jnp.zeros(
+                (self.num_slots, self.max_blocks), jnp.int32)
+
+    def decode(self, kv: LatentKVCache, tables, positions):
+        raw = self.attn_impl == "pallas"
+        attn = (latent_kernel_attend(tables, positions, self.interpret)
+                if raw else latent_xla_attend(tables))
+        mask = decode_mask(KindView(0, None), positions, self.ctx)
+        return latent_decode_write(tables, positions, raw=raw), attn, mask
+
+    def chunk(self, table_row, slot, positions, offset, length):
+        """The attend walks the span the chunk has (``chunk_span``) and
+        masks by position: no mask is built."""
+        return (latent_prefill_write(table_row, offset, length),
+                latent_span_attend(table_row, offset), None)
+
+    def chunk_span(self, offset: int, bucket: int) -> int:
+        """Positions a chunk's walk covers (the flight ring's
+        ``chunk_ctx``): whole steps, never past the table's."""
+        walk = latent_walk(self.block_tokens)
+        return min(latent_attend_span(offset, bucket, self.block_tokens),
+                   -(-self.ctx // walk) * walk)
+
+    def scratch(self, bucket: int):
+        """(stack, table row) of a throwaway one-sequence pool of ``bucket``
+        positions: the embeddings path's."""
+        nb = -(-bucket // self.block_tokens)
+        return (init_latent_cache(self.cfg, nb + 1, self.block_tokens,
+                                  self.kv_dtype).stacked(),
+                1 + jnp.arange(nb, dtype=jnp.int32))
+
+    # a block's rows to and from the host, and a slot's first rows in the
+    # export format ([L, n, W]: the real lanes, whatever the tiling pads)
+
+    def pack_block(self, kv: LatentKVCache, bid: int) -> dict:
+        import numpy as np
+
+        return {"c": np.asarray(kv.c[:, bid])}
+
+    def load_block(self, kv: LatentKVCache, bid: int, payload: dict):
+        return LatentKVCache(kv.c.at[:, bid].set(
+            jnp.asarray(payload["c"], kv.c.dtype)))
+
+    def export_rows(self, kv: LatentKVCache, blocks, n: int) -> dict:
+        g = kv.c[:, blocks]                         # [L, nb, bt, lanes]
+        return {"c": g.reshape(g.shape[0], -1, g.shape[-1])[
+            :, :n, :self.cfg.latent_width]}
+
+    def import_rows(self, kv: LatentKVCache, blk, off, arrays: dict,
+                    n: int):
+        """The pool with exported rows ``arrays["c"] [L, n, W]`` at
+        (``blk``, ``off``) [n]; None where they are not this pool's."""
+        c = arrays.get("c")
+        want = (self.cfg.cache_layers, n, self.cfg.latent_width)
+        if c is None or tuple(c.shape) != want:
+            return None
+        rows = _pad_lanes(jnp.asarray(c, kv.c.dtype), kv.c.shape[-1])
+        return LatentKVCache(kv.c.at[:, blk, off].set(rows))

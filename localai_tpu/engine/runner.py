@@ -210,14 +210,20 @@ class ModelRunner:
         # ops.select_attn_impl so tests can assert which path a given
         # (model, mesh) lands on at hardware shapes; a shape the compiled
         # kernels cannot take raises here, at load
-        self.attn_impl, self._attn_interpret = ops.select_attn_impl(
-            attn_impl,
-            num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.hd,
-            max_ctx=max_ctx or cfg.max_position_embeddings,
-            tp=mesh.shape["model"] if mesh is not None else 1,
-        )
+        if cfg.latent:
+            # a latent pool has no K/V a head for the contiguous-cache
+            # kernels to gate on (that layout is refused below)
+            self.attn_impl, self._attn_interpret = ops.resolve_attn_impl(
+                attn_impl)
+        else:
+            self.attn_impl, self._attn_interpret = ops.select_attn_impl(
+                attn_impl,
+                num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.hd,
+                max_ctx=max_ctx or cfg.max_position_embeddings,
+                tp=mesh.shape["model"] if mesh is not None else 1,
+            )
         # int8 KV rides the same flash decode kernel: per-position scales
         # fuse into the online-softmax loop (ops.attention), so the default
         # quantized config is both length-aware (block-skip past each slot's
@@ -259,9 +265,16 @@ class ModelRunner:
         # a stack with more than one kind of attention layer (models.afmoe):
         # a mask and an attend a kind, through the paged pool on one chip
         self.kinds = cfg.attn_kinds
-        if self.kinds:
+        # latent attention (models.deepseek): one latent row a token in a
+        # block pool of its own page (engine.kvcache ``LatentLayout``), on
+        # one chip; the refusals are the family's own sentence, as a stack
+        # of several kinds'
+        self.latent = bool(cfg.latent)
+        if self.kinds or self.latent:
             for what, asked in (
                     ("pipeline parallelism", self.pp_enabled),
+                    ("the ring prefill", mesh is not None
+                     and mesh.shape.get("seq", 1) > 1),
                     ("a device mesh", mesh is not None),
                     ("self-extend", ga_n > 1),
                     ("the contiguous K/V layout", not self.paged),
@@ -319,16 +332,22 @@ class ModelRunner:
                 self.max_blocks)
             self.prefill_chunk = max(
                 self.block_tokens, int(prefill_chunk or PREFILL_CHUNK))
-            (self.paged_attn_impl,
-             self._paged_attn_interpret) = ops.select_paged_attn_impl(
-                attn_impl,
-                num_heads=cfg.num_heads,
-                num_kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.hd,
-                block_tokens=self.block_tokens,
-                tp=mesh.shape["model"] if mesh is not None else 1,
-                kv_dtype=kv_dtype,
-            )
+            if self.latent:
+                (self.paged_attn_impl,
+                 self._paged_attn_interpret) = ops.select_latent_attn_impl(
+                    attn_impl, block_tokens=self.block_tokens,
+                    kv_dtype=kv_dtype)
+            else:
+                (self.paged_attn_impl,
+                 self._paged_attn_interpret) = ops.select_paged_attn_impl(
+                    attn_impl,
+                    num_heads=cfg.num_heads,
+                    num_kv_heads=cfg.num_kv_heads,
+                    head_dim=cfg.hd,
+                    block_tokens=self.block_tokens,
+                    tp=mesh.shape["model"] if mesh is not None else 1,
+                    kv_dtype=kv_dtype,
+                )
             # collective/compute overlap (parallel.overlap): meshed decode
             # runs the trunk as a manual-TP shard_map with the per-layer
             # psums decomposed into chunked psum_scatter+all_gather so ICI
@@ -369,7 +388,13 @@ class ModelRunner:
         # family of programs below asks the layout (engine.kvcache); it
         # keeps its shardings, so reinit() (self-healing engine rebuild)
         # rebuilds the device state into the exact same layout
-        if self.paged:
+        if self.latent:
+            self.layout = kvc.LatentLayout(
+                cfg, kv_dtype, num_slots, self.max_ctx,
+                self.paged_attn_impl, self._paged_attn_interpret,
+                self.block_tokens, self.max_blocks,
+                self.allocator.num_blocks)
+        elif self.paged:
             self.layout = kvc.PagedLayout(
                 cfg, mesh, kv_dtype, num_slots, self.max_ctx,
                 self.paged_attn_impl, self._paged_attn_interpret,
@@ -980,8 +1005,7 @@ class ModelRunner:
         ``bucket`` rows behind ``offset`` cached tokens: the rung the program
         takes on the device, by the same arithmetic (the flight ring's
         ``chunk_ctx``)."""
-        return kvc.attend_span(offset, bucket, self.ctx_pad,
-                               self.block_tokens)
+        return self.layout.chunk_span(offset, bucket)
 
     def _prefill_paged_mm_fn(self, params, kv, state, tokens, length,
                              table_row, slot, mm_embeds, mm_positions,
@@ -1067,6 +1091,18 @@ class ModelRunner:
         embeddings.go:13). Uses a throwaway single-sequence KV so it never
         touches serving slots."""
         cfg = self.cfg
+        if self.latent:
+            # a throwaway latent pool of the bucket's blocks, one chunk at
+            # offset 0 through the layout's own policy and attend
+            kv, table_row = self.layout.scratch(bucket)
+            positions = jnp.arange(bucket, dtype=jnp.int32)[None, :]
+            write, attn, mask = self.layout.chunk(
+                table_row, jnp.int32(0), positions, jnp.int32(0), length)
+            hidden, *_ = self._forward_rec(
+                params, tokens, positions, write, kv, mask,
+                self._init_rec(1), (jnp.arange(bucket) < length)[None, :],
+                attn=attn, slot=jnp.int32(0))
+            return self._mean_pool(hidden, bucket, length)
         # throwaway scratch cache stays in the compute dtype even when the
         # serving cache is int8 — it is read back within the same program
         kv_shape = (cfg.cache_layers, 1, cfg.num_kv_heads, bucket, cfg.hd)
@@ -1091,6 +1127,10 @@ class ModelRunner:
             hidden, _ = self._forward(
                 params, tokens, positions, write, kv, mask, attn=attn,
             )
+        return self._mean_pool(hidden, bucket, length)
+
+    @staticmethod
+    def _mean_pool(hidden, bucket: int, length):
         valid = (jnp.arange(bucket) < length)[None, :, None]
         # pool in f32: a bf16 sum over thousands of positions loses the
         # precision the embeddings exist to provide
@@ -1566,7 +1606,9 @@ class ModelRunner:
         if self.recurrent:
             # a rejected draft token has already moved the state it met
             raise ValueError(_refusal("speculative decoding"))
-        if self.kinds:      # the verify window has one attend for all layers
+        if self.kinds or self.latent:
+            # the verify window has one attend for all layers, and none over
+            # latent rows
             raise ValueError(
                 _kinds_refusal(self.cfg, "speculative decoding"))
         proposals = jnp.asarray(proposals, jnp.int32)
@@ -1713,6 +1755,8 @@ class ModelRunner:
         if self.allocator is None:
             return None
         kv = self.kv
+        if self.latent:
+            return self.layout.pack_block(kv, bid)
         out = {"k": np.asarray(kv.k[:, bid]), "v": np.asarray(kv.v[:, bid])}
         if kv.quantized:
             out["k_scale"] = np.asarray(kv.k_scale[:, bid])
@@ -1723,6 +1767,9 @@ class ModelRunner:
         """Scatter a spilled block's rows back into pool block ``bid``
         (tier re-onboarding; inverse of :meth:`pack_block`)."""
         kv = self.kv
+        if self.latent:
+            self.kv = self.layout.load_block(kv, bid, payload)
+            return
         new = {
             "k": kv.k.at[:, bid].set(jnp.asarray(payload["k"], kv.k.dtype)),
             "v": kv.v.at[:, bid].set(jnp.asarray(payload["v"], kv.v.dtype)),
@@ -1760,6 +1807,10 @@ class ModelRunner:
                 p = min(p, nb * bt)
                 blocks = np.asarray(table[:nb], np.int64)
 
+            if self.latent:     # the rows' real lanes, [L, p, W]
+                out.update(self.layout.export_rows(self.kv, blocks, p))
+                return out
+
             def rows(cache):  # [L, N, H, bt, hd] -> [L, H, p, hd]
                 g = cache[:, blocks]
                 L, _, H = g.shape[0], g.shape[1], g.shape[2]
@@ -1792,7 +1843,7 @@ class ModelRunner:
         has no native bfloat16); scaled-int8 caches keep their scales."""
         out: dict = {"kv_dtype": np.asarray(snapshot["kv_dtype"]),
                      "kv_rope": np.asarray(snapshot.get("kv_rope", "roped"))}
-        for name in ("k", "v", "k_scale", "v_scale"):
+        for name in ("k", "v", "k_scale", "v_scale", "c"):
             if name not in snapshot:
                 continue
             host = np.asarray(snapshot[name])
@@ -1831,6 +1882,9 @@ class ModelRunner:
                 host = host.view(ml_dtypes.bfloat16)
             return host
 
+        if self.latent:
+            return "c" in arrays and self._load_prefix_paged(
+                slot, {"c": unpack("c")}, n)
         k, v = unpack("k"), unpack("v")
         L, H, hd = self.cfg.cache_layers, self.cfg.num_kv_heads, self.cfg.hd
         if str(self.kv_dtype) == "int4":
@@ -1860,8 +1914,29 @@ class ModelRunner:
         self._active_slots.discard(slot)
         return True
 
+    @staticmethod
+    def _import_rows(kv, blk, off, arrays: dict, k, v):
+        """The block pool with exported rows at (``blk``, ``off``) [n]."""
+        # advanced indices (blk, off) around the head slice broadcast to
+        # the FRONT: the set value is row-major [n, L, H, ...]
+        new = {
+            "k": kv.k.at[:, blk, :, off].set(
+                jnp.asarray(k, kv.k.dtype).transpose(2, 0, 1, 3)),
+            "v": kv.v.at[:, blk, :, off].set(
+                jnp.asarray(v, kv.v.dtype).transpose(2, 0, 1, 3)),
+        }
+        if kv.quantized:
+            new["k_scale"] = kv.k_scale.at[:, blk, :, off].set(
+                jnp.asarray(arrays["k_scale"],
+                            jnp.float32).transpose(2, 0, 1))
+            new["v_scale"] = kv.v_scale.at[:, blk, :, off].set(
+                jnp.asarray(arrays["v_scale"],
+                            jnp.float32).transpose(2, 0, 1))
+        return kvc.PagedKVCache(**new)
+
     def _load_prefix_paged(self, slot: int, arrays: dict, n: int,
-                           k: np.ndarray, v: np.ndarray) -> bool:
+                           k: Optional[np.ndarray] = None,
+                           v: Optional[np.ndarray] = None) -> bool:
         """Paged load_prefix tail: scatter the exported contiguous rows
         into freshly allocated blocks and mark them slot-resident
         (``_loaded_rows``) so begin_admit can resume past them."""
@@ -1879,22 +1954,14 @@ class ModelRunner:
         pos = np.arange(n)
         blk = jnp.asarray(table[pos // bt], jnp.int32)
         off = jnp.asarray(pos % bt, jnp.int32)
-        # advanced indices (blk, off) around the head slice broadcast to
-        # the FRONT: the set value is row-major [n, L, H, ...]
-        new = {
-            "k": kv.k.at[:, blk, :, off].set(
-                jnp.asarray(k, kv.k.dtype).transpose(2, 0, 1, 3)),
-            "v": kv.v.at[:, blk, :, off].set(
-                jnp.asarray(v, kv.v.dtype).transpose(2, 0, 1, 3)),
-        }
-        if kv.quantized:
-            new["k_scale"] = kv.k_scale.at[:, blk, :, off].set(
-                jnp.asarray(arrays["k_scale"],
-                            jnp.float32).transpose(2, 0, 1))
-            new["v_scale"] = kv.v_scale.at[:, blk, :, off].set(
-                jnp.asarray(arrays["v_scale"],
-                            jnp.float32).transpose(2, 0, 1))
-        self.kv = kvc.PagedKVCache(**new)
+        if self.latent:
+            loaded = self.layout.import_rows(kv, blk, off, arrays, n)
+            if loaded is None:      # not this pool's rows
+                self.allocator.release(slot)
+                return False
+            self.kv = loaded
+        else:
+            self.kv = self._import_rows(kv, blk, off, arrays, k, v)
         self._install_table_row(slot)
         self._loaded_rows[slot] = n
         self.state = dataclasses.replace(

@@ -408,6 +408,12 @@ class Scheduler:
         # 0: no layer has a window): what ``window_tokens`` cuts a stream's
         # context to
         self._window = int(getattr(cfg, "sliding_window", 0) or 0)
+        # a model with latent attention (models.deepseek): launches by the
+        # FORM of the attention their program computes, counted at the
+        # enqueue (a decode program the absorbed one, a chunk the
+        # decompressed one); None for every other model
+        self.total_mla_attends = ({"absorbed": 0, "decompressed": 0}
+                                  if getattr(cfg, "latent", False) else None)
         self.total_experts_touched = 0
         self.total_local_assignments = 0
         self.total_state_slots_armed = 0
@@ -675,6 +681,8 @@ class Scheduler:
                if self._recurrent else {}),
             **({"kv_window_dead_tokens": window_dead}
                if self._window else {}),
+            **({"mla_attends": dict(self.total_mla_attends)}
+               if self.total_mla_attends is not None else {}),
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -787,6 +795,7 @@ class Scheduler:
         return rows[:, :-2]
 
     def _launch(self, k: int = 0, inflight: Sequence[_Dispatch] = (),
+                chunk: bool = False,
                 ) -> dict:  # jaxlint: disable=lock-guarded-attr
         """Number the serving program about to be enqueued, and count what
         a decode launch of ``k`` steps holds, NOW: the slots that hold a
@@ -803,6 +812,9 @@ class Scheduler:
         WORK_COLUMNS."""
         self._launch_seq += 1
         held = {"launch": self._launch_seq}
+        if self.total_mla_attends is not None:
+            self.total_mla_attends[
+                "decompressed" if chunk else "absorbed"] += 1
         if k:
             live = len(self._slots)
             cached = windowed = 0
@@ -1878,7 +1890,7 @@ class Scheduler:
             self.telemetry.finished(pf.handle.trace, pf.handle, "cancelled")
             pf.handle._finish("cancelled")
             return True, None
-        held = self._launch()
+        held = self._launch(chunk=True)
         t0 = time.monotonic()
         with TraceAnnotation(f"sched.launch/{held['launch']}"):
             last = pf.adm.launch_chunk()

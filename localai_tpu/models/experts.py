@@ -1,6 +1,6 @@
 """Routed experts beside a shared expert: the ONE routing and dispatch of the
 sparse families that are told which experts they hold (models.qwen3_next,
-models.afmoe).
+models.afmoe, models.deepseek).
 
 An expert block scores a token over ALL ``num_experts x ep_size`` experts of
 the deployment, keeps its k choices, and computes the part of the routed sum
@@ -10,9 +10,9 @@ kernels, ``experts_loop`` as XLA); the shared expert is added. On one chip the
 block runs without its exchange: the sum is this chip's partial result.
 
 Two things differ between the families, and are the block's arguments: the
-scoring rule (``softmax_scores`` / ``sigmoid_scores``: logits -> a token's k
-weights and choices) and the shared expert (a closure: under a sigmoid gate,
-or none). Everything else is here once: the weights of the held experts, the
+scoring rule (``softmax_scores`` / ``sigmoid_scores``, the latter plain or
+group-limited: logits -> a token's k weights and choices) and the shared
+expert (a closure: under a sigmoid gate, or none). Everything else is here once: the weights of the held experts, the
 tokens an expert got, the order of the walk, the two counts a launch reports
 ([experts touched, token-expert pairs that landed here]; engine.scheduler
 ``_routed``), the scopes ``router``, ``experts``, ``shared``.
@@ -44,14 +44,27 @@ def softmax_scores(k: int, renormalise: bool) -> Callable:
     return score
 
 
-def sigmoid_scores(k: int, bias, renormalise: bool, scale: float) -> Callable:
+def sigmoid_scores(k: int, bias, renormalise: bool, scale: float, *,
+                   n_group: int = 1, topk_group: int = 1) -> Callable:
     """s = sigmoid(logits) over ALL experts; the k largest of ``s + bias``
-    are chosen (the bias SELECTS and does not weigh); a choice weighs its
-    own s, over the sum of the k (+ 1e-20) where the family renormalises,
-    times ``scale``."""
+    are chosen (the bias SELECTS and does not weigh; None: the family has
+    none); a choice weighs its own s, over the sum of the k (+ 1e-20) where
+    the family renormalises, times ``scale``. GROUP-LIMITED where
+    ``n_group`` > 1 (models.deepseek): the experts lie in ``n_group`` equal
+    groups, a group scores the sum of its two largest, and only the
+    ``topk_group`` best groups' experts can be chosen (the others' scores
+    read 0 in the selection, as the published text fills them). ``n_group``
+    1 is the plain top-k."""
     def score(logits):
         s = jax.nn.sigmoid(logits)
-        _, topi = lax.top_k(s + bias.astype(jnp.float32), k)
+        choice = s if bias is None else s + bias.astype(jnp.float32)
+        if n_group > 1:
+            groups = choice.reshape(*choice.shape[:-1], n_group, -1)
+            best = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+            kept = best >= lax.top_k(best, topk_group)[0][..., -1:]
+            choice = jnp.where(kept[..., None], groups, 0.0).reshape(
+                choice.shape)
+        _, topi = lax.top_k(choice, k)
         topv = jnp.take_along_axis(s, topi, axis=-1)
         if renormalise:
             topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)

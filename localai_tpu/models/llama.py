@@ -78,6 +78,10 @@ class LlamaConfig:
     # pairs, the window None where the kind sees every key (models.afmoe).
     # None: every layer attends alike, under ``sliding_window``
     attn_kinds: ClassVar[Optional[tuple]] = None
+    # a family whose attention caches ONE latent row a token and no K/V a
+    # head (models.deepseek): the engine serves it from the latent block
+    # pool (engine.kvcache ``LatentLayout``)
+    latent: ClassVar[bool] = False
 
     @property
     def hd(self) -> int:
@@ -111,7 +115,9 @@ class LlamaConfig:
         and a gated full-attention layer, routed experts) is a subclass with
         its own keys: models.qwen3_next; so is ``afmoe`` (window and full
         attention layers in one stack, dense layers in front of
-        sigmoid-routed experts): models.afmoe."""
+        sigmoid-routed experts): models.afmoe; and ``axk1`` (the DeepSeek-V3
+        block: latent attention in every layer, a dense layer in front of
+        group-limited sigmoid-routed experts): models.deepseek."""
         if hf.get("model_type") == "qwen3_next":
             from localai_tpu.models.qwen3_next import Qwen3NextConfig
 
@@ -120,6 +126,10 @@ class LlamaConfig:
             from localai_tpu.models.afmoe import AfmoeConfig
 
             return AfmoeConfig.from_hf(hf)
+        if hf.get("model_type") == "axk1":
+            from localai_tpu.models.deepseek import DeepseekConfig
+
+            return DeepseekConfig.from_hf(hf)
         ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),

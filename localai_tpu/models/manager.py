@@ -397,7 +397,7 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
         # (nor a model with recurrent state, or with more than one kind of
         # attention layer: their runners take no mesh yet)
         if not (app.mirror_port or eng.grp_attn_n > 1 or cfg.recurrent
-                or cfg.attn_kinds):
+                or cfg.attn_kinds or cfg.latent):
             mesh = _auto_mesh(cfg, eng.max_slots)
             if mesh is not None:
                 log.info("auto mesh for %s: %s", mcfg.name,
@@ -503,12 +503,14 @@ def build_serving_model(mcfg: ModelConfig, app: AppConfig) -> ServingModel:
                 "%s: speculative decoding is not supported for a model "
                 "with recurrent state (a rejected draft token has already "
                 "moved it); serving without it", mcfg.name)
-    elif spec_want and getattr(runner, "kinds", None):
+    elif spec_want and (getattr(runner, "kinds", None)
+                        or getattr(runner, "latent", False)):
         if eng.spec:    # asked for by name; the default just stays off
             log.warning(
                 "%s: speculative decoding is not supported for a stack with "
-                "more than one kind of attention layer (the verify window "
-                "has one attend); serving without it", mcfg.name)
+                "more than one kind of attention layer, nor over latent "
+                "rows (the verify window has one attend, over K/V a head); "
+                "serving without it", mcfg.name)
     elif spec_want and getattr(runner, "pp_enabled", False):
         log.warning(
             "%s: speculative decoding is not supported with pipeline "

@@ -248,6 +248,14 @@ class Registry:
             "Token-expert pairs the router sent to experts held here "
             "(counted on the device)",
         )
+        self.mla_attends = Counter(
+            "localai_mla_attend_total",
+            "Launches of a model with latent attention by the form of the "
+            "attention their program computes, counted at the enqueue: "
+            "path=absorbed a decode program (queries folded into the "
+            "latent space, the pool never decompressed), path=decompressed "
+            "a prefill chunk (keys and values rebuilt from the span's rows)",
+        )
         self.kv_window_dead_tokens = Gauge(
             "localai_kv_window_dead_tokens",
             "Tokens the block pool holds that no window layer can read any "
@@ -743,6 +751,8 @@ def update_engine_gauges(name: str, m: dict,
         reg.moe_assignments.set_total(m["moe_assignments"], model=name)
     if "state_slots_armed" in m:
         reg.state_slots_armed.set_total(m["state_slots_armed"], model=name)
+    for path, n in (m.get("mla_attends") or {}).items():
+        reg.mla_attends.set_total(n, model=name, path=path)
     if "kv_window_dead_tokens" in m:
         reg.kv_window_dead_tokens.set(m["kv_window_dead_tokens"], model=name)
     if m.get("shed_total"):

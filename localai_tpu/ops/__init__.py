@@ -29,6 +29,7 @@ import jax
 
 from localai_tpu.ops.attention import (
     decode_attention,
+    latent_decode_attention,
     paged_decode_attention,
     paged_decode_attention_ref,
     prefill_attention,
@@ -36,11 +37,13 @@ from localai_tpu.ops.attention import (
 
 __all__ = [
     "decode_attention",
+    "latent_decode_attention",
     "paged_decode_attention",
     "paged_decode_attention_ref",
     "prefill_attention",
     "resolve_attn_impl",
     "select_attn_impl",
+    "select_latent_attn_impl",
     "select_moe_impl",
     "select_paged_attn_impl",
 ]
@@ -142,13 +145,38 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
                 f"Pallas paged attention needs Mosaic-tileable blocks "
                 f"(head_dim % 128 == 0, block_tokens % 32 == 0; got "
                 f"head_dim={head_dim} block_tokens={block_tokens}); "
-                f"{_OVERRIDE}")
+                f"{_OVERRIDE}. (A pool whose rows are off 128 lanes by "
+                f"nature is the LATENT layout's: one 576-element row a "
+                f"token in 640 lanes, ops.latent_decode_attention, for a "
+                f"model with latent attention; this K/V-a-head pool has "
+                f"no such page.)")
         if kv_dtype == "int4" and head_dim % 256:
             raise ValueError(
                 f"an int4 KV pool packs head_dim {head_dim} into "
                 f"{head_dim // 2}-lane rows, which Mosaic cannot DMA and "
                 f"which HBM tiling pads back to 128 lanes (no saving over "
                 f"int8); use kv_dtype: int8, or {_OVERRIDE}")
+    return impl, interpret
+
+
+def select_latent_attn_impl(requested: str, *, block_tokens: int,
+                            kv_dtype: str = "bfloat16",
+                            backend: str | None = None) -> tuple[str, bool]:
+    """Attention-impl decision for the decode step over a LATENT pool
+    (``ops.latent_decode_attention``). A row's width is no gate: the pool
+    stores it in whole 128-lane tiles (``ops.attention.latent_lanes``).
+    The kernel copies a table entry's ``[block_tokens, lanes]`` slab and
+    writes back whole sublane tiles of an unscaled pool: block_tokens a
+    multiple of 32, no int8 / int4 rows."""
+    impl, interpret = resolve_attn_impl(requested, backend)
+    if impl == "pallas" and not interpret and block_tokens % 32:
+        raise ValueError(
+            f"Pallas latent attention needs block_tokens % 32 == 0 (got "
+            f"{block_tokens}); {_OVERRIDE}")
+    if impl == "pallas" and kv_dtype in ("int8", "int4"):
+        raise ValueError(
+            f"the latent decode kernel reads and writes unscaled rows; a "
+            f"{kv_dtype} latent pool is not served")
     return impl, interpret
 
 

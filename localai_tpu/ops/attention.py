@@ -425,7 +425,10 @@ _PAGED_NUM_BUFFERS = 2
 
 def paged_decode_tiling(kv_heads: int, block_tokens: int, row_lanes: int,
                         itemsize: int, max_blocks: int,
-                        num_buffers: int = _PAGED_NUM_BUFFERS
+                        num_buffers: int = _PAGED_NUM_BUFFERS,
+                        arrays: int = 2,
+                        vmem_bytes: int = _PAGED_KV_VMEM_BYTES,
+                        step_blocks_max: int = _PAGED_STEP_BLOCKS_MAX,
                         ) -> tuple[int, int, int]:
     """(blocks a step, steps in flight, VMEM bytes of the K/V ring) the
     paged decode kernel derives from the pool it is handed: ``kv_heads``
@@ -436,9 +439,10 @@ def paged_decode_tiling(kv_heads: int, block_tokens: int, row_lanes: int,
     never under one block (a pool whose single row is over the budget gets
     one-block steps)."""
     depth = max(2, int(num_buffers))
-    row = 2 * kv_heads * block_tokens * row_lanes * itemsize  # K + V
-    blocks = max(1, min(_PAGED_KV_VMEM_BYTES // (depth * row),
-                        _PAGED_STEP_BLOCKS_MAX, max_blocks))
+    # K + V (``arrays`` 2), or the one array of a latent pool
+    row = arrays * kv_heads * block_tokens * row_lanes * itemsize
+    blocks = max(1, min(vmem_bytes // (depth * row), step_blocks_max,
+                        max_blocks))
     blocks = 1 << (blocks.bit_length() - 1)
     return blocks, depth, depth * blocks * row
 
@@ -849,3 +853,270 @@ def paged_decode_attention_ref(
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("skgl,sklh->skgh", probs, values)
     return out.reshape(S, Hq, hd).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent paged decode: one token per slot over a pool of LATENT rows
+# ---------------------------------------------------------------------------
+
+
+def latent_lanes(width: int) -> int:
+    """Lanes a latent pool stores a row of ``width`` elements in: whole
+    128-lane tiles, the rest zeros. A TPU array's minor dimension is tiled
+    by 128 lanes in HBM whatever its declared size (a [.., 576] pool IS
+    [.., 640] there, and Mosaic refuses a copy of the 576), so the pad is
+    what the row costs anyway; stated, the kernel can copy it."""
+    return -(-width // 128) * 128
+
+
+# bytes of the latent kernel's ring, and table entries a step of its walk.
+# Every query head of a slot (64 at the cell's shape) attends a step's rows,
+# so a step's fixed costs (the scalar core's copies and waits, the online
+# softmax's rescale of a [heads, lanes] accumulator) are paid a step, not a
+# row: on the chip (PR 48, us a layer at 32 slots x 33 k rows of 640 lanes,
+# 1670 at the HBM's peak) four entries a step (256 rows: the paged kernel's
+# 1 MiB and its cap of 8) ran 2845, eight 2135, eight three steps deep
+# 1931, sixteen (1024 rows, a ring of 2.5 MiB) 1827
+_LATENT_VMEM_BYTES = 4 << 20
+_LATENT_STEP_BLOCKS_MAX = 16
+
+
+def latent_decode_tiling(block_tokens: int, row_lanes: int, itemsize: int,
+                         max_blocks: int,
+                         num_buffers: int = _PAGED_NUM_BUFFERS
+                         ) -> tuple[int, int, int]:
+    """``paged_decode_tiling`` for a latent pool: one array, no kv-head
+    axis, so a table entry's copy is ``[bt, row_lanes]`` and the ring holds
+    it once (K and V are the same rows), under a budget of its own."""
+    return paged_decode_tiling(
+        1, block_tokens, row_lanes, itemsize, max_blocks, num_buffers,
+        arrays=1, vmem_bytes=_LATENT_VMEM_BYTES,
+        step_blocks_max=_LATENT_STEP_BLOCKS_MAX)
+
+
+def _latent_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, c_ref, new_ref,
+                          o_ref, cout_ref, buf, sem, ring_ref, stage, wsem, *,
+                          block_tokens: int, blocks: int, depth: int,
+                          sm_scale: float, v_lanes: int, mm_dtype,
+                          write_rows: int):
+    # ``_paged_decode_kernel``'s walk over ONE array with no kv-head axis:
+    # c_ref is the FULL stacked [L, N, bt, W] latent pool in HBM, a table
+    # entry's row pool[layer, tbl[s, i]] one contiguous [bt, W] slab. Every
+    # query head of the slot (q_ref [1, H, W]) scores the SAME rows over all
+    # W lanes and takes its values from the first ``v_lanes`` of them: the
+    # rows are read once a slot, not once a head. The kernel is the step's
+    # writer as the paged kernel is over an unscaled pool: the slot's new
+    # row (new_ref [1, 1, W]) is laid over position pos % bt of the frontier
+    # block in the ring before its fold, and the aligned group of
+    # ``write_rows`` rows around it goes back to pool[layer, frontier]. A
+    # slot on the trash block (row 0) writes nothing back.
+    s_idx = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    layer = layer_ref[0]
+    bt, P = block_tokens, blocks
+    T = P * bt
+
+    def walk(slot):
+        pos = pos_ref[slot]
+        nb = jnp.minimum(pos // bt + 1, tbl_ref.shape[1])
+        return pos, nb, (nb + P - 1) // P
+
+    def copy(row, into, b):
+        return pltpu.make_async_copy(c_ref.at[layer, row], buf.at[into],
+                                     sem.at[b])
+
+    def entries(slot, nb, t, b, do):
+        for j in range(P):
+            blk = t * P + j
+
+            @pl.when(blk < nb)
+            def _(j=j, blk=blk):
+                do(tbl_ref[slot, blk], (b, pl.ds(j * bt, bt), slice(None)), b)
+
+    def start(slot, nb, t, b):
+        entries(slot, nb, t, b, lambda *c: copy(*c).start())
+
+    pos, nb, steps = walk(s_idx)
+
+    @pl.when(s_idx == 0)
+    def _cold():
+        # skipped entries leave a buffer's rows as they were: masked to
+        # probability 0, which only a finite value multiplies to 0
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        ring_ref[0] = 0
+        ring_ref[1] = 0         # no write-back in flight
+        start(s_idx, nb, 0, 0)
+
+    base = ring_ref[0]
+    for j in range(1, depth - 1):
+        @pl.when(j < steps)
+        def _prime(j=j):
+            start(s_idx, nb, j, lax.rem(base + j, depth))
+
+    q = q_ref[0].astype(mm_dtype)                       # [H, W]
+    H = q.shape[0]
+    frontier = tbl_ref[s_idx, nb - 1]       # the block the position is in
+    group = pl.multiple_of(pos % bt // write_rows * write_rows, write_rows)
+
+    def write_back():
+        return pltpu.make_async_copy(
+            stage, cout_ref.at[layer, frontier, pl.ds(group, write_rows), :],
+            wsem.at[0])
+
+    def fold(i, carry, last=False):
+        m, l, acc = carry
+        b = lax.rem(base + i, depth)
+        entries(s_idx, nb, i, b, lambda *c: copy(*c).wait())
+        if last:
+            # the staging buffer is the previous slot's until its copy has
+            # left it: a whole program ago
+            @pl.when(ring_ref[1] == 1)
+            def _():
+                write_back().wait()
+            at = (b, pl.ds(pl.multiple_of(
+                lax.rem(nb - 1, P) * bt + group, write_rows), write_rows),
+                slice(None))
+            hit = lax.broadcasted_iota(
+                jnp.int32, (write_rows, 1), 0) == pos % bt - group
+            rows = jnp.where(hit, new_ref[0], buf[at])
+            buf[at] = rows
+
+            @pl.when(frontier != 0)
+            def _():
+                stage[...] = rows
+                write_back().start()
+        k = buf[b].astype(mm_dtype)                     # [T, W]
+        s = jnp.einsum("hd,td->ht", q, k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        idx = i * T + lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        s = jnp.where(idx <= pos, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.einsum(
+            "ht,td->hd", p.astype(mm_dtype), k[:, :v_lanes],
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    def body(i, carry):
+        @pl.when(i + depth - 1 < steps)
+        def _prefetch():
+            start(s_idx, nb, i + depth - 1,
+                  lax.rem(base + i + depth - 1, depth))
+        return fold(i, carry)
+
+    m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((H, v_lanes), jnp.float32)
+    carry = lax.fori_loop(0, steps - 1, body, (m0, l0, acc0))
+
+    # the last fold: its buffer's successor is free, so the next slot's
+    # first copies go there now
+    after = lax.rem(base + steps, depth)
+
+    @pl.when(s_idx + 1 < n_slots)
+    def _next_slot():
+        _, nb_n, _ = walk(s_idx + 1)
+        start(s_idx + 1, nb_n, 0, after)
+
+    ring_ref[0] = after
+    _, l, acc = fold(steps - 1, carry, last=True)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    # the next slot waits for this copy before it stages its own; the
+    # call's last program waits itself
+    ring_ref[1] = (frontier != 0).astype(jnp.int32)
+
+    @pl.when((s_idx + 1 == n_slots) & (frontier != 0))
+    def _():
+        write_back().wait()
+
+
+def latent_decode_attention(
+    q: jax.Array,            # [S, H, W]: a head's query over a latent row
+    cache: jax.Array,        # [L, N, bt, lanes] stacked latent block pool:
+                             # a row's W elements and zeros up to whole
+                             # 128-lane tiles (``latent_lanes``)
+    layer: jax.Array,        # scalar i32
+    tables: jax.Array,       # [S, MB] i32
+    positions: jax.Array,    # [S] i32: the step's write position
+    new: jax.Array,          # [S, W]: the step's rows, for the kernel to WRITE
+    *,
+    v_lanes: int,            # leading lanes of a row that are its VALUE
+    sm_scale: float,
+    interpret: bool = False,
+    num_buffers: int = _PAGED_NUM_BUFFERS,
+):
+    """Flash decode attention of H query heads over ONE latent row a token
+    (latent attention in its absorbed form: models.deepseek), through the
+    block tables: scores over all W lanes of a row, values its first
+    ``v_lanes``. Returns ``(out [S, H, v_lanes], cache)``, the pool aliased
+    to its argument and holding row ``s`` at ``[layer, tables[s,
+    positions[s] // bt], positions[s] % bt]``, trash block aside: the
+    kernel writes the step as ``paged_decode_attention`` does with
+    ``k_new`` (the pool arrives as it was BEFORE the step). The walk, the
+    ring and the slot-to-slot prefetch are that kernel's; the rows are
+    copied once a slot for all heads."""
+    S, H, _ = q.shape
+    bt, W, MB = cache.shape[2], cache.shape[3], tables.shape[1]
+    # the pad lanes are zeros in q as in the pool: they add 0 to a score
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, W - q.shape[2])))
+    new = jnp.pad(new, ((0, 0), (0, W - new.shape[1])))
+    write_rows = 32 // cache.dtype.itemsize
+    if bt % write_rows:
+        write_rows = bt
+    P, depth, _ = latent_decode_tiling(bt, W, cache.dtype.itemsize, MB,
+                                       num_buffers)
+    exact = q.dtype == jnp.bfloat16 and cache.dtype == jnp.bfloat16
+    kernel = functools.partial(
+        _latent_decode_kernel, block_tokens=bt, blocks=P, depth=depth,
+        sm_scale=float(sm_scale), v_lanes=v_lanes,
+        mm_dtype=jnp.bfloat16 if exact else jnp.float32,
+        write_rows=write_rows)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    out, pool = pl.pallas_call(
+        kernel,
+        name="latent_decode_attn",
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1,), lambda s: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((S,), lambda s: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((S, MB), lambda s: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, H, W), lambda s: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),      # the pool, whole, in HBM
+            pl.BlockSpec((1, 1, W), lambda s: (s, 0, 0)),
+        ],
+        out_specs=[pl.BlockSpec((1, H, v_lanes), lambda s: (s, 0, 0)),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=[jax.ShapeDtypeStruct((S, H, v_lanes), q.dtype),
+                   jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
+        input_output_aliases={4: 1},    # the pool: written where it lies
+        scratch_shapes=[
+            pltpu.VMEM((depth, P * bt, W), cache.dtype),
+            pltpu.SemaphoreType.DMA((depth,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((write_rows, W), cache.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(layer, positions.astype(jnp.int32), tables.astype(jnp.int32), q,
+      cache, new.astype(cache.dtype)[:, None, :])
+    return out, pool
+
+
+def latent_decode_attention_ref(q, cache, tables, positions, *,
+                                v_lanes: int, sm_scale: float):
+    """Pure-lax latent decode attention over ONE layer's pool [N, bt, W]
+    that already holds the step's rows (gather + masked softmax): the
+    numerical reference of the kernel. Returns [S, H, v_lanes]."""
+    S, MB = tables.shape
+    bt, W = cache.shape[1], cache.shape[2]
+    rows = cache[tables].reshape(S, MB * bt, W).astype(jnp.float32)
+    scores = jnp.einsum("shd,sld->shl", q.astype(jnp.float32),
+                        rows) * sm_scale
+    keep = jnp.arange(MB * bt)[None, None, :] <= positions[:, None, None]
+    probs = jax.nn.softmax(jnp.where(keep, scores, _NEG_INF), axis=-1)
+    return jnp.einsum("shl,sld->shd", probs,
+                      rows[..., :v_lanes]).astype(q.dtype)

@@ -8,7 +8,8 @@ join of a slice's launches to its executions (``test_launches.py``); and the
 sparse hybrid family's file, cell and readers, with a small model of it
 served through the harness and the control that fails
 (``test_qwen3_next_family.py``); the same for the window / full attention
-family (``test_afmoe_family.py``).
+family (``test_afmoe_family.py``) and for the latent-attention family
+(``test_deepseek_family.py``).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -16,6 +17,7 @@ The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ...`` means the benchmark's, and ``conftest`` here is tier-1's."""
 
 import importlib.util
+import json
 import sys
 from pathlib import Path
 
@@ -50,6 +52,7 @@ _ouro = _load("test_ouro_family", conftest=_conftest)
 _launches = _load("test_launches", conftest=_conftest)
 _qn = _load("test_qwen3_next_family", conftest=_conftest, test_walk=_walk)
 _af = _load("test_afmoe_family", conftest=_conftest, test_walk=_walk)
+_ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -115,11 +118,46 @@ test_every_published_number_of_the_mixed_stacks_catalog_row_is_in_the_file = (
     .test_every_published_number_of_the_mixed_stacks_catalog_row_is_in_the_file)
 test_the_served_pytree_is_a_dense_prefix_beside_rows = (
     _af.test_the_served_pytree_is_a_dense_prefix_beside_rows)
-test_the_mixed_cell_reports_what_the_issue_names = (
-    _af.test_the_mixed_cell_reports_what_the_issue_names)
+
+
+def test_the_mixed_cell_reports_what_the_issue_names(tmp_path, monkeypatch):
+    """PR 44's case as it stands. It COUNTS BENCHMARK.json's cells and
+    configurations (six, five) and takes its own for the last, and no later
+    PR may edit a benchmark file: so it reads the committed file WITHOUT the
+    entries appended behind its cell (PR 48's cell and configuration; the
+    per-layer metrics stay, each names its own cells), which is what its
+    "nothing that was there is changed" holds."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    last = [w["name"] for w in bench["workloads"]].index(_af.CELL) + 1
+    bench["workloads"] = bench["workloads"][:last]
+    used = {w["config"] for w in bench["workloads"]}
+    bench["configs"] = [c for c in bench["configs"] if c["name"] in used]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(_af, "ROOT", tmp_path)
+    _af.test_the_mixed_cell_reports_what_the_issue_names()
+
+
 test_the_swa_readers_read_the_ring_and_the_scopes = (
     _af.test_the_swa_readers_read_the_ring_and_the_scopes)
 test_a_mixed_attention_model_runs_by_files_alone = (
     _af.test_a_mixed_attention_model_runs_by_files_alone)
 test_the_control_fails_a_family_whose_window_layers_see_every_key = (
     _af.test_the_control_fails_a_family_whose_window_layers_see_every_key)
+# PR 48's file: the latent-attention family's hand arithmetic, the catalog
+# row in the file, the dense prefix beside the expert layers, its cell, its two
+# readers, and a small model through the harness with the control that fails
+test_the_hand_arithmetic_of_the_latent_stacks_published_keys = (
+    _ds.test_the_hand_arithmetic_of_the_latent_stacks_published_keys)
+test_every_published_number_of_the_latent_stacks_catalog_row_is_in_the_file = (
+    _ds
+    .test_every_published_number_of_the_latent_stacks_catalog_row_is_in_the_file)
+test_the_served_pytree_is_a_dense_prefix_beside_the_expert_layers = (
+    _ds.test_the_served_pytree_is_a_dense_prefix_beside_the_expert_layers)
+test_the_latent_cell_reports_what_the_issue_names = (
+    _ds.test_the_latent_cell_reports_what_the_issue_names)
+test_the_mla_readers_read_the_ring_and_the_scopes = (
+    _ds.test_the_mla_readers_read_the_ring_and_the_scopes)
+test_a_latent_attention_model_runs_by_files_alone = (
+    _ds.test_a_latent_attention_model_runs_by_files_alone)
+test_the_control_fails_a_family_whose_router_knows_no_groups = (
+    _ds.test_the_control_fails_a_family_whose_router_knows_no_groups)

@@ -294,17 +294,22 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
     params = jax.tree_util.tree_map_with_path(
         placed, jax.eval_shape(lambda: synthetic_params(cfg, quantization)),
         is_leaf=lambda x: isinstance(x, QuantizedTensor))
-    real_init = kvc.init_paged_cache
-    monkeypatch.setattr(
-        kvc, "init_paged_cache",
-        lambda *a, **k: jax.eval_shape(lambda: real_init(*a, **k)))
+    for init in ("init_paged_cache", "init_latent_cache"):
+        monkeypatch.setattr(
+            kvc, init, lambda *a, _real=getattr(kvc, init), **k:
+            jax.eval_shape(lambda: _real(*a, **k)))
     r = ModelRunner(cfg, params, paged=True, attn_impl="pallas_interpret",
                     **runner_kw)
     # as the TPU selector would have it: the compiled kernel
-    assert ops.select_paged_attn_impl(
-        "auto", num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.hd, block_tokens=r.block_tokens, tp=tp,
-        backend="tpu") == ("pallas", False)
+    if cfg.latent:
+        assert ops.select_latent_attn_impl(
+            "auto", block_tokens=r.block_tokens,
+            backend="tpu") == ("pallas", False)
+    else:
+        assert ops.select_paged_attn_impl(
+            "auto", num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+            head_dim=cfg.hd, block_tokens=r.block_tokens, tp=tp,
+            backend="tpu") == ("pallas", False)
     r._paged_attn_interpret = r._attn_interpret = False
     r.layout.interpret = False
     if r.routed:        # and its routed experts: the compiled kernel too
@@ -410,6 +415,7 @@ M7B, MS24B = "mistral-7b-v0.3-int8", "mistral-small-24b-int8-tp4"
 OURO = "ouro-2.6b-int8"
 QN80 = "qwen3-next-80b-a3b-ep8"
 TRL = "trinity-large-ep8"
+AXK1 = "axk1-ep16"
 
 
 @pytest.fixture
@@ -793,6 +799,106 @@ def test_mixed_attention_cell_programs_fit_one_chip(topo, monkeypatch, cell,
             <= hbm["arguments_gib"] + 0.005)
     assert need / 2**30 <= hbm["largest_program_gib"] + 0.001
     assert 0.25 * HBM_BYTES < need < HBM_BYTES
+
+
+@pytest.mark.parametrize("cell", [AXK1], indirect=True)
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk_128_sample",
+                                     "prefill_chunk_512",
+                                     "prefill_chunk_512_sample"])
+def test_latent_attention_cell_programs_fit_one_chip(topo, monkeypatch, cell,
+                                                     program):
+    """PR 48: the configuration FILE of the latent-attention stack (1 dense +
+    6 expert layers, 12 held experts of 7168 x 2048 a layer, bfloat16
+    weights, a 7 x 3072-block pool of 640-lane rows, 34816 positions)
+    compiles for one v5e chip and fits it, with the numbers its ``hbm`` block
+    restates. What Mosaic compiled of a decode program: the latent kernel
+    twice (the dense layer's call and ONE in the layer scan's body), under
+    ``attn.latent_decode``, the pool aliased through it, and ``moe_experts``
+    once, the three expert leaves its operands WHOLE; of a prefill chunk the
+    ``moe_experts`` call alone: its attend is XLA, a rolled loop over 1024
+    rows of the span at a time under ``attn.latent_chunk`` with the
+    decompression (``mla/kv_b``) inside. The pool is the scan's carry: no
+    pool-shaped temp."""
+    cfg, doc = cell
+    eng = doc["engine"]
+    assert cfg.latent and cfg.routed and not cfg.recurrent
+    assert cfg.latent_width == 576 and cfg.hd == 192
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="",
+        num_slots=eng["max_slots"], max_ctx=doc["context_size"],
+        kv_num_blocks=eng["kv_num_blocks"], kv_block_tokens=64)
+    pool = a["kv"].c.shape
+    assert pool == (7, eng["kv_num_blocks"], 64, 640)
+    assert a["kv"].c.dtype == bf16 and r.max_blocks == 544
+    c = compile_cell_program(r, a, program)
+    text = c.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    experts = [ln for ln in calls if "moe/experts/moe_experts" in ln]
+    leaves = tuple(a["params"]["layers"][n] for n in ("w_gate", "w_up",
+                                                      "w_down"))
+    assert [w.shape for w in leaves] == [
+        (6, 1, 12, 7168, 2048), (6, 1, 12, 7168, 2048),
+        (6, 1, 12, 2048, 7168)]
+    assert len(experts) == 1
+    assert "bf16[6,1,12,7168,2048]" in experts[0]
+    rest = [ln for ln in calls if ln not in experts]
+    if program == "decode":
+        assert len(rest) == 2 and all(
+            "attn.latent_decode/latent_decode_attn" in ln for ln in rest)
+    else:
+        assert not rest
+        assert "attn.latent_chunk" in text and "mla/kv_b" in text
+    # no layer's experts staged, no second pool
+    for staged in ("bf16[12,7168,2048]", "bf16[12,2048,7168]"):
+        assert staged not in text
+    m = c.memory_analysis()
+    pool_bytes = int(np.prod(pool)) * 2
+    need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    print(f"HBM {program}: arguments {m.argument_size_in_bytes / 2**30:.3f} "
+          f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
+          f"{need / 2**30:.3f} GiB")
+    assert m.temp_size_in_bytes < pool_bytes
+    if program == "decode":
+        assert m.temp_size_in_bytes < 0.25 * 2**30
+    hbm = doc["hbm"]
+    # (a chunk that samples nothing takes no head: 0.27 GiB fewer)
+    assert (hbm["arguments_gib"] - 0.28 < m.argument_size_in_bytes / 2**30
+            <= hbm["arguments_gib"] + 0.005)
+    assert need / 2**30 <= hbm["largest_program_gib"] + 0.001
+    assert 0.25 * HBM_BYTES < need < HBM_BYTES
+
+
+@pytest.mark.parametrize("num_buffers", [2, 3])
+@pytest.mark.parametrize("bt", [16, 32, 64, 128])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_latent_kernel_compiles_wherever_the_selector_says_pallas(
+        topo, dtype, bt, num_buffers):
+    """``ops.latent_decode_attention`` at the cell's row (576 elements in
+    640 lanes, 64 heads, values the first 512 lanes) for every block size
+    ``ops.select_latent_attn_impl`` answers "pallas" for; what it refuses
+    (a block under the write-back's sublane tile) names the override."""
+    import functools
+
+    from localai_tpu.ops.attention import latent_lanes
+
+    try:
+        answer = ops.select_latent_attn_impl("auto", block_tokens=bt,
+                                             backend="tpu")
+    except ValueError as e:
+        assert "attn_impl: xla" in str(e) and bt % 32
+        return
+    assert answer == ("pallas", False)
+    dt = {"bfloat16": bf16, "float32": f32}[dtype]
+    lanes = latent_lanes(576)
+    compile_for(
+        topo,
+        functools.partial(ops.latent_decode_attention, v_lanes=512,
+                          sm_scale=0.13, num_buffers=num_buffers),
+        ((S, 64, 576), dt), ((LAYERS, 65, bt, lanes), dt), ((), i32),
+        ((S, 2048 // bt), i32), ((S,), i32), ((S, 576), dt))
 
 
 @pytest.mark.parametrize("cell, program, overlap", [
