@@ -1167,7 +1167,7 @@ class PagedLayout:
     block_tokens: int
     max_blocks: int         # table entries a slot
     num_blocks: int         # blocks in the pool, the trash block included
-    overlap_mode: str       # parallel.overlap.resolve_mode ("": GSPMD)
+    overlap_mode: str       # overlap.resolve_mode: "manual", "" = GSPMD
 
     from_stacked = staticmethod(PagedKVCache.from_stacked)
 
@@ -1205,8 +1205,9 @@ class PagedLayout:
 
     def tp_trunk(self, params, rope, tokens, positions, kv: PagedKVCache,
                  tables):
-        """The decode forward as a manual-TP trunk with decomposed per-layer
-        reductions (parallel.overlap; where ``overlap_mode`` is set)."""
+        """The decode forward as a manual-TP trunk, one all-reduce a
+        row-parallel product (parallel.overlap; where ``overlap_mode`` is
+        set)."""
         from localai_tpu.parallel import overlap as ovl
 
         trunk = {k: params[k] for k in ovl.TRUNK_KEYS}
@@ -1214,7 +1215,6 @@ class PagedLayout:
             self.cfg, trunk, self.mesh, tokens, positions,
             kv.stacked(), tables, rope,
             ctx_pad=self.ctx,
-            mode=self.overlap_mode,
             use_pallas=self.attn_impl == "pallas",
             interpret=self.interpret,
         )

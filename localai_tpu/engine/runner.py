@@ -348,22 +348,20 @@ class ModelRunner:
                     tp=mesh.shape["model"] if mesh is not None else 1,
                     kv_dtype=kv_dtype,
                 )
-            # collective/compute overlap (parallel.overlap): meshed decode
-            # runs the trunk as a manual-TP shard_map with the per-layer
-            # psums decomposed into chunked psum_scatter+all_gather so ICI
-            # latency hides behind the matmuls; resolve_mode gates
-            # unsupported meshes back to GSPMD.
+            # meshed decode runs the trunk as a manual-TP shard_map
+            # (parallel.overlap): each chip's kernel writes its own heads,
+            # two all-reduces a layer; resolve_mode gates unsupported
+            # meshes back to GSPMD.
             self.overlap_mode = ""
             if mesh is not None:
                 from localai_tpu.parallel import overlap as ovl
 
                 self.overlap_mode, ovl_why = ovl.resolve_mode(cfg, mesh)
                 if self.overlap_mode:
-                    log.info("meshed decode: manual-TP %s reductions",
-                             self.overlap_mode)
+                    log.info("meshed decode: manual-TP trunk")
                 elif ovl_why:
-                    log.info("meshed decode overlap unavailable: %s "
-                             "(GSPMD psum path)", ovl_why)
+                    log.info("meshed decode: manual-TP trunk unavailable: "
+                             "%s (GSPMD path)", ovl_why)
             # one device-resident zeros row reused by every non-final
             # chunk dispatch (whose sample=False program ignores counts —
             # no per-chunk [V] host alloc + H2D copy)

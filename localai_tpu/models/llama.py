@@ -471,9 +471,8 @@ def forward(
                             # (multimodal injection bypasses the token gather)
     reduce: Any = None,     # manual-TP row-parallel reduction applied to the
                             # attention-out / mlp-down products inside a
-                            # shard_map body (parallel.overlap) — plain psum
-                            # or the chunked psum_scatter+all_gather overlap
-                            # decomposition; None = single device / GSPMD
+                            # shard_map body (parallel.overlap: one psum
+                            # each); None = single device / GSPMD
 ) -> tuple[jax.Array, Any]:
     """Shared transformer trunk: returns (hidden [B, T, D], updated kv_stack).
 

@@ -1,42 +1,29 @@
-"""Collective/compute overlap for the meshed paged decode hot path.
+"""The manual tensor-parallel trunk of the meshed paged decode step.
 
-The GSPMD meshed decode (pjit + NamedSharding, the PR 8 default) leaves
-the two per-layer Megatron psums — after attention-out and after mlp-down
-— as monolithic all-reduces whose ICI latency sits on the critical path
-of every decoded token. This module runs the SAME trunk math as a manual
-shard_map over the mesh (like parallel.ring does for sequence-parallel
-prefill) so the reduction can be *decomposed*: each row-parallel product
-splits into chunks along the hidden dim, every chunk goes through
-``psum_scatter`` (each device sums only its D/tp tile —
-parallel.sharding.overlap_intermediate_spec is the scattered layout)
-followed by a tiled ``all_gather``, and because the chunks are
-independent collectives instead of one fused all-reduce, XLA's
-latency-hiding scheduler can start chunk ``i``'s ICI transfer while
-chunk ``i+1``'s partial product (and the next layer-region matmul) is
-still on the MXU. Communication volume is identical to the plain psum
-(reduce-scatter + all-gather IS the canonical all-reduce decomposition);
-only the exposure of the latency changes.
-
-Numerics: ``psum_scatter`` + ``all_gather`` computes the same per-element
-device sums as ``psum`` — on a 2-wide 'model' axis there is exactly one
-addition per element, so greedy decode is BYTE-IDENTICAL between
-``mode="overlap"`` and ``mode="psum"`` (pinned by tests/test_overlap.py);
-on wider meshes the summation tree may differ at the ULP level, the same
-caveat every all-reduce implementation carries.
+The meshed decode runs the SAME trunk math as GSPMD's (pjit +
+NamedSharding) as a manual shard_map over the mesh, like parallel.ring
+does for sequence-parallel prefill: each chip's paged kernel attends and
+writes its own kv heads of the pool, and the two row-parallel products of
+a layer (attention-out, mlp-down) are summed by ONE ``lax.psum`` each
+under the scope ``mesh.reduce`` (:func:`make_reduce`): two all-reduces a
+layer, which is what a profiler's trace shows of the mesh. At one token a
+slot the message is [S, 1, D] and latency-bound (~10 us on a v5e 2x2):
+there is no matmul under which a piece of it could hide, so it is not
+split. The TPU compiler folds a chunked ``psum_scatter`` + ``all_gather``
+back into one all-reduce and then gathers, a chunk at a time, what every
+chip already holds: a quarter of the step (PERF.md 6, PR 49).
 
 Scope gates (``resolve_mode``): paged KV, 'model' the only busy mesh axis
 (data/seq/expert/pipe == 1 — the pool writes of distinct data shards
 cannot be reconciled manually without an extra collective), dense MLP,
 and tp dividing heads/kv-heads/ffn/hidden. Everything else keeps the
 GSPMD path. Knob, read by ``resolve_mode`` alone: ``LOCALAI_MESH_OVERLAP``
-= auto/1 (overlap when supported, the default), ``psum`` (manual shard_map,
-undecomposed psum — the parity reference), ``0`` (GSPMD, the pre-overlap
-behavior).
+= auto/1 (the manual trunk where supported, the default) or ``0`` (GSPMD:
+the tests' parity reference).
 """
 
 from __future__ import annotations
 
-import logging
 import os
 from functools import partial
 from typing import Any, Optional
@@ -50,30 +37,23 @@ from localai_tpu.models import llama as mdl
 from localai_tpu.models import quant as qnt
 from localai_tpu.models.llama import LlamaConfig
 
-log = logging.getLogger(__name__)
-
 TRUNK_KEYS = ("embed", "final_norm", "layers")
-
-# independent psum_scatter+all_gather pairs a row-parallel product splits
-# into along the hidden dim
-CHUNKS = 4
 
 
 def resolve_mode(cfg: LlamaConfig, mesh: Optional[Mesh],
                  requested: Optional[str] = None) -> tuple[str, str]:
-    """The overlap-path decision: ("overlap" | "psum" | "", reason).
+    """Who serves the meshed decode trunk: ("manual" | "", reason).
 
     ``requested`` defaults to ``LOCALAI_MESH_OVERLAP`` (unset: "auto").
     "" keeps the GSPMD decode; the reason explains any gate that fired
-    (empty when the requested mode is simply honored)."""
+    (empty when the request is simply honored)."""
     if requested is None:
         requested = os.environ.get("LOCALAI_MESH_OVERLAP", "")
     req = (requested or "auto").strip().lower()
     if req in ("0", "off", "none"):
         return "", ""
-    if req not in ("auto", "1", "overlap", "psum"):
+    if req not in ("auto", "1"):
         return "", f"unknown LOCALAI_MESH_OVERLAP value {requested!r}"
-    want = "psum" if req == "psum" else "overlap"
     if mesh is None:
         return "", ""
     tp = mesh.shape.get("model", 1)
@@ -82,7 +62,7 @@ def resolve_mode(cfg: LlamaConfig, mesh: Optional[Mesh],
     busy = [ax for ax in ("data", "seq", "expert", "pipe")
             if mesh.shape.get(ax, 1) > 1]
     if busy:
-        return "", (f"mesh also shards {busy}; manual-TP overlap needs "
+        return "", (f"mesh also shards {busy}; the manual-TP trunk needs "
                     "'model' as the only busy axis")
     if cfg.num_experts:
         return "", "MoE decode stays on the GSPMD path"
@@ -92,42 +72,21 @@ def resolve_mode(cfg: LlamaConfig, mesh: Optional[Mesh],
             f"heads ({cfg.num_heads} q / {cfg.num_kv_heads} kv), ffn "
             f"({cfg.intermediate_size}) or hidden ({cfg.hidden_size}) "
             f"not divisible by tensor_parallel {tp}")
-    return want, ""
+    return "manual", ""
 
 
-def make_reduce(mode: str, tp: int, chunks: int = CHUNKS,
-                axis_name: str = "model"):
-    """The row-parallel reduction for the manual-TP trunk.
-
-    "psum": one fused all-reduce (the parity reference). "overlap": split
-    the product into ``chunks`` independent psum_scatter+all_gather pairs
-    along the hidden dim so their ICI transfers overlap neighboring
-    compute. Falls back chunk-by-chunk to the largest split the dim
-    supports; an indivisible dim degrades to the plain psum."""
+def make_reduce(tp: int, axis_name: str = "model"):
+    """The row-parallel reduction of the manual-TP trunk: one all-reduce a
+    product, under a scope of its own so that a trace holds it apart from
+    the matmul in front of it. None on one device."""
     if tp <= 1:
         return None
-    if mode == "psum":
-        return lambda x: lax.psum(x, axis_name)
 
-    def overlap_reduce(x):
-        d = x.shape[-1]
-        n = max(1, min(chunks, d))
-        while n > 1 and d % (n * tp):
-            n -= 1
-        if d % tp:
+    def reduce(x):
+        with jax.named_scope("mesh.reduce"):
             return lax.psum(x, axis_name)
-        dim = x.ndim - 1
-        pieces = jnp.split(x, n, axis=-1) if n > 1 else [x]
-        out = [
-            lax.all_gather(
-                lax.psum_scatter(p, axis_name, scatter_dimension=dim,
-                                 tiled=True),
-                axis_name, axis=dim, tiled=True)
-            for p in pieces
-        ]
-        return jnp.concatenate(out, axis=-1) if n > 1 else out[0]
 
-    return overlap_reduce
+    return reduce
 
 
 def _embed_local(table, ids, dtype, axis_name: str = "model"):
@@ -152,7 +111,6 @@ def paged_decode_trunk(
     rope: tuple[jax.Array, jax.Array],
     *,
     ctx_pad: int,
-    mode: str = "overlap",
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> tuple[jax.Array, tuple]:
@@ -167,7 +125,7 @@ def paged_decode_trunk(
     shard of the pool, the KV write through the (replicated, data==1)
     block tables into the local shard (by the kernel itself on an unscaled
     pool, else the policy's scatter), and the two per-layer reductions
-    via :func:`make_reduce` — decomposed when ``mode="overlap"``."""
+    via :func:`make_reduce`."""
     from localai_tpu.engine import kvcache as kvc
     from localai_tpu.parallel import sharding as shd
 
@@ -186,7 +144,7 @@ def paged_decode_trunk(
 
     def local_fn(trunk, tokens, positions, kv_stacked, tables,
                  cos_t, sin_t):
-        reduce = make_reduce(mode, tp)
+        reduce = make_reduce(tp)
         mask = kvc.decode_mask(cfg, positions, ctx_pad)
         write = kvc.paged_decode_write(tables, positions, raw=use_pallas)
         if embed_sharded:

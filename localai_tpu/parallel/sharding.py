@@ -196,16 +196,6 @@ def tp_param_specs(cfg: LlamaConfig, mesh: Mesh, params: Any) -> Any:
     )
 
 
-def overlap_intermediate_spec() -> P:
-    """Layout of the reduce-scattered row-parallel intermediate in the
-    collective/compute-overlap decode path (parallel.overlap): each
-    psum_scatter chunk of the attention-out / mlp-down product lands
-    [S, T, D/tp] with the hidden dim on 'model' before its all_gather
-    re-replicates it. Exposed so tests can pin the decomposition's
-    layout contract."""
-    return P(None, None, "model")
-
-
 def state_specs(mesh: Mesh) -> dict:
     """PartitionSpecs for DecodeState fields (see engine.runner)."""
     return {

@@ -1,10 +1,11 @@
-"""Collective/compute overlap (parallel.overlap): the manual-TP meshed
-decode trunk with chunked psum_scatter+all_gather reductions.
+"""The manual-TP meshed decode trunk (parallel.overlap): one all-reduce a
+row-parallel product, under ``mesh.reduce``.
 
-The load-bearing pin: on the 2-virtual-device CPU mesh the overlap
-decomposition is BYTE-IDENTICAL to the plain-psum manual path (one
-addition per element on a 2-wide axis — no summation-tree freedom), and
-greedy output matches the GSPMD path token-for-token.
+The load-bearing pins: the reduction IS ``lax.psum`` on 2 and on 4 virtual
+devices for the trunk's ``[S, 1, D]`` messages, and on the 2-virtual-device
+CPU mesh (one addition an element: no summation-tree freedom) the trunk's
+greedy output matches the GSPMD path token for token, whatever the pool's
+dtype.
 """
 
 import numpy as np
@@ -29,34 +30,47 @@ def _tp_mesh(n=2):
     return build_mesh(MeshPlan(model=n), devices=jax.devices()[:n])
 
 
-def test_make_reduce_matches_psum_bytewise():
-    mesh = _tp_mesh(2)
-    x = jnp.asarray(
-        np.random.default_rng(0).normal(size=(4, 1, 64)), jnp.float32)
+@pytest.mark.parametrize("shape", [(4, 1, 64), (32, 1, 10), (3, 1, 7)])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_make_reduce_is_psum(tp, shape):
+    """The trunk's reduction against ``lax.psum`` under the same shard_map,
+    byte for byte, and against the sum written out: D divisible by the axis
+    and not."""
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} virtual devices")
+    mesh = _tp_mesh(tp)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=shape), jnp.float32)
 
     def run(reduce_fn):
         return shard_map(
             lambda v: reduce_fn(v * (1.0 + jax.lax.axis_index("model"))),
-            mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_vma=False)(x)
+            mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)(x)
 
-    plain = run(ovl.make_reduce("psum", 2))
-    for chunks in (1, 2, 4):
-        got = run(ovl.make_reduce("overlap", 2, chunks=chunks))
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
-    # indivisible chunk/tp splits degrade to the plain psum, not an error
-    odd = jnp.ones((4, 1, 6), jnp.float32)
-    got = shard_map(
-        ovl.make_reduce("overlap", 2, chunks=4), mesh=mesh,
-        in_specs=(P(),), out_specs=P(), check_vma=False)(odd)
-    np.testing.assert_array_equal(np.asarray(got), 2 * np.asarray(odd))
+    got = run(ovl.make_reduce(tp))
+    plain = run(lambda v: jax.lax.psum(v, "model"))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(x) * sum(range(1, tp + 1)), rtol=1e-6)
+
+
+def test_make_reduce_names_its_scope():
+    """One device has nothing to reduce; on a mesh the one collective of a
+    product sits under ``mesh.reduce`` (what the trace's rows are named by:
+    ``decode/layers/attn.out/mesh.reduce``)."""
+    assert ovl.make_reduce(1) is None
+    mesh = _tp_mesh(2)
+    text = jax.jit(shard_map(
+        ovl.make_reduce(2), mesh=mesh, in_specs=(P(),), out_specs=P(),
+        check_vma=False)).lower(jnp.ones((4, 1, 8))).as_text(debug_info=True)
+    assert "mesh.reduce" in text
+    assert "all_reduce" in text and "all_gather" not in text \
+        and "reduce_scatter" not in text
 
 
 def test_resolve_mode_gates():
     tiny = resolve_model("debug:tiny", dtype="float32").cfg
     mesh = _tp_mesh(2)
-    assert ovl.resolve_mode(tiny, mesh, "auto") == ("overlap", "")
-    assert ovl.resolve_mode(tiny, mesh, "psum") == ("psum", "")
+    assert ovl.resolve_mode(tiny, mesh, "auto") == ("manual", "")
     assert ovl.resolve_mode(tiny, mesh, "0") == ("", "")
     assert ovl.resolve_mode(tiny, None, "auto") == ("", "")
     # dp>1 meshes stay on GSPMD (pool writes of distinct data shards
@@ -78,8 +92,21 @@ def test_resolve_mode_gates():
     assert mode == "" and "divisible" in why
 
 
-def test_overlap_intermediate_spec():
-    assert shd.overlap_intermediate_spec() == P(None, None, "model")
+@pytest.mark.parametrize("value, mode", [
+    (None, "manual"), ("", "manual"), ("auto", "manual"), ("1", "manual"),
+    ("0", ""), ("off", ""), ("psum", ""), ("overlap", "")])
+def test_knob_is_on_or_off(monkeypatch, value, mode):
+    """``LOCALAI_MESH_OVERLAP`` says whether the manual trunk serves the mesh
+    or GSPMD does, and nothing else: the names of the reductions it once
+    chose between are refused with a reason, to GSPMD."""
+    tiny = resolve_model("debug:tiny", dtype="float32").cfg
+    if value is None:
+        monkeypatch.delenv("LOCALAI_MESH_OVERLAP", raising=False)
+    else:
+        monkeypatch.setenv("LOCALAI_MESH_OVERLAP", value)
+    got, why = ovl.resolve_mode(tiny, _tp_mesh(2))
+    assert got == mode
+    assert ("unknown" in why) == (value in ("psum", "overlap"))
 
 
 def _meshed_tokens(monkeypatch, mode, kv_dtype="float32", steps=12):
@@ -91,8 +118,7 @@ def _meshed_tokens(monkeypatch, mode, kv_dtype="float32", steps=12):
         model.cfg, params, num_slots=2, max_ctx=128,
         prefill_buckets=[64], kv_dtype=kv_dtype, paged=True,
         kv_block_tokens=16, mesh=mesh)
-    want = {"0": "", "psum": "psum", "auto": "overlap"}[mode]
-    assert runner.overlap_mode == want
+    assert runner.overlap_mode == {"0": "", "auto": "manual"}[mode]
     slot = runner.acquire_slot()
     toks = [runner.admit(slot, list(range(1, 40)), temperature=0.0)]
     for _ in range(steps // 4):
@@ -100,23 +126,18 @@ def _meshed_tokens(monkeypatch, mode, kv_dtype="float32", steps=12):
     return toks
 
 
-def test_overlap_vs_psum_greedy_byte_identical(monkeypatch):
-    """THE tentpole parity pin: the chunked psum_scatter+all_gather
-    decomposition emits byte-identical greedy tokens to the undecomposed
-    manual psum on the 2-device mesh."""
-    psum = _meshed_tokens(monkeypatch, "psum")
-    over = _meshed_tokens(monkeypatch, "auto")
-    assert psum == over
-
-
-def test_overlap_vs_gspmd_greedy_parity(monkeypatch):
-    gspmd = _meshed_tokens(monkeypatch, "0")
-    over = _meshed_tokens(monkeypatch, "auto")
-    assert gspmd == over
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_trunk_vs_gspmd_greedy_parity(monkeypatch, kv_dtype):
+    """THE parity pin: the manual trunk emits GSPMD's greedy tokens on the
+    2-device mesh, over an unscaled pool and over a scaled one (whose
+    scales ride the trunk's specs beside the rows)."""
+    gspmd = _meshed_tokens(monkeypatch, "0", kv_dtype=kv_dtype)
+    trunk = _meshed_tokens(monkeypatch, "auto", kv_dtype=kv_dtype)
+    assert gspmd == trunk
 
 
 def test_overlap_int4_pool(monkeypatch):
-    """int4 composes with the overlap trunk (packed pool sharded on its
+    """int4 composes with the manual trunk (packed pool sharded on its
     kv-head axis, scales riding the same specs)."""
     i4 = _meshed_tokens(monkeypatch, "auto", kv_dtype="int4")
     f32 = _meshed_tokens(monkeypatch, "auto", kv_dtype="float32")
@@ -124,7 +145,7 @@ def test_overlap_int4_pool(monkeypatch):
 
 
 def test_overlap_multi_slot_and_release(monkeypatch):
-    """The overlap trunk serves the multi-slot lifecycle (admit, decode,
+    """The manual trunk serves the multi-slot lifecycle (admit, decode,
     release, re-admit) identically to GSPMD."""
 
     def run(mode):

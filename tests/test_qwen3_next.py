@@ -707,6 +707,9 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
 # ``stablehlo.reduce_precision``; with that line taken out and the numbered
 # values aside, each text is PR 45's parent's line for line (the two-stage
 # candidates leave them alone: these vocabularies take the one ``top_k``).
+# PR 49 retook the four-chip trunk's two (``decode``, ``decode_n``): each is
+# the text PR 49's parent lowers under ``LOCALAI_MESH_OVERLAP=psum``, to the
+# letter (one ``psum`` a row-parallel product; the chunked form is gone).
 PARENT_TEXT = {
     "mistral-7b-v0.3-int8": {
         "decode":
@@ -720,9 +723,9 @@ PARENT_TEXT = {
     },
     "mistral-small-24b-int8-tp4": {
         "decode":
-            "106a4e5803761ad155f42445f27ec1a05090e5a7aa97ea7b55e85b7ecec387f3",
+            "871c112157b7fa37882418ffa5dc901f22b97f25d992a9712e12a5197dcc7829",
         "decode_n":
-            "8147b53f653f2845f2cc9e685c694891aee1f07ef402014b9daf7baa88cc1766",
+            "b2db0871dba4d7df9afcf88cd252ca53e1bc60e151cb7b2b34d49be02fb1d586",
         "prefill":
             "72bfe1e112c7a68df594347a5684c47074932e0da55929f006b4730ebfa37af5",
         "arm":

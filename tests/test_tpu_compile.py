@@ -345,6 +345,18 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
     return r, avals
 
 
+def collectives(text):
+    """(opcode, result, HLO line) of every collective of a compiled
+    program's text."""
+    import re
+
+    return [(m.group(2), m.group(1), ln.strip()) for ln in text.splitlines()
+            if (m := re.search(
+                r" = (.*?) (all-gather|all-reduce|all-to-all"
+                r"|collective-permute|reduce-scatter|collective-broadcast)"
+                r"[\w\-]*\(", ln))]
+
+
 def compile_program(fn, *args, **static):
     """A runner program compiled for the topology as the runner jits it
     (KV and decode state donated)."""
@@ -1108,13 +1120,55 @@ def test_cell_decode_programs_sort_no_vocabulary(topo, monkeypatch, cell, tp):
     for ln in sorts:
         assert widest(ln.split(", metadata=")[0]) < wide, ln[:200]
     if tp > 1:
-        talk = [(ln.strip(), m.group(1)) for ln in text.splitlines()
-                if (m := re.search(
-                    r" = (.*?) (?:all-gather|all-reduce|all-to-all"
-                    r"|collective-permute|reduce-scatter)[\w\-]*\(", ln))]
-        for ln, result in talk:
+        talk = collectives(text)
+        for _, result, ln in talk:
             assert widest(result) < wide, ln[:200]
-        sampler = [ln for ln, _ in talk if "/sample/" in ln]
+        sampler = [ln for _, _, ln in talk if "/sample/" in ln]
         assert len(sampler) == 2 and all(
             " all-gather" in ln and "/sample/shard_map/merge/" in ln
             for ln in sampler), [ln[:160] for ln in sampler]
+
+
+# ---------------------------------------------------------------------------
+# the mesh: a decode step's reduction is one all-reduce a product
+
+
+@pytest.mark.parametrize("cell, program", [
+    (MS24B, "decode"), (MS24B, "decode_n2")], indirect=["cell"])
+def test_tp4_decode_layers_hold_two_all_reduces(topo, monkeypatch, cell,
+                                                program):
+    """PR 49: the four-chip cell's decode programs hold, in the layer scan's
+    body, exactly two collectives: the all-reduce of ``[32, 1, 5120]`` after
+    ``attn.out``'s product and the one after ``mlp``'s down product, each
+    under ``mesh.reduce`` (80 a step over 40 layers). The chunked
+    ``psum_scatter`` + ``all_gather`` form the trunk had before reached the
+    chip as two all-reduces over tuples of four ``[32, 1, 1280]`` with no
+    ``op_name`` and EIGHT all-gathers a layer of what every chip already held
+    (400 collectives a step). Every collective of the program carries its
+    scope, so a trace's reader finds none under a shape for a name; outside
+    the layers the embedding's ``psum``, the step's ``reduce_and`` and the
+    sampler's two all-gathers are what PR 45 left."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    monkeypatch.setenv("LOCALAI_MESH_OVERLAP", "auto")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, tp=4, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    assert r.overlap_mode
+    text = compile_cell_program(r, a, program).as_text()
+    talk = [(op, result, name[1] if (name := re.search(
+        r'op_name="([^"]*)"', ln)) else "")
+        for op, result, ln in collectives(text)]
+    assert all(name for _, _, name in talk), [t for t in talk if not t[2]]
+    S, D = eng["max_slots"], cfg.hidden_size
+    layers = [t for t in talk if "/layers/" in t[2]]
+    assert sorted((op, re.sub(r"\{[^}]*\}", "", res)) for op, res, _ in layers
+                  ) == [("all-reduce", f"bf16[{S},1,{D}]")] * 2, layers
+    assert sorted(re.search(r"/(attn\.out|mlp)/mesh\.reduce/", name)[1]
+                  for _, _, name in layers) == ["attn.out", "mlp"], layers
+    rest = sorted(op for op, _, name in talk if "/layers/" not in name)
+    assert rest == ["all-gather", "all-gather", "all-reduce", "all-reduce"], [
+        t for t in talk if "/layers/" not in t[2]]
