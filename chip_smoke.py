@@ -253,19 +253,23 @@ def kernel_child(spec: dict) -> int:
     positions = jnp.asarray([edges[i % len(edges)] for i in range(S)],
                             jnp.int32)
 
-    def writes_case(name, q, k, v, layer, tabs, pos, wrap=lambda f: f):
+    def writes_case(name, q, k, v, layer, tabs, pos, wrap=lambda f: f,
+                    live=None, window=None):
         """The kernel as the decode step's WRITER (unscaled pools): handed
         the pool of before the step and the step's rows, its output against
         the reference over the pool the policy's scatter
         (``kvcache._write_rows``) leaves, and the two pools it hands back
         against that pool, EXACTLY, in every layer outside the trash block
-        (largest difference 0). ``wrap``: the kernel under a mesh."""
+        (largest difference 0). ``wrap``: the kernel under a mesh. ``live``
+        [S] bool: the slots that hold a stream, where not all do (the
+        others' rows of the output are zeros). ``window``: the layer's
+        sliding window."""
         from localai_tpu.engine import kvcache as kvc
 
         k_new, v_new = (normal((q.shape[0], k.shape[2], k.shape[-1]))
                         for _ in range(2))
         kernel = wrap(lambda *a: ops.paged_decode_attention(
-            *a, interpret=interpret))
+            *a, sliding_window=window, interpret=interpret))
 
         def written(q, k, v, tabs, pos, k_new, v_new):
             return kernel(q, k, v, jnp.int32(layer), tabs, pos, None, None,
@@ -284,8 +288,10 @@ def kernel_child(spec: dict) -> int:
 
         def reference(q, k, v, tabs, pos, *rows):
             k2, v2 = scattered(q, k, v, tabs, pos, *rows)
-            return ops.paged_decode_attention_ref(q, k2[layer], v2[layer],
-                                                  tabs, pos)
+            ref = ops.paged_decode_attention_ref(
+                q, k2[layer], v2[layer], tabs, pos, sliding_window=window)
+            return ref if live is None else jnp.where(
+                live[:, None, None], ref, 0)
 
         args = (q, k, v, tabs, pos, k_new, v_new)
         run(f"{name} writes: output", lambda *a: written(*a)[0], reference,
@@ -352,6 +358,43 @@ def kernel_child(spec: dict) -> int:
     # rows are hd/2 lanes), so the nibble kernel runs at the nearest shape
     # it serves — same bytes per row as the 8B int8 pool
     paged_case("int4", Hq // 2, Hkv // 2, 2 * hd)
+
+    # -- part-full batches (PR 50): a slot whose table row is on the trash
+    # block gets no copy and no fold, its output row is zeros; the live
+    # slots' rows and the pool are the reference's and the scatter's. The
+    # chat cells' occupancy at their shapes: 5 live of the 7B's 16 slots,
+    # every kv head in a program; 6 live of the 24B's 32, a chip's quarter;
+    # and the first under a sliding window, the walks' first entries past 0
+    def part_full_case(n_live, slots, Hq, Hkv, window=None):
+        mb = min(MB, 8)
+        n = n_live * mb + 1
+        rows = np.sort(rng.permutation(slots)[:n_live])
+        tabs, pos = np.zeros((slots, mb), np.int32), np.zeros(slots, np.int32)
+        tabs[rows] = rng.permutation(np.arange(1, n)).reshape(n_live, mb)
+        ends = edges if window is None else [
+            mb * bt - 1, mb * bt - 2, mb * bt // 2 + 3, (mb - 1) * bt, bt - 1]
+        pos[rows] = [min(ends[i % len(ends)], mb * bt - 1)
+                     for i in range(n_live)]
+        live = jnp.zeros(slots, bool).at[rows].set(True)
+        tabs, pos = jnp.asarray(tabs), jnp.asarray(pos)
+        q = normal((slots, Hq, hd))
+        k, v = normal((2, n, Hkv, bt, hd)), normal((2, n, Hkv, bt, hd))
+        name = (f"paged_decode bfloat16 bt={bt} hd={hd} {n_live} live of "
+                f"{slots} slots" + (f" window {window}" if window else ""))
+        run(name,
+            lambda q, k, v, tabs, pos: ops.paged_decode_attention(
+                q, k, v, jnp.int32(1), tabs, pos, sliding_window=window,
+                interpret=interpret),
+            lambda q, k, v, tabs, pos: jnp.where(
+                live[:, None, None],
+                ops.paged_decode_attention_ref(
+                    q, k[1], v[1], tabs, pos, sliding_window=window), 0),
+            q, k, v, tabs, pos)
+        writes_case(name, q, k, v, 1, tabs, pos, live=live, window=window)
+
+    part_full_case(5, 16, Hq, Hkv)
+    part_full_case(6, 32, Hq // Hkv * max(Hkv // 4, 1), max(Hkv // 4, 1))
+    part_full_case(5, 16, Hq, Hkv, window=bt + bt // 2)
 
     # -- the looped decoder's shape of the same kernel: one query row a kv
     # head (plain multi-head), and a pool whose leading dimension is passes

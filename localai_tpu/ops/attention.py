@@ -460,10 +460,16 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
     # land side by side in a [Hkv, blocks*bt, hd] buffer of a ``depth``-deep
     # ring and fold as one online-softmax tile; entries outside [lo, nb)
     # are not copied (their columns are masked, over whatever the buffer
-    # held). Before a slot's last fold the NEXT slot's first step is started
-    # into the free buffer, so only the call's first program waits on a
-    # copy nothing hides; the ring position rides SMEM scratch across grid
-    # steps (the grid is sequential: 'arbitrary').
+    # held). Before a slot's last fold the NEXT LIVE slot's first step is
+    # started into the free buffer, so only the call's first live program
+    # waits on a copy nothing hides; the ring position rides SMEM scratch
+    # across grid steps (the grid is sequential: 'arbitrary').
+    # A slot whose frontier entry tbl[s, pos // bt] is block 0, the trash
+    # block (a released slot, an admission before its last chunk: block 0 is
+    # never allocated), holds no stream: its program starts no copy, waits
+    # for none, folds nothing, leaves the ring and its flags as they were
+    # and writes zeros to its ``o`` block, which nothing reads. A part-full
+    # batch pays for its live slots; a full one runs what it always ran.
     # Scales of int8/int4 pools arrive gathered in logical order, one
     # [Hkv, steps, blocks*bt] f32 slab a slot through their BlockSpec (a
     # [1, bt] row of the f32 pool is narrower than Mosaic's 128-lane DMA
@@ -478,8 +484,10 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
     # every head in one strided copy, staged in a buffer of its own so the
     # ring is free at once) back to pool[layer, row]. Beside the one row the
     # group carries what was just read from those rows: this slot's older
-    # tokens, or rows past its frontier that nothing reads. A slot on the
-    # trash block (row 0: released slots) writes nothing back.
+    # tokens, or rows past its frontier that nothing reads. The write-back
+    # in flight (``ring_ref[1]``) is the previous LIVE slot's: the next live
+    # slot waits for it before it stages its own, the call's last program
+    # waits for it whether or not it is live.
     if quantized:
         ks_ref, vs_ref, o_ref, kbuf, vbuf, ksem, vsem, ring_ref = rest
     elif write_rows:
@@ -526,7 +534,28 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
         entries(slot, lo, nb, t, buf,
                 lambda *c: (k_copy(*c).start(), v_copy(*c).start()))
 
+    def frontier_of(slot):
+        """The block ``slot``'s position is in, the last entry of its walk:
+        0, the trash block, for a slot that holds no stream."""
+        return tbl_ref[slot, walk(slot)[2] - 1]
+
+    def start_live_from(slot, buf):
+        """The first step of the first live slot at or behind ``slot`` into
+        ``buf``, if the call has one: a walk over the frontier entries in
+        SMEM that ends at its first read where ``slot`` is live."""
+        nxt = lax.while_loop(
+            lambda s: (s < n_slots)
+            & (frontier_of(jnp.minimum(s, n_slots - 1)) == 0),
+            lambda s: s + 1, slot)
+
+        @pl.when(nxt < n_slots)
+        def _():
+            _, lo_n, nb_n, t0_n, _ = walk(nxt)
+            start(nxt, lo_n, nb_n, t0_n, buf)
+
     pos, lo, nb, t0, steps = walk(s_idx)
+    frontier = frontier_of(s_idx)
+    live = frontier != 0
 
     @pl.when(s_idx == 0)
     def _cold():
@@ -537,16 +566,10 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
         ring_ref[0] = 0
         if write_rows:
             ring_ref[1] = 0     # no write-back in flight
-        start(s_idx, lo, nb, t0, 0)
+        # the call's first copies: the first live slot's, wherever it is
+        start_live_from(s_idx, 0)
 
-    base = ring_ref[0]          # the buffer this slot's first step is in
-    for j in range(1, depth - 1):
-        @pl.when(j < steps)
-        def _prime(j=j):
-            start(s_idx, lo, nb, t0 + j, lax.rem(base + j, depth))
-
-    q = q_ref[0].astype(mm_dtype)                     # [Hkv, g, hd]
-    Hkv, g, hd = q.shape
+    q_dims = q_ref.shape[1:]                          # [Hkv, g, hd]
 
     def write_back(stage, out_ref, sem):
         return pltpu.make_async_copy(
@@ -564,93 +587,105 @@ def _paged_decode_kernel(layer_ref, pos_ref, tbl_ref, q_ref, k_ref, v_ref,
             jnp.int32, (1, write_rows, 1), 1) == pos % bt - group
         rows = jnp.where(hit, new_ref[0][:, None, :], ring[at])
         ring[at] = rows
-
-        @pl.when(frontier != 0)
-        def _():
-            stage[...] = rows
-            write_back(stage, out_ref, sem).start()
+        stage[...] = rows
+        write_back(stage, out_ref, sem).start()
 
     if write_rows:
-        frontier = tbl_ref[s_idx, nb - 1]   # the block the position is in
         group = pl.multiple_of(pos % bt // write_rows * write_rows,
                                write_rows)
 
-    def fold(i, carry, last=False):
-        m, l, acc = carry
-        t = t0 + i
-        buf = lax.rem(base + i, depth)
-        entries(s_idx, lo, nb, t, buf, lambda *c: k_copy(*c).wait())
-        if last and write_rows:
-            # the staging buffers are the previous slot's until its copies
-            # have left them: a whole program ago
-            @pl.when(ring_ref[1] == 1)
-            def _():
-                write_back(kstage, kout_ref, 0).wait()
-                write_back(vstage, vout_ref, 1).wait()
-            lay(knew_ref, kbuf, buf, kstage, kout_ref, 0)
-        if int4:
-            k = _unpack_nibbles(kbuf[buf], jnp.float32)
-        else:
-            k = kbuf[buf]
-        s = jnp.einsum("hgd,htd->hgt", q, k.astype(mm_dtype),
-                       preferred_element_type=jnp.float32) * sm_scale
-        if quantized:
-            s = s * ks_ref[0, :, pl.ds(t, 1), :]
-        idx = t * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-        keep = idx <= pos
-        if sliding_window is not None:
-            keep &= idx > pos - sliding_window
-        s = jnp.where(keep, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        # denominator sums the raw probabilities; V scales touch only the
-        # weighted-value numerator
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            p = p * vs_ref[0, :, pl.ds(t, 1), :]
-        entries(s_idx, lo, nb, t, buf, lambda *c: v_copy(*c).wait())
-        if last and write_rows:
-            lay(vnew_ref, vbuf, buf, vstage, vout_ref, 1)
-        if int4:
-            v = _unpack_nibbles(vbuf[buf], jnp.float32)
-        else:
-            v = vbuf[buf].astype(jnp.float32)
-        acc_new = acc * alpha + jnp.einsum(
-            "hgt,htd->hgd", p, v, preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+    @pl.when(live)
+    def _attend():
+        base = ring_ref[0]      # the buffer this slot's first step is in
+        for j in range(1, depth - 1):
+            @pl.when(j < steps)
+            def _prime(j=j):
+                start(s_idx, lo, nb, t0 + j, lax.rem(base + j, depth))
 
-    def body(i, carry):
-        @pl.when(i + depth - 1 < steps)
-        def _prefetch():
-            start(s_idx, lo, nb, t0 + i + depth - 1,
-                  lax.rem(base + i + depth - 1, depth))
-        return fold(i, carry)
+        q = q_ref[0].astype(mm_dtype)                 # [Hkv, g, hd]
+        Hkv, g, hd = q_dims
 
-    m0 = jnp.full((Hkv, g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hkv, g, 1), jnp.float32)
-    acc0 = jnp.zeros((Hkv, g, hd), jnp.float32)
-    carry = lax.fori_loop(0, steps - 1, body, (m0, l0, acc0))
+        def fold(i, carry, last=False):
+            m, l, acc = carry
+            t = t0 + i
+            buf = lax.rem(base + i, depth)
+            entries(s_idx, lo, nb, t, buf, lambda *c: k_copy(*c).wait())
+            if last and write_rows:
+                # the staging buffers are the previous live slot's until
+                # its copies have left them: a whole program ago or more
+                @pl.when(ring_ref[1] == 1)
+                def _():
+                    write_back(kstage, kout_ref, 0).wait()
+                    write_back(vstage, vout_ref, 1).wait()
+                lay(knew_ref, kbuf, buf, kstage, kout_ref, 0)
+            if int4:
+                k = _unpack_nibbles(kbuf[buf], jnp.float32)
+            else:
+                k = kbuf[buf]
+            s = jnp.einsum("hgd,htd->hgt", q, k.astype(mm_dtype),
+                           preferred_element_type=jnp.float32) * sm_scale
+            if quantized:
+                s = s * ks_ref[0, :, pl.ds(t, 1), :]
+            idx = t * T + lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+            keep = idx <= pos
+            if sliding_window is not None:
+                keep &= idx > pos - sliding_window
+            s = jnp.where(keep, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            # denominator sums the raw probabilities; V scales touch only
+            # the weighted-value numerator
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * vs_ref[0, :, pl.ds(t, 1), :]
+            entries(s_idx, lo, nb, t, buf, lambda *c: v_copy(*c).wait())
+            if last and write_rows:
+                lay(vnew_ref, vbuf, buf, vstage, vout_ref, 1)
+            if int4:
+                v = _unpack_nibbles(vbuf[buf], jnp.float32)
+            else:
+                v = vbuf[buf].astype(jnp.float32)
+            acc_new = acc * alpha + jnp.einsum(
+                "hgt,htd->hgd", p, v, preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
 
-    # the last fold: its buffer's successor is free (every step before it
-    # has been folded), so the next slot's first copies go there now
-    after = lax.rem(base + steps, depth)
+        def body(i, carry):
+            @pl.when(i + depth - 1 < steps)
+            def _prefetch():
+                start(s_idx, lo, nb, t0 + i + depth - 1,
+                      lax.rem(base + i + depth - 1, depth))
+            return fold(i, carry)
 
-    @pl.when(s_idx + 1 < n_slots)
-    def _next_slot():
-        _, lo_n, nb_n, t0_n, _ = walk(s_idx + 1)
-        start(s_idx + 1, lo_n, nb_n, t0_n, after)
+        m0 = jnp.full((Hkv, g, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((Hkv, g, 1), jnp.float32)
+        acc0 = jnp.zeros((Hkv, g, hd), jnp.float32)
+        carry = lax.fori_loop(0, steps - 1, body, (m0, l0, acc0))
 
-    ring_ref[0] = after
-    _, l, acc = fold(steps - 1, carry, last=True)
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        # the last fold: its buffer's successor is free (every step before
+        # it has been folded), so the next live slot's first copies go
+        # there now
+        after = lax.rem(base + steps, depth)
+        start_live_from(s_idx + 1, after)
+        ring_ref[0] = after
+        _, l, acc = fold(steps - 1, carry, last=True)
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        if write_rows:
+            # the next live slot waits for these copies before it stages
+            # its own
+            ring_ref[1] = 1
+
+    @pl.when(~live)
+    def _empty():
+        # a slot on the trash block holds no stream: no copy, no wait, no
+        # fold, the ring and its flags as they were. Nothing reads the row
+        o_ref[0] = jnp.zeros(q_dims, o_ref.dtype)
+
     if write_rows:
-        # the next slot waits for these copies before it stages its own;
-        # the call's last program waits itself: the next call of this cache
-        # layer, one step later, reads the row
-        ring_ref[1] = (frontier != 0).astype(jnp.int32)
-
-        @pl.when((s_idx + 1 == n_slots) & (frontier != 0))
+        # the call's last program, live or not, waits for the write-back in
+        # flight: the next call of this cache layer, one step later, reads
+        # the row
+        @pl.when((s_idx + 1 == n_slots) & (ring_ref[1] == 1))
         def _():
             write_back(kstage, kout_ref, 0).wait()
             write_back(vstage, vout_ref, 1).wait()

@@ -567,13 +567,15 @@ def test_speculation_and_quantization_are_refused():
 # PR 46 retook the kernel path's two DECODE programs, which now hold
 # ops/gdn.py's kernel where they sliced the state and stepped it as XLA; its
 # chunks and all four of the XLA path stand as taken: the step they run moved
-# into ``recur`` and lowers to the letter it did.
+# into ``recur`` and lowers to the letter it did. PR 50 retook the same two
+# (the paged kernel of its full-attention layers does no work for a slot on
+# the trash block); the chunks and the XLA path stand.
 QN_PARENT_TEXT = {
     "pallas_interpret": {
         "decode":
-            "2bd70500098b54f9a9898e008ef5b4e334cb87b0a8ed7c6efceb084c07399679",
+            "e4e6c6be85c5f816aba14559c96f7c5a34c73b67a84fdec4b4c1ce496ba7baea",
         "decode_n":
-            "96c2e18114a786bd279129f62923fbed9179822f1370735ad69b234e0567001f",
+            "f0d7dea69dcd89fcef6c498b8b8448527f27ec335ee2c3c8bc9a4a4c95caf330",
         "prefill_1":
             "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
         "prefill_0":

@@ -50,6 +50,14 @@ def test_kernel_phase_steps_on_cpu(smoke, capfd):
                  "paged_decode bfloat16 bt=64 hd=16 writes: output",
                  "paged_decode bfloat16 bt=64 hd=16 writes: pool",
                  "paged_decode looped q_per_kv=1 layer 5 of 6 writes: pool",
+                 # PR 50: part-full batches, the empty slots' rows zeros
+                 "paged_decode bfloat16 bt=64 hd=16 5 live of 16 slots",
+                 "paged_decode bfloat16 bt=64 hd=16 6 live of 32 slots "
+                 "writes: pool",
+                 "paged_decode bfloat16 bt=64 hd=16 5 live of 16 slots "
+                 "window 96",
+                 "paged_decode bfloat16 bt=64 hd=16 5 live of 16 slots "
+                 "window 96 writes: pool",
                  "paged_decode looped q_per_kv=1 layer 0 of 6",
                  "paged_decode looped q_per_kv=1 layer 5 of 6",
                  "decode bfloat16", "decode int8",

@@ -764,11 +764,13 @@ def test_the_configuration_file_is_the_published_row_cut_as_stated(family):
 # layout, and the window / full stack's programs (``trl-ep8-longshort-
 # decode``'s) are the parent's to the letter; the sparse hybrid's
 # (``qn80-ep8-decode``'s) are held by tests/test_afmoe.py's QN_PARENT_TEXT,
-# which stands as taken.
+# which stands as taken. PR 50 retook the kernel path's ``decode`` (the paged
+# kernel's body changed: no work for a slot on the trash block); its chunks
+# and the XLA path stand as taken.
 AF_PARENT_TEXT = {
     "pallas_interpret": {
         "decode":
-            "a064c385310b1a6f446719acf69799c351d5611591450bad90c324dacd2ab0c9",
+            "b55d2367aa650714233ee17e5d1f10efeeae5efcac5128768abdf01c98a4ceab",
         "prefill_1":
             "189c6e43b3a847679d68d877a305967c95504f428a42e3730bbeb7aa183b4259",
         "prefill_0":

@@ -3,6 +3,9 @@ attention parity vs the contiguous path, chunked-prefill scheduling, and
 pool-exhaustion admission control. All on the CPU backend (the Pallas
 paged kernel runs in interpret mode)."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -216,8 +219,8 @@ def test_paged_kernel_matches_reference(hkv, g, bt, kv_dtype, windowed,
     the local head count, the block and the element size) is the served
     one: frontiers on both sides of a block's and of a step's last row and
     on the table's last, ragged slots, empty slots on the trash block
-    between live ones (what the cross-slot prefetch walks into), and a
-    call of one slot."""
+    between live ones (what the cross-slot prefetch walks over: their rows
+    come out exact zeros), and a call of one slot."""
     from localai_tpu.models.quant import quantize_lastdim, quantize_lastdim4
     from localai_tpu.ops.attention import paged_decode_tiling
 
@@ -232,6 +235,7 @@ def test_paged_kernel_matches_reference(hkv, g, bt, kv_dtype, windowed,
     edges = [0, bt - 1, None, bt, P * bt - 1, None, P * bt, MB * bt - 1,
              (MB * bt) // 2 + 7]
     positions = np.asarray([p or 0 for p in edges], np.int32)
+    live = np.asarray([p is not None for p in edges])
     need = [0 if p is None else p // bt + 1 for p in edges]
     N = sum(need) + 1
     free = list(rng.permutation(np.arange(1, N)))
@@ -262,9 +266,10 @@ def test_paged_kernel_matches_reference(hkv, g, bt, kv_dtype, windowed,
     # float32 q: the same float32 arithmetic in another order; bf16 q: the
     # result is rounded to bf16 on both sides
     tol = 2e-5 if q_dtype == "float32" else 1e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
+    out = np.asarray(out, np.float32)
+    np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
                                rtol=tol, atol=tol)
+    np.testing.assert_array_equal(out[~live], 0.0)
     if windowed:
         return
     one = slice(7, 8)          # S = 1: the first program is the last
@@ -386,6 +391,133 @@ def test_paged_kernel_writes_the_row_it_reads(kv_dtype, hkv, g, bt, windowed,
     tol = 2e-5 if kv_dtype == "float32" else 1e-2
     np.testing.assert_allclose(np.asarray(out[live, 0]),
                                np.asarray(ref[live]), rtol=tol, atol=tol)
+
+
+def _empty_slot_batches(bt, ctx):
+    """name -> a batch's frontiers, None for a slot on the trash block.
+    Few distinct shapes (5, 11, 32 and 1 slots; 4 and 1 live), so that the
+    cases of one pool, role and window share their compiled calls."""
+    a, b, c, d = bt + 3, ctx - 1, 2 * bt, bt - 1
+    return {
+        "slot_0_empty": [None, a, b, c, d],
+        "last_empty_behind_a_live_one": [a, b, c, d, None],
+        "runs_of_empties": [a, None, None, None, b, None, None, None, None,
+                            c, d],
+        "one_live_of_32": [None] * 13 + [b] + [None] * 18,
+        "every_slot_empty": [None] * 5,
+        "one_slot_and_it_is_empty": [None],
+    }
+
+
+@functools.partial(jax.jit, static_argnames=("role", "window"))
+def _step_through_the_kernel(stack, at, q, k_new, v_new, tables, positions,
+                             *, role, window):
+    """(output [S, Hq, hd], the stack after the step). ``reader``: the
+    kernel alone over the stack as it is; ``writer``: through the decode
+    policy (``raw=True``), where the kernel writes an unscaled pool's rows
+    and a scaled pool's are scattered before it reads."""
+    from localai_tpu.engine import kvcache as kvc
+
+    kernel = functools.partial(ops.paged_decode_attention,
+                               sliding_window=window, interpret=True)
+    if role == "reader":
+        return kernel(q, stack[0], stack[1], at, tables, positions,
+                      *stack[2:]), stack
+    new, keys, values = kvc.paged_decode_write(tables, positions, raw=True)(
+        stack, at, k_new, v_new)
+    got = kvc.kernel_attend(kernel, tables, positions)(
+        q[:, None], keys, values, None)
+    if len(stack) == 4:
+        return got[:, 0], new
+    return got[0][:, 0], got[1]
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("role", ["writer", "reader"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("batch", list(_empty_slot_batches(1, 1)))
+def test_paged_kernel_does_nothing_for_a_slot_on_the_trash_block(
+        batch, kv_dtype, role, windowed):
+    """PR 50: a program whose slot's frontier entry is block 0 copies
+    nothing, folds nothing and writes zeros; the cross-slot prefetch, the
+    cold start and the write-back in flight go from live slot to live slot
+    over it. So the live rows of the output, and the pool, equal TO THE BIT
+    those of the same call over the live slots alone, in order; the empty
+    rows are exact zeros; the live rows are the reference's. Where the
+    kernel is the writer (an unscaled pool through the policy) the pool
+    equals ``_write_rows``' bit for bit outside block 0 and block 0 is as
+    it was: with the last slot empty the last program waits for a
+    write-back that is not its own, with every slot empty nothing moves."""
+    from localai_tpu.engine import kvcache as kvc
+    from localai_tpu.models.quant import quantize_lastdim
+    from localai_tpu.ops.attention import paged_decode_tiling
+
+    hkv, g, bt, hd, layers, layer = 2, 4, 32, 128, 2, 1
+    pool_dt = jnp.dtype(kv_dtype)
+    MB = 3      # a narrow table: steps of two entries, the second ragged
+    assert paged_decode_tiling(hkv, bt, hd, pool_dt.itemsize, MB)[0] == 2
+    edges = _empty_slot_batches(bt, MB * bt)[batch]
+    S, N = len(edges), 10
+    rng = np.random.default_rng(S * 10 + windowed)
+    live = np.asarray([p is not None for p in edges])
+    positions = np.asarray([p or 0 for p in edges], np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    tables = np.zeros((S, MB), np.int32)            # trash-padded
+    for s, p in enumerate(edges):
+        if p is not None:
+            tables[s, :p // bt + 1] = [free.pop() for _ in range(p // bt + 1)]
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+
+    q = jnp.asarray(rng.normal(size=(S, hkv * g, hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(S, 1, hkv, hd)),
+                                jnp.float32) for _ in range(2))
+    full = [jnp.asarray(rng.normal(size=(layers, N, hkv, bt, hd)),
+                        jnp.float32) for _ in range(2)]
+    if kv_dtype == "int8":
+        (k, ks), (v, vs) = (quantize_lastdim(a) for a in full)
+        stack = (k, v, ks, vs)
+    else:
+        stack = tuple(a.astype(pool_dt) for a in full)
+    at = jnp.int32(layer)
+
+    def call(keep):
+        return _step_through_the_kernel(
+            stack, at, q[keep], k_new[keep], v_new[keep], tables[keep],
+            positions[keep], role=role,
+            window=bt + 5 if windowed else None)
+
+    out, after = call(np.arange(S))
+    out = np.asarray(out)
+    assert out.shape == (S, hkv * g, hd)
+    np.testing.assert_array_equal(out[~live], 0.0)
+    wrote = role == "writer" and kv_dtype != "int8"
+    want = after
+    if wrote:
+        blk = tables[jnp.arange(S), positions // bt]
+        want = kvc._write_rows(stack, at, blk, positions % bt,
+                               k_new[:, 0], v_new[:, 0])
+        for was, now, scattered in zip(stack, after, want):
+            was, now, scattered = (np.asarray(a, np.float32)
+                                   for a in (was, now, scattered))
+            np.testing.assert_array_equal(now[:, 1:], scattered[:, 1:])
+            np.testing.assert_array_equal(now[:, 0], was[:, 0])
+    if not live.any():
+        return
+    alone, after_alone = call(np.flatnonzero(live))
+    np.testing.assert_array_equal(out[live], np.asarray(alone))
+    for a, b in zip(after, after_alone):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        np.testing.assert_array_equal(a[:, 1:], b[:, 1:])
+        # (a scaled pool's scatter lays empty slots' rows on block 0; the
+        # kernel lays nothing there)
+        if wrote or role == "reader":
+            np.testing.assert_array_equal(a[:, 0], b[:, 0])
+    ref = ops.paged_decode_attention_ref(
+        q, want[0][layer], want[1][layer], tables, positions,
+        *(sc[layer] for sc in want[2:]),
+        sliding_window=bt + 5 if windowed else None)
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               rtol=1e-2, atol=1e-2)
 
 
 # The greedy pair (two prompts sharing the pool, token for token against the

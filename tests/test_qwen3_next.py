@@ -710,12 +710,15 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
 # PR 49 retook the four-chip trunk's two (``decode``, ``decode_n``): each is
 # the text PR 49's parent lowers under ``LOCALAI_MESH_OVERLAP=psum``, to the
 # letter (one ``psum`` a row-parallel product; the chunked form is gone).
+# PR 50 retook the six ``decode`` / ``decode_n`` (the paged kernel's body
+# changed: it does no work for a slot on the trash block); ``prefill`` and
+# ``arm``, which hold no kernel, stand as taken.
 PARENT_TEXT = {
     "mistral-7b-v0.3-int8": {
         "decode":
-            "becee62042000f4f6926225feb39568937e521fadb2f78cebf9a1c67ff8f55d9",
+            "f2c5a0fbb56f1413911c177e72bed6bd30887e59922fd68ca56da7d902c4fc86",
         "decode_n":
-            "4304b0ddc87e98c88f931474636a4a0fd8456c10b7b020f1857fe84c1e8b9447",
+            "aeb400bc8401e521c6fc5dd310790dd772aa96efba2835f42adbf4fff9eaba66",
         "prefill":
             "5da338912ddb6924ef2ad7c994a1a6db2a1d233d941281a99b3c37a6affe5b12",
         "arm":
@@ -723,9 +726,9 @@ PARENT_TEXT = {
     },
     "mistral-small-24b-int8-tp4": {
         "decode":
-            "871c112157b7fa37882418ffa5dc901f22b97f25d992a9712e12a5197dcc7829",
+            "17d1318cb3353870a3a30acc6c055c94e692ac446f0dabaf22599ca0d218224b",
         "decode_n":
-            "b2db0871dba4d7df9afcf88cd252ca53e1bc60e151cb7b2b34d49be02fb1d586",
+            "0da1864b409456ed8239270fcd37a4c5c16fe1430aa48cc9bc85a50c62867bb2",
         "prefill":
             "72bfe1e112c7a68df594347a5684c47074932e0da55929f006b4730ebfa37af5",
         "arm":
@@ -733,9 +736,9 @@ PARENT_TEXT = {
     },
     "ouro-2.6b-int8": {
         "decode":
-            "fcce185254ac8c7d2087b67167bb86366cbaac12fe789945230beb5337ba3ef9",
+            "9f2f46c6133afb09f824ec5c6f79b8cadbb3617761ae1f7a0aa20fc39877c25a",
         "decode_n":
-            "a68c907f5457d42050173341eada9e5fee52a3a68f27c53953e4789d9c05632d",
+            "433dc057083dd983c5cc6f4b5c503965f67da4b1ff872a18a4450f40c6be0dd0",
         "prefill":
             "a6e0180316c86e3f77a14a239cb921c62f4bac783543a9b790d6eed56d97be94",
         "arm":

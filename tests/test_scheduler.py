@@ -1060,6 +1060,52 @@ def test_a_decode_row_holds_what_its_launch_held(paged, monkeypatch, steps):
 
 
 @pytest.mark.parametrize("steps", [None, 2], ids=["own_k", "k_2"])
+def test_live_slots_are_the_rows_off_the_trash_block(paged, monkeypatch,
+                                                     steps):
+    """PR 50: the paged kernel does no work for a slot whose frontier entry
+    ``tables[s, pos // bt]`` is block 0. At every decode launch that test,
+    made on the device table and frontiers as the launch finds them, names
+    as many slots as the ring's ``live_slots``: an admission between two
+    of its chunks is still on the trash block (its row is installed with
+    the arming, before the last chunk), a released slot is back on it, a
+    slot armed again is off it. So 100 less ``runner.occupancy_mean`` is
+    the share of programs the kernel skips."""
+    s, _ = paged
+    r = s.runner
+    bt = r.allocator.block_tokens
+    off_trash = {}
+
+    def spy(real):
+        def launch(*n):
+            tables = np.asarray(r.block_tables)
+            at = np.minimum(np.asarray(r.state.positions) // bt,
+                            tables.shape[1] - 1)
+            off_trash[s._launch_seq] = tables[np.arange(len(at)), at] != 0
+            return real(*n)
+        return launch
+
+    monkeypatch.setattr(r, "step_async", spy(r.step_async))
+    monkeypatch.setattr(r, "step_n_async", spy(r.step_n_async))
+    rows, log, _ = _held_run(s, monkeypatch, steps)
+    decode = {n: row for n, row in rows.items()
+              if row["program"].startswith("decode")}
+    assert len(decode) > 10 and set(decode) <= set(off_trash)
+    for n, row in decode.items():
+        assert row["live_slots"] == off_trash[n].sum() == len(log[n][1]) > 0
+    # between two chunks of one prompt a decode step ran, the prompt's slot
+    # not among its live ones; slots left the batch and came back
+    order = sorted(rows)
+    programs = [rows[n]["program"] for n in order]
+    between = [n for i, n in enumerate(order[1:-1], 1)
+               if n in decode and programs[i - 1] == programs[i + 1]
+               == "prefill_chunk" and rows[order[i + 1]]["chunk_offset"] > 0]
+    assert between
+    held = np.stack([off_trash[n] for n in sorted(decode)]).astype(int)
+    assert held.sum(1).min() == 1 and held.sum(1).max() == 2
+    assert (np.diff(held, axis=0) == 1).sum() >= 3      # armed (again)
+
+
+@pytest.mark.parametrize("steps", [None, 2], ids=["own_k", "k_2"])
 def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
     """Sum of ``chunk_tokens`` = the prompts' tokens less what the prefix
     pool served; a prompt's chunks run from the tokens the pool served in
