@@ -58,6 +58,7 @@ with ONE new-rows argument and one view (``LatentView``).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -837,14 +838,22 @@ class LatentKVCache:
     A token's row is ``cfg.latent_width`` elements (its compressed latent,
     then its rope key, shared by every head) in whole 128-lane tiles
     (``ops.attention.latent_lanes``: the pad is zeros and is what HBM's
-    tiling costs anyway). Block 0 is the trash block, as the paged pool's."""
+    tiling costs anyway). Block 0 is the trash block, as the paged pool's.
+
+    A model whose layers keep MORE THAN ONE KIND OF STATE a token
+    (models.dots3; ``cfg.latent_states`` names them) holds an array for
+    each, all under the ONE allocator and block table, each with a layer
+    axis and a row width of its own: ``c`` its full layers' rows, ``w`` its
+    window layers' rows, ``i`` its full layers' index keys."""
 
     c: jax.Array
+    w: Optional[jax.Array] = None
+    i: Optional[jax.Array] = None
 
     quantized = False
 
     def stacked(self):
-        return (self.c,)
+        return tuple(a for a in (self.c, self.w, self.i) if a is not None)
 
     @staticmethod
     def from_stacked(t) -> "LatentKVCache":
@@ -853,16 +862,16 @@ class LatentKVCache:
 
 def init_latent_cache(cfg: LlamaConfig, num_blocks: int, block_tokens: int,
                       dtype: str = "bfloat16") -> LatentKVCache:
-    return LatentKVCache(jnp.zeros(
-        (cfg.cache_layers, num_blocks, block_tokens,
-         latent_lanes(cfg.latent_width)), jnp.dtype(dtype)))
+    return LatentKVCache(*(jnp.zeros(
+        (layers, num_blocks, block_tokens, latent_lanes(width)),
+        jnp.dtype(dtype)) for _, layers, width in cfg.latent_states))
 
 
 class LatentView(NamedTuple):
-    """What a latent write policy hands its attend: the WHOLE stacked pool
-    and the layer to read (``LayerView``'s reasons), and the step's rows in
-    the pool's dtype and lanes where the policy has NOT stored them and the
-    kernel is to."""
+    """What a latent write policy hands its attend: the WHOLE stacked array
+    of the pool it wrote and the layer to read (``LayerView``'s reasons), and
+    the step's rows in the pool's dtype and lanes where the policy has NOT
+    stored them and the kernel is to."""
 
     cache: jax.Array                    # [L, N, bt, lanes]
     layer: jax.Array                    # scalar i32
@@ -871,8 +880,9 @@ class LatentView(NamedTuple):
 
 @dataclasses.dataclass(frozen=True)
 class LatentAttend:
-    """The ``attn`` a latent layout hands the model's forward. ``path`` says
-    which form of the attention the model is to compute, ``run`` attends:
+    """The ``attn`` a latent layout hands the model's forward (one a KIND of
+    layer where the stack has several). ``path`` says which form of the
+    attention the model is to compute, ``run`` attends:
 
     ``absorbed`` (a decode step): the queries folded into the latent space,
     attended over the rows as they lie: ``run(q [B, T, H, W], view, mask, *,
@@ -883,6 +893,10 @@ class LatentAttend:
     H, dq], view, mask, *, scale, expand, v_dim) -> out [1, T, H, v_dim]``,
     where ``expand(rows [n, W]) -> (k [n, H, dq], v [n, H, v_dim])`` is the
     model's.
+    An attend that SELECTS the rows it attends (``latent_sparse_decode``,
+    ``latent_sparse_chunk``) is handed ``index`` besides: the
+    tokens' index queries ``q [B, T, Hi, di]``, their heads' weights ``w [B,
+    T, Hi]`` and the pool's array of index ``keys``.
     """
 
     path: str
@@ -894,15 +908,20 @@ def _pad_lanes(rows, lanes: int):
                    + [(0, lanes - rows.shape[-1])])
 
 
+def _with(stack: tuple, state: int, cache) -> tuple:
+    return (*stack[:state], cache, *stack[state + 1:])
+
+
 def latent_decode_write(tables: jax.Array, positions: jax.Array,
                         raw: bool = False):
     """``paged_decode_write`` for a latent pool: ``write(stack, layer, row
-    [S, 1, W]) -> (stack, view)``. ``raw``: the kernel writes (the stack
-    goes back untouched, the view carries the rows); else one scatter, a
-    row a slot, released slots' into the trash block."""
+    [S, 1, W], state) -> (stack, view)``, ``state`` the array of the pool
+    the row is of. ``raw``: the kernel writes (the stack goes back
+    untouched, the view carries the rows); else one scatter, a row a slot,
+    released slots' into the trash block."""
 
-    def write(stack, layer, row):
-        (cache,) = stack
+    def write(stack, layer, row, state: int = 0):
+        cache = stack[state]
         with jax.named_scope("kv_pool.write"):
             new = _pad_lanes(row[:, 0].astype(cache.dtype), cache.shape[-1])
             if raw:
@@ -910,7 +929,7 @@ def latent_decode_write(tables: jax.Array, positions: jax.Array,
             bt = cache.shape[2]
             blk = tables[jnp.arange(tables.shape[0]), positions // bt]
             cache = cache.at[layer, blk, positions % bt].set(new)
-        return (cache,), LatentView(cache, layer)
+        return _with(stack, state, cache), LatentView(cache, layer)
 
     return write
 
@@ -927,6 +946,16 @@ def latent_kernel_attend(tables, positions, interpret: bool) -> LatentAttend:
     return LatentAttend("absorbed", scoped("attn.latent_decode")(run))
 
 
+def _absorbed(q, rows, keep, scale, v_lanes: int):
+    """The absorbed attend of q [S, T, H, W] over ``rows [S, n, W]``, of
+    which a query sees those of ``keep [S, T, n]``."""
+    scores = jnp.einsum("sthd,sld->shtl", q, rows).astype(
+        jnp.float32) * scale
+    scores = jnp.where(keep[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+    return jnp.einsum("shtl,sld->sthd", probs, rows[..., :v_lanes])
+
+
 def latent_xla_attend(tables) -> LatentAttend:
     """The absorbed attend as XLA over the rows the tables name (layer and
     blocks in one gather): the CPU path and the kernel's oracle."""
@@ -936,26 +965,288 @@ def latent_xla_attend(tables) -> LatentAttend:
             rows = view.cache[view.layer, tables]
             rows = rows.reshape(S, MB * rows.shape[2], rows.shape[3])
             rows = rows[..., :q.shape[-1]].astype(q.dtype)
-        scores = jnp.einsum("sthd,sld->shtl", q, rows).astype(
-            jnp.float32) * scale
-        scores = jnp.where(mask[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
-        return jnp.einsum("shtl,sld->sthd", probs, rows[..., :v_lanes])
+        return _absorbed(q, rows, mask, scale, v_lanes)
 
     return LatentAttend("absorbed", scoped("attn.latent_decode")(run))
+
+
+def latent_window_decode(tables, positions, window: int) -> LatentAttend:
+    """The absorbed attend of a WINDOW layer's decode step: each slot
+    gathers the blocks that hold its last ``window`` positions (the token
+    itself counted; ``window_span`` of them, from table entry ``(position -
+    window + 1) // bt`` on) and no other, and masks to the window."""
+    def run(q, view, _mask, *, scale, v_lanes):     # q [S, 1, H, W]
+        S, MB = tables.shape
+        bt = view.cache.shape[2]
+        nb = min(window_span(window, 1, bt) // bt, MB)
+        first = jnp.clip((positions - window + 1) // bt, 0, MB - nb)
+        with jax.named_scope("kv_pool.gather"):
+            ids = jnp.take_along_axis(
+                tables, first[:, None] + jnp.arange(nb)[None, :], axis=1)
+            rows = view.cache[view.layer, ids]
+            rows = rows.reshape(S, nb * bt, rows.shape[3])
+            rows = rows[..., :q.shape[-1]].astype(q.dtype)
+        kpos = first[:, None] * bt + jnp.arange(nb * bt)[None, :]
+        pos = positions[:, None]
+        keep = (kpos <= pos) & (kpos > pos - window)
+        return _absorbed(q, rows, keep[:, None], scale, v_lanes)
+
+    return LatentAttend("absorbed", scoped("attn.latent_window")(run))
+
+
+# ---------------------------------------------------------------------------
+# select before attend: an indexer's scores, the exact k best of them
+# ---------------------------------------------------------------------------
+
+
+def index_scores(q, w, keys):
+    """``I = sum_j w_j ReLU(q_j . k)`` of index queries ``q [..., T, Hi,
+    di]`` with head weights ``w [..., T, Hi]`` against ``keys [..., n,
+    lanes]`` (their first di lanes): [..., T, n] float32."""
+    keys = keys[..., :q.shape[-1]].astype(q.dtype)
+    s = jnp.einsum("...thd,...nd->...thn", q, keys,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("...thn,...th->...tn", jax.nn.relu(s),
+                      w.astype(jnp.float32))
+
+
+def _order_keys(scores, seen):
+    """float32 scores as uint32 that order as the scores do, 0 where a
+    position is not ``seen`` (under every score's key, -inf's included)."""
+    # + 0.0: a negative zero orders as zero does
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32) + 0.0,
+                                    jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    return jnp.where(seen, keys, jnp.uint32(0))
+
+
+def kth_key(keys, k):
+    """The EXACT ``k``-th largest of ``keys [..., n]`` uint32 (``k [...]``
+    >= 1), a bit at a time from the top: the largest value that ``k`` keys
+    reach. 32 counts over the row; no sort."""
+    def bit(b, kth):
+        cand = kth | (jnp.uint32(1) << (31 - b).astype(jnp.uint32))
+        reach = jnp.sum(keys >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, cand, kth)
+
+    return lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+# lanes of a step of the selection's dense forms: a running count along a row
+# is a [128, 128] triangle on the MXU a group and a short sum over the groups,
+# and a chosen row is found in ITS group: no long scan, no gather of scalars
+# (on the chip a ``cumsum`` over 34 k and a binary search of 2048 ranks by
+# gathered scalars ran 10.6 ms a layer at 32 streams; PERF.md section 6)
+SELECT_GROUP = 128
+
+
+def _groups(x):
+    """``x [R, C]`` in groups of ``SELECT_GROUP`` lanes, zeros behind the
+    row: [R, G, g]."""
+    g = SELECT_GROUP
+    return jnp.pad(x, ((0, 0), (0, -x.shape[-1] % g))).reshape(
+        x.shape[0], -1, g)
+
+
+def _running_count(x):
+    """Inclusive running count of ``x [R, C]`` bool along its row, as
+    (count inside its group [R, G, g] float32, the groups' totals [R, G]
+    int32): exact (0 / 1 in bfloat16, float32 sums)."""
+    g = SELECT_GROUP
+    inside = jnp.einsum(
+        "...i,ij->...j", _groups(x).astype(jnp.bfloat16),
+        jnp.triu(jnp.ones((g, g), jnp.bfloat16)),
+        preferred_element_type=jnp.float32)
+    return inside, inside[..., -1].astype(jnp.int32)
+
+
+def choose(keys, want):
+    """Which of ``keys [R, C]`` (``_order_keys``) are a row's exact ``want
+    [R]`` largest: every key over the ``want``-th largest (``kth_key``: a
+    threshold, no sort) and, of those that TIE with it, the earliest
+    positions that fit (``lax.top_k``'s order). [R, C] bool."""
+    kth = kth_key(keys, jnp.maximum(want, 1))[:, None]
+    above, ties = keys > kth, keys == kth
+    room = want - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    inside, totals = _running_count(ties)
+    before = jnp.cumsum(totals, axis=-1) - totals       # of earlier groups
+    fits = inside + before[..., None].astype(jnp.float32) <= room[
+        :, None, None].astype(jnp.float32)
+    return above | (ties & fits.reshape(keys.shape[0], -1)[
+        :, :keys.shape[1]])
+
+
+def select_rows(scores, n, k: int):
+    """The positions of the exact ``min(k, n)`` largest of ``scores [S, C]``
+    among each row's first ``n [S]`` positions: (positions [S, k] i32 in
+    rising order, which of them are real [S, k] bool). ``choose``, then a
+    compaction by rank in dense steps: the j-th chosen position lies in the
+    group whose running total first reaches j + 1 (a comparison against the
+    groups' totals), at the lane whose count inside the group is what is
+    left (that group's counts fetched by a one-hot product, exact). No
+    sort: on the chip ``lax.top_k`` of 2048 from 34 k is a whole sort of the
+    row."""
+    C, g = scores.shape[-1], SELECT_GROUP
+    want = jnp.minimum(k, n).astype(jnp.int32)
+    chosen = choose(_order_keys(
+        scores, jnp.arange(C)[None, :] < n[:, None]), want)
+    inside, totals = _running_count(chosen)             # [S, G, g], [S, G]
+    upto = jnp.cumsum(totals, axis=-1)                  # inclusive
+    ranks = jnp.arange(1, k + 1, dtype=jnp.int32)
+    # the group of rank j: how many groups end before it
+    group = jnp.sum(upto[:, None, :] < ranks[None, :, None], axis=-1,
+                    dtype=jnp.int32)                    # [S, k]
+    one_hot = group[..., None] == jnp.arange(totals.shape[-1])[None, None, :]
+    left = ranks[None, :] - jnp.sum(jnp.where(
+        one_hot, (upto - totals)[:, None, :], 0), axis=-1)
+    # that group's counts (0 where a lane is not chosen): <= 128, exact in
+    # bfloat16
+    counts = jnp.where(_groups(chosen), inside, 0.0).astype(jnp.bfloat16)
+    mine = jnp.einsum("skg,sgl->skl", one_hot.astype(jnp.bfloat16), counts,
+                      preferred_element_type=jnp.float32)
+    lane = jnp.sum(jnp.where(mine == left[..., None].astype(jnp.float32),
+                             jnp.arange(g, dtype=jnp.int32), 0), axis=-1)
+    where = group * g + lane
+    return (jnp.minimum(where, C - 1).astype(jnp.int32),
+            ranks[None, :] <= want[:, None])
+
+
+def table_entries(tables, index):
+    """``tables[s, index[s, j]]`` for ``index [S, k]`` as a comparison and a
+    sum over the table row: a gather of 65 k scalars ran 0.67 ms a layer on
+    the chip."""
+    one_hot = index[..., None] == jnp.arange(tables.shape[1])[None, None, :]
+    return jnp.sum(jnp.where(one_hot, tables[:, None, :], 0), axis=-1)
+
+
+def _attend_chosen(q, scores, n, k: int, view, tables, scope: str, scale,
+                   v_lanes: int):
+    """The absorbed attend of q [S, 1, H, W] over each stream's exact
+    ``min(k, n)`` best-scored rows (``scores [S, C]`` over its first ``n
+    [S]`` positions): chosen (``attn.select``), gathered through the
+    stream's table row and attended alone (``scope``)."""
+    bt = view.cache.shape[2]
+    with jax.named_scope("attn.select"):
+        where, real = select_rows(scores, n, k)
+    with jax.named_scope(scope):
+        blk = table_entries(tables, where // bt)
+        rows = view.cache[view.layer, blk, where % bt]       # [S, k, lanes]
+        rows = rows[..., :q.shape[-1]].astype(q.dtype)
+        return _absorbed(q, rows, real[:, None], scale, v_lanes)
+
+
+def latent_sparse_decode(tables, positions, topk: int) -> LatentAttend:
+    """The absorbed attend of a layer with an indexer, a decode step: SCORE
+    every cached index key of a slot through its table row
+    (``attn.index``), SELECT the exact ``min(topk, n)`` best positions
+    (``attn.select``), gather THOSE rows through the table and attend them
+    alone (``attn.sparse_decode``). A slot with fewer than ``topk`` rows
+    attends all of them: dense latent attention. The step's own row and key
+    are in the pool (the policy wrote them)."""
+    def run(q, view, _mask, *, scale, v_lanes, index):  # q [S, 1, H, W]
+        S, MB = tables.shape
+        bt = view.cache.shape[2]
+        k = min(topk, MB * bt)
+        with jax.named_scope("attn.index"):
+            keys = index["keys"][view.layer, tables]
+            keys = keys.reshape(S, MB * bt, keys.shape[3])
+            scores = index_scores(index["q"], index["w"], keys)[:, 0]
+        return _attend_chosen(q, scores, positions + 1, k, view, tables,
+                              "attn.sparse_decode", scale, v_lanes)
+
+    return LatentAttend("absorbed", run)
+
+
+# queries of a chunk that select and gather their rows at a time: the chosen
+# rows of 128 queries (2048 each, 640 lanes) are 320 MiB in bfloat16, their
+# heads' scores 128 MiB in float32
+SPARSE_CHUNK_QUERIES = 128
+
+
+def latent_sparse_chunk(table_row: jax.Array, offset: jax.Array,
+                        topk: int) -> LatentAttend:
+    """The absorbed attend of a layer with an indexer, a chunk behind
+    ``offset`` cached tokens: every query position is a decode step's
+    stream over the ONE table row. A walk lays the queries' index scores
+    over the span the chunk has (``attn.index``; [T, span] float32: 71 MiB
+    at 512 x 34816); then, ``SPARSE_CHUNK_QUERIES`` queries at a time, each
+    query's exact ``min(topk, t + 1)`` best positions (``attn.select``:
+    ``select_rows``, the decode step's, so a chunk and a step choose the
+    same rows to the tie) are gathered through the table and attended alone
+    (``attn.sparse_chunk``). No row the queries do not attend is read: an
+    admission behind a 32768-token document gathers 2048 rows a query where
+    the decompressed walk rebuilt the keys and values of the whole span for
+    every head (~48 ms a chunk and layer on the chip, PERF.md section 6).
+
+    A chunk that ENDS inside the first ``topk`` positions selects nothing
+    (every query attends all it sees): it attends the table's first
+    ``topk`` rows once for all its queries (``attn.dense_chunk``), scores
+    no index key and chooses nothing. The chunk's own rows and keys are in
+    the pool (the policy wrote them)."""
+    def run(q, view, _mask, *, scale, v_lanes, index):  # q [1, T, H, W]
+        cache, layer = view.cache, view.layer
+        bt, T, W = cache.shape[2], q.shape[1], q.shape[-1]
+        MB = table_row.shape[0]
+        k = min(topk, MB * bt)
+        qpos = offset + jnp.arange(T)
+
+        def every_row():
+            nb = -(-k // bt)
+            with jax.named_scope("attn.dense_chunk"):
+                rows = cache[layer, table_row[:nb]].reshape(
+                    nb * bt, -1)[:, :W].astype(q.dtype)
+                keep = jnp.arange(nb * bt)[None, :] <= qpos[:, None]
+                return _absorbed(q, rows[None], keep[None], scale, v_lanes)
+
+        def chosen_rows():
+            walk = latent_walk(bt)
+            nb = walk // bt
+            table = jnp.pad(table_row, (0, -MB % nb))   # whole steps: trash
+            span = table.shape[0] * bt
+            steps = jnp.minimum(latent_attend_span(offset, T, bt) // walk,
+                                table.shape[0] // nb)
+
+            def score(i, laid):
+                ids = lax.dynamic_slice(table, (i * nb,), (nb,))
+                keys = index["keys"][layer, ids]
+                return lax.dynamic_update_slice(laid, index_scores(
+                    index["q"][0], index["w"][0],
+                    keys.reshape(walk, keys.shape[-1])), (0, i * walk))
+
+            with jax.named_scope("attn.index"):
+                laid = lax.fori_loop(0, steps, score,
+                                     jnp.zeros((T, span), jnp.float32))
+            G = math.gcd(T, SPARSE_CHUNK_QUERIES)
+            tables = jnp.broadcast_to(table_row[None], (G, MB))
+
+            def some(group):
+                qg, scores, n = group           # [G, H, W], [G, span], [G]
+                return _attend_chosen(
+                    qg[:, None], scores, n, k, view, tables,
+                    "attn.sparse_chunk", scale, v_lanes)[:, 0]
+
+            out = lax.map(some, (
+                q[0].reshape(T // G, G, *q.shape[2:]),
+                laid.reshape(T // G, G, span), (qpos + 1).reshape(-1, G)))
+            return out.reshape(1, T, *out.shape[2:])
+
+        return lax.cond(offset + T <= k, every_row, chosen_rows)
+
+    return LatentAttend("absorbed", scoped("attn.latent_chunk")(run))
 
 
 def latent_prefill_write(table_row: jax.Array, offset: jax.Array,
                          length: jax.Array):
     """``paged_prefill_write`` for a latent pool: ``write(stack, layer, row
-    [1, T, W])`` lays the chunk's first ``length`` rows at positions
-    ``[offset, offset + length)`` through ``table_row``, a block at a time
-    (``_write_run``'s reasons: the blocks the run touches are gathered, the
-    rows laid over them and whole blocks scattered back; blocks it does not
-    reach go to the trash block unchanged)."""
+    [1, T, W], state)`` lays the chunk's first ``length`` rows at positions
+    ``[offset, offset + length)`` of the pool's array ``state`` through
+    ``table_row``, a block at a time (``_write_run``'s reasons: the blocks
+    the run touches are gathered, the rows laid over them and whole blocks
+    scattered back; blocks it does not reach go to the trash block
+    unchanged)."""
 
-    def write(stack, layer, row):
-        (cache,) = stack
+    def write(stack, layer, row, state: int = 0):
+        cache = stack[state]
         bt, lanes = cache.shape[2], cache.shape[3]
         nblk, real, ids = _run_blocks(table_row, offset, length,
                                       row.shape[1], bt)
@@ -966,7 +1257,7 @@ def latent_prefill_write(table_row: jax.Array, offset: jax.Array,
                 (offset % bt, 0)).reshape(nblk, bt, lanes)
             merged = jnp.where(real[..., None], frame, cache[layer, ids])
             cache = cache.at[layer, ids].set(merged)
-        return (cache,), LatentView(cache, layer)
+        return _with(stack, state, cache), LatentView(cache, layer)
 
     return write
 
@@ -990,8 +1281,9 @@ def latent_attend_span(offset: int, bucket: int, block_tokens: int) -> int:
     return (offset + bucket + walk - 1) // walk * walk
 
 
-def latent_span_attend(table_row: jax.Array, offset: jax.Array
-                       ) -> LatentAttend:
+def latent_span_attend(table_row: jax.Array, offset: jax.Array,
+                       window: int = 0,
+                       scope: str = "attn.latent_chunk") -> LatentAttend:
     """The decompressed attend of a chunk behind ``offset`` cached tokens:
     a rolled loop over the span, ``latent_walk`` rows a step (a traced trip
     count: the chunk attends the prefix it has, ``latent_attend_span``):
@@ -999,8 +1291,12 @@ def latent_span_attend(table_row: jax.Array, offset: jax.Array
     their keys and values (``expand``) and folds them into an online
     softmax, so that neither a span's keys (1.3 GiB at 32768 rows of 64
     heads) nor a chunk's scores over it ever exist whole. A position past a
-    row's own is masked: the walk needs no mask handed in."""
-    def run(q, view, _mask, *, scale, expand, v_dim):    # q [1, T, H, dq]
+    row's own is masked: the walk needs no mask handed in.
+
+    ``window``: a WINDOW layer's walk begins at the step that holds the
+    first query's window and masks to ``window`` keys a query, itself
+    counted."""
+    def run(q, view, _mask, *, scale, expand, v_dim):   # q [1, T, H, dq]
         cache, layer = view.cache, view.layer
         bt, T, H = cache.shape[2], q.shape[1], q.shape[2]
         walk = latent_walk(bt)
@@ -1011,6 +1307,9 @@ def latent_span_attend(table_row: jax.Array, offset: jax.Array
         steps = jnp.minimum(steps, table.shape[0] // nb)
         qpos = offset + jnp.arange(T)
         qh = q[0].transpose(1, 0, 2)                # [H, T, dq]
+        lo = 0
+        if window:
+            lo = jnp.maximum(offset - window + 1, 0) // walk
 
         def step(i, carry):
             m, l, acc = carry
@@ -1021,23 +1320,29 @@ def latent_span_attend(table_row: jax.Array, offset: jax.Array
             s = jnp.einsum("htd,lhd->htl", qh, k).astype(
                 jnp.float32) * scale
             kpos = i * walk + jnp.arange(walk)
-            s = jnp.where(kpos[None, None, :] <= qpos[None, :, None], s,
-                          -1e30)
+            keep = kpos[None, None, :] <= qpos[None, :, None]
+            if window:
+                keep &= kpos[None, None, :] > qpos[None, :, None] - window
+            s = jnp.where(keep, s, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if window:
+                # a step may hold no row a query sees (its running maximum
+                # is then the mask's value, and exp(0) counts every row)
+                p = jnp.where(keep, p, 0.0)
             alpha = jnp.exp(m - m_new)
             l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc = acc * alpha + jnp.einsum(
                 "htl,lhd->htd", p.astype(v.dtype), v).astype(jnp.float32)
             return m_new, l, acc
 
-        _, l, acc = lax.fori_loop(0, steps, step, (
+        _, l, acc = lax.fori_loop(lo, steps, step, (
             jnp.full((H, T, 1), -1e30, jnp.float32),
             jnp.zeros((H, T, 1), jnp.float32),
             jnp.zeros((H, T, v_dim), jnp.float32)))
         return (acc / l).astype(q.dtype).transpose(1, 0, 2)[None]
 
-    return LatentAttend("decompressed", scoped("attn.latent_chunk")(run))
+    return LatentAttend("decompressed", scoped(scope)(run))
 
 
 # ---------------------------------------------------------------------------
@@ -1310,7 +1615,16 @@ class LatentLayout:
     Its attends say which FORM of the attention the model computes: a
     decode step the absorbed one over the rows as they lie (the Pallas
     kernel, which writes the step's rows, or XLA), a chunk the decompressed
-    one over the span it has (``latent_span_attend``)."""
+    one over the span it has (``latent_span_attend``).
+
+    A stack with several KINDS of latent layer (``cfg.attn_kinds``;
+    models.dots3) is handed an attend a kind: a layer with an indexer
+    (``cfg.index_topk``) selects the rows it attends, gathers them and
+    attends them in the absorbed form, in a decode step and in a chunk
+    (``latent_sparse_decode``, ``latent_sparse_chunk``), a window layer
+    reads its window's blocks (``latent_window_decode``; a chunk's walk
+    from the window's first step). Those are XLA; the policy writes the
+    step's rows."""
 
     cfg: LlamaConfig
     kv_dtype: str
@@ -1326,10 +1640,14 @@ class LatentLayout:
 
     def __post_init__(self):
         self.ctx = self.max_blocks * self.block_tokens
+        # the pool's arrays by name, and the real elements of a row of each
+        self.widths = {name: width
+                       for name, _, width in self.cfg.latent_states}
 
     @property
     def kv_write_impl(self) -> str:
-        return "kernel" if self.attn_impl == "pallas" else "scatter"
+        raw = self.attn_impl == "pallas" and not self.cfg.attn_kinds
+        return "kernel" if raw else "scatter"
 
     def init(self):
         return init_latent_cache(
@@ -1338,6 +1656,12 @@ class LatentLayout:
                 (self.num_slots, self.max_blocks), jnp.int32)
 
     def decode(self, kv: LatentKVCache, tables, positions):
+        if self.cfg.attn_kinds:
+            attn = {kind: (latent_window_decode(tables, positions, window)
+                           if window else latent_sparse_decode(
+                               tables, positions, self.cfg.index_topk))
+                    for kind, window in self.cfg.attn_kinds}
+            return latent_decode_write(tables, positions), attn, None
         raw = self.attn_impl == "pallas"
         attn = (latent_kernel_attend(tables, positions, self.interpret)
                 if raw else latent_xla_attend(tables))
@@ -1347,8 +1671,15 @@ class LatentLayout:
     def chunk(self, table_row, slot, positions, offset, length):
         """The attend walks the span the chunk has (``chunk_span``) and
         masks by position: no mask is built."""
-        return (latent_prefill_write(table_row, offset, length),
-                latent_span_attend(table_row, offset), None)
+        write = latent_prefill_write(table_row, offset, length)
+        if self.cfg.attn_kinds:
+            return write, {kind: (
+                latent_span_attend(table_row, offset, window=window,
+                                   scope="attn.latent_window")
+                if window else latent_sparse_chunk(
+                    table_row, offset, self.cfg.index_topk))
+                for kind, window in self.cfg.attn_kinds}, None
+        return write, latent_span_attend(table_row, offset), None
 
     def chunk_span(self, offset: int, bucket: int) -> int:
         """Positions a chunk's walk covers (the flight ring's
@@ -1366,29 +1697,41 @@ class LatentLayout:
                 1 + jnp.arange(nb, dtype=jnp.int32))
 
     # a block's rows to and from the host, and a slot's first rows in the
-    # export format ([L, n, W]: the real lanes, whatever the tiling pads)
+    # export format ([L, n, W] an array of the pool: the real lanes,
+    # whatever the tiling pads), under the arrays' names
+
+    def _arrays(self, kv: LatentKVCache) -> dict:
+        return dict(zip(self.widths, kv.stacked()))
 
     def pack_block(self, kv: LatentKVCache, bid: int) -> dict:
         import numpy as np
 
-        return {"c": np.asarray(kv.c[:, bid])}
+        return {name: np.asarray(a[:, bid])
+                for name, a in self._arrays(kv).items()}
 
     def load_block(self, kv: LatentKVCache, bid: int, payload: dict):
-        return LatentKVCache(kv.c.at[:, bid].set(
-            jnp.asarray(payload["c"], kv.c.dtype)))
+        return LatentKVCache(*(
+            a.at[:, bid].set(jnp.asarray(payload[name], a.dtype))
+            for name, a in self._arrays(kv).items()))
 
     def export_rows(self, kv: LatentKVCache, blocks, n: int) -> dict:
-        g = kv.c[:, blocks]                         # [L, nb, bt, lanes]
-        return {"c": g.reshape(g.shape[0], -1, g.shape[-1])[
-            :, :n, :self.cfg.latent_width]}
+        out = {}
+        for name, a in self._arrays(kv).items():
+            g = a[:, blocks]                        # [L, nb, bt, lanes]
+            out[name] = g.reshape(g.shape[0], -1, g.shape[-1])[
+                :, :n, :self.widths[name]]
+        return out
 
     def import_rows(self, kv: LatentKVCache, blk, off, arrays: dict,
                     n: int):
-        """The pool with exported rows ``arrays["c"] [L, n, W]`` at
+        """The pool with exported rows ``arrays[name] [L, n, W]`` at
         (``blk``, ``off``) [n]; None where they are not this pool's."""
-        c = arrays.get("c")
-        want = (self.cfg.cache_layers, n, self.cfg.latent_width)
-        if c is None or tuple(c.shape) != want:
-            return None
-        rows = _pad_lanes(jnp.asarray(c, kv.c.dtype), kv.c.shape[-1])
-        return LatentKVCache(kv.c.at[:, blk, off].set(rows))
+        loaded = []
+        for name, a in self._arrays(kv).items():
+            rows = arrays.get(name)
+            want = (a.shape[0], n, self.widths[name])
+            if rows is None or tuple(rows.shape) != want:
+                return None
+            loaded.append(a.at[:, blk, off].set(
+                _pad_lanes(jnp.asarray(rows, a.dtype), a.shape[-1])))
+        return LatentKVCache(*loaded)

@@ -238,6 +238,10 @@ class ModelRunner:
         self.rope = mdl.rope_table(
             cfg, self.max_ctx, freq_base=rope_freq_base, freq_scale=rope_freq_scale
         )
+        if self.ga_n > 1 and (cfg.attn_kinds or cfg.latent):
+            # (before the unroped table is made: a table a kind is none of
+            # self-extend's; the family's other refusals are below)
+            raise ValueError(_kinds_refusal(cfg, "self-extend"))
         if self.ga_n > 1:
             from localai_tpu.engine import selfextend as se
 
@@ -276,7 +280,6 @@ class ModelRunner:
                     ("the ring prefill", mesh is not None
                      and mesh.shape.get("seq", 1) > 1),
                     ("a device mesh", mesh is not None),
-                    ("self-extend", ga_n > 1),
                     ("the contiguous K/V layout", not self.paged),
                     (f"a {kv_dtype} K/V pool", kv_dtype in ("int8", "int4"))):
                 if asked:
@@ -1841,7 +1844,8 @@ class ModelRunner:
         has no native bfloat16); scaled-int8 caches keep their scales."""
         out: dict = {"kv_dtype": np.asarray(snapshot["kv_dtype"]),
                      "kv_rope": np.asarray(snapshot.get("kv_rope", "roped"))}
-        for name in ("k", "v", "k_scale", "v_scale", "c"):
+        # k, v and their scales; a latent pool's arrays (c, w, i)
+        for name in ("k", "v", "k_scale", "v_scale", "c", "w", "i"):
             if name not in snapshot:
                 continue
             host = np.asarray(snapshot[name])
@@ -1881,8 +1885,10 @@ class ModelRunner:
             return host
 
         if self.latent:
-            return "c" in arrays and self._load_prefix_paged(
-                slot, {"c": unpack("c")}, n)
+            names = self.layout.widths
+            return all(k in arrays for k in names) and (
+                self._load_prefix_paged(
+                    slot, {k: unpack(k) for k in names}, n))
         k, v = unpack("k"), unpack("v")
         L, H, hd = self.cfg.cache_layers, self.cfg.num_kv_heads, self.cfg.hd
         if str(self.kv_dtype) == "int4":

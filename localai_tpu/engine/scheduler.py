@@ -414,6 +414,14 @@ class Scheduler:
         # decompressed one); None for every other model
         self.total_mla_attends = ({"absorbed": 0, "decompressed": 0}
                                   if getattr(cfg, "latent", False) else None)
+        # a model whose full layers select the rows they attend by an
+        # indexer (models.dots3): rows a decode step SCORED (every cached
+        # token's index key) and rows it ATTENDED (the ``index_topk`` best),
+        # a full layer each, counted at the enqueue; None for every other
+        self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        self._index_layers = int(getattr(cfg, "full_layers", 0) or 0)
+        self.total_dsa_rows = ({"scored": 0, "attended": 0}
+                               if self._index_topk else None)
         self.total_experts_touched = 0
         self.total_local_assignments = 0
         self.total_state_slots_armed = 0
@@ -683,6 +691,8 @@ class Scheduler:
                if self._window else {}),
             **({"mla_attends": dict(self.total_mla_attends)}
                if self.total_mla_attends is not None else {}),
+            **({"dsa_rows": dict(self.total_dsa_rows)}
+               if self.total_dsa_rows is not None else {}),
             "last_dispatch_steps": self.last_dispatch_steps,
             "dispatches": self._dispatch_seq,
             "preemptions": totals["preemptions"],
@@ -817,7 +827,7 @@ class Scheduler:
                 "decompressed" if chunk else "absorbed"] += 1
         if k:
             live = len(self._slots)
-            cached = windowed = 0
+            cached = windowed = selected = 0
             for c in self._slots.values():
                 n = c.handle.prompt_tokens + c.generated
                 for d in inflight:
@@ -829,9 +839,18 @@ class Scheduler:
                 if self._window:
                     windowed += sum(min(n + j, self._window)
                                     for j in range(k))
+                if self._index_topk:
+                    selected += sum(min(n + j, self._index_topk)
+                                    for j in range(k))
             held["live_slots"] = live
             held["attended_tokens"] = k * cached + live * (k * (k - 1) // 2)
             held["window_tokens"] = windowed
+            if self.total_dsa_rows is not None:
+                held["selected_tokens"] = selected
+                self.total_dsa_rows["scored"] += (
+                    self._index_layers * held["attended_tokens"])
+                self.total_dsa_rows["attended"] += (
+                    self._index_layers * selected)
         return held
 
     def _flight_record(self, program: str, steps: int, dt: float,

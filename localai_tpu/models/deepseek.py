@@ -86,6 +86,14 @@ class DeepseekConfig(LlamaConfig):
                                     # are scaled by hd^-1/2 m^2
     ep_size: int = 1
     ep_rank: int = 0
+    # what a family built on this block adds to it (models.dots3); each off
+    # here: constants on the two normed low-rank vectors, and an indexer
+    # whose ``index_topk`` best rows a full layer attends alone
+    q_rescale: float = 1.0
+    kv_rescale: float = 1.0
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
 
     family: ClassVar[str] = "deepseek"
     routed: ClassVar[bool] = True
@@ -116,6 +124,12 @@ class DeepseekConfig(LlamaConfig):
     def latent_width(self) -> int:
         """Elements of a token's cached row: ``[c | kr]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_states(self) -> tuple:
+        """(name, layers, elements a token) of each array the latent pool
+        holds (engine.kvcache ``LatentKVCache``): here the one, ``c``."""
+        return (("c", self.cache_layers, self.latent_width),)
 
     @property
     def softmax_scale(self) -> float:
@@ -287,9 +301,14 @@ def init_leaf(key, shape, name: str, dtype):
     base = name.removeprefix(DENSE)
     rest = LATENT_NORM_GAIN if base in ("q_norm", "kv_norm") else 1.0
     if base in OUTLIER_NORMS and shape[-1] >= OUTLIER_EVERY:
+        # the channels whose draw is among the vector's largest: those that
+        # fewer than that many draws exceed (a stacked vector's ``lax.top_k``
+        # costs the TPU compiler 12-16 s a leaf, this comparison under one:
+        # topology compile, PR 51; the same channels to the tie)
         u = jax.random.uniform(key, shape)
-        kth = lax.top_k(u, shape[-1] // OUTLIER_EVERY)[0][..., -1:]
-        return jnp.where(u >= kth, OUTLIER_GAIN, rest).astype(dtype)
+        above = jnp.sum(u[..., None, :] > u[..., :, None], axis=-1)
+        return jnp.where(above < shape[-1] // OUTLIER_EVERY, OUTLIER_GAIN,
+                         rest).astype(dtype)
     if name.endswith("norm"):
         return jnp.full(shape, rest, dtype)
     # another branch of the one draw a leaf makes, not a second use
@@ -409,14 +428,21 @@ def expand(cfg: DeepseekConfig, w_kvb, rows):
         return k, kv[..., cfg.qk_nope_head_dim:]
 
 
-def _attention(cfg: DeepseekConfig, h, w, cos, sin, attend, path: str):
+def _attention(cfg: DeepseekConfig, h, w, cos, sin, attend, path: str,
+               index=None, gate=None):
     """Latent attention on normed h [B, T, D]; ``w(name)`` reads one of the
     layer's leaves, ``attend(q, row, **how)`` writes the tokens' rows and
-    attends (``forward``), ``path`` the form to compute."""
+    attends (``forward``), ``path`` the form to compute. What a family built
+    on the block adds (models.dots3): ``index(h, cq)`` -> what its attend
+    selects rows by, handed on as ``how["index"]``; ``gate(h, o)`` -> the
+    heads' outputs [B, T, H, dv] gated, in front of ``wo``."""
     H, eps = cfg.num_heads, cfg.rms_norm_eps
     nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    how = {}
     with jax.named_scope("mla/q"):
         cq = latent_norm(qnt.matmul(h, w("wq_a")), w("q_norm"), eps)
+        if cfg.q_rescale != 1.0:
+            cq = cq * cfg.q_rescale
         q = qnt.matmul(cq, w("wq_b"))
         q = lax.optimization_barrier(q).reshape(*q.shape[:-1], H, cfg.hd)
         q_rope = mdl.apply_rope(rope_pairs(q[..., nope:]), cos, sin)
@@ -431,18 +457,25 @@ def _attention(cfg: DeepseekConfig, h, w, cos, sin, attend, path: str):
     with jax.named_scope("mla/kv_a"):
         ckr = qnt.matmul(h, w("wkv_a"))
         c = latent_norm(ckr[..., :kl], w("kv_norm"), eps)
+        if cfg.kv_rescale != 1.0:
+            c = c * cfg.kv_rescale
         # ONE rope key a token, whatever the head
         kr = mdl.apply_rope(rope_pairs(ckr[..., None, kl:]), cos, sin)
         row = jnp.concatenate([c, kr[..., 0, :]], axis=-1)
+    if index is not None:
+        how["index"] = index(h, cq)
     if path == "absorbed":
-        o, new_kv = attend(q, row, scale=cfg.softmax_scale, v_lanes=kl)
+        o, new_kv = attend(q, row, scale=cfg.softmax_scale, v_lanes=kl,
+                           **how)
         with jax.named_scope("mla/o"):
             _, w_uv = kv_b(cfg, w("wkv_b"))
             o = jnp.einsum("bthc,chv->bthv", o, w_uv)
     else:
         o, new_kv = attend(q, row, scale=cfg.softmax_scale,
                            expand=lambda rows: expand(cfg, w("wkv_b"), rows),
-                           v_dim=cfg.v_head_dim)
+                           v_dim=cfg.v_head_dim, **how)
+    if gate is not None:
+        o = gate(h, o)
     with jax.named_scope("mla/o"):
         out = qnt.matmul(o.reshape(*o.shape[:-2], H * cfg.v_head_dim),
                          w("wo"))

@@ -117,7 +117,10 @@ class LlamaConfig:
         attention layers in one stack, dense layers in front of
         sigmoid-routed experts): models.afmoe; and ``axk1`` (the DeepSeek-V3
         block: latent attention in every layer, a dense layer in front of
-        group-limited sigmoid-routed experts): models.deepseek."""
+        group-limited sigmoid-routed experts): models.deepseek; and
+        ``dots3_note`` (that block with an indexer's learned sparse
+        attention on its full layers and window layers of a shape of their
+        own): models.dots3."""
         if hf.get("model_type") == "qwen3_next":
             from localai_tpu.models.qwen3_next import Qwen3NextConfig
 
@@ -130,6 +133,10 @@ class LlamaConfig:
             from localai_tpu.models.deepseek import DeepseekConfig
 
             return DeepseekConfig.from_hf(hf)
+        if hf.get("model_type") == "dots3_note":
+            from localai_tpu.models.dots3 import Dots3Config
+
+            return Dots3Config.from_hf(hf)
         ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),
@@ -166,8 +173,13 @@ def rope_table(cfg: LlamaConfig, max_len: int,
 
     Supports HF rope_scaling types 'linear', 'llama3', 'yarn' and the
     reference's raw rope_freq_base/rope_freq_scale overrides
-    (/root/reference/core/config/backend_config.go:162-163).
+    (/root/reference/core/config/backend_config.go:162-163). A family whose
+    kinds of layer rotate by tables of their own brings its ``rope_table``
+    (models.dots3: a table a kind).
     """
+    fam = family_module(cfg)
+    if hasattr(fam, "rope_table"):
+        return fam.rope_table(cfg, max_len, freq_base, freq_scale)
     hd = cfg.rotary_dim
     base = freq_base or cfg.rope_theta
     inv_freq = 1.0 / (base ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))

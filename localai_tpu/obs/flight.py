@@ -46,7 +46,7 @@ from localai_tpu.obs.trace import mono_to_wall
 # what a launch held, as columns of the ring and keys of /debug/flight, in
 # the order of record()'s keywords
 WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "window_tokens",
-                "chunk_tokens", "chunk_bucket", "chunk_offset", "chunk_ctx",
+                "selected_tokens", "chunk_tokens", "chunk_bucket", "chunk_offset", "chunk_ctx",
                 "experts_touched", "local_assignments")
 
 
@@ -100,7 +100,8 @@ class FlightRecorder:
                sched_ms: float = 0.0, launch_ms: float = 0.0,
                sync_ms: float = 0.0, passes: int = 0, launch: int = 0,
                live_slots: int = 0, attended_tokens: int = 0,
-               window_tokens: int = 0, chunk_tokens: int = 0,
+               window_tokens: int = 0, selected_tokens: int = 0,
+               chunk_tokens: int = 0,
                chunk_bucket: int = 0, chunk_offset: int = 0,
                chunk_ctx: int = 0,
                experts_touched: int = 0, local_assignments: int = 0) -> None:
@@ -124,7 +125,7 @@ class FlightRecorder:
         its forwards times the model's passes a forward (a looped decoder
         runs its stack several times a token; every other model once).
 
-        ``launch`` and the seven counts after it say what work the launch
+        ``launch`` and the eight counts after it say what work the launch
         held, taken when its program was ENQUEUED and not at the drain
         (``WORK_COLUMNS``): ``launch`` is the scheduler's launch number,
         which the host trace carries as ``sched.launch/<n>`` around the
@@ -134,7 +135,10 @@ class FlightRecorder:
         its steps attend, summed over steps and live slots) and
         ``window_tokens`` (the same sum with each stream's context cut to the
         model's attention window: what a window layer's call reads; 0 for a
-        model with no window); a prefill row holds ``chunk_tokens`` (real
+        model with no window) and ``selected_tokens`` (the same sum with each
+        stream's context cut to ``index_topk``: the rows a layer with an
+        indexer attends of those it scored; 0 for a model with none); a
+        prefill row holds ``chunk_tokens`` (real
         tokens), ``chunk_bucket`` (rows the program computes),
         ``chunk_offset`` (cached tokens in front of the chunk) and
         ``chunk_ctx`` (positions its attend spans). 0 wherever a row's kind
@@ -172,7 +176,8 @@ class FlightRecorder:
             self._compile[i] = compile
             self._program[i] = program
             self._work[i] = (launch, live_slots, attended_tokens,
-                             window_tokens, chunk_tokens, chunk_bucket,
+                             window_tokens, selected_tokens, chunk_tokens,
+                             chunk_bucket,
                              chunk_offset, chunk_ctx, experts_touched,
                              local_assignments)
             self._n += 1

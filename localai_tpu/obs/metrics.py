@@ -256,6 +256,14 @@ class Registry:
             "latent space, the pool never decompressed), path=decompressed "
             "a prefill chunk (keys and values rebuilt from the span's rows)",
         )
+        self.dsa_rows = Counter(
+            "localai_dsa_rows_total",
+            "Cached rows the decode steps of a model with an indexer "
+            "(learned sparse attention) handled, a full layer and a stream "
+            "each, counted at the enqueue from the host's own lengths: "
+            "kind=scored every cached token's index key, kind=attended the "
+            "index_topk best of them, whose latent rows alone are read",
+        )
         self.kv_window_dead_tokens = Gauge(
             "localai_kv_window_dead_tokens",
             "Tokens the block pool holds that no window layer can read any "
@@ -753,6 +761,8 @@ def update_engine_gauges(name: str, m: dict,
         reg.state_slots_armed.set_total(m["state_slots_armed"], model=name)
     for path, n in (m.get("mla_attends") or {}).items():
         reg.mla_attends.set_total(n, model=name, path=path)
+    for kind, n in (m.get("dsa_rows") or {}).items():
+        reg.dsa_rows.set_total(n, model=name, kind=kind)
     if "kv_window_dead_tokens" in m:
         reg.kv_window_dead_tokens.set(m["kv_window_dead_tokens"], model=name)
     if m.get("shed_total"):

@@ -8,8 +8,9 @@ join of a slice's launches to its executions (``test_launches.py``); and the
 sparse hybrid family's file, cell and readers, with a small model of it
 served through the harness and the control that fails
 (``test_qwen3_next_family.py``); the same for the window / full attention
-family (``test_afmoe_family.py``) and for the latent-attention family
-(``test_deepseek_family.py``).
+family (``test_afmoe_family.py``), for the latent-attention family
+(``test_deepseek_family.py``) and for the family with an indexer in front of
+it (``test_dots3_family.py``).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -53,6 +54,7 @@ _launches = _load("test_launches", conftest=_conftest)
 _qn = _load("test_qwen3_next_family", conftest=_conftest, test_walk=_walk)
 _af = _load("test_afmoe_family", conftest=_conftest, test_walk=_walk)
 _ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
+_d3 = _load("test_dots3_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -161,3 +163,22 @@ test_a_latent_attention_model_runs_by_files_alone = (
     _ds.test_a_latent_attention_model_runs_by_files_alone)
 test_the_control_fails_a_family_whose_router_knows_no_groups = (
     _ds.test_the_control_fails_a_family_whose_router_knows_no_groups)
+# PR 51's file: the family with an indexer: its hand arithmetic, the catalog
+# row in the file, the dense and lone layers beside a row a period, its cell,
+# its five readers, and a small model through the harness (selection and window
+# binding) with the control that fails
+test_the_hand_arithmetic_of_the_sparse_stacks_published_keys = (
+    _d3.test_the_hand_arithmetic_of_the_sparse_stacks_published_keys)
+test_every_published_number_of_the_sparse_stacks_catalog_row_is_in_the_file = (
+    _d3
+    .test_every_published_number_of_the_sparse_stacks_catalog_row_is_in_the_file)
+test_the_served_pytree_is_dense_and_lone_layers_beside_a_row_a_period = (
+    _d3.test_the_served_pytree_is_dense_and_lone_layers_beside_a_row_a_period)
+test_the_sparse_cell_reports_what_the_issue_names = (
+    _d3.test_the_sparse_cell_reports_what_the_issue_names)
+test_the_five_readers_read_the_ring_and_the_scopes = (
+    _d3.test_the_five_readers_read_the_ring_and_the_scopes)
+test_a_sparse_attention_model_runs_by_files_alone = (
+    _d3.test_a_sparse_attention_model_runs_by_files_alone)
+test_the_control_fails_a_family_whose_full_layers_attend_every_row = (
+    _d3.test_the_control_fails_a_family_whose_full_layers_attend_every_row)
