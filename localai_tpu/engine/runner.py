@@ -62,6 +62,10 @@ SKIP = -1
 # tokens a chunked-prefill dispatch covers unless engine.prefill_chunk says
 # otherwise (never under one KV block)
 PREFILL_CHUNK = 512
+# rows from which a prompt's last chunk on a mesh runs in quarters behind
+# the attend (``ModelRunner.chunk_rows``); the 128 bucket is bound by the
+# weights' bytes and stays whole
+CHUNK_QUARTERED = 512
 
 
 def _refusal(what: str) -> str:
@@ -988,9 +992,12 @@ class ModelRunner:
             rec["routed"] = jnp.zeros_like(routed) if sample else routed
             state = dataclasses.replace(state, rec=rec)
         else:
+            rows = self.chunk_rows(bucket, sample)
             hidden, new_stack = self._forward(
                 params, tokens, positions, write, kv.stacked(), mask,
                 attn=attn, embeds=embeds,
+                live=None if len(rows) == 1 else (
+                    rows, kvc.attend_rung(length, rows)),
             )
         new_kv = self.layout.from_stacked(new_stack)
         if not sample:
@@ -1000,6 +1007,40 @@ class ModelRunner:
         if routed is not None:
             return new_kv, new_state, jnp.concatenate([tok, routed])
         return new_kv, new_state, tok[0]
+
+    def chunk_rows(self, bucket: int, last: bool = True) -> tuple[int, ...]:
+        """The row counts a chunk program of ``bucket`` rows can run BEHIND
+        THE ATTEND (the out product, the MLP, their reductions and
+        residuals: ``models.llama._behind_attend``), the bucket last; the
+        projections, the pool write and the attend always run the bucket.
+        On one device, and under ``CHUNK_QUARTERED`` rows, the bucket alone.
+        Over a mesh's 'model' axis the program of a prompt's LAST chunk (the
+        one that samples) runs as many QUARTERS of a bucket of
+        ``CHUNK_QUARTERED`` rows or more as hold a real token, two at least
+        (the ladder's buckets stand 1 : 4: a chunk with one live quarter
+        took the bucket below). The rows behind them are padding: no row in
+        front attends them and nothing downstream reads them. A branch a row
+        count inside the one program (``models.llama._layer``), so no
+        program is added; a chunk that is not the last holds
+        ``prefill_chunk`` tokens, and where those fill the bucket's last
+        quarter its program stays whole: the branches' way through HBM costs
+        a full chunk ~1 ms of 41 (PERF.md 6, PR 52)."""
+        tp = self.mesh.shape.get("model", 1) if self.mesh is not None else 1
+        if (tp <= 1 or not self.paged or self.routed
+                or bucket < CHUNK_QUARTERED or bucket % 4
+                or not last and self.prefill_chunk > bucket // 4 * 3):
+            return (bucket,)
+        return tuple(bucket // 4 * q for q in (2, 3, 4))
+
+    def chunk_parts(self, bucket: int, tokens: int, last: bool = True) -> int:
+        """Quarters of its bucket a chunk of ``tokens`` real tokens runs
+        behind the attend: host integers, by the expression of the program's
+        switch (``kvcache.attend_rung``; the flight ring's ``chunk_parts``).
+        1 where the program is not quartered."""
+        rows = self.chunk_rows(bucket, last)
+        if len(rows) == 1:
+            return 1
+        return rows[kvc.attend_rung(tokens, rows)] * 4 // bucket
 
     def chunk_span(self, offset: int, bucket: int) -> int:
         """Positions the attend of ``_prefill_paged_fn`` spans for a chunk of
@@ -1154,7 +1195,7 @@ class ModelRunner:
         )
 
     def _forward(self, params, tokens, positions, write, stack, mask,
-                 attn=None, embeds=None):
+                 attn=None, embeds=None, live=None):
         """models.llama.forward, or the pipeline-parallel stage chain
         when the mesh has a 'pipe' axis (layer-sharded capacity scaling —
         parallel.pipeline; attn overrides don't apply there: pp forces the
@@ -1170,7 +1211,7 @@ class ModelRunner:
             )
         return mdl.forward(
             self.cfg, params, tokens, positions, write, stack, mask,
-            self.rope, attn=attn, embeds=embeds,
+            self.rope, attn=attn, embeds=embeds, live=live,
         )
 
     def _forward_rec(self, params, tokens, positions, write, stack, mask,
@@ -2085,7 +2126,9 @@ class PagedAdmission:
             ctx = r.chunk_span(offset, bucket)
             self.pos += take
         self.last_chunk = {"chunk_tokens": take, "chunk_bucket": bucket,
-                           "chunk_offset": offset, "chunk_ctx": ctx}
+                           "chunk_offset": offset, "chunk_ctx": ctx,
+                           "chunk_parts": (1 if self.sp else r.chunk_parts(
+                               bucket, take, last))}
         r.admit_programs += 1
         if last:
             self.done = True

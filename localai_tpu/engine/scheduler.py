@@ -384,6 +384,10 @@ class Scheduler:
         self._chunked = bool(getattr(runner, "paged", False))
         self._prefills: "deque[_PendingPrefill]" = deque()
         self.total_prefill_chunks = 0
+        # chunk launches by the row parts they ran behind the attend
+        # (``chunk_parts`` of the flight ring: 1 a whole bucket, 2 to 4 the
+        # live quarters of one on a mesh)
+        self.total_chunk_parts: dict[int, int] = {}
         # what an admission costs the device besides its prefill: the
         # admissions made, and the times the admission path read the device
         # and WAITED (a frontier read to pick a slot, a first token the
@@ -649,6 +653,7 @@ class Scheduler:
                     self.runner, "kv_overcommit", 1.0),
                 "kv_shared_tokens": alloc.shared_tokens_total,
                 "prefill_chunks": self.total_prefill_chunks,
+                "prefill_chunk_parts": dict(self.total_chunk_parts),
                 "prefill_chunk_queue_depth": sum(
                     p.adm.chunks_remaining for p in list(self._prefills)
                 ),
@@ -1922,6 +1927,8 @@ class Scheduler:
             self._install_slot(pf.slot, pf.handle, pf.base, pf.mask_set)
         dt = time.monotonic() - t0
         self.total_prefill_chunks += 1
+        parts = held.get("chunk_parts", 1)
+        self.total_chunk_parts[parts] = self.total_chunk_parts.get(parts, 0) + 1
         # anatomy: the admission object measured its own enqueue span; the
         # remainder of THIS span is chunk staging and slot bookkeeping
         # (sched). Pre-built phases so the chunk does not consume

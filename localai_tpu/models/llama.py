@@ -345,7 +345,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
 # Forward
 # ---------------------------------------------------------------------------
 
-def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
+def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None,
+           live=None):
     """One decoder layer. ``attend(q, k_new, v_new) -> (attn_out, new_kv)``
     is injected so prefill/decode/KV-cache policies stay out of the math.
 
@@ -353,7 +354,11 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
     (attention-out, mlp-down) — under manual tensor parallelism inside
     shard_map it is ``lax.psum(·, 'model')``, turning the per-device
     partial sums into the Megatron two-psums-per-layer pattern. When None
-    (single device, or GSPMD-managed sharding) the products are complete."""
+    (single device, or GSPMD-managed sharding) the products are complete.
+
+    ``live`` (a prefill chunk on a mesh: ``forward``) cuts what stands
+    behind the attend to the chunk's live rows; ``lp`` then holds the
+    leaves in front of the attend alone."""
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     if reduce is not None:
         # local head counts under manual TP: weight shards carry Hq/tp and
@@ -387,8 +392,36 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
         k = apply_rope(k, cos, sin)
 
     attn, new_kv = attend(q, k, v)
+    if live is None:
+        return _behind_attend(cfg, x, attn, lp, reduce), new_kv
+    rows, branch, stacks, layer = live
+
+    def cut(r):
+        # the layer's leaves are cut from their stacks inside the branch,
+        # where the dots read them in place: a branch handed the layer's
+        # slices has them copied out of the stacks first, 131 MB a layer
+        def run(x, attn, stacks, layer):
+            back = jax.tree.map(
+                lambda w: lax.dynamic_index_in_dim(w, layer, 0, False), stacks)
+            return jnp.concatenate(
+                [_behind_attend(cfg, x[:, :r], attn[:, :r], {**lp, **back},
+                                reduce), x[:, r:]], axis=1)
+        return run
+
+    return lax.switch(branch, [cut(r) for r in rows], x, attn, stacks,
+                      layer), new_kv
+
+
+# the stacked leaves ``_behind_attend`` reads
+BEHIND_ATTEND = ("wo", "attn_post_norm", "mlp_norm", "moe_gate", "w_gate",
+                 "w_up", "w_down", "mlp_post_norm")
+
+
+def _behind_attend(cfg: LlamaConfig, x, attn, lp, reduce=None):
+    """A layer from the attend's output on: the out product and its
+    residual, the MLP (or the experts) and its residual. Rows do not mix."""
     with jax.named_scope("attn.out"):
-        attn = attn.reshape(*attn.shape[:-2], Hq * hd)
+        attn = attn.reshape(*attn.shape[:-2], -1)
         wo_out = qnt.matmul(attn, lp["wo"])
         if reduce is not None:
             wo_out = reduce(wo_out)
@@ -411,7 +444,7 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None):
             if "mlp_post_norm" in lp:   # sandwich layer: h + N4(MLP(N3 h))
                 down = rms_norm(down, lp["mlp_post_norm"], cfg.rms_norm_eps)
             x = x + down
-    return x, new_kv
+    return x
 
 
 def _moe_mlp(cfg: LlamaConfig, h, lp, reduce=None):
@@ -485,6 +518,10 @@ def forward(
                             # attention-out / mlp-down products inside a
                             # shard_map body (parallel.overlap: one psum
                             # each); None = single device / GSPMD
+    live: Any = None,       # a prefill chunk on a mesh: (row counts, index
+                            # of the one that covers the chunk's real
+                            # tokens); what stands behind the attend runs
+                            # on those rows alone, a branch a row count
 ) -> tuple[jax.Array, Any]:
     """Shared transformer trunk: returns (hidden [B, T, D], updated kv_stack).
 
@@ -526,6 +563,10 @@ def forward(
                 return _grouped_attn(cfg, q, keys, values, m)
 
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
+    scanned, stacks = params["layers"], None
+    if live is not None:
+        stacks = {k: v for k, v in scanned.items() if k in BEHIND_ATTEND}
+        scanned = {k: v for k, v in scanned.items() if k not in stacks}
 
     def stack(x, kv, first=None):
         """The whole stack once; its layers write and read the cache layers
@@ -545,11 +586,14 @@ def forward(
                     out, new_kv = out
                 return out, new_kv
 
-            return _layer(cfg, x, lp, cos, sin, attend, reduce=reduce), None
+            return _layer(
+                cfg, x, lp, cos, sin, attend, reduce=reduce,
+                live=live and (*live, stacks,
+                               layer if first is None else layer - first),
+            ), None
 
         with jax.named_scope("layers"):
-            (x, kv), _ = lax.scan(
-                body, (x, kv), (params["layers"], cache_layer))
+            (x, kv), _ = lax.scan(body, (x, kv), (scanned, cache_layer))
         return x, kv
 
     if cfg.num_passes == 1:

@@ -47,7 +47,7 @@ from localai_tpu.obs.trace import mono_to_wall
 # the order of record()'s keywords
 WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "window_tokens",
                 "selected_tokens", "chunk_tokens", "chunk_bucket", "chunk_offset", "chunk_ctx",
-                "experts_touched", "local_assignments")
+                "chunk_parts", "experts_touched", "local_assignments")
 
 
 def _default_capacity() -> int:
@@ -103,7 +103,7 @@ class FlightRecorder:
                window_tokens: int = 0, selected_tokens: int = 0,
                chunk_tokens: int = 0,
                chunk_bucket: int = 0, chunk_offset: int = 0,
-               chunk_ctx: int = 0,
+               chunk_ctx: int = 0, chunk_parts: int = 0,
                experts_touched: int = 0, local_assignments: int = 0) -> None:
         """Append one dispatch record (host scalars only).
 
@@ -125,7 +125,7 @@ class FlightRecorder:
         its forwards times the model's passes a forward (a looped decoder
         runs its stack several times a token; every other model once).
 
-        ``launch`` and the eight counts after it say what work the launch
+        ``launch`` and the counts after it say what work the launch
         held, taken when its program was ENQUEUED and not at the drain
         (``WORK_COLUMNS``): ``launch`` is the scheduler's launch number,
         which the host trace carries as ``sched.launch/<n>`` around the
@@ -139,9 +139,12 @@ class FlightRecorder:
         stream's context cut to ``index_topk``: the rows a layer with an
         indexer attends of those it scored; 0 for a model with none); a
         prefill row holds ``chunk_tokens`` (real
-        tokens), ``chunk_bucket`` (rows the program computes),
-        ``chunk_offset`` (cached tokens in front of the chunk) and
-        ``chunk_ctx`` (positions its attend spans). 0 wherever a row's kind
+        tokens), ``chunk_bucket`` (rows of its program),
+        ``chunk_offset`` (cached tokens in front of the chunk),
+        ``chunk_ctx`` (positions its attend spans) and ``chunk_parts`` (1: the
+        program computes every row of its bucket behind the attend; 2 to 4:
+        on a mesh, the quarters of the bucket it ran there,
+        ``ModelRunner.chunk_rows``). 0 wherever a row's kind
         has no such count.
 
         ``experts_touched`` and ``local_assignments`` are the two counts
@@ -178,8 +181,8 @@ class FlightRecorder:
             self._work[i] = (launch, live_slots, attended_tokens,
                              window_tokens, selected_tokens, chunk_tokens,
                              chunk_bucket,
-                             chunk_offset, chunk_ctx, experts_touched,
-                             local_assignments)
+                             chunk_offset, chunk_ctx, chunk_parts,
+                             experts_touched, local_assignments)
             self._n += 1
             self.total_tokens += int(tokens)
 

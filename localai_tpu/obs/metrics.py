@@ -211,6 +211,13 @@ class Registry:
             "localai_prefill_chunks_total",
             "Chunked-prefill dispatches issued by the engine thread",
         )
+        self.prefill_chunk_parts = Counter(
+            "localai_prefill_chunk_parts_total",
+            "Chunked-prefill dispatches by the row parts they ran behind "
+            "the attend: parts=1 every row of the program's bucket, "
+            "parts=2..4 the quarters of the bucket that held a real token "
+            "(a mesh's chunks of 512 rows or more)",
+        )
         self.decode_dispatches = Counter(
             "localai_decode_dispatches_total",
             "Compiled decode programs dispatched by the engine thread",
@@ -725,6 +732,8 @@ def update_engine_gauges(name: str, m: dict,
         reg.prefill_chunk_queue.set(
             m.get("prefill_chunk_queue_depth", 0), model=name)
         reg.prefill_chunks.set_total(m.get("prefill_chunks", 0), model=name)
+        for parts, n in (m.get("prefill_chunk_parts") or {}).items():
+            reg.prefill_chunk_parts.set_total(n, model=name, parts=str(parts))
         impl = m.get("paged_attn_impl")
         if impl:
             # one-hot over the impl label so a kernel→fallback flip is a

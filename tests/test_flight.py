@@ -47,12 +47,13 @@ def test_total_tokens_survives_wraparound():
 
 WORK = {"launch": 7, "live_slots": 3, "attended_tokens": 1234,
         "window_tokens": 999, "selected_tokens": 777, "chunk_tokens": 88, "chunk_bucket": 128, "chunk_offset": 512,
-        "chunk_ctx": 4096, "experts_touched": 29, "local_assignments": 41}
+        "chunk_ctx": 4096, "chunk_parts": 3, "experts_touched": 29,
+        "local_assignments": 41}
 
 
 @pytest.mark.parametrize("column", sorted(WORK))
 def test_what_a_launch_held_round_trips(column):
-    """Each of the eleven columns goes in by record()'s keyword and comes
+    """Each of the columns goes in by record()'s keyword and comes
     out of snapshot() under the same name, beside rows that gave none."""
     from localai_tpu.obs.flight import WORK_COLUMNS
 

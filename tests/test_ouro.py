@@ -319,9 +319,11 @@ def _layer_before_passes(cfg, x, lp, cos, sin, attend, reduce=None):
 
 
 def _forward_before_passes(cfg, params, tokens, positions, kv_write, kv_stack,
-                           mask, rope, attn=None, embeds=None, reduce=None):
+                           mask, rope, attn=None, embeds=None, reduce=None,
+                           live=None):
     """``models.llama.forward`` as it stood before PR 37: it never heard of
-    passes."""
+    passes (nor of ``live``, which the runner hands it as None on one
+    device: PR 52)."""
     cos_t, sin_t = rope
     cos = cos_t[positions][:, :, None, :]
     sin = sin_t[positions][:, :, None, :]

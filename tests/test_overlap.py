@@ -257,3 +257,122 @@ def test_meshed_runner_kernel_writes_what_the_scatter_wrote(monkeypatch,
         assert (np.abs(want[:, 1:]).sum(axis=(0, 2, 4)) > 0).sum() >= 39 + 10
         np.testing.assert_allclose(got[:, 1:], want[:, 1:],
                                    rtol=1e-4, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# PR 52: a chunk on the mesh runs the live quarters of its bucket
+
+
+def _chunk_runner(monkeypatch, tp, quartered=True):
+    """A paged runner over debug:small (8 q / 4 kv heads) on a 1 x ``tp``
+    'model' mesh (one device: no mesh), buckets 128 and 512 as the cells
+    have them; ``quartered`` False takes the rule away (every chunk
+    computes its whole bucket: the parent's program)."""
+    from localai_tpu.engine import runner as rmod
+
+    if not quartered:
+        monkeypatch.setattr(rmod, "CHUNK_QUARTERED", 1 << 30)
+    model = resolve_model("debug:small", dtype="float32")
+    mesh = _tp_mesh(tp) if tp > 1 else None
+    params = (shd.shard_params(model.params, model.cfg, mesh)
+              if mesh is not None else model.params)
+    return ModelRunner(
+        model.cfg, params, num_slots=2, max_ctx=1024,
+        prefill_buckets=[128, 512], kv_dtype="float32", paged=True,
+        kv_block_tokens=16, mesh=mesh)
+
+
+def _admit_in_chunks(r, prompt):
+    """(first token, K pool, V pool, the chunks' flight rows)."""
+    adm = r.begin_admit(r.acquire_slot(), prompt, temperature=0.0)
+    rows = []
+    while True:
+        last = adm.launch_chunk()
+        rows.append(dict(adm.last_chunk))
+        if last:
+            break
+    return adm.first_token(), np.asarray(r.kv.k), np.asarray(r.kv.v), rows
+
+
+@pytest.mark.parametrize("tail, parts", [(100, 1), (200, 2), (300, 3),
+                                         (500, 4)])
+def test_mesh_chunk_runs_its_live_quarters(monkeypatch, tail, parts):
+    """A prompt of 512 + ``tail`` tokens on a 1 x 4 'model' mesh: a full
+    512-row chunk that is not the last (its program is whole:
+    ``chunk_parts`` 1), then the tail in the 128 bucket (whole too) or in
+    the 512 bucket, whose part behind the attend is cut to the quarters
+    that hold a real token. Against the same runner
+    with the rule taken away: the same first token and the same pool
+    outside the trash block, EXACTLY (rows of a matmul do not mix; the rows
+    left out are padding, written nowhere and attended by nothing); the
+    attend stays whole, so the ring row states the span it did."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    prompt = np.random.default_rng(3).integers(1, 500, 512 + tail).tolist()
+    cut = _admit_in_chunks(_chunk_runner(monkeypatch, 4), prompt)
+    whole = _admit_in_chunks(_chunk_runner(monkeypatch, 4, False), prompt)
+    assert cut[0] == whole[0]
+    for got, want in zip(cut[1:3], whole[1:3]):
+        assert np.abs(want[:, 1:]).sum() > 0
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+    assert [row["chunk_parts"] for row in cut[3]] == [1, parts]
+    assert [row["chunk_parts"] for row in whole[3]] == [1, 1]
+    assert [row["chunk_bucket"] for row in cut[3]] == [
+        row["chunk_bucket"] for row in whole[3]] == [512, 128 if parts == 1
+                                                     else 512]
+    assert cut[3][1]["chunk_ctx"] == whole[3][1]["chunk_ctx"] == 1024
+
+
+@pytest.mark.parametrize("tp, bucket, rows", [
+    (1, 512, (512,)), (1, 128, (128,)), (2, 128, (128,)),
+    (2, 512, (256, 384, 512)), (4, 512, (256, 384, 512)),
+    (4, 2048, (1024, 1536, 2048))])
+def test_chunk_rows_rule(monkeypatch, tp, bucket, rows):
+    """The rule reads what the runner can see: one device runs every row of
+    every bucket and so does a bucket under 512 rows on a mesh (the 128
+    bucket is bound by the weights' bytes); 512 rows or more on a 'model'
+    axis run in quarters, and the host's arithmetic (the ring's
+    ``chunk_parts``) is the program's switch."""
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} virtual devices")
+    r = _chunk_runner(monkeypatch, tp)
+    assert r.chunk_rows(bucket) == rows
+    # a chunk that is not a prompt's last holds ``prefill_chunk`` (512)
+    # tokens: in the 512 bucket every quarter is live, its program is whole
+    assert r.chunk_rows(bucket, last=False) == (
+        rows if bucket > 512 else (bucket,))
+    for tokens in (bucket // 4 + 1, bucket // 2, bucket // 2 + 1,
+                   3 * bucket // 4 + 1, bucket):
+        assert r.chunk_parts(bucket, tokens) == (
+            1 if len(rows) == 1 else -(-4 * tokens // bucket))
+
+
+def test_chunk_parts_reach_the_ring_and_metrics(monkeypatch):
+    """Through the scheduler: the prefill rows of ``/debug/flight`` say how
+    many quarters each chunk ran (``chunk_parts``), and ``/metrics`` counts
+    the chunk launches by them."""
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.obs import metrics as obs_metrics
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    s = Scheduler(_chunk_runner(monkeypatch, 4), ByteTokenizer())
+    try:
+        for n in (512 + 200, 300, 100):     # no two share a first block
+            s.generate(GenRequest(prompt=[65 + (n + i) % 26 for i in range(n)],
+                                  max_new_tokens=2, temperature=0.0),
+                       timeout=300)
+        chunks = [x for x in s.flight.snapshot()
+                  if x["program"] == "prefill_chunk"]
+        assert [x["chunk_parts"] for x in chunks] == [1, 2, 3, 1]
+        assert [x["chunk_bucket"] for x in chunks] == [512, 512, 512, 128]
+        m = s.metrics()
+        assert m["prefill_chunk_parts"] == {1: 2, 2: 1, 3: 1}
+        obs_metrics.update_engine_gauges("q4", m)
+        text = obs_metrics.REGISTRY.render()
+        for parts, n in ((1, 2), (2, 1), (3, 1)):
+            assert ('localai_prefill_chunk_parts_total{model="q4",parts="'
+                    f'{parts}"}} {n}') in text
+    finally:
+        s.shutdown()

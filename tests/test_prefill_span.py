@@ -263,9 +263,9 @@ def test_the_ring_row_states_the_span_of_each_chunk(runners):
     r.release(slot)
     assert held == [
         {"chunk_tokens": 512, "chunk_bucket": 512, "chunk_offset": 0,
-         "chunk_ctx": 512},
+         "chunk_ctx": 512, "chunk_parts": 1},
         {"chunk_tokens": 88, "chunk_bucket": 128, "chunk_offset": 512,
-         "chunk_ctx": 1024}]
+         "chunk_ctx": 1024, "chunk_parts": 1}]
     assert all(c["chunk_ctx"] == kvc.attend_span(
         c["chunk_offset"], c["chunk_bucket"], r.ctx_pad, r.block_tokens)
         for c in held)

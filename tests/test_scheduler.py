@@ -1045,7 +1045,8 @@ def test_a_decode_row_holds_what_its_launch_held(paged, monkeypatch, steps):
         assert row["attended_tokens"] == sum(
             at + 1 + j for _, at, _ in held for j in range(k))
         assert not any(row[c] for c in ("chunk_tokens", "chunk_bucket",
-                                        "chunk_offset", "chunk_ctx"))
+                                        "chunk_offset", "chunk_ctx",
+                                        "chunk_parts"))
         done = [(h, at + 1 - h.prompt_tokens, seen) for h, at, seen in held]
         ended_inside += sum(
             h.finish_reason == "length" and g < h.completion_tokens == 5 <= g + k

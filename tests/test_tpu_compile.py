@@ -523,7 +523,7 @@ def spans(r, program):
                            r.block_tokens)
 
 
-def assert_who_writes(program, text, pool, ladder=None):
+def assert_who_writes(program, text, pool, ladder=None, quartered=False):
     """PR 38. A DECODE program holds no scatter into the pool: its one
     Pallas call takes the step's K and V rows as its last operands and
     hands both pools (``pool``: one device's K or V stack) back aliased to
@@ -543,10 +543,12 @@ def assert_who_writes(program, text, pool, ladder=None):
     if not program.startswith("decode"):
         assert not calls
         assert 'kv_pool.write/scatter"' in text
-        switch = [ln for ln in text.splitlines() if " conditional(" in ln]
-        assert len(switch) == 1, switch
-        branches = switch[0].split("branch_computations={")[1].split("}")[0]
-        assert len(branches.split(",")) == len(ladder) > 1
+        # (a mesh's 512-row chunk holds a second conditional, of three
+        # branches, over its row counts: PR 52, the test at the end)
+        switch = [ln.split("branch_computations={")[1].split("}")[0].split(",")
+                  for ln in text.splitlines() if " conditional(" in ln]
+        assert sorted(map(len, switch)) == sorted(
+            [len(ladder)] + [3] * quartered) and len(ladder) > 1, switch
         for span in ladder:     # K's gather and V's: span / bt whole blocks
             blocks = f"bf16[{span // pool[3]},{','.join(map(str, pool[2:]))}]"
             assert len(re.findall(
@@ -961,7 +963,9 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
     # gather of rows in front of it: the shapes are the shard's); none in a
     # chunk, which scatters, and whose attend's branches (the rung is a
     # replicated scalar) gather the chip's own two heads of their span
-    assert_who_writes(program, text, shard, spans(r, program))
+    assert_who_writes(program, text, shard, spans(r, program),
+                      quartered=len(r.chunk_rows(512)) > 1
+                      and "chunk_512_sample" in program)
 
 
 # ---------------------------------------------------------------------------
@@ -1172,3 +1176,78 @@ def test_tp4_decode_layers_hold_two_all_reduces(topo, monkeypatch, cell,
     rest = sorted(op for op, _, name in talk if "/layers/" not in name)
     assert rest == ["all-gather", "all-gather", "all-reduce", "all-reduce"], [
         t for t in talk if "/layers/" not in t[2]]
+
+
+# ---------------------------------------------------------------------------
+# the mesh: a chunk reduces the quarters of its bucket that hold a token
+
+
+@pytest.mark.parametrize("cell, program", [
+    (MS24B, "prefill_chunk_512"), (MS24B, "prefill_chunk_512_sample"),
+    (MS24B, "prefill_chunk_128")], indirect=["cell"])
+def test_tp4_chunk_layers_reduce_their_live_quarters(topo, monkeypatch, cell,
+                                                     program):
+    """PR 52: the four-chip cell's 512-bucket program of a prompt's LAST
+    chunk (the one that samples: the only 512-row chunk that can be part
+    full) holds, in the layer scan's body behind the attend, ONE conditional
+    of three branches,
+    a branch a row count (256, 384, 512: the quarters of the bucket that
+    can hold a real token), and each branch the parent's two all-reduces a
+    layer over ITS rows: ``bf16[1, rows, 5120]`` after ``attn.out``'s
+    product and after ``mlp``'s down product. A chunk of up to 256 real
+    tokens pays for 256 rows of products and 2.6 MB a reduction where it
+    paid for 512 and 5.2. The branches are handed the stacked ``[40, ...]``
+    leaves and the layer's number and cut the layer's weights inside, where
+    the dots read them in place: handed the layer's slices (what closing
+    over the scanned leaves gives) the conditional has them copied out of
+    the stacks first, 131 MB a layer, which only the compiler shows. The
+    pool stays the scan's carry and no branch's operand. Every all-reduce
+    is a synchronous ``all-reduce``, as in the parent's program: this
+    compiler schedules none as a start and a done, and combines two row
+    parts' all-reduces into one over a tuple (PERF.md 6, PR 52: why the
+    parts do not overlap). The 128-bucket program and the 512-bucket
+    program of a chunk that is not the last (512 tokens: every quarter live)
+    hold no such conditional and the parent's two whole all-reduces: a
+    conditional's result comes back through HBM, ~1 ms of a 41 ms chunk."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    monkeypatch.setenv("LOCALAI_MESH_OVERLAP", "auto")
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, tp=4, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    bucket = int(program.split("_")[2])
+    rows = r.chunk_rows(bucket, program.endswith("_sample"))
+    assert rows == ((256, 384, 512) if program == "prefill_chunk_512_sample"
+                    else (bucket,))
+    text = compile_cell_program(r, a, program).as_text()
+    L, D = cfg.num_layers, cfg.hidden_size
+    talk = [(re.sub(r"\{[^}]*\}", "", result), name[1] if (name := re.search(
+        r'op_name="([^"]*)"', ln)) else "")
+        for op, result, ln in collectives(text) if op == "all-reduce"]
+    assert not re.search(r" all-reduce-(start|done)\(", text)
+    layers = sorted((res, re.search(r"/(attn\.out|mlp)/", name)[1])
+                    for res, name in talk if "/layers/" in name)
+    assert layers == sorted((f"bf16[1,{n},{D}]", scope) for n in rows
+                            for scope in ("attn.out", "mlp")), layers
+    switch = [ln for ln in text.splitlines() if re.search(
+        rf"bf16\[1,{bucket},{D}\]\S* conditional\(", ln)]
+    if len(rows) == 1:
+        assert not switch
+        return
+    assert len(switch) == 1, switch
+    branches = switch[0].split("branch_computations={")[1].split("}")[0]
+    branches = [b.strip().lstrip("%") for b in branches.split(",")]
+    assert len(branches) == len(rows)
+    pool = "[" + ",".join(map(str, a["kv"].k.sharding.shard_shape(
+        a["kv"].k.shape))) + "]"
+    for name in branches:
+        head = next(ln for ln in text.splitlines()
+                    if ln.startswith(f"%{name} ("))
+        handed = head.split(") -> ")[0]
+        weights = re.findall(r"s8\[([\d,]+)\]", handed)
+        assert len(weights) == 4 and all(
+            w.startswith(f"{L},") for w in weights), handed
+        assert pool not in handed, handed
