@@ -9,11 +9,10 @@ eating its HBM" view.
 
 ``GET /debug/programs`` — the compiled-program cost catalog: per watched
 jit entry, XLA ``cost_analysis``/``memory_analysis`` (FLOPs, bytes
-accessed, temp/output sizes) joined with the scheduler's measured
-per-dispatch latency into achieved GFLOP/s, GB/s, and fractions of the
-device roofline — the direct answer to "where does the decode bandwidth
-go". The first call lazily re-lowers each program from its recorded
-abstract signature (``?harvest=0`` lists without compiling).
+accessed, temp/output sizes), compile seconds and dispatch counts: what a
+program costs by the compiler's account. The first call lazily re-lowers
+each program from its recorded abstract signature (``?harvest=0`` lists
+without compiling).
 
 ``GET /debug/stacks`` — every live thread's stack, on demand (the same
 payload the watchdog dumps on a stall, for when an operator wants it
@@ -21,8 +20,9 @@ BEFORE the deadline).
 
 ``GET /debug/flight`` — the engine flight recorder: per-model rings of
 per-dispatch records (step times, occupancy, queue depth, KV utilization,
-tokens, preemptions, speculative acceptance) with windowed step-time
-percentiles. ``?since=<monotonic ts>`` returns only records newer than
+tokens, preemptions, speculative acceptance, the anatomy's phases and
+parts, the engine thread's clocks for the row's span) with windowed
+step-time percentiles. ``?since=<monotonic ts>`` returns only records newer than
 the given timestamp (pollers pass the ``ts`` of the last record they
 saw); ``?limit=N`` (at most 4096) bounds the records returned: the newest
 N, or with ``since`` the first N after it (a page). The "what was the
@@ -33,8 +33,11 @@ device.
 per-model windowed gap/sched/launch/sync phase percentiles and totals
 from the flight ring's phase columns, the derived
 ``host_overhead_fraction``, per-phase wall shares (stacked-bar ready), and
-the unattributed remainder. How idle the DEVICE was is measured, not
-estimated: ``POST /backend/trace``.
+the unattributed remainder; beside them the measured PARTS of gap
+(``process``, ``book``, ``free``: quantiles, totals, ``part_share``) and the engine
+thread's own account of the window (``thread``: the shares of its span on a
+CPU, runnable with no core, blocked, waiting for the device, idle). How
+idle the DEVICE was is measured, not estimated: ``POST /backend/trace``.
 ``?window=S`` sets the window (default 60 s; ``window=0`` reads the
 whole ring). The "where did the dispatch time go" view — host-side
 reads only, zero device syncs.
@@ -119,7 +122,6 @@ async def devices(request: web.Request) -> web.Response:
             "census": obs_device.hbm_census(
                 obs_device.known_arrays(runners)),
             "watchdog": obs_watchdog.WATCHDOG.status(),
-            "roofline": obs_device.roofline(),
         }
         if want_probe:
             # the probe itself is timeout-guarded; a wedged device costs
@@ -138,22 +140,7 @@ async def programs(request: web.Request) -> web.Response:
     loop = asyncio.get_running_loop()
 
     def build() -> dict:
-        # feed the catalog the live schedulers' measured step EMAs so a
-        # report right after boot still joins a latency (the drain-time
-        # note_latency feed is authoritative once traffic flows)
-        for sm in state.manager.loaded_snapshot().values():
-            sched = getattr(sm, "scheduler", None)
-            ema = getattr(sched, "_step_ema", None)
-            steps = getattr(sched, "last_dispatch_steps", 0)
-            if ema and steps:
-                prog = "decode" if steps == 1 else "decode_n"
-                obs_compile.note_latency(prog, ema * steps, steps=steps)
-        rl = obs_device.roofline()
-        return {
-            "roofline": rl,
-            "programs": obs_compile.CATALOG.report(
-                roofline=rl, harvest=harvest),
-        }
+        return {"programs": obs_compile.CATALOG.report(harvest=harvest)}
 
     return web.json_response(
         await loop.run_in_executor(state.executor, build))
@@ -213,6 +200,7 @@ async def anatomy(request: web.Request) -> web.Response:
     return web.json_response({
         "now_monotonic": round(time.monotonic(), 6),
         "phases": list(obs_anatomy.PHASES),
+        "parts": list(obs_anatomy.PARTS),       # inside gap
         "models": models,
     })
 

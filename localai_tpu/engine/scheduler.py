@@ -39,7 +39,6 @@ from localai_tpu.engine.runner import NAN_TOKEN, ModelRunner
 from localai_tpu.engine.stream import IncrementalDetokenizer, StopChecker
 from localai_tpu.faults import registry as _faults
 from localai_tpu.obs import anatomy as obs_anatomy
-from localai_tpu.obs import compile as obs_compile
 from localai_tpu.obs import flight as obs_flight
 from localai_tpu.obs import ledger as obs_ledger
 from localai_tpu.obs import profiler as obs_profiler
@@ -53,6 +52,15 @@ def _ema(prev: Optional[float], sample: float) -> float:
     """The smoothing of the scheduler's per-dispatch timings (step seconds,
     host seconds): a fifth of each new sample."""
     return sample if prev is None else 0.8 * prev + 0.2 * sample
+
+
+# a decode row is SLOW (localai_slow_dispatch_total) when the engine thread's
+# wall for it, idle left out, is over SLOW_FACTOR times what its steps take
+# by the step EMA and SLOW_EXCESS_MS more than that: a pause a stream's
+# reader sees, well clear of a long step
+SLOW_FACTOR = 4.0
+SLOW_EXCESS_MS = 50.0
+SLOW_OWNERS = ("wait", "cpu", "runq", "blocked")
 
 
 class _EngineAbandoned(Exception):
@@ -360,6 +368,9 @@ class Scheduler:
         self.multi_step = max(1, multi_step)
         self.stream_latency_target = stream_latency_target
         self._step_ema: Optional[float] = None   # seconds per decoded token
+        # the EMA the newest sample was folded INTO: what a row is slow
+        # against (its own sample has already moved ``_step_ema``)
+        self._step_ema_prior: Optional[float] = None
         # host seconds per pipelined decode dispatch: the flight record's
         # gap + sched + launch (what the device does not wait for)
         self._host_ema: Optional[float] = None
@@ -370,6 +381,17 @@ class Scheduler:
         self._anat_sched_s = 0.0    # admit/select/host-mirror spans
         self._anat_launch_s = 0.0   # async jit call-return spans
         self._anat_overlap_s = 0.0  # wall other records already account
+        # measured PARTS of what gap holds (obs.anatomy.PARTS), accumulated
+        # the same way: token processing, the loop's own bookkeeping, and
+        # the drop of a drained dispatch's result
+        self._anat_process_s = 0.0
+        self._anat_book_s = 0.0
+        self._anat_free_s = 0.0
+        # the engine thread's own clocks (obs.flight.ThreadClock): built on
+        # that thread at the start of each ``_run``
+        self._clock: Optional[obs_flight.ThreadClock] = None
+        # slow decode rows by the state that owned most of the row
+        self.slow_dispatches = dict.fromkeys(SLOW_OWNERS, 0)
         self.last_dispatch_steps = 0             # observability + tests
         # program shapes already dispatched once: the FIRST dispatch of a
         # new step count includes XLA trace+compile time, which must not be
@@ -715,6 +737,12 @@ class Scheduler:
             # attribution over the same ring the step percentiles read
             "host_overhead_fraction": anat["host_overhead_fraction"],
             "dispatch_phase_ms": obs_anatomy.phase_quantiles(anat),
+            # the engine thread's seconds by state over every row written
+            # (the ring's totals), and its slow decode rows by owner
+            "engine_thread_seconds": {
+                st: ms * 1e-3
+                for st, ms in self.flight.thread_ms_total.items()},
+            "slow_dispatches": dict(self.slow_dispatches),
             **(
                 {"prompt_cache": self.prompt_cache.stats()}
                 if self.prompt_cache is not None else {}
@@ -792,6 +820,28 @@ class Scheduler:
         return {"gap_ms": gap * 1e3, "sched_ms": sched * 1e3,
                 "launch_ms": launch * 1e3, "sync_ms": sync * 1e3}
 
+    def _take_row(self, end: float, parts: bool = True,
+                  ) -> dict:  # jaxlint: disable=lock-guarded-attr
+        """Close the interval a record accounts for, AT its end (``end`` is
+        ``time.monotonic()`` just read: a drain's end of wait, a chunk's end
+        of launch): the engine thread's own clocks for the span since the
+        previous record's interval ended (obs.flight ``CLOCK_COLUMNS``), and
+        with ``parts`` the three measured parts of gap accumulated in it,
+        taken and reset (a chunk's record leaves them to the next decode
+        record, like the anatomy accumulators). What the thread does behind
+        ``end`` (the tokens' processing, the record itself) is the next
+        interval's, on every clock alike."""
+        taken = self._clock.take(end)
+        taken["process_ms"] = taken["book_ms"] = taken["free_ms"] = 0.0
+        if parts:
+            taken["process_ms"] = self._anat_process_s * 1e3
+            taken["book_ms"] = self._anat_book_s * 1e3
+            taken["free_ms"] = self._anat_free_s * 1e3
+            self._anat_process_s = 0.0
+            self._anat_book_s = 0.0
+            self._anat_free_s = 0.0
+        return taken
+
     def _routed(self, rows: np.ndarray,
                 held: Optional[dict] = None) -> np.ndarray:
         """Split what a launch's copy brought: the sampled tokens, and behind
@@ -862,7 +912,7 @@ class Scheduler:
                        fresh: bool, spec_proposed: int = 0,
                        spec_accepted: int = 0, sync_s: float = 0.0,
                        phases: Optional[dict] = None,
-                       held: Optional[dict] = None,
+                       held: Optional[dict] = None, *, taken: dict,
                        ) -> None:  # jaxlint: disable=lock-guarded-attr
         """One flight-ring record at a drain point. Everything here is a
         host mirror this (engine) thread already owns — ``_slots`` is only
@@ -876,7 +926,11 @@ class Scheduler:
         a pre-built ``phases`` dict (prefill chunks, whose span must not
         consume the accumulators owed to the next decode record). ``held``
         is the one part that is NOT end-of-dispatch state: what the launch
-        held when it was enqueued (``_launch``), carried here."""
+        held when it was enqueued (``_launch``), carried here. ``taken`` is
+        ``_take_row``'s reading at the END of the interval ``dt`` accounts
+        for: the thread's clocks, and the measured parts of gap, which are
+        clamped here to what this record's gap holds (process first, then
+        book, then free)."""
         emitted = self._tokens_emitted
         num_slots = self.runner.num_slots
         batch_slots = sum(
@@ -890,6 +944,13 @@ class Scheduler:
         forwards = int(steps) if program.startswith("decode") else 1
         passes = forwards * self._passes_per_forward
         self.total_loop_passes += passes
+        gap_ms = phases["gap_ms"]
+        process_ms = min(taken["process_ms"], gap_ms)
+        book_ms = min(taken["book_ms"], gap_ms - process_ms)
+        free_ms = min(taken["free_ms"], gap_ms - process_ms - book_ms)
+        taken.update(process_ms=process_ms, book_ms=book_ms, free_ms=free_ms)
+        if not fresh and program in ("decode", "decode_n"):
+            self._note_slow(steps, taken)
         self.flight.record(
             program=program,
             steps=steps,
@@ -910,6 +971,7 @@ class Scheduler:
             launch_ms=phases["launch_ms"],
             sync_ms=phases["sync_ms"],
             compile=fresh,
+            **taken,
             **(held or {}),
         )
         self._flight_mark = emitted
@@ -922,6 +984,20 @@ class Scheduler:
                 model=self.telemetry.model or "engine")
         if self._kv_check:
             self._check_kv_invariants()
+
+    def _note_slow(self, steps: int,
+                   clock: dict) -> None:  # jaxlint: disable=lock-guarded-attr
+        """Count a decode row that took the engine thread far longer than
+        its steps take (``SLOW_FACTOR``, ``SLOW_EXCESS_MS``), under the
+        state that owned most of it."""
+        if not self._step_ema_prior:
+            return
+        busy = clock["span_ms"] - clock["idle_ms"]
+        expected = max(1, steps) * self._step_ema_prior * 1e3
+        if busy > SLOW_FACTOR * expected and busy - expected >= SLOW_EXCESS_MS:
+            owner = max(SLOW_OWNERS,
+                        key=lambda st: clock[f"{st}_ms"] or 0.0)
+            self.slow_dispatches[owner] += 1
 
     def _check_kv_invariants(self) -> None:
         """Debug-flag drain sweep: the block allocator must conserve its
@@ -1153,11 +1229,15 @@ class Scheduler:
         rebuild fences this thread off (``_epoch`` moved past ours while
         we sat in a blocked round-trip), in which case exit silently:
         the replacement thread owns the state now."""
+        # this thread's clocks, opened here: ``thread-self`` is whoever opens
+        clock = self._clock = obs_flight.ThreadClock()
         try:
             self._run_loop(epoch)
         except _EngineAbandoned:
             log.warning("engine thread (epoch %d) abandoned after rebuild",
                         epoch)
+        finally:
+            clock.close()
 
     # the engine thread is the SOLE mutator of _slots/_prefills/etc.;
     # its own lock-free reads here are the single-owner-thread design the
@@ -1194,6 +1274,7 @@ class Scheduler:
             # a device that never answers parks this exact line forever, and
             # the stall forensics must say so.
             t_sync = time.monotonic()  # anatomy: the result-fetch block
+            waiting = self._clock.enter()
             with self.watchdog.guard(self._wd_channel), \
                     TraceAnnotation("sched.wait_device"):  # waiting, not work
                 if _faults.ACTIVE:  # chaos: wedge/raise inside the guard
@@ -1205,12 +1286,26 @@ class Scheduler:
                 raise _EngineAbandoned
             now = time.monotonic()
             sync_s = now - t_sync
-            rows = self._routed(rows, d.held)
+            # the interval ends here: the thread's clocks are read, and the
+            # routed counts split off, under the name the row's write has
+            with TraceAnnotation("sched.record"):
+                self._clock.leave(waiting, sync_s)
+                if d.first is None:
+                    taken = self._take_row(now)
+                rows = self._routed(rows, d.held)
             if d.first is not None:
                 # a final prefill chunk: the device has just finished it,
                 # so the decode step behind it is timed from here
                 self._last_drain_t = now
-                self._first_token(d.first, int(rows.reshape(-1)[0]))
+                t_proc = time.monotonic()
+                self._anat_book_s += t_proc - now
+                with TraceAnnotation("sched.process"):
+                    self._first_token(d.first, int(rows.reshape(-1)[0]))
+                t_free = time.monotonic()
+                self._anat_process_s += t_free - t_proc
+                with TraceAnnotation("sched.free"):
+                    del d, toks, rows
+                self._anat_free_s += time.monotonic() - t_free
                 return
             window = None
             if k == 0 and self.spec is not None:  # speculative window
@@ -1237,32 +1332,42 @@ class Scheduler:
                          if window["windows"] else 0)
             if not fresh and k_eff > 0:
                 self._observe_step_time(dt / k_eff)
-                # measured per-dispatch latency feeds the compiled-program
-                # cost catalog (achieved-vs-roofline at /debug/programs)
-                obs_compile.note_latency(
-                    "verify" if k == 0
-                    else "decode_n" if k > 1 else "decode",
-                    dt, steps=k_eff)
             self._last_drain_t = now
             if rows.ndim == 1:
                 rows = rows[None]
+            t_proc = time.monotonic()
+            self._anat_book_s += t_proc - now
             self._process_rows(rows, seq)
-            phases = self._take_anat(dt, sync_s)
-            if not fresh and k > 0:
-                self._observe_host_time(
-                    (phases["gap_ms"] + phases["sched_ms"]
-                     + phases["launch_ms"]) * 1e-3)
-            # flight ring: spec windows carry their yield as steps plus
-            # per-dispatch proposed/accepted counts (ROADMAP item 3:
-            # accept-rate in the flight ring); compile-bearing dispatches
-            # are flagged
-            self._flight_record(
-                "spec" if k == 0 else ("decode_n" if k > 1 else "decode"),
-                k_eff, dt, fresh,
-                spec_proposed=window["proposed"] if window else 0,
-                spec_accepted=window["accepted"] if window else 0,
-                phases=phases, held=d.held,
-            )
+            t_book = time.monotonic()
+            self._anat_process_s += t_book - t_proc
+            with TraceAnnotation("sched.record"):
+                phases = self._take_anat(dt, sync_s)
+                if not fresh and k > 0:
+                    self._observe_host_time(
+                        (phases["gap_ms"] + phases["sched_ms"]
+                         + phases["launch_ms"]) * 1e-3)
+                # flight ring: spec windows carry their yield as steps plus
+                # per-dispatch proposed/accepted counts (ROADMAP item 3:
+                # accept-rate in the flight ring); compile-bearing
+                # dispatches are flagged
+                self._flight_record(
+                    "spec" if k == 0
+                    else ("decode_n" if k > 1 else "decode"),
+                    k_eff, dt, fresh,
+                    spec_proposed=window["proposed"] if window else 0,
+                    spec_accepted=window["accepted"] if window else 0,
+                    phases=phases, held=d.held, taken=taken,
+                )
+            t_free = time.monotonic()
+            self._anat_book_s += t_free - t_book
+            # the dispatch's result dies HERE and not at the return, behind
+            # the last clock read: dropping a device array calls into the
+            # runtime, and that call lets go of the GIL, which the stream
+            # threads the tokens have just woken then take in turn: a
+            # millisecond of a 32-stream batch that had no name (PR 53)
+            with TraceAnnotation("sched.free"):
+                del d, toks, rows, window
+            self._anat_free_s += time.monotonic() - t_free
 
         while not self._stopping and self._epoch == epoch:
             if _faults.ACTIVE:
@@ -1319,8 +1424,15 @@ class Scheduler:
                     self._anat_sched_s = 0.0
                     self._anat_launch_s = 0.0
                     self._anat_overlap_s = 0.0
+                    self._anat_process_s = 0.0
+                    self._anat_book_s = 0.0
+                    self._anat_free_s = 0.0
                     with TraceAnnotation("sched.idle"):
+                        t_idle = time.monotonic()
+                        waiting = self._clock.enter()
                         self._wake.wait(timeout=0.05)
+                        self._clock.leave(
+                            waiting, time.monotonic() - t_idle, idle=True)
                     self._wake.clear()
                 continue
             try:
@@ -1345,56 +1457,55 @@ class Scheduler:
                     constrained = constrained_slots()
                     if not self._slots or not constrained:
                         continue
-                    steps = self._effective_steps(pipelined=False)
-                    self._dispatch_seq += 1
-                    if len(constrained) == len(self._slots) or steps == 1:
-                        fresh = self._fresh_shape(1)
-                        held = self._launch(1)
-                        t0 = time.monotonic()
-                        with TraceAnnotation(
-                                f"sched.launch/{held['launch']}"):
-                            rows = self._routed(self.runner.step()[None],
-                                                held)
-                        dt = time.monotonic() - t0
-                        # anatomy: the runner split its own wall into
-                        # enqueue vs result-fetch — harvest the scratch
-                        self._anat_launch_s += (
-                            self.runner.last_launch_ms * 1e-3)
-                        if not fresh:
-                            self._observe_step_time(dt)
-                            obs_compile.note_latency("decode", dt, steps=1)
-                        self.last_dispatch_steps = 1
-                        self._process_rows(rows, self._dispatch_seq)
+                    t_count = time.monotonic()
+                    with TraceAnnotation("sched.count"):
+                        steps = self._effective_steps(pipelined=False)
+                        self._dispatch_seq += 1
+                        # every slot waits for its own token, or one step
+                        # is all the budget holds: the plain program;
+                        # else the others ride ``steps`` tokens frozen-slot
+                        plain = (len(constrained) == len(self._slots)
+                                 or steps == 1)
+                        if plain:
+                            program, steps = "decode", 1
+                        else:
+                            program = "decode_frozen_n"
+                            freeze = np.zeros(self.runner.num_slots, bool)
+                            freeze[list(constrained)] = True
+                        fresh = self._fresh_shape(
+                            1 if plain else ("frozen", steps))
+                        # a frozen-slot launch writes no decode row: no counts
+                        held = self._launch(1 if plain else 0)
+                    t0 = time.monotonic()
+                    self._anat_book_s += t0 - t_count
+                    with TraceAnnotation(f"sched.launch/{held['launch']}"):
+                        rows = self._routed(
+                            self.runner.step()[None] if plain
+                            else self.runner.step_frozen_n(freeze, steps),
+                            held)
+                    t_proc = time.monotonic()
+                    dt = t_proc - t0
+                    # the runner waited for the device inside that call: its
+                    # wall is the row's wait, as a pipelined row's drain is
+                    self._clock.leave(None, self.runner.last_sync_ms * 1e-3)
+                    taken = self._take_row(t_proc)
+                    # anatomy: the runner split its own wall into
+                    # enqueue vs result-fetch — harvest the scratch
+                    self._anat_launch_s += self.runner.last_launch_ms * 1e-3
+                    if not fresh:
+                        self._observe_step_time(dt / steps)
+                    self.last_dispatch_steps = steps
+                    self._process_rows(
+                        rows, self._dispatch_seq,
+                        frozen=None if plain else constrained)
+                    t_book = time.monotonic()
+                    self._anat_process_s += t_book - t_proc
+                    with TraceAnnotation("sched.record"):
                         self._flight_record(
-                            "decode", 1, dt, fresh,
+                            program, steps, dt, fresh,
                             sync_s=self.runner.last_sync_ms * 1e-3,
-                            held=held)
-                    else:
-                        freeze = np.zeros(self.runner.num_slots, bool)
-                        freeze[list(constrained)] = True
-                        fresh = self._fresh_shape(("frozen", steps))
-                        held = self._launch()   # no decode row: no counts
-                        t0 = time.monotonic()
-                        with TraceAnnotation(
-                                f"sched.launch/{held['launch']}"):
-                            rows = self._routed(
-                                self.runner.step_frozen_n(freeze, steps),
-                                held)
-                        dt = time.monotonic() - t0
-                        self._anat_launch_s += (
-                            self.runner.last_launch_ms * 1e-3)
-                        if not fresh:
-                            self._observe_step_time(dt / steps)
-                            obs_compile.note_latency(
-                                "decode_frozen_n", dt, steps=steps)
-                        self.last_dispatch_steps = steps
-                        self._process_rows(
-                            rows, self._dispatch_seq, frozen=constrained
-                        )
-                        self._flight_record(
-                            "decode_frozen_n", steps, dt, fresh,
-                            sync_s=self.runner.last_sync_ms * 1e-3,
-                            held=held)
+                            held=held, taken=taken)
+                    self._anat_book_s += time.monotonic() - t_book
                     self._last_drain_t = None  # sync path: drain clock stale
                 else:
                     # cheap speculation pre-gate, BEFORE any drain or
@@ -1462,11 +1573,14 @@ class Scheduler:
                         continue
                     if self.spec is not None:
                         self._spec_dirty = True
-                    steps = self._effective_steps()
-                    self._dispatch_seq += 1
-                    fresh = self._fresh_shape(steps)
-                    held = self._launch(steps, inflight)
+                    t_count = time.monotonic()
+                    with TraceAnnotation("sched.count"):
+                        steps = self._effective_steps()
+                        self._dispatch_seq += 1
+                        fresh = self._fresh_shape(steps)
+                        held = self._launch(steps, inflight)
                     t_issue = time.monotonic()
+                    self._anat_book_s += t_issue - t_count
                     with TraceAnnotation("sched.decode_launch"):
                         with TraceAnnotation(
                                 f"sched.launch/{held['launch']}"):
@@ -1574,6 +1688,7 @@ class Scheduler:
         adaptive streaming dispatch size."""
         if dt <= 0:
             return
+        self._step_ema_prior = self._step_ema
         self._step_ema = _ema(self._step_ema, dt)
 
     def _observe_host_time(self, host_s: float) -> None:
@@ -1925,7 +2040,9 @@ class Scheduler:
             entry = _Dispatch(pf.adm.first, self._dispatch_seq, 0, False,
                               t0, False, first=pf)
             self._install_slot(pf.slot, pf.handle, pf.base, pf.mask_set)
-        dt = time.monotonic() - t0
+        t_end = time.monotonic()
+        dt = t_end - t0
+        taken = self._take_row(t_end, parts=False)
         self.total_prefill_chunks += 1
         parts = held.get("chunk_parts", 1)
         self.total_chunk_parts[parts] = self.total_chunk_parts.get(parts, 0) + 1
@@ -1940,8 +2057,11 @@ class Scheduler:
             "prefill_chunk", 0, dt, False,
             phases={"gap_ms": 0.0, "sched_ms": wall_ms - launch_ms,
                     "launch_ms": launch_ms, "sync_ms": 0.0},
-            held=held)
+            held=held, taken=taken)
         self._anat_overlap_s += dt
+        # writing the chunk's row is the loop's bookkeeping: it lies in the
+        # next decode row's gap, and gets its name there
+        self._anat_book_s += time.monotonic() - t_end
         return True, entry
 
     def _free_frontiers(self) -> np.ndarray:

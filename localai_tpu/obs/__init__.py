@@ -21,9 +21,8 @@ Cooperating pieces:
     ``memory_stats()`` gauges, and a live-array HBM census (KV cache vs
     weights vs other) behind ``GET /debug/devices``.
   * ``obs.compile`` — XLA compile telemetry plus the compiled-program cost
-    catalog (``cost_analysis``/``memory_analysis`` joined with measured
-    dispatch latency into achieved-vs-roofline fractions) behind
-    ``GET /debug/programs``.
+    catalog (``cost_analysis``/``memory_analysis`` of every watched
+    program) behind ``GET /debug/programs``.
   * ``obs.logging`` — structured JSON log formatter with the request
     trace id bound via contextvar by the API middleware.
   * ``obs.flight`` — the engine flight recorder: a lock-light fixed-size

@@ -18,6 +18,36 @@ sync    time blocked at the EXISTING result fetch (``np.asarray`` /
         ``int(tok)``): device-bound time when the host arrived early
 ======  ===========================================================
 
+Three PARTS of ``gap`` are measured where the work happens (the four phases
+above tile a dispatch; these lie inside ``gap`` and tile part of it):
+
+=======  ==========================================================
+part     meaning
+=======  ==========================================================
+process  the wall of ``Scheduler._process_rows`` (and of a first
+         token's consume): stop checks, detokenising, handing the
+         tokens to the streams
+book     the engine loop's own bookkeeping: choosing the step count
+         and counting what a launch holds in front of the enqueue;
+         the routed counts, the EMAs and the ring row behind the
+         drain. What the instrumentation itself costs a dispatch
+free     dropping the drained dispatch's result: the device arrays
+         die there, the runtime frees their buffers, and the call
+         lets go of the GIL, which the stream threads that the
+         tokens have just woken take in turn (on a 32-stream batch
+         a millisecond, asleep: found by PR 53 behind the last
+         clock read of a drain, at its ``return``)
+=======  ==========================================================
+
+``process + book + free <= gap`` for every record; what is left of gap is
+the host time that still has no name.
+
+Beside the wall clock a row carries the engine thread's OWN clocks for the
+span since the previous row (obs.flight ``CLOCK_COLUMNS``): inside
+``sched.wait_device``, inside ``sched.idle``, on a CPU, runnable with no core
+(the machine's other processes had it), and blocked (asleep on the GIL, a
+lock, a file). The five tile the span, so a long row names who owned it.
+
 Attribution model (interval tiling). Each record's phases decompose the
 wall interval its ``dispatch_ms`` accounts for — for pipelined records
 the inter-drain interval, for synchronous records the issue→drain span —
@@ -52,6 +82,9 @@ from typing import Any, Optional
 #: Phase column order — stable; UI stacked bars and bench lines rely on it.
 PHASES = ("gap", "sched", "launch", "sync")
 
+#: Measured parts of ``gap``: inside it, so in no sum over PHASES.
+PARTS = ("process", "book", "free")
+
 QUANTILES = ("p50", "p90", "p99")
 
 #: One-line phase definitions, served with /debug/anatomy payloads.
@@ -61,6 +94,13 @@ PHASE_HELP = {
     "sched": "admit/select/host-mirror work before entering the runner",
     "launch": "time for the async jit call to return (enqueue overhead)",
     "sync": "time blocked at the existing result fetch (device-bound)",
+    "process": ("part of gap: handling a dispatch's tokens (stop checks, "
+                "detokenising, handing them to the streams)"),
+    "book": ("part of gap: the engine loop's own bookkeeping (step count, "
+             "what a launch holds, EMAs, the flight row)"),
+    "free": ("part of gap: dropping the drained dispatch's device arrays "
+             "(a runtime call that lets go of the GIL: the stream threads "
+             "run here)"),
 }
 
 #: Window the scheduler/metrics plane summarizes over, matching the
@@ -81,7 +121,7 @@ def phase_quantiles(summary: dict) -> dict:
     windows and on payloads from replicas that predate the phase columns.
     """
     out: dict = {}
-    for ph in PHASES:
+    for ph in PHASES + PARTS:
         qs = {}
         for q in QUANTILES:
             v = summary.get(f"{ph}_ms_{q}")
@@ -99,7 +139,9 @@ def breakdown(flight: Any, window_s: Optional[float] = DEFAULT_WINDOW_S,
     Adds ``phase_share`` (each phase's fraction of the windowed dispatch
     wall), the ``unattributed`` remainder (records whose writers did not
     attribute phases — all-zero columns — land here, never silently in a
-    phase), and the phase definitions for self-description.
+    phase), ``part_share`` (process/book/free over the same wall: parts of
+    gap, counted in no sum), and the phase definitions for self-description.
+    The summary's ``thread`` block rides along.
     """
     s = summarize(flight, window_s=window_s, now=now)
     total = s.get("dispatch_ms_total") or 0.0
@@ -111,6 +153,9 @@ def breakdown(flight: Any, window_s: Optional[float] = DEFAULT_WINDOW_S,
         shares[ph] = round(ms / total, 4) if total > 0 else None
     unattr = max(0.0, total - attributed)
     s["phase_share"] = shares
+    s["part_share"] = {
+        pt: (round((s.get(f"{pt}_ms_total") or 0.0) / total, 4)
+             if total > 0 else None) for pt in PARTS}
     s["unattributed_ms_total"] = round(unattr, 3)
     s["unattributed_share"] = (round(unattr / total, 4)
                                if total > 0 else None)

@@ -24,9 +24,9 @@ lands in the process-wide :data:`CATALOG` as its abstract signature
 catalog report, by re-lowering from the stored avals: re-compiling at first
 dispatch would double every compile on the serving path, so the observatory
 pays that price only when an operator actually asks "where does the
-bandwidth go". The scheduler feeds measured per-dispatch latency via
-:func:`note_latency`; the report divides bytes-accessed and FLOPs by it and
-by the device roofline (obs.device) into achieved fractions.
+bandwidth go". The report is XLA's account of each program (FLOPs, bytes
+accessed, memory); it sets no dispatch's wall against it: a host clock is not
+a device share, and the benchmark's trace readers measure those.
 """
 
 from __future__ import annotations
@@ -101,16 +101,11 @@ class ProgramCatalog:
     Entries are keyed (program, watch-instance, shape-key): two loaded
     models both watch a "decode" program whose top-level args are pytrees
     (identical shape keys), and without the per-``watch()`` instance id
-    the second model's entries would overwrite the first's. The latency
-    EMA stays keyed (program, steps) — the scheduler feeding it does not
-    know instances, so with several models loaded it blends their decode
-    latencies (single-model serving, the v1 deployment, is exact)."""
+    the second model's entries would overwrite the first's."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._entries: dict[tuple, ProgramEntry] = {}
-        # measured seconds per DISPATCH, EMA, keyed (program, steps)
-        self._latency: dict[tuple, float] = {}
 
     def record(self, program: str, key: tuple, fn: Callable,
                args: tuple, kwargs: dict, compile_seconds: float) -> None:
@@ -131,19 +126,6 @@ class ProgramCatalog:
             e = self._entries.get((program, key))
             if e is not None:
                 e.dispatches += 1
-
-    def note_latency(self, program: str, seconds: float, *,
-                     steps: int = 1) -> None:
-        """Fold one measured per-dispatch wall time into the (program,
-        steps) EMA — called by the scheduler at its drain points, never on
-        the dispatch path."""
-        if seconds <= 0:
-            return
-        k = (program, int(steps))
-        with self._lock:
-            prev = self._latency.get(k)
-            self._latency[k] = (seconds if prev is None
-                                else 0.8 * prev + 0.2 * seconds)
 
     def _harvest(self, entry: ProgramEntry) -> None:
         """Lower+compile from the stored avals and cache the analysis.
@@ -175,21 +157,15 @@ class ProgramCatalog:
             # re-lower from bare avals (sharding was on the buffers)
             entry.cost_error = f"{type(e).__name__}: {e}"
 
-    def report(self, *, roofline: Optional[dict] = None,
-               harvest: bool = True) -> list[dict]:
-        """Catalog view joined with measured latency and the roofline.
+    def report(self, *, harvest: bool = True) -> list[dict]:
+        """Catalog view: each program with XLA's cost and memory analysis.
         ``harvest=False`` skips lazy compilation (cheap listing)."""
         with self._lock:
             entries = list(self._entries.values())
-            latency = dict(self._latency)
-        peak_gbps = (roofline or {}).get("peak_gbps")
-        peak_tflops = (roofline or {}).get("peak_tflops")
         out = []
         for e in entries:
             if harvest and e.cost is None and not e.cost_error:
                 self._harvest(e)
-            steps = int(e.statics.get("n", 1) or 1)
-            lat = latency.get((e.program, steps))
             row: dict = {
                 "program": e.program,
                 # which watch() wrapper (≈ which runner) this entry is —
@@ -198,22 +174,9 @@ class ProgramCatalog:
                 "statics": {k: v for k, v in e.statics.items()},
                 "first_dispatch_seconds": round(e.compile_seconds, 4),
                 "dispatches": e.dispatches,
-                "dispatch_seconds_ema": (None if lat is None
-                                         else round(lat, 6)),
             }
             if e.cost:
                 row.update(e.cost)
-                flops = e.cost.get("flops") or 0.0
-                byts = e.cost.get("bytes_accessed") or 0.0
-                if lat:
-                    row["achieved_gflops"] = round(flops / lat / 1e9, 3)
-                    row["achieved_gbps"] = round(byts / lat / 1e9, 3)
-                    if peak_tflops:
-                        row["flops_fraction"] = round(
-                            flops / lat / (peak_tflops * 1e12), 4)
-                    if peak_gbps:
-                        row["bandwidth_fraction"] = round(
-                            byts / lat / (peak_gbps * 1e9), 4)
             elif e.cost_error:
                 row["cost_error"] = e.cost_error
             out.append(row)
@@ -223,10 +186,6 @@ class ProgramCatalog:
 
 
 CATALOG = ProgramCatalog()
-
-
-def note_latency(program: str, seconds: float, *, steps: int = 1) -> None:
-    CATALOG.note_latency(program, seconds, steps=steps)
 
 
 # one id per watch() wrapper: it disambiguates catalog entries when two

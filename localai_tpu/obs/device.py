@@ -17,73 +17,16 @@ Three independent questions, three tools:
     sets supplied by the caller (the /debug/devices handler passes each
     loaded runner's ``kv`` leaves and param leaves). ``nbytes`` is
     metadata; the census never syncs.
-
-:func:`roofline` is the shared peak table the compiled-program cost
-observatory (obs.compile) divides by: known TPU generations by device_kind
-substring, env overrides ``LOCALAI_PEAK_GBPS``/``LOCALAI_PEAK_TFLOPS``.
-A device that is not in the table has NO peak — the observatory then
-reports no roofline fraction ("not measured") instead of a fraction of an
-invented number.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 import time
 from typing import Any, Iterable, Optional
 
 from localai_tpu.obs.metrics import REGISTRY, Registry
-
-# device_kind substring (lowercased) → (peak HBM GB/s, peak bf16 TFLOP/s).
-# Source: Google Cloud TPU documentation, "System architecture" pages per
-# generation (cloud.google.com/tpu/docs/system-architecture-tpu-vm and the
-# v5e / v5p / v6e pages), per-chip HBM bandwidth and peak bf16 compute.
-# jax reports a v5e chip as device_kind "TPU v5 lite".
-_ROOFLINES = (
-    ("v6", (1640.0, 918.0)),
-    ("v5p", (2765.0, 459.0)),
-    ("v5 lite", (819.0, 197.0)),
-    ("v5e", (819.0, 197.0)),
-    ("v4", (1228.0, 275.0)),
-    ("v3", (900.0, 123.0)),
-    ("v2", (700.0, 46.0)),
-)
-
-
-def roofline(device: Optional[Any] = None) -> dict:
-    """Peak bandwidth/compute for ``device`` (default: first jax device).
-    ``{"peak_gbps", "peak_tflops", "source": "env"|"table"|"unknown"}`` —
-    a peak is None when neither the env nor the table gives it (the CPU
-    test mesh, a TPU generation newer than the table)."""
-    env_bw = os.environ.get("LOCALAI_PEAK_GBPS")
-    env_fl = os.environ.get("LOCALAI_PEAK_TFLOPS")
-    if env_bw or env_fl:
-        try:
-            return {
-                "peak_gbps": float(env_bw) if env_bw else None,
-                "peak_tflops": float(env_fl) if env_fl else None,
-                "source": "env",
-            }
-        except ValueError:
-            pass
-    kind = ""
-    try:
-        if device is None:
-            import jax
-
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "")).lower()
-    except Exception:  # noqa: BLE001 — no backend is still an answer
-        pass
-    for sub, (bw, fl) in _ROOFLINES:
-        if sub in kind:
-            return {"peak_gbps": bw, "peak_tflops": fl, "source": "table",
-                    "device_kind": kind}
-    return {"peak_gbps": None, "peak_tflops": None, "source": "unknown",
-            "device_kind": kind}
-
 
 def device_report() -> dict:
     """The backend this process computes on, as jax reports it:

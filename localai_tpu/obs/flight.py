@@ -50,6 +50,138 @@ WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "window_tokens",
                 "chunk_parts", "experts_touched", "local_assignments")
 
 
+# the measured parts of what ``gap_ms`` holds (obs.anatomy.PARTS), and the
+# engine thread's own clocks for the span since the previous row: columns of
+# the ring and keys of /debug/flight, in the order of record()'s keywords
+PART_COLUMNS = ("process_ms", "book_ms", "free_ms")
+CLOCK_COLUMNS = ("span_ms", "wait_ms", "idle_ms", "cpu_ms", "runq_ms",
+                 "blocked_ms", "proc_cpu_ms")
+# the states that tile a row's span (``wait + idle + cpu + runq + blocked =
+# span``), as the ``state`` label of localai_engine_thread_seconds_total
+THREAD_STATES = ("cpu", "runq", "blocked", "wait", "idle")
+
+
+# the calling thread's scheduler statistics: "<ns on a cpu> <ns runnable and
+# waiting for one> <timeslices>" (Documentation/scheduler/sched-stats)
+SCHEDSTAT = "/proc/thread-self/schedstat"
+
+
+# the most CPU a ThreadClock owes its next rows: ten ticks of the coarsest
+# clock met. More than that is no tick but a clock that reads high, and rows
+# long after it would pay for it
+OWED_MAX_S = 0.1
+
+
+class ThreadClock:
+    """One thread's own clocks, read by that thread once a ring row.
+
+    A wall clock cannot tell a thread that computed from one that was
+    runnable with no core (the machine), one that slept on the GIL or a lock
+    (this process's other threads) or one whose device answered late. The
+    kernel keeps them apart for every thread: its CPU time
+    (``time.thread_time``) and its run-queue delay (the second field of
+    ``SCHEDSTAT``). :meth:`take` turns them into the ``CLOCK_COLUMNS`` of a
+    row: the differences since the previous take, with the wall the thread
+    spent inside its two waits (:meth:`enter` / :meth:`leave` around each)
+    and the CPU and run-queue delay of those waits taken out of ``cpu`` and
+    ``runq``, so that ``wait + idle + cpu + runq + blocked = span``.
+
+    A CPU clock may tick coarser than a row is long (10 ms on the chip's
+    machine, under rows of 8): a tick is credited to ``cpu`` as far as the
+    span has room for it and the rest is OWED to the next rows, so that a
+    window's ``cpu`` sums to what the clock read and ``blocked`` takes none
+    of it; one row's ``cpu_ms`` is good to a tick.
+
+    Build it ON the thread it reads: ``thread-self`` resolves when the file
+    is opened, and ``thread_time`` is the caller's. About half a microsecond
+    a read, four a take, three at each end of a wait; a file that cannot be
+    opened or read gives ``runq_ms`` None and nothing raises."""
+
+    def __init__(self):
+        try:
+            self._fd: Optional[int] = os.open(SCHEDSTAT, os.O_RDONLY)
+        except OSError:
+            self._fd = None
+        self._t = time.monotonic()
+        self._cpu = time.thread_time()
+        self._runq = self._runq_ns()
+        self._proc = time.process_time()
+        # inside the waits since the last take: wall by kind, CPU, delay
+        self._wait_s = 0.0
+        self._idle_s = 0.0
+        self._in_cpu = 0.0
+        self._in_runq = 0
+        # CPU the clock read that no span had room for yet (see take())
+        self._cpu_owed = 0.0
+
+    def _runq_ns(self) -> Optional[int]:
+        if self._fd is None:
+            return None
+        try:
+            return int(os.pread(self._fd, 64, 0).split()[1])
+        except (OSError, IndexError, ValueError):
+            return None
+
+    def enter(self) -> tuple:
+        """At a wait's start; hand the mark to :meth:`leave`."""
+        return time.thread_time(), self._runq_ns()
+
+    def leave(self, mark: Optional[tuple], wall_s: float,
+              idle: bool = False) -> None:
+        """At a wait's end: ``wall_s`` inside ``sched.wait_device``, or with
+        ``idle`` inside ``sched.idle``; what the thread burnt and queued in
+        there is the wait's, not ``cpu``'s or ``runq``'s. A wait whose two
+        ends the caller cannot reach (inside the runner's synchronous step)
+        passes no mark: its wall alone."""
+        if mark is not None:
+            cpu0, runq0 = mark
+            self._in_cpu += time.thread_time() - cpu0
+            runq1 = self._runq_ns()
+            if runq0 is not None and runq1 is not None:
+                self._in_runq += runq1 - runq0
+        if idle:
+            self._idle_s += wall_s
+        else:
+            self._wait_s += wall_s
+
+    def take(self, now: float) -> dict:
+        """The ``CLOCK_COLUMNS`` (ms) of the row whose span ends at ``now``
+        (``time.monotonic``, just read): the span since the previous take."""
+        cpu_t, runq_t, proc_t = (time.thread_time(), self._runq_ns(),
+                                 time.process_time())
+        span = max(0.0, now - self._t)
+        wait, idle, runq = self._wait_s, self._idle_s, 0.0
+        known = runq_t is not None and self._runq is not None
+        if known:
+            runq = max(0.0, (runq_t - self._runq - self._in_runq) * 1e-9)
+        # the clocks are not read at one instant: what the walls and the
+        # delay overshoot the span by comes off them, the delay first
+        over = max(0.0, wait + idle + runq - span)
+        cut = min(runq, over)
+        runq, over = runq - cut, over - cut
+        cut = min(wait, over)
+        wait, idle = wait - cut, idle - (over - cut)
+        # what the CPU clock read since the last take and what it still
+        # owes, as far as the span has room; the rest is owed on
+        read = max(0.0, cpu_t - self._cpu - self._in_cpu) + self._cpu_owed
+        cpu = min(read, max(0.0, span - wait - idle - runq))
+        self._cpu_owed = min(read - cpu, OWED_MAX_S)
+        blocked = max(0.0, span - wait - idle - runq - cpu)
+        proc = proc_t - self._proc
+        self._t, self._cpu, self._runq, self._proc = now, cpu_t, runq_t, proc_t
+        self._wait_s = self._idle_s = self._in_cpu = 0.0
+        self._in_runq = 0
+        return {"span_ms": span * 1e3, "wait_ms": wait * 1e3,
+                "idle_ms": idle * 1e3, "cpu_ms": cpu * 1e3,
+                "runq_ms": runq * 1e3 if known else None,
+                "blocked_ms": blocked * 1e3, "proc_cpu_ms": proc * 1e3}
+
+    def close(self) -> None:
+        if self._fd is not None:
+            os.close(self._fd)
+            self._fd = None
+
+
 def _default_capacity() -> int:
     try:
         return max(1, int(os.environ.get("LOCALAI_FLIGHT_CAPACITY", "512")))
@@ -85,8 +217,14 @@ class FlightRecorder:
         self._program: list[str] = [""] * n
         # what the launch held, taken when it was enqueued (see record())
         self._work = np.zeros((n, len(WORK_COLUMNS)), np.int64)
+        self._parts = np.zeros((n, len(PART_COLUMNS)))
+        # NaN in ``runq_ms``: the kernel's run-queue delay could not be read
+        self._clock = np.zeros((n, len(CLOCK_COLUMNS)))
         self._n = 0                # records ever written (ring head = n % cap)
         self.total_tokens = 0      # cumulative, survives wraparound
+        # ms the engine thread spent in each state, over every row ever
+        # written (survives wraparound): localai_engine_thread_seconds_total
+        self.thread_ms_total = dict.fromkeys(THREAD_STATES, 0.0)
 
     # -- hot path (engine thread) -----------------------------------------
 
@@ -104,7 +242,13 @@ class FlightRecorder:
                chunk_tokens: int = 0,
                chunk_bucket: int = 0, chunk_offset: int = 0,
                chunk_ctx: int = 0, chunk_parts: int = 0,
-               experts_touched: int = 0, local_assignments: int = 0) -> None:
+               experts_touched: int = 0, local_assignments: int = 0,
+               process_ms: float = 0.0, book_ms: float = 0.0,
+               free_ms: float = 0.0,
+               span_ms: float = 0.0, wait_ms: float = 0.0,
+               idle_ms: float = 0.0, cpu_ms: float = 0.0,
+               runq_ms: Optional[float] = None, blocked_ms: float = 0.0,
+               proc_cpu_ms: float = 0.0) -> None:
         """Append one dispatch record (host scalars only).
 
         ``batch_slots`` tags the record with the lane mix: how many of the
@@ -154,7 +298,28 @@ class FlightRecorder:
         over the expert blocks and the steps, and token-expert pairs that
         landed on the experts held here. A chunk's row is written at its
         launch, before its counts exist: 0 there (the scheduler's totals
-        have them)."""
+        have them).
+
+        ``process_ms``, ``book_ms`` and ``free_ms`` are measured PARTS of the
+        interval ``gap_ms`` accounts for (token processing; the loop's own
+        bookkeeping; dropping the drained dispatch's device arrays: see
+        :mod:`obs.anatomy`), never more than it together.
+
+        ``span_ms`` and the six columns after it are the engine thread's own
+        clocks for the wall since the previous row's span ended: the spans
+        tile the thread's life. A row's span ends where the interval its
+        ``dispatch_ms`` accounts for ends (a drain's end of wait, a chunk's
+        end of launch: the scheduler reads the clocks there, not at the
+        write), so all of a row's time columns speak of one stretch; it is
+        still NOT ``dispatch_ms``, which for a pipelined row runs from drain
+        to drain over the chunk rows written between. Inside ``sched.wait_device``
+        (``wait_ms``), inside ``sched.idle`` (``idle_ms``), on a CPU outside
+        those two (``cpu_ms``), runnable with no core (``runq_ms``, the
+        kernel's run-queue delay; None where it cannot be read), and asleep
+        on the GIL, a lock or a file (``blocked_ms``, what is left): the five
+        sum to the span. ``proc_cpu_ms`` is the CPU of ALL threads of the
+        process in the span. Writers that read no clocks pass the zero
+        defaults."""
         now = time.monotonic() if ts is None else ts
         with self._lock:
             i = self._n % self.capacity
@@ -183,8 +348,18 @@ class FlightRecorder:
                              chunk_bucket,
                              chunk_offset, chunk_ctx, chunk_parts,
                              experts_touched, local_assignments)
+            self._parts[i] = (process_ms, book_ms, free_ms)
+            self._clock[i] = (span_ms, wait_ms, idle_ms, cpu_ms,
+                              np.nan if runq_ms is None else runq_ms,
+                              blocked_ms, proc_cpu_ms)
             self._n += 1
             self.total_tokens += int(tokens)
+            tot = self.thread_ms_total
+            tot["cpu"] += cpu_ms
+            tot["runq"] += runq_ms or 0.0
+            tot["blocked"] += blocked_ms
+            tot["wait"] += wait_ms
+            tot["idle"] += idle_ms
 
     # -- read side ---------------------------------------------------------
 
@@ -244,6 +419,10 @@ class FlightRecorder:
                 "program": [self._program[i] for i in order],
             }
             work = self._work[order].tolist()
+            # 0.1 us: the five states still sum to the span to a microsecond
+            timed = np.round(np.hstack(
+                [self._parts[order], self._clock[order]]), 4).tolist()
+        timed_keys = PART_COLUMNS + CLOCK_COLUMNS
         out = []
         for j in range(len(cols["ts"])):
             steps = cols["steps"][j]
@@ -274,6 +453,8 @@ class FlightRecorder:
                 "sync_ms": round(cols["sync"][j], 3),
                 "compile": cols["compile"][j],
                 **dict(zip(WORK_COLUMNS, work[j])),
+                **{k: (None if v != v else v)       # NaN: not read
+                   for k, v in zip(timed_keys, timed[j])},
             })
         return out
 
@@ -323,6 +504,12 @@ class FlightRecorder:
         the share of accounted wall time the host spent NOT blocked on
         the device. How idle the DEVICE was is not in these columns: the
         profiler measures it (``POST /backend/trace``).
+
+        The measured parts of gap (process/book/free) get the same
+        quantiles and totals; they lie INSIDE gap and are in no sum here.
+        ``thread`` is the engine thread's own account of the same rows:
+        ``span_ms_total`` and each state's share of it (cpu/runq/blocked/
+        wait/idle; ``runq`` None where no row could read it).
         """
         with self._lock:
             order = self._order()
@@ -336,9 +523,21 @@ class FlightRecorder:
                 "sched": self._sched_ms[rows].copy(),
                 "launch": self._launch_ms[rows].copy(),
                 "sync": self._sync_ms[rows].copy(),
+                **{k[:-3]: self._parts[rows, j].copy()
+                   for j, k in enumerate(PART_COLUMNS)},
             }
             dispatch = self._dispatch_ms[rows].copy()
+            clock = dict(zip(CLOCK_COLUMNS, self._clock[rows].T.copy()))
         out: dict = {"samples": int(len(dispatch))}
+        span = float(clock["span_ms"].sum())
+        out["thread"] = {
+            "span_ms_total": round(span, 3),
+            "share": {
+                st: (None if span <= 0 or (
+                        st == "runq" and np.isnan(clock["runq_ms"]).all())
+                     else round(float(np.nansum(clock[f"{st}_ms"])) / span, 4))
+                for st in THREAD_STATES},
+        }
         if len(dispatch) == 0:
             for ph in (*ph_cols, "host"):
                 out[f"{ph}_ms_p50"] = None

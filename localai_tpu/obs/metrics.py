@@ -344,8 +344,24 @@ class Registry:
             "localai_dispatch_phase_ms",
             "Dispatch-anatomy phase time over the flight ring's recent "
             "window, compile rows excluded (phase label: gap/sched/"
-            "launch/sync, quantile label: p50/p90/p99 — see obs.anatomy "
-            "for phase semantics)",
+            "launch/sync, which tile a dispatch, and process/book/free, "
+            "measured parts that lie INSIDE gap; quantile label: "
+            "p50/p90/p99 — see obs.anatomy for phase semantics)",
+        )
+        self.engine_thread_seconds = Counter(
+            "localai_engine_thread_seconds_total",
+            "Seconds of the engine thread's life by state, from the "
+            "flight ring's per-row thread clocks (state label: cpu = on "
+            "a core, runq = runnable with no core: a noisy host, blocked "
+            "= asleep on the GIL/a lock/a file: a starved engine thread, "
+            "wait = inside sched.wait_device, idle = no work)",
+        )
+        self.slow_dispatch = Counter(
+            "localai_slow_dispatch_total",
+            "Non-compile decode rows whose engine-thread wall (idle left "
+            "out) was over 4x their steps' time by the step EMA and at "
+            "least 50 ms more than it, by the state that owned most of "
+            "the row (owner label: wait/cpu/runq/blocked)",
         )
         self.host_overhead_fraction = Gauge(
             "localai_host_overhead_fraction",
@@ -831,6 +847,10 @@ def update_engine_gauges(name: str, m: dict,
     v = m.get("host_overhead_fraction")
     if v is not None:
         reg.host_overhead_fraction.set(v, model=name)
+    for state, sec in (m.get("engine_thread_seconds") or {}).items():
+        reg.engine_thread_seconds.set_total(sec, model=name, state=state)
+    for owner, n in (m.get("slow_dispatches") or {}).items():
+        reg.slow_dispatch.set_total(n, model=name, owner=owner)
 
 
 REGISTRY = Registry()

@@ -35,12 +35,30 @@ SLOW_MODULES = {
 }
 
 
+# The tier-1 files that take longest (minutes of one worker, 2026-10), longest
+# first. They are collected FIRST: under ``-n 6 --dist loadfile`` a file goes
+# whole to the next free worker in collection order, and a ten-minute file
+# that starts last (``test_tpu_compile`` by the alphabet) is the run's tail.
+LONGEST_FIRST = (
+    "test_paged", "test_tpu_compile", "test_qwen3_next", "test_afmoe",
+    "test_bench_walk", "test_deepseek", "test_dots3", "test_overlap",
+    "test_kv_contract", "test_chip_smoke", "test_ouro", "test_spec",
+    "test_dots3_compile", "test_prefill_span",
+)
+
+
 def pytest_collection_modifyitems(config, items):
     import pathlib
 
-    for item in items:
-        if pathlib.Path(str(item.fspath)).stem in SLOW_MODULES:
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    stems = [pathlib.Path(str(item.fspath)).stem for item in items]
+    for item, stem in zip(items, stems):
+        if stem in SLOW_MODULES:
             item.add_marker(pytest.mark.slow)
+    # stable: a file's tests keep their order, the other files the alphabet's
+    order = sorted(range(len(items)),
+                   key=lambda i: rank.get(stems[i], len(rank)))
+    items[:] = [items[i] for i in order]
 
 
 @pytest.fixture()

@@ -605,8 +605,7 @@ def test_debug_devices_reports_health_and_census(client):
     assert census["by_category"]["kv_cache"] > 0
     assert data["probe"]["ok"] is True
     assert data["probe"]["seconds"] > 0
-    # the CPU test mesh is not in the peak table: no invented peak
-    assert data["roofline"]["peak_gbps"] is None
+    assert "roofline" not in data
     assert isinstance(data["watchdog"], dict)
 
 
@@ -618,8 +617,8 @@ def test_debug_devices_probe_skippable(client):
     ).status_code == 400
 
 
-def test_debug_programs_reports_cost_and_roofline_fraction(client):
-    # make sure the decode program has dispatched + has a latency sample
+def test_debug_programs_reports_cost(client):
+    # make sure the decode program has dispatched
     r = client.post("/v1/chat/completions", json={
         "model": "tiny",
         "messages": [{"role": "user", "content": "cost catalog"}],
@@ -627,19 +626,18 @@ def test_debug_programs_reports_cost_and_roofline_fraction(client):
     })
     assert r.status_code == 200
     data = client.get("/debug/programs").json()
-    assert data["roofline"]["source"] == "unknown"
+    assert "roofline" not in data
     programs = data["programs"]
     assert programs
     decode = [p for p in programs
               if p["program"].startswith("decode") and p.get("flops")]
     assert decode, f"no decode cost entry in {programs}"
     d = decode[0]
-    # nonzero FLOPs/bytes and an achieved rate for the decode-step
-    # program; no roofline FRACTION on the CPU test mesh — it has no peak
+    # nonzero FLOPs/bytes for the decode-step program, and no rate or
+    # fraction: a dispatch's wall is a host clock, not a device share
     assert d["flops"] > 0 and d["bytes_accessed"] > 0
-    rated = [p for p in decode if p.get("achieved_gbps") is not None]
-    assert rated, "no decode entry joined with a measured latency"
-    assert all("bandwidth_fraction" not in p for p in decode)
+    assert all("achieved_gbps" not in p and "bandwidth_fraction" not in p
+               for p in decode)
     # filter to live instances: the backend-shutdown test earlier in this
     # module unloads/reloads the model, leaving dead catalog entries
     # (cost_error="program no longer live") next to the live ones.
@@ -753,6 +751,53 @@ def test_debug_flight_reports_dispatch_records(client):
                       params={"since": "soon"}).status_code == 400
     assert client.get("/debug/flight",
                       params={"limit": "many"}).status_code == 400
+
+
+def test_the_endpoints_serve_a_rows_own_account(client):
+    """PR 53: /debug/flight rows carry the measured parts of gap and the
+    engine thread's clocks; /debug/anatomy the two parts (inside gap: in no
+    sum) and the ``thread`` block; /metrics the two phase labels and the two
+    counter families."""
+    r = client.post("/v1/chat/completions", json={
+        "model": "tiny",
+        "messages": [{"role": "user", "content": "account for the wall"}],
+        "max_tokens": 24,
+    })
+    assert r.status_code == 200
+    rows = client.get("/debug/flight").json()["models"]["tiny"]["records"]
+    timed = ("process_ms", "book_ms", "free_ms", "span_ms", "wait_ms", "idle_ms",
+             "cpu_ms", "runq_ms", "blocked_ms", "proc_cpu_ms")
+    assert all(set(timed) <= set(row) for row in rows)
+    row = next(r for r in reversed(rows) if r["program"].startswith("decode"))
+    assert row["span_ms"] > 0
+    assert (row["wait_ms"] + row["idle_ms"] + row["cpu_ms"]
+            + (row["runq_ms"] or 0.0) + row["blocked_ms"]) == pytest.approx(
+        row["span_ms"], abs=1e-3)
+    data = client.get("/debug/anatomy", params={"window": 0}).json()
+    assert data["phases"] == ["gap", "sched", "launch", "sync"]
+    assert data["parts"] == ["process", "book", "free"]
+    tiny = data["models"]["tiny"]
+    assert set(tiny["part_share"]) == {"process", "book", "free"}
+    assert {"process", "book", "free"} <= set(tiny["definitions"])
+    assert tiny["process_ms_p50"] is not None
+    assert sum(tiny["part_share"].values()) <= (
+        tiny["phase_share"]["gap"] + 1e-3)
+    assert set(tiny["thread"]["share"]) == {"cpu", "runq", "blocked", "wait",
+                                            "idle"}
+    assert tiny["thread"]["span_ms_total"] > 0
+    text = client.get("/metrics").text
+    for series in (
+            'localai_dispatch_phase_ms{model="tiny",phase="process",'
+            'quantile="p50"}',
+            'localai_dispatch_phase_ms{model="tiny",phase="book",'
+            'quantile="p90"}',
+            'localai_dispatch_phase_ms{model="tiny",phase="free",'
+            'quantile="p99"}',
+            'localai_engine_thread_seconds_total{model="tiny",state="cpu"}',
+            'localai_engine_thread_seconds_total{model="tiny",state="idle"}',
+            'localai_slow_dispatch_total{model="tiny",owner="wait"}',
+            'localai_slow_dispatch_total{model="tiny",owner="blocked"}'):
+        assert series in text, series
 
 
 def test_trace_detail_stitched_waterfall(client):

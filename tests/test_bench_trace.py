@@ -324,6 +324,171 @@ def test_a_nested_launch_changes_no_phase_reading(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# a ring row accounts for its own wall (PR 53): the host's share by measured
+# parts, and who owned the engine thread's time (harness/hostclock.py)
+
+HOST_PARTS = ("launch", "admit", "process", "book", "free", "unnamed")
+PR53 = ([f"sched.host_share.{p}" for p in HOST_PARTS]
+        + ["sched.chunk_stage_ms_p50", "sched.thread_offcpu_share",
+           "sched.worst_row_ms", "sched.worst_row_wait_share",
+           "sched.worst_row_cpu_share", "sched.annotated_share"])
+
+
+def timed_row(launch, program, at_s, ms, **cols) -> dict:
+    """A ring row of a program that accounts for its wall: ``ms`` is
+    (dispatch, gap, sched, launch, sync), ``cols`` the parts of gap and the
+    thread's clocks; what is not given is 0 (``runq_ms`` too)."""
+    row = ring_row(launch, program, at_s)
+    row.update(zip(("dispatch_ms", "gap_ms", "sched_ms", "launch_ms",
+                    "sync_ms"), ms))
+    row.update({c: 0.0 for c in (
+        "process_ms", "book_ms", "free_ms", "span_ms", "wait_ms", "idle_ms", "cpu_ms",
+        "runq_ms", "blocked_ms", "proc_cpu_ms")})
+    row.update(cols)
+    return row
+
+
+CLOCKED = [
+    # before the window, and a compile-bearing row inside it: left out
+    timed_row(1, "decode", -1.0, (900.0, 800.0, 0, 0, 100.0),
+              process_ms=700.0, span_ms=900.0, cpu_ms=900.0),
+    {**timed_row(2, "decode", 1.0, (5000.0, 4000.0, 0, 0, 1000.0),
+                 span_ms=5000.0, cpu_ms=5000.0), "compile": True},
+    timed_row(3, "decode", 2.0, (10.0, 4.0, 0.5, 1.5, 4.0), process_ms=2.0,
+              book_ms=1.0, free_ms=0.75, span_ms=10.0, wait_ms=4.0, cpu_ms=5.0,
+              runq_ms=0.25, blocked_ms=0.75),
+    # a chunk: no gap, staging 0.75 ms of its 3 ms
+    timed_row(4, "prefill_chunk", 2.1, (3.0, 0.0, 0.75, 2.25, 0.0),
+              span_ms=3.0, cpu_ms=3.0),
+    # the stall: 2.4 s asleep in the tokens' processing, after 50 ms idle
+    timed_row(5, "decode", 3.0, (2460.0, 2450.0, 1.0, 2.0, 5.0),
+              process_ms=2401.0, book_ms=1.0, free_ms=8.0, span_ms=2510.0, wait_ms=5.0,
+              idle_ms=50.0, cpu_ms=30.0, runq_ms=25.0, blocked_ms=2400.0),
+    timed_row(6, "prefill_chunk", 3.1, (5.0, 0.0, 1.25, 3.75, 0.0),
+              span_ms=5.0, cpu_ms=4.0, blocked_ms=1.0),
+]
+
+
+def clocked_ctx(rows=CLOCKED) -> dict:
+    return {"traced": {"flight": rows}, "anchor": (1000.0, 0.0),
+            "window": mtr.Window(0.0, 10.0, 12.0), "trace": None}
+
+
+def test_the_six_parts_sum_to_the_hosts_share():
+    """Over the SAME rows and the same denominator as ``sched.host_share``:
+    launch + admit + process + book + free + unnamed is that reader's
+    number."""
+    ctx = clocked_ctx()
+    parts = {p: spec.load_reader(f"sched.host_share.{p}", ROOT)(ctx)
+             for p in HOST_PARTS}
+    wall = 10.0 + 3.0 + 2460.0 + 5.0
+    assert parts == pytest.approx({
+        "launch": 100 * (1.5 + 2.25 + 2.0 + 3.75) / wall,
+        "admit": 100 * (0.5 + 0.75 + 1.0 + 1.25) / wall,
+        "process": 100 * (2.0 + 2401.0) / wall,
+        "book": 100 * (1.0 + 1.0) / wall,
+        "free": 100 * (0.75 + 8.0) / wall,
+        "unnamed": 100 * (0.25 + 40.0) / wall})
+    assert sum(parts.values()) == pytest.approx(
+        spec.load_reader("sched.host_share", ROOT)(ctx), abs=1e-9)
+
+
+@pytest.mark.parametrize("metric, value", [
+    ("sched.worst_row_ms", 2460.0),         # row 5: its span less its idle
+    ("sched.worst_row_wait_share", 100 * 5.0 / 2460.0),
+    ("sched.worst_row_cpu_share", 100 * 30.0 / 2460.0),
+    # (runq + blocked) over (span - wait - idle), all four rows
+    ("sched.thread_offcpu_share",
+     100 * (0.25 + 0.75 + 25.0 + 2400.0 + 1.0) / (6.0 + 3.0 + 2455.0 + 5.0)),
+    # the chunk rows' sched_ms alone: 0.75 and 1.25, not a decode row's
+    ("sched.chunk_stage_ms_p50", 1.0),
+])
+def test_the_thread_readers_read_the_windows_rows(metric, value):
+    read = spec.load_reader(metric, ROOT)
+    assert read(clocked_ctx()) == pytest.approx(value, rel=1e-12)
+
+
+def test_the_pr53_readers_answer_none_where_there_is_nothing_to_read():
+    """The parent's ring (no part, no clock), an empty window: None and no
+    error; the chunk's staging is in the parent's ring already. A kernel
+    whose file cannot be read (``runq_ms`` null in every row: the machine
+    the benchmark runs on, so no reader reads that column alone) takes
+    nothing from the others."""
+    old = clocked_ctx([{k: v for k, v in r.items() if k in (
+        "launch", "program", "compile", "steps", "ts_unix", "dispatch_ms",
+        "gap_ms", "sched_ms", "launch_ms", "sync_ms")} for r in CLOCKED])
+    for metric in PR53:
+        read = spec.load_reader(metric, ROOT)
+        assert read(clocked_ctx([])) is None, metric
+        if metric == "sched.chunk_stage_ms_p50":
+            assert read(old) == pytest.approx(1.0)
+        else:
+            assert read(old) is None, metric
+    blind = clocked_ctx([{**r, "runq_ms": None} for r in CLOCKED])
+    assert spec.load_reader("sched.thread_offcpu_share", ROOT)(
+        blind) == pytest.approx(100 * (0.75 + 2400.0 + 1.0) / 2469.0)
+    assert spec.load_reader("sched.worst_row_ms", ROOT)(blind) == 2460.0
+
+
+def test_every_pr53_entry_has_its_reader_and_the_files_kinds():
+    entries = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]
+    by_name = {m["name"]: m for m in entries}
+    before = [m for m in entries if m["name"] not in PR53]
+    assert [m["name"] for m in entries[len(before):]] == PR53  # at the end
+    for name in PR53:
+        m = by_name[name]
+        assert (BENCH / "layers" / f"{name}.py").exists()
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves"}          # no list: every cell reports it
+        assert m["layer"] == "scheduler" and m["moves"] == "tpot_ms_p90"
+        assert m["unit"] in {b["unit"] for b in before}
+        assert m["source"] in {b["source"] for b in before}
+        assert m["better"] == ("higher" if name == "sched.annotated_share"
+                               else "lower")
+
+
+def phase_trace(tmp_path, engine) -> dict:
+    """A one-chip trace with device operations over [100,1100) ns and the
+    given engine-thread annotations [(name, start, ns)]."""
+    path = tmp_path / "p.xplane.pb"
+    path.write_bytes(xspace.space([
+        xspace.plane("/device:TPU:0", {
+            "XLA Ops": [(HLO["mlp"], 100, 100), (HLO["mlp"], 1000, 100)],
+            "XLA Modules": [(f"jit__decode_paged_fn({FP})", 100, 100),
+                            (f"jit__decode_paged_fn({FP})", 1000, 100)]}),
+        xspace.plane("/host:CPU", {"engine-tiny/71": sorted(
+            engine, key=lambda ev: (ev[1], -ev[2]))}),
+        xspace.plane("Task Environment", {}, {
+            "profile_start_time": T0, "profile_stop_time": T0 + 2000})]))
+    return tr.reduce(path)
+
+
+def test_the_annotated_share_is_100_on_a_tiled_slice_and_less_with_a_hole(
+        tmp_path):
+    """The slice is [100,1100) ns. Tiled by phases (a launch nested in one,
+    an idle wait of 200 ns that is no part of the busy loop, annotations
+    that begin before the slice and end after it): 100. The same with
+    ``sched.record`` [600,660) taken out: 740 of 800 busy ns. A program that
+    annotates nothing, or no trace: None."""
+    read = spec.load_reader("sched.annotated_share", ROOT)
+    tiled = [("sched.admit", 50, 150), ("sched.count", 200, 50),
+             ("sched.decode_launch", 250, 100), ("sched.launch/7", 260, 80),
+             ("sched.wait_device", 350, 150), ("sched.process", 500, 100),
+             ("sched.record", 600, 60), ("sched.free", 660, 40),
+             ("sched.idle", 700, 200),
+             ("sched.admit", 900, 50), ("sched.prefill_chunk", 950, 400)]
+    assert read(tiny_ctx(phase_trace(tmp_path, tiled))) == pytest.approx(100.0)
+    holed = [ev for ev in tiled if ev[0] != "sched.record"]
+    assert read(tiny_ctx(phase_trace(tmp_path, holed))) == pytest.approx(
+        100 * 740 / 800)
+    bare = tr.reduce(named_trace(tmp_path, host=False, scopes=False))
+    assert read(tiny_ctx(bare)) is None and read(tiny_ctx(None)) is None
+    # the parent's annotations (PR 25's six and the launches): a number
+    parent = read(tiny_ctx(tr.reduce(named_trace(tmp_path, launches=LAUNCHES))))
+    assert 0.0 < parent < 100.0
+
+
+# ---------------------------------------------------------------------------
 # --trace 2, end to end on the CPU
 
 
@@ -379,8 +544,12 @@ def test_trace_2_scores_first_and_asks_the_server_afterwards(
     for name in ("sched.host_share", "runner.occupancy_mean",
                  "runner.compiles_in_window", "device.idle_share",
                  "runner.kv_move_share", "sched.process_ms_p90",
-                 "sched.device_idle_share"):
+                 "sched.device_idle_share", *PR53):
         assert name in out["metrics"], name
+    parts = [out["metrics"][f"sched.host_share.{p}"]["value"]
+             for p in HOST_PARTS]
+    assert sum(parts) == pytest.approx(
+        out["metrics"]["sched.host_share"]["value"], abs=1e-6)
     # PR 39's four: the decode share in every cell, the prefill three where
     # the cell reports TTFT and in no other
     assert "model.decode_bw_share.counted" in out["metrics"]

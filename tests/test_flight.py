@@ -264,10 +264,194 @@ def test_anatomy_breakdown_shares_and_quantiles():
     assert b["unattributed_ms_total"] == pytest.approx(10.0)
     assert b["unattributed_share"] == pytest.approx(0.5)
     q = anatomy.phase_quantiles(anatomy.summarize(fl, window_s=None))
-    assert set(q) == set(anatomy.PHASES)
+    assert set(q) == set(anatomy.PHASES + anatomy.PARTS)
     assert set(q["gap"]) == {"p50", "p90", "p99"}
     assert q["launch"]["p99"] == pytest.approx(
         np.percentile([3.0, 0.0], 99), abs=1e-3)
+
+
+# the measured parts of gap and the engine thread's clocks (PR 53): what a
+# row that gives them reads back, in ms
+TIMED = {"process_ms": 1.25, "book_ms": 0.5, "free_ms": 0.375, "span_ms": 10.0, "wait_ms": 6.0,
+         "idle_ms": 0.75, "cpu_ms": 2.0, "runq_ms": 0.125, "blocked_ms": 1.125,
+         "proc_cpu_ms": 7.5}
+
+
+@pytest.mark.parametrize("column", sorted(TIMED))
+def test_the_parts_and_the_thread_clocks_round_trip(column):
+    """Each column goes in by record()'s keyword and comes out of snapshot()
+    under the same name; a row that gave none reads 0, and ``runq_ms`` null
+    (the kernel's file was not read: not the same as no delay); the columns
+    survive the wrap and a page forward."""
+    from localai_tpu.obs.flight import CLOCK_COLUMNS, PART_COLUMNS
+
+    assert set(PART_COLUMNS + CLOCK_COLUMNS) == set(TIMED)
+    fl = FlightRecorder(4)
+    _rec(fl, 0)
+    fl.record(program="decode", steps=1, dispatch_ms=10.0, occupancy=0.5,
+              queue_depth=1, kv_utilization=0.25, tokens=1, gap_ms=3.0,
+              **{column: TIMED[column]})
+    bare, row = fl.snapshot()
+    assert row[column] == TIMED[column]
+    default = {c: (None if c == "runq_ms" else 0.0) for c in TIMED}
+    assert {c: bare[c] for c in TIMED} == default
+    assert {c: row[c] for c in TIMED if c != column} == {
+        c: v for c, v in default.items() if c != column}
+    for i in range(1, 10):          # past the wrap, every row with its own
+        fl.record(program="decode", steps=1, dispatch_ms=10.0, occupancy=0.5,
+                  queue_depth=1, kv_utilization=0.25, tokens=1,
+                  **{column: TIMED[column] * i})
+    snap = fl.snapshot()
+    assert [r[column] for r in snap] == [TIMED[column] * i
+                                         for i in (6, 7, 8, 9)]
+    page = fl.snapshot(since=snap[1]["ts"], limit=1)
+    assert [r[column] for r in page] == [TIMED[column] * 8]
+
+
+def _timed(fl, **cols):
+    fl.record(program="decode", steps=1, dispatch_ms=10.0, occupancy=1.0,
+              queue_depth=0, kv_utilization=0.5, tokens=1, **cols)
+
+
+def test_phases_report_the_parts_of_gap_and_the_thread_block():
+    """``process``, ``book`` and ``free`` get quantiles and totals like the
+    four phases, and are in no sum (they lie INSIDE gap: ``attributed`` counts
+    gap once); ``thread`` gives each state's share of the window's span,
+    ``runq`` None where no row could read it; compile rows are left out."""
+    from localai_tpu.obs import anatomy
+
+    fl = FlightRecorder(16)
+    _timed(fl, gap_ms=3.0, sync_ms=7.0, process_ms=1.0, book_ms=0.5,
+           free_ms=0.25, span_ms=10.0, wait_ms=7.0, cpu_ms=2.0, blocked_ms=1.0)
+    _timed(fl, gap_ms=5.0, sync_ms=5.0, process_ms=3.0, book_ms=1.5,
+           span_ms=30.0, wait_ms=5.0, idle_ms=20.0, cpu_ms=4.0,
+           blocked_ms=1.0)
+    _timed(fl, compile=True, gap_ms=9.0, process_ms=9.0, span_ms=1e3,
+           cpu_ms=1e3)
+    ph = fl.phases()
+    assert ph["samples"] == 2
+    assert ph["process_ms_total"] == 4.0 and ph["book_ms_total"] == 2.0
+    assert ph["free_ms_total"] == 0.25 and ph["free_ms_p50"] == 0.125
+    assert ph["process_ms_p50"] == pytest.approx(2.0)
+    assert ph["book_ms_p99"] == pytest.approx(np.percentile([0.5, 1.5], 99))
+    assert ph["host_ms_total"] == 8.0           # gap alone: parts not added
+    assert ph["thread"] == {"span_ms_total": 40.0, "share": {
+        "cpu": 0.15, "runq": None, "blocked": 0.05, "wait": 0.3,
+        "idle": 0.5}}
+    b = anatomy.breakdown(fl, window_s=None)
+    assert b["part_share"] == {"process": 0.2, "book": 0.1, "free": 0.0125}
+    assert sum(b["phase_share"].values()) == pytest.approx(1.0)
+    assert b["unattributed_ms_total"] == 0.0
+    assert b["thread"] == ph["thread"]
+    assert set(anatomy.PARTS) <= set(b["definitions"])
+    # a row that read the kernel's file gives runq a share; the ring's
+    # totals (localai_engine_thread_seconds_total) hold every row, compile
+    # rows too, and survive the wrap
+    _timed(fl, span_ms=10.0, cpu_ms=6.0, runq_ms=4.0)
+    assert fl.phases()["thread"]["share"]["runq"] == pytest.approx(0.08)
+    assert fl.thread_ms_total == {"cpu": 1012.0, "runq": 4.0, "blocked": 2.0,
+                                  "wait": 12.0, "idle": 20.0}
+    empty = FlightRecorder(4).phases()
+    assert empty["process_ms_p50"] is None and empty["book_ms_total"] == 0.0
+    assert set(empty["thread"]["share"].values()) == {None}
+
+
+def test_a_thread_clock_tiles_its_span(tmp_path, monkeypatch):
+    """wait + idle + cpu + runq + blocked = span for every take: a wait's
+    wall is the wait's, a sleep outside one is ``blocked``, a busy loop is
+    ``cpu``; the CPU burnt inside a wait is not ``cpu``'s; a file that is
+    not there gives ``runq_ms`` None and the identity still holds."""
+    import time
+
+    from localai_tpu.obs import flight
+    from localai_tpu.obs.flight import CLOCK_COLUMNS, ThreadClock
+
+    def states(row):
+        return (row["wait_ms"] + row["idle_ms"] + row["cpu_ms"]
+                + (row["runq_ms"] or 0.0) + row["blocked_ms"])
+
+    def burn(cpu_s):        # the thread's own CPU: a loaded machine's wall
+        t0 = time.thread_time()                 # gives a loop less
+        while time.thread_time() - t0 < cpu_s:
+            pass
+
+    for path, readable in ((flight.SCHEDSTAT, True),
+                           (str(tmp_path / "absent"), False)):
+        monkeypatch.setattr(flight, "SCHEDSTAT", path)
+        clock = ThreadClock()
+        cpu_start = time.thread_time()
+        mark, t0 = clock.enter(), time.monotonic()
+        burn(0.01)                              # burnt INSIDE a wait
+        clock.leave(mark, time.monotonic() - t0)
+        mark, t0 = clock.enter(), time.monotonic()
+        time.sleep(0.005)
+        clock.leave(mark, time.monotonic() - t0, idle=True)
+        time.sleep(0.015)                       # asleep outside a wait
+        burn(0.01)                              # computing outside one
+        row = clock.take(time.monotonic())
+        cpu_all = (time.thread_time() - cpu_start) * 1e3
+        assert tuple(row) == CLOCK_COLUMNS
+        assert states(row) == pytest.approx(row["span_ms"], abs=1e-6)
+        assert (row["runq_ms"] is not None) == readable
+        assert row["wait_ms"] >= 10.0 and row["idle_ms"] >= 5.0
+        # the burn outside the wait is ``cpu``; the wait's own 10 ms is not
+        assert row["cpu_ms"] >= 10.0 and cpu_all - row["cpu_ms"] >= 9.9
+        assert row["blocked_ms"] + (row["runq_ms"] or 0.0) >= 12.0
+        assert row["proc_cpu_ms"] >= row["cpu_ms"]
+        # the next take starts where this one ended: no wall is carried
+        again = clock.take(time.monotonic())
+        assert again["wait_ms"] == again["idle_ms"] == 0.0
+        assert again["span_ms"] < 5.0
+        assert states(again) == pytest.approx(again["span_ms"], abs=1e-6)
+        clock.close()
+        clock.close()                           # twice is fine
+        assert clock.take(time.monotonic())["runq_ms"] is None
+
+
+@pytest.mark.parametrize("tick_ms", [10.0, 4.0, 0.001])
+def test_a_coarse_cpu_clock_is_carried_not_cut(monkeypatch, tick_ms):
+    """A CPU clock that ticks coarser than a row is long (the chip's machine:
+    10 ms under rows of 8) loses nothing: a tick the span has no room for is
+    owed to the next rows, so the window's ``cpu_ms`` sums to the thread's
+    true CPU to a tick, ``blocked`` takes none of it, and every row tiles."""
+    from localai_tpu.obs import flight
+
+    # a scripted thread: rows of 8 ms = 3.6 ms in the wait (asleep), 2.4 ms
+    # on a CPU, 2.0 ms asleep on a lock; its CPU clock reads whole ticks
+    t = {"wall": 100.0, "cpu": 0.0}
+    tick = tick_ms * 1e-3
+    monkeypatch.setattr(flight.time, "monotonic", lambda: t["wall"])
+    monkeypatch.setattr(
+        flight.time, "thread_time", lambda: t["cpu"] // tick * tick)
+    monkeypatch.setattr(
+        flight.time, "process_time", lambda: t["cpu"] // tick * tick)
+    monkeypatch.setattr(flight, "SCHEDSTAT", "/nonexistent/schedstat")
+    clock = flight.ThreadClock()
+    rows = []
+    for _ in range(500):
+        mark = clock.enter()
+        t["wall"] += 3.6e-3
+        clock.leave(mark, 3.6e-3)
+        t["wall"] += 4.4e-3
+        t["cpu"] += 2.4e-3
+        rows.append(clock.take(t["wall"]))
+    for r in rows:
+        assert (r["wait_ms"] + r["idle_ms"] + r["cpu_ms"] + r["blocked_ms"]
+                == pytest.approx(r["span_ms"], abs=1e-6))
+        assert r["cpu_ms"] <= 4.4 + 1e-6        # never into the wait
+    cpu = sum(r["cpu_ms"] for r in rows)
+    assert cpu == pytest.approx(500 * 2.4, abs=tick_ms + 1e-6)
+    assert sum(r["blocked_ms"] for r in rows) == pytest.approx(
+        500 * 2.0, abs=tick_ms + 1e-6)
+    assert sum(r["wait_ms"] for r in rows) == pytest.approx(500 * 3.6)
+    # a clock that reads HIGH (a second of CPU in a row of 8 ms) is owed only
+    # as far as ``OWED_MAX_S``: the rows long after it do not pay for it
+    t["cpu"] += 1.0
+    later = [clock.take(t.__setitem__("wall", t["wall"] + 8e-3) or t["wall"])
+             for _ in range(100)]
+    assert sum(r["cpu_ms"] for r in later) == pytest.approx(
+        8.0 + flight.OWED_MAX_S * 1e3, abs=tick_ms + 1e-6)
+    assert later[-1]["cpu_ms"] == 0.0 and later[-1]["blocked_ms"] > 7.9
 
 
 # -- SLO observatory ---------------------------------------------------------

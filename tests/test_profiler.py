@@ -379,8 +379,9 @@ def test_a_capture_holds_the_schedulers_phases(paged_runner, tmp_path):
                for p in host for ln in p.lines}
     phases = {n for names in by_line.values() for n in names
               if n.startswith("sched.")}
-    assert {"sched.admit", "sched.decode_launch", "sched.wait_device",
-            "sched.process"} <= phases
+    assert {"sched.admit", "sched.count", "sched.decode_launch",
+            "sched.wait_device", "sched.process", "sched.record",
+            "sched.free"} <= phases
     # one thread wrote them all: the engine thread
     assert sum(1 for names in by_line.values()
                if any(n.startswith("sched.") for n in names)) == 1
@@ -449,6 +450,28 @@ def test_a_capture_holds_every_launch_under_its_phase(paged_runner, tmp_path):
     assert "decode" in seen and seen <= {"decode", "prefill_chunk"}
     ns = [int(ev[2].rsplit("/", 1)[1]) for ev in launches]
     assert ns == sorted(set(ns))
+    # PR 53: the loop's two bookkeeping stretches and the drop of a drained
+    # dispatch's result are phases like the six, top-level (no phase lies
+    # inside another: only a launch is nested), a ``sched.count`` directly
+    # in front of each decode launch's phase, a ``sched.record`` behind each
+    # wait (the clocks' reading) and behind each ``sched.process`` of a
+    # decode dispatch (the row's write), a ``sched.free`` behind that (and
+    # behind a first token's ``sched.process``)
+    for i, (lo, hi, name) in enumerate(phases):
+        assert not any(p[0] <= lo and hi <= p[1]
+                       for j, p in enumerate(phases) if j != i), name
+    order = [name for _, _, name in phases]
+    assert {"sched.count", "sched.record", "sched.free"} <= set(order)
+    for i, name in enumerate(order[1:], 1):
+        if name == "sched.decode_launch":
+            assert order[i - 1] == "sched.count"
+        if name == "sched.record":
+            assert order[i - 1] in ("sched.wait_device", "sched.process")
+        if name == "sched.process":
+            assert order[i - 1] == "sched.record"
+        if name == "sched.free":
+            assert order[i - 1] in ("sched.record", "sched.process")
+    assert order.count("sched.record") <= 2 * order.count("sched.process")
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

@@ -1135,3 +1135,299 @@ def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
         assert c["chunk_bucket"] == r.bucket_for(c["chunk_tokens"])
         assert c["chunk_ctx"] == r.ctx_pad == 96
         assert c["live_slots"] == c["attended_tokens"] == 0
+
+
+# ---------------------------------------------------------------------------
+# a ring row accounts for its own wall (PR 53): the measured parts of gap,
+# and the engine thread's own clocks
+
+
+def _states(r) -> float:
+    return (r["wait_ms"] + r["idle_ms"] + r["cpu_ms"] + (r["runq_ms"] or 0.0)
+            + r["blocked_ms"])
+
+
+def _served_rows(s: Scheduler, text: str, n: int = 12) -> list[dict]:
+    """The ring rows of one request served beside a keeper."""
+    mark = s._launch_seq
+    keeper = _keeper(s)
+    h = s.generate(_req(text, max_new_tokens=n, stream=True, ignore_eos=True,
+                        **GREEDY), timeout=120)
+    assert h.finish_reason == "length"
+    keeper.cancel()
+    keeper.result(60)
+    assert _wait(lambda: not s.busy)
+    return [r for r in s.flight.snapshot() if r["launch"] > mark]
+
+
+def test_every_row_of_a_served_request_accounts_for_its_wall(paged):
+    """``process_ms + book_ms + free_ms <= gap_ms`` and ``wait + idle + cpu + runq +
+    blocked == span`` to a microsecond for every row, chunks and decode
+    steps alike (1e-3 ms: the snapshot's rounding); a decode row measured
+    both parts, and its wait is the drain's own ``sync_ms`` unless a first
+    token was read in its span; a chunk's row leaves the parts to the next
+    decode row and tiles its wall into staging and enqueue."""
+    s, _ = paged
+    rows = _served_rows(s, "a prompt of two chunks, to be staged")
+    kinds = {r["program"] for r in rows}
+    assert "prefill_chunk" in kinds and kinds & {"decode", "decode_n"}
+    for r in rows:
+        assert (r["process_ms"] + r["book_ms"] + r["free_ms"]
+                <= r["gap_ms"] + 1e-3), r
+        assert _states(r) == pytest.approx(r["span_ms"], abs=1e-3), r
+        assert r["span_ms"] > 0.0 and r["proc_cpu_ms"] >= 0.0
+    decode = [r for r in rows if r["program"].startswith("decode")
+              and not r["compile"]]
+    assert len(decode) > 10
+    assert sum(r["process_ms"] > 0 and r["book_ms"] > 0
+               for r in decode) > len(decode) // 2
+    assert sum(abs(r["wait_ms"] - r["sync_ms"]) < 2e-3
+               for r in decode) > len(decode) // 2
+    for c in (r for r in rows if r["program"] == "prefill_chunk"):
+        assert c["process_ms"] == c["book_ms"] == c["free_ms"] == 0.0
+        assert c["gap_ms"] == 0.0
+        assert c["sched_ms"] + c["launch_ms"] == pytest.approx(
+            c["dispatch_ms"], abs=2e-3)
+    m = s.metrics()
+    assert set(m["engine_thread_seconds"]) == {
+        "cpu", "runq", "blocked", "wait", "idle"}
+    assert m["engine_thread_seconds"]["cpu"] > 0
+    assert set(m["dispatch_phase_ms"]) >= {"gap", "process", "book", "free"}
+
+
+class _Tokens:
+    """A launch's result that takes ``wait_s`` of the scripted clock to
+    arrive."""
+
+    def __init__(self, real, clock, wait_s):
+        self.real, self.clock, self.wait_s = real, clock, wait_s
+
+    def copy_to_host_async(self):
+        self.real.copy_to_host_async()
+
+    def __array__(self, *a, **kw):
+        self.clock.t += self.wait_s
+        return np.asarray(self.real)
+
+
+def test_the_old_time_columns_are_the_parents_under_a_scripted_clock(
+        paged, monkeypatch):
+    """``time.monotonic`` scripted: it moves only where this test moves it
+    (an admission 0.5 ms, an enqueue 2 ms, a result 5 ms, the tokens'
+    processing 1 ms), so the number of times the loop READS it changes
+    nothing. A steady pipelined dispatch is then 8.5 ms: sync 5, launch 2,
+    sched 0.5, gap 1.0, and the sample given to ``_observe_host_time`` 3.5
+    ms; the request's first decode row (two launches and the chunk before
+    one record) and its last (drained with no slot left: timed from its
+    issue, over the step before it) read what the parent's clamps give:
+    the same digits as commit 5591afe gives this test's body, run there."""
+    import time as real_time
+
+    from localai_tpu.engine import scheduler as sched_mod
+
+    s, _ = paged
+    assert _wait(lambda: not s.busy)
+
+    class Script:
+        t = 5000.0
+
+        def monotonic(self):
+            return self.t
+
+        def __getattr__(self, name):
+            return getattr(real_time, name)
+
+    clock = Script()
+
+    def moves(real, by, wrap=None):
+        def moved(*a, **kw):
+            out = real(*a, **kw)
+            clock.t += by
+            return wrap(out) if wrap else out
+        return moved
+
+    monkeypatch.setattr(sched_mod, "time", clock)
+    admit = s._admit_pending
+    monkeypatch.setattr(
+        s, "_admit_pending",
+        lambda: (admit(), setattr(clock, "t", clock.t + 0.0005))[0])
+    for name in ("step_async", "step_n_async"):
+        monkeypatch.setattr(s.runner, name, moves(
+            getattr(s.runner, name), 0.002,
+            lambda toks: _Tokens(toks, clock, 0.005)))
+    monkeypatch.setattr(s, "_process_rows", moves(s._process_rows, 0.001))
+    # one step a dispatch: the EMAs the tests before this one left, under a
+    # loaded host, can choose k = 2 for the first dispatches (_arrival_log)
+    monkeypatch.setattr(s, "_effective_steps", lambda pipelined=True: 1)
+    samples = []
+    observe = s._observe_host_time
+    monkeypatch.setattr(s, "_observe_host_time",
+                        lambda v: (samples.append(v), observe(v))[1])
+    mark = s._launch_seq
+    h = s.generate(_req("scripted", max_new_tokens=8, stream=True,
+                        ignore_eos=True, **GREEDY), timeout=120)
+    assert h.finish_reason == "length" and _wait(lambda: not s.busy)
+    # the step that was in flight when the stream ended writes its row too
+    assert _wait(lambda: s.flight.snapshot()[-1]["launch"] == s._launch_seq)
+    rows = [r for r in s.flight.snapshot() if r["launch"] > mark
+            and r["program"] == "decode" and not r["compile"]]
+    old = [tuple(r[c] for c in ("dispatch_ms", "gap_ms", "sched_ms",
+                                "launch_ms", "sync_ms")) for r in rows]
+    first, steady, last = ((7.5, 0.0, 0.0, 2.5, 5.0),
+                           (8.5, 1.0, 0.5, 2.0, 5.0),
+                           (13.5, 8.0, 0.5, 0.0, 5.0))
+    assert len(samples) == len(old)     # one a non-compile decode row
+    host_ms = [round(v * 1e3, 6) for v in samples]
+    if old[0] == first:     # the k = 1 program had run before: no compile
+        assert host_ms[0] == 2.5
+        old, host_ms, rows = old[1:], host_ms[1:], rows[1:]
+    assert len(old) >= 5 and set(old[:-1]) == {steady} and old[-1] == last
+    assert host_ms == [3.5] * (len(old) - 1) + [8.5]
+    # and the new columns beside them: the scripted millisecond of token
+    # processing, measured, inside gap
+    assert {r["process_ms"] for r in rows} == {1.0}
+    assert {r["book_ms"] + r["free_ms"] for r in rows} == {0.0}     # stood still
+
+
+@pytest.mark.parametrize("owner", ["wait", "blocked", "cpu"])
+def test_a_stalled_row_names_its_owner(paged, monkeypatch, owner):
+    """60 ms lost once in a decode dispatch: inside the drain's wait (the
+    device answered late), asleep in the tokens' processing (a lock, the
+    GIL), or computing there (our Python). The ring's worst row says which,
+    ``process_ms`` holds the two that were in ``_process_rows``, and
+    ``localai_slow_dispatch_total{owner}`` counts the row once."""
+    import time
+
+    from localai_tpu import faults
+    from localai_tpu.faults import FaultSpec
+    from localai_tpu.obs.metrics import Registry, update_engine_gauges
+
+    s, _ = paged
+    assert _wait(lambda: not s.busy)
+    before = dict(s.slow_dispatches)
+    keeper = _keeper(s)
+    mark = s.flight.snapshot()[-1]["ts"]
+    done = []
+
+    def lose_60ms(*a, _real=s._process_rows, **kw):
+        if not done:
+            done.append(1)
+            if owner == "blocked":
+                time.sleep(0.06)
+            else:
+                t0 = time.thread_time()
+                while time.thread_time() - t0 < 0.06:
+                    pass
+        return _real(*a, **kw)
+
+    try:
+        if owner == "wait":
+            faults.arm(FaultSpec(site="engine.drain", mode="sleep",
+                                 delay_s=0.06, times=1))
+        else:
+            monkeypatch.setattr(s, "_process_rows", lose_60ms)
+        assert _wait(lambda: any(
+            r["span_ms"] - r["idle_ms"] >= 60.0
+            for r in s.flight.snapshot(since=mark)))
+        # read before the keeper goes: a slot's first release may compile
+        rows = s.flight.snapshot(since=mark)
+        gained = {st: n - before[st] for st, n in s.slow_dispatches.items()}
+    finally:
+        faults.clear()
+        keeper.cancel()
+        keeper.result(60)
+    assert _wait(lambda: not s.busy)
+    worst = max(rows, key=lambda r: r["span_ms"] - r["idle_ms"])
+    assert worst["program"].startswith("decode")
+    assert worst[f"{owner}_ms"] >= 59.0
+    assert worst[f"{owner}_ms"] == max(
+        worst[f"{st}_ms"] or 0.0 for st in ("wait", "cpu", "runq", "blocked"))
+    assert _states(worst) == pytest.approx(worst["span_ms"], abs=1e-3)
+    if owner == "wait":
+        assert worst["sync_ms"] >= 59.0 and worst["process_ms"] < 50.0
+    else:
+        assert worst["process_ms"] >= 50.0 and worst["gap_ms"] >= 59.0
+    # once a row: as many as the rows that lost 50 ms and more to that state
+    # (one; a collection of this process's garbage collector may add its own)
+    states = ("wait", "cpu", "runq", "blocked")
+    lost = [r for r in rows if not r["compile"]
+            and r["program"].startswith("decode")
+            and (r[f"{owner}_ms"] or 0.0) >= 50.0
+            and (r[f"{owner}_ms"] or 0.0) == max(
+                r[f"{st}_ms"] or 0.0 for st in states)]
+    assert gained[owner] == len(lost) >= 1, (gained, lost)
+    reg = Registry()
+    update_engine_gauges("tiny", s.metrics(), registry=reg)
+    text = reg.render()
+    assert (f'localai_slow_dispatch_total{{model="tiny",owner="{owner}"}} '
+            f'{s.slow_dispatches[owner]}\n') in text
+    assert 'localai_engine_thread_seconds_total{model="tiny",state="wait"}' \
+        in text
+
+
+def test_a_synchronous_row_reads_its_device_wait_as_wait(paged, monkeypatch):
+    """A constrained stream decodes through the runner's synchronous step,
+    which waits for the device inside the call: the row's ``wait_ms`` is the
+    runner's own ``last_sync_ms``, as a pipelined row's is its drain, and a
+    device that answers 60 ms late there is a slow dispatch that ``wait``
+    owns, not a starved engine thread."""
+    import time
+
+    s, _ = paged
+    assert _wait(lambda: not s.busy)
+    before, mark = dict(s.slow_dispatches), s._launch_seq
+    step, late = s.runner.step, []
+
+    def slow_device():
+        out = step()
+        if mark + 4 < s._launch_seq and not late:
+            late.append(1)
+            time.sleep(0.06)
+            s.runner.last_sync_ms += 60.0
+        return out
+
+    monkeypatch.setattr(s.runner, "step", slow_device)
+    h = s.generate(_req("one constrained stream", max_new_tokens=12,
+                        constraint=_Band(12), **GREEDY), timeout=120)
+    assert h.completion_tokens == 12 and late and _wait(lambda: not s.busy)
+    rows = [r for r in s.flight.snapshot() if r["launch"] > mark
+            and r["program"] == "decode" and not r["compile"]]
+    assert len(rows) >= 10
+    for r in rows:      # the first row's span holds the first token's read
+        assert r["wait_ms"] >= r["sync_ms"] - 2e-3 and r["sync_ms"] > 0.0, r
+        assert _states(r) == pytest.approx(r["span_ms"], abs=1e-3), r
+    assert sum(abs(r["wait_ms"] - r["sync_ms"]) < 2e-3
+               for r in rows) >= len(rows) - 1
+    worst = max(rows[1:], key=lambda r: r["span_ms"])
+    assert worst["wait_ms"] >= 60.0 > worst["blocked_ms"]
+    gained = {st: n - before[st] for st, n in s.slow_dispatches.items()}
+    assert gained["wait"] >= 1, gained
+
+
+def test_the_thread_clock_is_reopened_by_a_rebuild_and_may_be_unreadable(
+        paged, monkeypatch, tmp_path):
+    """``thread-self`` resolves at ``open``: each engine thread opens its own
+    descriptor at the start of its ``_run`` and closes it at the end, a
+    rebuild's fresh thread among them. Where the kernel's file is not there
+    the rows read ``runq_ms`` null, the five states still sum to the span
+    and nothing raises. LAST in this file: a rebuild empties the pool."""
+    from localai_tpu.obs import flight as obs_flight
+
+    s, _ = paged
+    assert _wait(lambda: not s.busy)
+    first = s._clock
+    assert first._fd is not None
+    assert all(r["runq_ms"] is not None
+               for r in _served_rows(s, "the kernel's file is read", 4))
+    monkeypatch.setattr(obs_flight, "SCHEDSTAT", str(tmp_path / "absent"))
+    s.rebuild()
+    rows = _served_rows(s, "and here it is not there", 4)
+    assert s._clock is not first and first._fd is None     # closed
+    assert s._clock._fd is None
+    assert rows and all(r["runq_ms"] is None for r in rows)
+    for r in rows:
+        assert _states(r) == pytest.approx(r["span_ms"], abs=1e-3)
+    monkeypatch.undo()
+    s.rebuild()
+    assert _served_rows(s, "read again", 4)[-1]["runq_ms"] is not None
+    assert s._clock._fd is not None

@@ -212,35 +212,10 @@ def test_known_arrays_from_runner_shape():
     assert len(known["kv_cache"]) == 2 and len(known["weights"]) == 1
 
 
-def test_roofline_env_override(monkeypatch):
-    monkeypatch.setenv("LOCALAI_PEAK_GBPS", "123.5")
-    monkeypatch.setenv("LOCALAI_PEAK_TFLOPS", "9")
-    rl = obs_device.roofline()
-    assert rl["peak_gbps"] == 123.5 and rl["source"] == "env"
-
-
-def test_roofline_has_no_peak_for_a_device_not_in_the_table(monkeypatch):
-    """The CPU test mesh is not in the table: no peak, so the observatory
-    prints no roofline fraction — it used to divide by an invented
-    25 GB/s / 0.5 TFLOP/s."""
-    monkeypatch.delenv("LOCALAI_PEAK_GBPS", raising=False)
-    monkeypatch.delenv("LOCALAI_PEAK_TFLOPS", raising=False)
-    rl = obs_device.roofline()
-    assert rl == {"peak_gbps": None, "peak_tflops": None,
-                  "source": "unknown", "device_kind": "cpu"}
-
-    class V5e:
-        device_kind = "TPU v5 lite"
-
-    rl = obs_device.roofline(V5e())
-    assert (rl["peak_gbps"], rl["peak_tflops"], rl["source"]) == (
-        819.0, 197.0, "table")
-
-
 # -- program cost catalog ---------------------------------------------------
 
 
-def test_catalog_reports_cost_and_fractions():
+def test_catalog_reports_cost():
     import jax
     import jax.numpy as jnp
 
@@ -251,17 +226,15 @@ def test_catalog_reports_cost_and_fractions():
     x = jnp.ones((16, 16), jnp.float32)
     watched(x, n=2)
     watched(x, n=2)
-    obs_compile.note_latency("toyprog", 0.004, steps=2)
-    rep = obs_compile.CATALOG.report(
-        roofline={"peak_gbps": 100.0, "peak_tflops": 1.0})
+    rep = obs_compile.CATALOG.report()
     rows = [r for r in rep if r["program"] == "toyprog"]
     assert rows, "watched program missing from the catalog"
     row = rows[0]
     assert row["dispatches"] == 2
     assert row["flops"] > 0 and row["bytes_accessed"] > 0
-    assert row["dispatch_seconds_ema"] == pytest.approx(0.004)
-    assert row["achieved_gbps"] > 0
-    assert 0 <= row["bandwidth_fraction"] <= 1
+    # XLA's account alone: no dispatch's wall is set against it
+    assert not {"dispatch_seconds_ema", "achieved_gbps", "achieved_gflops",
+                "bandwidth_fraction", "flops_fraction"} & set(row)
 
 
 def test_catalog_survives_dead_program():

@@ -69,7 +69,9 @@ generations through the continuous-batching scheduler, then:
  11. asserts the round-19 dispatch anatomy: extra ``tools.loadgen``
      traffic through the smoke engine leaves every flight-ring record
      with gap/sched/launch/sync phases summing within its
-     ``dispatch_ms`` (the interval-tiling invariant), the derived
+     ``dispatch_ms`` (the interval-tiling invariant), its measured
+     ``process`` + ``book`` + ``free`` parts within its ``gap_ms`` and its thread
+     states summing to its ``span_ms``, the derived
      host-overhead fraction in (0, 1), the
      ``localai_dispatch_phase_ms`` / ``localai_host_overhead_fraction``
      series rendering, and the client-observed TTFT p95 agreeing with the server-side histogram;
@@ -215,7 +217,12 @@ REQUIRED_ANATOMY = (
     'localai_dispatch_phase_ms{model="smoke",phase="sched",quantile="p50"}',
     'localai_dispatch_phase_ms{model="smoke",phase="launch",quantile="p50"}',
     'localai_dispatch_phase_ms{model="smoke",phase="sync",quantile="p99"}',
+    'localai_dispatch_phase_ms{model="smoke",phase="process",quantile="p50"}',
+    'localai_dispatch_phase_ms{model="smoke",phase="book",quantile="p50"}',
+    'localai_dispatch_phase_ms{model="smoke",phase="free",quantile="p50"}',
     'localai_host_overhead_fraction{model="smoke"}',
+    'localai_engine_thread_seconds_total{model="smoke",state="cpu"}',
+    'localai_slow_dispatch_total{model="smoke",owner="blocked"}',
 )
 # elastic-capacity series (round 20): the autoscaled fleet must record a
 # spike-driven scale-out, the quiesce-driven scale-to-zero, the cold
@@ -900,6 +907,27 @@ def check_anatomy(sched, tok, registry, anatomy_out: str) -> list[str]:
                 f"dispatch_ms {r['dispatch_ms']:.3f} "
                 f"(program={r['program']})")
             break
+    # the two measured parts lie inside gap, and the engine thread's five
+    # states tile the row's span (2e-3 slack: the snapshot's rounding)
+    for r in decode_rows:
+        if (r["process_ms"] + r["book_ms"] + r["free_ms"]
+                > r["gap_ms"] + 2e-3):
+            problems.append(
+                f"anatomy: process {r['process_ms']} + book "
+                f"{r['book_ms']} + free {r['free_ms']} ms exceed gap_ms "
+                f"{r['gap_ms']} "
+                f"(program={r['program']})")
+            break
+        states = (r["wait_ms"] + r["idle_ms"] + r["cpu_ms"]
+                  + (r["runq_ms"] or 0.0) + r["blocked_ms"])
+        if abs(states - r["span_ms"]) > 2e-3:
+            problems.append(
+                f"anatomy: the thread's states sum to {states:.4f} ms of "
+                f"a span of {r['span_ms']} (program={r['program']})")
+            break
+    if decode_rows and not any(r["process_ms"] > 0 and r["book_ms"] > 0
+                               for r in decode_rows):
+        problems.append("anatomy: no row measured process and book")
 
     # (b) the derived fraction: a genuine open-interval fraction
     anat = obs_anatomy.summarize(sched.flight, window_s=None)
@@ -909,6 +937,9 @@ def check_anatomy(sched, tok, registry, anatomy_out: str) -> list[str]:
     elif hof is None or not (0.0 < hof < 1.0):
         problems.append(
             f"anatomy: host_overhead_fraction {hof} outside (0, 1)")
+    for part in obs_anatomy.PARTS:
+        if anat.get(f"{part}_ms_p50") is None:
+            problems.append(f"anatomy: summarize() has no {part} quantiles")
 
     # (c) client-vs-server latency cross-check: diff the histogram around
     # the loadgen run (isolating exactly this traffic's server view),
