@@ -62,9 +62,9 @@ SKIP = -1
 # tokens a chunked-prefill dispatch covers unless engine.prefill_chunk says
 # otherwise (never under one KV block)
 PREFILL_CHUNK = 512
-# rows from which a prompt's last chunk on a mesh runs in quarters behind
-# the attend (``ModelRunner.chunk_rows``); the 128 bucket is bound by the
-# weights' bytes and stays whole
+# rows from which a prompt's last chunk runs in quarters behind the attend
+# (``ModelRunner.chunk_rows``), on one chip as on a mesh; the 128 bucket is
+# bound by the weights' bytes and stays whole
 CHUNK_QUARTERED = 512
 
 
@@ -1013,20 +1013,21 @@ class ModelRunner:
         THE ATTEND (the out product, the MLP, their reductions and
         residuals: ``models.llama._behind_attend``), the bucket last; the
         projections, the pool write and the attend always run the bucket.
-        On one device, and under ``CHUNK_QUARTERED`` rows, the bucket alone.
-        Over a mesh's 'model' axis the program of a prompt's LAST chunk (the
-        one that samples) runs as many QUARTERS of a bucket of
-        ``CHUNK_QUARTERED`` rows or more as hold a real token, two at least
-        (the ladder's buckets stand 1 : 4: a chunk with one live quarter
-        took the bucket below). The rows behind them are padding: no row in
-        front attends them and nothing downstream reads them. A branch a row
-        count inside the one program (``models.llama._layer``), so no
-        program is added; a chunk that is not the last holds
-        ``prefill_chunk`` tokens, and where those fill the bucket's last
-        quarter its program stays whole: the branches' way through HBM costs
-        a full chunk ~1 ms of 41 (PERF.md 6, PR 52)."""
-        tp = self.mesh.shape.get("model", 1) if self.mesh is not None else 1
-        if (tp <= 1 or not self.paged or self.routed
+        Under ``CHUNK_QUARTERED`` rows, over the contiguous cache and for a
+        routed model (its family's own forward), the bucket alone.
+        Otherwise, on one chip as over a mesh's 'model' axis, the program of
+        a prompt's LAST chunk (the one that samples) runs as many QUARTERS
+        of a bucket of ``CHUNK_QUARTERED`` rows or more as hold a real
+        token, two at least (the ladder's buckets stand 1 : 4: a chunk with
+        one live quarter took the bucket below). The rows behind them are
+        padding: no row in front attends them and nothing downstream reads
+        them. A branch a row count inside the one program
+        (``models.llama._layer``), so no program is added; a chunk that is
+        not the last holds ``prefill_chunk`` tokens, and where those fill
+        the bucket's last quarter its program stays whole: the branches' way
+        through HBM costs a full chunk ~1 ms of 41 on four chips and 0.5 of
+        47 on one (PERF.md 6, PR 52 and PR 54)."""
+        if (not self.paged or self.routed
                 or bucket < CHUNK_QUARTERED or bucket % 4
                 or not last and self.prefill_chunk > bucket // 4 * 3):
             return (bucket,)

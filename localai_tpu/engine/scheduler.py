@@ -408,7 +408,7 @@ class Scheduler:
         self.total_prefill_chunks = 0
         # chunk launches by the row parts they ran behind the attend
         # (``chunk_parts`` of the flight ring: 1 a whole bucket, 2 to 4 the
-        # live quarters of one on a mesh)
+        # live quarters of a prompt's last)
         self.total_chunk_parts: dict[int, int] = {}
         # what an admission costs the device besides its prefill: the
         # admissions made, and the times the admission path read the device

@@ -356,9 +356,9 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, attend, reduce=None,
     partial sums into the Megatron two-psums-per-layer pattern. When None
     (single device, or GSPMD-managed sharding) the products are complete.
 
-    ``live`` (a prefill chunk on a mesh: ``forward``) cuts what stands
-    behind the attend to the chunk's live rows; ``lp`` then holds the
-    leaves in front of the attend alone."""
+    ``live`` (a prompt's last prefill chunk, on one chip as on a mesh:
+    ``forward``) cuts what stands behind the attend to the chunk's live
+    rows; ``lp`` then holds the leaves in front of the attend alone."""
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     if reduce is not None:
         # local head counts under manual TP: weight shards carry Hq/tp and
@@ -518,8 +518,9 @@ def forward(
                             # attention-out / mlp-down products inside a
                             # shard_map body (parallel.overlap: one psum
                             # each); None = single device / GSPMD
-    live: Any = None,       # a prefill chunk on a mesh: (row counts, index
-                            # of the one that covers the chunk's real
+    live: Any = None,       # a prompt's last prefill chunk
+                            # (``ModelRunner.chunk_rows``): (row counts,
+                            # index of the one that covers the chunk's real
                             # tokens); what stands behind the attend runs
                             # on those rows alone, a branch a row count
 ) -> tuple[jax.Array, Any]:

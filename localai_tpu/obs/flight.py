@@ -287,8 +287,8 @@ class FlightRecorder:
         ``chunk_offset`` (cached tokens in front of the chunk),
         ``chunk_ctx`` (positions its attend spans) and ``chunk_parts`` (1: the
         program computes every row of its bucket behind the attend; 2 to 4:
-        on a mesh, the quarters of the bucket it ran there,
-        ``ModelRunner.chunk_rows``). 0 wherever a row's kind
+        the quarters of the bucket a prompt's last chunk ran there, on one
+        chip as on a mesh, ``ModelRunner.chunk_rows``). 0 wherever a row's kind
         has no such count.
 
         ``experts_touched`` and ``local_assignments`` are the two counts

@@ -216,7 +216,7 @@ class Registry:
             "Chunked-prefill dispatches by the row parts they ran behind "
             "the attend: parts=1 every row of the program's bucket, "
             "parts=2..4 the quarters of the bucket that held a real token "
-            "(a mesh's chunks of 512 rows or more)",
+            "(a prompt's last chunk of 512 rows or more)",
         )
         self.decode_dispatches = Counter(
             "localai_decode_dispatches_total",

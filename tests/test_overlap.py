@@ -8,6 +8,8 @@ greedy output matches the GSPMD path token for token, whatever the pool's
 dtype.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -260,26 +262,27 @@ def test_meshed_runner_kernel_writes_what_the_scatter_wrote(monkeypatch,
 
 
 # ---------------------------------------------------------------------------
-# PR 52: a chunk on the mesh runs the live quarters of its bucket
+# PR 52, PR 54: a prompt's last chunk runs the live quarters of its bucket,
+# on a mesh and on one device
 
 
-def _chunk_runner(monkeypatch, tp, quartered=True):
-    """A paged runner over debug:small (8 q / 4 kv heads) on a 1 x ``tp``
-    'model' mesh (one device: no mesh), buckets 128 and 512 as the cells
-    have them; ``quartered`` False takes the rule away (every chunk
-    computes its whole bucket: the parent's program)."""
+def _chunk_runner(monkeypatch, tp, quartered=True, ref="debug:small", **kw):
+    """A paged runner over debug:small (8 q / 4 kv heads; or ``ref``) on a
+    1 x ``tp`` 'model' mesh (one device: no mesh), buckets 128 and 512 as
+    the cells have them; ``quartered`` False takes the rule away (every
+    chunk computes its whole bucket: the parent's program)."""
     from localai_tpu.engine import runner as rmod
 
     if not quartered:
         monkeypatch.setattr(rmod, "CHUNK_QUARTERED", 1 << 30)
-    model = resolve_model("debug:small", dtype="float32")
+    model = resolve_model(ref, dtype="float32")
     mesh = _tp_mesh(tp) if tp > 1 else None
     params = (shd.shard_params(model.params, model.cfg, mesh)
               if mesh is not None else model.params)
-    return ModelRunner(
-        model.cfg, params, num_slots=2, max_ctx=1024,
-        prefill_buckets=[128, 512], kv_dtype="float32", paged=True,
-        kv_block_tokens=16, mesh=mesh)
+    kw = {"num_slots": 2, "max_ctx": 1024, "prefill_buckets": [128, 512],
+          "kv_dtype": "float32", "paged": True, "kv_block_tokens": 16,
+          "mesh": mesh, **kw}
+    return ModelRunner(model.cfg, params, **kw)
 
 
 def _admit_in_chunks(r, prompt):
@@ -294,27 +297,33 @@ def _admit_in_chunks(r, prompt):
     return adm.first_token(), np.asarray(r.kv.k), np.asarray(r.kv.v), rows
 
 
-@pytest.mark.parametrize("tail, parts", [(100, 1), (200, 2), (300, 3),
-                                         (500, 4)])
-def test_mesh_chunk_runs_its_live_quarters(monkeypatch, tail, parts):
-    """A prompt of 512 + ``tail`` tokens on a 1 x 4 'model' mesh: a full
-    512-row chunk that is not the last (its program is whole:
-    ``chunk_parts`` 1), then the tail in the 128 bucket (whole too) or in
-    the 512 bucket, whose part behind the attend is cut to the quarters
-    that hold a real token. Against the same runner
-    with the rule taken away: the same first token and the same pool
-    outside the trash block, EXACTLY (rows of a matmul do not mix; the rows
-    left out are padding, written nowhere and attended by nothing); the
-    attend stays whole, so the ring row states the span it did."""
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 virtual devices")
-    prompt = np.random.default_rng(3).integers(1, 500, 512 + tail).tolist()
-    cut = _admit_in_chunks(_chunk_runner(monkeypatch, 4), prompt)
-    whole = _admit_in_chunks(_chunk_runner(monkeypatch, 4, False), prompt)
+def _same_token_and_pool(cut, whole):
+    """The first token, and the pool outside the trash block, EXACTLY."""
     assert cut[0] == whole[0]
     for got, want in zip(cut[1:3], whole[1:3]):
         assert np.abs(want[:, 1:]).sum() > 0
         np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+
+
+@pytest.mark.parametrize("tail, parts", [(100, 1), (200, 2), (300, 3),
+                                         (500, 4)])
+@pytest.mark.parametrize("tp", [1, 4])
+def test_chunk_runs_its_live_quarters(monkeypatch, tp, tail, parts):
+    """A prompt of 512 + ``tail`` tokens on one device and on a 1 x 4
+    'model' mesh: a full 512-row chunk that is not the last (its program is
+    whole: ``chunk_parts`` 1), then the tail in the 128 bucket (whole too)
+    or in the 512 bucket, whose part behind the attend is cut to the
+    quarters that hold a real token. Against the same runner
+    with the rule taken away: the same first token and the same pool
+    outside the trash block, EXACTLY (rows of a matmul do not mix; the rows
+    left out are padding, written nowhere and attended by nothing); the
+    attend stays whole, so the ring row states the span it did."""
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} virtual devices")
+    prompt = np.random.default_rng(3).integers(1, 500, 512 + tail).tolist()
+    cut = _admit_in_chunks(_chunk_runner(monkeypatch, tp), prompt)
+    whole = _admit_in_chunks(_chunk_runner(monkeypatch, tp, False), prompt)
+    _same_token_and_pool(cut, whole)
     assert [row["chunk_parts"] for row in cut[3]] == [1, parts]
     assert [row["chunk_parts"] for row in whole[3]] == [1, 1]
     assert [row["chunk_bucket"] for row in cut[3]] == [
@@ -323,16 +332,40 @@ def test_mesh_chunk_runs_its_live_quarters(monkeypatch, tail, parts):
     assert cut[3][1]["chunk_ctx"] == whole[3][1]["chunk_ctx"] == 1024
 
 
+@pytest.mark.parametrize("tokens, parts", [(200, 2), (300, 3)])
+def test_looped_chunk_runs_its_live_quarters(monkeypatch, tokens, parts):
+    """A looped decoder (debug:tiny-loop: 2 layers run 3 times a token, a
+    cache layer a (pass, layer) pair) on one device: the layer scan and its
+    switch sit inside the loop over the passes, the switch cuts the weights
+    of layer ``cache layer - pass * layers`` from their stacks, and every
+    pass leaves the dead rows out. The same first token and the same pool,
+    all six cache layers of it, as the whole program's."""
+    prompt = np.random.default_rng(5).integers(1, 500, tokens).tolist()
+    kw = {"ref": "debug:tiny-loop", "max_ctx": 512}
+    r = _chunk_runner(monkeypatch, 1, **kw)
+    assert r.cfg.num_passes == 3 and r.kv.k.shape[0] == 6
+    assert r.chunk_rows(512) == (256, 384, 512)
+    cut = _admit_in_chunks(r, prompt)
+    whole = _admit_in_chunks(_chunk_runner(monkeypatch, 1, False, **kw),
+                             prompt)
+    _same_token_and_pool(cut, whole)
+    for got in cut[1:3]:        # every pass wrote its own cache layers
+        assert (np.abs(got[:, 1:]).sum(axis=(1, 2, 3, 4)) > 0).all()
+    assert [row["chunk_parts"] for row in cut[3]] == [parts]
+    assert [row["chunk_parts"] for row in whole[3]] == [1]
+
+
 @pytest.mark.parametrize("tp, bucket, rows", [
-    (1, 512, (512,)), (1, 128, (128,)), (2, 128, (128,)),
+    (1, 512, (256, 384, 512)), (1, 2048, (1024, 1536, 2048)),
+    (1, 128, (128,)), (2, 128, (128,)),
     (2, 512, (256, 384, 512)), (4, 512, (256, 384, 512)),
     (4, 2048, (1024, 1536, 2048))])
 def test_chunk_rows_rule(monkeypatch, tp, bucket, rows):
-    """The rule reads what the runner can see: one device runs every row of
-    every bucket and so does a bucket under 512 rows on a mesh (the 128
-    bucket is bound by the weights' bytes); 512 rows or more on a 'model'
-    axis run in quarters, and the host's arithmetic (the ring's
-    ``chunk_parts``) is the program's switch."""
+    """The rule reads the bucket and the chunk, not the device count: a
+    bucket under 512 rows runs every row, on one device as on a mesh (the
+    128 bucket is bound by the weights' bytes); 512 rows or more run in
+    quarters, and the host's arithmetic (the ring's ``chunk_parts``) is the
+    program's switch."""
     if len(jax.devices()) < tp:
         pytest.skip(f"needs {tp} virtual devices")
     r = _chunk_runner(monkeypatch, tp)
@@ -347,7 +380,43 @@ def test_chunk_rows_rule(monkeypatch, tp, bucket, rows):
             1 if len(rows) == 1 else -(-4 * tokens // bucket))
 
 
-def test_chunk_parts_reach_the_ring_and_metrics(monkeypatch):
+@pytest.mark.parametrize("why", ["contiguous", "routed"])
+def test_chunk_rows_rule_refuses(monkeypatch, why):
+    """What else the runner observes: over the contiguous cache
+    (``paged=False``) and for a model with routed experts (its family's own
+    forward takes no ``live``) every chunk computes its whole bucket."""
+    if why == "contiguous":
+        r = _chunk_runner(monkeypatch, 1, paged=False)
+    else:
+        from localai_tpu.models import llama as mdl
+        from localai_tpu.models.llama import LlamaConfig
+
+        cfg = dataclasses.replace(LlamaConfig.from_hf({
+            "model_type": "afmoe", "vocab_size": 384, "hidden_size": 64,
+            "intermediate_size": 96, "num_hidden_layers": 5,
+            "num_attention_heads": 4, "num_key_value_heads": 2,
+            "head_dim": 16, "max_position_embeddings": 1024,
+            "sliding_window": 8, "global_attn_every_n_layers": 4,
+            "layer_types": ["full_attention" if (i + 1) % 4 == 0
+                            else "sliding_attention" for i in range(5)],
+            "num_dense_layers": 1, "num_experts": 8,
+            "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+            "num_shared_experts": 1, "score_func": "sigmoid",
+            "route_norm": True, "route_scale": 2.448, "mup_enabled": True,
+            "expert_parallel": {"size": 2, "rank": 1}}), dtype="float32")
+        r = ModelRunner(cfg, mdl.init_params(jax.random.key(0), cfg),
+                        num_slots=2, max_ctx=1024, paged=True,
+                        kv_block_tokens=16, prefill_buckets=[128, 512],
+                        kv_dtype="float32")
+        assert r.routed
+    assert (r.paged, r.routed) == (why == "routed", why == "routed")
+    for bucket in (128, 512, 2048):
+        assert r.chunk_rows(bucket) == (bucket,)
+        assert r.chunk_parts(bucket, bucket // 2) == 1
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_chunk_parts_reach_the_ring_and_metrics(monkeypatch, tp):
     """Through the scheduler: the prefill rows of ``/debug/flight`` say how
     many quarters each chunk ran (``chunk_parts``), and ``/metrics`` counts
     the chunk launches by them."""
@@ -355,9 +424,9 @@ def test_chunk_parts_reach_the_ring_and_metrics(monkeypatch):
     from localai_tpu.obs import metrics as obs_metrics
     from localai_tpu.utils.tokenizer import ByteTokenizer
 
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 virtual devices")
-    s = Scheduler(_chunk_runner(monkeypatch, 4), ByteTokenizer())
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} virtual devices")
+    s = Scheduler(_chunk_runner(monkeypatch, tp), ByteTokenizer())
     try:
         for n in (512 + 200, 300, 100):     # no two share a first block
             s.generate(GenRequest(prompt=[65 + (n + i) % 26 for i in range(n)],

@@ -357,27 +357,40 @@ def collectives(text):
                 r"[\w\-]*\(", ln))]
 
 
-def compile_program(fn, *args, **static):
-    """A runner program compiled for the topology as the runner jits it
+def lower_program(fn, *args, **static):
+    """A runner program lowered for the topology as the runner jits it
     (KV and decode state donated)."""
     return jax.jit(fn, donate_argnums=(1, 2),
                    static_argnames=tuple(static)).trace(
-        *args, **static).lower(lowering_platforms=("tpu",)).compile()
+        *args, **static).lower(lowering_platforms=("tpu",))
 
 
-def compile_cell_program(r, a, program):
+def compile_program(fn, *args, **static):
+    return lower_program(fn, *args, **static).compile()
+
+
+def compile_cell_program(r, a, program, build=compile_program):
     """One of a cell's dispatched programs over ``abstract_runner``'s
     (runner, avals): ``decode``, ``decode_n2`` or ``prefill_chunk_<bucket>``
     (``..._sample``: the chunk that ends a prompt and samples)."""
     base = (a["params"], a["kv"], a["state"])
     if program == "decode":
-        return compile_program(r._decode_paged_fn, *base, a["tables"])
+        return build(r._decode_paged_fn, *base, a["tables"])
     if program == "decode_n2":
-        return compile_program(r._decode_paged_n_fn, *base, a["tables"], n=2)
+        return build(r._decode_paged_n_fn, *base, a["tables"], n=2)
     bucket = int(program.split("_")[2])
-    return compile_program(
+    return build(
         r._prefill_paged_fn, *base, *a["chunk"](bucket), bucket=bucket,
         sample=program.endswith("_sample"))
+
+
+def quartered(r, program):
+    """Whether ``program`` is a chunk program that holds the switch over its
+    bucket's live quarters (``ModelRunner.chunk_rows``)."""
+    if not program.startswith("prefill_chunk_"):
+        return False
+    return len(r.chunk_rows(int(program.split("_")[2]),
+                            program.endswith("_sample"))) > 1
 
 
 def test_smoke_programs_fit_one_chip(topo, monkeypatch):
@@ -480,7 +493,16 @@ def test_cell_programs_write_the_pool_in_place(topo, monkeypatch, cell,
     # reader sums every ``tpu_custom_call`` of theirs), which writes the
     # step's rows too; chunked prefill attends through XLA, holds none, and
     # writes through the policy's scatter as before
-    assert_who_writes(program, c.as_text(), pool, spans(r, program))
+    text = c.as_text()
+    assert_who_writes(program, text, pool, spans(r, program),
+                      quartered=quartered(r, program))
+    if program.startswith("prefill"):
+        # PR 54: the last chunk's 512-row program holds the switch over its
+        # live quarters on one chip too, its branches handed the stacks; no
+        # chunk program stages a stack of what they read (wo's is 512 MiB)
+        assert_live_quarters(r, program, text, cfg, pool)
+        assert c.memory_analysis().temp_size_in_bytes < (
+            cfg.num_layers * cfg.hidden_size ** 2)
 
 
 def assert_in_place(program, c, pool):
@@ -543,8 +565,9 @@ def assert_who_writes(program, text, pool, ladder=None, quartered=False):
     if not program.startswith("decode"):
         assert not calls
         assert 'kv_pool.write/scatter"' in text
-        # (a mesh's 512-row chunk holds a second conditional, of three
-        # branches, over its row counts: PR 52, the test at the end)
+        # (the 512-row program of a prompt's last chunk holds a second
+        # conditional, of three branches, over its row counts: PR 52 on a
+        # mesh, PR 54 on one chip, the tests at the end)
         switch = [ln.split("branch_computations={")[1].split("}")[0].split(",")
                   for ln in text.splitlines() if " conditional(" in ln]
         assert sorted(map(len, switch)) == sorted(
@@ -598,7 +621,12 @@ def test_looped_cell_programs_write_the_pool_in_place(topo, monkeypatch,
     c = compile_cell_program(r, a, program)
     assert_in_place(program, c, pool)
     text = c.as_text()
-    assert_who_writes(program, text, pool, spans(r, program))
+    assert_who_writes(program, text, pool, spans(r, program),
+                      quartered=quartered(r, program))
+    if program.startswith("prefill"):
+        # PR 54: the switch over the live quarters sits inside the loop over
+        # the passes with the layer scan; nothing is copied a pass
+        assert_live_quarters(r, program, text, cfg, pool)
     m = c.memory_analysis()
     # no weight stack staged: the smallest stacked leaf is [48, 2048, 2048]
     assert m.temp_size_in_bytes < cfg.num_layers * cfg.hidden_size ** 2, (
@@ -964,8 +992,7 @@ def test_cell_programs_write_their_own_heads_on_a_tp4_mesh(
     # chunk, which scatters, and whose attend's branches (the rung is a
     # replicated scalar) gather the chip's own two heads of their span
     assert_who_writes(program, text, shard, spans(r, program),
-                      quartered=len(r.chunk_rows(512)) > 1
-                      and "chunk_512_sample" in program)
+                      quartered=quartered(r, program))
 
 
 # ---------------------------------------------------------------------------
@@ -1219,11 +1246,10 @@ def test_tp4_chunk_layers_reduce_their_live_quarters(topo, monkeypatch, cell,
         max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
         kv_block_tokens=64)
     bucket = int(program.split("_")[2])
-    rows = r.chunk_rows(bucket, program.endswith("_sample"))
-    assert rows == ((256, 384, 512) if program == "prefill_chunk_512_sample"
-                    else (bucket,))
     text = compile_cell_program(r, a, program).as_text()
-    L, D = cfg.num_layers, cfg.hidden_size
+    rows = assert_live_quarters(r, program, text, cfg,
+                                a["kv"].k.sharding.shard_shape(a["kv"].k.shape))
+    D = cfg.hidden_size
     talk = [(re.sub(r"\{[^}]*\}", "", result), name[1] if (name := re.search(
         r'op_name="([^"]*)"', ln)) else "")
         for op, result, ln in collectives(text) if op == "all-reduce"]
@@ -1232,22 +1258,86 @@ def test_tp4_chunk_layers_reduce_their_live_quarters(topo, monkeypatch, cell,
                     for res, name in talk if "/layers/" in name)
     assert layers == sorted((f"bf16[1,{n},{D}]", scope) for n in rows
                             for scope in ("attn.out", "mlp")), layers
+
+
+def assert_live_quarters(r, program, text, cfg, pool):
+    """The row counts the rule gives chunk ``program`` (256, 384 and 512 for
+    the 512-row program of a prompt's last chunk, the bucket alone for every
+    other), and its compiled ``text`` held to them: ONE conditional whose
+    result is the layer's ``bf16[1, bucket, D]``, a branch a row count (none
+    where the rows are the bucket alone). Each branch is handed the four
+    stacked ``s8[L, ...]`` leaves behind the attend and the layer's number,
+    never the pool (``pool``: one device's K or V stack), and produces no
+    int8 array of its own: the dots read the layer's weights where they lie
+    in the stacks. Returns the row counts."""
+    import re
+
+    bucket = int(program.split("_")[2])
+    L, D = cfg.num_layers, cfg.hidden_size
+    rows = r.chunk_rows(bucket, program.endswith("_sample"))
+    assert rows == ((256, 384, 512) if program == "prefill_chunk_512_sample"
+                    else (bucket,))
     switch = [ln for ln in text.splitlines() if re.search(
         rf"bf16\[1,{bucket},{D}\]\S* conditional\(", ln)]
     if len(rows) == 1:
         assert not switch
-        return
+        return rows
     assert len(switch) == 1, switch
     branches = switch[0].split("branch_computations={")[1].split("}")[0]
     branches = [b.strip().lstrip("%") for b in branches.split(",")]
     assert len(branches) == len(rows)
-    pool = "[" + ",".join(map(str, a["kv"].k.sharding.shard_shape(
-        a["kv"].k.shape))) + "]"
+    pool = "[" + ",".join(map(str, pool)) + "]"
+    lines = text.splitlines()
     for name in branches:
-        head = next(ln for ln in text.splitlines()
-                    if ln.startswith(f"%{name} ("))
-        handed = head.split(") -> ")[0]
+        at = next(i for i, ln in enumerate(lines)
+                  if ln.startswith(f"%{name} ("))
+        handed = lines[at].split(") -> ")[0]
         weights = re.findall(r"s8\[([\d,]+)\]", handed)
         assert len(weights) == 4 and all(
             w.startswith(f"{L},") for w in weights), handed
         assert pool not in handed, handed
+        body = lines[at + 1:lines.index("}", at)]
+        made = [ln.strip()[:120] for ln in body if re.search(
+            r" = s8\[[\d,]+\]\S* (?!get-tuple-element\()[\w\-]+\(", ln)]
+        assert not made, f"{name} stages int8 weights: {made}"
+    return rows
+
+
+@pytest.mark.parametrize("cell", [M7B, OURO], indirect=True)
+def test_one_chip_chunk_layers_run_their_live_quarters(topo, monkeypatch,
+                                                       cell):
+    """PR 54: the rule PR 52 held to a mesh reads the bucket and the chunk,
+    so of the one-chip cells' four chunk programs ONE changes: the 512-row
+    program of a prompt's LAST chunk. The other three (the 128 bucket, and a
+    512-row chunk that is not the last) lower to the text they lower to
+    with the rule taken away: the parent's. What the changed program holds
+    once compiled (``assert_live_quarters``: the three row counts as
+    branches, handed the stacks, no pool and no weight slice staged, under
+    plain jit as under GSPMD; Ouro's inside its loop over four passes, the
+    layer's number the cache layer's less ``pass * 48``) is held where the
+    cells' programs are compiled anyway:
+    ``test_cell_programs_write_the_pool_in_place`` and
+    ``test_looped_cell_programs_write_the_pool_in_place``."""
+    from localai_tpu.engine import runner as rmod
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    assert r.mesh is None
+    programs = ("prefill_chunk_512_sample", "prefill_chunk_512",
+                "prefill_chunk_128", "prefill_chunk_128_sample")
+
+    def lowered():
+        return {p: compile_cell_program(r, a, p, build=lower_program).as_text()
+                for p in programs}
+
+    now = lowered()
+    assert [quartered(r, p) for p in programs] == [True, False, False, False]
+    monkeypatch.setattr(rmod, "CHUNK_QUARTERED", 1 << 30)
+    assert not any(quartered(r, p) for p in programs)
+    parent = lowered()
+    assert [p for p in programs if now[p] != parent[p]] == [
+        "prefill_chunk_512_sample"]
