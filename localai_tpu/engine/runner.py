@@ -72,7 +72,8 @@ def _refusal(what: str) -> str:
     """The one sentence that refuses ``what`` for a model with recurrent
     state (``LlamaConfig.recurrent``)."""
     return (f"{what} is not served for a model whose layers carry recurrent "
-            f"state (model_type qwen3_next): that state is not keys, it "
+            f"state (model_type qwen3_next, falcon_h1): that state is not "
+            f"keys, it "
             f"lives in one dense row a slot beside the paged K/V pool and "
             f"cannot be re-read, split or shipped as a prefix of keys can")
 
@@ -106,9 +107,10 @@ class DecodeState:
     bias: jax.Array        # [S, V] f32 — additive logit bias (logit_bias API
                            #              + grammar/FSM masks as -1e30)
     params: smp.SamplingParams
-    # per-slot state that is not keys (models.qwen3_next.init_rec) and, for
-    # a model with routed experts, the routed work of chunks that have not
-    # sampled yet; None, and so no leaf of any program, for every other model
+    # per-slot state that is not keys (the family module's ``init_rec``) and,
+    # for a model with routed experts, the routed work of chunks that have
+    # not sampled yet; None, and so no leaf of any program, for every other
+    # model
     rec: Any = None
 
     @staticmethod
@@ -292,7 +294,8 @@ class ModelRunner:
             raise ValueError(
                 f"paged KV cache is incompatible with {incompat}")
         # a model whose layers carry per-slot state that is not keys
-        # (models.qwen3_next): that state is one dense row a slot beside
+        # (``cfg.recurrent``: its family module builds the state, and its
+        # forward carries it): that state is one dense row a slot beside
         # the block pool, and what takes a sequence for its keys is refused
         self.recurrent = bool(cfg.recurrent)
         if self.recurrent:
@@ -310,10 +313,15 @@ class ModelRunner:
         # where attention's are kernels (the value: in the Pallas
         # interpreter), None for their XLA forms where ``attn_impl`` says xla
         self.routed = bool(cfg.routed)
+        # either has a forward of its family's own (``_forward_rec``)
+        self.own_forward = self.routed or self.recurrent
         if self.routed:
             impl, interpret = ops.select_moe_impl(
                 attn_impl, hidden=cfg.hidden_size,
                 intermediate=cfg.moe_intermediate_size)
+            self.family_kernels = interpret if impl == "pallas" else None
+        elif self.recurrent:
+            impl, interpret = ops.resolve_attn_impl(attn_impl)
             self.family_kernels = interpret if impl == "pallas" else None
         if kv_dtype == "int4" and not self.paged:
             raise ValueError(
@@ -550,13 +558,12 @@ class ModelRunner:
 
     def _init_rec(self, num_slots: int):
         """``DecodeState.rec`` for ``num_slots`` slots: the recurrent state
-        (models.qwen3_next.init_rec), for a model with routed experts at
-        least the routed work of chunks whose token no copy brings to the
-        host yet (``_prefill_paged_fn``), None for every other model."""
+        (the family module's ``init_rec``: what its forward carries), for a
+        model with routed experts at least the routed work of chunks whose
+        token no copy brings to the host yet (``_prefill_paged_fn``), None
+        for every other model."""
         if self.recurrent:
-            from localai_tpu.models import qwen3_next
-
-            return qwen3_next.init_rec(self.cfg, num_slots)
+            return mdl.family_module(self.cfg).init_rec(self.cfg, num_slots)
         if self.routed:
             return {"routed": jnp.zeros(2, jnp.int32)}
         return None
@@ -900,7 +907,7 @@ class ModelRunner:
             new_state, tokens = self._decode_tail(params, state, hidden)
             return self.layout.from_stacked(new_stack), new_state, tokens
         write, attn, mask = self.layout.decode(kv, tables, pos)
-        if self.routed:
+        if self.own_forward:
             # a slot with no stream is the identity on its state; the step's
             # routed work rides behind the S sampled tokens, in their copy
             hidden, new_stack, rec, routed = self._forward_rec(
@@ -909,8 +916,9 @@ class ModelRunner:
                 attn=attn)
             new_state, tokens = self._decode_tail(
                 params, dataclasses.replace(state, rec=rec), hidden)
-            return (self.layout.from_stacked(new_stack), new_state,
-                    jnp.concatenate([tokens, routed]))
+            if routed is not None:
+                tokens = jnp.concatenate([tokens, routed])
+            return self.layout.from_stacked(new_stack), new_state, tokens
         hidden, new_stack = self._forward(
             params, state.tokens[:, None], pos[:, None],
             write, kv.stacked(), mask, attn=attn,
@@ -977,7 +985,7 @@ class ModelRunner:
         write, attn, mask = self.layout.chunk(table_row, slot, positions,
                                               offset, length)
         routed = None
-        if self.routed:
+        if self.own_forward:
             # the chunk goes on from the slot's state at ``offset``: zero at
             # 0 whatever the slot held (the arming program runs before the
             # LAST chunk, too late to zero it), rows past ``length`` leave
@@ -986,10 +994,12 @@ class ModelRunner:
                 params, tokens, positions, write, kv.stacked(), mask,
                 state.rec, (jnp.arange(bucket) < length)[None, :],
                 attn=attn, embeds=embeds, slot=slot, fresh=offset == 0)
-            # only the final chunk's token is copied to the host: the routed
-            # work of the chunks before it waits in ``routed`` for that copy
-            routed = rec["routed"] + routed
-            rec["routed"] = jnp.zeros_like(routed) if sample else routed
+            if routed is not None:
+                # only the final chunk's token is copied to the host: the
+                # routed work of the chunks before it waits in ``routed``
+                # for that copy
+                routed = rec["routed"] + routed
+                rec["routed"] = jnp.zeros_like(routed) if sample else routed
             state = dataclasses.replace(state, rec=rec)
         else:
             rows = self.chunk_rows(bucket, sample)
@@ -1027,7 +1037,7 @@ class ModelRunner:
         the bucket's last quarter its program stays whole: the branches' way
         through HBM costs a full chunk ~1 ms of 41 on four chips and 0.5 of
         47 on one (PERF.md 6, PR 52 and PR 54)."""
-        if (not self.paged or self.routed
+        if (not self.paged or self.own_forward
                 or bucket < CHUNK_QUARTERED or bucket % 4
                 or not last and self.prefill_chunk > bucket // 4 * 3):
             return (bucket,)
@@ -1160,7 +1170,7 @@ class ModelRunner:
             mask = {kind: kvc.prefill_mask(view, bucket, length)
                     for kind, view in kvc.kind_views(cfg)}
             attn = None
-        if self.routed:
+        if self.own_forward:
             hidden, *_ = self._forward_rec(
                 params, tokens, positions, write, kv, mask,
                 self._init_rec(1),
@@ -1218,15 +1228,17 @@ class ModelRunner:
     def _forward_rec(self, params, tokens, positions, write, stack, mask,
                      rec, valid, attn=None, embeds=None, slot=None,
                      fresh=None):
-        """The forward of a model with routed experts (``cfg.routed``), its
-        family module's own (``models.llama.family_module``): one that
-        carries recurrent state too (``cfg.recurrent``) takes and returns
-        it, one with ``cfg.attn_kinds`` is handed a mask and an attend a
-        kind of layer. Returns the hidden states and the K/V stack as
-        ``_forward`` does, then the new state and the launch's routed work
-        [experts touched, token-expert pairs that landed here]."""
+        """The forward of a model with routed experts (``cfg.routed``) or
+        with recurrent state (``cfg.recurrent``), its family module's own
+        (``models.llama.family_module``): one that carries recurrent state
+        takes and returns it (what its ``init_rec`` built), one with
+        ``cfg.attn_kinds`` is handed a mask and an attend a kind of layer.
+        Returns the hidden states and the K/V stack as ``_forward`` does,
+        then the new state and the launch's routed work [experts touched,
+        token-expert pairs that landed here] (None from a family that routes
+        nothing)."""
         rec = dict(rec)
-        carried = rec.pop("routed")
+        carried = {"routed": rec.pop("routed")} if self.routed else {}
         forward = partial(
             mdl.family_module(self.cfg).forward, self.cfg, params, tokens,
             positions, write, stack, mask, self.rope, attn=attn,
@@ -1236,7 +1248,7 @@ class ModelRunner:
                                                      fresh=fresh)
         else:
             hidden, new_stack, routed = forward()
-        return hidden, new_stack, {**rec, "routed": carried}, routed
+        return hidden, new_stack, {**rec, **carried}, routed
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""

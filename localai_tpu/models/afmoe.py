@@ -254,7 +254,7 @@ BIAS_STD = 0.01
 OUTLIER_NORMS = ("attn_norm", "final_norm")
 
 
-def init_leaf(key, shape, name: str, dtype):
+def init_leaf(key, shape, name: str, dtype, cfg=None):
     """One synthetic leaf, for models.llama.init_params' loop: matrices
     N(0, 0.02) as models.llama's; norm gains 1, but ``QK_NORM_GAIN`` on q
     and k and ``OUTLIER_GAIN`` on a seeded ``1 / OUTLIER_EVERY`` of the

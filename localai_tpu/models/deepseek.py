@@ -293,7 +293,7 @@ OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192
 OUTLIER_NORMS = ("attn_norm", "final_norm", "kv_norm")
 
 
-def init_leaf(key, shape, name: str, dtype):
+def init_leaf(key, shape, name: str, dtype, cfg=None):
     """One synthetic leaf, for models.llama.init_params' loop: matrices
     N(0, 0.02); norm gains 1, but ``LATENT_NORM_GAIN`` on q_norm and kv_norm
     and ``OUTLIER_GAIN`` on a seeded ``1 / OUTLIER_EVERY`` of the channels of

@@ -364,7 +364,7 @@ BIAS_STD = 0.01
 STACKED_DRAW = 1 << 24
 
 
-def init_leaf(key, shape, name: str, dtype):
+def init_leaf(key, shape, name: str, dtype, cfg=None):
     """One synthetic leaf: models.deepseek's draw under this file's names
     (norm gains as a trained checkpoint's lie); the selection bias N(0,
     ``BIAS_STD``) in float32 and the index key's LayerNorm bias N(0, 0.02):

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, ClassVar, Optional
 
 import jax
@@ -65,8 +66,10 @@ class LlamaConfig:
     dtype: str = "bfloat16"
 
     # a family whose layers carry per-slot state that is not keys (a
-    # subclass: models.qwen3_next): the engine keeps that state beside the
-    # K/V pool and refuses what re-reads a prefix from keys alone
+    # subclass: models.qwen3_next, models.falcon_h1): the engine keeps that
+    # state beside the K/V pool, built by the family module's ``init_rec``
+    # and carried by its ``forward``, and refuses what re-reads a prefix
+    # from keys alone
     recurrent: ClassVar[bool] = False
     # a family with a parameter pytree and a forward of its own: the module
     # under localai_tpu.models that has them (``family_module``)
@@ -120,7 +123,12 @@ class LlamaConfig:
         group-limited sigmoid-routed experts): models.deepseek; and
         ``dots3_note`` (that block with an indexer's learned sparse
         attention on its full layers and window layers of a shape of their
-        own): models.dots3."""
+        own): models.dots3; and ``falcon_h1`` (a Mamba-2 mixer and
+        grouped-query attention side by side in every layer, under muP
+        multipliers): models.falcon_h1. A type not named here is built as a
+        dense llama stack of the file's widths, unless its keys say that it
+        has a state-space mixer (``mamba_*``), which such a stack would
+        leave out: refused."""
         if hf.get("model_type") == "qwen3_next":
             from localai_tpu.models.qwen3_next import Qwen3NextConfig
 
@@ -137,6 +145,17 @@ class LlamaConfig:
             from localai_tpu.models.dots3 import Dots3Config
 
             return Dots3Config.from_hf(hf)
+        if hf.get("model_type") == "falcon_h1":
+            from localai_tpu.models.falcon_h1 import FalconH1Config
+
+            return FalconH1Config.from_hf(hf)
+        mamba = sorted(k for k in hf if k.startswith("mamba_"))
+        if mamba:
+            raise ValueError(
+                f"model_type {hf.get('model_type')!r} is not served: its "
+                f"config carries a state-space mixer's keys ({mamba[0]}, "
+                f"...) and a dense llama stack of its widths would leave "
+                f"that mixer out")
         ouro = hf.get("model_type") == "ouro"
         return cls(
             vocab_size=hf.get("vocab_size", 32000),
@@ -253,8 +272,10 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 def family_module(cfg: LlamaConfig):
     """The module of a family that brings its own parameters and forward
-    (``param_shapes``, ``init_leaf``, ``checkpoint_leaves``,
-    ``refuse_quantization``, ``forward``, and ``refusal`` where it has
+    (``param_shapes``, ``init_leaf(key, shape, name, dtype, cfg)``,
+    ``checkpoint_leaves``, ``refuse_quantization``, ``forward``;
+    ``leaf_std(cfg, name)`` where it serves a quantised mode, ``init_rec``
+    where it is ``recurrent``, and ``refusal`` where it has
     ``attn_kinds``); None for this file's."""
     if cfg.family is None:
         return None
@@ -323,7 +344,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
     (parallel.sharding.ParamPlacement), it is generated directly on the
     devices that will hold it — no leaf is ever whole on one chip first."""
     fam = family_module(cfg)
-    leaf = _init_leaf if fam is None else fam.init_leaf
+    leaf = _init_leaf if fam is None else partial(fam.init_leaf, cfg=cfg)
     shapes = param_shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))

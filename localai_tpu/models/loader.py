@@ -144,11 +144,12 @@ def load_llama_params(
                 layers[name] = place(("layers", name), host)
             else:
                 top[name] = place((name,), host)
+        if "final_norm" not in top:     # (a family may bring its own name)
+            top["final_norm"] = place(("final_norm",),
+                                      _get(tensors, body + "norm.weight"))
         return cfg, {
             "embed": place(("embed",),
                            _get(tensors, body + "embed_tokens.weight")),
-            "final_norm": place(("final_norm",),
-                                _get(tensors, body + "norm.weight")),
             "layers": layers, **top,
             **({} if cfg.tie_word_embeddings else {
                 "lm_head": place(("lm_head",), _get(tensors, head).T)})}

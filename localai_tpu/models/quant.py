@@ -385,6 +385,7 @@ def embed_rows(w, tokens: jax.Array, dtype) -> jax.Array:
 _LAYER_AXES = {
     "wq": 1, "wk": 1, "wv": 1, "wo": 1,
     "w_gate": 1, "w_up": 1, "w_down": 1,
+    "ssm_in": 1, "ssm_out": 1,      # a state-space mixer's two projections
 }
 
 

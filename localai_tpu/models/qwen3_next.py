@@ -230,7 +230,7 @@ def param_shapes(cfg: Qwen3NextConfig) -> dict:
     return shapes
 
 
-def init_leaf(key, shape, name: str, dtype):
+def init_leaf(key, shape, name: str, dtype, cfg=None):
     """One synthetic leaf, for models.llama.init_params' loop. Weights are
     N(0, 0.02) as models.llama's; a zero-centred gain is N(0, 0.02) (gain
     ~ 1), the gated norm's plain gain 1 + N(0, 0.02); ``A = exp(A_log)`` is

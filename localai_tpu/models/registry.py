@@ -170,6 +170,8 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
     dtype = jnp.dtype(cfg.dtype)
 
     def plain(key, shape, name):
+        if fam is not None:     # the family's own draw of what stays plain
+            return fam.init_leaf(key, shape, name, dtype, cfg)
         if name.endswith("norm"):
             return jnp.ones(shape, dtype)
         if name in ("bq", "bk", "bv"):
@@ -178,9 +180,15 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
         return (jax.random.normal(key, shape, jnp.float32) * 0.02
                 ).astype(dtype)
 
-    def quantized(key, shape, axis, mode):
+    def quantized(key, shape, name, axis, mode):
         bits = 4 if mode == "int4" else 8
         lim = 7 if bits == 4 else 127
+        # init_params' 0.02 amplitude; a family that serves a quantised
+        # mode states its matrices' deviations (``leaf_std``: a number, or
+        # one an output column), and uniform integers of amplitude ``lim``
+        # have deviation lim / sqrt 3
+        amp = 0.02 if fam is None else jnp.asarray(
+            fam.leaf_std(cfg, name), jnp.float32) * 3 ** 0.5
         # raw uint8 bits reinterpreted as int8 — no int32 intermediates
         # (randint would spike 4× the tensor size during generation)
         v = jax.lax.bitcast_convert_type(
@@ -195,7 +203,7 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
             sshape = shape[:axis] + shape[axis + 1:]
         mm = {"int4": "w4", "int8_w8a8": "w8a8"}.get(mode, "w8")
         return QuantizedTensor(
-            q=q, scale=jnp.full(sshape, 0.02 / lim, jnp.float32),
+            q=q, scale=jnp.broadcast_to(amp / lim, sshape).astype(jnp.float32),
             axis=axis, mode=mm)
 
     leaves = []
@@ -205,7 +213,7 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
         if plan is None:
             make = partial(plain, key, shape, path[-1])
         else:
-            make = partial(quantized, key, shape, *plan)
+            make = partial(quantized, key, shape, path[-1], *plan)
         shardings = (placement.shardings(path, jax.eval_shape(make))
                      if placement is not None else None)
         # one program per leaf: XLA fuses generation into the (sharded)

@@ -20,11 +20,18 @@ over dk, so nothing is rounded to bfloat16 on the way to the MXU. A head's S
 is 64 KB; a block is a slot's heads (2 MB at the published widths), sized by
 what a grid step costs and not by a head (PERF.md section 6, PR 46).
 
+The Mamba-2 mixer's decode step (models.falcon_h1) is the same call with
+``delta=False``: a head's S [N, P] decays by a scalar and gains k (x) v with
+no correction by what S already holds of k (k = B, q = C, v = dt x); its
+carried array has one leading axis, the layer (``g_idx`` None).
+
 Runs under ``interpret=True`` on the CPU (tests/test_gdn_kernel.py) and is
 compiled for v5e at the served widths in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +58,17 @@ def head_step(S, kc, qc, v, decay, beta, kq):
     return decay * S + kc * d, decay * Sq + kq * d
 
 
-def _kernel(p_ref, S_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, S_out, o_ref):
+def plain_step(S, kc, qc, v, decay, beta, kq):
+    """``head_step`` without the delta correction
+    (models.falcon_h1.ssm_step): what is written is beta v as it stands, and
+    S^T k is never formed."""
+    d = beta * v
+    return (decay * S + kc * d,
+            decay * jnp.sum(S * qc, axis=0, keepdims=True) + kq * d)
+
+
+def _kernel(delta, p_ref, S_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, S_out,
+            o_ref):
     del p_ref                               # the index maps read it
     q, k, v = q_ref[...], k_ref[...], v_ref[...]
     # a head's three scalars, each along the lanes of its row [hb, dv]. The
@@ -63,36 +80,42 @@ def _kernel(p_ref, S_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, S_out, o_ref):
             jnp.sum(k * q, axis=-1, keepdims=True)))
     decay = jnp.exp(g)
     kT, qT = k.T, q.T               # [dk, hb]: a head's vector is a column
+    step = head_step if delta else plain_step
     for h in range(S_ref.shape[0]):
         one = slice(h, h + 1)
-        S_out[h], o_ref[one, :] = head_step(
+        S_out[h], o_ref[one, :] = step(
             S_ref[h], kT[:, one], qT[:, one], v[one], decay[one], beta[one],
             kq[one])
 
 
-def gdn_state_step(S_all, p, g_idx: int, q, k, v, g, beta, *,
+def gdn_state_step(S_all, p, g_idx, q, k, v, g, beta, *, delta: bool = True,
                    head_block: int = HEAD_BLOCK, interpret: bool = False):
     """One token of the gated delta rule on layer (p, g_idx) of the carried
     state, batch row b = slot b: (``S_all`` with that layer's rows replaced,
     in place; o [slots, Hv, dv] float32).
 
     S_all [P, G, slots, Hv, dk, dv] float32; ``p`` a scalar (traced or not),
-    ``g_idx`` a Python int; q, k [slots, Hv, dk], v [slots, Hv, dv], g, beta
-    [slots, Hv], float32 (a row with g = 0 and beta = 0 is the identity on
-    its S)."""
-    _, _, slots, Hv, dk, dv = S_all.shape
+    ``g_idx`` a Python int, or None for a state with ONE leading axis, [L,
+    slots, Hv, dk, dv], whose layer is ``p``; q, k [slots, Hv, dk], v
+    [slots, Hv, dv], g, beta [slots, Hv], float32 (a row with g = 0 and beta
+    = 0 is the identity on its S). ``delta`` False: the recurrence without
+    the delta correction (``plain_step``), a kernel of another name
+    (``ssm_state_step``)."""
+    slots, Hv, dk, dv = S_all.shape[-4:]
     if S_all.dtype != jnp.float32:
-        raise ValueError(f"the DeltaNet state is float32, not {S_all.dtype}")
+        raise ValueError(f"the recurrent state is float32, not {S_all.dtype}")
     if not interpret and (dk % 128 or dv % 128):
         raise ValueError(
-            f"the DeltaNet step kernel needs 128-aligned head dims (key "
+            f"the recurrent step kernel needs 128-aligned head dims (key "
             f"{dk}, value {dv}); set engine.attn_impl: xla to serve the "
             f"step as XLA instead")
     hb = min(head_block, Hv)
+    lead = (None,) * (S_all.ndim - 3)       # the layer's axes and the slot's
 
     # index maps take (slot, head block, p)
     def state(s, j, p):
-        return p[0], g_idx, s, j, 0, 0
+        layer = (p[0],) if g_idx is None else (p[0], g_idx)
+        return *layer, s, j, 0, 0
 
     def heads(s, j, p):
         return s, j, 0
@@ -102,15 +125,14 @@ def gdn_state_step(S_all, p, g_idx: int, q, k, v, g, beta, *,
 
     f32 = jnp.float32
     S_all, o = pl.pallas_call(
-        _kernel,
-        name="gdn_state_step",
+        functools.partial(_kernel, delta),
+        name="gdn_state_step" if delta else "ssm_state_step",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(slots, pl.cdiv(Hv, hb)),
-            in_specs=[pl.BlockSpec((None, None, None, hb, dk, dv), state),
+            in_specs=[pl.BlockSpec((*lead, hb, dk, dv), state),
                       vec(dk), vec(dk), vec(dv), vec(1), vec(1)],
-            out_specs=[pl.BlockSpec((None, None, None, hb, dk, dv), state),
-                       vec(dv)],
+            out_specs=[pl.BlockSpec((*lead, hb, dk, dv), state), vec(dv)],
         ),
         out_shape=[jax.ShapeDtypeStruct(S_all.shape, f32),
                    jax.ShapeDtypeStruct((slots, Hv, dv), f32)],

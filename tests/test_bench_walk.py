@@ -9,8 +9,11 @@ sparse hybrid family's file, cell and readers, with a small model of it
 served through the harness and the control that fails
 (``test_qwen3_next_family.py``); the same for the window / full attention
 family (``test_afmoe_family.py``), for the latent-attention family
-(``test_deepseek_family.py``) and for the family with an indexer in front of
-it (``test_dots3_family.py``).
+(``test_deepseek_family.py``), for the family with an indexer in front of
+it (``test_dots3_family.py``) and for the state-space hybrid
+(``test_falcon_h1_family.py``: its server-free cases; the small model served
+and its failing control run with ``benchmark/tests/``: this file is among the
+suite's longest, and tests/test_falcon_h1.py serves the family in tier-1).
 
 The modules are loaded by path with ``benchmark/`` and ``benchmark/tests/`` on
 ``sys.path`` (as tests/test_bench_trace.py does it) and the benchmark's own
@@ -55,6 +58,7 @@ _qn = _load("test_qwen3_next_family", conftest=_conftest, test_walk=_walk)
 _af = _load("test_afmoe_family", conftest=_conftest, test_walk=_walk)
 _ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
 _d3 = _load("test_dots3_family", conftest=_conftest, test_walk=_walk)
+_fh = _load("test_falcon_h1_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -182,3 +186,16 @@ test_a_sparse_attention_model_runs_by_files_alone = (
     _d3.test_a_sparse_attention_model_runs_by_files_alone)
 test_the_control_fails_a_family_whose_full_layers_attend_every_row = (
     _d3.test_the_control_fails_a_family_whose_full_layers_attend_every_row)
+# PR 55's file: the state-space hybrid's hand arithmetic, the catalog row in
+# the file, a row a layer with pool AND state, its cell, its two readers
+test_the_hand_arithmetic_of_the_state_space_hybrids_published_keys = (
+    _fh.test_the_hand_arithmetic_of_the_state_space_hybrids_published_keys)
+test_every_published_number_of_the_state_space_hybrids_catalog_row_is_in_the_file = (
+    _fh
+    .test_every_published_number_of_the_state_space_hybrids_catalog_row_is_in_the_file)
+test_the_served_stack_is_a_row_a_layer_with_pool_and_state = (
+    _fh.test_the_served_stack_is_a_row_a_layer_with_pool_and_state)
+test_the_state_space_cell_reports_what_the_issue_names = (
+    _fh.test_the_state_space_cell_reports_what_the_issue_names)
+test_the_ssm_readers_read_the_ring_and_the_scopes = (
+    _fh.test_the_ssm_readers_read_the_ring_and_the_scopes)

@@ -319,6 +319,9 @@ def abstract_runner(topo, monkeypatch, cfg, tp=1, quantization="int8",
             backend="tpu") == ("pallas", False)
         assert r.family_kernels is True
         r.family_kernels = False
+    elif r.recurrent:   # a state-space mixer's decode step: ops.gdn's kernel
+        assert r.family_kernels is True
+        r.family_kernels = False
     if mesh is not None:
         r.mesh = r.layout.mesh = mesh
     pool_spec = None if mesh is None else tuple(r.layout.kv_sharding.spec)
