@@ -5,8 +5,30 @@ compile (tests/test_tpu_compile.py). The chip is reached only through the
 chip tool, one process per chip, starting with ``python chip_smoke.py``."""
 
 import os
+import shutil
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+
+# A run pays each XLA compilation ONCE. Nearly every case builds a runner of
+# its own, so its jitted programs are new Python objects and the in-process
+# jit cache misses on HLO the case before (or another worker) compiled: JAX's
+# persistent cache, keyed by the HLO, holds it. The process that starts the
+# run makes an EMPTY directory named by its own pid (what a killed run leaves
+# is never read again) and removes it at the end; xdist's workers and every
+# server a test spawns inherit the variables. Nothing carries from one run to
+# the next, so no case can pass on a stale entry.
+STARTS_THE_RUN = "PYTEST_XDIST_WORKER" not in os.environ
+if STARTS_THE_RUN:
+    CACHE_DIR = os.path.join(tempfile.gettempdir(),
+                             f"localai-tpu-tests-jax-cache-{os.getpid()}")
+    shutil.rmtree(CACHE_DIR, ignore_errors=True)
+    os.makedirs(CACHE_DIR)
+    os.environ.update({
+        "JAX_COMPILATION_CACHE_DIR": CACHE_DIR,
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
+    })
 
 import jax  # noqa: E402
 
@@ -14,11 +36,24 @@ jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 
+
+def pytest_unconfigure(config):
+    if STARTS_THE_RUN:
+        shutil.rmtree(CACHE_DIR, ignore_errors=True)
+
+
 # Modules measured ≥ ~20 s on CPU CI (per-file wall clock, 2026-07) get the
-# module-level `slow` marker, leaving a <2-minute inner-loop tier:
-#   python -m pytest -m "not slow" -q     (fast tier)
+# module-level `slow` marker. What is left is tier-1, and it is no inner loop:
+# twelve to fifteen minutes of six workers (2026-10, PR 56: 696-891 s,
+# 3906-4979 worker-seconds, 1757 cases, under ``-n 6 --dist loadfile`` on
+# eight shared cores; twenty-five minutes and cut by its limit before the
+# run's compilation cache above).
+#   python -m pytest -m "not slow" -q     (tier-1, one process)
 #   python -m pytest -q                   (everything)
-# Re-measure when adding heavy suites; pyproject registers the marker.
+# Re-measure when adding heavy suites (seconds per file and worker: the
+# driver's command of ROADMAP.md with ``--durations=0 --durations-min=1 -o
+# junit_family=xunit2 --junitxml=<file>``, summed by file); pyproject
+# registers the marker.
 SLOW_MODULES = {
     "test_aio", "test_api", "test_audio", "test_cli", "test_controlnet",
     "test_engine",
@@ -35,16 +70,29 @@ SLOW_MODULES = {
 }
 
 
-# The tier-1 files that take longest (minutes of one worker, 2026-10), longest
-# first. They are collected FIRST: under ``-n 6 --dist loadfile`` a file goes
-# whole to the next free worker in collection order, and a ten-minute file
-# that starts last (``test_tpu_compile`` by the alphabet) is the run's tail.
+# The tier-1 files of half a minute and more, longest first (seconds of one
+# of six workers, 2026-10, PR 56: 643 for the first, 31 for the last). They
+# are collected FIRST: under ``-n 6 --dist loadfile`` a file goes whole to the
+# next free worker in collection order, so the run's wall is a sixth of the
+# files' sum and no late file is its tail (``test_tpu_compile``, one file for
+# libtpu's lock, is last by the alphabet). Rewrite it from a run's table.
 LONGEST_FIRST = (
-    "test_paged", "test_tpu_compile", "test_qwen3_next", "test_afmoe",
-    "test_bench_walk", "test_deepseek", "test_dots3", "test_overlap",
-    "test_kv_contract", "test_chip_smoke", "test_ouro", "test_spec",
-    "test_dots3_compile", "test_prefill_span",
+    "test_tpu_compile", "test_paged", "test_qwen3_next", "test_afmoe",
+    "test_deepseek", "test_dots3", "test_chip_smoke", "test_kv_contract",
+    "test_bench_walk_latent", "test_bench_walk", "test_overlap",
+    "test_neighbour_texts", "test_ouro", "test_dots3_compile",
+    "test_paged_serving", "test_falcon_h1", "test_moe_kernel",
+    "test_prefill_span", "test_int4", "test_spec", "test_bench_trace",
+    "test_falcon_h1_compile", "test_sampling", "test_fleet",
+    "test_scheduler",
 )
+
+
+def pytest_configure(config):
+    # xdist hands out the files with the MOST CASES first unless told not to
+    # (``--no-loadscope-reorder``: its option for a suite that orders its own
+    # files), which leaves LONGEST_FIRST a tie-break
+    config.option.loadscopereorder = False
 
 
 def pytest_collection_modifyitems(config, items):

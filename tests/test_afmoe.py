@@ -17,28 +17,23 @@ forward over prompt + served tokens, no cache.
 """
 
 import dataclasses
-import hashlib
-import sys
+import functools
 import time
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import agree, reference_logits, served_logits, tap
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu.engine import kvcache as kvc  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import afmoe as af  # noqa: E402
-from localai_tpu.models import experts as xp  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import synthetic_params  # noqa: E402
-from test_qwen3_next import agree, tap  # noqa: E402
+from localai_tpu.engine import kvcache as kvc
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import afmoe as af
+from localai_tpu.models import experts as xp
+from localai_tpu.models import llama as mdl
+from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.registry import synthetic_params
 
 S, F = af.WINDOW, af.FULL
 LAYERS = 5
@@ -74,8 +69,7 @@ BF16_MEAN_TOL, BF16_TOL = 0.1, 1.0
 
 @pytest.fixture(scope="module")
 def family():
-    return spec.load_family(spec.family_file(
-        {"reference": {"family": "afmoe_family"}}, "tests/test_afmoe.py"))
+    return families.reference_family("afmoe_family", "tests/test_afmoe.py")
 
 
 # 2 dense layers (S S) and 2 rows (S F S S): more than one of each
@@ -83,9 +77,7 @@ DEEP = {"num_hidden_layers": 10, "num_dense_layers": 2,
         "layer_types": [F if (i + 1) % 4 == 0 else S for i in range(10)]}
 
 
-def config(dtype="float32", **changed):
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = functools.partial(families.config, HF)
 
 
 def seeded_params(cfg, seed: int = 0):
@@ -94,20 +86,17 @@ def seeded_params(cfg, seed: int = 0):
     0.3 N (it must MOVE choices: the scores spread over ~0.2-0.8) and the
     matrices three times as large, so that every branch weighs on the
     logits."""
-    params = mdl.init_params(jax.random.key(seed), cfg)
     rng = np.random.default_rng(seed + 1)
 
     def redraw(name, a):
         if name.endswith("norm"):
-            return jnp.asarray(1 + 0.3 * rng.standard_normal(a.shape),
-                               a.dtype)
+            return families.gain(rng, a)
         if name == "expert_bias":
-            return jnp.asarray(0.3 * rng.standard_normal(a.shape), a.dtype)
-        return (3.0 * a.astype(jnp.float32)).astype(a.dtype)
+            return families.gain(rng, a, centre=0.0)
+        return families.tripled(a)
 
-    out = {k: redraw(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: redraw(k, v) for k, v in params["layers"].items()}
-    return out
+    return families.redrawn(mdl.init_params(jax.random.key(seed), cfg),
+                            redraw)
 
 
 def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
@@ -120,24 +109,6 @@ def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
           "prefill_buckets": [16, 32], "attn_impl": impl,
           "kv_dtype": cfg.dtype, **kw}
     return ModelRunner(cfg, params, **kw)
-
-
-def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
-                  steps: int = STEPS):
-    """Prefill then ``steps`` decode steps through the pool: ([1 + steps, V]
-    logits, the greedy tokens)."""
-    mark = len(seen)
-    tokens = [r.admit(slot, prompt, temperature=0.0)]
-    tokens += [int(r.step()[slot]) for _ in range(steps)]
-    logits = np.stack([seen[mark][0]] + [row[slot] for row in seen[mark + 1:]])
-    return logits, tokens
-
-
-def reference_logits(family, params, hf, prompt, tokens, monkeypatch):
-    """The family's full forward over prompt + served tokens: [n, V]."""
-    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
-    seq = np.array([prompt + tokens[:-1]], np.int32)
-    return refcheck.reference_logits(params, family, hf, seq, len(tokens))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +132,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype, impl,
     assert cfg.row_kinds == ((S, F, S, S) if deep else (S, S, F, S))
     assert r.kinds == ((S, 8), (F, None)) and r.routed and not r.recurrent
     assert (r.family_kernels is not None) == (impl == "pallas_interpret")
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     assert r.admit_programs == 1 + 3            # the arming and three chunks
     assert r.kv.k.shape[0] == cfg.cache_layers == (10 if deep else 5)
     assert set(r.state.rec) == {"routed"} and r.state_bytes == 0
@@ -497,6 +468,14 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
         load_llama_params(tmp_path, hf=whole_hf, quantization="int8")
 
 
+@pytest.fixture(scope="module")
+def deep():
+    """An even number of layers, for the pipe: the configuration and its
+    ``init_params``, drawn once for a runner that refuses them unread."""
+    cfg = config(**DEEP)
+    return cfg, mdl.init_params(jax.random.key(0), cfg)
+
+
 @pytest.mark.parametrize("what, kw", [
     ("the contiguous K/V layout", {"paged": False}),
     ("a int8 K/V pool", {"kv_dtype": "int8"}),
@@ -504,17 +483,15 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
     ("a device mesh", {"mesh": {"model": 2}}),
     ("pipeline parallelism", {"mesh": {"pipe": 2}}),
 ])
-def test_what_the_kinds_cannot_be_served_through_is_refused(what, kw):
+def test_what_the_kinds_cannot_be_served_through_is_refused(deep, what, kw):
     from localai_tpu.parallel.mesh import MeshPlan, build_mesh
 
-    cfg = config(**DEEP)        # an even number of layers, for the pipe
     if "mesh" in kw:
-        n = 2
         kw["mesh"] = build_mesh(MeshPlan(**kw["mesh"]),
-                                devices=jax.devices()[:n])
+                                devices=jax.devices()[:2])
     with pytest.raises(ValueError, match=f"^{what} is not served for "
                                          f"model_type afmoe"):
-        runner_for(cfg, mdl.init_params(jax.random.key(0), cfg), **kw)
+        runner_for(*deep, **kw)
 
 
 def test_the_synthetic_gains_are_a_checkpoints_kind_of_draw():
@@ -554,85 +531,6 @@ def test_speculation_and_quantization_are_refused():
         r.verify_async(np.zeros((4, 2), np.int32))
     with pytest.raises(ValueError, match="engine.quantization 'int8'"):
         synthetic_params(cfg, "int8")
-
-
-# sha256 of the lowered text (StableHLO, no debug info) of a small
-# ``qwen3_next`` runner's programs, taken from the PARENT commit of PR 44 by
-# ``qn_texts`` below under this installation (jax 0.9.0): the routing and the
-# expert dispatch moved out of models/qwen3_next.py into models/experts.py,
-# and the sparse hybrid's programs are the parent's to the letter, the kernel
-# path's and the XLA loop's. PR 45 retook the six of programs that sample
-# (``prefill_0`` stands as taken) for the one ``stablehlo.reduce_precision``
-# ``sample`` gained (tests/test_qwen3_next.py PARENT_TEXT says how checked).
-# PR 46 retook the kernel path's two DECODE programs, which now hold
-# ops/gdn.py's kernel where they sliced the state and stepped it as XLA; its
-# chunks and all four of the XLA path stand as taken: the step they run moved
-# into ``recur`` and lowers to the letter it did. PR 50 retook the same two
-# (the paged kernel of its full-attention layers does no work for a slot on
-# the trash block); the chunks and the XLA path stand.
-QN_PARENT_TEXT = {
-    "pallas_interpret": {
-        "decode":
-            "e4e6c6be85c5f816aba14559c96f7c5a34c73b67a84fdec4b4c1ce496ba7baea",
-        "decode_n":
-            "f0d7dea69dcd89fcef6c498b8b8448527f27ec335ee2c3c8bc9a4a4c95caf330",
-        "prefill_1":
-            "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
-        "prefill_0":
-            "7dd2443df4e98f837b8555fd6623c5d128539b89e653c9fed3ac59289142fc08",
-    },
-    "xla": {
-        "decode":
-            "e03b0cae3c794183ef5b23d3bcf79397bbc84f6ff63a830980e9b553ba43d173",
-        "decode_n":
-            "3aaf8ca9f6fb5474663d4cf30601161f90b86f2391b608129d12e13ebc461e08",
-        "prefill_1":
-            "6f692f72bdc7e9cc5a38c698b9e8b4b1b6e01a318b2b0c75bb47c1a30437443c",
-        "prefill_0":
-            "e0664a26be528c1a5f909534d54256275c2044f56213e4b2e664ea4e0ab56074",
-    },
-}
-QN_HF = {"model_type": "qwen3_next", "vocab_size": 384, "hidden_size": 64,
-         "num_hidden_layers": 8, "num_attention_heads": 4,
-         "num_key_value_heads": 2, "head_dim": 128, "rope_theta": 1e7,
-         "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
-         "tie_word_embeddings": False, "num_experts": 4,
-         "num_experts_per_tok": 3, "full_attention_interval": 4,
-         "linear_num_key_heads": 2, "linear_num_value_heads": 4,
-         "linear_key_head_dim": 16, "linear_value_head_dim": 16,
-         "linear_conv_kernel_dim": 4, "partial_rotary_factor": 0.25,
-         "moe_intermediate_size": 128, "shared_expert_intermediate_size": 32,
-         "norm_topk_prob": True, "expert_parallel": {"size": 2, "rank": 1}}
-
-
-def qn_texts(attn_impl: str) -> dict:
-    cfg = dataclasses.replace(LlamaConfig.from_hf(QN_HF), dtype="bfloat16")
-    r = ModelRunner(cfg, mdl.init_params(jax.random.key(0), cfg),
-                    num_slots=4, max_ctx=128, paged=True, kv_block_tokens=16,
-                    attn_impl=attn_impl)
-    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
-             r.block_tables[0], jnp.int32(0),
-             jnp.zeros(cfg.vocab_size, jnp.int32))
-    out = {
-        "decode": jax.jit(r._decode_paged_fn).lower(
-            r.params, r.kv, r.state, r.block_tables).as_text(),
-        "decode_n": jax.jit(
-            r._decode_paged_n_fn, static_argnames=("n",)).lower(
-                r.params, r.kv, r.state, r.block_tables, n=4).as_text()}
-    prefill = jax.jit(r._prefill_paged_fn,
-                      static_argnames=("bucket", "sample"))
-    for sample in (True, False):
-        out[f"prefill_{int(sample)}"] = prefill.lower(
-            r.params, r.kv, r.state, *chunk, bucket=32,
-            sample=sample).as_text()
-    return out
-
-
-@pytest.mark.parametrize("attn_impl", sorted(QN_PARENT_TEXT))
-def test_the_sparse_hybrids_programs_lower_to_the_parents_text(attn_impl):
-    now = {k: hashlib.sha256(t.encode()).hexdigest()
-           for k, t in qn_texts(attn_impl).items()}
-    assert now == QN_PARENT_TEXT[attn_impl]
 
 
 # ---------------------------------------------------------------------------

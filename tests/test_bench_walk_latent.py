@@ -1,0 +1,70 @@
+"""tests/test_bench_walk.py's other half, loaded the same way: the cases of
+``benchmark/tests/`` for the latent-attention family
+(``test_deepseek_family.py``), for the family with an indexer in front of it
+(``test_dots3_family.py``), each with a small model served through the
+harness and the control that fails, and for the state-space hybrid
+(``test_falcon_h1_family.py``: its server-free cases; the small model served
+and its failing control run with ``benchmark/tests/``, and
+tests/test_falcon_h1.py serves the family in tier-1)."""
+
+from test_bench_walk import _conftest, _load, _walk
+
+_ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
+_d3 = _load("test_dots3_family", conftest=_conftest, test_walk=_walk)
+_fh = _load("test_falcon_h1_family", conftest=_conftest, test_walk=_walk)
+
+# the fixtures those cases ask for
+bench_copy = _conftest.bench_copy
+cpu_peaks = _conftest.cpu_peaks
+params = _walk.params
+
+# PR 48's file: the latent-attention family's hand arithmetic, the catalog
+# row in the file, the dense prefix beside the expert layers, its cell, its two
+# readers, and a small model through the harness with the control that fails
+test_the_hand_arithmetic_of_the_latent_stacks_published_keys = (
+    _ds.test_the_hand_arithmetic_of_the_latent_stacks_published_keys)
+test_every_published_number_of_the_latent_stacks_catalog_row_is_in_the_file = (
+    _ds
+    .test_every_published_number_of_the_latent_stacks_catalog_row_is_in_the_file)
+test_the_served_pytree_is_a_dense_prefix_beside_the_expert_layers = (
+    _ds.test_the_served_pytree_is_a_dense_prefix_beside_the_expert_layers)
+test_the_latent_cell_reports_what_the_issue_names = (
+    _ds.test_the_latent_cell_reports_what_the_issue_names)
+test_the_mla_readers_read_the_ring_and_the_scopes = (
+    _ds.test_the_mla_readers_read_the_ring_and_the_scopes)
+test_a_latent_attention_model_runs_by_files_alone = (
+    _ds.test_a_latent_attention_model_runs_by_files_alone)
+test_the_control_fails_a_family_whose_router_knows_no_groups = (
+    _ds.test_the_control_fails_a_family_whose_router_knows_no_groups)
+# PR 51's file: the family with an indexer: its hand arithmetic, the catalog
+# row in the file, the dense and lone layers beside a row a period, its cell,
+# its five readers, and a small model through the harness (selection and window
+# binding) with the control that fails
+test_the_hand_arithmetic_of_the_sparse_stacks_published_keys = (
+    _d3.test_the_hand_arithmetic_of_the_sparse_stacks_published_keys)
+test_every_published_number_of_the_sparse_stacks_catalog_row_is_in_the_file = (
+    _d3
+    .test_every_published_number_of_the_sparse_stacks_catalog_row_is_in_the_file)
+test_the_served_pytree_is_dense_and_lone_layers_beside_a_row_a_period = (
+    _d3.test_the_served_pytree_is_dense_and_lone_layers_beside_a_row_a_period)
+test_the_sparse_cell_reports_what_the_issue_names = (
+    _d3.test_the_sparse_cell_reports_what_the_issue_names)
+test_the_five_readers_read_the_ring_and_the_scopes = (
+    _d3.test_the_five_readers_read_the_ring_and_the_scopes)
+test_a_sparse_attention_model_runs_by_files_alone = (
+    _d3.test_a_sparse_attention_model_runs_by_files_alone)
+test_the_control_fails_a_family_whose_full_layers_attend_every_row = (
+    _d3.test_the_control_fails_a_family_whose_full_layers_attend_every_row)
+# PR 55's file: the state-space hybrid's hand arithmetic, the catalog row in
+# the file, a row a layer with pool AND state, its cell, its two readers
+test_the_hand_arithmetic_of_the_state_space_hybrids_published_keys = (
+    _fh.test_the_hand_arithmetic_of_the_state_space_hybrids_published_keys)
+test_every_published_number_of_the_state_space_hybrids_catalog_row_is_in_the_file = (
+    _fh
+    .test_every_published_number_of_the_state_space_hybrids_catalog_row_is_in_the_file)
+test_the_served_stack_is_a_row_a_layer_with_pool_and_state = (
+    _fh.test_the_served_stack_is_a_row_a_layer_with_pool_and_state)
+test_the_state_space_cell_reports_what_the_issue_names = (
+    _fh.test_the_state_space_cell_reports_what_the_issue_names)
+test_the_ssm_readers_read_the_ring_and_the_scopes = (
+    _fh.test_the_ssm_readers_read_the_ring_and_the_scopes)

@@ -17,31 +17,27 @@ prompt + served tokens, in the published, decompressed form, no cache.
 """
 
 import dataclasses
-import hashlib
+import functools
 import json
-import sys
 import time
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import (ROOT, agree, lowered_texts, refcheck,
+                      reference_logits, served_logits, spec, tap)
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu import ops  # noqa: E402
-from localai_tpu.engine import kvcache as kvc  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import deepseek as ds  # noqa: E402
-from localai_tpu.models import experts as xp  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import synthetic_params  # noqa: E402
-from localai_tpu.ops import attention as att  # noqa: E402
-from test_qwen3_next import agree, tap  # noqa: E402
+from localai_tpu import ops
+from localai_tpu.engine import kvcache as kvc
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import deepseek as ds
+from localai_tpu.models import experts as xp
+from localai_tpu.models import llama as mdl
+from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.registry import synthetic_params
+from localai_tpu.ops import attention as att
 
 YARN = {"type": "yarn", "factor": 4, "original_max_position_embeddings": 16,
         "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}
@@ -77,18 +73,15 @@ BF16_MEAN_TOL, BF16_TOL = 0.05, 0.6
 
 @pytest.fixture(scope="module")
 def family():
-    return spec.load_family(spec.family_file(
-        {"reference": {"family": "deepseek_family"}},
-        "tests/test_deepseek.py"))
+    return families.reference_family("deepseek_family",
+                                     "tests/test_deepseek.py")
 
 
 # two dense layers and three expert layers: more than one of each
 DEEP = {"num_hidden_layers": 5, "first_k_dense_replace": 2}
 
 
-def config(dtype="float32", **changed):
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = functools.partial(families.config, HF)
 
 
 def seeded_params(cfg, seed: int = 0):
@@ -96,18 +89,14 @@ def seeded_params(cfg, seed: int = 0):
     (at a constant, dropping or swapping a norm would change little) and the
     matrices three times as large, so that every branch weighs on the
     logits."""
-    params = mdl.init_params(jax.random.key(seed), cfg)
     rng = np.random.default_rng(seed + 1)
 
     def redraw(name, a):
-        if name.endswith("norm"):
-            return jnp.asarray(1 + 0.3 * rng.standard_normal(a.shape),
-                               a.dtype)
-        return (3.0 * a.astype(jnp.float32)).astype(a.dtype)
+        return (families.gain(rng, a) if name.endswith("norm")
+                else families.tripled(a))
 
-    out = {k: redraw(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: redraw(k, v) for k, v in params["layers"].items()}
-    return out
+    return families.redrawn(mdl.init_params(jax.random.key(seed), cfg),
+                            redraw)
 
 
 def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
@@ -120,24 +109,6 @@ def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
           "prefill_buckets": [16, 32], "attn_impl": impl,
           "kv_dtype": cfg.dtype, **kw}
     return ModelRunner(cfg, params, **kw)
-
-
-def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
-                  steps: int = STEPS, **admit):
-    """Prefill then ``steps`` decode steps through the pool: ([1 + steps, V]
-    logits, the greedy tokens)."""
-    mark = len(seen)
-    tokens = [r.admit(slot, prompt, temperature=0.0, **admit)]
-    tokens += [int(r.step()[slot]) for _ in range(steps)]
-    logits = np.stack([seen[mark][0]] + [row[slot] for row in seen[mark + 1:]])
-    return logits, tokens
-
-
-def reference_logits(family, params, hf, prompt, tokens, monkeypatch):
-    """The family's full forward over prompt + served tokens: [n, V]."""
-    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
-    seq = np.array([prompt + tokens[:-1]], np.int32)
-    return refcheck.reference_logits(params, family, hf, seq, len(tokens))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +135,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype, impl,
     assert r.paged_kv_write_impl == (
         "kernel" if impl == "pallas_interpret" else "scatter")
     assert (r.family_kernels is not None) == (impl == "pallas_interpret")
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     assert r.admit_programs == 1 + 3            # the arming and three chunks
     # ONE array: a row a token a layer, 40 elements in 128 lanes, no heads
     assert r.kv.c.shape == (cfg.num_layers, r.allocator.num_blocks,
@@ -651,6 +622,14 @@ def test_a_config_the_family_cannot_hold_is_refused(changed, says):
 SENTENCE = "is not served for model_type axk1: its latent attention reads"
 
 
+@pytest.fixture(scope="module")
+def four_layers():
+    """An even number of layers, for the pipe: the configuration and its
+    ``init_params``, drawn once for a runner that refuses them unread."""
+    cfg = config(num_hidden_layers=4)
+    return cfg, mdl.init_params(jax.random.key(0), cfg)
+
+
 @pytest.mark.parametrize("what, kw", [
     ("the contiguous K/V layout", {"paged": False}),
     ("a int8 K/V pool", {"kv_dtype": "int8"}),
@@ -660,15 +639,15 @@ SENTENCE = "is not served for model_type axk1: its latent attention reads"
     ("the ring prefill", {"mesh": {"seq": 2}}),
     ("pipeline parallelism", {"mesh": {"pipe": 2}}),
 ])
-def test_what_latent_rows_cannot_be_served_through_is_refused(what, kw):
+def test_what_latent_rows_cannot_be_served_through_is_refused(four_layers,
+                                                              what, kw):
     from localai_tpu.parallel.mesh import MeshPlan, build_mesh
 
-    cfg = config(num_hidden_layers=4)   # an even number, for the pipe
     if "mesh" in kw:
         kw["mesh"] = build_mesh(MeshPlan(**kw["mesh"]),
                                 devices=jax.devices()[:2])
     with pytest.raises(ValueError, match=f"^{what} {SENTENCE}"):
-        runner_for(cfg, mdl.init_params(jax.random.key(0), cfg), **kw)
+        runner_for(*four_layers, **kw)
 
 
 def test_speculation_and_quantised_weights_are_refused():
@@ -757,77 +736,6 @@ def test_the_configuration_file_is_the_published_row_cut_as_stated(family):
     assert built == family.param_count(hf) == 4_841_331_712
 
 
-# sha256 of the lowered text (StableHLO, no debug info) of a small ``afmoe``
-# runner's programs, taken at the PARENT commit of PR 48 (fdb73cd) by
-# ``af_texts`` below under this installation (jax 0.9.0): the scoring rule
-# both sigmoid families call gained its group limit and the runner a third
-# layout, and the window / full stack's programs (``trl-ep8-longshort-
-# decode``'s) are the parent's to the letter; the sparse hybrid's
-# (``qn80-ep8-decode``'s) are held by tests/test_afmoe.py's QN_PARENT_TEXT,
-# which stands as taken. PR 50 retook the kernel path's ``decode`` (the paged
-# kernel's body changed: no work for a slot on the trash block); its chunks
-# and the XLA path stand as taken.
-AF_PARENT_TEXT = {
-    "pallas_interpret": {
-        "decode":
-            "b55d2367aa650714233ee17e5d1f10efeeae5efcac5128768abdf01c98a4ceab",
-        "prefill_1":
-            "189c6e43b3a847679d68d877a305967c95504f428a42e3730bbeb7aa183b4259",
-        "prefill_0":
-            "1cce56d6e5a0511bafb613df2491ad4b765f08a4ff89fb10d6d5359f2f2a5ae0",
-    },
-    "xla": {
-        "decode":
-            "1bb6388ecbf095a4630444bc8df62a50933f131d6bae279be4abe39538cabb4f",
-        "prefill_1":
-            "04536ef99315d04002ebb520178a17cf5e4ce1a4e134c6fb448dbf3f6c485205",
-        "prefill_0":
-            "7b26cacc9110cc1afa89e3dc26bbdc09def606e4a4e38a87e1f838d74122ee0c",
-    },
-}
-S_, F_ = "sliding_attention", "full_attention"
-AF_HF = {"model_type": "afmoe", "vocab_size": 384, "hidden_size": 64,
-         "intermediate_size": 96, "num_hidden_layers": 5,
-         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
-         "rope_theta": 10000, "rms_norm_eps": 1e-5,
-         "max_position_embeddings": 512, "tie_word_embeddings": False,
-         "sliding_window": 8, "global_attn_every_n_layers": 4,
-         "layer_types": [F_ if (i + 1) % 4 == 0 else S_ for i in range(5)],
-         "num_dense_layers": 1, "num_experts": 8, "num_experts_per_tok": 2,
-         "moe_intermediate_size": 128, "num_shared_experts": 1,
-         "score_func": "sigmoid", "route_norm": True, "route_scale": 2.448,
-         "mup_enabled": True, "n_group": 1, "topk_group": 1,
-         "num_expert_groups": 1, "num_limited_groups": 1,
-         "expert_parallel": {"size": 2, "rank": 1}}
-
-
-def lowered(r: ModelRunner, cfg, debug_info: bool = False) -> dict:
-    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
-             r.block_tables[0], jnp.int32(0),
-             jnp.zeros(cfg.vocab_size, jnp.int32))
-    out = {"decode": jax.jit(r._decode_paged_fn).lower(
-        r.params, r.kv, r.state, r.block_tables).as_text(
-            debug_info=debug_info)}
-    prefill = jax.jit(r._prefill_paged_fn,
-                      static_argnames=("bucket", "sample"))
-    for sample in (True, False):
-        out[f"prefill_{int(sample)}"] = prefill.lower(
-            r.params, r.kv, r.state, *chunk, bucket=32,
-            sample=sample).as_text(debug_info=debug_info)
-    return out
-
-
-@pytest.mark.parametrize("attn_impl", sorted(AF_PARENT_TEXT))
-def test_the_mixed_stacks_programs_lower_to_the_parents_text(attn_impl):
-    cfg = dataclasses.replace(LlamaConfig.from_hf(AF_HF), dtype="bfloat16")
-    r = ModelRunner(cfg, mdl.init_params(jax.random.key(0), cfg),
-                    num_slots=4, max_ctx=128, paged=True, kv_block_tokens=16,
-                    attn_impl=attn_impl)
-    now = {k: hashlib.sha256(t.encode()).hexdigest()
-           for k, t in lowered(r, cfg).items()}
-    assert now == AF_PARENT_TEXT[attn_impl]
-
-
 # ---------------------------------------------------------------------------
 # (f) the scopes; the scheduler's counts
 
@@ -837,7 +745,7 @@ def test_the_programs_name_the_two_paths_and_the_expert_scopes():
     r = runner_for(cfg, mdl.init_params(jax.random.key(0), cfg),
                    "pallas_interpret", kv_block_tokens=32, max_ctx=128,
                    prefill_chunk=32, prefill_buckets=[32])
-    text = lowered(r, cfg, debug_info=True)
+    text = lowered_texts(r, ("decode", "prefill_1"), debug_info=True)
     for scope in ("mla/q", "mla/kv_a", "mla/o",
                   "attn.latent_decode/latent_decode_attn", "moe/router",
                   "moe/experts/moe_experts", "moe/shared", "dense_mlp"):

@@ -17,31 +17,25 @@ served tokens, published form, ``lax.top_k`` for the selection, no cache.
 """
 
 import dataclasses
-import hashlib
+import functools
 import re
-import sys
 import time
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import (agree, lowered_texts, reference_logits, served_logits,
+                      tap)
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu.engine import kvcache as kvc  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import deepseek as ds  # noqa: E402
-from localai_tpu.models import dots3  # noqa: E402
-from localai_tpu.models import experts as xp  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import synthetic_params  # noqa: E402
-import test_deepseek as axk1  # noqa: E402
-from test_qwen3_next import agree, tap  # noqa: E402
+from localai_tpu.engine import kvcache as kvc
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import deepseek as ds
+from localai_tpu.models import dots3
+from localai_tpu.models import experts as xp
+from localai_tpu.models import llama as mdl
+from localai_tpu.models.registry import synthetic_params
 
 F, S = dots3.FULL, dots3.WINDOW
 TYPES = [F, F] + [S, S, S, F] * 11
@@ -88,15 +82,12 @@ def family():
     """The benchmark's family module; its walk pads the probes to whole
     multiples of 64 positions here (1152 where the harness runs it: the
     tests/test_bench_walk.py cases), the sequences here being 37 to 47."""
-    fam = spec.load_family(spec.family_file(
-        {"reference": {"family": "dots3_family"}}, "tests/test_dots3.py"))
+    fam = families.reference_family("dots3_family", "tests/test_dots3.py")
     fam.PROBE_PAD = 64
     return fam
 
 
-def config(dtype="float32", **changed):
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = functools.partial(families.config, HF)
 
 
 def seeded_params(cfg, seed: int = 0):
@@ -104,19 +95,23 @@ def seeded_params(cfg, seed: int = 0):
     key's LayerNorm) redrawn at 1 + 0.3 N, the matrices three times as
     large and the selection bias ten times, so that every term weighs on
     the logits."""
-    params = mdl.init_params(jax.random.key(seed), cfg)
     rng = np.random.default_rng(seed + 1)
 
     def redraw(name, a):
         if name.endswith("norm"):
-            return jnp.asarray(1 + 0.3 * rng.standard_normal(a.shape),
-                               a.dtype)
+            return families.gain(rng, a)
         scale = 10.0 if name.endswith("expert_bias") else 3.0
         return (scale * a.astype(jnp.float32)).astype(a.dtype)
 
-    out = {k: redraw(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: redraw(k, v) for k, v in params["layers"].items()}
-    return out
+    return families.redrawn(mdl.init_params(jax.random.key(seed), cfg),
+                            redraw)
+
+
+@pytest.fixture(scope="module")
+def drawn():
+    """``init_params`` of the small configuration, drawn once for the cases
+    that only hand them to a runner (most of which refuses them unread)."""
+    return mdl.init_params(jax.random.key(0), config())
 
 
 def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
@@ -124,10 +119,6 @@ def runner_for(cfg, params, impl="xla", **kw) -> ModelRunner:
           "prefill_chunk": 16, "prefill_buckets": [16, 32],
           "attn_impl": impl, "kv_dtype": cfg.dtype, **kw}
     return ModelRunner(cfg, params, **kw)
-
-
-served_logits = axk1.served_logits
-reference_logits = axk1.reference_logits
 
 
 # ---------------------------------------------------------------------------
@@ -152,7 +143,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype, impl,
     assert isinstance(r.layout, kvc.LatentLayout)
     assert r.paged_kv_write_impl == "scatter"
     assert (r.family_kernels is not None) == (impl == "pallas_interpret")
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     assert r.admit_programs == 1 + 3
     # THREE arrays under one table: full rows, window rows, index keys
     nf, nw = (4, 6) if deep else (3, 3)
@@ -722,20 +713,20 @@ SENTENCE = ("is not served for model_type dots3_note: its full layers "
     ("the ring prefill", {"mesh": {"seq": 2}}),
     ("pipeline parallelism", {"mesh": {"pipe": 2}}),
 ])
-def test_what_the_three_arrays_cannot_be_served_through_is_refused(what, kw):
+def test_what_the_three_arrays_cannot_be_served_through_is_refused(
+        drawn, what, kw):
     from localai_tpu.parallel.mesh import MeshPlan, build_mesh
 
-    cfg = config()
     if "mesh" in kw:
         kw["mesh"] = build_mesh(MeshPlan(**kw["mesh"]),
                                 devices=jax.devices()[:2])
     with pytest.raises(ValueError, match=f"^{what} {SENTENCE}"):
-        runner_for(cfg, mdl.init_params(jax.random.key(0), cfg), **kw)
+        runner_for(config(), drawn, **kw)
 
 
-def test_speculation_and_quantised_weights_are_refused():
+def test_speculation_and_quantised_weights_are_refused(drawn):
     cfg = config()
-    r = runner_for(cfg, mdl.init_params(jax.random.key(0), cfg))
+    r = runner_for(cfg, drawn)
     with pytest.raises(ValueError, match=f"^speculative decoding {SENTENCE}"):
         r.verify_async(np.zeros((4, 2), np.int32))
     with pytest.raises(ValueError,
@@ -747,61 +738,6 @@ def test_speculation_and_quantised_weights_are_refused():
 
 
 # ---------------------------------------------------------------------------
-# (d) the configurations that share the changed code lower to the parent's
-# text
-
-
-# sha256 of the lowered text (StableHLO, no debug info) of a small ``axk1``
-# runner's programs (tests/test_deepseek.py's HF in bfloat16, 4 slots, 128
-# positions, blocks of 32), taken at the PARENT commit of PR 51 (4d619ad)
-# under this installation (jax 0.9.0): ``LatentKVCache`` gained two optional
-# arrays, the latent writes a ``state`` argument, ``latent_span_attend`` its
-# window and its marks, ``models.deepseek._attention`` its hooks, and the
-# one-array latent stack's programs (``axk1-ep16-longdoc-decode``'s) are the
-# parent's to the letter. The window / full stack's
-# (``trl-ep8-longshort-decode``'s) are tests/test_deepseek.py's
-# AF_PARENT_TEXT, which stands as taken.
-AX_PARENT_TEXT = {
-    "pallas_interpret": {
-        "decode":
-            "f5d4281f4d63c43b523aa2cfb605e7be023b0a7619373a045d54f32689d1b048",
-        "prefill_1":
-            "f193eb88ea2d0088837ce2736de3a3e4264560810215d62789ae1993d81ee02b",
-        "prefill_0":
-            "f8435bf0075356551b7ce0db16502d80079dd23d56a5a17f927b69d4ee42a447",
-    },
-    "xla": {
-        "decode":
-            "7237f1b5326f8e9029871b878b780dacbfd1c14a75640f188bdcc41a190e2cab",
-        "prefill_1":
-            "2428dc28c173ea72bb07592160c5a91824c59991f11929c3085adfbda9797469",
-        "prefill_0":
-            "bb13789487d6c403c0e58d26662203bf47b6bbe8d303da9e8442e6d75cb1b318",
-    },
-}
-
-
-@pytest.mark.parametrize("which, attn_impl", [
-    ("axk1", "pallas_interpret"), ("axk1", "xla"),
-    # (its kernel path: tests/test_deepseek.py, every run of the suite)
-    ("afmoe", "xla")])
-def test_the_sibling_stacks_programs_lower_to_the_parents_text(which,
-                                                               attn_impl):
-    if which == "axk1":
-        cfg, block, want = axk1.config("bfloat16"), 32, AX_PARENT_TEXT
-    else:
-        cfg = dataclasses.replace(LlamaConfig.from_hf(axk1.AF_HF),
-                                  dtype="bfloat16")
-        block, want = 16, axk1.AF_PARENT_TEXT
-    r = ModelRunner(cfg, mdl.init_params(jax.random.key(0), cfg),
-                    num_slots=4, max_ctx=128, paged=True,
-                    kv_block_tokens=block, attn_impl=attn_impl)
-    now = {k: hashlib.sha256(t.encode()).hexdigest()
-           for k, t in axk1.lowered(r, cfg).items()}
-    assert now == want[attn_impl]
-
-
-# ---------------------------------------------------------------------------
 # (e) the scopes; the scheduler's counts
 
 
@@ -809,7 +745,7 @@ def test_the_programs_name_the_new_scopes():
     cfg = config("bfloat16")
     r = runner_for(cfg, mdl.init_params(jax.random.key(0), cfg),
                    prefill_chunk=32, prefill_buckets=[32])
-    text = axk1.lowered(r, cfg, debug_info=True)
+    text = lowered_texts(r, ("decode", "prefill_1"), debug_info=True)
     for scope in ("mla/q", "mla/kv_a", "mla/o", "mla/gate", "dsa/q", "dsa/k",
                   "attn.index", "attn.select", "attn.sparse_decode",
                   "attn.latent_window", "moe/experts", "dense_mlp"):

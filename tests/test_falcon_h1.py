@@ -14,25 +14,21 @@ over prompt + served tokens, no cache, no state carried.
 """
 
 import dataclasses
-import sys
 from functools import partial
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import refcheck, reference_logits, served_logits, tap
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import falcon_h1 as fh  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models import quant as qnt  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import synthetic_params  # noqa: E402
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import falcon_h1 as fh
+from localai_tpu.models import llama as mdl
+from localai_tpu.models import quant as qnt
+from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.registry import synthetic_params
 
 HF = {"model_type": "falcon_h1", "vocab_size": 384, "hidden_size": 64,
       "intermediate_size": 96, "num_hidden_layers": 3,
@@ -68,14 +64,11 @@ BF16_TOL, BF16_MEAN_TOL = 0.2, 0.04
 
 @pytest.fixture(scope="module")
 def family():
-    return spec.load_family(spec.family_file(
-        {"reference": {"family": "falcon_h1_family"}},
-        "tests/test_falcon_h1.py"))
+    return families.reference_family("falcon_h1_family",
+                                     "tests/test_falcon_h1.py")
 
 
-def config(dtype="float32", **changed):
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = partial(families.config, HF)
 
 
 @pytest.fixture(scope="module")
@@ -89,44 +82,6 @@ def served_params(params32, dtype: str, quantization: str):
     params = jax.tree.map(lambda a: a.astype(dtype), params32)
     return qnt.quantize_params(params, quantization) if quantization else (
         params)
-
-
-def tap(runner: ModelRunner) -> list:
-    """The runner's own prefill and decode programs, each also returning the
-    logits it samples from (``logits_from_hidden``'s result, taken inside
-    the same trace); the list they are appended to."""
-    seen: list = []
-
-    def wrap(fn, **jit_kw):
-        def with_logits(*a, **k):
-            inside: list = []
-            real = mdl.logits_from_hidden
-
-            def spy(cfg, params, x):
-                inside.append(real(cfg, params, x))
-                return inside[-1]
-
-            mdl.logits_from_hidden = spy
-            try:
-                out = fn(*a, **k)
-            finally:
-                mdl.logits_from_hidden = real
-            return out, (inside[0] if inside else None)
-
-        jitted = jax.jit(with_logits, **jit_kw)
-
-        def call(*a, **k):
-            out, logits = jitted(*a, **k)
-            if logits is not None:
-                seen.append(np.asarray(logits, np.float32))
-            return out
-
-        return call
-
-    runner._prefill_paged = wrap(runner._prefill_paged_fn,
-                                 static_argnames=("bucket", "sample"))
-    runner._decode_paged = wrap(runner._decode_paged_fn)
-    return seen
 
 
 STEP = ("xla", "kernel")
@@ -147,27 +102,8 @@ def runner_for(cfg, params, step="xla", **kw) -> ModelRunner:
     return r
 
 
-def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
-                  steps: int = STEPS):
-    """Prefill then ``steps`` decode steps through pool and state: ([1 +
-    steps, V] logits, the greedy tokens)."""
-    mark = len(seen)
-    tokens = [r.admit(slot, prompt, temperature=0.0)]
-    tokens += [int(r.step()[slot]) for _ in range(steps)]
-    logits = np.stack([seen[mark][0]] + [row[slot] for row in seen[mark + 1:]])
-    return logits, tokens
-
-
-def reference_logits(family, params, hf, prompt, tokens, monkeypatch):
-    """The family's full forward over prompt + served tokens: [n, V]."""
-    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
-    seq = np.array([prompt + tokens[:-1]], np.int32)
-    return refcheck.reference_logits(params, family, hf, seq, len(tokens))[0]
-
-
-def agree(served, ref, tol):
-    assert np.abs(ref).max() > 1.0          # logits that spread, not zeros
-    assert np.abs(served - ref).max() < tol, np.abs(served - ref).max()
+# logits that spread, not zeros
+agree = partial(families.agree, spread=1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +130,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, params32,
                      "ssm_dt_bias", "ssm_norm", "attn_norm", "mlp_norm"):
             assert params["layers"][name].dtype == jnp.bfloat16, name
     r = runner_for(cfg, params, step)
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     assert r.admit_programs == 1 + 2            # the arming and two chunks
     assert r.kv.k.shape[0] == LAYERS == cfg.cache_layers
     assert r.state.rec["S"].shape == (LAYERS, SLOTS, 4, 16, 8)

@@ -204,14 +204,19 @@ def test_telemetry_payload_no_scheduler_and_metrics_error():
 @pytest.fixture(scope="module")
 def worker():
     from localai_tpu.worker import WorkerClient
-    from localai_tpu.worker.server import serve_worker
+    from localai_tpu.worker.server import BackendServicer, serve_worker
 
-    server, port = serve_worker("127.0.0.1:0", block=False)
+    servicer = BackendServicer()
+    server, port = serve_worker("127.0.0.1:0", servicer=servicer, block=False)
     client = WorkerClient(f"127.0.0.1:{port}")
     res = client.load_model(config_yaml=TINY_YAML)
     assert res.success, res.message
     yield client
     client.close()
+    # the loaded model's engine thread goes with its worker: left running,
+    # it writes ``sched.*`` into whatever this process captures next
+    # (tests/test_profiler.py's two capture cases, when they share a worker)
+    servicer.shutdown()
     server.stop(grace=None)
 
 

@@ -1,0 +1,201 @@
+"""With another ``model_type`` nothing new is traced: sha256 of the lowered
+text (StableHLO, no debug info) of the programs of each configuration that
+shares code with the newer families, at small sizes, under this installation
+(jax 0.9.0). A row's hashes were taken from the PARENT commit of the PR that
+added it, by ``families.lowered_texts`` on the runner the row builds: a change
+that leaves a row standing left that configuration's programs as they were,
+to the letter, which is what lets a PR say "no other cell's program changed"
+before a chip run. A change that MEANS to alter a row retakes it from its own
+parent and says why, here. The next family adds a row, not a function.
+
+What each row has seen:
+
+- the three older cells (PR 41, when ``qwen3_next`` came): PR 45 retook the
+  nine of programs that sample (``arm`` stands as taken): ``sample`` holds a
+  bfloat16 head's float32 copy to bfloat16 with ONE
+  ``stablehlo.reduce_precision``; with that line taken out and the numbered
+  values aside, each text is PR 45's parent's line for line (the two-stage
+  candidates leave them alone: these vocabularies take the one ``top_k``).
+  PR 49 retook the four-chip trunk's two (``decode``, ``decode_n``): each is
+  the text PR 49's parent lowers under ``LOCALAI_MESH_OVERLAP=psum``, to the
+  letter (one ``psum`` a row-parallel product; the chunked form is gone). PR
+  50 retook the six ``decode`` / ``decode_n`` (the paged kernel's body
+  changed: it does no work for a slot on the trash block); ``prefill_1`` and
+  ``arm``, which hold no kernel, stand as taken.
+- ``qwen3_next`` (PR 44: the routing and the expert dispatch moved out of
+  models/qwen3_next.py into models/experts.py): PR 45 retook the six of
+  programs that sample (``prefill_0`` stands as taken) for the same one
+  ``stablehlo.reduce_precision``. PR 46 retook the kernel path's two DECODE
+  programs, which now hold ops/gdn.py's kernel where they sliced the state
+  and stepped it as XLA; its chunks and all four of the XLA path stand as
+  taken: the step they run moved into ``recur`` and lowers to the letter it
+  did. PR 50 retook the same two (the paged kernel of its full-attention
+  layers); the chunks and the XLA path stand.
+- ``afmoe`` (PR 48, parent fdb73cd: the scoring rule both sigmoid families
+  call gained its group limit and the runner a third layout): PR 50 retook
+  the kernel path's ``decode``; its chunks and the XLA path stand as taken.
+- ``axk1`` (PR 51, parent 4d619ad: ``LatentKVCache`` gained two optional
+  arrays, the latent writes a ``state`` argument, ``latent_span_attend`` its
+  window and its marks, ``models.deepseek._attention`` its hooks): as taken.
+- ``dots3_note`` (PR 56, this tree: the newest family with a cell, which no
+  row held; in the place of a second ``afmoe`` XLA row that tests/test_dots3.py
+  ran beside tests/test_deepseek.py's): as taken.
+"""
+
+import functools
+import hashlib
+import json
+
+import families
+import jax
+import pytest
+from families import ROOT
+from test_afmoe import HF as AFMOE
+from test_deepseek import HF as AXK1
+from test_dots3 import HF as DOTS3
+from test_qwen3_next import HF as QWEN3_NEXT
+
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import llama as mdl
+from localai_tpu.models.registry import synthetic_params
+
+# a head of 128 lanes and experts of 128 (what the compiled kernels take)
+WIDE = {"head_dim": 128, "moe_intermediate_size": 128}
+
+
+def cell_runner(name: str) -> ModelRunner:
+    """``benchmark/configs/<name>.json`` cut to two layers of small widths,
+    int8, the paged kernel in the interpreter; ``tp4`` over four devices."""
+    from localai_tpu.parallel.mesh import MeshPlan, build_mesh
+    from localai_tpu.parallel.sharding import ParamPlacement
+
+    doc = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
+    cut = {"hidden_size": 256, "intermediate_size": 512,
+           "num_hidden_layers": 2, "num_attention_heads": 4,
+           "num_key_value_heads": 2, "head_dim": 128, "vocab_size": 512,
+           "max_position_embeddings": 512}
+    mesh = None
+    if name.endswith("tp4"):
+        cut.update(num_attention_heads=8, num_key_value_heads=4)
+        mesh = build_mesh(MeshPlan(model=4), devices=jax.devices()[:4])
+    if name.startswith("ouro"):
+        cut.update(num_key_value_heads=4)
+    cfg = families.config({**doc, **cut}, "bfloat16")
+    params = synthetic_params(cfg, "int8", seed=0,
+                              placement=ParamPlacement(cfg, mesh))
+    r = ModelRunner(cfg, params, num_slots=4, max_ctx=128, paged=True,
+                    kv_block_tokens=16, attn_impl="pallas_interpret",
+                    mesh=mesh)
+    assert r.recurrent is False and r.state.rec is None
+    named = families.lowered_texts(r, ("decode",), debug_info=True)["decode"]
+    for scope in ("gdn/", "moe/", "attn_gate"):
+        assert scope not in named
+    return r
+
+
+def family_runner(hf: dict, block: int, attn_impl: str) -> ModelRunner:
+    cfg = families.config(hf, "bfloat16")
+    return ModelRunner(cfg, mdl.init_params(jax.random.key(0), cfg),
+                       num_slots=4, max_ctx=128, paged=True,
+                       kv_block_tokens=block, attn_impl=attn_impl)
+
+
+def cell(name, hashes):
+    return pytest.param(functools.partial(cell_runner, name), hashes, id=name)
+
+
+def family(hf, block, attn_impl, hashes):
+    return pytest.param(
+        functools.partial(family_runner, hf, block, attn_impl), hashes,
+        id=f"{hf['model_type']}-{attn_impl}")
+
+
+TAKEN = [
+    cell("mistral-7b-v0.3-int8", {
+        "decode":
+            "f2c5a0fbb56f1413911c177e72bed6bd30887e59922fd68ca56da7d902c4fc86",
+        "decode_n":
+            "aeb400bc8401e521c6fc5dd310790dd772aa96efba2835f42adbf4fff9eaba66",
+        "prefill_1":
+            "5da338912ddb6924ef2ad7c994a1a6db2a1d233d941281a99b3c37a6affe5b12",
+        "arm":
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499"}),
+    cell("mistral-small-24b-int8-tp4", {
+        "decode":
+            "17d1318cb3353870a3a30acc6c055c94e692ac446f0dabaf22599ca0d218224b",
+        "decode_n":
+            "0da1864b409456ed8239270fcd37a4c5c16fe1430aa48cc9bc85a50c62867bb2",
+        "prefill_1":
+            "72bfe1e112c7a68df594347a5684c47074932e0da55929f006b4730ebfa37af5",
+        "arm":
+            "3efff5c076c6e3e16b28936ccc7fd25d87950dbbcfc6726e4694f537db26b136"}),
+    cell("ouro-2.6b-int8", {
+        "decode":
+            "9f2f46c6133afb09f824ec5c6f79b8cadbb3617761ae1f7a0aa20fc39877c25a",
+        "decode_n":
+            "433dc057083dd983c5cc6f4b5c503965f67da4b1ff872a18a4450f40c6be0dd0",
+        "prefill_1":
+            "a6e0180316c86e3f77a14a239cb921c62f4bac783543a9b790d6eed56d97be94",
+        "arm":
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499"}),
+    family({**QWEN3_NEXT, **WIDE}, 16, "pallas_interpret", {
+        "decode":
+            "e4e6c6be85c5f816aba14559c96f7c5a34c73b67a84fdec4b4c1ce496ba7baea",
+        "decode_n":
+            "f0d7dea69dcd89fcef6c498b8b8448527f27ec335ee2c3c8bc9a4a4c95caf330",
+        "prefill_1":
+            "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
+        "prefill_0":
+            "7dd2443df4e98f837b8555fd6623c5d128539b89e653c9fed3ac59289142fc08"}),
+    family({**QWEN3_NEXT, **WIDE}, 16, "xla", {
+        "decode":
+            "e03b0cae3c794183ef5b23d3bcf79397bbc84f6ff63a830980e9b553ba43d173",
+        "decode_n":
+            "3aaf8ca9f6fb5474663d4cf30601161f90b86f2391b608129d12e13ebc461e08",
+        "prefill_1":
+            "6f692f72bdc7e9cc5a38c698b9e8b4b1b6e01a318b2b0c75bb47c1a30437443c",
+        "prefill_0":
+            "e0664a26be528c1a5f909534d54256275c2044f56213e4b2e664ea4e0ab56074"}),
+    family({**AFMOE, **WIDE}, 16, "pallas_interpret", {
+        "decode":
+            "b55d2367aa650714233ee17e5d1f10efeeae5efcac5128768abdf01c98a4ceab",
+        "prefill_1":
+            "189c6e43b3a847679d68d877a305967c95504f428a42e3730bbeb7aa183b4259",
+        "prefill_0":
+            "1cce56d6e5a0511bafb613df2491ad4b765f08a4ff89fb10d6d5359f2f2a5ae0"}),
+    family({**AFMOE, **WIDE}, 16, "xla", {
+        "decode":
+            "1bb6388ecbf095a4630444bc8df62a50933f131d6bae279be4abe39538cabb4f",
+        "prefill_1":
+            "04536ef99315d04002ebb520178a17cf5e4ce1a4e134c6fb448dbf3f6c485205",
+        "prefill_0":
+            "7b26cacc9110cc1afa89e3dc26bbdc09def606e4a4e38a87e1f838d74122ee0c"}),
+    family(AXK1, 32, "pallas_interpret", {
+        "decode":
+            "f5d4281f4d63c43b523aa2cfb605e7be023b0a7619373a045d54f32689d1b048",
+        "prefill_1":
+            "f193eb88ea2d0088837ce2736de3a3e4264560810215d62789ae1993d81ee02b",
+        "prefill_0":
+            "f8435bf0075356551b7ce0db16502d80079dd23d56a5a17f927b69d4ee42a447"}),
+    family(AXK1, 32, "xla", {
+        "decode":
+            "7237f1b5326f8e9029871b878b780dacbfd1c14a75640f188bdcc41a190e2cab",
+        "prefill_1":
+            "2428dc28c173ea72bb07592160c5a91824c59991f11929c3085adfbda9797469",
+        "prefill_0":
+            "bb13789487d6c403c0e58d26662203bf47b6bbe8d303da9e8442e6d75cb1b318"}),
+    family(DOTS3, 32, "xla", {
+        "decode":
+            "8c4db33a351256b2c48607c64735ca82dad6ed3ca9443d391c957f38c114cfbe",
+        "prefill_1":
+            "c84221f04fe52131fbd26b46c8c604e945d57ecb884f7c363ccf492875cccb43",
+        "prefill_0":
+            "9d92593db19120a980fc7fc7d7a6e486c06fcfbe9e90d275022a77c141cf1e64"}),
+]
+
+
+@pytest.mark.parametrize("build, taken", TAKEN)
+def test_the_programs_lower_to_the_text_taken(build, taken):
+    now = {name: hashlib.sha256(text.encode()).hexdigest()
+           for name, text in families.lowered_texts(build(), taken).items()}
+    assert now == taken

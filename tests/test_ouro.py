@@ -13,29 +13,25 @@ from the published description) run as the benchmark runs it
 """
 
 import dataclasses
+import functools
 import json
-import sys
 import time
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import ROOT, tap
 from jax import lax
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu import ops  # noqa: E402
-from localai_tpu.engine import kvcache as kvc  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models import quant as qnt  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import (DEBUG_PRESETS,  # noqa: E402
-                                         synthetic_params)
+from localai_tpu import ops
+from localai_tpu.engine import kvcache as kvc
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import llama as mdl
+from localai_tpu.models import quant as qnt
+from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.registry import DEBUG_PRESETS, synthetic_params
 
 HF = {"model_type": "ouro", "vocab_size": 512, "hidden_size": 64,
       "intermediate_size": 128, "num_hidden_layers": 2,
@@ -58,13 +54,10 @@ BF16_TOL = 0.012
 
 @pytest.fixture(scope="module")
 def family():
-    return spec.load_family(spec.family_file(
-        {"reference": {"family": "ouro_family"}}, "tests/test_ouro.py"))
+    return families.reference_family("ouro_family", "tests/test_ouro.py")
 
 
-def config(dtype="float32", **changed) -> LlamaConfig:
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = functools.partial(families.config, HF)
 
 
 def seeded_params(cfg: LlamaConfig):
@@ -84,48 +77,6 @@ def seeded_params(cfg: LlamaConfig):
     return params
 
 
-def tap(runner: ModelRunner) -> list:
-    """The runner's own prefill and decode programs, each also returning the
-    logits it samples from (``logits_from_hidden``'s result, taken inside
-    the same trace); the list they are appended to."""
-    seen: list = []
-
-    def wrap(fn, **jit_kw):
-        def with_logits(*a, **k):
-            inside: list = []
-            real = mdl.logits_from_hidden
-
-            def spy(cfg, params, x):
-                inside.append(real(cfg, params, x))
-                return inside[-1]
-
-            mdl.logits_from_hidden = spy
-            try:
-                out = fn(*a, **k)
-            finally:
-                mdl.logits_from_hidden = real
-            return out, (inside[0] if inside else None)
-
-        jitted = jax.jit(with_logits, **jit_kw)
-
-        def call(*a, **k):
-            out, logits = jitted(*a, **k)
-            if logits is not None:
-                seen.append(np.asarray(logits, np.float32))
-            return out
-
-        return call
-
-    # one family of programs over both layouts; the fresh whole-prompt
-    # prefill is the contiguous rows' own
-    runner._prefill_paged = wrap(runner._prefill_paged_fn,
-                                 static_argnames=("bucket", "sample"))
-    runner._decode_paged = wrap(runner._decode_paged_fn)
-    if not runner.paged:
-        runner._prefill = wrap(runner._prefill_fn, static_argnames=("bucket",))
-    return seen
-
-
 def runner_for(cfg, params, paged: bool, **kw) -> ModelRunner:
     # a 16-token chunk: the 25-token prompt is prefilled in TWO chunks
     return ModelRunner(cfg, params, num_slots=2, max_ctx=128, paged=paged,
@@ -138,20 +89,16 @@ def served_logits(cfg, params, paged: bool, slot: int = 1):
     """Prefill then 8 decode steps through the cache: ([9, V] logits, the 9
     greedy tokens, the runner)."""
     r = runner_for(cfg, params, paged)
-    seen = tap(r)
-    tokens = [r.admit(slot, PROMPT, temperature=0.0)]
+    logits, tokens = families.served_logits(r, tap(r), slot, PROMPT, STEPS)
     if paged:
         assert r.admit_programs == 1 + 2      # the arming and two chunks
-    tokens += [int(r.step()[slot]) for _ in range(STEPS)]
-    logits = np.stack([seen[0][0]] + [row[slot] for row in seen[1:]])
     return logits, tokens, r
 
 
 def reference_logits(family, params, hf, tokens, monkeypatch) -> np.ndarray:
     """The family's full forward over prompt + served tokens: [9, V]."""
-    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
-    seq = np.array([PROMPT + tokens[:-1]], np.int32)
-    return refcheck.reference_logits(params, family, hf, seq, 1 + STEPS)[0]
+    return families.reference_logits(family, params, hf, PROMPT, tokens,
+                                     monkeypatch)
 
 
 # ---------------------------------------------------------------------------
@@ -359,18 +306,8 @@ def _forward_before_passes(cfg, params, tokens, positions, kv_write, kv_stack,
     return x, new_kv_stack
 
 
-def lowered(r: ModelRunner, debug_info: bool = False) -> dict:
-    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
-             r.block_tables[0], jnp.int32(0),
-             jnp.zeros(r.cfg.vocab_size, jnp.int32))
-    return {
-        "decode": jax.jit(r._decode_paged_fn).lower(
-            r.params, r.kv, r.state, r.block_tables).as_text(
-                debug_info=debug_info),
-        "prefill": jax.jit(
-            r._prefill_paged_fn, static_argnames=("bucket", "sample")).lower(
-                r.params, r.kv, r.state, *chunk, bucket=32,
-                sample=True).as_text(debug_info=debug_info)}
+lowered = functools.partial(families.lowered_texts,
+                            programs=("decode", "prefill_1"))
 
 
 @pytest.mark.parametrize("attn_impl", ["xla", "pallas_interpret"])
@@ -396,8 +333,7 @@ def test_one_pass_lowers_to_the_text_it_had_before_passes(monkeypatch,
     now, named = lowered(runner()), lowered(runner(), debug_info=True)
     monkeypatch.setattr(mdl, "forward", _forward_before_passes)
     before = lowered(runner())
-    assert now["decode"] == before["decode"]
-    assert now["prefill"] == before["prefill"]
+    assert now == before
     for text in named.values():
         assert "/layers" in text and "/final_norm" in text
         assert "loop.pass" not in text and "loop.norm" not in text
@@ -411,7 +347,7 @@ def test_the_looped_programs_hold_one_rolled_loop():
     looped = lowered(runner_for(cfg, seeded_params(cfg), paged=True))
     once_cfg = dataclasses.replace(cfg, num_passes=1)
     once = lowered(runner_for(once_cfg, seeded_params(once_cfg), paged=True))
-    for program in ("decode", "prefill"):
+    for program in ("decode", "prefill_1"):
         # the layer scan, and around it the loop over passes: one more loop
         # than the same model run once, however many passes
         assert (looped[program].count("stablehlo.while")

@@ -402,7 +402,10 @@ def test_a_capture_holds_every_launch_under_its_phase(paged_runner, tmp_path):
     from localai_tpu.engine.scheduler import GenRequest, Scheduler
     from localai_tpu.utils.tokenizer import ByteTokenizer
 
-    s = Scheduler(paged_runner, ByteTokenizer())
+    # a ring that keeps the capture's rows however long a loaded machine
+    # takes to write the capture out: serving goes on meanwhile, and the
+    # default 512 rows had rolled over a 0.3 s window's by then
+    s = Scheduler(paged_runner, ByteTokenizer(), flight=FlightRecorder(1 << 16))
     try:
         done = threading.Event()
 

@@ -14,26 +14,23 @@ forward over prompt + served tokens, no cache, no state carried.
 """
 
 import dataclasses
-import hashlib
+import functools
 import json
-import sys
 import time
-from pathlib import Path
 
+import families
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from families import (ROOT, agree, reference_logits, served_logits, spec,
+                      tap)
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "benchmark")]
-
-from harness import refcheck, spec  # noqa: E402
-from localai_tpu.engine.runner import ModelRunner  # noqa: E402
-from localai_tpu.models import llama as mdl  # noqa: E402
-from localai_tpu.models import qwen3_next as qn  # noqa: E402
-from localai_tpu.models.llama import LlamaConfig  # noqa: E402
-from localai_tpu.models.registry import synthetic_params  # noqa: E402
+from localai_tpu.engine.runner import ModelRunner
+from localai_tpu.models import llama as mdl
+from localai_tpu.models import qwen3_next as qn
+from localai_tpu.models.llama import LlamaConfig
+from localai_tpu.models.registry import synthetic_params
 
 HF = {"model_type": "qwen3_next", "vocab_size": 384, "hidden_size": 64,
       "num_hidden_layers": 8, "num_attention_heads": 4,
@@ -70,14 +67,11 @@ BF16_TOL, BF16_MEAN_TOL = 0.25, 0.12
 
 @pytest.fixture(scope="module")
 def family():
-    return spec.load_family(spec.family_file(
-        {"reference": {"family": "qwen3_next_family"}},
-        "tests/test_qwen3_next.py"))
+    return families.reference_family("qwen3_next_family",
+                                     "tests/test_qwen3_next.py")
 
 
-def config(dtype="float32", **changed):
-    return dataclasses.replace(LlamaConfig.from_hf({**HF, **changed}),
-                               dtype=dtype)
+config = functools.partial(families.config, HF)
 
 
 def seeded_params(cfg, seed: int = 0):
@@ -86,60 +80,19 @@ def seeded_params(cfg, seed: int = 0):
     all the same, but swapping two norms would change little), the gated
     norm's plain gain at 1 + 0.3 N, and the matmul weights three times as
     large, so that every branch weighs on the logits."""
-    params = mdl.init_params(jax.random.key(seed), cfg)
     rng = np.random.default_rng(seed + 1)
 
     def redraw(name, a):
         if name in qn.ZERO_CENTRED:
-            return jnp.asarray(0.3 * rng.standard_normal(a.shape), a.dtype)
+            return families.gain(rng, a, centre=0.0)
         if name == "gdn_out_norm":
-            return jnp.asarray(1 + 0.3 * rng.standard_normal(a.shape),
-                               a.dtype)
+            return families.gain(rng, a)
         if name in ("gdn_A_log", "gdn_dt_bias"):
             return a
-        return (3.0 * a.astype(jnp.float32)).astype(a.dtype)
+        return families.tripled(a)
 
-    out = {k: redraw(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: redraw(k, v) for k, v in params["layers"].items()}
-    return out
-
-
-def tap(runner: ModelRunner) -> list:
-    """The runner's own prefill and decode programs, each also returning the
-    logits it samples from (``logits_from_hidden``'s result, taken inside
-    the same trace); the list they are appended to."""
-    seen: list = []
-
-    def wrap(fn, **jit_kw):
-        def with_logits(*a, **k):
-            inside: list = []
-            real = mdl.logits_from_hidden
-
-            def spy(cfg, params, x):
-                inside.append(real(cfg, params, x))
-                return inside[-1]
-
-            mdl.logits_from_hidden = spy
-            try:
-                out = fn(*a, **k)
-            finally:
-                mdl.logits_from_hidden = real
-            return out, (inside[0] if inside else None)
-
-        jitted = jax.jit(with_logits, **jit_kw)
-
-        def call(*a, **k):
-            out, logits = jitted(*a, **k)
-            if logits is not None:
-                seen.append(np.asarray(logits, np.float32))
-            return out
-
-        return call
-
-    runner._prefill_paged = wrap(runner._prefill_paged_fn,
-                                 static_argnames=("bucket", "sample"))
-    runner._decode_paged = wrap(runner._decode_paged_fn)
-    return seen
+    return families.redrawn(mdl.init_params(jax.random.key(seed), cfg),
+                            redraw)
 
 
 EXPERTS = ("loop", "kernel")
@@ -162,29 +115,6 @@ def runner_for(cfg, params, experts="loop", **kw) -> ModelRunner:
     return r
 
 
-def served_logits(r: ModelRunner, seen: list, slot: int, prompt,
-                  steps: int = STEPS):
-    """Prefill then ``steps`` decode steps through pool and state: ([1 +
-    steps, V] logits, the greedy tokens)."""
-    mark = len(seen)
-    tokens = [r.admit(slot, prompt, temperature=0.0)]
-    tokens += [int(r.step()[slot]) for _ in range(steps)]
-    logits = np.stack([seen[mark][0]] + [row[slot] for row in seen[mark + 1:]])
-    return logits, tokens
-
-
-def reference_logits(family, params, hf, prompt, tokens, monkeypatch):
-    """The family's full forward over prompt + served tokens: [n, V]."""
-    monkeypatch.setattr(refcheck, "LETTERS", slice(0, hf["vocab_size"]))
-    seq = np.array([prompt + tokens[:-1]], np.int32)
-    return refcheck.reference_logits(params, family, hf, seq, len(tokens))[0]
-
-
-def agree(served, ref, tol):
-    assert np.abs(ref).max() > 0.2          # logits, not zeros
-    assert np.abs(served - ref).max() < tol, np.abs(served - ref).max()
-
-
 # ---------------------------------------------------------------------------
 # (i) the served path against the plain reference
 
@@ -200,7 +130,7 @@ def test_served_logits_match_the_reference(family, monkeypatch, dtype,
     cfg = config(dtype, num_experts_per_tok=chosen)
     params = seeded_params(cfg)
     r = runner_for(cfg, params, experts)
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     assert r.admit_programs == 1 + 2            # the arming and two chunks
     assert r.kv.k.shape[0] == PERIODS == cfg.cache_layers
     assert r.state.rec["S"].shape == (PERIODS, G, 4, 4, 16, 16)
@@ -416,7 +346,7 @@ def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
     params = seeded_params(cfg)
     served_cfg = config(**left_out(monkeypatch))
     r = runner_for(served_cfg, params, experts)
-    served, tokens = served_logits(r, tap(r), 1, PROMPT)
+    served, tokens = served_logits(r, tap(r), 1, PROMPT, STEPS)
     monkeypatch.undo()
     ref = reference_logits(family, params, HF, PROMPT, tokens, monkeypatch)
     if getattr(left_out, "kernel_only", False) and experts == "loop":
@@ -474,19 +404,26 @@ def _mesh(**axes):
     return build_mesh(MeshPlan(**axes), devices=jax.devices()[:n])
 
 
+@pytest.fixture(scope="module")
+def seeded():
+    """``seeded_params`` of the small configuration, drawn once for a runner
+    that refuses them unread."""
+    return seeded_params(config())
+
+
 @pytest.mark.parametrize("what, kw", [
     ("the contiguous K/V layout", {"paged": False}),
     ("pipeline parallelism", {"paged": False, "mesh": {"pipe": 2}}),
     ("the ring prefill", {"mesh": {"seq": 2}}),
     ("a device mesh", {"mesh": {"model": 2}}),
 ])
-def test_layouts_that_take_a_sequence_for_its_keys_are_refused(what, kw):
-    cfg = config()
+def test_layouts_that_take_a_sequence_for_its_keys_are_refused(seeded, what,
+                                                               kw):
     kw = dict(kw)
     if "mesh" in kw:
         kw["mesh"] = _mesh(**kw["mesh"])
     with pytest.raises(ValueError, match=f"^{what} is not served"):
-        runner_for(cfg, seeded_params(cfg), **kw)
+        runner_for(config(), seeded, **kw)
 
 
 def test_speculation_and_prefix_reuse_are_refused():
@@ -691,116 +628,3 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
     np.testing.assert_array_equal(held["layers"]["moe_gate"], lay["moe_gate"])
     with pytest.raises(ValueError, match="quantization"):
         load_llama_params(tmp_path, hf=whole_hf, quantization="int8")
-
-
-# ---------------------------------------------------------------------------
-# (vii) with another model_type nothing new is traced
-
-
-# sha256 of the lowered text (StableHLO, no debug info) of the programs of
-# the benchmark's three older configurations at small sizes, taken from the
-# PARENT commit of PR 41 by the recipe below under this installation (jax
-# 0.9.0): the four older cells' programs are the parent's to the letter. A
-# change that means to alter them regenerates these from its own parent.
-# PR 45 retook the nine of programs that sample (``arm`` stands as taken):
-# ``sample`` holds a bfloat16 head's float32 copy to bfloat16 with ONE
-# ``stablehlo.reduce_precision``; with that line taken out and the numbered
-# values aside, each text is PR 45's parent's line for line (the two-stage
-# candidates leave them alone: these vocabularies take the one ``top_k``).
-# PR 49 retook the four-chip trunk's two (``decode``, ``decode_n``): each is
-# the text PR 49's parent lowers under ``LOCALAI_MESH_OVERLAP=psum``, to the
-# letter (one ``psum`` a row-parallel product; the chunked form is gone).
-# PR 50 retook the six ``decode`` / ``decode_n`` (the paged kernel's body
-# changed: it does no work for a slot on the trash block); ``prefill`` and
-# ``arm``, which hold no kernel, stand as taken.
-PARENT_TEXT = {
-    "mistral-7b-v0.3-int8": {
-        "decode":
-            "f2c5a0fbb56f1413911c177e72bed6bd30887e59922fd68ca56da7d902c4fc86",
-        "decode_n":
-            "aeb400bc8401e521c6fc5dd310790dd772aa96efba2835f42adbf4fff9eaba66",
-        "prefill":
-            "5da338912ddb6924ef2ad7c994a1a6db2a1d233d941281a99b3c37a6affe5b12",
-        "arm":
-            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
-    },
-    "mistral-small-24b-int8-tp4": {
-        "decode":
-            "17d1318cb3353870a3a30acc6c055c94e692ac446f0dabaf22599ca0d218224b",
-        "decode_n":
-            "0da1864b409456ed8239270fcd37a4c5c16fe1430aa48cc9bc85a50c62867bb2",
-        "prefill":
-            "72bfe1e112c7a68df594347a5684c47074932e0da55929f006b4730ebfa37af5",
-        "arm":
-            "3efff5c076c6e3e16b28936ccc7fd25d87950dbbcfc6726e4694f537db26b136",
-    },
-    "ouro-2.6b-int8": {
-        "decode":
-            "9f2f46c6133afb09f824ec5c6f79b8cadbb3617761ae1f7a0aa20fc39877c25a",
-        "decode_n":
-            "433dc057083dd983c5cc6f4b5c503965f67da4b1ff872a18a4450f40c6be0dd0",
-        "prefill":
-            "a6e0180316c86e3f77a14a239cb921c62f4bac783543a9b790d6eed56d97be94",
-        "arm":
-            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
-    },
-}
-
-
-def _older_cells_runner(name: str) -> ModelRunner:
-    from localai_tpu.parallel.mesh import MeshPlan, build_mesh
-    from localai_tpu.parallel.sharding import ParamPlacement
-
-    doc = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
-    cut = {"hidden_size": 256, "intermediate_size": 512,
-           "num_hidden_layers": 2, "num_attention_heads": 4,
-           "num_key_value_heads": 2, "head_dim": 128, "vocab_size": 512,
-           "max_position_embeddings": 512}
-    mesh = None
-    if name.endswith("tp4"):
-        cut.update(num_attention_heads=8, num_key_value_heads=4)
-        mesh = build_mesh(MeshPlan(model=4), devices=jax.devices()[:4])
-    if name.startswith("ouro"):
-        cut.update(num_key_value_heads=4)
-    cfg = dataclasses.replace(LlamaConfig.from_hf({**doc, **cut}),
-                              dtype="bfloat16")
-    params = synthetic_params(cfg, "int8", seed=0,
-                              placement=ParamPlacement(cfg, mesh))
-    return ModelRunner(cfg, params, num_slots=4, max_ctx=128, paged=True,
-                       kv_block_tokens=16, attn_impl="pallas_interpret",
-                       mesh=mesh)
-
-
-def lowered_texts(r: ModelRunner) -> dict:
-    chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
-             r.block_tables[0], jnp.int32(0),
-             jnp.zeros(r.cfg.vocab_size, jnp.int32))
-    ints, floats = r.state.params.pack()
-    return {
-        "decode": jax.jit(r._decode_paged_fn).lower(
-            r.params, r.kv, r.state, r.block_tables).as_text(),
-        "decode_n": jax.jit(
-            r._decode_paged_n_fn, static_argnames=("n",)).lower(
-                r.params, r.kv, r.state, r.block_tables, n=4).as_text(),
-        "prefill": jax.jit(
-            r._prefill_paged_fn, static_argnames=("bucket", "sample")).lower(
-                r.params, r.kv, r.state, *chunk, bucket=32,
-                sample=True).as_text(),
-        "arm": jax.jit(r._arm_slot_fn).lower(
-            r.state, r.block_tables,
-            np.concatenate([np.array([0, 0, 0], np.int32), ints]), floats,
-            jnp.zeros(r.cfg.vocab_size, jnp.float32),
-            r.block_tables[0]).as_text()}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_TEXT))
-def test_the_older_cells_programs_lower_to_the_parents_text(name):
-    r = _older_cells_runner(name)
-    assert r.recurrent is False and r.state.rec is None
-    now = {k: hashlib.sha256(t.encode()).hexdigest()
-           for k, t in lowered_texts(r).items()}
-    assert now == PARENT_TEXT[name]
-    named = jax.jit(r._decode_paged_fn).lower(
-        r.params, r.kv, r.state, r.block_tables).as_text(debug_info=True)
-    for scope in ("gdn/", "moe/", "attn_gate"):
-        assert scope not in named

@@ -1142,6 +1142,14 @@ def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
 # and the engine thread's own clocks
 
 
+def _lost_s(s: Scheduler) -> float:
+    """What a test loses in ONE dispatch to see it counted slow: 60 ms, and
+    on a machine so loaded that a step of ``debug:tiny`` is over 12 ms, five
+    of its steps by the scheduler's own EMA (a row is slow past
+    ``SLOW_FACTOR`` = 4 times its steps' time)."""
+    return max(0.06, 5.0 * (s._step_ema_prior or 0.0))
+
+
 def _states(r) -> float:
     return (r["wait_ms"] + r["idle_ms"] + r["cpu_ms"] + (r["runq_ms"] or 0.0)
             + r["blocked_ms"])
@@ -1295,7 +1303,13 @@ def test_a_stalled_row_names_its_owner(paged, monkeypatch, owner):
     device answered late), asleep in the tokens' processing (a lock, the
     GIL), or computing there (our Python). The ring's worst row says which,
     ``process_ms`` holds the two that were in ``_process_rows``, and
-    ``localai_slow_dispatch_total{owner}`` counts the row once."""
+    ``localai_slow_dispatch_total{owner}`` counts the row once.
+
+    What a loaded machine decides is held to what the test injects: the loss
+    is 60 ms or five of THIS machine's steps (``_lost_s``), and a thread
+    that computes beside others waits for a core about as long as it
+    computes, so the row it computed in may be ``runq``'s, counted once all
+    the same."""
     import time
 
     from localai_tpu import faults
@@ -1313,17 +1327,17 @@ def test_a_stalled_row_names_its_owner(paged, monkeypatch, owner):
         if not done:
             done.append(1)
             if owner == "blocked":
-                time.sleep(0.06)
+                time.sleep(_lost_s(s))
             else:
-                t0 = time.thread_time()
-                while time.thread_time() - t0 < 0.06:
+                t0, lost = time.thread_time(), _lost_s(s)
+                while time.thread_time() - t0 < lost:
                     pass
         return _real(*a, **kw)
 
     try:
         if owner == "wait":
             faults.arm(FaultSpec(site="engine.drain", mode="sleep",
-                                 delay_s=0.06, times=1))
+                                 delay_s=_lost_s(s), times=1))
         else:
             monkeypatch.setattr(s, "_process_rows", lose_60ms)
         assert _wait(lambda: any(
@@ -1337,11 +1351,17 @@ def test_a_stalled_row_names_its_owner(paged, monkeypatch, owner):
         keeper.cancel()
         keeper.result(60)
     assert _wait(lambda: not s.busy)
+    states = ("wait", "cpu", "runq", "blocked")
+    owners = ("cpu", "runq") if owner == "cpu" else (owner,)
+
+    def owned(r) -> bool:
+        return max(states, key=lambda st: r[f"{st}_ms"] or 0.0) in owners
+
     worst = max(rows, key=lambda r: r["span_ms"] - r["idle_ms"])
     assert worst["program"].startswith("decode")
-    assert worst[f"{owner}_ms"] >= 59.0
+    assert worst[f"{owner}_ms"] >= 59.0 and owned(worst)
     assert worst[f"{owner}_ms"] == max(
-        worst[f"{st}_ms"] or 0.0 for st in ("wait", "cpu", "runq", "blocked"))
+        worst[f"{st}_ms"] or 0.0 for st in states if st != "runq")
     assert _states(worst) == pytest.approx(worst["span_ms"], abs=1e-3)
     if owner == "wait":
         assert worst["sync_ms"] >= 59.0 and worst["process_ms"] < 50.0
@@ -1349,18 +1369,16 @@ def test_a_stalled_row_names_its_owner(paged, monkeypatch, owner):
         assert worst["process_ms"] >= 50.0 and worst["gap_ms"] >= 59.0
     # once a row: as many as the rows that lost 50 ms and more to that state
     # (one; a collection of this process's garbage collector may add its own)
-    states = ("wait", "cpu", "runq", "blocked")
     lost = [r for r in rows if not r["compile"]
             and r["program"].startswith("decode")
-            and (r[f"{owner}_ms"] or 0.0) >= 50.0
-            and (r[f"{owner}_ms"] or 0.0) == max(
-                r[f"{st}_ms"] or 0.0 for st in states)]
-    assert gained[owner] == len(lost) >= 1, (gained, lost)
+            and (r[f"{owner}_ms"] or 0.0) >= 50.0 and owned(r)]
+    assert sum(gained[st] for st in owners) == len(lost) >= 1, (gained, lost)
     reg = Registry()
     update_engine_gauges("tiny", s.metrics(), registry=reg)
     text = reg.render()
-    assert (f'localai_slow_dispatch_total{{model="tiny",owner="{owner}"}} '
-            f'{s.slow_dispatches[owner]}\n') in text
+    counted = max(owners, key=s.slow_dispatches.get)
+    assert (f'localai_slow_dispatch_total{{model="tiny",owner="{counted}"}} '
+            f'{s.slow_dispatches[counted]}\n') in text
     assert 'localai_engine_thread_seconds_total{model="tiny",state="wait"}' \
         in text
 
@@ -1369,7 +1387,8 @@ def test_a_synchronous_row_reads_its_device_wait_as_wait(paged, monkeypatch):
     """A constrained stream decodes through the runner's synchronous step,
     which waits for the device inside the call: the row's ``wait_ms`` is the
     runner's own ``last_sync_ms``, as a pipelined row's is its drain, and a
-    device that answers 60 ms late there is a slow dispatch that ``wait``
+    device that answers 60 ms late there (``_lost_s``: five steps, where
+    this machine's steps are that long) is a slow dispatch that ``wait``
     owns, not a starved engine thread."""
     import time
 
@@ -1381,9 +1400,9 @@ def test_a_synchronous_row_reads_its_device_wait_as_wait(paged, monkeypatch):
     def slow_device():
         out = step()
         if mark + 4 < s._launch_seq and not late:
-            late.append(1)
-            time.sleep(0.06)
-            s.runner.last_sync_ms += 60.0
+            late.append(_lost_s(s))
+            time.sleep(late[0])
+            s.runner.last_sync_ms += late[0] * 1e3
         return out
 
     monkeypatch.setattr(s.runner, "step", slow_device)

@@ -43,12 +43,22 @@ bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 
 @pytest.fixture(scope="module")
 def topo():
+    """The described topology. While a module holds it, nothing is WRITTEN
+    to the run's compilation cache (tests/conftest.py): libtpu's compile-only
+    client serializes an executable and cannot load one (``UNIMPLEMENTED:
+    DeserializeLoadedExecutable``), so an entry would cost its write and then
+    a warning and the same compile at every repeat."""
     try:
         from jax.experimental import topologies
 
-        return topologies.get_topology_desc("v5e:2x2", "tpu")
+        desc = topologies.get_topology_desc("v5e:2x2", "tpu")
     except Exception as e:  # noqa: BLE001 — no libtpu / no topology support
         pytest.skip(f"libtpu cannot describe a v5e topology here: {e}")
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float("inf"))
+    yield desc
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
 
 
 def compile_for(topo, fn, *args):
