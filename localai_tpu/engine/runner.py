@@ -72,7 +72,8 @@ def _refusal(what: str) -> str:
     """The one sentence that refuses ``what`` for a model with recurrent
     state (``LlamaConfig.recurrent``)."""
     return (f"{what} is not served for a model whose layers carry recurrent "
-            f"state (model_type qwen3_next, falcon_h1): that state is not "
+            f"state (model_type qwen3_next, falcon_h1, lfm2_moe): that state "
+            f"is not "
             f"keys, it "
             f"lives in one dense row a slot beside the paged K/V pool and "
             f"cannot be re-read, split or shipped as a prefix of keys can")

@@ -1,6 +1,6 @@
 """Routed experts beside a shared expert: the ONE routing and dispatch of the
 sparse families that are told which experts they hold (models.qwen3_next,
-models.afmoe, models.deepseek).
+models.afmoe, models.deepseek, models.dots3, models.lfm2).
 
 An expert block scores a token over ALL ``num_experts x ep_size`` experts of
 the deployment, keeps its k choices, and computes the part of the routed sum
@@ -12,7 +12,8 @@ block runs without its exchange: the sum is this chip's partial result.
 Two things differ between the families, and are the block's arguments: the
 scoring rule (``softmax_scores`` / ``sigmoid_scores``, the latter plain or
 group-limited: logits -> a token's k weights and choices) and the shared
-expert (a closure: under a sigmoid gate, or none). Everything else is here once: the weights of the held experts, the
+expert (a closure: under a sigmoid gate, ungated, or None where the family
+has no shared expert). Everything else is here once: the weights of the held experts, the
 tokens an expert got, the order of the walk, the two counts a launch reports
 ([experts touched, token-expert pairs that landed here]; engine.scheduler
 ``_routed``), the scopes ``router``, ``experts``, ``shared``.
@@ -45,11 +46,13 @@ def softmax_scores(k: int, renormalise: bool) -> Callable:
 
 
 def sigmoid_scores(k: int, bias, renormalise: bool, scale: float, *,
-                   n_group: int = 1, topk_group: int = 1) -> Callable:
+                   n_group: int = 1, topk_group: int = 1,
+                   eps: float = 1e-20) -> Callable:
     """s = sigmoid(logits) over ALL experts; the k largest of ``s + bias``
     are chosen (the bias SELECTS and does not weigh; None: the family has
-    none); a choice weighs its own s, over the sum of the k (+ 1e-20) where
-    the family renormalises, times ``scale``. GROUP-LIMITED where
+    none); a choice weighs its own s, over the sum of the k (+ ``eps``: 1e-20,
+    models.lfm2's 1e-6) where the family renormalises, times ``scale``.
+    GROUP-LIMITED where
     ``n_group`` > 1 (models.deepseek): the experts lie in ``n_group`` equal
     groups, a group scores the sum of its two largest, and only the
     ``topk_group`` best groups' experts can be chosen (the others' scores
@@ -67,7 +70,7 @@ def sigmoid_scores(k: int, bias, renormalise: bool, scale: float, *,
         _, topi = lax.top_k(choice, k)
         topv = jnp.take_along_axis(s, topi, axis=-1)
         if renormalise:
-            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
         return topv * scale, topi
 
     return score
@@ -110,13 +113,15 @@ def experts_loop(h, weights, order, n_touched, experts, p, m_idx):
 
 
 def moe_block(h, w_router, score: Callable, experts, p, m_idx, *,
-              num_experts: int, ep_rank: int, valid, shared: Callable,
+              num_experts: int, ep_rank: int, valid,
+              shared: Optional[Callable],
               experts_kernel: Optional[bool] = None):
     """One expert block on normed h [N, D]: this chip's part of the routed
     sum plus the shared expert. ``experts``: the three stacked expert leaves
     WHOLE ([rows, M, E, ...]), indexed here by (p, m_idx, expert) so that a
     step reads the experts it touched and nothing else of them. ``shared``:
-    h -> the shared expert's [N, D] float32. ``experts_kernel``: None is the
+    h -> the shared expert's [N, D] float32; None where the family has none
+    (nothing is formed or added). ``experts_kernel``: None is the
     XLA loop over the touched experts, else ops.moe's grouped kernel (the
     value: in the Pallas interpreter). Returns (out [N, D], experts touched,
     tokens each held expert got [E]): ``counts`` makes the launch's two
@@ -136,6 +141,8 @@ def moe_block(h, w_router, score: Callable, experts, p, m_idx, *,
         else:
             routed = experts_loop(h, weights, order, n_touched, experts,
                                   p, m_idx)
+    if shared is None:
+        return routed.astype(h.dtype), n_touched, load
     with jax.named_scope("shared"):
         out = (routed + shared(h)).astype(h.dtype)
     return out, n_touched, load

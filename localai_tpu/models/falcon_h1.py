@@ -375,7 +375,7 @@ def init_rec(cfg: FalconH1Config, num_slots: int) -> dict:
     }
 
 
-def _rec_read(arr, layer, slot):
+def rec_read(arr, layer, slot):
     """Rows of ``layer``: every slot's (``slot`` None) or one slot's, with a
     leading batch axis either way, in ONE slice."""
     zeros = (0,) * (arr.ndim - 2)
@@ -386,7 +386,7 @@ def _rec_read(arr, layer, slot):
                              (1, 1) + arr.shape[2:])[0]
 
 
-def _rec_write(arr, new, layer, slot):
+def rec_write(arr, new, layer, slot):
     zeros = (0,) * (arr.ndim - 2)
     return lax.dynamic_update_slice(
         arr, new[None].astype(arr.dtype),
@@ -673,8 +673,8 @@ def forward(
             # the per-slot arrays are read and written under the scope of
             # the recurrence: ``ssm/state`` is all that moves state
             with jax.named_scope("state"):
-                S0 = None if fused else _rec_read(S_all, i, slot)
-                conv0 = _rec_read(conv_all, i, slot)
+                S0 = None if fused else rec_read(S_all, i, slot)
+                conv0 = rec_read(conv_all, i, slot)
                 if fresh is not None:       # a chunk: never fused
                     S0 = jnp.where(fresh, 0.0, S0)
                     conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
@@ -684,8 +684,8 @@ def forward(
                         recur, S0, chunk=cfg.mamba_chunk_size))
             m, S, conv = _mixer(cfg, h, lp, state_step, conv0, valid)
             with jax.named_scope("state"):
-                S_all = S if fused else _rec_write(S_all, S, i, slot)
-                conv_all = _rec_write(conv_all, conv, i, slot)
+                S_all = S if fused else rec_write(S_all, S, i, slot)
+                conv_all = rec_write(conv_all, conv, i, slot)
 
         def attend(q, k_new, v_new):
             new_kv, keys, values = kv_write(kv, i, k_new, v_new)

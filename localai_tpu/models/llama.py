@@ -66,8 +66,8 @@ class LlamaConfig:
     dtype: str = "bfloat16"
 
     # a family whose layers carry per-slot state that is not keys (a
-    # subclass: models.qwen3_next, models.falcon_h1): the engine keeps that
-    # state beside the K/V pool, built by the family module's ``init_rec``
+    # subclass: models.qwen3_next, models.falcon_h1, models.lfm2): the engine
+    # keeps that state beside the K/V pool, built by the family's ``init_rec``
     # and carried by its ``forward``, and refuses what re-reads a prefix
     # from keys alone
     recurrent: ClassVar[bool] = False
@@ -125,7 +125,10 @@ class LlamaConfig:
         attention on its full layers and window layers of a shape of their
         own): models.dots3; and ``falcon_h1`` (a Mamba-2 mixer and
         grouped-query attention side by side in every layer, under muP
-        multipliers): models.falcon_h1. A type not named here is built as a
+        multipliers): models.falcon_h1; and ``lfm2_moe`` (a gated short
+        convolution or grouped-query attention by a LIST of layer kinds,
+        dense layers in front of sigmoid-routed experts with no shared
+        expert): models.lfm2. A type not named here is built as a
         dense llama stack of the file's widths, unless its keys say that it
         has a state-space mixer (``mamba_*``), which such a stack would
         leave out: refused."""
@@ -149,6 +152,10 @@ class LlamaConfig:
             from localai_tpu.models.falcon_h1 import FalconH1Config
 
             return FalconH1Config.from_hf(hf)
+        if hf.get("model_type") == "lfm2_moe":
+            from localai_tpu.models.lfm2 import Lfm2Config
+
+            return Lfm2Config.from_hf(hf)
         mamba = sorted(k for k in hf if k.startswith("mamba_"))
         if mamba:
             raise ValueError(

@@ -112,7 +112,10 @@ def load_llama_params(
             raise ValueError(
                 f"param {path}: shape {a.shape} != expected {want}")
         plan = quantize_plan(path, a.ndim, quantization) if quantization else None
-        if path[-1] in getattr(fam, "FLOAT32_LEAVES", ()):
+        # (a family that prefixes a leaf's name by its group says how to
+        # take the prefix off: ``base_name``)
+        if (getattr(fam, "base_name", str)(path[-1])
+                in getattr(fam, "FLOAT32_LEAVES", ())):
             leaf = a.astype(np.float32)     # as published, whatever ``dtype``
         elif plan is None:
             # source dtype stays on the host until here (bf16 checkpoints

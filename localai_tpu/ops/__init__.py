@@ -72,6 +72,18 @@ def resolve_attn_impl(requested: str = "auto",
     return impl, False
 
 
+def _unpacked(head_dim: int) -> str:
+    """What a refusal adds for heads that COULD share a 128-lane row
+    (``ops.attention.heads_per_row``): the selectors are told the POOL's
+    rows, so such heads arrive here only where nothing packed them."""
+    if head_dim >= 128 or 128 % head_dim:
+        return ""
+    return (f": {128 // head_dim} K/V heads of {head_dim} share a 128-lane "
+            f"row where the family packs them "
+            f"(ops.attention.heads_per_row) and their count is a multiple "
+            f"of {128 // head_dim}; these stand alone")
+
+
 def _check_tp_heads(num_heads: int, num_kv_heads: int, tp: int) -> None:
     # under a mesh the flash kernels run per-device via shard_map (slots
     # on 'data', heads on 'model') — both head counts must split evenly or
@@ -100,11 +112,12 @@ def select_attn_impl(requested: str, *, num_heads: int, num_kv_heads: int,
         return impl, interpret
     _check_tp_heads(num_heads, num_kv_heads, tp)
     if not interpret and (head_dim % 128 or max_ctx % 128):
-        # Mosaic DMA slices are 128 lanes wide: hd-64 families and
-        # unaligned contexts have no compiled kernel
+        # Mosaic DMA slices are 128 lanes wide: a head that is no multiple
+        # of them and an unaligned context have no compiled kernel
         raise ValueError(
             f"Pallas attention needs head_dim and context 128-aligned "
-            f"(head_dim={head_dim} ctx={max_ctx}); {_OVERRIDE}")
+            f"(head_dim={head_dim} ctx={max_ctx}){_unpacked(head_dim)}; "
+            f"{_OVERRIDE}")
     return impl, interpret
 
 
@@ -124,6 +137,11 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
     entries an online-softmax step), so on hardware it needs Mosaic-tileable
     blocks: head_dim 128-aligned and block_tokens a multiple of 32 (the
     int8 sublane tile; every such size is compiled for v5e in the tests).
+    ``num_kv_heads`` and ``head_dim`` are the POOL's rows: a family whose
+    heads are 64 wide packs two to a row (ops.attention ``heads_per_row``)
+    and is answered as 128-wide heads are; an odd count of
+    such heads, or a width that packs to no 128, arrives as it is and is
+    refused.
     int4 pools are nibble-packed along head_dim, so their DMA'd last dim is
     head_dim/2 — that needs head_dim 256-aligned, and below it the packed
     rows are also padded back to 128 lanes in HBM, so an hd-128 int4 pool
@@ -144,8 +162,9 @@ def select_paged_attn_impl(requested: str, *, num_heads: int,
             raise ValueError(
                 f"Pallas paged attention needs Mosaic-tileable blocks "
                 f"(head_dim % 128 == 0, block_tokens % 32 == 0; got "
-                f"head_dim={head_dim} block_tokens={block_tokens}); "
-                f"{_OVERRIDE}. (A pool whose rows are off 128 lanes by "
+                f"head_dim={head_dim} block_tokens={block_tokens})"
+                f"{_unpacked(head_dim)}; {_OVERRIDE}. (A pool whose rows "
+                f"are off 128 lanes by "
                 f"nature is the LATENT layout's: one 576-element row a "
                 f"token in 640 lanes, ops.latent_decode_attention, for a "
                 f"model with latent attention; this K/V-a-head pool has "

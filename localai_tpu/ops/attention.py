@@ -891,6 +891,65 @@ def paged_decode_attention_ref(
 
 
 # ---------------------------------------------------------------------------
+# heads narrower than a lane tile: several K/V heads share one pool row
+# ---------------------------------------------------------------------------
+#
+# Mosaic copies 128-lane rows, so a K/V head of 64 has no compiled kernel of
+# its own. A family whose heads are narrower (models.lfm2) hands pool and
+# kernels a model of ``Hkv / f`` heads of ``f hd`` = 128 lanes:
+# ``k.reshape(.., Hkv / f, f hd)`` IS ``[head f j | .. | head f j + f - 1]``,
+# so the pool, its tables and write policies see nothing new and move the
+# true model's bytes. A query head's ``hd`` values stand at its OWN K/V head's
+# place in the row, zeros elsewhere: the scores are exact (the other heads'
+# lanes meet zeros), the output's own ``hd`` lanes are the head's result, and
+# the rest (the neighbours' values under this head's weights) is dropped.
+# Every attend scales by the ROW's width, ``(f hd)^-1/2``: the family folds
+# the missing ``f^1/2`` into q where it rounds q anyway. The matmul units do
+# f times the products, in kernels that are bound by bytes.
+
+
+def heads_per_row(num_kv_heads: int, head_dim: int) -> int:
+    """K/V heads of ``head_dim`` that share one 128-lane pool row: 128 /
+    head_dim where that is whole and divides the head count, else 1 (the
+    heads stand alone: 128-aligned ones by right, others for the XLA attend
+    alone, which ``ops.select_paged_attn_impl`` says at load)."""
+    f = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return f if num_kv_heads % f == 0 else 1
+
+
+def pack_kv(x: jax.Array, f: int) -> jax.Array:
+    """k or v [.., Hkv, hd] as the pool's rows [.., Hkv / f, f hd]."""
+    *lead, h, hd = x.shape
+    return x.reshape(*lead, h // f, f * hd)
+
+
+def pack_q(q: jax.Array, f: int, num_kv_heads: int) -> jax.Array:
+    """q [.., Hq, hd] as [.., Hq, f hd]: a head's values at the lanes of its
+    own K/V head in the packed row (head h reads K/V head h // (Hq / Hkv),
+    which is part (..) % f of its row), zeros at the others'."""
+    if f == 1:
+        return q
+    *lead, hq, hd = q.shape
+    parts = q.reshape(*lead, num_kv_heads // f, f, hq // num_kv_heads, hd)
+    pad = [(0, 0)] * (parts.ndim - 2)
+    return jnp.stack(
+        [jnp.pad(parts[..., j, :, :], [*pad, (j * hd, (f - 1 - j) * hd)])
+         for j in range(f)], axis=-3).reshape(*lead, hq, f * hd)
+
+
+def unpack_out(out: jax.Array, f: int, num_kv_heads: int) -> jax.Array:
+    """The attend's [.., Hq, f hd] as [.., Hq, hd]: each head's own lanes."""
+    if f == 1:
+        return out
+    *lead, hq, row = out.shape
+    hd = row // f
+    parts = out.reshape(*lead, num_kv_heads // f, f, hq // num_kv_heads, f,
+                        hd)
+    return jnp.stack([parts[..., j, :, j, :] for j in range(f)],
+                     axis=-3).reshape(*lead, hq, hd)
+
+
+# ---------------------------------------------------------------------------
 # latent paged decode: one token per slot over a pool of LATENT rows
 # ---------------------------------------------------------------------------
 

@@ -81,9 +81,9 @@ LONGEST_FIRST = (
     "test_deepseek", "test_dots3", "test_chip_smoke", "test_kv_contract",
     "test_bench_walk_latent", "test_bench_walk", "test_overlap",
     "test_neighbour_texts", "test_ouro", "test_dots3_compile",
-    "test_paged_serving", "test_falcon_h1", "test_moe_kernel",
-    "test_prefill_span", "test_int4", "test_spec", "test_bench_trace",
-    "test_falcon_h1_compile", "test_sampling", "test_fleet",
+    "test_paged_serving", "test_lfm2", "test_falcon_h1", "test_moe_kernel",
+    "test_lfm2_compile", "test_prefill_span", "test_int4", "test_spec",
+    "test_bench_trace", "test_falcon_h1_compile", "test_sampling", "test_fleet",
     "test_scheduler",
 )
 

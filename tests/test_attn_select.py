@@ -34,18 +34,66 @@ def test_llama8b_paged_lands_on_pallas_on_tpu(tp, kv_dtype, bt):
 
 
 def test_llama1b_hd64_is_refused_with_the_override_named():
-    """debug:1b has head_dim 64: no compiled kernel. On a TPU `auto` must
-    say so at load (it used to serve XLA attention with a log.info)."""
-    with pytest.raises(ValueError, match="128-aligned.*attn_impl: xla"):
-        select_attn_impl("auto", num_heads=32, num_kv_heads=8, head_dim=64,
-                         max_ctx=1024, backend="tpu")
-    with pytest.raises(ValueError, match="tileable.*attn_impl: xla"):
-        select_paged_attn_impl(
-            "auto", num_heads=32, num_kv_heads=8, head_dim=64,
-            block_tokens=64, backend="tpu")
+    """Heads of 64 and the compiled kernels. Mosaic copies 128-lane rows, so
+    a K/V head of 64 ALONE has none: debug:1b through models/llama.py, whose
+    layers hand the pool their heads as they are, is still refused on a TPU
+    under ``auto``, at load, with the override named (it used to serve XLA
+    attention with a log.info) and now with what would serve it. A family
+    that PACKS two such heads into one pool row (ops.attention
+    ``heads_per_row``; model_type lfm2_moe) tells the selectors the POOL's
+    rows, 128 wide, and lands on the compiled kernel: the published
+    LFM2-8B-A1B (32 query heads over 8 K/V heads of 64) is 4 rows of 128. What
+    still cannot be tiled arrives as it is and is refused: an odd count of
+    64-wide K/V heads, a width that packs to no 128."""
+    from localai_tpu.models.llama import LlamaConfig
+    from localai_tpu.ops.attention import heads_per_row
+
+    hd64 = dict(num_heads=32, num_kv_heads=8, head_dim=64)
+    with pytest.raises(ValueError, match="128-aligned.*2 K/V heads of 64 "
+                                         "share a 128-lane row.*attn_impl: "
+                                         "xla"):
+        select_attn_impl("auto", **hd64, max_ctx=1024, backend="tpu")
+    with pytest.raises(ValueError, match="tileable.*heads_per_row.*"
+                                         "attn_impl: xla"):
+        select_paged_attn_impl("auto", **hd64, block_tokens=64,
+                               backend="tpu")
     # the explicit choice is honoured
-    assert select_attn_impl("xla", num_heads=32, num_kv_heads=8, head_dim=64,
-                            max_ctx=1024, backend="tpu") == ("xla", False)
+    assert select_attn_impl("xla", **hd64, max_ctx=1024,
+                            backend="tpu") == ("xla", False)
+
+    def lfm2(**keys):
+        types = ["conv", "conv", "full_attention", "conv"]
+        cfg = LlamaConfig.from_hf({
+            "model_type": "lfm2_moe", "vocab_size": 65536,
+            "hidden_size": 2048, "intermediate_size": 7168,
+            "moe_intermediate_size": 1792, "num_hidden_layers": 4,
+            "layer_types": types, "num_dense_layers": 2,
+            "num_attention_heads": 32, "num_key_value_heads": 8,
+            "num_experts": 32, "num_experts_per_tok": 4, **keys})
+        return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=cfg.hd)
+
+    assert heads_per_row(8, 64) == 2
+    assert lfm2() == dict(num_heads=32, num_kv_heads=4, head_dim=128)
+    for tp in (1, 4):
+        assert select_paged_attn_impl("auto", **lfm2(), block_tokens=64,
+                                      tp=tp, backend="tpu") == ("pallas",
+                                                                False)
+    assert select_attn_impl("auto", **lfm2(), max_ctx=4096,
+                            backend="tpu") == ("pallas", False)
+    # 32 heads of 32 over 8 K/V heads: four to a row
+    assert lfm2(hidden_size=1024) == dict(num_heads=32, num_kv_heads=2,
+                                          head_dim=128)
+    # an odd count of 64-wide K/V heads, and heads of 96: as they are
+    for keys, alone in (({"num_key_value_heads": 1}, (1, 64)),
+                        ({"head_dim": 96}, (8, 96))):
+        shape = lfm2(**keys)
+        assert (shape["num_kv_heads"], shape["head_dim"]) == alone
+        with pytest.raises(ValueError, match="tileable.*attn_impl: xla"):
+            select_paged_attn_impl("auto", **shape, block_tokens=64,
+                                   backend="tpu")
+        assert select_paged_attn_impl("xla", **shape, block_tokens=64,
+                                      backend="tpu") == ("xla", False)
 
 
 def test_unaligned_ctx_is_refused():

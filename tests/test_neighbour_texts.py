@@ -40,6 +40,13 @@ What each row has seen:
 - ``dots3_note`` (PR 56, this tree: the newest family with a cell, which no
   row held; in the place of a second ``afmoe`` XLA row that tests/test_dots3.py
   ran beside tests/test_deepseek.py's): as taken.
+- ``falcon_h1`` (PR 57, parent 69627c5: ``lfm2_moe`` came through the door
+  beside it, shares ``_forward_rec`` and the refusals of recurrent state with
+  it and calls its ``rec_read``, ``rec_write`` and ``conv_rows``, the first
+  two under names that lost an underscore; ``moe_block``'s shared expert and
+  ``sigmoid_scores``' epsilon became arguments that every older family
+  leaves as they were): as taken. Its chunk holds no kernel, so the two
+  rows' ``prefill`` texts are one text.
 """
 
 import functools
@@ -53,6 +60,7 @@ from families import ROOT
 from test_afmoe import HF as AFMOE
 from test_deepseek import HF as AXK1
 from test_dots3 import HF as DOTS3
+from test_falcon_h1 import HF as FALCON_H1
 from test_qwen3_next import HF as QWEN3_NEXT
 
 from localai_tpu.engine.runner import ModelRunner
@@ -61,6 +69,9 @@ from localai_tpu.models.registry import synthetic_params
 
 # a head of 128 lanes and experts of 128 (what the compiled kernels take)
 WIDE = {"head_dim": 128, "moe_intermediate_size": 128}
+# ... and a mixer head of 128 with a state of 128
+WIDE_SSM = {"head_dim": 128, "mamba_d_head": 128, "mamba_d_ssm": 512,
+            "mamba_d_state": 128}
 
 
 def cell_runner(name: str) -> ModelRunner:
@@ -191,6 +202,20 @@ TAKEN = [
             "c84221f04fe52131fbd26b46c8c604e945d57ecb884f7c363ccf492875cccb43",
         "prefill_0":
             "9d92593db19120a980fc7fc7d7a6e486c06fcfbe9e90d275022a77c141cf1e64"}),
+    family({**FALCON_H1, **WIDE_SSM}, 16, "pallas_interpret", {
+        "decode":
+            "58da9ea3a4476534b5a365616fa537faceb29f5f808281c4f47b82cbab09449c",
+        "prefill_1":
+            "f8bb71eacc0d156f53b9d45ff6b87a7c3dd05760495e886fe3258da0aec36505",
+        "prefill_0":
+            "17b891ddebad6bda6c4f4405b8ff5229d91abe82860db6871ca3b728c0a30cf9"}),
+    family({**FALCON_H1, **WIDE_SSM}, 16, "xla", {
+        "decode":
+            "3c8fb5d2f187c89425b000b0615a95018b3531b978bbe85ec3141ab6fcdfc215",
+        "prefill_1":
+            "f8bb71eacc0d156f53b9d45ff6b87a7c3dd05760495e886fe3258da0aec36505",
+        "prefill_0":
+            "17b891ddebad6bda6c4f4405b8ff5229d91abe82860db6871ca3b728c0a30cf9"}),
 ]
 
 
