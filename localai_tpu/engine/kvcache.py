@@ -1607,6 +1607,48 @@ class PagedLayout:
                 for kind, view in views}
         return paged_prefill_write(table_row, offset, length), attn, mask
 
+    def ride(self, kv: PagedKVCache, tables, positions, table_row, slot,
+             chunk_positions, offset, length):
+        """A chunk's rows and a decode step's in ONE forward (``[1, bucket +
+        S]``, the chunk in front: ``ModelRunner._decode_prefill_paged_fn``).
+        Rows meet nowhere but in the attend, so the composite is the two
+        policies side by side: the chunk's rows go where ``chunk`` sends
+        them, the step's where ``decode`` does, with the arguments each is
+        handed alone, and the two outputs are laid end to end. Both attends
+        read the stack as BOTH writes left it (one buffer, written in
+        place: the streams' blocks and the chunk's are not the same
+        blocks), and the kernel that stores an unscaled pool's step rows
+        hands the stack on. The masks travel as a pair."""
+        cfg, T = self.cfg, chunk_positions.shape[1]
+        assert not cfg.attn_kinds and self.mesh is None
+        c_write, c_attn, c_mask = self.chunk(table_row, slot, chunk_positions,
+                                             offset, length)
+        d_write, d_attn, d_mask = self.decode(kv, tables, positions)
+        if d_attn is None:      # the XLA attend ``forward`` would bring
+
+            def d_attn(q, keys, values, m):
+                with jax.named_scope("attn.decode"):
+                    return _grouped_attn(cfg, q, keys, values, m)
+
+        def write(kv_stack, layer, k_new, v_new):   # k_new [1, T + S, H, hd]
+            new, *_ = c_write(kv_stack, layer, k_new[:, :T], v_new[:, :T])
+            new, d_keys, d_values = d_write(new, layer, k_new[0, T:, None],
+                                            v_new[0, T:, None])
+            c_keys, c_values = _views(new, layer)
+            return new, (c_keys, d_keys), (c_values, d_values)
+
+        def attn(q, keys, values, mask):            # q [1, T + S, Hq, hd]
+            (c_keys, d_keys), (c_values, d_values) = keys, values
+            out = c_attn(q[:, :T], c_keys, c_values, mask[0])
+            step = d_attn(q[0, T:, None], d_keys, d_values, mask[1])
+            stack = None
+            if isinstance(step, tuple):     # the kernel wrote the stack
+                step, stack = step
+            out = jnp.concatenate([out, step[:, 0][None]], axis=1)
+            return out if stack is None else (out, stack)
+
+        return write, attn, (c_mask, d_mask)
+
 
 @dataclasses.dataclass
 class LatentLayout:

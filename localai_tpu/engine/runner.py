@@ -66,6 +66,11 @@ PREFILL_CHUNK = 512
 # (``ModelRunner.chunk_rows``), on one chip as on a mesh; the 128 bucket is
 # bound by the weights' bytes and stays whole
 CHUNK_QUARTERED = 512
+# rows up to which a prompt's LAST chunk rides the decode step launched behind
+# it (``ModelRunner._decode_prefill_paged_fn``): the ladder's first rung, where
+# a chunk is bound by the weights' bytes and not by its rows' products, so one
+# read of them serves the chunk's rows and the step's
+RIDE_ROWS = 128
 
 
 def _refusal(what: str) -> str:
@@ -452,6 +457,10 @@ class ModelRunner:
             self._prefill_paged_fn, static_argnames=("bucket", "sample"),
             donate_argnums=(1, 2),
         ), "prefill_chunk" if self.paged else "prefill_resume")
+        self._decode_prefill_paged = obs_compile.watch(jax.jit(
+            self._decode_prefill_paged_fn, static_argnames=("bucket",),
+            donate_argnums=(1, 2),
+        ), "decode_chunk")
         # the fresh whole-prompt prefills stay a layout's own (ROADMAP C1b):
         # a multimodal one and, over a 'seq' mesh axis, a ring-attention one
         # (one long prompt uses every chip without stalling decode — chosen
@@ -613,11 +622,13 @@ class ModelRunner:
 
     # -- jitted programs -------------------------------------------------
 
-    def _decode_tail(self, params, state: DecodeState, hidden):
+    def _decode_tail(self, params, state: DecodeState, hidden, logits=None):
         """Sampling + per-slot state advance of the decode step
-        (KV-layout-independent)."""
+        (KV-layout-independent), from ``hidden [S, 1, D]`` or from the
+        ``logits [S, V]`` a caller has already taken of it."""
         pos = state.positions
-        logits = mdl.logits_from_hidden(self.cfg, params, hidden[:, 0])
+        if logits is None:
+            logits = mdl.logits_from_hidden(self.cfg, params, hidden[:, 0])
         tokens, keys = smp.sample(
             logits, state.params, state.counts, state.keys, state.bias,
             mesh=self.mesh,
@@ -747,18 +758,20 @@ class ModelRunner:
         return self.layout.from_stacked(new_stack), new_state, emitted
 
     def _first_token(self, params, state: DecodeState, hidden, length, slot,
-                     offset=None, counts_row=None, prompt=None):
+                     offset=None, counts_row=None, prompt=None, logits=None):
         """What ends every prefill: take the row at ``length - 1`` of
-        ``hidden [1, T, D]``, sample it with the slot's parameters and arm
+        ``hidden [1, T, D]`` (or the ``logits [1, V]`` a caller has already
+        taken of it), sample it with the slot's parameters and arm
         the slot at its frontier, ``length`` behind the ``offset`` cached
         tokens of a chunk (None: a fresh prompt). The slot's penalty counts are
         ``counts_row`` ([V] i32, the host's bincount of the FULL prompt: a
         program that sees a tail of it cannot count) or, where none comes,
         ``prompt``'s ([T], or [1, T] as the forward took it) first ``length``
         tokens counted here. Returns the armed state and the token, [1]."""
-        last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
-                                              keepdims=True)
-        logits = mdl.logits_from_hidden(self.cfg, params, last_h)  # [1, V]
+        if logits is None:
+            last_h = jax.lax.dynamic_index_in_dim(hidden[0], length - 1,
+                                                  keepdims=True)
+            logits = mdl.logits_from_hidden(self.cfg, params, last_h)  # [1, V]
         if counts_row is None:
             counts = smp.count_prompt_tokens(
                 state.counts, slot, prompt[0] if prompt.ndim == 2 else prompt,
@@ -1018,6 +1031,55 @@ class ModelRunner:
         if routed is not None:
             return new_kv, new_state, jnp.concatenate([tok, routed])
         return new_kv, new_state, tok[0]
+
+    @property
+    def rides(self) -> bool:
+        """Whether a prompt's small last chunk can ride a decode step
+        (``_decode_prefill_paged_fn``): over the block pool, on one chip,
+        for a model that runs ``models.llama.forward``. A family's own
+        forward (routed experts, recurrent state, latent rows, several kinds
+        of layer) and a mesh (the manual-TP trunk, GSPMD's slots over
+        'data') keep the chunk and the step two programs."""
+        return (self.paged and self.mesh is None and not self.latent
+                and not self.own_forward and not self.kinds)
+
+    @scoped("ride")
+    def _decode_prefill_paged_fn(self, params, kv, state: DecodeState, tables,
+                                 tokens, length, offset, table_row, slot,
+                                 counts_row, *, bucket: int):
+        """A prompt's LAST chunk of at most ``RIDE_ROWS`` rows and the decode
+        step the loop would launch behind it, as ONE program: one forward
+        over the chunk's ``bucket`` rows and the S slots' rows side by side
+        (``PagedLayout.ride``: the rows meet nowhere but in the attend, and
+        there each goes where its own program sends it), so the embedding,
+        the projections, the MLP and the head read their weights once for
+        both. The step samples for the streams the state held as it stood:
+        the new slot is not among them (its device table row, installed by
+        the arming update in front, is put back on the trash block for the
+        step's rows), and then the chunk's last real row samples the first
+        token and arms the slot, as ``_prefill_paged_fn`` does. What the
+        step then the chunk leave, this leaves: pool, state, tokens. Returns
+        the S tokens and the first token behind them, [S + 1]."""
+        S = self.num_slots
+        pos = state.positions
+        positions = jnp.concatenate(
+            [offset + jnp.arange(bucket, dtype=jnp.int32), pos])[None, :]
+        write, attn, mask = self.layout.ride(
+            kv, tables.at[slot].set(0), pos, table_row, slot,
+            positions[:, :bucket], offset, length)
+        hidden, new_stack = self._forward(
+            params, jnp.concatenate([tokens[0], state.tokens])[None, :],
+            positions, write, kv.stacked(), mask, attn=attn)
+        last_h = jax.lax.dynamic_index_in_dim(hidden[0, :bucket], length - 1,
+                                              keepdims=True)
+        logits = mdl.logits_from_hidden(
+            self.cfg, params, jnp.concatenate([hidden[0, bucket:], last_h]))
+        state, step = self._decode_tail(params, state, None, logits[:S])
+        state, tok = self._first_token(
+            params, state, None, length, slot, offset, counts_row,
+            logits=logits[S:])
+        return (self.layout.from_stacked(new_stack), state,
+                jnp.concatenate([step, tok]))
 
     def chunk_rows(self, bucket: int, last: bool = True) -> tuple[int, ...]:
         """The row counts a chunk program of ``bucket`` rows can run BEHIND
@@ -2058,6 +2120,7 @@ class PagedAdmission:
         self.mm_positions = mm_positions
         self.sp = sp                         # ring-attention one-shot path
         self.first: Optional[jax.Array] = None   # the final chunk's sample
+        self.rode = False                    # ... behind a decode step's [S]
         self.done = False
         # dispatch-anatomy scratch: the last launch_chunk()'s enqueue span
         # (obs.anatomy)
@@ -2077,15 +2140,33 @@ class PagedAdmission:
         return max(1, -(-(len(self.prompt) - self.pos)
                         // self.runner.prefill_chunk))
 
+    @property
+    def ride_bucket(self) -> Optional[int]:
+        """The bucket of the next chunk where it is the prompt's last and
+        small enough to ride a decode step (``launch_chunk(ride=True)``: at
+        most ``RIDE_ROWS`` rows, on a runner whose programs can,
+        ``ModelRunner.rides``; a multimodal or ring prefill has a program of
+        its own), else None."""
+        r = self.runner
+        rem = len(self.prompt) - self.pos
+        if (self.done or self.mm or self.sp or not r.rides
+                or rem > r.prefill_chunk):
+            return None
+        bucket = r.bucket_for(rem)
+        return bucket if bucket <= RIDE_ROWS else None
+
     def _counts_row(self) -> np.ndarray:
         return _prompt_counts_row(self.runner.cfg.vocab_size, self.prompt)
 
-    def launch_chunk(self) -> bool:
+    def launch_chunk(self, ride: bool = False) -> bool:
         """Dispatch the next prefill chunk without waiting for it; True when
         it was the final one (``first`` then holds the sampled token, on its
         way to the host). Arguments go up as host arrays: the dispatch's
-        own transfer, no program of their own."""
-        assert not self.done
+        own transfer, no program of their own. ``ride`` (the caller has
+        asked ``ride_bucket``): the chunk and the decode step every slot would
+        take next are ONE launch, and ``first`` holds the step's [S] tokens
+        with the chunk's behind them."""
+        assert not self.done and (not ride or self.ride_bucket)
         r = self.runner
         slot = np.int32(self.slot)
         n = len(self.prompt)
@@ -2131,12 +2212,20 @@ class PagedAdmission:
             padded[0, :take] = self.prompt[self.pos:self.pos + take]
             crow = (self._counts_row() if last
                     else r._zero_counts)  # sample=False ignores counts
-            r.kv, r.state, tok = r._prefill_paged(
-                r.params, r.kv, r.state, padded,
-                np.int32(take), np.int32(self.pos), table_row,
-                slot, crow, bucket=bucket,
-                sample=last,
-            )
+            if ride:
+                r.kv, r.state, tok = r._decode_prefill_paged(
+                    r.params, r.kv, r.state, r.block_tables, padded,
+                    np.int32(take), np.int32(self.pos), table_row, slot,
+                    crow, bucket=bucket,
+                )
+                self.rode = True
+            else:
+                r.kv, r.state, tok = r._prefill_paged(
+                    r.params, r.kv, r.state, padded,
+                    np.int32(take), np.int32(self.pos), table_row,
+                    slot, crow, bucket=bucket,
+                    sample=last,
+                )
             ctx = r.chunk_span(offset, bucket)
             self.pos += take
         self.last_chunk = {"chunk_tokens": take, "chunk_bucket": bucket,
@@ -2160,9 +2249,10 @@ class PagedAdmission:
         (guarded: a device that never answers would hang here silently)."""
         with self.runner.watchdog.guard("device"):
             # a model with recurrent state sends the chunk's routed work
-            # behind the token (``_prefill_paged_fn``)
+            # behind the token (``_prefill_paged_fn``); a chunk that rode
+            # has the step's tokens in front of its own
             return int(np.asarray(  # jaxlint: disable=host-sync-in-hot-path
-                self.first).reshape(-1)[0])
+                self.first).reshape(-1)[-1 if self.rode else 0])
 
     def step_chunk(self) -> Optional[int]:
         """Dispatch the next chunk; the first token once the admission is

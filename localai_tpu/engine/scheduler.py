@@ -250,7 +250,9 @@ class _Dispatch:
     t_issue: float
     fresh: bool              # first dispatch of its shape: pays a compile
     # a final prefill chunk (``toks`` its one sampled token, k = 0): the
-    # admission whose first token this is
+    # admission whose first token this is. With k = 1 the chunk rode the
+    # step (``Scheduler._launch_ride``): ``toks`` is the step's [S] tokens
+    # and the first token behind them
     first: Optional[_PendingPrefill] = None
     # what the launch held, counted when it was enqueued and written with
     # its row at the drain (``Scheduler._launch``)
@@ -406,6 +408,9 @@ class Scheduler:
         self._chunked = bool(getattr(runner, "paged", False))
         self._prefills: "deque[_PendingPrefill]" = deque()
         self.total_prefill_chunks = 0
+        # ... of them, a prompt's small last chunks that were ONE launch with
+        # the decode step behind them (``_launch_ride``)
+        self.total_chunk_rides = 0
         # chunk launches by the row parts they ran behind the attend
         # (``chunk_parts`` of the flight ring: 1 a whole bucket, 2 to 4 the
         # live quarters of a prompt's last)
@@ -675,6 +680,7 @@ class Scheduler:
                     self.runner, "kv_overcommit", 1.0),
                 "kv_shared_tokens": alloc.shared_tokens_total,
                 "prefill_chunks": self.total_prefill_chunks,
+                "chunk_rides": self.total_chunk_rides,
                 "prefill_chunk_parts": dict(self.total_chunk_parts),
                 "prefill_chunk_queue_depth": sum(
                     p.adm.chunks_remaining for p in list(self._prefills)
@@ -888,7 +894,9 @@ class Scheduler:
                 for d in inflight:
                     if d.first is not None:
                         n += d.first.handle is c.handle
-                    elif d.seq > c.admit_seq:
+                    # (a chunk that rode a step: the step's token for the
+                    # streams armed before it, as any step's)
+                    if d.seq > c.admit_seq:
                         n += d.k
                 cached += n
                 if self._window:
@@ -949,7 +957,7 @@ class Scheduler:
         book_ms = min(taken["book_ms"], gap_ms - process_ms)
         free_ms = min(taken["free_ms"], gap_ms - process_ms - book_ms)
         taken.update(process_ms=process_ms, book_ms=book_ms, free_ms=free_ms)
-        if not fresh and program in ("decode", "decode_n"):
+        if not fresh and program in ("decode", "decode_n", "decode_chunk"):
             self._note_slow(steps, taken)
         self.flight.record(
             program=program,
@@ -1290,10 +1298,10 @@ class Scheduler:
             # routed counts split off, under the name the row's write has
             with TraceAnnotation("sched.record"):
                 self._clock.leave(waiting, sync_s)
-                if d.first is None:
+                if d.first is None or k:
                     taken = self._take_row(now)
                 rows = self._routed(rows, d.held)
-            if d.first is not None:
+            if d.first is not None and not k:
                 # a final prefill chunk: the device has just finished it,
                 # so the decode step behind it is timed from here
                 self._last_drain_t = now
@@ -1310,6 +1318,11 @@ class Scheduler:
             window = None
             if k == 0 and self.spec is not None:  # speculative window
                 window = self.spec.observe_window(rows)
+            rode = d.first
+            if rode is not None:
+                # a chunk rode this step: its first token lies behind the
+                # step's [S]
+                rows, first_tok = rows[:-1], int(rows[-1])
             # per-token timing for the adaptive streaming dispatch size:
             # when this dispatch was issued while another was still on the
             # device, the interval between drains is pure device time for
@@ -1330,7 +1343,8 @@ class Scheduler:
                 k_eff = (max(1, round(window["emitted"]
                                       / window["windows"]))
                          if window["windows"] else 0)
-            if not fresh and k_eff > 0:
+            if not fresh and k_eff > 0 and rode is None:
+                # (a step that carried a chunk is no sample of a step's time)
                 self._observe_step_time(dt / k_eff)
             self._last_drain_t = now
             if rows.ndim == 1:
@@ -1338,6 +1352,9 @@ class Scheduler:
             t_proc = time.monotonic()
             self._anat_book_s += t_proc - now
             self._process_rows(rows, seq)
+            if rode is not None:
+                with TraceAnnotation("sched.process"):
+                    self._first_token(rode, first_tok)
             t_book = time.monotonic()
             self._anat_process_s += t_book - t_proc
             with TraceAnnotation("sched.record"):
@@ -1352,6 +1369,7 @@ class Scheduler:
                 # dispatches are flagged
                 self._flight_record(
                     "spec" if k == 0
+                    else "decode_chunk" if rode is not None
                     else ("decode_n" if k > 1 else "decode"),
                     k_eff, dt, fresh,
                     spec_proposed=window["proposed"] if window else 0,
@@ -1366,7 +1384,7 @@ class Scheduler:
             # threads the tokens have just woken then take in turn: a
             # millisecond of a 32-stream batch that had no name (PR 53)
             with TraceAnnotation("sched.free"):
-                del d, toks, rows, window
+                del d, toks, rows, window, rode
             self._anat_free_s += time.monotonic() - t_free
 
         while not self._stopping and self._epoch == epoch:
@@ -1389,9 +1407,12 @@ class Scheduler:
             # chunked prefill: ONE chunk per loop iteration, so pending
             # chunks and decode dispatches alternate — a long prompt
             # spreads its prefill across the batch's decode cadence
-            # instead of stalling it
+            # instead of stalling it. A prompt's small LAST chunk waits for
+            # the plain step this iteration launches and rides it
+            # (``_ride_head``): the two are one program
             chunked = False
-            if self._prefills:
+            ride = self._ride_head()
+            if self._prefills and ride is None:
                 with TraceAnnotation("sched.prefill_chunk"):
                     chunked, entry = self._step_prefill_chunk()
                 if entry is not None:
@@ -1575,16 +1596,20 @@ class Scheduler:
                         self._spec_dirty = True
                     t_count = time.monotonic()
                     with TraceAnnotation("sched.count"):
-                        steps = self._effective_steps()
+                        # (``_ride_head`` has asked: a ride is one step)
+                        steps = 1 if ride else self._effective_steps()
                         self._dispatch_seq += 1
-                        fresh = self._fresh_shape(steps)
+                        fresh = self._fresh_shape(
+                            ("ride", ride.adm.ride_bucket) if ride else steps)
                         held = self._launch(steps, inflight)
                     t_issue = time.monotonic()
                     self._anat_book_s += t_issue - t_count
                     with TraceAnnotation("sched.decode_launch"):
                         with TraceAnnotation(
                                 f"sched.launch/{held['launch']}"):
-                            if steps > 1:
+                            if ride:
+                                tokens = self._launch_ride(ride, held)
+                            elif steps > 1:
                                 tokens = self.runner.step_n_async(steps)
                             else:
                                 tokens = self.runner.step_async()
@@ -1597,7 +1622,8 @@ class Scheduler:
                     self._anat_launch_s += time.monotonic() - t_issue
                     inflight.append(_Dispatch(
                         tokens, self._dispatch_seq, steps,
-                        bool(inflight), t_issue, fresh, held=held))
+                        bool(inflight), t_issue, fresh, first=ride,
+                        held=held))
                     # with a final chunk's entry in the queue this reads
                     # more than one: the step that was in flight at the
                     # arrival (on time), then the chunk's token as soon as
@@ -2043,9 +2069,7 @@ class Scheduler:
         t_end = time.monotonic()
         dt = t_end - t0
         taken = self._take_row(t_end, parts=False)
-        self.total_prefill_chunks += 1
-        parts = held.get("chunk_parts", 1)
-        self.total_chunk_parts[parts] = self.total_chunk_parts.get(parts, 0) + 1
+        self._count_chunk(held)
         # anatomy: the admission object measured its own enqueue span; the
         # remainder of THIS span is chunk staging and slot bookkeeping
         # (sched). Pre-built phases so the chunk does not consume
@@ -2063,6 +2087,57 @@ class Scheduler:
         # next decode row's gap, and gets its name there
         self._anat_book_s += time.monotonic() - t_end
         return True, entry
+
+    # engine-thread only (the loop's chunk stage) — see _run_loop
+    def _ride_head(  # jaxlint: disable=lock-guarded-attr
+            self) -> Optional[_PendingPrefill]:
+        """The head admission, where its next chunk will RIDE the decode
+        step this iteration launches instead of going out in front of it:
+        the chunk is the prompt's last and of at most ``RIDE_ROWS`` rows on
+        a runner whose programs can (``PagedAdmission.ride_bucket``), streams
+        are decoding, and the launch will be the plain pipelined single
+        step: no stream and not this request under a constraint (theirs is
+        the synchronous branch, and the host waits for a constrained first
+        token), no drafter whose window the launch might be, one step a
+        dispatch. Asked ONCE an iteration, of what the loop holds; every
+        other case is ``_step_prefill_chunk``'s, as before: a cancelled
+        head too, which is dropped there."""
+        if not self._prefills or not self._slots or self.spec is not None:
+            return None
+        pf = self._prefills[0]
+        if (pf.handle.cancelled or pf.handle.request.constraint is not None
+                or not getattr(pf.adm, "ride_bucket", None)
+                or any(c.handle.request.constraint is not None
+                       for c in self._slots.values())):
+            return None
+        return pf if self._effective_steps() == 1 else None
+
+    def _launch_ride(self, pf: _PendingPrefill,
+                     held: dict):  # jaxlint: disable=lock-guarded-attr
+        """Enqueue the head admission's last chunk and the decode step as
+        ONE program (``ModelRunner._decode_prefill_paged_fn``); the device
+        array of the step's [S] tokens with the first token behind them.
+        What ``_step_prefill_chunk`` does for a final chunk is done here for
+        this one: the slot's context is installed at once, behind the
+        step's number (the step's row for the new slot is not its stream's:
+        ``admit_seq``), and ``held`` takes what the chunk held beside what
+        the step did. Its one ring row is written at the drain, as
+        ``decode_chunk``."""
+        pf.adm.launch_chunk(ride=True)
+        # (behind the launch: one that raises leaves the admission queued)
+        self._prefills.popleft()
+        held.update(pf.adm.last_chunk)
+        self._install_slot(pf.slot, pf.handle, pf.base, pf.mask_set)
+        self._count_chunk(held)
+        self.total_chunk_rides += 1
+        return pf.adm.first
+
+    def _count_chunk(self, held: dict) -> None:
+        """One more chunk launched, by the row parts it ran behind the
+        attend (``held``: what its launch held)."""
+        self.total_prefill_chunks += 1
+        parts = held.get("chunk_parts", 1)
+        self.total_chunk_parts[parts] = self.total_chunk_parts.get(parts, 0) + 1
 
     def _free_frontiers(self) -> np.ndarray:
         """[S] KV frontier of every free slot. A paged runner answers from

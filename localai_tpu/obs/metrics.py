@@ -211,6 +211,13 @@ class Registry:
             "localai_prefill_chunks_total",
             "Chunked-prefill dispatches issued by the engine thread",
         )
+        self.prefill_chunk_rides = Counter(
+            "localai_prefill_chunk_rides_total",
+            "Chunked-prefill dispatches that were ONE program launch with "
+            "the decode step behind them: a prompt's last chunk of at most "
+            "128 rows admitted while streams decode (the weights are read "
+            "once for both)",
+        )
         self.prefill_chunk_parts = Counter(
             "localai_prefill_chunk_parts_total",
             "Chunked-prefill dispatches by the row parts they ran behind "
@@ -748,6 +755,7 @@ def update_engine_gauges(name: str, m: dict,
         reg.prefill_chunk_queue.set(
             m.get("prefill_chunk_queue_depth", 0), model=name)
         reg.prefill_chunks.set_total(m.get("prefill_chunks", 0), model=name)
+        reg.prefill_chunk_rides.set_total(m.get("chunk_rides", 0), model=name)
         for parts, n in (m.get("prefill_chunk_parts") or {}).items():
             reg.prefill_chunk_parts.set_total(n, model=name, parts=str(parts))
         impl = m.get("paged_attn_impl")

@@ -119,7 +119,8 @@ def agree(served, ref, tol, spread=0.2):
 def lowered_texts(r: ModelRunner, programs, debug_info: bool = False) -> dict:
     """The lowered text (StableHLO) of a paged runner's programs by name:
     ``decode``, ``decode_n`` (4 steps), ``prefill_1`` / ``prefill_0`` (a chunk
-    of 5 tokens in a bucket of 32, sampling or not), ``arm``."""
+    of 5 tokens in a bucket of 32, sampling or not), ``arm``, ``ride`` (that
+    chunk and the step as one program, where the runner's can)."""
     chunk = (jnp.zeros((1, 32), jnp.int32), jnp.int32(5), jnp.int32(0),
              r.block_tables[0], jnp.int32(0),
              jnp.zeros(r.cfg.vocab_size, jnp.int32))
@@ -143,6 +144,9 @@ def lowered_texts(r: ModelRunner, programs, debug_info: bool = False) -> dict:
             r.params, r.kv, r.state, *chunk, bucket=32, sample=True),
         "prefill_0": lambda: prefill.lower(
             r.params, r.kv, r.state, *chunk, bucket=32, sample=False),
-        "arm": arm}
+        "arm": arm,
+        "ride": lambda: jax.jit(
+            r._decode_prefill_paged_fn, static_argnames=("bucket",)).lower(
+                r.params, r.kv, r.state, r.block_tables, *chunk, bucket=32)}
     return {name: lower[name]().as_text(debug_info=debug_info)
             for name in programs}

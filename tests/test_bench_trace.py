@@ -593,3 +593,50 @@ def test_trace_2_scores_first_and_asks_the_server_afterwards(
         assert min(times) > last, name
     assert min(r.sent for r in slice_) > last
     assert not list(run_dir.rglob("*.xplane.pb"))   # reduced, then deleted
+
+
+# -- PR 59: rides over small last chunks, from the ring ----------------------
+
+
+def _ride_rows(*rows):
+    """Ring rows (program, seconds into the window, chunk_bucket[, compile])."""
+    return [{"program": p, "ts_unix": 1000.0 + at, "chunk_bucket": bucket,
+             "compile": bool(rest), "launch": i + 1}
+            for i, (p, at, bucket, *rest) in enumerate(rows)]
+
+
+@pytest.mark.parametrize("rows, value", [
+    # three rides, one small last chunk that met an idle engine; a 512-row
+    # chunk is no candidate, nor is a row outside the window or one that
+    # compiled
+    ([("decode_chunk", 1.0, 128), ("decode_chunk", 2.0, 128),
+      ("decode_chunk", 3.0, 64), ("prefill_chunk", 4.0, 128),
+      ("prefill_chunk", 5.0, 512), ("decode", 5.5, 0),
+      ("prefill_chunk", -1.0, 128), ("prefill_chunk", 11.0, 128),
+      ("prefill_chunk", 6.0, 128, True), ("decode_chunk", 7.0, 128, True)],
+     75.0),
+    ([("decode_chunk", 1.0, 128)], 100.0),
+    # no ride in the window (the parent's ring; a cell that steps aside;
+    # an empty ring): nothing to read, and no error
+    ([("prefill_chunk", 4.0, 128), ("decode", 5.0, 0)], None),
+    ([], None)])
+def test_chunk_ride_share_counts_rides_over_small_last_chunks(rows, value):
+    from localai_tpu.engine import runner
+
+    read = spec.load_reader("runner.chunk_ride_share", ROOT)
+    assert read.__globals__["RIDE_ROWS"] == runner.RIDE_ROWS
+    assert read(clocked_ctx(_ride_rows(*rows))) == (
+        value if value is None else pytest.approx(value))
+    # a ring with no ``chunk_bucket`` column (before PR 39): still no error
+    bare = [{k: v for k, v in r.items() if k != "chunk_bucket"}
+            for r in _ride_rows(*rows)]
+    assert read(clocked_ctx(bare)) == (None if value is None else 100.0)
+
+
+def test_the_ride_entry_is_appended_for_the_one_cell_that_claims_it():
+    entries = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]
+    assert entries[-1] == {
+        "name": "runner.chunk_ride_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "runner",
+        "moves": "stall_ms_p98", "workloads": ["m7b-decode"]}
+    assert (BENCH / "layers" / "runner.chunk_ride_share.py").exists()

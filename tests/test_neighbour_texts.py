@@ -47,6 +47,16 @@ What each row has seen:
   ``sigmoid_scores``' epsilon became arguments that every older family
   leaves as they were): as taken. Its chunk holds no kernel, so the two
   rows' ``prefill`` texts are one text.
+- PR 59 (a prompt's small last chunk rides the decode step,
+  ``_decode_prefill_paged_fn``): NO row retaken. The two cells that ride
+  (the 7B and Ouro: ``models.llama.forward`` on one chip) gain a ``ride``
+  text, taken from this PR's own tree as a new program is; their four older
+  texts stand as taken (``_decode_tail`` and ``_first_token`` trace what they
+  traced where no caller hands them logits). The configurations that step
+  aside (the four-chip trunk by ``overlap_mode``; ``qwen3_next``, ``afmoe``,
+  ``axk1``, ``dots3_note``, ``falcon_h1`` and, with no row here, ``lfm2_moe``
+  by ``own_forward``) have no such program (``ModelRunner.rides`` is False,
+  held below) and every text of theirs is the parent's.
 """
 
 import functools
@@ -130,7 +140,9 @@ TAKEN = [
         "prefill_1":
             "5da338912ddb6924ef2ad7c994a1a6db2a1d233d941281a99b3c37a6affe5b12",
         "arm":
-            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499"}),
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
+        "ride":
+            "8aea657dd172f22494f4fbe9cf4edaf8334c01488f34430091cbb3294315397f"}),
     cell("mistral-small-24b-int8-tp4", {
         "decode":
             "17d1318cb3353870a3a30acc6c055c94e692ac446f0dabaf22599ca0d218224b",
@@ -148,7 +160,9 @@ TAKEN = [
         "prefill_1":
             "a6e0180316c86e3f77a14a239cb921c62f4bac783543a9b790d6eed56d97be94",
         "arm":
-            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499"}),
+            "ae443047b695987e2d6d6a07f968496b8a8cf5eb35dc10355e2fdafe7d166499",
+        "ride":
+            "fa04f314f83073a65bab61e8dbe1fae3c9adc52f250f84c9ba6a6badb9038bd6"}),
     family({**QWEN3_NEXT, **WIDE}, 16, "pallas_interpret", {
         "decode":
             "e4e6c6be85c5f816aba14559c96f7c5a34c73b67a84fdec4b4c1ce496ba7baea",
@@ -221,6 +235,10 @@ TAKEN = [
 
 @pytest.mark.parametrize("build, taken", TAKEN)
 def test_the_programs_lower_to_the_text_taken(build, taken):
+    r = build()
+    # a row with a ``ride`` text is a configuration whose last chunk can ride
+    # the step; every other one steps aside and holds no such program
+    assert r.rides == ("ride" in taken)
     now = {name: hashlib.sha256(text.encode()).hexdigest()
-           for name, text in families.lowered_texts(build(), taken).items()}
+           for name, text in families.lowered_texts(r, taken).items()}
     assert now == taken

@@ -113,10 +113,12 @@ def test_chunked_prefill_interleaves_with_decode(tiny):
     finally:
         s.shutdown()
     progs = [rec["program"] for rec in flight.snapshot(limit=256)]
-    chunk_idx = [i for i, p in enumerate(progs) if p == "prefill_chunk"]
+    # (the prompt's LAST chunk may ride a decode step: ``decode_chunk``)
+    chunks = ("prefill_chunk", "decode_chunk")
+    chunk_idx = [i for i, p in enumerate(progs) if p in chunks]
     assert len(chunk_idx) >= 5, progs
     interleaved = any(
-        any(p != "prefill_chunk" for p in progs[i + 1:j])
+        any(p not in chunks for p in progs[i + 1:j])
         for i, j in zip(chunk_idx, chunk_idx[1:])
     )
     assert interleaved, progs
@@ -294,6 +296,140 @@ def test_spec_decoder_accepts_paged_runner(tiny):
                       prefill_buckets=[16], kv_dtype="float32", paged=True)
     with pytest.raises(ValueError, match="contiguous"):
         SpecDecoder(rc, rp2)
+
+
+# ---------------------------------------------------------------------------
+# a prompt's small last chunk rides the decode step (PR 59)
+# ---------------------------------------------------------------------------
+
+
+def _state_and_pool(r):
+    """Every leaf of the pool and of the decode state, on the host."""
+    import numpy as np
+
+    state = r.state
+    leaves = jax.tree.leaves((r.kv, state.tokens, state.positions,
+                              state.active, state.counts, state.bias,
+                              state.params, jax.random.key_data(state.keys)))
+    return [np.asarray(a) for a in leaves]
+
+
+@pytest.mark.parametrize("sampling", [
+    dict(temperature=0.0), dict(temperature=0.8, top_p=0.95, seed=11)],
+    ids=["greedy", "seeded"])
+@pytest.mark.parametrize("weights, kv_dtype, attn_impl", [
+    ("int8", "bfloat16", "pallas_interpret"),   # the 7B cell's: the kernel
+    ("", "bfloat16", "xla"),                    # writes the step's rows
+    ("", "int8", "pallas_interpret")])          # a scaled pool: the scatter
+def test_a_ride_leaves_what_the_step_then_the_chunk_leave(
+        weights, kv_dtype, attn_impl, sampling):
+    """``_decode_prefill_paged_fn`` against the two programs it stands for,
+    on the same state: a decode step (the new slot not yet armed: its rows
+    go to the trash block), then the arming update and the prompt's last
+    chunk. Same pool to the bit, same decode state, same S tokens, same
+    first token, and the same streams afterwards."""
+    import numpy as np
+
+    model = resolve_model("debug:tiny", dtype="bfloat16",
+                          quantization=weights)
+
+    def serve(ride):
+        r = ModelRunner(model.cfg, model.params, num_slots=4, max_ctx=96,
+                        prefill_buckets=[16, 32], kv_dtype=kv_dtype,
+                        paged=True, kv_block_tokens=16, prefill_chunk=16,
+                        attn_impl=attn_impl, seed=3)
+        assert r.rides
+        for text in (b"the first stream", b"second"):
+            r.admit(r.acquire_slot(), list(text), **{**sampling, "seed": 7})
+        for _ in range(3):
+            r.step()
+        adm = r.begin_admit(r.acquire_slot(),
+                            list(b"a third prompt of two chunks"), **sampling)
+        assert adm.ride_bucket is None      # 28 tokens: not the last chunk
+        assert adm.launch_chunk() is False
+        assert adm.ride_bucket == 16
+        if ride:
+            assert adm.launch_chunk(ride=True) is True
+            out = np.asarray(adm.first)
+            step, first = out[:-1], int(out[-1])
+            assert adm.first_token() == first
+        else:
+            step = r.step()
+            first = adm.step_chunk()
+        after = [r.step() for _ in range(3)]
+        return [np.asarray(step), first, *after], _state_and_pool(r)
+
+    (want, want_state), (got, got_state) = serve(False), serve(True)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    assert len(want_state) == len(got_state)
+    for a, b in zip(want_state, got_state):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("sampling", [
+    dict(temperature=0.0), dict(temperature=0.8, top_p=0.95, seed=5)],
+    ids=["greedy", "seeded"])
+def test_streams_are_the_same_with_and_without_a_ride(tiny, sampling):
+    """Through the scheduler: a stream's tokens are the same whether it was
+    admitted into an idle engine (its chunk a launch of its own) or into a
+    busy one (its chunk rides a neighbour's decode step), and the
+    neighbour's are the same with and without an admission riding. The ride
+    is one ``decode_chunk`` row: a decode row's counts and the chunk's."""
+    import time
+
+    from localai_tpu.engine import kvcache as kvc
+
+    def serve(neighbour, arrival):
+        flight = FlightRecorder(256)
+        runner = ModelRunner(tiny.cfg, tiny.params, num_slots=4, max_ctx=96,
+                             prefill_buckets=[16, 32], kv_dtype="float32",
+                             paged=True, kv_block_tokens=16, prefill_chunk=16)
+        s = Scheduler(runner, ByteTokenizer(), flight=flight, multi_step=1)
+        a = b = None
+        try:
+            if neighbour:
+                a = s.submit(GenRequest(prompt=list(b"neighbour"),
+                                        max_new_tokens=60, ignore_eos=True,
+                                        temperature=0.7, seed=1))
+                while a.completion_tokens < 2:
+                    time.sleep(0.001)
+            if arrival:
+                b = s.submit(GenRequest(prompt=list(b"a new prompt arrives"),
+                                        max_new_tokens=8, ignore_eos=True,
+                                        **sampling))
+                b.result(timeout=60)
+            if a is not None:
+                a.result(timeout=60)
+            m = s.metrics()
+        finally:
+            s.shutdown()
+        rows = [r for r in flight.snapshot(limit=256)
+                if r["program"] == "decode_chunk"]
+        return (a and a.token_ids, b and b.token_ids, m, rows)
+
+    _, alone, m_idle, rows_idle = serve(False, True)
+    quiet, _, m_quiet, _ = serve(True, False)
+    beside, rode, m_busy, rows_busy = serve(True, True)
+    assert rode == alone and beside == quiet
+    # 20 tokens: a chunk of 16, then the last of 4 in the 16 bucket
+    assert (m_idle["chunk_rides"], m_idle["prefill_chunks"]) == (0, 2)
+    assert (m_quiet["chunk_rides"], m_quiet["prefill_chunks"]) == (0, 1)
+    assert (m_busy["chunk_rides"], m_busy["prefill_chunks"]) == (1, 3)
+    assert not rows_idle and len(rows_busy) == 1
+    row = rows_busy[0]
+    assert (row["steps"], row["live_slots"]) == (1, 1)
+    assert row["attended_tokens"] > 9       # the neighbour's context
+    assert (row["chunk_tokens"], row["chunk_bucket"], row["chunk_offset"],
+            row["chunk_parts"]) == (4, 16, 16, 1)
+    assert row["chunk_ctx"] == kvc.attend_span(16, 16, 96, 16)
+
+    from localai_tpu.obs import metrics as obs_metrics
+
+    reg = obs_metrics.Registry()
+    obs_metrics.update_engine_gauges("tiny", m_busy, registry=reg)
+    assert ('localai_prefill_chunk_rides_total{model="tiny"} 1'
+            in reg.render())
 
 
 # ---------------------------------------------------------------------------

@@ -1004,6 +1004,11 @@ def _held_run(s: Scheduler, monkeypatch, steps):
     monkeypatch.setattr(s.runner, "step_async", spy(s.runner.step_async))
     monkeypatch.setattr(s.runner, "step_n_async",
                         spy(s.runner.step_n_async))
+    # (a last chunk that rides a step is launched with it: the new slot is
+    # not among the step's, and is installed behind the launch)
+    ride = s._launch_ride
+    monkeypatch.setattr(s, "_launch_ride",
+                        lambda pf, held: spy(lambda: ride(pf, held))())
     mark = s._launch_seq
     keeper = _keeper(s)
     # the prefix pool outlives a run: each run's prompts open with its own tag
@@ -1044,9 +1049,11 @@ def test_a_decode_row_holds_what_its_launch_held(paged, monkeypatch, steps):
         assert row["live_slots"] == len(held) > 0
         assert row["attended_tokens"] == sum(
             at + 1 + j for _, at, _ in held for j in range(k))
-        assert not any(row[c] for c in ("chunk_tokens", "chunk_bucket",
-                                        "chunk_offset", "chunk_ctx",
-                                        "chunk_parts"))
+        # every other count of a decode row is 0; a row whose step carried
+        # a prompt's last chunk (``decode_chunk``) holds the chunk's too
+        assert all(bool(row[c]) == (row["program"] == "decode_chunk")
+                   for c in ("chunk_tokens", "chunk_bucket", "chunk_ctx",
+                             "chunk_parts"))
         done = [(h, at + 1 - h.prompt_tokens, seen) for h, at, seen in held]
         ended_inside += sum(
             h.finish_reason == "length" and g < h.completion_tokens == 5 <= g + k
@@ -1087,6 +1094,11 @@ def test_live_slots_are_the_rows_off_the_trash_block(paged, monkeypatch,
 
     monkeypatch.setattr(r, "step_async", spy(r.step_async))
     monkeypatch.setattr(r, "step_n_async", spy(r.step_n_async))
+    # (a chunk that rides a step: its slot's table row goes up with the
+    # launch and the program puts it back on the trash block for the step)
+    ride = s._launch_ride
+    monkeypatch.setattr(s, "_launch_ride",
+                        lambda pf, held: spy(lambda: ride(pf, held))())
     rows, log, _ = _held_run(s, monkeypatch, steps)
     decode = {n: row for n, row in rows.items()
               if row["program"].startswith("decode")}
@@ -1119,9 +1131,11 @@ def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
     order = sorted(rows)
     assert order == [rows[n]["launch"] for n in order] and order[0] > 0
     assert len(set(order)) == len(rows)
+    # (a prompt's last chunk may have ridden a decode step: ``decode_chunk``)
+    kinds = ("prefill_chunk", "decode_chunk")
     ts = [rows[n]["ts"] for n in order if rows[n]["program"] == "prefill_chunk"]
     assert ts == sorted(ts)
-    chunks = [rows[n] for n in order if rows[n]["program"] == "prefill_chunk"]
+    chunks = [rows[n] for n in order if rows[n]["program"] in kinds]
     assert served[1][1] == 32 and served[0][1] == served[2][1] == 0
     want = [(6, 0)]                                 # the keeper's one chunk
     for prompt, reused in served:
@@ -1134,7 +1148,10 @@ def test_a_prefill_row_holds_its_chunk(paged, monkeypatch, steps):
         assert c["chunk_bucket"] in r.buckets
         assert c["chunk_bucket"] == r.bucket_for(c["chunk_tokens"])
         assert c["chunk_ctx"] == r.ctx_pad == 96
-        assert c["live_slots"] == c["attended_tokens"] == 0
+        if c["program"] == "prefill_chunk":
+            assert c["live_slots"] == c["attended_tokens"] == 0
+        else:
+            assert c["live_slots"] > 0 and c["chunk_bucket"] <= 128
 
 
 # ---------------------------------------------------------------------------
@@ -1450,3 +1467,158 @@ def test_the_thread_clock_is_reopened_by_a_rebuild_and_may_be_unreadable(
     s.rebuild()
     assert _served_rows(s, "read again", 4)[-1]["runq_ms"] is not None
     assert s._clock._fd is not None
+
+
+# ---------------------------------------------------------------------------
+# a prompt's small last chunk rides the decode step (PR 59): who steps aside
+
+
+def _aside_runner(tiny, monkeypatch, why):
+    import jax
+
+    kw = dict(PAGED_KW)
+    cfg, params = tiny.cfg, tiny.params
+    if why == "own_forward":        # a family's own forward: recurrent state
+        import families
+        from test_falcon_h1 import HF
+
+        from localai_tpu.models import llama as mdl
+
+        cfg = families.config(HF, "float32")
+        params = mdl.init_params(jax.random.key(0), cfg)
+        kw["attn_impl"] = "xla"
+    elif why == "overlap_mode":     # the manual-TP trunk of a mesh
+        from localai_tpu.parallel import sharding as shd
+        from localai_tpu.parallel.mesh import MeshPlan, build_mesh
+
+        monkeypatch.setenv("LOCALAI_MESH_OVERLAP", "auto")
+        kw["mesh"] = build_mesh(MeshPlan(model=2), devices=jax.devices()[:2])
+        params = shd.shard_params(params, cfg, kw["mesh"])
+    elif why == "contiguous":
+        kw = dict(num_slots=4, max_ctx=96, prefill_buckets=[16, 32],
+                  kv_dtype="float32")
+    elif why == "bucket_512":
+        kw.update(max_ctx=768, prefill_buckets=[128, 512], prefill_chunk=512,
+                  kv_block_tokens=64)
+    r = ModelRunner(cfg, params, **kw)
+    assert r.own_forward == (why == "own_forward")
+    assert bool(r.overlap_mode) == (why == "overlap_mode")
+    assert r.rides == (why not in ("own_forward", "overlap_mode",
+                                   "contiguous"))
+    return r
+
+
+@pytest.mark.parametrize("why", [
+    "own_forward", "overlap_mode", "contiguous", "bucket_512", "k_2",
+    "constrained", "constrained_neighbour", "idle", None])
+def test_who_steps_aside_from_the_ride(tiny, monkeypatch, why):
+    """One rule, by what the loop holds when a prompt's last chunk is the
+    head of the queue: the chunk rides the decode step (None: the control)
+    unless the runner's programs cannot (a family's own forward, the
+    manual-TP trunk, the contiguous cache), its bucket is over
+    ``RIDE_ROWS``, the dispatch is of more than one step, the request or a
+    neighbour is under a constraint (the synchronous branch), or no stream
+    is decoding. Whoever steps aside is served as before: ``chunk_rides``
+    stays 0 and ``prefill_chunks`` counts."""
+    r = _aside_runner(tiny, monkeypatch, why)
+    s = Scheduler(r, ByteTokenizer(), multi_step=1)
+    if why == "k_2":
+        monkeypatch.setattr(s, "_effective_steps", lambda pipelined=True: 2)
+    paged = why != "contiguous"
+    try:
+        keeper = None
+        if why != "idle":
+            keeper = s.submit(_req(
+                "keeper", max_new_tokens=70, stream=True, ignore_eos=True,
+                constraint=(_Band(70) if why == "constrained_neighbour"
+                            else None), **GREEDY))
+            assert _wait(lambda: keeper.completion_tokens >= 3)
+        text = "x" * 150 if why == "bucket_512" else "an arrival"
+        h = s.generate(_req(
+            text, max_new_tokens=4, ignore_eos=True,
+            constraint=_Band(4) if why == "constrained" else None, **GREEDY),
+            timeout=120)
+        assert h.finish_reason in ("length", "stop")
+        assert len(h.token_ids) == 4
+        if keeper is not None:
+            keeper.cancel()
+            keeper.result(60)
+        chunks = s.total_prefill_chunks
+        m = s.metrics()
+    finally:
+        s.shutdown()
+    rides = int(why is None)
+    assert s.total_chunk_rides == rides
+    assert chunks == (2 - (why == "idle") if paged else 0)
+    if paged:
+        assert (m["chunk_rides"], m["prefill_chunks"]) == (rides, chunks)
+    programs = [row["program"] for row in s.flight.snapshot()]
+    assert ("decode_chunk" in programs) == bool(rides)
+
+
+def test_a_cancelled_head_admission_is_dropped_not_ridden(tiny, monkeypatch):
+    """An admission cancelled while its last chunk waits at the head of the
+    queue, streams decoding beside it: it is dropped where a cancelled
+    admission always was (``_step_prefill_chunk``: blocks freed, slot back,
+    ``cancelled``), not launched with the step."""
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    s = Scheduler(r, ByteTokenizer(), multi_step=1)
+    start = s._start
+
+    def cancel_once_queued(slot, handle, positions=None):
+        ok = start(slot, handle, positions)
+        if ok and handle.request.max_new_tokens == 5:
+            assert s._prefills[-1].adm.ride_bucket == 16 and s._slots
+            handle.cancel()
+        return ok
+
+    monkeypatch.setattr(s, "_start", cancel_once_queued)
+    try:
+        keeper = s.submit(_req("keeper", max_new_tokens=60, stream=True,
+                               ignore_eos=True, **GREEDY))
+        assert _wait(lambda: keeper.completion_tokens >= 3)
+        free = r.allocator.stats().free
+        h = s.submit(_req("cancelled", max_new_tokens=5, **GREEDY))
+        assert h.result(60).finish_reason == "cancelled" and not h.token_ids
+        assert _wait(lambda: r.allocator.stats().free == free)
+        assert len(r.free_slots()) == 3
+        after = s.generate(_req("the next one", max_new_tokens=4,
+                                ignore_eos=True, **GREEDY), timeout=60)
+        assert after.finish_reason == "length"
+        keeper.cancel()
+        keeper.result(60)
+    finally:
+        s.shutdown()
+    # the cancelled admission launched nothing; the next one rode
+    assert (s.total_chunk_rides, s.total_prefill_chunks) == (1, 2)
+    assert r.allocator.check_invariants() == []
+
+
+def test_a_ride_that_fails_to_launch_leaves_its_admission_queued(
+        tiny, monkeypatch):
+    """The ride's launch raises (a program that does not compile): the
+    engine fails the streams that were decoding, as for any decode launch
+    that raises, and the admission, still at the head of the queue, is
+    served by the plain chunk into the now idle engine."""
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    s = Scheduler(r, ByteTokenizer(), multi_step=1)
+    calls = []
+
+    def no_program(*a, **k):
+        calls.append(k["bucket"])
+        raise RuntimeError("the ride does not compile")
+
+    monkeypatch.setattr(r, "_decode_prefill_paged", no_program)
+    try:
+        keeper = s.submit(_req("keeper", max_new_tokens=60, stream=True,
+                               ignore_eos=True, **GREEDY))
+        assert _wait(lambda: keeper.completion_tokens >= 3)
+        h = s.generate(_req("an arrival", max_new_tokens=4, ignore_eos=True,
+                            **GREEDY), timeout=60)
+        assert keeper.result(60).finish_reason == "error"
+        assert (h.finish_reason, len(h.token_ids)) == ("length", 4)
+    finally:
+        s.shutdown()
+    assert calls == [16]
+    assert (s.total_chunk_rides, s.total_prefill_chunks) == (0, 2)
+    assert r.allocator.check_invariants() == []

@@ -384,14 +384,18 @@ def compile_program(fn, *args, **static):
 
 def compile_cell_program(r, a, program, build=compile_program):
     """One of a cell's dispatched programs over ``abstract_runner``'s
-    (runner, avals): ``decode``, ``decode_n2`` or ``prefill_chunk_<bucket>``
-    (``..._sample``: the chunk that ends a prompt and samples)."""
+    (runner, avals): ``decode``, ``decode_n2``, ``prefill_chunk_<bucket>``
+    (``..._sample``: the chunk that ends a prompt and samples) or
+    ``decode_prefill_<bucket>`` (that chunk and the step as one program)."""
     base = (a["params"], a["kv"], a["state"])
     if program == "decode":
         return build(r._decode_paged_fn, *base, a["tables"])
     if program == "decode_n2":
         return build(r._decode_paged_n_fn, *base, a["tables"], n=2)
     bucket = int(program.split("_")[2])
+    if program.startswith("decode_prefill_"):
+        return build(r._decode_prefill_paged_fn, *base, a["tables"],
+                     *a["chunk"](bucket), bucket=bucket)
     return build(
         r._prefill_paged_fn, *base, *a["chunk"](bucket), bucket=bucket,
         sample=program.endswith("_sample"))
@@ -552,7 +556,7 @@ def spans(r, program):
     program)."""
     from localai_tpu.engine import kvcache as kvc
 
-    if program.startswith("decode"):
+    if program in ("decode", "decode_n2"):
         return None
     return kvc.span_ladder(int(program.split("_")[2]), r.ctx_pad,
                            r.block_tokens)
@@ -570,13 +574,17 @@ def assert_who_writes(program, text, pool, ladder=None, quartered=False):
     produce a pool-shaped result in either, so no copy stands before or
     behind the aliased call). PR 40: its attend is ONE ``conditional`` (in
     the layer scan's body), a branch for every span of ``ladder``, each
-    gathering that span's positions of a chip's kv heads and no more."""
+    gathering that span's positions of a chip's kv heads and no more. PR 59:
+    a chunk that RIDES the step (``decode_prefill_<bucket>``) is held to
+    both: the chunk's scatters and its one conditional, and the step's one
+    Pallas call, which writes the step's rows."""
     import re
 
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    if not program.startswith("decode"):
-        assert not calls
+    rides = program.startswith("decode_prefill_")
+    if rides or not program.startswith("decode"):
+        assert rides or not calls
         assert 'kv_pool.write/scatter"' in text
         # (the 512-row program of a prompt's last chunk holds a second
         # conditional, of three branches, over its row counts: PR 52 on a
@@ -589,9 +597,10 @@ def assert_who_writes(program, text, pool, ladder=None, quartered=False):
             blocks = f"bf16[{span // pool[3]},{','.join(map(str, pool[2:]))}]"
             assert len(re.findall(
                 "= " + re.escape(blocks) + r"\S* gather\(", text)) == 2, span
-        return
+        if not rides:
+            return
     assert len(calls) == 1 and "paged_decode_attn" in calls[0]
-    assert "kv_pool.write/scatter" not in text, (
+    assert rides or "kv_pool.write/scatter" not in text, (
         f"{program} still scatters the step's rows")
     shape = "bf16[" + ",".join(map(str, pool)) + "]"
     rows = f"bf16[{{}},{pool[2]},{pool[4]}]"
@@ -1354,3 +1363,50 @@ def test_one_chip_chunk_layers_run_their_live_quarters(topo, monkeypatch,
     parent = lowered()
     assert [p for p in programs if now[p] != parent[p]] == [
         "prefill_chunk_512_sample"]
+
+
+@pytest.mark.parametrize("cell", [M7B, OURO], indirect=True)
+def test_a_last_chunk_rides_the_step_in_one_program(topo, monkeypatch, cell):
+    """PR 59: at the 7B cell's shapes (and the looped decoder's: four passes
+    over the composite attend, no family code) a prompt's 128-row last chunk
+    and the decode step are ONE program (``_decode_prefill_paged_fn``), and
+    it is held to what each half is alone: no second pool and no layer of
+    it copied (the chunk's scatter, its gathers and the kernel that stores
+    the step's rows follow each other on the one buffer: a pool-shaped
+    ``copy`` between them would cost more than the second weight read
+    saved), the chunk's scatters and ONE conditional over its spans, ONE
+    Pallas call that writes the step's rows. Its temps stand beside the
+    chunk's and the step's own (7B: 3.98 MiB beside 3.28 and 1.69; Ouro:
+    18.6 beside 4.06 and 1.23): rows of activations, no weight stack
+    staged. The MLP's products run bucket + slots rows: every layer's
+    weights are read once for both."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, num_slots=eng["max_slots"],
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    assert r.rides
+    pool = a["kv"].k.shape
+    temps = {}
+    for program in ("decode_prefill_128", "prefill_chunk_128_sample",
+                    "decode"):
+        c = compile_cell_program(r, a, program)
+        temps[program] = c.memory_analysis().temp_size_in_bytes
+        if program == "decode_prefill_128":
+            ride = c
+    print({k: f"{v / 2**20:.2f} MiB" for k, v in temps.items()})
+    assert_in_place("decode_prefill_128", ride, pool)
+    text = ride.as_text()
+    assert_who_writes("decode_prefill_128", text, pool,
+                      spans(r, "decode_prefill_128"))
+    assert temps["decode_prefill_128"] < (
+        temps["prefill_chunk_128_sample"] + temps["decode"] + 16 * 2**20)
+    assert temps["decode_prefill_128"] < cfg.num_layers * cfg.hidden_size ** 2
+    # the MLP's products hold the chunk's rows and the step's side by side
+    rows = 128 + eng["max_slots"]
+    assert re.search(rf"bf16\[{rows},{cfg.intermediate_size}\]", text)
+    assert not re.search(rf"bf16\[(128|{eng['max_slots']}),"
+                         rf"{cfg.intermediate_size}\]", text)
