@@ -73,24 +73,6 @@ CHUNK_QUARTERED = 512
 RIDE_ROWS = 128
 
 
-def _refusal(what: str) -> str:
-    """The one sentence that refuses ``what`` for a model with recurrent
-    state (``LlamaConfig.recurrent``)."""
-    return (f"{what} is not served for a model whose layers carry recurrent "
-            f"state (model_type qwen3_next, falcon_h1, lfm2_moe): that state "
-            f"is not "
-            f"keys, it "
-            f"lives in one dense row a slot beside the paged K/V pool and "
-            f"cannot be re-read, split or shipped as a prefix of keys can")
-
-
-def _kinds_refusal(cfg: LlamaConfig, what: str) -> str:
-    """The one sentence that refuses ``what`` for a stack with more than one
-    kind of attention layer (``LlamaConfig.attn_kinds``): its family's own
-    (the family module's ``refusal``)."""
-    return mdl.family_module(cfg).refusal(what)
-
-
 def _prompt_counts_row(vocab_size: int, prompt) -> np.ndarray:
     """[V] i32 bincount of the FULL prompt for resume-style prefills (the
     in-program count would only see the tail chunk)."""
@@ -250,10 +232,17 @@ class ModelRunner:
         self.rope = mdl.rope_table(
             cfg, self.max_ctx, freq_base=rope_freq_base, freq_scale=rope_freq_scale
         )
-        if self.ga_n > 1 and (cfg.attn_kinds or cfg.latent):
-            # (before the unroped table is made: a table a kind is none of
-            # self-extend's; the family's other refusals are below)
-            raise ValueError(_kinds_refusal(cfg, "self-extend"))
+        # what the model's family does not serve (its ``UNSERVED``), in its
+        # own sentence: ONE pass, before the unroped table is made (a table
+        # a kind is none of self-extend's) and the layouts are chosen
+        mdl.refuse(cfg, (
+            ("self-extend", ga_n > 1),
+            ("pipeline parallelism", self.pp_enabled),
+            ("the ring prefill", mesh is not None
+             and mesh.shape.get("seq", 1) > 1),
+            ("a device mesh", mesh is not None),
+            ("the contiguous K/V layout", not paged),
+            (f"a {kv_dtype} K/V pool", kv_dtype in ("int8", "int4"))))
         if self.ga_n > 1:
             from localai_tpu.engine import selfextend as se
 
@@ -283,36 +272,17 @@ class ModelRunner:
         self.kinds = cfg.attn_kinds
         # latent attention (models.deepseek): one latent row a token in a
         # block pool of its own page (engine.kvcache ``LatentLayout``), on
-        # one chip; the refusals are the family's own sentence, as a stack
-        # of several kinds'
+        # one chip
         self.latent = bool(cfg.latent)
-        if self.kinds or self.latent:
-            for what, asked in (
-                    ("pipeline parallelism", self.pp_enabled),
-                    ("the ring prefill", mesh is not None
-                     and mesh.shape.get("seq", 1) > 1),
-                    ("a device mesh", mesh is not None),
-                    ("the contiguous K/V layout", not self.paged),
-                    (f"a {kv_dtype} K/V pool", kv_dtype in ("int8", "int4"))):
-                if asked:
-                    raise ValueError(_kinds_refusal(cfg, what))
         if self.paged and incompat:
             raise ValueError(
                 f"paged KV cache is incompatible with {incompat}")
         # a model whose layers carry per-slot state that is not keys
         # (``cfg.recurrent``: its family module builds the state, and its
         # forward carries it): that state is one dense row a slot beside
-        # the block pool, and what takes a sequence for its keys is refused
+        # the block pool (what takes a sequence for its keys was refused
+        # above)
         self.recurrent = bool(cfg.recurrent)
-        if self.recurrent:
-            if self.pp_enabled:
-                raise ValueError(_refusal("pipeline parallelism"))
-            if mesh is not None and mesh.shape.get("seq", 1) > 1:
-                raise ValueError(_refusal("the ring prefill"))
-            if mesh is not None:
-                raise ValueError(_refusal("a device mesh"))
-            if not self.paged:
-                raise ValueError(_refusal("the contiguous K/V layout"))
         # a model with routed experts (models.experts): its forward counts
         # each launch's routed work, and its own kernels (the experts as
         # ops.moe's; a recurrent family's decode step as ops.gdn's) run
@@ -567,16 +537,12 @@ class ModelRunner:
         self._active_slots: set[int] = set()
 
     def _init_rec(self, num_slots: int):
-        """``DecodeState.rec`` for ``num_slots`` slots: the recurrent state
-        (the family module's ``init_rec``: what its forward carries), for a
-        model with routed experts at least the routed work of chunks whose
-        token no copy brings to the host yet (``_prefill_paged_fn``), None
-        for every other model."""
-        if self.recurrent:
-            return mdl.family_module(self.cfg).init_rec(self.cfg, num_slots)
-        if self.routed:
-            return {"routed": jnp.zeros(2, jnp.int32)}
-        return None
+        """``DecodeState.rec`` for ``num_slots`` slots, the family module's
+        ``init_rec``: the state its forward carries and, where it routes,
+        the routed work of chunks whose token no copy brings to the host yet
+        (``_prefill_paged_fn``). None for a model of no family."""
+        fam = mdl.family_module(self.cfg)
+        return None if fam is None else fam.init_rec(self.cfg, num_slots)
 
     def _place_state(self, state: DecodeState) -> DecodeState:
         """Shard a fresh DecodeState over the mesh (the construction-time
@@ -1013,7 +979,8 @@ class ModelRunner:
                 # routed work of the chunks before it waits in ``routed``
                 # for that copy
                 routed = rec["routed"] + routed
-                rec["routed"] = jnp.zeros_like(routed) if sample else routed
+                rec = {**rec, "routed": jnp.zeros_like(routed) if sample
+                       else routed}
             state = dataclasses.replace(state, rec=rec)
         else:
             rows = self.chunk_rows(bucket, sample)
@@ -1291,27 +1258,17 @@ class ModelRunner:
     def _forward_rec(self, params, tokens, positions, write, stack, mask,
                      rec, valid, attn=None, embeds=None, slot=None,
                      fresh=None):
-        """The forward of a model with routed experts (``cfg.routed``) or
-        with recurrent state (``cfg.recurrent``), its family module's own
-        (``models.llama.family_module``): one that carries recurrent state
-        takes and returns it (what its ``init_rec`` built), one with
-        ``cfg.attn_kinds`` is handed a mask and an attend a kind of layer.
-        Returns the hidden states and the K/V stack as ``_forward`` does,
-        then the new state and the launch's routed work [experts touched,
-        token-expert pairs that landed here] (None from a family that routes
-        nothing)."""
-        rec = dict(rec)
-        carried = {"routed": rec.pop("routed")} if self.routed else {}
-        forward = partial(
-            mdl.family_module(self.cfg).forward, self.cfg, params, tokens,
-            positions, write, stack, mask, self.rope, attn=attn,
-            embeds=embeds, valid=valid, kernels=self.family_kernels)
-        if self.recurrent:
-            hidden, new_stack, rec, routed = forward(rec=rec, slot=slot,
-                                                     fresh=fresh)
-        else:
-            hidden, new_stack, routed = forward()
-        return hidden, new_stack, {**rec, **carried}, routed
+        """The forward of a family module (``models.llama.family_module``,
+        which states the contract): every one takes these arguments and
+        returns the hidden states and the K/V stack as ``_forward`` does,
+        then ``rec`` with its own entries renewed and the launch's routed
+        work [experts touched, token-expert pairs that landed here] (None
+        from a family that routes nothing). One with ``cfg.attn_kinds`` is
+        handed a mask and an attend a kind of layer."""
+        return mdl.family_module(self.cfg).forward(
+            self.cfg, params, tokens, positions, write, stack, mask,
+            self.rope, attn=attn, embeds=embeds, rec=rec, valid=valid,
+            slot=slot, fresh=fresh, kernels=self.family_kernels)
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""
@@ -1721,14 +1678,10 @@ class ModelRunner:
         Works on both KV layouts; over the pool the window is written
         through the block-table mirror and rejected tails roll back
         per slot. No host sync — callers overlap the read."""
-        if self.recurrent:
-            # a rejected draft token has already moved the state it met
-            raise ValueError(_refusal("speculative decoding"))
-        if self.kinds or self.latent:
-            # the verify window has one attend for all layers, and none over
-            # latent rows
-            raise ValueError(
-                _kinds_refusal(self.cfg, "speculative decoding"))
+        # (a rejected draft token has already moved the recurrent state it
+        # met; the verify window has one attend for all layers, and none
+        # over latent rows)
+        mdl.refuse(self.cfg, [("speculative decoding", True)])
         proposals = jnp.asarray(proposals, jnp.int32)
         self.kv, self.state, emitted = self._verify_paged(
             self.params, self.kv, self.state, self.block_tables, proposals,
@@ -1982,8 +1935,9 @@ class ModelRunner:
         (admit() then reuses them via the resident/resume path). Returns
         False on any mismatch (dtype, shape, context) — callers fall back
         to a full prefill."""
-        if self.recurrent:
-            log.warning("%s", _refusal("the prompt cache's import"))
+        if mdl.unserved(self.cfg, "the prompt cache's import"):
+            log.warning("%s", mdl.refusal(
+                self.cfg, "the prompt cache's import"))
             return False
         if str(arrays.get("kv_dtype")) != str(self.kv_dtype):
             return False

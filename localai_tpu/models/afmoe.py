@@ -56,13 +56,6 @@ from localai_tpu.models.llama import LlamaConfig
 WINDOW, FULL = "sliding_attention", "full_attention"
 
 
-def refusal(what: str) -> str:
-    """The one sentence that refuses ``what`` for the family."""
-    return (f"{what} is not served for model_type afmoe: its window and "
-            f"full attention layers read one bfloat16 paged K/V pool through "
-            f"an attend chosen by the layer's kind, on one chip")
-
-
 @dataclasses.dataclass(frozen=True)
 class AfmoeConfig(LlamaConfig):
     """``LlamaConfig`` with the keys the family adds. ``num_experts`` is the
@@ -180,16 +173,19 @@ class AfmoeConfig(LlamaConfig):
         )
 
 
+CONFIG = AfmoeConfig
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``)
+UNSERVED = mdl.ONE_CHIP_POOL
+WEIGHTS = ()
+WHY = ("model_type afmoe: its window and full attention layers read one "
+       "bfloat16 paged K/V pool through an attend chosen by the layer's "
+       "kind, on one chip")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    """``engine.quantization`` is not served for the family (synthetic
-    weights and checkpoints alike)."""
-    if quantization:
-        raise ValueError(refusal(f"engine.quantization {quantization!r}"))
-
 
 DENSE = "dense_"        # a dense-prefix leaf: top level, ``[n_dense, ...]``
 # the selection bias is float32 as published, whatever the compute dtype
@@ -249,7 +245,7 @@ def param_shapes(cfg: AfmoeConfig) -> dict:
 # of the router and the experts keeps gain 1: outliers there make the 26
 # letters a benchmark's streams decode route alike.
 QK_NORM_GAIN = 1.5          # scores' spread x 2.25
-OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192     # one channel in 192, at 32
+OUTLIER_GAIN, OUTLIER_EVERY = mdl.OUTLIER_GAIN, mdl.OUTLIER_EVERY
 BIAS_STD = 0.01
 OUTLIER_NORMS = ("attn_norm", "final_norm")
 
@@ -332,6 +328,10 @@ def checkpoint_leaves(cfg: AfmoeConfig, get, body: str = "model."):
             f"mlp.shared_experts.{name}.weight", True)
 
 
+# no per-slot state beside the pool: the routed count alone
+init_rec = xp.init_rec
+
+
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
@@ -341,25 +341,9 @@ def embed_scale(cfg: AfmoeConfig) -> float:
     return math.sqrt(cfg.hidden_size) if cfg.mup_enabled else 1.0
 
 
-def output_gate(attn, gate):
-    """The per-element sigmoid gate on the attention output."""
-    return attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
-
-
 def post_norm(x, w, eps: float):
     """The norm on a branch's OUTPUT (the sandwich's second slice)."""
     return mdl.rms_norm(x, w, eps)
-
-
-def swiglu(h, w_gate, w_up, w_down):
-    """down(silu(gate h) * up h): the dense MLP, the shared expert."""
-    y = jax.nn.silu(qnt.matmul(h, w_gate)) * qnt.matmul(h, w_up)
-    return qnt.matmul(y, w_down)
-
-
-def shared_expert(h, w_gate, w_up, w_down):
-    """The shared expert on h [N, D]: NO gate; float32."""
-    return swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
 
 
 def scores(cfg: AfmoeConfig, bias):
@@ -397,7 +381,7 @@ def _attention(cfg: AfmoeConfig, h, w, cos, sin, attend, kind: str):
             k = mdl.apply_rope(k, cos, sin)
     attn, new_kv = attend(q, k, v)
     with jax.named_scope("attn_gate"):
-        attn = output_gate(attn, gate.reshape(attn.shape))
+        attn = mdl.output_gate(attn, gate.reshape(attn.shape))
     with jax.named_scope("attn.out"):
         out = qnt.matmul(attn.reshape(*attn.shape[:-2], Hq * hd), w("wo"))
     return out, new_kv
@@ -416,46 +400,31 @@ def forward(
                                     # runner's attends by kind; None = XLA
     embeds: Optional[jax.Array] = None,
     *,
+    rec: Any = None,        # handed back as it came: no per-slot state
     valid: jax.Array,       # [B, T] bool: the real tokens
+    slot: Any = None,       # (a recurrent family's: models.llama
+    fresh: Any = None,      # ``family_module`` has the contract)
     kernels: Optional[bool] = None,     # models.experts.moe_block's
                             # ``experts_kernel``
-) -> tuple[jax.Array, Any, jax.Array]:
+) -> tuple[jax.Array, Any, Any, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
-    stack, [experts touched, token-expert pairs] summed over the expert
-    blocks). The dense prefix layer by layer, then one ``lax.scan`` over the
-    rows; (x, K/V) is its carry, so the cache is written in place."""
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]
-    sin = sin_t[positions][:, :, None, :]
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope("embed"):
-        if embeds is None:
-            x = qnt.embed_rows(params["embed"], tokens, dtype)
-            x = (x.astype(jnp.float32) * embed_scale(cfg)).astype(dtype)
-        else:       # the caller's rows, as they are
-            x = embeds.astype(dtype)
+    stack, ``rec``, [experts touched, token-expert pairs] summed over the
+    expert blocks). The dense prefix layer by layer, then one ``lax.scan``
+    over the rows; (x, K/V) is its carry, so the cache is written in
+    place."""
+    cos, sin = mdl.rope_rows(rope, positions)
+    x = mdl.embed(cfg, params, tokens, embeds, embed_scale(cfg))
     if attn is None:
-        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
-
-        def xla_attn(q, keys, values, m):
-            with jax.named_scope(xla_scope):
-                return mdl._grouped_attn(cfg, q, keys, values, m)
-
+        xla_attn = mdl.xla_attend(cfg, positions)
         attn = {kind: xla_attn for kind, _ in cfg.attn_kinds}
     eps, M, nd = (cfg.rms_norm_eps, cfg.global_attn_every_n_layers,
                   cfg.num_dense_layers)
 
     def mixer(x, kv, w, layer, kind):
         """x + N(Attn(N(x))) of cache layer ``layer``, a ``kind`` layer."""
-        def attend(q, k_new, v_new):
-            new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
-            out = attn[kind](q, keys, values, mask[kind])
-            if isinstance(out, tuple):      # the attend wrote the stack
-                out, new_kv = out
-            return out, new_kv
-
         h = mdl.rms_norm(x, w("attn_norm"), eps)
-        out, kv = _attention(cfg, h, w, cos, sin, attend, kind)
+        out, kv = _attention(cfg, h, w, cos, sin, mdl.attend_through(
+            kv_write, attn[kind], mask[kind], kv, layer), kind)
         return x + post_norm(out, w("attn_post_norm"), eps), kv
 
     with jax.named_scope("layers"):
@@ -467,7 +436,7 @@ def forward(
                                 cfg.layer_types[i])
             with jax.named_scope("dense_mlp"):
                 h = mdl.rms_norm(x, w("mlp_norm"), eps)
-                out = swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
+                out = xp.swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
                 x = x + post_norm(out, w("mlp_post_norm"), eps)
 
         layers = params["layers"]
@@ -491,7 +460,7 @@ def forward(
                         scores(cfg, w("expert_bias")), experts, r, m,
                         num_experts=cfg.num_experts, ep_rank=cfg.ep_rank,
                         valid=valid.reshape(-1),
-                        shared=lambda h, w=w: shared_expert(
+                        shared=lambda h, w=w: xp.shared_expert(
                             h, w("shared_gate"), w("shared_up"),
                             w("shared_down")),
                         experts_kernel=kernels)
@@ -506,4 +475,4 @@ def forward(
             jnp.arange(cfg.rows, dtype=jnp.int32))
     with jax.named_scope("final_norm"):
         x = mdl.rms_norm(x, params["final_norm"], eps)
-    return x, kv_stack, counts
+    return x, kv_stack, rec, counts

@@ -56,14 +56,6 @@ from localai_tpu.models import quant as qnt
 from localai_tpu.models.llama import LlamaConfig
 
 
-def refusal(what: str) -> str:
-    """The one sentence that refuses ``what`` for the family."""
-    return (f"{what} is not served for model_type axk1: its latent "
-            f"attention reads one bfloat16 pool of latent rows (no K/V a "
-            f"head), absorbed in a decode step and decompressed in a "
-            f"prefill chunk, on one chip")
-
-
 @dataclasses.dataclass(frozen=True)
 class DeepseekConfig(LlamaConfig):
     """``LlamaConfig`` with the keys the block adds. ``num_experts`` is the
@@ -220,16 +212,19 @@ class DeepseekConfig(LlamaConfig):
         )
 
 
+CONFIG = DeepseekConfig
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``)
+UNSERVED = mdl.ONE_CHIP_POOL
+WEIGHTS = ()
+WHY = ("model_type axk1: its latent attention reads one bfloat16 pool of "
+       "latent rows (no K/V a head), absorbed in a decode step and "
+       "decompressed in a prefill chunk, on one chip")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    """``engine.quantization`` is not served for the family (synthetic
-    weights and checkpoints alike)."""
-    if quantization:
-        raise ValueError(refusal(f"engine.quantization {quantization!r}"))
-
 
 DENSE = "dense_"        # a dense-prefix leaf: top level, ``[n_dense, ...]``
 
@@ -289,7 +284,7 @@ def param_shapes(cfg: DeepseekConfig) -> dict:
 # rounded to 8 bits read inside the sound runs' range: PERF.md section 6,
 # PR 48). The norm in front of the router and the experts keeps gain 1.
 LATENT_NORM_GAIN = 1.5
-OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192
+OUTLIER_GAIN, OUTLIER_EVERY = mdl.OUTLIER_GAIN, mdl.OUTLIER_EVERY
 OUTLIER_NORMS = ("attn_norm", "final_norm", "kv_norm")
 
 
@@ -364,24 +359,17 @@ def checkpoint_leaves(cfg: DeepseekConfig, get, body: str = "model."):
                 rows, f"mlp.shared_experts.{name}.weight", True)
 
 
+# no per-slot state beside the pool: the routed count alone
+init_rec = xp.init_rec
+
+
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
 
-def swiglu(h, w_gate, w_up, w_down):
-    """down(silu(gate h) * up h): the dense MLP, the shared expert."""
-    y = jax.nn.silu(qnt.matmul(h, w_gate)) * qnt.matmul(h, w_up)
-    return qnt.matmul(y, w_down)
-
-
 def latent_norm(x, w, eps: float):
     """The norm of a low-rank projection's output (Nq, Nkv)."""
     return mdl.rms_norm(x, w, eps)
-
-
-def shared_expert(h, w_gate, w_up, w_down):
-    """The shared expert on h [N, D]: NO gate; float32."""
-    return swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
 
 
 def scores(cfg: DeepseekConfig):
@@ -495,38 +483,31 @@ def forward(
     attn: Any = None,       # engine.kvcache ``LatentAttend``
     embeds: Optional[jax.Array] = None,
     *,
+    rec: Any = None,        # handed back as it came: no per-slot state
     valid: jax.Array,       # [B, T] bool: the real tokens
+    slot: Any = None,       # (a recurrent family's: models.llama
+    fresh: Any = None,      # ``family_module`` has the contract)
     kernels: Optional[bool] = None,     # models.experts.moe_block's
                             # ``experts_kernel``
-) -> tuple[jax.Array, Any, jax.Array]:
+) -> tuple[jax.Array, Any, Any, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new pool
-    stack, [experts touched, token-expert pairs] summed over the expert
-    blocks). The dense prefix layer by layer, then one ``lax.scan`` over the
-    expert layers; (x, pool) is its carry, so the pool is written in
-    place."""
+    stack, ``rec``, [experts touched, token-expert pairs] summed over the
+    expert blocks). The dense prefix layer by layer, then one ``lax.scan``
+    over the expert layers; (x, pool) is its carry, so the pool is written
+    in place."""
     if attn is None:
-        raise ValueError(refusal("a forward with no latent attend (the "
-                                 "contiguous K/V layout)"))
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]
-    sin = sin_t[positions][:, :, None, :]
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope("embed"):
-        x = (qnt.embed_rows(params["embed"], tokens, dtype)
-             if embeds is None else embeds.astype(dtype))
+        raise ValueError(mdl.refusal(
+            cfg, "a forward with no latent attend (the contiguous K/V "
+                 "layout)"))
+    cos, sin = mdl.rope_rows(rope, positions)
+    x = mdl.embed(cfg, params, tokens, embeds)
     eps, nd = cfg.rms_norm_eps, cfg.num_dense_layers
 
     def mixer(x, kv, w, layer):
         """x + Attn(N(x)) of cache layer ``layer``."""
-        def attend(q, row, **how):
-            new_kv, view = kv_write(kv, layer, row)
-            out = attn.run(q, view, mask, **how)
-            if isinstance(out, tuple):      # the attend wrote the pool
-                out, new_kv = out
-            return out, new_kv
-
         h = mdl.rms_norm(x, w("attn_norm"), eps)
-        out, kv = _attention(cfg, h, w, cos, sin, attend, attn.path)
+        out, kv = _attention(cfg, h, w, cos, sin, mdl.attend_through(
+            kv_write, attn.run, mask, kv, layer), attn.path)
         return x + out, kv
 
     with jax.named_scope("layers"):
@@ -537,7 +518,7 @@ def forward(
             x, kv_stack = mixer(x, kv_stack, w, jnp.int32(i))
             with jax.named_scope("dense_mlp"):
                 h = mdl.rms_norm(x, w("mlp_norm"), eps)
-                x = x + swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
+                x = x + xp.swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
 
         layers = params["layers"]
         experts = tuple(layers[n] for n in xp.EXPERT_LEAVES)
@@ -547,8 +528,8 @@ def forward(
         def shared(h, w):
             if not cfg.num_shared_experts:
                 return jnp.zeros(h.shape, jnp.float32)
-            return shared_expert(h, w("shared_gate"), w("shared_up"),
-                                 w("shared_down"))
+            return xp.shared_expert(h, w("shared_gate"), w("shared_up"),
+                                    w("shared_down"))
 
         def row(carry, r):
             x, kv, counts = carry
@@ -573,4 +554,4 @@ def forward(
             jnp.arange(cfg.expert_layers, dtype=jnp.int32))
     with jax.named_scope("final_norm"):
         x = mdl.rms_norm(x, params["final_norm"], eps)
-    return x, kv_stack, counts
+    return x, kv_stack, rec, counts

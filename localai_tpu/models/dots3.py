@@ -71,14 +71,6 @@ INDEX_STATE = 2
 INDEX_NORM_EPS = 1e-6
 
 
-def refusal(what: str) -> str:
-    """The one sentence that refuses ``what`` for the family."""
-    return (f"{what} is not served for model_type dots3_note: its full "
-            f"layers select rows by an indexer over one bfloat16 latent "
-            f"pool and its window layers keep latent rows of their own "
-            f"width there (no K/V a head), on one chip")
-
-
 @dataclasses.dataclass(frozen=True)
 class Dots3Config(DeepseekConfig):
     """``DeepseekConfig`` (the FULL layers' shapes) with the window layers'
@@ -105,12 +97,14 @@ class Dots3Config(DeepseekConfig):
                 f"{sorted(set(types))}; the stack has {self.num_layers} of "
                 f"{FULL} / {WINDOW}")
         if WINDOW in types[:nd]:
-            raise ValueError(refusal("a window layer with a dense MLP"))
+            raise ValueError(mdl.refusal(
+                self, "a window layer with a dense MLP"))
         rest = types[nd + self.lone_layers:]
         M = self.period
         if M and (rest[M - 1] != FULL or len(rest) % M or any(
                 rest[i:i + M] != rest[:M] for i in range(0, len(rest), M))):
-            raise ValueError(refusal(
+            raise ValueError(mdl.refusal(
+                self,
                 f"a depth of {self.num_layers} layers that ends inside a "
                 f"period of {M} ({M - 1} window layers closed by a full "
                 f"one)"))
@@ -278,14 +272,19 @@ def rope_table(cfg: Dots3Config, max_len: int, freq_base=None,
             for kind, _ in cfg.attn_kinds}
 
 
+CONFIG = Dots3Config
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``)
+UNSERVED = mdl.ONE_CHIP_POOL
+WEIGHTS = ()
+WHY = ("model_type dots3_note: its full layers select rows by an indexer over "
+       "one bfloat16 latent pool and its window layers keep latent rows of "
+       "their own width there (no K/V a head), on one chip")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    if quantization:
-        raise ValueError(refusal(f"engine.quantization {quantization!r}"))
-
 
 FLOAT32_LEAVES = ("expert_bias",)
 # the leaves of a period's row that EVERY layer of the period has ([P, M,
@@ -471,6 +470,10 @@ def checkpoint_leaves(cfg: Dots3Config, get, body: str = "model."):
         yield from moe(every, (P, M), (P, M))
 
 
+# no per-slot state beside the pool: the routed count alone
+init_rec = xp.init_rec
+
+
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
@@ -543,26 +546,26 @@ def forward(
     attn: Optional[dict] = None,    # {kind: engine.kvcache ``LatentAttend``}
     embeds: Optional[jax.Array] = None,
     *,
+    rec: Any = None,        # handed back as it came: no per-slot state
     valid: jax.Array,       # [B, T] bool: the real tokens
+    slot: Any = None,       # (a recurrent family's: models.llama
+    fresh: Any = None,      # ``family_module`` has the contract)
     kernels: Optional[bool] = None,     # models.experts.moe_block's
                             # ``experts_kernel``
-) -> tuple[jax.Array, Any, jax.Array]:
+) -> tuple[jax.Array, Any, Any, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new pool
-    stack, [experts touched, token-expert pairs] summed over the expert
-    blocks). The dense and the lone layers one by one, then one ``lax.scan``
-    over the periods; (x, pool) is its carry, so the pool is written in
-    place."""
+    stack, ``rec``, [experts touched, token-expert pairs] summed over the
+    expert blocks). The dense and the lone layers one by one, then one
+    ``lax.scan`` over the periods; (x, pool) is its carry, so the pool is
+    written in place."""
     if attn is None:
-        raise ValueError(refusal("a forward with no latent attend (the "
-                                 "contiguous K/V layout)"))
+        raise ValueError(mdl.refusal(
+            cfg, "a forward with no latent attend (the contiguous K/V "
+                 "layout)"))
     views = {kind: cfg.kind(kind) for kind, _ in cfg.attn_kinds}
-    tables = {kind: (cos_t[positions][:, :, None, :],
-                     sin_t[positions][:, :, None, :])
-              for kind, (cos_t, sin_t) in rope.items()}
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope("embed"):
-        x = (qnt.embed_rows(params["embed"], tokens, dtype)
-             if embeds is None else embeds.astype(dtype))
+    tables = {kind: mdl.rope_rows(table, positions)
+              for kind, table in rope.items()}
+    x = mdl.embed(cfg, params, tokens, embeds)
     eps = cfg.rms_norm_eps
     nd, nl, P, M = (cfg.num_dense_layers, cfg.lone_layers, cfg.periods,
                     cfg.period)
@@ -595,7 +598,7 @@ def forward(
             def shared(h):
                 if not cfg.num_shared_experts:
                     return jnp.zeros(h.shape, jnp.float32)
-                return ds.shared_expert(h, w("shared_gate"), w("shared_up"),
+                return xp.shared_expert(h, w("shared_gate"), w("shared_up"),
                                         w("shared_down"))
 
             out, n_touched, load = xp.moe_block(
@@ -616,7 +619,7 @@ def forward(
                                 jnp.int32(i), FULL)
             with jax.named_scope("dense_mlp"):
                 h = mdl.rms_norm(x, w("mlp_norm"), eps)
-                x = x + ds.swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
+                x = x + xp.swiglu(h, w("w_gate"), w("w_up"), w("w_down"))
         for j in range(nl):
             def w(name, j=j):
                 return params[LONE + name][j]
@@ -666,4 +669,4 @@ def forward(
                 jnp.arange(P, dtype=jnp.int32))
     with jax.named_scope("final_norm"):
         x = mdl.rms_norm(x, params["final_norm"], eps)
-    return x, kv_stack, counts
+    return x, kv_stack, rec, counts

@@ -32,6 +32,27 @@ from localai_tpu.models import quant as qnt
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
+def init_rec(cfg=None, num_slots: int = 0) -> dict:
+    """What ``DecodeState.rec`` holds for a family that routes: the routed
+    work of prefill chunks whose token no copy brings to the host yet
+    (engine.runner ``_prefill_paged_fn`` adds to it and empties it; a forward
+    hands it on untouched). The whole ``init_rec`` of a family with no
+    per-slot state of its own, an entry of a recurrent one's."""
+    return {"routed": jnp.zeros(2, jnp.int32)}
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    """down(silu(gate h) * up h): a dense layer's feed-forward, the shared
+    expert."""
+    y = jax.nn.silu(qnt.matmul(h, w_gate)) * qnt.matmul(h, w_up)
+    return qnt.matmul(y, w_down)
+
+
+def shared_expert(h, w_gate, w_up, w_down):
+    """An ungated shared expert on h [N, D]; float32."""
+    return swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
+
+
 def softmax_scores(k: int, renormalise: bool) -> Callable:
     """softmax over ALL experts, the k largest, renormalised to sum 1 where
     the family says so."""

@@ -173,20 +173,20 @@ class FalconH1Config(LlamaConfig):
         )
 
 
+CONFIG = FalconH1Config
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``). ``int8``: every projection and both
+# tables through models.quant; conv, ``A_log``, ``D``, ``dt_bias`` and the
+# norm gains stay as they are
+UNSERVED = mdl.KEYS_ALONE
+WEIGHTS = ("int8",)
+WHY = (f"model_type falcon_h1: its mixers {mdl.STATE_WHY}; its projections "
+       f"are served in bfloat16 or as weight-only int8")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    """The family serves weight-only ``int8`` (every projection and both
-    tables through models.quant; conv, ``A_log``, ``D``, ``dt_bias`` and the
-    norm gains stay as they are) and nothing else."""
-    if quantization and quantization != "int8":
-        raise ValueError(
-            f"engine.quantization {quantization!r} is not served for "
-            f"model_type falcon_h1: its projections are served in bfloat16 "
-            f"or as weight-only int8")
-
 
 def param_shapes(cfg: FalconH1Config) -> dict:
     """Shapes of the stacked-parameter pytree, a row a layer."""
@@ -242,16 +242,8 @@ SCORE_STD = 2.0
 BC_RMS = 1.7
 LOGIT_STD = 1.5
 CONV_TAP_STD, CONV_BIAS_STD = 0.5, 0.5
-OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192     # one channel in 192, at 32
+OUTLIER_GAIN, OUTLIER_EVERY = mdl.OUTLIER_GAIN, mdl.OUTLIER_EVERY
 OUTLIER_NORMS = ("attn_norm", "final_norm")
-
-
-def _outlier_rms(width: int) -> float:
-    """RMS of a normed activation behind a gain with outlier channels."""
-    if width < OUTLIER_EVERY:
-        return 1.0
-    share = (width // OUTLIER_EVERY) / width
-    return math.sqrt(1.0 + share * (OUTLIER_GAIN ** 2 - 1.0))
 
 
 def leaf_std(cfg: FalconH1Config, name: str):
@@ -259,7 +251,7 @@ def leaf_std(cfg: FalconH1Config, name: str):
     column of ``ssm_in`` (its five segments stand under five multipliers);
     None for a leaf that is no matrix (``init_leaf`` draws those)."""
     D = cfg.hidden_size
-    h_rms = _outlier_rms(D)                 # behind attn_norm / final_norm
+    h_rms = mdl.outlier_rms(D)                # behind attn_norm / final_norm
     fan_h = math.sqrt(D) * h_rms
     if name == "embed":
         return 1.0 / cfg.embedding_multiplier
@@ -373,24 +365,6 @@ def init_rec(cfg: FalconH1Config, num_slots: int) -> dict:
         "conv": jnp.zeros((L, num_slots, cfg.mamba_d_conv - 1, cfg.conv_dim),
                           jnp.dtype(cfg.dtype)),
     }
-
-
-def rec_read(arr, layer, slot):
-    """Rows of ``layer``: every slot's (``slot`` None) or one slot's, with a
-    leading batch axis either way, in ONE slice."""
-    zeros = (0,) * (arr.ndim - 2)
-    if slot is None:
-        return lax.dynamic_slice(arr, (layer, 0) + zeros,
-                                 (1,) + arr.shape[1:])[0]
-    return lax.dynamic_slice(arr, (layer, slot) + zeros,
-                             (1, 1) + arr.shape[2:])[0]
-
-
-def rec_write(arr, new, layer, slot):
-    zeros = (0,) * (arr.ndim - 2)
-    return lax.dynamic_update_slice(
-        arr, new[None].astype(arr.dtype),
-        (layer, 0 if slot is None else slot) + zeros)
 
 
 # ---------------------------------------------------------------------------
@@ -514,14 +488,6 @@ def causal_conv(cat, w, bias, T: int):
         cat[:, i:i + T].astype(F32) * w[i] for i in range(w.shape[0]))
 
 
-def conv_rows(cat, n_real, K: int):
-    """The K - 1 rows in front of the first token that is NOT real: after n
-    real tokens rows n .. n + K - 2 (n = 0 leaves the state as it was)."""
-    return jax.vmap(
-        lambda rows, n: lax.dynamic_slice_in_dim(rows, n, K - 1, 0))(
-            cat, n_real)
-
-
 def gate_norm(y, z, w, groups: int, eps: float):
     """The mixer's output norm, float32: the gate silu(z) FIRST, then an
     RMSNorm over each of ``groups`` groups of channels, gain ``w``. y, z
@@ -558,7 +524,8 @@ def _mixer(cfg: FalconH1Config, h, lp, state_step, conv0, valid):
         w = lp["ssm_conv"].astype(F32) * mup[ssm:ssm + C]
         xbc = jax.nn.silu(causal_conv(cat, w, lp["ssm_conv_bias"], T))
         # (float32 from here on)
-        new_conv = conv_rows(cat, jnp.sum(valid, axis=1).astype(jnp.int32), K)
+        new_conv = mdl.conv_rows(cat, jnp.sum(valid, axis=1).astype(jnp.int32),
+                                 K)
     with jax.named_scope("state"):
         x = xbc[..., :ssm].reshape(B_, T, H, P)
         Bm = heads_of_groups(
@@ -630,11 +597,9 @@ def forward(
     *,
     rec: dict,              # init_rec's arrays
     valid: jax.Array,       # [B, T] bool: the real tokens, a prefix a row
-    slot: Any = None,       # None: batch row b is slot b (a decode step);
-                            # else the ONE slot the [1, T] chunk belongs to
-    fresh: Any = None,      # with ``slot``: the chunk starts the sequence
-                            # (offset 0), so the state it starts from is zero
-                            # whatever the slot held
+    slot: Any = None,       # the decode step's rows or ONE slot's chunk, and
+    fresh: Any = None,      # whether that starts from zero state: the
+                            # contract (models.llama ``family_module``)
     kernels: Optional[bool] = None,     # None: the decode step's recurrence
                             # is XLA; else ops.gdn's kernel (the value:
                             # interpreted)
@@ -644,23 +609,10 @@ def forward(
     work to count). One ``lax.scan``
     over the layers; (x, K/V, S, conv) is its carry, so pool and state are
     written in place."""
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]
-    sin = sin_t[positions][:, :, None, :]
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope("embed"):
-        if embeds is None:
-            x = (qnt.embed_rows(params["embed"], tokens, F32)
-                 * cfg.embedding_multiplier).astype(dtype)
-        else:
-            x = embeds.astype(dtype)
+    cos, sin = mdl.rope_rows(rope, positions)
+    x = mdl.embed(cfg, params, tokens, embeds, cfg.embedding_multiplier)
     if attn is None:
-        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
-
-        def attn(q, keys, values, m):
-            with jax.named_scope(xla_scope):
-                return mdl._grouped_attn(cfg, q, keys, values, m)
-
+        attn = mdl.xla_attend(cfg, positions)
     # the decode step (batch row b is slot b, one token): the recurrence is
     # ONE kernel a layer on the carried state, which is then never sliced
     fused = kernels is not None and slot is None and tokens.shape[1] == 1
@@ -673,8 +625,8 @@ def forward(
             # the per-slot arrays are read and written under the scope of
             # the recurrence: ``ssm/state`` is all that moves state
             with jax.named_scope("state"):
-                S0 = None if fused else rec_read(S_all, i, slot)
-                conv0 = rec_read(conv_all, i, slot)
+                S0 = None if fused else mdl.rec_read(S_all, (i,), slot)
+                conv0 = mdl.rec_read(conv_all, (i,), slot)
                 if fresh is not None:       # a chunk: never fused
                     S0 = jnp.where(fresh, 0.0, S0)
                     conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
@@ -684,17 +636,11 @@ def forward(
                         recur, S0, chunk=cfg.mamba_chunk_size))
             m, S, conv = _mixer(cfg, h, lp, state_step, conv0, valid)
             with jax.named_scope("state"):
-                S_all = S if fused else rec_write(S_all, S, i, slot)
-                conv_all = rec_write(conv_all, conv, i, slot)
+                S_all = S if fused else mdl.rec_write(S_all, S, (i,), slot)
+                conv_all = mdl.rec_write(conv_all, conv, (i,), slot)
 
-        def attend(q, k_new, v_new):
-            new_kv, keys, values = kv_write(kv, i, k_new, v_new)
-            out = attn(q, keys, values, mask)
-            if isinstance(out, tuple):      # the attend wrote the stack
-                out, new_kv = out
-            return out, new_kv
-
-        a, kv = _attention(cfg, h, lp, cos, sin, attend)
+        a, kv = _attention(cfg, h, lp, cos, sin, mdl.attend_through(
+            kv_write, attn, mask, kv, i))
         x = (x.astype(F32) + m + a).astype(x.dtype)
         return (_mlp(cfg, x, lp), kv, S_all, conv_all), None
 
@@ -705,4 +651,4 @@ def forward(
     with jax.named_scope("final_norm"):
         x = norm(x, params["final_norm"], cfg.rms_norm_eps,
                  cfg.lm_head_multiplier)
-    return x, kv_stack, {"S": S_all, "conv": conv_all}, None
+    return x, kv_stack, {**rec, "S": S_all, "conv": conv_all}, None

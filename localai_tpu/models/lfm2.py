@@ -65,7 +65,6 @@ from jax import lax
 from localai_tpu.models import experts as xp
 from localai_tpu.models import llama as mdl
 from localai_tpu.models import quant as qnt
-from localai_tpu.models.falcon_h1 import conv_rows, rec_read, rec_write
 from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.ops.attention import (heads_per_row, pack_kv, pack_q,
                                        unpack_out)
@@ -226,19 +225,19 @@ class Lfm2Config(LlamaConfig):
         )
 
 
+CONFIG = Lfm2Config
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``)
+UNSERVED = mdl.KEYS_ALONE
+WEIGHTS = ()
+WHY = (f"model_type lfm2_moe: its convolution layers {mdl.STATE_WHY}; its "
+       f"routed experts are read one expert at a time from the stacked "
+       f"bfloat16 leaves")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    """``engine.quantization`` is not served for the family (synthetic
-    weights and checkpoints alike)."""
-    if quantization:
-        raise ValueError(
-            f"engine.quantization {quantization!r} is not served for "
-            f"model_type lfm2_moe: its routed experts are read one expert "
-            f"at a time from the stacked bfloat16 leaves")
-
 
 # the selection bias is float32 as published, whatever the compute dtype
 FLOAT32_LEAVES = ("expert_bias",)
@@ -342,23 +341,15 @@ LOGIT_STD = 1.5
 QK_NORM_GAIN = 1.5
 CONV_TAP_STD = 0.5
 BIAS_STD = 0.004
-OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192     # one channel in 192, at 32
+OUTLIER_GAIN, OUTLIER_EVERY = mdl.OUTLIER_GAIN, mdl.OUTLIER_EVERY
 OUTLIER_NORMS = ("op_norm", "final_norm")
-
-
-def _outlier_rms(width: int) -> float:
-    """RMS of a normed activation behind a gain with outlier channels."""
-    if width < OUTLIER_EVERY:
-        return 1.0
-    share = (width // OUTLIER_EVERY) / width
-    return math.sqrt(1.0 + share * (OUTLIER_GAIN ** 2 - 1.0))
 
 
 def leaf_std(cfg: Lfm2Config, name: str) -> Optional[float]:
     """The deviation a synthetic MATRIX leaf is drawn at; None for a leaf
     that is no matrix (``init_leaf`` draws those)."""
     D = cfg.hidden_size
-    fan_h = math.sqrt(D) * _outlier_rms(D)      # behind op_norm / final_norm
+    fan_h = math.sqrt(D) * mdl.outlier_rms(D)     # behind op_norm / final_norm
     name = base_name(name)
     if name in ("embed", "lm_head"):
         return LOGIT_STD / fan_h
@@ -478,9 +469,7 @@ def init_rec(cfg: Lfm2Config, num_slots: int) -> dict:
     return {
         "conv": jnp.zeros((cfg.conv_layers, num_slots, cfg.conv_L_cache - 1,
                            cfg.hidden_size), jnp.dtype(cfg.dtype)),
-        # routed work of prefill chunks whose token no copy brings to the
-        # host yet (engine.runner._prefill_paged_fn)
-        "routed": jnp.zeros(2, jnp.int32),
+        **xp.init_rec(),
     }
 
 
@@ -532,7 +521,8 @@ def _conv_mixer(cfg: Lfm2Config, h, w, rows, valid):
         # [the slot's last K-1 rows; the chunk's]: token t is row t + K - 1
         cat = jnp.concatenate([rows().astype(u.dtype), u], axis=1)
         y = gated(p[..., D:2 * D], short_conv(cat, w("conv_w"), T))
-        new_rows = conv_rows(cat, jnp.sum(valid, axis=1).astype(jnp.int32), K)
+        new_rows = mdl.conv_rows(cat, jnp.sum(valid, axis=1).astype(jnp.int32),
+                                 K)
     with jax.named_scope("out_proj"):
         return qnt.matmul(y.astype(h.dtype), w("conv_out")), new_rows
 
@@ -571,12 +561,6 @@ def scores(cfg: Lfm2Config, bias):
                              eps=ROUTE_EPS)
 
 
-def swiglu(h, w_gate, w_up, w_down):
-    """down(silu(gate h) * up h): the dense layers' feed-forward."""
-    y = jax.nn.silu(qnt.matmul(h, w_gate)) * qnt.matmul(h, w_up)
-    return qnt.matmul(y, w_down)
-
-
 def forward(
     cfg: Lfm2Config,
     params: Any,
@@ -592,11 +576,9 @@ def forward(
     *,
     rec: dict,              # init_rec's array
     valid: jax.Array,       # [B, T] bool: the real tokens, a prefix a row
-    slot: Any = None,       # None: batch row b is slot b (a decode step);
-                            # else the ONE slot the [1, T] chunk belongs to
-    fresh: Any = None,      # with ``slot``: the chunk starts the sequence
-                            # (offset 0), so the rows it starts from are zero
-                            # whatever the slot held
+    slot: Any = None,       # the decode step's rows or ONE slot's chunk, and
+    fresh: Any = None,      # whether that starts from zero state: the
+                            # contract (models.llama ``family_module``)
     kernels: Optional[bool] = None,     # models.experts.moe_block's
                             # ``experts_kernel``
 ) -> tuple[jax.Array, Any, dict, jax.Array]:
@@ -604,22 +586,10 @@ def forward(
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
     the expert blocks). One ``lax.scan`` a run of like rows; (x, K/V, conv
     rows, counts) is the carry, so pool and state are written in place."""
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]
-    sin = sin_t[positions][:, :, None, :]
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope("embed"):
-        if embeds is None:
-            x = qnt.embed_rows(params["embed"], tokens, dtype)
-        else:
-            x = embeds.astype(dtype)
+    cos, sin = mdl.rope_rows(rope, positions)
+    x = mdl.embed(cfg, params, tokens, embeds)
     if attn is None:
-        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
-
-        def attn(q, keys, values, m):
-            with jax.named_scope(xla_scope):
-                return mdl._grouped_attn(cfg, q, keys, values, m)
-
+        attn = mdl.xla_attend(cfg, positions)
     eps = cfg.rms_norm_eps
     flat_valid = valid.reshape(-1)
 
@@ -651,32 +621,25 @@ def forward(
                     layer = run.conv0 + at
                     with jax.named_scope("sconv"):
                         def rows(conv_all=conv_all, layer=layer):
-                            r0 = rec_read(conv_all, layer, slot)
+                            r0 = mdl.rec_read(conv_all, (layer,), slot)
                             if fresh is None:
                                 return r0
                             return jnp.where(fresh, 0, r0).astype(r0.dtype)
 
                         out, new_rows = _conv_mixer(cfg, h, w, rows, valid)
                         with jax.named_scope("conv"):
-                            conv_all = rec_write(conv_all, new_rows, layer,
-                                                 slot)
+                            conv_all = mdl.rec_write(conv_all, new_rows,
+                                                     (layer,), slot)
                 else:
-                    def attend(q, k_new, v_new, kv=kv,
-                               layer=run.attn0 + at):
-                        new_kv, keys, values = kv_write(kv, layer, k_new,
-                                                        v_new)
-                        out = attn(q, keys, values, mask)
-                        if isinstance(out, tuple):  # the attend wrote the
-                            out, new_kv = out       # stack
-                        return out, new_kv
-
-                    out, kv = _attention(cfg, h, w, cos, sin, attend)
+                    out, kv = _attention(
+                        cfg, h, w, cos, sin, mdl.attend_through(
+                            kv_write, attn, mask, kv, run.attn0 + at))
                 x = x + out
                 h = norm(x, w("ffn_norm"), eps)
                 if run.dense:
                     with jax.named_scope("dense_mlp"):
-                        x = x + swiglu(h, w("w_gate"), w("w_up"),
-                                       w("w_down"))
+                        x = x + xp.swiglu(h, w("w_gate"), w("w_up"),
+                                          w("w_down"))
                     continue
                 with jax.named_scope("moe"):
                     out, n_touched, load = xp.moe_block(
@@ -701,4 +664,4 @@ def forward(
     x, kv_stack, conv_all, counts = carry
     with jax.named_scope("final_norm"):
         x = norm(x, params["final_norm"], eps)
-    return x, kv_stack, {"conv": conv_all}, counts
+    return x, kv_stack, {**rec, "conv": conv_all}, counts

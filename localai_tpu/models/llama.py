@@ -22,6 +22,7 @@ compute path. Architectural choices are TPU-first, not a translation:
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
 from functools import partial
 from typing import Any, ClassVar, Optional
@@ -65,14 +66,14 @@ class LlamaConfig:
                                           # on each branch's OUTPUT
     dtype: str = "bfloat16"
 
-    # a family whose layers carry per-slot state that is not keys (a
-    # subclass: models.qwen3_next, models.falcon_h1, models.lfm2): the engine
-    # keeps that state beside the K/V pool, built by the family's ``init_rec``
-    # and carried by its ``forward``, and refuses what re-reads a prefix
-    # from keys alone
+    # What the engine asks of a model, never its name; a family's subclass
+    # (``FAMILIES``) answers otherwise.
+    # layers that carry per-slot state that is not keys: the engine keeps it
+    # beside the K/V pool, built by the family's ``init_rec`` and carried by
+    # its ``forward``, and shares no prefix (keys alone do not restore it)
     recurrent: ClassVar[bool] = False
-    # a family with a parameter pytree and a forward of its own: the module
-    # under localai_tpu.models that has them (``family_module``)
+    # the module under localai_tpu.models that has the family's parameter
+    # pytree and forward (``family_module``); None: this file's
     family: ClassVar[Optional[str]] = None
     # a family with routed experts that are told which they hold: its
     # forward counts each launch's routed work (models.experts)
@@ -110,52 +111,17 @@ class LlamaConfig:
 
     @classmethod
     def from_hf(cls, hf: dict) -> "LlamaConfig":
-        """Build from an HF config.json dict, by its ``model_type``: llama,
-        mistral, mixtral (``num_local_experts``), qwen2 (qkv bias), and
-        ouro, the looped decoder (``total_ut_steps`` passes over the stack,
-        four norms a layer; its exit gate is not served: every pass runs
-        for every token). ``qwen3_next`` (periods of Gated DeltaNet layers
-        and a gated full-attention layer, routed experts) is a subclass with
-        its own keys: models.qwen3_next; so is ``afmoe`` (window and full
-        attention layers in one stack, dense layers in front of
-        sigmoid-routed experts): models.afmoe; and ``axk1`` (the DeepSeek-V3
-        block: latent attention in every layer, a dense layer in front of
-        group-limited sigmoid-routed experts): models.deepseek; and
-        ``dots3_note`` (that block with an indexer's learned sparse
-        attention on its full layers and window layers of a shape of their
-        own): models.dots3; and ``falcon_h1`` (a Mamba-2 mixer and
-        grouped-query attention side by side in every layer, under muP
-        multipliers): models.falcon_h1; and ``lfm2_moe`` (a gated short
-        convolution or grouped-query attention by a LIST of layer kinds,
-        dense layers in front of sigmoid-routed experts with no shared
-        expert): models.lfm2. A type not named here is built as a
-        dense llama stack of the file's widths, unless its keys say that it
-        has a state-space mixer (``mamba_*``), which such a stack would
-        leave out: refused."""
-        if hf.get("model_type") == "qwen3_next":
-            from localai_tpu.models.qwen3_next import Qwen3NextConfig
-
-            return Qwen3NextConfig.from_hf(hf)
-        if hf.get("model_type") == "afmoe":
-            from localai_tpu.models.afmoe import AfmoeConfig
-
-            return AfmoeConfig.from_hf(hf)
-        if hf.get("model_type") == "axk1":
-            from localai_tpu.models.deepseek import DeepseekConfig
-
-            return DeepseekConfig.from_hf(hf)
-        if hf.get("model_type") == "dots3_note":
-            from localai_tpu.models.dots3 import Dots3Config
-
-            return Dots3Config.from_hf(hf)
-        if hf.get("model_type") == "falcon_h1":
-            from localai_tpu.models.falcon_h1 import FalconH1Config
-
-            return FalconH1Config.from_hf(hf)
-        if hf.get("model_type") == "lfm2_moe":
-            from localai_tpu.models.lfm2 import Lfm2Config
-
-            return Lfm2Config.from_hf(hf)
+        """Build from an HF config.json dict, by its ``model_type``: a row
+        of ``FAMILIES`` is built by its module's config class; llama,
+        mistral, mixtral (``num_local_experts``), qwen2 (qkv bias) and ouro,
+        the looped decoder (``total_ut_steps`` passes over the stack, four
+        norms a layer; its exit gate is not served: every pass runs for
+        every token) are this class. A type in no row is built as a dense
+        llama stack of the file's widths, unless its keys say that it has a
+        state-space mixer (``mamba_*``), which such a stack would leave out:
+        refused."""
+        if hf.get("model_type") in FAMILIES:
+            return _module(FAMILIES[hf["model_type"]]).CONFIG.from_hf(hf)
         mamba = sorted(k for k in hf if k.startswith("mamba_"))
         if mamba:
             raise ValueError(
@@ -277,18 +243,104 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 # Parameters
 # ---------------------------------------------------------------------------
 
-def family_module(cfg: LlamaConfig):
-    """The module of a family that brings its own parameters and forward
-    (``param_shapes``, ``init_leaf(key, shape, name, dtype, cfg)``,
-    ``checkpoint_leaves``, ``refuse_quantization``, ``forward``;
-    ``leaf_std(cfg, name)`` where it serves a quantised mode, ``init_rec``
-    where it is ``recurrent``, and ``refusal`` where it has
-    ``attn_kinds``); None for this file's."""
-    if cfg.family is None:
-        return None
-    import importlib
+# model_type -> the module under localai_tpu.models that serves it. THE one
+# door: ``LlamaConfig.from_hf`` and ``family_module`` enter a family here, so
+# the next one adds a row and its file (this file, models.loader and
+# models.registry are not edited for it)
+FAMILIES = {
+    "qwen3_next": "qwen3_next",
+    "afmoe": "afmoe",
+    "axk1": "deepseek",
+    "dots3_note": "dots3",
+    "falcon_h1": "falcon_h1",
+    "lfm2_moe": "lfm2",
+}
 
-    return importlib.import_module(f"localai_tpu.models.{cfg.family}")
+
+def _module(name: str):
+    return importlib.import_module(f"localai_tpu.models.{name}")
+
+
+def family_module(cfg: LlamaConfig):
+    """The module of the family ``cfg`` is of (``cfg.family``: a value of
+    ``FAMILIES``); None for this file's plain stack. The contract, what
+    every family's module holds:
+
+      ``CONFIG``    its ``LlamaConfig`` subclass: ``from_hf``, ``family`` and
+                    what the engine asks of a model without naming it
+                    (``recurrent``, ``routed``, ``latent``, ``attn_kinds``,
+                    ``cache_layers``);
+      ``param_shapes(cfg)``, ``init_leaf(key, shape, name, dtype, cfg)``,
+      ``checkpoint_leaves(cfg, get, body)``: its pytree, its synthetic draw
+                    and its checkpoint's names;
+      ``init_rec(cfg, num_slots)``: ``DecodeState.rec``, the per-slot state
+                    its forward carries (and the runner's routed count);
+      ``forward(cfg, params, tokens, positions, kv_write, kv_stack, mask,
+      rope, attn=None, embeds=None, *, rec, valid, slot=None, fresh=None,
+      kernels=None)`` -> (hidden [B, T, D], new stack, ``rec`` with its own
+                    entries renewed, the launch's routed work or None).
+                    ``valid`` [B, T] marks the real tokens; ``slot`` None:
+                    batch row b is slot b (a decode step), else the ONE slot
+                    the [1, T] chunk belongs to, which with ``fresh`` starts
+                    from zero state; ``kernels`` None: the family's own
+                    kernels as XLA, else whether they are interpreted. Built
+                    on this file's frame (``rope_rows``, ``embed``,
+                    ``xla_attend``, ``attend_through``);
+      ``UNSERVED``, ``WEIGHTS``, ``WHY``: the engine's features it does not
+                    serve, the ``engine.quantization`` modes it does, and
+                    the one sentence that says why (``refusal``).
+
+    Optional, probed by ``getattr``: ``base_name(leaf name)`` (a leaf's name
+    without its group's prefix) and ``FLOAT32_LEAVES`` (models.loader),
+    ``leaf_std(cfg, name)`` where ``WEIGHTS`` names a mode
+    (models.registry), ``rope_table(cfg, max_len, freq_base, freq_scale)``
+    (``rope_table`` above)."""
+    return None if cfg.family is None else _module(cfg.family)
+
+
+# What a family may not serve, by the engine's names for it (``UNSERVED``:
+# engine.runner and models.manager ask ``unserved``). Two sets recur: what
+# takes a sequence for its KEYS (state beside the pool is none), and what
+# leaves one chip, the paged layout or a bfloat16 pool (attends chosen by a
+# layer's kind, latent rows)
+KEYS_ALONE = frozenset({
+    "pipeline parallelism", "the ring prefill", "a device mesh",
+    "the contiguous K/V layout", "speculative decoding",
+    "the prompt cache's import"})
+ONE_CHIP_POOL = frozenset({
+    "self-extend", "pipeline parallelism", "the ring prefill",
+    "a device mesh", "the contiguous K/V layout", "a int8 K/V pool",
+    "a int4 K/V pool", "speculative decoding"})
+# (a clause of a recurrent family's ``WHY``)
+STATE_WHY = ("carry recurrent state, which is not keys: it lives in one "
+             "dense row a slot beside the paged K/V pool and cannot be "
+             "re-read, split or shipped as a prefix of keys can")
+
+
+def unserved(cfg: LlamaConfig, feature: str) -> bool:
+    fam = family_module(cfg)
+    return fam is not None and feature in fam.UNSERVED
+
+
+def refusal(cfg: LlamaConfig, what: str) -> str:
+    """The one sentence that refuses ``what`` for ``cfg``'s family."""
+    return f"{what} is not served for {family_module(cfg).WHY}"
+
+
+def refuse(cfg: LlamaConfig, asked) -> None:
+    """Raise the refusal of the first of ``asked``'s (feature, whether it is
+    asked for) that is asked for and that ``cfg``'s family does not serve."""
+    for feature, wanted in asked:
+        if wanted and unserved(cfg, feature):
+            raise ValueError(refusal(cfg, feature))
+
+
+def refuse_quantization(cfg: LlamaConfig, quantization: str) -> None:
+    """``engine.quantization`` for a family, synthetic weights and
+    checkpoints alike: a mode its ``WEIGHTS`` does not name is refused."""
+    fam = family_module(cfg)
+    if fam is not None and quantization and quantization not in fam.WEIGHTS:
+        raise ValueError(refusal(cfg, f"engine.quantization {quantization!r}"))
 
 
 def param_shapes(cfg: LlamaConfig) -> dict:
@@ -345,6 +397,20 @@ def _init_leaf(key, shape, name: str, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
 
 
+# what a family's synthetic draw that has OUTLIER channels in a norm's gain
+# shares (one channel in ``OUTLIER_EVERY``, at ``OUTLIER_GAIN``: what makes a
+# lower-precision ACTIVATION lossy)
+OUTLIER_GAIN, OUTLIER_EVERY = 32.0, 192
+
+
+def outlier_rms(width: int) -> float:
+    """RMS of a normed activation behind a gain with outlier channels."""
+    if width < OUTLIER_EVERY:
+        return 1.0
+    share = (width // OUTLIER_EVERY) / width
+    return math.sqrt(1.0 + share * (OUTLIER_GAIN ** 2 - 1.0))
+
+
 def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
     """Random init (testing / benchmarking with synthetic weights). Each
     leaf is its own jitted program so that, with a ``placement``
@@ -367,6 +433,44 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, placement=None) -> PyTree:
             leaf, static_argnums=(1, 2, 3), out_shardings=sh)(
                 k, shape, kpath[-1].key, dtype))
     return jax.tree.unflatten(treedef, leaves)
+
+
+# ---------------------------------------------------------------------------
+# Per-slot state that is not keys (a ``recurrent`` family's ``init_rec``):
+# dense arrays ``[*index, slots, ...]`` beside the K/V pool, a layer's rows
+# read and written in place
+# ---------------------------------------------------------------------------
+
+def rec_read(arr, index: tuple, slot):
+    """Rows of the layer at ``index`` (its leading axes: ``(layer,)``,
+    ``(period, g)``): every slot's (``slot`` None) or one slot's, with a
+    leading batch axis either way. ONE slice of the layer's rows: an index by
+    the first axis alone would stage what lies under it."""
+    n = len(index)
+    zeros = (0,) * (arr.ndim - n - 1)
+    if slot is None:
+        return lax.dynamic_slice(arr, (*index, 0) + zeros,
+                                 (1,) * n + arr.shape[n:])[(0,) * n]
+    return lax.dynamic_slice(arr, (*index, slot) + zeros,
+                             (1,) * (n + 1) + arr.shape[n + 1:])[(0,) * n]
+
+
+def rec_write(arr, new, index: tuple, slot):
+    n = len(index)
+    zeros = (0,) * (arr.ndim - n - 1)
+    return lax.dynamic_update_slice(
+        arr, new[(None,) * n].astype(arr.dtype),
+        (*index, 0 if slot is None else slot) + zeros)
+
+
+def conv_rows(cat, n_real, K: int):
+    """Of a causal convolution's rows ``cat`` [B, K - 1 + T, C] (a slot's last
+    K - 1 inputs, then the chunk's) the K - 1 in front of the first token
+    that is NOT real: after n real tokens rows n .. n + K - 2 (n = 0 leaves
+    the state as it was)."""
+    return jax.vmap(
+        lambda rows, n: lax.dynamic_slice_in_dim(rows, n, K - 1, 0))(
+            cat, n_real)
 
 
 # ---------------------------------------------------------------------------
@@ -525,6 +629,62 @@ def _grouped_attn(cfg: LlamaConfig, q, keys, values, mask):
     return out.reshape(S, T, Hq, hd)
 
 
+def output_gate(attn, gate):
+    """A per-element sigmoid gate on the attention output, from a projection
+    of the layer's input (models.qwen3_next, models.afmoe)."""
+    return attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
+
+
+def rope_rows(rope, positions):
+    """(cos, sin) [B, T, 1, hd/2]: the table's rows at the tokens' positions."""
+    cos_t, sin_t = rope
+    return cos_t[positions][:, :, None, :], sin_t[positions][:, :, None, :]
+
+
+def embed(cfg: LlamaConfig, params: PyTree, tokens, embeds=None, scale=None):
+    """The ``embed`` scope: the tokens' rows of the table in the compute
+    dtype (under ``scale``, a family's multiplier on them, in float32 in
+    front of the one rounding), or the caller's rows as they are (multimodal
+    injection bypasses the token gather)."""
+    dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope("embed"):
+        if embeds is not None:
+            return embeds.astype(dtype)
+        if scale is None:
+            return qnt.embed_rows(params["embed"], tokens, dtype)
+        return (qnt.embed_rows(params["embed"], tokens, jnp.float32)
+                * scale).astype(dtype)
+
+
+def xla_attend(cfg: LlamaConfig, positions):
+    """The XLA attend over the context kv_write exposes: what a prefill
+    chunk runs (and a decode step under attn_impl: xla). A kernel passed in
+    as ``attn`` brings its own scope (attn.paged_decode)."""
+    scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
+
+    def attn(q, keys, values, m):
+        with jax.named_scope(scope):
+            return _grouped_attn(cfg, q, keys, values, m)
+
+    return attn
+
+
+def attend_through(kv_write, attn, mask, kv, layer):
+    """``attend(q, *new) -> (out, new stack)`` of cache layer ``layer`` for a
+    layer body: ``kv_write`` scatters the layer's new rows (K and V, or one
+    latent row) into the whole stack and exposes what ``attn`` reads of it;
+    an attend that stored the rows itself hands the stack back (the paged
+    decode kernel)."""
+    def attend(q, *new, **how):
+        new_kv, *seen = kv_write(kv, layer, *new)
+        out = attn(q, *seen, mask, **how)
+        if isinstance(out, tuple):  # the attend wrote the stack
+            out, new_kv = out
+        return out, new_kv
+
+    return attend
+
+
 def forward(
     cfg: LlamaConfig,
     params: PyTree,
@@ -573,24 +733,10 @@ def forward(
     ``pass * layers + layer`` (``cfg.cache_layers`` of them). With one pass
     nothing of that is traced: the programs are what they were.
     """
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]  # [B, T, 1, hd/2]
-    sin = sin_t[positions][:, :, None, :]
-    with jax.named_scope("embed"):
-        if embeds is None:
-            x = qnt.embed_rows(params["embed"], tokens, jnp.dtype(cfg.dtype))
-        else:
-            x = embeds.astype(jnp.dtype(cfg.dtype))
+    cos, sin = rope_rows(rope, positions)
+    x = embed(cfg, params, tokens, embeds)
     if attn is None:
-        # the XLA attend over the context kv_write exposes: what a prefill
-        # chunk runs (and a decode step under attn_impl: xla). A kernel
-        # passed in as ``attn`` brings its own scope (attn.paged_decode)
-        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
-
-        def attn(q, keys, values, m):
-            with jax.named_scope(xla_scope):
-                return _grouped_attn(cfg, q, keys, values, m)
-
+        attn = xla_attend(cfg, positions)
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
     scanned, stacks = params["layers"], None
     if live is not None:
@@ -608,15 +754,9 @@ def forward(
             x, kv = carry
             lp, layer = layer_in
 
-            def attend(q, k_new, v_new):
-                new_kv, keys, values = kv_write(kv, layer, k_new, v_new)
-                out = attn(q, keys, values, mask)
-                if isinstance(out, tuple):  # the attend wrote the stack
-                    out, new_kv = out
-                return out, new_kv
-
             return _layer(
-                cfg, x, lp, cos, sin, attend, reduce=reduce,
+                cfg, x, lp, cos, sin,
+                attend_through(kv_write, attn, mask, kv, layer), reduce=reduce,
                 live=live and (*live, stacks,
                                layer if first is None else layer - first),
             ), None

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from localai_tpu.models.llama import (LlamaConfig, family_module,
-                                      param_shapes)
+                                      param_shapes, refuse_quantization)
 
 log = logging.getLogger(__name__)
 
@@ -139,7 +139,7 @@ def load_llama_params(
         # DeltaNet and gated-attention layers; models.afmoe: a dense prefix
         # beside rows of window and full layers): its own names and
         # regrouping. A leaf that is no ``layers`` leaf is a top-level one
-        fam.refuse_quantization(quantization)
+        refuse_quantization(cfg, quantization)
         layers, top = {}, {}
         for name, host in fam.checkpoint_leaves(
                 cfg, lambda n: _get(tensors, n), body):

@@ -27,6 +27,7 @@ from localai_tpu.config.loader import ConfigLoader
 from localai_tpu.config.model_config import ModelConfig
 from localai_tpu.engine.runner import ModelRunner
 from localai_tpu.engine.scheduler import Scheduler
+from localai_tpu.models import llama as mdl
 from localai_tpu.templates.cache import TemplateCache
 
 log = logging.getLogger(__name__)
@@ -394,10 +395,9 @@ def build_runner(mcfg: ModelConfig, app: AppConfig) -> tuple[Any, ModelRunner]:
         # and self-extend forces the unroped single-row cache.
         # Speculative decoding composes now — the draft runner shares
         # the target's mesh (localai_tpu.spec.ModelDrafter)
-        # (nor a model with recurrent state, or with more than one kind of
-        # attention layer: their runners take no mesh yet)
-        if not (app.mirror_port or eng.grp_attn_n > 1 or cfg.recurrent
-                or cfg.attn_kinds or cfg.latent):
+        # (nor a model whose family serves no mesh: models.llama ``UNSERVED``)
+        if not (app.mirror_port or eng.grp_attn_n > 1
+                or mdl.unserved(cfg, "a device mesh")):
             mesh = _auto_mesh(cfg, eng.max_slots)
             if mesh is not None:
                 log.info("auto mesh for %s: %s", mcfg.name,
@@ -497,20 +497,10 @@ def build_serving_model(mcfg: ModelConfig, app: AppConfig) -> ServingModel:
             "%s: speculative decoding is not supported with self-extend "
             "(grp_attn_n>1); serving without it", mcfg.name,
         )
-    elif spec_want and getattr(runner, "recurrent", False):
+    elif spec_want and mdl.unserved(model.cfg, "speculative decoding"):
         if eng.spec:    # asked for by name; the default just stays off
-            log.warning(
-                "%s: speculative decoding is not supported for a model "
-                "with recurrent state (a rejected draft token has already "
-                "moved it); serving without it", mcfg.name)
-    elif spec_want and (getattr(runner, "kinds", None)
-                        or getattr(runner, "latent", False)):
-        if eng.spec:    # asked for by name; the default just stays off
-            log.warning(
-                "%s: speculative decoding is not supported for a stack with "
-                "more than one kind of attention layer, nor over latent "
-                "rows (the verify window has one attend, over K/V a head); "
-                "serving without it", mcfg.name)
+            log.warning("%s: %s; serving without it", mcfg.name,
+                        mdl.refusal(model.cfg, "speculative decoding"))
     elif spec_want and getattr(runner, "pp_enabled", False):
         log.warning(
             "%s: speculative decoding is not supported with pipeline "
@@ -548,11 +538,10 @@ def build_serving_model(mcfg: ModelConfig, app: AppConfig) -> ServingModel:
             "%s: prompt_cache_path is not supported with multi-host command "
             "mirroring (KV loads would desync followers); ignoring", mcfg.name
         )
-    elif mcfg.prompt_cache_path and getattr(runner, "recurrent", False):
-        log.warning(
-            "%s: prompt_cache_path is not supported for a model with "
-            "recurrent state (the state after a prefix is not kept with its "
-            "keys); ignoring", mcfg.name)
+    elif mcfg.prompt_cache_path and mdl.unserved(
+            model.cfg, "the prompt cache's import"):
+        log.warning("%s: %s; ignoring prompt_cache_path", mcfg.name,
+                    mdl.refusal(model.cfg, "the prompt cache's import"))
     elif mcfg.prompt_cache_path:
         from pathlib import Path
 

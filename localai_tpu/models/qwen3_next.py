@@ -160,19 +160,19 @@ class Qwen3NextConfig(LlamaConfig):
         )
 
 
+CONFIG = Qwen3NextConfig
+# what the family does not serve, the weight modes it does, and why
+# (models.llama ``refusal``)
+UNSERVED = mdl.KEYS_ALONE
+WEIGHTS = ()
+WHY = (f"model_type qwen3_next: its DeltaNet layers {mdl.STATE_WHY}; its "
+       f"routed experts are read one expert at a time from the stacked "
+       f"bfloat16 leaves")
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-def refuse_quantization(quantization: str) -> None:
-    """``engine.quantization`` is not served for the family (synthetic
-    weights and checkpoints alike)."""
-    if quantization:
-        raise ValueError(
-            f"engine.quantization {quantization!r} is not served for "
-            f"model_type qwen3_next: its routed experts are read one expert "
-            f"at a time from the stacked bfloat16 leaves")
-
 
 # leaves that are a zero-centred RMSNorm gain: N(x; w) = norm(x) * (1 + w)
 ZERO_CENTRED = ("gdn_norm", "attn_norm", "mlp_norm", "q_norm", "k_norm",
@@ -339,29 +339,8 @@ def init_rec(cfg: Qwen3NextConfig, num_slots: int) -> dict:
                        jnp.float32),
         "conv": jnp.zeros((P, G, num_slots, cfg.linear_conv_kernel_dim - 1,
                            cfg.conv_dim), jnp.dtype(cfg.dtype)),
-        # routed work of prefill chunks whose token no copy brings to the
-        # host yet (engine.runner._prefill_paged_fn)
-        "routed": jnp.zeros(2, jnp.int32),
+        **xp.init_rec(),
     }
-
-
-def _rec_read(arr, p, g: int, slot):
-    """Rows of layer (p, g): every slot's (``slot`` None) or one slot's,
-    with a leading batch axis either way. ONE slice of the layer's rows: an
-    index by the period first would stage the period's G layers."""
-    zeros = (0,) * (arr.ndim - 3)
-    if slot is None:
-        return lax.dynamic_slice(
-            arr, (p, g, 0) + zeros, (1, 1) + arr.shape[2:])[0, 0]
-    return lax.dynamic_slice(
-        arr, (p, g, slot) + zeros, (1, 1, 1) + arr.shape[3:])[0, 0]
-
-
-def _rec_write(arr, new, p, g: int, slot):
-    zeros = (0,) * (arr.ndim - 3)
-    return lax.dynamic_update_slice(
-        arr, new[None, None].astype(arr.dtype),
-        (p, g, 0 if slot is None else slot) + zeros)
 
 
 # ---------------------------------------------------------------------------
@@ -489,11 +468,6 @@ def _partial_rope(x, cos, sin, rot: int):
         [mdl.apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
 
 
-def output_gate(attn, gate):
-    """The full-attention layer's sigmoid gate on each head's output."""
-    return attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
-
-
 def _full_attention(cfg: Qwen3NextConfig, h, lp, cos, sin, attend):
     """The gated full-attention mixer on normed h [B, T, D]."""
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
@@ -514,7 +488,7 @@ def _full_attention(cfg: Qwen3NextConfig, h, lp, cos, sin, attend):
         k = _partial_rope(k, cos, sin, cfg.rotary_dim)
     attn, new_kv = attend(q, k, v)
     with jax.named_scope("attn_gate"):
-        attn = output_gate(attn, gate)
+        attn = mdl.output_gate(attn, gate)
     with jax.named_scope("attn.out"):
         out = qnt.matmul(attn.reshape(*attn.shape[:-2], Hq * hd), lp["wo"])
     return out, new_kv
@@ -569,11 +543,9 @@ def forward(
     *,
     rec: dict,              # init_rec's arrays
     valid: jax.Array,       # [B, T] bool: the real tokens, a prefix a row
-    slot: Any = None,       # None: batch row b is slot b (a decode step);
-                            # else the ONE slot the [1, T] chunk belongs to
-    fresh: Any = None,      # with ``slot``: the chunk starts the sequence
-                            # (offset 0), so the state it starts from is zero
-                            # whatever the slot held
+    slot: Any = None,       # the decode step's rows or ONE slot's chunk, and
+    fresh: Any = None,      # whether that starts from zero state: the
+                            # contract (models.llama ``family_module``)
     kernels: Optional[bool] = None,     # None: the experts' walk and the
                             # DeltaNet's decode step are XLA; else ops.moe's
                             # and ops.gdn's kernels (the value: interpreted)
@@ -582,21 +554,10 @@ def forward(
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
     the expert blocks). One ``lax.scan`` over the periods; (x, K/V, rec) is
     its carry, so both caches are written in place."""
-    cos_t, sin_t = rope
-    cos = cos_t[positions][:, :, None, :]
-    sin = sin_t[positions][:, :, None, :]
-    with jax.named_scope("embed"):
-        if embeds is None:
-            x = qnt.embed_rows(params["embed"], tokens, jnp.dtype(cfg.dtype))
-        else:
-            x = embeds.astype(jnp.dtype(cfg.dtype))
+    cos, sin = mdl.rope_rows(rope, positions)
+    x = mdl.embed(cfg, params, tokens, embeds)
     if attn is None:
-        xla_scope = "attn.prefill" if positions.shape[1] > 1 else "attn.decode"
-
-        def attn(q, keys, values, m):
-            with jax.named_scope(xla_scope):
-                return mdl._grouped_attn(cfg, q, keys, values, m)
-
+        attn = mdl.xla_attend(cfg, positions)
     layers = params["layers"]
     # the expert stacks stay OUT of the scanned operands: a scanned slice of
     # them would be a period's experts (1.6 GB at the published widths)
@@ -626,12 +587,13 @@ def forward(
             return x + out, counts + c
 
         for g_idx in range(G):
+            at = (p, g_idx)
             with jax.named_scope("gdn"):
                 # the per-slot arrays are read and written under the scope
                 # of the recurrence: ``gdn/state`` is all that moves state
                 with jax.named_scope("state"):
-                    S0 = None if fused else _rec_read(S_all, p, g_idx, slot)
-                    conv0 = _rec_read(conv_all, p, g_idx, slot)
+                    S0 = None if fused else mdl.rec_read(S_all, at, slot)
+                    conv0 = mdl.rec_read(conv_all, at, slot)
                     if fresh is not None:       # a chunk: never fused
                         S0 = jnp.where(fresh, 0.0, S0)
                         conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
@@ -646,21 +608,14 @@ def forward(
                                                keepdims=False)
                       for w in (w_in, w_out)), state_step, conv0, valid)
                 with jax.named_scope("state"):
-                    S_all = S if fused else _rec_write(S_all, S, p, g_idx,
-                                                       slot)
-                    conv_all = _rec_write(conv_all, conv, p, g_idx, slot)
+                    S_all = S if fused else mdl.rec_write(S_all, S, at, slot)
+                    conv_all = mdl.rec_write(conv_all, conv, at, slot)
                 x = x + out
             x, counts = moe(x, g_idx, counts)
 
-        def attend(q, k_new, v_new):
-            new_kv, keys, values = kv_write(kv, p, k_new, v_new)
-            out = attn(q, keys, values, mask)
-            if isinstance(out, tuple):      # the attend wrote the stack
-                out, new_kv = out
-            return out, new_kv
-
         h = zc_norm(x, lp["attn_norm"], eps)
-        out, kv = _full_attention(cfg, h, lp, cos, sin, attend)
+        out, kv = _full_attention(cfg, h, lp, cos, sin, mdl.attend_through(
+            kv_write, attn, mask, kv, p))
         x = x + out
         x, counts = moe(x, G, counts)
         return (x, kv, S_all, conv_all, counts), None
@@ -672,4 +627,4 @@ def forward(
             (scanned, jnp.arange(cfg.periods, dtype=jnp.int32)))
     with jax.named_scope("final_norm"):
         x = zc_norm(x, params["final_norm"], eps)
-    return x, kv_stack, {"S": S_all, "conv": conv_all}, counts
+    return x, kv_stack, {**rec, "S": S_all, "conv": conv_all}, counts

@@ -161,8 +161,7 @@ def synthetic_params(cfg: LlamaConfig, quantization: str = "", *,
     if not quantization:
         return mdl.init_params(jax.random.key(seed), cfg, placement)
     fam = mdl.family_module(cfg)
-    if fam is not None:
-        fam.refuse_quantization(quantization)
+    mdl.refuse_quantization(cfg, quantization)
     shapes = mdl.param_shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))

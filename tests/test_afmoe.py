@@ -248,9 +248,9 @@ def shared_expert_gated(monkeypatch):
     def gated(h, w_gate, w_up, w_down):
         gate = jax.nn.sigmoid(jnp.sum(h.astype(jnp.float32), -1,
                                       keepdims=True))
-        return gate * af.swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
+        return gate * xp.swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
 
-    monkeypatch.setattr(af, "shared_expert", gated)
+    monkeypatch.setattr(xp, "shared_expert", gated)
     return {}
 
 
@@ -305,7 +305,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(family):
     w = {n: np.asarray(a[at], np.float32) for n, a in lay.items()}
     with jax.default_matmul_precision("highest"):
         want = np.asarray(family.experts(h, w, whole_hf))
-        shared = np.asarray(af.shared_expert(
+        shared = np.asarray(xp.shared_expert(
             h, *(lay[n][at] for n in ("shared_gate", "shared_up",
                                       "shared_down"))))
         total, pairs = shared.copy(), 0
@@ -318,7 +318,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(family):
                 h, lay["moe_gate"][at], af.scores(cut, lay["expert_bias"][at]),
                 held, jnp.int32(at[0]), at[1], num_experts=cut.num_experts,
                 ep_rank=rank, valid=valid,
-                shared=lambda h: af.shared_expert(h, *(
+                shared=lambda h: xp.shared_expert(h, *(
                     lay[n][at] for n in ("shared_gate", "shared_up",
                                          "shared_down"))))
             total += np.asarray(out) - shared

@@ -306,15 +306,15 @@ def shared_expert_gated(monkeypatch, cfg):
     def gated(h, w_gate, w_up, w_down):
         gate = jax.nn.sigmoid(jnp.sum(h.astype(jnp.float32), -1,
                                       keepdims=True))
-        return gate * ds.swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
+        return gate * xp.swiglu(h, w_gate, w_up, w_down).astype(jnp.float32)
 
-    monkeypatch.setattr(ds, "shared_expert", gated)
+    monkeypatch.setattr(xp, "shared_expert", gated)
     return cfg
 
 
 def dense_layer_routed_as_an_expert_layer_would_not_be(monkeypatch, cfg):
     """The first layer's MLP at half its width: not the dense SwiGLU."""
-    real = ds.swiglu
+    real = xp.swiglu
 
     def swiglu(h, w_gate, w_up, w_down):
         if w_gate.shape[-1] == cfg.intermediate_size:
@@ -322,7 +322,7 @@ def dense_layer_routed_as_an_expert_layer_would_not_be(monkeypatch, cfg):
             return real(h, w_gate[:, :half], w_up[:, :half], w_down[:half])
         return real(h, w_gate, w_up, w_down)
 
-    monkeypatch.setattr(ds, "swiglu", swiglu)
+    monkeypatch.setattr(xp, "swiglu", swiglu)
     return cfg
 
 
@@ -434,7 +434,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(family):
                                           "shared_down"))
     with jax.default_matmul_precision("highest"):
         want = np.asarray(family.experts(h, w, whole_hf))
-        shared = np.asarray(ds.shared_expert(h, *shared_w))
+        shared = np.asarray(xp.shared_expert(h, *shared_w))
         total, pairs = shared.copy(), 0
         for rank in range(size):
             cut = config(n_routed_experts=E // size,
@@ -446,7 +446,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(family):
                 h, lay["moe_gate"][at], ds.scores(cut), held,
                 jnp.int32(at), 0, num_experts=cut.num_experts,
                 ep_rank=rank, valid=valid,
-                shared=lambda h: ds.shared_expert(h, *shared_w))
+                shared=lambda h: xp.shared_expert(h, *shared_w))
             total += np.asarray(out) - shared
             pairs += int(xp.counts(n_touched, load)[1])
     assert pairs == 6 * 3

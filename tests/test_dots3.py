@@ -622,7 +622,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(family):
                                             "shared_down"))
     with jax.default_matmul_precision("highest"):
         want = np.asarray(family.experts(h, w, whole_hf))
-        shared = np.asarray(ds.shared_expert(h, *shared_w))
+        shared = np.asarray(xp.shared_expert(h, *shared_w))
         cut = config(n_routed_experts=E // size,
                      expert_parallel={"size": size, "rank": 0})
         assert cut.router_width == E
@@ -636,7 +636,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(family):
                 dots3.scores(cut, lay["expert_bias"][0, m]), held,
                 jnp.int32(0), m, num_experts=cut.num_experts,
                 ep_rank=rank, valid=valid,
-                shared=lambda h: ds.shared_expert(h, *shared_w))
+                shared=lambda h: xp.shared_expert(h, *shared_w))
             return out, xp.counts(n_touched, load)[1]
 
         total, pairs = shared.copy(), 0

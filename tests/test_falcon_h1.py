@@ -328,9 +328,9 @@ def no_conv_bias(monkeypatch):
 
 def conv_state_one_row_short(monkeypatch):
     """The slot keeps K - 2 rows: the oldest of its K - 1 reads zero."""
-    real = fh.conv_rows
+    real = mdl.conv_rows
     monkeypatch.setattr(
-        fh, "conv_rows",
+        mdl, "conv_rows",
         lambda cat, n, K: real(cat, n, K).at[:, 0].set(0))
     return {}
 
@@ -556,9 +556,9 @@ def test_what_recurrent_state_refuses_stays_refused(params32, what, kw):
     kw = dict(kw)
     if "mesh" in kw:
         kw["mesh"] = _mesh(**kw["mesh"])
-    with pytest.raises(ValueError, match=f"^{what} is not served for a model "
-                                         f"whose layers carry recurrent "
-                                         f"state .*falcon_h1"):
+    with pytest.raises(ValueError, match=f"^{what} is not served for "
+                                         f"model_type falcon_h1: .* carry "
+                                         f"recurrent state"):
         runner_for(config(), params32, **kw)
 
 

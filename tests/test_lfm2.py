@@ -231,9 +231,9 @@ def oldest_tap_dropped(monkeypatch):
 
 def state_one_row_short(monkeypatch):
     """The slot keeps one row: the older of its two reads zero."""
-    real = lfm2.conv_rows
+    real = mdl.conv_rows
     monkeypatch.setattr(
-        lfm2, "conv_rows",
+        mdl, "conv_rows",
         lambda cat, n, K: real(cat, n, K).at[:, 0].set(0))
 
 
@@ -533,9 +533,9 @@ def test_what_recurrent_state_refuses_stays_refused(params32, what, kw):
     kw = dict(kw)
     if "mesh" in kw:
         kw["mesh"] = _mesh(**kw["mesh"])
-    with pytest.raises(ValueError, match=f"^{what} is not served for a model "
-                                         f"whose layers carry recurrent "
-                                         f"state .*lfm2_moe"):
+    with pytest.raises(ValueError, match=f"^{what} is not served for "
+                                         f"model_type lfm2_moe: .* carry "
+                                         f"recurrent state"):
         runner_for(config(), params32, **kw)
 
 

@@ -57,6 +57,15 @@ What each row has seen:
   ``axk1``, ``dots3_note``, ``falcon_h1`` and, with no row here, ``lfm2_moe``
   by ``own_forward``) have no such program (``ModelRunner.rides`` is False,
   held below) and every text of theirs is the parent's.
+- ``lfm2_moe`` (PR 60, parent 8fddbb0, taken BEFORE any file under
+  localai_tpu/models/ was touched: the six family forwards came to call one
+  frame in models.llama (``rope_rows``, ``embed``, ``xla_attend``,
+  ``attend_through``), one ``rec_read`` / ``rec_write`` / ``conv_rows`` there
+  and one ``swiglu`` / ``shared_expert`` in models.experts, to take one set
+  of keywords and to return ``(hidden, stack, rec, routed)``; the families
+  came through one table, their refusals from data): as taken, experts of
+  128 under the family's own heads of 64 (two to a pool row). NO row
+  retaken: every text above is what it was.
 """
 
 import functools
@@ -71,6 +80,7 @@ from test_afmoe import HF as AFMOE
 from test_deepseek import HF as AXK1
 from test_dots3 import HF as DOTS3
 from test_falcon_h1 import HF as FALCON_H1
+from test_lfm2 import HF as LFM2
 from test_qwen3_next import HF as QWEN3_NEXT
 
 from localai_tpu.engine.runner import ModelRunner
@@ -230,6 +240,20 @@ TAKEN = [
             "f8bb71eacc0d156f53b9d45ff6b87a7c3dd05760495e886fe3258da0aec36505",
         "prefill_0":
             "17b891ddebad6bda6c4f4405b8ff5229d91abe82860db6871ca3b728c0a30cf9"}),
+    family({**LFM2, "moe_intermediate_size": 128}, 16, "pallas_interpret", {
+        "decode":
+            "551efa45ea499bb3456b870593a72f5792efdfaf79a3305b2193c13c24d6f240",
+        "prefill_1":
+            "3d6d32682ea7a7f56f1c8ea8fc82b8aac5e68a0ce8006b468feca80277c1aaa0",
+        "prefill_0":
+            "2da814dd8c3f07ec6cb357ed0597d433cbb48fe0ae69759b82057b0e4f5d3f53"}),
+    family({**LFM2, "moe_intermediate_size": 128}, 16, "xla", {
+        "decode":
+            "77ec6134d68aa05f2982e8792d8378b43be97f0f8fcc4a1952fe8b2e63386223",
+        "prefill_1":
+            "80d407a77ab80489093713371f7ef2007233d6f58d9b831573651d88f5ca5e6f",
+        "prefill_0":
+            "0572c404742d7340a0aad104edae774b8f6caeb8b004aa19967d3bfb35d66acc"}),
 ]
 
 

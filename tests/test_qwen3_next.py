@@ -295,7 +295,7 @@ def rope_on_every_dim(monkeypatch):
 
 
 def no_output_gate(monkeypatch):
-    monkeypatch.setattr(qn, "output_gate", lambda attn, gate: attn)
+    monkeypatch.setattr(mdl, "output_gate", lambda attn, gate: attn)
     return {}
 
 
