@@ -1002,13 +1002,15 @@ class ModelRunner:
     @property
     def rides(self) -> bool:
         """Whether a prompt's small last chunk can ride a decode step
-        (``_decode_prefill_paged_fn``): over the block pool, on one chip,
-        for a model that runs ``models.llama.forward``. A family's own
-        forward (routed experts, recurrent state, latent rows, several kinds
-        of layer) and a mesh (the manual-TP trunk, GSPMD's slots over
-        'data') keep the chunk and the step two programs."""
-        return (self.paged and self.mesh is None and not self.latent
-                and not self.own_forward and not self.kinds)
+        (``_decode_prefill_paged_fn``): over the block pool, on one chip
+        (a mesh keeps the chunk and the step two programs: the manual-TP
+        trunk, GSPMD's slots over 'data'), for a model that runs
+        ``models.llama.forward`` or a family whose module says that its own
+        forward takes such a batch (``RIDES``: the contract,
+        ``models.llama.family_module``)."""
+        fam = mdl.family_module(self.cfg)
+        return (self.paged and self.mesh is None
+                and (fam is None or getattr(fam, "RIDES", False)))
 
     @scoped("ride")
     def _decode_prefill_paged_fn(self, params, kv, state: DecodeState, tables,
@@ -1018,15 +1020,18 @@ class ModelRunner:
         step the loop would launch behind it, as ONE program: one forward
         over the chunk's ``bucket`` rows and the S slots' rows side by side
         (``PagedLayout.ride``: the rows meet nowhere but in the attend, and
-        there each goes where its own program sends it), so the embedding,
-        the projections, the MLP and the head read their weights once for
-        both. The step samples for the streams the state held as it stood:
-        the new slot is not among them (its device table row, installed by
-        the arming update in front, is put back on the trash block for the
-        step's rows), and then the chunk's last real row samples the first
-        token and arms the slot, as ``_prefill_paged_fn`` does. What the
-        step then the chunk leave, this leaves: pool, state, tokens. Returns
-        the S tokens and the first token behind them, [S + 1]."""
+        there each goes where its own program sends it; a family's forward
+        is told which rows are whose, ``ride``), so the embedding, the
+        projections, the MLP or the experts and the head read their weights
+        once for both. The step samples for the streams the state held as it
+        stood: the new slot is not among them (its device table row,
+        installed by the arming update in front, is put back on the trash
+        block for the step's rows), and then the chunk's last real row
+        samples the first token and arms the slot, as ``_prefill_paged_fn``
+        does. What the step then the chunk leave, this leaves: pool, state,
+        tokens. Returns the S tokens and the first token behind them,
+        [S + 1], and behind those a routed model's count of the launch (the
+        chunks' before it too, as a final chunk's)."""
         S = self.num_slots
         pos = state.positions
         positions = jnp.concatenate(
@@ -1034,9 +1039,22 @@ class ModelRunner:
         write, attn, mask = self.layout.ride(
             kv, tables.at[slot].set(0), pos, table_row, slot,
             positions[:, :bucket], offset, length)
-        hidden, new_stack = self._forward(
-            params, jnp.concatenate([tokens[0], state.tokens])[None, :],
-            positions, write, kv.stacked(), mask, attn=attn)
+        tokens = jnp.concatenate([tokens[0], state.tokens])[None, :]
+        routed = None
+        if self.own_forward:
+            hidden, new_stack, rec, routed = self._forward_rec(
+                params, tokens, positions, write, kv.stacked(), mask,
+                state.rec, jnp.concatenate(
+                    [jnp.arange(bucket) < length, state.active])[None, :],
+                attn=attn, slot=slot, fresh=offset == 0, ride=bucket)
+            if routed is not None:
+                routed = rec["routed"] + routed
+                rec = {**rec, "routed": jnp.zeros_like(routed)}
+            state = dataclasses.replace(state, rec=rec)
+        else:
+            hidden, new_stack = self._forward(
+                params, tokens, positions, write, kv.stacked(), mask,
+                attn=attn)
         last_h = jax.lax.dynamic_index_in_dim(hidden[0, :bucket], length - 1,
                                               keepdims=True)
         logits = mdl.logits_from_hidden(
@@ -1046,7 +1064,8 @@ class ModelRunner:
             params, state, None, length, slot, offset, counts_row,
             logits=logits[S:])
         return (self.layout.from_stacked(new_stack), state,
-                jnp.concatenate([step, tok]))
+                jnp.concatenate([step, tok] + ([] if routed is None
+                                               else [routed])))
 
     def chunk_rows(self, bucket: int, last: bool = True) -> tuple[int, ...]:
         """The row counts a chunk program of ``bucket`` rows can run BEHIND
@@ -1257,18 +1276,20 @@ class ModelRunner:
 
     def _forward_rec(self, params, tokens, positions, write, stack, mask,
                      rec, valid, attn=None, embeds=None, slot=None,
-                     fresh=None):
+                     fresh=None, ride: int = 0):
         """The forward of a family module (``models.llama.family_module``,
         which states the contract): every one takes these arguments and
         returns the hidden states and the K/V stack as ``_forward`` does,
         then ``rec`` with its own entries renewed and the launch's routed
         work [experts touched, token-expert pairs that landed here] (None
         from a family that routes nothing). One with ``cfg.attn_kinds`` is
-        handed a mask and an attend a kind of layer."""
+        handed a mask and an attend a kind of layer; ``ride`` is passed to
+        a family that ``rides``, in a ride, and to no other."""
         return mdl.family_module(self.cfg).forward(
             self.cfg, params, tokens, positions, write, stack, mask,
             self.rope, attn=attn, embeds=embeds, rec=rec, valid=valid,
-            slot=slot, fresh=fresh, kernels=self.family_kernels)
+            slot=slot, fresh=fresh, kernels=self.family_kernels,
+            **({"ride": ride} if ride else {}))
 
     def _prefill_attn(self, length):
         """Pallas flash attention for the prefill/embed paths (None = XLA)."""
@@ -2202,11 +2223,12 @@ class PagedAdmission:
         """The first sampled token, on the host: waits for the final chunk
         (guarded: a device that never answers would hang here silently)."""
         with self.runner.watchdog.guard("device"):
-            # a model with recurrent state sends the chunk's routed work
+            # a model with routed experts sends the launch's routed work
             # behind the token (``_prefill_paged_fn``); a chunk that rode
-            # has the step's tokens in front of its own
+            # has the step's [S] tokens in front of its own
             return int(np.asarray(  # jaxlint: disable=host-sync-in-hot-path
-                self.first).reshape(-1)[-1 if self.rode else 0])
+                self.first).reshape(-1)[
+                    self.runner.num_slots if self.rode else 0])
 
     def step_chunk(self) -> Optional[int]:
         """Dispatch the next chunk; the first token once the admission is

@@ -252,7 +252,8 @@ class _Dispatch:
     # a final prefill chunk (``toks`` its one sampled token, k = 0): the
     # admission whose first token this is. With k = 1 the chunk rode the
     # step (``Scheduler._launch_ride``): ``toks`` is the step's [S] tokens
-    # and the first token behind them
+    # and the first token behind them (a routed model's count of the launch
+    # behind both, as behind any step's or final chunk's)
     first: Optional[_PendingPrefill] = None
     # what the launch held, counted when it was enqueued and written with
     # its row at the drain (``Scheduler._launch``)
@@ -1321,7 +1322,8 @@ class Scheduler:
             rode = d.first
             if rode is not None:
                 # a chunk rode this step: its first token lies behind the
-                # step's [S]
+                # step's [S] (one row: ``_routed`` hands back [1, S + 1])
+                rows = rows.reshape(-1)
                 rows, first_tok = rows[:-1], int(rows[-1])
             # per-token timing for the adaptive streaming dispatch size:
             # when this dispatch was issued while another was still on the
@@ -1359,7 +1361,13 @@ class Scheduler:
             self._anat_process_s += t_book - t_proc
             with TraceAnnotation("sched.record"):
                 phases = self._take_anat(dt, sync_s)
-                if not fresh and k > 0:
+                # (nor is a ride a sample of a step's HOST work: its launch
+                # holds the admission's, arming and chunk included. Where the
+                # host stands just under the step, as with a family's 96
+                # streams, a burst of rides would carry the EMA over it and
+                # the next dispatch would be the first of two steps: a program
+                # nothing has compiled)
+                if not fresh and k > 0 and rode is None:
                     self._observe_host_time(
                         (phases["gap_ms"] + phases["sched_ms"]
                          + phases["launch_ms"]) * 1e-3)

@@ -34,7 +34,10 @@ What the serving engine holds of it (engine.runner):
     updated in place; a token that is not real (an empty slot of a decode
     step, a padded row of a chunk) moves nothing. A prefill chunk is the
     PARALLEL form (``conv_L_cache`` shifted products over the chunk, the
-    tail handed on through the slot's rows), never a token scan;
+    tail handed on through the slot's rows), never a token scan; a small
+    last chunk and a decode step go through as ONE batch (``RIDES``:
+    models.llama ``family_module``'s third case), the convolution alone
+    in two halves;
   * the expert block is models.experts' (the routing and dispatch
     models.afmoe shares): sigmoid scores, the bias inside the selection and
     outside the weight, ``norm_topk_prob`` over the sum + 1e-6,
@@ -230,6 +233,9 @@ CONFIG = Lfm2Config
 # (models.llama ``refusal``)
 UNSERVED = mdl.KEYS_ALONE
 WEIGHTS = ()
+# ``forward`` takes a prompt's small last chunk and a decode step as one
+# batch (the contract's ``ride``): rows meet in ``_conv_mixer`` alone
+RIDES = True
 WHY = (f"model_type lfm2_moe: its convolution layers {mdl.STATE_WHY}; its "
        f"routed experts are read one expert at a time from the stacked "
        f"bfloat16 leaves")
@@ -506,23 +512,41 @@ def short_conv(cat, taps, T: int):
                for i in range(taps.shape[0]))
 
 
-def _conv_mixer(cfg: Lfm2Config, h, w, rows, valid):
+def _conv_mixer(cfg: Lfm2Config, h, w, rows, valid, ride: int = 0):
     """The gated short convolution on normed h [B, T, D]; ``w(name)`` reads
     one of the layer's leaves, ``rows()`` the slots' last K - 1 rows of
     ``B * x`` [B, K - 1, D]; ``valid`` [B, T] marks the real tokens, a PREFIX
-    of each row. Returns (out [B, T, D], the rows after the real tokens)."""
-    T, D, K = h.shape[1], cfg.hidden_size, cfg.conv_L_cache
+    of each row. Returns (out [B, T, D], the rows after the real tokens).
+
+    ``ride`` (the contract's third case; h [1, ride + S, D]): the two
+    products run once over every row; the convolution between them runs on
+    the chunk's ``ride`` rows against ``rows()`` and on the step's S rows, a
+    row a slot, against ``rows(None)`` (every slot's), each in the shape its
+    own program gives it, and the two are laid end to end. The rows come
+    back as the pair (the chunk's, the step's)."""
+    D, K = cfg.hidden_size, cfg.conv_L_cache
     with jax.named_scope("in_proj"):
         p = lax.optimization_barrier(qnt.matmul(h, w("conv_in")))
         # rounded where the slot's rows hold it: a step and a chunk read
         # the same values
         u = gated(p[..., :D], p[..., 2 * D:]).astype(h.dtype)
-    with jax.named_scope("conv"):
+
+    def conv(part, rows):
         # [the slot's last K-1 rows; the chunk's]: token t is row t + K - 1
-        cat = jnp.concatenate([rows().astype(u.dtype), u], axis=1)
-        y = gated(p[..., D:2 * D], short_conv(cat, w("conv_w"), T))
-        new_rows = mdl.conv_rows(cat, jnp.sum(valid, axis=1).astype(jnp.int32),
-                                 K)
+        cat = jnp.concatenate([rows.astype(u.dtype), part(u)], axis=1)
+        y = gated(part(p[..., D:2 * D]),
+                  short_conv(cat, w("conv_w"), cat.shape[1] - K + 1))
+        return y, mdl.conv_rows(
+            cat, jnp.sum(part(valid), axis=1).astype(jnp.int32), K)
+
+    with jax.named_scope("conv"):
+        if ride:
+            y, chunk_rows = conv(lambda a: a[:, :ride], rows())
+            step, new_rows = conv(lambda a: a[0, ride:, None], rows(None))
+            y = jnp.concatenate([y, step[:, 0][None]], axis=1)
+            new_rows = (chunk_rows, new_rows)
+        else:
+            y, new_rows = conv(lambda a: a, rows())
     with jax.named_scope("out_proj"):
         return qnt.matmul(y.astype(h.dtype), w("conv_out")), new_rows
 
@@ -581,11 +605,16 @@ def forward(
                             # contract (models.llama ``family_module``)
     kernels: Optional[bool] = None,     # models.experts.moe_block's
                             # ``experts_kernel``
+    ride: int = 0,          # rows of ``slot``'s chunk in front of a decode
+                            # step's S, [1, ride + S] in all: the contract
 ) -> tuple[jax.Array, Any, dict, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
     the expert blocks). One ``lax.scan`` a run of like rows; (x, K/V, conv
-    rows, counts) is the carry, so pool and state are written in place."""
+    rows, counts) is the carry, so pool and state are written in place.
+    Everything but the convolution is per row (the packed heads are formed
+    in front of the attend, which a ride's composite policy splits), so a
+    ride's rows go through as any batch's."""
     cos, sin = mdl.rope_rows(rope, positions)
     x = mdl.embed(cfg, params, tokens, embeds)
     if attn is None:
@@ -620,14 +649,19 @@ def forward(
                 if kind == CONV:
                     layer = run.conv0 + at
                     with jax.named_scope("sconv"):
-                        def rows(conv_all=conv_all, layer=layer):
-                            r0 = mdl.rec_read(conv_all, (layer,), slot)
-                            if fresh is None:
+                        def rows(of=slot, conv_all=conv_all, layer=layer):
+                            r0 = mdl.rec_read(conv_all, (layer,), of)
+                            if of is None or fresh is None:
                                 return r0
                             return jnp.where(fresh, 0, r0).astype(r0.dtype)
 
-                        out, new_rows = _conv_mixer(cfg, h, w, rows, valid)
+                        out, new_rows = _conv_mixer(cfg, h, w, rows, valid,
+                                                    ride)
                         with jax.named_scope("conv"):
+                            if ride:    # the step's first, for every slot
+                                new_rows, step_rows = new_rows
+                                conv_all = mdl.rec_write(
+                                    conv_all, step_rows, (layer,), None)
                             conv_all = mdl.rec_write(conv_all, new_rows,
                                                      (layer,), slot)
                 else:

@@ -285,14 +285,33 @@ def family_module(cfg: LlamaConfig):
                     from zero state; ``kernels`` None: the family's own
                     kernels as XLA, else whether they are interpreted. Built
                     on this file's frame (``rope_rows``, ``embed``,
-                    ``xla_attend``, ``attend_through``);
+                    ``xla_attend``, ``attend_through``).
+                    A family that states ``RIDES`` takes one keyword more,
+                    ``ride`` (a static row count; 0, the default, is the
+                    two cases above, and no other family is ever passed it):
+                    of the ``[1, ride + S]`` rows the first ``ride`` are
+                    ``slot``'s chunk (``fresh`` as for any chunk) and the S
+                    behind them a decode step's, row ``ride + b`` slot b's
+                    (``engine.runner _decode_prefill_paged_fn``). Whatever
+                    is per row runs ONCE over all of them, which is the
+                    point: its weights are read once; where a layer mixes
+                    rows each half goes in its own shape against its own
+                    state, and the two are laid end to end. State goes
+                    through ``rec_read`` / ``rec_write`` as ever: the step's
+                    rows first, for every slot (``slot``'s own step row is
+                    not live, ``valid`` False, and leaves its state as it
+                    was), then the chunk's for ``slot``. What the step then
+                    the chunk leave, a ride leaves; its routed work is one
+                    count over both halves' real rows;
       ``UNSERVED``, ``WEIGHTS``, ``WHY``: the engine's features it does not
                     serve, the ``engine.quantization`` modes it does, and
                     the one sentence that says why (``refusal``).
 
-    Optional, probed by ``getattr``: ``base_name(leaf name)`` (a leaf's name
-    without its group's prefix) and ``FLOAT32_LEAVES`` (models.loader),
-    ``leaf_std(cfg, name)`` where ``WEIGHTS`` names a mode
+    Optional, probed by ``getattr``: ``RIDES`` (True: the forward takes a
+    ride, above; absent: the family's last chunk and the decode step stay two
+    programs, ``engine.runner ModelRunner.rides``), ``base_name(leaf name)``
+    (a leaf's name without its group's prefix) and ``FLOAT32_LEAVES``
+    (models.loader), ``leaf_std(cfg, name)`` where ``WEIGHTS`` names a mode
     (models.registry), ``rope_table(cfg, max_len, freq_base, freq_scale)``
     (``rope_table`` above)."""
     return None if cfg.family is None else _module(cfg.family)

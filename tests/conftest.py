@@ -73,18 +73,21 @@ SLOW_MODULES = {
 # The tier-1 files of half a minute and more, longest first (seconds of one
 # of six workers, 2026-10, PR 56: 643 for the first, 31 for the last; PR 59
 # moved ``test_paged_serving`` (131 with its ride cases) and ``test_scheduler``
-# (60) up to where their seconds stand). They
+# (60) up to where their seconds stand; PR 61 reordered the first twenty by
+# its own run's table: 709 for the first, ``test_lfm2`` 432 with its ride
+# cases, ``test_lfm2_compile`` 128 with the ride program's compile). They
 # are collected FIRST: under ``-n 6 --dist loadfile`` a file goes whole to the
 # next free worker in collection order, so the run's wall is a sixth of the
 # files' sum and no late file is its tail (``test_tpu_compile``, one file for
 # libtpu's lock, is last by the alphabet). Rewrite it from a run's table.
 LONGEST_FIRST = (
-    "test_tpu_compile", "test_paged", "test_qwen3_next", "test_afmoe",
-    "test_deepseek", "test_dots3", "test_chip_smoke", "test_kv_contract",
-    "test_bench_walk_latent", "test_bench_walk", "test_overlap",
-    "test_paged_serving", "test_neighbour_texts", "test_ouro",
-    "test_dots3_compile", "test_lfm2", "test_falcon_h1", "test_moe_kernel",
-    "test_lfm2_compile", "test_prefill_span", "test_int4", "test_spec",
+    "test_tpu_compile", "test_paged", "test_qwen3_next", "test_lfm2",
+    "test_afmoe", "test_deepseek", "test_dots3", "test_neighbour_texts",
+    "test_chip_smoke", "test_paged_serving", "test_kv_contract",
+    "test_overlap", "test_bench_walk_latent", "test_bench_walk",
+    "test_lfm2_compile", "test_dots3_compile", "test_ouro",
+    "test_falcon_h1", "test_prefill_span", "test_moe_kernel", "test_int4",
+    "test_spec",
     "test_bench_trace", "test_scheduler", "test_falcon_h1_compile",
     "test_sampling", "test_fleet",
 )

@@ -647,10 +647,20 @@ def test_chunk_ride_share_counts_rides_over_small_last_chunks(rows, value):
     assert read(clocked_ctx(bare)) == (None if value is None else 100.0)
 
 
-def test_the_ride_entry_is_appended_for_the_one_cell_that_claims_it():
+@pytest.mark.parametrize("at, name, moves, cell", [
+    (-2, "runner.chunk_ride_share", "stall_ms_p98", "m7b-decode"),
+    # PR 61: a family's forward takes the ride; the accepted entry lists its
+    # cells, so this cell's reads the same reader under a name of its own
+    (-1, "lfm2.chunk_ride_share", "tpot_ms_p90", "lfm2-pp2-decode")])
+def test_the_ride_entry_is_appended_for_the_one_cell_that_claims_it(
+        at, name, moves, cell):
     entries = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]
-    assert entries[-1] == {
-        "name": "runner.chunk_ride_share", "unit": "%", "better": "higher",
+    assert entries[at] == {
+        "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "runner",
-        "moves": "stall_ms_p98", "workloads": ["m7b-decode"]}
-    assert (BENCH / "layers" / "runner.chunk_ride_share.py").exists()
+        "moves": moves, "workloads": [cell]}
+    rows = _ride_rows(("decode_chunk", 1.0, 128), ("prefill_chunk", 2.0, 128),
+                      ("prefill_chunk", 3.0, 512))
+    read = spec.load_reader(name, ROOT)
+    assert read(clocked_ctx(rows)) == 50.0
+    assert read(clocked_ctx(rows[1:])) is None

@@ -593,3 +593,209 @@ def test_the_scheduler_serves_it_and_counts_its_routed_work(params32):
         assert m["moe_assignments"] >= 22 * 2 * 5
     finally:
         s.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# (vii) a prompt's small last chunk rides the decode step through the family's
+# own forward (PR 61; tests/test_paged_serving.py holds the dense stack's)
+
+# ``c c | a c c | a c``: a dense prefix, the stack and a tail; convolution AND
+# attention layers with experts behind them (5 expert blocks a row)
+RIDE_HF = {"num_hidden_layers": 7, "layer_types": [C, C, A, C, C, A, C]}
+EXPERT_BLOCKS, TOP_K = 5, HF["num_experts_per_tok"]
+STREAMS = (RNG.integers(1, 380, 5).tolist(), RNG.integers(1, 380, 11).tolist())
+LONG = RNG.integers(1, 380, 13).tolist()         # two chunks: 8, then 5 of 8
+
+
+def _host(r):
+    """Every leaf of the pool, of the decode state and of the family's own
+    (the convolution rows of EVERY slot, the routed count), on the host."""
+    st = r.state
+    return [np.asarray(a) for a in jax.tree.leaves(
+        (r.kv, st.tokens, st.positions, st.active, st.counts, st.bias,
+         st.params, jax.random.key_data(st.keys), st.rec))]
+
+
+def _busy_runner(cfg, params, attn_impl, sampling):
+    """Two streams three steps in (slots 0 and 1), a slot that held a stream
+    that has ended (2: its rows are what the stream left) and one that never
+    held any (3)."""
+    r = runner_for(cfg, params, attn_impl, seed=3)
+    assert r.rides and r.own_forward
+    for prompt in (*STREAMS, SHORT):
+        r.admit(r.acquire_slot(), prompt, **{**sampling, "seed": 7})
+    for _ in range(3):
+        r.step()
+    r.release(2)
+    conv = np.asarray(r.state.rec["conv"])
+    assert conv[:, :3].any(axis=(0, 2, 3)).all() and not conv[:, 3].any()
+    return r
+
+
+@pytest.fixture(scope="module")
+def ride_models():
+    return {dtype: (cfg, mdl.init_params(jax.random.key(0), cfg))
+            for dtype in ("float32", "bfloat16")
+            for cfg in [config(dtype=dtype, **RIDE_HF)]}
+
+
+GREEDY, SEEDED = dict(temperature=0.0), dict(temperature=0.8, top_p=0.95,
+                                              seed=11)
+
+
+@pytest.mark.parametrize("dtype, attn_impl, prompt, sampling", [
+    ("float32", "xla", SHORT, GREEDY), ("float32", "xla", SHORT, SEEDED),
+    ("float32", "xla", LONG, GREEDY), ("float32", "xla", LONG, SEEDED),
+    # (the kernels in the interpreter: 20 s)
+    ("bfloat16", "pallas_interpret", LONG, SEEDED)],
+    ids=lambda v: {id(SHORT): "fresh", id(LONG): "resumed",
+                   id(GREEDY): "greedy", id(SEEDED): "seeded"}.get(id(v), v))
+def test_a_ride_leaves_what_the_step_then_the_chunk_leave(
+        ride_models, dtype, attn_impl, prompt, sampling):
+    """``_decode_prefill_paged_fn`` through ``lfm2.forward(ride=bucket)``
+    against the two programs it stands for, on the same state: a decode step
+    (the new slot not live: its row moves no state), then the prompt's last
+    chunk; the new slot is the one a finished stream left its rows in, and
+    the chunk is the prompt's only one (``fresh``: from zero) or its second
+    (from what the first left). The same S tokens and first token, the same
+    pool, convolution rows of EVERY slot and sampling state to the bit, the
+    same streams afterwards; the launch's token-expert pairs are the step's
+    plus the chunks', and the experts it touched at most the sum and at least
+    the larger (one block's rows share what they touch: the one read)."""
+    cfg, params = ride_models[dtype]
+
+    def serve(ride):
+        r = _busy_runner(cfg, params, attn_impl, sampling)
+        adm = r.begin_admit(r.acquire_slot(2), prompt, **sampling)
+        assert adm.slot == 2
+        if len(prompt) > 8:
+            assert adm.ride_bucket is None and adm.launch_chunk() is False
+        assert adm.ride_bucket == 8
+        if ride:
+            assert adm.launch_chunk(ride=True) is True
+            out = np.asarray(adm.first)
+            step, first, routed = out[:SLOTS], int(out[SLOTS]), out[SLOTS + 1:]
+            assert adm.first_token() == first
+        else:
+            out = np.asarray(r.step_async())
+            step, routed = out[:SLOTS], out[SLOTS:]
+            assert adm.launch_chunk() is True
+            out = np.asarray(adm.first)
+            first, routed = int(out[0]), np.stack([routed, out[1:]])
+        after = [r.step() for _ in range(3)]
+        return [step, first, *after], routed, _host(r)
+
+    (want, apart, want_state), (got, routed, got_state) = (
+        serve(False), serve(True))
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    assert len(want_state) == len(got_state)
+    for a, b in zip(want_state, got_state):
+        np.testing.assert_array_equal(a, b)
+    # two live streams and the prompt's real tokens, top-2 in 5 blocks: rows
+    # past ``length`` and slots with no stream chose no expert
+    assert routed[1] == apart[:, 1].sum() == (
+        (2 + len(prompt)) * TOP_K * EXPERT_BLOCKS)
+    assert apart[:, 0].max() <= routed[0] <= apart[:, 0].sum()
+    conv = got_state[-2]
+    assert conv.shape[1] == SLOTS and not conv[:, 3].any()
+
+
+def test_a_rides_padded_rows_and_idle_slots_move_nothing(ride_models):
+    """The ride's rows that are nobody's: the chunk's 3 rows past ``length``
+    and the step's rows of the slots with no stream (the new slot's own
+    among them), whatever token they hold, choose no expert and move no
+    state: the pool's live blocks, every slot's convolution rows, the tokens
+    and the routed count are the same to the bit."""
+    from localai_tpu.engine.runner import _prompt_counts_row
+
+    cfg, params = ride_models["float32"]
+
+    def ride(junk):
+        r = _busy_runner(cfg, params, "xla", dict(temperature=0.0))
+        adm = r.begin_admit(r.acquire_slot(2), SHORT, temperature=0.0)
+        row = np.asarray(r.allocator.table_row(adm.slot), np.int32)
+        r._arm(adm.arm_args, row)
+        # the tokens the idle slots' step rows feed
+        r.state = dataclasses.replace(r.state, tokens=jnp.where(
+            r.state.active, r.state.tokens, junk))
+        chunk = np.full((1, 8), junk, np.int32)
+        chunk[0, :5] = SHORT
+        r.kv, r.state, out = r._decode_prefill_paged(
+            r.params, r.kv, r.state, r.block_tables, chunk, np.int32(5),
+            np.int32(0), row, np.int32(adm.slot),
+            _prompt_counts_row(cfg.vocab_size, SHORT), bucket=8)
+        live = sorted({b for s in (0, 1, 2)
+                       for b in r.allocator.table_row(s) if b})
+        out = np.asarray(out)
+        keep = np.array([0, 1, SLOTS, SLOTS + 1, SLOTS + 2])
+        return (out[keep], np.asarray(r.state.rec["conv"]),
+                np.asarray(r.kv.k[:, live]), np.asarray(r.kv.v[:, live]))
+
+    for a, b in zip(ride(0), ride(377)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_scheduler_rides_it_and_the_texts_are_the_same(ride_models):
+    """Through ``Scheduler``: the same three requests with the arrival's
+    chunk riding the decode step and with it stepped aside (a neighbour
+    under a constraint that allows every token: the loop's synchronous
+    branch, the chunk a launch of its own) return the same tokens at
+    temperature 0, and the ride's one ``decode_chunk`` row carries the routed
+    work of both halves behind its S + 1 tokens."""
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    cfg, params = ride_models["float32"]
+
+    class Anything:
+        done = False
+
+        def allowed_mask(self):
+            return np.zeros(cfg.vocab_size, np.float32)
+
+        def advance(self, tid):
+            pass
+
+    def wait(pred):
+        import time
+
+        deadline = time.monotonic() + 120.0
+        while not pred() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert pred()
+
+    def serve(aside):
+        r = runner_for(cfg, params)
+        s = Scheduler(r, ByteTokenizer(), multi_step=1)
+        greedy = dict(temperature=0.0, ignore_eos=True)
+        try:
+            a = s.submit(GenRequest(prompt=STREAMS[0], max_new_tokens=40,
+                                    **greedy))
+            b = s.submit(GenRequest(
+                prompt=STREAMS[1], max_new_tokens=40,
+                constraint=Anything() if aside else None, **greedy))
+            wait(lambda: min(a.completion_tokens, b.completion_tokens) >= 3)
+            c = s.generate(GenRequest(prompt=SHORT, max_new_tokens=6,
+                                      **greedy), timeout=300)
+            texts = [h.result(300).token_ids for h in (a, b)] + [c.token_ids]
+        finally:
+            s.shutdown()
+        rows = [x for x in s.flight.snapshot(limit=256)
+                if x["program"] == "decode_chunk"]
+        return texts, s.total_chunk_rides, s.total_prefill_chunks, rows
+
+    rode, rides, chunks, rows = serve(False)
+    aside, no_rides, chunks_aside, no_rows = serve(True)
+    assert rode == aside and [len(t) for t in rode] == [40, 40, 6]
+    # (the two streams' own chunks are 5 and 8 + 3 tokens: launches of their
+    # own, the first into an idle engine; the second's last of 3 rode the
+    # first's step where nothing stepped aside)
+    assert (rides, no_rides, chunks, chunks_aside) == (2, 0, 4, 4)
+    assert not no_rows and [x["chunk_tokens"] for x in rows] == [3, 5]
+    row = rows[1]
+    assert (row["steps"], row["live_slots"], row["chunk_tokens"],
+            row["chunk_bucket"]) == (1, 2, 5, 8)
+    assert row["local_assignments"] == (2 + 5) * TOP_K * EXPERT_BLOCKS
+    assert EXPERT_BLOCKS <= row["experts_touched"] <= (
+        row["local_assignments"])

@@ -66,6 +66,17 @@ What each row has seen:
   came through one table, their refusals from data): as taken, experts of
   128 under the family's own heads of 64 (two to a pool row). NO row
   retaken: every text above is what it was.
+- PR 61 (a FAMILY's forward takes the ride: ``ModelRunner.rides`` asks the
+  family's module for ``RIDES``, ``models.lfm2.forward`` gains the keyword
+  ``ride`` and ``_conv_mixer`` its two halves): NO row retaken. ``lfm2_moe``
+  gains ONE program, its ``ride`` text (under either ``attn_impl``: the
+  kernels in it or their XLA forms), taken from this PR's own tree as a new
+  program is; its ``decode`` and both chunks stand as taken (the keyword
+  defaults to no ride and the convolution's one half traces what the whole
+  did), and so does every other row: the five families that do not say
+  ``RIDES`` are never passed the keyword and hold no such program
+  (``rides`` is False, held below), the 7B's and Ouro's ``ride`` texts are
+  PR 59's.
 """
 
 import functools
@@ -246,14 +257,18 @@ TAKEN = [
         "prefill_1":
             "3d6d32682ea7a7f56f1c8ea8fc82b8aac5e68a0ce8006b468feca80277c1aaa0",
         "prefill_0":
-            "2da814dd8c3f07ec6cb357ed0597d433cbb48fe0ae69759b82057b0e4f5d3f53"}),
+            "2da814dd8c3f07ec6cb357ed0597d433cbb48fe0ae69759b82057b0e4f5d3f53",
+        "ride":
+            "52b4ac8569805a1bec4f2aa365cd6ae460c2dfdc0fbdb832f89fe83179f39cbd"}),
     family({**LFM2, "moe_intermediate_size": 128}, 16, "xla", {
         "decode":
             "77ec6134d68aa05f2982e8792d8378b43be97f0f8fcc4a1952fe8b2e63386223",
         "prefill_1":
             "80d407a77ab80489093713371f7ef2007233d6f58d9b831573651d88f5ca5e6f",
         "prefill_0":
-            "0572c404742d7340a0aad104edae774b8f6caeb8b004aa19967d3bfb35d66acc"}),
+            "0572c404742d7340a0aad104edae774b8f6caeb8b004aa19967d3bfb35d66acc",
+        "ride":
+            "5c4f4aebea0a1eb2b7ff5f9547ba8a263eae5aebb6c127553a545328f868301b"}),
 ]
 
 

@@ -1556,6 +1556,42 @@ def test_who_steps_aside_from_the_ride(tiny, monkeypatch, why):
     assert ("decode_chunk" in programs) == bool(rides)
 
 
+def test_a_ride_is_no_sample_of_a_steps_time_or_of_its_host_work(
+        tiny, monkeypatch):
+    """The two EMAs that size the next dispatch (``_effective_steps``) are
+    fed by plain pipelined steps alone: a ``decode_chunk`` row lasts a chunk
+    longer on the device AND holds the admission's host work in its launch
+    (PR 61: with the host just under the step, rides in a burst carried
+    ``_host_ema`` over ``_step_ema`` and the loop compiled a two-step
+    program inside the measured window)."""
+    r = ModelRunner(tiny.cfg, tiny.params, **PAGED_KW)
+    s = Scheduler(r, ByteTokenizer(), multi_step=1)
+    fed = {"_observe_step_time": 0, "_observe_host_time": 0}
+    for name in fed:
+        real = getattr(s, name)
+        monkeypatch.setattr(s, name, lambda v, name=name, real=real: (
+            fed.__setitem__(name, fed[name] + 1), real(v))[1])
+    try:
+        keeper = s.submit(_req("keeper", max_new_tokens=40, stream=True,
+                               ignore_eos=True, **GREEDY))
+        assert _wait(lambda: keeper.completion_tokens >= 3)
+        for text in ("one arrival", "and another"):
+            s.generate(_req(text, max_new_tokens=3, ignore_eos=True,
+                            **GREEDY), timeout=120)
+        keeper.result(60)
+        assert _wait(lambda: not s.busy)
+        assert _wait(
+            lambda: s.flight.snapshot()[-1]["launch"] == s._launch_seq)
+    finally:
+        s.shutdown()
+    rows = [x for x in s.flight.snapshot(limit=512) if not x["compile"]]
+    plain = sum(x["program"] == "decode" for x in rows)
+    assert s.total_chunk_rides == 2
+    assert sum(x["program"] == "decode_chunk" for x in rows) >= 1
+    assert fed["_observe_host_time"] == plain
+    assert fed["_observe_step_time"] <= plain
+
+
 def test_a_cancelled_head_admission_is_dropped_not_ridden(tiny, monkeypatch):
     """An admission cancelled while its last chunk waits at the head of the
     queue, streams decoding beside it: it is dropped where a cancelled

@@ -580,8 +580,11 @@ def assert_who_writes(program, text, pool, ladder=None, quartered=False):
     Pallas call, which writes the step's rows."""
     import re
 
+    # (a routed family's grouped expert kernel aside: it touches no pool,
+    # and tests/test_lfm2_compile.py counts its calls)
     calls = [ln for ln in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "moe/experts/moe_experts" not in ln]
     rides = program.startswith("decode_prefill_")
     if rides or not program.startswith("decode"):
         assert rides or not calls
