@@ -629,6 +629,142 @@ def window_attend(view: KindView, table_row: jax.Array, offset: jax.Array,
     return attn
 
 
+# ---------------------------------------------------------------------------
+# attends over a SELECTION of a stream's blocks (``LlamaConfig.select_blocks``)
+# ---------------------------------------------------------------------------
+#
+# A family whose queries attend the best blocks of their context hands its
+# attend the selection (``select=``): which blocks is the model's, how the
+# pool is read under them is the layout's. The selection is one a (stream,
+# K/V head), so the pool is read as a pool of ONE-head blocks, ``[L, N Hkv,
+# 1, bt, hd]`` (the same bytes: N and Hkv are neighbours), whose block ``b
+# Hkv + g`` is head g of block b, and a (stream, head)'s selected blocks are
+# a short block table of its own: the decode attend over it is the attend
+# every model runs, kernel or XLA, with rows = (stream, K/V head).
+
+def _one_head(cache):
+    """``[L, N, Hkv, bt, hd]`` as ``[L, N Hkv, 1, bt, hd]``."""
+    return cache.reshape(cache.shape[0], -1, 1, *cache.shape[3:])
+
+
+def select_tables(tables, positions, select, heads: int, bt: int):
+    """(tables ``[S Hkv, W]``, positions ``[S Hkv]``) of the one-head pool
+    for ``select`` = (the logical blocks a (stream, head) attends ``[S, Hkv,
+    W]``, ascending, the stream's own block the LAST of the first ``n``; n
+    ``[S, Hkv]``). A row's position is its token's place in the COMPACTED
+    context: every block before the last is whole. A stream on the trash
+    block stays there, whatever its head."""
+    blocks, n = select
+    # the table's entries at the selected places, as a one-hot sum: a gather
+    # of a few thousand scattered words is a serial walk on this chip (82 us
+    # a layer against ~10: PERF.md section 6, PR 62)
+    at = blocks[..., None] == jnp.arange(tables.shape[1], dtype=blocks.dtype)
+    phys = jnp.sum(jnp.where(at, tables[:, None, None, :], 0), axis=-1)
+    rows = jnp.where(phys == 0, 0,
+                     phys * heads + jnp.arange(heads)[None, :, None])
+    pos = (n - 1) * bt + positions[:, None] % bt
+    return rows.reshape(-1, rows.shape[-1]), pos.reshape(-1)
+
+
+def select_decode(cfg: LlamaConfig, kernel, tables: jax.Array,
+                  positions: jax.Array):
+    """(write policy, attend) of a decode step whose streams attend a
+    selection of their blocks. ``kernel`` (``ops.paged_decode_attention``,
+    or None for XLA) runs over the compacted tables; where it is given it
+    stores the step's rows too, as for every model (the stream's own block
+    is the last of its table); else the policy scatters them."""
+    def write(kv_stack, layer, k_new, v_new):
+        if kernel is not None:
+            return paged_decode_write(tables, positions, raw=True)(
+                kv_stack, layer, k_new, v_new)
+        bt = kv_stack[0].shape[3]
+        blk = tables[jnp.arange(tables.shape[0]), positions // bt]
+        new = _write_rows(kv_stack, layer, blk, positions % bt, k_new[:, 0],
+                          v_new[:, 0])
+        return (new, *_views(new, layer))
+
+    def attn(q, keys, values, _mask, *, select):    # q [S, 1, Hq, hd]
+        S, _, Hq, hd = q.shape
+        G, bt = keys.cache.shape[2], keys.cache.shape[3]
+        rows, pos = select_tables(tables, positions, select, G, bt)
+        qr = q[:, 0].reshape(S * G, Hq // G, hd)
+        if kernel is None:
+            k, v = _gather_context(
+                (_one_head(keys.cache), _one_head(values.cache)),
+                keys.layer, rows, q)
+            with jax.named_scope("attn.select_decode"):
+                keep = jnp.arange(k.shape[2])[None, None, :] <= pos[:, None,
+                                                                    None]
+                out = _grouped_attn(cfg, qr[:, None], k, v, keep)
+            return out.reshape(S, 1, Hq, hd)
+        with jax.named_scope("attn.select_decode"):
+            out, *pools = kernel(
+                qr, _one_head(keys.cache), _one_head(values.cache),
+                keys.layer, rows, pos, None, None,
+                keys.new.reshape(S * G, 1, hd),
+                values.new.reshape(S * G, 1, hd))
+        return out.reshape(S, 1, Hq, hd), tuple(
+            pool.reshape(cache.shape)
+            for pool, cache in zip(pools, (keys.cache, values.cache)))
+
+    return write, attn
+
+
+# query rows of a selecting chunk that are attended at once (their scores
+# over the whole span are float32: 64 rows of 32 heads over 32768 keys are
+# 0.25 GiB)
+SELECT_ROWS = 64
+
+
+def select_span_attend(cfg: LlamaConfig, table_row: jax.Array,
+                       offset: jax.Array, ctx_pad: int):
+    """``span_attend`` for a chunk whose rows attend a selection of the
+    prefix's blocks: ``select`` ``[T, Hkv, blocks]`` says which blocks each
+    (row, K/V head) attends (all of them for a row that does not select),
+    and a row attends the keys of those at or before its own position. The
+    span is the ladder's rung, as everywhere; the mask is the rule's own
+    (the caller's says nothing of heads)."""
+    def attn(q, keys, values, _mask, *, select):    # q [1, T, Hq, hd]
+        T, bt = q.shape[1], keys.cache.shape[3]
+        G = keys.cache.shape[2]
+        rungs = span_ladder(T, ctx_pad, bt)
+        # tiles of at most SELECT_ROWS rows, and at least eight of them: a
+        # loop of two is unrolled, and both tiles' scores are then live
+        rows = math.gcd(T, min(SELECT_ROWS, max(8, T // 8)))
+
+        def rung(c: int):
+            def run(q, stack, keep):
+                k, v = _gather_context(stack, keys.layer,
+                                       table_row[None, :c // bt], q)
+                kpos = jnp.arange(c)
+
+                def tile(args):
+                    qt, keep_t, qpos = args     # [rows, ...]
+                    seen = jnp.repeat(keep_t[:, :, :c // bt], bt, axis=2) & (
+                        kpos[None, None, :] <= qpos[:, None, None])
+                    qg = qt.reshape(rows, G, -1, qt.shape[-1])
+                    s = jnp.einsum("tkgh,klh->kgtl", qg, k[0]).astype(
+                        jnp.float32) / math.sqrt(cfg.hd)
+                    s = jnp.where(jnp.moveaxis(seen, 0, 1)[:, None], s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+                    return jnp.einsum("kgtl,klh->tkgh", p, v[0]).reshape(
+                        qt.shape)
+
+                with jax.named_scope("attn.select_chunk"):
+                    out = lax.map(tile, (
+                        q[0].reshape(T // rows, rows, *q.shape[2:]),
+                        keep.reshape(T // rows, rows, *keep.shape[1:]),
+                        (offset + jnp.arange(T)).reshape(T // rows, rows)))
+                return out.reshape(q.shape)
+            return run
+
+        return lax.switch(attend_rung(offset + T, rungs),
+                          [rung(c) for c in rungs],
+                          q, _stacked(keys, values), select)
+
+    return attn
+
+
 def paged_verify_write(tables: jax.Array, positions: jax.Array,
                        ctx_limit: int):
     """KV write policy for the batched speculative verify forward over a
@@ -1479,6 +1615,12 @@ class PagedLayout:
     def __post_init__(self):
         self.ctx = self.max_blocks * self.block_tokens
         self.kv_sharding = self.table_sharding = None
+        sel = self.cfg.select_blocks
+        if sel and sel[0] != self.block_tokens:
+            raise ValueError(
+                f"the model selects blocks of {sel[0]} tokens and the pool's "
+                f"hold {self.block_tokens}: a selected block is a block of "
+                f"the pool (engine.kv_block_tokens)")
         if self.mesh is not None:
             from localai_tpu.parallel import sharding as shd
 
@@ -1533,6 +1675,12 @@ class PagedLayout:
         cfg = self.cfg
         raw = self.attn_impl == "pallas"
         attn = None
+        if cfg.select_blocks:
+            # its streams attend a selection of their blocks: compacted
+            # tables, rows = (stream, K/V head)
+            write, attn = select_decode(
+                cfg, self._kernel(None) if raw else None, tables, positions)
+            return write, attn, decode_mask(cfg, positions, self.ctx)
         if raw:
             kernel = self._kernel(cfg.sliding_window)
             if self.mesh is not None:
@@ -1595,7 +1743,8 @@ class PagedLayout:
         the bucket, picked on the device: the mask is sliced to it."""
         cfg, bucket = self.cfg, positions.shape[1]
         mask = resume_mask(cfg, bucket, offset, self.ctx)
-        attn = span_attend(cfg, table_row, offset, self.ctx)
+        attn = (select_span_attend if cfg.select_blocks else span_attend)(
+            cfg, table_row, offset, self.ctx)
         if cfg.attn_kinds:
             # a window layer's chunk gathers its window of the prefix
             views = kind_views(cfg)

@@ -26,6 +26,19 @@ Design points:
     read-only and computes only the tail — chunked prefill then starts at
     a block boundary. Writes never touch a shared block: a sequence's
     write frontier always lies past its shared prefix.
+  * **A prefix of a model with recurrent state ends on a SNAPSHOT.** The
+    keys of a prefix can be read again; the per-slot state that is not keys
+    (a linear-attention sum, a convolution's rows) stands only where it was
+    kept. With ``snapshots`` > 0 the allocator also owns that many rows of
+    the runner's snapshot arrays: a prompt whose prefill is worth keeping
+    (``begin_snapshot``) has the state at its last whole prefill chunk (a
+    block boundary: prompts that open with one document and add less than a
+    chunk keep ONE state, the document's) copied into a row, bound to the
+    chain's key at that boundary when the prompt is registered; ``match_prefix`` then returns the longest chain
+    THAT ENDS ON A SNAPSHOT (else nothing) and ``allocate`` records the row
+    the admission restores from. A snapshot lives and dies with its chain's
+    last block: pinned while a sequence maps the block, evicted with it
+    (LRU, as the pool's). With ``snapshots`` = 0 nothing of this runs.
   * **Block 0 is the trash block.** The decode program writes a KV row for
     every slot each step, active or not (static shapes). Released slots'
     device table rows are reset to all-zeros so those garbage writes land
@@ -98,7 +111,7 @@ class BlockAllocator:
     """Free list + per-sequence block tables + refcounted prefix pool."""
 
     def __init__(self, num_blocks: int, block_tokens: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int, snapshots: int = 0):
         if num_blocks < 2:
             raise ValueError("need at least 2 blocks (one is the trash block)")
         self.num_blocks = num_blocks
@@ -137,6 +150,19 @@ class BlockAllocator:
         self._tier_load = None
         self.spills_total = 0
         self.reloads_total = 0
+        # state snapshots (the module docstring): chain key -> row of the
+        # runner's snapshot arrays; rows no chain holds; a row an admission
+        # is filling (bound at register_prefix); the row an admission
+        # restores from. ``snapshots`` 0: keys alone, none of it is touched
+        self.snapshots = snapshots
+        self._snap: dict[str, int] = {}
+        self._snap_at: dict[str, int] = {}      # ... -> tokens it stands for
+        self._snap_free: list[int] = list(range(snapshots - 1, -1, -1))
+        self._snap_pending: dict[int, int] = {}
+        self.restore_row: dict[int, int] = {}
+        self.snapshots_taken = 0
+        self.snapshots_restored = 0
+        self.snapshot_evictions = 0
 
     # -- sizing -----------------------------------------------------------
 
@@ -244,6 +270,7 @@ class BlockAllocator:
         if nb <= 0:
             return []
         out: list[int] = []
+        kept = 0
         with self._lock:
             for key in self._chain(prompt, nb, bt):
                 bid = self._prefix.get(key)
@@ -254,7 +281,10 @@ class BlockAllocator:
                 if bid is None:
                     break
                 out.append(bid)
-        return out
+                if key in self._snap:
+                    kept = len(out)
+        # recurrent state: the chain as far as its last snapshot
+        return out[:kept] if self.snapshots else out
 
     def register_prefix(self, seq: int, prompt: list[int]) -> int:
         """Insert ``seq``'s full prompt blocks into the prefix pool (each
@@ -269,7 +299,8 @@ class BlockAllocator:
                 return 0
             bt = self.block_tokens
             nb = min((len(prompt) - 1) // bt, len(table))
-            for i, key in enumerate(self._chain(prompt, nb, bt)):
+            keys = self._chain(prompt, nb, bt)
+            for i, key in enumerate(keys):
                 if key in self._prefix:
                     self._prefix.move_to_end(key)
                     continue
@@ -286,7 +317,68 @@ class BlockAllocator:
                     # HBM-resident XOR spilled, audited by
                     # check_invariants)
                     self._tier.discard(key)
+            row, at = self._snap_pending.pop(seq, (None, 0))
+            if row is not None:
+                # the state ``begin_snapshot`` had copied: the chain's now,
+                # if the block it was taken behind is (else the row goes
+                # back)
+                key = keys[at // bt - 1] if 0 < at // bt <= nb else None
+                if key in self._prefix and key not in self._snap:
+                    self._snap[key], self._snap_at[key] = row, at
+                    self.snapshots_taken += 1
+                else:
+                    self._snap_free.append(row)
         return added
+
+    def begin_snapshot(self, seq: int, prompt: list[int], shared: int,
+                       chunk: int) -> Optional[tuple[int, int]]:
+        """Whether ``seq``'s admission keeps the state of its prompt: (the
+        snapshot row to copy it into, the position it is taken at), or
+        None. The position is the prompt's last multiple of ``chunk`` (the
+        engine's prefill chunk) in whole blocks, short of its last token:
+        prompts that open with one long document and go on for less than a
+        chunk then keep ONE state, the document's, under one key. Kept
+        where that saves a later prompt at least a chunk of prefill behind
+        the ``shared`` this one was itself served from, no chain holds it
+        yet, and a row is free or the LRU snapshot that no sequence pins
+        and that is no longer than this one can go (with its block): a
+        short prompt never costs a long document its state."""
+        bt = self.block_tokens
+        at = (len(prompt) - 1) // max(chunk, 1) * max(chunk, 1) // bt * bt
+        if not self.snapshots or at - shared < max(chunk, 1):
+            return None
+        key = self._chain(prompt, at // bt, bt)[-1]
+        with self._lock:
+            if key in self._snap or seq not in self.tables:
+                return None
+            if not self._snap_free:
+                victim = next((k for k, b in self._prefix.items()
+                               if self._ref[b] == 1
+                               and self._snap_at.get(k, at + 1) <= at), None)
+                if victim is None:
+                    return None
+                self._free.append(self._drop(victim))
+            self._snap_pending[seq] = (self._snap_free.pop(), at)
+            return self._snap_pending[seq]
+
+    def snapshot_pending(self, seq: int) -> bool:
+        """Whether ``seq``'s admission is filling a snapshot row."""
+        with self._lock:
+            return seq in self._snap_pending
+
+    def _drop(self, key: str) -> int:  # jaxlint: guarded-by(_lock)
+        """Take chain entry ``key`` out of the pool, its snapshot with it;
+        returns the block, unreferenced. Caller holds the lock."""
+        bid = self._prefix.pop(key)
+        del self._block_key[bid]
+        self._ref[bid] = 0
+        self.evictions_total += 1
+        row = self._snap.pop(key, None)
+        if row is not None:
+            del self._snap_at[key]
+            self._snap_free.append(row)
+            self.snapshot_evictions += 1
+        return bid
 
     def _evict_one(self, exclude: Optional[list[int]] = None,
                    ) -> Optional[int]:  # jaxlint: guarded-by(_lock)
@@ -300,10 +392,7 @@ class BlockAllocator:
                        if self._ref[b] == 1 and b not in shielded), None)
         if victim is None:
             return None
-        bid = self._prefix.pop(victim)
-        del self._block_key[bid]
-        self._ref[bid] = 0
-        self.evictions_total += 1
+        bid = self._drop(victim)
         if self._tier is not None:
             self._spill(victim, bid)
         return bid
@@ -329,6 +418,8 @@ class BlockAllocator:
         nb = self.blocks_for(tokens + spec_tokens)
         nb_spec = nb - self.blocks_for(tokens)
         shared = self.match_prefix(prompt) if prompt else []
+        if self.snapshots and len(shared) > max(0, nb - 1):
+            shared = []     # a chain cut short would end on no snapshot
         shared = shared[: max(0, nb - 1)]  # at least one writable block
         with self._lock:
             assert seq not in self.tables, f"seq {seq} already has a table"
@@ -357,6 +448,10 @@ class BlockAllocator:
                 self._ref[bid] = 1
             self.tables[seq] = shared + fresh
             self.shared_blocks[seq] = len(shared)
+            if self.snapshots and shared:
+                self.restore_row[seq] = self._snap[
+                    self._block_key[shared[-1]]]
+                self.snapshots_restored += 1
             if nb_spec:
                 self.spec_blocks[seq] = nb_spec
             used = self.num_blocks - 1 - len(self._free) - self._reclaimable()
@@ -407,6 +502,10 @@ class BlockAllocator:
             table = self.tables.pop(seq, None)
             self.shared_blocks.pop(seq, None)
             self.spec_blocks.pop(seq, None)
+            self.restore_row.pop(seq, None)
+            row, _ = self._snap_pending.pop(seq, (None, 0))
+            if row is not None:     # an admission abandoned part-way
+                self._snap_free.append(row)
             if table is None:
                 return
             for bid in table:
@@ -526,6 +625,26 @@ class BlockAllocator:
                         f"block {bid}")
             if len(self._block_key) != len(self._prefix):
                 problems.append("block-key index size != prefix pool size")
+            # snapshots: every one hangs on a chain entry of the pool, and
+            # every row is free, a chain's or an admission's, exactly once
+            for key in self._snap:
+                if key not in self._prefix:
+                    problems.append(
+                        f"snapshot of chain {key[:12]}… outlived its block")
+            rows = (self._snap_free + list(self._snap.values())
+                    + [row for row, _ in self._snap_pending.values()])
+            if set(self._snap_at) != set(self._snap):
+                problems.append("snapshot lengths and rows disagree on "
+                                "which chains hold one")
+            if sorted(rows) != list(range(self.snapshots)):
+                problems.append(
+                    f"snapshot rows {sorted(rows)} are not the "
+                    f"{self.snapshots} rows once each")
+            for seq, row in self.restore_row.items():
+                if seq not in self.tables or not self.shared_blocks.get(seq):
+                    problems.append(
+                        f"seq {seq} restores snapshot row {row} behind no "
+                        "shared prefix")
             if self._tier is not None:
                 # tier residency: a chain lives in the HBM pool XOR the
                 # host tier — double residency means a reload forgot to

@@ -305,8 +305,11 @@ class ModelRunner:
                 "packed pool scatter only exists for block pools); use "
                 "int8 for contiguous caches")
         if self.paged:
+            # (a model that selects blocks of the pool says how large one
+            # is: ``LlamaConfig.select_blocks``)
             self.block_tokens = max(8, int(
-                kv_block_tokens or pgd.block_tokens_default()))
+                kv_block_tokens or (cfg.select_blocks or (0,))[0]
+                or pgd.block_tokens_default()))
             self.max_blocks = -(-self.max_ctx // self.block_tokens)
             self.ctx_pad = self.max_blocks * self.block_tokens
             # default pool = the contiguous layout's HBM footprint (every
@@ -318,9 +321,14 @@ class ModelRunner:
             default_blocks = max(
                 self.max_blocks,
                 int(num_slots * self.max_blocks * self.kv_overcommit)) + 1
+            # a model with recurrent state shares a prefix where the state
+            # at its end was kept (engine.paged: a snapshot a registered
+            # prompt): rows for an eighth of the slots' own state, at
+            # least two, sized here from what one costs and by no option
             self.allocator = pgd.BlockAllocator(
                 int(kv_num_blocks or default_blocks), self.block_tokens,
-                self.max_blocks)
+                self.max_blocks,
+                snapshots=max(2, num_slots // 8) if self.recurrent else 0)
             self.prefill_chunk = max(
                 self.block_tokens, int(prefill_chunk or PREFILL_CHUNK))
             if self.latent:
@@ -485,6 +493,15 @@ class ModelRunner:
         self._release_slot = obs_compile.watch(
             jax.jit(self._release_slot_fn, donate_argnums=(0, 1)),
             "release_slot")
+        # a recurrent family's state kept at a prompt's boundary and laid
+        # back in front of a later prompt's tail
+        self._take_snapshot = obs_compile.watch(
+            jax.jit(self._move_state_fn, donate_argnums=(0,)),
+            "take_snapshot")
+        self._restore_snapshot = obs_compile.watch(jax.jit(
+            lambda state, snaps, slot, row: dataclasses.replace(
+                state, rec=self._move_state_fn(state.rec, snaps, slot, row)),
+            donate_argnums=(0,)), "restore_snapshot")
         # programs the admission path has launched (arming updates and
         # prefill dispatches): Scheduler.metrics() sets it against the
         # admissions made
@@ -510,7 +527,7 @@ class ModelRunner:
         if self.allocator is not None:
             self.allocator = pgd.BlockAllocator(
                 self.allocator.num_blocks, self.block_tokens,
-                self.max_blocks)
+                self.max_blocks, snapshots=self.allocator.snapshots)
             # disk prompt-cache rows loaded into a slot's fresh blocks
             # (the only slot-resident reuse that survives release)
             self._loaded_rows: dict[int, int] = {}
@@ -531,6 +548,12 @@ class ModelRunner:
         if self.mesh is not None:
             state = self._place_state(state)
         self.state = state
+        # the snapshot rows beside it (``_snap_axes``: the per-slot arrays
+        # of ``rec``, the allocator's rows where their slots are)
+        self.snaps = None
+        if self.allocator is not None and self.allocator.snapshots:
+            rows = self._init_rec(self.allocator.snapshots)
+            self.snaps = {name: rows[name] for name in self._snap_axes()}
         self._free_slots = list(range(self.num_slots))
         # host mirror of which slots are serving: admit()/release() are the
         # only transitions, so liveness queries never touch the device
@@ -543,6 +566,42 @@ class ModelRunner:
         (``_prefill_paged_fn``). None for a model of no family."""
         fam = mdl.family_module(self.cfg)
         return None if fam is None else fam.init_rec(self.cfg, num_slots)
+
+    def _snap_axes(self) -> dict:
+        """{name: slot axis} of the arrays of ``rec`` that hold a row a
+        slot: what a snapshot copies. Read off the family's ``init_rec``
+        (the axis that grows with the slots), so every recurrent family is
+        served by the one door; an entry that is not per slot (a routed
+        count) is none."""
+        fam = mdl.family_module(self.cfg)
+        one, two = (jax.eval_shape(
+            lambda n=n: fam.init_rec(self.cfg, n)) for n in (1, 2))
+        axes = {name: next((i for i, (a, b) in enumerate(
+            zip(one[name].shape, two[name].shape)) if a != b), None)
+            for name in one}
+        return {name: ax for name, ax in axes.items() if ax is not None}
+
+    def _move_state_fn(self, into, out_of, to, at):
+        """Row ``at`` of ``out_of``'s per-slot arrays laid over row ``to``
+        of ``into``'s: a snapshot taken (slot -> row) or restored (row ->
+        slot), a device copy of one slot's state."""
+        return {**into, **{
+            name: jax.lax.dynamic_update_slice_in_dim(
+                into[name], jax.lax.dynamic_slice_in_dim(
+                    out_of[name], at, 1, ax), to, ax)
+            for name, ax in self._snap_axes().items()}}
+
+    def take_snapshot(self, slot: int, row: int) -> None:
+        """``slot``'s state as it stands (behind every chunk dispatched so
+        far) into snapshot row ``row``."""
+        self.snaps = self._take_snapshot(
+            self.snaps, self.state.rec, np.int32(row), np.int32(slot))
+
+    def restore_snapshot(self, slot: int, row: int) -> None:
+        """Snapshot row ``row`` into ``slot``'s state, in front of the
+        chunk that goes on from it."""
+        self.state = self._restore_snapshot(
+            self.state, self.snaps, np.int32(slot), np.int32(row))
 
     def _place_state(self, state: DecodeState) -> DecodeState:
         """Shard a fresh DecodeState over the mesh (the construction-time
@@ -1527,6 +1586,7 @@ class ModelRunner:
         the pool cannot cover the reservation (the scheduler keeps the
         request queued)."""
         assert self.paged, "begin_admit requires a paged runner"
+        snapshot = None
         if not prompt:
             prompt = [0]
         n = len(prompt)
@@ -1569,14 +1629,17 @@ class ModelRunner:
                 self.allocator.release(slot)
             self._loaded_rows.pop(slot, None)
             # blocks of a prefix are shared by the prompt's tokens; a model
-            # with recurrent state shares none (``reusable_prefix``)
+            # with recurrent state shares a chain that ends on a snapshot
+            # of that state (the allocator's ``match_prefix``)
             shared = self.allocator.allocate(
-                slot, reserve,
-                prompt=None if mm or self.recurrent else prompt,
+                slot, reserve, prompt=None if mm else prompt,
                 spec_tokens=spec_tokens)
             if shared is None:
                 return None
             lcp = shared
+            if self.recurrent and not mm:
+                snapshot = self.allocator.begin_snapshot(
+                    slot, prompt, shared, self.prefill_chunk)
             self.last_prefill_path = ("paged_mm" if mm
                                       else "paged_shared" if shared
                                       else "paged")
@@ -1595,7 +1658,9 @@ class ModelRunner:
         return PagedAdmission(self, slot, list(prompt), lcp,
                               self._arm_args(slot, **sampling),
                               mm_embeds=mm_embeds,
-                              mm_positions=mm_positions, sp=use_sp)
+                              mm_positions=mm_positions, sp=use_sp,
+                              restore=self.allocator.restore_row.get(slot),
+                              snapshot=snapshot)
 
     def _install_table_row(self, slot: int) -> None:
         self.block_tables = self.block_tables.at[slot].set(
@@ -1608,7 +1673,10 @@ class ModelRunner:
         prompt's full blocks to the prefix pool (their contents are
         dispatched by now; token-keyed sharing is meaningless for
         multimodal prompts), mark the slot live."""
-        if not mm and not self.recurrent:
+        # (a model with recurrent state: a prompt whose state was kept at
+        # its boundary; blocks that end on no snapshot serve nobody)
+        if not mm and (not self.recurrent
+                       or self.allocator.snapshot_pending(slot)):
             self.allocator.register_prefix(slot, prompt)
         self._loaded_rows.pop(slot, None)
         self._active_slots.add(slot)
@@ -1626,8 +1694,9 @@ class ModelRunner:
         validity frontier (disk prompt-cache hits score their own row count
         instead of the slot's current position)."""
         if not resident or not prompt or self.recurrent:
-            # prefix reuse, refused for recurrent state: the keys of a
-            # prefix can be read again, the state after it was not kept
+            # a SLOT's resident rows, refused for recurrent state: the keys
+            # of a prefix can be read again, the state after it was not
+            # kept (the pool's shared prefixes keep it: engine.paged)
             return 0
         if valid_n is None:
             valid_n = (self._loaded_rows.get(slot, 0) if self.paged
@@ -2082,8 +2151,18 @@ class PagedAdmission:
 
     def __init__(self, runner: ModelRunner, slot: int, prompt: list[int],
                  start: int, arm_args: tuple, mm_embeds=None,
-                 mm_positions=None, sp: bool = False):
+                 mm_positions=None, sp: bool = False,
+                 restore: Optional[int] = None,
+                 snapshot: Optional[tuple[int, int]] = None):
         self.runner = runner
+        # a model with recurrent state: the snapshot row the shared prefix's
+        # state is restored from in front of the first chunk, and (row,
+        # position) of the snapshot this prompt leaves, where it leaves one
+        # (``BlockAllocator.begin_snapshot``: its last whole prefill chunk):
+        # a chunk ends at that position, as chunks behind a prefix of whole
+        # chunks do anyway, and the state behind it is copied
+        self.restore = restore
+        self.snapshot = snapshot
         self.slot = slot
         self.prompt = prompt
         self.pos = start                     # next position to prefill
@@ -2112,8 +2191,17 @@ class PagedAdmission:
             return 0
         if self.mm or self.sp:
             return 1
-        return max(1, -(-(len(self.prompt) - self.pos)
-                        // self.runner.prefill_chunk))
+        chunk = self.runner.prefill_chunk
+        cut = self._cut()
+        if cut:     # the chunks up to the snapshot's position, then the rest
+            return -(-cut // chunk) + -(
+                -(len(self.prompt) - self.pos - cut) // chunk)
+        return max(1, -(-(len(self.prompt) - self.pos) // chunk))
+
+    def _cut(self) -> int:
+        """Tokens from ``pos`` to the position a snapshot is taken at, while
+        that lies ahead (0: no snapshot, or behind)."""
+        return max(0, self.snapshot[1] - self.pos) if self.snapshot else 0
 
     @property
     def ride_bucket(self) -> Optional[int]:
@@ -2125,7 +2213,7 @@ class PagedAdmission:
         r = self.runner
         rem = len(self.prompt) - self.pos
         if (self.done or self.mm or self.sp or not r.rides
-                or rem > r.prefill_chunk):
+                or rem > r.prefill_chunk or self._cut()):
             return None
         bucket = r.bucket_for(rem)
         return bucket if bucket <= RIDE_ROWS else None
@@ -2149,7 +2237,17 @@ class PagedAdmission:
         table_row = np.asarray(r.allocator.table_row(self.slot), np.int32)
         rem = n - self.pos
         offset = self.pos
-        last = self.sp or self.mm or rem <= r.prefill_chunk
+        # a chunk ends where a snapshot is taken (never the prompt's end)
+        limit = min(r.prefill_chunk, self._cut() or r.prefill_chunk)
+        last = self.sp or self.mm or rem <= limit
+        chunk_state = 0
+        if r.recurrent:
+            # 1: from zero state; 2: from the slot's own; 3: from a
+            # snapshot, laid into the slot's rows in front of this chunk
+            chunk_state = 1 if offset == 0 else 2
+            if self.restore is not None:
+                r.restore_snapshot(self.slot, self.restore)
+                self.restore, chunk_state = None, 3
         if last:
             # before the chunk that samples with them; not earlier, for a
             # decode step between two chunks must still find the slot's
@@ -2181,7 +2279,7 @@ class PagedAdmission:
             take, ctx = n, r.chunk_span(0, bucket)
             self.pos = n
         else:
-            take = min(rem, r.prefill_chunk)
+            take = min(rem, limit)
             bucket = r.bucket_for(take)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :take] = self.prompt[self.pos:self.pos + take]
@@ -2203,8 +2301,13 @@ class PagedAdmission:
                 )
             ctx = r.chunk_span(offset, bucket)
             self.pos += take
+            if self.snapshot and self.pos == self.snapshot[1]:
+                r.take_snapshot(self.slot, self.snapshot[0])
+                self.snapshot = None
         self.last_chunk = {"chunk_tokens": take, "chunk_bucket": bucket,
                            "chunk_offset": offset, "chunk_ctx": ctx,
+                           **({"chunk_state": chunk_state}
+                              if chunk_state else {}),
                            "chunk_parts": (1 if self.sp else r.chunk_parts(
                                bucket, take, last))}
         r.admit_programs += 1

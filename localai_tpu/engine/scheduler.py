@@ -454,6 +454,11 @@ class Scheduler:
         self._index_layers = int(getattr(cfg, "full_layers", 0) or 0)
         self.total_dsa_rows = ({"scored": 0, "attended": 0}
                                if self._index_topk else None)
+        # a model whose streams attend a selection of their blocks once
+        # their context has a dense length (``LlamaConfig.select_blocks``):
+        # the first position whose row selects; 0 for every other
+        self._select_from = int((getattr(cfg, "select_blocks", None)
+                                 or (0, 0, 0))[2])
         self.total_experts_touched = 0
         self.total_local_assignments = 0
         self.total_state_slots_armed = 0
@@ -719,7 +724,8 @@ class Scheduler:
                 "moe_assignments": self.total_local_assignments}
                if self._routed_model else {}),
             **({"state_slots_armed": self.total_state_slots_armed,
-                "state_bytes": self.runner.state_bytes}
+                "state_bytes": self.runner.state_bytes,
+                **self._snapshot_counts()}
                if self._recurrent else {}),
             **({"kv_window_dead_tokens": window_dead}
                if self._window else {}),
@@ -889,7 +895,7 @@ class Scheduler:
                 "decompressed" if chunk else "absorbed"] += 1
         if k:
             live = len(self._slots)
-            cached = windowed = selected = 0
+            cached = windowed = selected = sparse = 0
             for c in self._slots.values():
                 n = c.handle.prompt_tokens + c.generated
                 for d in inflight:
@@ -906,9 +912,15 @@ class Scheduler:
                 if self._index_topk:
                     selected += sum(min(n + j, self._index_topk)
                                     for j in range(k))
+                if self._select_from:
+                    # step j writes position n + j
+                    sparse += sum(n + j >= self._select_from
+                                  for j in range(k))
             held["live_slots"] = live
             held["attended_tokens"] = k * cached + live * (k * (k - 1) // 2)
             held["window_tokens"] = windowed
+            if self._select_from:
+                held["sparse_rows"] = sparse
             if self.total_dsa_rows is not None:
                 held["selected_tokens"] = selected
                 self.total_dsa_rows["scored"] += (
@@ -916,6 +928,17 @@ class Scheduler:
                 self.total_dsa_rows["attended"] += (
                     self._index_layers * selected)
         return held
+
+    def _snapshot_counts(self) -> dict:
+        """A recurrent model's state snapshots (engine.paged): taken at a
+        registered prompt's boundary, restored in front of an admission's
+        tail, evicted with their chain's last block."""
+        alloc = getattr(self.runner, "allocator", None)
+        if alloc is None:
+            return {}
+        return {"state_snapshots_taken": alloc.snapshots_taken,
+                "state_snapshots_restored": alloc.snapshots_restored,
+                "state_snapshot_evictions": alloc.snapshot_evictions}
 
     def _flight_record(self, program: str, steps: int, dt: float,
                        fresh: bool, spec_proposed: int = 0,

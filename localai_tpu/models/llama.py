@@ -82,6 +82,12 @@ class LlamaConfig:
     # pairs, the window None where the kind sees every key (models.afmoe).
     # None: every layer attends alike, under ``sliding_window``
     attn_kinds: ClassVar[Optional[tuple]] = None
+    # a family whose attention layers attend a SELECTION of the pool's
+    # blocks, one a (stream, K/V head) (models.minicpm_sala): (tokens a
+    # block, the most blocks a row attends, the first position whose row
+    # selects). The engine serves it from the block pool under compacted
+    # block tables (engine.kvcache ``select_decode``). None: every block
+    select_blocks: ClassVar[Optional[tuple]] = None
     # a family whose attention caches ONE latent row a token and no K/V a
     # head (models.deepseek): the engine serves it from the latent block
     # pool (engine.kvcache ``LatentLayout``)
@@ -254,6 +260,7 @@ FAMILIES = {
     "dots3_note": "dots3",
     "falcon_h1": "falcon_h1",
     "lfm2_moe": "lfm2",
+    "minicpm_sala": "minicpm_sala",
 }
 
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 from functools import partial
 from typing import Any, Optional
 
@@ -386,7 +387,11 @@ _LAYER_AXES = {
     "wq": 1, "wk": 1, "wv": 1, "wo": 1,
     "w_gate": 1, "w_up": 1, "w_down": 1,
     "ssm_in": 1, "ssm_out": 1,      # a state-space mixer's two projections
+    "w_ogate": 1,                   # an output gate the width of wq
 }
+# a layer that is no row of the stack: its leaves lie at the top level under
+# a prefix of their own, no leading axis (models.minicpm_sala: ``sa<n>_``)
+_LONE_LAYER = re.compile(r"^sa\d+_(.+)$")
 
 
 def quantize_plan(path: tuple[str, ...], ndim: int,
@@ -413,6 +418,9 @@ def quantize_plan(path: tuple[str, ...], ndim: int,
         return 1, ("int8" if mode == "int4" else mode)
     if path == ("lm_head",):
         return 0, mode
+    lone = _LONE_LAYER.match(path[0]) if len(path) == 1 else None
+    if lone and lone.group(1) in _LAYER_AXES and ndim == 2:
+        return _LAYER_AXES[lone.group(1)] - 1, mode
     if len(path) == 2 and path[0] == "layers" and path[1] in _LAYER_AXES:
         if ndim == 4:
             return 2, "int8"

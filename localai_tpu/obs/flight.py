@@ -47,7 +47,8 @@ from localai_tpu.obs.trace import mono_to_wall
 # the order of record()'s keywords
 WORK_COLUMNS = ("launch", "live_slots", "attended_tokens", "window_tokens",
                 "selected_tokens", "chunk_tokens", "chunk_bucket", "chunk_offset", "chunk_ctx",
-                "chunk_parts", "experts_touched", "local_assignments")
+                "chunk_parts", "experts_touched", "local_assignments",
+                "sparse_rows", "chunk_state")
 
 
 # the measured parts of what ``gap_ms`` holds (obs.anatomy.PARTS), and the
@@ -243,6 +244,7 @@ class FlightRecorder:
                chunk_bucket: int = 0, chunk_offset: int = 0,
                chunk_ctx: int = 0, chunk_parts: int = 0,
                experts_touched: int = 0, local_assignments: int = 0,
+               sparse_rows: int = 0, chunk_state: int = 0,
                process_ms: float = 0.0, book_ms: float = 0.0,
                free_ms: float = 0.0,
                span_ms: float = 0.0, wait_ms: float = 0.0,
@@ -288,7 +290,13 @@ class FlightRecorder:
         ``chunk_ctx`` (positions its attend spans) and ``chunk_parts`` (1: the
         program computes every row of its bucket behind the attend; 2 to 4:
         the quarters of the bucket a prompt's last chunk ran there, on one
-        chip as on a mesh, ``ModelRunner.chunk_rows``). 0 wherever a row's kind
+        chip as on a mesh, ``ModelRunner.chunk_rows``). A decode row of a
+        model whose streams attend a SELECTION of their blocks past a dense
+        length holds ``sparse_rows`` (of its (live slot, step) pairs, those
+        at or past that length); a prefill row of a model with recurrent
+        state holds ``chunk_state`` (what the chunk went on from: 1 zero
+        state, 2 the slot's own, 3 a snapshot restored in front of it: an
+        admission's first chunk reads 1 or 3). 0 wherever a row's kind
         has no such count.
 
         ``experts_touched`` and ``local_assignments`` are the two counts
@@ -347,7 +355,8 @@ class FlightRecorder:
                              window_tokens, selected_tokens, chunk_tokens,
                              chunk_bucket,
                              chunk_offset, chunk_ctx, chunk_parts,
-                             experts_touched, local_assignments)
+                             experts_touched, local_assignments,
+                             sparse_rows, chunk_state)
             self._parts[i] = (process_ms, book_ms, free_ms)
             self._clock[i] = (span_ms, wait_ms, idle_ms, cpu_ms,
                               np.nan if runq_ms is None else runq_ms,

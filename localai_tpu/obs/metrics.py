@@ -290,6 +290,20 @@ class Registry:
             "Slots armed with a zeroed recurrent state: one an admission of "
             "a model whose layers carry state that is not keys",
         )
+        self.state_snapshots_taken = Counter(
+            "localai_state_snapshots_taken_total",
+            "Recurrent state kept behind a registered prompt's last whole "
+            "prefill chunk: what lets a later prompt share the prefix",
+        )
+        self.state_snapshots_restored = Counter(
+            "localai_state_snapshots_restored_total",
+            "Admissions whose shared prefix's recurrent state was restored "
+            "from a snapshot in front of their tail's first chunk",
+        )
+        self.state_snapshot_evictions = Counter(
+            "localai_state_snapshot_evictions_total",
+            "State snapshots dropped with their chain's last block (LRU)",
+        )
         self.prompt_cache_hits = Counter(
             "localai_prompt_cache_hits_total",
             "Disk prompt-KV cache lookups that returned a usable prefix",
@@ -792,6 +806,10 @@ def update_engine_gauges(name: str, m: dict,
         reg.moe_assignments.set_total(m["moe_assignments"], model=name)
     if "state_slots_armed" in m:
         reg.state_slots_armed.set_total(m["state_slots_armed"], model=name)
+    for key in ("state_snapshots_taken", "state_snapshots_restored",
+                "state_snapshot_evictions"):
+        if key in m:
+            getattr(reg, key).set_total(m[key], model=name)
     for path, n in (m.get("mla_attends") or {}).items():
         reg.mla_attends.set_total(n, model=name, path=path)
     for kind, n in (m.get("dsa_rows") or {}).items():

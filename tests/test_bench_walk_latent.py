@@ -7,7 +7,9 @@ harness and the control that fails, and for the state-space hybrid
 and its failing control run with ``benchmark/tests/``, and
 tests/test_falcon_h1.py serves the family in tier-1) and the same cut of the
 convolution hybrid's (``test_lfm2_family.py``; tests/test_lfm2.py serves that
-family in tier-1)."""
+family in tier-1) and the Lightning / block-sparse hybrid's
+(``test_minicpm_sala_family.py``: all of its cases are server-free;
+tests/test_minicpm_sala.py serves the family in tier-1)."""
 
 import pytest
 
@@ -17,6 +19,7 @@ _ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
 _d3 = _load("test_dots3_family", conftest=_conftest, test_walk=_walk)
 _fh = _load("test_falcon_h1_family", conftest=_conftest, test_walk=_walk)
 _lf = _load("test_lfm2_family", conftest=_conftest, test_walk=_walk)
+_ms = _load("test_minicpm_sala_family", conftest=_conftest, test_walk=_walk)
 
 # the fixtures those cases ask for
 bench_copy = _conftest.bench_copy
@@ -137,3 +140,20 @@ def test_the_convolution_cell_reports_what_the_issue_names(tmp_path,
 
 test_the_convolution_cells_readers_read_the_ring_and_the_scopes = (
     _lf.test_the_convolution_cells_readers_read_the_ring_and_the_scopes)
+
+# PR 62's file: the Lightning / block-sparse hybrid's hand arithmetic, the
+# catalog row in the file, the selection against a slower writing of itself,
+# Lightning rows beside lone sparse layers, its cell, its five readers
+test_the_hand_arithmetic_of_the_lightning_hybrids_published_keys = (
+    _ms.test_the_hand_arithmetic_of_the_lightning_hybrids_published_keys)
+test_every_published_number_of_the_lightning_hybrids_catalog_row_is_in_the_file = (
+    _ms
+    .test_every_published_number_of_the_lightning_hybrids_catalog_row_is_in_the_file)
+test_the_selection_agrees_with_a_slower_writing_of_itself = (
+    _ms.test_the_selection_agrees_with_a_slower_writing_of_itself)
+test_the_served_pytree_is_lightning_rows_beside_lone_sparse_layers = (
+    _ms.test_the_served_pytree_is_lightning_rows_beside_lone_sparse_layers)
+test_the_lightning_cell_reports_what_the_issue_names = (
+    _ms.test_the_lightning_cell_reports_what_the_issue_names)
+test_the_five_sala_readers_read_the_ring_and_the_scopes = (
+    _ms.test_the_five_readers_read_the_ring_and_the_scopes)

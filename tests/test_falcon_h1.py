@@ -562,17 +562,23 @@ def test_what_recurrent_state_refuses_stays_refused(params32, what, kw):
         runner_for(config(), params32, **kw)
 
 
-def test_speculation_and_prefix_reuse_are_refused(params32):
+def test_speculation_and_a_slots_resident_rows_are_refused(params32):
     r = runner_for(config(), params32)
     with pytest.raises(ValueError, match="^speculative decoding is not"):
         r.verify_async(np.zeros((SLOTS, 2), np.int32))
-    # the same prompt twice: no block of the first is shared with the second,
-    # and a resident record is no reason to skip a token
+    # the same prompt twice: the first's whole chunk (a block of 8) is shared
+    # with the second BECAUSE the state behind it was kept (PR 62,
+    # engine.paged: a snapshot a registered prompt, restored in front of the
+    # tail; no line of this family's), and the token is the same; a SLOT's
+    # resident record is still no reason to skip a token
     first = r.admit(0, PROMPT, temperature=0.0)
+    assert r.allocator.snapshots_taken == 1
     assert r.admit(1, PROMPT, temperature=0.0,
                    resident=list(PROMPT)) == first
-    assert (r.last_prefix_reused, r.total_prefix_reused) == (0, 0)
-    assert r.allocator.shared_tokens_total == 0
+    assert (r.last_prefix_reused, r.total_prefix_reused) == (8, 8)
+    assert r.allocator.snapshots_restored == 1
+    assert r.allocator.check_invariants() == []
+    assert r.reusable_prefix(2, list(PROMPT), list(PROMPT), valid_n=11) == 0
     # the prompt cache's import: rows of keys without the state behind them
     assert r.load_prefix(2, r.export_prefix(0, 8), 8) is False
 

@@ -21,6 +21,7 @@ from test_deepseek import HF as AXK1
 from test_dots3 import HF as DOTS3
 from test_falcon_h1 import HF as FALCON_H1
 from test_lfm2 import HF as LFM2
+from test_minicpm_sala import HF as MINICPM_SALA
 from test_qwen3_next import HF as QWEN3_NEXT
 
 from localai_tpu.engine.runner import ModelRunner
@@ -29,7 +30,8 @@ from localai_tpu.models.llama import LlamaConfig
 from localai_tpu.models.registry import synthetic_params
 
 HF = {"qwen3_next": QWEN3_NEXT, "afmoe": AFMOE, "axk1": AXK1,
-      "dots3_note": DOTS3, "falcon_h1": FALCON_H1, "lfm2_moe": LFM2}
+      "dots3_note": DOTS3, "falcon_h1": FALCON_H1, "lfm2_moe": LFM2,
+      "minicpm_sala": MINICPM_SALA}
 CONTRACT = ("CONFIG", "param_shapes", "init_leaf", "checkpoint_leaves",
             "init_rec", "forward", "UNSERVED", "WEIGHTS", "WHY")
 KEYWORDS = ("rec", "valid", "slot", "fresh", "kernels")
@@ -61,7 +63,9 @@ def runner_for(cfg, **kw):
                          is_leaf=lambda x: isinstance(x, tuple))
     return ModelRunner(cfg, zeros, **{
         "num_slots": SLOTS, "max_ctx": 64, "paged": True,
-        "kv_block_tokens": 32, "attn_impl": "xla", "kv_dtype": "float32",
+        # (a family that selects blocks of the pool says how large one is)
+        "kv_block_tokens": (cfg.select_blocks or (32,))[0],
+        "attn_impl": "xla", "kv_dtype": "float32",
         **kw})
 
 

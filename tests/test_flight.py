@@ -48,7 +48,7 @@ def test_total_tokens_survives_wraparound():
 WORK = {"launch": 7, "live_slots": 3, "attended_tokens": 1234,
         "window_tokens": 999, "selected_tokens": 777, "chunk_tokens": 88, "chunk_bucket": 128, "chunk_offset": 512,
         "chunk_ctx": 4096, "chunk_parts": 3, "experts_touched": 29,
-        "local_assignments": 41}
+        "local_assignments": 41, "sparse_rows": 17, "chunk_state": 3}
 
 
 @pytest.mark.parametrize("column", sorted(WORK))

@@ -77,6 +77,16 @@ What each row has seen:
   ``RIDES`` are never passed the keyword and hold no such program
   (``rides`` is False, held below), the 7B's and Ouro's ``ride`` texts are
   PR 59's.
+- ``minicpm_sala`` (PR 62, this tree: the family came through the door with
+  a selecting attend in ``PagedLayout.decode`` / ``.chunk`` behind
+  ``cfg.select_blocks``, a snapshot of recurrent state a registered prompt
+  in engine.paged and the runner's admission, two columns of the flight
+  ring and ``w_ogate`` / the lone layers' names in ``models.quant``'s
+  plan): NO row retaken: the layouts' other branches trace what they
+  traced, the snapshot's copies are programs of their own and ``DecodeState``
+  holds what it held. The new family's two rows are taken from this PR's own
+  tree, heads of 128 (what the compiled kernels take): its chunk holds no
+  kernel, so the two rows' ``prefill`` texts are one text.
 """
 
 import functools
@@ -92,6 +102,7 @@ from test_deepseek import HF as AXK1
 from test_dots3 import HF as DOTS3
 from test_falcon_h1 import HF as FALCON_H1
 from test_lfm2 import HF as LFM2
+from test_minicpm_sala import HF as MINICPM_SALA
 from test_qwen3_next import HF as QWEN3_NEXT
 
 from localai_tpu.engine.runner import ModelRunner
@@ -103,6 +114,9 @@ WIDE = {"head_dim": 128, "moe_intermediate_size": 128}
 # ... and a mixer head of 128 with a state of 128
 WIDE_SSM = {"head_dim": 128, "mamba_d_head": 128, "mamba_d_ssm": 512,
             "mamba_d_state": 128}
+# ... and a Lightning head of 128 beside an attention head of 128
+WIDE_LIGHTNING = {"head_dim": 128, "lightning_head_dim": 128,
+                  "hidden_size": 128}
 
 
 def cell_runner(name: str) -> ModelRunner:
@@ -269,6 +283,20 @@ TAKEN = [
             "0572c404742d7340a0aad104edae774b8f6caeb8b004aa19967d3bfb35d66acc",
         "ride":
             "5c4f4aebea0a1eb2b7ff5f9547ba8a263eae5aebb6c127553a545328f868301b"}),
+    family({**MINICPM_SALA, **WIDE_LIGHTNING}, 16, "pallas_interpret", {
+        "decode":
+            "760343ed7db58267edc796d9abb52bb80ffd7b0fd7fc99a4efc0dc43876bd3f1",
+        "prefill_1":
+            "4310671fe5e13920280e9991b4edb573c2e75ae46775fd0e2b2a77571ca471c3",
+        "prefill_0":
+            "cfa6079c1431318847d1fa804ef2fa707cbc57b809581547d04c76870f0f57c4"}),
+    family({**MINICPM_SALA, **WIDE_LIGHTNING}, 16, "xla", {
+        "decode":
+            "f1eb54131f9a574b813c392b770a0efa20e6e1a515e9323346e0f1c73ded562c",
+        "prefill_1":
+            "4310671fe5e13920280e9991b4edb573c2e75ae46775fd0e2b2a77571ca471c3",
+        "prefill_0":
+            "cfa6079c1431318847d1fa804ef2fa707cbc57b809581547d04c76870f0f57c4"}),
 ]
 
 
