@@ -11,20 +11,32 @@ accepted in config, mapped to plain temperature sampling).
 
 Design notes (TPU):
   * no op sorts the vocabulary: the K = 256 candidates (covers llama.cpp's
-    default top_k=40 and caps tail work) are found in two exact stages.
-    A row's K largest lie in the K chunks (TOPK_CHUNK consecutive logits)
-    with the largest maxima, since a chunk that holds a winner has a
-    maximum no smaller than the K-th value and at most K chunks can; so one
-    pass takes every chunk's maximum, a sort of the V / C maxima picks K
-    chunks, and their K x C elements are ranked in TOPK_GROUPS groups side
-    by side, then the groups' K each once more (the TPU unrolls a sort into
-    its code: narrow rows sort in less time and less code than one wide
-    one). Every sort orders by (value, index) in the total order XLA's
-    top-k compares floats by, so the result is ``lax.top_k(logits, K)``'s
-    values and indices to the bit, ties, -0.0 and rows that are mostly
-    -inf (a grammar's mask) included: among equal maxima stage one prefers
-    the lower chunk, which is where the lowest tied indices lie, and
-    padding (-inf at the highest indices) stands behind every real entry.
+    default top_k=40 and caps tail work) are found in exact stages, all by
+    one lemma that holds for any partition of a row: a row's K largest lie
+    in the K parts with the largest maxima, since a part that holds a winner
+    has a maximum no smaller than the K-th value and at most K parts can.
+    Two stages over chunks (TOPK_CHUNK consecutive logits): one pass takes
+    every chunk's maximum, a sort of the V / C maxima picks K chunks, and
+    their K x C elements are ranked in TOPK_GROUPS groups side by side, then
+    the groups' K each once more (the TPU unrolls a sort into its code:
+    narrow rows sort in less time and less code than one wide one). Over a
+    row of more than TILE_FROM chunks a third stage stands in FRONT of the
+    two: the maximum of every tile (TOPK_TILE = 128 consecutive logits, a
+    whole lane row: a dense reduction where a chunk fills an eighth of the
+    lanes), the K tiles with the largest maxima by the same sort, and those
+    tiles' K x 128 logits gathered as 512-byte rows IN THE ROW'S ORDER
+    (the chosen tile numbers sorted ascending), over which the two stages
+    run as they run over a vocabulary of 32768; a candidate's place in that
+    block gives its index back through its tile's number. The depth follows
+    the width the program sees (a shard under a mesh decides by ITS width)
+    and nothing else. Every sort orders by (value, index) in the total
+    order XLA's top-k compares floats by, so the result is
+    ``lax.top_k(logits, K)``'s values and indices to the bit, ties, -0.0
+    and rows that are mostly -inf (a grammar's mask) included: among equal
+    maxima a stage prefers the lower part, which is where the lowest tied
+    indices lie (which is why the gathered block keeps the row's order:
+    position stands for index in the stages behind it), and padding (-inf
+    at the highest indices) stands behind every real entry.
     Where V / C <= K (vocabularies of a few thousand) the stages select
     nothing and one ``lax.top_k`` is the program. Under a mesh that shards
     the vocabulary each chip takes the stages over its own shard (padding
@@ -44,6 +56,7 @@ Design notes (TPU):
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +72,16 @@ MAX_TOPK = 256  # candidate cap; llama.cpp default top_k=40
 TOPK_CHUNK = 16
 # groups the chunks' elements are ranked in: 1, 2, 4 and 8 were timed there
 TOPK_GROUPS = 4
+# the front stage's tile, a whole row of the chip's 128 lanes (512 bytes, what
+# the gather moves at a time); 256 and 512 were timed and gather too much
+TOPK_TILE = 128
+# a row of more chunks than this takes the front stage: ``sample`` alone was
+# timed on the chip without | with it at the cells' (slots, V): 1593 | 644 us
+# at (64, 261120), 715 | 480 at (64, 131072), 398 | 214 at (32, 73448), where
+# the sort of the chunks' maxima is 8192 wide or more; ties at (96, 65536)
+# (602 | 590: 4096 maxima sort as they are) and (8, 49152) (66 | 68); no
+# stage at 32768 and under (a row has no more tiles than the K it would take)
+TILE_FROM = 4096
 
 
 @jax.tree_util.register_dataclass
@@ -183,21 +206,16 @@ def _largest(keys: jax.Array, idx: jax.Array, k: int
     return ~inv[:, :k], idx[:, :k]
 
 
-def _two_stage(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """(keys, indices) of a row's k largest: the k chunks with the largest
-    maxima, then the k largest of those chunks' elements (ranked in
-    TOPK_GROUPS groups side by side, whose k each are ranked once more)."""
-    S, V = logits.shape
+def _chunk_stages(keys: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """(keys, positions) of the k largest of each row of [S, n x TOPK_CHUNK]
+    keys: the k chunks with the largest maxima, then the k largest of those
+    chunks' elements (ranked in TOPK_GROUPS groups side by side, whose k
+    each are ranked once more)."""
+    S = keys.shape[0]
     C, G = TOPK_CHUNK, TOPK_GROUPS
-    n = -(-V // C)
-    if n <= k:      # a narrow shard: every chunk would be taken
-        return _largest(_ordered(logits),
-                        jax.lax.broadcasted_iota(jnp.int32, (S, V), 1), k)
+    n = keys.shape[1] // C
     with jax.named_scope("chunk_max"):
-        if n * C != V:
-            logits = jnp.pad(logits, ((0, 0), (0, n * C - V)),
-                             constant_values=-jnp.inf)
-        chunks = _ordered(logits).reshape(S, n, C)
+        chunks = keys.reshape(S, n, C)
         peaks = jnp.max(chunks, axis=-1)
     with jax.named_scope("topk"):
         _, cid = _largest(
@@ -208,6 +226,76 @@ def _two_stage(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
         keys, idx = _largest(cand.reshape(S * G, k * C // G),
                              where.reshape(S * G, k * C // G), k)
         return _largest(keys.reshape(S, G * k), idx.reshape(S, G * k), k)
+
+
+def _best_tiles(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The k tiles (TOPK_TILE consecutive logits) of each row with the
+    largest maxima, laid side by side in the order of the row:
+    ([S, k x TOPK_TILE] keys, [S, k] tile numbers, ascending)."""
+    S, V = logits.shape
+    T = TOPK_TILE
+    m = -(-V // T)
+    # the chip lays a 32-bit [S, V] out in tiles of 8 rows x 128 lanes: read
+    # as (row // 8, tile, row % 8) its 512-byte rows are one linear array,
+    # which the reduction and the gather both take as it lies (as
+    # [S, m, T] the compiler copies the whole block into that order first)
+    R = 8 if S % 8 == 0 else 1
+    with jax.named_scope("tile_max"):
+        if m * T != V:
+            logits = jnp.pad(logits, ((0, 0), (0, m * T - V)),
+                             constant_values=-jnp.inf)
+        # two passes, held apart: fused into the pass that writes the keys
+        # the reduction lays 8 maxima to a row of 128 lanes and that pass
+        # goes by its packing (491 us at [64, 261120] on the chip); alone,
+        # over 128 rows at a time, it fills the lanes (224 + 49 us)
+        rows = jax.lax.optimization_barrier(
+            _ordered(logits).reshape(S // R, R, m, T).transpose(
+                0, 2, 1, 3).reshape(S * m, T))
+        L = math.gcd(S * m, 128)
+        peaks = jax.lax.optimization_barrier(
+            jnp.max(rows.reshape(S * m // L, L, T), axis=-1))
+        peaks = peaks.reshape(S // R, m, R).transpose(0, 2, 1).reshape(S, m)
+    with jax.named_scope("tile_topk"):
+        _, tid = _largest(
+            peaks, jax.lax.broadcasted_iota(jnp.int32, (S, m), 1), k)
+        # back into the row's order, so that a position in the block still
+        # orders equal values as their indices do
+        tid = jnp.sort(tid, axis=1)
+        s = jnp.arange(S, dtype=jnp.int32)[:, None]
+        at = ((s // R) * m + tid) * R + s % R
+        block = jnp.take_along_axis(rows, at.reshape(S * k, 1), axis=0,
+                                    mode="promise_in_bounds")
+        return block.reshape(S, k * T), tid
+
+
+def _two_stage(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """(keys, indices) of a row's k largest by the chunk stages, over the
+    whole row or, where it is wide, over its k best tiles."""
+    S, V = logits.shape
+    C, T = TOPK_CHUNK, TOPK_TILE
+    n = -(-V // C)
+    if n <= k:      # a narrow shard: every chunk would be taken
+        return _largest(_ordered(logits),
+                        jax.lax.broadcasted_iota(jnp.int32, (S, V), 1), k)
+    if n > TILE_FROM:
+        block, tid = _best_tiles(logits, k)
+        keys, at = _chunk_stages(block, k)
+        with jax.named_scope("tile_topk"):
+            # a candidate's tile number by comparison with every place in
+            # the block (a gather of 16384 single numbers took 166 us on the
+            # chip, this 9)
+            place = jax.lax.broadcasted_iota(jnp.int32, (S, k, k), 2)
+            tile = jnp.sum(jnp.where((at // T)[:, :, None] == place,
+                                     tid[:, None, :], 0), axis=-1)
+            return keys, tile * T + at % T
+    # (scope and order as before the front stage came: at these widths the
+    # lowered program is what it was, to the letter)
+    with jax.named_scope("chunk_max"):
+        if n * C != V:
+            logits = jnp.pad(logits, ((0, 0), (0, n * C - V)),
+                             constant_values=-jnp.inf)
+        keys = _ordered(logits)
+    return _chunk_stages(keys, k)
 
 
 def top_candidates(logits: jax.Array, k: int, mesh=None

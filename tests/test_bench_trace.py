@@ -667,3 +667,72 @@ def test_the_ride_entry_is_appended_for_the_one_cell_that_claims_it(
     read = spec.load_reader(name, ROOT)
     assert read(clocked_ctx(rows)) == 50.0
     assert read(clocked_ctx(rows[1:])) is None
+
+
+# ---------------------------------------------------------------------------
+# the sampler's share of the device (PR 63)
+
+SAMPLER = {     # HLO line -> (program, the path it was staged under)
+    "%fusion.20 = s32[8,2040,8]{2,1,0} fusion(bf16[64,261120]{1,0} %l)":
+        ("_decode_paged_fn", "decode/sample/tile_max/reduce_max:"),
+    "%sort.21 = s32[64,2048]{1,0} sort(s32[64,2048]{1,0} %p)":
+        ("_decode_paged_fn", "decode/sample/topk/sort:"),
+    "%fusion.22 = f32[64,256]{1,0} fusion(f32[64,256]{1,0} %c)":
+        ("_decode_paged_fn", "decode/sample/div:"),
+    "%fusion.23 = s32[96,4096]{1,0} fusion(f32[96,65536]{1,0} %l)":
+        ("_decode_prefill_paged_fn", "ride/sample/chunk_max/reduce_max:"),
+    "%fusion.24 = s32[1,4096]{1,0} fusion(f32[1,65536]{1,0} %l)":
+        ("_prefill_paged_fn", "prefill/sample/chunk_max/reduce_max:"),
+    "%fusion.25 = bf16[64,128]{1,0} fusion(bf16[64,64]{1,0} %h)":
+        ("_decode_paged_fn", "decode/layers/while/body/mlp/dot_general:"),
+    "%fusion.26 = bf16[64,128]{1,0} fusion(bf16[64,64]{1,0} %g)":
+        ("_decode_paged_fn", "decode/resample_ish/dot_general:"),
+}
+
+
+def sampler_trace(tmp_path, lines) -> dict:
+    """One chip; the given operations of ``SAMPLER`` run 100 ns each, end
+    to end, each inside an execution of its own program."""
+    ops, modules, meta = [], [], {}
+    for i, line in enumerate(lines):
+        program, path = SAMPLER[line]
+        fp = 70 + sorted({p for p, _ in SAMPLER.values()}).index(program)
+        ops.append((line, 100 + 100 * i, 100))
+        modules.append((f"jit_{program}({fp})", 100 + 100 * i, 100))
+        meta[line] = {"tf_op": f"jit({program})/jit(main)/{path}",
+                      "program_id": fp}
+    path = tmp_path / "s.xplane.pb"
+    path.write_bytes(xspace.space([
+        xspace.plane("/device:TPU:0", {"XLA Ops": ops,
+                                       "XLA Modules": modules}, meta=meta),
+        xspace.plane("Task Environment", {}, {
+            "profile_start_time": T0, "profile_stop_time": T0 + 2000})]))
+    return tr.reduce(path)
+
+
+def test_sample_device_share_reads_the_decode_programs_sampler(tmp_path):
+    """``sample.device_share``: the device seconds of the decode programs'
+    operations under a ``sample`` scope (the front stage's, the older
+    stages', the draw directly under it; the ride's, whose program is a
+    decode program too) over the slice's busy time. A chunk program's
+    sampler, the layers and a scope that only holds the word are not it. It
+    reads the PARENT's trace too (``chunk_max`` and ``topk`` alone: the
+    scope is PR 45's); a trace with no such row, or none, reads nothing."""
+    entries = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]
+    assert next(e for e in entries if e["name"] == "sample.device_share") == {
+        "name": "sample.device_share", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "runner", "moves": "tpot_ms_p90",
+        "workloads": ["fh1-34b-decode", "lfm2-pp2-decode",
+                      "sala-longdoc-decode", "m7b-decode"]}
+    read = spec.load_reader("sample.device_share", ROOT)
+    assert read(tiny_ctx(sampler_trace(tmp_path, list(SAMPLER)))) == (
+        pytest.approx(100.0 * 4 / 7))
+    parents = [ln for ln, (_, p) in SAMPLER.items() if "tile_" not in p]
+    assert read(tiny_ctx(sampler_trace(tmp_path, parents))) == (
+        pytest.approx(100.0 * 3 / 6))
+    rest = [ln for ln, (prog, p) in SAMPLER.items()
+            if "/sample/" not in p or prog == "_prefill_paged_fn"]
+    assert read(tiny_ctx(sampler_trace(tmp_path, rest))) is None
+    bare = tr.reduce(named_trace(tmp_path, host=False, scopes=False))
+    assert read(tiny_ctx(bare)) is None
+    assert read(tiny_ctx(None)) is None

@@ -13,7 +13,7 @@ tests/test_minicpm_sala.py serves the family in tier-1)."""
 
 import pytest
 
-from test_bench_walk import _conftest, _load, _walk
+from test_bench_walk import _conftest, _load, _walk, committed_without
 
 _ds = _load("test_deepseek_family", conftest=_conftest, test_walk=_walk)
 _d3 = _load("test_dots3_family", conftest=_conftest, test_walk=_walk)
@@ -74,7 +74,8 @@ test_the_served_stack_is_a_row_a_layer_with_pool_and_state = (
     _fh.test_the_served_stack_is_a_row_a_layer_with_pool_and_state)
 
 
-def test_the_state_space_cell_reports_what_the_issue_names():
+def test_the_state_space_cell_reports_what_the_issue_names(tmp_path,
+                                                           monkeypatch):
     """PR 55's case AS IT STANDS against the tree AS COMMITTED, and it FAILS:
     its last two lines hold ``fh1-34b-decode`` and its configuration to be
     the LAST entries of ``BENCHMARK.json``, ISSUE 57 appends a cell and a
@@ -83,7 +84,10 @@ def test_the_state_space_cell_reports_what_the_issue_names():
     hold: a failure anywhere else is a failure here. Reported as an expected
     failure, by name, until a ``benchmark`` PR takes the two lines out
     (CHANGES.md PR 57, PERF.md section 7 (fd)); when it has, this case says
-    so and the plain alias comes back."""
+    so and the plain alias comes back. (Read without
+    ``sample.device_share``, PR 63's, which lists this cell: "no other
+    metric lists it" is held as of the case's day.)"""
+    committed_without(_fh, tmp_path, monkeypatch, ["sample.device_share"])
     with pytest.raises(AssertionError) as failure:
         _fh.test_the_state_space_cell_reports_what_the_issue_names()
     at = failure.traceback[-1]
@@ -116,25 +120,17 @@ def test_the_convolution_cell_reports_what_the_issue_names(tmp_path,
     readers beside ``m7b-decode``'s unlisted ones and that NO other metric
     lists the cell, and no later PR may edit a benchmark file: so it reads
     the committed file WITHOUT the per-layer entry appended for this cell
-    since (PR 61's ``lfm2.chunk_ride_share``, which
-    tests/test_bench_trace.py holds to be there as appended), through a root
-    that links the benchmark's directory in: what its "nothing that was
-    there is changed" holds."""
-    import functools
+    since (PR 61's ``lfm2.chunk_ride_share`` and PR 63's
+    ``sample.device_share``, which tests/test_bench_trace.py holds to be
+    there as appended), through a root that links the benchmark's directory
+    in: what its "nothing that was there is changed" holds."""
     import json
-    import types
 
     bench = json.loads((_lf.ROOT / "BENCHMARK.json").read_text())
     since = [m["name"] for m in bench["per_layer"]
              if _lf.CELL in m.get("workloads", [])][3:]
-    assert since == ["lfm2.chunk_ride_share"]
-    bench["per_layer"] = [m for m in bench["per_layer"]
-                          if m["name"] not in since]
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    (tmp_path / "benchmark").symlink_to(_lf.ROOT / "benchmark")
-    monkeypatch.setattr(_lf, "spec", types.SimpleNamespace(
-        load_cell=functools.partial(_lf.spec.load_cell, root=tmp_path)))
-    monkeypatch.setattr(_lf, "ROOT", tmp_path)
+    assert since == ["lfm2.chunk_ride_share", "sample.device_share"]
+    committed_without(_lf, tmp_path, monkeypatch, since)
     _lf.test_the_convolution_cell_reports_what_the_issue_names()
 
 

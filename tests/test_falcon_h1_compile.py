@@ -80,3 +80,65 @@ def test_state_space_cell_programs_fit_one_chip(topo, monkeypatch, cell,
     # over the floor a new cell is held to (a quarter of the chip), and with
     # room for the reference check's float32 layer (1.6 GiB) beside it
     assert 0.25 * HBM_BYTES < need < HBM_BYTES - 1.7 * 2**30
+
+
+# the parent's ``decode`` program of this cell (topology compile, PR 63, the
+# parent 9df3b47 under this installation): what the sampler's front stage
+# may add to
+PARENT_DECODE = {"temp_bytes": 172988416, "code_bytes": 12248576}
+
+
+@pytest.mark.parametrize("cell", [FH1], indirect=True)
+def test_wide_vocabulary_decode_program_takes_the_front_stage(
+        topo, monkeypatch, cell):
+    """PR 63: 64 slots x 261120 columns are 16320 chunks a row, over
+    ``sampling.TILE_FROM``, so the cell's ``decode`` program holds the front
+    stage (``sample/tile_max``: the penalized block written as int32 keys
+    as it lies, and the 2040 tiles' maxima a row; ``sample/tile_topk``: their
+    sort and the gather of 256 tiles) and the two older stages over the gathered
+    ``[64, 32768]``: no ``sort`` and no ``TopK`` holds anything as wide as
+    the ``[64, 16320]`` maxima the parent sorted, nothing copies the block
+    into another order, and temps and generated code stand within the
+    stage's own buffers of the parent's (REVIEW 45: a sort unrolls into its
+    code; the gathered block and its keys are 8 MiB each)."""
+    import re
+
+    from localai_tpu.engine import sampling as smp
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    S, V = eng["max_slots"], cfg.vocab_size
+    assert -(-V // smp.TOPK_CHUNK) > smp.TILE_FROM and S % 8 == 0
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="int8", num_slots=S,
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    c = compile_cell_program(r, a, "decode")
+    text = c.as_text()
+    for scope in ("tile_max", "tile_topk", "chunk_max", "topk"):
+        assert f"/sample/{scope}/" in text, scope
+
+    def widest(line):
+        return max((int(np.prod([int(d) for d in dims.split(",")]))
+                    for dims in re.findall(r"\[(\d+(?:,\d+)*)\]", line)),
+                   default=1)
+
+    sorts = [ln.strip() for ln in text.splitlines() if re.search(
+        r' sort\(|custom_call_target="TopK"', ln)]
+    assert len(sorts) == 5 and all("/sample/" in ln for ln in sorts)
+    for ln in sorts:
+        assert widest(ln.split(", metadata=")[0]) < S * (
+            V // smp.TOPK_CHUNK), ln[:200]
+    # the block is reduced and gathered as it lies: no copy of [64, 261120]
+    block = S * V
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r" (copy|transpose)\(", ln)
+              and widest(ln.split(", metadata=")[0]) >= block]
+    assert not copies, [ln[:160] for ln in copies]
+    m = c.memory_analysis()
+    print(f"decode: temp {m.temp_size_in_bytes} code "
+          f"{m.generated_code_size_in_bytes}")
+    gathered = S * smp.MAX_TOPK * smp.TOPK_TILE * 4
+    assert m.temp_size_in_bytes <= PARENT_DECODE["temp_bytes"] + 2 * gathered
+    assert m.generated_code_size_in_bytes <= (
+        PARENT_DECODE["code_bytes"] + 2**20)
