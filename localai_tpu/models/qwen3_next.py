@@ -18,7 +18,10 @@ What the serving engine holds of it (engine.runner):
     lives beside the pool as two dense per-SLOT arrays (``init_rec``) that the
     forward carries through the scan and updates in place; a token that is
     not real (an empty slot of a decode step, a padded row of a chunk) is the
-    identity on both;
+    identity on both, and a chunk's recurrence walks its real rows alone; a
+    small last chunk and a decode step go through as ONE batch (``RIDES``:
+    models.llama ``family_module``'s third case), the convolution and the
+    recurrence alone in two halves (``_gdn``);
   * the expert block is TOLD which experts it holds (``ep_size``,
     ``ep_rank``: a deployment's expert parallelism): the router scores all
     ``num_experts x ep_size`` experts, the ``num_experts`` held here compute
@@ -30,7 +33,7 @@ What the serving engine holds of it (engine.runner):
 
 The plain reference is benchmark/reference/qwen3_next_family.py, and
 tests/test_qwen3_next.py holds this file to it. The conv, the router, the
-shared expert and a chunk's DeltaNet scan are XLA under named scopes
+shared expert and a chunk's DeltaNet token loop are XLA under named scopes
 (``gdn/*``, ``moe/*``, ``attn_gate``); the routed experts' kernel runs under
 ``moe/experts``, as their loop does, and the decode step's recurrence
 (ops.gdn's kernel where attention's are kernels, ``gdn_step`` as XLA) under
@@ -165,6 +168,9 @@ CONFIG = Qwen3NextConfig
 # (models.llama ``refusal``)
 UNSERVED = mdl.KEYS_ALONE
 WEIGHTS = ()
+# ``forward`` takes a prompt's small last chunk and a decode step as one
+# batch (the contract's ``ride``): rows meet in ``_gdn_mix`` alone
+RIDES = True
 WHY = (f"model_type qwen3_next: its DeltaNet layers {mdl.STATE_WHY}; its "
        f"routed experts are read one expert at a time from the stacked "
        f"bfloat16 leaves")
@@ -375,24 +381,43 @@ def gdn_step(S, q, k, v, g, beta):
     return decay[..., None] * S + k[..., :, None] * d[..., None, :], o
 
 
-def recur(S0, q, k, v, g, beta):
+def recur(S0, q, k, v, g, beta, *, valid):
     """The recurrence as XLA from state S0 [B, Hv, dk, dv] over the tokens
     of q, k [B, T, Hv, dk], v [B, T, Hv, dv], g, beta [B, T, Hv]:
-    (S after them, o [B, T, Hv, dv])."""
+    (S after them, o [B, T, Hv, dv]). ``valid`` [B, T] marks each row's real
+    tokens, a prefix: a chunk walks rows 0 .. n - 1, n the most real tokens
+    of a row, and no others (a token that is not real is the identity on S;
+    its ``o`` is zero and nobody reads it)."""
     if q.shape[1] == 1:     # the decode step: no loop
         S, o = gdn_step(S0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
         return S, o[:, None]
-    # a prefill chunk: the same recurrence, token by token
-    S, o = lax.scan(lambda S, xs: gdn_step(S, *xs), S0, tuple(
-        jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    # a prefill chunk: the same recurrence, token by token, the trip count
+    # read from the program's input
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta))
+
+    def token(t, carry):
+        S, o = carry
+        S, o_t = gdn_step(S, *(lax.dynamic_index_in_dim(
+            x, t, 0, keepdims=False) for x in xs))
+        return S, lax.dynamic_update_index_in_dim(o, o_t, t, 0)
+
+    S, o = lax.fori_loop(
+        0, jnp.max(jnp.sum(valid, axis=1).astype(jnp.int32)), token,
+        (S0, jnp.zeros(xs[2].shape, jnp.float32)))
     return S, jnp.moveaxis(o, 0, 1)
 
 
+@functools.partial(jax.jit, static_argnums=(2, 3), inline=True)
 def recur_in_place(S_all, p, g_idx: int, interpret: bool, q, k, v, g, beta):
     """``recur`` for the decode step (T = 1, batch row b is slot b) as
     ops.gdn's kernel on layer (p, g_idx) of the carried state ``S_all``
     [P, G, slots, Hv, dk, dv]: (``S_all`` with the layer's rows stepped, in
-    place; o [B, 1, Hv, dv])."""
+    place; o [B, 1, Hv, dv]). Behind ONE trace a (layer of the period,
+    shape): inlined, so a program holds what it held, but the kernel's body
+    (32 heads unrolled) is traced once a process and not once a program: a
+    ride's step half is the decode step's. (A test that plants a fault in
+    the kernel clears the kept trace: tests/conftest.py
+    ``fresh_kernel_traces``.)"""
     from localai_tpu.ops import gdn
 
     S_all, o = gdn.gdn_state_step(
@@ -409,24 +434,21 @@ def gated_norm(o, z, w, eps: float):
     return o * jax.nn.silu(z.astype(jnp.float32))
 
 
-def _gdn(cfg: Qwen3NextConfig, h, lp, g_idx: int, w_in, w_out, state_step,
-         conv0, valid):
-    """The DeltaNet mixer on normed activations h [B, T, D] from the conv's
-    rows conv0 [B, K-1, C] and the state ``state_step`` steps: ``recur``
-    on the layer's S0, or the decode step's kernel on the carried array;
-    ``valid`` [B, T] marks the real tokens, a PREFIX of each row; ``w_in``,
-    ``w_out`` the layer's two large projections (``GDN_FLAT``). Returns
-    (out [B, T, D], ``state_step``'s state, conv)."""
-    B, T, _ = h.shape
+def _gdn_mix(cfg: Qwen3NextConfig, qkv, z, b, a, lp, g_idx: int, state_step,
+             conv0, valid):
+    """Where a DeltaNet layer's rows meet: the short convolution of the
+    projected qkv [B, T, C] behind the conv's rows conv0 [B, K-1, C], and
+    the recurrence ``state_step`` steps (``recur`` on the layer's S0, or the
+    decode step's kernel on the carried array) under the gates b, a
+    [B, T, Hv]; ``valid`` [B, T] marks the real tokens, a PREFIX of each
+    row. The gated norm under z [B, T, Hv dv] goes with them: it fuses with
+    the recurrence's output, which then rounds as it does in the program of
+    these rows alone. Returns (o [B, T, Hv, dv] float32, normed and gated,
+    ``state_step``'s state, conv)."""
+    B, T, _ = qkv.shape
     Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    K, C = cfg.linear_conv_kernel_dim, cfg.conv_dim
-    with jax.named_scope("proj"):
-        qkvz = qnt.matmul(h, w_in)
-        ba = qnt.matmul(h, lp["gdn_in_ba"][g_idx])
-        qkvz, ba = lax.optimization_barrier((qkvz, ba))
-        qkv, z = qkvz[..., :C], qkvz[..., C:]
-        b, a = ba[..., :Hv], ba[..., Hv:]
+    K = cfg.linear_conv_kernel_dim
     with jax.named_scope("conv"):
         # [the slot's last K-1 inputs; the chunk's]: token t of the chunk is
         # row t + K - 1, and its output reads rows t .. t + K - 1
@@ -456,10 +478,96 @@ def _gdn(cfg: Qwen3NextConfig, h, lp, g_idx: int, w_in, w_out, state_step,
         beta = jnp.where(valid[..., None], beta, 0.0)
         S, o = state_step(q, k, v, g, beta)
     with jax.named_scope("out"):
-        o = gated_norm(o, z.reshape(B, T, Hv, dv),
-                       lp["gdn_out_norm"][g_idx], cfg.rms_norm_eps)
-        out = qnt.matmul(o.astype(h.dtype).reshape(B, T, Hv * dv), w_out)
-    return out, S, new_conv
+        o = gated_norm(o, z.reshape(o.shape), lp["gdn_out_norm"][g_idx],
+                       cfg.rms_norm_eps)
+    return o, S, new_conv
+
+
+def _gdn(cfg: Qwen3NextConfig, x, lp, at: tuple, flat, S_all, conv_all, valid,
+         slot, fresh, kernels: Optional[bool], ride: int = 0):
+    """DeltaNet layer ``at`` = (period, g) on the residual x [B, T, D]
+    against the carried per-slot arrays ``S_all`` [P, G, slots, Hv, dk, dv]
+    and ``conv_all`` [P, G, slots, K-1, C], both stepped in place; ``flat``
+    the layers' two large projections (``GDN_FLAT``) as [P G, ...]. Returns
+    (out [B, T, D], ``S_all``, ``conv_all``).
+
+    The norm, the projections and the output product are per row and run
+    once; rows meet in ``_gdn_mix`` alone, which takes a decode
+    step's rows (``slot`` None: batch row b is slot b) against every slot's
+    state, as ops.gdn's kernel on the carried array where ``kernels`` says
+    so, and ONE slot's chunk against that slot's, from zero where ``fresh``.
+    ``ride`` (the contract's third case; x [1, ride + S, D]) is the two side
+    by side, each half in the shape its own program gives it: the step's S
+    rows first, for every slot, then the chunk's ``ride`` rows against what
+    that left in ``slot``'s state (its own step row is not real and left it
+    as it was), and the two outputs laid end to end."""
+    p, g_idx = at
+    C, Hv, dv = (cfg.conv_dim, cfg.linear_num_value_heads,
+                 cfg.linear_value_head_dim)
+    kernel = kernels is not None
+
+    # a half is ``part`` of the rows of a [B, T, ...] against the state of
+    # ``of`` (a slot, or None: every slot's, a row each), ``fused`` where the
+    # step's ONE kernel a layer runs on the carried state, which is then
+    # never sliced. The per-slot arrays are read and written under the scope
+    # of the recurrence: ``gdn/state`` is all that moves state
+    def read(S_all, conv_all, part, of, fused):
+        with jax.named_scope("state"):
+            S0 = None if fused else mdl.rec_read(S_all, at, of)
+            conv0 = mdl.rec_read(conv_all, at, of)
+            if of is not None and fresh is not None:    # a chunk
+                S0 = jnp.where(fresh, 0.0, S0)
+                conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
+            if fused:
+                return functools.partial(recur_in_place, S_all, *at,
+                                         kernels), conv0
+            return functools.partial(recur, S0, valid=part(valid)), conv0
+
+    def write(S_all, conv_all, S, conv, of, fused):
+        with jax.named_scope("state"):
+            return (S if fused else mdl.rec_write(S_all, S, at, of),
+                    mdl.rec_write(conv_all, conv, at, of))
+
+    def mix(part, state_step, conv0):
+        return _gdn_mix(cfg, *(part(t) for t in (qkv, z, b, a)), lp, g_idx,
+                        state_step, conv0, part(valid))
+
+    if ride:        # the step's half first
+        def chunk(t):
+            return t[:, :ride]
+
+        def step(t):
+            return t[0, ride:, None]
+
+        state = read(S_all, conv_all, step, None, kernel)
+    else:
+        def whole(t):
+            return t
+
+        fused = kernel and slot is None and x.shape[1] == 1
+        state = read(S_all, conv_all, whole, slot, fused)
+    h = zc_norm(x, lp["gdn_norm"][g_idx], cfg.rms_norm_eps)
+    w_in, w_out = (lax.dynamic_index_in_dim(
+        w, p * cfg.gdn_per_period + g_idx, 0, keepdims=False) for w in flat)
+    with jax.named_scope("proj"):
+        qkvz = qnt.matmul(h, w_in)
+        ba = qnt.matmul(h, lp["gdn_in_ba"][g_idx])
+        qkvz, ba = lax.optimization_barrier((qkvz, ba))
+        qkv, z = qkvz[..., :C], qkvz[..., C:]
+        b, a = ba[..., :Hv], ba[..., Hv:]
+    if ride:        # the step's rows are written before the chunk's reads
+        o_step, S, conv = mix(step, *state)
+        S_all, conv_all = write(S_all, conv_all, S, conv, None, kernel)
+        fused = False
+        o, S, conv = mix(chunk, *read(S_all, conv_all, chunk, slot, fused))
+        # the chunk's rows in front, a step's row a slot behind
+        o = jnp.concatenate([o, o_step[:, 0][None]], axis=1)
+    else:
+        o, S, conv = mix(whole, *state)
+    with jax.named_scope("out"):
+        out = qnt.matmul(o.astype(h.dtype).reshape(*z.shape[:2], Hv * dv),
+                         w_out)
+    return (out, *write(S_all, conv_all, S, conv, slot, fused))
 
 
 def _partial_rope(x, cos, sin, rot: int):
@@ -549,11 +657,16 @@ def forward(
     kernels: Optional[bool] = None,     # None: the experts' walk and the
                             # DeltaNet's decode step are XLA; else ops.moe's
                             # and ops.gdn's kernels (the value: interpreted)
+    ride: int = 0,          # rows of ``slot``'s chunk in front of a decode
+                            # step's S, [1, ride + S] in all: the contract
 ) -> tuple[jax.Array, Any, dict, jax.Array]:
     """models.llama.forward for this family: (hidden [B, T, D], new K/V
     stack, new ``rec``, [experts touched, token-expert pairs] summed over
     the expert blocks). One ``lax.scan`` over the periods; (x, K/V, rec) is
-    its carry, so both caches are written in place."""
+    its carry, so both caches are written in place. Everything but the
+    DeltaNet's convolution and recurrence is per row (the full-attention
+    layer's rows meet in the attend, which a ride's composite policy
+    splits), so a ride's rows go through as any batch's."""
     cos, sin = mdl.rope_rows(rope, positions)
     x = mdl.embed(cfg, params, tokens, embeds)
     if attn is None:
@@ -567,14 +680,11 @@ def forward(
     # [P, G, ...] leaf is the period's G layers, copied whole (0.45 GB a step
     # at the published widths) before a layer's is taken. Flat (a bitcast)
     # and indexed by p G + g, the dot reads its layer in place
-    w_in, w_out = (layers[n].reshape(-1, *layers[n].shape[2:])
-                   for n in GDN_FLAT)
+    flat = tuple(layers[n].reshape(-1, *layers[n].shape[2:])
+                 for n in GDN_FLAT)
     scanned = {n: w for n, w in layers.items()
                if n not in EXPERT_LEAVES + GDN_FLAT}
     eps, G = cfg.rms_norm_eps, cfg.gdn_per_period
-    # the decode step (batch row b is slot b, one token): the recurrence is
-    # ONE kernel a layer on the carried state, which is then never sliced
-    fused = kernels is not None and slot is None and tokens.shape[1] == 1
 
     def period(carry, xs):
         x, kv, S_all, conv_all, counts = carry
@@ -587,29 +697,10 @@ def forward(
             return x + out, counts + c
 
         for g_idx in range(G):
-            at = (p, g_idx)
             with jax.named_scope("gdn"):
-                # the per-slot arrays are read and written under the scope
-                # of the recurrence: ``gdn/state`` is all that moves state
-                with jax.named_scope("state"):
-                    S0 = None if fused else mdl.rec_read(S_all, at, slot)
-                    conv0 = mdl.rec_read(conv_all, at, slot)
-                    if fresh is not None:       # a chunk: never fused
-                        S0 = jnp.where(fresh, 0.0, S0)
-                        conv0 = jnp.where(fresh, 0, conv0).astype(conv0.dtype)
-                    state_step = (
-                        functools.partial(recur_in_place, S_all, p, g_idx,
-                                          kernels)
-                        if fused else functools.partial(recur, S0))
-                h = zc_norm(x, lp["gdn_norm"][g_idx], eps)
-                out, S, conv = _gdn(
-                    cfg, h, lp, g_idx,
-                    *(lax.dynamic_index_in_dim(w, p * G + g_idx, 0,
-                                               keepdims=False)
-                      for w in (w_in, w_out)), state_step, conv0, valid)
-                with jax.named_scope("state"):
-                    S_all = S if fused else mdl.rec_write(S_all, S, at, slot)
-                    conv_all = mdl.rec_write(conv_all, conv, at, slot)
+                out, S_all, conv_all = _gdn(
+                    cfg, x, lp, (p, g_idx), flat, S_all, conv_all, valid,
+                    slot, fresh, kernels, ride)
                 x = x + out
             x, counts = moe(x, g_idx, counts)
 

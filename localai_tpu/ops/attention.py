@@ -746,7 +746,29 @@ def paged_decode_attention(
     The attention output stays the FIRST result (the benchmark's readers
     name an operation by the first shape of its result). Unscaled pools
     only: a scaled pool's f32 scale row is narrower than a DMA tile, and
-    its policy scatters (engine.kvcache)."""
+    its policy scatters (engine.kvcache).
+
+    Behind ONE trace a shape (``_paged_decode_call``, a ``jax.jit`` that is
+    inlined where it is lowered: a program holds the operations it held):
+    the kernel's body is the costliest thing a decode program traces (a
+    quarter of a first dispatch on the chip's host: PERF.md 6, PR 64), and
+    every program of a runner that holds a decode step, the multi-step one
+    and a ride among them, calls it with the same shapes."""
+    return _paged_decode_call(
+        q, layer, positions, tables, k_cache, v_cache, k_scale, v_scale,
+        k_new, v_new, sliding_window=sliding_window, interpret=interpret,
+        num_buffers=num_buffers)
+
+
+# (the arguments in the order the body first uses them: the call stands in
+# the traced program as one equation, and a loop body's closed-over values
+# are numbered by its operands' order: tests/test_neighbour_texts.py)
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "sliding_window", "interpret", "num_buffers"))
+def _paged_decode_call(q, layer, positions, tables, k_cache, v_cache,
+                       k_scale, v_scale, k_new, v_new, *, sliding_window,
+                       interpret, num_buffers):
+    """``paged_decode_attention``'s body."""
     S, Hq, hd = q.shape
     Hkv, bt = k_cache.shape[2], k_cache.shape[3]
     MB = tables.shape[1]

@@ -122,6 +122,26 @@ def tmp_models_dir(tmp_path):
 
 
 @pytest.fixture()
+def fresh_kernel_traces():
+    """For a case that plants a fault INSIDE a kernel whose body the program
+    keeps one trace of (``ops.attention._paged_decode_call``, ``models.
+    qwen3_next.recur_in_place``: inlined ``jax.jit``s, a trace a shape and
+    process): the kept traces are dropped before the case, so that what it
+    planted is traced whatever ran before it, and after it, so that no later
+    case meets the fault's trace."""
+    from localai_tpu.models import qwen3_next
+    from localai_tpu.ops import attention
+
+    def drop():
+        attention._paged_decode_call.clear_cache()
+        qwen3_next.recur_in_place.clear_cache()
+
+    drop()
+    yield
+    drop()
+
+
+@pytest.fixture()
 def in_stack():
     """``in_stack(a, layer=1, layers=3)``: ``a`` as layer ``layer`` of a
     stacked KV cache whose other layers hold noise: what the attention

@@ -150,3 +150,15 @@ def lowered_texts(r: ModelRunner, programs, debug_info: bool = False) -> dict:
                 r.params, r.kv, r.state, r.block_tables, *chunk, bucket=32)}
     return {name: lower[name]().as_text(debug_info=debug_info)
             for name in programs}
+
+
+def host_state(r: ModelRunner) -> list:
+    """Every leaf of a runner's pool, of its decode state and of its
+    family's own (``rec``: the per-slot rows of EVERY slot, the routed
+    count), on the host: what a ride and the two programs it stands for
+    are compared by."""
+    st = r.state
+    return [np.asarray(a) for a in jax.tree.leaves(
+        (r.kv, st.tokens, st.positions, st.active, st.counts, st.bias,
+         st.params, jax.random.key_data(st.keys), st.rec))]
+

@@ -653,7 +653,9 @@ def test_chunk_ride_share_counts_rides_over_small_last_chunks(rows, value):
     ("runner.chunk_ride_share", "stall_ms_p98", "m7b-decode"),
     # PR 61: a family's forward takes the ride; the accepted entry lists its
     # cells, so this cell's reads the same reader under a name of its own
-    ("lfm2.chunk_ride_share", "tpot_ms_p90", "lfm2-pp2-decode")])
+    ("lfm2.chunk_ride_share", "tpot_ms_p90", "lfm2-pp2-decode"),
+    # PR 64: the DeltaNet hybrid's forward takes it too, under its cell's name
+    ("gdn.chunk_ride_share", "tpot_ms_p90", "qn80-ep8-decode")])
 def test_the_ride_entry_is_appended_for_the_one_cell_that_claims_it(
         name, moves, cell):
     # (by NAME: later PRs append behind it, PR 62 the first)

@@ -136,8 +136,12 @@ test_the_served_stack_is_a_stack_of_periods = (
 
 
 def test_the_hybrid_cell_reports_what_the_issue_names(tmp_path, monkeypatch):
-    """PR 41's case as it stands, read as the looped cell's above."""
-    committed_without(_qn, tmp_path, monkeypatch, ["sample.device_share"])
+    """PR 41's case as it stands, read as the looped cell's above, and
+    without the entry appended for this cell since (PR 64's
+    ``gdn.chunk_ride_share``, which tests/test_bench_trace.py holds to be
+    there as appended)."""
+    committed_without(_qn, tmp_path, monkeypatch,
+                      ["sample.device_share", "gdn.chunk_ride_share"])
     _qn.test_the_new_cell_reports_what_the_issue_names()
 
 
@@ -165,8 +169,13 @@ def test_the_mixed_cell_reports_what_the_issue_names(tmp_path, monkeypatch):
     PR may edit a benchmark file: so it reads the committed file WITHOUT the
     entries appended behind its cell (PR 48's cell and configuration; the
     per-layer metrics stay, each names its own cells), which is what its
-    "nothing that was there is changed" holds."""
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    "nothing that was there is changed" holds; and it reads its cells
+    without the entry PR 64 appended for ``qn80-ep8-decode``, the cell it
+    compares its own to (``committed_without``)."""
+    full = tmp_path / "full"
+    full.mkdir()
+    committed_without(_af, full, monkeypatch, ["gdn.chunk_ride_share"])
+    bench = json.loads((full / "BENCHMARK.json").read_text())
     last = [w["name"] for w in bench["workloads"]].index(_af.CELL) + 1
     bench["workloads"] = bench["workloads"][:last]
     used = {w["config"] for w in bench["workloads"]}

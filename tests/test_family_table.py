@@ -104,10 +104,12 @@ def test_a_row_of_the_table_is_a_family(model_type, caplog):
     r = runner_for(cfg)     # ... and what it serves is built
     assert (r.recurrent, r.routed, r.latent) == (
         cfg.recurrent, cfg.routed, cfg.latent)
-    # a family that says ``RIDES`` (PR 61: ``lfm2_moe``, the first) takes the
-    # contract's one keyword more, off unless passed; no other holds it
+    # a family that says ``RIDES`` (PR 61: ``lfm2_moe``, the first; PR 64:
+    # ``qwen3_next``) takes the contract's one keyword more, off unless
+    # passed; no other holds it
     rides = getattr(module, "RIDES", False)
-    assert r.own_forward and r.rides == rides == (model_type == "lfm2_moe")
+    assert r.own_forward and r.rides == rides == (
+        model_type in ("lfm2_moe", "qwen3_next"))
     assert ("ride" in takes) == rides
     if rides:
         assert (takes["ride"].kind, takes["ride"].default) == (

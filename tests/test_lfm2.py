@@ -612,15 +612,6 @@ STREAMS = (RNG.integers(1, 380, 5).tolist(), RNG.integers(1, 380, 11).tolist())
 LONG = RNG.integers(1, 380, 13).tolist()         # two chunks: 8, then 5 of 8
 
 
-def _host(r):
-    """Every leaf of the pool, of the decode state and of the family's own
-    (the convolution rows of EVERY slot, the routed count), on the host."""
-    st = r.state
-    return [np.asarray(a) for a in jax.tree.leaves(
-        (r.kv, st.tokens, st.positions, st.active, st.counts, st.bias,
-         st.params, jax.random.key_data(st.keys), st.rec))]
-
-
 def _busy_runner(cfg, params, attn_impl, sampling):
     """Two streams three steps in (slots 0 and 1), a slot that held a stream
     that has ended (2: its rows are what the stream left) and one that never
@@ -688,7 +679,7 @@ def test_a_ride_leaves_what_the_step_then_the_chunk_leave(
             out = np.asarray(adm.first)
             first, routed = int(out[0]), np.stack([routed, out[1:]])
         after = [r.step() for _ in range(3)]
-        return [step, first, *after], routed, _host(r)
+        return [step, first, *after], routed, families.host_state(r)
 
     (want, apart, want_state), (got, routed, got_state) = (
         serve(False), serve(True))

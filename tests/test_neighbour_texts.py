@@ -87,6 +87,38 @@ What each row has seen:
   holds what it held. The new family's two rows are taken from this PR's own
   tree, heads of 128 (what the compiled kernels take): its chunk holds no
   kernel, so the two rows' ``prefill`` texts are one text.
+- PR 64 (``models.qwen3_next.forward`` takes the ride: the module says
+  ``RIDES``, ``_gdn`` runs the convolution and the recurrence in two halves,
+  and ``recur`` walks a chunk's REAL rows, a loop whose trip count is read
+  from ``valid``, where it scanned the bucket): ``qwen3_next``'s FOUR chunk
+  texts (``prefill_1`` / ``prefill_0`` of both rows) are retaken ON PURPOSE
+  from this PR's own tree. With the values' numbers aside each differs from
+  its parent's (b7d4e76) in the token loop alone: ``lax.scan``'s ``while``
+  over 32 rows with the step a called function became ``fori_loop``'s over
+  the most real rows of ``valid`` with the step inline, its reads and its
+  one write at a clamped index, and the count in front of it (``valid``
+  summed where a layer's state is read); nothing else moved. The rows gain ONE program
+  each, their ``ride`` text, taken from this PR's own tree as a new program
+  is; their ``decode`` / ``decode_n`` stand as taken (the keyword defaults
+  to no ride and the one half traces what the whole did, in the order it
+  did). The four families that do not say ``RIDES`` and ``minicpm_sala``
+  never enter models/qwen3_next.py, and the 7B's and Ouro's ``ride`` texts
+  are PR 59's. FOUR texts of three OTHER kernel rows are retaken for the
+  NUMBER behind a private function's name and for nothing else (``afmoe``
+  ``decode``; ``lfm2_moe`` ``decode`` and ``ride``; ``minicpm_sala``
+  ``decode``: ``@threefry2x32_<n>`` and its like): the chip forced a mend of
+  set-up in general terms (the ride program's first dispatch put the hybrid
+  cell's ``setup_s`` at its bound, and a quarter of it was the paged
+  kernel's body traced again): ``ops.paged_decode_attention`` keeps ONE
+  trace a shape (``_paged_decode_call``, a ``jax.jit`` inlined where it is
+  lowered), and a program that calls the kernel more than once now emits
+  fewer private functions in front of the sampler's, whose numbers move
+  down. With those numbers aside every one of the 34 kernel-path texts
+  (these four included) is its parent's (b7d4e76) line for line
+  (``re.sub(r"@([A-Za-z0-9_]*?)_[0-9]+\b", ...)`` over both, taken by this
+  PR on both trees); the eight rows whose programs call it once stand as
+  taken, and so does every XLA row. ``test_one_trace_of_the_paged_kernel_a_
+  runner`` below holds the mend.
 """
 
 import functools
@@ -204,21 +236,25 @@ TAKEN = [
         "decode_n":
             "f0d7dea69dcd89fcef6c498b8b8448527f27ec335ee2c3c8bc9a4a4c95caf330",
         "prefill_1":
-            "af54fb087b9406c94990c2db770d38255666181b935115359ea32aea51b959b1",
+            "3ac2b60f6091b4f8ad8437e376bc1f268ecce61b3ea49b69bf812454671e59a7",
         "prefill_0":
-            "7dd2443df4e98f837b8555fd6623c5d128539b89e653c9fed3ac59289142fc08"}),
+            "5f1693a46745f57ddef2c74f456cf14f8c4abce1bbe388f0866df2b4ad30cf80",
+        "ride":
+            "fe343be08dd224b83aebc9c7c77d86bfa8d400c9fa5f3c879a026be980e72636"}),
     family({**QWEN3_NEXT, **WIDE}, 16, "xla", {
         "decode":
             "e03b0cae3c794183ef5b23d3bcf79397bbc84f6ff63a830980e9b553ba43d173",
         "decode_n":
             "3aaf8ca9f6fb5474663d4cf30601161f90b86f2391b608129d12e13ebc461e08",
         "prefill_1":
-            "6f692f72bdc7e9cc5a38c698b9e8b4b1b6e01a318b2b0c75bb47c1a30437443c",
+            "690e2f7a718ea243ee7030641fa01eaa823ba0950c1d7b7a7146fa5f3fdfe789",
         "prefill_0":
-            "e0664a26be528c1a5f909534d54256275c2044f56213e4b2e664ea4e0ab56074"}),
+            "91dea89764dd9bad78aff6deac31a8e019ddf2d7aa27b39724679dd5098d47e4",
+        "ride":
+            "029b436bac6517488247cc746e7f74b49869295bbc2941f66e0e379d44c964c0"}),
     family({**AFMOE, **WIDE}, 16, "pallas_interpret", {
         "decode":
-            "b55d2367aa650714233ee17e5d1f10efeeae5efcac5128768abdf01c98a4ceab",
+            "e0c46668cd703de1cad7e5a120e7d5610ea857c0b34aab47947647596e281af6",
         "prefill_1":
             "189c6e43b3a847679d68d877a305967c95504f428a42e3730bbeb7aa183b4259",
         "prefill_0":
@@ -267,13 +303,13 @@ TAKEN = [
             "17b891ddebad6bda6c4f4405b8ff5229d91abe82860db6871ca3b728c0a30cf9"}),
     family({**LFM2, "moe_intermediate_size": 128}, 16, "pallas_interpret", {
         "decode":
-            "551efa45ea499bb3456b870593a72f5792efdfaf79a3305b2193c13c24d6f240",
+            "855071bda9153d90d0a1a03017774c3452eb05744d9cb34a3df60e595d55f71a",
         "prefill_1":
             "3d6d32682ea7a7f56f1c8ea8fc82b8aac5e68a0ce8006b468feca80277c1aaa0",
         "prefill_0":
             "2da814dd8c3f07ec6cb357ed0597d433cbb48fe0ae69759b82057b0e4f5d3f53",
         "ride":
-            "52b4ac8569805a1bec4f2aa365cd6ae460c2dfdc0fbdb832f89fe83179f39cbd"}),
+            "18a06790a41432ec17ea83aa97d3b6e4bc2bf6419366c2ea45310456c6f82c3e"}),
     family({**LFM2, "moe_intermediate_size": 128}, 16, "xla", {
         "decode":
             "77ec6134d68aa05f2982e8792d8378b43be97f0f8fcc4a1952fe8b2e63386223",
@@ -285,7 +321,7 @@ TAKEN = [
             "5c4f4aebea0a1eb2b7ff5f9547ba8a263eae5aebb6c127553a545328f868301b"}),
     family({**MINICPM_SALA, **WIDE_LIGHTNING}, 16, "pallas_interpret", {
         "decode":
-            "760343ed7db58267edc796d9abb52bb80ffd7b0fd7fc99a4efc0dc43876bd3f1",
+            "482400c46cd5a15c5e324a4bf60f9aba3875956f31b1094a1a0750d14df80759",
         "prefill_1":
             "4310671fe5e13920280e9991b4edb573c2e75ae46775fd0e2b2a77571ca471c3",
         "prefill_0":
@@ -309,3 +345,26 @@ def test_the_programs_lower_to_the_text_taken(build, taken):
     now = {name: hashlib.sha256(text.encode()).hexdigest()
            for name, text in families.lowered_texts(r, taken).items()}
     assert now == taken
+
+
+def test_one_trace_of_the_paged_kernel_a_runner(monkeypatch,
+                                                fresh_kernel_traces):
+    """PR 64 (set-up, in general terms): the paged decode kernel's BODY is
+    traced once for all the programs of a runner that hold a decode step:
+    the multi-step program and a ride reuse the decode step's trace
+    (``ops.attention._paged_decode_call``: a ``jax.jit`` keeps a trace by
+    shape, and ``inline=True`` leaves a program's operations as they were,
+    which the rows above hold). The kept traces are dropped around this case
+    (``fresh_kernel_traces``): it counts its own runner's and no other's."""
+    from localai_tpu.ops import attention as att
+
+    traced = []
+    real = att._paged_decode_kernel
+    monkeypatch.setattr(att, "_paged_decode_kernel", lambda *a, **kw: (
+        traced.append(1), real(*a, **kw))[1])
+    r = cell_runner("mistral-7b-v0.3-int8")     # (lowers ``decode`` itself)
+    families.lowered_texts(r, ("decode",))
+    assert len(traced) == 1
+    families.lowered_texts(r, ("decode_n", "ride"))
+    assert len(traced) == 1
+

@@ -257,6 +257,8 @@ def _head_step_with(monkeypatch, change):
     from localai_tpu.ops import gdn
 
     real = gdn.head_step
+    # (``qn.recur_in_place`` keeps ONE trace of the kernel a process: the
+    # case that runs this asks for ``fresh_kernel_traces``)
     monkeypatch.setattr(gdn, "head_step",
                         lambda S, *rest: change(real, S, *rest))
     return {}
@@ -337,8 +339,8 @@ def plain_gain_on_the_gated_norm_as_one_plus(monkeypatch):
     plain_gain_on_the_gated_norm_as_one_plus, no_decay_in_the_kernels_step,
     no_outer_product_in_the_kernels_step])
 @pytest.mark.parametrize("experts", EXPERTS)
-def test_mathematics_left_out_fails_the_tolerance(family, monkeypatch,
-                                                  left_out, experts):
+def test_mathematics_left_out_fails_the_tolerance(
+        family, monkeypatch, fresh_kernel_traces, left_out, experts):
     """``no_decay`` patches ``gdn_step``, which the ``kernel`` runner's decode
     steps no longer run (its chunks do): the two ``kernel_only`` cases leave
     a term out of the step a cell runs, and move nothing under ``loop``."""
@@ -632,3 +634,267 @@ def test_a_checkpoint_in_the_published_layout_loads_to_the_served_leaves(
     np.testing.assert_array_equal(held["layers"]["moe_gate"], lay["moe_gate"])
     with pytest.raises(ValueError, match="quantization"):
         load_llama_params(tmp_path, hf=whole_hf, quantization="int8")
+
+
+# ---------------------------------------------------------------------------
+# (vii) a prompt's small last chunk rides the decode step through the family's
+# own forward (PR 64; tests/test_lfm2.py holds ``lfm2_moe``'s, and
+# tests/test_paged_serving.py the dense stack's)
+
+SLOTS, EXPERT_BLOCKS = 4, HF["num_hidden_layers"]
+STREAMS = (RNG.integers(1, 380, 5).tolist(), RNG.integers(1, 380, 11).tolist())
+GREEDY, SEEDED = dict(temperature=0.0), dict(temperature=0.8, top_p=0.95,
+                                              seed=11)
+# the paged kernel takes heads of 128 lanes, ops.moe's experts of 128
+WIDE = {"head_dim": 128, "moe_intermediate_size": 128}
+
+
+def ride_runner(cfg, params, impl, **kw):
+    """``impl``: "loop" and "kernel" are ``runner_for``'s (XLA attention under
+    the experts' walk and ``gdn_step``, or under ops.moe's and ops.gdn's
+    kernels interpreted); "pallas_interpret" is the runner's own choice by
+    ``attn_impl`` (the paged kernel beside those two)."""
+    if impl == "pallas_interpret":
+        r = ModelRunner(cfg, params, num_slots=SLOTS, max_ctx=128, paged=True,
+                        kv_block_tokens=16, prefill_chunk=16,
+                        prefill_buckets=[16], attn_impl=impl,
+                        kv_dtype=cfg.dtype, **kw)
+        assert r.family_kernels is True
+        return r
+    return runner_for(cfg, params, impl, prefill_buckets=[16], **kw)
+
+
+def _busy_runner(cfg, params, impl, sampling):
+    """Two streams three steps in (slots 0 and 1), a slot that held a stream
+    that has ended (2: its state is what the stream left) and one that never
+    held any (3)."""
+    r = ride_runner(cfg, params, impl, seed=3)
+    assert r.rides and r.own_forward
+    for prompt in (*STREAMS, SHORT):
+        r.admit(r.acquire_slot(), prompt, **{**sampling, "seed": 7})
+    for _ in range(3):
+        r.step()
+    r.release(2)
+    for name in ("S", "conv"):
+        rows = np.asarray(r.state.rec[name]).reshape(PERIODS * G, SLOTS, -1)
+        assert rows[:, :3].any(axis=(0, 2)).all() and not rows[:, 3].any()
+    return r
+
+
+@pytest.fixture(scope="module")
+def ride_models():
+    return {(dtype, wide): (cfg, seeded_params(cfg))
+            for dtype, wide in (("float32", False), ("bfloat16", False),
+                                ("bfloat16", True))
+            for cfg in [config(dtype, **(WIDE if wide else {}))]}
+
+
+@pytest.mark.parametrize("dtype, impl, prompt, sampling", [
+    ("float32", "loop", SHORT, GREEDY), ("float32", "loop", SHORT, SEEDED),
+    ("float32", "loop", PROMPT, GREEDY), ("float32", "kernel", PROMPT, SEEDED),
+    ("bfloat16", "loop", PROMPT, SEEDED), ("bfloat16", "kernel", SHORT, GREEDY),
+    # (attention's kernel in the interpreter too: 25 s)
+    ("bfloat16", "pallas_interpret", PROMPT, SEEDED)],
+    ids=lambda v: {id(SHORT): "fresh", id(PROMPT): "resumed",
+                   id(GREEDY): "greedy", id(SEEDED): "seeded"}.get(id(v), v))
+def test_a_ride_leaves_what_the_step_then_the_chunk_leave(
+        ride_models, dtype, impl, prompt, sampling):
+    """``_decode_prefill_paged_fn`` through ``qn.forward(ride=bucket)``
+    against the two programs it stands for, on the same state: a decode step
+    (the new slot not live: its row moves no state), then the prompt's last
+    chunk; the new slot is the one a finished stream left its state in, and
+    the chunk is the prompt's only one (``fresh``: from zero) or its second
+    (from what the first left). The same S tokens and first token, the same
+    pool, S and convolution rows of EVERY slot and sampling state to the
+    bit, the same streams afterwards; the launch's token-expert pairs are
+    the step's plus the chunks', and the experts it touched at most the sum
+    and at least the larger (one block's rows share what they touch: the one
+    read)."""
+    cfg, params = ride_models[dtype, impl == "pallas_interpret"]
+
+    def serve(ride):
+        r = _busy_runner(cfg, params, impl, sampling)
+        adm = r.begin_admit(r.acquire_slot(2), prompt, **sampling)
+        assert adm.slot == 2
+        if len(prompt) > 16:
+            assert adm.ride_bucket is None and adm.launch_chunk() is False
+        assert adm.ride_bucket == 16
+        if ride:
+            assert adm.launch_chunk(ride=True) is True
+            out = np.asarray(adm.first)
+            step, first, routed = out[:SLOTS], int(out[SLOTS]), out[SLOTS + 1:]
+            assert adm.first_token() == first
+        else:
+            out = np.asarray(r.step_async())
+            step, routed = out[:SLOTS], out[SLOTS:]
+            assert adm.launch_chunk() is True
+            out = np.asarray(adm.first)
+            first, routed = int(out[0]), np.stack([routed, out[1:]])
+        after = [r.step() for _ in range(3)]
+        return [step, first, *after], routed, families.host_state(r)
+
+    (want, apart, want_state), (got, routed, got_state) = (
+        serve(False), serve(True))
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    assert len(want_state) == len(got_state)
+    for a, b in zip(want_state, got_state):
+        np.testing.assert_array_equal(a, b)
+    # two live streams and the prompt's real tokens, top-3 of 8 in 8 blocks,
+    # of which the pairs that chose one of the 4 experts held here land: rows
+    # past ``length`` and slots with no stream chose no expert
+    assert 0 < routed[1] == apart[:, 1].sum() <= (
+        (2 + len(prompt)) * HF["num_experts_per_tok"] * EXPERT_BLOCKS)
+    assert apart[:, 0].max() <= routed[0] <= apart[:, 0].sum()
+    S, conv = got_state[-3:-1]          # ``rec``'s leaves: S, conv, routed
+    assert S.shape[2] == conv.shape[2] == SLOTS
+    assert not S[:, :, 3].any() and not conv[:, :, 3].any()
+
+
+def test_a_rides_padded_rows_and_idle_slots_move_nothing(ride_models):
+    """The ride's rows that are nobody's: the chunk's 7 rows past ``length``
+    and the step's rows of the slots with no stream (the new slot's own
+    among them), whatever token they hold, choose no expert and move no
+    state: the pool's live blocks, every slot's S and convolution rows, the
+    tokens and the routed count are the same to the bit; and the slot that
+    never held a stream still holds zeros."""
+    from localai_tpu.engine.runner import _prompt_counts_row
+
+    cfg, params = ride_models["float32", False]
+
+    def ride(junk):
+        r = _busy_runner(cfg, params, "loop", GREEDY)
+        adm = r.begin_admit(r.acquire_slot(2), SHORT, temperature=0.0)
+        row = np.asarray(r.allocator.table_row(adm.slot), np.int32)
+        r._arm(adm.arm_args, row)
+        # the tokens the idle slots' step rows feed
+        r.state = dataclasses.replace(r.state, tokens=jnp.where(
+            r.state.active, r.state.tokens, junk))
+        chunk = np.full((1, 16), junk, np.int32)
+        chunk[0, :9] = SHORT
+        r.kv, r.state, out = r._decode_prefill_paged(
+            r.params, r.kv, r.state, r.block_tables, chunk, np.int32(9),
+            np.int32(0), row, np.int32(adm.slot),
+            _prompt_counts_row(cfg.vocab_size, SHORT), bucket=16)
+        live = sorted({b for s in (0, 1, 2)
+                       for b in r.allocator.table_row(s) if b})
+        out = np.asarray(out)
+        keep = np.array([0, 1, SLOTS, SLOTS + 1, SLOTS + 2])
+        S, conv = (np.asarray(r.state.rec[n]) for n in ("S", "conv"))
+        assert not S[:, :, 3].any() and not conv[:, :, 3].any()
+        return (out[keep], S, conv, np.asarray(r.kv.k[:, live]),
+                np.asarray(r.kv.v[:, live]))
+
+    for a, b in zip(ride(0), ride(377)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_scheduler_rides_it_and_the_texts_are_the_same(ride_models):
+    """Through ``Scheduler``: the same three requests with the arrival's
+    chunk riding the decode step and with it stepped aside (a neighbour
+    under a constraint that allows every token: the loop's synchronous
+    branch, the chunk a launch of its own) return the same tokens at
+    temperature 0, and the ride's ``decode_chunk`` row carries the routed
+    work of both halves behind its S + 1 tokens."""
+    from localai_tpu.engine.scheduler import GenRequest, Scheduler
+    from localai_tpu.utils.tokenizer import ByteTokenizer
+
+    cfg, params = ride_models["float32", False]
+
+    class Anything:
+        done = False
+
+        def allowed_mask(self):
+            return np.zeros(cfg.vocab_size, np.float32)
+
+        def advance(self, tid):
+            pass
+
+    def wait(pred):
+        deadline = time.monotonic() + 120.0
+        while not pred() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert pred()
+
+    def serve(aside):
+        r = ride_runner(cfg, params, "loop")
+        s = Scheduler(r, ByteTokenizer(), multi_step=1)
+        greedy = dict(temperature=0.0, ignore_eos=True)
+        try:
+            a = s.submit(GenRequest(prompt=STREAMS[0], max_new_tokens=40,
+                                    **greedy))
+            b = s.submit(GenRequest(
+                prompt=PROMPT, max_new_tokens=40,
+                constraint=Anything() if aside else None, **greedy))
+            wait(lambda: min(a.completion_tokens, b.completion_tokens) >= 3)
+            c = s.generate(GenRequest(prompt=SHORT, max_new_tokens=6,
+                                      **greedy), timeout=300)
+            texts = [h.result(300).token_ids for h in (a, b)] + [c.token_ids]
+        finally:
+            s.shutdown()
+        rows = [x for x in s.flight.snapshot(limit=256)
+                if x["program"] == "decode_chunk"]
+        return texts, s.total_chunk_rides, s.total_prefill_chunks, rows
+
+    rode, rides, chunks, rows = serve(False)
+    aside, no_rides, chunks_aside, no_rows = serve(True)
+    assert rode == aside and [len(t) for t in rode] == [40, 40, 6]
+    # (the two streams' own chunks are 5 and 16 + 7 tokens: launches of their
+    # own, the first into an idle engine; the second's last of 7 rode the
+    # first's step where nothing stepped aside)
+    assert (rides, no_rides, chunks, chunks_aside) == (2, 0, 4, 4)
+    assert not no_rows and [x["chunk_tokens"] for x in rows] == [7, 9]
+    row = rows[1]
+    assert (row["steps"], row["live_slots"], row["chunk_tokens"],
+            row["chunk_bucket"]) == (1, 2, 9, 16)
+    assert 0 < row["local_assignments"] <= (
+        (2 + 9) * HF["num_experts_per_tok"] * EXPERT_BLOCKS)
+    assert EXPERT_BLOCKS <= row["experts_touched"] <= (
+        row["local_assignments"])
+
+
+@pytest.mark.parametrize("n_real", [0, 1, 5, 12])
+def test_recur_over_the_real_rows_is_the_scan_over_the_bucket(n_real):
+    """Part 4 of PR 64: ``recur`` walks rows 0 .. n_real - 1 of a chunk (a
+    loop whose trip count the program reads from ``valid``) where it scanned
+    the bucket. Through ``_gdn_mix`` (the convolution in front, the gates
+    masked by ``valid``) S, the rows the convolution keeps and every real
+    row of ``o`` are, to the bit, what ``lax.scan`` of the same ``gdn_step``
+    over all T rows leaves (the rows past ``n_real`` are the identity on S
+    by ``g = 0``, ``beta = 0``); ``o`` of the rows nobody reads is zero."""
+    from jax import lax
+
+    cfg = config()
+    T, Hv, dk, dv = 12, 4, 16, 16
+    lp = jax.tree.map(lambda a: a[0], seeded_params(cfg)["layers"])
+    rng = np.random.default_rng(n_real)
+    S0, conv0, qkv, z, b, a = (
+        jnp.asarray(rng.normal(size=s), jnp.float32) for s in (
+            (1, Hv, dk, dv), (1, 3, cfg.conv_dim), (1, T, cfg.conv_dim),
+            (1, T, Hv * dv), (1, T, Hv), (1, T, Hv)))
+    valid = jnp.arange(T)[None] < n_real
+
+    def scanned(S0, q, k, v, g, beta, valid):
+        S, o = lax.scan(lambda S, xs: qn.gdn_step(S, *xs), S0, tuple(
+            jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+        return S, jnp.moveaxis(o, 0, 1)
+
+    # (traced over its arguments: closed over, XLA would fold them)
+    @functools.partial(jax.jit, static_argnums=0)
+    def mixed(step, S0, *xs):
+        return qn._gdn_mix(cfg, *xs[:4], lp, 1,
+                           functools.partial(step, S0, valid=xs[5]), *xs[4:])
+
+    (want_o, want_S, want_conv), (got_o, got_S, got_conv) = (
+        mixed(step, S0, qkv, z, b, a, conv0, valid)
+        for step in (scanned, qn.recur))
+    np.testing.assert_array_equal(got_S, want_S)
+    np.testing.assert_array_equal(got_conv, want_conv)
+    np.testing.assert_array_equal(got_o[:, :n_real], want_o[:, :n_real])
+    assert not np.asarray(got_o[:, n_real:]).any()
+    if n_real == 0:
+        np.testing.assert_array_equal(got_S, S0)
+        np.testing.assert_array_equal(got_conv, conv0)
+    else:
+        assert np.asarray(want_o[:, :n_real]).any()
+        assert np.abs(np.asarray(got_S - S0)).max() > 1e-3

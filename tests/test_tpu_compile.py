@@ -580,11 +580,13 @@ def assert_who_writes(program, text, pool, ladder=None, quartered=False):
     Pallas call, which writes the step's rows."""
     import re
 
-    # (a routed family's grouped expert kernel aside: it touches no pool,
-    # and tests/test_lfm2_compile.py counts its calls)
+    # (a routed family's grouped expert kernel and a DeltaNet step's aside:
+    # they touch no pool; tests/test_lfm2_compile.py and the hybrid cell's
+    # cases below count their calls)
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln
-             and "moe/experts/moe_experts" not in ln]
+             and "moe/experts/moe_experts" not in ln
+             and "gdn/state/gdn_state_step" not in ln]
     rides = program.startswith("decode_prefill_")
     if rides or not program.startswith("decode"):
         assert rides or not calls
@@ -780,6 +782,117 @@ def test_hybrid_cell_programs_fit_one_chip(topo, monkeypatch, cell, program):
     assert need / 2**30 <= hbm["largest_program_gib"] + over
     # over the floor a new cell is held to: a quarter of the chip
     assert 0.25 * HBM_BYTES < need < HBM_BYTES
+
+
+@pytest.mark.parametrize("cell", [QN80], indirect=True)
+def test_hybrid_cells_last_chunk_rides_the_step(topo, monkeypatch, cell):
+    """PR 64: at the hybrid cell's served shapes (32 slots, bucket 128,
+    ``kv_num_blocks`` 641) a prompt's last chunk and the decode step are ONE
+    program through the family's own forward (``_decode_prefill_paged_fn``,
+    ``models.qwen3_next.forward(ride=128)``), held to what PR 59's is for
+    the dense stack (no second pool, the chunk's scatters and ONE
+    conditional over its spans, ONE paged kernel call that writes the step's
+    rows) and to what this cell's programs are above: the grouped expert
+    kernel once an expert block of the (rolled) period over the 160 rows of
+    both halves, the DeltaNet step's kernel once a DeltaNet layer on the
+    carried state WHOLE and aliased, and behind it the chunk's ``rec_write``
+    onto the same buffer: one slot's rows, never a ``[P, G, slots, ...]``
+    copy between the two; the DeltaNet projections over 160 rows and over no
+    half alone; no period of experts or of projections staged, and no
+    weight-shaped copy that its halves' own programs do not make. Its
+    arguments are the decode step's (6.271 GiB); its temps read 5.62 MiB
+    beside the 128 chunk's 3.85 and the step's 3.20, its need 6.301 GiB
+    beside 6.291 and 6.286, under the file's ``largest_program_gib``."""
+    import re
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    slots = eng["max_slots"]
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="", num_slots=slots,
+        max_ctx=doc["context_size"], kv_num_blocks=eng["kv_num_blocks"],
+        kv_block_tokens=64)
+    assert r.rides and r.own_forward
+    rows = 128 + slots
+    pool = a["kv"].k.shape
+    need, temps, texts = {}, {}, {}
+    for program in ("decode_prefill_128", "prefill_chunk_128_sample",
+                    "decode"):
+        c = compile_cell_program(r, a, program)
+        texts[program] = c.as_text()
+        m = c.memory_analysis()
+        temps[program] = m.temp_size_in_bytes
+        need[program] = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                         + m.output_size_in_bytes - m.alias_size_in_bytes
+                         + m.generated_code_size_in_bytes)
+        if program == "decode_prefill_128":
+            ride, args = c, m.argument_size_in_bytes
+    print({k: f"need {need[k] / 2**30:.3f} GiB temp {v / 2**20:.2f} MiB"
+           for k, v in temps.items()},
+          f"ride arguments {args / 2**30:.3f} GiB")
+    assert_in_place("decode_prefill_128", ride, pool)
+    text = texts["decode_prefill_128"]
+    assert_who_writes("decode_prefill_128", text, pool,
+                      spans(r, "decode_prefill_128"))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    experts = [ln for ln in calls if "moe/experts/moe_experts" in ln]
+    steps = [ln for ln in calls if "gdn/state/gdn_state_step" in ln]
+    assert (len(experts), len(steps), len(calls)) == (
+        cfg.full_attention_interval, cfg.gdn_per_period,
+        cfg.full_attention_interval + cfg.gdn_per_period + 1)
+    for ln in experts:                              # both halves' rows
+        assert ln.split(" custom-call(")[0].count(f"f32[{rows},2048]") == 1
+    # the step's kernel takes the carried state whole and hands it back as
+    # the same buffer; the chunk's rows are then laid onto that buffer (one
+    # slot's: ``f32[1,1,1,32,128,128]``), and nothing copies the carry
+    carried = "f32[3,3,32,32,128,128]"
+    for ln in steps:
+        assert ln.split("= (")[1].startswith(carried)
+        assert "output_to_operand_aliasing={{0}: (1, {})}" in ln
+    assert not [ln for ln in text.splitlines()
+                if f"= {carried}" in ln and re.search(r" copy(-start)?\(", ln)]
+    # nor is a layer's state of every slot, or a period's, sliced out of it
+    assert "f32[32,32,128,128]" not in text
+    assert "f32[3,32,32,128,128]" not in text
+    # the DeltaNet's projections run every row once, no half alone
+    W = cfg.conv_dim + cfg.value_dim
+    assert re.search(rf"bf16\[(1,)?{rows},{W}\]", text)
+    assert not re.search(rf"bf16\[(1,)?(128|{slots})(,1)?,{W}\]", text)
+    # no period of DeltaNet projections, no block's experts nor a period's
+    for staged in ("bf16[3,2048,12288]", "bf16[64,2048,512]",
+                   "bf16[64,512,2048]", "bf16[4,64,2048,512]",
+                   "bf16[4,64,512,2048]"):
+        assert staged not in text
+    # and no leaf's matrix copied on its way to a product that its two
+    # halves' own programs do not copy (a copy of a weight's shape is a
+    # read of it)
+    import jax
+
+    matrices = {leaf.shape[-2:] for leaf in jax.tree.leaves(a["params"])
+                if leaf.ndim >= 2 and leaf.shape[-2] * leaf.shape[-1] >= 2**20}
+    assert (2048, W) in matrices and (2048, 512) in matrices
+
+    def copied(text):
+        return sorted(
+            ln.split(" = ")[1].split("{")[0].lstrip("(")
+            for ln in text.splitlines()
+            if re.search(r" copy(-start|-done)?\(", ln) and any(
+                re.search(rf"\[([0-9]+,)*{m},{n}\]", ln.split(" copy")[0])
+                for m, n in matrices))
+
+    assert set(copied(text)) <= (
+        set(copied(texts["prefill_chunk_128_sample"]))
+        | set(copied(texts["decode"])))
+    state_bytes = int(np.prod(a["state"].rec["S"].shape)) * 4
+    assert temps["decode_prefill_128"] < (
+        temps["prefill_chunk_128_sample"] + temps["decode"] + 16 * 2**20)
+    assert temps["decode_prefill_128"] < state_bytes / 8
+    hbm = doc["hbm"]
+    assert (hbm["arguments_gib"] - 0.01 < args / 2**30
+            <= hbm["arguments_gib"] + 0.005)
+    assert need["decode_prefill_128"] / 2**30 <= (
+        hbm["largest_program_gib"] + 0.001)
 
 
 @pytest.mark.parametrize("cell", [TRL], indirect=True)
