@@ -9,19 +9,26 @@ experts that HAVE a token (ops.moe's grouped kernel where attention's are
 kernels, ``experts_loop`` as XLA); the shared expert is added. On one chip the
 block runs without its exchange: the sum is this chip's partial result.
 
-Two things differ between the families, and are the block's arguments: the
+Three things differ between the families, and are the block's arguments: the
 scoring rule (``softmax_scores`` / ``sigmoid_scores``, the latter plain or
-group-limited: logits -> a token's k weights and choices) and the shared
+group-limited: logits -> a token's k weights and choices), the shared
 expert (a closure: under a sigmoid gate, ungated, or None where the family
-has no shared expert). Everything else is here once: the weights of the held experts, the
-tokens an expert got, the order of the walk, the two counts a launch reports
-([experts touched, token-expert pairs that landed here]; engine.scheduler
-``_routed``), the scopes ``router``, ``experts``, ``shared``.
+has no shared expert) and the function on an expert's gate (``act``: SiLU;
+ReLU for models.smallthinker). Everything else is here once: the weights of
+the held experts, the tokens an expert got, the order of the walk, the two
+counts a launch reports ([experts touched, token-expert pairs that landed
+here]; engine.scheduler ``_routed``), the scopes ``router``, ``experts``,
+``shared``.
+
+``moe_block`` is the two halves in a row on ONE tensor: ``route`` (scope
+``router``) and ``walk`` (scope ``experts``). A family whose router reads
+another tensor than its experts (models.smallthinker: the attention's input)
+calls the halves apart and hands the first's ``Routing`` to the second.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -97,24 +104,45 @@ def sigmoid_scores(k: int, bias, renormalise: bool, scale: float, *,
     return score
 
 
+class Routing(NamedTuple):
+    """What ``route`` hands ``walk``: the held experts' weights [N, E]
+    float32 (0 off a token's choices and for a token that is not real), the
+    tokens each got [E] i32, the experts that have a token first (``order``
+    [E] i32) and how many those are."""
+
+    weights: jax.Array
+    load: jax.Array
+    order: jax.Array
+    n_touched: jax.Array
+
+
 def route(h, w_router, score: Callable, num_experts: int, ep_rank: int,
-          valid):
-    """Routing weights of the experts HELD here. h [N, D] -> (weights
-    [N, E] float32, 0 off a token's choices and for a token that is not
-    real; tokens an expert got [E] i32)."""
+          valid) -> Routing:
+    """The block's first half, under the scope ``router``: normed h [N, D]
+    scored over ALL experts, and what of it lands on the experts HELD
+    here."""
     E = num_experts
-    logits = qnt.matmul(h, w_router).astype(jnp.float32)      # over ALL
-    topv, topi = score(logits)
-    local = topi - ep_rank * E
-    here = (local >= 0) & (local < E) & valid[:, None]
-    onehot = jax.nn.one_hot(jnp.where(here, local, E), E, dtype=jnp.float32)
-    return (jnp.sum(onehot * topv[..., None], axis=1),
-            jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32))
+    with jax.named_scope("router"):
+        logits = qnt.matmul(h, w_router).astype(jnp.float32)      # over ALL
+        topv, topi = score(logits)
+        local = topi - ep_rank * E
+        here = (local >= 0) & (local < E) & valid[:, None]
+        onehot = jax.nn.one_hot(jnp.where(here, local, E), E,
+                                dtype=jnp.float32)
+        weights = jnp.sum(onehot * topv[..., None], axis=1)
+        load = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)
+        touched = load > 0
+        n_touched = jnp.sum(touched).astype(jnp.int32)
+        # the experts that have a token first, in their own order
+        order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
+    return Routing(weights, load, order, n_touched)
 
 
-def experts_loop(h, weights, order, n_touched, experts, p, m_idx):
+def experts_loop(h, weights, order, n_touched, experts, p, m_idx,
+                 act: Callable = jax.nn.silu):
     """ops.moe.moe_experts as XLA, and its oracle: a loop over the touched
-    experts, three dots behind a scalar-indexed slice an iteration."""
+    experts, three dots behind a scalar-indexed slice an iteration; ``act``
+    the function on the gate's product."""
     w_gate, w_up, w_down = experts
 
     def pick(w, e):
@@ -123,7 +151,7 @@ def experts_loop(h, weights, order, n_touched, experts, p, m_idx):
 
     def one_expert(i, acc):
         e = order[i]
-        y = (jax.nn.silu(qnt.matmul(h, pick(w_gate, e)))
+        y = (act(qnt.matmul(h, pick(w_gate, e)))
              * qnt.matmul(h, pick(w_up, e)))
         y = qnt.matmul(y, pick(w_down, e))
         col = lax.dynamic_index_in_dim(weights, e, 1, keepdims=True)
@@ -133,10 +161,29 @@ def experts_loop(h, weights, order, n_touched, experts, p, m_idx):
                          jnp.zeros(h.shape, jnp.float32))
 
 
+def walk(h, routed: Routing, experts, p, m_idx, *,
+         experts_kernel: Optional[bool] = None,
+         act: Callable = jax.nn.silu):
+    """The block's second half, under the scope ``experts``: this chip's
+    part of the routed sum of normed h [N, D] under ``routed``, [N, D]
+    float32. ``experts``, ``p``, ``m_idx``, ``experts_kernel`` and ``act``
+    are ``moe_block``'s."""
+    with jax.named_scope("experts"):
+        if experts_kernel is not None:
+            from localai_tpu.ops import moe
+
+            return moe.moe_experts(h, routed.weights, routed.order,
+                                   routed.n_touched, experts, p, m_idx,
+                                   interpret=experts_kernel, act=act)
+        return experts_loop(h, routed.weights, routed.order,
+                            routed.n_touched, experts, p, m_idx, act)
+
+
 def moe_block(h, w_router, score: Callable, experts, p, m_idx, *,
               num_experts: int, ep_rank: int, valid,
               shared: Optional[Callable],
-              experts_kernel: Optional[bool] = None):
+              experts_kernel: Optional[bool] = None,
+              act: Callable = jax.nn.silu):
     """One expert block on normed h [N, D]: this chip's part of the routed
     sum plus the shared expert. ``experts``: the three stacked expert leaves
     WHOLE ([rows, M, E, ...]), indexed here by (p, m_idx, expert) so that a
@@ -144,29 +191,17 @@ def moe_block(h, w_router, score: Callable, experts, p, m_idx, *,
     h -> the shared expert's [N, D] float32; None where the family has none
     (nothing is formed or added). ``experts_kernel``: None is the
     XLA loop over the touched experts, else ops.moe's grouped kernel (the
-    value: in the Pallas interpreter). Returns (out [N, D], experts touched,
-    tokens each held expert got [E]): ``counts`` makes the launch's two
-    numbers of the last two."""
-    with jax.named_scope("router"):
-        weights, load = route(h, w_router, score, num_experts, ep_rank, valid)
-        touched = load > 0
-        n_touched = jnp.sum(touched).astype(jnp.int32)
-        # the experts that have a token first, in their own order
-        order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
-    with jax.named_scope("experts"):
-        if experts_kernel is not None:
-            from localai_tpu.ops import moe
-
-            routed = moe.moe_experts(h, weights, order, n_touched, experts,
-                                     p, m_idx, interpret=experts_kernel)
-        else:
-            routed = experts_loop(h, weights, order, n_touched, experts,
-                                  p, m_idx)
+    value: in the Pallas interpreter). ``act``: the function on an expert's
+    gate. Returns (out [N, D], experts touched, tokens each held expert got
+    [E]): ``counts`` makes the launch's two numbers of the last two."""
+    routed = route(h, w_router, score, num_experts, ep_rank, valid)
+    out = walk(h, routed, experts, p, m_idx, experts_kernel=experts_kernel,
+               act=act)
     if shared is None:
-        return routed.astype(h.dtype), n_touched, load
+        return out.astype(h.dtype), routed.n_touched, routed.load
     with jax.named_scope("shared"):
-        out = (routed + shared(h)).astype(h.dtype)
-    return out, n_touched, load
+        out = (out + shared(h)).astype(h.dtype)
+    return out, routed.n_touched, routed.load
 
 
 def counts(n_touched, load):

@@ -261,6 +261,7 @@ FAMILIES = {
     "falcon_h1": "falcon_h1",
     "lfm2_moe": "lfm2",
     "minicpm_sala": "minicpm_sala",
+    "smallthinker": "smallthinker",
 }
 
 

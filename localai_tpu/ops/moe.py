@@ -16,13 +16,14 @@ The form is the loop's: every row against every touched expert, the routing
 weight 0 where a token did not choose it (bytes-bound at a decode step's and a
 chunk's row counts), the experts summed in the same order into a float32
 [N, D] block that stays in VMEM for the whole walk. The operands are the
-leaves' dtype and every product accumulates in float32; ``silu(gate) * up`` is
-formed in float32 and rounded once, for the third dot (the loop rounds each
-dot's result).
+leaves' dtype and every product accumulates in float32; ``act(gate) * up``
+(``act`` the family's: SiLU, or ReLU for models.smallthinker) is formed in
+float32 and rounded once, for the third dot (the loop rounds each dot's
+result).
 
 An expert too large to lie in VMEM whole, twice (``BLOCK_BYTES``: 3072 x 3072
 is 18 MiB a matrix), goes through in TILES of its intermediate width F, an
-innermost grid axis: ``(silu(h Wg[:, f]) * (h Wu[:, f])) Wd[f, :]`` summed
+innermost grid axis: ``(act(h Wg[:, f]) * (h Wu[:, f])) Wd[f, :]`` summed
 over the tiles f is the expert (the down projection is linear in F), each
 tile's product rounded once as the whole expert's is. An expert that fits
 (2048 x 512) has no such axis: its program is what it was.
@@ -75,7 +76,7 @@ def f_tile(D: int, F: int, itemsize: int) -> int:
 
 
 def _kernel(order_ref, meta_ref, h_ref, wts_ref, wg_ref, wu_ref, wd_ref,
-            o_ref, *, tiled: bool):
+            o_ref, *, tiled: bool, act):
     i = pl.program_id(1)
     first = i == 0
     if tiled:       # the walk's first step is the first expert's first tile
@@ -90,7 +91,7 @@ def _kernel(order_ref, meta_ref, h_ref, wts_ref, wg_ref, wu_ref, wd_ref,
         h = h_ref[...]
         gate = jnp.dot(h, wg_ref[...], preferred_element_type=jnp.float32)
         up = jnp.dot(h, wu_ref[...], preferred_element_type=jnp.float32)
-        y = (jax.nn.silu(gate) * up).astype(h.dtype)
+        y = (act(gate) * up).astype(h.dtype)
         y = jnp.dot(y, wd_ref[...], preferred_element_type=jnp.float32)
         # the expert's column of the routing weights: one lane of [rows, E]
         wts = wts_ref[...]
@@ -101,9 +102,9 @@ def _kernel(order_ref, meta_ref, h_ref, wts_ref, wg_ref, wu_ref, wd_ref,
 
 
 def moe_experts(h, weights, order, n_touched, experts, p, m_idx, *,
-                interpret: bool = False):
+                interpret: bool = False, act=jax.nn.silu):
     """sum over the first ``n_touched`` experts e of ``order`` of
-    ``weights[:, e] * (silu(h @ w_gate[p, m_idx, e]) * (h @ w_up[p, m_idx,
+    ``weights[:, e] * (act(h @ w_gate[p, m_idx, e]) * (h @ w_up[p, m_idx,
     e])) @ w_down[p, m_idx, e]``: [N, D] float32.
 
     h [N, D]; weights [N, E] float32; order [E] i32; ``experts`` the three
@@ -135,7 +136,7 @@ def moe_experts(h, weights, order, n_touched, experts, p, m_idx, *,
         return r, 0
 
     out = pl.pallas_call(
-        functools.partial(_kernel, tiled=tiled),
+        functools.partial(_kernel, tiled=tiled, act=act),
         name="moe_experts",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

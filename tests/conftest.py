@@ -75,7 +75,10 @@ SLOW_MODULES = {
 # moved ``test_paged_serving`` (131 with its ride cases) and ``test_scheduler``
 # (60) up to where their seconds stand; PR 61 reordered the first twenty by
 # its own run's table: 709 for the first, ``test_lfm2`` 432 with its ride
-# cases, ``test_lfm2_compile`` 128 with the ride program's compile). They
+# cases, ``test_lfm2_compile`` 128 with the ride program's compile; PR 65
+# put in, where its run's table has them, four files the alphabet sent out
+# late: ``test_smallthinker`` 163, ``test_minicpm_sala`` 114,
+# ``test_minicpm_sala_compile`` 99, ``test_bench_walk_router`` 59). They
 # are collected FIRST: under ``-n 6 --dist loadfile`` a file goes whole to the
 # next free worker in collection order, so the run's wall is a sixth of the
 # files' sum and no late file is its tail (``test_tpu_compile``, one file for
@@ -85,9 +88,10 @@ LONGEST_FIRST = (
     "test_afmoe", "test_deepseek", "test_dots3", "test_neighbour_texts",
     "test_chip_smoke", "test_paged_serving", "test_kv_contract",
     "test_overlap", "test_bench_walk_latent", "test_bench_walk",
-    "test_lfm2_compile", "test_dots3_compile", "test_ouro",
-    "test_falcon_h1", "test_prefill_span", "test_moe_kernel", "test_int4",
-    "test_spec",
+    "test_smallthinker", "test_lfm2_compile", "test_dots3_compile",
+    "test_minicpm_sala", "test_minicpm_sala_compile", "test_ouro",
+    "test_falcon_h1", "test_prefill_span", "test_moe_kernel",
+    "test_bench_walk_router", "test_int4", "test_spec",
     "test_bench_trace", "test_scheduler", "test_falcon_h1_compile",
     "test_sampling", "test_fleet",
 )

@@ -23,6 +23,7 @@ from test_falcon_h1 import HF as FALCON_H1
 from test_lfm2 import HF as LFM2
 from test_minicpm_sala import HF as MINICPM_SALA
 from test_qwen3_next import HF as QWEN3_NEXT
+from test_smallthinker import HF as SMALLTHINKER
 
 from localai_tpu.engine.runner import ModelRunner
 from localai_tpu.models import llama as mdl
@@ -31,7 +32,7 @@ from localai_tpu.models.registry import synthetic_params
 
 HF = {"qwen3_next": QWEN3_NEXT, "afmoe": AFMOE, "axk1": AXK1,
       "dots3_note": DOTS3, "falcon_h1": FALCON_H1, "lfm2_moe": LFM2,
-      "minicpm_sala": MINICPM_SALA}
+      "minicpm_sala": MINICPM_SALA, "smallthinker": SMALLTHINKER}
 CONTRACT = ("CONFIG", "param_shapes", "init_leaf", "checkpoint_leaves",
             "init_rec", "forward", "UNSERVED", "WEIGHTS", "WHY")
 KEYWORDS = ("rec", "valid", "slot", "fresh", "kernels")

@@ -83,6 +83,41 @@ def test_the_kernel_is_the_loop(dtype, n_rows, touched, at):
     assert np.abs(np.asarray(got - want)).max() < TOL[dtype]
 
 
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+@pytest.mark.parametrize("touched", [[5], [6, 1, 3], list(range(E))],
+                         ids=["one", "some", "all"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("gate", sorted(GATES))
+def test_the_gates_function_is_the_familys(gate, dtype, touched):
+    """PR 65: the function on an expert's gate is an argument of kernel and
+    loop (SiLU for five families, ReLU for models.smallthinker): under each
+    the kernel's [N, D] is the loop's under the same one, and the other
+    function's is another number."""
+    experts = leaves(dtype)
+    h = jnp.asarray(np.random.default_rng(len(touched)).standard_normal(
+        (32, D)), dtype)
+    weights, order = routed(32, touched, seed=32)
+    n = jnp.int32(len(touched))
+
+    @jax.jit
+    def kernel(p):
+        return moe.moe_experts(h, weights, order, n, experts, p, 2,
+                               interpret=True, act=GATES[gate])
+
+    got = kernel(jnp.int32(1))
+    want = xp.experts_loop(h, weights, order, n, experts, 1, 2, GATES[gate])
+    other = xp.experts_loop(h, weights, order, n, experts, 1, 2,
+                            GATES["relu" if gate == "silu" else "silu"])
+    assert np.abs(np.asarray(want)).max() > 0.5
+    assert np.abs(np.asarray(want - other)).max() > 0.2
+    assert np.abs(np.asarray(got - want)).max() < TOL[dtype]
+    if gate == "silu":      # the default of both is what it was
+        assert np.array_equal(np.asarray(want), np.asarray(xp.experts_loop(
+            h, weights, order, n, experts, 1, 2)))
+
+
 @pytest.mark.parametrize("touched", [[], [6, 1, 3], list(range(E))],
                          ids=["none", "some", "all"])
 @pytest.mark.parametrize("n_rows", [32, moe.ROW_TILE + 40])

@@ -136,6 +136,7 @@ from test_falcon_h1 import HF as FALCON_H1
 from test_lfm2 import HF as LFM2
 from test_minicpm_sala import HF as MINICPM_SALA
 from test_qwen3_next import HF as QWEN3_NEXT
+from test_smallthinker import HF as SMALLTHINKER
 
 from localai_tpu.engine.runner import ModelRunner
 from localai_tpu.models import llama as mdl
@@ -143,6 +144,8 @@ from localai_tpu.models.registry import synthetic_params
 
 # a head of 128 lanes and experts of 128 (what the compiled kernels take)
 WIDE = {"head_dim": 128, "moe_intermediate_size": 128}
+# ... under the names ``smallthinker`` publishes
+WIDE_SMT = {"head_dim": 128, "moe_ffn_hidden_size": 128}
 # ... and a mixer head of 128 with a state of 128
 WIDE_SSM = {"head_dim": 128, "mamba_d_head": 128, "mamba_d_ssm": 512,
             "mamba_d_state": 128}
@@ -333,6 +336,20 @@ TAKEN = [
             "4310671fe5e13920280e9991b4edb573c2e75ae46775fd0e2b2a77571ca471c3",
         "prefill_0":
             "cfa6079c1431318847d1fa804ef2fa707cbc57b809581547d04c76870f0f57c4"}),
+    family({**SMALLTHINKER, **WIDE_SMT}, 16, "pallas_interpret", {
+        "decode":
+            "c9f00a69a262b137a9c2466e8f702a496aabfbbcab517ea8cd6f77de6198c809",
+        "prefill_1":
+            "bc538ca9cc7f1c6a62e3c9636a1f4b3668d983b79f1b5730699c13dd650c3a30",
+        "prefill_0":
+            "701a8bb556a2619f3fd9e69b52a706078bd0cf3a5b6585e1d12ade8a574739b3"}),
+    family({**SMALLTHINKER, **WIDE_SMT}, 16, "xla", {
+        "decode":
+            "542c7dd3fe9688b6a07cb5038dca1ef70c75384fc7b33cb47db54e554d21f43e",
+        "prefill_1":
+            "0f2dd2302fb062c8c306da6e58ccc1cd48a7c8cd36854881258782dc1e7620c6",
+        "prefill_0":
+            "53c6491e8a96ac18fe0e0667d82fdb1fc97ff58773084e293d10a89b46ad3418"}),
 ]
 
 

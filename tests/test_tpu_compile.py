@@ -458,6 +458,7 @@ OURO = "ouro-2.6b-int8"
 QN80 = "qwen3-next-80b-a3b-ep8"
 TRL = "trinity-large-ep8"
 AXK1 = "axk1-ep16"
+SMT = "smallthinker-21b-a3b-pp4"
 
 
 @pytest.fixture
@@ -972,6 +973,90 @@ def test_mixed_attention_cell_programs_fit_one_chip(topo, monkeypatch, cell,
           f"{need / 2**30:.3f} GiB")
     # the pool is written in place: a program's temps hold no second one
     assert m.temp_size_in_bytes < 2 * pool_bytes
+    if program == "decode":
+        assert m.temp_size_in_bytes < 0.25 * 2**30
+    hbm = doc["hbm"]
+    assert (hbm["arguments_gib"] - 0.16 < m.argument_size_in_bytes / 2**30
+            <= hbm["arguments_gib"] + 0.005)
+    assert need / 2**30 <= hbm["largest_program_gib"] + 0.001
+    assert 0.25 * HBM_BYTES < need < HBM_BYTES
+
+
+@pytest.mark.parametrize("cell", [SMT], indirect=True)
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk_128_sample",
+                                     "prefill_chunk_512_sample"])
+def test_router_first_cell_programs_fit_one_chip(topo, monkeypatch, cell,
+                                                 program):
+    """PR 65: the configuration FILE of the stack whose router stands in
+    front of attention (3 rows of F W W W, 64 ReLU-gated experts a layer all
+    held, bfloat16 weights, a 12 x 2048-block pool, 14336 positions, 28
+    query heads in groups of 7 a K/V head, a vocabulary of 151936) compiles
+    for one v5e chip and fits it, with the numbers its ``hbm`` block
+    restates. What Mosaic compiled of a decode program: in the row scan's
+    body the paged kernel ONCE under ``attn.paged_decode`` (the full layer)
+    and three times under ``attn.window_decode``, and ``moe_experts`` once a
+    layer with the three expert leaves its operands WHOLE (an expert's 11.8
+    MB lie in VMEM whole, twice: no F axis); of a prefill chunk the
+    ``moe_experts`` calls alone. The pool is the scan's carry: no
+    pool-shaped temp, no layer's experts (755 MB) staged."""
+    from localai_tpu.ops import moe
+
+    cfg, doc = cell
+    eng = doc["engine"]
+    assert cfg.attn_kinds == (("sliding_attention", 4096),
+                              ("full_attention", None))
+    assert cfg.row_kinds == ("full_attention",) + ("sliding_attention",) * 3
+    assert (cfg.rows, cfg.q_per_kv, cfg.vocab_size) == (3, 7, 151936)
+    assert cfg.routed and not cfg.recurrent and not eng.get("quantization")
+    assert moe.f_tile(2560, 768, 2) == 768
+    r, a = abstract_runner(
+        topo, monkeypatch, cfg, quantization="",
+        num_slots=eng["max_slots"], max_ctx=doc["context_size"],
+        kv_num_blocks=eng["kv_num_blocks"], kv_block_tokens=64)
+    pool = a["kv"].k.shape
+    assert pool == (12, eng["kv_num_blocks"], 4, 64, 128)
+    assert a["kv"].k.dtype == bf16 and r.max_blocks == 224
+    c = compile_cell_program(r, a, program)
+    text = c.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    experts = [ln for ln in calls if "moe/experts/moe_experts" in ln]
+    rows = (eng["max_slots"] if program == "decode"
+            else int(program.split("_")[2]))
+    leaves = tuple(a["params"]["layers"][n] for n in ("w_gate", "w_up",
+                                                      "w_down"))
+    assert [w.shape for w in leaves] == [
+        (12, 64, 2560, 768)] * 2 + [(12, 64, 768, 2560)]
+    assert len(experts) == 4
+    for ln in experts:
+        assert f"f32[{rows},2560]" in ln.split("custom-call(")[0]
+        # the stack WHOLE, through the bitcast [rows, M, E, ...]
+        assert "bf16[3,4,64,2560,768]" in ln
+    rest = [ln for ln in calls if ln not in experts]
+    if program == "decode":
+        window = [ln for ln in rest
+                  if "attn.window_decode/paged_decode_attn" in ln]
+        full = [ln for ln in rest
+                if "attn.paged_decode/paged_decode_attn" in ln]
+        assert (len(window), len(full), len(rest)) == (3, 1, 4)
+    else:
+        assert not rest
+        assert "attn.prefill_window" in text
+    # no layer's or row's experts staged, no second pool
+    for staged in ("bf16[64,2560,768]", "bf16[4,64,2560,768]",
+                   "bf16[1,64,2560,768]"):
+        assert staged not in text.replace("parameter(", "").split(
+            "ENTRY")[0]
+    m = c.memory_analysis()
+    pool_bytes = int(np.prod(pool)) * 2
+    need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    print(f"HBM {program}: arguments {m.argument_size_in_bytes / 2**30:.3f} "
+          f"temp {m.temp_size_in_bytes / 2**30:.4f} in all "
+          f"{need / 2**30:.3f} GiB")
+    # the pool is written in place: a program's temps hold no second one
+    assert m.temp_size_in_bytes < pool_bytes
     if program == "decode":
         assert m.temp_size_in_bytes < 0.25 * 2**30
     hbm = doc["hbm"]
